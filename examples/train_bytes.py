@@ -41,9 +41,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from torchft_tpu.utils.platform import pin_platform_from_env
+from torchft_tpu.utils.compile_cache import place_compile_cache
 
-pin_platform_from_env()
+place_compile_cache()  # before first use of jax; children inherit it
 import jax
 import jax.numpy as jnp
 import optax

@@ -85,7 +85,8 @@ int tcp_listen(const std::string& bind_addr, std::string* err) {
   bool v6 = host.empty() || host == "::" || host.find(':') != std::string::npos;
   int fd = socket(v6 ? AF_INET6 : AF_INET, SOCK_STREAM, 0);
   if (fd < 0) {
-    if (err) *err = std::string("socket: ") + errno_str(errno);
+    // e.g. "[::]:0" on a host without IPv6: name the address asked for
+    if (err) *err = "socket for " + bind_addr + ": " + errno_str(errno);
     return -1;
   }
   int on = 1;
@@ -127,7 +128,7 @@ int tcp_listen(const std::string& bind_addr, std::string* err) {
     rc = bind(fd, (sockaddr*)&sa, sizeof(sa));
   }
   if (rc != 0 || listen(fd, 1024) != 0) {
-    if (err) *err = std::string("bind/listen: ") + errno_str(errno);
+    if (err) *err = "bind/listen " + bind_addr + ": " + errno_str(errno);
     close(fd);
     return -1;
   }

@@ -17,7 +17,6 @@ import json
 import os
 import re
 import signal
-import subprocess
 import sys
 import time
 import pytest
@@ -28,7 +27,7 @@ from torchft_tpu.coordination import LighthouseServer
 
 # multi-process soak tier: excluded from the default run (pyproject
 # addopts); execute with `pytest -m soak`
-from conftest import scaled_timeout
+from conftest import finish_logged, spawn_logged
 
 pytestmark = pytest.mark.soak
 
@@ -66,11 +65,8 @@ def _spawn(gid, lighthouse_addr, tmp, plane_env=None):
         JAX_PLATFORMS="cpu",
     )
     env.update(plane_env or {})
-    return subprocess.Popen(
-        [sys.executable, os.path.join(_EXAMPLES, "train_bytes.py")],
-        env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
+    return spawn_logged(
+        [sys.executable, os.path.join(_EXAMPLES, "train_bytes.py")], env, tmp, gid
     )
 
 
@@ -114,9 +110,7 @@ def test_repeated_kill_restart_converges(tmp_path, plane):
 
         outs = {}
         for g in (0, 1):
-            out, _ = procs[g].communicate(timeout=scaled_timeout(300))
-            assert procs[g].returncode == 0, out.decode()[-2000:]
-            outs[g] = out.decode()
+            outs[g] = finish_logged(procs[g], tmp, g)
     finally:
         for p in procs.values():
             if p.poll() is None:

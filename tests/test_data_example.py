@@ -10,7 +10,6 @@ Reference behavior being matched: train_ddp.py:34-80's stateful dataloader
 import json
 import os
 import signal
-import subprocess
 import sys
 import time
 
@@ -21,7 +20,7 @@ from torchft_tpu.coordination import LighthouseServer
 
 # multi-process soak tier: excluded from the default run (pyproject
 # addopts); execute with `pytest -m soak`
-from conftest import scaled_timeout
+from conftest import finish_logged, spawn_logged
 
 pytestmark = pytest.mark.soak
 
@@ -47,11 +46,8 @@ def _spawn(gid, lighthouse_addr, tmp, env_extra=None):
     )
     if env_extra:
         env.update(env_extra)
-    return subprocess.Popen(
-        [sys.executable, os.path.join(_EXAMPLES, "train_bytes.py")],
-        env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
+    return spawn_logged(
+        [sys.executable, os.path.join(_EXAMPLES, "train_bytes.py")], env, tmp, gid
     )
 
 
@@ -90,8 +86,7 @@ def test_kill_restart_no_sample_skipped_or_repeated(tmp_path):
         # restart: disk-resume + live heal, then run to completion
         procs[1] = _spawn(1, addr, tmp)
         for g in (0, 1):
-            out, _ = procs[g].communicate(timeout=scaled_timeout(300))
-            assert procs[g].returncode == 0, out.decode()[-2000:]
+            finish_logged(procs[g], tmp, g)
     finally:
         for p in procs.values():
             if p.poll() is None:

@@ -100,9 +100,11 @@ def test_hsdp_example_two_groups():
         num_groups=2,
         extra_env={
             "STEPS": "3",
-            "DEVICES_PER_GROUP": "4",
             "FSDP": "2",
             "TP": "2",
+            # one group = one process with its own devices: each gets a
+            # 4-device virtual platform (on the TPU: its own chips)
+            "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
         },
     )
     sums = _checksums(logs)

@@ -119,6 +119,26 @@ def test_use_flash_auto_threshold(monkeypatch):
         assert not T._use_flash(cfg, 32768, 1)
 
 
+@pytest.mark.parametrize("impl", ["flash", "auto"])
+def test_flash_inside_pipeline_region_raises(monkeypatch, impl):
+    """No quiet fallback: when flash is wanted (explicitly, or by `auto`
+    because plain attention's scores would not fit) inside the pipeline's
+    manual region, plain attention cannot fit either — raise."""
+    from torchft_tpu.models import transformer as T
+    from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    monkeypatch.setattr(T, "_use_flash", lambda *a, **k: True)
+    cfg = T.TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
+        d_ff=64, dtype=jnp.float32, pp=2, microbatches=2, attention_impl=impl,
+    )
+    mesh = make_mesh(MeshConfig(pp=2))
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((4, 96), jnp.int32)  # 96 % 128 != 0: not chunked
+    with jax.set_mesh(mesh), pytest.raises(ValueError, match="manual region"):
+        jax.jit(lambda p, t: T.loss_fn(p, t, cfg, mesh))(params, tokens)
+
+
 def test_chunked_loss_matches_dense(monkeypatch):
     """Long-context loss head: chunked cross entropy (scan over the
     unembed, [S,V] logits never materialized) must match the dense path

@@ -223,6 +223,42 @@ class TestStripedMultiSource:
         out = rx.recv_checkpoint_multi([s1.metadata()], 8, T)
         _tree_equal(out, state)
 
+    def test_slow_staging_is_waited_for(self, transports, monkeypatch):
+        """Flatten + digest of a multi-GB state outlasts the brief
+        staging-window bound. First contact at 647M parameters (7.8 GB of
+        state on a v5e host): every heal answered 503 "no checkpoint
+        staged within timeout" and no step ever committed. A source that
+        IS staging must be waited for."""
+        monkeypatch.setenv("TORCHFT_HEAL_META_TIMEOUT_S", "0.3")
+        real = dm.leaf_digests
+
+        def slow_digests(buffers):
+            time.sleep(1.5)  # well past the 0.3 s bound
+            return real(buffers)
+
+        monkeypatch.setattr(dm, "leaf_digests", slow_digests)
+        state = _state(9)
+        s1, rx = transports(), transports()
+        staging = threading.Thread(
+            target=s1.send_checkpoint, args=([1], 9, state, T)
+        )
+        staging.start()
+        try:
+            out = rx.recv_checkpoint_multi([s1.metadata()], 9, T)
+        finally:
+            staging.join(timeout=30)
+        assert not staging.is_alive()
+        _tree_equal(out, state)
+
+    def test_source_that_never_stages_fails_fast(self, transports, monkeypatch):
+        monkeypatch.setenv("TORCHFT_HEAL_META_TIMEOUT_S", "0.3")
+        s1, rx = transports(), transports()
+        t0 = time.perf_counter()
+        with pytest.raises(ConnectionError, match="no heal source reachable"):
+            rx.recv_checkpoint_multi([s1.metadata()], 9, T)
+        # the brief bound, not the 20 s transfer timeout
+        assert time.perf_counter() - t0 < 5.0
+
 
 # ---------------------------------------------------------------------------
 # differential heal

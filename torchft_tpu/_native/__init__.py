@@ -72,12 +72,17 @@ def _build(force: bool = False) -> None:
                 if _abi_of_file(_LIB_PATH) == _ABI_VERSION:
                     return
             if force or not os.path.exists(_LIB_PATH):
-                subprocess.run(
-                    ["make", "-s", "-B"] if force else ["make", "-s"],
-                    cwd=_NATIVE_SRC,
-                    check=True,
-                    capture_output=True,
+                cmd = ["make", "-s", "-B"] if force else ["make", "-s"]
+                proc = subprocess.run(
+                    cmd, cwd=_NATIVE_SRC, capture_output=True, text=True
                 )
+                if proc.returncode != 0:
+                    # the compiler's own words, not a bare CalledProcessError
+                    raise RuntimeError(
+                        f"native core build failed ({' '.join(cmd)} in "
+                        f"{_NATIVE_SRC}, rc={proc.returncode}):\n"
+                        f"{proc.stdout}{proc.stderr}"
+                    )
         finally:
             fcntl.flock(lock, fcntl.LOCK_UN)
 

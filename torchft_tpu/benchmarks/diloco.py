@@ -71,13 +71,10 @@ def _worker_main(argv: List[str]) -> None:
 
     import numpy as np
 
-    from torchft_tpu.utils.platform import pin_platform_from_env
-
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    pin_platform_from_env()
-
     import jax
     import optax
+
+    jax.config.update("jax_platforms", "cpu")  # host-plane row: never the chip
 
     from torchft_tpu.collectives import CollectivesTcp
     from torchft_tpu.local_sgd import DiLoCo

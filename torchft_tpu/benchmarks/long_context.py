@@ -17,7 +17,6 @@ import sys
 
 def run() -> dict:
     import jax
-    import jax.numpy as jnp
 
     from bench import (
         _model_flops_per_step,
@@ -25,7 +24,10 @@ def run() -> dict:
         headline_config,
         train_bench,
     )
-    from torchft_tpu.models.transformer import TransformerConfig
+    from torchft_tpu.models.transformer import PRESETS, TransformerConfig
+    from torchft_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
 
     # the long-context rows ARE the headline model at longer S — import
     # the config so the two can never silently diverge
@@ -51,20 +53,16 @@ def run() -> dict:
             out[f"long_context_s{s}"] = {
                 "steps_per_sec": round(sps, 4),
                 "tokens_per_sec": round(sps * b * s),
-                "mfu_pct": round(sps * flops / peak * 100.0, 2) if peak else None,
+                "mfu_pct": round(sps * flops / peak * 100.0, 2),
                 "attention": attn_note,
             }
         except Exception as e:  # noqa: BLE001
             out[f"long_context_s{s}"] = {"error": str(e)}
 
-    big = TransformerConfig(
-        vocab_size=32000, d_model=2048, n_layers=12, n_heads=16,
-        head_dim=64, d_ff=5632, dtype=jnp.bfloat16,
-        # measured round 5 (FT loop, fresh process, noremat leg FIRST):
-        # 6.17 vs 5.80 steps/s — at 647M recompute costs more than the
-        # activation spill, the OPPOSITE of the d512 headline
-        remat=False,
-    )
+    # remat=False in the preset, measured round 5 (FT loop, fresh process,
+    # noremat leg FIRST): 6.17 vs 5.80 steps/s — at 647M recompute costs
+    # more than the activation spill, the OPPOSITE of the d512 headline
+    big = TransformerConfig(**PRESETS["scale_647M"])
     try:
         big_sps, big_n = train_bench(big, 4, 1024, 8, 2, averaging=True)
         big_flops = _model_flops_per_step(big, big_n, 4, 1024)
@@ -72,9 +70,7 @@ def run() -> dict:
             "steps_per_sec": round(big_sps, 4),
             "tokens_per_sec": round(big_sps * 4 * 1024),
             "n_params": big_n,
-            "mfu_pct": round(big_sps * big_flops / peak * 100.0, 2)
-            if peak
-            else None,
+            "mfu_pct": round(big_sps * big_flops / peak * 100.0, 2),
             "config": "d2048 L12 b4 s1024 bf16, remat=False (measured "
             "faster than remat at this size); OWN process",
         }

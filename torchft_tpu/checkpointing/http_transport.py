@@ -104,12 +104,12 @@ def _heal_digest_enabled() -> bool:
 
 
 def _heal_meta_timeout_s() -> float:
-    """Staging-window wait bound for the striped-heal endpoints
-    (``TORCHFT_HEAL_META_TIMEOUT_S``, default 5): long enough for a
-    source mid-staging (flatten+digest complete in well under this for
-    any state the full timeout could move anyway), short enough that a
-    source that will not stage this round costs seconds, not the
-    transfer timeout."""
+    """How long the striped-heal endpoints wait for a source that is NOT
+    staging to start (``TORCHFT_HEAL_META_TIMEOUT_S``, default 5): a
+    source that will not stage this round costs seconds, not the transfer
+    timeout. A source that IS staging is waited for up to the transfer
+    timeout — flatten + digest of a multi-GB state takes longer than any
+    fixed few seconds (:meth:`HTTPTransport._await_window`)."""
     try:
         return float(os.environ.get("TORCHFT_HEAL_META_TIMEOUT_S", "5"))
     except ValueError:
@@ -185,6 +185,10 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
         # flips it, and that path is single-threaded by the Manager.
         self._lock.w_acquire()
         self._allowed = False
+        # True while send_checkpoint is between closing the old window and
+        # opening the new one: the window WILL open, however long the
+        # flatten + digest of this state takes
+        self._staging = False
 
         transport = self
 
@@ -243,9 +247,7 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
                     or parts[2].startswith(("range_", "delta_"))
                 )
                 try:
-                    transport._lock.r_acquire(
-                        timeout=_heal_meta_timeout_s() if bounded else None
-                    )
+                    transport._await_window(bounded)
                 except TimeoutError:
                     self.send_error(503, "no checkpoint staged within timeout")
                     return
@@ -434,6 +436,29 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
     def metadata(self) -> str:
         return f"http://{self._hostname}:{self._port}"
 
+    def _await_window(self, bounded: bool) -> None:
+        """Take the read lock for one GET. Unbounded requests wait the
+        transfer timeout. The striped-heal endpoints give a source that is
+        not staging only :func:`_heal_meta_timeout_s` to start; once it is
+        staging they wait for it like everyone else. Raises TimeoutError."""
+        if not bounded:
+            self._lock.r_acquire()
+            return
+        start = time.monotonic()
+        brief = _heal_meta_timeout_s()
+        full = max(brief, self._timeout.total_seconds())
+        while True:
+            waited = time.monotonic() - start
+            limit = full if self._staging else brief
+            if waited >= limit:
+                raise TimeoutError(f"no serving window after {waited:.1f}s")
+            try:
+                # short slices: staging may begin while this request waits
+                self._lock.r_acquire(timeout=min(0.25, limit - waited))
+                return
+            except TimeoutError:
+                continue
+
     def send_checkpoint(
         self, dst_ranks: List[int], step: int, state_dict: T, timeout: timedelta
     ) -> None:
@@ -441,6 +466,13 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
         # step aborted before should_commit ran disallow_checkpoint), so
         # staging never races active GET streams
         self.disallow_checkpoint()
+        self._staging = True
+        try:
+            self._stage(dst_ranks, step, state_dict)
+        finally:
+            self._staging = False
+
+    def _stage(self, dst_ranks: List[int], step: int, state_dict: T) -> None:
         t0 = time.perf_counter()
         header, buffers = flatten_state(state_dict)
         # pin contiguity: the blob plane serves raw base pointers, and
@@ -633,15 +665,19 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
         metas: Dict[str, Dict[str, Any]] = {}
         meta_errors: Dict[str, str] = {}
 
-        # bounded per-source planning probe: the server answers within
-        # _heal_meta_timeout_s (or 503s), so a blackholed host must not
-        # consume the whole transfer deadline before a single range moves
+        # planning probe. A source that is not staging answers 503 within
+        # _heal_meta_timeout_s; one that is staging answers when its window
+        # opens, which for a multi-GB state is well past that. The primary
+        # (lighthouse-named, so alive) gets the whole deadline; a secondary
+        # stays bounded so that a blackholed host cannot consume the
+        # deadline before a single range moves
         meta_secs = min(secs, _heal_meta_timeout_s() + 5.0)
 
         def fetch_meta(src: str) -> None:
             try:
                 with _traced_urlopen(
-                    f"{src}/checkpoint/{step}/stripemeta", timeout=meta_secs
+                    f"{src}/checkpoint/{step}/stripemeta",
+                    timeout=secs if src == sources[0] else meta_secs,
                 ) as r:
                     metas[src] = pickle.loads(r.read())
             except Exception as e:  # noqa: BLE001 — a dead source is dropped

@@ -149,8 +149,6 @@ def _reduction_fn(mesh, specs: Tuple, op: ReduceOp, world: int) -> Callable:
     (mesh, specs, op, world) so steady-state steps never recompile."""
     import jax
 
-    import torchft_tpu.utils.jax_compat  # noqa: F401 — polyfills older jax
-
     key = (mesh, specs, op, world)
     with _PSUM_CACHE_LOCK:
         fn = _PSUM_CACHE.get(key)

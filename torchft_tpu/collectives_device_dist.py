@@ -66,9 +66,6 @@ def init_distributed(
     use in the process; the launcher can do this for you."""
     import jax
 
-    from torchft_tpu.utils.jax_compat import enable_cpu_gloo_collectives
-
-    enable_cpu_gloo_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,
@@ -186,8 +183,6 @@ class CollectivesDeviceDist(Collectives):
             return fn
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-
-        import torchft_tpu.utils.jax_compat  # noqa: F401 — polyfills older jax
 
         out_spec = P() if replicated_out else P("ft")
         fn = jax.jit(

@@ -181,18 +181,17 @@ def allreduce_gradients(
     # averaged once and re-placed to every holder.
     from torchft_tpu.checkpointing.serialization import _index_desc
 
-    # stage 0: kick off D2H for every leaf/shard before anything blocks
-    try:
-        for leaf in leaves:
-            if not isinstance(leaf, jax.Array):
-                continue
-            if leaf.is_fully_addressable:
-                leaf.copy_to_host_async()
-            else:
-                for s in leaf.addressable_shards:
-                    s.data.copy_to_host_async()
-    except Exception:  # noqa: BLE001 — prefetch is best-effort
-        pass
+    # stage 0: kick off D2H for every leaf/shard before anything blocks.
+    # No guard: a runtime that rejects the prefetch would serialise every
+    # bucket's D2H behind the ring, and that must be seen, not absorbed
+    for leaf in leaves:
+        if not isinstance(leaf, jax.Array):
+            continue
+        if leaf.is_fully_addressable:
+            leaf.copy_to_host_async()
+        else:
+            for s in leaf.addressable_shards:
+                s.data.copy_to_host_async()
 
     # item descriptors (metadata only; no blocking transfer yet)
     items: List[_Item] = []

@@ -17,6 +17,12 @@ torchelastic restart, which its integration tests emulate with
 ``attempts=3``) up to ``--max-restarts`` times. The lighthouse is spawned
 automatically unless an address is given.
 
+A TPU chip belongs to one process at a time, so on a host with chips
+every worker is handed its own disjoint set (:func:`chip_env`; a
+respawned group gets the same set back). The supervisor itself never
+touches JAX — a parent that had initialised a backend would hold the
+chips its children need.
+
 CLI::
 
     python -m torchft_tpu.launcher --groups 2 --nproc 1 -- \
@@ -36,7 +42,115 @@ from typing import Dict, List, Optional, Sequence
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["launch", "launch_shared_runtime", "main"]
+__all__ = ["launch", "launch_shared_runtime", "main", "chip_env", "host_chips"]
+
+# PCI ids of TPU chips (the scan jax's own cloud_tpu_init does before it
+# loads libtpu): vendor Google, devices v3 / v4 / v5p / v5e / v6e / 7x
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = frozenset(
+    ("0x0027", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076")
+)
+# libtpu's TPU_CHIPS_PER_PROCESS_BOUNDS (x,y,z) for a process holding n
+# consecutive chips. Found on a v5e 2x2 host (libtpu 0.0.34): chips 0,1
+# and 2,3 are neighbours along y — "1,2,1" starts, "2,1,1" dies at
+# backend init. The 8-chip shape is by extension, not run.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+_TPU_BASE_PORT = 8476  # libtpu's default TPU_PROCESS_PORT
+
+
+def host_chips(env: Optional[Dict[str, str]] = None) -> List[int]:
+    """Ids of the TPU chips this launcher may hand out — without loading
+    libtpu or JAX. ``JAX_PLATFORMS`` naming no tpu → none; an inherited
+    ``TPU_VISIBLE_CHIPS`` → that subset (an outer scheduler already
+    split the host); else the TPU functions on the PCI bus that have a
+    device node."""
+    import glob
+
+    env = os.environ if env is None else env
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return []
+    visible = env.get("TPU_VISIBLE_CHIPS")
+    if visible:
+        return [int(c) for c in visible.split(",") if c.strip()]
+    on_bus = 0
+    for vendor_path in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor_path) as f:
+                if f.read().strip() != _GOOGLE_PCI_VENDOR:
+                    continue
+            with open(os.path.join(os.path.dirname(vendor_path), "device")) as f:
+                on_bus += f.read().strip() in _TPU_PCI_DEVICES
+        except OSError:
+            continue
+    # the bus can list more chips than this machine was given (a one-chip
+    # share of a four-chip host shows four): only those with a device node
+    # can be opened
+    nodes = glob.glob("/dev/accel[0-9]*") or glob.glob("/dev/vfio/[0-9]*")
+    return list(range(min(on_bus, len(nodes))))
+
+
+def chip_env(
+    chips: Sequence[int], num_procs: int, procs_per_runtime: int = 1
+) -> List[Dict[str, str]]:
+    """Per-process libtpu environment giving each of ``num_procs`` workers
+    a disjoint, equal set of ``chips``. Pure: no chips → empty dicts (CPU
+    runs), one process → empty (it owns the host, libtpu's default).
+
+    ``procs_per_runtime`` consecutive workers form ONE libtpu runtime (a
+    ``jax.distributed`` group, ``--nproc`` / ``--shared-runtime``): they
+    share ``TPU_PROCESS_BOUNDS`` / ``TPU_PROCESS_ADDRESSES`` and differ in
+    ``CLOUD_TPU_TASK_ID``. Otherwise every worker is a runtime of its
+    own. Each worker gets its own ``TPU_PROCESS_PORT``; the ports are a
+    function of the worker's index so a respawn reuses its predecessor's.
+    """
+    if not chips or num_procs <= 1:
+        return [{} for _ in range(num_procs)]
+    if num_procs % procs_per_runtime:
+        raise ValueError(
+            f"{num_procs} workers do not divide into runtimes of "
+            f"{procs_per_runtime}"
+        )
+    per = max(
+        (n for n in _CHIP_BOUNDS if n <= len(chips) // num_procs), default=0
+    )
+    if per == 0:
+        raise ValueError(
+            f"{num_procs} workers need a TPU chip each but this host offers "
+            f"{len(chips)} ({list(chips)}): a chip belongs to one process"
+        )
+    if per * procs_per_runtime not in _CHIP_BOUNDS:
+        raise ValueError(
+            f"no TPU process layout for {procs_per_runtime} workers of "
+            f"{per} chips in one runtime"
+        )
+    envs: List[Dict[str, str]] = []
+    for i in range(num_procs):
+        first = i - i % procs_per_runtime
+        ports = [_TPU_BASE_PORT + first + k for k in range(procs_per_runtime)]
+        envs.append(
+            {
+                "TPU_VISIBLE_CHIPS": ",".join(
+                    str(c) for c in chips[i * per : (i + 1) * per]
+                ),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[per],
+                "TPU_PROCESS_BOUNDS": _process_bounds(per, procs_per_runtime),
+                "TPU_PROCESS_ADDRESSES": ",".join(
+                    f"localhost:{p}" for p in ports
+                ),
+                "TPU_PROCESS_PORT": str(ports[i - first]),
+                "CLOUD_TPU_TASK_ID": str(i - first),
+            }
+        )
+    return envs
+
+
+def _process_bounds(chips_per_proc: int, procs: int) -> str:
+    """TPU_PROCESS_BOUNDS: how ``procs`` blocks of ``chips_per_proc`` chips
+    tile the block that holds them all."""
+    cx, cy, _ = (int(v) for v in _CHIP_BOUNDS[chips_per_proc].split(","))
+    tx, ty, _ = (int(v) for v in _CHIP_BOUNDS[chips_per_proc * procs].split(","))
+    return f"{tx // cx},{ty // cy},1"
 
 
 @dataclass
@@ -65,8 +179,11 @@ def _spawn_group(
     nproc: int,
     lighthouse_addr: str,
     base_env: Dict[str, str],
+    chip_envs: Sequence[Dict[str, str]],
     cohort_env: Optional[Dict[str, str]] = None,
 ) -> _Group:
+    """``chip_envs``: :func:`chip_env`'s entry for each rank of this group
+    (empty dicts off the TPU)."""
     from torchft_tpu.store import StoreServer
 
     store = StoreServer()
@@ -91,7 +208,15 @@ def _spawn_group(
             env["TORCHFT_JAX_COORDINATOR"] = coordinator
         if cohort_env:
             env.update(cohort_env)
-        group.procs.append(subprocess.Popen(list(cmd), env=env))
+        env.update(chip_envs[rank])
+        proc = subprocess.Popen(list(cmd), env=env)
+        group.procs.append(proc)
+        logger.info(
+            "group %d rank %d: pid %d, TPU chips %s",
+            gid, rank, proc.pid,
+            env.get("TPU_VISIBLE_CHIPS")
+            or "not assigned (whatever the process finds)",
+        )
     return group
 
 
@@ -129,6 +254,8 @@ def launch_shared_runtime(
         lighthouse_addr, num_groups
     )
     base_env = dict(os.environ)
+    # the cohort is ONE jax runtime: its processes tile the host's chips
+    chip_envs = chip_env(host_chips(), num_groups, procs_per_runtime=num_groups)
     groups: List[_Group] = []
 
     def spawn_cohort() -> None:
@@ -143,6 +270,7 @@ def launch_shared_runtime(
             groups.append(
                 _spawn_group(
                     g, cmd, num_groups, 1, lighthouse_addr, base_env,
+                    chip_envs[g : g + 1],
                     {**cohort_env, "TORCHFT_COHORT_ID": str(g)},
                 )
             )
@@ -221,10 +349,17 @@ def launch(
     )
 
     base_env = dict(os.environ)
-    groups = [
-        _spawn_group(g, cmd, num_groups, nproc, lighthouse_addr, base_env)
-        for g in range(num_groups)
-    ]
+    chip_envs = chip_env(
+        host_chips(), num_groups * nproc, procs_per_runtime=nproc
+    )
+
+    def spawn(gid: int) -> _Group:
+        return _spawn_group(
+            gid, cmd, num_groups, nproc, lighthouse_addr, base_env,
+            chip_envs[gid * nproc : (gid + 1) * nproc],
+        )
+
+    groups = [spawn(g) for g in range(num_groups)]
     exit_code = 0
     min_needed = min_replicas or num_groups
     try:
@@ -267,10 +402,7 @@ def launch(
                         exit_code = 1
                         continue
                     if group.restarts < max_restarts:
-                        fresh = _spawn_group(
-                            group.gid, cmd, num_groups, nproc,
-                            lighthouse_addr, base_env,
-                        )
+                        fresh = spawn(group.gid)
                         fresh.restarts = group.restarts + 1
                         groups.append(fresh)
                         logger.info(

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-import torchft_tpu.utils.jax_compat  # noqa: F401 — polyfills older jax
-
 from torchft_tpu.ops.attention import (
     attention,
     chunked_attention,
@@ -37,6 +35,7 @@ from torchft_tpu.ops.layers import moe_dispatch, rms_norm, rotary_embed, swiglu
 
 __all__ = [
     "TransformerConfig",
+    "PRESETS",
     "init_params",
     "param_specs",
     "forward",
@@ -82,6 +81,30 @@ class TransformerConfig:
     @property
     def qkv_dim(self) -> int:
         return self.n_heads * self.head_dim
+
+
+# Named shapes (``TransformerConfig(**PRESETS[name])``), one definition for
+# examples/train_hsdp.py (MODEL=...), the benchmarks and chip_smoke.py.
+PRESETS: Dict[str, Dict[str, Any]] = {
+    # CPU-mesh testable
+    "tiny": dict(
+        vocab_size=64, d_model=16, n_layers=2, n_heads=2, head_dim=8,
+        d_ff=32, dtype=jnp.float32,
+    ),
+    # the widest model this repo has run on one v5e chip: f32 params +
+    # Adam + f32 gradients are ~10.4 GB of its 16 GB. remat=False: at
+    # this size recompute cost more than the activations it saved
+    "scale_647M": dict(
+        vocab_size=32000, d_model=2048, n_layers=12, n_heads=16,
+        head_dim=64, d_ff=5632, dtype=jnp.bfloat16, remat=False,
+    ),
+    # Llama-2-7B shape (BASELINE.md north-star config); needs fsdp>=8
+    # per group on v5e for params+optimizer. Never run (ROADMAP R7)
+    "llama2-7b": dict(
+        vocab_size=32000, d_model=4096, n_layers=32, n_heads=32,
+        head_dim=128, d_ff=11008, dtype=jnp.bfloat16,
+    ),
+}
 
 
 def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
@@ -382,15 +405,15 @@ def _make_layer_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
                 mesh is not None and mesh.shape.get("pp", 1) > 1
             )
             if inside_manual:
-                if cfg.attention_impl == "flash":
-                    raise ValueError(
-                        "attention_impl='flash' cannot run inside the "
-                        "pipeline's manual region (pp>1); shard the sequence "
-                        "(sp>1, ring attention) for long context under pp"
-                    )
-                att = attention(q, k, v, causal=True)  # auto: quiet fallback
-            else:
-                att = _flash_sharded(q, k, v, mesh)
+                # no fallback: flash was picked because plain attention's
+                # scores cannot fit either
+                raise ValueError(
+                    f"flash attention (attention_impl={cfg.attention_impl!r}, "
+                    f"b{b} s{s}) cannot run inside the pipeline's manual "
+                    "region (pp>1); shard the sequence (sp>1, ring "
+                    "attention) for long context under pp"
+                )
+            att = _flash_sharded(q, k, v, mesh)
         else:
             att = attention(q, k, v, causal=True)
         x = x + att.reshape(b, s, cfg.qkv_dim) @ lp["wo"]

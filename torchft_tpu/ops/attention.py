@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-import torchft_tpu.utils.jax_compat  # noqa: F401 — polyfills older jax
-
 __all__ = [
     "attention",
     "chunked_attention",

@@ -11,10 +11,13 @@ composition problem.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import logging
+from dataclasses import dataclass
 from typing import Dict, Optional, Sequence
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 __all__ = ["MeshConfig", "make_mesh", "AXES"]
 
@@ -52,6 +55,13 @@ def make_mesh(config: MeshConfig, devices: Optional[Sequence] = None):
     if len(devices) < config.total:
         raise ValueError(
             f"mesh needs {config.total} devices, have {len(devices)}"
+        )
+    if len(devices) > config.total and devices[0].platform != "cpu":
+        # e.g. FSDP=1 TP=1 on a four-chip host: everything lands on chip 0
+        logger.warning(
+            "mesh %s uses %d of the %d visible devices; %s stay idle",
+            {a: n for a, n in config.sizes.items() if n > 1} or "(1 device)",
+            config.total, len(devices), devices[config.total :],
         )
     shape = tuple(config.sizes[a] for a in AXES)
     dev = np.array(devices[: config.total]).reshape(shape)

@@ -66,9 +66,6 @@ def initialize_group(
         return
     import jax
 
-    from torchft_tpu.utils.jax_compat import enable_cpu_gloo_collectives
-
-    enable_cpu_gloo_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,

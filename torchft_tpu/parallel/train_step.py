@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-import torchft_tpu.utils.jax_compat  # noqa: F401 — polyfills older jax
-
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -41,11 +39,37 @@ class TrainStep:
             lambda spec: NamedSharding(mesh, spec), self._pspecs
         )
         self._batch_sharding = NamedSharding(mesh, P(("dp", "fsdp"), "sp"))
+        replicated = NamedSharding(mesh, P())
+        # optimizer state: every subtree shaped like the params (Adam's
+        # moments) is sharded like them, the rest (counts) is replicated
+        params_abs = jax.eval_shape(
+            lambda: init_params(jax.random.PRNGKey(0), cfg)
+        )
+        pdef = jax.tree_util.tree_structure(params_abs)
+
+        def like_params(sub) -> bool:
+            return jax.tree_util.tree_structure(sub) == pdef
+
+        self._opt_shardings = jax.tree_util.tree_map(
+            lambda sub: self._param_shardings if like_params(sub) else replicated,
+            jax.eval_shape(tx.init, params_abs),
+            is_leaf=like_params,
+        )
 
         def compute_loss(params, tokens):
             return loss_fn(params, tokens, cfg, mesh)
 
-        self._value_and_grad = jax.jit(jax.value_and_grad(compute_loss))
+        # Shardings are pinned on both sides of grads/apply, so each is ONE
+        # program whatever placed its inputs — init, the previous step, a
+        # heal, the host after cross-group averaging. Left to inference,
+        # apply compiled three variants (first step, steady state, first
+        # step after a heal): the last is a persistent-cache miss for every
+        # respawned group, and none matched what warm_apply lowers.
+        self._value_and_grad = jax.jit(
+            jax.value_and_grad(compute_loss),
+            in_shardings=(self._param_shardings, self._batch_sharding),
+            out_shardings=(replicated, self._param_shardings),
+        )
 
         def apply_updates(params, opt_state, grads):
             updates, opt_state = tx.update(grads, opt_state, params)
@@ -53,7 +77,15 @@ class TrainStep:
 
             return optax.apply_updates(params, updates), opt_state
 
-        self._apply = jax.jit(apply_updates, donate_argnums=(0, 1))
+        self._apply_shardings = dict(
+            in_shardings=(
+                self._param_shardings, self._opt_shardings, self._param_shardings
+            ),
+            out_shardings=(self._param_shardings, self._opt_shardings),
+        )
+        self._apply = jax.jit(
+            apply_updates, donate_argnums=(0, 1), **self._apply_shardings
+        )
         # pipelined-commit variant, compiled lazily: the inputs must NOT
         # be donated so the pre-update (params, opt_state) stays alive on
         # device as the rollback snapshot (a reference, not a copy)
@@ -79,16 +111,20 @@ class TrainStep:
 
     def init_opt(self, params) -> Any:
         with jax.set_mesh(self.mesh):
-            return jax.jit(self.tx.init)(params)
+            return jax.jit(self.tx.init, out_shardings=self._opt_shardings)(
+                params
+            )
 
     def warm_apply(self, params_spec, opt_state_spec) -> None:
         """AOT-compile the donated ``apply`` jit from abstract specs (the
         heal/compile overlap, docs/heal_plane.md): called on a background
         thread while checkpoint stripes stream, so the healer's first
-        post-heal apply finds the executable warm (via the shared jit
-        lowering cache and/or the persistent XLA compilation cache)
-        instead of paying the compile serially after recv. Grad specs
-        mirror param specs (identical pytree/shapes/dtypes)."""
+        post-heal apply finds the executable in the persistent compilation
+        cache (utils/compile_cache.py) instead of paying the compile
+        serially after recv. It is the same program as the main thread's
+        because ``apply`` pins its shardings; the AOT path and the later
+        call share nothing in memory. Grad specs mirror param specs
+        (identical pytree/shapes/dtypes)."""
         with jax.set_mesh(self.mesh):
             self._apply.lower(params_spec, opt_state_spec, params_spec).compile()
 
@@ -155,7 +191,9 @@ class TrainStep:
                 out = self._apply(params, opt_state, grads)
             else:
                 if self._apply_keep is None:
-                    self._apply_keep = jax.jit(self._apply_updates_fn)
+                    self._apply_keep = jax.jit(
+                        self._apply_updates_fn, **self._apply_shardings
+                    )
                 out = self._apply_keep(params, opt_state, grads)
         self._record_compute(t0)
         return out
