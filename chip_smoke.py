@@ -364,8 +364,8 @@ def _cache_counts(tr: Trainer) -> Dict[str, List[int]]:
     out = {"grads": [0, 0], "apply": [0, 0], "other": [0, 0]}
     for name, (h, m) in tr.cache.items():
         key = (
-            "grads" if "compute_loss" in name
-            else "apply" if "apply_updates" in name
+            "grads" if "tft_grads" in name
+            else "apply" if "tft_apply" in name
             else "other"
         )
         out[key][0] += h

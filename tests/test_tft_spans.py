@@ -1,0 +1,281 @@
+"""The program's own spans on the profiler's clock (docs/observability.md,
+"Spans in the profiler's trace"): three ``FTTrainer.step``s of a small model on
+a one-group Manager over CollectivesTcp, read back from the ``.xplane.pb`` a
+``jax.profiler`` session wrote, and from the Tracer ring without a session.
+"""
+
+import contextlib
+import glob
+import os
+import re
+from datetime import timedelta
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from torchft_tpu.collectives import CollectivesTcp
+from torchft_tpu.coordination import LighthouseServer
+from torchft_tpu.manager import Manager
+from torchft_tpu.models.transformer import TransformerConfig, init_params
+from torchft_tpu.parallel.ft import FTTrainer
+from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+from torchft_tpu.parallel.train_step import TrainStep
+from torchft_tpu.store import StoreServer
+from torchft_tpu.telemetry import tracing
+
+CFG = TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=2, n_heads=2, head_dim=32, d_ff=128,
+    dtype=jnp.float32,
+)
+# the smallest bucket the knob allows, so that one step has several
+BUCKET_BYTES = 1 << 16
+
+STEP_SPANS = (
+    "step", "quorum.start", "shard_batch", "grads", "exchange", "commit",
+    "commit.prepare", "apply", "loss_sync",
+)
+MAIN_THREAD = (
+    "exchange.d2h_issue", "exchange.plan", "exchange.d2h_wait", "exchange.pack",
+    "exchange.submit", "exchange.tail_wait", "exchange.reassemble",
+    "exchange.counters",
+)
+# these carry step, bucket and bytes; the ring, a layer below, only its bytes
+PER_BUCKET = ("exchange.d2h_wait", "exchange.pack", "exchange.submit", "exchange.h2d")
+
+
+@pytest.fixture(scope="module")
+def train_step():
+    mesh = make_mesh(MeshConfig(), devices=jax.devices()[:1])
+    return TrainStep(CFG, optax.adamw(1e-2), mesh)
+
+
+def n_params() -> int:
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), CFG))
+    return sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(shapes))
+
+
+def run_steps(ts, steps, monkeypatch, around=None, commit_pipeline=False):
+    """``steps`` FT steps on a fresh one-group job; returns (losses, checksum).
+    ``around(fn)`` runs the stepping inside whatever it sets up."""
+    monkeypatch.setenv("TORCHFT_WIRE_BUCKET_BYTES", str(BUCKET_BYTES))
+    lighthouse = LighthouseServer(bind="[::]:0", min_replicas=1)
+    store = StoreServer()
+    manager = Manager(
+        collectives=CollectivesTcp(timeout=timedelta(seconds=10)),
+        load_state_dict=None,
+        state_dict=None,
+        min_replica_size=1,
+        replica_id="spans_0",
+        store_addr=store.address(),
+        lighthouse_addr=lighthouse.address(),
+        rank=0,
+        world_size=1,
+        timeout=timedelta(seconds=10),
+        commit_pipeline=commit_pipeline,
+    )
+    try:
+        trainer = FTTrainer(manager, ts)
+        trainer.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(0)
+        batches = [
+            jnp.asarray(rng.integers(0, CFG.vocab_size, (2, 16)), jnp.int32)
+            for _ in range(steps)
+        ]
+
+        def drive():
+            out = []
+            for tokens in batches:
+                loss, committed = trainer.step(tokens)
+                assert committed
+                out.append(loss)
+            assert trainer.finish() in (None, True)
+            jax.block_until_ready(trainer.params)
+            return out
+
+        losses = around(drive) if around else drive()
+        checksum = sum(
+            float(jnp.sum(l)) for l in jax.tree_util.tree_leaves(trainer.params)
+        )
+        return losses, checksum
+    finally:
+        manager.shutdown(wait=False)
+        store.shutdown()
+        lighthouse.shutdown()
+
+
+@pytest.fixture(scope="module")
+def traced(train_step, tmp_path_factory):
+    """(host lines of the trace of three steps, their losses, the checksum)."""
+    trace_dir = str(tmp_path_factory.mktemp("xplane"))
+    mp = pytest.MonkeyPatch()
+
+    def around(drive):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        try:
+            return drive()
+        finally:
+            jax.profiler.stop_trace()
+
+    try:
+        losses, checksum = run_steps(train_step, 3, mp, around)
+    finally:
+        mp.undo()
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    lines = []  # one dict per host thread: name -> [(start_ns, end_ns, stats)]
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            by_name = {}
+            for ev in line.events:
+                if ev.name.startswith(tracing.TRACE_PREFIX):
+                    by_name.setdefault(ev.name[len(tracing.TRACE_PREFIX):], []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+                    )
+            if by_name:
+                lines.append(by_name)
+    return lines, losses, checksum
+
+
+def main_line(lines):
+    (main,) = [ln for ln in lines if "step" in ln]
+    return main
+
+
+def test_every_span_is_in_the_trace_on_its_thread(traced):
+    lines, _, _ = traced
+    main = main_line(lines)
+    assert len(main["step"]) == 3
+    assert [s["step_num"] for _, _, s in main["step"]] == [0, 1, 2]
+    for name in STEP_SPANS + MAIN_THREAD:
+        assert name in main, name
+    # children lie inside their step, on the main thread's line
+    for name in STEP_SPANS[1:] + MAIN_THREAD:
+        for s, e, _ in main[name]:
+            assert any(s0 <= s and e <= e0 for s0, e0, _ in main["step"]), name
+    for s, e, _ in main["commit.prepare"]:
+        assert any(s0 <= s and e <= e0 for s0, e0, _ in main["commit"])
+    # the manager's and the RPC layer's existing spans gained their twins;
+    # the quorum itself is the quorum thread's, apart from the step's call
+    names = {n for ln in lines for n in ln}
+    assert {"quorum_rpc", "should_commit", "should_commit_rpc"} <= names
+    assert "quorum" not in main and any("quorum" in ln for ln in lines)
+    # the ring runs on the collectives op thread; a bucket's h2d follows it
+    # there, or runs inline on the main thread when the ring was done before
+    # ddp attached the continuation (world size 1: the ring is ~0)
+    (op,) = [ln for ln in lines if "exchange.ring" in ln]
+    assert op is not main
+    h2d = sum(len(ln.get("exchange.h2d", ())) for ln in (op, main))
+    assert h2d == len(op["exchange.ring"]) == len(main["exchange.submit"])
+    assert sum(len(ln.get("exchange.h2d", ())) for ln in lines) == h2d
+
+
+def test_bucket_stats_tie_the_threads_together(traced):
+    lines, _, _ = traced
+    main = main_line(lines)
+    everything = {}
+    for ln in lines:
+        for name, evs in ln.items():
+            everything.setdefault(name, []).extend(evs)
+    total = 4 * n_params()
+    assert total > 2 * BUCKET_BYTES
+    for step in (0, 1, 2):
+        keys = {}
+        for name in PER_BUCKET:
+            mine = [s for _, _, s in everything[name] if s["step"] == step]
+            assert sum(s["bytes"] for s in mine) == total, name
+            keys[name] = sorted(s["bucket"] for s in mine)
+        assert len(set(map(tuple, keys.values()))) == 1, keys
+        assert keys["exchange.submit"] == list(range(len(keys["exchange.submit"])))
+    # the op thread runs the rings in submission order: order is the link
+    ring = sorted(everything["exchange.ring"])
+    assert [s["bytes"] for _, _, s in ring] == [s["bytes"] for _, _, s in sorted(main["exchange.submit"])]
+    assert all(set(s) == {"bytes", "queued_s"} and s["queued_s"] >= 0 for _, _, s in ring)
+    (_, _, counters), *_ = [c for c in main["exchange.counters"] if c[2]["step"] == 1]
+    assert counters["buckets"] == len(keys["exchange.submit"]) >= 3
+    assert counters["bytes_d2h"] == total
+    assert set(counters) == {
+        "step", "buckets", "bytes_d2h", "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
+    }
+    assert all(counters[key] >= 0 for key in counters)
+    # a zero-length carrier at the end of its exchange
+    for (s, e, _), (s0, e0, _) in zip(main["exchange.counters"], main["exchange"]):
+        assert s0 <= s and e <= e0 and e - s < 1e6
+
+
+def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, monkeypatch):
+    _, traced_losses, traced_checksum = traced
+    tracing.TRACER.clear()
+    # ... and the annotations change nothing: same loss, same parameters
+    monkeypatch.setattr(tracing, "annotate", lambda name, **stats: contextlib.nullcontext())
+    losses, checksum = run_steps(train_step, 3, monkeypatch)
+    assert losses[0] == traced_losses[0] and checksum == traced_checksum
+
+    spans = tracing.TRACER.recent()
+    steps = [s for s in spans if s["name"] == "step"]
+    # replica:step:epoch, with the step the root was opened for
+    assert all(s["trace_id"].startswith("spans_0") for s in steps)
+    assert [s["trace_id"].split(":")[1] for s in steps] == ["0", "1", "2"]
+    last = steps[-1]
+    children = [s for s in spans if s.get("parent_id") == last["span_id"]]
+    # commit.prepare is the commit's child
+    assert sorted(s["name"] for s in children) == sorted(set(STEP_SPANS[1:]) - {"commit.prepare"})
+    for s in children:
+        assert s["trace_id"] == last["trace_id"]
+        assert last["t0_mono_ns"] <= s["t0_mono_ns"]
+        assert s["t0_mono_ns"] + s["dur_s"] * 1e9 <= last["t0_mono_ns"] + last["dur_s"] * 1e9 + 1e6
+    assert last["attrs"]["committed"] is True
+    (exchange,) = [s for s in children if s["name"] == "exchange"]
+    assert set(exchange["attrs"]) == {
+        "step", "buckets", "bytes_d2h", "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
+    }
+    # nothing per bucket, and at most 12 new entries a step
+    assert not [s for s in spans if s["name"].startswith("exchange.")]
+    assert "resolve_speculation" not in {s["name"] for s in spans}  # no vote was pending
+    assert sum(1 for s in spans if s["name"] in STEP_SPANS) <= 12 * 3
+
+
+def test_resolve_speculation_is_a_span_only_while_a_vote_is_pending(traced, train_step, monkeypatch):
+    _, traced_losses, traced_checksum = traced
+    tracing.TRACER.clear()
+    losses, checksum = run_steps(train_step, 3, monkeypatch, commit_pipeline=True)
+    assert losses == traced_losses and checksum == traced_checksum
+    spans = tracing.TRACER.recent()
+    steps = [s for s in spans if s["name"] == "step"]
+    # a vote in flight makes the step the one after current_step()
+    assert [s["trace_id"].split(":")[1] for s in steps] == ["0", "1", "2"]
+    resolved = [s for s in spans if s["name"] == "resolve_speculation"]
+    # steps 1 and 2 resolve their predecessor's vote; finish() the last, outside any step
+    assert [s.get("parent_id") for s in resolved] == [steps[1]["span_id"], steps[2]["span_id"]]
+    assert sum(1 for s in spans if s["name"] in STEP_SPANS + ("resolve_speculation",)) <= 12 * 3
+
+
+def test_programs_and_scopes_have_stable_names(train_step):
+    ts = train_step
+    params = ts.init_params(jax.random.PRNGKey(0))
+    opt = ts.init_opt(params)
+    tokens = ts.shard_batch(jnp.zeros((2, 16), jnp.int32))
+    with jax.set_mesh(ts.mesh):
+        grads = ts._value_and_grad.lower(params, tokens).as_text(debug_info=True)
+        apply = ts._apply.lower(params, opt, params).as_text(debug_info=True)
+        fused = ts._fused.lower(params, opt, tokens).as_text(debug_info=True)
+    assert "module @jit_tft_grads" in grads
+    assert "module @jit_tft_apply" in apply
+    assert "module @jit_tft_fused" in fused
+
+    def scopes(text):
+        found = set()
+        for loc in re.findall(r'loc\("([^"]*)"', text):
+            # a scope is a component of the op's path: attn/add, jvp(embed)/jit
+            found.update(re.findall(r"(?:^|[/(])(embed|attn|ffn|moe|head_loss|optimizer)(?=[/)])", loc))
+        return found
+
+    assert scopes(grads) == {"embed", "attn", "ffn", "head_loss"}
+    assert scopes(apply) == {"optimizer"}
+    assert scopes(fused) == {"embed", "attn", "ffn", "head_loss", "optimizer"}

@@ -31,6 +31,7 @@ import logging
 import socket
 import struct
 import threading
+import time
 from abc import ABC, abstractmethod
 from concurrent.futures import ThreadPoolExecutor
 from datetime import timedelta
@@ -73,6 +74,7 @@ __all__ = [
 # every record into tft_wire_stage_seconds_total as before.
 # ---------------------------------------------------------------------------
 
+from torchft_tpu.telemetry import tracing  # noqa: E402
 from torchft_tpu.telemetry.anatomy import (  # noqa: E402
     LEDGER as _ANATOMY_LEDGER,
     WIRE_STAGES,
@@ -1261,24 +1263,28 @@ class CollectivesTcp(Collectives):
         # counted at submission like every other op (uniform semantics);
         # bytes + latency are recorded at completion in run()
         fid = self._count_op("allreduce", nbytes, tag)
+        t_submit = time.perf_counter()
 
         def run() -> List[np.ndarray]:
-            import time
-
             from torchft_tpu import telemetry
 
             t0 = time.perf_counter()
-            if world > 1:
-                # ops are serialized on the op thread, so arrays of one
-                # allreduce may share the tag (it is a desync check, not a
-                # demultiplexer; the native plane offsets per-stripe)
-                for arr in arrays:
-                    if self._dp_eligible(arr):
-                        self._dp_allreduce(arr, op, tag)
-                    else:
-                        self._ring_allreduce(arr, op, tag)
-                        if op == ReduceOp.AVG:
-                            np.divide(arr, world, out=arr)
+            # queued_s: the op's wait for this one thread. Ops run here in
+            # submission order, which is what ties an event to its submitter
+            with tracing.annotate(
+                "exchange.ring", bytes=nbytes, queued_s=t0 - t_submit
+            ):
+                if world > 1:
+                    # ops are serialized on the op thread, so arrays of one
+                    # allreduce may share the tag (it is a desync check, not
+                    # a demultiplexer; the native plane offsets per-stripe)
+                    for arr in arrays:
+                        if self._dp_eligible(arr):
+                            self._dp_allreduce(arr, op, tag)
+                        else:
+                            self._ring_allreduce(arr, op, tag)
+                            if op == ReduceOp.AVG:
+                                np.divide(arr, world, out=arr)
             telemetry.record_collective(
                 "allreduce", nbytes, time.perf_counter() - t0,
                 self.plane_info(), count_op=False,

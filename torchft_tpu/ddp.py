@@ -26,14 +26,24 @@ be rolled back) state must never enter a collective. The bucket buffers
 here always own their memory (``np.concatenate`` / explicit ``copy``),
 so the in-place ring reduction can never corrupt the caller's retained
 gradient pytree across a rollback/replay.
+
+What the exchange spends its time on is visible from inside
+(docs/observability.md "Spans in the profiler's trace"): one ``exchange``
+span around the call, carrying the per-step sums and the process's
+CPU-time deltas, and in a profiler trace one ``tft.exchange.*`` event per
+piece of work per bucket, on the thread that did it.
 """
 
 from __future__ import annotations
 
 import os
+import resource
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from torchft_tpu.telemetry import tracing
 
 __all__ = ["flatten_buckets", "unflatten_buckets", "allreduce_gradients"]
 
@@ -169,10 +179,47 @@ def allreduce_gradients(
     if bucket_bytes is None:
         bucket_bytes = default_bucket_bytes()
     leaves, treedef = _leaves(grads)
+    # duck-typed managers (tests, benches) may have no step counter
+    step = int(getattr(manager, "current_step", lambda: -1)())
 
-    if getattr(manager, "device_data_plane", lambda: False)():
-        out = manager.allreduce_many(leaves).wait()
-        return jax.tree_util.tree_unflatten(treedef, out)
+    ru0 = resource.getrusage(resource.RUSAGE_SELF)
+    with tracing.TRACER.span("exchange", step=step) as span:
+        if getattr(manager, "device_data_plane", lambda: False)():
+            out = manager.allreduce_many(leaves).wait()
+            counters: Dict[str, Any] = {}
+        else:
+            out, counters = _host_exchange(
+                manager, leaves, bucket_bytes, error_feedback, step
+            )
+        ru1 = resource.getrusage(resource.RUSAGE_SELF)
+        # one syscall at each end, all threads of the process — the
+        # runtime's own among them, whose work no span of ours covers
+        counters.update(
+            step=step,
+            utime_s=round(ru1.ru_utime - ru0.ru_utime, 6),
+            stime_s=round(ru1.ru_stime - ru0.ru_stime, 6),
+        )
+        span.set(**counters)
+        # an annotation takes its stats at entry: a zero-length one at exit
+        # carries the counters into the profiler's trace
+        with tracing.annotate("exchange.counters", **counters):
+            pass
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _host_exchange(
+    manager,
+    leaves: List[Any],
+    bucket_bytes: int,
+    error_feedback: Optional[Any],
+    step: int,
+) -> Tuple[List[Any], Dict[str, Any]]:
+    """The host path of :func:`allreduce_gradients`: averaged leaves, and
+    the step's sums for the ``exchange`` span."""
+    import jax
+
+    from torchft_tpu.collectives import record_wire_stage
+    from torchft_tpu.telemetry.anatomy import LEDGER as _ledger
 
     # host path. A leaf sharded across processes (multi-host group) cannot
     # be gathered: this process averages only its addressable shards —
@@ -181,56 +228,70 @@ def allreduce_gradients(
     # averaged once and re-placed to every holder.
     from torchft_tpu.checkpointing.serialization import _index_desc
 
+    sums = {"d2h_wait_s": 0.0, "pack_s": 0.0}  # per step
+
     # stage 0: kick off D2H for every leaf/shard before anything blocks.
     # No guard: a runtime that rejects the prefetch would serialise every
     # bucket's D2H behind the ring, and that must be seen, not absorbed
-    for leaf in leaves:
-        if not isinstance(leaf, jax.Array):
-            continue
-        if leaf.is_fully_addressable:
-            leaf.copy_to_host_async()
-        else:
-            for s in leaf.addressable_shards:
-                s.data.copy_to_host_async()
+    with tracing.annotate("exchange.d2h_issue", step=step, leaves=len(leaves)):
+        for leaf in leaves:
+            if not isinstance(leaf, jax.Array):
+                continue
+            if leaf.is_fully_addressable:
+                leaf.copy_to_host_async()
+            else:
+                for s in leaf.addressable_shards:
+                    s.data.copy_to_host_async()
 
     # item descriptors (metadata only; no blocking transfer yet)
-    items: List[_Item] = []
-    for li, leaf in enumerate(leaves):
-        if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable:
-            seen: Dict[Tuple, Any] = {}
-            for s in leaf.addressable_shards:
-                idx = _index_desc(s.index, leaf.shape)
-                if idx not in seen:  # replicated copies average once
-                    seen[idx] = s.data
-            for idx, data in seen.items():
-                items.append(_Item(li, data, data.dtype, data.shape, idx))
-        else:
-            dtype = getattr(leaf, "dtype", None) or np.asarray(leaf).dtype
-            shape = getattr(leaf, "shape", None)
-            if shape is None:
-                shape = np.asarray(leaf).shape
-            items.append(_Item(li, leaf, dtype, shape))
+    with tracing.annotate("exchange.plan", step=step):
+        items: List[_Item] = []
+        for li, leaf in enumerate(leaves):
+            if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable:
+                seen: Dict[Tuple, Any] = {}
+                for s in leaf.addressable_shards:
+                    idx = _index_desc(s.index, leaf.shape)
+                    if idx not in seen:  # replicated copies average once
+                        seen[idx] = s.data
+                for idx, data in seen.items():
+                    items.append(_Item(li, data, data.dtype, data.shape, idx))
+            else:
+                dtype = getattr(leaf, "dtype", None) or np.asarray(leaf).dtype
+                shape = getattr(leaf, "shape", None)
+                if shape is None:
+                    shape = np.asarray(leaf).shape
+                items.append(_Item(li, leaf, dtype, shape))
 
-    plan = plan_buckets([(it.dtype, it.nbytes) for it in items], bucket_bytes)
+        plan = plan_buckets(
+            [(it.dtype, it.nbytes) for it in items], bucket_bytes
+        )
 
     def _run_bucket(ordinal: int, idxs: List[int]):
-        import time as _time
-
-        from torchft_tpu.collectives import record_wire_stage
+        # what ties this bucket's events together across threads
+        tags = {
+            "step": step,
+            "bucket": ordinal,
+            "bytes": sum(items[i].nbytes for i in idxs),
+        }
 
         # stage 1 (main thread): materialize this bucket's host buffers —
         # blocks only on *this* bucket's D2H while earlier buckets are
         # already riding the ring on the op thread
-        t0 = _time.perf_counter()
-        flat = [
-            np.ascontiguousarray(np.asarray(items[i].src)).reshape(-1)
-            for i in idxs
-        ]
-        # the bucket buffer always owns its memory: the ring reduces (and
-        # non-participants zero) in place, which must never write through
-        # a view of the caller's arrays or a read-only XLA host buffer
-        buf = np.concatenate(flat) if len(flat) > 1 else flat[0].copy()
-        record_wire_stage("host_copy", _time.perf_counter() - t0)
+        t0 = time.perf_counter()
+        with tracing.annotate("exchange.d2h_wait", **tags):
+            host = [np.asarray(items[i].src) for i in idxs]
+        t1 = time.perf_counter()
+        with tracing.annotate("exchange.pack", **tags):
+            flat = [np.ascontiguousarray(h).reshape(-1) for h in host]
+            # the bucket buffer always owns its memory: the ring reduces
+            # (and non-participants zero) in place, which must never write
+            # through a view of the caller's arrays or a read-only XLA
+            # host buffer
+            buf = np.concatenate(flat) if len(flat) > 1 else flat[0].copy()
+        t2 = time.perf_counter()
+        record_wire_stage("host_copy", t2 - t0)
+        sums["d2h_wait_s"] += t1 - t0
+        sums["pack_s"] += t2 - t1
 
         if error_feedback is not None:
             # compensate with the committed residual and project onto the
@@ -240,12 +301,14 @@ def allreduce_gradients(
             # the fresh residual stays PENDING until the step's fate
             # resolves. The key is stable across steps as long as the
             # bucket plan is (same tree -> same plan).
-            t0 = _time.perf_counter()
-            error_feedback.apply(f"b{ordinal}_{buf.size}", buf)
-            record_wire_stage("quantize", _time.perf_counter() - t0)
+            t0 = time.perf_counter()
+            with tracing.annotate("exchange.ef", **tags):
+                error_feedback.apply(f"b{ordinal}_{buf.size}", buf)
+            record_wire_stage("quantize", time.perf_counter() - t0)
 
         # stage 2 (op thread): quorum-managed ring allreduce of the bucket
-        fut = manager.allreduce_many([buf])
+        with tracing.annotate("exchange.submit", **tags):
+            fut = manager.allreduce_many([buf])
 
         # dense jax leaves carry their sharding so stage 3 can start the
         # averaged piece's H2D without waiting for the whole tree
@@ -262,18 +325,21 @@ def allreduce_gradients(
 
         def scatter(f):
             # stage 3 (runs on the op thread as soon as this bucket's ring
-            # finishes, while the next bucket's ring occupies the wire):
-            # slice the averaged buffer and dispatch H2D immediately
+            # finishes, while the next bucket's ring occupies the wire — or
+            # inline on the main thread when the ring was done before the
+            # continuation was attached): slice the averaged buffer and
+            # dispatch H2D immediately
             res = f.value()[0]
             parts = []
             off = 0
-            for shp, sharding in zip(shapes, put_shardings):
-                n = int(np.prod(shp, dtype=np.int64))
-                piece = res[off : off + n].reshape(shp)
-                off += n
-                if sharding is not None:
-                    piece = jax.device_put(piece, sharding)
-                parts.append(piece)
+            with tracing.annotate("exchange.h2d", **tags):
+                for shp, sharding in zip(shapes, put_shardings):
+                    n = int(np.prod(shp, dtype=np.int64))
+                    piece = res[off : off + n].reshape(shp)
+                    off += n
+                    if sharding is not None:
+                        piece = jax.device_put(piece, sharding)
+                    parts.append(piece)
             return parts
 
         return fut.then(scatter)
@@ -290,35 +356,39 @@ def allreduce_gradients(
     # totals the crossgroup bench attributes stages with). In a
     # synchronous fleet a slow peer inflates exactly this wait, which is
     # what lets the straggler detector's local-time signal exclude it.
-    import time as _time
-
-    from torchft_tpu.telemetry.anatomy import LEDGER as _ledger
-
     item_out: List[np.ndarray] = [None] * len(items)  # type: ignore[list-item]
-    t_wait = _time.perf_counter()
-    for idxs, fut in bucket_futs:
-        parts = fut.wait()
-        for i, piece in zip(idxs, parts):
-            item_out[i] = piece
-    _ledger.record("wire", _time.perf_counter() - t_wait)
+    t_wait = time.perf_counter()
+    with tracing.annotate("exchange.tail_wait", step=step):
+        for idxs, fut in bucket_futs:
+            parts = fut.wait()
+            for i, piece in zip(idxs, parts):
+                item_out[i] = piece
+    tail_wait_s = time.perf_counter() - t_wait
+    _ledger.record("wire", tail_wait_s)
 
     # reassemble leaves
-    out: List[Any] = [None] * len(leaves)
-    shard_acc: Dict[int, Dict[Tuple, np.ndarray]] = {}
-    for it, averaged in zip(items, item_out):
-        if it.index is None:
-            out[it.leaf_pos] = averaged
-        else:
-            shard_acc.setdefault(it.leaf_pos, {})[it.index] = averaged
-    for li, by_idx in shard_acc.items():
-        template = leaves[li]
-        arrays = [
-            jax.device_put(by_idx[_index_desc(index, template.shape)], dev)
-            for dev, index in template.sharding.addressable_devices_indices_map(
-                template.shape
-            ).items()
-        ]
-        out[li] = jax.make_array_from_single_device_arrays(
-            template.shape, template.sharding, arrays
-        )
-    return jax.tree_util.tree_unflatten(treedef, out)
+    with tracing.annotate("exchange.reassemble", step=step):
+        out: List[Any] = [None] * len(leaves)
+        shard_acc: Dict[int, Dict[Tuple, np.ndarray]] = {}
+        for it, averaged in zip(items, item_out):
+            if it.index is None:
+                out[it.leaf_pos] = averaged
+            else:
+                shard_acc.setdefault(it.leaf_pos, {})[it.index] = averaged
+        for li, by_idx in shard_acc.items():
+            template = leaves[li]
+            arrays = [
+                jax.device_put(by_idx[_index_desc(index, template.shape)], dev)
+                for dev, index in template.sharding.addressable_devices_indices_map(
+                    template.shape
+                ).items()
+            ]
+            out[li] = jax.make_array_from_single_device_arrays(
+                template.shape, template.sharding, arrays
+            )
+    return out, {
+        "buckets": len(plan),
+        "bytes_d2h": sum(it.nbytes for it in items),
+        "tail_wait_s": tail_wait_s,
+        **sums,
+    }

@@ -1946,7 +1946,9 @@ class Manager:
             self.resolve_pending_commit()
 
         t_commit = _time.perf_counter()
-        rec = self._prepare_commit()
+        # the drain of the step's pending work, apart from the vote RPC
+        with telemetry.TRACER.span("commit.prepare", trace_id=self._trace_id()):
+            rec = self._prepare_commit()
         with telemetry.TRACER.span(
             "should_commit",
             trace_id=self._trace_id(),
@@ -1997,7 +1999,8 @@ class Manager:
         ), "at most one speculative commit may be outstanding"
         assert not self._healing, "healing replica must not speculate"
 
-        rec = self._prepare_commit()
+        with telemetry.TRACER.span("commit.prepare", trace_id=self._trace_id()):
+            rec = self._prepare_commit()
         rec.on_resolved = on_resolved
         # close the checkpoint-serving window at ISSUE time: resolution
         # happens after the NEXT step's quorum, which may re-stage a fresh

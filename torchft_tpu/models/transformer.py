@@ -376,53 +376,55 @@ def _make_layer_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
 
     def layer_fn(x: jnp.ndarray, lp: Dict[str, Any]) -> jnp.ndarray:
         x = _constrain(x, _act_spec(sp_manual))
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        b, s, _ = h.shape  # s is the sp-local block inside a manual region
-        if sp_manual and sp_size > 1:
-            positions = jax.lax.axis_index("sp") * s + jnp.arange(s)
-        else:
-            positions = jnp.arange(s)
-        q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-        v = (h @ lp["wv"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-        q = rotary_embed(q, positions, cfg.rope_theta)
-        k = rotary_embed(k, positions, cfg.rope_theta)
-        if sp_size > 1 and sp_manual:
-            att = ring_attention_local(q, k, v, sp_size, causal=True)
-        elif sp_size > 1:
-            att = ring_attention(q, k, v, mesh, causal=True)
-        elif _use_chunked(cfg, s):
-            att = chunked_attention(
-                q, k, v, causal=True, chunk=_attn_chunk(s),
-                tiers=_attn_tiers(),
-            )
-        elif _use_flash(cfg, s, b, mesh):
-            # flash needs its own (full) manual region, which can't nest
-            # inside the pipeline's partial-manual shard_map (Shardy rejects
-            # nested manual regions) — pp>1 long-context should shard the
-            # sequence (sp), which routes to ring attention above
-            inside_manual = sp_manual or (
-                mesh is not None and mesh.shape.get("pp", 1) > 1
-            )
-            if inside_manual:
-                # no fallback: flash was picked because plain attention's
-                # scores cannot fit either
-                raise ValueError(
-                    f"flash attention (attention_impl={cfg.attention_impl!r}, "
-                    f"b{b} s{s}) cannot run inside the pipeline's manual "
-                    "region (pp>1); shard the sequence (sp>1, ring "
-                    "attention) for long context under pp"
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+            b, s, _ = h.shape  # s is the sp-local block inside a manual region
+            if sp_manual and sp_size > 1:
+                positions = jax.lax.axis_index("sp") * s + jnp.arange(s)
+            else:
+                positions = jnp.arange(s)
+            q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = (h @ lp["wk"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+            v = (h @ lp["wv"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+            q = rotary_embed(q, positions, cfg.rope_theta)
+            k = rotary_embed(k, positions, cfg.rope_theta)
+            if sp_size > 1 and sp_manual:
+                att = ring_attention_local(q, k, v, sp_size, causal=True)
+            elif sp_size > 1:
+                att = ring_attention(q, k, v, mesh, causal=True)
+            elif _use_chunked(cfg, s):
+                att = chunked_attention(
+                    q, k, v, causal=True, chunk=_attn_chunk(s),
+                    tiers=_attn_tiers(),
                 )
-            att = _flash_sharded(q, k, v, mesh)
-        else:
-            att = attention(q, k, v, causal=True)
-        x = x + att.reshape(b, s, cfg.qkv_dim) @ lp["wo"]
+            elif _use_flash(cfg, s, b, mesh):
+                # flash needs its own (full) manual region, which can't nest
+                # inside the pipeline's partial-manual shard_map (Shardy rejects
+                # nested manual regions) — pp>1 long-context should shard the
+                # sequence (sp), which routes to ring attention above
+                inside_manual = sp_manual or (
+                    mesh is not None and mesh.shape.get("pp", 1) > 1
+                )
+                if inside_manual:
+                    # no fallback: flash was picked because plain attention's
+                    # scores cannot fit either
+                    raise ValueError(
+                        f"flash attention (attention_impl={cfg.attention_impl!r}, "
+                        f"b{b} s{s}) cannot run inside the pipeline's manual "
+                        "region (pp>1); shard the sequence (sp>1, ring "
+                        "attention) for long context under pp"
+                    )
+                att = _flash_sharded(q, k, v, mesh)
+            else:
+                att = attention(q, k, v, causal=True)
+            x = x + att.reshape(b, s, cfg.qkv_dim) @ lp["wo"]
 
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.n_experts:
-            x = x + _ffn_moe(lp, h, cfg)
-        else:
-            x = x + _ffn_dense(lp, h)
+        with jax.named_scope("moe" if cfg.n_experts else "ffn"):
+            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.n_experts:
+                x = x + _ffn_moe(lp, h, cfg)
+            else:
+                x = x + _ffn_dense(lp, h)
         return _constrain(x, _act_spec(sp_manual))
 
     return layer_fn
@@ -463,15 +465,16 @@ def _embed_lookup(
     back to "involuntary full rematerialization", replicating [V,D] on
     every device each step). Only the (much smaller) [B,S,D] activation
     is resharded to the standard spec afterwards."""
-    embed = _constrain(params["embed"].astype(dt), P(None, ("tp", "fsdp")))
-    tok = _constrain(tokens, P("dp", "sp"))
-    x = jnp.take(embed, tok, axis=0)
-    # reshard to the activation spec ONE axis move per step — GSPMD falls
-    # back to a full-remat copy on the combined move (fsdp D→B while
-    # dropping tp) but handles each single-axis hop efficiently
-    x = _constrain(x, P("dp", "sp", ("tp", "fsdp")))
-    x = _constrain(x, P(("dp", "fsdp"), "sp", "tp"))
-    return _constrain(x, _act_spec())
+    with jax.named_scope("embed"):
+        embed = _constrain(params["embed"].astype(dt), P(None, ("tp", "fsdp")))
+        tok = _constrain(tokens, P("dp", "sp"))
+        x = jnp.take(embed, tok, axis=0)
+        # reshard to the activation spec ONE axis move per step — GSPMD
+        # falls back to a full-remat copy on the combined move (fsdp D→B
+        # while dropping tp) but handles each single-axis hop efficiently
+        x = _constrain(x, P("dp", "sp", ("tp", "fsdp")))
+        x = _constrain(x, P(("dp", "fsdp"), "sp", "tp"))
+        return _constrain(x, _act_spec())
 
 
 def _hidden_states(
@@ -548,12 +551,14 @@ def loss_fn(
     # very long context under sp by adding sp shards, not chunking.
     if sp == 1 and _per_device_logit_elems(cfg, b, s, mesh) > _loss_chunk_elems():
         return _chunked_loss(params, tokens, cfg, mesh)
-    logits = forward(params, tokens, cfg, mesh)
-    targets = jnp.roll(tokens, -1, axis=1)
-    logprobs = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)[..., 0]
-    mask = jnp.ones_like(nll).at[:, -1].set(0.0)
-    return jnp.sum(nll * mask) / jnp.sum(mask)
+    x = _hidden_states(params, tokens, cfg, mesh)
+    with jax.named_scope("head_loss"):
+        logits = (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
+        targets = jnp.roll(tokens, -1, axis=1)
+        logprobs = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)[..., 0]
+        mask = jnp.ones_like(nll).at[:, -1].set(0.0)
+        return jnp.sum(nll * mask) / jnp.sum(mask)
 
 
 def _loss_chunk_elems() -> int:
@@ -631,10 +636,11 @@ def _chunked_loss(
         nll_sum, cnt = carry
         return (nll_sum + jnp.sum(nll * m_c), cnt + jnp.sum(m_c)), None
 
-    (nll_sum, cnt), _ = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.float32(0.0)), (hs, ts, ms)
-    )
-    return nll_sum / cnt
+    with jax.named_scope("head_loss"):
+        (nll_sum, cnt), _ = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.float32(0.0)), (hs, ts, ms)
+        )
+        return nll_sum / cnt
 
 
 def _pipelined_loss(
@@ -668,6 +674,7 @@ def _pipelined_loss(
         "out": params["out"].astype(dt),
     }
 
+    @jax.named_scope("head_loss")
     def head_fn(hp, outs, t):
         h = rms_norm(outs, hp["final_norm"], cfg.norm_eps)
         logits = (h @ hp["out"]).astype(jnp.float32)

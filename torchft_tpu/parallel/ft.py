@@ -36,6 +36,7 @@ from torchft_tpu.ddp import allreduce_gradients
 from torchft_tpu.manager import Manager
 from torchft_tpu.optim import SpeculativeCommitMixin
 from torchft_tpu.parallel.train_step import TrainStep
+from torchft_tpu.telemetry.tracing import TRACER
 
 __all__ = ["FTTrainer"]
 
@@ -142,7 +143,8 @@ class FTTrainer(SpeculativeCommitMixin):
         meaning the current batch's forward/backward ran on the restored
         state's vetoed successor and must be replayed."""
         if self._manager.pending_commit() is not None:
-            self._manager.resolve_pending_commit()
+            with TRACER.span("resolve_speculation"):
+                self._manager.resolve_pending_commit()
         return self._consume_replay()
 
     # -- drive --
@@ -157,32 +159,50 @@ class FTTrainer(SpeculativeCommitMixin):
         authoritative result lands when the NEXT step (or :meth:`finish`)
         resolves the vote — a veto rolls the update back, replays, and
         bumps :attr:`rollbacks`."""
-        self._manager.start_quorum()
-        tokens = self._ts.shard_batch(tokens)
-        # forward/backward first: in pipelined mode this is the compute
-        # that hides the previous step's vote RTT
-        loss, grads = self._ts.grads(self._params, tokens)
-        if self._resolve_speculation():
-            # previous step vetoed: grads above were taken on the now
-            # rolled-back params — replay this batch on the restored state
-            loss, grads = self._ts.grads(self._params, tokens)
-        # cross the elastic replica axis on host
-        grads = allreduce_gradients(self._manager, grads)
-        if self._manager.speculation_allowed():
-            # keep the pre-update trees alive (references, no copy) and
-            # publish the snapshot BEFORE the apply so a concurrent
-            # checkpoint serve never sees the speculative trees
-            self._snapshot = (self._params, self._opt_state)
-            self._params, self._opt_state = self._ts.apply(
-                self._params, self._opt_state, grads, donate=False
-            )
-            self._manager.should_commit_async(
-                on_resolved=self._on_vote_resolved
-            )
-            return float(loss), True
-        committed = self._manager.should_commit()
-        if committed:
-            self._params, self._opt_state = self._ts.apply(
-                self._params, self._opt_state, grads
-            )
-        return float(loss), committed
+        mgr = self._manager
+        # the step's own timeline (docs/observability.md): one span per piece
+        # of the step, in the Tracer ring and — as tft.<name> — in a profiler
+        # trace. A pipelined vote still in flight makes this the step after
+        # current_step(), as the manager labels it.
+        label = mgr.current_step() + (1 if mgr.pending_commit() is not None else 0)
+        with TRACER.span("step", step_num=label) as step_span:
+            # the call only: the quorum itself runs on the quorum thread
+            with TRACER.span("quorum.start"):
+                mgr.start_quorum()
+            with TRACER.span("shard_batch"):
+                tokens = self._ts.shard_batch(tokens)
+            # forward/backward first: in pipelined mode this is the compute
+            # that hides the previous step's vote RTT
+            with TRACER.span("grads"):  # dispatch only
+                loss, grads = self._ts.grads(self._params, tokens)
+            if self._resolve_speculation():
+                # previous step vetoed: grads above were taken on the now
+                # rolled-back params — replay this batch on the restored state
+                with TRACER.span("grads"):  # a step's second: the replay
+                    loss, grads = self._ts.grads(self._params, tokens)
+            # cross the elastic replica axis on host (the `exchange` span)
+            grads = allreduce_gradients(mgr, grads)
+            if mgr.speculation_allowed():
+                # keep the pre-update trees alive (references, no copy) and
+                # publish the snapshot BEFORE the apply so a concurrent
+                # checkpoint serve never sees the speculative trees
+                self._snapshot = (self._params, self._opt_state)
+                with TRACER.span("apply"):
+                    self._params, self._opt_state = self._ts.apply(
+                        self._params, self._opt_state, grads, donate=False
+                    )
+                with TRACER.span("commit"):
+                    mgr.should_commit_async(on_resolved=self._on_vote_resolved)
+                committed = True
+            else:
+                with TRACER.span("commit"):
+                    committed = mgr.should_commit()
+                if committed:
+                    with TRACER.span("apply"):  # dispatch only
+                        self._params, self._opt_state = self._ts.apply(
+                            self._params, self._opt_state, grads
+                        )
+            with TRACER.span("loss_sync"):
+                loss = float(loss)
+            step_span.set(committed=committed)
+        return loss, committed

@@ -65,17 +65,25 @@ class TrainStep:
         # apply compiled three variants (first step, steady state, first
         # step after a heal): the last is a persistent-cache miss for every
         # respawned group, and none matched what warm_apply lowers.
+        # The three programs carry deliberate names (jit_tft_grads,
+        # jit_tft_apply, jit_tft_fused): a profiler trace's `XLA Modules`
+        # line and the compile log name a program after its function, and
+        # what reads them must find it after a refactor.
+        def tft_grads(params, tokens):
+            return jax.value_and_grad(compute_loss)(params, tokens)
+
         self._value_and_grad = jax.jit(
-            jax.value_and_grad(compute_loss),
+            tft_grads,
             in_shardings=(self._param_shardings, self._batch_sharding),
             out_shardings=(replicated, self._param_shardings),
         )
 
-        def apply_updates(params, opt_state, grads):
-            updates, opt_state = tx.update(grads, opt_state, params)
+        def tft_apply(params, opt_state, grads):
             import optax
 
-            return optax.apply_updates(params, updates), opt_state
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                return optax.apply_updates(params, updates), opt_state
 
         self._apply_shardings = dict(
             in_shardings=(
@@ -84,20 +92,20 @@ class TrainStep:
             out_shardings=(self._param_shardings, self._opt_shardings),
         )
         self._apply = jax.jit(
-            apply_updates, donate_argnums=(0, 1), **self._apply_shardings
+            tft_apply, donate_argnums=(0, 1), **self._apply_shardings
         )
         # pipelined-commit variant, compiled lazily: the inputs must NOT
         # be donated so the pre-update (params, opt_state) stays alive on
         # device as the rollback snapshot (a reference, not a copy)
-        self._apply_updates_fn = apply_updates
+        self._apply_updates_fn = tft_apply
         self._apply_keep = None
 
-        def fused(params, opt_state, tokens):
-            loss, grads = jax.value_and_grad(compute_loss)(params, tokens)
-            new_params, opt_state = apply_updates(params, opt_state, grads)
+        def tft_fused(params, opt_state, tokens):
+            loss, grads = tft_grads(params, tokens)
+            new_params, opt_state = tft_apply(params, opt_state, grads)
             return loss, new_params, opt_state
 
-        self._fused = jax.jit(fused, donate_argnums=(0, 1))
+        self._fused = jax.jit(tft_fused, donate_argnums=(0, 1))
 
     # -- state --
 

@@ -2,11 +2,11 @@
 
 Reference: wall-clock context managers ``_time`` / ``_timeit`` logging
 checkpoint-stage durations (http_transport.py:31-36, pg_transport.py:73-78)
-— no deeper profiler. The TPU build goes further: ``profile`` wraps
-``jax.profiler`` traces (viewable in TensorBoard/XProf, capturing XLA ops,
-HBM traffic and ICI collectives) and ``StepTimer`` keeps a rolling
-steps/sec with outlier-marked quorum/heal steps, feeding the
+— no deeper profiler. The TPU build goes further: ``StepTimer`` keeps a
+rolling steps/sec with outlier-marked quorum/heal steps, feeding the
 ``tft_step_duration_seconds`` histogram in :mod:`torchft_tpu.telemetry`.
+Device-level traces (XLA ops, HBM traffic, the program's own ``tft.*``
+spans) are taken by ``telemetry/profiler.py::capture_jax_trace``.
 """
 
 from __future__ import annotations
@@ -19,7 +19,7 @@ from typing import Deque, Iterator, List, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["timed", "profile", "StepTimer"]
+__all__ = ["timed", "StepTimer"]
 
 
 @contextlib.contextmanager
@@ -32,24 +32,6 @@ def timed(what: str, log: logging.Logger = logger) -> Iterator[None]:
     t0 = time.perf_counter()
     yield
     log.info("%s took %.3fs", what, time.perf_counter() - t0)
-
-
-@contextlib.contextmanager
-def profile(log_dir: Optional[str] = None) -> Iterator[None]:
-    """jax.profiler trace around a block; no-op if log_dir is None.
-
-    View with ``tensorboard --logdir <log_dir>`` (Profile tab) — includes
-    per-op device timelines, memory viewer, and collective stats."""
-    if log_dir is None:
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 class StepTimer:

@@ -21,31 +21,76 @@ Spans export two ways:
   cluster merged on one timeline (replicas piggyback recent span batches
   on their quorum traffic — see ``docs/observability.md``).
 
-Design constraints match the rest of the package: stdlib-only, no jax
-import, exception-free on the hot path (a tracing bug must never fail a
-step), and cheap when idle (span entry/exit is a couple of dict ops).
+Every span also lands in the profiler's trace: for its lifetime it holds a
+``jax.profiler.TraceAnnotation`` named ``tft.<name>``, so a
+``jax.profiler`` session (``telemetry/profiler.py::capture_jax_trace`` or
+any other) shows the program's spans on the thread that ran them, on the
+clock the device plane shares. The hot inner loop — per bucket, on the
+collectives op thread — uses :func:`annotate`, the same annotation with no
+ring entry. With no session open an annotation costs under a microsecond.
+
+Design constraints match the rest of the package: stdlib-only at import
+(the annotation class is taken from an already-imported ``jax``, and is
+skipped when there is none), exception-free on the hot path (a tracing bug
+must never fail a step), and cheap when idle (span entry/exit is a couple
+of dict ops).
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import zlib
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, ContextManager, Deque, Dict, List, Optional
 
 __all__ = [
     "Span",
     "Tracer",
     "TRACER",
+    "annotate",
     "chrome_trace",
     "ENV_TRACE_PATH",
+    "TRACE_PREFIX",
 ]
 
 ENV_TRACE_PATH = "TORCHFT_TRACE_PATH"
 ENV_TRACE_RING = "TORCHFT_TRACE_RING"
+# the program's spans in a profiler trace; keeps them apart from a
+# harness's own wrapper spans
+TRACE_PREFIX = "tft."
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _profiler_annotation(name: str, step_num: Optional[int], stats: Dict[str, Any]):
+    """A ``jax.profiler`` annotation ``tft.<name>`` carrying the scalar
+    ``stats`` (an annotation takes its stats at entry), or None when JAX was
+    never imported. ``step_num`` makes it a ``StepTraceAnnotation``: the
+    profiler's step tooling and the device plane's ``Steps`` line key on it."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    scalars = {
+        k: v for k, v in stats.items() if isinstance(v, (bool, int, float, str))
+    }
+    if step_num is not None:
+        return profiler.StepTraceAnnotation(
+            TRACE_PREFIX + name, step_num=int(step_num), **scalars
+        )
+    return profiler.TraceAnnotation(TRACE_PREFIX + name, **scalars)
+
+
+def annotate(name: str, **stats: Any) -> ContextManager[Any]:
+    """The light span of the hot inner loop: ``tft.<name>`` in the
+    profiler's trace with ``stats`` as the event's stats, and nothing else
+    — no ring entry, no piggyback entry, no lock, no counter. A no-op when
+    JAX was never imported."""
+    return _profiler_annotation(name, None, stats) or _NO_SPAN
 
 
 def _ring_size() -> int:
@@ -76,6 +121,7 @@ class Span:
         "parent_id",
         "replica_id",
         "ts",
+        "t0_mono_ns",
         "dur_s",
         "tid",
         "attrs",
@@ -94,7 +140,10 @@ class Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.replica_id = replica_id
+        # wall clock for the cross-replica /trace merge; CLOCK_MONOTONIC so
+        # the ring/JSONL spans line up with other stamps of this host
         self.ts = time.time()
+        self.t0_mono_ns = time.monotonic_ns()
         self.dur_s = 0.0
         self.tid = threading.get_ident() & 0x7FFFFFFF
         self.attrs: Dict[str, Any] = {}
@@ -109,6 +158,7 @@ class Span:
             "trace_id": self.trace_id,
             "span_id": self.span_id,
             "ts": self.ts,
+            "t0_mono_ns": self.t0_mono_ns,
             "dur_s": round(self.dur_s, 6),
             "replica_id": self.replica_id,
             "tid": self.tid,
@@ -159,16 +209,33 @@ def _chrome_process_name(replica_id: str) -> Dict[str, Any]:
 class _SpanCtx:
     """Context manager produced by :meth:`Tracer.span`."""
 
-    def __init__(self, tracer: "Tracer", span: Span) -> None:
+    def __init__(
+        self, tracer: "Tracer", span: Span, step_num: Optional[int] = None
+    ) -> None:
         self._tracer = tracer
         self.span = span
+        self._step_num = step_num
+        self._ann = None
         self._t0 = time.perf_counter()
 
     def __enter__(self) -> Span:
         self._tracer._push(self.span)
+        try:
+            self._ann = _profiler_annotation(
+                self.span.name, self._step_num, self.span.attrs
+            )
+            if self._ann is not None:
+                self._ann.__enter__()
+        except Exception:  # noqa: BLE001 — tracing must never fail a step
+            self._ann = None
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ann is not None:
+            try:
+                self._ann.__exit__(exc_type, exc, tb)
+            except Exception:  # noqa: BLE001 — as above
+                pass
         self.span.dur_s = time.perf_counter() - self._t0
         if exc is not None:
             self.span.attrs.setdefault("error", repr(exc))
@@ -244,13 +311,23 @@ class Tracer:
         parent: Optional[Dict[str, Any]] = None,
         trace_id: Optional[str] = None,
         replica_id: Optional[str] = None,
+        step_num: Optional[int] = None,
         **attrs: Any,
     ) -> _SpanCtx:
         """Open a span. ``parent`` is a carrier dict (from :meth:`inject`,
         possibly received over an RPC) that both links the parent span and
         adopts its trace_id; otherwise the innermost open span on this
-        thread is the parent and the process context names the trace."""
+        thread is the parent and the process context names the trace.
+        ``step_num`` marks the span as the root of a training step: it is
+        the step coordinate of the trace_id, and the profiler sees a
+        ``StepTraceAnnotation``. Scalar ``attrs`` given here are the stats
+        of the span's ``tft.<name>`` event in a profiler trace."""
         parent_id: Optional[str] = None
+        if trace_id is None and step_num is not None:
+            # a step's root opens before start_quorum moves the process
+            # context on to that step
+            c = self.context()
+            trace_id = f"{c['replica_id']}:{int(step_num)}:{c['quorum_epoch']}"
         if parent:
             parent_id = parent.get("span_id") or None
             if trace_id is None:
@@ -268,7 +345,7 @@ class Tracer:
         s = Span(name, trace_id, self._next_span_id(), parent_id, replica_id)
         if attrs:
             s.attrs.update(attrs)
-        return _SpanCtx(self, s)
+        return _SpanCtx(self, s, step_num)
 
     def inject(self) -> Dict[str, str]:
         """Carrier for RPC metadata: the current span (or bare context) as
