@@ -484,6 +484,54 @@ def test_pipelined_averaging_latches_midway_error(harness):
     assert m.should_commit() is False
 
 
+def test_step_after_a_latched_error_packs_into_new_buffers(harness, monkeypatch):
+    """The exchange keeps its bucket buffers between steps, but not past an
+    error: the op thread may still hold them. The step after the latch
+    allocates anew, averages correctly and commits; the one after it reuses."""
+    import jax
+    import jax.numpy as jnp
+
+    from torchft_tpu import ddp
+    from torchft_tpu.collectives import PeerGoneError, ReduceOp
+    from torchft_tpu.telemetry import tracing
+
+    # as on the chip: device_put copies, so device leaves make buckets keepable
+    monkeypatch.setattr(ddp, "_put_copies", lambda src: isinstance(src, jax.Array))
+    h = harness()
+    m = h.manager
+    h.client._quorum.return_value = quorum_result(max_rank=1)
+    real_allreduce = h.collectives.allreduce
+    calls = {"n": 0, "fail_at": None}
+
+    def flaky(arrays, op=ReduceOp.SUM):
+        calls["n"] += 1
+        if calls["n"] == calls["fail_at"]:
+            raise PeerGoneError(0, "peer died mid-bucket")
+        return real_allreduce(arrays, op)
+
+    h.collectives.allreduce = flaky
+
+    def step(value, commits):
+        m.start_quorum()
+        grads = {f"g{i}": jnp.full((16,), value + i) for i in range(4)}
+        out = ddp.allreduce_gradients(m, grads, bucket_bytes=64)
+        h.client.should_commit.return_value = commits
+        assert m.should_commit() is commits
+        attrs = tracing.TRACER.recent("exchange")[-1]["attrs"]
+        return out, attrs["buckets_reused"], attrs["buckets"]
+
+    assert step(1.0, True)[1:] == (0, 4)
+    assert step(2.0, True)[1:] == (4, 4)
+    calls["fail_at"] = calls["n"] + 2
+    _, reused, _ = step(3.0, False)
+    assert reused == 4 and m not in ddp._KEPT
+    out, reused, _ = step(4.0, True)
+    assert reused == 0 and m.errored() is None
+    for i in range(4):  # two participants, the dummy plane adds nothing
+        np.testing.assert_array_equal(np.asarray(out[f"g{i}"]), (4.0 + i) / 2)
+    assert step(5.0, True)[1:] == (4, 4)
+
+
 def test_start_quorum_retries_after_timeout(harness):
     """A timed-out quorum must not poison the Manager: the next
     start_quorum is the caller's retry and starts fresh (a loaded host

@@ -201,7 +201,8 @@ def test_bucket_stats_tie_the_threads_together(traced):
     assert counters["buckets"] == len(keys["exchange.submit"]) >= 3
     assert counters["bytes_d2h"] == total
     assert set(counters) == {
-        "step", "buckets", "bytes_d2h", "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
+        "step", "buckets", "buckets_reused", "bytes_d2h", "d2h_wait_s", "pack_s", "tail_wait_s",
+        "utime_s", "stime_s",
     }
     assert all(counters[key] >= 0 for key in counters)
     # a zero-length carrier at the end of its exchange
@@ -233,7 +234,8 @@ def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, mo
     assert last["attrs"]["committed"] is True
     (exchange,) = [s for s in children if s["name"] == "exchange"]
     assert set(exchange["attrs"]) == {
-        "step", "buckets", "bytes_d2h", "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
+        "step", "buckets", "buckets_reused", "bytes_d2h", "d2h_wait_s", "pack_s", "tail_wait_s",
+        "utime_s", "stime_s",
     }
     # nothing per bucket, and at most 12 new entries a step
     assert not [s for s in spans if s["name"].startswith("exchange.")]

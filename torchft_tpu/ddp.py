@@ -23,9 +23,19 @@ any in-flight commit vote (``manager.resolve_pending_commit()``) before
 calling :func:`allreduce_gradients` for the next step — the Manager
 raises otherwise, because gradients of a speculative (possibly about to
 be rolled back) state must never enter a collective. The bucket buffers
-here always own their memory (``np.concatenate`` / explicit ``copy``),
+here always own their memory (``np.empty``, packed with ``np.copyto``),
 so the in-place ring reduction can never corrupt the caller's retained
 gradient pytree across a rollback/replay.
+
+The bucket buffers live as long as the bucket plan does. Mapping fresh
+host pages costs ~4 us each where writing touched ones runs at memory
+speed (PERF.md §5), so a manager's first exchange with a plan allocates
+the buffers and every later one packs into them (:class:`_KeptBuckets`
+holds the three rules that keep this as safe as a fresh buffer; the
+``exchange`` span counts ``buckets_reused`` beside ``buckets``). The cost
+is host memory: a tree's size per manager stays resident between
+exchanges — for ``LocalSGD``, which exchanges every H steps, a model's
+size held for one saved allocation per sync.
 
 What the exchange spends its time on is visible from inside
 (docs/observability.md "Spans in the profiler's trace"): one ``exchange``
@@ -39,6 +49,7 @@ from __future__ import annotations
 import os
 import resource
 import time
+import weakref
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -139,8 +150,75 @@ class _Item:
         self.index = index  # shard index desc, or None for dense
 
     @property
+    def size(self) -> int:
+        return int(np.prod(self.shape, dtype=np.int64))
+
+    @property
     def nbytes(self) -> int:
-        return int(np.prod(self.shape, dtype=np.int64)) * self.dtype.itemsize
+        return self.size * self.dtype.itemsize
+
+
+class _KeptBuckets:
+    """One bucket plan's staging buffers, from one exchange of a manager to
+    its next. They belong to that manager (``_KEPT``), never to the module:
+    several managers run in one process.
+
+    A buffer is rewritten only when nothing can still read or write it:
+
+    * what the last exchange placed from it is on the device. ``placed``
+      holds the arrays whose ``device_put`` had not finished when that
+      exchange returned, and :func:`_take_kept` blocks on them (a step the
+      commit refused never ran the ``apply`` that would have);
+    * the last ring that was handed it has left the op thread. The manager
+      completes a timed-out or failed op with the very buffers while the
+      op thread may still be inside the ring, always with its error
+      latched: an exchange that ends with ``errored()`` set keeps nothing;
+    * nothing handed out aliases it. A host leaf comes back as a slice of
+      its bucket, and on the CPU backend ``device_put`` of an aligned NumPy
+      array may share its memory (:func:`_put_copies`): ``bufs`` holds
+      ``None`` for a bucket with such a piece, and that bucket is
+      allocated anew every time.
+    """
+
+    __slots__ = ("key", "bufs", "placed")
+
+    def __init__(self, key: Tuple[Tuple[np.dtype, int], ...]) -> None:
+        self.key = key  # per bucket (dtype, element count)
+        self.bufs: List[Optional[np.ndarray]] = [None] * len(key)
+        self.placed: List[Any] = []
+
+
+_KEPT: "weakref.WeakKeyDictionary[Any, _KeptBuckets]" = weakref.WeakKeyDictionary()
+
+
+def _put_copies(src: Any) -> bool:
+    """Whether an averaged piece goes back to ``src``'s devices as an array
+    that owns its memory: a device array off the CPU backend."""
+    import jax
+
+    return (
+        isinstance(src, jax.Array)
+        and next(iter(src.sharding.device_set)).platform != "cpu"
+    )
+
+
+def _take_kept(manager, key: Tuple[Tuple[np.dtype, int], ...]) -> _KeptBuckets:
+    """``manager``'s kept buffers if they are of this plan and safe to
+    rewrite, else an empty set. Taken out of ``_KEPT`` for the length of
+    the exchange: only one that ends clean puts its set back."""
+    import jax
+
+    kept = _KEPT.pop(manager, None)
+    if (
+        kept is None
+        or kept.key != key
+        # given away (donated) before its transfer was seen to end
+        or any(a.is_deleted() for a in kept.placed)
+    ):
+        return _KeptBuckets(key)
+    jax.block_until_ready(kept.placed)
+    kept.placed = []
+    return kept
 
 
 def allreduce_gradients(
@@ -228,7 +306,7 @@ def _host_exchange(
     # averaged once and re-placed to every holder.
     from torchft_tpu.checkpointing.serialization import _index_desc
 
-    sums = {"d2h_wait_s": 0.0, "pack_s": 0.0}  # per step
+    sums = {"buckets_reused": 0, "d2h_wait_s": 0.0, "pack_s": 0.0}  # per step
 
     # stage 0: kick off D2H for every leaf/shard before anything blocks.
     # No guard: a runtime that rejects the prefetch would serialise every
@@ -265,6 +343,15 @@ def _host_exchange(
         plan = plan_buckets(
             [(it.dtype, it.nbytes) for it in items], bucket_bytes
         )
+        # the wait for what the last exchange was still placing is here:
+        # microseconds, unless the step in between never ran its update
+        kept = _take_kept(
+            manager,
+            tuple(
+                (items[idxs[0]].dtype, sum(items[i].size for i in idxs))
+                for idxs in plan
+            ),
+        )
 
     def _run_bucket(ordinal: int, idxs: List[int]):
         # what ties this bucket's events together across threads
@@ -282,12 +369,24 @@ def _host_exchange(
             host = [np.asarray(items[i].src) for i in idxs]
         t1 = time.perf_counter()
         with tracing.annotate("exchange.pack", **tags):
-            flat = [np.ascontiguousarray(h).reshape(-1) for h in host]
             # the bucket buffer always owns its memory: the ring reduces
             # (and non-participants zero) in place, which must never write
             # through a view of the caller's arrays or a read-only XLA
             # host buffer
-            buf = np.concatenate(flat) if len(flat) > 1 else flat[0].copy()
+            buf = kept.bufs[ordinal]
+            if buf is None:
+                dtype, count = kept.key[ordinal]
+                buf = np.empty(count, dtype)
+            else:
+                sums["buckets_reused"] += 1
+            off = 0
+            for h in host:
+                np.copyto(buf[off : off + h.size].reshape(h.shape), h)
+                off += h.size
+            # it outlives the call only if no piece of it does
+            kept.bufs[ordinal] = (
+                buf if all(_put_copies(items[i].src) for i in idxs) else None
+            )
         t2 = time.perf_counter()
         record_wire_stage("host_copy", t2 - t0)
         sums["d2h_wait_s"] += t1 - t0
@@ -357,12 +456,15 @@ def _host_exchange(
     # synchronous fleet a slow peer inflates exactly this wait, which is
     # what lets the straggler detector's local-time signal exclude it.
     item_out: List[np.ndarray] = [None] * len(items)  # type: ignore[list-item]
+    placed: List[Any] = []  # every device array this exchange put
     t_wait = time.perf_counter()
     with tracing.annotate("exchange.tail_wait", step=step):
         for idxs, fut in bucket_futs:
             parts = fut.wait()
             for i, piece in zip(idxs, parts):
                 item_out[i] = piece
+                if isinstance(piece, jax.Array):
+                    placed.append(piece)
     tail_wait_s = time.perf_counter() - t_wait
     _ledger.record("wire", tail_wait_s)
 
@@ -383,9 +485,15 @@ def _host_exchange(
                     template.shape
                 ).items()
             ]
+            placed.extend(arrays)
             out[li] = jax.make_array_from_single_device_arrays(
                 template.shape, template.sharding, arrays
             )
+    if any(buf is not None for buf in kept.bufs) and not getattr(
+        manager, "errored", lambda: None
+    )():
+        kept.placed = [a for a in placed if not a.is_ready()]
+        _KEPT[manager] = kept
     return out, {
         "buckets": len(plan),
         "bytes_d2h": sum(it.nbytes for it in items),

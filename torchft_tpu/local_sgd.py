@@ -60,7 +60,14 @@ __all__ = ["LocalSGD", "DiLoCo"]
 
 
 class LocalSGD:
-    """Parameter averaging every ``sync_every`` local steps."""
+    """Parameter averaging every ``sync_every`` local steps.
+
+    The exchange keeps its bucket buffers from one sync to the next
+    (``ddp._KeptBuckets``) when ``params`` are device arrays off the CPU
+    backend: a model's size of host memory stays resident between syncs
+    for one saved allocation per sync. Host ``params`` (and ``DiLoCo``'s
+    pseudogradients) come back as slices of their buckets, so nothing is
+    kept for them."""
 
     def __init__(
         self,
