@@ -7,7 +7,7 @@ import program_spans
 
 NAME, UNIT, SOURCE = "apply_device_s", "s", "device_trace"
 LAYER = "device compute"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

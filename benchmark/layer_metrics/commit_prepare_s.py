@@ -7,7 +7,7 @@ import program_spans
 
 NAME, UNIT, SOURCE = "commit_prepare_s", "s", "program_span"
 LAYER = "manager and native core"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

@@ -6,7 +6,7 @@ from measure import median
 
 NAME, UNIT, SOURCE = "device_step_s", "s", "device_trace"
 LAYER = "device compute"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

@@ -8,7 +8,7 @@ import program_spans
 
 NAME, UNIT, SOURCE = "exchange_h2d_s", "s", "program_span"
 LAYER = "gradient exchange (host path)"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

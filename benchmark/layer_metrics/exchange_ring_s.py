@@ -8,7 +8,7 @@ import program_spans
 
 NAME, UNIT, SOURCE = "exchange_ring_s", "s", "program_span"
 LAYER = "collectives (CollectivesTcp)"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

@@ -5,7 +5,7 @@ line of the output."""
 
 NAME, UNIT, SOURCE = "exchange_s", "s", "program_span"
 LAYER = "gradient exchange (host path)"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

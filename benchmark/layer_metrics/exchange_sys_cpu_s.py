@@ -7,7 +7,7 @@ import program_spans
 
 NAME, UNIT, SOURCE = "exchange_sys_cpu_s", "s", "program_counter"
 LAYER = "gradient exchange (host path)"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

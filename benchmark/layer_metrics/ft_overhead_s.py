@@ -9,7 +9,7 @@ from measure import median
 
 NAME, UNIT, SOURCE = "ft_overhead_s", "s", "device_trace"
 LAYER = "ft loop"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

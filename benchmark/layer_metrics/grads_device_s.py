@@ -6,7 +6,7 @@ import program_spans
 
 NAME, UNIT, SOURCE = "grads_device_s", "s", "device_trace"
 LAYER = "device compute"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

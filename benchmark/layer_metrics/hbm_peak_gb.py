@@ -6,7 +6,7 @@ where ``compile_check.py`` counts 13.1 GB while ``fused`` runs."""
 
 NAME, UNIT, SOURCE = "hbm_peak_gb", "GB", "program_counter"
 LAYER = "device"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

@@ -9,7 +9,7 @@ from measure import tokens_per_s
 
 NAME, UNIT, SOURCE = "mfu_pct", "%", "host_clock"
 LAYER = "device compute"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

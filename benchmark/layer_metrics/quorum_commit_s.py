@@ -4,7 +4,7 @@ the window's steps, mean over groups."""
 
 NAME, UNIT, SOURCE = "quorum_commit_s", "s", "program_span"
 LAYER = "manager and native core"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

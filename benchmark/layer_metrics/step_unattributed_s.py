@@ -8,7 +8,7 @@ import program_spans
 
 NAME, UNIT, SOURCE = "step_unattributed_s", "s", "program_span"
 LAYER = "ft loop"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

@@ -4,7 +4,7 @@ groups. Exists only where there is a wire: more than one group."""
 
 NAME, UNIT, SOURCE = "wire_s", "s", "program_span"
 LAYER = "collectives (CollectivesTcp)"
-MOVES = "tokens_per_s"
+MOVES = "step_p50_s"
 
 
 def compute(run):

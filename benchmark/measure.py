@@ -75,6 +75,32 @@ def tokens_per_s(results: Sequence[Dict[str, Any]], skip_traced: bool = False) -
     return total
 
 
+def step_p50_s(results: Sequence[Dict[str, Any]]) -> float:
+    """Median time of a step inside the window: per group the median over its
+    commit-to-commit intervals (every unit whose commit stamp lies inside the
+    window, committed or not) of the interval over the unit's steps, mean over
+    groups. The same stamps ``tokens_per_s`` rests on, all of them; a stall
+    of the host that lengthens a few steps moves it little, a slower step
+    moves it in full."""
+    per_group = []
+    for r in results:
+        units = window_units(r)
+        steps = [(b["t_end"] - a["t_end"]) / len(b["steps"]) for a, b in zip(units, units[1:])]
+        if len(steps) < 2:
+            raise NotEnoughCommits(f"group {r['group']}: {len(steps)} commit-to-commit interval(s) inside the window")
+        per_group.append(median(steps))
+    return sum(per_group) / len(per_group)
+
+
+# what a ``--trace 0`` run may report beside ``setup_s``: name -> (function of
+# the groups' results, unit). BENCHMARK.json's ``end_to_end`` says which of
+# them a cell reports (an entry's ``workloads`` key; absent: every cell).
+END_TO_END = {
+    "tokens_per_s": (tokens_per_s, "tokens/s"),
+    "step_p50_s": (step_p50_s, "s"),
+}
+
+
 def attempted_failed(results: Sequence[Dict[str, Any]]) -> Tuple[int, int]:
     """Steps started inside the window, and those of them that did not
     commit, over all groups."""

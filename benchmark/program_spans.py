@@ -32,7 +32,7 @@ import reduce_trace
 from measure import median
 
 PREFIX = "tft."
-MODULE_LINE = "XLA Modules"
+MODULE_LINE = reduce_trace.MODULE_LINE
 
 # (start_ns, end_ns, name, line index, stats)
 Event = Tuple[float, float, str, int, Dict[str, Any]]

@@ -1,4 +1,5 @@
-"""opcount.py against numbers worked by hand for OLMo-1B's widths at 6 layers."""
+"""opcount.py against numbers worked by hand: OLMo-1B's widths at 6 layers (dense),
+OLMoE-1B-7B's at one layer (64 experts, 8 per token)."""
 
 import json
 import os
@@ -40,3 +41,77 @@ def test_embed_gather_is_not_counted():
 
 def test_both_configurations_hold_the_same_model():
     assert _tc("olmo1b-1g") == _tc("olmo1b-4g")
+
+
+# OLMoE-1B-7B's widths (allenai/OLMoE-1B-7B-0125-Instruct config.json: hidden 2048,
+# 16 x 128 heads, 64 experts of width 1024, 8 per token, vocab 50304) at one layer,
+# under the program's names; written here, not read from a configuration file
+OLMOE_1L = dict(
+    vocab_size=50304, d_model=2048, n_layers=1, n_heads=16, head_dim=128,
+    d_ff=1024, n_experts=64, top_k=8,
+)
+
+
+def test_hand_worked_counts_with_sparse_experts():
+    tc = OLMOE_1L
+    attn = 4 * 2048 * 2048                  # q, k, v, o
+    expert = 3 * 2048 * 1024                # gate, in, out of one expert
+    router = 2048 * 64
+    head = 2048 * 50304
+    assert (attn, 8 * expert, router, head) == (16_777_216, 50_331_648, 131_072, 103_022_592)
+    # a token passes through the router and 8 experts
+    assert opcount.matmul_params(tc) == attn + 8 * expert + router + head == 170_262_528
+    # the program holds all 64, the router, two norm weights, embed and out, the final norm
+    layer = attn + 64 * expert + router + 2 * 2048
+    assert layer == 419_565_568
+    assert opcount.n_params(tc) == layer + 2 * head + 2048 == 625_612_800
+    # f32 parameter and moments read and written, one bf16 gradient read (no dtype key: bf16)
+    assert opcount.bytes_per_step_optimizer(tc) == 26 * 625_612_800
+    assert opcount.bytes_per_step_optimizer({**tc, "dtype": "float32"}) == 28 * 625_612_800
+    # top_k absent: 2, what ops/layers.moe_dispatch hard-wires
+    two = {k: v for k, v in tc.items() if k != "top_k"}
+    assert opcount.matmul_params(two) == attn + 2 * expert + router + head
+    assert opcount.n_params(two) == opcount.n_params(tc)
+
+
+@pytest.mark.parametrize("tc, ffn", [(None, "ffn"), (OLMOE_1L, "moe")])
+def test_scopes_add_up_to_the_whole(tc, ffn):
+    tc = tc or _tc()
+    by_scope = opcount.flops_per_token_by_scope(tc, 2048)
+    assert set(by_scope) == {"attn", ffn, "head_loss"} and opcount.ffn_scope(tc) == ffn
+    assert sum(by_scope.values()) == pytest.approx(opcount.flops_per_token(tc, 2048), rel=1e-12)
+    d, layers = tc["d_model"], tc["n_layers"]
+    assert by_scope["head_loss"] == 6 * d * tc["vocab_size"]
+    # projections, and causal scores: 2 matmuls x 2*S*qkv at half the square, x3 with the backward
+    assert by_scope["attn"] == layers * (6 * 4 * d * d + 3 * 2 * 2 * 2048 * d / 2)
+
+
+def test_no_experts_is_the_dense_count_to_the_digit():
+    tc = _tc()
+    for zero in ({}, {"n_experts": 0}, {"n_experts": 0, "top_k": 8}):
+        assert opcount.matmul_params({**tc, **zero}) == 505_675_776
+        assert opcount.n_params({**tc, **zero}) == 608_724_992
+        assert opcount.flops_per_token({**tc, **zero}, 2048) == 6 * 505_675_776 + 6 * 3 * (2 * 2 * 2048 * 2048 / 2)
+    assert opcount.flops_per_token_by_scope(tc, 2048)["ffn"] == 6 * 6 * 3 * 2048 * 8192
+
+
+def test_hand_worked_bytes_by_scope():
+    """The other bound of each scope's roofline: what crosses the scope's edge, bf16."""
+    tc = _tc()
+    act = 8 * 2048 * 2048 * 2                       # one [tokens, d_model] activation
+    attn = 3 * 2 * (4 * 2048 * 2048) + 5 * act      # weights read twice and their gradient written; 5 activations
+    ffn = 3 * 2 * (3 * 2048 * 8192) + 5 * act
+    head = 3 * 2 * (2048 * 50304) + 3 * act
+    assert (attn, ffn, head) == (436_207_616, 637_534_208, 819_462_144)
+    assert opcount.bytes_per_step_by_scope(tc, 8, 2048) == {
+        "attn": 6 * attn, "ffn": 6 * ffn, "head_loss": head, "optimizer": 26 * 608_724_992,
+    }
+    # at these shapes the operations are the nearer bound of every matmul scope (v5e: 197 TFLOP/s, 819 GB/s)
+    flops = opcount.flops_per_token_by_scope(tc, 2048)
+    for scope in ("attn", "ffn", "head_loss"):
+        assert flops[scope] * 8 * 2048 / 197e12 > 10 * opcount.bytes_per_step_by_scope(tc, 8, 2048)[scope] / 819e9
+    # with experts all 64 and the router are read; a single sequence of 16 tokens is bound by that traffic
+    moe = opcount.bytes_per_step_by_scope(OLMOE_1L, 8, 2048)
+    assert moe["moe"] == 3 * 2 * (64 * 3 * 2048 * 1024 + 2048 * 64) + 5 * act and "ffn" not in moe
+    few = opcount.flops_per_token_by_scope(OLMOE_1L, 16)["moe"] * 16 / 197e12
+    assert few < opcount.bytes_per_step_by_scope(OLMOE_1L, 1, 16)["moe"] / 819e9

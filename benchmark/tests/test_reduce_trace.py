@@ -1,4 +1,4 @@
-"""reduce_trace.py against a trace recorded on one TPU v5e chip
+"""reduce_trace.py against traces recorded on one TPU v5e chip
 (``record_trace.py``): three annotated units of a jitted 3-iteration scan.
 The expected numbers were worked by hand from the events of the file.
 
@@ -62,3 +62,142 @@ def test_self_times_and_union():
 def test_a_trace_without_units_or_device_is_an_error():
     with pytest.raises(ValueError, match="bench_unit"):
         rt.reduce_planes([], ["ft.grads"])
+
+
+# -- device time by named scope: a second trace recorded on the v5e
+# (``record_trace.py <dir> scopes``): three units of a training step in
+# miniature, a scan over three checkpointed layers with ``attn`` and ``ffn``
+# scopes, a ``head_loss``, its gradient, and an ``optimizer`` update.
+
+SCOPED = os.path.join(HERE, "recorded_scopes_v5e.xplane.pb")
+
+
+@pytest.fixture(scope="module")
+def scoped():
+    return rt.reduce_file(SCOPED, ["ft.grads", "ft.exchange"])
+
+
+def test_scopes_and_unscoped_add_up_to_the_busy_time(scoped):
+    by_scope = scoped["by_scope"]
+    assert set(by_scope) == {"attn", "ffn", "head_loss", "optimizer", "unscoped"}
+    assert all(v > 0 for v in by_scope.values())
+    # every op is in exactly one scope: the same trace's device-busy seconds
+    assert sum(by_scope.values()) == pytest.approx(scoped["busy_s"], rel=1e-9)
+    # each unit blocks on its program, so the units own all of it between them
+    for scope, sec in by_scope.items():
+        assert sum(u["by_scope"].get(scope, 0.0) for u in scoped["units"]) == pytest.approx(sec, rel=1e-9)
+    for u in scoped["units"]:
+        assert sum(u["by_scope"].values()) == pytest.approx(u["busy_s"], rel=1e-9)
+    # the first unit's program, in ns as recorded: 232503 busy
+    assert {k: round(v * 1e9) for k, v in scoped["units"][0]["by_scope"].items()} == {
+        "attn": 36684, "ffn": 131679, "head_loss": 36111, "optimizer": 14956, "unscoped": 13073,
+    }
+    # the breakdown names the scope beside the op
+    labels = [name for name, _ in scoped["device_ops"]]
+    assert any(name.endswith(" @ffn") for name in labels) and all(len(n) <= 96 for n in labels)
+
+
+def test_scopes_against_a_plain_sum_over_the_events(scoped):
+    """The same numbers without the self-time stack: only the ``while`` nests,
+    so every other op's time is its duration, and the ``while``'s own time is
+    what its span leaves uncovered."""
+    from jax.profiler import ProfileData
+
+    import xplane_meta
+
+    (plane,) = [p for p in ProfileData.from_file(SCOPED).planes if p.name.startswith(rt.DEVICE_PREFIX)]
+    names = xplane_meta.op_names(SCOPED)[plane.name]
+    by_name = {}
+    for (program, name), op_name in names.items():
+        assert by_name.setdefault(name, op_name) == op_name  # one program: the name alone decides
+    units = [(float(ev.start_ns), float(ev.start_ns + ev.duration_ns)) for p in ProfileData.from_file(SCOPED).planes
+             if p.name.startswith("/host:CPU") for ln in p.lines for ev in ln.events if ev.name == rt.UNIT_SPAN]
+    lo, hi = min(s for s, _ in units), max(e for _, e in units)
+    plain = {}
+    paths = set()
+    for ln in plane.lines:
+        if ln.name != rt.OP_LINE:
+            continue
+        for ev in ln.events:
+            s, e = float(ev.start_ns), float(ev.start_ns + ev.duration_ns)
+            assert lo <= s and e <= hi
+            if ev.name.startswith("%while"):
+                continue
+            scope = xplane_meta.scope_of(by_name.get(ev.name))
+            paths.add((scope, "rematted_computation" in (by_name.get(ev.name) or ""), "transpose(jvp(" in (by_name.get(ev.name) or "")))
+            plain[scope] = plain.get(scope, 0.0) + (e - s) / 1e9
+    # forward ops, what jax.checkpoint computes again and the backward ops all count to their scope
+    for scope in ("attn", "ffn"):
+        assert {(scope, False, False), (scope, True, True), (scope, False, True)} <= paths
+    for scope in ("attn", "ffn", "head_loss", "optimizer"):
+        assert scoped["by_scope"][scope] == pytest.approx(plain[scope], rel=1e-9)
+    # the whiles' own time is unscoped
+    assert scoped["by_scope"]["unscoped"] > plain["unscoped"]
+    assert scoped["by_scope"]["unscoped"] - plain["unscoped"] < 1e-6
+
+
+def test_scope_of_takes_the_innermost_scope_on_the_path():
+    from xplane_meta import scope_of
+
+    assert scope_of("jit(tft_grads)/jvp()/while/body/closed_call/attn/bqhd,bkhd->bhqk/dot_general") == "attn"
+    assert scope_of("jit(tft_grads)/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/ffn/jit(silu)/mul") == "ffn"
+    assert scope_of("jit(tft_fused)/transpose(jvp(head_loss))/jit(log_softmax)/sub") == "head_loss"
+    assert scope_of("jit(tft_fused)/jvp(embed)/jit(_take)/gather") == "embed"
+    assert scope_of("jit(tft_apply)/optimizer/add") == "optimizer"
+    assert scope_of("jit(tft_fused)/moe/router/dot_general") == "moe"  # a scope the readers do not know is its parent's
+    assert scope_of("jit(tft_fused)/attn/jvp(moe)/dot_general") == "moe"  # the innermost
+    for none in ("jit(tft_fused)/transpose(jvp())/while", "jit(tft_fused)/jvp()/squeeze", "", None):
+        assert scope_of(none) == "unscoped"
+    # a primitive or an inner jit that happens to share a scope's spelling elsewhere in its name does not count
+    assert scope_of("jit(tft_fused)/jit(attn_helper)/dot_general") == "unscoped"
+
+
+def test_a_device_plane_without_any_op_name_is_an_error():
+    """A runtime that stops writing ``tf_op`` must not read as a step that is all ``unscoped``."""
+    from jax.profiler import ProfileData
+
+    import xplane_meta
+
+    planes = list(ProfileData.from_file(SCOPED).planes)
+    names = {name: {} for name in xplane_meta.op_names(SCOPED)}
+    with pytest.raises(ValueError, match="names no op_name"):
+        rt.reduce_planes(planes, [], op_names=names)
+    assert set(rt.reduce_planes(planes, [])["by_scope"]) == {"unscoped"}  # asked for no names: no error
+
+
+@pytest.mark.parametrize("path", [SCOPED, TRACE])
+def test_the_wire_reader_against_the_generated_protobuf_class(path):
+    """``xplane_meta.py`` reads the wire format by field number; TensorFlow's generated class,
+    where it imports (10 s, 0.8 GB: why the worker does not use it), must read the same."""
+    xplane_pb2 = pytest.importorskip("tensorflow.tsl.profiler.protobuf.xplane_pb2")
+    import xplane_meta
+
+    space = xplane_pb2.XSpace()
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
+    want = {}
+    for plane in space.planes:
+        if not plane.name.startswith(rt.DEVICE_PREFIX):
+            continue
+        stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+        ops = want.setdefault(plane.name, {})
+        for meta in plane.event_metadata.values():
+            stats = {stat_names[s.metadata_id]: s for s in meta.stats}
+            if xplane_meta.OP_NAME_STAT not in stats:
+                continue
+            tf_op = stats[xplane_meta.OP_NAME_STAT]
+            text = tf_op.str_value or stat_names.get(tf_op.ref_value, "")
+            program = stats.get(xplane_meta.PROGRAM_STAT)
+            if text.rsplit(":", 1)[0]:
+                ops[(program.uint64_value or program.int64_value if program else 0, meta.name)] = text.rsplit(":", 1)[0]
+    assert want and all(want.values())
+    assert xplane_meta.op_names(path) == want
+
+
+def test_a_unit_owns_the_runs_it_launched_whatever_the_devices_clock_says():
+    """In the first recorded trace the device's clock runs 1.3 ms ahead of the host's: the first unit's program
+    starts 1.26 ms "before" its unit and the third's ends before its unit begins, so by device time the units
+    hold 1, 1 and 0 runs' worth of busy time. By when the host enqueued each run, every unit owns its own."""
+    units = rt.reduce_file(TRACE, [])["units"]
+    assert [round(u["busy_s"] * 1e9) for u in units] == [43451, 43450, 0]
+    assert [round(sum(u["by_scope"].values()) * 1e9) for u in units] == [43698, 43451, 43450]

@@ -1,0 +1,129 @@
+"""The scope readers (``layer_metrics/<scope>_device_s.py``, ``<scope>_roofline.py``)
+on the trace recorded with scopes on the v5e, and on rows written by hand;
+``exchange_buckets_reused`` on a trace object that carries the counter."""
+
+import os
+
+import pytest
+
+import measure
+import opcount
+import program_spans as ps
+import reduce_trace as rt
+from common import load_json, load_module
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+SCOPED = os.path.join(HERE, "recorded_scopes_v5e.xplane.pb")
+PEAKS = load_json(os.path.join(BENCH, "peaks.json"))["TPU v5 lite"]
+SCOPE_READERS = (
+    "attn_device_s", "ffn_device_s", "head_loss_device_s", "optimizer_device_s", "embed_device_s",
+    "unscoped_device_s", "attn_roofline", "ffn_roofline", "head_loss_roofline", "optimizer_roofline",
+)
+
+
+def reader(name):
+    return load_module(os.path.join(BENCH, "layer_metrics", name + ".py"), "m_" + name)
+
+
+def a_run(units, tc, steps_per_unit=1, peaks=PEAKS, groups=1, chips_per_group=1):
+    config = {"program": {"transformer_config": tc}, "layout": {"groups": groups, "chips_per_group": chips_per_group}}
+    traffic = {"batch": 8, "seq": 2048, "steps_per_unit": steps_per_unit}
+    results = [{"group": g, "trace": {"units": units}} for g in range(groups)]
+    return measure.Run({}, config, traffic, peaks, results)
+
+
+def olmo_tc():
+    return load_json(os.path.join(BENCH, "configs", "olmo1b-1g.json"))["program"]["transformer_config"]
+
+
+def test_the_readers_on_the_recorded_trace():
+    """One step a unit: the median of the first two of the three units' rows."""
+    units = rt.reduce_file(SCOPED, [])["units"]
+    run = a_run(units, olmo_tc())
+    got = {name: reader(name).compute(run) for name in SCOPE_READERS}
+    assert got["attn_device_s"] == pytest.approx((36684 + 36685) / 2 * 1e-9, rel=1e-9)
+    assert got["ffn_device_s"] == pytest.approx((131679 + 131614) / 2 * 1e-9, rel=1e-9)
+    assert got["head_loss_device_s"] == pytest.approx(36111e-9, rel=1e-9)
+    assert got["optimizer_device_s"] == pytest.approx((14956 + 14917) / 2 * 1e-9, rel=1e-9)
+    assert got["unscoped_device_s"] == pytest.approx((13073 + 13012) / 2 * 1e-9, rel=1e-9)
+    assert got["embed_device_s"] is None  # the recorded step has no such scope: left out, not 0
+
+
+def test_shares_of_the_peaks_worked_by_hand():
+    """The fused cell's numbers of PR 25's first chip run: five steps a unit."""
+    tc = olmo_tc()
+    row = {"attn": 1.06567255, "ffn": 1.06661835, "head_loss": 0.493307089, "optimizer": 0.116583527,
+           "embed": 0.025150404, "unscoped": 0.157432779}
+    run = a_run([{"by_scope": row}], tc, steps_per_unit=5)
+    tokens = 8 * 2048
+    assert reader("ffn_device_s").compute(run) == pytest.approx(1.06661835 / 5)
+    # ffn: 6 x (6 layers x 3 x 2048 x 8192) operations a token
+    assert reader("ffn_roofline").compute(run) == pytest.approx(
+        100 * 6 * 6 * 3 * 2048 * 8192 * tokens / (1.06661835 / 5 * 197e12)) == pytest.approx(70.64, abs=0.01)
+    # attn: the projections, and causal scores at half the square, three times with the backward
+    attn = 6 * 6 * 4 * 2048 * 2048 + 3 * 6 * 2 * (2 * 2048 * 2048) / 2
+    assert reader("attn_roofline").compute(run) == pytest.approx(100 * attn * tokens / (1.06567255 / 5 * 197e12))
+    assert reader("head_loss_roofline").compute(run) == pytest.approx(
+        100 * 6 * 2048 * 50304 * tokens / (0.493307089 / 5 * 197e12)) == pytest.approx(52.11, abs=0.01)
+    # optimizer: 24 B of f32 state and a bf16 gradient for each of 608 724 992 parameters against 819 GB/s
+    assert reader("optimizer_roofline").compute(run) == pytest.approx(
+        100 * 26 * 608_724_992 / (0.116583527 / 5 * 819e9)) == pytest.approx(82.88, abs=0.01)
+    # a group sharded over two chips does half the work on each
+    two = a_run([{"by_scope": row}], tc, steps_per_unit=5, chips_per_group=2)
+    assert reader("ffn_roofline").compute(two) == pytest.approx(70.64 / 2, abs=0.01)
+
+
+def test_the_feed_forward_readers_follow_the_configurations_scope():
+    moe = dict(olmo_tc(), n_layers=1, d_ff=1024, n_experts=64, top_k=8)
+    run = a_run([{"by_scope": {"moe": 0.2, "attn": 0.1}}], moe)
+    assert reader("ffn_device_s").compute(run) == 0.2
+    assert reader("ffn_roofline").compute(run) == pytest.approx(
+        100 * opcount.flops_per_token_by_scope(moe, 2048)["moe"] * 8 * 2048 / (0.2 * 197e12))
+    assert reader("ffn_device_s").compute(a_run([{"by_scope": {"moe": 0.2}}], olmo_tc())) is None
+
+
+@pytest.mark.parametrize("name", SCOPE_READERS)
+def test_a_scope_reader_returns_none_where_there_is_nothing_to_read(name):
+    compute = reader(name).compute
+    tc = olmo_tc()
+    assert compute(measure.Run({}, {"program": {"transformer_config": tc}, "layout": {"chips_per_group": 1}},
+                               {"batch": 8, "seq": 2048, "steps_per_unit": 1}, PEAKS, [{"group": 0}])) is None  # untraced
+    assert compute(a_run([{"dur_s": 1.0, "busy_s": 0.5}], tc)) is None  # a trace reduced without scopes
+    if name.endswith("_roofline"):
+        row = {"attn": 0.2, "ffn": 0.2, "head_loss": 0.1, "optimizer": 0.02}
+        assert compute(a_run([{"by_scope": row}], tc, peaks=None)) is None  # a device without published peaks
+
+
+def test_the_last_of_several_traced_units_is_left_out():
+    """Its ``apply`` may be cut where the trace stops: with two units, or two cut ones, a median would take it in."""
+    units = [{"by_scope": {"optimizer": 0.0254}}, {"by_scope": {"optimizer": 0.0256}}, {"by_scope": {"optimizer": 0.0021}}]
+    assert reader("optimizer_device_s").compute(a_run(units, olmo_tc())) == pytest.approx(0.0255)
+    assert reader("optimizer_device_s").compute(a_run(units[1:], olmo_tc())) == 0.0256
+    assert reader("optimizer_device_s").compute(a_run(units[2:], olmo_tc())) == 0.0021  # one unit: its loop blocks
+    # two groups: the mean of their medians
+    units2 = [{"by_scope": {"optimizer": 0.03}}]
+    run = a_run(units, olmo_tc(), groups=2)
+    run.results[1]["trace"]["units"] = units2
+    assert reader("optimizer_device_s").compute(run) == pytest.approx((0.0255 + 0.03) / 2)
+
+
+def test_buckets_reused_is_the_counters_median(monkeypatch):
+    class Counters:
+        units = [(0.0, 1.0), (1.0, 2.0), (2.0, 3.0)]
+
+        def __init__(self, values):
+            self.values = values
+
+        def stat(self, name, key):
+            assert (name, key) == (ps.PREFIX + "exchange.counters", "buckets_reused")
+            return self.values
+
+    run = measure.Run({}, {}, {}, None, [{"group": 0}])
+    compute = reader("exchange_buckets_reused").compute
+    monkeypatch.setattr(ps, "load", lambda result: Counters([10.0, 10.0, 0.0]))
+    assert compute(run) == 10.0
+    monkeypatch.setattr(ps, "load", lambda result: Counters([0.0, 0.0, 0.0]))
+    assert compute(run) == 0.0  # nothing kept is a reading
+    monkeypatch.setattr(ps, "load", lambda result: Counters(None))
+    assert compute(run) is None  # a program that does not count it

@@ -246,7 +246,8 @@ def run(run_dir, group, log, stamps, fail) -> int:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 2
-            opts.enable_hlo_proto = False  # keeps the trace small; names do not need it
+            # op names and their scopes (xplane_meta.py) are in the trace without it
+            opts.enable_hlo_proto = False
             trace_state["t0"] = time.monotonic()
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
         elif not tracing_this and trace_state["first"] is not None and trace_state["t1"] is None:
