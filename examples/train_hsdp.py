@@ -13,9 +13,11 @@ Env:
 
     TORCHFT_LIGHTHOUSE=host:port
     REPLICA_GROUP_ID / NUM_REPLICA_GROUPS (default 2)
-    MODEL=tiny|scale_647M|llama2-7b  models.transformer.PRESETS (default
+    MODEL=tiny|scale_647M|llama2-7b|olmoe-1b-7b
+                                   models.transformer.PRESETS (default
                                    tiny; scale_647M fills one v5e chip,
-                                   7b needs >= 8 real chips per group)
+                                   the 7b shapes need >= 8 real chips
+                                   per group)
     FSDP/TP/SP/PP                  inner mesh axis sizes (default 2/2/1/1)
                                    over the devices THIS process sees: a
                                    chip belongs to one process, so the

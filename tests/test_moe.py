@@ -1,0 +1,361 @@
+"""OLMoE's block through the one transformer: dropless top-k experts, a
+float32 router whose weights are not renormalised, QK-norm, and the
+load-balancing term in the loss — against the plain reference
+(``benchmark/reference/olmoe_decoder.py``, loaded by path: one copy).
+
+Tolerance: both sides compute in float32 on the CPU (matmuls at "highest");
+what differs is the order of the sums — rows sorted by expert and a grouped
+matmul on one side, every expert over every token on the other; a scan,
+remat and chunked attention on one side, none on the other — a few float32
+ulps of values of order 1 accumulated over a few hundred terms: 2e-5
+relative to the largest entry of a gradient leaf (measured here: under 2e-6).
+A term left out, a dropped token or a renormalised router is off by orders
+more; the tests below show each.
+"""
+
+import dataclasses
+import importlib.util
+import os
+from datetime import timedelta
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from torchft_tpu.models.transformer import (
+    PRESETS,
+    TransformerConfig,
+    init_params,
+    loss_and_stats,
+    loss_fn,
+)
+from torchft_tpu.ops.layers import moe_dropless
+from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+from torchft_tpu.parallel.train_step import TrainStep
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RTOL = 2e-5
+
+
+def _load_reference():
+    path = os.path.join(ROOT, "benchmark", "reference", "olmoe_decoder.py")
+    spec = importlib.util.spec_from_file_location("olmoe_decoder_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load_reference()
+
+# two tiny sizes: few experts, and OLMoE's own count and experts per token
+SIZES = {
+    "e8k2": dict(
+        vocab_size=97, d_model=32, n_layers=2, n_heads=4, head_dim=8, d_ff=16,
+        n_experts=8, top_k=2, qk_norm=True, router_aux_loss_coef=0.01,
+        rope_theta=10000.0, norm_eps=1e-5,
+    ),
+    "e64k8": dict(
+        vocab_size=97, d_model=32, n_layers=2, n_heads=4, head_dim=8, d_ff=8,
+        n_experts=64, top_k=8, qk_norm=True, router_aux_loss_coef=0.01,
+        rope_theta=10000.0, norm_eps=1e-5,
+    ),
+}
+
+
+def make(size, skewed=False):
+    sizes = dict(SIZES[size])
+    cfg = TransformerConfig(dtype=jnp.float32, remat=True, **sizes)
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    layers = params["layers"]
+    # norm weights off 1, or a norm applied without its weight would pass
+    spread = lambda a, lo, hi: a * jnp.linspace(lo, hi, a.shape[-1])
+    layers["ln1"], layers["ln2"] = layers["ln1"] * 1.3, layers["ln2"] * 0.8
+    if cfg.qk_norm:
+        layers["q_norm"] = spread(layers["q_norm"], 0.6, 1.5)
+        layers["k_norm"] = spread(layers["k_norm"], 1.4, 0.7)
+    params["final_norm"] = params["final_norm"] * 0.7
+    # a livelier router than the 1/sqrt(d) init: the experts' loads differ
+    layers["router"] = layers["router"] * 3.0
+    if skewed:
+        # one coordinate of the residual stream positive in every token, and
+        # expert 0's router column large on it: expert 0 is among the k of
+        # (nearly) every token, where a capacity of 1.25 x the mean load
+        # would hold 1.25 k / E of them
+        params["embed"] = params["embed"].at[:, 0].add(4.0)
+        layers["router"] = layers["router"].at[..., 0, 0].set(6.0)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, 97, (2, 24)), jnp.int32)
+    return cfg, params, tokens, sizes
+
+
+def grad_errors(g_got, g_want):
+    return jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))), g_got, g_want
+    )
+
+
+def system(cfg, params, tokens):
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(loss_fn)(params, tokens, cfg)
+
+
+@pytest.mark.parametrize("skewed", [False, True], ids=["balanced", "skewed"])
+@pytest.mark.parametrize("size", sorted(SIZES))
+def test_loss_and_every_gradient_leaf_agree_with_the_reference(size, skewed):
+    cfg, params, tokens, sizes = make(size, skewed)
+    got, g_got = system(cfg, params, tokens)
+    want, g_want = jax.value_and_grad(ref.loss)(params, tokens, sizes)
+    assert float(got) == pytest.approx(float(want), rel=RTOL)
+    errs = grad_errors(g_got, g_want)
+    assert set(errs["layers"]) >= {"q_norm", "k_norm", "router", "w_gate", "w_in", "w_out"}
+    assert max(jax.tree_util.tree_leaves(errs)) < RTOL, errs
+    load = np.asarray(loss_and_stats(params, tokens, cfg)[1]["tokens_per_expert"])
+    assert load.shape == (cfg.n_layers, cfg.n_experts)
+    assert (load.sum(axis=1) == tokens.size * cfg.top_k).all()  # no row is dropped
+    if skewed:
+        # the load a capacity would have cut: 1.25 x the mean holds 1.25 k / E
+        assert (load[:, 0] >= 0.9 * tokens.size).all(), load[:, 0]
+        assert load[:, 0].min() > 3 * 1.25 * tokens.size * cfg.top_k / cfg.n_experts
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+def test_the_check_would_see_renormalised_weights_a_dropped_expert_or_no_qk_norm(size):
+    cfg, params, tokens, sizes = make(size)
+    want = float(ref.loss(params, tokens, sizes))
+    assert float(system(cfg, params, tokens)[0]) == pytest.approx(want, rel=RTOL)
+    far = 10 * RTOL * want  # the nearest miss below is 17 x the tolerance
+    # the k weights divided by their sum: OLMoE does not (norm_topk_prob false)
+    renormalised = dict(sizes, norm_topk_prob=True)
+    assert abs(float(ref.loss(params, tokens, renormalised)) - want) > far
+    g_got, g_off = system(cfg, params, tokens)[1], jax.grad(ref.loss)(params, tokens, renormalised)
+    assert grad_errors(g_got, g_off)["layers"]["w_out"] > 1000 * RTOL
+    # one expert fewer a token
+    assert abs(float(ref.loss(params, tokens, dict(sizes, top_k=sizes["top_k"] - 1))) - want) > far
+    # the program without its QK-norm (the two weights are then unused leaves)
+    no_norm = dataclasses.replace(cfg, qk_norm=False)
+    assert abs(float(system(no_norm, params, tokens)[0]) - want) > far
+    # the balance term left out of the loss
+    no_aux = dataclasses.replace(cfg, router_aux_loss_coef=0.0)
+    assert abs(float(system(no_aux, params, tokens)[0]) - want) > far
+
+
+def test_per_sequence_loss_is_what_the_worker_compares():
+    """One sequence a call: the balance term is over that sequence's tokens."""
+    cfg, params, tokens, sizes = make("e64k8")
+    per_seq = ref.per_sequence_loss(params, tokens, sizes)
+    for i in range(2):
+        assert float(loss_fn(params, tokens[i : i + 1], cfg)) == pytest.approx(float(per_seq[i]), rel=RTOL)
+    # f_e and P_e are shares of the call: the batch's term is not the sequences' mean
+    assert float(ref.loss(params, tokens, sizes)) != pytest.approx(float(jnp.mean(per_seq)), rel=RTOL)
+
+
+def test_a_zero_router_balances_at_k_and_the_term_reaches_the_router():
+    cfg, params, tokens, _ = make("e64k8")
+    params["layers"]["router"] = jnp.zeros_like(params["layers"]["router"])
+    stats = loss_and_stats(params, tokens, cfg)[1]
+    # p = 1/E for every expert: E * sum_e f_e / E = sum_e f_e = k
+    assert float(stats["balance_loss"]) == pytest.approx(cfg.top_k, rel=1e-6)
+    # ties go to the lowest indices: every token to experts 0..k-1, none dropped
+    load = np.asarray(stats["tokens_per_expert"])
+    assert (load[:, : cfg.top_k] == tokens.size).all() and (load[:, cfg.top_k :] == 0).all()
+    # the cross entropy's own gradient into the router, and the term's on top
+    no_aux = dataclasses.replace(cfg, router_aux_loss_coef=0.0)
+    g_aux = jax.grad(loss_fn)(params, tokens, cfg)["layers"]["router"]
+    g_ce = jax.grad(loss_fn)(params, tokens, no_aux)["layers"]["router"]
+    assert float(jnp.max(jnp.abs(g_aux - g_ce))) > 1e-6
+
+
+def test_every_expert_chosen_is_the_dense_sum_over_all_experts():
+    """top_k = n_experts: the sort and the grouped matmul against an einsum."""
+    rng = np.random.default_rng(1)
+    t, d, f, e = 40, 16, 8, 4
+    x, wg, wi, wo = (jnp.asarray(0.3 * rng.normal(size=s), jnp.float32) for s in ((t, d), (e, d, f), (e, d, f), (e, f, d)))
+    p = jax.nn.softmax(jnp.asarray(rng.normal(size=(t, e)), jnp.float32), axis=-1)
+    top_w, top_idx = jax.lax.top_k(p, e)
+
+    def dense(x, wg, wi, wo):
+        h = jax.nn.silu(jnp.einsum("td,edf->tef", x, wg)) * jnp.einsum("td,edf->tef", x, wi)
+        return jnp.einsum("te,ted->td", p, jnp.einsum("tef,efd->ted", h, wo))
+
+    def sparse(x, wg, wi, wo):
+        y, counts = moe_dropless(x, top_idx, top_w, wg, wi, wo)
+        assert counts.shape == (e,)
+        return y
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(sparse(x, wg, wi, wo), dense(x, wg, wi, wo), rtol=1e-5, atol=1e-6)
+        loss = lambda fn: (lambda *a: jnp.sum(fn(*a) ** 2))
+        got = jax.grad(loss(sparse), argnums=(0, 1, 2, 3))(x, wg, wi, wo)
+        want = jax.grad(loss(dense), argnums=(0, 1, 2, 3))(x, wg, wi, wo)
+    assert max(grad_errors(got, want)) < RTOL
+
+
+def test_the_tpu_kernel_and_the_xla_form_are_the_same_grouped_matmul():
+    """The Pallas kernel the TPU runs (here in the interpreter) against
+    ``jax.lax.ragged_dot``: values and both gradients, with rows that do not
+    fill a row tile (the wrapper pads them) and an empty group."""
+    from torchft_tpu.ops.layers import _grouped_matmul_tpu
+
+    rng = np.random.default_rng(2)
+    m, k, n, e = 200, 128, 256, 4
+    rows = jnp.asarray(0.3 * rng.normal(size=(m, k)), jnp.float32)
+    w = jnp.asarray(0.3 * rng.normal(size=(e, k, n)), jnp.float32)
+    counts = jnp.asarray([90, 0, 70, 40], jnp.int32)
+    kernel = lambda r, w: _grouped_matmul_tpu(r, w, counts, interpret=True)
+    xla = lambda r, w: jax.lax.ragged_dot(r, w, counts)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(kernel(rows, w), xla(rows, w), rtol=1e-5, atol=1e-6)
+        loss = lambda fn: (lambda r, w: jnp.sum(jnp.sin(fn(r, w))))
+        got = jax.grad(loss(kernel), argnums=(0, 1))(rows, w)
+        want = jax.grad(loss(xla), argnums=(0, 1))(rows, w)
+    assert max(grad_errors(got, want)) < RTOL
+
+
+def test_two_shapes_in_one_program_trace_with_cache_miss_explanations_on():
+    """``benchmark/run.py`` sets JAX_EXPLAIN_CACHE_MISSES; jax 0.9.0's explanation
+    raises on the second shape of a ``platform_dependent`` branch it has seen
+    (PR 26's first chip call of the kernel path died there)."""
+    from torchft_tpu.ops.layers import _grouped_matmul
+
+    counts = jnp.asarray([3, 5], jnp.int32)
+    two = jax.jit(lambda x, w1, w2: _grouped_matmul(_grouped_matmul(x, w1, counts), w2, counts))
+    with jax.explain_cache_misses(True):
+        out = two(jnp.ones((8, 4)), jnp.ones((2, 4, 6)), jnp.ones((2, 6, 4)))
+    assert out.shape == (8, 4) and float(out[0, 0]) == 24.0
+
+
+def test_remat_on_and_off_agree():
+    cfg, params, tokens, _ = make("e64k8")
+    on, g_on = system(cfg, params, tokens)
+    off, g_off = system(dataclasses.replace(cfg, remat=False), params, tokens)
+    assert float(on) == pytest.approx(float(off), rel=1e-6)
+    assert max(jax.tree_util.tree_leaves(grad_errors(g_on, g_off))) < RTOL
+
+
+def test_the_chunked_head_carries_the_balance_term(monkeypatch):
+    cfg, params, tokens, sizes = make("e8k2")
+    plain, g_plain = system(cfg, params, tokens)
+    monkeypatch.setenv("TORCHFT_TPU_LOSS_CHUNK_ELEMS", str(2 * 5 * cfg.vocab_size))  # chunks of 5 positions
+    chunked, g_chunked = system(cfg, params, tokens)
+    assert float(chunked) == pytest.approx(float(plain), rel=1e-6)
+    assert float(chunked) == pytest.approx(float(ref.loss(params, tokens, sizes)), rel=RTOL)
+    assert max(jax.tree_util.tree_leaves(grad_errors(g_chunked, g_plain))) < RTOL
+    no_aux = dataclasses.replace(cfg, router_aux_loss_coef=0.0)
+    assert abs(float(system(no_aux, params, tokens)[0]) - float(chunked)) > 1e-3
+
+
+def test_what_is_refused_under_pp_and_ep():
+    cfg, params, tokens, _ = make("e8k2")
+    with pytest.raises(ValueError, match="not carried across pipeline stages"):
+        loss_fn(params, tokens, dataclasses.replace(cfg, pp=2))
+    # experts over chips still run the top-2 capacity dispatch: no other k, no term
+    mesh = make_mesh(MeshConfig(ep=2), devices=jax.devices()[:2])
+    for bad in (dict(top_k=4, router_aux_loss_coef=0.0), dict(top_k=2)):
+        refused = dataclasses.replace(cfg, **bad)
+        with pytest.raises(ValueError, match="experts over chips"), jax.set_mesh(mesh):
+            jax.jit(lambda p, t: loss_fn(p, t, refused, mesh))(params, tokens)
+
+
+def test_the_preset_is_olmoe_as_published():
+    cfg = TransformerConfig(**PRESETS["olmoe-1b-7b"])
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    n = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(shapes))
+    per_layer = 4 * 2048 * 2048 + 64 * 3 * 2048 * 1024 + 2048 * 64 + 4 * 2048  # attention, experts, router, four norms
+    assert n == 16 * per_layer + 2 * 50304 * 2048 + 2048 == 6_919_161_856
+    assert (cfg.n_experts, cfg.top_k, cfg.qk_norm, cfg.router_aux_loss_coef) == (64, 8, True, 0.01)
+
+
+# -- through TrainStep and FTTrainer -------------------------------------------
+
+TRAIN = dict(SIZES["e8k2"], vocab_size=128)
+
+
+@pytest.fixture(scope="module")
+def train_step():
+    mesh = make_mesh(MeshConfig(), devices=jax.devices()[:1])
+    return TrainStep(TransformerConfig(dtype=jnp.float32, **TRAIN), optax.adamw(1e-2), mesh)
+
+
+def batches(n):
+    rng = np.random.default_rng(0)
+    return [jnp.asarray(rng.integers(0, TRAIN["vocab_size"], (2, 16)), jnp.int32) for _ in range(n)]
+
+
+def fused_losses(ts, n):
+    params = ts.init_params(jax.random.PRNGKey(0))
+    opt = ts.init_opt(params)
+    out = []
+    for tokens in batches(n):
+        loss, params, opt = ts.step(params, opt, ts.shard_batch(tokens))
+        out.append(float(loss))
+    return out
+
+
+def test_the_fused_step_learns_and_keeps_the_last_steps_statistics(train_step):
+    params = train_step.init_params(jax.random.PRNGKey(0))
+    opt = train_step.init_opt(params)
+    tokens = train_step.shard_batch(batches(1)[0])
+    losses = []
+    for _ in range(8):
+        out = train_step.step(params, opt, tokens)
+        assert len(out) == 3  # the signature the benchmark's loop calls
+        loss, params, opt = out
+        losses.append(float(loss))
+    assert losses[-1] < losses[0] - 0.5
+    stats = train_step.last_stats
+    assert set(stats) == {"tokens_per_expert", "balance_loss"}
+    load = np.asarray(stats["tokens_per_expert"])
+    assert load.shape == (2, 8) and (load.sum(axis=1) == 2 * 16 * 2).all()
+    loss, grads = train_step.grads(params, tokens)  # (loss, grads), as FTTrainer calls it
+    assert jax.tree_util.tree_structure(grads) == jax.tree_util.tree_structure(params)
+
+
+def test_a_dense_model_has_no_statistics():
+    dense = {k: v for k, v in TRAIN.items() if k not in ("n_experts", "top_k", "router_aux_loss_coef")}
+    mesh = make_mesh(MeshConfig(), devices=jax.devices()[:1])
+    ts = TrainStep(TransformerConfig(dtype=jnp.float32, **dense), optax.adamw(1e-2), mesh)
+    params = ts.init_params(jax.random.PRNGKey(0))
+    loss, params, opt = ts.step(params, ts.init_opt(params), ts.shard_batch(batches(1)[0]))
+    assert np.isfinite(float(loss)) and ts.last_stats == {}
+
+
+def test_ft_steps_at_world_size_one_are_the_fused_steps_and_record_the_counters(train_step, monkeypatch):
+    """The gradients make the trip through ``ddp``'s buckets (several: the
+    smallest bucket the knob allows) and come back what they were."""
+    from torchft_tpu.collectives import CollectivesTcp
+    from torchft_tpu.coordination import LighthouseServer
+    from torchft_tpu.manager import Manager
+    from torchft_tpu.parallel.ft import FTTrainer
+    from torchft_tpu.store import StoreServer
+    from torchft_tpu.telemetry.tracing import TRACER
+
+    monkeypatch.setenv("TORCHFT_WIRE_BUCKET_BYTES", str(1 << 16))
+    want = fused_losses(train_step, 3)
+    lighthouse = LighthouseServer(bind="[::]:0", min_replicas=1)
+    store = StoreServer()
+    manager = Manager(
+        collectives=CollectivesTcp(timeout=timedelta(seconds=10)),
+        load_state_dict=None, state_dict=None, min_replica_size=1, replica_id="moe_0",
+        store_addr=store.address(), lighthouse_addr=lighthouse.address(),
+        rank=0, world_size=1, timeout=timedelta(seconds=10),
+    )
+    try:
+        trainer = FTTrainer(manager, train_step)
+        trainer.init(jax.random.PRNGKey(0))
+        got = []
+        for tokens in batches(3):
+            out = trainer.step(tokens)
+            assert len(out) == 2 and out[1] is True  # (loss, committed)
+            got.append(out[0])
+    finally:
+        manager.shutdown(wait=False)
+        store.shutdown()
+        lighthouse.shutdown()
+    # the same programs' arithmetic, split in two and through the host: to rounding
+    assert got == pytest.approx(want, rel=1e-6)
+    syncs = [s["attrs"] for s in TRACER.recent("loss_sync") if "max_load" in s.get("attrs", {})]
+    assert len(syncs) >= 3
+    attrs = syncs[-1]
+    assert attrs["mean_load"] == 2 * 16 * 2 / 8 and attrs["min_load"] <= attrs["mean_load"] <= attrs["max_load"]
+    assert 1.9 < attrs["balance_loss"] < 8.0

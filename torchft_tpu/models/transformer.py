@@ -7,7 +7,9 @@ Pure-JAX pytree params with explicit ``PartitionSpec``s per leaf:
 * ``sp``  — sequence axis via ring attention (ops/attention.py)
 * ``pp``  — layer stages via the microbatched ppermute ring
   (parallel/pipeline.py); stage params carry a leading [pp, Lp] axis
-* ``ep``  — MoE experts (top-2 capacity dispatch, ops/layers.py)
+* ``ep``  — MoE experts over chips (``ep`` > 1 ONLY: the top-2 capacity
+  einsum dispatch, ``_ffn_moe_ep``; on ``ep`` = 1 the experts are the
+  dropless top-k of ``ops/layers.moe_dropless``)
 * ``dp``/``fsdp`` — batch / parameter sharding
 
 Layers within a stage run under ``lax.scan`` (one compile per stage, not
@@ -18,7 +20,7 @@ HBM both scale O(1) in depth.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +33,13 @@ from torchft_tpu.ops.attention import (
     ring_attention,
     ring_attention_local,
 )
-from torchft_tpu.ops.layers import moe_dispatch, rms_norm, rotary_embed, swiglu
+from torchft_tpu.ops.layers import (
+    moe_dispatch,
+    moe_dropless,
+    rms_norm,
+    rotary_embed,
+    swiglu,
+)
 
 __all__ = [
     "TransformerConfig",
@@ -40,6 +48,7 @@ __all__ = [
     "param_specs",
     "forward",
     "loss_fn",
+    "loss_and_stats",
 ]
 
 
@@ -52,7 +61,14 @@ class TransformerConfig:
     head_dim: int = 64
     d_ff: int = 1408
     n_experts: int = 0  # 0 => dense FFN
-    capacity_factor: float = 1.25
+    top_k: int = 2  # experts per token (router weights NOT renormalised)
+    capacity_factor: float = 1.25  # ep > 1 only: the dropless path has none
+    # weight of the load-balancing term E·Σ f_e·P_e (mean over layers) in
+    # the training loss; 0 => the loss is the cross entropy alone
+    router_aux_loss_coef: float = 0.0
+    # RMSNorm with a learned weight over the whole q and k projections,
+    # before the head split and RoPE (OLMoE's attention)
+    qk_norm: bool = False
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16  # compute dtype (MXU-native)
@@ -104,6 +120,14 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         vocab_size=32000, d_model=4096, n_layers=32, n_heads=32,
         head_dim=128, d_ff=11008, dtype=jnp.bfloat16,
     ),
+    # OLMoE-1B-7B-0125-Instruct as published (6.9B parameters: needs a
+    # sharded group); the benchmark's olmoe-1g runs one of its 16 layers
+    "olmoe-1b-7b": dict(
+        vocab_size=50304, d_model=2048, n_layers=16, n_heads=16,
+        head_dim=128, d_ff=1024, n_experts=64, top_k=8, qk_norm=True,
+        router_aux_loss_coef=0.01, rope_theta=10000.0, norm_eps=1e-5,
+        dtype=jnp.bfloat16,
+    ),
 }
 
 
@@ -127,6 +151,11 @@ def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
         "wv": dense(keys[2], pp, lp, d, qkv, fan_in=d),
         "wo": dense(keys[3], pp, lp, qkv, d, fan_in=qkv),
     }
+    if cfg.qk_norm:
+        layers.update(
+            q_norm=jnp.ones((pp, lp, qkv), jnp.float32),
+            k_norm=jnp.ones((pp, lp, qkv), jnp.float32),
+        )
     if cfg.n_experts:
         e = cfg.n_experts
         layers.update(
@@ -160,6 +189,9 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "wv": row,
         "wo": col,
     }
+    if cfg.qk_norm:
+        # over the tp-sharded projection: the norm's mean is one all-reduce
+        layers.update(q_norm=P("pp", None, "tp"), k_norm=P("pp", None, "tp"))
     if cfg.n_experts:
         layers.update(
             router=P("pp", None, "fsdp", None),
@@ -201,7 +233,31 @@ def _ffn_dense(lp: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
     return swiglu(x, lp["w_gate"], lp["w_in"], lp["w_out"])
 
 
-def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
+def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig):
+    """Sparse experts, dropless: ``p = softmax(h·Wr)`` in float32, the k
+    largest chosen, their ``p`` applied as they are (not renormalised), every
+    chosen expert counted whatever its load. Returns (y, (balance term
+    ``E·Σ_e f_e·P_e`` over the call's tokens, tokens per expert [E]))."""
+    b, s, d = x.shape
+    tokens = x.reshape(b * s, d)
+    with jax.named_scope("router"):
+        logits = jnp.dot(tokens, lp["router"], preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)
+    y, counts = moe_dropless(
+        tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"]
+    )
+    with jax.named_scope("router"):
+        frac = counts.astype(jnp.float32) / (b * s)  # sums to k; no gradient
+        balance = cfg.n_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
+    return y.reshape(b, s, d), (balance, counts)
+
+
+def _ffn_moe_ep(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
+    """Experts over chips (``ep`` > 1) ONLY: the top-2 einsum dispatch that
+    drops tokens over ``capacity_factor`` and renormalises the two gates —
+    not OLMoE's mathematics, no balance term. Kept untouched until ROADMAP
+    R4 gives ``moe_dropless`` a ``shard_map`` over ``ep``."""
     b, s, d = x.shape
     g = b * s
     tokens = x.reshape(g, d)
@@ -373,8 +429,21 @@ def _flash_sharded(q, k, v, mesh):
 
 def _make_layer_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    experts_over_chips = (
+        bool(cfg.n_experts) and mesh is not None and mesh.shape.get("ep", 1) > 1
+    )
+    if experts_over_chips and (cfg.top_k != 2 or cfg.router_aux_loss_coef):
+        raise ValueError(
+            f"ep={mesh.shape['ep']}: experts over chips still run the top-2 "
+            "capacity dispatch (_ffn_moe_ep), which has no other top_k and no "
+            f"balance term; got top_k={cfg.top_k}, "
+            f"router_aux_loss_coef={cfg.router_aux_loss_coef}"
+        )
 
-    def layer_fn(x: jnp.ndarray, lp: Dict[str, Any]) -> jnp.ndarray:
+    def layer_fn(x: jnp.ndarray, lp: Dict[str, Any]):
+        """(x, aux): aux is (balance term, tokens per expert) of a dropless
+        expert layer and () otherwise."""
+        aux = ()
         x = _constrain(x, _act_spec(sp_manual))
         with jax.named_scope("attn"):
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
@@ -383,8 +452,12 @@ def _make_layer_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
                 positions = jax.lax.axis_index("sp") * s + jnp.arange(s)
             else:
                 positions = jnp.arange(s)
-            q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-            k = (h @ lp["wk"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+            q, k = h @ lp["wq"], h @ lp["wk"]
+            if cfg.qk_norm:
+                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+            q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = k.reshape(b, s, cfg.n_heads, cfg.head_dim)
             v = (h @ lp["wv"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
             q = rotary_embed(q, positions, cfg.rope_theta)
             k = rotary_embed(k, positions, cfg.rope_theta)
@@ -421,11 +494,14 @@ def _make_layer_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
 
         with jax.named_scope("moe" if cfg.n_experts else "ffn"):
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.n_experts:
-                x = x + _ffn_moe(lp, h, cfg)
+            if experts_over_chips:
+                x = x + _ffn_moe_ep(lp, h, cfg)
+            elif cfg.n_experts:
+                y, aux = _ffn_moe(lp, h, cfg)
+                x = x + y
             else:
                 x = x + _ffn_dense(lp, h)
-        return _constrain(x, _act_spec(sp_manual))
+        return _constrain(x, _act_spec(sp_manual)), aux
 
     return layer_fn
 
@@ -444,15 +520,20 @@ def _make_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
             )
         layer_fn = jax.checkpoint(layer_fn, policy=policy)
 
-    def stage_fn(stage_params: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
+    def stage_fn(stage_params: Dict[str, Any], x: jnp.ndarray):
+        """(x, aux stacked over the stage's layers); see ``layer_fn``."""
         # stage_params leaves: [Lp, ...]; scan over the layer axis
-        def body(x, lp):
-            return layer_fn(x, lp), ()
-
-        x, _ = jax.lax.scan(body, x, stage_params)
-        return x
+        return jax.lax.scan(layer_fn, x, stage_params)
 
     return stage_fn
+
+
+def _pipeline_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool):
+    """The stage as ``pipeline_forward`` calls it: hidden state in, hidden
+    state out. The experts' balance term does not cross stages
+    (``loss_and_stats`` refuses a non-zero coefficient under ``pp`` > 1)."""
+    stage_fn = _make_stage_fn(cfg, mesh, sp_manual=sp_manual)
+    return lambda stage_params, x: stage_fn(stage_params, x)[0]
 
 
 def _embed_lookup(
@@ -482,10 +563,12 @@ def _hidden_states(
     tokens: jnp.ndarray,
     cfg: TransformerConfig,
     mesh=None,
-) -> jnp.ndarray:
-    """tokens [B, S] -> final-norm hidden states [B, S, D] in cfg.dtype
+):
+    """tokens [B, S] -> (final-norm hidden states [B, S, D] in cfg.dtype, aux)
     (everything except the unembed — the chunked loss head consumes this
-    without ever materializing [S, V] logits)."""
+    without ever materializing [S, V] logits). ``aux`` is what the layer
+    scan carries out beside the hidden state: (balance term [L], tokens per
+    expert [L, E]) of dropless expert layers, else ()."""
     from torchft_tpu.parallel.pipeline import pipeline_forward
 
     b, s = tokens.shape
@@ -495,21 +578,22 @@ def _hidden_states(
     layers = jax.tree_util.tree_map(lambda a: a.astype(dt), params["layers"])
 
     pp = max(cfg.pp, 1)
+    aux = ()
     if pp == 1:
         stage_fn = _make_stage_fn(cfg, mesh, sp_manual=False)
-        x = stage_fn(jax.tree_util.tree_map(lambda a: a[0], layers), x)
+        x, aux = stage_fn(jax.tree_util.tree_map(lambda a: a[0], layers), x)
     else:
         # inside the pipeline's manual region the sp axis is manual too
         # (Shardy forbids nested manual regions)
         sp_manual = mesh is not None and mesh.shape.get("sp", 1) > 1
-        stage_fn = _make_stage_fn(cfg, mesh, sp_manual=sp_manual)
+        stage_fn = _pipeline_stage_fn(cfg, mesh, sp_manual)
         m = cfg.microbatches or pp
         assert b % m == 0, f"batch {b} must divide into {m} microbatches"
         x_mb = x.reshape(m, b // m, s, -1)
         x_mb = pipeline_forward(layers, x_mb, stage_fn, mesh)
         x = x_mb.reshape(b, s, -1)
 
-    return rms_norm(x, params["final_norm"].astype(dt), cfg.norm_eps)
+    return rms_norm(x, params["final_norm"].astype(dt), cfg.norm_eps), aux
 
 
 def forward(
@@ -520,7 +604,7 @@ def forward(
 ) -> jnp.ndarray:
     """tokens [B, S] int32 -> logits [B, S, V] (compute in cfg.dtype,
     logits in float32)."""
-    x = _hidden_states(params, tokens, cfg, mesh)
+    x, _ = _hidden_states(params, tokens, cfg, mesh)
     return (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
 
 
@@ -530,15 +614,56 @@ def loss_fn(
     cfg: TransformerConfig,
     mesh=None,
 ) -> jnp.ndarray:
-    """Next-token cross entropy; position S-1 is unsupervised (targets are
-    tokens shifted left; same [B, S] shape keeps sp sharding aligned)."""
+    """The training loss: next-token cross entropy; position S-1 is
+    unsupervised (targets are tokens shifted left; same [B, S] shape keeps
+    sp sharding aligned). With dropless experts and a non-zero
+    ``router_aux_loss_coef``, plus that times the load-balancing term."""
+    return loss_and_stats(params, tokens, cfg, mesh)[0]
+
+
+def loss_and_stats(
+    params: Dict[str, Any],
+    tokens: jnp.ndarray,
+    cfg: TransformerConfig,
+    mesh=None,
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """(:func:`loss_fn`'s loss, router statistics of the call). The
+    statistics are ``{}`` for a model without dropless experts, else
+    ``tokens_per_expert`` [L, E] int32 and ``balance_loss`` (the mean over
+    layers of E·Σ_e f_e·P_e, before the coefficient) — what
+    ``TrainStep`` keeps of its last step."""
+    if max(cfg.pp, 1) > 1 and cfg.n_experts and cfg.router_aux_loss_coef:
+        raise ValueError(
+            f"pp={cfg.pp} with router_aux_loss_coef={cfg.router_aux_loss_coef}: "
+            "the load-balancing term is not carried across pipeline stages, "
+            "and is refused rather than dropped; set the coefficient to 0 or pp=1"
+        )
     if max(cfg.pp, 1) > 1 and mesh is not None:
         # pipelined training path: the head (final norm + unembed + NLL)
         # runs inside the pipeline's manual region on the last stage and
         # only SCALAR reductions cross the pp axis — the replicate-the-
         # activations psum the plain forward() pays is for logits
         # consumers, not the training loop
-        return _pipelined_loss(params, tokens, cfg, mesh)
+        return _pipelined_loss(params, tokens, cfg, mesh), {}
+    x, aux = _hidden_states(params, tokens, cfg, mesh)
+    ce = _cross_entropy(params, x, tokens, cfg, mesh)
+    if not aux:
+        return ce, {}
+    balance = jnp.mean(aux[0])
+    stats = {"tokens_per_expert": aux[1], "balance_loss": balance}
+    if cfg.router_aux_loss_coef:
+        ce = ce + cfg.router_aux_loss_coef * balance
+    return ce, stats
+
+
+def _cross_entropy(
+    params: Dict[str, Any],
+    x: jnp.ndarray,
+    tokens: jnp.ndarray,
+    cfg: TransformerConfig,
+    mesh=None,
+) -> jnp.ndarray:
+    """Mean next-token cross entropy of final-norm hidden states ``x``."""
     b, s = tokens.shape
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     # Long-context memory wall: at s=32k vocab=32k the [B,S,V] f32 logits
@@ -550,8 +675,7 @@ def loss_fn(
     # dense path stays (its per-device logits are S/sp smaller), so scale
     # very long context under sp by adding sp shards, not chunking.
     if sp == 1 and _per_device_logit_elems(cfg, b, s, mesh) > _loss_chunk_elems():
-        return _chunked_loss(params, tokens, cfg, mesh)
-    x = _hidden_states(params, tokens, cfg, mesh)
+        return _chunked_loss(params, x, tokens, cfg, mesh)
     with jax.named_scope("head_loss"):
         logits = (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
         targets = jnp.roll(tokens, -1, axis=1)
@@ -593,6 +717,7 @@ def _per_device_logit_elems(
 
 def _chunked_loss(
     params: Dict[str, Any],
+    h: jnp.ndarray,
     tokens: jnp.ndarray,
     cfg: TransformerConfig,
     mesh=None,
@@ -603,7 +728,6 @@ def _chunked_loss(
     the dense path (f32 log_softmax per position; accumulation order
     differs only in the final f32 sums)."""
     b, s = tokens.shape
-    h = _hidden_states(params, tokens, cfg, mesh)
     out_w = params["out"].astype(cfg.dtype)
     targets = jnp.roll(tokens, -1, axis=1)
     mask = jnp.ones((b, s), jnp.float32).at[:, -1].set(0.0)
@@ -662,7 +786,7 @@ def _pipelined_loss(
 
     sp_size = mesh.shape.get("sp", 1)
     sp_manual = sp_size > 1
-    stage_fn = _make_stage_fn(cfg, mesh, sp_manual=sp_manual)
+    stage_fn = _pipeline_stage_fn(cfg, mesh, sp_manual)
     m = cfg.microbatches or pp
     assert b % m == 0, f"batch {b} must divide into {m} microbatches"
     x_mb = x.reshape(m, b // m, s, -1)

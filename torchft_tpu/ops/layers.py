@@ -1,19 +1,23 @@
-"""Building-block layers: RMSNorm, rotary embeddings, SwiGLU, MoE dispatch.
+"""Building-block layers: RMSNorm, rotary embeddings, SwiGLU, and the two
+expert dispatches (dropless top-k, and the capacity einsum kept for ``ep`` > 1).
 
 All pure functions over explicit params — XLA fuses the elementwise chains
-into the adjacent matmuls, so there is nothing to hand-schedule here
-(pallas is reserved for attention, where fusion across the softmax is
-beyond XLA).
+into the adjacent matmuls, so there is nothing to hand-schedule here. The
+only hand-written kernels in the repo are attention's (ops/pallas); the
+experts' grouped matmul is JAX's own: ``jax.lax.ragged_dot`` off the TPU, and
+on it the Pallas kernel that ships with JAX (``megablox.gmm``), see
+:func:`_grouped_matmul` for why not XLA's.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["rms_norm", "rotary_embed", "swiglu", "moe_dispatch"]
+__all__ = ["rms_norm", "rotary_embed", "swiglu", "moe_dropless", "moe_dispatch"]
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
@@ -47,12 +51,130 @@ def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.nd
     return h @ w_out
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x: jnp.ndarray, idx: jnp.ndarray, inv: jnp.ndarray, k: int) -> jnp.ndarray:
+    """``x[idx // k]`` for ``idx`` a permutation of ``k * len(x)`` row slots
+    (slot ``t * k + j`` is token ``t``'s j-th copy) with inverse ``inv``. The
+    backward is the inverse gather summed over a token's k copies — autodiff
+    of a gather would be a scatter-add, which the TPU runs row by row."""
+    return x.at[idx // k].get(mode="promise_in_bounds")
+
+
+def _take_rows_fwd(x, idx, inv, k):
+    return _take_rows(x, idx, inv, k), inv
+
+
+def _take_rows_bwd(k, inv, g):
+    back = g.at[inv].get(mode="promise_in_bounds", unique_indices=True)
+    if k > 1:
+        copies = back.reshape(-1, k, g.shape[-1]).astype(jnp.float32)
+        back = jnp.sum(copies, axis=1).astype(g.dtype)
+    return back, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+# megablox's (rows, contraction, columns) tile on the v5e, from a sweep of
+# twelve tilings at OLMoE's shapes (131 072 rows, 2048 <-> 1024, 64 groups; PR
+# 26, PERF.md §6): 3.7-4.0 ms for each of the forward, the rows' gradient and
+# the weights' gradient at this one, within 7 % of the best of each; larger
+# tiles do not fit the kernel's VMEM. XLA's own kernel: 5.0-5.5 ms.
+_GMM_TILE = (512, 1024, 1024)
+
+
+def _grouped_matmul_tpu(
+    rows: jnp.ndarray, w: jnp.ndarray, counts: jnp.ndarray, interpret: bool = False
+) -> jnp.ndarray:
+    """``interpret``: run the kernel in Pallas's interpreter (the CPU tests)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = rows.shape
+    tm = min(_GMM_TILE[0], -(-m // 128) * 128)
+    pad = -m % tm  # the kernel wants whole row tiles; the rows added belong to no group
+    out = gmm(
+        jnp.pad(rows, ((0, pad), (0, 0))) if pad else rows,
+        w,
+        counts,
+        preferred_element_type=rows.dtype,
+        tiling=(tm, min(_GMM_TILE[1], k), min(_GMM_TILE[2], w.shape[2])),
+        interpret=interpret,
+    )
+    return out[:m] if pad else out
+
+
+def _grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
+    """rows [M, k] ordered by group, w [E, k, n], counts [E] summing to M ->
+    [M, n]: each row times its group's matrix, and no other.
+
+    One algorithm, two lowerings, chosen by the platform the program is
+    lowered for: ``jax.lax.ragged_dot`` everywhere but the TPU, where XLA
+    would turn it into its own grouped-matmul kernel — correct and dropless,
+    but its ops reach a profiler trace named ``ragged-dot-none``, without the
+    ``moe/experts`` scope, so the yardstick cannot tell the experts' time from
+    nothing (PR 26's first chip run read them as ``unscoped``). The Pallas
+    kernel keeps the scope and was the faster of the two on the v5e."""
+    # fresh closures at every call: jax 0.9.0 keeps a tracing cache per branch
+    # function, and with JAX_EXPLAIN_CACHE_MISSES on (benchmark/run.py sets
+    # it) explaining a second shape for a function it has seen raises inside
+    # JAX; a function it has never seen is only logged
+    return jax.lax.platform_dependent(
+        rows,
+        w,
+        counts,
+        tpu=lambda *args: _grouped_matmul_tpu(*args),
+        default=lambda *args: jax.lax.ragged_dot(*args),
+    )
+
+
+def moe_dropless(
+    tokens: jnp.ndarray,
+    top_idx: jnp.ndarray,
+    top_w: jnp.ndarray,
+    w_gate: jnp.ndarray,
+    w_in: jnp.ndarray,
+    w_out: jnp.ndarray,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless top-k experts with static shapes: every one of a token's k
+    experts contributes, whatever its load — no capacity, no drop.
+
+    tokens [T, d]; top_idx [T, k] the chosen experts; top_w [T, k] their
+    weights as they are to be applied; w_gate, w_in [E, d, f]; w_out
+    [E, f, d]. The T·k token-expert rows are ordered by expert (stable
+    sort), each expert's contiguous group goes through its three matrices
+    (:func:`_grouped_matmul`), and the rows are put back, weighted and summed
+    per token. Returns (y [T, d], tokens per expert [E] int32).
+
+    Scopes ``dispatch`` / ``experts`` / ``combine`` nest under the caller's
+    ``moe`` (docs/observability.md)."""
+    t, k = top_idx.shape
+    e = w_gate.shape[0]
+    with jax.named_scope("dispatch"):
+        flat = top_idx.reshape(t * k)
+        order = jnp.argsort(flat, stable=True)  # row slots, by expert
+        inv = jnp.argsort(order)
+        # a compare and a sum: a bincount would be a scatter-add of T·k ones
+        counts = jnp.sum(flat[:, None] == jnp.arange(e, dtype=flat.dtype), axis=0, dtype=jnp.int32)
+        rows = _take_rows(tokens, order, inv, k)  # [T·k, d]
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(_grouped_matmul(rows, w_gate, counts)) * _grouped_matmul(
+            rows, w_in, counts
+        )
+        out = _grouped_matmul(h, w_out, counts)
+    with jax.named_scope("combine"):
+        out = _take_rows(out, inv, order, 1).reshape(t, k, -1)
+        y = jnp.einsum("tkd,tk->td", out, top_w.astype(out.dtype))
+    return y, counts
+
+
 def moe_dispatch(
     gates: jnp.ndarray, capacity: int
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Top-2 token→expert dispatch with capacity (mesh-tensorflow style —
     static shapes, einsum-friendly, so XLA turns the expert axis sharding
-    into an all-to-all over ``ep``).
+    into an all-to-all over ``ep``). Since the dropless :func:`moe_dropless`
+    this is the path of ``ep`` > 1 ONLY (``models/transformer._ffn_moe_ep``),
+    kept until experts over chips get a ``shard_map`` dispatch (ROADMAP R4).
 
     gates: [G, E] softmax router probabilities for G tokens.
     Returns (dispatch [G, E, C] one-hot-ish float, combine [G, E, C]).

@@ -36,6 +36,7 @@ from torchft_tpu.ddp import allreduce_gradients
 from torchft_tpu.manager import Manager
 from torchft_tpu.optim import SpeculativeCommitMixin
 from torchft_tpu.parallel.train_step import TrainStep
+from torchft_tpu.telemetry import tracing
 from torchft_tpu.telemetry.tracing import TRACER
 
 __all__ = ["FTTrainer"]
@@ -147,6 +148,29 @@ class FTTrainer(SpeculativeCommitMixin):
                 self._manager.resolve_pending_commit()
         return self._consume_replay()
 
+    def _record_moe_counters(self, step: int, sync_span) -> None:
+        """The router's load of the step just taken, fetched with the loss
+        (the device is idle by then): on the ``loss_sync`` span in the
+        Tracer ring and, as ``tft.moe.counters``, in a profiler trace. A
+        model without dropless experts has no statistics and emits nothing."""
+        stats = self._ts.last_stats
+        if not stats:
+            return
+        import numpy as np
+
+        load = np.asarray(stats["tokens_per_expert"])  # [layers, experts]
+        counters = dict(
+            step=step,
+            max_load=int(load.max()),
+            min_load=int(load.min()),
+            mean_load=float(load.mean()),
+            balance_loss=float(stats["balance_loss"]),
+        )
+        sync_span.set(**counters)
+        # an annotation takes its stats at entry: a zero-length one carries them
+        with tracing.annotate("moe.counters", **counters):
+            pass
+
     # -- drive --
 
     def step(self, tokens) -> Tuple[float, bool]:
@@ -202,7 +226,8 @@ class FTTrainer(SpeculativeCommitMixin):
                         self._params, self._opt_state = self._ts.apply(
                             self._params, self._opt_state, grads
                         )
-            with TRACER.span("loss_sync"):
+            with TRACER.span("loss_sync") as sync_span:
                 loss = float(loss)
+                self._record_moe_counters(label, sync_span)
             step_span.set(committed=committed)
         return loss, committed

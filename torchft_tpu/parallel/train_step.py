@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     init_params,
-    loss_fn,
+    loss_and_stats,
     param_specs,
 )
 
@@ -56,8 +56,14 @@ class TrainStep:
             is_leaf=like_params,
         )
 
+        # router statistics of the last step (``loss_and_stats``: tokens per
+        # expert per layer, the balance term), left on the device: whoever
+        # wants them fetches them (``FTTrainer.step``, with the loss). ``{}``
+        # for a model without dropless experts, which emits none.
+        self.last_stats: Dict[str, jnp.ndarray] = {}
+
         def compute_loss(params, tokens):
-            return loss_fn(params, tokens, cfg, mesh)
+            return loss_and_stats(params, tokens, cfg, mesh)
 
         # Shardings are pinned on both sides of grads/apply, so each is ONE
         # program whatever placed its inputs — init, the previous step, a
@@ -70,12 +76,15 @@ class TrainStep:
         # line and the compile log name a program after its function, and
         # what reads them must find it after a refactor.
         def tft_grads(params, tokens):
-            return jax.value_and_grad(compute_loss)(params, tokens)
+            (loss, stats), grads = jax.value_and_grad(compute_loss, has_aux=True)(
+                params, tokens
+            )
+            return loss, grads, stats
 
         self._value_and_grad = jax.jit(
             tft_grads,
             in_shardings=(self._param_shardings, self._batch_sharding),
-            out_shardings=(replicated, self._param_shardings),
+            out_shardings=(replicated, self._param_shardings, replicated),
         )
 
         def tft_apply(params, opt_state, grads):
@@ -101,9 +110,9 @@ class TrainStep:
         self._apply_keep = None
 
         def tft_fused(params, opt_state, tokens):
-            loss, grads = tft_grads(params, tokens)
+            loss, grads, stats = tft_grads(params, tokens)
             new_params, opt_state = tft_apply(params, opt_state, grads)
-            return loss, new_params, opt_state
+            return loss, new_params, opt_state, stats
 
         self._fused = jax.jit(tft_fused, donate_argnums=(0, 1))
 
@@ -171,9 +180,9 @@ class TrainStep:
 
         t0 = _time.perf_counter()
         with jax.set_mesh(self.mesh):
-            out = self._fused(params, opt_state, tokens)
+            *out, self.last_stats = self._fused(params, opt_state, tokens)
         self._record_compute(t0)
-        return out
+        return tuple(out)
 
     def grads(self, params, tokens) -> Tuple[jnp.ndarray, Any]:
         """Loss + gradient pytree (still on device)."""
@@ -181,9 +190,9 @@ class TrainStep:
 
         t0 = _time.perf_counter()
         with jax.set_mesh(self.mesh):
-            out = self._value_and_grad(params, tokens)
+            *out, self.last_stats = self._value_and_grad(params, tokens)
         self._record_compute(t0)
-        return out
+        return tuple(out)
 
     def apply(self, params, opt_state, grads, donate: bool = True) -> Tuple[Any, Any]:
         """Apply (possibly host-averaged) grads.
