@@ -12,5 +12,4 @@ MOVES = "step_p50_s"
 
 
 def compute(run):
-    name = program_spans.PREFIX + "exchange.counters"
-    return program_spans.per_step_median(run, lambda t: t.stat(name, "buckets_reused"))
+    return program_spans.exchange_counter_median(run, "buckets_reused")

@@ -11,4 +11,4 @@ MOVES = "step_p50_s"
 
 
 def compute(run):
-    return program_spans.exchange_cpu_median(run, "stime_s")
+    return program_spans.exchange_counter_median(run, "stime_s")

@@ -14,4 +14,4 @@ MOVES = "step_p50_s"
 
 
 def compute(run):
-    return program_spans.exchange_cpu_median(run, "utime_s")
+    return program_spans.exchange_counter_median(run, "utime_s")

@@ -1,8 +1,8 @@
 """Device seconds of a step's forward and backward pass: the executions of the
-program ``jit_tft_grads`` on the device plane's ``XLA Modules`` line. Median
-over the traced steps, mean over groups."""
+program ``jit_tft_grads`` on the device plane's ``XLA Modules`` line that the
+step's unit launched. Median over the traced units but the last, mean over groups."""
 
-import program_spans
+import scope_metrics
 
 NAME, UNIT, SOURCE = "grads_device_s", "s", "device_trace"
 LAYER = "device compute"
@@ -10,4 +10,4 @@ MOVES = "step_p50_s"
 
 
 def compute(run):
-    return program_spans.per_step_median(run, lambda t: t.program_seconds("tft_grads"))
+    return scope_metrics.program_seconds(run, "tft_grads")

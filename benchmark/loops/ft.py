@@ -89,6 +89,11 @@ class Loop:
             "ledger_rows": telemetry.LEDGER.dump()["rows"],
             "heal_events": telemetry.EVENTS.recent("heal_end"),
             "exchange_bytes": 4 * self._ctx.n_params,
+            # the program's own record of each exchange (its ring of spans), traced or not
+            "exchange_spans": [
+                {"dur_s": s["dur_s"], **s.get("attrs", {})}
+                for s in telemetry.TRACER.recent("exchange")
+            ],
         }
         self.manager.shutdown(wait=False)
         if self._store is not None:

@@ -132,6 +132,27 @@ def compiles_in_window(results: Sequence[Dict[str, Any]]) -> List[str]:
     return out
 
 
+# a worker's stamps in order, and what the stretch that ends at each is
+SETUP_STAGES = (
+    ("start", "launch"), ("imports", "imports"), ("devices", "backend"), ("state", "state"),
+    ("reference", "reference"), ("ready", "warmup"),
+)
+
+
+def setup_stages(results: Sequence[Dict[str, Any]], t_exec: float, t_open: float) -> Dict[str, float]:
+    """``setup_s`` split at the workers' stamps, each the slowest group's:
+    exec of ``run.py`` -> the workers run (launcher, lighthouse) -> imports ->
+    ``jax.devices()`` -> state placed -> reference check -> warm-up commits
+    (the first program load is here) -> the parent opens the window."""
+    out, prev = {}, t_exec
+    for stamp, name in SETUP_STAGES:
+        t = max(r["stamps"][stamp] for r in results)
+        out[name + "_s"] = t - prev
+        prev = t
+    out["open_s"] = t_open - prev
+    return out
+
+
 class Run:
     """What a per-layer metric's ``compute(run)`` may read."""
 

@@ -34,6 +34,7 @@ from typing import Any, Dict, Optional
 
 import opcount
 import reduce_trace
+import scope_metrics
 import xplane_meta
 from measure import median
 
@@ -96,7 +97,11 @@ def _units_by_subscope(path: str):
             if i >= 0 and s < modules[i][1]:
                 ident = modules[i][2].rsplit("(", 1)[-1].rstrip(")")
                 program = int(ident) if ident.isdigit() else 0
-                launched = enqueued.get(modules[i][3], starts[i])
+                launched = enqueued.get(modules[i][3])
+            elif modules:
+                launched = None
+            if launched is None:
+                continue  # a run launched before the trace began: no unit's (reduce_trace._with_scopes)
             u = bisect_right(units, launched) - 1
             if u >= 0:
                 per_unit[u].append((s, e, subscope_of(op_names.get((program, name)))))
@@ -111,7 +116,10 @@ def device_seconds(run) -> Optional[Dict[str, float]]:
     """Device seconds a step by subscope of ``moe`` (``router``, ``dispatch``,
     ``experts``, ``combine``, and ``moe`` for what names none): median over the
     traced units but the last of several, mean over groups. None where no
-    group's trace has an op of the scope."""
+    group's trace has an op of the scope, or the units' scopes do not add
+    up to their program runs (``scope_metrics.adds_up``)."""
+    if not scope_metrics.adds_up(run):
+        return None
     per = int(run.traffic["steps_per_unit"])
     per_group = []
     for r in run.results:
@@ -121,9 +129,7 @@ def device_seconds(run) -> Optional[Dict[str, float]]:
         except Exception as e:  # noqa: BLE001 — a metric left out, never a failed run
             print(f"[bench] moe_scopes: cannot read {path}: {type(e).__name__}: {e}", flush=True)
             rows = None
-        if rows and len(rows) > 1:
-            rows = rows[:-1]  # its apply may be cut where the trace stops
-        rows = [row for row in rows or [] if row]
+        rows = [row for row in scope_metrics.whole(rows or []) if row]  # the last of several may be cut
         if rows:
             per_group.append({
                 sub: median([row.get(sub, 0.0) for row in rows]) / per

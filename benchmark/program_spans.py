@@ -13,8 +13,7 @@ traced step:
 * seconds by span name, over all threads or the main thread only;
 * a stat of a span (the counters ``tft.exchange.counters`` carries);
 * self time: a main-thread span minus the program spans nested in it;
-* the chip's idle seconds under a span, by ``reduce_trace.reduce_planes``;
-* device seconds of a jitted program, by its name on ``XLA Modules``.
+* the chip's idle seconds under a span, by ``reduce_trace.reduce_planes``.
 
 A program without such spans (an older commit), a trace without a device
 plane (the CPU rehearsal) or a missing file gives ``None``, never an error.
@@ -32,7 +31,6 @@ import reduce_trace
 from measure import median
 
 PREFIX = "tft."
-MODULE_LINE = reduce_trace.MODULE_LINE
 
 # (start_ns, end_ns, name, line index, stats)
 Event = Tuple[float, float, str, int, Dict[str, Any]]
@@ -174,32 +172,6 @@ class Trace:
                 out[n].append(gaps.get(n, 0.0))
         return out
 
-    def program_seconds(self, program: str) -> Optional[List[float]]:
-        """Per traced step: device seconds of the executions of the jitted
-        program ``program`` (``XLA Modules`` events ``jit_<program>(<id>)``),
-        mean over the group's chips. None without such a line or event.
-
-        A step owns what starts before the next step does: the program a
-        step dispatches last (``apply``) starts on the device as the step's
-        unit ends, or just after. The last step's is cut short where the
-        trace stops; a median over the steps leaves that one out."""
-        starts = [lo for lo, _ in self.units]
-        owned = list(zip(starts, starts[1:] + [float("inf")]))
-        per_chip = []
-        for plane in self.device_planes:
-            runs = [
-                (float(ev.start_ns), float(ev.duration_ns))
-                for line in plane.lines if line.name == MODULE_LINE
-                for ev in line.events if ev.name.split("(")[0] == "jit_" + program
-            ]
-            if runs:
-                per_chip.append([
-                    sum(d for s, d in runs if lo <= s < hi) / 1e9 for lo, hi in owned
-                ])
-        if not per_chip:
-            return None
-        return [sum(col) / len(per_chip) for col in zip(*per_chip)]
-
 
 @functools.lru_cache(maxsize=8)
 def _load(path: str) -> Trace:
@@ -235,11 +207,10 @@ def per_step_median(run, per_step: Callable[[Trace], Optional[List[float]]]) -> 
     return run.per_group_mean(per_group)
 
 
-def exchange_cpu_median(run, key: str) -> Optional[float]:
-    """:func:`per_step_median` of the CPU seconds ``key`` (``utime_s`` or
-    ``stime_s``) that the program's ``tft.exchange.counters`` carries. The
-    kernel splits a process's CPU time into user and system by its tick, so
-    over an exchange of a few ms either may read exactly 0: no exchange runs
-    on no CPU time, so that is a delta under the clock's resolution, and it
-    is left out like any reading that is not there."""
-    return per_step_median(run, lambda t: t.stat(PREFIX + "exchange.counters", key)) or None
+def exchange_counter_median(run, key: str) -> Optional[float]:
+    """:func:`per_step_median` of the stat ``key`` that the program's
+    ``tft.exchange.counters`` carries (``utime_s``, ``stime_s``,
+    ``buckets_reused``, ``d2h_pages_kept``). An exact 0 is a reading — no
+    bucket reused, or CPU seconds under the kernel's 10 ms tick — and only a
+    program without the counter gives None."""
+    return per_step_median(run, lambda t: t.stat(PREFIX + "exchange.counters", key))

@@ -19,7 +19,9 @@ whose event covers its start. The planes share one clock to within about 1.5 ms:
 trace a program starts on the device plane ~1.3 ms before the host span
 that launched it. Against units of 0.6 s and more that is under 0.3 %, and
 the busy and gap seconds do not correct it; which unit a program run belongs
-to is decided on the host's clock alone, by when the host enqueued the run.
+to is decided on the host's clock alone, by when the host enqueued the run;
+a run that no enqueue event in the trace launched was launched before the
+trace began, and belongs to no traced unit.
 """
 
 from __future__ import annotations
@@ -125,17 +127,23 @@ def _with_scopes(ops, modules, names, enqueued):
     ``op_name``; an op's program run is the ``modules`` event (start, end,
     ``jit_<function>(<id>)``, run id) that covers its start, launched when
     the host enqueued that run id (``enqueued``: on the host's clock, as the
-    units are) or else when it started; an op outside any starts its own."""
+    units are). Launched is None where the trace does not say: no run covers
+    the op, or no enqueue event names the run. Both are what a run launched
+    BEFORE the trace began leaves in it — the ``apply`` of the step before
+    the first traced one is enqueued late and is still running when the
+    profiler attaches (its module event is cut short, or missing) — so such
+    ops are no traced unit's. A plane without a ``XLA Modules`` line (the
+    CPU rehearsal) promises no ownership: its ops count where they start."""
     starts = [m[0] for m in modules]
     ids = [m[2].rsplit("(", 1)[-1].rstrip(")") for m in modules]
     cache: Dict[Tuple[int, str], Tuple[str, str]] = {}
     out = []
     for s, e, name in ops:
         i = bisect_right(starts, s) - 1
-        program, launched = 0, s
+        program, launched = 0, (None if modules else s)
         if i >= 0 and s < modules[i][1]:
             program = int(ids[i]) if ids[i].isdigit() else 0
-            launched = enqueued.get(modules[i][3], starts[i])
+            launched = enqueued.get(modules[i][3])
         key = (program, name)
         if key not in cache:
             cache[key] = (short_op_name(name), xplane_meta.scope_of(names.get(key)))
@@ -207,6 +215,11 @@ def reduce_planes(
                 per_chip.append((cpu_ops, [], {}))
     # per chip: (start, end, (short name, scope), launched), see _with_scopes
     device_ops = [_with_scopes(*chip, enqueued) for chip in per_chip]
+    # per chip: its program runs as (seconds, ``jit_<function>``, launched)
+    device_runs = [
+        [((e - s) / 1e9, name.split("(")[0], enqueued[run]) for s, e, name, run in modules if run in enqueued]
+        for _, modules, _ in per_chip
+    ]
     units = sorted((s, e) for s, e, n in host_spans if n == UNIT_SPAN)
     if not units:
         raise ValueError(f"the trace holds no {UNIT_SPAN!r} span")
@@ -221,20 +234,34 @@ def reduce_planes(
     # the program a step dispatches last (``apply``) starts on the device as
     # its unit ends and runs on into the next; the last unit's is cut where
     # the trace stops. Launched, not started: the device's clock runs up to
-    # 1.5 ms ahead of the host's, and a unit's first run can start "before" it
+    # 1.5 ms ahead of the host's, and a unit's first run can start "before" it.
+    # A run the trace holds no launch of was launched before it began: no unit's
     starts = [s for s, _ in units]
     owned = list(zip(starts, starts[1:] + [float("inf")]))
     unit_rows = []
     for (s, e), (own_lo, own_hi) in zip(units, owned):
         by_scope: Dict[str, float] = {}
-        for ops in device_ops:
-            scoped = [(a, b, key[1]) for a, b, key, launched in ops if own_lo <= launched < own_hi]
+        programs: Dict[str, float] = {}
+        unlaunched_s = 0.0
+        for ops, runs in zip(device_ops, device_runs):
+            scoped = [(a, b, key[1]) for a, b, key, launched in ops if launched is not None and own_lo <= launched < own_hi]
             for scope, sec in _self_times(scoped).items():
                 by_scope[scope] = by_scope.get(scope, 0.0) + sec / chips
+            stray = [(a, b, None) for a, b, _, launched in ops if launched is None and own_lo <= a < own_hi]
+            unlaunched_s += sum(_self_times(stray).values()) / chips
+            for sec, name, launched in runs:
+                if own_lo <= launched < own_hi:
+                    programs[name] = programs.get(name, 0.0) + sec / chips
         unit_rows.append({
             "dur_s": (e - s) / 1e9,
             "busy_s": sum(union_seconds(m, s, e) for m in merged) / chips,
             "by_scope": by_scope,
+            # the same ownership for the program runs themselves: what the
+            # scopes have to add up to (scope_metrics.py holds them to it)
+            "programs": programs,
+            # ops that ran in this unit's stretch of a run launched before
+            # the trace began: no traced unit's, so in no unit's scopes
+            "unlaunched_s": unlaunched_s,
         })
 
     op_s: Dict[str, float] = {}

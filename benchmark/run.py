@@ -211,6 +211,14 @@ def report(args, bench, cell, config, traffic, results, t_open):
         print(f"[bench] g{r['group']}: persistent compile cache {hits} hit(s), {misses} miss(es)", flush=True)
         for e in r.get("heal_events") or []:
             print(f"[bench] g{r['group']}: heal_end {json.dumps(e)}", flush=True)
+        probes = r.get("host_probe") or {}
+        print(
+            f"[bench] g{r['group']}: host_probe "
+            + "; ".join(f"{at}: copy {p['copy_s']:.4f} s, spin {p['spin_s']:.4f} s" for at, p in probes.items()),
+            flush=True,
+        )
+    stages = measure.setup_stages(results, T_EXEC, t_open)
+    print("[bench] setup_s by stage: " + ", ".join(f"{k} {v:.2f}" for k, v in stages.items()), flush=True)
 
     same_state = len({(r["final_step"], r["param_checksum"]) for r in results}) == 1
     correct = bool(measure.losses_finite(results) and same_state)

@@ -9,6 +9,16 @@ where the trace stops, so of several traced units the last is left out (a loop
 that traces one unit blocks at its end: ``loops/fused.py``). A group's seconds
 are the mean over its chips, and a sharded group's chips share the step's
 operations and its state: a share divides by ``chips_per_group``.
+
+The same rule owns the program runs themselves (``program_seconds``: what
+``grads_device_s`` and ``apply_device_s`` read), and the scopes of a unit have to
+add up to its programs: every op a unit owns runs inside a program run it owns
+(``reduce_trace`` keeps what is left of a run launched before the trace began out
+of every unit: given to the first unit by its start on the device's clock, 4.3 ms
+of such an ``apply`` once read ``optimizer_roofline`` 131 %; ledger, PR 28). Where
+they do not, part of a run went to another unit, a scope's seconds are short and
+its roofline can pass 100 %: the readers then give None for every scope and say
+why (``adds_up``).
 """
 
 from __future__ import annotations
@@ -19,15 +29,58 @@ import opcount
 from measure import median
 
 
+SUM_TOLERANCE = 0.005  # the scopes of a unit against its program runs
+
+
+def whole(units):
+    return units[:-1] if len(units) > 1 else units
+
+
+def fault(run) -> Optional[str]:
+    """Why the scopes of a traced unit do not add up to its program runs, or
+    None where they do (or the rows carry no programs: nothing was promised)."""
+    for r in run.results:
+        for i, u in enumerate(whole((r.get("trace") or {}).get("units") or [])):
+            if not u.get("programs"):
+                continue
+            scopes, programs = sum(u.get("by_scope", {}).values()), sum(u["programs"].values())
+            if abs(scopes - programs) > SUM_TOLERANCE * programs:
+                return (
+                    f"group {r.get('group')}, traced unit {i}: the scopes add up to {scopes:.6f} s, "
+                    f"its program runs {u['programs']} to {programs:.6f} s"
+                )
+    return None
+
+
+def adds_up(run) -> bool:
+    """:func:`fault` once a run, with its reason in the run's log."""
+    if not hasattr(run, "scope_fault"):
+        run.scope_fault = fault(run)
+        if run.scope_fault:
+            print(f"[bench] scope metrics left out: {run.scope_fault}", flush=True)
+    return run.scope_fault is None
+
+
+def program_seconds(run, program: str) -> Optional[float]:
+    """Device seconds a step spends in the jitted program ``program`` (the
+    ``XLA Modules`` events ``jit_<program>(<id>)`` of the runs its unit
+    launched): median over the traced units but the last of several, mean
+    over groups; None where no such unit ran it."""
+    per = int(run.traffic["steps_per_unit"])
+    name = "jit_" + program
+    return run.per_group_mean([
+        median([u["programs"][name] / per for u in whole(r["trace"].get("units") or []) if name in u.get("programs", {})])
+        for r in run.results if r.get("trace")
+    ])
+
+
 def device_seconds(run, scope: str) -> Optional[float]:
     """Device seconds a step spends in ``scope``: median over the traced
     units but the last of several, mean over groups; None where no such unit
-    ran an op of it."""
+    ran an op of it, or the units' scopes do not add up (:func:`adds_up`)."""
+    if not adds_up(run):
+        return None
     per = int(run.traffic["steps_per_unit"])
-
-    def whole(units):
-        return units[:-1] if len(units) > 1 else units
-
     return run.per_group_mean([
         median([
             u["by_scope"][scope] / per

@@ -10,6 +10,7 @@ import measure
 import opcount
 import program_spans as ps
 import reduce_trace as rt
+import scope_metrics
 from common import load_json, load_module
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -41,6 +42,10 @@ def test_the_readers_on_the_recorded_trace():
     """One step a unit: the median of the first two of the three units' rows."""
     units = rt.reduce_file(SCOPED, [])["units"]
     run = a_run(units, olmo_tc())
+    # a miniature: the gaps between its ops are 0.6 % of the program run (0.002-0.012 % at a cell's size),
+    # which the readers would take for a unit whose scopes do not add up
+    assert "scopes add up to" in scope_metrics.fault(run)
+    run = a_run([{k: v for k, v in u.items() if k != "programs"} for u in units], olmo_tc())
     got = {name: reader(name).compute(run) for name in SCOPE_READERS}
     assert got["attn_device_s"] == pytest.approx((36684 + 36685) / 2 * 1e-9, rel=1e-9)
     assert got["ffn_device_s"] == pytest.approx((131679 + 131614) / 2 * 1e-9, rel=1e-9)
@@ -127,3 +132,67 @@ def test_buckets_reused_is_the_counters_median(monkeypatch):
     assert compute(run) == 0.0  # nothing kept is a reading
     monkeypatch.setattr(ps, "load", lambda result: Counters(None))
     assert compute(run) is None  # a program that does not count it
+
+
+def test_pages_kept_is_the_counters_median(monkeypatch):
+    class Counters:
+        units = [(0.0, 1.0), (1.0, 2.0), (2.0, 3.0)]
+
+        def __init__(self, values):
+            self.values = values
+
+        def stat(self, name, key):
+            assert (name, key) == (ps.PREFIX + "exchange.counters", "d2h_pages_kept")
+            return self.values
+
+    run = measure.Run({}, {}, {}, None, [{"group": 0}])
+    compute = reader("exchange_d2h_pages_kept").compute
+    monkeypatch.setattr(ps, "load", lambda result: Counters([1.0, 1.0, 1.0]))
+    assert compute(run) == 1.0
+    monkeypatch.setattr(ps, "load", lambda result: Counters([0.0, 0.0, 1.0]))
+    assert compute(run) == 0.0  # fresh pages on most steps is a reading
+    monkeypatch.setattr(ps, "load", lambda result: Counters(None))
+    assert compute(run) is None  # a program from before PR 27 does not count it
+
+
+def test_the_host_probe_reader_takes_the_copy_at_the_windows_open():
+    compute = reader("host_probe_copy_s").compute
+    probe = lambda copy: {"open": {"copy_s": copy, "spin_s": 0.04}, "close": {"copy_s": 9.0, "spin_s": 0.05}}
+    run = measure.Run({}, {}, {}, None, [{"group": 0, "host_probe": probe(0.024)}, {"group": 1, "host_probe": probe(0.030)}])
+    assert compute(run) == pytest.approx(0.027)  # the mean of the groups', never the close's
+    assert compute(measure.Run({}, {}, {}, None, [{"group": 0}])) is None  # a worker from before the probe
+
+
+# -- the scopes of a unit add up to its program runs, or no scope is reported
+
+def pr28_units():
+    """The traced run of the ledger's PR 28 line (change's side), as the rows of then: the first unit was given 4.3 ms
+    of the ``apply`` that began before the trace did, so ``optimizer_device_s`` read 0.014749 s and its roofline 131.02 %.
+    ``reduce_trace`` now keeps such ops out of every unit (``unlaunched_s``); these rows are what a reduction that
+    does not would hand the readers."""
+    full = {"attn": 0.21278, "ffn": 0.2134, "head_loss": 0.098626, "optimizer": 0.025195, "embed": 0.0060976, "unscoped": 0.035978}
+    programs = {"jit_tft_grads": 0.56677, "jit_tft_apply": 0.025441, "jit_convert_element_type": 6e-7}
+    cut = dict(full, optimizer=0.004303)
+    return [{"by_scope": cut, "programs": {k: v for k, v in programs.items() if k != "jit_tft_apply"}},
+            {"by_scope": full, "programs": programs}, {"by_scope": full, "programs": programs}]
+
+
+@pytest.mark.parametrize("name", SCOPE_READERS)
+def test_no_scope_is_reported_where_a_units_scopes_do_not_add_up(name, capsys):
+    compute = reader(name).compute
+    units = pr28_units()
+    sound = [units[1], units[1], units[0]]  # the cut unit last: left out, as every last unit is
+    assert compute(a_run(sound, olmo_tc())) is not None
+    if name == "optimizer_roofline":
+        # what the readers printed before they checked: the median of a cut and a whole unit
+        unchecked = [{"by_scope": u["by_scope"]} for u in units]
+        assert compute(a_run(unchecked, olmo_tc())) == pytest.approx(131.0, abs=0.1)
+    assert compute(a_run(units, olmo_tc())) is None  # 0.76 % over what the unit's runs took
+    assert "scope metrics left out: group 0, traced unit 0: the scopes add up to 0.571" in capsys.readouterr().out
+    # the other way: the scopes lack 1.8 % of the runs
+    lacking = [dict(units[1], by_scope=dict(units[1]["by_scope"], optimizer=0.014749)), units[1]]
+    assert compute(a_run(lacking, olmo_tc())) is None
+    assert "the scopes add up to 0.58" in capsys.readouterr().out
+    # 0.012 % apart, as the cells read (PERF.md section 5), is sound
+    near = [dict(units[1], by_scope=dict(units[1]["by_scope"], unscoped=0.035978 - 7e-5)), units[1]]
+    assert compute(a_run(near, olmo_tc())) is not None

@@ -111,6 +111,7 @@ def main() -> int:
 
 def run(run_dir, group, log, stamps, fail) -> int:
     from common import load_json, load_module, write_atomic
+    from host_probe import HostProbe
 
     cell = load_json(os.path.join(run_dir, "cell.json"))
     config, traffic = cell["config"], cell["traffic"]
@@ -134,6 +135,8 @@ def run(run_dir, group, log, stamps, fail) -> int:
     from torchft_tpu.parallel.train_step import TrainStep
 
     stamps["imports"] = time.monotonic()
+    probe = HostProbe()  # its buffers fill while the backend comes up
+    host_probe = {}
     initialize_group()
     devices = jax.devices()
     stamps["devices"] = time.monotonic()
@@ -269,6 +272,7 @@ def run(run_dir, group, log, stamps, fail) -> int:
         elif not all(r["committed"] for r in results):
             time.sleep(0.2)  # back off while the quorum is short, as train_hsdp
         if ready_at is None and full_commits >= int(traffic["warmup_full_commits"]):
+            host_probe["open"] = probe.take()
             ready_at = time.monotonic()
             stamps["ready"] = ready_at
             write_atomic(os.path.join(run_dir, f"ready.{group}"), str(ready_at))
@@ -279,6 +283,7 @@ def run(run_dir, group, log, stamps, fail) -> int:
         jax.profiler.stop_trace()
         trace_state["t1"] = time.monotonic()
     stamps["loop_end"] = time.monotonic()
+    host_probe["close"] = probe.take()
     window = load_json(window_path)
 
     # -- after the window: what the checks and the per-layer metrics read
@@ -293,6 +298,7 @@ def run(run_dir, group, log, stamps, fail) -> int:
         "group": group,
         "device": {"platform": dev.platform, "kind": dev.device_kind, "count": len(devices), "used": mesh_cfg.total},
         "stamps": stamps,
+        "host_probe": host_probe,
         "window": window,
         "units": units,
         "tokens_per_step": batch * seq,
