@@ -1,6 +1,6 @@
 """Fault-tolerant ResNet-18 CIFAR-10 DDP — the reference's flagship
-real-data config (BASELINE.md: "ResNet-18 CIFAR-10 DDP with kill/rejoin";
-reference train_ddp.py:34-80).
+real-data config ("ResNet-18 CIFAR-10 DDP with kill/rejoin": reference
+train_ddp.py:34-80).
 
 TPU-native differences from the torch original: the model is the pure-JAX
 NHWC ResNet (models/resnet.py) with functional batch norm — running stats

@@ -1,4 +1,4 @@
-"""DiLoCo training example — the BASELINE.md "DiLoCo 4 groups" config.
+"""DiLoCo training example — four replica groups, one outer step per H.
 
 Communication-reduced fault-tolerant training (arxiv 2311.08105): each
 replica group runs ``SYNC_EVERY`` purely-local AdamW steps, then the

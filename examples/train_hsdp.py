@@ -1,4 +1,4 @@
-"""HSDP training example — the BASELINE.md "HSDP Llama-2-7B" config shape.
+"""HSDP training example — replica groups of sharded (fsdp x tp) meshes.
 
 The flagship composition: each replica group owns a fixed inner
 ``jax.sharding.Mesh`` (fsdp x tp [x sp x pp] — XLA's ICI collectives,
@@ -13,11 +13,11 @@ Env:
 
     TORCHFT_LIGHTHOUSE=host:port
     REPLICA_GROUP_ID / NUM_REPLICA_GROUPS (default 2)
-    MODEL=tiny|scale_647M|llama2-7b|olmoe-1b-7b
+    MODEL=tiny|scale_647M|olmoe-1b-7b
                                    models.transformer.PRESETS (default
                                    tiny; scale_647M fills one v5e chip,
-                                   the 7b shapes need >= 8 real chips
-                                   per group)
+                                   olmoe-1b-7b needs a sharded group of
+                                   >= 8 real chips)
     FSDP/TP/SP/PP                  inner mesh axis sizes (default 2/2/1/1)
                                    over the devices THIS process sees: a
                                    chip belongs to one process, so the

@@ -11,12 +11,7 @@
 #      runs too instead of being silently skipped
 #   3. the quick faultmatrix subset  (runner --quick) — every scenario
 #      now also replays spec-conformance-clean or fails
-#   4. the profiler-overhead smoke  (armed-at-default-Hz vs disarmed
-#      headline leg, gate <=2% — ISSUE 12)
-#   5. the telemetry-overhead smoke  (piggyback armed vs disarmed
-#      headline leg, gate <=1% / TORCHFT_TELEMETRY_BUDGET_PCT —
-#      ISSUE 16's self-metering budget)
-#   6. the protocol verification gate (ISSUE 15/20): bounded model check
+#   4. the protocol verification gate (ISSUE 15/20): bounded model check
 #      of the quorum/commit spec AND the HA lighthouse tier (crash at
 #      every transition point, POR+symmetry reductions) + a conformance
 #      replay of the quick matrix's trails
@@ -28,10 +23,9 @@
 # "can I even propose this diff" check.
 #
 # Usage:
-#   scripts/premerge.sh              # all six gates
+#   scripts/premerge.sh              # all four gates
 #   scripts/premerge.sh --no-matrix  # skip the faultmatrix (seconds-fast;
-#                                    # gate 6 then skips the replay leg)
-#   scripts/premerge.sh --no-smoke   # skip both overhead smokes
+#                                    # gate 4 then skips the replay leg)
 #   scripts/premerge.sh --json       # append a machine-readable per-gate
 #                                    # summary (name/status/seconds) as the
 #                                    # final stdout line — skips (e.g. the
@@ -47,14 +41,12 @@ REPO="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
 cd "$REPO"
 
 RUN_MATRIX=1
-RUN_SMOKE=1
 JSON_OUT=0
 for arg in "$@"; do
   case "$arg" in
     --no-matrix) RUN_MATRIX=0 ;;
-    --no-smoke) RUN_SMOKE=0 ;;
     --json) JSON_OUT=1 ;;
-    *) echo "unknown arg: $arg (known: --no-matrix --no-smoke --json)" >&2
+    *) echo "unknown arg: $arg (known: --no-matrix --json)" >&2
        exit 2 ;;
   esac
 done
@@ -68,7 +60,7 @@ record_gate() {
   GATE_RECORDS+=("{\"name\":\"$1\",\"status\":\"$2\",\"seconds\":$3}")
 }
 
-echo "=== [1/6] static-analysis gate (python -m torchft_tpu.analysis) ==="
+echo "=== [1/4] static-analysis gate (python -m torchft_tpu.analysis) ==="
 t0=$SECONDS
 if JAX_PLATFORMS=cpu python -m torchft_tpu.analysis; then
   record_gate "analysis" passed $((SECONDS - t0))
@@ -77,7 +69,7 @@ else
   record_gate "analysis" failed $((SECONDS - t0))
 fi
 
-echo "=== [2/6] native strict-warning build (make -C native warn) ==="
+echo "=== [2/4] native strict-warning build (make -C native warn) ==="
 t0=$SECONDS
 if make -C native warn; then
   record_gate "native-warn" passed $((SECONDS - t0))
@@ -106,7 +98,7 @@ fi
 
 MATRIX_DIR="${TMPDIR:-/tmp}/premerge_faultmatrix"
 if [ "$RUN_MATRIX" = 1 ]; then
-  echo "=== [3/6] quick faultmatrix subset (runner --quick) ==="
+  echo "=== [3/4] quick faultmatrix subset (runner --quick) ==="
   t0=$SECONDS
   if JAX_PLATFORMS=cpu python -m torchft_tpu.faultinject.runner --quick \
       --outdir "$MATRIX_DIR"; then
@@ -116,57 +108,11 @@ if [ "$RUN_MATRIX" = 1 ]; then
     record_gate "faultmatrix-quick" failed $((SECONDS - t0))
   fi
 else
-  echo "=== [3/6] faultmatrix skipped (--no-matrix) ==="
+  echo "=== [3/4] faultmatrix skipped (--no-matrix) ==="
   record_gate "faultmatrix-quick" skipped 0
 fi
 
-if [ "$RUN_SMOKE" = 1 ]; then
-  echo "=== [4/6] profiler-overhead smoke (armed vs disarmed, gate <=2%) ==="
-  # a single short leg on a loaded box can swing past the gate on
-  # weather (the row's own note says so) — one breach earns one retry,
-  # and only a breach on BOTH runs fails the gate
-  t0=$SECONDS
-  if ! JAX_PLATFORMS=cpu python -m torchft_tpu.benchmarks.profiler_overhead \
-      --smoke; then
-    echo "premerge: smoke breached once — retrying (box weather?)" >&2
-    if ! JAX_PLATFORMS=cpu python -m torchft_tpu.benchmarks.profiler_overhead \
-        --smoke; then
-      fail "profiler-overhead smoke (breached twice)"
-      record_gate "profiler-smoke" failed $((SECONDS - t0))
-    else
-      record_gate "profiler-smoke" passed $((SECONDS - t0))
-    fi
-  else
-    record_gate "profiler-smoke" passed $((SECONDS - t0))
-  fi
-else
-  echo "=== [4/6] profiler-overhead smoke skipped (--no-smoke) ==="
-  record_gate "profiler-smoke" skipped 0
-fi
-
-if [ "$RUN_SMOKE" = 1 ]; then
-  echo "=== [5/6] telemetry-overhead smoke (piggyback armed vs disarmed, gate <=1%) ==="
-  # same weather policy as gate 4: one breach earns one retry
-  t0=$SECONDS
-  if ! JAX_PLATFORMS=cpu python -m torchft_tpu.benchmarks.telemetry_overhead \
-      --smoke; then
-    echo "premerge: smoke breached once — retrying (box weather?)" >&2
-    if ! JAX_PLATFORMS=cpu python -m torchft_tpu.benchmarks.telemetry_overhead \
-        --smoke; then
-      fail "telemetry-overhead smoke (breached twice)"
-      record_gate "telemetry-smoke" failed $((SECONDS - t0))
-    else
-      record_gate "telemetry-smoke" passed $((SECONDS - t0))
-    fi
-  else
-    record_gate "telemetry-smoke" passed $((SECONDS - t0))
-  fi
-else
-  echo "=== [5/6] telemetry-overhead smoke skipped (--no-smoke) ==="
-  record_gate "telemetry-smoke" skipped 0
-fi
-
-echo "=== [6/6] protocol verification (model check + conformance replay) ==="
+echo "=== [4/4] protocol verification (model check + conformance replay) ==="
 PROTO_ARGS=()
 if [ "$RUN_MATRIX" = 1 ] && [ -d "$MATRIX_DIR" ]; then
   PROTO_ARGS+=(--conformance "$MATRIX_DIR")

@@ -464,14 +464,13 @@ class TestPremergeGateDrift:
         finds = docdrift.check_premerge_gates(self.DOC, "echo hi\n")
         assert [f.symbol for f in finds] == ["<script>"]
 
-    def test_real_script_records_all_six_gates(self):
+    def test_real_script_records_every_gate(self):
         """Every gate in premerge.sh emits a --json record — including
         the clang-tidy skip, which must be VISIBLE, not silent."""
         with open(os.path.join(REPO, "scripts", "premerge.sh")) as f:
             ids = set(re.findall(r'record_gate "([a-z0-9-]+)"', f.read()))
         assert ids == {"analysis", "native-warn", "native-tidy",
-                       "faultmatrix-quick", "profiler-smoke",
-                       "telemetry-smoke", "protocol"}
+                       "faultmatrix-quick", "protocol"}
 
     def test_clean_tree(self):
         finds = [

@@ -162,8 +162,8 @@ class TestWireStageShim:
         assert wire_stage_snapshot()["wire"] == pytest.approx(0.625)
         assert row["phases"].get("wire", 0.0) == pytest.approx(0.125)
 
-    def test_crossgroup_bench_reader_unchanged(self):
-        # the crossgroup bench protocol: reset, run, read per-stage totals
+    def test_wire_stage_snapshot_reset_then_read(self):
+        # a reader's protocol: reset the mark, run, read per-stage totals
         from torchft_tpu.collectives import (
             WIRE_STAGES,
             record_wire_stage,

@@ -515,6 +515,43 @@ class TestManagerE2E:
                 m.shutdown()
             lh.shutdown()
 
+    @pytest.mark.parametrize("world_size", [1, 2])
+    def test_one_lighthouse_rpc_per_group_per_quorum_round(self, world_size):
+        """What the lighthouse serves per step: ONE ``lh.quorum`` call a
+        group a round, however many ranks the group has (the native
+        ``quorum.fanout`` histogram takes one observation per call)."""
+        groups, rounds = 2, 3
+        lh, mgrs = self._setup(n_replicas=groups, world_size=world_size)
+        before = _native.lathist_snapshot()["quorum.fanout"]["count"]
+        try:
+            for step in range(rounds):
+
+                def run(i, rank, step=step):
+                    c = ManagerClient(
+                        mgrs[i].address(), connect_timeout=timedelta(seconds=10)
+                    )
+                    c._quorum(
+                        rank=rank, step=step, checkpoint_metadata="",
+                        shrink_only=False, timeout=timedelta(seconds=10),
+                    )
+                    c.close()
+
+                ts = [
+                    threading.Thread(target=run, args=(i, r))
+                    for i in range(groups)
+                    for r in range(world_size)
+                ]
+                for t in ts:
+                    t.start()
+                for t in ts:
+                    t.join()
+            after = _native.lathist_snapshot()["quorum.fanout"]["count"]
+            assert after - before == groups * rounds
+        finally:
+            for m in mgrs:
+                m.shutdown()
+            lh.shutdown()
+
     def test_should_commit_one_failure_rejects_all(self):
         # world_size=2 ranks on one manager; one False vote fails the round
         # (src/manager.rs:295-347 semantics)

@@ -1,4 +1,4 @@
-"""End-to-end example configs from BASELINE.md: "DiLoCo 4 groups" and the
+"""End-to-end example configs: DiLoCo over 4 groups and the
 HSDP composition, driven as real subprocesses against an in-process
 lighthouse, asserting cross-group state convergence (the reference's
 integ-test bar: state-dict equality across groups)."""
@@ -112,7 +112,7 @@ def test_hsdp_example_two_groups():
 
 
 def test_resnet_cifar_two_groups(tmp_path):
-    """BASELINE.md config: "ResNet-18 CIFAR-10 DDP" — conv model family
+    """ResNet-18 CIFAR-10 DDP over two groups — conv model family
     through the full FT loop, bit-identical params across groups."""
     logs = _run_groups(
         "train_cifar.py",

@@ -162,6 +162,42 @@ def test_diloco_outer_step_descends_toward_inner_progress():
     np.testing.assert_allclose(out["w"], np.full(4, 1.0), atol=1e-6)
 
 
+class _CountingManager(_StubManager):
+    """Counts the bytes that cross the replica axis."""
+
+    def __init__(self, commits):
+        super().__init__(commits)
+        self.bytes = 0
+
+    def allreduce_many(self, arrays):
+        self.bytes += sum(int(a.nbytes) for a in arrays)
+        return super().allreduce_many(arrays)
+
+
+@pytest.mark.parametrize("mode", ["local_sgd", "diloco"])
+def test_one_exchange_of_the_tree_per_sync_every_steps(mode):
+    """The communication these wrappers exist to save: over K * H local
+    steps the groups exchange K times, each time the parameter tree's
+    bytes once (f32), and vote K times — nothing crosses in between."""
+    every, syncs = 4, 3
+    manager = _CountingManager([True] * syncs)
+    if mode == "local_sgd":
+        wrapper = LocalSGD(manager, sync_every=every)
+    else:
+        wrapper = DiLoCo(manager, optax.sgd(0.7), sync_every=every)
+    params = {
+        "w": np.ones((8, 16), dtype=np.float32),
+        "b": np.ones(16, dtype=np.float32),
+    }
+    tree_bytes = sum(v.nbytes for v in params.values())
+    wrapper.save(params)
+    for i in range(every * syncs):
+        params = wrapper.step(params)
+        done = (i + 1) // every
+        assert manager.bytes == done * tree_bytes
+        assert len(manager._commits) == syncs - done
+
+
 def test_local_sgd_backup_does_not_alias_live_params():
     """Rollback safety: after a committed sync the caller keeps training
     (possibly in place) on the returned params; a later failed commit must

@@ -3,8 +3,8 @@
 per-rank CollectivesTcp — the torchrun-per-group analogue
 (/root/reference/torchft/torchx.py:11-76) with jax.distributed instead of
 torch.distributed. Two groups x two processes, full FT loop, asserting
-cross-group state convergence (the BASELINE.md v5e-32 north-star shape:
-replica groups that span hosts)."""
+cross-group state convergence (the v5e-32 north-star shape: replica
+groups that span hosts)."""
 
 import os
 import re
@@ -128,7 +128,7 @@ def _kill_respawn_attempt(workdir) -> None:
 
 
 def test_multihost_group_kill_respawn_heal(tmp_path):
-    """The north-star scenario (BASELINE.md): replica groups spanning
+    """The north-star scenario: replica groups spanning
     processes, one group SIGKILLed mid-run. The launcher tears down and
     respawns the whole group (fresh store + fresh jax coordinator — a
     multi-controller runtime cannot lose a member and live, so groups

@@ -21,7 +21,7 @@ Five layers, mirroring the package:
 * **the trace→schedule compiler + CLI** — checker traces lower into the
   faultinject grammar deterministically (the shipped
   ``faultinject/compiled/`` descriptors are pinned regenerable), and
-  ``python -m torchft_tpu.analysis.protocol`` is premerge gate [6] with
+  ``python -m torchft_tpu.analysis.protocol`` is premerge gate [4] with
   its exit-code contract pinned here.
 """
 
@@ -612,7 +612,7 @@ class TestTraceRoundTrip:
 
 
 # ---------------------------------------------------------------------------
-# CLI (premerge gate [6])
+# CLI (premerge gate [4])
 # ---------------------------------------------------------------------------
 
 

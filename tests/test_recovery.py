@@ -1,7 +1,7 @@
 """Recovery-envelope test: the wall-clock bound the reference encodes in
 assertions (lighthouse_test.py:44-47 quorum < 0.4s; manager_integ_test.py:
 325-368 deadline enforcement < 1s) — here measured on the full kill/heal
-path with real process kills (torchft_tpu/benchmarks/recovery.py).
+path with real process kills (torchft_tpu/faultinject/recovery.py).
 
 Bounds are deliberately loose multiples of the configured detection
 cadence (1s op timeout, 1s heartbeat lease) so the test is about the
@@ -11,7 +11,7 @@ luck.
 
 import pytest
 
-from torchft_tpu.benchmarks.recovery import measure_recovery
+from torchft_tpu.faultinject.recovery import measure_recovery
 
 # multi-process soak tier: excluded from the default run (pyproject
 # addopts); execute with `pytest -m soak`
@@ -38,7 +38,7 @@ def test_recovery_envelope():
 
 
 def test_recovery_1of4_north_star_shape():
-    """BASELINE north star: survive killing 1-of-4 replica groups. The
+    """The north star: survive killing 1-of-4 replica groups. The
     three survivors must keep committing through the blackout and the
     victim must rejoin and commit."""
     r = measure_recovery(
@@ -58,7 +58,7 @@ def test_recovery_1of4_one_step_envelope():
     """Round-4: with the death watch (socket-FIN-driven evict + early
     re-quorum overlapping the doomed step), killing 1-of-4 groups must
     cost the survivors at most ONE committed step (the reference's
-    product promise, README.md:29-47). The bench box can be contended,
+    product promise, README.md:29-47). The box can be contended,
     so one retry is allowed — but it is LOGGED and every run's envelope
     lands in the failure message, so a silently-degrading envelope shows
     up as retry noise in CI history instead of being masked (round-4

@@ -1,4 +1,4 @@
-"""ResNet-18 model family unit tests (BASELINE.md CIFAR-10 config;
+"""ResNet-18 model family unit tests (the CIFAR-10 DDP config;
 reference train_ddp.py:34-80 trains the torchvision equivalent)."""
 
 import jax

@@ -482,7 +482,7 @@ def test_kill_one_replica_trail_records_death_then_heal():
     with no one to heal from (no heal_end in the trail, by design)."""
     import warnings
 
-    from torchft_tpu.benchmarks.recovery import measure_recovery
+    from torchft_tpu.faultinject.recovery import measure_recovery
 
     for attempt in range(2):
         r = measure_recovery(

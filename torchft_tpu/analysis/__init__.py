@@ -22,7 +22,7 @@ Four analyzers:
 The FT-protocol verification plane (executable spec + bounded model
 checker + trace conformance) lives in
 :mod:`~torchft_tpu.analysis.protocol` with its own CLI
-(``python -m torchft_tpu.analysis.protocol``, premerge gate [6]).
+(``python -m torchft_tpu.analysis.protocol``, premerge gate [4]).
 
 See ``docs/static_analysis.md`` for the rule catalog and the baseline
 workflow.

@@ -1,6 +1,6 @@
 """CLI: ``python -m torchft_tpu.analysis.protocol``.
 
-Two halves, one exit code (premerge gate [5]):
+Two halves, one exit code (premerge gate [4]):
 
 * **model check** (default) — exhaustively explore every gate
   configuration (:data:`~torchft_tpu.analysis.protocol.checker.GATE_CONFIGS`)

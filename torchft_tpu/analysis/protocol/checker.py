@@ -368,7 +368,7 @@ def check(
     return res
 
 
-# The repo-gate configurations (premerge gate [6] + tier-1 wrapper):
+# The repo-gate configurations (premerge gate [4] + tier-1 wrapper):
 # every one of these must come back clean. The broken variants live in
 # tests/fixtures/analysis/ as seeded fixtures, not here.
 GATE_CONFIGS: Dict[str, SpecConfig] = {

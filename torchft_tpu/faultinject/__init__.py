@@ -6,7 +6,9 @@ Python layers' injection points consult it through
 ``faultinject.runner`` drives the 2-group example trainer through a
 scenario matrix (mid-op kills per data plane, torn CMA pulls, delayed
 commit votes, checkpoint-serve death) and asserts the end-to-end safety
-invariant — no committed step may carry corrupt averages. See
+invariant — no committed step may carry corrupt averages;
+``faultinject.recovery`` is the kill harness (SIGKILL one of N numpy
+groups, respawn it, blackout and rejoin bookkeeping). See
 ``docs/fault_injection.md``.
 """
 

@@ -1,20 +1,21 @@
-"""Recovery wall-clock measurement: kill one of two replica groups, time
-the survivor's blackout and the rejoiner's time-to-first-commit.
+"""Kill harness: SIGKILL one of N replica groups, respawn it, and keep the
+books on the survivor's blackout and the rejoiner's time to first commit.
 
-BASELINE.md names "quorum-recovery wall-clock after killing 1 replica
-group" as the driver metric and "re-quorum in < 1 step" as the north star;
-the reference never measures it (its envelope lives in test assertions,
+The product's claim is "lose at most one step when a group dies"; the
+reference never measures it (its envelope lives in test assertions,
 lighthouse_test.py:44-47, manager_integ_test.py:325-368). This harness
-measures it for real, with real process kills:
+exercises it with real process kills, for the soak tests
+(tests/test_recovery.py, tests/test_telemetry.py); the benchmark's kill
+cell (ROADMAP S6/R3) will take its bookkeeping from here:
 
-* two replica groups run as **subprocesses** (numpy data plane over
-  ``CollectivesTcp`` — hardware-independent; the TPU stays free for the
-  throughput bench in the parent),
-* at a chosen step, group 1 takes SIGKILL (no cleanup, no goodbye — its
-  manager server and heartbeats die with it),
-* group 1 is respawned fresh and heals from the survivor.
+* the replica groups run as **subprocesses** (numpy data plane over
+  ``CollectivesTcp`` — hardware-independent, jax-free workers),
+* at a chosen step, the last group takes SIGKILL (no cleanup, no goodbye —
+  its manager server and heartbeats die with it),
+* it is respawned fresh and heals from a survivor.
 
-Reported numbers (seconds, wall-clock):
+Bookkeeping (seconds on this host's clock — a CPU run: counts and
+ordering are the result, the seconds are only bounded loosely by tests):
 
 * ``survivor_blackout_s`` — last commit before the kill → first commit
   after it, on the surviving group. Covers dead-peer detection (socket
@@ -24,7 +25,7 @@ Reported numbers (seconds, wall-clock):
   step, covering store bootstrap, quorum join, live checkpoint heal, and
   one training step.
 * ``steady_step_s`` — median healthy step time, so the blackout can be
-  read in reference units ("< N steps").
+  read in step units (``survivor_steps_lost``, ``blackout_steps``).
 
 The detection cadence is configurable; the defaults here use aggressive
 1 s leases (the reference's defaults — 5 s heartbeat timeout, 60 s op
@@ -47,7 +48,7 @@ __all__ = ["measure_recovery", "RecoveryResult"]
 
 
 # ---------------------------------------------------------------------------
-# worker (subprocess entry: python -m torchft_tpu.benchmarks.recovery)
+# worker (subprocess entry: python -m torchft_tpu.faultinject.recovery)
 # ---------------------------------------------------------------------------
 
 
@@ -59,8 +60,8 @@ def _emit(log, **event) -> None:
 
 def _worker() -> None:
     """Numpy-only FT training loop; commits are timestamped to the event
-    log. Deliberately jax-free so killing it never disturbs the
-    accelerator held by the parent bench process."""
+    log. Deliberately jax-free so killing it never disturbs an
+    accelerator held by the parent process."""
     from datetime import timedelta
 
     import numpy as np
@@ -133,7 +134,7 @@ def _worker() -> None:
             except TimeoutError as e:
                 # a loaded host can blow the aggressive 1 s deadlines past
                 # even the quorum timeout; a real trainer retries the step
-                # rather than crashing — so does the bench worker (the
+                # rather than crashing — so does this worker (the
                 # orchestrator's own deadline still bounds a true wedge)
                 _emit(log, event="timeout_retry", gid=gid, err=str(e)[:120])
                 continue
@@ -155,9 +156,9 @@ def _worker() -> None:
                     pid=os.getpid(),
                 )
     finally:
-        # rejoin-SLO + heal-stage attribution for the bench row (ISSUE 9):
-        # the orchestrator reads these from the rejoiner's log so the
-        # envelope numbers come with their per-stage explanation
+        # rejoin-SLO + heal-stage attribution (ISSUE 9): the orchestrator
+        # reads these from the rejoiner's log so the envelope numbers
+        # come with their per-stage explanation
         try:
             from torchft_tpu import telemetry
 
@@ -267,7 +268,7 @@ def _spawn(
         stderr_f = subprocess.DEVNULL
     try:
         proc = subprocess.Popen(
-            [sys.executable, "-m", "torchft_tpu.benchmarks.recovery"],
+            [sys.executable, "-m", "torchft_tpu.faultinject.recovery"],
             env=env,
             stdout=subprocess.DEVNULL,
             stderr=stderr_f,
@@ -297,7 +298,7 @@ def _wait_for(path: str, pred, timeout_s: float, procs=()) -> Dict:
             if p.poll() not in (None, 0):
                 raise RuntimeError(f"worker died early (rc={p.poll()})")
         time.sleep(0.02)
-    raise TimeoutError("recovery bench: expected event never arrived")
+    raise TimeoutError("recovery harness: expected event never arrived")
 
 
 def measure_recovery(
@@ -311,8 +312,8 @@ def measure_recovery(
     rejoin_slo_s: float = 1.0,
 ) -> RecoveryResult:
     """Kill 1 of ``num_groups`` replica groups and measure the envelope
-    (``num_groups=4`` is the BASELINE north-star shape: survive killing
-    1-of-4 and re-quorum in < 1 step)."""
+    (``num_groups=4`` is the north-star shape: survive killing 1-of-4
+    and re-quorum in < 1 step)."""
     from torchft_tpu.coordination import LighthouseServer
 
     victim_gid = num_groups - 1
@@ -337,7 +338,7 @@ def measure_recovery(
         # hang forensics land next to the trails (flight dumps per pid)
         "TORCHFT_FLIGHT_DIR": tmp,
         # rejoin-to-commit SLO (telemetry/slo.py BurnRateSlo): the
-        # rejoiner's Manager evaluates it live; the bench row reports the
+        # rejoiner's Manager evaluates it live; the result reports the
         # latch state next to the measured wall-clock
         "TORCHFT_SLO_REJOIN_S": str(rejoin_slo_s),
     }
@@ -482,7 +483,4 @@ def measure_recovery(
 
 
 if __name__ == "__main__":
-    if "TORCHFT_EVENT_LOG" in os.environ:
-        _worker()
-    else:
-        print(json.dumps(measure_recovery().as_dict()))
+    _worker()

@@ -1,7 +1,7 @@
 """ResNet-18 (CIFAR variant) — the conv model family.
 
 The reference's flagship real-data config is "ResNet-18 CIFAR-10 DDP with
-kill/rejoin" (BASELINE.md config list; reference train_ddp.py:34-80 trains
+kill/rejoin" (reference train_ddp.py:34-80 trains
 it through torchvision). TPU-native rebuild: pure-JAX pytree params in
 NHWC layout (the TPU conv-friendly layout — XLA lowers NHWC convs onto
 the MXU without transposes), functional batch norm whose running stats

@@ -100,7 +100,7 @@ class TransformerConfig:
 
 
 # Named shapes (``TransformerConfig(**PRESETS[name])``), one definition for
-# examples/train_hsdp.py (MODEL=...), the benchmarks and chip_smoke.py.
+# examples/train_hsdp.py (MODEL=...) and chip_smoke.py.
 PRESETS: Dict[str, Dict[str, Any]] = {
     # CPU-mesh testable
     "tiny": dict(
@@ -113,12 +113,6 @@ PRESETS: Dict[str, Dict[str, Any]] = {
     "scale_647M": dict(
         vocab_size=32000, d_model=2048, n_layers=12, n_heads=16,
         head_dim=64, d_ff=5632, dtype=jnp.bfloat16, remat=False,
-    ),
-    # Llama-2-7B shape (BASELINE.md north-star config); needs fsdp>=8
-    # per group on v5e for params+optimizer. Never run (ROADMAP R7)
-    "llama2-7b": dict(
-        vocab_size=32000, d_model=4096, n_layers=32, n_heads=32,
-        head_dim=128, d_ff=11008, dtype=jnp.bfloat16,
     ),
     # OLMoE-1B-7B-0125-Instruct as published (6.9B parameters: needs a
     # sharded group); the benchmark's olmoe-1g runs one of its 16 layers

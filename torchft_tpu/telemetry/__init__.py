@@ -296,9 +296,8 @@ STEP_LOCAL_SECONDS = REGISTRY.histogram(
 # channel. `piggyback` = delta/JSON blobs attached to quorum RPCs,
 # `spans` = chrome-trace fragments riding the same RPC; the lighthouse
 # meters its own `scrape` channel (HTTP bodies served) as the native
-# torchft_telemetry_bytes_total counterpart. The budget gate
-# (benchmarks/telemetry_overhead.py) keys off the step-rate delta, but
-# this counter is what tells you WHERE an overhead regression lives.
+# torchft_telemetry_bytes_total counterpart: this counter is what tells
+# you WHERE an overhead regression lives.
 TELEMETRY_BYTES = REGISTRY.counter(
     "tft_telemetry_bytes_total",
     "Bytes moved by the telemetry plane itself, by channel "
@@ -490,7 +489,7 @@ def dump(lighthouse_addr: Optional[str] = None) -> Dict[str, Any]:
 
 
 def summary() -> Dict[str, Any]:
-    """Compact FT/perf digest for bench rows: one flat dict instead of the
+    """Compact FT/perf digest: one flat dict instead of the
     full exposition (quorum count, heal count, allreduce traffic, and a
     step-duration histogram summary by kind)."""
     step: Dict[str, Any] = {}

@@ -25,7 +25,7 @@ Two accounting views, one mechanism:
   thread and cannot be part of a wall-clock decomposition;
 * **wire-stage totals** keep PR 6's semantics byte-for-byte: every
   ``record_wire_stage`` call (either thread) accumulates into the
-  process-cumulative per-stage totals the crossgroup bench reads via
+  process-cumulative per-stage totals read via
   ``collectives.wire_stage_snapshot`` — the shim's old private dict is
   gone; this ledger is the one source of truth.
 
@@ -330,8 +330,8 @@ class StepLedger:
         """Exact interpolated percentile of a value list (the summary's
         quantiles come from the retained step rows, not the log2-bucket
         histograms — one bucket per octave is fine for Prometheus but its
-        ±50% quantile resolution would swamp the bench row's 5%
-        phase-sum-vs-wall reconciliation)."""
+        ±50% quantile resolution is too coarse for a per-phase
+        digest)."""
         if not values:
             return 0.0
         vs = sorted(values)
@@ -380,7 +380,7 @@ class StepLedger:
             return []
 
     def summary(self) -> Dict[str, Any]:
-        """Compact per-phase digest for piggybacks and bench rows:
+        """Compact per-phase digest for piggybacks and dumps:
         per-phase p50/p99/cumulative seconds, wall/local p50s, step count
         and the tagged-outlier digest. Quantiles are EXACT percentiles
         over the retained row window (see :meth:`_percentile`); every

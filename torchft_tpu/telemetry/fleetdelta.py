@@ -2,10 +2,10 @@
 
 Before this module, every replica re-shipped its FULL JSON telemetry
 digest (summary + anatomy + series) on every quorum RPC — ~4-10 KB per
-step per replica, all of it landing on the one lighthouse whose quorum
-fan-out is already superlinear at 256 groups (the ``quorum_scale``
-evidence). Steady state is almost entirely redundant: between two steps
-a handful of counters increment and one or two histogram buckets move.
+step per replica, all of it landing on the one lighthouse that also
+serves every group's quorum. Steady state is almost entirely
+redundant: between two steps a handful of counters increment and one or
+two histogram buckets move.
 This module makes the piggyback proportional to what CHANGED, not to
 what EXISTS:
 
@@ -125,7 +125,7 @@ _I64_MAX = (1 << 63) - 1
 
 def delta_enabled() -> bool:
     """``TORCHFT_TELEMETRY_DELTA=0`` falls back to the legacy full-JSON
-    piggyback (also the ``quorum_scale`` contrast leg)."""
+    piggyback."""
     return os.environ.get("TORCHFT_TELEMETRY_DELTA", "1") != "0"
 
 

@@ -1,11 +1,12 @@
 """Wire codecs + error feedback — the compression layer of the cross-group
 gradient plane (docs/wire_plane.md).
 
-The cross-group average is wire-bound (BENCH_r05: 0.144 GB/s serial /
-0.609 GB/s pipelined on the host plane — a derived ~44 s per llama2-7B
-f32 gradient tree), so the wire carries QUANTIZED bytes while local
-accumulation stays f32. A codec maps an f32 chunk to its wire form and
-back:
+The cross-group average is bound by bytes moved on the host (at four
+groups the ring takes 1.18 s for 2.43 GB of f32 gradients a group, of a
+2.13 s step: ledger, PR 29, ``exchange_ring_s``), so the wire can carry
+QUANTIZED bytes while local accumulation stays f32; what a codec gains
+is not measured on the chip (no cell selects one). A codec maps an f32
+chunk to its wire form and back:
 
 * ``f32``      — identity (4 bytes/elem), the exact default.
 * ``bfloat16`` — round-to-nearest-even truncation (2 bytes/elem).
