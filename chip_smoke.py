@@ -414,10 +414,14 @@ def steady(
 # ---------------------------------------------------------------------------
 
 
-def kernels_child(platform: str, flash_shapes: list, chunked_shape: list) -> None:
-    """Runs in the child. Flash forward+backward per (b, s, h, d), and
-    chunked attention at the smoke's shape, in bf16 against ops.attention
-    in f32 (matmuls at highest precision)."""
+def kernels_child(
+    platform: str, flash_shapes: list, chunked_shape: list, cells_shape: list
+) -> None:
+    """Runs in the child. Flash forward+backward per (b, s, h, d) at its
+    default blocks and, at the benchmark cells' sequence and heads, at the
+    blocks ``attention_impl`` "auto" picks there; chunked attention at the
+    smoke's shape; all in bf16 against ops.attention in f32 (matmuls at
+    highest precision)."""
     from torchft_tpu.utils.compile_cache import place_compile_cache
 
     place_compile_cache()
@@ -425,6 +429,7 @@ def kernels_child(platform: str, flash_shapes: list, chunked_shape: list) -> Non
     import jax.numpy as jnp
     import numpy as np
 
+    from torchft_tpu.models.transformer import _flash_blocks
     from torchft_tpu.ops.attention import attention, chunked_attention
     from torchft_tpu.ops.pallas.flash_attention import flash_attention
 
@@ -484,6 +489,14 @@ def kernels_child(platform: str, flash_shapes: list, chunked_shape: list) -> Non
         )
         for shape in flash_shapes
     ]
+    block_q, block_k = _flash_blocks(cells_shape[1], cells_shape[3]) or (128, 128)
+    oks.append(check(
+        "flash_cells",
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=block_q, block_k=block_k
+        ),
+        cells_shape, must_be_mosaic=platform == "tpu",
+    ))
     oks.append(check(
         "chunked",
         lambda q, k, v: chunked_attention(q, k, v, causal=True, chunk=128),
@@ -496,14 +509,18 @@ def kernels(
     platform: str,
     flash_shapes: Optional[list] = None,
     chunked_shape: Optional[list] = None,
+    cells_shape: Optional[list] = None,
     timeout: float = 600,
 ) -> List[Dict[str, Any]]:
     # head_dim 64 and 128 are the two the presets use; S >= 2048
     flash_shapes = flash_shapes or [[1, 2048, 4, 64], [1, 2048, 4, 128]]
     chunked_shape = chunked_shape or [BATCH, SEQ, 16, 64]  # scale_647M's
+    # the benchmark cells' s2048 x 16 heads x 128 (b8 there; 2 keeps the
+    # f32 reference's [B,H,S,S] scores at 0.5 GB)
+    cells_shape = cells_shape or [2, 2048, 16, 128]
     code = (
         "import chip_smoke; chip_smoke.kernels_child("
-        f"{platform!r}, {flash_shapes!r}, {chunked_shape!r})"
+        f"{platform!r}, {flash_shapes!r}, {chunked_shape!r}, {cells_shape!r})"
     )
     try:
         text = run_child("3_kernels", [sys.executable, "-c", code], _child_env(), timeout)

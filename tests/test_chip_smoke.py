@@ -227,8 +227,8 @@ def test_rehearse_probe_steady_cache_and_placement(smoke, monkeypatch, tmp_path)
 
 @pytest.mark.slow
 def test_rehearse_kernels(smoke):
-    checks = smoke.kernels("cpu", [[1, 256, 2, 64]], [2, 256, 2, 64])
-    assert [c["check"] for c in checks] == ["flash_d64", "chunked"]
+    checks = smoke.kernels("cpu", [[1, 256, 2, 64]], [2, 256, 2, 64], [1, 256, 2, 128])
+    assert [c["check"] for c in checks] == ["flash_d64", "flash_cells", "chunked"]
     assert all(c["ok"] and not c["mosaic_custom_call"] for c in checks)
 
 

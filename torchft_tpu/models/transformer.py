@@ -20,6 +20,7 @@ HBM both scale O(1) in depth.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -80,13 +81,14 @@ class TransformerConfig:
     remat_policy: str = "all"
     pp: int = 1  # pipeline stages; n_layers % pp == 0
     microbatches: int = 0  # 0 => = pp
-    # "auto" | "plain" | "chunked" | "flash". auto: plain XLA attention at
-    # short S (it wins there), tiered chunked-scan attention
-    # (ops/attention.chunked_attention, pure XLA) from s>=4096 — the
-    # HBM-bandwidth path that took s=8192 from 15% to ~31% MFU on v5e and
-    # makes s=32k single-chip viable; the pallas flash kernel engages only
-    # for an explicit "flash" or past the scores-memory ceiling when
-    # chunked can't run (S not divisible by the chunk)
+    # "auto" | "plain" | "chunked" | "flash". auto picks from what it can
+    # observe (``_attention_path``): ring attention under sp > 1; on a TPU,
+    # outside a manual region, at head_dim % 128 == 0 and s % 512 == 0 from
+    # s1024 on the Pallas flash kernel at 512 x 512 tiles (the fastest core
+    # measured there, PERF.md §6 PR 31); else the tiered chunked scan
+    # (ops/attention.chunked_attention, pure XLA) from s >= 1024 and plain
+    # XLA attention below; the kernel again, for memory's sake, past the
+    # scores-memory ceiling where chunked cannot run
     attention_impl: str = "auto"
 
     @property
@@ -274,15 +276,11 @@ def _ffn_moe_ep(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig) -> j
 
 
 def _flash_threshold_bytes() -> float:
-    """Scores-memory ceiling above which auto engages the pallas kernel.
-
-    When the materialized [B,H,S,S] scores exceed this, XLA's plain
-    attention stops fitting HBM and the pallas kernel's O(S·block) memory
-    becomes the only option. Below it, plain is strictly faster — a
-    controlled plain-vs-flash comparison measured 46x at b1 h8 s8192 on
-    v5e (the round-2 "flash at s>=8192" rule was costing auto users
-    exactly that). Override via TORCHFT_TPU_FLASH_SCORES_GB for chips
-    with a different HBM budget."""
+    """Scores-memory ceiling above which auto engages the pallas kernel
+    whatever its speed: when the materialized [B,H,S,S] scores exceed
+    this, XLA's plain attention stops fitting HBM and the kernel's
+    O(S·block) memory is the only option. Override via
+    TORCHFT_TPU_FLASH_SCORES_GB for chips with a different HBM budget."""
     import os
 
     raw = os.environ.get("TORCHFT_TPU_FLASH_SCORES_GB", "4")
@@ -300,6 +298,9 @@ def _flash_threshold_bytes() -> float:
 def _use_flash(
     cfg: TransformerConfig, seq_len: int, batch: int = 1, mesh=None
 ) -> bool:
+    """The Pallas kernel as the MEMORY path: asked for by name, or (auto)
+    because plain attention's scores would not fit. The speed rule is
+    :func:`_flash_blocks`, which :func:`_attention_path` asks first."""
     if cfg.attention_impl in ("plain", "chunked"):
         return False
     if cfg.attention_impl == "flash":
@@ -309,11 +310,9 @@ def _use_flash(
             "attention_impl must be 'auto'|'plain'|'chunked'|'flash', "
             f"got {cfg.attention_impl!r}"
         )
-    # auto: engage the pallas kernel only when plain attention's scores
-    # would blow PER-CHIP HBM — it is the memory-ceiling path, never the
-    # speed path. The estimate divides the global shapes by the mesh's
-    # batch (dp·fsdp) and head (tp) factors, and uses 4 bytes/element:
-    # plain attention's softmax runs in f32 whatever the compute dtype.
+    # The estimate divides the global shapes by the mesh's batch (dp·fsdp)
+    # and head (tp) factors, and uses 4 bytes/element: plain attention's
+    # softmax runs in f32 whatever the compute dtype.
     itemsize = max(jnp.dtype(cfg.dtype).itemsize, 4)
     batch_shards = heads_shards = 1
     if mesh is not None:
@@ -333,17 +332,30 @@ def _use_flash(
     )
 
 
+def _flash_blocks(seq_len: int, head_dim: int) -> Optional[Tuple[int, int]]:
+    """(block_q, block_k) at which the Pallas kernel is the fastest causal
+    core measured on a v5e, or None where plain / chunked keep the shape.
+
+    Measured (my chip run, PR 31; PERF.md §6 holds the table) at 16 heads x
+    128, forward + ``remat``'s forward + backward, device time of the whole
+    call: b8 x s2048 8.3 ms a layer at 512 x 512 against 16.3 ms chunked and
+    22.2 plain (1024 x 1024: 8.8; 256 x 512: 11.6; 128 x 128: 24.4);
+    b4 x s4096 13.8 against 25.5; b8 x s1024 3.1 against 4.9. At head_dim
+    64 (b4 x s1024 x 16: ``scale_647M``) the kernel ties chunked (1.25
+    against 1.28 ms) and a head is then no whole lane tile (a transpose a
+    side): declined. Below s1024 nothing was measured: plain keeps it."""
+    if head_dim % 128 or seq_len % 512 or seq_len < 1024:
+        return None
+    return 512, 512
+
+
 def _attn_chunk(seq_len: int) -> int:
-    """Sequence-aware q-block size; TORCHFT_TPU_ATTN_CHUNK overrides
-    (env-overridable, like every other knob in this file — an
-    unparseable value is IGNORED, not treated as an override).
-    Round-5 v5e sweep (full-model grads / FT-loop steps, d512 L8): C=128
-    beats 256 by ~7% at s=8k and ~15% at s=32k (1046 vs 1241 ms with 16
-    tiers) and is within noise at 1k-2k — smaller q-blocks keep the
-    per-block f32 scores fusion-local deeper into the causal prefix.
-    s=16k is the measured exception: C=256 with 16 tiers runs +6%
-    (3.52 vs 3.33 steps/s, reproduced fresh-process) — at 1k-row
-    segments the halved scan trip count beats the smaller working set."""
+    """Sequence-aware q-block size of :func:`chunked_attention`;
+    TORCHFT_TPU_ATTN_CHUNK overrides (an unparseable value is IGNORED, not
+    treated as an override). 128 everywhere but s=16k (256), from sweeps of
+    a d512 / head_dim 64 model on a v5e before the benchmark existed; not
+    measured at the cells' widths, where "auto" no longer takes this path
+    on a TPU (PERF.md §6, PR 31)."""
     import os
 
     raw = os.environ.get("TORCHFT_TPU_ATTN_CHUNK")
@@ -370,16 +382,13 @@ def _attn_tiers() -> Optional[int]:
 
 
 def _use_chunked(cfg: TransformerConfig, seq_len: int) -> bool:
-    """Route to :func:`chunked_attention` (round-3 review missing #4: the
-    4k–16k band sat at 15% MFU on XLA plain attention with no mitigation).
-    Round-5 sweep moved the engage point down to 1024: even there plain
-    attention's f32 [S,S] scores round-trip HBM (full-model grads at the
-    d512/L8/b8/s1024 headline: 52 ms plain vs 41–44 ms chunked; s=2048:
-    133 vs 92; s=512 is a wash, so plain keeps its simpler compile below
-    1k). Pure XLA — works under GSPMD sharding AND inside the pipeline's
-    manual region, unlike the pallas kernel. Override the engage point
-    with TORCHFT_TPU_ATTN_CHUNKED_MIN_S. Sequences not divisible by the
-    chunk fall back to plain (both explicit and auto)."""
+    """Route to :func:`chunked_attention`: by name, or (auto) from s = 1024
+    on, where plain attention's f32 [S,S] scores round-trip HBM. Pure XLA —
+    works under GSPMD sharding AND inside the pipeline's manual region,
+    unlike the pallas kernel, so it is what "auto" takes there, on a CPU,
+    and at the shapes :func:`_flash_blocks` declines. Override the engage
+    point with TORCHFT_TPU_ATTN_CHUNKED_MIN_S. Sequences not divisible by
+    the chunk fall back to plain (both explicit and auto)."""
     if seq_len % _attn_chunk(seq_len) != 0:
         return False
     if cfg.attention_impl == "chunked":
@@ -395,18 +404,95 @@ def _use_chunked(cfg: TransformerConfig, seq_len: int) -> bool:
     return seq_len >= min_s
 
 
-def _flash_sharded(q, k, v, mesh):
+def _attention_path(
+    cfg: TransformerConfig, seq_len: int, batch: int, mesh, sp_manual: bool = False
+) -> Tuple[str, str, Optional[Tuple[int, int]]]:
+    """(impl, reason, (block_q, block_k) or None): which code computes the
+    causal core softmax(QKᵀ)V of a layer, decided from what can be
+    observed — the backend, the mesh, whether the caller is already inside
+    a manual region, and the shapes. impl is "ring" (sp > 1), "flash" (the
+    Pallas kernel), "chunked" or "plain"."""
+    sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    if sp_size > 1:
+        return "ring", "sp > 1: the sequence is sharded over chips", None
+    # the kernel needs its own (full) manual region, which cannot nest in
+    # the pipeline's partial-manual shard_map (Shardy rejects it)
+    inside_manual = sp_manual or (mesh is not None and mesh.shape.get("pp", 1) > 1)
+    fast = _flash_blocks(seq_len, cfg.head_dim)
+    if (
+        cfg.attention_impl == "auto"
+        and fast is not None
+        and jax.default_backend() == "tpu"
+        and not inside_manual
+    ):
+        return "flash", "auto on a tpu: the fastest core measured at this (seq, head_dim)", fast
+    if _use_chunked(cfg, seq_len):
+        why = "attention_impl" if cfg.attention_impl == "chunked" else (
+            "auto: seq past the chunked engage point"
+        )
+        return "chunked", why, None
+    if _use_flash(cfg, seq_len, batch, mesh):
+        if inside_manual:
+            # no fallback: flash was picked because plain attention's
+            # scores cannot fit either — pp>1 long-context should shard the
+            # sequence (sp), which routes to ring attention above
+            raise ValueError(
+                f"flash attention (attention_impl={cfg.attention_impl!r}, "
+                f"b{batch} s{seq_len}) cannot run inside the pipeline's manual "
+                "region (pp>1); shard the sequence (sp>1, ring "
+                "attention) for long context under pp"
+            )
+        why = "attention_impl" if cfg.attention_impl == "flash" else (
+            "auto: plain attention's scores would not fit the chip"
+        )
+        return "flash", why, fast or (128, 128)  # the kernel clamps a tile to S
+    why = {
+        "plain": "attention_impl",
+        "chunked": "seq is not a multiple of the chunk",
+    }.get(cfg.attention_impl, "auto: a short sequence, or no multiple of the chunk")
+    return "plain", why, None
+
+
+_PATHS_SAID: set = set()
+
+
+def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg) -> None:
+    """One ``attention_path`` event and one INFO line per traced shape, so a
+    worker's log and event trail say which core every program took and why."""
+    block_q, block_k = blocks or (0, 0)
+    fields = dict(
+        impl=impl, block_q=block_q, block_k=block_k, batch=batch, seq=seq_len,
+        head_dim=cfg.head_dim, reason=reason,
+    )
+    key = (*fields.values(), cfg.n_heads)
+    if key in _PATHS_SAID:
+        return
+    _PATHS_SAID.add(key)
+    import logging
+
+    from torchft_tpu import telemetry
+
+    telemetry.emit("attention_path", **fields)
+    logging.getLogger(__name__).info(
+        "attention_path %s", " ".join(f"{k}={v}" for k, v in fields.items())
+    )
+
+
+def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int]):
     """Flash attention under GSPMD: pallas_call has no partitioning rules,
     so without shard_map the SPMD partitioner would all-gather q/k/v onto
     every chip. Attention is independent per (batch, head), so manualize
     the batch/head axes and run the kernel per shard."""
     from torchft_tpu.ops.pallas.flash_attention import flash_attention
 
+    kernel = functools.partial(
+        flash_attention, causal=True, block_q=blocks[0], block_k=blocks[1]
+    )
     if mesh is None:
-        return flash_attention(q, k, v, causal=True)
+        return kernel(q, k, v)
     spec = P(("dp", "fsdp"), None, "tp", None)
     return jax.shard_map(
-        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        kernel,
         in_specs=(spec, spec, spec),
         out_specs=spec,
         # ALL mesh axes must be manual here: any axis left auto keeps the
@@ -455,35 +541,24 @@ def _make_layer_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
             v = (h @ lp["wv"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
             q = rotary_embed(q, positions, cfg.rope_theta)
             k = rotary_embed(k, positions, cfg.rope_theta)
-            if sp_size > 1 and sp_manual:
-                att = ring_attention_local(q, k, v, sp_size, causal=True)
-            elif sp_size > 1:
-                att = ring_attention(q, k, v, mesh, causal=True)
-            elif _use_chunked(cfg, s):
-                att = chunked_attention(
-                    q, k, v, causal=True, chunk=_attn_chunk(s),
-                    tiers=_attn_tiers(),
-                )
-            elif _use_flash(cfg, s, b, mesh):
-                # flash needs its own (full) manual region, which can't nest
-                # inside the pipeline's partial-manual shard_map (Shardy rejects
-                # nested manual regions) — pp>1 long-context should shard the
-                # sequence (sp), which routes to ring attention above
-                inside_manual = sp_manual or (
-                    mesh is not None and mesh.shape.get("pp", 1) > 1
-                )
-                if inside_manual:
-                    # no fallback: flash was picked because plain attention's
-                    # scores cannot fit either
-                    raise ValueError(
-                        f"flash attention (attention_impl={cfg.attention_impl!r}, "
-                        f"b{b} s{s}) cannot run inside the pipeline's manual "
-                        "region (pp>1); shard the sequence (sp>1, ring "
-                        "attention) for long context under pp"
+            # s is the sp-local block inside a manual region; the rule
+            # reads sp from the mesh, not from s
+            impl, why, blocks = _attention_path(cfg, s, b, mesh, sp_manual)
+            _say_attention_path(impl, why, blocks, b, s, cfg)
+            with jax.named_scope("core"):
+                if impl == "ring" and sp_manual:
+                    att = ring_attention_local(q, k, v, sp_size, causal=True)
+                elif impl == "ring":
+                    att = ring_attention(q, k, v, mesh, causal=True)
+                elif impl == "flash":
+                    att = _flash_sharded(q, k, v, mesh, blocks)
+                elif impl == "chunked":
+                    att = chunked_attention(
+                        q, k, v, causal=True, chunk=_attn_chunk(s),
+                        tiers=_attn_tiers(),
                     )
-                att = _flash_sharded(q, k, v, mesh)
-            else:
-                att = attention(q, k, v, causal=True)
+                else:
+                    att = attention(q, k, v, causal=True)
             x = x + att.reshape(b, s, cfg.qkv_dim) @ lp["wo"]
 
         with jax.named_scope("moe" if cfg.n_experts else "ffn"):

@@ -68,30 +68,28 @@ def chunked_attention(
     q-blocks computes [chunk, S] scores with the softmax fused into the
     block, and ``jax.checkpoint`` recomputes them in the backward.
 
-    This is the HBM-bandwidth fix for long context on TPU: plain
-    attention's f32 scores round-trip HBM ([B,H,S,S] ~2 GB at s=8192),
-    while here per-block scores stay fusion-local. Measured on v5e at
-    b1 h8 s8192 hd64 (fwd+bwd): 57 ms vs 277 ms plain — and it BEATS the
-    official pallas flash kernel (71 ms) while remaining pure XLA: it
-    needs no shard_map manual region, so it composes with GSPMD sharding
-    and the pipeline's manual region where a Mosaic kernel cannot.
+    Pure XLA: it needs no shard_map manual region, so it composes with
+    GSPMD sharding and the pipeline's manual region where a Mosaic kernel
+    cannot, and it runs on a CPU. On a v5e at b8 x s2048 x 16 heads x 128
+    (forward, ``remat``'s forward, backward; my chip run, PR 31) it takes
+    16.3 ms where plain attention takes 22.2 (f32 scores round-trip HBM)
+    and the Pallas kernel 8.3, so ``attention_impl`` "auto" takes the
+    kernel there; at head_dim 64 (b4 x s1024 x 16) its 1.28 ms beat plain
+    (2.67) and tie the kernel (1.25), and it stays.
 
     Causal runs additionally skip provably-masked key blocks via static
     k-prefix TIERS: q-segment t of ``tiers`` only scores against keys
     ``[0, (t+1)·S/tiers)`` — at 4 tiers that is 62.5% of the full S²
     score flops (53% at 16) for ~tiers compiled bodies (still one jit).
-    ``tiers=None`` adapts to S: more tiers pay off once segments stay
-    ~2k rows (v5e sweep: s=32k fwd+bwd 140→121 ms going 4→16 tiers;
-    s=8k prefers 4–8).
+    ``tiers=None`` picks 4 below s=16k and 16 from there on (from sweeps
+    of a d512 / head_dim 64 model before the benchmark existed; not
+    measured at the cells' widths).
 
     Requires ``S % chunk == 0`` (callers fall back to plain otherwise).
     """
     b, s, h, d = q.shape
     assert s % chunk == 0, f"seq {s} not divisible by chunk {chunk}"
     if tiers is None:
-        # round-5 v5e sweep (full-model grads, C=128): s<=8k prefers 4
-        # tiers (9.92 vs 9.44 steps/s at 8k going 4->8), s>=16k prefers
-        # 16 (16k: 3.34 vs 3.29 at 8; 32k: 1046 ms at 16 vs 1089 at 8)
         tiers = 16 if s >= 16384 else 4
     # the divisibility gate below would otherwise silently drop tiering
     # for (s, chunk) pairs the pick doesn't divide — fall to the largest

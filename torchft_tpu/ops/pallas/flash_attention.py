@@ -1,38 +1,55 @@
 """Flash attention (causal) as pallas TPU kernels, fwd + bwd.
 
 FlashAttention-2 style: the [Sq, Sk] score matrix never materializes in
-HBM; probabilities are recomputed blockwise in the backward from a saved
-logsumexp. The K/V (resp. Q/dO) block axis is the innermost *grid*
-dimension — pallas double-buffers each block's HBM→VMEM DMA against the
-previous block's compute — with the running accumulators (acc/m/l, dq,
-dk/dv) living in VMEM scratch that persists across the inner grid
-sweep (TPU grids execute sequentially per core).
+HBM; probabilities are recomputed tile by tile in the backward from a
+saved logsumexp. Two kernels: the forward, and ONE backward that computes
+dQ, dK and dV from a single pass over the scores (five matmuls and one exp
+a tile, where separate dQ and dK/dV kernels pay seven and two).
 
-Causal scheduling masks the diagonal blocks and skips compute above the
-diagonal via ``pl.when``.
+Tiling: the grid is (batch, head, outer block, inner block). A grid step
+holds one block of ``block_q`` query rows and up to ``_RESIDENT_KEYS`` rows
+of K and V in VMEM, and loops inside the step over tiles of ``block_k``
+keys — pallas double-buffers the next step's blocks against this one's
+compute; the running accumulators (acc/m/l; dk/dv) live in VMEM scratch
+that persists across the inner grid sweep (TPU grids execute sequentially
+per core). With the whole sequence resident (S <= 2048) the forward is one
+step a q block and the backward's dQ is complete within its step; longer
+sequences write dQ in one part per K block, summed outside.
 
-Matmuls keep their storage dtype (bf16) into the MXU and request
-``preferred_element_type=float32`` (f32 accumulate). On CPU the kernels
+Causal scheduling: the loop over key tiles stops at the diagonal — a tile
+above it is never computed, a K/V block above it never fetched (the index
+maps clamp to the last block the diagonal needs, and a step whose block
+index did not change re-uses the resident block) — and only the tiles the
+diagonal crosses build the mask.
+
+Layout: with ``head_dim`` a multiple of 128 (one lane tile) a head's
+columns are read in place from the ``[B, S, H·Dh]`` view, no transpose
+around the call; other head sizes go through ``[B, H, S, Dh]``. Both
+kernels work on TRANSPOSED scores ``K·Qᵀ`` ``[block_k, block_q]``: the
+per-query statistics (running max and sum, logsumexp, delta) are then
+lane rows that broadcast along sublanes, where as ``[block_q, 1]`` columns
+each of their updates costs a pass over a ``[block_q, 128]`` array.
+
+Precision: matmul operands keep their storage dtype (bf16) into the MXU
+with f32 accumulation (``preferred_element_type``); scores, running max /
+sum and exp are f32 (the v5e has no bf16 VPU); probabilities and dS are
+cast to the storage dtype before their second matmul; 1/sqrt(d) goes onto
+the q tile once (in f32, rounded to the storage dtype). On CPU the kernels
 run under ``interpret=True`` so unit tests check numerics against
 ``ops.attention``.
 
-Role: this kernel is the MEMORY-CEILING path — it makes sequences whose
-[S,S] scores can't fit HBM trainable at all (32k tokens on one v5e chip).
-It is not the speed path: at d=64 each 128×128 block is ~2 microscopic
-matmuls, so the grid is DMA/sequencing-latency-bound and XLA's fused
-attention was an order of magnitude faster wherever it fits (19x fwd at
-s=8192 on a v5e, measured before this round). The (1,128,128) blocks are
-a choice of an earlier toolchain; larger blocks and several heads per
-grid step have not been tried on the directly attached chip (ROADMAP
-D3). The crossover is handled in policy: models/transformer.py
-``_use_flash`` engages this kernel only above the scores-memory
-threshold.
+Role: at the tiles ``models/transformer._flash_blocks`` picks it is the
+speed path of ``attention_impl`` "auto" on a TPU (the policy and its
+measured table: ``_attention_path`` there and PERF.md §6, PR 31), and at
+any tile the memory-ceiling path for sequences whose [S, S] scores cannot
+fit HBM.
 
 On the chip the kernels compile as written (libtpu 0.0.34, jax 0.9.0):
 ``chip_smoke.py`` phase 3 checks for the Mosaic ``tpu_custom_call`` in
 the lowered text and for agreement of forward and backward with
-``ops.attention`` at head_dim 64 and 128, which is what guards
-:func:`_should_interpret`'s choice from the backend.
+``ops.attention`` at head_dim 64 and 128 and at the benchmark cells'
+sequence and heads, which is what guards :func:`_should_interpret`'s
+choice from the backend.
 """
 
 from __future__ import annotations
@@ -48,16 +65,12 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
-_LANES = 128  # m/l scratch padded to a full lane tile
+_LANES = 128  # one lane tile: a head of that many columns is read in place
+_ROWS = 8  # sublanes a row of per-position statistics is stored over
 
 
 def _should_interpret() -> bool:
     return jax.default_backend() == "cpu"
-
-
-def _iota(n: int) -> jnp.ndarray:
-    # 1D iota is unsupported on TPU; build 2D and squeeze
-    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
 
 
 def _dot(a, b, dims):
@@ -65,17 +78,52 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
+_NT = ((1,), (1,))  # a · bᵀ
+_NN = ((1,), (0,))  # a · b
+_TN = ((0,), (0,))  # aᵀ · b
+
+
+def _causal(key0, n_keys, q0, n_q):
+    """[n_keys, n_q] True where the key may be attended (k_pos <= q_pos)."""
+    k = key0 + jax.lax.broadcasted_iota(jnp.int32, (n_keys, n_q), 0)
+    q = q0 + jax.lax.broadcasted_iota(jnp.int32, (n_keys, n_q), 1)
+    return k <= q
+
+
+def _over_key_tiles(row0, bq, col0, bk, bkc, causal, step) -> None:
+    """Run ``step(c, masked)`` for the tiles c of ``bkc`` keys, of the
+    ``bk`` resident from ``col0``, that the q rows ``[row0, row0 + bq)``
+    may attend: first those wholly below the diagonal, without a mask,
+    then those it crosses; a tile above it is not computed."""
+    nc = bk // bkc
+    if not causal:
+        jax.lax.fori_loop(0, nc, lambda c, _: step(c, False), None)
+        return
+    below = jnp.clip(jnp.maximum(row0 - col0 + 1, 0) // bkc, 0, nc)
+    reached = jnp.clip(jnp.maximum(row0 + bq - 1 - col0 + bkc, 0) // bkc, 0, nc)
+    jax.lax.fori_loop(0, below, lambda c, _: step(c, False), None)
+    jax.lax.fori_loop(below, reached, lambda c, _: step(c, True), None)
+
+
+def _scaled(q_ref, scale):
+    # 1/sqrt(d) once on the [bq, d] tile, not on every [bq, bkc] of scores
+    return (q_ref[...].astype(jnp.float32) * scale).astype(q_ref.dtype)
+
+
 # ---------------------------------------------------------------------------
-# forward: grid (bh, nq, nk) — nk innermost, acc/m/l in scratch
+# forward: grid (b, h, nq, nk) — nk innermost, acc/m/l in scratch
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, bq, bk, scale, causal,
+    *, bq, bk, bkc, scale, causal,
 ):
-    i, j = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    # transposed scores [bkc, bq]: the running max and sum are lane rows
+    # [1, bq] — as a [bq, 1] column each of their updates costs a pass over a
+    # [bq, 128] array, more than the scores' own at 512 keys — and the output
+    # accumulates transposed, [d, bq], turned once per q block
+    i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
@@ -83,189 +131,213 @@ def _fwd_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # blocks strictly above the diagonal contribute nothing
-    run = (j * bk <= i * bq + bq - 1) if causal else True
+    q = _scaled(q_ref, scale)
 
-    @pl.when(run)
-    def _compute():
-        # inputs keep their storage dtype (bf16): the MXU takes bf16
-        # operands at full rate and accumulates f32 via
-        # preferred_element_type — upcasting first costs an extra VPU pass
-        s = _dot(q_ref[0], k_ref[0], ((1,), (1,))) * scale
-        if causal:
-            q_pos = i * bq + _iota(bq)
-            k_pos = j * bk + _iota(bk)
-            s = jnp.where(k_pos[None, :] <= q_pos[:, None], s, _NEG_INF)
-        m_prev = m_ref[:, 0]
-        blk_max = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, blk_max)
-        p = jnp.exp(s - m_new[:, None])
+    def step(c, masked: bool):
+        keys = pl.ds(pl.multiple_of(c * bkc, bkc), bkc)
+        st = _dot(k_ref[keys, :], q, _NT)
+        if masked:
+            st = jnp.where(_causal(j * bk + c * bkc, bkc, i * bq, bq), st, _NEG_INF)
+        m_prev = m_ref[:1, :]
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        pt = jnp.exp(st - m_new)
         corr = jnp.exp(m_prev - m_new)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + _dot(
-            p.astype(v_ref.dtype), v_ref[0], ((1,), (0,))
+        acc_ref[...] = acc_ref[...] * corr + _dot(
+            v_ref[keys, :], pt.astype(v_ref.dtype), _TN
         )
-        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-        m_ref[:, 0] = m_new
+        l_new = l_ref[:1, :] * corr + jnp.sum(pt, axis=0, keepdims=True)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    @pl.when(j == nk - 1)
+    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, step)
+
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse = m_ref[:, 0] + jnp.log(l)
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], (8, bq))
-
-
-def _fwd(q, k, v, bq, bk, scale, causal, interpret):
-    bh, s, d = q.shape
-    grid = (bh, s // bq, s // bk)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, bq=bq, bk=bk, scale=scale, causal=causal),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 8, s), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
+        l = jnp.maximum(l_ref[:1, :], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).T.astype(o_ref.dtype)
+        lse_ref[...] = jnp.broadcast_to(m_ref[:1, :] + jnp.log(l), lse_ref.shape)
 
 
 # ---------------------------------------------------------------------------
-# backward
+# backward: grid (b, h, nk, nq) — nq innermost, dk/dv in scratch; dq of
+# this (k block, q block) is complete within the step
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
-    *, bq, bk, scale, causal,
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+    dk_acc, dv_acc, dq_acc,
+    *, bq, bk, bkc, scale, causal,
 ):
-    i, j = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    run = (j * bk <= i * bq + bq - 1) if causal else True
-
-    @pl.when(run)
-    def _compute():
-        lse = lse_ref[0, 0, :]
-        delta = delta_ref[0, 0, :]
-        s = _dot(q_ref[0], k_ref[0], ((1,), (1,))) * scale
-        if causal:
-            q_pos = i * bq + _iota(bq)
-            k_pos = j * bk + _iota(bk)
-            s = jnp.where(k_pos[None, :] <= q_pos[:, None], s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dp = _dot(do_ref[0], v_ref[0], ((1,), (1,)))
-        ds = p * (dp - delta[:, None]) * scale
-        acc_ref[...] += _dot(ds.astype(k_ref.dtype), k_ref[0], ((1,), (0,)))
-
-    @pl.when(j == nk - 1)
-    def _finish():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc,
-    *, bq, bk, scale, causal,
-):
-    j, i = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    j, i = pl.program_id(2), pl.program_id(3)
 
     @pl.when(i == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = (i * bq + bq - 1 >= j * bk) if causal else True
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+    q = _scaled(q_ref, scale)
+    do = do_ref[...]
+    lse = lse_ref[:1, :]
+    # delta = rowsum(dO * O), wanted as a lane row like lse: a matmul with
+    # ones puts it there, where a reduction would leave a column
+    delta = jax.lax.dot_general(
+        jnp.ones((_ROWS, do.shape[1]), jnp.float32),
+        do.astype(jnp.float32) * o_ref[...].astype(jnp.float32),
+        (_NT, ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )[:1, :]
 
-    @pl.when(run)
-    def _compute():
-        lse = lse_ref[0, 0, :]
-        delta = delta_ref[0, 0, :]
-        s = _dot(q_ref[0], k_ref[0], ((1,), (1,))) * scale
-        if causal:
-            q_pos = i * bq + _iota(bq)
-            k_pos = j * bk + _iota(bk)
-            s = jnp.where(k_pos[None, :] <= q_pos[:, None], s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dv_acc[...] += _dot(p.astype(do_ref.dtype), do_ref[0], ((0,), (0,)))
-        dp = _dot(do_ref[0], v_ref[0], ((1,), (1,)))
-        ds = p * (dp - delta[:, None]) * scale
-        dk_acc[...] += _dot(ds.astype(q_ref.dtype), q_ref[0], ((0,), (0,)))
+    def step(c, masked: bool):
+        keys = pl.ds(pl.multiple_of(c * bkc, bkc), bkc)
+        k, v = k_ref[keys, :], v_ref[keys, :]
+        # transposed scores [bkc, bq]: the statistics' lane rows broadcast
+        # along sublanes as they are
+        st = _dot(k, q, _NT)
+        if masked:
+            st = jnp.where(_causal(j * bk + c * bkc, bkc, i * bq, bq), st, _NEG_INF)
+        pt = jnp.exp(st - lse)
+        dv_acc[keys, :] += _dot(pt.astype(do.dtype), do, _NN)
+        dst = (pt * (_dot(v, do, _NT) - delta)).astype(q.dtype)
+        dk_acc[keys, :] += _dot(dst, q, _NN)  # q carries the scale
+        dq_acc[...] += _dot(dst, k, _TN)
 
-    @pl.when(i == nq - 1)
+    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, step)
+
+    dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(i == pl.num_programs(3) - 1)
     def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd(bq, bk, scale, causal, interpret, res, do):
-    q, k, v, o, lse = res
-    bh, s, d = q.shape
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, None, :], (bh, 8, s))
+# ---------------------------------------------------------------------------
+# block specs and the two calls
+# ---------------------------------------------------------------------------
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, bq=bq, bk=bk, scale=scale, causal=causal),
-        grid=(bh, s // bq, s // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, bq, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM
+
+def _tile(lanes: bool, blk: int, d: int, idx, lead: bool = False) -> pl.BlockSpec:
+    """One [blk, d] tile of a q/k/v-shaped array; ``idx(x, y)`` gives its
+    block along S from the grid's last two indices. ``lead``: the array has
+    one more axis in front, indexed by x (dq's part of each k block)."""
+    if lanes:  # [B, S, H·Dh]: head h is lane tile h
+        at = lambda b, h, x, y: (b, idx(x, y), h)
+        shape = (None, blk, d)
+    else:  # [B, H, S, Dh]
+        at = lambda b, h, x, y: (b, h, idx(x, y), 0)
+        shape = (None, None, blk, d)
+    if lead:
+        return pl.BlockSpec(
+            (None,) + shape, lambda b, h, x, y: (x,) + at(b, h, x, y),
+            memory_space=pltpu.VMEM,
+        )
+    return pl.BlockSpec(shape, at, memory_space=pltpu.VMEM)
+
+
+def _row(blk: int, idx) -> pl.BlockSpec:
+    """One [8, blk] tile of per-position statistics, [B, H, 8, S]."""
+    return pl.BlockSpec(
+        (None, None, _ROWS, blk), lambda b, h, x, y: (b, h, 0, idx(x, y)),
+        memory_space=pltpu.VMEM,
+    )
+
+
+def _params(bq: int, bkc: int) -> pltpu.CompilerParams:
+    # the f32 [bq, bkc] temporaries (scores, p, dp, ds, the mask) outgrow
+    # the default scoped limit from 512 x 512 on: raise the limit rather
+    # than shrink the tiles (a v5e core has 128 MiB)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=min(112 << 20, (24 << 20) + 40 * bq * bkc),
+    )
+
+
+def _fwd(q, k, v, shape, blocks, causal, interpret):
+    b, s, h, d = shape
+    scale = d**-0.5
+    bq, bk, bkc = blocks
+    lanes = q.ndim == 3
+    q_at = lambda i, j: i
+    if causal:  # a block above the diagonal: keep the last one needed resident
+        k_at = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
+    else:
+        k_at = lambda i, j: j
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, bq=bq, bk=bk, scale=scale, causal=causal),
-        grid=(bh, s // bk, s // bq),
+        grid=(b, h, s // bq, s // bk),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, bq), lambda b, j, i: (b, 0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, bq), lambda b, j, i: (b, 0, i), memory_space=pltpu.VMEM),
+            _tile(lanes, bq, d, q_at),
+            _tile(lanes, bk, d, k_at),
+            _tile(lanes, bk, d, k_at),
+        ],
+        out_specs=[_tile(lanes, bq, d, q_at), _row(bq, q_at)],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, h, _ROWS, s), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((d, bq), jnp.float32),
+            pltpu.VMEM((_ROWS, bq), jnp.float32),
+            pltpu.VMEM((_ROWS, bq), jnp.float32),
+        ],
+        compiler_params=_params(bq, bkc),
+        interpret=interpret,
+        name="flash_fwd",
+    )(q, k, v)
+
+
+def _bwd(shape, blocks, causal, interpret, res, do):
+    q, k, v, o, lse = res
+    b, s, h, d = shape
+    scale = d**-0.5
+    bq, bk, bkc = blocks
+    lanes = q.ndim == 3
+    nk = s // bk
+    k_at = lambda j, i: j
+    if causal:  # a q block above the diagonal: fetch the first one below
+        q_at = lambda j, i: jnp.maximum(i, (j * bk) // bq)
+    else:
+        q_at = lambda j, i: i
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal
+        ),
+        grid=(b, h, nk, s // bq),
+        in_specs=[
+            _tile(lanes, bq, d, q_at),
+            _tile(lanes, bk, d, k_at),
+            _tile(lanes, bk, d, k_at),
+            _tile(lanes, bq, d, q_at),
+            _tile(lanes, bq, d, q_at),
+            _row(bq, q_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM),
+            # dq's part of EVERY (k block, q block), zeros above the diagonal
+            _tile(lanes, bq, d, lambda j, i: i, lead=True),
+            _tile(lanes, bk, d, k_at),
+            _tile(lanes, bk, d, k_at),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
+            # one k block (the whole sequence resident): the part is dq
+            jax.ShapeDtypeStruct((nk,) + q.shape, q.dtype if nk == 1 else jnp.float32),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
         ],
+        compiler_params=_params(bq, bkc),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+        name="flash_bwd",
+    )(q, k, v, o, do, lse)
+    dq = dq[0] if nk == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
     return dq, dk, dv
 
 
@@ -275,24 +347,18 @@ def _bwd(bq, bk, scale, causal, interpret, res, do):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, bq, bk, causal, interpret):
-    scale = q.shape[-1] ** -0.5
-    o, _ = _fwd(q, k, v, bq, bk, scale, causal, interpret)
-    return o
+def _flash(q, k, v, shape, blocks, causal, interpret):
+    return _fwd(q, k, v, shape, blocks, causal, interpret)[0]
 
 
-def _flash_fwd(q, k, v, bq, bk, causal, interpret):
-    scale = q.shape[-1] ** -0.5
-    o, lse = _fwd(q, k, v, bq, bk, scale, causal, interpret)
+def _flash_fwd(q, k, v, shape, blocks, causal, interpret):
+    o, lse = _fwd(q, k, v, shape, blocks, causal, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(bq, bk, causal, interpret, res, do):
-    scale = res[0].shape[-1] ** -0.5
-    return _bwd(bq, bk, scale, causal, interpret, res, do)
+_flash.defvjp(_flash_fwd, _bwd)
 
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_RESIDENT_KEYS = 2048  # most rows of K and V a grid step keeps in VMEM
 
 
 def flash_attention(
@@ -306,18 +372,36 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Causal flash attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh].
 
+    ``block_q`` x ``block_k`` is the tile of scores computed at a time.
     Requires S % block == 0 (pick smaller blocks for short sequences).
     Differentiable (custom FlashAttention-2 backward)."""
     b, s, h, d = q.shape
     bq = min(block_q, s)
-    bk = min(block_k, s)
-    if s % bq or s % bk:
-        raise ValueError(f"seq len {s} must be a multiple of block sizes ({bq},{bk})")
+    bkc = min(block_k, s)
+    if s % bq or s % bkc:
+        raise ValueError(f"seq len {s} must be a multiple of block sizes ({bq},{bkc})")
     if interpret is None:
         interpret = _should_interpret()
+    # K and V arrive in the largest whole number of tiles that divides S and
+    # stays under _RESIDENT_KEYS rows: fewer grid steps and DMAs than a tile
+    # a step, and at S <= 2048 the backward's dq needs no second pass
+    tiles = max(
+        m for m in range(1, max(_RESIDENT_KEYS // bkc, 1) + 1) if (s // bkc) % m == 0
+    )
+    blocks = (bq, bkc * tiles, bkc)
 
-    def pack(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    if d % _LANES == 0:
+        # a head's columns are whole lane tiles of the [B, S, H·Dh] view
+        def pack(x):
+            return x.reshape(b, s, h * d)
 
-    o = _flash(pack(q), pack(k), pack(v), bq, bk, causal, interpret)
-    return o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        def unpack(x):
+            return x.reshape(b, s, h, d)
+    else:
+        def pack(x):
+            return x.transpose(0, 2, 1, 3)
+
+        unpack = pack
+
+    o = _flash(pack(q), pack(k), pack(v), (b, s, h, d), blocks, causal, interpret)
+    return unpack(o)

@@ -76,6 +76,7 @@ CANONICAL_EVENTS = (
     "perf_regression",
     "perf_regression_cleared",
     "diagnosis_captured",
+    "attention_path",
 )
 
 # The protocol-lifecycle subset of the vocabulary: the events the
