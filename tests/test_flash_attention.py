@@ -1,6 +1,8 @@
 """Flash-attention kernel numerics vs the reference jnp implementation
 (interpreter mode on CPU; the same kernels compile for TPU)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -332,7 +334,7 @@ def test_chunked_loss_matches_dense(monkeypatch):
     dense = T.loss_fn(params, tokens, cfg, None)
     g_dense = jax.grad(lambda p: T.loss_fn(p, tokens, cfg, None))(params)
 
-    monkeypatch.setenv("TORCHFT_TPU_LOSS_CHUNK_ELEMS", "64")  # force chunking
+    monkeypatch.setattr(T, "_LOSS_CHUNK_ELEMS", 64)  # force chunking
     chunked = T.loss_fn(params, tokens, cfg, None)
     g_chunk = jax.grad(lambda p: T.loss_fn(p, tokens, cfg, None))(params)
 
@@ -341,6 +343,130 @@ def test_chunked_loss_matches_dense(monkeypatch):
         jax.tree_util.tree_leaves(g_dense), jax.tree_util.tree_leaves(g_chunk)
     ):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+
+def _head_case(dtype, vocab=97, batch=2, s=16):
+    from torchft_tpu.models import transformer as T
+
+    sizes = dict(vocab_size=vocab, d_model=32, n_layers=2, n_heads=2, head_dim=16, d_ff=64)
+    cfg = T.TransformerConfig(dtype=dtype, **sizes)
+    params = T.init_params(jax.random.PRNGKey(0), cfg)  # f32 whatever the compute dtype
+    # the init's 1/sqrt(d) unembed gives a near-uniform softmax: make it lively
+    params["out"] = params["out"] * 4.0
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, vocab, (batch, s)), jnp.int32)
+    return T, cfg, params, tokens
+
+
+def _chunked_head(T, cfg, tokens, scale=1.0, mesh=None):
+    """The chunked head on the model's own hidden states, called directly:
+    through ``loss_fn`` a budget that makes ONE chunk selects the dense branch."""
+
+    def loss(p):
+        x, _ = T._hidden_states(p, tokens, cfg, mesh)
+        return scale * T._chunked_loss(p, x, tokens, cfg, mesh)
+
+    return loss
+
+
+# f32 cases: both sides compute in f32 and differ in the order of a few
+# hundred adds (per-chunk sums; softmax - onehot formed directly, not as the
+# transpose of log_softmax): 2e-6 of a leaf's largest entry (measured: 4e-7).
+# bf16: the system rounds every activation, the logits and `softmax - onehot`
+# to 8 bits of mantissa (2^-9 = 2e-3 relative, a step) where the f32 dense
+# twin rounds none; through two layers and the head that comes to 4 % of a
+# leaf's largest entry (measured: `wq` 2.7e-2 the worst leaf, `out` 1.1e-2,
+# the value 6e-5). What that cannot hide is a missing mask, count or chunk:
+# each is off by 1/16 of a leaf and more. So the bf16 case is also held to the
+# dense branch IN bf16, which rounds at the same places except that it rounds
+# `d out` to bf16 and the chunked head sums it in f32: one bf16 step of a
+# leaf's largest entry, 2^-8 (measured: `out` 2.1e-3, every other leaf 0).
+HEAD_CASES = {
+    "s_divides_the_chunk": dict(chunk=4),
+    "padded_last_chunk": dict(chunk=5),
+    "one_chunk": dict(chunk=16),
+    "bf16_compute": dict(chunk=5, dtype=jnp.bfloat16, tol=4e-2),
+    "cotangent_2.5": dict(chunk=5, scale=2.5),
+    # b over dp x fsdp, `out` [d, V] sharded over tp: the budget is per device
+    "out_sharded_over_tp": dict(chunk=5, mesh=dict(dp=2, fsdp=2, tp=2), vocab=96, batch=4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HEAD_CASES))
+def test_head_gradient_taken_in_the_forward_matches_dense(monkeypatch, case):
+    """The chunked head computes its gradient in its forward pass
+    (``transformer._chunked_nll``): value, ``d out`` and the gradient
+    reaching the layers against ``jax.value_and_grad`` of the dense branch
+    in f32."""
+    spec = {"dtype": jnp.float32, "scale": 1.0, "tol": 2e-6, "mesh": None, "vocab": 97, "batch": 2}
+    spec.update(HEAD_CASES[case])
+    T, cfg, params, tokens = _head_case(spec["dtype"], spec["vocab"], spec["batch"])
+    scale = spec["scale"]
+    dense = lambda c: jax.value_and_grad(lambda p: scale * T.loss_fn(p, tokens, c, None))(params)
+    want, g_want = dense(dataclasses.replace(cfg, dtype=jnp.float32))
+    same, g_same = dense(cfg)  # the dense branch in the case's own dtype
+
+    per_pos = spec["batch"] * cfg.vocab_size  # a position's logits on one device
+    mesh, placed = None, params
+    if spec["mesh"]:
+        from jax.sharding import NamedSharding
+
+        from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+
+        mesh = make_mesh(MeshConfig(**spec["mesh"]))
+        placed = jax.device_put(
+            params, jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), T.param_specs(cfg))
+        )
+        per_pos //= mesh.shape["dp"] * mesh.shape["fsdp"] * mesh.shape["tp"]
+    monkeypatch.setattr(T, "_LOSS_CHUNK_ELEMS", spec["chunk"] * per_pos)
+    head = jax.value_and_grad(_chunked_head(T, cfg, tokens, scale, mesh))
+    if mesh is None:
+        got, g_got = head(placed)
+    else:
+        with jax.set_mesh(mesh):
+            got, g_got = jax.jit(head)(placed)
+        assert g_got["out"].sharding.spec[-1] == "tp"  # the f32 sum over chunks stays sharded
+    assert got.dtype == want.dtype and g_got["out"].dtype == params["out"].dtype
+
+    def worst(g, g_ref):
+        errs = jax.tree_util.tree_map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))), g, g_ref
+        )
+        return max(jax.tree_util.tree_leaves(errs))
+
+    assert float(got) == pytest.approx(float(want), rel=spec["tol"])
+    assert worst(g_got, g_want) < spec["tol"]  # `out`, embed, every layer leaf, final_norm
+    if spec["dtype"] == jnp.bfloat16:
+        assert float(got) == pytest.approx(float(same), rel=1e-6)
+        assert worst(g_got, g_same) < 2.0**-8
+
+
+def _vocab_matmuls(jaxpr, vocab):
+    """dot_generals, anywhere under ``jaxpr``, with an operand of the
+    vocabulary's width (a scan's body counts once: per chunk)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            n += any(vocab in v.aval.shape for v in eqn.invars)
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _vocab_matmuls(sub, vocab)
+    return n
+
+
+def test_head_runs_one_vocabulary_matmul_a_chunk_plainly_and_three_differentiated(monkeypatch):
+    """The head's gradient costs two products beside the forward's one: the
+    backward does not compute the logits again (``jax.checkpoint`` on the
+    scan's body would make it four)."""
+    T, cfg, params, tokens = _head_case(jnp.float32)
+    monkeypatch.setattr(T, "_LOSS_CHUNK_ELEMS", 2 * 5 * cfg.vocab_size)
+    loss = lambda p: T.loss_fn(p, tokens, cfg, None)
+    assert _vocab_matmuls(jax.make_jaxpr(loss)(params).jaxpr, cfg.vocab_size) == 1
+    assert _vocab_matmuls(jax.make_jaxpr(jax.value_and_grad(loss))(params).jaxpr, cfg.vocab_size) == 3
+    # the counter sees the dense branch's three too: forward, d h, d out
+    monkeypatch.setattr(T, "_LOSS_CHUNK_ELEMS", 1 << 27)
+    assert _vocab_matmuls(jax.make_jaxpr(jax.value_and_grad(loss))(params).jaxpr, cfg.vocab_size) == 3
 
 
 class TestChunkedAttention:

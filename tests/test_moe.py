@@ -24,6 +24,7 @@ import numpy as np
 import optax
 import pytest
 
+from torchft_tpu.models import transformer
 from torchft_tpu.models.transformer import (
     PRESETS,
     TransformerConfig,
@@ -236,7 +237,7 @@ def test_remat_on_and_off_agree():
 def test_the_chunked_head_carries_the_balance_term(monkeypatch):
     cfg, params, tokens, sizes = make("e8k2")
     plain, g_plain = system(cfg, params, tokens)
-    monkeypatch.setenv("TORCHFT_TPU_LOSS_CHUNK_ELEMS", str(2 * 5 * cfg.vocab_size))  # chunks of 5 positions
+    monkeypatch.setattr(transformer, "_LOSS_CHUNK_ELEMS", 2 * 5 * cfg.vocab_size)  # chunks of 5 positions
     chunked, g_chunked = system(cfg, params, tokens)
     assert float(chunked) == pytest.approx(float(plain), rel=1e-6)
     assert float(chunked) == pytest.approx(float(ref.loss(params, tokens, sizes)), rel=RTOL)

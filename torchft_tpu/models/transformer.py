@@ -725,6 +725,12 @@ def loss_and_stats(
     return ce, stats
 
 
+# Logit-element budget, per device, above which the loss head chunks the
+# sequence: 2^27 elements = 512 MB of f32 logits a live buffer; tests
+# monkeypatch it to force the chunked head on tiny shapes.
+_LOSS_CHUNK_ELEMS = 1 << 27
+
+
 def _cross_entropy(
     params: Dict[str, Any],
     x: jnp.ndarray,
@@ -743,7 +749,7 @@ def _cross_entropy(
     # already sharded and a global-s scan would fight that sharding: the
     # dense path stays (its per-device logits are S/sp smaller), so scale
     # very long context under sp by adding sp shards, not chunking.
-    if sp == 1 and _per_device_logit_elems(cfg, b, s, mesh) > _loss_chunk_elems():
+    if sp == 1 and _per_device_logit_elems(cfg, b, s, mesh) > _LOSS_CHUNK_ELEMS:
         return _chunked_loss(params, x, tokens, cfg, mesh)
     with jax.named_scope("head_loss"):
         logits = (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
@@ -752,19 +758,6 @@ def _cross_entropy(
         nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)[..., 0]
         mask = jnp.ones_like(nll).at[:, -1].set(0.0)
         return jnp.sum(nll * mask) / jnp.sum(mask)
-
-
-def _loss_chunk_elems() -> int:
-    """Logit-element budget above which the loss head chunks the sequence
-    (default 2^27 ≈ 134M elems = 512 MB of f32 logits per live buffer).
-    Override via TORCHFT_TPU_LOSS_CHUNK_ELEMS (also how tests force the
-    chunked path on tiny shapes)."""
-    import os
-
-    try:
-        return int(os.environ.get("TORCHFT_TPU_LOSS_CHUNK_ELEMS", 1 << 27))
-    except ValueError:
-        return 1 << 27
 
 
 def _per_device_logit_elems(
@@ -792,21 +785,18 @@ def _chunked_loss(
     mesh=None,
 ) -> jnp.ndarray:
     """Cross entropy without materializing [B, S, V]: scan the unembed +
-    softmax over sequence chunks, ``jax.checkpoint`` on the body so the
-    backward rematerializes one chunk's logits at a time. Same numbers as
-    the dense path (f32 log_softmax per position; accumulation order
+    softmax over sequence chunks (:func:`_chunked_nll`). Same numbers as
+    the dense path (f32 log-sum-exp per position; accumulation order
     differs only in the final f32 sums)."""
     b, s = tokens.shape
-    out_w = params["out"].astype(cfg.dtype)
     targets = jnp.roll(tokens, -1, axis=1)
     mask = jnp.ones((b, s), jnp.float32).at[:, -1].set(0.0)
 
     # chunk size straight from the per-device budget; s needn't divide —
     # the tail chunk is padded and masked out (any s, prime or odd, gets
     # full chunking)
-    budget = max(1, _loss_chunk_elems())
     per_pos = _per_device_logit_elems(cfg, b, 1, mesh)
-    chunk = max(1, min(s, budget // max(1, per_pos)))
+    chunk = max(1, min(s, _LOSS_CHUNK_ELEMS // max(1, per_pos)))
     if chunk >= 128:
         chunk -= chunk % 128  # lane-aligned chunks
     n_chunks = -(-s // chunk)
@@ -819,21 +809,75 @@ def _chunked_loss(
     hs = jnp.moveaxis(h.reshape(b, n_chunks, chunk, -1), 1, 0)
     ts = jnp.moveaxis(targets.reshape(b, n_chunks, chunk), 1, 0)
     ms = jnp.moveaxis(mask.reshape(b, n_chunks, chunk), 1, 0)
+    with jax.named_scope("head_loss"):
+        return _chunked_nll(hs, params["out"], ts, ms)
 
-    @jax.checkpoint
+
+def _chunk_nll(h_c, out_w, t_c, m_c):
+    """One chunk's f32 logits, their log-sum-exp, and its masked NLL sum."""
+    logits = h_c @ out_w
+    # the target's logit is picked BEFORE the cast (the same number): picked
+    # after it, XLA keeps an f32 copy of the chunk's logits for the gather
+    target = jnp.take_along_axis(logits, t_c[..., None], axis=-1).astype(jnp.float32)
+    logits = logits.astype(jnp.float32)
+    mx = jnp.max(logits, axis=-1, keepdims=True)
+    lse = jnp.log(jnp.sum(jnp.exp(logits - mx), axis=-1, keepdims=True)) + mx
+    return logits, lse, jnp.sum((lse - target)[..., 0] * m_c)
+
+
+@jax.custom_vjp
+def _chunked_nll(hs, out, ts, ms):
+    """Mean masked NLL of hidden-state chunks ``hs`` [n, B, c, d] through
+    the unembed ``out`` [d, V] (cast to ``hs.dtype`` here, so its gradient
+    arrives in ``out``'s own dtype). Called plainly it is the forward scan
+    and nothing more. Differentiated (:func:`_chunked_nll_fwd`) the same
+    scan also forms each chunk's ``softmax - onehot`` while its logits are
+    on the chip and multiplies it into both gradients there: three matrix
+    products a chunk, not the four of a backward that computes the logits
+    again, and no logits kept."""
+    out_w = out.astype(hs.dtype)
+
+    def body(nll_sum, xt):
+        h_c, t_c, m_c = xt
+        return nll_sum + _chunk_nll(h_c, out_w, t_c, m_c)[2], None
+
+    nll_sum, _ = jax.lax.scan(body, jnp.float32(0.0), (hs, ts, ms))
+    return nll_sum / jnp.sum(ms)
+
+
+def _chunked_nll_fwd(hs, out, ts, ms):
+    out_w = out.astype(hs.dtype)
+    cnt = jnp.sum(ms)
+
     def body(carry, xt):
         h_c, t_c, m_c = xt
-        logits = (h_c @ out_w).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, t_c[..., None], axis=-1)[..., 0]
-        nll_sum, cnt = carry
-        return (nll_sum + jnp.sum(nll * m_c), cnt + jnp.sum(m_c)), None
-
-    with jax.named_scope("head_loss"):
-        (nll_sum, cnt), _ = jax.lax.scan(
-            body, (jnp.float32(0.0), jnp.float32(0.0)), (hs, ts, ms)
+        nll_sum, d_out = carry
+        logits, lse, nll_c = _chunk_nll(h_c, out_w, t_c, m_c)
+        onehot = jax.nn.one_hot(t_c, logits.shape[-1], dtype=logits.dtype)
+        # in the dtype the transposed products of `(h_c @ out_w).astype(f32)`
+        # read: that cast's cotangent is cast back to the compute dtype
+        dlogits = ((jnp.exp(logits - lse) - onehot) * (m_c / cnt)[..., None]).astype(h_c.dtype)
+        dh_c = jnp.einsum("bcv,dv->bcd", dlogits, out_w)
+        # summed over the chunks in f32, so `d out` is rounded once and not
+        # once a chunk: 1.2 ms of a 67 ms head at b8 x s2048 on a v5e
+        d_out = d_out + jnp.einsum(
+            "bcd,bcv->dv", h_c, dlogits, preferred_element_type=jnp.float32
         )
-        return nll_sum / cnt
+        return (nll_sum + nll_c, d_out), dh_c
+
+    (nll_sum, d_out), dhs = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.zeros(out.shape, jnp.float32)), (hs, ts, ms)
+    )
+    return nll_sum / cnt, (dhs, d_out.astype(out.dtype))
+
+
+def _chunked_nll_bwd(res, g):
+    dhs, d_out = res
+    with jax.named_scope("head_loss"):
+        return (g * dhs).astype(dhs.dtype), (g * d_out).astype(d_out.dtype), None, None
+
+
+_chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
 
 
 def _pipelined_loss(
