@@ -125,11 +125,41 @@ void encode_int8(const float* src, uint8_t* dst, size_t n) {
   }
 }
 
-void decode_int8(const uint8_t* wire, float* dst, size_t n) {
+// The average joins the ring: whoever writes an element's FINAL f32 value
+// divides it by `div` there (the allreduce's divisor as a float; 1 = a
+// plain reduce) — the owner's last reduce-scatter step on the exact
+// planes, the decode of the owner's wire bytes on the lossy ones — so no
+// pass of its own ever runs over the buffer. A true f32 division, never a
+// multiply by the reciprocal: bit for bit np.divide(x, n, out=x) for
+// every n (x * (1/3.f) is not x / 3.f), which needs the build to stay
+// free of -ffast-math.
+void decode_bf16(const uint16_t* in, float* dst, size_t n, float div) {
+  if (div == 1.0f) {
+    for (size_t i = 0; i < n; ++i) dst[i] = bf16_to_f32(in[i]);
+  } else {
+    for (size_t i = 0; i < n; ++i) dst[i] = bf16_to_f32(in[i]) / div;
+  }
+}
+
+void decode_int8(const uint8_t* wire, float* dst, size_t n, float div) {
   float scale;
   std::memcpy(&scale, wire, 4);
   const int8_t* q = (const int8_t*)(wire + 4);
-  for (size_t i = 0; i < n; ++i) dst[i] = (float)q[i] * scale;
+  if (div == 1.0f) {
+    for (size_t i = 0; i < n; ++i) dst[i] = (float)q[i] * scale;
+  } else {
+    for (size_t i = 0; i < n; ++i) dst[i] = ((float)q[i] * scale) / div;
+  }
+}
+
+void divide_f32(float* x, size_t n, float div) {
+  for (size_t i = 0; i < n; ++i) x[i] = x[i] / div;
+}
+
+// the exact planes' last reduce-scatter step: sum and divide in the one
+// loop the owner of the chunk runs anyway
+void reduce_sum_div_f32(float* acc, const float* in, size_t n, float div) {
+  for (size_t i = 0; i < n; ++i) acc[i] = (acc[i] + in[i]) / div;
 }
 
 // NaN-propagating max/min, matching np.maximum/np.minimum (the Python
@@ -147,7 +177,7 @@ inline float nan_min(float a, float b) {
 void reduce_f32(float* acc, const float* in, size_t n, DpOp op) {
   switch (op) {
     case DpOp::kSum:
-    case DpOp::kAvg:
+    case DpOp::kAvg:  // resolved to kSum + divisor in allreduce()
       for (size_t i = 0; i < n; ++i) acc[i] += in[i];
       break;
     case DpOp::kMax:
@@ -162,7 +192,7 @@ void reduce_f32(float* acc, const float* in, size_t n, DpOp op) {
 void reduce_from_bf16(float* acc, const uint16_t* in, size_t n, DpOp op) {
   switch (op) {
     case DpOp::kSum:
-    case DpOp::kAvg:
+    case DpOp::kAvg:  // resolved to kSum + divisor in allreduce()
       for (size_t i = 0; i < n; ++i) acc[i] += bf16_to_f32(in[i]);
       break;
     case DpOp::kMax:
@@ -180,7 +210,7 @@ void reduce_from_int8(float* acc, const uint8_t* wire, size_t n, DpOp op) {
   const int8_t* q = (const int8_t*)(wire + 4);
   switch (op) {
     case DpOp::kSum:
-    case DpOp::kAvg:
+    case DpOp::kAvg:  // resolved to kSum + divisor in allreduce()
       for (size_t i = 0; i < n; ++i) acc[i] += (float)q[i] * scale;
       break;
     case DpOp::kMax:
@@ -685,6 +715,7 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
   const bool use_cma = cma_.load(std::memory_order_acquire);
   if (use_cma) job.codec = DpCodec::kF32;
   const DpCodec codec = job.codec;
+  const float div = (float)job.divisor;
 
   float* flat = (float*)job.base;
   int64_t n = job.nelems;
@@ -776,8 +807,17 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
         break;
       case DpCodec::kF32:
       default:
-        reduce_f32(chunk_ptr(recv_idx), (const float*)st.scratch_recv.data(),
-                   chunk_n(recv_idx), job.op);
+        // recv_idx of the last step is the chunk this rank owns: its
+        // next write is the final value, so the divisor goes in here
+        if (div != 1.0f && step == world_ - 2) {
+          reduce_sum_div_f32(chunk_ptr(recv_idx),
+                             (const float*)st.scratch_recv.data(),
+                             chunk_n(recv_idx), div);
+        } else {
+          reduce_f32(chunk_ptr(recv_idx),
+                     (const float*)st.scratch_recv.data(), chunk_n(recv_idx),
+                     job.op);
+        }
         break;
     }
   }
@@ -800,21 +840,22 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
     // forward what they received, zero re-encode work) and the owner
     // keeps the decode of its own bytes — every rank lands on the
     // identical f32 image by construction, not by fp-rounding luck
-    // (collectives.py's _ring_allreduce_codec is the same schedule)
+    // (collectives.py's _ring_allreduce_codec is the same schedule).
+    // The divisor is applied by each decode of those bytes: encode the
+    // sum, decode, divide — the order a trailing np.divide gave
     int owned = (rank_ + 1) % world_;
     size_t own_wire = wire_nbytes(codec, chunk_n(owned));
     switch (codec) {
       case DpCodec::kBf16:
         encode_bf16(chunk_ptr(owned), (uint16_t*)st.scratch_fwd.data(),
                     chunk_n(owned));
-        for (size_t i = 0; i < chunk_n(owned); ++i) {
-          chunk_ptr(owned)[i] =
-              bf16_to_f32(((const uint16_t*)st.scratch_fwd.data())[i]);
-        }
+        decode_bf16((const uint16_t*)st.scratch_fwd.data(), chunk_ptr(owned),
+                    chunk_n(owned), div);
         break;
       case DpCodec::kInt8:
         encode_int8(chunk_ptr(owned), st.scratch_fwd.data(), chunk_n(owned));
-        decode_int8(st.scratch_fwd.data(), chunk_ptr(owned), chunk_n(owned));
+        decode_int8(st.scratch_fwd.data(), chunk_ptr(owned), chunk_n(owned),
+                    div);
         break;
       default:
         break;
@@ -830,21 +871,15 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
         return fail();
       }
       if (codec == DpCodec::kBf16) {
-        const uint16_t* in = (const uint16_t*)spare;
-        float* dst = chunk_ptr(recv_idx);
-        for (size_t i = 0; i < cn; ++i) dst[i] = bf16_to_f32(in[i]);
+        decode_bf16((const uint16_t*)spare, chunk_ptr(recv_idx), cn, div);
       } else {
-        decode_int8(spare, chunk_ptr(recv_idx), cn);
+        decode_int8(spare, chunk_ptr(recv_idx), cn, div);
       }
       uint8_t* t = cur;
       cur = spare;
       spare = t;
       cur_n = rn;
     }
-  }
-  if (job.op == DpOp::kAvg) {
-    float inv = 1.0f / (float)world_;
-    for (int64_t i = 0; i < n; ++i) flat[i] *= inv;
   }
   return 0;
 }
@@ -888,9 +923,18 @@ void DataPlane::worker_loop(int stripe_idx) {
 }
 
 int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
-                         DpCodec codec, uint32_t tag, int64_t timeout_ms,
-                         int* bad_peer, std::string* err) {
+                         int divisor, DpCodec codec, uint32_t tag,
+                         int64_t timeout_ms, int* bad_peer, std::string* err) {
   *bad_peer = -1;
+  // AVG is SUM with the divisor `world`: one code path
+  if (op == DpOp::kAvg && divisor == 1) {
+    op = DpOp::kSum;
+    divisor = world_;
+  }
+  if (divisor < 1 || (divisor != 1 && op != DpOp::kSum)) {
+    *err = "a divisor goes with SUM only";
+    return -1;
+  }
   if (dtype != DpDtype::kF32) {
     *err = "unsupported dtype";
     return -1;
@@ -900,7 +944,11 @@ int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
     *err = "unsupported wire codec";
     return -1;
   }
-  if (world_ <= 1 || nelems == 0) return 0;
+  if (world_ <= 1) {
+    if (divisor != 1) divide_f32((float*)data, (size_t)nelems, (float)divisor);
+    return 0;
+  }
+  if (nelems == 0) return 0;
   int64_t deadline = now_ms() + timeout_ms;
   // stripe partition: contiguous, 16-element aligned so reduce loops stay
   // vectorizable and no stripe's chunk is pathologically small
@@ -913,6 +961,7 @@ int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
     st.job.base = (uint8_t*)((float*)data + sb[s]);
     st.job.nelems = sb[s + 1] - sb[s];
     st.job.op = op;
+    st.job.divisor = divisor;
     st.job.codec = codec;
     st.job.tag = tag + (uint32_t)s;
     st.job.deadline_ms = deadline;
@@ -995,7 +1044,9 @@ extern "C" {
 // v7: always-on sampling profiler (profiler.h): tft_prof_set_hz/hz/
 // snapshot/reset/samples_total — a stale build would fail the loader's
 // symbol lookup at import.
-int tft_abi_version() { return 7; }
+// v8: tft_dp_allreduce takes the divisor after `op` (the average is taken
+// inside the ring) — a stale library would read it as the codec.
+int tft_abi_version() { return 8; }
 
 int64_t tft_dp_create(int rank, int world, int nstripes, char* err,
                       int errlen) {
@@ -1057,7 +1108,7 @@ int tft_dp_enable_cma(int64_t h, const int64_t* pids, int n, char* err,
 }
 
 int tft_dp_allreduce(int64_t h, void* data, int64_t nelems, int dtype, int op,
-                     int codec, uint32_t tag, int64_t timeout_ms,
+                     int divisor, int codec, uint32_t tag, int64_t timeout_ms,
                      int* bad_peer, char* err, int errlen) {
   auto dp = dp_get(h);
   if (!dp) {
@@ -1067,7 +1118,8 @@ int tft_dp_allreduce(int64_t h, void* data, int64_t nelems, int dtype, int op,
   std::string e;
   int bp = -1;
   int rc = dp->allreduce(data, nelems, (tft::DpDtype)dtype, (tft::DpOp)op,
-                         (tft::DpCodec)codec, tag, timeout_ms, &bp, &e);
+                         divisor, (tft::DpCodec)codec, tag, timeout_ms, &bp,
+                         &e);
   if (bad_peer) *bad_peer = bp;
   if (rc != 0) dp_set_err(err, errlen, e);
   return rc;

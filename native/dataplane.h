@@ -28,7 +28,7 @@ namespace tft {
 
 // element dtypes on the local buffer
 enum class DpDtype : int { kF32 = 0 };
-// reduce ops (AVG divides after the allgather phase)
+// reduce ops (AVG is SUM with the divisor `world`; see allreduce)
 enum class DpOp : int { kSum = 0, kAvg = 1, kMax = 2, kMin = 3 };
 // wire codecs (torchft_tpu/wire_codec.py mirrors these formats byte for
 // byte; values must match the ctypes binding's NativeDataPlane.CODEC):
@@ -78,8 +78,15 @@ class DataPlane {
   // carries encoded bytes while accumulation stays f32; the allgather
   // phase forwards the chunk owner's wire bytes VERBATIM, so the decoded
   // average is bit-identical on every rank by construction.
+  // `divisor` (SUM only; >= 1) turns the sum into an average WITHOUT a
+  // pass of its own: the rank that owns a chunk divides in its last
+  // reduce-scatter step (exact planes) or every rank divides as it
+  // decodes the owner's bytes (lossy codecs) — a true f32 division by
+  // (float)divisor, bit for bit np.divide(sum, divisor). The caller
+  // names the divisor because it is not always `world`: the Manager
+  // counts participants, and a healing group's zeros are not one.
   int allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
-                DpCodec codec, uint32_t tag, int64_t timeout_ms,
+                int divisor, DpCodec codec, uint32_t tag, int64_t timeout_ms,
                 int* bad_peer, std::string* err);
 
   void shutdown();
@@ -89,6 +96,7 @@ class DataPlane {
     uint8_t* base = nullptr;   // stripe start
     int64_t nelems = 0;        // stripe elements
     DpOp op = DpOp::kSum;
+    int divisor = 1;           // see allreduce()
     DpCodec codec = DpCodec::kF32;
     uint32_t tag = 0;
     int64_t deadline_ms = 0;  // absolute, now_ms() clock

@@ -1,0 +1,134 @@
+#!/usr/bin/env python3
+"""The collectives op thread's account of a traced step, from a ``.xplane.pb``.
+
+The host-path exchange ends by waiting for ONE thread (``CollectivesTcp``'s
+op thread): it runs each bucket's ring (``tft.exchange.ring``), then the
+callbacks hung on the op's future — ``ddp``'s ``scatter``
+(``tft.exchange.h2d``) and, where the ring could not take the average, the
+fallback division (``tft.exchange.average``). This prints, per traced step
+(one ``tft.exchange`` span on the main thread), what that thread did between
+its first ring's start and its last callback's end: seconds in each span,
+and the rest — time with a ring already queued that no span names
+(PERF.md §5, "the op thread's account"). A gap in which the next ring had
+not been submitted yet is the main thread's (starved), not the op thread's.
+
+    python scripts/op_thread_account.py benchmark_runs/<cell>/trace.*   # after a --trace 1 run
+
+One JSON line a trace on stdout; needs ``jax.profiler.ProfileData`` only
+(no backend is initialised).
+"""
+
+from __future__ import annotations
+
+import glob
+import json
+import os
+import statistics
+import sys
+
+PREFIX = "tft.exchange"
+RING, H2D, AVERAGE = f"{PREFIX}.ring", f"{PREFIX}.h2d", f"{PREFIX}.average"
+
+
+def _xplane(path: str) -> str:
+    if os.path.isdir(path):
+        hits = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True))
+        if not hits:
+            raise SystemExit(f"no .xplane.pb under {path}")
+        return hits[-1]
+    return path
+
+
+def account(path: str) -> dict:
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(_xplane(path))
+    exchanges, by_line, counters = [], {}, []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for li, line in enumerate(plane.lines):
+            for ev in line.events:
+                if not ev.name.startswith(PREFIX):
+                    continue
+                s = float(ev.start_ns) / 1e9
+                e = s + float(ev.duration_ns) / 1e9
+                if ev.name == PREFIX:
+                    exchanges.append((s, e))
+                elif ev.name == f"{PREFIX}.counters":
+                    counters.append((s, dict(ev.stats)))
+                elif ev.name in (RING, H2D, AVERAGE):
+                    by_line.setdefault((plane.name, li), []).append(
+                        (s, e, ev.name, dict(ev.stats))
+                    )
+    # the op thread's line is the one that holds the rings
+    op = [evs for evs in by_line.values() if any(n == RING for _, _, n, _ in evs)]
+    steps = []
+    for lo, hi in sorted(exchanges):
+        evs = sorted(ev for line in op for ev in line if lo <= ev[0] < hi)
+        rings = [ev for ev in evs if ev[2] == RING]
+        if not rings:
+            continue
+        named = {RING: 0.0, H2D: 0.0, AVERAGE: 0.0}
+        for s, e, n, _ in evs:
+            named[n] += e - s
+        # between two rings: what no span covers, split at the next ring's
+        # submission (its start less queued_s): before it the thread had
+        # nothing to run
+        between = starved = 0.0
+        for a, b in zip(rings, rings[1:]):
+            submitted = b[0] - float(b[3].get("queued_s", 0.0))
+            x = a[1]
+            for s, e, n, _ in evs + [(b[0], b[0], "", {})]:
+                if n == RING or not a[1] <= s <= b[0]:
+                    continue
+                if s > x:
+                    cut = min(max(submitted, x), s)
+                    starved += cut - x
+                    between += s - cut
+                x = max(x, e)
+        end = max(e for _, e, _, _ in evs)
+        after = (end - rings[-1][1]) - sum(
+            e - s for s, e, n, _ in evs if n != RING and s >= rings[-1][1]
+        )
+        step = {
+            "rings": len(rings),
+            "ring_s": named[RING],
+            "h2d_s": named[H2D],
+            "average_s": named[AVERAGE],
+            "average_events": sum(1 for ev in evs if ev[2] == AVERAGE),
+            "between_rings_unnamed_s": between,
+            "after_last_ring_unnamed_s": after,
+            "starved_s": starved,
+            "op_thread_span_s": end - rings[0][0],
+            "first_ring_after_exchange_start_s": rings[0][0] - lo,
+            "exchange_s": hi - lo,
+            "last_ring_queued_s": float(rings[-1][3].get("queued_s", 0.0)),
+            "ring_divisors": sorted({r[3].get("divisor") for r in rings}, key=str),
+        }
+        for s, stats in counters:
+            if lo <= s <= hi + 1e-3:
+                for k in ("buckets", "buckets_reused", "buckets_avg_in_ring"):
+                    if k in stats:
+                        step[k] = stats[k]
+        steps.append(step)
+    out = {"trace": path, "steps": steps}
+    if steps:
+        out["median"] = {
+            k: statistics.median(st[k] for st in steps)
+            for k, v in steps[0].items()
+            if isinstance(v, float)
+        }
+    return out
+
+
+def main(argv) -> int:
+    if not argv:
+        raise SystemExit(__doc__)
+    for path in argv:
+        print(json.dumps(account(path)))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

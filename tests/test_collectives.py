@@ -780,3 +780,172 @@ class TestCmaP2P:
         assert outs[0][0] and outs[1][0], outs
         assert outs[0][1] == "tcp-striped" and outs[1][1] == "tcp-striped"
         assert outs[0][2] == 3.0 and outs[1][2] == 3.0
+
+
+def _bits(a):
+    return np.ascontiguousarray(a).view(np.uint32)
+
+
+class TestAverageInRing:
+    """The divisor travels with the allreduce. The native ring applies it
+    where an element's final f32 value is written — the chunk owner's
+    last reduce-scatter step on the exact planes, the decode of the
+    owner's wire bytes on the lossy ones — and the result is bit for bit
+    what a trailing ``np.divide(sum, n, out=sum)`` gave; everything else
+    divides through ``average_in_place``."""
+
+    # sizes that are no multiple of world x stripes x 16; 67 < stripes x 64
+    # takes the one-stripe route
+    SIZES = (100003, 67)
+
+    @staticmethod
+    def _sum_and_avg(world, divisor, zero_last=False):
+        def fn(c, rank):
+            rng = np.random.default_rng(11 + rank)
+            out = []
+            for size in TestAverageInRing.SIZES:
+                a = (rng.standard_normal(size) * 3).astype(np.float32)
+                if zero_last and rank == world - 1:
+                    a[...] = 0  # a healing group's contribution
+                t = timedelta(seconds=20)
+                summed = c.allreduce([a.copy()], ReduceOp.SUM).wait(t)[0]
+                before = c.avg_in_ring_ops()
+                avg = c.allreduce([a.copy()], ReduceOp.SUM, divisor).wait(t)[0]
+                out.append((summed, avg, c.avg_in_ring_ops() - before))
+            return c.plane_info(), out
+
+        return fn
+
+    @pytest.mark.parametrize("cma", ["1", "0"])
+    @pytest.mark.parametrize(
+        "world,divisor", [(2, 1), (2, 2), (3, 3), (3, 2), (4, 4), (4, 3), (4, 1)]
+    )
+    def test_native_f32_equals_np_divide_bit_for_bit(
+        self, store, monkeypatch, cma, world, divisor
+    ):
+        monkeypatch.setenv("TORCHFT_DP_CMA", cma)
+        outs = _run_world(
+            store, world,
+            self._sum_and_avg(world, divisor, zero_last=divisor < world),
+            prefix=f"air{cma}{world}{divisor}",
+        )
+        assert {p for p, _ in outs} == {"cma" if cma == "1" else "tcp-striped"}
+        for _, per_size in outs:
+            for k, (summed, avg, in_ring) in enumerate(per_size):
+                expect = summed.copy()
+                np.divide(expect, divisor, out=expect)
+                np.testing.assert_array_equal(_bits(avg), _bits(expect))
+                # every rank holds identical bits
+                np.testing.assert_array_equal(_bits(avg), _bits(outs[0][1][k][1]))
+                assert in_ring == (1 if divisor > 1 else 0)
+
+    @pytest.mark.parametrize("codec", ["bfloat16", "int8"])
+    @pytest.mark.parametrize("world,divisor", [(2, 2), (3, 3), (4, 3)])
+    def test_lossy_codec_equals_encode_decode_divide_bit_for_bit(
+        self, store, monkeypatch, codec, world, divisor
+    ):
+        monkeypatch.setenv("TORCHFT_DP_CMA", "0")  # cma bypasses the codec
+        outs = _run_world(
+            store, world, self._sum_and_avg(world, divisor),
+            prefix=f"airc{codec}{world}", wire_dtype=codec,
+        )
+        assert {p for p, _ in outs} == {"tcp-striped"}
+        for _, per_size in outs:
+            for k, (summed, avg, in_ring) in enumerate(per_size):
+                expect = summed.copy()  # the decoded sum, as today
+                np.divide(expect, divisor, out=expect)
+                np.testing.assert_array_equal(_bits(avg), _bits(expect))
+                np.testing.assert_array_equal(_bits(avg), _bits(outs[0][1][k][1]))
+                assert in_ring == 1
+
+    @pytest.mark.parametrize("cma", ["1", "0"])
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_avg_is_sum_over_world(self, store, monkeypatch, cma, world):
+        monkeypatch.setenv("TORCHFT_DP_CMA", cma)
+
+        def fn(c, rank):
+            a = (np.random.default_rng(3 + rank).standard_normal(4099) * 7).astype(
+                np.float32
+            )
+            t = timedelta(seconds=20)
+            summed = c.allreduce([a.copy()], ReduceOp.SUM).wait(t)[0]
+            return summed, c.allreduce([a.copy()], ReduceOp.AVG).wait(t)[0]
+
+        for summed, avg in _run_world(store, world, fn, prefix=f"avg{cma}{world}"):
+            np.divide(summed, world, out=summed)  # a division, not x * (1/3)
+            np.testing.assert_array_equal(_bits(avg), _bits(summed))
+
+    def test_a_divisor_goes_with_sum_only(self, store):
+        def fn(c, rank):
+            a = np.ones(4, np.float32)
+            with pytest.raises(ValueError, match="SUM only"):
+                c.allreduce([a], ReduceOp.MAX, 2)
+            with pytest.raises(ValueError, match="SUM only"):
+                c.allreduce([a], ReduceOp.AVG, 2)
+            with pytest.raises(ValueError, match="SUM only"):
+                c.allreduce([a], ReduceOp.SUM, 0)
+            return True
+
+        assert all(_run_world(store, 2, fn, prefix="sumonly"))
+
+    @pytest.mark.parametrize(
+        "case", ["python-ring", "f64-beside-f32", "python-codec", "world-1"]
+    )
+    def test_what_cannot_fuse_goes_through_the_one_helper(
+        self, store, monkeypatch, case
+    ):
+        from torchft_tpu import collectives
+
+        seen = []
+        real = collectives.average_in_place
+
+        def spy(arrays, divisor):
+            if divisor != 1 and arrays:
+                seen.append((len(arrays), divisor))
+            return real(arrays, divisor)
+
+        monkeypatch.setattr(collectives, "average_in_place", spy)
+        kwargs = {"python-ring": {"native_plane": False},
+                  "python-codec": {"native_plane": False, "wire_dtype": "bfloat16"}}
+        world = 1 if case == "world-1" else 2
+
+        def fn(c, rank):
+            arrays = [np.full(4099, rank + 1.0, np.float32)]
+            if case == "f64-beside-f32":
+                arrays.append(np.full(515, rank + 1.0, np.float64))
+            out = c.allreduce(arrays, ReduceOp.SUM, 2).wait(timedelta(seconds=20))
+            return [a.copy() for a in out], c.avg_in_ring_ops()
+
+        outs = _run_world(store, world, fn, prefix=f"fb{case}", **kwargs.get(case, {}))
+        total = sum(range(1, world + 1)) / 2
+        for arrays, in_ring in outs:
+            for a in arrays:
+                np.testing.assert_array_equal(a, np.full(a.shape, total, a.dtype))
+            assert in_ring == 0  # an op counts only if EVERY array fused
+        # one pass per op and rank, over what the ring did not divide
+        assert seen == [(1, 2)] * world
+
+    def test_dummy_divides_through_the_helper(self, monkeypatch):
+        from torchft_tpu import collectives
+
+        seen = []
+        real = collectives.average_in_place
+        monkeypatch.setattr(
+            collectives, "average_in_place",
+            lambda arrays, d: (seen.append(d), real(arrays, d))[1],
+        )
+        c = CollectivesDummy(rank=0, world_size=1)
+        a = np.array([3.0, 6.0], np.float32)
+        assert c.allreduce([a], ReduceOp.SUM, 3).wait()[0] is a
+        np.testing.assert_array_equal(a, [1.0, 2.0])
+        assert seen == [3] and c.avg_in_ring_ops() == 0
+
+    def test_error_swallowing_forwards_divisor_and_count(self, store):
+        inner = CollectivesTcp(timeout=timedelta(seconds=5), hostname="localhost")
+        wrap = ErrorSwallowingCollectives(inner)
+        wrap.configure(f"{store.address()}/eswd", 0, 1)
+        a = np.array([2.0, 4.0], np.float32)
+        np.testing.assert_array_equal(wrap.allreduce([a], ReduceOp.SUM, 2).wait()[0], [1.0, 2.0])
+        inner._avg_in_ring_ops = 7
+        assert wrap.avg_in_ring_ops() == 7
+        wrap.shutdown()

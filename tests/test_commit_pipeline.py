@@ -413,11 +413,11 @@ class FaultyDummy(CollectivesDummy):
         self.fault_calls = set(fault_calls)
         self.calls = 0
 
-    def allreduce(self, arrays, op=None):
+    def allreduce(self, arrays, op=None, divisor=1):
         self.calls += 1
         if self.calls in self.fault_calls:
             raise PeerGoneError(0, f"peer 0 died mid-op (call {self.calls})")
-        return super().allreduce(arrays)
+        return super().allreduce(arrays, divisor=divisor)
 
 
 def _tree_checksum(tree) -> str:

@@ -61,11 +61,11 @@ def store_server():
 
 
 class ManagerHarness:
-    def __init__(self, store_server, **kwargs):
+    def __init__(self, store_server, collectives=None, **kwargs):
         self.store = StoreClient(store_server.address())
         self.store.set(MANAGER_ADDR_KEY, "dummy")
         self.store.set(REPLICA_ID_KEY, "dummy_id")
-        self.collectives = CollectivesDummy(rank=0, world_size=1)
+        self.collectives = collectives or CollectivesDummy(rank=0, world_size=1)
         self.load_state_dict = MagicMock()
         self.transport = MagicMock()
         self.transport.metadata.return_value = "transport_meta"
@@ -79,8 +79,14 @@ class ManagerHarness:
         kwargs.setdefault("timeout", timedelta(seconds=10))
         # patch stays active for the harness lifetime: the healing path
         # constructs a second ManagerClient for the recovery source
-        self._patcher = patch("torchft_tpu.manager.ManagerClient", autospec=True)
-        self._patcher.start()
+        # (a second harness alive at once shares the patch and gets a
+        # client double of its own below)
+        import torchft_tpu.manager as manager_mod
+
+        self._patcher = None
+        if not isinstance(manager_mod.ManagerClient, MagicMock):
+            self._patcher = patch("torchft_tpu.manager.ManagerClient", autospec=True)
+            self._patcher.start()
         self.manager = Manager(
             collectives=self.collectives,
             load_state_dict=self.load_state_dict,
@@ -91,11 +97,14 @@ class ManagerHarness:
             checkpoint_transport=self.transport,
             **kwargs,
         )
+        if self._patcher is None:
+            self.manager._client = MagicMock()
         self.client = self.manager._client
 
     def shutdown(self):
         self.manager.shutdown(wait=False)
-        self._patcher.stop()
+        if self._patcher is not None:
+            self._patcher.stop()
 
 
 @pytest.fixture
@@ -463,11 +472,11 @@ def test_pipelined_averaging_latches_midway_error(harness):
     calls = {"n": 0}
     real_allreduce = h.collectives.allreduce
 
-    def flaky(arrays, op=ReduceOp.SUM):
+    def flaky(arrays, op=ReduceOp.SUM, divisor=1):
         calls["n"] += 1
         if calls["n"] == 2:
             raise PeerGoneError(0, "peer died mid-bucket")
-        return real_allreduce(arrays, op)
+        return real_allreduce(arrays, op, divisor)
 
     h.collectives.allreduce = flaky
 
@@ -503,11 +512,11 @@ def test_step_after_a_latched_error_packs_into_new_buffers(harness, monkeypatch)
     real_allreduce = h.collectives.allreduce
     calls = {"n": 0, "fail_at": None}
 
-    def flaky(arrays, op=ReduceOp.SUM):
+    def flaky(arrays, op=ReduceOp.SUM, divisor=1):
         calls["n"] += 1
         if calls["n"] == calls["fail_at"]:
             raise PeerGoneError(0, "peer died mid-bucket")
-        return real_allreduce(arrays, op)
+        return real_allreduce(arrays, op, divisor)
 
     h.collectives.allreduce = flaky
 
@@ -557,3 +566,155 @@ def test_start_quorum_retries_after_timeout(harness):
     m.start_quorum()
     m.wait_quorum()
     assert m.num_participants() == 2
+
+
+def test_the_divisor_travels_with_the_op_and_normalize_leaves_the_bytes(harness):
+    """Host path: the Manager hands its participant count to the data plane
+    with the op (SUM + divisor) and never divides the buffers itself."""
+    from torchft_tpu.collectives import ReduceOp, Work
+
+    h = harness()
+    m = h.manager
+    h.client._quorum.return_value = quorum_result(max_rank=1)
+    m.start_quorum()
+    seen = []
+
+    def recording(arrays, op=ReduceOp.SUM, divisor=1):
+        seen.append((op, divisor))
+        return Work.completed(arrays)  # a plane that "forgot" to divide
+
+    h.collectives.allreduce = recording
+    t = np.array([2.0, 4.0], dtype=np.float32)
+    assert m.allreduce_many([t]).wait()[0] is t
+    assert seen == [(ReduceOp.SUM, 2)]
+    np.testing.assert_array_equal(t, [2.0, 4.0])  # nobody else divided
+    assert m.avg_in_ring_ops() == 0
+
+
+@pytest.mark.parametrize("cma", ["1", "0"])
+def test_a_spares_zeros_leave_the_participants_average_unperturbed(
+    store_server, harness, monkeypatch, cma
+):
+    """Three groups on the native ring, two participants and a spare: the
+    ring divides by the PARTICIPANTS (2), not the world (3), the spare's
+    zeros add nothing, and all three hold np.divide(a0 + a1, 2) bit for
+    bit — averaged inside the ring, no pass on the host."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from torchft_tpu.collectives import CollectivesTcp
+
+    monkeypatch.setenv("TORCHFT_DP_CMA", cma)
+    hs = [
+        harness(
+            collectives=CollectivesTcp(
+                hostname="localhost", timeout=timedelta(seconds=20)
+            ),
+            world_size_mode=WorldSizeMode.FIXED_WITH_SPARES,
+        )
+        for _ in range(3)
+    ]
+    data = [
+        (np.random.default_rng(40 + r).standard_normal(100003) * 5).astype(np.float32)
+        for r in range(3)
+    ]
+
+    def run(r):
+        h = hs[r]
+        q = quorum_result(
+            max_rank=r, max_world_size=3, replica_rank=r, replica_world_size=3
+        )
+        q.store_address = f"{store_server.address()}/spare{cma}"
+        h.client._quorum.return_value = q
+        h.manager.start_quorum()
+        buf = data[r].copy()
+        out = h.manager.allreduce_many([buf]).wait()[0]
+        assert h.manager.errored() is None
+        return out, h.manager.is_participating(), h.manager.avg_in_ring_ops()
+
+    with ThreadPoolExecutor(max_workers=3) as ex:
+        outs = list(ex.map(run, range(3)))
+    assert [p for _, p, _ in outs] == [True, True, False]
+    expect = data[0] + data[1]
+    np.divide(expect, 2, out=expect)
+    for out, _, in_ring in outs:
+        np.testing.assert_array_equal(out.view(np.uint32), expect.view(np.uint32))
+        assert in_ring == 1
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native-ring", "python-ring"])
+def test_the_trace_says_where_the_average_was_taken(
+    store_server, harness, tmp_path, native
+):
+    """Two groups exchange four buckets under a profiler session. On the
+    native f32 path every ``tft.exchange.ring`` carries ``divisor`` 2, no
+    ``tft.exchange.average`` runs, and the ``exchange`` span and
+    ``tft.exchange.counters`` count ``buckets_avg_in_ring`` = ``buckets``;
+    on the Python ring the divisor stat is 0, the counter 0, and the
+    fallback pass appears under its own name, once a bucket."""
+    import glob
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+
+    from torchft_tpu import ddp
+    from torchft_tpu.collectives import CollectivesTcp
+    from torchft_tpu.telemetry import tracing
+
+    hs = [
+        harness(
+            collectives=CollectivesTcp(
+                hostname="localhost", timeout=timedelta(seconds=20),
+                native_plane=native,
+            )
+        )
+        for _ in range(2)
+    ]
+
+    def run(r):
+        h = hs[r]
+        q = quorum_result(max_rank=r, replica_rank=r)
+        q.store_address = f"{store_server.address()}/where{int(native)}"
+        h.client._quorum.return_value = q
+        h.manager.start_quorum()
+        grads = {f"g{i}": np.full((1024,), float(r + i), np.float32) for i in range(4)}
+        out = ddp.allreduce_gradients(h.manager, grads, bucket_bytes=4096)
+        assert h.manager.errored() is None
+        return out
+
+    tracing.TRACER.clear()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with ThreadPoolExecutor(max_workers=2) as ex:
+            outs = list(ex.map(run, range(2)))
+    finally:
+        jax.profiler.stop_trace()
+    for out in outs:
+        for i in range(4):
+            np.testing.assert_array_equal(np.asarray(out[f"g{i}"]), i + 0.5)
+
+    spans = [s for s in tracing.TRACER.recent() if s["name"] == "exchange"]
+    assert len(spans) == 2
+    for s in spans:
+        assert s["attrs"]["buckets"] == 4
+        assert s["attrs"]["buckets_avg_in_ring"] == (4 if native else 0)
+
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    by_name = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("tft.exchange"):
+                        by_name.setdefault(ev.name, []).append(dict(ev.stats))
+    rings = by_name["tft.exchange.ring"]
+    assert len(rings) == 8
+    assert {r["divisor"] for r in rings} == {2 if native else 0}
+    averages = by_name.get("tft.exchange.average", [])
+    assert len(averages) == (0 if native else 8)
+    assert all(a["divisor"] == 2 and a["bytes"] == 4096 for a in averages)
+    counters = by_name["tft.exchange.counters"]
+    assert [c["buckets_avg_in_ring"] for c in counters] == [4 if native else 0] * 2

@@ -112,6 +112,21 @@ class TestCollectivesProxy:
         np.testing.assert_array_equal(a.astype(np.float32), 1.5)
         np.testing.assert_array_equal(b.astype(np.float32), 1.5)
 
+    @pytest.mark.parametrize("n", [3, 1 << 16], ids=["pickle", "shm"])
+    def test_allreduce_forwards_the_divisor(self, proxy_pair, n):
+        """The divisor goes to the child with the op, on the pickle path
+        and the shared-memory path: its backend takes the average."""
+        a = np.full(n, 1.0, dtype=np.float32)
+        b = np.full(n, 4.0, dtype=np.float32)
+        w0 = proxy_pair[0].allreduce([a], ReduceOp.SUM, 2)
+        w1 = proxy_pair[1].allreduce([b], ReduceOp.SUM, divisor=2)
+        w0.wait(timeout=timedelta(seconds=20))
+        w1.wait(timeout=timedelta(seconds=20))
+        np.testing.assert_array_equal(a, np.full(n, 2.5, np.float32))
+        np.testing.assert_array_equal(b, np.full(n, 2.5, np.float32))
+        # the child's ring is out of the parent's sight
+        assert proxy_pair[0].avg_in_ring_ops() == 0
+
     def test_allreduce_in_place(self, proxy_pair):
         a = np.array([1.0, 2.0], dtype=np.float32)
         b = np.array([3.0, 4.0], dtype=np.float32)

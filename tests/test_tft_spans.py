@@ -196,15 +196,21 @@ def test_bucket_stats_tie_the_threads_together(traced):
     # the op thread runs the rings in submission order: order is the link
     ring = sorted(everything["exchange.ring"])
     assert [s["bytes"] for _, _, s in ring] == [s["bytes"] for _, _, s in sorted(main["exchange.submit"])]
-    assert all(set(s) == {"bytes", "queued_s"} and s["queued_s"] >= 0 for _, _, s in ring)
+    # one group: nothing to divide by, so the ring says it did not divide
+    assert all(
+        set(s) == {"bytes", "queued_s", "divisor"} and s["queued_s"] >= 0 and s["divisor"] == 0
+        for _, _, s in ring
+    )
+    assert "exchange.average" not in everything
     (_, _, counters), *_ = [c for c in main["exchange.counters"] if c[2]["step"] == 1]
     assert counters["buckets"] == len(keys["exchange.submit"]) >= 3
     assert counters["bytes_d2h"] == total
     assert set(counters) == {
-        "step", "buckets", "buckets_reused", "d2h_pages_kept", "bytes_d2h", "d2h_wait_s", "pack_s",
-        "tail_wait_s", "utime_s", "stime_s",
+        "step", "buckets", "buckets_reused", "buckets_avg_in_ring", "d2h_pages_kept", "bytes_d2h",
+        "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
     }
     assert all(counters[key] >= 0 for key in counters)
+    assert counters["buckets_avg_in_ring"] == 0
     # a zero-length carrier at the end of its exchange
     for (s, e, _), (s0, e0, _) in zip(main["exchange.counters"], main["exchange"]):
         assert s0 <= s and e <= e0 and e - s < 1e6
@@ -234,8 +240,8 @@ def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, mo
     assert last["attrs"]["committed"] is True
     (exchange,) = [s for s in children if s["name"] == "exchange"]
     assert set(exchange["attrs"]) == {
-        "step", "buckets", "buckets_reused", "d2h_pages_kept", "bytes_d2h", "d2h_wait_s", "pack_s",
-        "tail_wait_s", "utime_s", "stime_s",
+        "step", "buckets", "buckets_reused", "buckets_avg_in_ring", "d2h_pages_kept", "bytes_d2h",
+        "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
     }
     # nothing per bucket, and at most 12 new entries a step
     assert not [s for s in spans if s["name"].startswith("exchange.")]

@@ -52,7 +52,9 @@ _TIMEOUT_CODES = (CANCELLED, DEADLINE_EXCEEDED)
 # v7: always-on sampling profiler (profiler.h): tft_prof_set_hz/hz/
 #     snapshot/reset/samples_total + /diagnosis.json bundle index — an
 #     old build would fail the loader's symbol lookup at import.
-_ABI_VERSION = 7
+# v8: tft_dp_allreduce takes the divisor after `op` (the average is taken
+#     inside the ring) — an old build would read it as the codec.
+_ABI_VERSION = 8
 
 
 def _build(force: bool = False) -> None:
@@ -260,7 +262,7 @@ def _load() -> ctypes.CDLL:
     ]
     lib.tft_dp_enable_cma.restype = c.c_int
     lib.tft_dp_allreduce.argtypes = [
-        c.c_int64, c.c_void_p, c.c_int64, c.c_int, c.c_int, c.c_int,
+        c.c_int64, c.c_void_p, c.c_int64, c.c_int, c.c_int, c.c_int, c.c_int,
         c.c_uint32, c.c_int64, c.POINTER(c.c_int), c.c_char_p, c.c_int,
     ]
     lib.tft_dp_allreduce.restype = c.c_int
@@ -747,17 +749,22 @@ class NativeDataPlane:
         codec: "int | str" = 0,
         tag: int = 0,
         timeout_ms: int = 60000,
+        divisor: int = 1,
     ) -> None:
         """In-place f32 ring allreduce on the buffer at ``ptr``. Blocking —
         call from the collectives op thread; the GIL is released.
         ``codec`` selects the wire format (``CODEC`` map / DpCodec enum):
         lossy codecs quantize on the wire while accumulation stays f32,
-        and the decoded result is bit-identical on every rank."""
+        and the decoded result is bit-identical on every rank.
+        ``divisor`` (``"sum"`` only) makes the sum an average inside the
+        ring — each element divided once, where its final f32 value is
+        written, bit for bit ``np.divide(sum, divisor)``; ``"avg"`` is
+        ``"sum"`` with the divisor ``world``."""
         err = _errbuf()
         bad_peer = ctypes.c_int(-1)
         codec_i = self.CODEC[codec] if isinstance(codec, str) else int(codec)
         rc = _lib.tft_dp_allreduce(
-            self._h, ptr, nelems, self.DTYPE_F32, self.OP[op],
+            self._h, ptr, nelems, self.DTYPE_F32, self.OP[op], int(divisor),
             codec_i, tag, timeout_ms,
             ctypes.byref(bad_peer), err, _ERRLEN,
         )

@@ -36,7 +36,7 @@ from abc import ABC, abstractmethod
 from concurrent.futures import ThreadPoolExecutor
 from datetime import timedelta
 from enum import Enum
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -58,6 +58,8 @@ __all__ = [
     "PeerGoneError",
     "record_wire_stage",
     "wire_stage_snapshot",
+    "average_in_place",
+    "resolve_divisor",
 ]
 
 
@@ -126,6 +128,40 @@ _REDUCE_FNS: Dict[ReduceOp, Callable[[np.ndarray, np.ndarray], np.ndarray]] = {
 }
 
 
+def resolve_divisor(
+    op: ReduceOp, divisor: int, world: int
+) -> Tuple[ReduceOp, int]:
+    """What an allreduce's ``(op, divisor)`` means to a backend: ``AVG`` is
+    ``SUM`` with the divisor ``world``, and a divisor goes with ``SUM``
+    only — so a backend reduces with ONE path, sum then divide once."""
+    if op == ReduceOp.AVG and divisor == 1:
+        return ReduceOp.SUM, max(world, 1)
+    if divisor < 1 or (divisor != 1 and op != ReduceOp.SUM):
+        raise ValueError(
+            f"allreduce: divisor {divisor} with {op}; a divisor goes with SUM only"
+        )
+    return op, divisor
+
+
+def average_in_place(arrays: List[np.ndarray], divisor: int) -> None:
+    """The allreduce's division where it cannot join the ring: one NumPy
+    pass over each buffer, on the thread that completes the op. Every
+    backend whose reduction cannot apply ``divisor`` as it writes an
+    element's final value (the Python ring, a dtype or codec the native
+    plane does not take, a world of one, :class:`CollectivesDummy`) comes
+    through here, so a trace shows the pass wherever it still runs:
+    ``tft.exchange.average`` (stats ``bytes``, ``divisor``)."""
+    if divisor == 1 or not arrays:
+        return
+    with tracing.annotate(
+        "exchange.average",
+        bytes=sum(int(a.nbytes) for a in arrays),
+        divisor=divisor,
+    ):
+        for a in arrays:
+            np.divide(a, divisor, out=a)
+
+
 class Work:
     """Async op handle (torch Work analogue)."""
 
@@ -158,8 +194,23 @@ class Collectives(ABC):
         call repeatedly; each call fully replaces connectivity."""
 
     @abstractmethod
-    def allreduce(self, arrays: List[np.ndarray], op: ReduceOp = ReduceOp.SUM) -> Work:
-        """In-place allreduce of each array; future resolves to the list."""
+    def allreduce(
+        self,
+        arrays: List[np.ndarray],
+        op: ReduceOp = ReduceOp.SUM,
+        divisor: int = 1,
+    ) -> Work:
+        """In-place allreduce of each array; future resolves to the list.
+
+        ``divisor`` (with ``SUM`` only) makes the sum an average: every
+        element of the result is the sum divided ONCE by ``divisor`` in
+        the array's own dtype — for f32 bit for bit
+        ``np.divide(sum, divisor, out=sum)``. The caller names it because
+        it is not always the world size (the Manager divides by the
+        participants; a healing group's zeros are not one). A backend
+        applies it where the final value is written if it can (the native
+        ring, a device psum) and through :func:`average_in_place` if it
+        cannot. ``AVG`` is ``SUM`` with the divisor ``size()``."""
 
     @abstractmethod
     def allgather(self, arr: np.ndarray) -> Work:
@@ -219,6 +270,15 @@ class Collectives(ABC):
         :class:`~torchft_tpu.wire_codec.ErrorFeedback` compensates for;
         wrappers must delegate to the inner backend."""
         return "f32"
+
+    def avg_in_ring_ops(self) -> int:
+        """How many allreduces this backend has completed whose
+        ``divisor`` (> 1) was applied inside the reduction for every
+        array, with no :func:`average_in_place` pass (monotonic; read as a
+        difference, e.g. ``ddp``'s ``buckets_avg_in_ring``). Wrappers
+        must delegate; a backend that cannot tell (the proxy's parent
+        side) says 0."""
+        return 0
 
     def shutdown(self) -> None:  # noqa: B027 — optional hook
         pass
@@ -481,6 +541,7 @@ class CollectivesTcp(Collectives):
         self._ring_send_worker: Optional[ThreadPoolExecutor] = None
         self._p2p: Optional[ThreadPoolExecutor] = None
         self._op_seq = 0
+        self._avg_in_ring_ops = 0  # written on the op thread only
 
     # -- lifecycle --
 
@@ -744,6 +805,9 @@ class CollectivesTcp(Collectives):
         if self._dp is not None and getattr(self, "_dp_cma", False):
             return "f32"
         return self._codec.name
+
+    def avg_in_ring_ops(self) -> int:
+        return self._avg_in_ring_ops
 
     def _epoch_scratch(self, dtype: np.dtype, nelems: int,
                        slot: str = "") -> np.ndarray:
@@ -1256,8 +1320,14 @@ class CollectivesTcp(Collectives):
 
     # -- collectives (all run on the op thread, SPMD-ordered) --
 
-    def allreduce(self, arrays: List[np.ndarray], op: ReduceOp = ReduceOp.SUM) -> Work:
-        world, rank = self._world, self._rank
+    def allreduce(
+        self,
+        arrays: List[np.ndarray],
+        op: ReduceOp = ReduceOp.SUM,
+        divisor: int = 1,
+    ) -> Work:
+        world = self._world
+        op, divisor = resolve_divisor(op, divisor, world)
         tag = self._next_tag() | 0x01000000
         nbytes = sum(int(a.nbytes) for a in arrays)
         # counted at submission like every other op (uniform semantics);
@@ -1269,22 +1339,29 @@ class CollectivesTcp(Collectives):
             from torchft_tpu import telemetry
 
             t0 = time.perf_counter()
+            # what the native ring takes divides as it writes; the rest is
+            # summed here and divided after the span, under its own name
+            native = [world > 1 and self._dp_eligible(a) for a in arrays]
+            in_ring = divisor > 1 and bool(arrays) and all(native)
             # queued_s: the op's wait for this one thread. Ops run here in
             # submission order, which is what ties an event to its submitter
             with tracing.annotate(
-                "exchange.ring", bytes=nbytes, queued_s=t0 - t_submit
+                "exchange.ring", bytes=nbytes, queued_s=t0 - t_submit,
+                divisor=divisor if in_ring else 0,
             ):
                 if world > 1:
                     # ops are serialized on the op thread, so arrays of one
                     # allreduce may share the tag (it is a desync check, not
                     # a demultiplexer; the native plane offsets per-stripe)
-                    for arr in arrays:
-                        if self._dp_eligible(arr):
-                            self._dp_allreduce(arr, op, tag)
+                    for arr, dp in zip(arrays, native):
+                        if dp:
+                            self._dp_allreduce(arr, op, tag, divisor)
                         else:
                             self._ring_allreduce(arr, op, tag)
-                            if op == ReduceOp.AVG:
-                                np.divide(arr, world, out=arr)
+            average_in_place(
+                [a for a, dp in zip(arrays, native) if not dp], divisor
+            )
+            self._avg_in_ring_ops += in_ring
             telemetry.record_collective(
                 "allreduce", nbytes, time.perf_counter() - t0,
                 self.plane_info(), count_op=False,
@@ -1307,11 +1384,14 @@ class CollectivesTcp(Collectives):
 
         return self._codec.name in NativeDataPlane.CODEC
 
-    def _dp_allreduce(self, arr: np.ndarray, op: ReduceOp, tag: int) -> None:
-        """Hot path: the striped C++ ring (AVG divides natively; the wire
-        codec — bf16 or int8 — runs in C++, with the same owner-bytes
-        verbatim allgather as the Python ring so the decoded average is
-        bit-identical on every rank)."""
+    def _dp_allreduce(
+        self, arr: np.ndarray, op: ReduceOp, tag: int, divisor: int
+    ) -> None:
+        """Hot path: the striped C++ ring (it applies ``divisor`` where an
+        element's final value is written; the wire codec — bf16 or int8 —
+        runs in C++, with the same owner-bytes verbatim allgather as the
+        Python ring so the decoded average is bit-identical on every
+        rank)."""
         import time as _time
 
         from torchft_tpu._native import DataPlaneError
@@ -1328,6 +1408,7 @@ class CollectivesTcp(Collectives):
                 self._codec.name,  # resolved via NativeDataPlane.CODEC
                 tag,
                 int(self._timeout.total_seconds() * 1000),
+                divisor,
             )
         except DataPlaneError as e:
             if e.peer_rank >= 0:
@@ -1632,7 +1713,8 @@ class CollectivesDummy(Collectives):
         self._rank, self._world = rank, world_size
         self.configure_count += 1
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, divisor=1):
+        average_in_place(arrays, divisor)
         return Work.completed(arrays)
 
     def allgather(self, arr):
@@ -1686,6 +1768,9 @@ class ErrorSwallowingCollectives(Collectives):
     def wire_codec(self) -> str:
         return self._inner.wire_codec()
 
+    def avg_in_ring_ops(self) -> int:
+        return self._inner.avg_in_ring_ops()
+
     def report_error(self, e: Exception) -> None:
         self._error = e
 
@@ -1714,8 +1799,12 @@ class ErrorSwallowingCollectives(Collectives):
 
         return Work(work.get_future().then(swallow))
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
-        return self._guard(lambda: self._inner.allreduce(arrays, op), arrays)
+    def allreduce(self, arrays, op=ReduceOp.SUM, divisor=1):
+        # a swallowed failure hands back the buffers as the failed op left
+        # them — half summed, divided or not: the step never commits
+        return self._guard(
+            lambda: self._inner.allreduce(arrays, op, divisor), arrays
+        )
 
     def allgather(self, arr):
         return self._guard(
@@ -1768,9 +1857,11 @@ class ManagedCollectives(Collectives):
     def configure(self, store_addr: str, rank: int, world_size: int) -> None:
         raise RuntimeError("ManagedCollectives is configured by its Manager")
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, divisor=1):
         if len(arrays) != 1:
             raise ValueError("ManagedCollectives.allreduce takes a single array")
+        if divisor != 1:
+            raise ValueError("the Manager divides by its participants itself")
         return Work(self._manager.allreduce(arrays[0]))
 
     def allgather(self, arr):

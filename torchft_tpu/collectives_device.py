@@ -44,7 +44,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from torchft_tpu.collectives import Collectives, ReduceOp, Work
+from torchft_tpu.collectives import Collectives, ReduceOp, Work, resolve_divisor
 from torchft_tpu.futures import Future, future_timeout
 
 __all__ = ["CollectivesDevice"]
@@ -144,12 +144,14 @@ _PSUM_CACHE: Dict[Tuple, Callable] = {}
 _PSUM_CACHE_LOCK = threading.Lock()
 
 
-def _reduction_fn(mesh, specs: Tuple, op: ReduceOp, world: int) -> Callable:
-    """Jitted shard_map reduction over the 'ft' axis; cached per
-    (mesh, specs, op, world) so steady-state steps never recompile."""
+def _reduction_fn(mesh, specs: Tuple, op: ReduceOp, divisor: int) -> Callable:
+    """Jitted shard_map reduction over the 'ft' axis, divided by
+    ``divisor`` where it is not 1 (AVG arrives as SUM with the divisor
+    ``world``: ``collectives.resolve_divisor``); cached per
+    (mesh, specs, op, divisor) so steady-state steps never recompile."""
     import jax
 
-    key = (mesh, specs, op, world)
+    key = (mesh, specs, op, divisor)
     with _PSUM_CACHE_LOCK:
         fn = _PSUM_CACHE.get(key)
     if fn is not None:
@@ -157,15 +159,14 @@ def _reduction_fn(mesh, specs: Tuple, op: ReduceOp, world: int) -> Callable:
 
     red = {
         ReduceOp.SUM: jax.lax.psum,
-        ReduceOp.AVG: jax.lax.psum,
         ReduceOp.MAX: jax.lax.pmax,
         ReduceOp.MIN: jax.lax.pmin,
     }[op]
 
     def block_fn(*blocks):
         outs = tuple(red(b, "ft") for b in blocks)
-        if op == ReduceOp.AVG:
-            outs = tuple((o / world).astype(o.dtype) for o in outs)
+        if divisor != 1:
+            outs = tuple((o / divisor).astype(o.dtype) for o in outs)
         return outs
 
     fn = jax.jit(
@@ -377,14 +378,19 @@ class CollectivesDevice(Collectives):
 
     # -- collectives --
 
-    def allreduce(self, arrays: List[Any], op: ReduceOp = ReduceOp.SUM) -> Work:
+    def allreduce(
+        self, arrays: List[Any], op: ReduceOp = ReduceOp.SUM, divisor: int = 1
+    ) -> Work:
         import time
 
         from torchft_tpu import telemetry
 
+        op, divisor = resolve_divisor(op, divisor, self._world)
         arrays = [_as_device(a) for a in arrays]
         nbytes = sum(int(a.nbytes) for a in arrays)
         if self._world == 1:
+            if divisor != 1:
+                arrays = [(a / divisor).astype(a.dtype) for a in arrays]
             # sum/avg/max/min of one input is itself; no timer registration.
             # Count the op + bytes but record NO latency observation — a
             # hard-coded 0.0 for the no-op path would drown the histogram's
@@ -394,7 +400,7 @@ class CollectivesDevice(Collectives):
             return Work(Future.completed(arrays))
         telemetry.COLLECTIVE_OPS.labels(op="allreduce", plane="device").inc()
         t0 = time.perf_counter()
-        work = self._rendezvous("allreduce", arrays, (op,))
+        work = self._rendezvous("allreduce", arrays, (op, divisor))
 
         def observe(f: Future) -> None:
             # dispatch latency of the cross-group rendezvous + psum launch
@@ -578,9 +584,8 @@ def _unstack_over_ft(out, shardings, per_rank_devices) -> List[Any]:
 
 
 def _compute_allreduce(inputs: Dict[int, List[Any]], meta: Tuple) -> Dict[int, Any]:
-    (op,) = meta
+    op, divisor = meta
     ranks = sorted(inputs)
-    world = len(ranks)
     n_arrays = {len(v) for v in inputs.values()}
     if len(n_arrays) != 1:
         raise RuntimeError(f"collective desync: array counts differ: {n_arrays}")
@@ -597,7 +602,7 @@ def _compute_allreduce(inputs: Dict[int, List[Any]], meta: Tuple) -> Dict[int, A
             [[s.device for s in inputs[r][i].addressable_shards] for r in ranks]
         )
 
-    fn = _reduction_fn(big_mesh, tuple(specs), op, world)
+    fn = _reduction_fn(big_mesh, tuple(specs), op, divisor)
     outs = fn(*garrs)
     per_rank: Dict[int, List[Any]] = {r: [] for r in ranks}
     for i, out in enumerate(outs):

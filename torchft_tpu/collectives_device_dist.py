@@ -53,7 +53,13 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from torchft_tpu.collectives import Collectives, ReduceOp, Work
+from torchft_tpu.collectives import (
+    Collectives,
+    ReduceOp,
+    Work,
+    average_in_place,
+    resolve_divisor,
+)
 from torchft_tpu.futures import Future
 
 __all__ = ["CollectivesDeviceDist", "init_distributed", "init_from_env"]
@@ -210,23 +216,21 @@ class CollectivesDeviceDist(Collectives):
             (self._world, *host_block.shape[1:]),
         )
 
-    def _reduce_jit(self, shape, dtype, op: ReduceOp) -> Callable:
+    def _reduce_jit(self, shape, dtype, op: ReduceOp, divisor: int) -> Callable:
         import jax
 
-        world = self._world
-
         def block(x):  # x: local [1, *shape] block
-            if op in (ReduceOp.SUM, ReduceOp.AVG):
+            if op == ReduceOp.SUM:
                 r = jax.lax.psum(x, "ft")
-                if op == ReduceOp.AVG:
-                    r = r / world
+                if divisor != 1:
+                    r = r / divisor
             elif op == ReduceOp.MAX:
                 r = jax.lax.pmax(x, "ft")
             else:
                 r = jax.lax.pmin(x, "ft")
             return r
 
-        return self._cached_jit((tuple(shape), str(dtype), op), block)
+        return self._cached_jit((tuple(shape), str(dtype), op, divisor), block)
 
     def _gather_jit(self, shape, dtype) -> Callable:
         import jax
@@ -241,33 +245,39 @@ class CollectivesDeviceDist(Collectives):
         )
 
     @staticmethod
-    def _check_avg_dtype(op: ReduceOp, dtype: np.dtype) -> None:
-        """AVG on integer inputs would silently truncate on the host-copy
-        assignment here, while the host TCP plane's in-place np.divide
-        raises a casting error — keep the planes' failure semantics
-        identical (round-4 advisor low)."""
-        if op == ReduceOp.AVG and not np.issubdtype(dtype, np.inexact):
+    def _check_avg_dtype(averaged: bool, dtype: np.dtype) -> None:
+        """An average (AVG, or SUM with a divisor) of integer inputs would
+        silently truncate on the host-copy assignment here, while the
+        host TCP plane's in-place np.divide raises a casting error — keep
+        the planes' failure semantics identical (round-4 advisor low)."""
+        if averaged and not np.issubdtype(dtype, np.inexact):
             raise TypeError(
                 f"ReduceOp.AVG on dtype {np.dtype(dtype)} would truncate; "
                 "cast to a float dtype first (matches the host plane's "
                 "np.divide casting error)"
             )
 
-    def _allreduce_one(self, arr: np.ndarray, op: ReduceOp) -> None:
-        self._check_avg_dtype(op, arr.dtype)
+    def _allreduce_one(self, arr: np.ndarray, op: ReduceOp, divisor: int) -> None:
+        self._check_avg_dtype(divisor != 1, arr.dtype)
         garr = self._stage(np.ascontiguousarray(arr)[None, ...])
-        out = self._reduce_jit(arr.shape, arr.dtype, op)(garr)
+        out = self._reduce_jit(arr.shape, arr.dtype, op, divisor)(garr)
         arr[...] = np.asarray(out.addressable_shards[0].data)[0]
 
     # -- collectives --
 
-    def allreduce(self, arrays: List[np.ndarray], op: ReduceOp = ReduceOp.SUM) -> Work:
+    def allreduce(
+        self,
+        arrays: List[np.ndarray],
+        op: ReduceOp = ReduceOp.SUM,
+        divisor: int = 1,
+    ) -> Work:
         try:
+            op, divisor = resolve_divisor(op, divisor, self._world)
             if self._world > 1:
                 for arr in arrays:
-                    self._allreduce_one(arr, op)
-            elif op == ReduceOp.AVG:
-                pass  # world 1: average of one is identity
+                    self._allreduce_one(arr, op, divisor)  # divides on the device
+            else:
+                average_in_place(arrays, divisor)  # 1 for AVG at world 1
             return Work.completed(arrays)
         except Exception as e:  # noqa: BLE001 — surface through the future
             return Work.failed(e)
@@ -339,7 +349,7 @@ class CollectivesDeviceDist(Collectives):
                 )
             # dtype check BEFORE the world==1 return: the host plane's
             # np.divide raises for AVG-on-int even at world 1
-            self._check_avg_dtype(op, arrays[0].dtype)
+            self._check_avg_dtype(op == ReduceOp.AVG, arrays[0].dtype)
             if self._world == 1:
                 return Work.completed(arrays[0].copy())
             if not self._uniform(arrays):

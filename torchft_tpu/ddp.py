@@ -48,6 +48,14 @@ the heap and never to trim it (:func:`_landing_on_kept_pages`; the
 ``exchange`` span says so in ``d2h_pages_kept``), at the cost of a second
 tree's size of host memory that stays with the process.
 
+The average is no stage of this pipeline. The divisor (the Manager's
+participants) travels with each bucket's allreduce, and the data plane
+applies it where it writes an element's final value — the native ring in
+the chunk owner's last reduce step — so the op thread the step ends by
+waiting for runs ring, then ``exchange.h2d``, and nothing else
+(``buckets_avg_in_ring`` beside ``buckets``; where a plane cannot, its
+NumPy pass shows as ``tft.exchange.average``).
+
 What the exchange spends its time on is visible from inside
 (docs/observability.md "Spans in the profiler's trace"): one ``exchange``
 span around the call, carrying the per-step sums and the process's
@@ -379,6 +387,11 @@ def _host_exchange(
     sums = {  # per step
         "buckets_reused": 0, "d2h_pages_kept": 0, "d2h_wait_s": 0.0, "pack_s": 0.0
     }
+    # the data plane's count of ops averaged inside its ring: its growth
+    # over this exchange is how many buckets needed no division pass
+    # (duck-typed managers have no such count)
+    avg_in_ring_ops = getattr(manager, "avg_in_ring_ops", lambda: 0)
+    avg_in_ring_0 = avg_in_ring_ops()
 
     # stage 0: kick off D2H for every leaf/shard before anything blocks.
     # No guard: a runtime that rejects the prefetch would serialise every
@@ -575,5 +588,6 @@ def _host_exchange(
         "buckets": len(plan),
         "bytes_d2h": sum(it.nbytes for it in items),
         "tail_wait_s": tail_wait_s,
+        "buckets_avg_in_ring": avg_in_ring_ops() - avg_in_ring_0,
         **sums,
     }

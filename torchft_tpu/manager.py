@@ -1408,6 +1408,13 @@ class Manager:
         — gradient averaging then skips the host round trip entirely."""
         return bool(getattr(self._collectives, "device_arrays", False))
 
+    def avg_in_ring_ops(self) -> int:
+        """The data plane's count of allreduces whose average was taken
+        inside the reduction (``Collectives.avg_in_ring_ops``); ``ddp``
+        reads its growth over an exchange as ``buckets_avg_in_ring``."""
+        fn = getattr(self._collectives, "avg_in_ring_ops", None)
+        return int(fn()) if callable(fn) else 0
+
     def wire_codec(self) -> str:
         """Name of the codec the configured data plane ships large f32
         allreduces with (``"f32"`` = exact). ``ManagedOptimizer`` keys its
@@ -1495,7 +1502,14 @@ class Manager:
             ids_snapshot = list(self._participant_ids)
 
         try:
-            work = self._collectives.allreduce(tensors, ReduceOp.SUM)
+            # host path: the divisor travels with the op, and the backend
+            # applies it where an element's final value is written (the
+            # native ring: in the owner's last reduce step, no pass of its
+            # own on the op thread the step waits for). Device path: one
+            # jitted divide with n traced, so membership never recompiles
+            work = self._collectives.allreduce(
+                tensors, ReduceOp.SUM, divisor=1 if device else max(n_at_issue, 1)
+            )
 
             def normalize(fut: Future) -> List[Any]:
                 try:
@@ -1503,13 +1517,8 @@ class Manager:
                 except BaseException as e:  # noqa: BLE001 — annotate + rethrow
                     e._tft_participants = ids_snapshot
                     raise
-                n = n_at_issue
-                if n > 1:
-                    if device:
-                        reduced = _divide_tree(reduced, n)
-                    else:
-                        for t in reduced:
-                            np.divide(t, n, out=t)
+                if device and n_at_issue > 1:
+                    reduced = _divide_tree(reduced, n_at_issue)
                 if self._divergence_sentinel:
                     self._digest_reduced(reduced)
                 return reduced

@@ -86,10 +86,10 @@ def _safe_close(shm: shared_memory.SharedMemory) -> None:
             pass
 
 
-def _child_allreduce(backend: Collectives, buf, metas, op) -> None:
+def _child_allreduce(backend: Collectives, buf, metas, op, divisor) -> None:
     # scoped so the views (and the Work future that captures them) are
     # dropped before the caller closes the mapping
-    backend.allreduce(_buf_views(buf, metas), op).wait()
+    backend.allreduce(_buf_views(buf, metas), op, divisor).wait()
 
 
 def _copy_out(shm, metas, arrays: List[np.ndarray]) -> None:
@@ -119,7 +119,7 @@ def _worker(factory, store_addr, rank, world_size, tx, rx) -> None:
         op_id, name, args, kwargs = cmd
         try:
             if name == "allreduce_shm":
-                shm_name, metas, op = args
+                shm_name, metas, op, divisor = args
                 # attach by raw mmap of the POSIX segment: SharedMemory's
                 # attach path registers with the resource tracker (CPython
                 # <=3.12 has no track=False), which would both leak a
@@ -138,7 +138,7 @@ def _worker(factory, store_addr, rank, world_size, tx, rx) -> None:
                     # the backend reduces IN PLACE on the mapped views; the
                     # reduced bytes are visible to the parent with no
                     # return payload
-                    _child_allreduce(backend, buf, metas, op)
+                    _child_allreduce(backend, buf, metas, op, divisor)
                     result = None
                 finally:
                     try:
@@ -329,7 +329,12 @@ class CollectivesProxy(Collectives):
 
     # -- collectives --
 
-    def allreduce(self, arrays, op: ReduceOp = ReduceOp.SUM) -> Work:
+    def allreduce(
+        self, arrays, op: ReduceOp = ReduceOp.SUM, divisor: int = 1
+    ) -> Work:
+        # the divisor goes to the child with the op: its backend divides
+        # inside its ring where it can (the parent cannot see whether it
+        # did: avg_in_ring_ops() stays 0 on this side)
         total = sum(getattr(a, "nbytes", 0) for a in arrays)
         if (
             total >= _SHM_MIN_BYTES
@@ -339,10 +344,14 @@ class CollectivesProxy(Collectives):
                 for a in arrays
             )
         ):
-            return self._allreduce_shm(arrays, op)
-        return self._copy_back(self._submit("allreduce", arrays, op), arrays)
+            return self._allreduce_shm(arrays, op, divisor)
+        return self._copy_back(
+            self._submit("allreduce", arrays, op, divisor), arrays
+        )
 
-    def _allreduce_shm(self, arrays: List[np.ndarray], op: ReduceOp) -> Work:
+    def _allreduce_shm(
+        self, arrays: List[np.ndarray], op: ReduceOp, divisor: int
+    ) -> Work:
         """Hot path: stage buffers in a per-op shared-memory segment; the
         child reduces in place on the mapping, the parent copies back."""
         total = sum(a.nbytes for a in arrays)
@@ -359,7 +368,7 @@ class CollectivesProxy(Collectives):
             shm.unlink()
             raise
 
-        work = self._submit("allreduce_shm", shm.name, metas, op)
+        work = self._submit("allreduce_shm", shm.name, metas, op, divisor)
 
         def copy_back(fut: Future):
             try:
