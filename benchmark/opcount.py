@@ -24,16 +24,32 @@ bfloat16: the width of activations and gradients in the byte counts).
 
 Bytes are counted the same way as operations: the least the algorithm has to
 move between the chip and its memory, not what a program moves.
+
+**A block this file cannot count brings its own.** A configuration whose
+``program.opcount`` names ``<name>`` is counted by ``opcounts/<name>.py``, and
+every reader asks :func:`for_config` which count is the configuration's; with
+no such key it is this file, as it always was. What a count has to have is
+:data:`INTERFACE`, what the readers use and no more: ``n_params(tc)``;
+``flops_per_token_by_scope(tc, seq)``, whose keys are top-level scopes
+(``xplane_meta.SCOPES``) and whose sum is the operations a trained token needs
+(``mfu_pct``); ``bytes_per_step_by_scope(tc, batch, seq)``; and
+``ffn_scopes(tc)``, the scopes that hold the feed-forward blocks (``ffn`` for
+dense layers, ``moe`` for expert layers, both where a model has both), which
+``ffn_device_s`` and ``ffn_roofline`` read together.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+import os
+import sys
+from typing import Any, Dict, Tuple
+
+from common import load_module
 
 __all__ = [
     "matmul_params", "flops_per_token", "flops_per_step", "n_params",
-    "ffn_scope", "flops_per_token_by_scope", "bytes_per_step_optimizer",
-    "bytes_per_step_by_scope",
+    "ffn_scope", "ffn_scopes", "flops_per_token_by_scope", "bytes_per_step_optimizer",
+    "bytes_per_step_by_scope", "for_config", "INTERFACE",
 ]
 
 # AdamW over f32 state, as the program keeps it: a parameter and its two
@@ -65,6 +81,12 @@ def ffn_scope(tc: Dict[str, Any]) -> str:
     """The scope the program names its feed-forward block by
     (``models/transformer.py``: ``moe`` with experts, else ``ffn``)."""
     return "moe" if tc.get("n_experts") else "ffn"
+
+
+def ffn_scopes(tc: Dict[str, Any]) -> Tuple[str, ...]:
+    """The scopes that hold this configuration's feed-forward blocks: the one
+    of :func:`ffn_scope` (this file counts layers of one kind)."""
+    return (ffn_scope(tc),)
 
 
 def n_params(tc: Dict[str, Any]) -> int:
@@ -144,3 +166,27 @@ def bytes_per_step_by_scope(tc: Dict[str, Any], batch: int, seq: int) -> Dict[st
         "head_loss": 3 * c * d * vocab + 3 * act,
         "optimizer": bytes_per_step_optimizer(tc),
     }
+
+
+# -- which count is a configuration's
+
+OPCOUNTS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "opcounts")
+INTERFACE = ("n_params", "flops_per_token_by_scope", "bytes_per_step_by_scope", "ffn_scopes")
+
+
+def for_config(config: Dict[str, Any]):
+    """The count of a configuration (the configuration file, as a reader's
+    ``run.config``): the module ``opcounts/<program.opcount>.py``, or this one
+    where the key is absent. A named file that is missing, or lacks a function
+    of :data:`INTERFACE`, is an error that names the file."""
+    name = (config.get("program") or {}).get("opcount")
+    if not name:
+        return sys.modules[__name__]
+    path = os.path.join(OPCOUNTS_DIR, name + ".py")
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"program.opcount names {name!r} and there is no {path}")
+    counts = load_module(path, "bench_opcount_" + name)
+    missing = [fn for fn in INTERFACE if not callable(getattr(counts, fn, None))]
+    if missing:
+        raise AttributeError(f"{path} lacks {', '.join(missing)}: a count has {', '.join(INTERFACE)}")
+    return counts

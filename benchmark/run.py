@@ -64,6 +64,9 @@ def resolve_cell(workload: str, rehearse: bool):
         # flow; the result line then names that backend and is no result
         rehearsal = load_json(os.path.join(HERE, "tests", "rehearsal.json"))
         config["program"]["transformer_config"].update(rehearsal["transformer_config"])
+        # sizes only this configuration has (latent ranks, a second head size,
+        # experts held) are made tiny by its own file, after the shared seven
+        config["program"]["transformer_config"].update((config.get("rehearsal") or {}).get("transformer_config", {}))
         traffic.update(rehearsal["traffic"])
     check_events(traffic.get("events", []))
     return bench, cell, config, traffic, rehearsal

@@ -1,6 +1,7 @@
 """What the per-layer metrics of a named scope share: the scope's device
 seconds a step from the reduced trace (``reduce_trace.reduce_planes``'s
-``by_scope`` rows), and its share of its roofline from ``opcount.py``'s counts.
+``by_scope`` rows), and its share of its roofline from the configuration's count
+(``opcount.for_config``: ``opcount.py``, or the file the configuration names).
 
 A traced unit owns the program runs the host launched before the next unit's
 start, so the ``optimizer`` of a step's ``apply``, which starts as the unit ends
@@ -23,7 +24,7 @@ why (``adds_up``).
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
 import opcount
 from measure import median
@@ -90,20 +91,39 @@ def device_seconds(run, scope: str) -> Optional[float]:
     ])
 
 
-def roofline(run, scope: str) -> Optional[float]:
-    """The least time the published peaks allow the scope a step — its
-    counted operations over the bf16 peak or its counted bytes over the HBM
-    bandwidth, whichever is longer (``opcount.py``: what the algorithm needs,
-    not what the program executes) — as a share of the scope's device seconds."""
+def device_seconds_of(run, scopes: Sequence[str]) -> Optional[float]:
+    """Device seconds a step in ``scopes`` together (``ffn`` and ``moe`` of a
+    model with a dense layer ahead of its expert layers): the sum over those
+    that ran an op, None where none did."""
+    found = [s for s in (device_seconds(run, scope) for scope in scopes) if s is not None]
+    return sum(found) if found else None
+
+
+def roofline(run, *scopes: str) -> Optional[float]:
+    """The least time the published peaks allow ``scopes`` a step — for each
+    its counted operations over the bf16 peak or its counted bytes over the HBM
+    bandwidth, whichever is longer (the configuration's count,
+    ``opcount.for_config``: what the algorithm needs, not what the program
+    executes), summed: scopes run one after another — as a share of their
+    device seconds together. None where a counted scope ran no op: its least
+    time over the others' seconds would be no share of anything."""
+    if run.peaks is None:
+        return None  # a rehearsal on a device without published peaks
     tc = run.config["program"]["transformer_config"]
     batch, seq = int(run.traffic["batch"]), int(run.traffic["seq"])
-    seconds = device_seconds(run, scope)
-    if run.peaks is None or not seconds:
-        return None  # a rehearsal on a device without published peaks
-    flops = opcount.flops_per_token_by_scope(tc, seq).get(scope, 0.0) * batch * seq
-    moved = opcount.bytes_per_step_by_scope(tc, batch, seq).get(scope, 0.0)
-    least = max(flops / run.peaks["bf16_flops_per_s"], moved / run.peaks["hbm_bytes_per_s"])
-    if not least:
+    counts = opcount.for_config(run.config)
+    flops, moved = counts.flops_per_token_by_scope(tc, seq), counts.bytes_per_step_by_scope(tc, batch, seq)
+    least = seconds = 0.0
+    for scope in scopes:
+        ran = device_seconds(run, scope)
+        needs = max(
+            flops.get(scope, 0.0) * batch * seq / run.peaks["bf16_flops_per_s"],
+            moved.get(scope, 0.0) / run.peaks["hbm_bytes_per_s"],
+        )
+        if needs and not ran:
+            return None
+        least, seconds = least + needs, seconds + (ran or 0.0)
+    if not least or not seconds:
         return None  # a scope this configuration does not have
     chips = int(run.config["layout"]["chips_per_group"])
     return 100.0 * least / (seconds * chips)

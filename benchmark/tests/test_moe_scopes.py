@@ -12,6 +12,7 @@ import measure
 import moe_scopes
 import opcount
 import reduce_trace as rt
+import subscopes
 from common import load_json, load_module
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -119,3 +120,20 @@ def test_a_reader_returns_none_where_there_is_nothing_to_read(name):
     assert compute(a_run(os.path.join(HERE, "recorded_scopes_v5e.xplane.pb"), dense)) is None
     assert compute(measure.Run({}, {"program": {"transformer_config": dense}, "layout": {"chips_per_group": 1}},
                                {"batch": 8, "seq": 2048, "steps_per_unit": 1}, PEAKS, [{"group": 0}])) is None
+
+
+def test_a_scope_split_into_names_it_does_not_carry_stays_whole():
+    """``subscopes.py`` on another parent: PR 25's dense recording nests nothing under ``attn``, so a split of it
+    into two kinds of sequence mixing gives every op of the scope to ``attn`` itself — what ``attn_device_s`` reads."""
+    scoped = os.path.join(HERE, "recorded_scopes_v5e.xplane.pb")
+    rows = subscopes.units(scoped, "attn", ("kda", "mla"))
+    whole = [u["by_scope"]["attn"] for u in rt.reduce_file(scoped, [])["units"]]
+    assert [set(row) for row in rows] == [{"attn"}] * 3
+    assert [row["attn"] for row in rows] == pytest.approx(whole, rel=1e-9)
+    assert subscopes.innermost("jit(f)/jvp()/while/body/attn/kda/dot_general", "attn", ("kda", "mla")) == "kda"
+    assert subscopes.innermost("jit(f)/transpose(jvp(attn))/transpose(jvp(mla))/core/dot_general", "attn", ("kda", "mla")) == "mla"
+    assert subscopes.innermost("jit(f)/jvp()/while/body/moe/kda/dot_general", "attn", ("kda", "mla")) is None
+    # the readers' reduction: the median of the first two units, and nothing where the trace is gone
+    run = a_run(scoped, olmoe_tc())
+    assert subscopes.seconds(run, "attn", ("kda", "mla")) == {"attn": pytest.approx((whole[0] + whole[1]) / 2, rel=1e-9)}
+    assert subscopes.seconds(a_run(os.path.join(HERE, "no_such.xplane.pb"), olmoe_tc()), "attn", ("kda", "mla")) is None

@@ -1,5 +1,6 @@
 """opcount.py against numbers worked by hand: OLMo-1B's widths at 6 layers (dense),
-OLMoE-1B-7B's at one layer (64 experts, 8 per token)."""
+OLMoE-1B-7B's at one layer (64 experts, 8 per token); and the resolver that
+gives a configuration its count (``opcount.for_config``)."""
 
 import json
 import os
@@ -115,3 +116,49 @@ def test_hand_worked_bytes_by_scope():
     assert moe["moe"] == 3 * 2 * (64 * 3 * 2048 * 1024 + 2048 * 64) + 5 * act and "ffn" not in moe
     few = opcount.flops_per_token_by_scope(OLMOE_1L, 16)["moe"] * 16 / 197e12
     assert few < opcount.bytes_per_step_by_scope(OLMOE_1L, 1, 16)["moe"] / 819e9
+
+
+# -- the resolver: a configuration's own count, or this file's
+
+A_COUNT = """
+def n_params(tc):
+    return 7
+
+def flops_per_token_by_scope(tc, seq):
+    return {"attn": 1.0 * seq, "ffn": 2.0, "moe": 3.0, "head_loss": 4.0}
+
+def bytes_per_step_by_scope(tc, batch, seq):
+    return {"attn": 1, "ffn": 2, "moe": 3, "head_loss": 4, "optimizer": 5}
+
+def ffn_scopes(tc):
+    return ("ffn", "moe")
+"""
+
+
+def test_a_configuration_without_the_key_is_counted_by_opcount_py():
+    tc = _tc()
+    for config in ({"program": {"transformer_config": tc}}, {"program": {"opcount": None}}, {"program": {}}, {}):
+        assert opcount.for_config(config) is opcount
+    assert all(callable(getattr(opcount, fn)) for fn in opcount.INTERFACE)
+    assert opcount.ffn_scopes(tc) == ("ffn",) and opcount.ffn_scopes(OLMOE_1L) == ("moe",)
+    # the three configuration files name none: their numbers are this file's, as before
+    for name in ("olmo1b-1g", "olmo1b-4g", "olmoe-1g"):
+        with open(os.path.join(HERE, "..", "configs", name + ".json")) as f:
+            assert opcount.for_config(json.load(f)) is opcount
+
+
+def test_a_named_count_is_the_file_of_that_name(tmp_path, monkeypatch):
+    monkeypatch.setattr(opcount, "OPCOUNTS_DIR", str(tmp_path))
+    (tmp_path / "latent.py").write_text(A_COUNT)
+    counts = opcount.for_config({"program": {"opcount": "latent"}})
+    assert counts is not opcount and counts.n_params({}) == 7 and counts.ffn_scopes({}) == ("ffn", "moe")
+    assert sum(counts.flops_per_token_by_scope({}, 8).values()) == 17.0
+
+
+def test_a_missing_file_and_a_missing_function_are_errors_that_name_the_file(tmp_path, monkeypatch):
+    monkeypatch.setattr(opcount, "OPCOUNTS_DIR", str(tmp_path))
+    with pytest.raises(FileNotFoundError, match=r"'latent'.*latent\.py"):
+        opcount.for_config({"program": {"opcount": "latent"}})
+    (tmp_path / "latent.py").write_text(A_COUNT.replace("def ffn_scopes", "def ffn_scope").replace("def n_params", "n_params = 7\ndef params"))
+    with pytest.raises(AttributeError, match=r"latent\.py lacks n_params, ffn_scopes"):
+        opcount.for_config({"program": {"opcount": "latent"}})

@@ -88,6 +88,47 @@ def test_the_feed_forward_readers_follow_the_configurations_scope():
     assert reader("ffn_device_s").compute(a_run([{"by_scope": {"moe": 0.2}}], olmo_tc())) is None
 
 
+# a count as an architecture with a dense layer ahead of its expert layers brings it (``opcounts/<name>.py``):
+# both feed-forward scopes, ``ffn`` bound by its operations and ``moe`` by its bytes at these made-up numbers
+BOTH = """
+def n_params(tc):
+    return 1000
+
+def flops_per_token_by_scope(tc, seq):
+    return {"attn": 4.0e9, "ffn": 1.97e9, "moe": 0.197e9, "head_loss": 1.0e9}
+
+def bytes_per_step_by_scope(tc, batch, seq):
+    return {"attn": 1e9, "ffn": 0.819e9, "moe": 81.9e9, "head_loss": 1e9, "optimizer": 26000}
+
+def ffn_scopes(tc):
+    return ("ffn", "moe")
+"""
+
+
+def test_a_configuration_with_a_dense_layer_ahead_of_its_expert_layers_reads_both(tmp_path, monkeypatch):
+    monkeypatch.setattr(opcount, "OPCOUNTS_DIR", str(tmp_path))
+    (tmp_path / "both.py").write_text(BOTH)
+    units = [{"by_scope": {"ffn": 0.5, "moe": 1.5, "attn": 1.0, "optimizer": 0.1}}]
+    run = a_run(units, olmo_tc(), steps_per_unit=5)
+    run.config["program"]["opcount"] = "both"
+    assert reader("ffn_device_s").compute(run) == pytest.approx((0.5 + 1.5) / 5)
+    # 16 384 tokens: ffn needs 1.97e9 x 16 384 / 197e12 = 0.16384 s (its bytes 0.001 s), moe 81.9e9 / 819e9 = 0.1 s
+    # (its operations 0.016384 s): each scope its own nearer bound, one after the other
+    assert reader("ffn_roofline").compute(run) == pytest.approx(100 * (0.16384 + 0.1) / 0.4)
+    assert reader("attn_roofline").compute(run) == pytest.approx(100 * 4.0e9 * 16384 / 197e12 / 0.2)
+    assert reader("optimizer_roofline").compute(run) == pytest.approx(100 * 26000 / 819e9 / 0.02)
+    assert reader("mfu_pct").compute(a_run(units, olmo_tc(), peaks=None)) is None
+    # one of the two ran no op (a program that names its dense layer otherwise): the seconds are the other's,
+    # and no share is given of a least time that counts both
+    run = a_run([{"by_scope": {"moe": 1.5, "attn": 1.0}}], olmo_tc(), steps_per_unit=5)
+    run.config["program"]["opcount"] = "both"
+    assert reader("ffn_device_s").compute(run) == pytest.approx(1.5 / 5)
+    assert reader("ffn_roofline").compute(run) is None
+    # without the key the same rows are opcount.py's dense configuration: ``ffn`` alone
+    plain = a_run(units, olmo_tc(), steps_per_unit=5)
+    assert reader("ffn_device_s").compute(plain) == pytest.approx(0.5 / 5)
+
+
 @pytest.mark.parametrize("name", SCOPE_READERS)
 def test_a_scope_reader_returns_none_where_there_is_nothing_to_read(name):
     compute = reader(name).compute
