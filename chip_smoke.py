@@ -18,7 +18,9 @@ from the files git would commit, and places the compile cache
 Phases:
   1 probe    a short-lived child names the platform; anything but "tpu" fails
   2 steady   launcher --groups 1, scale_647M b4 s1024, a few committed steps
-  3 kernels  Pallas flash + chunked attention, compiled, against ops.attention
+  3 kernels  Pallas flash + chunked attention, compiled, against ops.attention;
+             at the hybrid cell's widths the latent attention's core (keys 192,
+             values 128) and chunked KDA against the recurrence
   4 cache    phase 2 again on the same compile cache: zero grads/apply misses
   5 four chips, only when the probe counted >= 4:
     5a one group on all four chips (FSDP=2 TP=2): shards + memory everywhere
@@ -415,13 +417,17 @@ def steady(
 
 
 def kernels_child(
-    platform: str, flash_shapes: list, chunked_shape: list, cells_shape: list
+    platform: str, flash_shapes: list, chunked_shape: list, cells_shape: list,
+    mla_shape: Optional[list] = None, kda_shape: Optional[list] = None,
 ) -> None:
     """Runs in the child. Flash forward+backward per (b, s, h, d) at its
     default blocks and, at the benchmark cells' sequence and heads, at the
     blocks ``attention_impl`` "auto" picks there; chunked attention at the
     smoke's shape; all in bf16 against ops.attention in f32 (matmuls at
-    highest precision)."""
+    highest precision). ``mla_shape`` (b, s, h, key width, value width):
+    the latent attention's core as "auto" takes it (``mla_cells``);
+    ``kda_shape`` (b, s, h, d): chunked KDA in bf16 against the recurrence in
+    f32 (``kda_cells``) — both at the hybrid cell's widths and a short length."""
     from torchft_tpu.utils.compile_cache import place_compile_cache
 
     place_compile_cache()
@@ -445,9 +451,11 @@ def kernels_child(
     atol = rtol = 3e-2
 
     def check(name, fn, shape, must_be_mosaic):
-        b, s, h, d = shape
+        b, s, h, d = shape[:4]
+        dv = shape[4] if len(shape) > 4 else d  # values of another width than keys
         ks = jax.random.split(jax.random.PRNGKey(s + d), 4)
-        q, k, v, w = (jax.random.normal(kk, (b, s, h, d), jnp.float32) for kk in ks)
+        q, k = (jax.random.normal(kk, (b, s, h, d), jnp.float32) for kk in ks[:2])
+        v, w = (jax.random.normal(kk, (b, s, h, dv), jnp.float32) for kk in ks[2:])
         qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
 
         def loss(f, q, k, v):
@@ -502,7 +510,61 @@ def kernels_child(
         lambda q, k, v: chunked_attention(q, k, v, causal=True, chunk=128),
         chunked_shape, must_be_mosaic=False,
     ))
+    if mla_shape:
+        blocks = _flash_blocks(mla_shape[1], mla_shape[4])
+        oks.append(check(
+            "mla_cells",
+            (lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1]))
+            if blocks and platform == "tpu" else (lambda q, k, v: chunked_attention(q, k, v, causal=True, chunk=128)),
+            mla_shape, must_be_mosaic=bool(blocks) and platform == "tpu",
+        ))
+    if kda_shape:
+        oks.append(_kda_cells(kda_shape, dev))
     sys.exit(0 if all(oks) else 1)
+
+
+def _kda_cells(shape: list, dev) -> bool:
+    """Chunked KDA, forward and backward, bf16 operands, against the
+    recurrence position by position in f32. Outputs are of order 0.3 and
+    bf16 operands put them 2e-3 off (tests/test_kda.py): 2e-2 + 2e-2 |ref|."""
+    import jax
+    import jax.numpy as jnp
+
+    from torchft_tpu.ops.kda import kda_chunked, kda_recurrent
+
+    b, s, h, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(s + d), 6)
+    q, k, v, w = (jax.random.normal(kk, (b, s, h, d), jnp.float32) for kk in ks[:4])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d**-0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -jax.nn.softplus(jax.random.normal(ks[4], (b, s, h, d))) * 0.1
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (b, s, h)))
+    qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
+
+    def loss(f, q, k, v, g, beta):
+        o = f(q, k, v, g, beta)[0]
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    got_fn = jax.jit(jax.value_and_grad(lambda *a: loss(kda_chunked, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
+    with jax.default_matmul_precision("highest"):
+        ref_fn = jax.jit(jax.value_and_grad(lambda *a: loss(kda_recurrent, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
+        (_, o_ref), g_ref = ref_fn(*(x.astype(jnp.float32) for x in (qb, kb, vb)), g, beta)
+    t0 = time.perf_counter()
+    (_, o), grads = jax.block_until_ready(got_fn(qb, kb, vb, g, beta))
+    t_first = time.perf_counter() - t0
+    atol = rtol = 2e-2
+    errs = {
+        n: float(jnp.max(jnp.abs(a.astype(jnp.float32) - r) - rtol * jnp.abs(r)))
+        for n, a, r in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), (o, *grads), (o_ref, *g_ref))
+    }
+    finite = all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in (o, *grads))
+    ok = finite and max(errs.values()) <= atol
+    print(json.dumps({
+        "check": "kda_cells", "shape": shape, "ok": ok, "finite": finite,
+        "err_minus_rtol_ref": {k: round(v, 5) for k, v in errs.items()}, "atol": atol, "rtol": rtol,
+        "compile_and_first_run_s": round(t_first, 2), "device": dev.device_kind,
+    }), flush=True)
+    return ok
 
 
 def kernels(
@@ -510,6 +572,8 @@ def kernels(
     flash_shapes: Optional[list] = None,
     chunked_shape: Optional[list] = None,
     cells_shape: Optional[list] = None,
+    mla_shape: Optional[list] = None,
+    kda_shape: Optional[list] = None,
     timeout: float = 600,
 ) -> List[Dict[str, Any]]:
     # head_dim 64 and 128 are the two the presets use; S >= 2048
@@ -518,9 +582,13 @@ def kernels(
     # the benchmark cells' s2048 x 16 heads x 128 (b8 there; 2 keeps the
     # f32 reference's [B,H,S,S] scores at 0.5 GB)
     cells_shape = cells_shape or [2, 2048, 16, 128]
+    # the hybrid cell's widths at a short length: MLA's keys 192 and values 128
+    # wide, KDA's heads of 128 (8 of the 32: the recurrence's reference is slow)
+    mla_shape = mla_shape or [1, 2048, 8, 192, 128]
+    kda_shape = kda_shape or [1, 1024, 8, 128]
     code = (
         "import chip_smoke; chip_smoke.kernels_child("
-        f"{platform!r}, {flash_shapes!r}, {chunked_shape!r}, {cells_shape!r})"
+        f"{platform!r}, {flash_shapes!r}, {chunked_shape!r}, {cells_shape!r}, {mla_shape!r}, {kda_shape!r})"
     )
     try:
         text = run_child("3_kernels", [sys.executable, "-c", code], _child_env(), timeout)

@@ -15,10 +15,21 @@ Pure-JAX pytree params with explicit ``PartitionSpec``s per leaf:
 Layers within a stage run under ``lax.scan`` (one compile per stage, not
 per layer) with ``jax.checkpoint`` rematerialization — compile time and
 HBM both scale O(1) in depth.
+
+A model whose layers are not all of one kind DECLARES its pattern
+(``TransformerConfig.kda_layers`` / ``mla_layers`` / ``n_dense_layers``): per
+layer a sequence mixer — ``full`` softmax attention with RoPE, ``kda``
+(gated delta-rule linear attention, ``ops/kda.py``) or ``mla`` (latent
+attention without positions) — and a feed-forward, ``dense`` or ``experts``.
+Its parameters are grouped by kind of layer and the stack runs the leading
+layers one by one, then ``lax.scan`` over whole periods of the pattern with
+the period unrolled inside the body (:func:`layer_pattern`). A model of one
+kind is a period of one: today's tree and today's scan.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
@@ -34,9 +45,11 @@ from torchft_tpu.ops.attention import (
     ring_attention,
     ring_attention_local,
 )
+from torchft_tpu.ops.kda import kda_chunked, short_conv
 from torchft_tpu.ops.layers import (
     moe_dispatch,
     moe_dropless,
+    moe_dropless_held,
     rms_norm,
     rotary_embed,
     swiglu,
@@ -45,6 +58,7 @@ from torchft_tpu.ops.layers import (
 __all__ = [
     "TransformerConfig",
     "PRESETS",
+    "layer_pattern",
     "init_params",
     "param_specs",
     "forward",
@@ -91,6 +105,63 @@ class TransformerConfig:
     # scores-memory ceiling where chunked cannot run
     attention_impl: str = "auto"
 
+    # -- a declared layer pattern. Layers are counted from 1, as published
+    # configurations count them; a layer named in neither list mixes with
+    # ``full`` attention.
+    kda_layers: Tuple[int, ...] = ()  # gated delta-rule linear attention (ops/kda.py)
+    mla_layers: Tuple[int, ...] = ()  # latent attention without positions
+    # with experts: this many leading layers keep a dense SwiGLU of d_ff
+    n_dense_layers: int = 0
+    moe_d_ff: int = 0  # one expert's width; 0 => d_ff (a model of expert layers only)
+    # -- experts under a share: the router stays n_experts wide; this layer
+    # holds the contiguous block [expert_share_index * n_experts_held, ...)
+    # and leaves out what the absent experts would add. 0 => all are held
+    n_experts_held: int = 0
+    expert_share_index: int = 0
+    n_shared_experts: int = 0  # dense SwiGLUs of moe_d_ff beside the routed experts
+    # "softmax": p = softmax(h·Wr), the k largest, applied as they are.
+    # "sigmoid": s = sigmoid(h·Wr), the k largest of s + a selection-only
+    # bias, weights s (renormalised over the chosen if ``router_renormalize``)
+    # times ``routed_scaling_factor``
+    router_gate: str = "softmax"
+    router_renormalize: bool = False
+    routed_scaling_factor: float = 1.0
+    # -- mla: q is n_heads x (qk_nope + qk_rope); keys are a per-head part of
+    # qk_nope from the latent (kv_lora_rank) and one part of qk_rope shared by
+    # all heads (not rotated: this path has no positions); values v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # -- kda: linear_n_heads x linear_head_dim keys and values, a causal
+    # depthwise convolution of conv_kernel taps on q, k and v; the two
+    # low-rank gates (decay, output) are linear_head_dim wide inside
+    linear_head_dim: int = 0
+    linear_n_heads: int = 0
+    conv_kernel: int = 4
+
+    def __post_init__(self) -> None:
+        for name in ("kda_layers", "mla_layers"):  # a JSON file gives lists
+            object.__setattr__(self, name, tuple(int(i) for i in getattr(self, name)))
+        named = self.kda_layers + self.mla_layers
+        if len(set(named)) != len(named) or any(not 1 <= i <= self.n_layers for i in named):
+            raise ValueError(
+                f"kda_layers {self.kda_layers} and mla_layers {self.mla_layers} name layers "
+                f"1..{self.n_layers}, each at most once"
+            )
+        if self.router_gate not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_gate must be 'softmax'|'sigmoid', got {self.router_gate!r}")
+        if self.n_experts_held and (
+            self.n_experts % self.n_experts_held
+            or not 0 <= self.expert_share_index < self.n_experts // self.n_experts_held
+        ):
+            raise ValueError(
+                f"n_experts_held={self.n_experts_held}, expert_share_index={self.expert_share_index}: "
+                f"the {self.n_experts} experts divide into equal blocks and the index names one"
+            )
+        if (self.n_dense_layers or self.n_shared_experts or self.n_experts_held) and not self.n_experts:
+            raise ValueError("n_dense_layers, n_shared_experts and n_experts_held describe a model with experts")
+
     @property
     def layers_per_stage(self) -> int:
         assert self.n_layers % max(self.pp, 1) == 0
@@ -99,6 +170,64 @@ class TransformerConfig:
     @property
     def qkv_dim(self) -> int:
         return self.n_heads * self.head_dim
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, feed-forward) of every layer, in order."""
+        kinds = []
+        for i in range(1, self.n_layers + 1):
+            mixer = "kda" if i in self.kda_layers else "mla" if i in self.mla_layers else "full"
+            ff = "experts" if self.n_experts and i > self.n_dense_layers else "dense"
+            kinds.append((mixer, ff))
+        return tuple(kinds)
+
+
+def layer_pattern(cfg: TransformerConfig) -> Tuple[int, Tuple[Tuple[str, str], ...]]:
+    """(leading layers, the period): the stack is ``lead`` layers run one by
+    one and then whole repeats of ``period`` (kinds of layer, in order) under
+    ``lax.scan`` — the split with the fewest unrolled layers, and of several
+    such the one with the fewest leading layers. A model of one kind gives
+    (0, (kind,))."""
+    kinds = cfg.layer_kinds()
+    splits = [
+        (lead, kinds[lead : lead + p])
+        for lead in range(len(kinds))
+        for p in range(1, len(kinds) - lead + 1)
+        if kinds[lead:] == kinds[lead : lead + p] * ((len(kinds) - lead) // p)
+    ]
+    return min(splits, key=lambda split: (split[0] + len(split[1]), split[0]))
+
+
+def _kind_key(kind: Tuple[str, str]) -> str:
+    return f"{kind[0]}.{kind[1]}"
+
+
+def _of_one_kind(cfg: TransformerConfig) -> bool:
+    return len(set(cfg.layer_kinds())) == 1
+
+
+def _slots(kinds) -> Tuple[Tuple[str, int], ...]:
+    """For layers of these kinds in order: (kind's key, index among the
+    layers of its kind) — where a layer's parameters sit in its group."""
+    seen: Dict[str, int] = {}
+    out = []
+    for kind in kinds:
+        key = _kind_key(kind)
+        out.append((key, seen.get(key, 0)))
+        seen[key] = seen.get(key, 0) + 1
+    return tuple(out)
+
+
+def _group_sizes(kinds) -> Dict[str, Tuple[Tuple[str, str], int]]:
+    """By kind's key: (the kind, how many of ``kinds`` are of it)."""
+    return {_kind_key(kind): (kind, n) for kind, n in collections.Counter(kinds).items()}
 
 
 # Named shapes (``TransformerConfig(**PRESETS[name])``), one definition for
@@ -127,87 +256,213 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 }
 
 
-def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
-    """Params as a pytree of float32 numpy-backed arrays; leading [pp, Lp]
-    axes on per-layer tensors."""
+# leaves that stay float32 under a narrower compute dtype: the decay's two
+# parameters (a rounded log-decay is another model) and the selection bias
+_F32_LEAVES = ("a_log", "dt_bias", "router_bias")
+
+
+def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[int, ...]) -> Dict[str, Any]:
+    """The parameters of ``prod(lead)`` layers of one kind, each leaf with
+    the leading axes ``lead``."""
     keys = jax.random.split(rng, 16)
-    d, qkv, f = cfg.d_model, cfg.qkv_dim, cfg.d_ff
-    lp, pp = cfg.layers_per_stage, max(cfg.pp, 1)
+    more = iter(jax.random.split(keys[10], 16))  # leaves the first kind of layer did not have
+    mixer, ff = kind
+    d = cfg.d_model
+
+    def dense(key, *shape, fan_in):
+        return (
+            jax.random.normal(key, lead + shape, jnp.float32) * (fan_in**-0.5)
+        )
+
+    def ones(*shape):
+        return jnp.ones(lead + shape, jnp.float32)
+
+    layers: Dict[str, Any] = {"ln1": ones(d), "ln2": ones(d)}
+    if mixer == "full":
+        qkv = cfg.qkv_dim
+        layers.update(
+            wq=dense(keys[0], d, qkv, fan_in=d),
+            wk=dense(keys[1], d, qkv, fan_in=d),
+            wv=dense(keys[2], d, qkv, fan_in=d),
+            wo=dense(keys[3], qkv, d, fan_in=qkv),
+        )
+        if cfg.qk_norm:
+            layers.update(q_norm=ones(qkv), k_norm=ones(qkv))
+    elif mixer == "kda":
+        hd, taps = cfg.linear_head_dim, cfg.conv_kernel
+        ch = cfg.linear_n_heads * hd
+        layers.update(
+            wq=dense(keys[0], d, ch, fan_in=d),
+            wk=dense(keys[1], d, ch, fan_in=d),
+            wv=dense(keys[2], d, ch, fan_in=d),
+            wo=dense(keys[3], ch, d, fan_in=ch),
+            conv_q=dense(next(more), taps, ch, fan_in=taps),
+            conv_k=dense(next(more), taps, ch, fan_in=taps),
+            conv_v=dense(next(more), taps, ch, fan_in=taps),
+            w_fa=dense(next(more), d, hd, fan_in=d),
+            # a tenth of the usual scale: the initial decay is dt_bias's, as in the
+            # published model class (at the full scale the log-decay's input is
+            # N(0, 1) and a position forgets up to 60 nats)
+            w_fb=0.1 * dense(next(more), hd, ch, fan_in=hd),
+            w_ga=dense(next(more), d, hd, fan_in=d),
+            w_gb=dense(next(more), hd, ch, fan_in=hd),
+            w_beta=dense(next(more), d, cfg.linear_n_heads, fan_in=d),
+            # exp(a_log) in [1, 16] and softplus(dt_bias) in [1e-3, 1e-1], log-uniform:
+            # a position forgets between a thousandth and most of a channel
+            a_log=jnp.log(jax.random.uniform(next(more), lead + (cfg.linear_n_heads,), jnp.float32, 1.0, 16.0)),
+            dt_bias=_inv_softplus(jnp.exp(jax.random.uniform(
+                next(more), lead + (ch,), jnp.float32, np.log(1e-3), np.log(1e-1)
+            ))),
+            o_norm=ones(hd),
+        )
+    elif mixer == "mla":
+        h, rank = cfg.n_heads, cfg.kv_lora_rank
+        nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        layers.update(
+            wq=dense(keys[0], d, h * (nope + rope), fan_in=d),
+            w_kva=dense(keys[1], d, rank + rope, fan_in=d),
+            kv_norm=ones(rank),
+            w_kvb=dense(keys[2], rank, h * (nope + dv), fan_in=rank),
+            wo=dense(keys[3], h * dv, d, fan_in=h * dv),
+        )
+    else:
+        raise ValueError(f"unknown mixer {mixer!r}")
+    if ff == "experts":
+        e, held, f = cfg.n_experts, cfg.experts_held, cfg.expert_d_ff
+        layers.update(
+            router=dense(keys[4], d, e, fan_in=d),
+            w_gate=dense(keys[5], held, d, f, fan_in=d),
+            w_in=dense(keys[6], held, d, f, fan_in=d),
+            w_out=dense(keys[7], held, f, d, fan_in=f),
+        )
+        if cfg.router_gate == "sigmoid":
+            layers.update(router_bias=jnp.zeros(lead + (e,), jnp.float32))
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            layers.update(
+                shared_gate=dense(next(more), d, fs, fan_in=d),
+                shared_in=dense(next(more), d, fs, fan_in=d),
+                shared_out=dense(next(more), fs, d, fan_in=fs),
+            )
+    else:
+        f = cfg.d_ff
+        layers.update(
+            w_gate=dense(keys[5], d, f, fan_in=d),
+            w_in=dense(keys[6], d, f, fan_in=d),
+            w_out=dense(keys[7], f, d, fan_in=f),
+        )
+    return layers
+
+
+def _inv_softplus(y: jnp.ndarray) -> jnp.ndarray:
+    return y + jnp.log(-jnp.expm1(-y))
+
+
+def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
+    """Params as a pytree of float32 arrays. A model of one kind of layer:
+    ``layers``, each leaf with leading [pp, Lp] axes. A declared pattern
+    (:func:`layer_pattern`): ``lead`` and ``periods``, each a dict by kind of
+    layer (``"<mixer>.<ff>"``) of that kind's leaves, with leading axes [n]
+    (the leading layers of the kind) and [repeats, n] (those in a period)."""
+    keys = jax.random.split(rng, 16)
+    d = cfg.d_model
 
     def dense(key, *shape, fan_in):
         return (
             jax.random.normal(key, shape, jnp.float32) * (fan_in**-0.5)
         )
 
-    layers: Dict[str, Any] = {
-        "ln1": jnp.ones((pp, lp, d), jnp.float32),
-        "ln2": jnp.ones((pp, lp, d), jnp.float32),
-        "wq": dense(keys[0], pp, lp, d, qkv, fan_in=d),
-        "wk": dense(keys[1], pp, lp, d, qkv, fan_in=d),
-        "wv": dense(keys[2], pp, lp, d, qkv, fan_in=d),
-        "wo": dense(keys[3], pp, lp, qkv, d, fan_in=qkv),
-    }
-    if cfg.qk_norm:
-        layers.update(
-            q_norm=jnp.ones((pp, lp, qkv), jnp.float32),
-            k_norm=jnp.ones((pp, lp, qkv), jnp.float32),
-        )
-    if cfg.n_experts:
-        e = cfg.n_experts
-        layers.update(
-            router=dense(keys[4], pp, lp, d, e, fan_in=d),
-            w_gate=dense(keys[5], pp, lp, e, d, f, fan_in=d),
-            w_in=dense(keys[6], pp, lp, e, d, f, fan_in=d),
-            w_out=dense(keys[7], pp, lp, e, f, d, fan_in=f),
-        )
-    else:
-        layers.update(
-            w_gate=dense(keys[5], pp, lp, d, f, fan_in=d),
-            w_in=dense(keys[6], pp, lp, d, f, fan_in=d),
-            w_out=dense(keys[7], pp, lp, f, d, fan_in=f),
-        )
-    return {
+    params: Dict[str, Any] = {
         "embed": dense(keys[8], cfg.vocab_size, d, fan_in=1.0),
-        "layers": layers,
         "final_norm": jnp.ones((d,), jnp.float32),
         "out": dense(keys[9], d, cfg.vocab_size, fan_in=d),
     }
+    if _of_one_kind(cfg):
+        lead = (max(cfg.pp, 1), cfg.layers_per_stage)
+        params["layers"] = _init_layers(rng, cfg, cfg.layer_kinds()[0], lead)
+        return params
+    n_lead, period = layer_pattern(cfg)
+    kinds = cfg.layer_kinds()
+    repeats = (cfg.n_layers - n_lead) // len(period)
+    params["lead"] = {
+        key: _init_layers(jax.random.fold_in(rng, 1 + i), cfg, kind, (n,))
+        for i, (key, (kind, n)) in enumerate(sorted(_group_sizes(kinds[:n_lead]).items()))
+    }
+    params["periods"] = {
+        key: _init_layers(jax.random.fold_in(rng, 101 + i), cfg, kind, (repeats, n))
+        for i, (key, (kind, n)) in enumerate(sorted(_group_sizes(period).items()))
+    }
+    return params
+
+
+def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any, ...]) -> Dict[str, Any]:
+    """PartitionSpec per leaf of one kind of layer under the leading axes
+    ``lead`` (matches :func:`_init_layers`)."""
+    mixer, ff = kind
+
+    def spec(*axes):
+        return P(*lead, *axes)
+
+    row, col = spec("fsdp", "tp"), spec("tp", "fsdp")
+    layers: Dict[str, Any] = {"ln1": spec(None), "ln2": spec(None)}
+    if mixer == "full":
+        layers.update(wq=row, wk=row, wv=row, wo=col)
+        if cfg.qk_norm:
+            # over the tp-sharded projection: the norm's mean is one all-reduce
+            layers.update(q_norm=spec("tp"), k_norm=spec("tp"))
+    elif mixer == "kda":
+        # heads over tp, as full attention's; the narrow side of the low-rank gates whole
+        layers.update(
+            wq=row, wk=row, wv=row, wo=col,
+            conv_q=spec(None, "tp"), conv_k=spec(None, "tp"), conv_v=spec(None, "tp"),
+            w_fa=spec("fsdp", None), w_fb=spec(None, "tp"),
+            w_ga=spec("fsdp", None), w_gb=spec(None, "tp"),
+            w_beta=spec("fsdp", "tp"), a_log=spec("tp"), dt_bias=spec("tp"), o_norm=spec(None),
+        )
+    else:
+        layers.update(
+            wq=row, w_kva=spec("fsdp", None), kv_norm=spec(None), w_kvb=spec(None, "tp"), wo=col,
+        )
+    if ff == "experts":
+        layers.update(
+            router=spec("fsdp", None),
+            w_gate=spec("ep", "fsdp", "tp"),
+            w_in=spec("ep", "fsdp", "tp"),
+            w_out=spec("ep", "tp", "fsdp"),
+        )
+        if cfg.router_gate == "sigmoid":
+            layers.update(router_bias=spec(None))
+        if cfg.n_shared_experts:
+            layers.update(shared_gate=row, shared_in=row, shared_out=col)
+    else:
+        layers.update(w_gate=row, w_in=row, w_out=col)
+    return layers
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpec per leaf (matches init_params structure)."""
-    row, col = P("pp", None, "fsdp", "tp"), P("pp", None, "tp", "fsdp")
-    layers: Dict[str, Any] = {
-        "ln1": P("pp", None, None),
-        "ln2": P("pp", None, None),
-        "wq": row,
-        "wk": row,
-        "wv": row,
-        "wo": col,
-    }
-    if cfg.qk_norm:
-        # over the tp-sharded projection: the norm's mean is one all-reduce
-        layers.update(q_norm=P("pp", None, "tp"), k_norm=P("pp", None, "tp"))
-    if cfg.n_experts:
-        layers.update(
-            router=P("pp", None, "fsdp", None),
-            w_gate=P("pp", None, "ep", "fsdp", "tp"),
-            w_in=P("pp", None, "ep", "fsdp", "tp"),
-            w_out=P("pp", None, "ep", "tp", "fsdp"),
-        )
-    else:
-        layers.update(w_gate=row, w_in=row, w_out=col)
-    return {
+    specs: Dict[str, Any] = {
         # [V,D] with vocab UNSHARDED and D over (tp,fsdp): the same bytes
         # per device as the row+col P("tp","fsdp") layout, but the token
         # gather is fully local and the cotangent lands in the stored
         # layout — SPMD previously fell back to involuntary full
         # rematerialization on both (round-3 review missing #2)
         "embed": P(None, ("tp", "fsdp")),
-        "layers": layers,
         "final_norm": P(None),
         "out": P("fsdp", "tp"),
     }
+    if _of_one_kind(cfg):
+        specs["layers"] = _layer_specs(cfg, cfg.layer_kinds()[0], ("pp", None))
+        return specs
+    n_lead, period = layer_pattern(cfg)
+    specs["lead"] = {
+        key: _layer_specs(cfg, kind, (None,))
+        for key, (kind, _) in _group_sizes(cfg.layer_kinds()[:n_lead]).items()
+    }
+    specs["periods"] = {
+        key: _layer_specs(cfg, kind, (None, None)) for key, (kind, _) in _group_sizes(period).items()
+    }
+    return specs
 
 
 def _act_spec(sp_manual: bool = False) -> P:
@@ -229,24 +484,67 @@ def _ffn_dense(lp: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
     return swiglu(x, lp["w_gate"], lp["w_in"], lp["w_out"])
 
 
+def _route(lp: Dict[str, Any], tokens: jnp.ndarray, cfg: TransformerConfig):
+    """(weights [T, k] as they are applied, experts [T, k], scores [T, E]
+    float32 summing to one over E) of the router's gate."""
+    logits = jnp.dot(tokens, lp["router"], preferred_element_type=jnp.float32)
+    if cfg.router_gate == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)
+        return top_w, top_idx, probs
+    scores = jax.nn.sigmoid(logits)
+    # the bias moves which experts are chosen and not what they weigh
+    _, top_idx = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32), cfg.top_k)
+    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+    if cfg.router_renormalize:
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    top_w = top_w * cfg.routed_scaling_factor
+    return top_w, top_idx, scores / jnp.sum(scores, axis=-1, keepdims=True)
+
+
+def _held_row_bound(cfg: TransformerConfig, rows: int) -> int:
+    """Rows the grouped matmuls of a layer under a share are sized for:
+    twice what the held experts draw at balance, in whole tiles of 512 —
+    ``moe_dropless_held`` takes all ``rows`` in a step that exceeds it."""
+    at_balance = rows * cfg.experts_held / cfg.n_experts
+    return min(rows, -(-int(2 * at_balance) // 512) * 512)
+
+
 def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig):
-    """Sparse experts, dropless: ``p = softmax(h·Wr)`` in float32, the k
-    largest chosen, their ``p`` applied as they are (not renormalised), every
-    chosen expert counted whatever its load. Returns (y, (balance term
-    ``E·Σ_e f_e·P_e`` over the call's tokens, tokens per expert [E]))."""
+    """Sparse experts, dropless: the router's gate (:func:`_route`) picks k
+    experts a token, every chosen expert counted whatever its load; under a
+    share (``n_experts_held``) the experts held here compute their part and
+    what the absent ones would add is left out; a shared expert is a dense
+    SwiGLU beside them. Returns (y, (balance term ``E·Σ_e f_e·P_e`` over the
+    call's tokens, tokens per expert [E]) and, under a share, the rows held)."""
     b, s, d = x.shape
     tokens = x.reshape(b * s, d)
     with jax.named_scope("router"):
-        logits = jnp.dot(tokens, lp["router"], preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)
-    y, counts = moe_dropless(
-        tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"]
-    )
+        top_w, top_idx, probs = _route(lp, tokens, cfg)
+    if cfg.n_experts_held:
+        y, held = moe_dropless_held(
+            tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"],
+            first_expert=cfg.expert_share_index * cfg.n_experts_held,
+            row_bound=_held_row_bound(cfg, b * s * cfg.top_k),
+        )
+        with jax.named_scope("router"):
+            flat = top_idx.reshape(-1)
+            counts = jnp.sum(
+                flat[:, None] == jnp.arange(cfg.n_experts, dtype=flat.dtype), axis=0, dtype=jnp.int32
+            )
+        more = (held,)
+    else:
+        y, counts = moe_dropless(
+            tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"]
+        )
+        more = ()
     with jax.named_scope("router"):
         frac = counts.astype(jnp.float32) / (b * s)  # sums to k; no gradient
         balance = cfg.n_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
-    return y.reshape(b, s, d), (balance, counts)
+    if cfg.n_shared_experts:
+        with jax.named_scope("shared"):
+            y = y + swiglu(tokens, lp["shared_gate"], lp["shared_in"], lp["shared_out"])
+    return y.reshape(b, s, d), (balance, counts) + more
 
 
 def _ffn_moe_ep(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
@@ -405,20 +703,30 @@ def _use_chunked(cfg: TransformerConfig, seq_len: int) -> bool:
 
 
 def _attention_path(
-    cfg: TransformerConfig, seq_len: int, batch: int, mesh, sp_manual: bool = False
+    cfg: TransformerConfig, seq_len: int, batch: int, mesh, sp_manual: bool = False,
+    widths: Optional[Tuple[int, int]] = None,
 ) -> Tuple[str, str, Optional[Tuple[int, int]]]:
     """(impl, reason, (block_q, block_k) or None): which code computes the
     causal core softmax(QKᵀ)V of a layer, decided from what can be
     observed — the backend, the mesh, whether the caller is already inside
     a manual region, and the shapes. impl is "ring" (sp > 1), "flash" (the
-    Pallas kernel), "chunked" or "plain"."""
+    Pallas kernel), "chunked" or "plain". ``widths``: a head's (key, value)
+    widths where they are not ``cfg.head_dim`` (a latent attention's): the
+    kernel reads heads in place when the VALUES are whole lane tiles and
+    pads the keys with zero columns to the next one."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
     if sp_size > 1:
+        if widths is not None and widths[0] != widths[1]:
+            raise ValueError(
+                f"sp={sp_size} with keys {widths[0]} and values {widths[1]} wide: ring attention "
+                "(ops/attention.ring_attention_local) accumulates in the keys' width; it has to "
+                "learn a value width before a latent attention's sequence can be sharded"
+            )
         return "ring", "sp > 1: the sequence is sharded over chips", None
     # the kernel needs its own (full) manual region, which cannot nest in
     # the pipeline's partial-manual shard_map (Shardy rejects it)
     inside_manual = sp_manual or (mesh is not None and mesh.shape.get("pp", 1) > 1)
-    fast = _flash_blocks(seq_len, cfg.head_dim)
+    fast = _flash_blocks(seq_len, widths[1] if widths else cfg.head_dim)
     if (
         cfg.attention_impl == "auto"
         and fast is not None
@@ -456,26 +764,46 @@ def _attention_path(
 _PATHS_SAID: set = set()
 
 
-def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg) -> None:
-    """One ``attention_path`` event and one INFO line per traced shape, so a
-    worker's log and event trail say which core every program took and why."""
-    block_q, block_k = blocks or (0, 0)
-    fields = dict(
-        impl=impl, block_q=block_q, block_k=block_k, batch=batch, seq=seq_len,
-        head_dim=cfg.head_dim, reason=reason,
-    )
-    key = (*fields.values(), cfg.n_heads)
-    if key in _PATHS_SAID:
+def _say_once(kind: str, key, **fields) -> None:
+    """One ``kind`` event and one INFO line with the same text, once a
+    process for each ``key``."""
+    if (kind, key) in _PATHS_SAID:
         return
-    _PATHS_SAID.add(key)
+    _PATHS_SAID.add((kind, key))
     import logging
 
     from torchft_tpu import telemetry
 
-    telemetry.emit("attention_path", **fields)
+    telemetry.emit(kind, **fields)
     logging.getLogger(__name__).info(
-        "attention_path %s", " ".join(f"{k}={v}" for k, v in fields.items())
+        "%s %s", kind, " ".join(f"{k}={v}" for k, v in fields.items())
     )
+
+
+def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None) -> None:
+    """One ``attention_path`` event and one INFO line per traced shape, so a
+    worker's log and event trail say which core every program took and why."""
+    block_q, block_k = blocks or (0, 0)
+    key_dim, value_dim = widths or (cfg.head_dim, cfg.head_dim)
+    fields = dict(
+        impl=impl, block_q=block_q, block_k=block_k, batch=batch, seq=seq_len,
+        head_dim=key_dim, value_dim=value_dim, reason=reason,
+    )
+    _say_once("attention_path", (*fields.values(), cfg.n_heads), **fields)
+
+
+def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None:
+    """One ``layer_pattern`` event and INFO line per traced shape of a model
+    with a declared pattern: what the stack unrolls and what it scans."""
+    n_lead, period = layer_pattern(cfg)
+    kinds = cfg.layer_kinds()
+    fields = dict(
+        layers=cfg.n_layers, lead=",".join(_kind_key(k) for k in kinds[:n_lead]) or "-",
+        period=",".join(_kind_key(k) for k in period),
+        repeats=(cfg.n_layers - n_lead) // len(period),
+        experts_held=cfg.experts_held, experts=cfg.n_experts, batch=batch, seq=seq_len,
+    )
+    _say_once("layer_pattern", tuple(fields.values()), **fields)
 
 
 def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int]):
@@ -507,11 +835,140 @@ def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int]):
     )(q, k, v)
 
 
-def _make_layer_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
+def _causal_core(cfg, mesh, sp_manual, q, k, v, widths=None, scope="core"):
+    """softmax(QKᵀ)V by the code :func:`_attention_path` picks, said once."""
+    b, s = q.shape[:2]
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
-    experts_over_chips = (
-        bool(cfg.n_experts) and mesh is not None and mesh.shape.get("ep", 1) > 1
-    )
+    # s is the sp-local block inside a manual region; the rule
+    # reads sp from the mesh, not from s
+    impl, why, blocks = _attention_path(cfg, s, b, mesh, sp_manual, widths=widths)
+    _say_attention_path(impl, why, blocks, b, s, cfg, widths)
+    with jax.named_scope(scope):
+        if impl == "ring" and sp_manual:
+            return ring_attention_local(q, k, v, sp_size, causal=True)
+        if impl == "ring":
+            return ring_attention(q, k, v, mesh, causal=True)
+        if impl == "flash":
+            return _flash_sharded(q, k, v, mesh, blocks)
+        if impl == "chunked":
+            return chunked_attention(
+                q, k, v, causal=True, chunk=_attn_chunk(s),
+                tiers=_attn_tiers(),
+            )
+        return attention(q, k, v, causal=True)
+
+
+def _mix_full(cfg, mesh, sp_manual, lp, h):
+    sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    b, s, _ = h.shape  # s is the sp-local block inside a manual region
+    if sp_manual and sp_size > 1:
+        positions = jax.lax.axis_index("sp") * s + jnp.arange(s)
+    else:
+        positions = jnp.arange(s)
+    q, k = h @ lp["wq"], h @ lp["wk"]
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(b, s, cfg.n_heads, cfg.head_dim)
+    v = (h @ lp["wv"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+    q = rotary_embed(q, positions, cfg.rope_theta)
+    k = rotary_embed(k, positions, cfg.rope_theta)
+    att = _causal_core(cfg, mesh, sp_manual, q, k, v)
+    return att.reshape(b, s, cfg.qkv_dim) @ lp["wo"]
+
+
+def _mix_mla(cfg, mesh, sp_manual, lp, h):
+    """Latent attention without positions: per-head keys and values come
+    from one normalised latent, a second key part is shared by all heads
+    and NOT rotated; keys are wider than values."""
+    b, s, _ = h.shape
+    heads, rank = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("mla"):
+        q = (h @ lp["wq"]).reshape(b, s, heads, nope + rope)
+        latent = h @ lp["w_kva"]
+        c = rms_norm(latent[..., :rank], lp["kv_norm"], cfg.norm_eps)
+        kv = (c @ lp["w_kvb"]).reshape(b, s, heads, nope + dv)
+        shared = jnp.broadcast_to(latent[:, :, None, rank:], (b, s, heads, rope))
+        k = jnp.concatenate([kv[..., :nope], shared], axis=-1)
+        att = _causal_core(
+            cfg, mesh, sp_manual, q, k, kv[..., nope:], widths=(nope + rope, dv), scope="mla_core"
+        )
+        return att.reshape(b, s, heads * dv) @ lp["wo"]
+
+
+# Positions a KDA mixer takes at a time. Everything in it but the recurrent
+# state and the convolution's K-1 taps of history is local to a position, so
+# the mixer runs as a ``lax.scan`` over blocks of the sequence that carries
+# those two, each block under its own ``jax.checkpoint``: the float32
+# [batch, block, heads x head_dim] temporaries of the gates and of the chunked
+# core (two dozen of them live in a block's backward) are then a block's and
+# not the sequence's — at b2 x s8192 x 32 x 128 the difference between 15.7 GB
+# of temporaries in the backward, which no chip holds, and 4.5 (PERF.md §6, PR 35).
+_KDA_BLOCK = 1024
+
+
+def _mix_kda(cfg, lp, h):
+    """Gated delta-rule linear attention (``ops/kda.py``): q, k, v through a
+    causal short convolution and SiLU, q and k L2-normalised per head, a
+    per-channel log-decay and a per-head write strength from the layer's
+    input, the output normalised per head and gated. No positions."""
+    b, s, d = h.shape
+    heads, hd, taps = cfg.linear_n_heads, cfg.linear_head_dim, cfg.conv_kernel
+    ch = heads * hd
+    f32 = jnp.float32
+    blk = _KDA_BLOCK if s % _KDA_BLOCK == 0 else s
+
+    def unit(x):  # L2 over a head, in float32
+        xf = x.astype(f32)
+        return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + 1e-6)
+
+    def block(carry, hb):
+        state, before = carry  # [B, H, D, D] float32; [B, K-1, 3·ch]: q | k | v ahead of the convolution
+        qkv = jnp.concatenate([hb @ lp["wq"], hb @ lp["wk"], hb @ lp["wv"]], axis=-1)
+        with jax.named_scope("conv"):
+            filters = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)
+            mixed = jax.nn.silu(short_conv(qkv, filters, before))
+            q, k, v = (mixed[..., i * ch : (i + 1) * ch].reshape(b, blk, heads, hd) for i in range(3))
+        with jax.named_scope("gates"):
+            raw = jnp.dot(hb @ lp["w_fa"], lp["w_fb"], preferred_element_type=f32)
+            g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * jax.nn.softplus(
+                raw + lp["dt_bias"].astype(f32)
+            ).reshape(b, blk, heads, hd)
+            beta = jax.nn.sigmoid(jnp.dot(hb, lp["w_beta"], preferred_element_type=f32))
+            out_gate = jax.nn.sigmoid(
+                jnp.dot(hb @ lp["w_ga"], lp["w_gb"], preferred_element_type=f32)
+            ).astype(hb.dtype).reshape(b, blk, heads, hd)
+        q = (unit(q) * hd**-0.5).astype(v.dtype)
+        k = unit(k).astype(v.dtype)
+        with jax.named_scope("kda_core"):
+            o, state = kda_chunked(q, k, v, g, beta, initial_state=state)
+        o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * out_gate
+        return (state, qkv[:, blk - (taps - 1) :]), o.reshape(b, blk, ch) @ lp["wo"]
+
+    with jax.named_scope("kda"):
+        start = (jnp.zeros((b, heads, hd, hd), f32), jnp.zeros((b, taps - 1, 3 * ch), h.dtype))
+        blocks = jnp.moveaxis(h.reshape(b, s // blk, blk, d), 1, 0)
+        _, out = jax.lax.scan(jax.checkpoint(block), start, blocks)
+        return jnp.moveaxis(out, 0, 1).reshape(b, s, d)
+
+
+def _make_layer_fn(
+    cfg: TransformerConfig, mesh, sp_manual: bool = False, kind: Optional[Tuple[str, str]] = None,
+    remat_parts: bool = False,
+):
+    """The function of one layer of ``kind`` (mixer, feed-forward); absent:
+    the one kind a model of one kind has. ``remat_parts``: ``jax.checkpoint``
+    (``cfg.remat``) around the mixer and around the feed-forward, each by
+    itself, where the caller puts none around the layer — but a ``kda`` mixer,
+    which checkpoints itself block by block (:data:`_KDA_BLOCK`): a second one
+    around it would run its forward a third time."""
+    mixer, ff = kind or cfg.layer_kinds()[0]
+    part = (lambda fn: _remat(cfg, fn)) if remat_parts else (lambda fn: fn)
+    sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    ep_size = mesh.shape.get("ep", 1) if mesh is not None else 1
+    experts_over_chips = ff == "experts" and ep_size > 1
     if experts_over_chips and (cfg.top_k != 2 or cfg.router_aux_loss_coef):
         raise ValueError(
             f"ep={mesh.shape['ep']}: experts over chips still run the top-2 "
@@ -519,75 +976,65 @@ def _make_layer_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
             f"balance term; got top_k={cfg.top_k}, "
             f"router_aux_loss_coef={cfg.router_aux_loss_coef}"
         )
+    if experts_over_chips and (cfg.n_experts_held or cfg.n_shared_experts or cfg.router_gate != "softmax"):
+        raise ValueError(
+            f"ep={ep_size} with a held share, a shared expert or a sigmoid gate: experts over chips "
+            "still run the top-2 capacity dispatch (_ffn_moe_ep); the all-to-all that would carry "
+            "a token's rows to the chips holding its experts, and their outputs back, is missing, "
+            "and a share held on one chip does not stand in for it"
+        )
+    if mixer == "kda" and sp_size > 1:
+        raise ValueError(
+            f"sp={sp_size} with a kda layer: the recurrent state at a sequence shard's start is the "
+            "state at the end of the shard before it; the hand-over of that state (and of the "
+            "short convolution's last taps) from one sp shard to the next is missing"
+        )
 
     def layer_fn(x: jnp.ndarray, lp: Dict[str, Any]):
-        """(x, aux): aux is (balance term, tokens per expert) of a dropless
-        expert layer and () otherwise."""
+        """(x, aux): aux is (balance term, tokens per expert[, rows held]) of
+        a dropless expert layer and () otherwise."""
         aux = ()
         x = _constrain(x, _act_spec(sp_manual))
         with jax.named_scope("attn"):
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            b, s, _ = h.shape  # s is the sp-local block inside a manual region
-            if sp_manual and sp_size > 1:
-                positions = jax.lax.axis_index("sp") * s + jnp.arange(s)
+            if mixer == "full":
+                x = x + part(functools.partial(_mix_full, cfg, mesh, sp_manual))(lp, h)
+            elif mixer == "kda":
+                x = x + _mix_kda(cfg, lp, h)
             else:
-                positions = jnp.arange(s)
-            q, k = h @ lp["wq"], h @ lp["wk"]
-            if cfg.qk_norm:
-                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-            q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
-            k = k.reshape(b, s, cfg.n_heads, cfg.head_dim)
-            v = (h @ lp["wv"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-            q = rotary_embed(q, positions, cfg.rope_theta)
-            k = rotary_embed(k, positions, cfg.rope_theta)
-            # s is the sp-local block inside a manual region; the rule
-            # reads sp from the mesh, not from s
-            impl, why, blocks = _attention_path(cfg, s, b, mesh, sp_manual)
-            _say_attention_path(impl, why, blocks, b, s, cfg)
-            with jax.named_scope("core"):
-                if impl == "ring" and sp_manual:
-                    att = ring_attention_local(q, k, v, sp_size, causal=True)
-                elif impl == "ring":
-                    att = ring_attention(q, k, v, mesh, causal=True)
-                elif impl == "flash":
-                    att = _flash_sharded(q, k, v, mesh, blocks)
-                elif impl == "chunked":
-                    att = chunked_attention(
-                        q, k, v, causal=True, chunk=_attn_chunk(s),
-                        tiers=_attn_tiers(),
-                    )
-                else:
-                    att = attention(q, k, v, causal=True)
-            x = x + att.reshape(b, s, cfg.qkv_dim) @ lp["wo"]
+                x = x + part(functools.partial(_mix_mla, cfg, mesh, sp_manual))(lp, h)
 
-        with jax.named_scope("moe" if cfg.n_experts else "ffn"):
+        with jax.named_scope("moe" if ff == "experts" else "ffn"):
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
             if experts_over_chips:
                 x = x + _ffn_moe_ep(lp, h, cfg)
-            elif cfg.n_experts:
-                y, aux = _ffn_moe(lp, h, cfg)
+            elif ff == "experts":
+                y, aux = part(functools.partial(_ffn_moe, cfg=cfg))(lp, h)
                 x = x + y
             else:
-                x = x + _ffn_dense(lp, h)
+                x = x + part(_ffn_dense)(lp, h)
         return _constrain(x, _act_spec(sp_manual)), aux
 
     return layer_fn
 
 
+def _remat(cfg: TransformerConfig, layer_fn):
+    if not cfg.remat:
+        return layer_fn
+    if cfg.remat_policy == "dots":
+        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    elif cfg.remat_policy == "all":
+        policy = None
+    else:
+        raise ValueError(
+            f"remat_policy={cfg.remat_policy!r}: expected 'all' or "
+            "'dots' (a typo here would silently pay full recompute)"
+        )
+    return jax.checkpoint(layer_fn, policy=policy)
+
+
 def _make_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
-    layer_fn = _make_layer_fn(cfg, mesh, sp_manual)
-    if cfg.remat:
-        if cfg.remat_policy == "dots":
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        elif cfg.remat_policy == "all":
-            policy = None
-        else:
-            raise ValueError(
-                f"remat_policy={cfg.remat_policy!r}: expected 'all' or "
-                "'dots' (a typo here would silently pay full recompute)"
-            )
-        layer_fn = jax.checkpoint(layer_fn, policy=policy)
+    layer_fn = _remat(cfg, _make_layer_fn(cfg, mesh, sp_manual))
 
     def stage_fn(stage_params: Dict[str, Any], x: jnp.ndarray):
         """(x, aux stacked over the stage's layers); see ``layer_fn``."""
@@ -595,6 +1042,46 @@ def _make_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
         return jax.lax.scan(layer_fn, x, stage_params)
 
     return stage_fn
+
+
+def _make_pattern_fn(cfg: TransformerConfig, mesh):
+    """The stack of a declared pattern: ``(lead, periods, x) -> (x, aux)``.
+    The leading layers run one by one, then ``lax.scan`` over the repeats of
+    the period with its layers unrolled in the body, each layer's mixer and
+    feed-forward under a ``jax.checkpoint`` of their own (``remat_parts``).
+    ``aux`` is the expert layers' (see ``layer_fn``), stacked in layer order."""
+    n_lead, period = layer_pattern(cfg)
+    kinds = cfg.layer_kinds()
+    fns = {
+        _kind_key(kind): _make_layer_fn(cfg, mesh, kind=kind, remat_parts=True)
+        for kind in set(kinds)
+    }
+
+    def run(group, slots, x):
+        auxes = []
+        for key, i in slots:
+            lp = jax.tree_util.tree_map(lambda a: a[i], group[key])
+            x, aux = fns[key](x, lp)
+            if aux:
+                auxes.append(aux)
+        return x, auxes
+
+    def pattern_fn(lead, periods, x):
+        x, lead_aux = run(lead, _slots(kinds[:n_lead]), x)
+        x, period_aux = jax.lax.scan(lambda x, group: run(group, _slots(period), x), x, periods)
+        if not lead_aux and not period_aux:
+            return x, ()
+        # [layers with experts, ...]: the leading ones, then repeat by repeat
+        parts = []
+        for j in range(len((lead_aux + period_aux)[0])):
+            rows = [a[j][None] for a in lead_aux]
+            if period_aux:
+                inner = jnp.stack([a[j] for a in period_aux], axis=1)  # [repeats, in a period, ...]
+                rows.append(inner.reshape((-1,) + inner.shape[2:]))
+            parts.append(jnp.concatenate(rows, axis=0))
+        return x, tuple(parts)
+
+    return pattern_fn
 
 
 def _pipeline_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool):
@@ -627,6 +1114,41 @@ def _embed_lookup(
         return _constrain(x, _act_spec())
 
 
+def _compute_dtype(layers: Dict[str, Any], dt) -> Dict[str, Any]:
+    """The layers' leaves in the compute dtype, but :data:`_F32_LEAVES`."""
+    return {
+        name: _compute_dtype(leaf, dt) if isinstance(leaf, dict)
+        else leaf if name in _F32_LEAVES else leaf.astype(dt)
+        for name, leaf in sorted(layers.items())  # tree_map's order
+    }
+
+
+@jax.custom_vjp
+def _gradients_apart(tree):
+    """The identity, whose gradients pass an ``optimization_barrier``: an aid
+    to MEASUREMENT, with its cost in the step measured (PERF.md §6, PR 35).
+    The layers of a declared pattern are unrolled, so XLA is free to run a
+    leaf's optimizer update as the tail of the matrix product that forms its
+    gradient; the update's seconds then count to that product's scope
+    (``attn`` / ``ffn``), and what is left under ``optimizer`` moves its bytes
+    faster than the memory can (105 % of its roofline there). Behind a
+    ``lax.scan`` over identical layers no such fusion exists. It goes when the
+    benchmark's readers can give a fused update's seconds to the optimizer."""
+    return tree
+
+
+_gradients_apart.defvjp(lambda tree: (tree, None), lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
+def _refuse_pattern_under_pp(cfg: TransformerConfig) -> None:
+    if max(cfg.pp, 1) > 1:
+        raise ValueError(
+            f"pp={cfg.pp} with a declared layer pattern: a pipeline stage is a scan over identical "
+            "layers under one [pp, Lp] parameter layout; stages that each hold their own kinds of "
+            "layer (a per-stage tree and stage function in parallel/pipeline.py) are missing"
+        )
+
+
 def _hidden_states(
     params: Dict[str, Any],
     tokens: jnp.ndarray,
@@ -644,10 +1166,16 @@ def _hidden_states(
     dt = cfg.dtype
     x = _embed_lookup(params, tokens, dt)
 
-    layers = jax.tree_util.tree_map(lambda a: a.astype(dt), params["layers"])
-
     pp = max(cfg.pp, 1)
     aux = ()
+    if not _of_one_kind(cfg):
+        _refuse_pattern_under_pp(cfg)
+        _say_layer_pattern(cfg, b, s)
+        lead, periods = _gradients_apart((params["lead"], params["periods"]))
+        x, aux = _make_pattern_fn(cfg, mesh)(_compute_dtype(lead, dt), _compute_dtype(periods, dt), x)
+        return rms_norm(x, params["final_norm"].astype(dt), cfg.norm_eps), aux
+
+    layers = _compute_dtype(params["layers"], dt)
     if pp == 1:
         stage_fn = _make_stage_fn(cfg, mesh, sp_manual=False)
         x, aux = stage_fn(jax.tree_util.tree_map(lambda a: a[0], layers), x)
@@ -707,6 +1235,8 @@ def loss_and_stats(
             "the load-balancing term is not carried across pipeline stages, "
             "and is refused rather than dropped; set the coefficient to 0 or pp=1"
         )
+    if not _of_one_kind(cfg):
+        _refuse_pattern_under_pp(cfg)
     if max(cfg.pp, 1) > 1 and mesh is not None:
         # pipelined training path: the head (final norm + unembed + NLL)
         # runs inside the pipeline's manual region on the last stage and
@@ -720,6 +1250,8 @@ def loss_and_stats(
         return ce, {}
     balance = jnp.mean(aux[0])
     stats = {"tokens_per_expert": aux[1], "balance_loss": balance}
+    if len(aux) > 2:  # under a share: the token-expert rows whose expert is held, a layer
+        stats["rows_held"] = aux[2]
     if cfg.router_aux_loss_coef:
         ce = ce + cfg.router_aux_loss_coef * balance
     return ce, stats
@@ -895,7 +1427,7 @@ def _pipelined_loss(
     dt = cfg.dtype
     pp = cfg.pp
     x = _embed_lookup(params, tokens, dt)
-    layers = jax.tree_util.tree_map(lambda a: a.astype(dt), params["layers"])
+    layers = _compute_dtype(params["layers"], dt)
 
     sp_size = mesh.shape.get("sp", 1)
     sp_manual = sp_size > 1

@@ -43,7 +43,9 @@ def attention(
     v: jnp.ndarray,
     causal: bool = True,
 ) -> jnp.ndarray:
-    """Plain attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh]."""
+    """Plain attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh]; the values
+    may have another width than the keys (the output's), here and in
+    :func:`chunked_attention`."""
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
@@ -121,7 +123,7 @@ def chunked_attention(
             return carry, jnp.einsum("bhqk,bkhd->bqhd", p, v_seg)
 
         _, out = jax.lax.scan(jax.checkpoint(body), 0, (qb, jnp.arange(nq)))
-        return jnp.moveaxis(out, 0, 1).reshape(b, sq, h, d)
+        return jnp.moveaxis(out, 0, 1).reshape(b, sq, h, v_seg.shape[-1])
 
     if not causal or tiers <= 1 or s % (tiers * chunk) != 0:
         return scan_segment(q, k, v, 0)

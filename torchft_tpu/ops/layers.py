@@ -1,5 +1,6 @@
-"""Building-block layers: RMSNorm, rotary embeddings, SwiGLU, and the two
-expert dispatches (dropless top-k, and the capacity einsum kept for ``ep`` > 1).
+"""Building-block layers: RMSNorm, rotary embeddings, SwiGLU, and the
+expert dispatches (dropless top-k, whole or over the block of experts a layer
+holds, and the capacity einsum kept for ``ep`` > 1).
 
 All pure functions over explicit params — XLA fuses the elementwise chains
 into the adjacent matmuls, so there is nothing to hand-schedule here. The
@@ -17,7 +18,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["rms_norm", "rotary_embed", "swiglu", "moe_dropless", "moe_dispatch"]
+__all__ = ["rms_norm", "rotary_embed", "swiglu", "moe_dropless", "moe_dropless_held", "moe_dispatch"]
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
@@ -165,6 +166,129 @@ def moe_dropless(
         out = _take_rows(out, inv, order, 1).reshape(t, k, -1)
         y = jnp.einsum("tkd,tk->td", out, top_w.astype(out.dtype))
     return y, counts
+
+
+def _window_rows_or_zero(rows: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
+    """``rows[at]`` where ``0 <= at < len(rows)``, a zero row elsewhere."""
+    m = rows.shape[0]
+    padded = jnp.concatenate([rows, jnp.zeros((1, rows.shape[1]), rows.dtype)])
+    inside = (at >= 0) & (at < m)
+    return padded.at[jnp.where(inside, at, m)].get(mode="promise_in_bounds")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_window_rows(x: jnp.ndarray, window: jnp.ndarray, at: jnp.ndarray, k: int) -> jnp.ndarray:
+    """``x[window // k]`` for ``window`` m consecutive entries of a
+    permutation of the ``k * len(x)`` row slots; ``at`` [T·k] is each slot's
+    place in that window (outside ``[0, m)``: not in it). The backward
+    gathers each slot's gradient from its place (none, outside) and sums a
+    token's k — no scatter."""
+    return x.at[window // k].get(mode="promise_in_bounds")
+
+
+def _take_window_rows_fwd(x, window, at, k):
+    return _take_window_rows(x, window, at, k), at
+
+
+def _take_window_rows_bwd(k, at, g):
+    back = _window_rows_or_zero(g, at).reshape(-1, k, g.shape[-1]).astype(jnp.float32)
+    return jnp.sum(back, axis=1).astype(g.dtype), None, None
+
+
+_take_window_rows.defvjp(_take_window_rows_fwd, _take_window_rows_bwd)
+
+
+@jax.custom_vjp
+def _spread_window_rows(rows: jnp.ndarray, window: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
+    """The window's m computed rows back in their slots ([T·k, d]; a zero
+    row where a slot is not in the window); the backward reads each of the
+    window's slots."""
+    return _window_rows_or_zero(rows, at)
+
+
+def _spread_window_rows_fwd(rows, window, at):
+    return _spread_window_rows(rows, window, at), window
+
+
+def _spread_window_rows_bwd(window, g):
+    return g.at[window].get(mode="promise_in_bounds", unique_indices=True), None, None
+
+
+_spread_window_rows.defvjp(_spread_window_rows_fwd, _spread_window_rows_bwd)
+
+
+def moe_dropless_held(
+    tokens: jnp.ndarray,
+    top_idx: jnp.ndarray,
+    top_w: jnp.ndarray,
+    w_gate: jnp.ndarray,
+    w_in: jnp.ndarray,
+    w_out: jnp.ndarray,
+    first_expert: int,
+    row_bound: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`moe_dropless` for a layer that holds a contiguous block of the
+    experts a router chooses among (a chip's share of a deployment that
+    divides each layer's experts over chips): ``top_idx`` counts over ALL
+    the router's experts, the weights are those of experts
+    ``[first_expert, first_expert + H)``. What the absent experts would add
+    is left out — their rows are neither gathered nor computed. Returns
+    (y [T, d], rows held = token-expert rows whose expert is here, int32).
+
+    Static shapes without a drop: the row slots are sorted by held expert,
+    absent ones last, and go through the grouped matmuls a window of
+    ``row_bound`` slots at a time. A step whose held rows fit the first
+    window — every step near balance — computes that window and no more; one
+    that exceeds it (``lax.cond``) sums over all the windows in turn, each
+    at the first's memory, so every row routed to a held expert contributes
+    whatever the load."""
+    t, k = top_idx.shape
+    held = w_gate.shape[0]
+    m = min(row_bound, t * k)
+    with jax.named_scope("dispatch"):
+        local = top_idx.reshape(t * k) - first_expert
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True)  # row slots by held expert, the absent last
+        inv = jnp.argsort(order)
+        counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0, dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        n_held = ends[-1]
+        # whole windows: the slots added are past every held row, and masked with them
+        order = jnp.pad(order, (0, -(t * k) % m))
+
+    def window(start) -> jnp.ndarray:
+        """What the sorted slots ``[start, start + m)`` add to y."""
+        with jax.named_scope("dispatch"):
+            slots = jax.lax.dynamic_slice_in_dim(order, start, m)
+            # a held slot's place in the window. The slots past the held ones
+            # are in no expert's group: the grouped matmul leaves their rows
+            # as it found them (whatever the memory held), so nothing may
+            # read them, forward or backward
+            at = jnp.where(inv < n_held, inv - start, -1)
+            rows = _take_window_rows(tokens, slots, at, k)  # [m, d]
+            # each held expert's rows inside the window
+            edges = jnp.clip(jnp.concatenate([jnp.zeros((1,), ends.dtype), ends]), start, start + m)
+            sizes = edges[1:] - edges[:-1]
+        with jax.named_scope("experts"):
+            h = jax.nn.silu(_grouped_matmul(rows, w_gate, sizes)) * _grouped_matmul(rows, w_in, sizes)
+            out = _grouped_matmul(h, w_out, sizes)
+            # rows past the held ones belong to no expert's group
+            out = jnp.where((start + jnp.arange(m))[:, None] < n_held, out, jnp.zeros_like(out))
+        with jax.named_scope("combine"):
+            back = _spread_window_rows(out, slots, at).reshape(t, k, -1)
+            return jnp.einsum("tkd,tk->td", back, top_w.astype(back.dtype))
+
+    if m == t * k:
+        return window(0), n_held
+
+    def every_window():
+        def add(y, start):
+            return y + jax.checkpoint(window)(start), None
+
+        y, _ = jax.lax.scan(add, jnp.zeros(tokens.shape, tokens.dtype), jnp.arange(0, t * k, m))
+        return y
+
+    return jax.lax.cond(n_held <= m, lambda: window(0), every_window), n_held
 
 
 def moe_dispatch(

@@ -12,7 +12,7 @@ of K and V in VMEM, and loops inside the step over tiles of ``block_k``
 keys — pallas double-buffers the next step's blocks against this one's
 compute; the running accumulators (acc/m/l; dk/dv) live in VMEM scratch
 that persists across the inner grid sweep (TPU grids execute sequentially
-per core). With the whole sequence resident (S <= 2048) the forward is one
+per core). With the whole sequence resident (S <= ``_RESIDENT_KEYS``) the forward is one
 step a q block and the backward's dQ is complete within its step; longer
 sequences write dQ in one part per K block, summed outside.
 
@@ -245,19 +245,25 @@ def _row(blk: int, idx) -> pl.BlockSpec:
     )
 
 
-def _params(bq: int, bkc: int) -> pltpu.CompilerParams:
+def _params(bq: int, bkc: int, resident: int = 0) -> pltpu.CompilerParams:
     # the f32 [bq, bkc] temporaries (scores, p, dp, ds, the mask) outgrow
     # the default scoped limit from 512 x 512 on: raise the limit rather
-    # than shrink the tiles (a v5e core has 128 MiB)
+    # than shrink the tiles (a v5e core has 128 MiB). ``resident``: bytes of
+    # the K and V blocks a step keeps (double-buffered in and, as dK and dV,
+    # out, with their f32 accumulators); the 8 MiB of 2048 keys of two lane
+    # tiles are inside the 24
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=min(112 << 20, (24 << 20) + 40 * bq * bkc),
+        vmem_limit_bytes=min(112 << 20, (24 << 20) + 40 * bq * bkc + max(0, resident - (8 << 20))),
     )
 
 
+def _resident_bytes(bk: int, d: int, dv: int) -> int:
+    return 12 * bk * (d + dv)
+
+
 def _fwd(q, k, v, shape, blocks, causal, interpret):
-    b, s, h, d = shape
-    scale = d**-0.5
+    b, s, h, d, dv, scale = shape
     bq, bk, bkc = blocks
     lanes = q.ndim == 3
     q_at = lambda i, j: i
@@ -273,19 +279,19 @@ def _fwd(q, k, v, shape, blocks, causal, interpret):
         in_specs=[
             _tile(lanes, bq, d, q_at),
             _tile(lanes, bk, d, k_at),
-            _tile(lanes, bk, d, k_at),
+            _tile(lanes, bk, dv, k_at),
         ],
-        out_specs=[_tile(lanes, bq, d, q_at), _row(bq, q_at)],
+        out_specs=[_tile(lanes, bq, dv, q_at), _row(bq, q_at)],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(q.shape[:-1] + (v.shape[-1],), q.dtype),
             jax.ShapeDtypeStruct((b, h, _ROWS, s), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((d, bq), jnp.float32),
+            pltpu.VMEM((dv, bq), jnp.float32),
             pltpu.VMEM((_ROWS, bq), jnp.float32),
             pltpu.VMEM((_ROWS, bq), jnp.float32),
         ],
-        compiler_params=_params(bq, bkc),
+        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv)),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
@@ -293,8 +299,7 @@ def _fwd(q, k, v, shape, blocks, causal, interpret):
 
 def _bwd(shape, blocks, causal, interpret, res, do):
     q, k, v, o, lse = res
-    b, s, h, d = shape
-    scale = d**-0.5
+    b, s, h, d, dv, scale = shape
     bq, bk, bkc = blocks
     lanes = q.ndim == 3
     nk = s // bk
@@ -311,16 +316,16 @@ def _bwd(shape, blocks, causal, interpret, res, do):
         in_specs=[
             _tile(lanes, bq, d, q_at),
             _tile(lanes, bk, d, k_at),
-            _tile(lanes, bk, d, k_at),
-            _tile(lanes, bq, d, q_at),
-            _tile(lanes, bq, d, q_at),
+            _tile(lanes, bk, dv, k_at),
+            _tile(lanes, bq, dv, q_at),
+            _tile(lanes, bq, dv, q_at),
             _row(bq, q_at),
         ],
         out_specs=[
             # dq's part of EVERY (k block, q block), zeros above the diagonal
             _tile(lanes, bq, d, lambda j, i: i, lead=True),
             _tile(lanes, bk, d, k_at),
-            _tile(lanes, bk, d, k_at),
+            _tile(lanes, bk, dv, k_at),
         ],
         out_shape=[
             # one k block (the whole sequence resident): the part is dq
@@ -330,10 +335,10 @@ def _bwd(shape, blocks, causal, interpret, res, do):
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_params(bq, bkc),
+        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv)),
         interpret=interpret,
         name="flash_bwd",
     )(q, k, v, o, do, lse)
@@ -358,7 +363,11 @@ def _flash_fwd(q, k, v, shape, blocks, causal, interpret):
 
 _flash.defvjp(_flash_fwd, _bwd)
 
-_RESIDENT_KEYS = 2048  # most rows of K and V a grid step keeps in VMEM
+# Most rows of K and V a grid step keeps in VMEM. A sequence up to this long
+# is one K block: the backward's dQ is then complete within its step, where a
+# longer one writes a float32 part per K block ([4, B, S, H·D] = 2.1 GB at
+# s8192 under the 2048 this was until PR 35) and sums them outside.
+_RESIDENT_KEYS = 8192
 
 
 def flash_attention(
@@ -374,8 +383,20 @@ def flash_attention(
 
     ``block_q`` x ``block_k`` is the tile of scores computed at a time.
     Requires S % block == 0 (pick smaller blocks for short sequences).
-    Differentiable (custom FlashAttention-2 backward)."""
+    Differentiable (custom FlashAttention-2 backward).
+
+    Values may be narrower or wider than keys (q, k [B, S, H, Dk], v
+    [B, S, H, Dv] -> [B, S, H, Dv]; scores scaled by Dk^-1/2). Where the
+    values are whole lane tiles and the keys are not (a latent attention's
+    128 + 64 against 128), q and k are padded with zero columns to the next
+    lane tile, which changes no score, so that heads are still read in place."""
     b, s, h, d = q.shape
+    dv = v.shape[-1]
+    scale = d**-0.5
+    if d % _LANES and dv % _LANES == 0:
+        widen = ((0, 0), (0, 0), (0, 0), (0, -d % _LANES))
+        q, k = jnp.pad(q, widen), jnp.pad(k, widen)
+        d += -d % _LANES
     bq = min(block_q, s)
     bkc = min(block_k, s)
     if s % bq or s % bkc:
@@ -384,24 +405,24 @@ def flash_attention(
         interpret = _should_interpret()
     # K and V arrive in the largest whole number of tiles that divides S and
     # stays under _RESIDENT_KEYS rows: fewer grid steps and DMAs than a tile
-    # a step, and at S <= 2048 the backward's dq needs no second pass
+    # a step, and up to _RESIDENT_KEYS the backward's dq needs no second pass
     tiles = max(
         m for m in range(1, max(_RESIDENT_KEYS // bkc, 1) + 1) if (s // bkc) % m == 0
     )
     blocks = (bq, bkc * tiles, bkc)
 
-    if d % _LANES == 0:
+    if d % _LANES == 0 and dv % _LANES == 0:
         # a head's columns are whole lane tiles of the [B, S, H·Dh] view
         def pack(x):
-            return x.reshape(b, s, h * d)
+            return x.reshape(b, s, h * x.shape[-1])
 
         def unpack(x):
-            return x.reshape(b, s, h, d)
+            return x.reshape(b, s, h, dv)
     else:
         def pack(x):
             return x.transpose(0, 2, 1, 3)
 
         unpack = pack
 
-    o = _flash(pack(q), pack(k), pack(v), (b, s, h, d), blocks, causal, interpret)
+    o = _flash(pack(q), pack(k), pack(v), (b, s, h, d, dv, scale), blocks, causal, interpret)
     return unpack(o)

@@ -158,13 +158,18 @@ class FTTrainer(SpeculativeCommitMixin):
             return
         import numpy as np
 
-        load = np.asarray(stats["tokens_per_expert"])  # [layers, experts]
+        load = np.asarray(stats["tokens_per_expert"])  # [layers with experts, the router's experts]
+        # token-expert rows the step routed, and those whose expert is held
+        # here: all of them, but under a share (``n_experts_held``)
+        rows_routed = int(load.sum())
         counters = dict(
             step=step,
             max_load=int(load.max()),
             min_load=int(load.min()),
             mean_load=float(load.mean()),
             balance_loss=float(stats["balance_loss"]),
+            rows_routed=rows_routed,
+            rows_held=int(np.asarray(stats["rows_held"]).sum()) if "rows_held" in stats else rows_routed,
         )
         sync_span.set(**counters)
         # an annotation takes its stats at entry: a zero-length one carries them

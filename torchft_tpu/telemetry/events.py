@@ -77,6 +77,7 @@ CANONICAL_EVENTS = (
     "perf_regression_cleared",
     "diagnosis_captured",
     "attention_path",
+    "layer_pattern",
 )
 
 # The protocol-lifecycle subset of the vocabulary: the events the
