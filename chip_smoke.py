@@ -526,7 +526,11 @@ def kernels_child(
 def _kda_cells(shape: list, dev) -> bool:
     """Chunked KDA, forward and backward, bf16 operands, against the
     recurrence position by position in f32. Outputs are of order 0.3 and
-    bf16 operands put them 2e-3 off (tests/test_kda.py): 2e-2 + 2e-2 |ref|."""
+    bf16 operands put them 2e-3 off (tests/test_kda.py): 2e-2 + 2e-2 |ref|,
+    the 2e-2 in units of the array's largest entry where that is over 1 — q's
+    gradient reaches 11.7 here and bf16 operands put it 0.028 off past the
+    relative part, in the kernels and in the jax.numpy form alike (PR 36, on
+    the CPU and on the chip: the absolute 2e-2 failed both)."""
     import jax
     import jax.numpy as jnp
 
@@ -549,18 +553,22 @@ def _kda_cells(shape: list, dev) -> bool:
     with jax.default_matmul_precision("highest"):
         ref_fn = jax.jit(jax.value_and_grad(lambda *a: loss(kda_recurrent, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
         (_, o_ref), g_ref = ref_fn(*(x.astype(jnp.float32) for x in (qb, kb, vb)), g, beta)
+    # on a TPU, heads 128 wide are the Pallas kernels' (ops/pallas/kda.py): a
+    # silent fall back to the jax.numpy form fails here and not only a metric
+    must_be_mosaic = dev.platform == "tpu" and d == 128
+    mosaic = "tpu_custom_call" in got_fn.lower(qb, kb, vb, g, beta).as_text()
     t0 = time.perf_counter()
     (_, o), grads = jax.block_until_ready(got_fn(qb, kb, vb, g, beta))
     t_first = time.perf_counter() - t0
     atol = rtol = 2e-2
-    errs = {
-        n: float(jnp.max(jnp.abs(a.astype(jnp.float32) - r) - rtol * jnp.abs(r)))
+    errs = {  # past the relative part, in units of the array's scale
+        n: float(jnp.max(jnp.abs(a.astype(jnp.float32) - r) - rtol * jnp.abs(r)) / jnp.maximum(1.0, jnp.max(jnp.abs(r))))
         for n, a, r in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), (o, *grads), (o_ref, *g_ref))
     }
     finite = all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in (o, *grads))
-    ok = finite and max(errs.values()) <= atol
+    ok = finite and max(errs.values()) <= atol and (mosaic or not must_be_mosaic)
     print(json.dumps({
-        "check": "kda_cells", "shape": shape, "ok": ok, "finite": finite,
+        "check": "kda_cells", "shape": shape, "ok": ok, "mosaic_custom_call": mosaic, "finite": finite,
         "err_minus_rtol_ref": {k: round(v, 5) for k, v in errs.items()}, "atol": atol, "rtol": rtol,
         "compile_and_first_run_s": round(t_first, 2), "device": dev.device_kind,
     }), flush=True)

@@ -618,3 +618,34 @@ def test_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, shape):
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
     assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("batch, seq, heads", [(2, 1024, 32), (1, 1024, 8), (1, 128, 2)], ids=str)
+def test_the_kda_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch, seq, heads):
+    """``ops/pallas/kda.py``, forward and backward, through Mosaic for a
+    described v5e (in this file: one process a run may load the TPU's
+    library): a block of the KDA cell's mixer (1024 positions of 32 heads,
+    four heads a grid step), ``chip_smoke.py``'s shape, and two heads a step."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.ops.pallas import kda as kernels
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    wide, g = of((batch, seq, heads * 128), jnp.bfloat16), of((batch, seq, heads * 128), jnp.float32)
+    beta, state = of((batch, seq, heads), jnp.float32), of((batch, heads, 128, 128), jnp.float32)
+    starts = of((batch, heads, seq // kernels.CHUNK, 128, 128), jnp.float32)
+    forward = jax.jit(lambda *a: kernels.kda_forward(*a, interpret=False))
+    backward = jax.jit(lambda *a: kernels.kda_backward(*a, interpret=False))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        texts = (
+            forward.lower(wide, wide, wide, g, beta, state).compile().as_text(),
+            backward.lower(wide, wide, wide, g, beta, starts, wide, state).compile().as_text(),
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert all("tpu_custom_call" in text for text in texts)

@@ -1,7 +1,9 @@
 """``ops/kda.py`` — gated delta-rule linear attention in chunked form and the
 causal short convolution — against the recurrence position by position, and
-the flash kernel with keys wider than values, on the CPU (the kernel
-interpreted). ``tests/test_hybrid.py`` holds the model that uses them to the
+the flash kernel with keys wider than values, on the CPU (the kernels
+interpreted). Heads 128 wide take the Pallas kernels of ``ops/pallas/kda.py``
+(the ``wide`` cases: one sequence of two heads, so that an interpreted call
+takes seconds), every other width the ``jax.numpy`` form. ``tests/test_hybrid.py`` holds the model that uses them to the
 plain reference."""
 
 import jax
@@ -24,43 +26,70 @@ def kda_inputs(b, s, h=2, dk=16, dv=8, seed=0, decay=1.0, dtype=jnp.float32):
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
-@pytest.mark.parametrize("batch, seq, decay", [
-    (1, 128, 1.0),   # a length the chunk divides
-    (2, 100, 1.0),   # one it does not: the tail neither decays nor writes
-    (1, 64, 1.0),    # one chunk
-    (2, 40, 1.0),    # less than one
-    (2, 192, 30.0),  # decays whose inverse overflows float32 inside a chunk: exp(30 x 64)
-    (1, 128, 0.01),  # hardly any decay: the delta rule's solve does the work
+WIDE = dict(h=2, dk=128, dv=128)  # what the kernels take
+NARROW = dict(h=2, dk=16, dv=8)
+
+
+def enters_the_kernel(*args, **kw):
+    return "pallas_call" in str(jax.make_jaxpr(lambda *a: kda.kda_chunked(*a, **kw))(*args))
+
+
+def relative(a, b):
+    return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize("batch, seq, decay, widths", [
+    (1, 128, 1.0, NARROW),   # a length the chunk divides
+    (2, 100, 1.0, NARROW),   # one it does not: the tail neither decays nor writes
+    (1, 64, 1.0, NARROW),    # one chunk
+    (2, 40, 1.0, NARROW),    # less than one
+    (2, 192, 30.0, NARROW),  # decays whose inverse overflows float32 inside a chunk: exp(30 x 64)
+    (1, 128, 0.01, NARROW),  # hardly any decay: the delta rule's solve does the work
+    (1, 128, 1.0, WIDE),
+    (1, 192, 1.0, WIDE),
+    (1, 100, 1.0, WIDE),     # the kernel on a padded tail
+    (1, 128, 0.01, WIDE),
 ])
-def test_chunked_kda_is_the_recurrence(batch, seq, decay):
-    args = kda_inputs(batch, seq, decay=decay)
-    o, state = jax.jit(kda.kda_chunked)(*args)
-    o_want, state_want = jax.jit(kda.kda_recurrent)(*args)
+def test_chunked_kda_is_the_recurrence(batch, seq, decay, widths):
+    args = kda_inputs(batch, seq, decay=decay, **widths)
+    wide = widths is WIDE
+    assert enters_the_kernel(*args) == wide  # a head 16 wide never does
+    state = None
+    if wide:  # a state to start from, and its gradient
+        state = 0.1 * jax.random.normal(jax.random.PRNGKey(11), (batch, 2, 128, 128))
+    o, end = jax.jit(kda.kda_chunked)(*args, initial_state=state)
+    o_want, end_want = jax.jit(kda.kda_recurrent)(*args, initial_state=state)
     assert bool(jnp.all(jnp.isfinite(o)))
     np.testing.assert_allclose(o, o_want, atol=2e-6)  # outputs of order 0.3; float32 sums in another order
-    np.testing.assert_allclose(state, state_want, atol=2e-6)
+    np.testing.assert_allclose(end, end_want, atol=2e-6)
 
-    if seq not in (100, 192):
+    if seq not in (100, 192) and not wide:
         return  # the gradients at a length the chunk does not divide, and under the hard decay
 
     def scalar(fn):
-        return lambda *a: jnp.sum(jnp.sin(fn(*a)[0]))
+        def of(*a):
+            o, end = fn(*a[:5], initial_state=a[5] if wide else None)
+            return jnp.sum(jnp.sin(o)) + (jnp.sum(jnp.sin(end)) if wide else 0.0)
 
-    got = jax.jit(jax.grad(scalar(kda.kda_chunked), argnums=(0, 1, 2, 3, 4)))(*args)
-    want = jax.jit(jax.grad(scalar(kda.kda_recurrent), argnums=(0, 1, 2, 3, 4)))(*args)
+        return of
+
+    wrt = (0, 1, 2, 3, 4, 5) if wide else (0, 1, 2, 3, 4)
+    got = jax.jit(jax.grad(scalar(kda.kda_chunked), argnums=wrt))(*args, state)
+    want = jax.jit(jax.grad(scalar(kda.kda_recurrent), argnums=wrt))(*args, state)
     for a, b in zip(got, want):
-        assert float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))) < 5e-5
+        assert relative(a, b) < 5e-5
 
 
+@pytest.mark.parametrize("widths", [NARROW, WIDE], ids=["narrow", "wide"])
 @pytest.mark.parametrize("alike", [0.0, 0.9, 0.99])
-def test_chunked_kda_with_keys_alike_and_beta_near_one(alike):
+def test_chunked_kda_with_keys_alike_and_beta_near_one(alike, widths):
     """What a model is one optimizer step from its initial values: a chunk's
     keys share a direction and beta is near 1, so ``diag(beta) A_kk`` has
     entries near 1 all of one sign. The delta rule's triangular system has to
     be SOLVED: the product form of its inverse cancels powers of 1e9 there
     (it read 2e18 off here, NaN on the chip at the cell's second step)."""
-    q, k, v, g, beta = kda_inputs(1, 128, decay=0.01)
-    shared = jax.random.normal(jax.random.PRNGKey(7), (1, 1, 2, 16))
+    q, k, v, g, beta = kda_inputs(1, 128, decay=0.01, **widths)
+    shared = jax.random.normal(jax.random.PRNGKey(7), (1, 1, 2, widths["dk"]))
     k = shared + (1 - alike) * k
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     beta = jax.nn.sigmoid(4.0 * beta + 1.0)  # 0.73 .. 0.99
@@ -70,8 +99,9 @@ def test_chunked_kda_with_keys_alike_and_beta_near_one(alike):
     np.testing.assert_allclose(state, state_want, atol=5e-6)
 
 
-def test_chunked_kda_carries_a_state_from_block_to_block():
-    args = kda_inputs(2, 192)
+@pytest.mark.parametrize("batch, widths", [(2, NARROW), (1, WIDE)], ids=["narrow", "wide"])
+def test_chunked_kda_carries_a_state_from_block_to_block(batch, widths):
+    args = kda_inputs(batch, 192, **widths)
     chunked = jax.jit(kda.kda_chunked)
     whole, end = chunked(*args)
     first, mid = chunked(*(a[:, :128] for a in args))
@@ -80,15 +110,72 @@ def test_chunked_kda_carries_a_state_from_block_to_block():
     np.testing.assert_allclose(end2, end, atol=2e-6)
 
 
-def test_chunked_kda_in_bfloat16():
+@pytest.mark.parametrize("batch, seq, widths", [(2, 256, NARROW), (1, 192, WIDE)], ids=["narrow", "wide"])
+def test_chunked_kda_in_bfloat16(batch, seq, widths):
     """bfloat16 operands (8 mantissa bits: 2^-9 relative an operand), float32
     accumulation, state and decay: outputs of order 0.3 are off the float32
-    recurrence by a few 1e-3 (measured 1.8e-3); 1e-2 is the bound."""
-    args = kda_inputs(2, 256, dtype=jnp.bfloat16)
+    recurrence by a few 1e-3 (measured 1.8e-3; the kernels, whose small float32
+    products are three bfloat16 passes there, the same); 1e-2 is the bound."""
+    args = kda_inputs(batch, seq, dtype=jnp.bfloat16, **widths)
     o, _ = jax.jit(kda.kda_chunked)(*args)
     assert o.dtype == jnp.bfloat16
     o_want, _ = jax.jit(kda.kda_recurrent)(*(a.astype(jnp.float32) for a in args))
     assert 1e-5 < float(jnp.max(jnp.abs(o.astype(jnp.float32) - o_want))) < 1e-2
+    if widths is WIDE:  # and the gradients the kernel writes in bfloat16, against float32's
+        scalar = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)[0].astype(jnp.float32)))  # noqa: E731
+        got = jax.jit(jax.grad(scalar(kda.kda_chunked), argnums=(0, 1, 2, 3, 4)))(*args)
+        want = jax.jit(jax.grad(scalar(kda.kda_recurrent), argnums=(0, 1, 2, 3, 4)))(*(a.astype(jnp.float32) for a in args))
+        for a, b in zip(got, want):
+            assert relative(a.astype(jnp.float32), b) < 3e-2
+
+
+def jax_numpy_form(*args, state=None):
+    """The ``jax.numpy`` path at any width, for whole chunks."""
+    b, _, h, d = args[0].shape
+    state = jnp.zeros((b, h, d, args[2].shape[-1]), jnp.float32) if state is None else state
+    return kda._chunked(*args, state, 64)
+
+
+@pytest.mark.parametrize("seq, decay", [(128, 1.0), (192, 0.1), (64, 3.0)])
+def test_the_kernels_and_the_jax_numpy_form_agree(seq, decay):
+    args = kda_inputs(1, seq, decay=decay, seed=seq, **WIDE)
+    state = 0.1 * jax.random.normal(jax.random.PRNGKey(5), (1, 2, 128, 128))
+    assert enters_the_kernel(*args, initial_state=state)
+    o, end = jax.jit(kda.kda_chunked)(*args, initial_state=state)
+    o_want, end_want = jax.jit(jax_numpy_form)(*args, state=state)
+    np.testing.assert_allclose(o, o_want, atol=2e-6)
+    np.testing.assert_allclose(end, end_want, atol=2e-6)
+
+
+def test_a_mesh_of_several_devices_keeps_the_jax_numpy_form():
+    """The SPMD partitioner refuses a Mosaic call outside a ``shard_map``: a
+    program traced under a mesh of two devices never enters the kernels, one
+    under a mesh of a single device does (the benchmark's one-chip cell)."""
+    from jax.sharding import Mesh
+
+    args = kda_inputs(1, 128, **WIDE)
+    for devices, enters in ((1, True), (2, False)):
+        with jax.set_mesh(Mesh(np.array(jax.devices()[:devices]).reshape(devices, 1), ("fsdp", "tp"))):
+            assert enters_the_kernel(*args) == enters
+
+
+def test_a_strong_decay_takes_the_exact_path_at_the_kernels_width():
+    """30 x the decay: channels lose thousands of nats inside a sub-block, the
+    two-factor form the kernels are written in overflows, and the call — its
+    ``g`` decides, inside the ``custom_vjp``'s rules — runs the ``jax.numpy``
+    form whole: that form's results bit for bit, and its gradients."""
+    args = kda_inputs(1, 128, decay=30.0, **WIDE)  # whole pairs of chunks: no padding between the two forms
+    assert not bool(kda._kernel_serves(args[3])) and bool(kda._kernel_serves(args[3] / 30.0))
+    o, end = jax.jit(kda.kda_chunked)(*args)
+    o_want, end_want = jax.jit(jax_numpy_form)(*args)
+    assert bool(jnp.all(o == o_want)) and bool(jnp.all(end == end_want))
+    o_rec, _ = jax.jit(kda.kda_recurrent)(*args)
+    np.testing.assert_allclose(o, o_rec, atol=2e-6)
+    scalar = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)[0]))  # noqa: E731
+    got = jax.jit(jax.grad(scalar(kda.kda_chunked), argnums=(0, 1, 2, 3, 4)))(*args)
+    want = jax.jit(jax.grad(scalar(jax_numpy_form), argnums=(0, 1, 2, 3, 4)))(*args)
+    for a, b in zip(got, want):  # the same products pulled back in the backward rule: XLA orders the sums its own way
+        assert relative(a, b) < 1e-6
 
 
 def test_the_short_convolution_is_causal_and_per_channel():

@@ -27,9 +27,34 @@ inside a chunk and ``S0`` the state at its start,
   and ``S <- diag(e^{G_C}) S + (k e^{G_C - G})^T u``; the outputs
   ``o = (q e^G) S0 + A_qk u`` are taken for all chunks at once after it.
 
-The backward is autodiff of these same products: chunked too. Operands of
-the large products are in the inputs' dtype (bfloat16 in the model) with
-float32 accumulation; the state, the decay, ``A`` and ``T`` are float32.
+Operands of the large products are in the inputs' dtype (bfloat16 in the
+model) with float32 accumulation; the state, the decay, ``A`` and ``T`` are
+float32.
+
+Which code runs where. :func:`kda_chunked` has two forms of this mathematics
+and picks by what it sees in its input, no field and no switch:
+
+* heads 128 wide in chunks of 64 — the model on a chip — go to the Pallas
+  kernels of ``ops/pallas/kda.py``, forward and a hand-written backward
+  under one ``custom_vjp`` (:func:`_by_decay`): a chunk's products and a
+  head's state stay in VMEM, where this file's ``jax.numpy`` spreads them
+  over thousands of small ops through HBM (PERF.md §6, PR 36). On the CPU
+  the kernels run interpreted, in the tests only in effect: no model there
+  has such heads.
+* the kernels build the pairs inside a sub-block of 16 positions as a product
+  of two factors around its first row (:func:`_two_factors`), which overflows
+  float32 for a channel that decays over 80 nats inside one. A call whose
+  ``g`` has such a channel (:func:`_kernel_serves`, a ``lax.cond`` inside
+  the ``custom_vjp``'s forward AND backward rules, so that no branch's
+  residuals are written for the other) takes the EXACT path whole:
+  :func:`_chunked`, the ``jax.numpy`` form, whose :func:`_decayed_lower`
+  falls back to pairs element by element and whose backward is autodiff of
+  the same products. A trained model can have such channels; a model near
+  its initial decays has none.
+* every other shape (a head width that is no lane tile, another chunk; the
+  CPU rehearsals' heads of 8 and 16) is :func:`_chunked` directly, and so
+  is any call traced under a mesh of more than one device, where a Mosaic
+  call needs a ``shard_map`` around it that the mixer does not have yet.
 """
 
 from __future__ import annotations
@@ -204,28 +229,15 @@ def _unit_lower_inverse(L: jnp.ndarray) -> jnp.ndarray:
     return inv[..., 0, :, :]
 
 
-def kda_chunked(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    g: jnp.ndarray,
-    beta: jnp.ndarray,
-    chunk: int = 64,
-    initial_state: Optional[jnp.ndarray] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """q, k [B, S, H, dk] (as they enter the rule: normalised, q scaled);
-    v [B, S, H, dv]; g [B, S, H, dk] float32 log-decay (<= 0); beta
-    [B, S, H] float32. Returns (o [B, S, H, dv] in v's dtype, the final
-    state [B, H, dk, dv] float32). Any S: the tail is padded with positions
-    that neither decay nor write (g = 0, beta = 0)."""
+def _chunked(q, k, v, g, beta, S0, chunk):
+    """The chunked rule in ``jax.numpy``, exact whatever the decay: q, k, v, g
+    [B, S, H, d] with S whole chunks, beta [B, S, H], S0 [B, H, dk, dv]
+    float32. What :func:`kda_chunked` runs for widths the kernels do not take
+    and, at any width, for a call with a channel that decays too fast for them."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     dt = v.dtype
-    pad = -s % chunk
-    if pad:
-        q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    n = (s + pad) // chunk
+    n = s // chunk
 
     def chunks(x):  # [B, S, H, d] -> [B, H, N, C, d]
         return jnp.moveaxis(x.reshape(b, n, chunk, h, x.shape[-1]), 3, 1)
@@ -255,14 +267,137 @@ def kda_chunked(
         )
         return S_next, (S, u)
 
-    S0 = jnp.zeros((b, h, dk, dv), f32) if initial_state is None else initial_state.astype(f32)
     xs = tuple(jnp.moveaxis(x, 2, 0) for x in (w_k, w_v, k_out, decay))
     S_end, (starts, us) = jax.lax.scan(step, S0, xs)
     starts, us = jnp.moveaxis(starts, 0, 2), jnp.moveaxis(us, 0, 2)  # [B, H, N, ...]
     o = jnp.einsum("...ck,...kd->...cd", q_in, starts.astype(dt), preferred_element_type=f32)
     o = o + jnp.einsum("...ij,...jd->...id", a_qk.astype(dt), us.astype(dt), preferred_element_type=f32)
-    o = jnp.moveaxis(o, 1, 3).reshape(b, s + pad, h, dv)[:, :s]
+    o = jnp.moveaxis(o, 1, 3).reshape(b, s, h, dv)
     return o.astype(dt), S_end
+
+
+def _kernel_serves(g):
+    """What :func:`_decays_mildly` asks of every sub-block of a call, from
+    ``g`` [B, S, H, d] itself: the kernels build the pairs inside a sub-block
+    as :func:`_two_factors` does and have no other form."""
+    b, s, h, d = g.shape
+    subs = g.astype(jnp.float32).reshape(b, s // _SUB, _SUB, h, d)
+    # from a sub-block's first row to its last: all of it but the first row
+    return jnp.max(subs[:, :, 0] - jnp.sum(subs, axis=2)) < _TWO_FACTOR_NATS
+
+
+def _wide(x):  # [B, S, H, d] -> the [B, S, H·d] view the kernels read heads from
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+def _kernels():
+    # at first use: importing pallas takes most of a second, and a model
+    # without a kda layer never needs it
+    from torchft_tpu.ops.pallas import kda
+
+    return kda
+
+
+def _kernel_forward(q, k, v, g, beta, S0):
+    with jax.named_scope("kda_kernel"):
+        o, starts, S_end = _kernels().kda_forward(
+            _wide(q), _wide(k), _wide(v), _wide(g.astype(jnp.float32)), beta.astype(jnp.float32), S0
+        )
+    return o.reshape(v.shape), S_end, starts
+
+
+def _kernel_backward(q, k, v, g, beta, S0, starts, do, d_end):
+    with jax.named_scope("kda_kernel"):
+        dq, dk, dv, dg, dbeta, dS0 = _kernels().kda_backward(
+            _wide(q), _wide(k), _wide(v), _wide(g.astype(jnp.float32)), beta.astype(jnp.float32),
+            starts, _wide(do), d_end,
+        )
+    return (
+        dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+        dg.reshape(g.shape).astype(g.dtype), dbeta.astype(beta.dtype), dS0,
+    )
+
+
+# A ``jax.jit`` of its own, as the kernels' two calls are: a model's layers, and
+# a layer's forward, recomputed forward and backward, share one trace of this
+# form, one differentiation and one lowering — seconds of a program's set-up
+# each — though a model near its initial decays never runs it.
+@jax.jit
+def _exact(q, k, v, g, beta, S0):
+    return _chunked(q, k, v, g, beta, S0, _kernels().CHUNK)
+
+
+# The path is chosen by the call's ``g``, INSIDE the forward and the backward
+# rule of one ``custom_vjp`` (as :func:`_inside`): ``lax.cond``'s own backward
+# would carry both branches' residuals out of the forward, zeros for the one
+# not taken. Branches are fresh closures at every call (see :func:`_inside`).
+@jax.custom_vjp
+def _by_decay(q, k, v, g, beta, S0):
+    return jax.lax.cond(
+        _kernel_serves(g), lambda *a: _kernel_forward(*a)[:2], lambda *a: _exact(*a),
+        q, k, v, g, beta, S0,
+    )
+
+
+def _by_decay_fwd(q, k, v, g, beta, S0):
+    # the states at the chunks' starts are the kernel path's one residual
+    # beside the inputs; the exact path differentiates itself and leaves zeros
+    b, s, h, d = q.shape
+    serves = _kernel_serves(g)
+    o, S_end, starts = jax.lax.cond(
+        serves,
+        lambda *a: _kernel_forward(*a),
+        lambda *a: _exact(*a) + (jnp.zeros((b, h, s // _kernels().CHUNK, d, d), jnp.float32),),
+        q, k, v, g, beta, S0,
+    )
+    return (o, S_end), (serves, q, k, v, g, beta, S0, starts)
+
+
+def _by_decay_bwd(res, cts):
+    def exact(q, k, v, g, beta, S0, starts, do, d_end):
+        return jax.vjp(lambda *a: _exact(*a), q, k, v, g, beta, S0)[1]((do, d_end))
+
+    return jax.lax.cond(res[0], lambda *a: _kernel_backward(*a), exact, *res[1:], *cts)
+
+
+_by_decay.defvjp(_by_decay_fwd, _by_decay_bwd)
+
+
+def kda_chunked(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    g: jnp.ndarray,
+    beta: jnp.ndarray,
+    chunk: int = 64,
+    initial_state: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """q, k [B, S, H, dk] (as they enter the rule: normalised, q scaled);
+    v [B, S, H, dv]; g [B, S, H, dk] float32 log-decay (<= 0); beta
+    [B, S, H] float32. Returns (o [B, S, H, dv] in v's dtype, the final
+    state [B, H, dk, dv] float32). Any S: the tail is padded with positions
+    that neither decay nor write (g = 0, beta = 0)."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    # heads of whole lane tiles on one device are the kernels', which take two
+    # chunks at a time. Under a mesh of several devices the SPMD partitioner
+    # refuses a Mosaic call outside a ``shard_map`` (``transformer._flash_sharded``):
+    # such a program keeps the ``jax.numpy`` form until its mixer brings one
+    kernels = (
+        dk % 128 == 0  # before pallas is imported at all
+        and _kernels().serves(dk, dv, chunk) and q.dtype == k.dtype == v.dtype
+        and jax.sharding.get_abstract_mesh().size <= 1
+    )
+    pad = -s % (_kernels().ROWS if kernels else chunk)
+    if pad:
+        q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    S0 = jnp.zeros((b, h, dk, dv), jnp.float32) if initial_state is None else initial_state.astype(jnp.float32)
+    if kernels:
+        o, S_end = _by_decay(q, k, v, g, beta, S0)
+    else:
+        o, S_end = _chunked(q, k, v, g, beta, S0, chunk)
+    return o[:, :s], S_end
 
 
 def kda_recurrent(q, k, v, g, beta, initial_state=None):

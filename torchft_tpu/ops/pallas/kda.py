@@ -1,0 +1,506 @@
+"""The chunked gated delta rule (KDA, ``ops/kda.py``) as pallas TPU kernels,
+fwd + bwd: a head's state and a chunk's products never leave VMEM.
+
+``ops/kda.kda_chunked`` in ``jax.numpy`` writes every intermediate of a chunk
+— the running decay sums, three ``exp`` factors, the decayed products, the
+triangular inverse's ten small products, the scan's carries — to HBM, forward
+and again for autodiff: thousands of ops of a few milliseconds (PERF.md §6,
+PR 36). Here a grid step holds TWO chunks of 64 positions of ``hb`` heads and
+does all of it on the chip. Two kernels:
+
+* the forward: grid ``(batch, head blocks, pairs of chunks)``, the last axis
+  sequential, the heads' states in float32 scratch across it. It writes the
+  outputs, the state at every chunk's START (what the backward recomputes a
+  chunk from) and the final state.
+* the backward: the same grid walked from the last pair to the first,
+  carrying ``dS``. A step recomputes its chunks' ``G``, ``A``, ``M^-1``,
+  ``u`` from the inputs and the saved start states, then the gradients of
+  q, k, v, g, beta, and at the first pair the initial state's.
+
+Two chunks a step because everything of a chunk that does not read the state
+— the running sums, the decayed products, the inverse, ``M^-1 [beta v | beta
+k e^G]`` — is a product of 64 x 64 matrices, half an MXU tile a side: the two
+chunks' matrices are the diagonal blocks of one [128, 128] matrix and every
+such product serves both (the inverse's merges stop at blocks of 64). Only
+``u``, the outputs' read of the state and the state's update go chunk by
+chunk, three products each.
+
+The mathematics is ``ops/kda.py``'s, product for product (that docstring's
+notation): the decayed products around a sub-block's first row
+(``_two_factors`` for pairs inside a sub-block too: the caller sends here only
+calls whose decay that form holds), ``M^-1 = (I + diag(beta) A_kk)^-1`` by
+block forward substitution in matrix products (8-row diagonal blocks in
+product form, merged pairwise up to 64), ``u = M^-1 (beta v) - M^-1 (beta k
+e^G) S``, ``o = (q e^G) S + A_qk u``, ``S <- e^{G_end} S + (k e^{G_end - G})^T
+u``. The backward differentiates these by hand; through the inverse as ``dM =
+-(M^-T dw) (M^-1 r)^T``, and never through the sub-blocks' reference rows,
+whose two factors cancel.
+
+Layout: q, k, v, g, o are read and written in place from the ``[B, S, H·128]``
+views (``flash_attention.py``'s rule), a block ``(128, 128·hb)`` a step; beta
+and its gradient go as ``[B, H/hb, S, hb]`` (a step's heads the lanes of a
+small block). The state is ``[dk, dv]`` outside and ``[dv, dk]`` in here,
+turned at a sequence's two ends, so that a chunk's decay — a lane row like
+``G`` — broadcasts over it.
+
+Precision is the inputs': bfloat16 q, k, v give bfloat16 operands with
+float32 accumulation to the 128-wide products and keep ``G``, ``A``,
+``M^-1`` and the state float32, their small float32 products taken as three
+products of operands split into a bfloat16 head and tail (Mosaic's ``dot``
+has ``DEFAULT`` and ``HIGHEST`` only); float32 inputs — the CPU tests, which
+hold the kernel to the recurrence at 2e-6 — run every product at ``HIGHEST``.
+Several heads a step, written stage by stage — every head's loads, then a
+step of every head's inverse before the next step of any, every store last:
+one head's chain of nine small dependent products is latency, and the other
+heads' fill it (one head a step took 2.57 ms a block of 1024 positions
+forward where four take 1.64 and eight 1.57, host dispatch included; PERF.md
+§6, PR 36).
+
+On the chip: ``chip_smoke.py`` ``kda_cells`` holds the kernels, forward and
+gradients, to the recurrence and fails without their Mosaic call in the
+lowered text; ``tests/test_flash_attention.py`` compiles them for a described
+v5e at the cell's shapes; ``tests/test_kda.py`` runs them interpreted.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["kda_forward", "kda_backward", "serves", "CHUNK", "ROWS"]
+
+CHUNK = 64  # positions of a chunk: the only one the kernels are written for
+ROWS = 2 * CHUNK  # positions a grid step takes: two chunks, the diagonal blocks of its [128, 128] matrices
+_SUB = 16  # ops/kda._SUB
+_BASE = 8  # ops/kda._BASE
+_LANES = 128
+_HEADS = (4, 2, 1)  # heads a grid step, the most that divides H
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NT = ((1,), (1,))  # a · bᵀ
+_NN = ((1,), (0,))  # a · b
+_TN = ((0,), (0,))  # aᵀ · b
+_F32, _BF16 = jnp.float32, jnp.bfloat16
+
+
+def serves(dk: int, dv: int, chunk: int) -> bool:
+    """Whether the kernels take these widths: heads read in place as lane
+    tiles, one chunk size."""
+    return dk == _LANES and dv == _LANES and chunk == CHUNK
+
+
+def _should_interpret() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def _dot(a, b, dims, exact):
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), precision=_HIGHEST if exact else None, preferred_element_type=_F32,
+    )
+
+
+def _split(x, exact):
+    """A float32 operand as a small product takes it: itself for ``HIGHEST``,
+    else its bfloat16 head and tail (16 of its 24 bits)."""
+    if exact:
+        return (x,)
+    head = x.astype(_BF16)
+    return head, (x - head.astype(_F32)).astype(_BF16)
+
+
+def _dot_f32(a, b, dims, exact):
+    """A product of two :func:`_split` operands to about 2^-16 of them: at
+    ``HIGHEST``, or as three bfloat16 passes."""
+    if exact:
+        return _dot(a[0], b[0], dims, True)
+    return _dot(a[0], b[0], dims, False) + (_dot(a[0], b[1], dims, False) + _dot(a[1], b[0], dims, False))
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _square():
+    """Row and column of a [ROWS, ROWS] matrix, and whether the two are of one chunk."""
+    i, j = _iota((ROWS, ROWS), 0), _iota((ROWS, ROWS), 1)
+    return i, j, i // CHUNK == j // CHUNK
+
+
+def _running_sum(g, exact, transposed=False):
+    """``G = tril · g`` over each chunk's rows (``trilᵀ · dG`` for the
+    gradient), exactly — the exponents of everything after: ones are exact in
+    bfloat16, and three bfloat16 pieces are all of a float32."""
+    i, j, same = _square()
+    tril = same & (j <= i)
+    dims = _TN if transposed else _NN
+    if exact:
+        return _dot(tril.astype(_F32), g, dims, True)
+    ones = tril.astype(_BF16)
+    head, tail = _split(g, False)
+    rest = (g - head.astype(_F32) - tail.astype(_F32)).astype(_BF16)
+    return _dot(ones, head, dims, False) + (_dot(ones, tail, dims, False) + _dot(ones, rest, dims, False))
+
+
+def _column(block, j):
+    """Lane j of a small [ROWS, hb] block as a [ROWS, 1] column."""
+    return jnp.sum(jnp.where(_iota(block.shape, 1) == j, block, 0.0), axis=1, keepdims=True)
+
+
+def _by_chunk(first, second):
+    """A [1, 128] row of each chunk, over the rows of its chunk."""
+    return jnp.where(_iota((ROWS, _LANES), 0) < CHUNK, first, second)
+
+
+def _sub_rows(b):
+    """Where sub-block b of each chunk lies in a grid step's rows."""
+    return slice(b * _SUB, (b + 1) * _SUB), slice(CHUNK + b * _SUB, CHUNK + (b + 1) * _SUB)
+
+
+def _sub_blocks(x, b):
+    """Sub-block b of both chunks, [sub, 128] each."""
+    first, second = _sub_rows(b)
+    return x[first, :], x[second, :]
+
+
+def _part(product, c, part):
+    """Of a sub-block's product [4·sub, …] the rows of chunk c: q's (part 0) or k's (1)."""
+    return product[(2 * c + part) * _SUB : (2 * c + part + 1) * _SUB]
+
+
+def _pairs(q, k, G, exact):
+    """``A_qk`` (j <= i) and ``A_kk`` (j < i) of two chunks, the diagonal
+    blocks of [ROWS, ROWS] float32 matrices, and the factors they are products
+    of, which the backward needs again. Sub-block b of BOTH chunks is one
+    product: rows ``[q; k] e^{G - ref}`` of the first chunk's, then of the
+    second's, against the keys ``k e^{ref - G}`` of both — ref the running sum
+    at each sub-block's first row — whose other chunk's columns are dropped."""
+    row = _iota((ROWS, _LANES), 0) % CHUNK
+    left_exp, right_exp, lefts, rights, products = [], [], [], [], []
+    for b in range(CHUNK // _SUB):
+        g0, g1 = _sub_blocks(G, b)
+        e0, e1 = jnp.exp(g0 - g0[:1, :]), jnp.exp(g1 - g1[:1, :])  # from the sub-block's first row on: <= 1
+        # keys up to the end of sub-block b, seen from its first row: <= 1 for
+        # the earlier sub-blocks, under e^80 inside it (the caller's condition)
+        e_r = jnp.where(row < (b + 1) * _SUB, jnp.exp(jnp.minimum(_by_chunk(g0[:1, :], g1[:1, :]) - G, 85.0)), 0.0)
+        (q0, q1), (k0, k1) = _sub_blocks(q, b), _sub_blocks(k, b)
+        left = jnp.concatenate([q0 * e0, k0 * e0, q1 * e1, k1 * e1], axis=0)  # [4·sub, 128]
+        right = k * e_r
+        left_exp.append((e0, e1)), right_exp.append(e_r)
+        lefts.append(_split(left, exact)), rights.append(_split(right, exact))
+        products.append(_dot_f32(lefts[-1], rights[-1], _NT, exact))  # [4·sub, ROWS]
+    i, j, same = _square()
+    rows = lambda part: jnp.concatenate([_part(p, c, part) for c in (0, 1) for p in products], axis=0)  # noqa: E731
+    a_qk = jnp.where(same & (j <= i), rows(0), 0.0)
+    a_kk = jnp.where(same & (j < i), rows(1), 0.0)
+    return a_qk, a_kk, dict(left_exp=left_exp, right_exp=right_exp, lefts=lefts, rights=rights)
+
+
+def _unit_lower_inverses(Ls, exact):
+    """``(I + L)^-1`` of each L [ROWS, ROWS] (strictly lower, two diagonal
+    blocks of CHUNK): ``ops/kda._unit_lower_inverse`` on whole matrices — a
+    matrix of diagonal blocks times one of diagonal blocks is the blocks'
+    products — up to blocks of CHUNK. One step of every matrix before the
+    next step of any: each is a chain of ten dependent small products, and
+    the chains of a grid step's heads fill each other's waits."""
+    i, j, _ = _square()
+    eye = (i == j).astype(_F32)
+    base = i // _BASE == j // _BASE
+    powers = [_split(jnp.where(base, L, 0.0), exact) for L in Ls]
+    invs = [eye - jnp.where(base, L, 0.0) for L in Ls]
+    reach = 2
+    while reach < _BASE:
+        squares = [_dot_f32(p, p, _NN, exact) for p in powers]
+        invs = [_dot_f32(_split(inv, exact), _split(eye + sq, exact), _NN, exact) for inv, sq in zip(invs, squares)]
+        reach *= 2
+        if reach < _BASE:
+            powers = [_split(sq, exact) for sq in squares]
+    s = _BASE
+    while s < CHUNK:
+        corner = (i // (2 * s) == j // (2 * s)) & (i // s != j // s)
+        halves = [_split(inv, exact) for inv in invs]
+        across = [_dot_f32(h, _split(jnp.where(corner, L, 0.0), exact), _NN, exact) for h, L in zip(halves, Ls)]
+        invs = [inv - _dot_f32(_split(a, exact), h, _NN, exact) for inv, a, h in zip(invs, across, halves)]
+        s *= 2
+    return invs
+
+
+def _chunks(qs, ks, vs, gs, betas, dt, exact):
+    """What two chunks' forward and backward share, from their inputs alone
+    (no state), for every head of a grid step, stage by stage: q, k, v
+    [ROWS, 128] float32, g [ROWS, 128], beta [ROWS, 1] a head."""
+    Gs = [_running_sum(g, exact) for g in gs]
+    pairs = [_pairs(q, k, G, exact) for q, k, G in zip(qs, ks, Gs)]
+    invs = _unit_lower_inverses([beta * a_kk for beta, (_, a_kk, _) in zip(betas, pairs)], exact)
+    out = []
+    for q, k, v, G, beta, (a_qk, a_kk, factors), inv in zip(qs, ks, vs, Gs, betas, pairs, invs):
+        ends = G[CHUNK - 1 : CHUNK, :], G[ROWS - 1 : ROWS, :]
+        e_in, e_out = jnp.exp(G), jnp.exp(_by_chunk(*ends) - G)
+        k_in, inv_d = k * e_in, inv.astype(dt)
+        out.append(dict(
+            factors, a_qk=a_qk, a_kk=a_kk, inv=inv_d, e_in=e_in, e_out=e_out,
+            decay=[jnp.exp(end) for end in ends], q_in=q * e_in, k_in=k_in, k_out=k * e_out,
+            # [w_v | w_k] = M^-1 [beta v | beta k e^G]
+            w_v=_dot(inv_d, (beta * v).astype(dt), _NN, exact),
+            w_k=_dot(inv_d, (beta * k_in).astype(dt), _NN, exact),
+        ))
+    return out
+
+
+_HALVES = (slice(0, CHUNK), slice(CHUNK, ROWS))  # a grid step's two chunks
+
+
+def _heads(ref, hb, dtype=_F32):
+    """The heads' lane tiles of a [ROWS, 128·hb] block."""
+    return [ref[:, j * _LANES : (j + 1) * _LANES].astype(dtype) for j in range(hb)]
+
+
+# ---------------------------------------------------------------------------
+# forward: grid (b, head block, pair of chunks) — in turn, the states in scratch
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_ref, end_ref, state, *, hb, exact):
+    n = pl.program_id(2)
+    dt = v_ref.dtype
+
+    @pl.when(n == 0)
+    def _init():
+        for j in range(hb):
+            state[j] = s0_ref[j].T
+
+    # every load first and every store last, the heads side by side in between
+    betas = [_column(beta_ref[...], j) for j in range(hb)]
+    vs = _heads(v_ref, hb)
+    xs = _chunks(_heads(q_ref, hb), _heads(k_ref, hb), vs, _heads(g_ref, hb), betas, dt, exact)
+    states = [state[j] for j in range(hb)]
+    starts, us, reads = [], [], []
+    for at, half in enumerate(_HALVES):
+        starts.append(states)
+        sd = [st.astype(dt) for st in states]
+        us.append([x["w_v"][half] - _dot(x["w_k"][half].astype(dt), s, _NT, exact) for x, s in zip(xs, sd)])
+        reads.append([_dot(x["q_in"][half].astype(dt), s, _NT, exact) for x, s in zip(xs, sd)])
+        states = [
+            st * x["decay"][at] + _dot(u.astype(dt), x["k_out"][half].astype(dt), _TN, exact)
+            for st, x, u in zip(states, xs, us[-1])
+        ]
+    for j, x in enumerate(xs):
+        u = jnp.concatenate([us[0][j], us[1][j]], axis=0).astype(dt)
+        o = jnp.concatenate([reads[0][j], reads[1][j]], axis=0) + _dot(x["a_qk"].astype(dt), u, _NN, exact)
+        o_ref[:, j * _LANES : (j + 1) * _LANES] = o.astype(o_ref.dtype)
+        starts_ref[j, 0], starts_ref[j, 1] = starts[0][j], starts[1][j]
+        state[j] = states[j]
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _finish():
+        for j in range(hb):
+            end_ref[j] = state[j].T
+
+
+# ---------------------------------------------------------------------------
+# backward: the same grid from the last pair to the first, dS in scratch
+# ---------------------------------------------------------------------------
+
+
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, dend_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds0_ref, dstate, *, hb, exact,
+):
+    n = pl.program_id(2)
+    dt = v_ref.dtype
+
+    @pl.when(n == 0)
+    def _init():
+        for j in range(hb):
+            dstate[j] = dend_ref[j].T
+
+    heads = range(hb)
+    beta_block = beta_ref[...]
+    betas = [_column(beta_block, j) for j in heads]
+    qs, ks, vs = _heads(q_ref, hb), _heads(k_ref, hb), _heads(v_ref, hb)
+    dos = _heads(do_ref, hb, dt)
+    xs = _chunks(qs, ks, vs, _heads(g_ref, hb), betas, dt, exact)
+    sts = [[starts_ref[j, at].astype(dt) for at in (0, 1)] for j in heads]  # [dv, dk], as the products take them
+    i, jj, same = _square()
+
+    # u of both chunks from their saved start states; A_qkᵀ do of both at once
+    us = [
+        jnp.concatenate([x["w_v"][half] - _dot(x["w_k"][half].astype(dt), st[at], _NT, exact)
+                         for at, half in enumerate(_HALVES)], axis=0)
+        for x, st in zip(xs, sts)
+    ]
+    from_o = [_dot(x["a_qk"].astype(dt), do, _TN, exact) for x, do in zip(xs, dos)]
+    # the second chunk, then the first: o = q_in S + A_qk u;  S' = decay S + k_outᵀ u;  u = w_v - w_k S
+    ds = [dstate[j] for j in heads]
+    dus, dk_outs, dg_ends = [[None, None] for _ in heads], [[None, None] for _ in heads], [[None, None] for _ in heads]
+    for at in (1, 0):
+        half = _HALVES[at]
+        for j, x in enumerate(xs):
+            dsd = ds[j].astype(dt)
+            du = from_o[j][half] + _dot(x["k_out"][half].astype(dt), dsd, _NT, exact)
+            dus[j][at] = du
+            dk_outs[j][at] = _dot(us[j][half].astype(dt), dsd, _NN, exact)
+            dg_ends[j][at] = jnp.sum(ds[j] * starts_ref[j, at], axis=0, keepdims=True) * x["decay"][at]
+            ds[j] = (
+                _dot(dos[j][half], x["q_in"][half].astype(dt), _TN, exact) + ds[j] * x["decay"][at]
+                - _dot(du.astype(dt), x["w_k"][half].astype(dt), _TN, exact)
+            )
+    dbetas = jnp.zeros(beta_block.shape, _F32)
+    for j, x in enumerate(xs):
+        q, k, v, do, beta = qs[j], ks[j], vs[j], dos[j], betas[j]
+        k_out, k_in, q_in = x["k_out"], x["k_in"], x["q_in"]
+        du = jnp.concatenate(dus[j], axis=0).astype(dt)
+        dk_out = jnp.concatenate(dk_outs[j], axis=0)
+        da_qk = jnp.where(same & (jj <= i), _dot(do, us[j].astype(dt), _NT, exact), 0.0)
+        dq_in = jnp.concatenate([_dot(do[half], sts[j][at], _NN, exact) for at, half in enumerate(_HALVES)], axis=0)
+        dw_k = -jnp.concatenate([_dot(du[half], sts[j][at], _NN, exact) for at, half in enumerate(_HALVES)], axis=0)
+        dr_v = _dot(x["inv"], du, _TN, exact)
+        dr_k = _dot(x["inv"], dw_k.astype(dt), _TN, exact)
+        # M = I + diag(beta) A_kk:  dM = -M^-T dM^-1 M^-T = -(dr_v w_vᵀ + dr_k w_kᵀ)
+        dm = -(
+            _dot(dr_v.astype(dt), x["w_v"].astype(dt), _NT, exact)
+            + _dot(dr_k.astype(dt), x["w_k"].astype(dt), _NT, exact)
+        )
+        da_kk = jnp.where(same & (jj < i), beta * dm, 0.0)
+        dbeta = (
+            jnp.sum(dm * x["a_kk"], axis=1, keepdims=True)
+            + jnp.sum(dr_v * v, axis=1, keepdims=True)
+            + jnp.sum(dr_k * k_in, axis=1, keepdims=True)
+        )
+        dk_in = beta * dr_k
+
+        # the decayed products, a sub-block of both chunks at a time: rows
+        # [q; k] e^{G - ref} against keys k e^{ref - G}; ref is a constant (its
+        # two factors cancel)
+        dq_rows, dk_rows, dG_rows = [[], []], [[], []], [[], []]
+        dk_keys = jnp.zeros((ROWS, _LANES), _F32)
+        dG_keys = jnp.zeros((ROWS, _LANES), _F32)
+        for b in range(CHUNK // _SUB):
+            dp = _split(jnp.concatenate([m[at] for at in _sub_rows(b) for m in (da_qk, da_kk)], axis=0), exact)
+            d_left = _dot_f32(dp, x["rights"][b], _NN, exact)  # [4·sub, 128]
+            d_right = _dot_f32(dp, x["lefts"][b], _TN, exact)  # [ROWS, 128]
+            for c, at in enumerate(_sub_rows(b)):
+                e = x["left_exp"][b][c]
+                dq_b, dk_b = _part(d_left, c, 0), _part(d_left, c, 1)
+                dq_rows[c].append(dq_b * e), dk_rows[c].append(dk_b * e)
+                dG_rows[c].append((dq_b * q[at] + dk_b * k[at]) * e)
+            right = x["right_exp"][b]
+            dk_keys = dk_keys + d_right * right
+            dG_keys = dG_keys + d_right * (k * right)
+        whole = lambda rows: jnp.concatenate(rows[0] + rows[1], axis=0)  # noqa: E731
+        dq = whole(dq_rows) + dq_in * x["e_in"]
+        dk = whole(dk_rows) + dk_keys + dk_in * x["e_in"] + dk_out * x["e_out"]
+        dG = whole(dG_rows) - dG_keys + dk_in * k_in + dq_in * q_in - dk_out * k_out
+        row = _iota((ROWS, _LANES), 0)
+        for at, half in enumerate(_HALVES):  # the chunk's last row also sets its decay
+            dg_end = dg_ends[j][at] + jnp.sum((dk_out * k_out)[half], axis=0, keepdims=True)
+            dG = dG + jnp.where(row == half.stop - 1, dg_end, 0.0)
+        lanes = slice(j * _LANES, (j + 1) * _LANES)
+        dq_ref[:, lanes] = dq.astype(dq_ref.dtype)
+        dk_ref[:, lanes] = dk.astype(dk_ref.dtype)
+        dv_ref[:, lanes] = (beta * dr_v).astype(dv_ref.dtype)
+        dg_ref[:, lanes] = _running_sum(dG, exact, transposed=True)
+        dbetas = dbetas + jnp.where(_iota(beta_block.shape, 1) == j, dbeta, 0.0)
+        dstate[j] = ds[j]
+    dbeta_ref[...] = dbetas
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _finish():
+        for j in heads:
+            ds0_ref[j] = dstate[j].T
+
+
+# ---------------------------------------------------------------------------
+# block specs and the two calls
+# ---------------------------------------------------------------------------
+
+
+def _heads_a_step(h: int) -> int:
+    return next(m for m in _HEADS if h % m == 0)
+
+
+def _specs(hb, at):
+    """Block specs by role; ``at(n)`` gives the pair of chunks a grid step works on."""
+    vm = pltpu.VMEM
+    wide = pl.BlockSpec((None, ROWS, _LANES * hb), lambda b, h, n: (b, at(n), h), memory_space=vm)
+    beta = pl.BlockSpec((None, None, ROWS, hb), lambda b, h, n: (b, h, at(n), 0), memory_space=vm)
+    state = pl.BlockSpec((None, hb, _LANES, _LANES), lambda b, h, n: (b, h, 0, 0), memory_space=vm)
+    starts = pl.BlockSpec(
+        (None, hb, 2, _LANES, _LANES), lambda b, h, n: (b, h, at(n), 0, 0), memory_space=vm
+    )
+    return wide, beta, state, starts
+
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=64 << 20,
+)
+
+
+def _beta_blocks(beta, hb):  # [B, S, H] -> [B, H/hb, S, hb]
+    b, s, h = beta.shape
+    return jnp.moveaxis(beta.reshape(b, s, h // hb, hb), 2, 1)
+
+
+# Both calls are ``jax.jit``s of their own: a model's layers, and a layer's
+# forward, recomputed forward and backward, then share ONE trace of a kernel's
+# body and one lowering of it to Mosaic — seconds each, of a program's set-up —
+# where a plain function is traced and lowered at every call site.
+@functools.partial(jax.jit, static_argnames="interpret")
+def kda_forward(q, k, v, g, beta, initial_state, interpret=None):
+    """q, k, v [B, S, H·128] in one dtype, g [B, S, H·128] float32, beta
+    [B, S, H] float32, the state [B, H, 128, 128] float32; S whole pairs of
+    chunks (:data:`ROWS`). Returns (o [B, S, H·128], the state at each chunk's
+    start [B, H, S/64, 128, 128] as the kernels hold it ([dv, dk]), the final
+    state)."""
+    b, s, _ = q.shape
+    h = beta.shape[-1]
+    hb = _heads_a_step(h)
+    wide, beta_spec, state, starts = _specs(hb, lambda n: n)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, hb=hb, exact=q.dtype == jnp.float32),
+        grid=(b, h // hb, s // ROWS),
+        in_specs=[wide, wide, wide, wide, beta_spec, state],
+        out_specs=[wide, starts, state],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, h, s // CHUNK, _LANES, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, _LANES, _LANES), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((hb, _LANES, _LANES), jnp.float32)],
+        compiler_params=_PARAMS,
+        interpret=_should_interpret() if interpret is None else interpret,
+        name="kda_fwd",
+    )(q, k, v, g, _beta_blocks(beta, hb), initial_state)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def kda_backward(q, k, v, g, beta, starts, do, d_end, interpret=None):
+    """The gradients of q, k, v, g, beta and the initial state, from the
+    forward's inputs, its ``starts``, and the cotangents of o and of the final
+    state."""
+    b, s, _ = q.shape
+    h = beta.shape[-1]
+    hb, n = _heads_a_step(h), s // ROWS
+    wide, beta_spec, state, starts_spec = _specs(hb, lambda i: n - 1 - i)
+    dq, dk, dv, dg, dbeta, ds0 = pl.pallas_call(
+        functools.partial(_bwd_kernel, hb=hb, exact=q.dtype == jnp.float32),
+        grid=(b, h // hb, n),
+        in_specs=[wide, wide, wide, wide, beta_spec, starts_spec, wide, state],
+        out_specs=[wide, wide, wide, wide, beta_spec, state],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(g.shape, jnp.float32),
+            jax.ShapeDtypeStruct((b, h // hb, s, hb), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, _LANES, _LANES), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((hb, _LANES, _LANES), jnp.float32)],
+        compiler_params=_PARAMS,
+        interpret=_should_interpret() if interpret is None else interpret,
+        name="kda_bwd",
+    )(q, k, v, g, _beta_blocks(beta, hb), starts, do, d_end)
+    return dq, dk, dv, dg, jnp.moveaxis(dbeta, 1, 2).reshape(b, s, h), ds0
