@@ -621,9 +621,18 @@ void DataPlane::enable_cma(const std::vector<int64_t>& pids) {
 bool DataPlane::cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf,
                         size_t sn, uint8_t* rbuf, size_t rn, uint32_t tag,
                         int64_t deadline_ms, bool* send_failed,
-                        bool* timed_out, std::string* err) {
+                        bool* timed_out, std::string* err, DpAccount* acct) {
   const int left = (rank_ - 1 + world_) % world_;
   *send_failed = false;
+  // the account's laps: each returns the nanoseconds since the last one
+  // (a failed hop books the piece it was in before it returns)
+  int64_t lap_t = 0;
+  auto lap = [&lap_t]() {
+    int64_t now = lathist::now_ns();
+    int64_t d = now - lap_t;
+    lap_t = now;
+    return d;
+  };
   // env-gated injection points: die with a published pull descriptor
   // outstanding (the torn-read window the ROADMAP divergence hypothesis
   // names), or tear this hop's own pull partway.
@@ -642,11 +651,12 @@ bool DataPlane::cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf,
     // dying here is exactly "peer death mid-op with a dangling pull"
     fi::kill_self("cma.desc", fi_h);
   }
+  lap();  // own descriptor sent
   CmaDesc theirs{};
-  if (!recv_small(recv_fd, &theirs, sizeof(theirs), deadline_ms, timed_out,
-                  err)) {
-    return false;
-  }
+  bool got_desc = recv_small(recv_fd, &theirs, sizeof(theirs), deadline_ms,
+                             timed_out, err);
+  acct->desc_wait_ns += lap();
+  if (!got_desc) return false;
   if (theirs.tag != tag || theirs.len != rn) {
     *err = "cma desc mismatch: tag " + std::to_string(theirs.tag) + "/" +
            std::to_string(tag) + " len " + std::to_string(theirs.len) + "/" +
@@ -668,10 +678,13 @@ bool DataPlane::cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf,
     if (k <= 0) {
       *err = std::string("process_vm_readv: ") +
              (k == 0 ? "zero read" : errno_str(errno));
-      return false;
+      break;
     }
     off += (size_t)k;
   }
+  acct->pull_ns += lap();
+  acct->pull_bytes += (int64_t)off;
+  if (off < goal) return false;
   if (goal < rn) {
     *err = "fault injection: torn CMA pull (" + std::to_string(goal) + "/" +
            std::to_string(rn) + " bytes)";
@@ -681,8 +694,12 @@ bool DataPlane::cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf,
   if (!send_small(recv_fd, &ack, sizeof(ack), deadline_ms, timed_out, err)) {
     return false;
   }
+  lap();  // own ack sent
   uint32_t rack = 0;
-  if (!recv_small(send_fd, &rack, sizeof(rack), deadline_ms, timed_out, err)) {
+  bool got_ack =
+      recv_small(send_fd, &rack, sizeof(rack), deadline_ms, timed_out, err);
+  acct->ack_wait_ns += lap();
+  if (!got_ack) {
     *send_failed = true;
     return false;
   }
@@ -733,21 +750,25 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
   st.scratch_send.resize(max_wire);
   st.scratch_recv.resize(max_wire);
   if (codec != DpCodec::kF32) st.scratch_fwd.resize(max_wire);
+  DpAccount& acct = st.acct;  // see DpAccount (dataplane.h)
 
   auto prep_send = [&](int idx) -> std::pair<const uint8_t*, size_t> {
     size_t cn = chunk_n(idx);
-    switch (codec) {
-      case DpCodec::kBf16:
-        encode_bf16(chunk_ptr(idx), (uint16_t*)st.scratch_send.data(), cn);
-        return {st.scratch_send.data(), cn * 2};
-      case DpCodec::kInt8:
-        encode_int8(chunk_ptr(idx), st.scratch_send.data(), cn);
-        return {st.scratch_send.data(), 4 + cn};
-      case DpCodec::kF32:
-      default:
-        // zero-copy: the chunk's own bytes are the wire form
-        return {(const uint8_t*)chunk_ptr(idx), cn * 4};
+    if (codec == DpCodec::kF32) {
+      // zero-copy: the chunk's own bytes are the wire form
+      return {(const uint8_t*)chunk_ptr(idx), cn * 4};
     }
+    int64_t t0 = lathist::now_ns();
+    size_t wn;
+    if (codec == DpCodec::kBf16) {
+      encode_bf16(chunk_ptr(idx), (uint16_t*)st.scratch_send.data(), cn);
+      wn = cn * 2;
+    } else {
+      encode_int8(chunk_ptr(idx), st.scratch_send.data(), cn);
+      wn = 4 + cn;
+    }
+    acct.codec_ns += lathist::now_ns() - t0;
+    return {st.scratch_send.data(), wn};
   };
 
   bool send_failed = false;
@@ -759,11 +780,18 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
     // deadline'd hop's duration is exactly the evidence wanted
     int64_t t0 = lathist::now_ns();
     bool ok = use_cma ? cma_hop(send_fd, recv_fd, sb, sn, rb, rn, job.tag,
-                                job.deadline_ms, &send_failed, &timed_out, err)
+                                job.deadline_ms, &send_failed, &timed_out, err,
+                                &acct)
                       : hop(send_fd, recv_fd, sb, sn, rb, rn, job.tag,
                             job.deadline_ms, &send_failed, &timed_out, err);
     int64_t hop_ns = lathist::now_ns() - t0;
     lathist::observe(lathist::kDpHop, (double)hop_ns / 1e9);
+    if (!use_cma) {
+      // the pump's whole hop, waiting and moving alike; bytes of the
+      // hops that ended (a failed one's progress is not known here)
+      acct.pump_ns += hop_ns;
+      if (ok) acct.pump_bytes += (int64_t)rn;
+    }
     // crash-durable breadcrumb: a worker SIGKILLed mid-allreduce leaves
     // its last hops (a = op tag, b = ok flag) in the black box — the
     // postmortem's "what was in flight" answer for the native plane
@@ -795,6 +823,7 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
     if (!do_hop(sb, sn, st.scratch_recv.data(), rn)) {
       return fail();
     }
+    int64_t reduce_t0 = lathist::now_ns();
     switch (codec) {
       case DpCodec::kBf16:
         reduce_from_bf16(chunk_ptr(recv_idx),
@@ -820,6 +849,8 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
         }
         break;
     }
+    acct.reduce_ns += lathist::now_ns() - reduce_t0;
+    acct.reduce_bytes += (int64_t)chunk_n(recv_idx) * 4;
   }
   if (codec == DpCodec::kF32) {
     // raw allgather: f32 lands straight in the target chunk and the
@@ -845,6 +876,7 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
     // sum, decode, divide — the order a trailing np.divide gave
     int owned = (rank_ + 1) % world_;
     size_t own_wire = wire_nbytes(codec, chunk_n(owned));
+    int64_t codec_t0 = lathist::now_ns();
     switch (codec) {
       case DpCodec::kBf16:
         encode_bf16(chunk_ptr(owned), (uint16_t*)st.scratch_fwd.data(),
@@ -860,6 +892,7 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
       default:
         break;
     }
+    acct.codec_ns += lathist::now_ns() - codec_t0;
     uint8_t* cur = st.scratch_fwd.data();
     size_t cur_n = own_wire;
     uint8_t* spare = st.scratch_recv.data();
@@ -870,11 +903,13 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
       if (!do_hop(cur, cur_n, spare, rn)) {
         return fail();
       }
+      codec_t0 = lathist::now_ns();
       if (codec == DpCodec::kBf16) {
         decode_bf16((const uint16_t*)spare, chunk_ptr(recv_idx), cn, div);
       } else {
         decode_int8(spare, chunk_ptr(recv_idx), cn, div);
       }
+      acct.codec_ns += lathist::now_ns() - codec_t0;
       uint8_t* t = cur;
       cur = spare;
       spare = t;
@@ -926,6 +961,7 @@ int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
                          int divisor, DpCodec codec, uint32_t tag,
                          int64_t timeout_ms, int* bad_peer, std::string* err) {
   *bad_peer = -1;
+  last_account_ = DpAccount{};
   // AVG is SUM with the divisor `world`: one code path
   if (op == DpOp::kAvg && divisor == 1) {
     op = DpOp::kSum;
@@ -965,6 +1001,7 @@ int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
     st.job.codec = codec;
     st.job.tag = tag + (uint32_t)s;
     st.job.deadline_ms = deadline;
+    st.acct = DpAccount{};
     st.has_job = true;
     st.done = false;
     st.cv.notify_all();
@@ -999,6 +1036,27 @@ int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
       *err = st.err;
     }
   }
+  // the op's account: see DpAccount. A stripe whose worker never took the
+  // job (shutdown) adds the zeros it was handed
+  static constexpr int64_t DpAccount::*kTimes[] = {
+      &DpAccount::desc_wait_ns, &DpAccount::pull_ns,   &DpAccount::ack_wait_ns,
+      &DpAccount::pump_ns,      &DpAccount::reduce_ns, &DpAccount::codec_ns};
+  static constexpr int64_t DpAccount::*kBytes[] = {
+      &DpAccount::pull_bytes, &DpAccount::pump_bytes, &DpAccount::reduce_bytes};
+  DpAccount& a = last_account_;
+  a.stripes = ns;
+  for (int s = 0; s < ns; ++s) {
+    auto& st = *stripes_[s];
+    std::lock_guard<std::mutex> g(st.mu);
+    int64_t total = 0;
+    for (auto t : kTimes) {
+      a.*t += st.acct.*t;
+      total += st.acct.*t;
+    }
+    for (auto c : kBytes) a.*c += st.acct.*c;
+    if (total > a.slowest_stripe_ns) a.slowest_stripe_ns = total;
+  }
+  for (auto t : kTimes) a.*t /= ns;
   return rc;
 }
 
@@ -1046,7 +1104,10 @@ extern "C" {
 // symbol lookup at import.
 // v8: tft_dp_allreduce takes the divisor after `op` (the average is taken
 // inside the ring) — a stale library would read it as the codec.
-int tft_abi_version() { return 8; }
+// v9: tft_dp_last_account added (the ring's account of its last allreduce:
+// DpAccount, dataplane.h) — a stale build would fail the loader's symbol
+// lookup at import.
+int tft_abi_version() { return 9; }
 
 int64_t tft_dp_create(int rank, int world, int nstripes, char* err,
                       int errlen) {
@@ -1123,6 +1184,18 @@ int tft_dp_allreduce(int64_t h, void* data, int64_t nelems, int dtype, int op,
   if (bad_peer) *bad_peer = bp;
   if (rc != 0) dp_set_err(err, errlen, e);
   return rc;
+}
+
+// The account of the plane's last allreduce as int64s in DpAccount's order;
+// writes min(n, kDpAccountFields) of them and returns that count (-1: bad
+// handle). Call it from the thread that called tft_dp_allreduce.
+int tft_dp_last_account(int64_t h, int64_t* out, int n) {
+  auto dp = dp_get(h);
+  if (!dp) return -1;
+  const tft::DpAccount a = dp->last_account();
+  int k = n < 0 ? 0 : n < tft::kDpAccountFields ? n : tft::kDpAccountFields;
+  memcpy(out, &a, (size_t)k * sizeof(int64_t));
+  return k;
 }
 
 void tft_dp_free(int64_t h) {

@@ -39,6 +39,38 @@ enum class DpOp : int { kSum = 0, kAvg = 1, kMax = 2, kMin = 3 };
 //           values so NaN propagates loudly) + one int8 per element
 enum class DpCodec : int { kF32 = 0, kBf16 = 1, kInt8 = 2 };
 
+// Where one allreduce's hops spent their time: nanoseconds of the clock
+// lathist::now_ns reads, summed over a stripe's hops. Always kept — a
+// handful of clock reads a hop. Plane cma (cma_hop) splits a hop into
+// desc_wait (own descriptor sent -> the left neighbour's received), pull
+// (the process_vm_readv loop) and ack_wait (own ack sent -> the right
+// neighbour's received); plane tcp (hop) is a full-duplex pump that cannot
+// tell waiting from moving, so it has the one number pump. Both: reduce
+// (the reduce_* passes over scratch after a hop) and codec (encode / decode
+// passes; 0 on cma, whose payload stays f32). Bytes are what was pulled,
+// received by the pump, and reduced (f32 bytes written).
+// The op's account (last_account) is the MEAN over its stripes of each time
+// — stripes run in parallel, so the mean compares with the caller's wall
+// seconds — the SUM over stripes of each byte count, the number of stripes,
+// and the slowest stripe's summed times. The order of the fields is the
+// C ABI's (tft_dp_last_account; _native.NativeDataPlane.ACCOUNT mirrors it).
+struct DpAccount {
+  int64_t desc_wait_ns = 0;
+  int64_t pull_ns = 0;
+  int64_t ack_wait_ns = 0;
+  int64_t pump_ns = 0;
+  int64_t reduce_ns = 0;
+  int64_t codec_ns = 0;
+  int64_t pull_bytes = 0;
+  int64_t pump_bytes = 0;
+  int64_t reduce_bytes = 0;
+  int64_t stripes = 0;
+  int64_t slowest_stripe_ns = 0;
+};
+constexpr int kDpAccountFields = 11;
+static_assert(sizeof(DpAccount) == kDpAccountFields * sizeof(int64_t),
+              "DpAccount is kDpAccountFields int64s, in the ABI's order");
+
 class DataPlane {
  public:
   // Listens on an ephemeral port and starts the acceptor + stripe workers.
@@ -89,6 +121,11 @@ class DataPlane {
                 int divisor, DpCodec codec, uint32_t tag, int64_t timeout_ms,
                 int* bad_peer, std::string* err);
 
+  // The account of the last allreduce() on this plane, failed ones too (an
+  // op that failed reports what it had). Call it from the thread that
+  // called allreduce(): the collectives' one op thread.
+  DpAccount last_account() const { return last_account_; }
+
   void shutdown();
 
  private:
@@ -116,6 +153,9 @@ class DataPlane {
     std::vector<uint8_t> scratch_send;  // wire-encoded outgoing chunk
     std::vector<uint8_t> scratch_recv;  // wire-encoded incoming chunk
     std::vector<uint8_t> scratch_fwd;   // verbatim-forward double buffer
+    // this job's account: zeroed under mu when the job is handed over,
+    // written by the worker alone while it runs, read under mu once done
+    DpAccount acct;
   };
 
   void accept_loop();
@@ -127,7 +167,8 @@ class DataPlane {
            bool* send_failed, bool* timed_out, std::string* err);
   bool cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf, size_t sn,
                uint8_t* rbuf, size_t rn, uint32_t tag, int64_t deadline_ms,
-               bool* send_failed, bool* timed_out, std::string* err);
+               bool* send_failed, bool* timed_out, std::string* err,
+               DpAccount* acct);
   int fd_for(int peer, int stripe);
 
   int rank_;
@@ -144,6 +185,7 @@ class DataPlane {
   std::map<int, std::vector<int>> socks_;
 
   std::vector<std::unique_ptr<Stripe>> stripes_;
+  DpAccount last_account_;  // allreduce()'s caller thread only
 
   // atomic publication flag: enable_cma() runs on the Python control
   // thread AFTER the stripe workers (started in the constructor) are

@@ -11,6 +11,12 @@ its first ring's start and its last callback's end: seconds in each span,
 and the rest — time with a ring already queued that no span names
 (PERF.md §5, "the op thread's account"). A gap in which the next ring had
 not been submitted yet is the main thread's (starved), not the op thread's.
+Beside each ring's ``queued_s`` and ``divisor`` it prints the ring's own
+account of its hops (``tft.exchange.ring.account``: waiting for a neighbour,
+pulling, reducing — ``collectives.RING_ACCOUNT``), and per step the pack's
+``dst_ahead_b`` by bucket and, by name, the seconds of the other threads'
+``tft.exchange.*`` spans between the exchange's start and its first ring's
+(``before_first_ring_s``: which piece a late first ring waited for).
 
     python scripts/op_thread_account.py benchmark_runs/<cell>/trace.*   # after a --trace 1 run
 
@@ -28,6 +34,7 @@ import sys
 
 PREFIX = "tft.exchange"
 RING, H2D, AVERAGE = f"{PREFIX}.ring", f"{PREFIX}.h2d", f"{PREFIX}.average"
+ACCOUNT, PACK = f"{PREFIX}.ring.account", f"{PREFIX}.pack"
 
 
 def _xplane(path: str) -> str:
@@ -43,7 +50,7 @@ def account(path: str) -> dict:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(_xplane(path))
-    exchanges, by_line, counters = [], {}, []
+    exchanges, by_line, counters, accounts, packs, pieces = [], {}, [], [], [], []
     for plane in data.planes:
         if not plane.name.startswith("/host:CPU"):
             continue
@@ -57,6 +64,12 @@ def account(path: str) -> dict:
                     exchanges.append((s, e))
                 elif ev.name == f"{PREFIX}.counters":
                     counters.append((s, dict(ev.stats)))
+                elif ev.name == ACCOUNT:
+                    accounts.append((s, dict(ev.stats)))
+                elif ev.name == PACK:
+                    packs.append((s, dict(ev.stats)))
+                if ev.name not in (PREFIX, RING, H2D, AVERAGE, ACCOUNT, f"{PREFIX}.counters"):
+                    pieces.append((s, e, ev.name[len(PREFIX) + 1:]))
                 elif ev.name in (RING, H2D, AVERAGE):
                     by_line.setdefault((plane.name, li), []).append(
                         (s, e, ev.name, dict(ev.stats))
@@ -106,9 +119,27 @@ def account(path: str) -> dict:
             "last_ring_queued_s": float(rings[-1][3].get("queued_s", 0.0)),
             "ring_divisors": sorted({r[3].get("divisor") for r in rings}, key=str),
         }
+        # the n-th account of a step is its n-th ring's (one op thread)
+        mine = sorted(((s, a) for s, a in accounts if lo <= s <= hi), key=lambda sa: sa[0])
+        if len(mine) == len(rings):
+            step["ring_accounts"] = [
+                {"ring_s": e - s, "queued_s": st.get("queued_s"), "divisor": st.get("divisor"),
+                 **{k: v for k, v in a.items() if k != "bytes"}}
+                for (s, e, _, st), (_, a) in zip(rings, mine)
+            ]
+        before = {}
+        for s, e, name in pieces:
+            if lo <= s < rings[0][0]:
+                before[name] = before.get(name, 0.0) + min(e, rings[0][0]) - s
+        step["before_first_ring_s"] = before
+        step["first_ring_queued_s"] = float(rings[0][3].get("queued_s", 0.0))
+        ahead = [p.get("dst_ahead_b") for s, p in sorted(packs, key=lambda sp: sp[0]) if lo <= s < hi]
+        if any(a is not None for a in ahead):
+            step["pack_dst_ahead_b"] = ahead
         for s, stats in counters:
             if lo <= s <= hi + 1e-3:
-                for k in ("buckets", "buckets_reused", "buckets_avg_in_ring"):
+                for k in ("buckets", "buckets_reused", "buckets_avg_in_ring", "pack_aliased_bytes",
+                          "ring_wait_s", "ring_pull_s", "ring_reduce_s"):
                     if k in stats:
                         step[k] = stats[k]
         steps.append(step)

@@ -545,3 +545,100 @@ def test_cpu_seconds_under_the_clocks_tick_read_as_the_quantum_not_as_zero(monke
     )
     _, attrs = exchange(RingStub(), tree_of(1.0, "numpy"), bucket_bytes=128)
     assert attrs["utime_s"] == pytest.approx(0.03) and attrs["stime_s"] == 1e-6
+
+
+# -- the account of the exchange's copies (ddp._pack_account, the ring's) -----
+
+
+def at_offset(arena, offset, nbytes):
+    """``nbytes`` of ``arena`` as float32, starting ``offset`` B into a page."""
+    base = arena.__array_interface__["data"][0]
+    start = (-base) % 4096 + offset
+    return arena[start : start + nbytes].view(np.float32)
+
+
+@pytest.mark.parametrize(
+    "ahead,inside",
+    [(0, False), (16, True), (800, True), (1023, True), (1024, False), (4095, False)],
+)
+def test_the_window_counts_the_copies_whose_destination_is_just_ahead(ahead, inside):
+    """0 B ahead is the same offset in a page (no false dependence); 1 to
+    1023 B ahead is the window; from 1024 B on, and behind the source
+    (4095 = 1 B behind), is outside it."""
+    from torchft_tpu.ddp import _pack_account
+
+    src_arena, dst_arena = np.zeros(1 << 16, np.uint8), np.zeros(1 << 16, np.uint8)
+    src = at_offset(src_arena, 100 * 4, 4096)
+    dst = at_offset(dst_arena, (100 * 4 + ahead) % 4096, 3 * 4096)
+    assert _pack_account(dst, [src]) == (ahead, 4096, 4096 if inside else 0)
+    # the second leaf lands where the first ended; the largest names the offset
+    small = at_offset(src_arena, 8192 + 100 * 4 + 4096 - 16, 512)
+    ahead2 = (ahead + 16) % 4096
+    assert _pack_account(dst, [src, small]) == (
+        ahead, 4096 + 512, (4096 if inside else 0) + (512 if 0 < ahead2 < 1024 else 0)
+    )
+    assert _pack_account(dst, [small, src])[0] == (ahead + 512) % 4096
+
+
+def test_the_exchange_says_what_it_copied_and_the_pack_where(off_cpu, monkeypatch):
+    """``pack_bytes``, ``pack_aliased_bytes`` and ``h2d_bytes`` on the
+    ``exchange`` span and on ``tft.exchange.counters``; ``dst_ahead_b`` on
+    each ``tft.exchange.pack``. A manager without ``ring_account`` (this
+    stub) still exchanges, and reports no ``ring_*`` sum."""
+    from torchft_tpu.telemetry import tracing
+
+    counters, packs = {}, []
+    real = tracing.annotate
+
+    def annotate(name, **stats):
+        if name == "exchange.counters":
+            counters.update(stats)
+        elif name == "exchange.pack":
+            packs.append(stats)
+        return real(name, **stats)
+
+    monkeypatch.setattr(tracing, "annotate", annotate)
+    out, attrs = exchange(RingStub(), tree_of(1.0), bucket_bytes=128)
+    np.testing.assert_allclose(np.asarray(out["g0"]), (1.0 + np.arange(16)) / 2)
+    assert attrs["pack_bytes"] == attrs["h2d_bytes"] == attrs["bytes_d2h"] == 5 * 64
+    assert 0 <= attrs["pack_aliased_bytes"] <= attrs["pack_bytes"]
+    assert attrs["pack_aliased_bytes"] % 64 == 0  # whole leaf copies
+    for key in ("pack_bytes", "pack_aliased_bytes", "h2d_bytes"):
+        assert counters[key] == attrs[key]
+    assert not [k for k in attrs if k.startswith("ring_")]
+    assert len(packs) == 3 and all(0 <= p["dst_ahead_b"] < 4096 for p in packs)
+    # host leaves are handed back as slices of their bucket: nothing is put
+    _, attrs = exchange(RingStub(), tree_of(1.0, kind="numpy"), bucket_bytes=128)
+    assert attrs["pack_bytes"] == 5 * 64 and attrs["h2d_bytes"] == 0
+
+
+def test_the_rings_account_grows_into_the_steps_sums():
+    """What the manager's ``ring_account()`` gained over the exchange lands
+    on the span: wait = desc_wait + ack_wait; a field the exchange has no
+    sum for (codec, slowest stripe, stripes) is left to the per-op event."""
+    from torchft_tpu.collectives import RING_ACCOUNT
+
+    class Accounting(RingStub):
+        def __init__(self):
+            super().__init__()
+            self.total = dict.fromkeys(RING_ACCOUNT, 0)
+
+        def ring_account(self):
+            return dict(self.total)
+
+        def ring(self, buf):
+            super().ring(buf)
+            for k, v in dict(
+                desc_wait_s=0.25, ack_wait_s=0.5, pull_s=0.125, reduce_s=1.0, pump_s=2.0,
+                codec_s=4.0, pull_bytes=buf.nbytes, reduce_bytes=buf.nbytes // 2, stripes=4,
+            ).items():
+                self.total[k] += v
+
+    m = Accounting()
+    m.total["pull_s"] = 100.0  # what earlier exchanges left: only growth counts
+    _, attrs = exchange(m, tree_of(1.0), bucket_bytes=128)
+    assert attrs["buckets"] == 3
+    assert {k: v for k, v in attrs.items() if k.startswith("ring_")} == {
+        "ring_wait_s": 3 * 0.75, "ring_pull_s": 3 * 0.125, "ring_reduce_s": 3.0,
+        "ring_pump_s": 6.0, "ring_pull_bytes": 5 * 64, "ring_reduce_bytes": 5 * 32,
+    }

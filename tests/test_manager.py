@@ -718,3 +718,22 @@ def test_the_trace_says_where_the_average_was_taken(
     assert all(a["divisor"] == 2 and a["bytes"] == 4096 for a in averages)
     counters = by_name["tft.exchange.counters"]
     assert [c["buckets_avg_in_ring"] for c in counters] == [4 if native else 0] * 2
+    # every ring is followed by its account, and the step's sums are the
+    # accounts' (both groups' here: one process holds them)
+    accounts = by_name["tft.exchange.ring.account"]
+    assert len(accounts) == 8 and all(a["bytes"] == 4096 for a in accounts)
+    (plane,) = {a["plane"] for a in accounts}
+    assert plane in (("cma", "tcp-striped") if native else ("python-ring",))
+    for c in counters:
+        assert c["pack_bytes"] == 4 * 4096 and c["h2d_bytes"] == 0  # host leaves
+        # 2 x (w - 1) / w of a bucket is pulled, (w - 1) / w reduced natively
+        assert c["ring_pull_bytes"] == (4 * 4096 if plane == "cma" else 0)
+        assert c["ring_reduce_bytes"] == (4 * 2048 if native else 0)
+        assert (c["ring_pump_s"] > 0) == (plane != "cma")
+    for total, fields in (
+        ("ring_wait_s", ("desc_wait_s", "ack_wait_s")), ("ring_pull_s", ("pull_s",)),
+        ("ring_reduce_s", ("reduce_s",)), ("ring_pump_s", ("pump_s",)),
+    ):
+        assert sum(c[total] for c in counters) == pytest.approx(
+            sum(a[f] for a in accounts for f in fields), abs=1e-9
+        )

@@ -44,6 +44,13 @@ MAIN_THREAD = (
 )
 # these carry step, bucket and bytes; the ring, a layer below, only its bytes
 PER_BUCKET = ("exchange.d2h_wait", "exchange.pack", "exchange.submit", "exchange.h2d")
+# the step's sums: attributes of the ``exchange`` span, stats of its counters
+EXCHANGE_SUMS = {
+    "step", "buckets", "buckets_reused", "buckets_avg_in_ring", "d2h_pages_kept", "bytes_d2h",
+    "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
+    "pack_bytes", "pack_aliased_bytes", "h2d_bytes",
+    "ring_wait_s", "ring_pull_s", "ring_reduce_s", "ring_pump_s", "ring_pull_bytes", "ring_reduce_bytes",
+}
 
 
 @pytest.fixture(scope="module")
@@ -205,15 +212,37 @@ def test_bucket_stats_tie_the_threads_together(traced):
     (_, _, counters), *_ = [c for c in main["exchange.counters"] if c[2]["step"] == 1]
     assert counters["buckets"] == len(keys["exchange.submit"]) >= 3
     assert counters["bytes_d2h"] == total
-    assert set(counters) == {
-        "step", "buckets", "buckets_reused", "buckets_avg_in_ring", "d2h_pages_kept", "bytes_d2h",
-        "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
-    }
+    assert set(counters) == EXCHANGE_SUMS
     assert all(counters[key] >= 0 for key in counters)
     assert counters["buckets_avg_in_ring"] == 0
+    # every byte of the tree is packed once and put back once; one group has
+    # no neighbour to wait for or pull from
+    assert counters["pack_bytes"] == counters["h2d_bytes"] == total
+    assert 0 <= counters["pack_aliased_bytes"] <= total
+    assert all(counters[k] == 0 for k in counters if k.startswith("ring_"))
+    # the pack says where its largest copy lands within a page
+    assert all(0 <= s["dst_ahead_b"] < 4096 for _, _, s in main["exchange.pack"])
     # a zero-length carrier at the end of its exchange
     for (s, e, _), (s0, e0, _) in zip(main["exchange.counters"], main["exchange"]):
         assert s0 <= s and e <= e0 and e - s < 1e6
+
+
+def test_each_ring_is_followed_by_its_account(traced):
+    """``exchange.ring.account``: zero-length, on the op thread, one a ring,
+    after it and before the next."""
+    lines, _, _ = traced
+    (op,) = [ln for ln in lines if "exchange.ring" in ln]
+    rings, accounts = sorted(op["exchange.ring"]), sorted(op["exchange.ring.account"])
+    assert sum(len(ln.get("exchange.ring.account", ())) for ln in lines) == len(accounts) == len(rings)
+    from torchft_tpu.collectives import RING_ACCOUNT
+
+    for i, ((rs, re_, ring), (s, e, account)) in enumerate(zip(rings, accounts)):
+        assert e - s < 1e6 and re_ <= s
+        assert i + 1 == len(rings) or e <= rings[i + 1][0]
+        assert set(account) == set(RING_ACCOUNT) | {"bytes", "plane"}
+        assert account["bytes"] == ring["bytes"]
+        # world size 1: no hop ran
+        assert all(account[k] == 0 for k in RING_ACCOUNT)
 
 
 def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, monkeypatch):
@@ -239,10 +268,9 @@ def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, mo
         assert s["t0_mono_ns"] + s["dur_s"] * 1e9 <= last["t0_mono_ns"] + last["dur_s"] * 1e9 + 1e6
     assert last["attrs"]["committed"] is True
     (exchange,) = [s for s in children if s["name"] == "exchange"]
-    assert set(exchange["attrs"]) == {
-        "step", "buckets", "buckets_reused", "buckets_avg_in_ring", "d2h_pages_kept", "bytes_d2h",
-        "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
-    }
+    # the step's sums reach /trace, the JSONL and the piggyback untraced too
+    assert set(exchange["attrs"]) == EXCHANGE_SUMS
+    assert exchange["attrs"]["pack_bytes"] == exchange["attrs"]["h2d_bytes"] == 4 * n_params()
     # nothing per bucket, and at most 12 new entries a step
     assert not [s for s in spans if s["name"].startswith("exchange.")]
     assert "resolve_speculation" not in {s["name"] for s in spans}  # no vote was pending

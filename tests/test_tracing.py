@@ -488,3 +488,85 @@ class TestTrailRotation:
         trail.close()
         assert not os.path.exists(str(tmp_path / "t.jsonl.1"))
 
+
+
+# ---------------------------------------------------------------------------
+# the ring's account in the profiler's trace
+# ---------------------------------------------------------------------------
+
+
+class TestRingAccountEvent:
+    @pytest.mark.parametrize("cma", ["1", "0"])
+    def test_one_zero_length_account_follows_each_ring_on_the_op_thread(
+        self, tmp_path, monkeypatch, cma
+    ):
+        """Two ranks, three allreduces each, under a ``jax.profiler`` session:
+        every ``tft.exchange.ring`` has one ``tft.exchange.ring.account``
+        after it on its own thread's line, a microsecond long at most, with
+        the bytes the plane moved for it."""
+        import glob
+        from concurrent.futures import ThreadPoolExecutor
+
+        import jax
+
+        from torchft_tpu.collectives import RING_ACCOUNT, CollectivesTcp, ReduceOp
+        from torchft_tpu.store import StoreServer
+
+        monkeypatch.setenv("TORCHFT_DP_CMA", cma)
+        store = StoreServer()
+        colls = [
+            CollectivesTcp(hostname="localhost", timeout=timedelta(seconds=10))
+            for _ in range(2)
+        ]
+        n = 4 * 16 * 2 * 100
+
+        def run(rank):
+            colls[rank].configure(f"{store.address()}/rae{cma}", rank, 2)
+            try:
+                for _ in range(3):
+                    colls[rank].allreduce(
+                        [np.ones(n, np.float32)], ReduceOp.SUM, 2
+                    ).wait(timedelta(seconds=20))
+            finally:
+                colls[rank].shutdown()
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with ThreadPoolExecutor(max_workers=2) as ex:
+                list(ex.map(run, range(2)))
+        finally:
+            jax.profiler.stop_trace()
+            store.shutdown()
+
+        (path,) = glob.glob(
+            os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+        )
+        op_lines = []
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:CPU"):
+                continue
+            for line in plane.lines:
+                evs = sorted(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name, dict(ev.stats))
+                    for ev in line.events
+                    if ev.name in ("tft.exchange.ring", "tft.exchange.ring.account")
+                )
+                if evs:
+                    op_lines.append(evs)
+        assert len(op_lines) == 2  # one op thread a rank, nothing elsewhere
+        for evs in op_lines:
+            assert [name for _, _, name, _ in evs] == [
+                "tft.exchange.ring", "tft.exchange.ring.account"
+            ] * 3
+            for (_, ring_end, _, ring), (s, e, _, acct) in zip(evs[::2], evs[1::2]):
+                assert ring_end <= s and e - s < 1e6
+                assert set(acct) == set(RING_ACCOUNT) | {"bytes", "plane"}
+                assert acct["bytes"] == ring["bytes"] == 4 * n
+                moved = "pull_bytes" if cma == "1" else "pump_bytes"
+                assert acct[moved] == 4 * n and acct["reduce_bytes"] == 2 * n
+                assert acct["plane"] == ("cma" if cma == "1" else "tcp-striped")
+                busy = sum(acct[k] for k in RING_ACCOUNT if k.endswith("_s")) - acct["slowest_stripe_s"]
+                assert 0 < busy <= acct["slowest_stripe_s"] + 1e-9

@@ -54,7 +54,9 @@ _TIMEOUT_CODES = (CANCELLED, DEADLINE_EXCEEDED)
 #     old build would fail the loader's symbol lookup at import.
 # v8: tft_dp_allreduce takes the divisor after `op` (the average is taken
 #     inside the ring) — an old build would read it as the codec.
-_ABI_VERSION = 8
+# v9: tft_dp_last_account (the ring's account of its last allreduce) — an
+#     old build would fail the loader's symbol lookup at import.
+_ABI_VERSION = 9
 
 
 def _build(force: bool = False) -> None:
@@ -266,6 +268,10 @@ def _load() -> ctypes.CDLL:
         c.c_uint32, c.c_int64, c.POINTER(c.c_int), c.c_char_p, c.c_int,
     ]
     lib.tft_dp_allreduce.restype = c.c_int
+    lib.tft_dp_last_account.argtypes = [
+        c.c_int64, c.POINTER(c.c_int64), c.c_int
+    ]
+    lib.tft_dp_last_account.restype = c.c_int
     lib.tft_dp_free.argtypes = [c.c_int64]
     lib.tft_dp_free.restype = None
 
@@ -704,6 +710,13 @@ class NativeDataPlane:
     # wire codecs (native/dataplane.h DpCodec; formats mirror
     # torchft_tpu/wire_codec.py byte for byte)
     CODEC = {"f32": 0, "bfloat16": 1, "int8": 2}
+    # the fields of last_account(), in the order of native/dataplane.h's
+    # DpAccount (which says what each one times or counts)
+    ACCOUNT = (
+        "desc_wait_ns", "pull_ns", "ack_wait_ns", "pump_ns", "reduce_ns",
+        "codec_ns", "pull_bytes", "pump_bytes", "reduce_bytes", "stripes",
+        "slowest_stripe_ns",
+    )
 
     def __init__(self, rank: int, world: int, nstripes: int = 4) -> None:
         err = _errbuf()
@@ -777,6 +790,16 @@ class NativeDataPlane:
                 int(bad_peer.value),
                 f"dataplane allreduce: {err.value.decode()}",
             )
+
+    def last_account(self) -> "dict[str, int]":
+        """Where the last :meth:`allreduce` on this plane spent its hops
+        (``ACCOUNT``; a failed op reports what it had): each time the mean
+        over the op's stripes, each byte count their sum; ``{}`` once the
+        plane is closed. Call it from the thread that called
+        ``allreduce``."""
+        out = (ctypes.c_int64 * len(self.ACCOUNT))()
+        n = _lib.tft_dp_last_account(self._h, out, len(self.ACCOUNT))
+        return dict(zip(self.ACCOUNT, out)) if n == len(self.ACCOUNT) else {}
 
     def close(self) -> None:
         if self._h:

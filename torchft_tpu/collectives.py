@@ -162,6 +162,19 @@ def average_in_place(arrays: List[np.ndarray], divisor: int) -> None:
             np.divide(a, divisor, out=a)
 
 
+# The ring's account of an allreduce: what ``tft.exchange.ring.account``
+# carries for one op and ``ring_account()`` totals over all of them. Seconds
+# are the mean over the native plane's stripes (they run in parallel, so the
+# mean compares with ``tft.exchange.ring``'s wall seconds), bytes their sum;
+# native/dataplane.h's DpAccount says where each starts and ends. Plane cma
+# fills desc_wait / pull / ack_wait, plane tcp and the Python ring pump — a
+# full-duplex pump cannot tell waiting from moving.
+RING_ACCOUNT = (
+    "desc_wait_s", "pull_s", "ack_wait_s", "pump_s", "reduce_s", "codec_s",
+    "slowest_stripe_s", "pull_bytes", "pump_bytes", "reduce_bytes", "stripes",
+)
+
+
 class Work:
     """Async op handle (torch Work analogue)."""
 
@@ -279,6 +292,13 @@ class Collectives(ABC):
         must delegate; a backend that cannot tell (the proxy's parent
         side) says 0."""
         return 0
+
+    def ring_account(self) -> Dict[str, float]:
+        """Where this backend's allreduces have spent their time inside the
+        ring, as monotonic totals of :data:`RING_ACCOUNT`'s fields (read as
+        a difference, e.g. ``ddp``'s ``ring_wait_s``). Wrappers must
+        delegate; a backend that keeps no such account says ``{}``."""
+        return {}
 
     def shutdown(self) -> None:  # noqa: B027 — optional hook
         pass
@@ -542,6 +562,8 @@ class CollectivesTcp(Collectives):
         self._p2p: Optional[ThreadPoolExecutor] = None
         self._op_seq = 0
         self._avg_in_ring_ops = 0  # written on the op thread only
+        # totals of RING_ACCOUNT, written on the op thread only
+        self._ring_account: Dict[str, float] = dict.fromkeys(RING_ACCOUNT, 0)
 
     # -- lifecycle --
 
@@ -808,6 +830,9 @@ class CollectivesTcp(Collectives):
 
     def avg_in_ring_ops(self) -> int:
         return self._avg_in_ring_ops
+
+    def ring_account(self) -> Dict[str, float]:
+        return dict(self._ring_account)
 
     def _epoch_scratch(self, dtype: np.dtype, nelems: int,
                        slot: str = "") -> np.ndarray:
@@ -1343,6 +1368,7 @@ class CollectivesTcp(Collectives):
             # summed here and divided after the span, under its own name
             native = [world > 1 and self._dp_eligible(a) for a in arrays]
             in_ring = divisor > 1 and bool(arrays) and all(native)
+            account: Dict[str, float] = dict.fromkeys(RING_ACCOUNT, 0)
             # queued_s: the op's wait for this one thread. Ops run here in
             # submission order, which is what ties an event to its submitter
             with tracing.annotate(
@@ -1353,11 +1379,23 @@ class CollectivesTcp(Collectives):
                     # ops are serialized on the op thread, so arrays of one
                     # allreduce may share the tag (it is a desync check, not
                     # a demultiplexer; the native plane offsets per-stripe)
-                    for arr, dp in zip(arrays, native):
-                        if dp:
-                            self._dp_allreduce(arr, op, tag, divisor)
-                        else:
-                            self._ring_allreduce(arr, op, tag)
+                    try:
+                        for arr, dp in zip(arrays, native):
+                            if dp:
+                                self._dp_allreduce(arr, op, tag, divisor, account)
+                            else:
+                                self._ring_allreduce(arr, op, tag, account)
+                    finally:  # a failed op's account counts too
+                        for k, v in account.items():
+                            self._ring_account[k] += v
+            # an annotation takes its stats at entry: a zero-length one
+            # after the ring carries its account, the n-th of a step for
+            # the step's n-th ring
+            with tracing.annotate(
+                "exchange.ring.account", bytes=nbytes,
+                plane=self.plane_info(), **account,
+            ):
+                pass
             average_in_place(
                 [a for a, dp in zip(arrays, native) if not dp], divisor
             )
@@ -1385,7 +1423,8 @@ class CollectivesTcp(Collectives):
         return self._codec.name in NativeDataPlane.CODEC
 
     def _dp_allreduce(
-        self, arr: np.ndarray, op: ReduceOp, tag: int, divisor: int
+        self, arr: np.ndarray, op: ReduceOp, tag: int, divisor: int,
+        account: Dict[str, float],
     ) -> None:
         """Hot path: the striped C++ ring (it applies ``divisor`` where an
         element's final value is written; the wire codec — bf16 or int8 —
@@ -1418,8 +1457,22 @@ class CollectivesTcp(Collectives):
             # codec work happens inside the C++ stripe workers and is not
             # separable from here; the whole native op lands in "wire"
             record_wire_stage("wire", _time.perf_counter() - t0)
+            # what the stripe workers say of their own hops ({} once the
+            # plane is closed)
+            for k, v in dp.last_account().items():
+                if k.endswith("_ns"):
+                    account[k[:-3] + "_s"] += v / 1e9
+                else:
+                    account[k] += v
 
-    def _ring_allreduce(self, arr: np.ndarray, op: ReduceOp, tag: int) -> None:
+    def _ring_allreduce(
+        self, arr: np.ndarray, op: ReduceOp, tag: int,
+        account: Optional[Dict[str, float]] = None,
+    ) -> None:
+        """The Python ring. Into ``account`` (RING_ACCOUNT) goes what it
+        measures anyway: its hops' seconds and received bytes as the
+        pump's, a lossy codec's passes (the reduce with the decode) as
+        ``codec_s``."""
         world, rank = self._world, self._rank
         right = (rank + 1) % world
         left = (rank - 1) % world
@@ -1436,7 +1489,7 @@ class CollectivesTcp(Collectives):
         lossy = codec.lossy and arr.dtype == np.float32 and flat.size > 0
         if lossy:
             self._ring_allreduce_codec(
-                arr, op, tag, chunks, max_elems, reduce_fn
+                arr, op, tag, chunks, max_elems, reduce_fn, account
             )
             return
 
@@ -1473,10 +1526,16 @@ class CollectivesTcp(Collectives):
             t_wire += _time.perf_counter() - t0
             chunks[recv_idx][:] = view.reshape(chunks[recv_idx].shape)
         record_wire_stage("wire", t_wire)
+        if account is not None:
+            account["pump_s"] += t_wire
+            account["pump_bytes"] += (
+                2 * flat.nbytes - chunks[rank].nbytes - chunks[right].nbytes
+            )
 
     def _ring_allreduce_codec(
         self, arr: np.ndarray, op: ReduceOp, tag: int,
         chunks: List[np.ndarray], max_elems: int, reduce_fn,
+        account: Optional[Dict[str, float]] = None,
     ) -> None:
         """Lossy-codec ring. Reduce-scatter ships freshly encoded partial
         sums per hop (re-quantized at each hop's own magnitude, residual
@@ -1552,6 +1611,9 @@ class CollectivesTcp(Collectives):
         record_wire_stage("quantize", t_quant)
         record_wire_stage("wire", t_wire)
         record_wire_stage("dequant_reduce", t_dq)
+        if account is not None:
+            account["pump_s"] += t_wire
+            account["codec_s"] += t_quant + t_dq
 
     def allgather(self, arr: np.ndarray) -> Work:
         world, rank = self._world, self._rank
@@ -1770,6 +1832,9 @@ class ErrorSwallowingCollectives(Collectives):
 
     def avg_in_ring_ops(self) -> int:
         return self._inner.avg_in_ring_ops()
+
+    def ring_account(self) -> Dict[str, float]:
+        return self._inner.ring_account()
 
     def report_error(self, e: Exception) -> None:
         self._error = e

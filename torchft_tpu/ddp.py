@@ -58,9 +58,12 @@ NumPy pass shows as ``tft.exchange.average``).
 
 What the exchange spends its time on is visible from inside
 (docs/observability.md "Spans in the profiler's trace"): one ``exchange``
-span around the call, carrying the per-step sums and the process's
-CPU-time deltas, and in a profiler trace one ``tft.exchange.*`` event per
-piece of work per bucket, on the thread that did it.
+span around the call, carrying the per-step sums, the process's CPU-time
+deltas, the bytes each host copy moved (with those of the pack's copies
+that sit inside the 4K-aliasing window) and what the data plane's account
+of its rings grew by (waiting for a neighbour, pulling, reducing), and in
+a profiler trace one ``tft.exchange.*`` event per piece of work per
+bucket, on the thread that did it.
 """
 
 from __future__ import annotations
@@ -82,6 +85,14 @@ __all__ = ["flatten_buckets", "unflatten_buckets", "allreduce_gradients"]
 
 _DEFAULT_BUCKET_BYTES = 25 * 1024 * 1024
 
+_PAGE_B = 4096
+# A copy whose destination lies 1 to 1023 B ahead of its source modulo 4096
+# has its loads falsely depend on its own earlier stores (4K aliasing): the
+# window the pack's account counts bytes in. PR 29 measured ``np.copyto`` at
+# 4-7 GB/s instead of 20 with the destination 16-800 B ahead (PERF.md §2);
+# which level a process draws is its allocator's, for its life.
+_ALIAS_WINDOW_B = 1024
+
 
 def default_bucket_bytes() -> int:
     """Streamed-bucket size for the host wire plane — the
@@ -95,6 +106,25 @@ def default_bucket_bytes() -> int:
         except ValueError:
             pass
     return _DEFAULT_BUCKET_BYTES
+
+
+def _pack_account(
+    buf: np.ndarray, host: Sequence[np.ndarray]
+) -> Tuple[int, int, int]:
+    """What the pack of ``host`` into ``buf``, leaf after leaf, copies where:
+    ``(dst - src) mod 4096`` of the largest leaf's copy, the bytes of all
+    the copies, and the bytes of those whose destination lies inside the
+    aliasing window ahead of their source. Addresses only; nothing is read."""
+    dst = buf.__array_interface__["data"][0]
+    ahead_of_largest = largest = copied = aliased = 0
+    for h in host:
+        ahead = (dst + copied - h.__array_interface__["data"][0]) % _PAGE_B
+        if h.nbytes > largest:
+            largest, ahead_of_largest = h.nbytes, ahead
+        if 0 < ahead < _ALIAS_WINDOW_B:
+            aliased += h.nbytes
+        copied += h.nbytes
+    return ahead_of_largest, copied, aliased
 
 
 def _leaves(tree: Any) -> Tuple[List[Any], Any]:
@@ -385,13 +415,17 @@ def _host_exchange(
     from torchft_tpu.checkpointing.serialization import _index_desc
 
     sums = {  # per step
-        "buckets_reused": 0, "d2h_pages_kept": 0, "d2h_wait_s": 0.0, "pack_s": 0.0
+        "buckets_reused": 0, "d2h_pages_kept": 0, "d2h_wait_s": 0.0, "pack_s": 0.0,
+        "pack_bytes": 0, "pack_aliased_bytes": 0,
     }
     # the data plane's count of ops averaged inside its ring: its growth
     # over this exchange is how many buckets needed no division pass
     # (duck-typed managers have no such count)
     avg_in_ring_ops = getattr(manager, "avg_in_ring_ops", lambda: 0)
     avg_in_ring_0 = avg_in_ring_ops()
+    # and its account of the time inside the ring, read the same way
+    ring_account = getattr(manager, "ring_account", dict)
+    ring_0 = ring_account()
 
     # stage 0: kick off D2H for every leaf/shard before anything blocks.
     # No guard: a runtime that rejects the prefetch would serialise every
@@ -443,6 +477,8 @@ def _host_exchange(
             ),
         )
 
+    h2d_bytes: List[int] = []  # by bucket: what its scatter put
+
     def _run_bucket(ordinal: int, idxs: List[int]):
         # what ties this bucket's events together across threads
         tags = {
@@ -458,17 +494,21 @@ def _host_exchange(
         with tracing.annotate("exchange.d2h_wait", **tags):
             host = [np.asarray(items[i].src) for i in idxs]
         t1 = time.perf_counter()
-        with tracing.annotate("exchange.pack", **tags):
-            # the bucket buffer always owns its memory: the ring reduces
-            # (and non-participants zero) in place, which must never write
-            # through a view of the caller's arrays or a read-only XLA
-            # host buffer
-            buf = kept.bufs[ordinal]
-            if buf is None:
-                dtype, count = kept.key[ordinal]
-                buf = np.empty(count, dtype)
-            else:
-                sums["buckets_reused"] += 1
+        # the bucket buffer always owns its memory: the ring reduces (and
+        # non-participants zero) in place, which must never write through a
+        # view of the caller's arrays or a read-only XLA host buffer
+        buf = kept.bufs[ordinal]
+        if buf is None:
+            dtype, count = kept.key[ordinal]
+            buf = np.empty(count, dtype)
+        else:
+            sums["buckets_reused"] += 1
+        # an annotation takes its stats at entry, and where the copies will
+        # land is known before they run
+        dst_ahead_b, copied, aliased = _pack_account(buf, host)
+        sums["pack_bytes"] += copied
+        sums["pack_aliased_bytes"] += aliased
+        with tracing.annotate("exchange.pack", dst_ahead_b=dst_ahead_b, **tags):
             off = 0
             for h in host:
                 np.copyto(buf[off : off + h.size].reshape(h.shape), h)
@@ -511,6 +551,8 @@ def _host_exchange(
             )
             put_shardings.append(s)
         shapes = [items[i].shape for i in idxs]
+        # one slot a bucket: scatter may run on either thread
+        h2d_bytes.append(0)
 
         def scatter(f):
             # stage 3 (runs on the op thread as soon as this bucket's ring
@@ -527,6 +569,7 @@ def _host_exchange(
                     piece = res[off : off + n].reshape(shp)
                     off += n
                     if sharding is not None:
+                        h2d_bytes[ordinal] += piece.nbytes
                         piece = jax.device_put(piece, sharding)
                     parts.append(piece)
             return parts
@@ -575,6 +618,7 @@ def _host_exchange(
                     template.shape
                 ).items()
             ]
+            h2d_bytes.append(sum(a.nbytes for a in arrays))
             placed.extend(arrays)
             out[li] = jax.make_array_from_single_device_arrays(
                 template.shape, template.sharding, arrays
@@ -584,9 +628,24 @@ def _host_exchange(
     )():
         kept.placed = [a for a in placed if not a.is_ready()]
         _KEPT[manager] = kept
+    # every bucket's ring is over (the tail wait saw to it), so the growth
+    # of the data plane's account is this exchange's: what the ring waited
+    # for a neighbour, pulled, reduced and pumped
+    ring_1 = ring_account()
+    ring = {k: v - ring_0.get(k, 0) for k, v in ring_1.items()}
+    if ring:
+        sums.update(
+            ring_wait_s=ring["desc_wait_s"] + ring["ack_wait_s"],
+            ring_pull_s=ring["pull_s"],
+            ring_reduce_s=ring["reduce_s"],
+            ring_pump_s=ring["pump_s"],
+            ring_pull_bytes=ring["pull_bytes"],
+            ring_reduce_bytes=ring["reduce_bytes"],
+        )
     return out, {
         "buckets": len(plan),
         "bytes_d2h": sum(it.nbytes for it in items),
+        "h2d_bytes": sum(h2d_bytes),
         "tail_wait_s": tail_wait_s,
         "buckets_avg_in_ring": avg_in_ring_ops() - avg_in_ring_0,
         **sums,

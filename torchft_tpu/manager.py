@@ -1415,6 +1415,14 @@ class Manager:
         fn = getattr(self._collectives, "avg_in_ring_ops", None)
         return int(fn()) if callable(fn) else 0
 
+    def ring_account(self) -> Dict[str, float]:
+        """The data plane's running account of where its allreduces spent
+        their time inside the ring (``Collectives.ring_account``; ``{}``
+        where it keeps none); ``ddp`` reads its growth over an exchange as
+        ``ring_wait_s``, ``ring_pull_s`` and the rest."""
+        fn = getattr(self._collectives, "ring_account", None)
+        return dict(fn()) if callable(fn) else {}
+
     def wire_codec(self) -> str:
         """Name of the codec the configured data plane ships large f32
         allreduces with (``"f32"`` = exact). ``ManagedOptimizer`` keys its
