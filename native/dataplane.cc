@@ -12,6 +12,7 @@
 #include <sys/uio.h>
 #include <unistd.h>
 
+#include <algorithm>
 #include <chrono>
 #include <cmath>
 #include <cstring>
@@ -38,11 +39,21 @@ namespace {
 
 constexpr uint32_t kHelloMagic = 0x7F7A0D01;  // distinct from control hello
 
+// What a cma hop offers its right neighbour to pull: `len` bytes of this
+// process, lying in `npieces` >= 1 pieces whose {addr, len} follow the
+// descriptor on the socket (one piece: a range of the op's buffer; several:
+// an op's source where its segments' bounds fall inside the chunk).
 struct CmaDesc {
   uint32_t tag;
   uint32_t len;
-  uint64_t addr;
+  uint32_t npieces;
+  uint32_t reserved;
 };
+struct CmaPiece {
+  uint64_t addr;
+  uint64_t len;
+};
+constexpr uint32_t kCmaMaxPieces = 1u << 16;  // a garbage count fails, not allocates
 
 // bf16 round-to-nearest-even, matching numpy/ml_dtypes astype semantics
 // for the values gradients take (the Python wire codec this plane must be
@@ -189,6 +200,31 @@ void reduce_f32(float* acc, const float* in, size_t n, DpOp op) {
   }
 }
 
+// The three-operand forms an allreduce with a source reduces with: dst = a
+// (+) in, where the in-place ones compute acc (+)= in — the same operation
+// on the same operands in the same order, so the same bits. dst never
+// overlaps a or in (the destination, a caller's source, the stripe's scratch).
+void reduce3_f32(float* __restrict dst, const float* __restrict a,
+                 const float* __restrict in, size_t n, DpOp op) {
+  switch (op) {
+    case DpOp::kSum:
+    case DpOp::kAvg:  // resolved to kSum + divisor in allreduce()
+      for (size_t i = 0; i < n; ++i) dst[i] = a[i] + in[i];
+      break;
+    case DpOp::kMax:
+      for (size_t i = 0; i < n; ++i) dst[i] = nan_max(a[i], in[i]);
+      break;
+    case DpOp::kMin:
+      for (size_t i = 0; i < n; ++i) dst[i] = nan_min(a[i], in[i]);
+      break;
+  }
+}
+
+void reduce3_sum_div_f32(float* __restrict dst, const float* __restrict a,
+                         const float* __restrict in, size_t n, float div) {
+  for (size_t i = 0; i < n; ++i) dst[i] = (a[i] + in[i]) / div;
+}
+
 void reduce_from_bf16(float* acc, const uint16_t* in, size_t n, DpOp op) {
   switch (op) {
     case DpOp::kSum:
@@ -220,6 +256,29 @@ void reduce_from_int8(float* acc, const uint8_t* wire, size_t n, DpOp op) {
       for (size_t i = 0; i < n; ++i) acc[i] = nan_min(acc[i], (float)q[i] * scale);
       break;
   }
+}
+
+// Calls fn(src, off, len) for every run of the bucket's elements [first,
+// first + n) that lies inside one segment of `source` (a DataPlane::Source):
+// `src` points at the run's first element, `off` counts from `first`.
+template <class SourceT, class Fn>
+void walk_source(const SourceT& source, int64_t first, int64_t n, Fn&& fn) {
+  // the last segment that starts at or before `first` (none is empty)
+  size_t k = (size_t)(std::upper_bound(source.start.begin(),
+                                       source.start.end(), first) -
+                      source.start.begin()) - 1;
+  for (int64_t off = 0; off < n; ++k) {
+    int64_t at = first + off;
+    int64_t len = std::min(n - off, source.start[k + 1] - at);
+    fn(source.ptr[k] + (at - source.start[k]), off, len);
+    off += len;
+  }
+}
+
+// the bytes of a copy dst <- src that 4K aliasing slows: see DpAccount
+inline bool copy_aliases(const void* dst, const void* src) {
+  uintptr_t ahead = ((uintptr_t)dst - (uintptr_t)src) % 4096;
+  return ahead > 0 && ahead < 1024;
 }
 
 // poll-bounded small-message helpers now live in the shared stripe layer
@@ -613,13 +672,18 @@ void DataPlane::enable_cma(const std::vector<int64_t>& pids) {
 }
 
 // CMA hop: descriptors and acks ride the stripe socket; the payload is
-// pulled straight from the left neighbor's address space. Message flow per
+// pulled straight from the left neighbor's address space — from the pieces
+// its descriptor names (`offer`: one range of the op's buffer, or the
+// pieces of an op's source), in as few process_vm_readv calls as their
+// number allows: a call costs ~0.2 ms on the benchmark's host whatever it
+// moves (PERF.md §6, PR 39: pulling 256 KB at a time tripled the pulls'
+// seconds). Message flow per
 // socket direction is clean: descs flow rank→right, acks flow reader→owner
 // (so on my left socket I read descs and write acks; on my right socket I
 // write descs and read acks) — with world=2 both are the same fd and the
 // peer's desc→ack send order keeps the stream unambiguous.
-bool DataPlane::cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf,
-                        size_t sn, uint8_t* rbuf, size_t rn, uint32_t tag,
+bool DataPlane::cma_hop(int send_fd, int recv_fd, const DpSegment* offer,
+                        int noffer, uint8_t* rbuf, size_t rn, uint32_t tag,
                         int64_t deadline_ms, bool* send_failed,
                         bool* timed_out, std::string* err, DpAccount* acct) {
   const int left = (rank_ - 1 + world_) % world_;
@@ -641,8 +705,20 @@ bool DataPlane::cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf,
       fi::parse_nth("TORCHFT_FI_CMA_TORN");
   long fi_h = 0;
   if (fi_cma_kill > 0 || fi_cma_torn.nth > 0) fi_h = ++g_fi_cma_hops;
-  CmaDesc mine{tag, (uint32_t)sn, (uint64_t)(uintptr_t)sbuf};
-  if (!send_small(send_fd, &mine, sizeof(mine), deadline_ms, timed_out, err)) {
+  // one message: the descriptor and its pieces (a stripe's own thread runs
+  // its hops, so the buffers are the thread's and allocate once)
+  thread_local std::vector<uint8_t> mine;
+  thread_local std::vector<CmaPiece> theirs;
+  mine.resize(sizeof(CmaDesc) + (size_t)noffer * sizeof(CmaPiece));
+  CmaDesc desc{tag, 0, (uint32_t)noffer, 0};
+  CmaPiece* out = (CmaPiece*)(mine.data() + sizeof(CmaDesc));
+  for (int i = 0; i < noffer; ++i) {
+    out[i] = {(uint64_t)(uintptr_t)offer[i].addr, (uint64_t)offer[i].bytes};
+    desc.len += (uint32_t)offer[i].bytes;
+  }
+  std::memcpy(mine.data(), &desc, sizeof(desc));
+  if (!send_small(send_fd, mine.data(), mine.size(), deadline_ms, timed_out,
+                  err)) {
     *send_failed = true;
     return false;
   }
@@ -652,15 +728,33 @@ bool DataPlane::cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf,
     fi::kill_self("cma.desc", fi_h);
   }
   lap();  // own descriptor sent
-  CmaDesc theirs{};
-  bool got_desc = recv_small(recv_fd, &theirs, sizeof(theirs), deadline_ms,
+  CmaDesc got{};
+  bool got_desc = recv_small(recv_fd, &got, sizeof(got), deadline_ms,
                              timed_out, err);
+  if (got_desc && (got.tag != tag || got.len != rn || got.npieces == 0 ||
+                   got.npieces > kCmaMaxPieces)) {
+    acct->desc_wait_ns += lap();
+    *err = "cma desc mismatch: tag " + std::to_string(got.tag) + "/" +
+           std::to_string(tag) + " len " + std::to_string(got.len) + "/" +
+           std::to_string(rn) + " pieces " + std::to_string(got.npieces);
+    return false;
+  }
+  if (got_desc) {
+    theirs.resize(got.npieces);
+    got_desc = recv_small(recv_fd, theirs.data(),
+                          theirs.size() * sizeof(CmaPiece), deadline_ms,
+                          timed_out, err);
+  }
   acct->desc_wait_ns += lap();
   if (!got_desc) return false;
-  if (theirs.tag != tag || theirs.len != rn) {
-    *err = "cma desc mismatch: tag " + std::to_string(theirs.tag) + "/" +
-           std::to_string(tag) + " len " + std::to_string(theirs.len) + "/" +
-           std::to_string(rn);
+  theirs.erase(std::remove_if(theirs.begin(), theirs.end(),
+                              [](const CmaPiece& p) { return p.len == 0; }),
+               theirs.end());
+  uint64_t offered = 0;
+  for (const CmaPiece& p : theirs) offered += p.len;
+  if (offered != rn) {
+    *err = "cma desc mismatch: its pieces hold " + std::to_string(offered) +
+           " of " + std::to_string(rn) + " bytes";
     return false;
   }
   size_t goal = rn;
@@ -670,17 +764,37 @@ bool DataPlane::cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf,
     goal = (size_t)((double)rn * fi_cma_torn.frac);
     fi::write_evidence("cma.pull", fi_h, "torn");
   }
+  // the cursor (piece, offset in it) walks the neighbour's pieces
+  size_t piece = 0;
+  uint64_t piece_off = 0;
   size_t off = 0;
   while (off < goal) {
     iovec lv{rbuf + off, goal - off};
-    iovec rv{(void*)(uintptr_t)(theirs.addr + off), goal - off};
-    ssize_t k = ::process_vm_readv((pid_t)peer_pids_[left], &lv, 1, &rv, 1, 0);
+    iovec rv[16];
+    int nrv = 0;
+    size_t need = goal - off;
+    for (size_t i = piece, o = piece_off; need > 0 && nrv < 16; ++i, o = 0) {
+      size_t take = std::min<uint64_t>(need, theirs[i].len - o);
+      rv[nrv++] = {(void*)(uintptr_t)(theirs[i].addr + o), take};
+      need -= take;
+    }
+    ssize_t k = ::process_vm_readv((pid_t)peer_pids_[left], &lv, 1, rv,
+                                   (unsigned long)nrv, 0);
     if (k <= 0) {
       *err = std::string("process_vm_readv: ") +
              (k == 0 ? "zero read" : errno_str(errno));
       break;
     }
     off += (size_t)k;
+    for (uint64_t moved = (uint64_t)k; moved > 0;) {  // advance the cursor
+      uint64_t step = std::min(moved, theirs[piece].len - piece_off);
+      piece_off += step;
+      moved -= step;
+      if (piece_off == theirs[piece].len) {
+        ++piece;
+        piece_off = 0;
+      }
+    }
   }
   acct->pull_ns += lap();
   acct->pull_bytes += (int64_t)off;
@@ -752,6 +866,39 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
   if (codec != DpCodec::kF32) st.scratch_fwd.resize(max_wire);
   DpAccount& acct = st.acct;  // see DpAccount (dataplane.h)
 
+  // With a source (see allreduce) nothing of this rank's contribution is
+  // in `flat` yet: the stripe copies what the schedule sends before it
+  // reduces it, and reads the rest where it lies.
+  const Source* source = job.source;
+  auto copy_from_source = [&](int64_t at, int64_t cnt) {
+    int64_t t0 = lathist::now_ns();
+    walk_source(*source, job.first + at, cnt,
+                [&](const float* src, int64_t off, int64_t len) {
+                  float* dst = flat + at + off;
+                  std::memcpy(dst, src, (size_t)len * 4);
+                  acct.copy_bytes += len * 4;
+                  if (copy_aliases(dst, src)) acct.copy_aliased_bytes += len * 4;
+                });
+    acct.copy_ns += lathist::now_ns() - t0;
+  };
+  // step 0 sends this rank's own chunk raw, the one chunk no reduce step
+  // ever writes: plane cma offers the source's pieces of it as they lie
+  std::vector<DpSegment> own_pieces;
+  if (source != nullptr && codec != DpCodec::kF32) {
+    // a lossy codec encodes the partial sums it reads back from `flat`
+    // hop after hop: all of the stripe first, then in place as ever
+    copy_from_source(0, n);
+    source = nullptr;
+  } else if (source != nullptr && !use_cma) {
+    // the pump sends from `flat`
+    copy_from_source(bounds[rank_], (int64_t)chunk_n(rank_));
+  } else if (source != nullptr) {
+    walk_source(*source, job.first + bounds[rank_], (int64_t)chunk_n(rank_),
+                [&](const float* src, int64_t, int64_t len) {
+                  own_pieces.push_back({src, len * 4});
+                });
+  }
+
   auto prep_send = [&](int idx) -> std::pair<const uint8_t*, size_t> {
     size_t cn = chunk_n(idx);
     if (codec == DpCodec::kF32) {
@@ -773,15 +920,22 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
 
   bool send_failed = false;
   bool timed_out = false;
-  auto do_hop = [&](const uint8_t* sb, size_t sn, uint8_t* rb, size_t rn) {
+  auto do_hop = [&](const uint8_t* sb, size_t sn, uint8_t* rb, size_t rn,
+                    const std::vector<DpSegment>* pieces = nullptr) {
+    // what the right neighbour is offered: sb[0:sn], or (plane cma, step
+    // 0 of an op with a source) the pieces of the source that hold it
+    DpSegment whole{sb, (int64_t)sn};
+    const bool in_pieces = pieces != nullptr && !pieces->empty();
     // per-hop latency histogram (full-duplex send+recv pump — the wait
     // for a slow left neighbor lands here, which is what makes the
     // distribution a straggler lens); failed hops record too: a
     // deadline'd hop's duration is exactly the evidence wanted
     int64_t t0 = lathist::now_ns();
-    bool ok = use_cma ? cma_hop(send_fd, recv_fd, sb, sn, rb, rn, job.tag,
-                                job.deadline_ms, &send_failed, &timed_out, err,
-                                &acct)
+    bool ok = use_cma ? cma_hop(send_fd, recv_fd,
+                                in_pieces ? pieces->data() : &whole,
+                                in_pieces ? (int)pieces->size() : 1, rb, rn,
+                                job.tag, job.deadline_ms, &send_failed,
+                                &timed_out, err, &acct)
                       : hop(send_fd, recv_fd, sb, sn, rb, rn, job.tag,
                             job.deadline_ms, &send_failed, &timed_out, err);
     int64_t hop_ns = lathist::now_ns() - t0;
@@ -818,9 +972,14 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
   for (int step = 0; step < world_ - 1; ++step) {
     int send_idx = ((rank_ - step) % world_ + world_) % world_;
     int recv_idx = ((rank_ - step - 1) % world_ + world_) % world_;
-    auto [sb, sn] = prep_send(send_idx);
+    const bool from_pieces = step == 0 && !own_pieces.empty();
+    auto [sb, sn] = from_pieces
+                        ? std::pair<const uint8_t*, size_t>{nullptr,
+                                                            chunk_n(send_idx) * 4}
+                        : prep_send(send_idx);
     size_t rn = wire_nbytes(codec, chunk_n(recv_idx));
-    if (!do_hop(sb, sn, st.scratch_recv.data(), rn)) {
+    if (!do_hop(sb, sn, st.scratch_recv.data(), rn,
+                from_pieces ? &own_pieces : nullptr)) {
       return fail();
     }
     int64_t reduce_t0 = lathist::now_ns();
@@ -835,19 +994,33 @@ int DataPlane::run_stripe(int stripe_idx, Job& job, int* bad_peer,
                          chunk_n(recv_idx), job.op);
         break;
       case DpCodec::kF32:
-      default:
+      default: {
         // recv_idx of the last step is the chunk this rank owns: its
         // next write is the final value, so the divisor goes in here
-        if (div != 1.0f && step == world_ - 2) {
-          reduce_sum_div_f32(chunk_ptr(recv_idx),
-                             (const float*)st.scratch_recv.data(),
-                             chunk_n(recv_idx), div);
+        const bool final_value = div != 1.0f && step == world_ - 2;
+        const float* pulled = (const float*)st.scratch_recv.data();
+        if (source != nullptr) {
+          // the chunk's first (and only) reduce: own operand from the source
+          walk_source(*source, job.first + bounds[recv_idx],
+                      (int64_t)chunk_n(recv_idx),
+                      [&](const float* own, int64_t off, int64_t len) {
+                        float* dst = chunk_ptr(recv_idx) + off;
+                        if (final_value) {
+                          reduce3_sum_div_f32(dst, own, pulled + off,
+                                              (size_t)len, div);
+                        } else {
+                          reduce3_f32(dst, own, pulled + off, (size_t)len,
+                                      job.op);
+                        }
+                      });
+        } else if (final_value) {
+          reduce_sum_div_f32(chunk_ptr(recv_idx), pulled, chunk_n(recv_idx),
+                             div);
         } else {
-          reduce_f32(chunk_ptr(recv_idx),
-                     (const float*)st.scratch_recv.data(), chunk_n(recv_idx),
-                     job.op);
+          reduce_f32(chunk_ptr(recv_idx), pulled, chunk_n(recv_idx), job.op);
         }
         break;
+      }
     }
     acct.reduce_ns += lathist::now_ns() - reduce_t0;
     acct.reduce_bytes += (int64_t)chunk_n(recv_idx) * 4;
@@ -957,9 +1130,10 @@ void DataPlane::worker_loop(int stripe_idx) {
   }
 }
 
-int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
-                         int divisor, DpCodec codec, uint32_t tag,
-                         int64_t timeout_ms, int* bad_peer, std::string* err) {
+int DataPlane::allreduce(void* data, int64_t nelems, const DpSegment* source,
+                         int nsegments, DpDtype dtype, DpOp op, int divisor,
+                         DpCodec codec, uint32_t tag, int64_t timeout_ms,
+                         int* bad_peer, std::string* err) {
   *bad_peer = -1;
   last_account_ = DpAccount{};
   // AVG is SUM with the divisor `world`: one code path
@@ -980,7 +1154,31 @@ int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
     *err = "unsupported wire codec";
     return -1;
   }
+  source_.ptr.clear();
+  source_.start.assign(1, 0);
+  for (int i = 0; i < nsegments; ++i) {
+    if (source[i].bytes < 0 || source[i].bytes % 4 != 0) {
+      *err = "a source segment is whole f32s";
+      return -1;
+    }
+    if (source[i].bytes == 0) continue;
+    source_.ptr.push_back((const float*)source[i].addr);
+    source_.start.push_back(source_.start.back() + source[i].bytes / 4);
+  }
+  const bool from_source = nsegments > 0;
+  if (from_source && source_.start.back() != nelems) {
+    *err = "the source's segments hold " + std::to_string(source_.start.back()) +
+           " elements, the destination " + std::to_string(nelems);
+    return -1;
+  }
+  last_account_.from_source = from_source;
   if (world_ <= 1) {
+    if (from_source && nelems > 0) {
+      walk_source(source_, 0, nelems,
+                  [&](const float* src, int64_t off, int64_t len) {
+                    std::memcpy((float*)data + off, src, (size_t)len * 4);
+                  });
+    }
     if (divisor != 1) divide_f32((float*)data, (size_t)nelems, (float)divisor);
     return 0;
   }
@@ -996,6 +1194,8 @@ int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
     std::lock_guard<std::mutex> g(st.mu);
     st.job.base = (uint8_t*)((float*)data + sb[s]);
     st.job.nelems = sb[s + 1] - sb[s];
+    st.job.source = from_source ? &source_ : nullptr;
+    st.job.first = sb[s];
     st.job.op = op;
     st.job.divisor = divisor;
     st.job.codec = codec;
@@ -1040,9 +1240,11 @@ int DataPlane::allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
   // job (shutdown) adds the zeros it was handed
   static constexpr int64_t DpAccount::*kTimes[] = {
       &DpAccount::desc_wait_ns, &DpAccount::pull_ns,   &DpAccount::ack_wait_ns,
-      &DpAccount::pump_ns,      &DpAccount::reduce_ns, &DpAccount::codec_ns};
+      &DpAccount::pump_ns,      &DpAccount::reduce_ns, &DpAccount::codec_ns,
+      &DpAccount::copy_ns};
   static constexpr int64_t DpAccount::*kBytes[] = {
-      &DpAccount::pull_bytes, &DpAccount::pump_bytes, &DpAccount::reduce_bytes};
+      &DpAccount::pull_bytes, &DpAccount::pump_bytes, &DpAccount::reduce_bytes,
+      &DpAccount::copy_bytes, &DpAccount::copy_aliased_bytes};
   DpAccount& a = last_account_;
   a.stripes = ns;
   for (int s = 0; s < ns; ++s) {
@@ -1107,7 +1309,11 @@ extern "C" {
 // v9: tft_dp_last_account added (the ring's account of its last allreduce:
 // DpAccount, dataplane.h) — a stale build would fail the loader's symbol
 // lookup at import.
-int tft_abi_version() { return 9; }
+// v10: tft_dp_allreduce_from added (an allreduce that reads this rank's
+// contribution from read-only segments), and DpAccount grew copy_ns,
+// copy_bytes, copy_aliased_bytes and from_source — a stale build would fail
+// the symbol lookup, or hand tft_dp_last_account's caller a short account.
+int tft_abi_version() { return 10; }
 
 int64_t tft_dp_create(int rank, int world, int nstripes, char* err,
                       int errlen) {
@@ -1168,22 +1374,40 @@ int tft_dp_enable_cma(int64_t h, const int64_t* pids, int n, char* err,
   return 0;
 }
 
-int tft_dp_allreduce(int64_t h, void* data, int64_t nelems, int dtype, int op,
-                     int divisor, int codec, uint32_t tag, int64_t timeout_ms,
-                     int* bad_peer, char* err, int errlen) {
+// `data` ends as the allreduce of what the `nsegments` read-only segments
+// hold (`seg_addrs[i]`, `seg_bytes[i]`: their concatenation is this rank's
+// nelems f32); nsegments 0 is the in-place op on `data`. The segments are
+// never written and must outlive the call (DataPlane::allreduce).
+int tft_dp_allreduce_from(int64_t h, void* data, int64_t nelems,
+                          const uint64_t* seg_addrs, const int64_t* seg_bytes,
+                          int nsegments, int dtype, int op, int divisor,
+                          int codec, uint32_t tag, int64_t timeout_ms,
+                          int* bad_peer, char* err, int errlen) {
   auto dp = dp_get(h);
   if (!dp) {
     dp_set_err(err, errlen, "bad handle");
     return -1;
   }
+  std::vector<tft::DpSegment> source((size_t)(nsegments > 0 ? nsegments : 0));
+  for (size_t i = 0; i < source.size(); ++i) {
+    source[i] = {(const void*)(uintptr_t)seg_addrs[i], seg_bytes[i]};
+  }
   std::string e;
   int bp = -1;
-  int rc = dp->allreduce(data, nelems, (tft::DpDtype)dtype, (tft::DpOp)op,
-                         divisor, (tft::DpCodec)codec, tag, timeout_ms, &bp,
-                         &e);
+  int rc = dp->allreduce(data, nelems, source.data(), (int)source.size(),
+                         (tft::DpDtype)dtype, (tft::DpOp)op, divisor,
+                         (tft::DpCodec)codec, tag, timeout_ms, &bp, &e);
   if (bad_peer) *bad_peer = bp;
   if (rc != 0) dp_set_err(err, errlen, e);
   return rc;
+}
+
+int tft_dp_allreduce(int64_t h, void* data, int64_t nelems, int dtype, int op,
+                     int divisor, int codec, uint32_t tag, int64_t timeout_ms,
+                     int* bad_peer, char* err, int errlen) {
+  return tft_dp_allreduce_from(h, data, nelems, nullptr, nullptr, 0, dtype, op,
+                               divisor, codec, tag, timeout_ms, bad_peer, err,
+                               errlen);
 }
 
 // The account of the plane's last allreduce as int64s in DpAccount's order;

@@ -48,7 +48,12 @@ enum class DpCodec : int { kF32 = 0, kBf16 = 1, kInt8 = 2 };
 // tell waiting from moving, so it has the one number pump. Both: reduce
 // (the reduce_* passes over scratch after a hop) and codec (encode / decode
 // passes; 0 on cma, whose payload stays f32). Bytes are what was pulled,
-// received by the pump, and reduced (f32 bytes written).
+// received by the pump, and reduced (f32 bytes written). An allreduce
+// with a source (see allreduce) adds copy: the stripe's memcpy of own
+// elements from the source into the destination (plane tcp and the lossy
+// codecs; plane cma copies nothing), its bytes, and those of them whose
+// destination lies 1 to 1023 B ahead of their source modulo 4096 (the window
+// of 4K aliasing, ddp.py's _ALIAS_WINDOW_B); from_source is 1 for such an op.
 // The op's account (last_account) is the MEAN over its stripes of each time
 // — stripes run in parallel, so the mean compares with the caller's wall
 // seconds — the SUM over stripes of each byte count, the number of stripes,
@@ -66,10 +71,20 @@ struct DpAccount {
   int64_t reduce_bytes = 0;
   int64_t stripes = 0;
   int64_t slowest_stripe_ns = 0;
+  int64_t copy_ns = 0;
+  int64_t copy_bytes = 0;
+  int64_t copy_aliased_bytes = 0;
+  int64_t from_source = 0;
 };
-constexpr int kDpAccountFields = 11;
+constexpr int kDpAccountFields = 15;
 static_assert(sizeof(DpAccount) == kDpAccountFields * sizeof(int64_t),
               "DpAccount is kDpAccountFields int64s, in the ABI's order");
+
+// One read-only piece of an allreduce's source (see DataPlane::allreduce).
+struct DpSegment {
+  const void* addr;
+  int64_t bytes;
+};
 
 class DataPlane {
  public:
@@ -92,7 +107,7 @@ class DataPlane {
   bool wait_ready(int64_t timeout_ms, std::string* err);
 
   // Switch payload transport to cross-memory attach (process_vm_readv):
-  // ring hops exchange tiny {tag,len,addr} descriptors + acks over the
+  // ring hops exchange tiny {tag,len,pieces} descriptors + acks over the
   // stripe sockets and pull the payload straight out of the left
   // neighbor's address space — one copy at memcpy speed, no loopback-TCP
   // syscall tax. Caller (Python rendezvous) must have verified every rank
@@ -117,8 +132,24 @@ class DataPlane {
   // (float)divisor, bit for bit np.divide(sum, divisor). The caller
   // names the divisor because it is not always `world`: the Manager
   // counts participants, and a healing group's zeros are not one.
-  int allreduce(void* data, int64_t nelems, DpDtype dtype, DpOp op,
-                int divisor, DpCodec codec, uint32_t tag, int64_t timeout_ms,
+  // With a source (`nsegments` > 0: read-only segments whose concatenation
+  // is the nelems f32 this rank contributes) the op is no longer in place:
+  // `data` is a destination whose content on entry is ignored, and the ring
+  // reads this rank's contribution from the segments — each element exactly
+  // once: every reduce step writes data = source (+) pulled, and the one
+  // chunk a stripe sends raw, at step 0, goes out as it lies — plane cma
+  // offers the right neighbour the segments' own pieces of it to pull
+  // (nothing is copied: the allgather writes that chunk of `data` last),
+  // plane tcp copies it into `data` first, by the stripe's own thread
+  // (1/world of the stripe), so that its pump sends from `data` as ever.
+  // The same additions in the same order on the same values: `data` ends
+  // bit for bit as the in-place op on a packed copy would leave it. A lossy
+  // codec re-reads partial sums it wrote, so there the stripe copies its
+  // whole range first and runs in place (job.codec decides). The segments
+  // are never written and must stay alive until allreduce() returns.
+  int allreduce(void* data, int64_t nelems, const DpSegment* source,
+                int nsegments, DpDtype dtype, DpOp op, int divisor,
+                DpCodec codec, uint32_t tag, int64_t timeout_ms,
                 int* bad_peer, std::string* err);
 
   // The account of the last allreduce() on this plane, failed ones too (an
@@ -129,9 +160,17 @@ class DataPlane {
   void shutdown();
 
  private:
+  // An op's source as the stripes read it: each segment's first element
+  // and its element offset in the bucket (`start` holds one more: the end).
+  struct Source {
+    std::vector<const float*> ptr;
+    std::vector<int64_t> start;
+  };
   struct Job {
     uint8_t* base = nullptr;   // stripe start
     int64_t nelems = 0;        // stripe elements
+    const Source* source = nullptr;  // null: in place
+    int64_t first = 0;         // the stripe's first element in the bucket
     DpOp op = DpOp::kSum;
     int divisor = 1;           // see allreduce()
     DpCodec codec = DpCodec::kF32;
@@ -165,7 +204,7 @@ class DataPlane {
   bool hop(int send_fd, int recv_fd, const uint8_t* sbuf, size_t sn,
            uint8_t* rbuf, size_t rn, uint32_t tag, int64_t deadline_ms,
            bool* send_failed, bool* timed_out, std::string* err);
-  bool cma_hop(int send_fd, int recv_fd, const uint8_t* sbuf, size_t sn,
+  bool cma_hop(int send_fd, int recv_fd, const DpSegment* offer, int noffer,
                uint8_t* rbuf, size_t rn, uint32_t tag, int64_t deadline_ms,
                bool* send_failed, bool* timed_out, std::string* err,
                DpAccount* acct);
@@ -186,6 +225,9 @@ class DataPlane {
 
   std::vector<std::unique_ptr<Stripe>> stripes_;
   DpAccount last_account_;  // allreduce()'s caller thread only
+  // the running op's source: written by allreduce() before it hands the
+  // jobs over, read by the stripes until it has seen them all done
+  Source source_;
 
   // atomic publication flag: enable_cma() runs on the Python control
   // thread AFTER the stripe workers (started in the constructor) are

@@ -138,7 +138,8 @@ def account(path: str) -> dict:
             step["pack_dst_ahead_b"] = ahead
         for s, stats in counters:
             if lo <= s <= hi + 1e-3:
-                for k in ("buckets", "buckets_reused", "buckets_avg_in_ring", "pack_aliased_bytes",
+                for k in ("buckets", "buckets_reused", "buckets_avg_in_ring", "buckets_from_source",
+                          "pack_s", "pack_bytes", "pack_aliased_bytes", "tail_wait_s",
                           "ring_wait_s", "ring_pull_s", "ring_reduce_s"):
                     if k in stats:
                         step[k] = stats[k]

@@ -48,7 +48,7 @@ PER_BUCKET = ("exchange.d2h_wait", "exchange.pack", "exchange.submit", "exchange
 EXCHANGE_SUMS = {
     "step", "buckets", "buckets_reused", "buckets_avg_in_ring", "d2h_pages_kept", "bytes_d2h",
     "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
-    "pack_bytes", "pack_aliased_bytes", "h2d_bytes",
+    "pack_bytes", "pack_aliased_bytes", "h2d_bytes", "buckets_from_source",
     "ring_wait_s", "ring_pull_s", "ring_reduce_s", "ring_pump_s", "ring_pull_bytes", "ring_reduce_bytes",
 }
 

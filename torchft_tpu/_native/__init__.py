@@ -10,7 +10,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Sequence, Tuple
 
 from torchft_tpu.utils import wire
 
@@ -56,7 +56,10 @@ _TIMEOUT_CODES = (CANCELLED, DEADLINE_EXCEEDED)
 #     inside the ring) — an old build would read it as the codec.
 # v9: tft_dp_last_account (the ring's account of its last allreduce) — an
 #     old build would fail the loader's symbol lookup at import.
-_ABI_VERSION = 9
+# v10: tft_dp_allreduce_from (an allreduce that reads this rank's
+#     contribution from read-only segments) and four more fields of the
+#     account (copy_ns, copy_bytes, copy_aliased_bytes, from_source).
+_ABI_VERSION = 10
 
 
 def _build(force: bool = False) -> None:
@@ -268,6 +271,12 @@ def _load() -> ctypes.CDLL:
         c.c_uint32, c.c_int64, c.POINTER(c.c_int), c.c_char_p, c.c_int,
     ]
     lib.tft_dp_allreduce.restype = c.c_int
+    lib.tft_dp_allreduce_from.argtypes = [
+        c.c_int64, c.c_void_p, c.c_int64, c.POINTER(c.c_uint64),
+        c.POINTER(c.c_int64), c.c_int, c.c_int, c.c_int, c.c_int, c.c_int,
+        c.c_uint32, c.c_int64, c.POINTER(c.c_int), c.c_char_p, c.c_int,
+    ]
+    lib.tft_dp_allreduce_from.restype = c.c_int
     lib.tft_dp_last_account.argtypes = [
         c.c_int64, c.POINTER(c.c_int64), c.c_int
     ]
@@ -715,7 +724,8 @@ class NativeDataPlane:
     ACCOUNT = (
         "desc_wait_ns", "pull_ns", "ack_wait_ns", "pump_ns", "reduce_ns",
         "codec_ns", "pull_bytes", "pump_bytes", "reduce_bytes", "stripes",
-        "slowest_stripe_ns",
+        "slowest_stripe_ns", "copy_ns", "copy_bytes", "copy_aliased_bytes",
+        "from_source",
     )
 
     def __init__(self, rank: int, world: int, nstripes: int = 4) -> None:
@@ -763,6 +773,7 @@ class NativeDataPlane:
         tag: int = 0,
         timeout_ms: int = 60000,
         divisor: int = 1,
+        source: "Sequence[tuple[int, int]] | None" = None,
     ) -> None:
         """In-place f32 ring allreduce on the buffer at ``ptr``. Blocking —
         call from the collectives op thread; the GIL is released.
@@ -772,15 +783,28 @@ class NativeDataPlane:
         ``divisor`` (``"sum"`` only) makes the sum an average inside the
         ring — each element divided once, where its final f32 value is
         written, bit for bit ``np.divide(sum, divisor)``; ``"avg"`` is
-        ``"sum"`` with the divisor ``world``."""
+        ``"sum"`` with the divisor ``world``.
+        With ``source`` — ``(address, nbytes)`` of read-only segments whose
+        concatenation is this rank's ``nelems`` f32 — the op is not in
+        place: the ring reads its contribution from the segments and only
+        writes the buffer at ``ptr``, which ends bit for bit as the
+        in-place op on a packed copy would leave it. The caller keeps the
+        segments' memory alive until this returns."""
         err = _errbuf()
         bad_peer = ctypes.c_int(-1)
         codec_i = self.CODEC[codec] if isinstance(codec, str) else int(codec)
-        rc = _lib.tft_dp_allreduce(
-            self._h, ptr, nelems, self.DTYPE_F32, self.OP[op], int(divisor),
-            codec_i, tag, timeout_ms,
-            ctypes.byref(bad_peer), err, _ERRLEN,
+        tail = (
+            self.DTYPE_F32, self.OP[op], int(divisor), codec_i, tag,
+            timeout_ms, ctypes.byref(bad_peer), err, _ERRLEN,
         )
+        if source is None:
+            rc = _lib.tft_dp_allreduce(self._h, ptr, nelems, *tail)
+        else:
+            addrs = (ctypes.c_uint64 * len(source))(*(a for a, _ in source))
+            sizes = (ctypes.c_int64 * len(source))(*(n for _, n in source))
+            rc = _lib.tft_dp_allreduce_from(
+                self._h, ptr, nelems, addrs, sizes, len(source), *tail
+            )
         if rc == -2:
             # deadline, no peer named: slow-but-alive must be retryable,
             # never an eviction-worthy accusation

@@ -36,7 +36,7 @@ from abc import ABC, abstractmethod
 from concurrent.futures import ThreadPoolExecutor
 from datetime import timedelta
 from enum import Enum
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -168,11 +168,37 @@ def average_in_place(arrays: List[np.ndarray], divisor: int) -> None:
 # mean compares with ``tft.exchange.ring``'s wall seconds), bytes their sum;
 # native/dataplane.h's DpAccount says where each starts and ends. Plane cma
 # fills desc_wait / pull / ack_wait, plane tcp and the Python ring pump — a
-# full-duplex pump cannot tell waiting from moving.
+# full-duplex pump cannot tell waiting from moving. An allreduce with sources
+# adds copy: own elements copied from a source into its array (by the native
+# ring's stripes on plane tcp, a quarter of them at world 4, none on plane
+# cma; all of them by :func:`fill_from_sources` where the native ring does
+# not run the array),
+# the copies' bytes, those inside the 4K-aliasing window, and from_source:
+# the arrays whose contribution the native ring read from their source.
 RING_ACCOUNT = (
     "desc_wait_s", "pull_s", "ack_wait_s", "pump_s", "reduce_s", "codec_s",
     "slowest_stripe_s", "pull_bytes", "pump_bytes", "reduce_bytes", "stripes",
+    "copy_s", "copy_bytes", "copy_aliased_bytes", "from_source",
 )
+
+# One array's source: arrays whose elements, one after the other, are what
+# the array is to contribute. See :meth:`Collectives.allreduce`.
+Source = Sequence[np.ndarray]
+
+
+def fill_from_sources(
+    arrays: Sequence[np.ndarray], sources: Optional[Sequence[Optional[Source]]]
+) -> None:
+    """Copy each array's source into it: what an allreduce with sources
+    amounts to wherever the reduction is in place (every path but the
+    native ring's)."""
+    for arr, source in zip(arrays, sources or ()):
+        if source is None:
+            continue
+        flat, off = _flat_view(arr), 0
+        for piece in source:
+            np.copyto(flat[off : off + piece.size].reshape(piece.shape), piece)
+            off += piece.size
 
 
 class Work:
@@ -214,6 +240,15 @@ class Collectives(ABC):
         divisor: int = 1,
     ) -> Work:
         """In-place allreduce of each array; future resolves to the list.
+
+        A backend that answers :meth:`takes_sources` also takes
+        ``sources``: per array ``None`` or a :data:`Source`, C-contiguous
+        arrays of the array's dtype and together of its size, whose
+        elements are the array's contribution in place of what it holds.
+        They are only ever read, and the backend keeps them alive for as
+        long as any of its threads may read them — longer than the
+        returned future, which a deadline can complete early. The array
+        ends as if the sources had been copied into it first.
 
         ``divisor`` (with ``SUM`` only) makes the sum an average: every
         element of the result is the sum divided ONCE by ``divisor`` in
@@ -283,6 +318,13 @@ class Collectives(ABC):
         :class:`~torchft_tpu.wire_codec.ErrorFeedback` compensates for;
         wrappers must delegate to the inner backend."""
         return "f32"
+
+    def takes_sources(self) -> bool:
+        """Whether :meth:`allreduce` takes ``sources`` and has a reduction
+        that reads them where they lie instead of a packed copy (the
+        native ring). ``ddp`` asks before it skips its pack; wrappers must
+        delegate, and a backend that says no is never handed any."""
+        return False
 
     def avg_in_ring_ops(self) -> int:
         """How many allreduces this backend has completed whose
@@ -828,6 +870,11 @@ class CollectivesTcp(Collectives):
             return "f32"
         return self._codec.name
 
+    def takes_sources(self) -> bool:
+        from torchft_tpu._native import NativeDataPlane
+
+        return self._dp is not None and self._codec.name in NativeDataPlane.CODEC
+
     def avg_in_ring_ops(self) -> int:
         return self._avg_in_ring_ops
 
@@ -1350,8 +1397,20 @@ class CollectivesTcp(Collectives):
         arrays: List[np.ndarray],
         op: ReduceOp = ReduceOp.SUM,
         divisor: int = 1,
+        sources: Optional[Sequence[Optional[Source]]] = None,
     ) -> Work:
         world = self._world
+        if sources is None:
+            sources = [None] * len(arrays)
+        for arr, source in zip(arrays, sources):
+            # the native ring is handed bare addresses
+            for piece in source or ():
+                if piece.dtype != arr.dtype or not piece.flags.c_contiguous:
+                    raise ValueError(
+                        "allreduce: a source is C-contiguous arrays of its "
+                        f"array's dtype ({arr.dtype}); got {piece.dtype}, "
+                        f"strides {piece.strides}"
+                    )
         op, divisor = resolve_divisor(op, divisor, world)
         tag = self._next_tag() | 0x01000000
         nbytes = sum(int(a.nbytes) for a in arrays)
@@ -1375,19 +1434,25 @@ class CollectivesTcp(Collectives):
                 "exchange.ring", bytes=nbytes, queued_s=t0 - t_submit,
                 divisor=divisor if in_ring else 0,
             ):
-                if world > 1:
-                    # ops are serialized on the op thread, so arrays of one
-                    # allreduce may share the tag (it is a desync check, not
-                    # a demultiplexer; the native plane offsets per-stripe)
-                    try:
-                        for arr, dp in zip(arrays, native):
-                            if dp:
-                                self._dp_allreduce(arr, op, tag, divisor, account)
-                            else:
+                # ops are serialized on the op thread, so arrays of one
+                # allreduce may share the tag (it is a desync check, not
+                # a demultiplexer; the native plane offsets per-stripe)
+                try:
+                    for arr, dp, source in zip(arrays, native, sources):
+                        if dp:
+                            self._dp_allreduce(
+                                arr, op, tag, divisor, account, source
+                            )
+                        else:
+                            self._fill(arr, source, account)
+                            if world > 1:
                                 self._ring_allreduce(arr, op, tag, account)
-                    finally:  # a failed op's account counts too
-                        for k, v in account.items():
-                            self._ring_account[k] += v
+                finally:  # a failed op's account counts too
+                    for k, v in account.items():
+                        self._ring_account[k] += v
+            # `sources` stays referenced from this frame until here: the
+            # stripe threads are out of the ring, whatever a deadline has
+            # done to the op's future meanwhile
             # an annotation takes its stats at entry: a zero-length one
             # after the ring carries its account, the n-th of a step for
             # the step's n-th ring
@@ -1422,15 +1487,29 @@ class CollectivesTcp(Collectives):
 
         return self._codec.name in NativeDataPlane.CODEC
 
+    @staticmethod
+    def _fill(
+        arr: np.ndarray, source: Optional[Source], account: Dict[str, float]
+    ) -> None:
+        """An array the native ring does not run gets its source the plain
+        way, on the op thread, and the account says so."""
+        if source is None:
+            return
+        t0 = time.perf_counter()
+        fill_from_sources([arr], [source])
+        account["copy_s"] += time.perf_counter() - t0
+        account["copy_bytes"] += arr.nbytes
+
     def _dp_allreduce(
         self, arr: np.ndarray, op: ReduceOp, tag: int, divisor: int,
-        account: Dict[str, float],
+        account: Dict[str, float], source: Optional[Source] = None,
     ) -> None:
         """Hot path: the striped C++ ring (it applies ``divisor`` where an
         element's final value is written; the wire codec — bf16 or int8 —
         runs in C++, with the same owner-bytes verbatim allgather as the
         Python ring so the decoded average is bit-identical on every
-        rank)."""
+        rank). With a ``source`` it reads this rank's contribution from
+        there and only writes ``arr``."""
         import time as _time
 
         from torchft_tpu._native import DataPlaneError
@@ -1448,6 +1527,9 @@ class CollectivesTcp(Collectives):
                 tag,
                 int(self._timeout.total_seconds() * 1000),
                 divisor,
+                None
+                if source is None
+                else [(piece.ctypes.data, piece.nbytes) for piece in source],
             )
         except DataPlaneError as e:
             if e.peer_rank >= 0:
@@ -1830,6 +1912,9 @@ class ErrorSwallowingCollectives(Collectives):
     def wire_codec(self) -> str:
         return self._inner.wire_codec()
 
+    def takes_sources(self) -> bool:
+        return self._inner.takes_sources()
+
     def avg_in_ring_ops(self) -> int:
         return self._inner.avg_in_ring_ops()
 
@@ -1864,11 +1949,13 @@ class ErrorSwallowingCollectives(Collectives):
 
         return Work(work.get_future().then(swallow))
 
-    def allreduce(self, arrays, op=ReduceOp.SUM, divisor=1):
+    def allreduce(self, arrays, op=ReduceOp.SUM, divisor=1, sources=None):
         # a swallowed failure hands back the buffers as the failed op left
         # them — half summed, divided or not: the step never commits
+        # (sources go only to an inner backend that takes them)
+        extra = {} if sources is None else {"sources": sources}
         return self._guard(
-            lambda: self._inner.allreduce(arrays, op, divisor), arrays
+            lambda: self._inner.allreduce(arrays, op, divisor, **extra), arrays
         )
 
     def allgather(self, arr):

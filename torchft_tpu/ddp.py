@@ -18,14 +18,28 @@ transfers complete on the main thread and bucket k−1's averaged pieces
 are already being device_put back — so wire time hides behind transfer
 time instead of adding to it.
 
+Stage 1 hands the ring a bucket the cheapest way its data plane allows.
+Where the plane has a reduction that reads a contribution from where it
+lies (``manager.takes_sources()``: the native ring), a float32 bucket
+without error feedback goes as its bucket buffer — a destination — plus
+the landing arrays of its leaves as the source: the ring reads each own
+element once, where the device-to-host copy put it, and the main thread
+copies nothing (``buckets_from_source`` beside ``buckets`` on the
+``exchange`` span). Anything else — the Python ring, error feedback, which
+rewrites the packed bucket before the ring, a dtype the native ring does
+not take, a leaf that is not contiguous — is packed into the bucket buffer
+with ``np.copyto`` and reduced in place, as every bucket was before PR 39.
+
 Pipelined-commit note (docs/commit_pipeline.md): callers must resolve
 any in-flight commit vote (``manager.resolve_pending_commit()``) before
 calling :func:`allreduce_gradients` for the next step — the Manager
 raises otherwise, because gradients of a speculative (possibly about to
 be rolled back) state must never enter a collective. The bucket buffers
-here always own their memory (``np.empty``, packed with ``np.copyto``),
-so the in-place ring reduction can never corrupt the caller's retained
-gradient pytree across a rollback/replay.
+here always own their memory (``np.empty``) and are all the ring ever
+writes — the landing arrays and a caller's NumPy leaves are packed from or
+handed over as a source, and either way only read — so the ring
+reduction can never corrupt the caller's retained gradient pytree across
+a rollback/replay.
 
 The bucket buffers live as long as the bucket plan does. Mapping fresh
 host pages costs ~4 us each where writing touched ones runs at memory
@@ -59,8 +73,11 @@ NumPy pass shows as ``tft.exchange.average``).
 What the exchange spends its time on is visible from inside
 (docs/observability.md "Spans in the profiler's trace"): one ``exchange``
 span around the call, carrying the per-step sums, the process's CPU-time
-deltas, the bytes each host copy moved (with those of the pack's copies
-that sit inside the 4K-aliasing window) and what the data plane's account
+deltas, the bytes each host copy moved (``pack_*``: own gradients copied
+into bucket buffers, by the main thread's pack and by the ring itself —
+plane ``tcp`` copies the quarter it sends first when it reads a source,
+plane ``cma`` nothing — with those of the copies inside the 4K-aliasing
+window) and what the data plane's account
 of its rings grew by (waiting for a neighbour, pulling, reducing), and in
 a profiler trace one ``tft.exchange.*`` event per piece of work per
 bucket, on the thread that did it.
@@ -404,7 +421,7 @@ def _host_exchange(
     the step's sums for the ``exchange`` span."""
     import jax
 
-    from torchft_tpu.collectives import record_wire_stage
+    from torchft_tpu.collectives import fill_from_sources, record_wire_stage
     from torchft_tpu.telemetry.anatomy import LEDGER as _ledger
 
     # host path. A leaf sharded across processes (multi-host group) cannot
@@ -416,8 +433,11 @@ def _host_exchange(
 
     sums = {  # per step
         "buckets_reused": 0, "d2h_pages_kept": 0, "d2h_wait_s": 0.0, "pack_s": 0.0,
-        "pack_bytes": 0, "pack_aliased_bytes": 0,
+        "pack_bytes": 0, "pack_aliased_bytes": 0, "buckets_from_source": 0,
     }
+    # whether the data plane reads a bucket's contribution where it landed
+    # (duck-typed managers have no such reduction)
+    takes_sources = getattr(manager, "takes_sources", lambda: False)
     # the data plane's count of ops averaged inside its ring: its growth
     # over this exchange is how many buckets needed no division pass
     # (duck-typed managers have no such count)
@@ -494,25 +514,33 @@ def _host_exchange(
         with tracing.annotate("exchange.d2h_wait", **tags):
             host = [np.asarray(items[i].src) for i in idxs]
         t1 = time.perf_counter()
-        # the bucket buffer always owns its memory: the ring reduces (and
-        # non-participants zero) in place, which must never write through a
-        # view of the caller's arrays or a read-only XLA host buffer
+        # the bucket buffer always owns its memory: the ring writes (and
+        # non-participants zero) it alone, never a view of the caller's
+        # arrays or a read-only XLA host buffer
+        dtype, count = kept.key[ordinal]
         buf = kept.bufs[ordinal]
         if buf is None:
-            dtype, count = kept.key[ordinal]
             buf = np.empty(count, dtype)
         else:
             sums["buckets_reused"] += 1
+        # the landing arrays go to the ring as they lie where it can read
+        # them there and nothing rewrites the bucket on its way to the ring
+        source = (
+            host
+            if error_feedback is None
+            and dtype == np.float32
+            and all(h.flags.c_contiguous for h in host)
+            and takes_sources()
+            else None
+        )
         # an annotation takes its stats at entry, and where the copies will
-        # land is known before they run
+        # land (or the ring's reduce reads and writes) is known before
         dst_ahead_b, copied, aliased = _pack_account(buf, host)
-        sums["pack_bytes"] += copied
-        sums["pack_aliased_bytes"] += aliased
         with tracing.annotate("exchange.pack", dst_ahead_b=dst_ahead_b, **tags):
-            off = 0
-            for h in host:
-                np.copyto(buf[off : off + h.size].reshape(h.shape), h)
-                off += h.size
+            if source is None:
+                sums["pack_bytes"] += copied
+                sums["pack_aliased_bytes"] += aliased
+                fill_from_sources([buf], [host])
             # it outlives the call only if no piece of it does
             kept.bufs[ordinal] = (
                 buf if all(_put_copies(items[i].src) for i in idxs) else None
@@ -537,7 +565,10 @@ def _host_exchange(
 
         # stage 2 (op thread): quorum-managed ring allreduce of the bucket
         with tracing.annotate("exchange.submit", **tags):
-            fut = manager.allreduce_many([buf])
+            if source is None:
+                fut = manager.allreduce_many([buf])
+            else:
+                fut = manager.allreduce_many([buf], sources=[source])
 
         # dense jax leaves carry their sharding so stage 3 can start the
         # averaged piece's H2D without waiting for the whole tree
@@ -641,7 +672,13 @@ def _host_exchange(
             ring_pump_s=ring["pump_s"],
             ring_pull_bytes=ring["pull_bytes"],
             ring_reduce_bytes=ring["reduce_bytes"],
+            buckets_from_source=ring["from_source"],
         )
+        # own gradients the ring copied into a bucket buffer itself: on plane
+        # tcp the chunk it sends raw at its first step, where it read a source
+        sums["pack_s"] += ring["copy_s"]
+        sums["pack_bytes"] += ring["copy_bytes"]
+        sums["pack_aliased_bytes"] += ring["copy_aliased_bytes"]
     return out, {
         "buckets": len(plan),
         "bytes_d2h": sum(it.nbytes for it in items),

@@ -37,7 +37,7 @@ import numpy as np
 from torchft_tpu import telemetry
 from torchft_tpu.checkpointing.http_transport import HTTPTransport
 from torchft_tpu.checkpointing.transport import CheckpointTransport
-from torchft_tpu.collectives import Collectives, ReduceOp
+from torchft_tpu.collectives import Collectives, ReduceOp, fill_from_sources
 from torchft_tpu.coordination import ManagerClient, ManagerServer
 from torchft_tpu.faultinject.core import fault_point
 from torchft_tpu.futures import Future, future_timeout, run_in_executor
@@ -1408,6 +1408,14 @@ class Manager:
         — gradient averaging then skips the host round trip entirely."""
         return bool(getattr(self._collectives, "device_arrays", False))
 
+    def takes_sources(self) -> bool:
+        """Whether the data plane has a reduction that reads an array's
+        contribution from a source (``Collectives.takes_sources``): ``ddp``
+        then hands :meth:`allreduce_many` its landing arrays instead of
+        packing them."""
+        fn = getattr(self._collectives, "takes_sources", None)
+        return bool(fn()) if callable(fn) else False
+
     def avg_in_ring_ops(self) -> int:
         """The data plane's count of allreduces whose average was taken
         inside the reduction (``Collectives.avg_in_ring_ops``); ``ddp``
@@ -1436,17 +1444,27 @@ class Manager:
         scaled by ``1 / num_participants()``; see :meth:`allreduce_many`."""
         return self.allreduce_many([tensor]).then(lambda f: f.value()[0])
 
-    def allreduce_many(self, tensors: List[Any]) -> Future:
+    def allreduce_many(
+        self, tensors: List[Any], sources: Optional[List[Any]] = None
+    ) -> Future:
         """Fault-tolerant cross-replica-group allreduce of a list of
         buffers (numpy, averaged in place — or ``jax.Array``s when the data
         plane is device-path, averaged on device), scaled by
         ``1 / num_participants()``.
+
+        ``sources`` (host path; per buffer ``None`` or a
+        ``collectives.Source``) names where each buffer's contribution
+        lies in place of what the buffer holds: arrays that are only ever
+        read. A data plane that :meth:`takes_sources` reads them there;
+        for any other, and for a step that is already lost, they are
+        copied into the buffers here — the result is the same.
 
         On error the future still completes (with the possibly-corrupt
         tensors) and the error is latched — subsequent calls no-op and the
         step fails at the commit barrier. Healing/spare replicas contribute
         zeros so the participants' average is unperturbed."""
         if not tensors or self.errored():
+            fill_from_sources(tensors, sources)
             return Future.completed(tensors)
 
         if self._pending_commit is not None:
@@ -1468,6 +1486,7 @@ class Manager:
             # whose peers aborted — a full op-timeout of dead wait before
             # the inevitable abort (observed in the stripe_heal_peer_death
             # bring-up: +30s per step on the healer)
+            fill_from_sources(tensors, sources)
             return Future.completed(tensors)
         # record which plane epoch this op rides: a death-watch re-quorum
         # can land MID-step, and a step whose ops span two epochs mixes
@@ -1502,6 +1521,10 @@ class Manager:
             else:
                 for t in tensors:
                     t[...] = 0  # in place: host buffers are bucket views
+            sources = None  # zeros are the whole of its contribution
+        elif sources is not None and not self.takes_sources():
+            fill_from_sources(tensors, sources)
+            sources = None
 
         # snapshot this epoch's rank→replica map: an in-flight op can fail
         # AFTER the next quorum has renumbered ranks, and a PeerGoneError
@@ -1515,8 +1538,11 @@ class Manager:
             # native ring: in the owner's last reduce step, no pass of its
             # own on the op thread the step waits for). Device path: one
             # jitted divide with n traced, so membership never recompiles
+            # (sources: only to a backend that said it takes them)
+            extra = {} if sources is None else {"sources": sources}
             work = self._collectives.allreduce(
-                tensors, ReduceOp.SUM, divisor=1 if device else max(n_at_issue, 1)
+                tensors, ReduceOp.SUM,
+                divisor=1 if device else max(n_at_issue, 1), **extra,
             )
 
             def normalize(fut: Future) -> List[Any]:
