@@ -620,6 +620,39 @@ def test_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, shape):
     assert text.count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize(
+    "batch, heads, window", [(2, 64, 512), (2, 48, None), (1, 64, 512), (1, 48, None)], ids=str
+)
+def test_banded_and_grouped_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch, heads, window):
+    """The two kinds of layer of `laguna-xs2-1g.fused-s8192` (PR 41) — 64 query
+    heads over 8 key/value heads under a band of 512, 48 over 8 over the whole
+    prefix — at s8192 and 512 x 512 tiles, the cell's batch and the reference
+    check's batch 1, forward and backward through Mosaic for a described v5e
+    (in this file: one process a run may load the TPU's library)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    q = jax.ShapeDtypeStruct((batch, 8192, heads, 128), jnp.bfloat16, sharding=one_v5e_chip)
+    kv = jax.ShapeDtypeStruct((batch, 8192, 8, 128), jnp.bfloat16, sharding=one_v5e_chip)
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True, block_q=512, block_k=512, interpret=False, window=window)
+            return jnp.sum(o.astype(jnp.float32))
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(step).lower(q, kv, kv).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    dq, dk, dv = jax.tree_util.tree_leaves(compiled.out_info)[1:]
+    assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape  # dK, dV summed over the group in the kernel
+
+
 @pytest.mark.parametrize("batch, seq, heads", [(2, 1024, 32), (1, 1024, 8), (1, 128, 2)], ids=str)
 def test_the_kda_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch, seq, heads):
     """``ops/pallas/kda.py``, forward and backward, through Mosaic for a

@@ -213,6 +213,27 @@ def test_the_tpu_kernel_and_the_xla_form_are_the_same_grouped_matmul():
     assert max(grad_errors(got, want)) < RTOL
 
 
+@pytest.mark.parametrize("dtype,tile", [(jnp.bfloat16, (512, 1024, 1024)), (jnp.float32, (512, 512, 512))])
+def test_the_kernels_tile_is_sized_in_bytes(monkeypatch, dtype, tile):
+    """The swept tile is for 2-byte operands; float32 ones take half the
+    contraction and half the columns (at the full tile the chip's compiler
+    refuses the kernel for VMEM: the float32 program of
+    ``benchmark/check_laguna.py``, PR 41)."""
+    from jax.experimental.pallas.ops.tpu import megablox
+    from torchft_tpu.ops.layers import _grouped_matmul_tpu
+
+    asked = []
+
+    def recorder(rows, w, counts, preferred_element_type, tiling, interpret):
+        asked.append(tiling)
+        return jax.lax.ragged_dot(rows, w, counts)
+
+    monkeypatch.setattr(megablox, "gmm", recorder)
+    counts = jnp.asarray([512, 512], jnp.int32)
+    _grouped_matmul_tpu(jnp.ones((1024, 2048), dtype), jnp.ones((2, 2048, 1024), dtype), counts)
+    assert asked == [tile]
+
+
 def test_two_shapes_in_one_program_trace_with_cache_miss_explanations_on():
     """``benchmark/run.py`` sets JAX_EXPLAIN_CACHE_MISSES; jax 0.9.0's explanation
     raises on the second shape of a ``platform_dependent`` branch it has seen

@@ -18,9 +18,12 @@ HBM both scale O(1) in depth.
 
 A model whose layers are not all of one kind DECLARES its pattern
 (``TransformerConfig.kda_layers`` / ``mla_layers`` / ``n_dense_layers``): per
-layer a sequence mixer — ``full`` softmax attention with RoPE, ``kda``
-(gated delta-rule linear attention, ``ops/kda.py``) or ``mla`` (latent
-attention without positions) — and a feed-forward, ``dense`` or ``experts``.
+layer a sequence mixer — ``full`` softmax attention with RoPE, ``window``
+(the same over a band of ``window`` keys, ``window_layers``; each of the two
+kinds with its own query heads over ``n_kv_heads`` grouped key/value heads
+and its own rotation), ``kda`` (gated delta-rule linear attention,
+``ops/kda.py``) or ``mla`` (latent attention without positions) — and a
+feed-forward, ``dense`` or ``experts``.
 Its parameters are grouped by kind of layer and the stack runs the leading
 layers one by one, then ``lax.scan`` over whole periods of the pattern with
 the period unrolled inside the body (:func:`layer_pattern`). A model of one
@@ -30,6 +33,7 @@ kind is a period of one: today's tree and today's scan.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
@@ -53,6 +57,7 @@ from torchft_tpu.ops.layers import (
     rms_norm,
     rotary_embed,
     swiglu,
+    yarn_inv_freq,
 )
 
 __all__ = [
@@ -76,7 +81,9 @@ class TransformerConfig:
     head_dim: int = 64
     d_ff: int = 1408
     n_experts: int = 0  # 0 => dense FFN
-    top_k: int = 2  # experts per token (router weights NOT renormalised)
+    # experts per token; the softmax gate applies their weights as they are,
+    # the sigmoid gate renormalises them over the chosen under ``router_renormalize``
+    top_k: int = 2
     capacity_factor: float = 1.25  # ep > 1 only: the dropless path has none
     # weight of the load-balancing term E·Σ f_e·P_e (mean over layers) in
     # the training loss; 0 => the loss is the cross entropy alone
@@ -102,7 +109,8 @@ class TransformerConfig:
     # measured there, PERF.md §6 PR 31); else the tiered chunked scan
     # (ops/attention.chunked_attention, pure XLA) from s >= 1024 and plain
     # XLA attention below; the kernel again, for memory's sake, past the
-    # scores-memory ceiling where chunked cannot run
+    # scores-memory ceiling where chunked cannot run. A layer's window and
+    # grouped key/value heads go to whichever it picks: each core takes both
     attention_impl: str = "auto"
 
     # -- a declared layer pattern. Layers are counted from 1, as published
@@ -139,16 +147,59 @@ class TransformerConfig:
     linear_head_dim: int = 0
     linear_n_heads: int = 0
     conv_kernel: int = 4
+    # -- grouped-query heads: ``full`` and ``window`` layers project keys and
+    # values to this many heads, and query head a reads key/value head
+    # a // (heads / n_kv_heads). 0 => as many as query heads
+    n_kv_heads: int = 0
+    # query heads layer by layer (entry i: layer i + 1; a published list may
+    # run past n_layers); layers of one kind agree. () => n_heads everywhere
+    n_heads_per_layer: Tuple[int, ...] = ()
+    # -- a band: the layers named here are ``window`` layers, whose position i
+    # attends to the keys j with i - window < j <= i; the others stay global
+    window: int = 0
+    window_layers: Tuple[int, ...] = ()
+    window_rope_theta: float = 0.0  # the window layers' own base; 0 => rope_theta
+    # -- the rotation. "interleaved": lane 2i with lane 2i + 1 (what this
+    # program always did); "half": lane i with lane i + r/2 inside the first r
+    # lanes. ``rotary_dim`` = r of the GLOBAL layers (0 => head_dim; window
+    # layers rotate the whole head); under it needs "half"
+    rope_pairing: str = "interleaved"
+    rotary_dim: int = 0
+    # YaRN on the global layers' table (``ops/layers.yarn_inv_freq``): 0 => none
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0  # cos and sin are scaled by it
+    # the sigmoid gate's selection-only bias leaf; False => the k largest scores
+    router_selection_bias: bool = True
 
     def __post_init__(self) -> None:
-        for name in ("kda_layers", "mla_layers"):  # a JSON file gives lists
+        for name in ("kda_layers", "mla_layers", "window_layers", "n_heads_per_layer"):  # a JSON file gives lists
             object.__setattr__(self, name, tuple(int(i) for i in getattr(self, name)))
-        named = self.kda_layers + self.mla_layers
+        named = self.kda_layers + self.mla_layers + self.window_layers
         if len(set(named)) != len(named) or any(not 1 <= i <= self.n_layers for i in named):
             raise ValueError(
-                f"kda_layers {self.kda_layers} and mla_layers {self.mla_layers} name layers "
-                f"1..{self.n_layers}, each at most once"
+                f"kda_layers {self.kda_layers}, mla_layers {self.mla_layers} and window_layers "
+                f"{self.window_layers} name layers 1..{self.n_layers}, each at most once"
             )
+        if bool(self.window_layers) != bool(self.window):
+            raise ValueError(f"window={self.window} and window_layers={self.window_layers}: a band has both")
+        if self.rope_pairing not in ("interleaved", "half"):
+            raise ValueError(f"rope_pairing must be 'interleaved'|'half', got {self.rope_pairing!r}")
+        if (self.rotary_dim or self.yarn_factor) and self.rope_pairing != "half":
+            raise ValueError("a partial rotation (rotary_dim) and YaRN come with rope_pairing='half'")
+        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.head_dim:
+            raise ValueError(f"rotary_dim={self.rotary_dim}: an even number of a head's {self.head_dim} lanes")
+        if self.n_heads_per_layer and len(self.n_heads_per_layer) < self.n_layers:
+            raise ValueError(f"n_heads_per_layer has {len(self.n_heads_per_layer)} entries for {self.n_layers} layers")
+        for mixer in ("full", "window"):
+            heads = {self.layer_heads(i) for i, (m, _) in enumerate(self.layer_kinds(), 1) if m == mixer}
+            if len(heads) > 1 or any(h % self.kv_heads for h in heads):
+                raise ValueError(
+                    f"{mixer} layers have {sorted(heads)} query heads over {self.kv_heads} key/value heads: "
+                    "layers of one kind agree, and the groups are whole"
+                )
         if self.router_gate not in ("softmax", "sigmoid"):
             raise ValueError(f"router_gate must be 'softmax'|'sigmoid', got {self.router_gate!r}")
         if self.n_experts_held and (
@@ -168,8 +219,19 @@ class TransformerConfig:
         return self.n_layers // max(self.pp, 1)
 
     @property
-    def qkv_dim(self) -> int:
-        return self.n_heads * self.head_dim
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    def layer_heads(self, layer: int) -> int:
+        """Query heads of ``layer`` (counted from 1)."""
+        return self.n_heads_per_layer[layer - 1] if self.n_heads_per_layer else self.n_heads
+
+    def mixer_heads(self, mixer: str) -> int:
+        """Query heads of the ``full`` or ``window`` layers (which agree)."""
+        for i, (m, _) in enumerate(self.layer_kinds(), 1):
+            if m == mixer:
+                return self.layer_heads(i)
+        return self.n_heads
 
     @property
     def expert_d_ff(self) -> int:
@@ -183,7 +245,10 @@ class TransformerConfig:
         """(mixer, feed-forward) of every layer, in order."""
         kinds = []
         for i in range(1, self.n_layers + 1):
-            mixer = "kda" if i in self.kda_layers else "mla" if i in self.mla_layers else "full"
+            mixer = (
+                "kda" if i in self.kda_layers else "mla" if i in self.mla_layers
+                else "window" if i in self.window_layers else "full"
+            )
             ff = "experts" if self.n_experts and i > self.n_dense_layers else "dense"
             kinds.append((mixer, ff))
         return tuple(kinds)
@@ -278,16 +343,17 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
         return jnp.ones(lead + shape, jnp.float32)
 
     layers: Dict[str, Any] = {"ln1": ones(d), "ln2": ones(d)}
-    if mixer == "full":
-        qkv = cfg.qkv_dim
+    if mixer in ("full", "window"):
+        qkv = cfg.mixer_heads(mixer) * cfg.head_dim
+        kv = cfg.kv_heads * cfg.head_dim
         layers.update(
             wq=dense(keys[0], d, qkv, fan_in=d),
-            wk=dense(keys[1], d, qkv, fan_in=d),
-            wv=dense(keys[2], d, qkv, fan_in=d),
+            wk=dense(keys[1], d, kv, fan_in=d),
+            wv=dense(keys[2], d, kv, fan_in=d),
             wo=dense(keys[3], qkv, d, fan_in=qkv),
         )
         if cfg.qk_norm:
-            layers.update(q_norm=ones(qkv), k_norm=ones(qkv))
+            layers.update(q_norm=ones(qkv), k_norm=ones(kv))
     elif mixer == "kda":
         hd, taps = cfg.linear_head_dim, cfg.conv_kernel
         ch = cfg.linear_n_heads * hd
@@ -335,7 +401,7 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
             w_in=dense(keys[6], held, d, f, fan_in=d),
             w_out=dense(keys[7], held, f, d, fan_in=f),
         )
-        if cfg.router_gate == "sigmoid":
+        if cfg.router_gate == "sigmoid" and cfg.router_selection_bias:
             layers.update(router_bias=jnp.zeros(lead + (e,), jnp.float32))
         if cfg.n_shared_experts:
             fs = cfg.n_shared_experts * f
@@ -405,7 +471,7 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
 
     row, col = spec("fsdp", "tp"), spec("tp", "fsdp")
     layers: Dict[str, Any] = {"ln1": spec(None), "ln2": spec(None)}
-    if mixer == "full":
+    if mixer in ("full", "window"):
         layers.update(wq=row, wk=row, wv=row, wo=col)
         if cfg.qk_norm:
             # over the tp-sharded projection: the norm's mean is one all-reduce
@@ -430,7 +496,7 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
             w_in=spec("ep", "fsdp", "tp"),
             w_out=spec("ep", "tp", "fsdp"),
         )
-        if cfg.router_gate == "sigmoid":
+        if cfg.router_gate == "sigmoid" and cfg.router_selection_bias:
             layers.update(router_bias=spec(None))
         if cfg.n_shared_experts:
             layers.update(shared_gate=row, shared_in=row, shared_out=col)
@@ -494,7 +560,8 @@ def _route(lp: Dict[str, Any], tokens: jnp.ndarray, cfg: TransformerConfig):
         return top_w, top_idx, probs
     scores = jax.nn.sigmoid(logits)
     # the bias moves which experts are chosen and not what they weigh
-    _, top_idx = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32), cfg.top_k)
+    biased = scores + lp["router_bias"].astype(jnp.float32) if "router_bias" in lp else scores
+    _, top_idx = jax.lax.top_k(biased, cfg.top_k)
     top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
     if cfg.router_renormalize:
         top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
@@ -704,17 +771,26 @@ def _use_chunked(cfg: TransformerConfig, seq_len: int) -> bool:
 
 def _attention_path(
     cfg: TransformerConfig, seq_len: int, batch: int, mesh, sp_manual: bool = False,
-    widths: Optional[Tuple[int, int]] = None,
+    widths: Optional[Tuple[int, int]] = None, window: int = 0, grouped: bool = False,
 ) -> Tuple[str, str, Optional[Tuple[int, int]]]:
     """(impl, reason, (block_q, block_k) or None): which code computes the
     causal core softmax(QKᵀ)V of a layer, decided from what can be
     observed — the backend, the mesh, whether the caller is already inside
-    a manual region, and the shapes. impl is "ring" (sp > 1), "flash" (the
-    Pallas kernel), "chunked" or "plain". ``widths``: a head's (key, value)
-    widths where they are not ``cfg.head_dim`` (a latent attention's): the
-    kernel reads heads in place when the VALUES are whole lane tiles and
+    a manual region, the shapes and the layer's ``window``. impl is "ring"
+    (sp > 1), "flash" (the Pallas kernel: banded under a window, a group's
+    one key/value head read in place under ``grouped`` heads), "chunked" or
+    "plain", each of which takes the same two. ``widths``: a head's (key,
+    value) widths where they are not ``cfg.head_dim`` (a latent attention's):
+    the kernel reads heads in place when the VALUES are whole lane tiles and
     pads the keys with zero columns to the next one."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    if sp_size > 1 and (window or grouped):
+        raise ValueError(
+            f"sp={sp_size} with a window ({window}) or grouped-query heads: ring attention "
+            "(ops/attention.ring_attention_local) passes every K/V block to every shard and gives each "
+            "query head its own; the band's early stop (only the shards that hold keys i - window < j <= i "
+            "take part) and the group map of a shard's key/value heads are missing"
+        )
     if sp_size > 1:
         if widths is not None and widths[0] != widths[1]:
             raise ValueError(
@@ -780,16 +856,22 @@ def _say_once(kind: str, key, **fields) -> None:
     )
 
 
-def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None) -> None:
-    """One ``attention_path`` event and one INFO line per traced shape, so a
-    worker's log and event trail say which core every program took and why."""
+def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, kind=None) -> None:
+    """One ``attention_path`` event and one INFO line per traced shape — and
+    per KIND of layer where a model declares a band or grouped heads
+    (``kind``: its (query heads, key/value heads, window, rotated lanes)) —
+    so a worker's log and event trail say which core every program took and why."""
     block_q, block_k = blocks or (0, 0)
     key_dim, value_dim = widths or (cfg.head_dim, cfg.head_dim)
     fields = dict(
         impl=impl, block_q=block_q, block_k=block_k, batch=batch, seq=seq_len,
         head_dim=key_dim, value_dim=value_dim, reason=reason,
     )
-    _say_once("attention_path", (*fields.values(), cfg.n_heads), **fields)
+    heads = cfg.n_heads
+    if kind is not None:
+        heads, kv_heads, window, rotary_dim = kind
+        fields.update(n_heads=heads, n_kv_heads=kv_heads, window=window, rotary_dim=rotary_dim)
+    _say_once("attention_path", (*fields.values(), heads), **fields)
 
 
 def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None:
@@ -806,15 +888,16 @@ def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None
     _say_once("layer_pattern", tuple(fields.values()), **fields)
 
 
-def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int]):
+def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int], window: Optional[int] = None):
     """Flash attention under GSPMD: pallas_call has no partitioning rules,
     so without shard_map the SPMD partitioner would all-gather q/k/v onto
     every chip. Attention is independent per (batch, head), so manualize
-    the batch/head axes and run the kernel per shard."""
+    the batch/head axes and run the kernel per shard (grouped heads: a
+    shard's query heads read the shard's own key/value heads, ``tp`` divides both)."""
     from torchft_tpu.ops.pallas.flash_attention import flash_attention
 
     kernel = functools.partial(
-        flash_attention, causal=True, block_q=blocks[0], block_k=blocks[1]
+        flash_attention, causal=True, block_q=blocks[0], block_k=blocks[1], window=window,
     )
     if mesh is None:
         return kernel(q, k, v)
@@ -835,47 +918,88 @@ def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int]):
     )(q, k, v)
 
 
-def _causal_core(cfg, mesh, sp_manual, q, k, v, widths=None, scope="core"):
-    """softmax(QKᵀ)V by the code :func:`_attention_path` picks, said once."""
+def _causal_core(cfg, mesh, sp_manual, q, k, v, widths=None, scope="core", window=0, kind=None):
+    """softmax(QKᵀ)V by the code :func:`_attention_path` picks, said once.
+    ``window``: the layer's band; k and v may have fewer heads than q."""
     b, s = q.shape[:2]
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
     # s is the sp-local block inside a manual region; the rule
     # reads sp from the mesh, not from s
-    impl, why, blocks = _attention_path(cfg, s, b, mesh, sp_manual, widths=widths)
-    _say_attention_path(impl, why, blocks, b, s, cfg, widths)
+    impl, why, blocks = _attention_path(
+        cfg, s, b, mesh, sp_manual, widths=widths, window=window, grouped=k.shape[2] != q.shape[2]
+    )
+    _say_attention_path(impl, why, blocks, b, s, cfg, widths, kind)
+    band = window or None  # the cores' "no band"
     with jax.named_scope(scope):
         if impl == "ring" and sp_manual:
             return ring_attention_local(q, k, v, sp_size, causal=True)
         if impl == "ring":
             return ring_attention(q, k, v, mesh, causal=True)
         if impl == "flash":
-            return _flash_sharded(q, k, v, mesh, blocks)
+            return _flash_sharded(q, k, v, mesh, blocks, band)
         if impl == "chunked":
             return chunked_attention(
                 q, k, v, causal=True, chunk=_attn_chunk(s),
-                tiers=_attn_tiers(),
+                tiers=_attn_tiers(), window=band,
             )
-        return attention(q, k, v, causal=True)
+        return attention(q, k, v, causal=True, window=band)
 
 
-def _mix_full(cfg, mesh, sp_manual, lp, h):
+def _declares_kinds(cfg: TransformerConfig) -> bool:
+    """Whether the model declares what makes its softmax layers differ by
+    kind: a band, grouped heads, heads by layer or a table of frequencies."""
+    return bool(
+        cfg.window_layers or cfg.n_kv_heads or cfg.n_heads_per_layer or cfg.rope_pairing != "interleaved"
+    )
+
+
+def _rotation(cfg: TransformerConfig, mixer: str) -> Dict[str, Any]:
+    """``rotary_embed``'s arguments for a ``full`` or ``window`` layer: the
+    one ``rope_theta`` with today's lane pairing, or under ``rope_pairing``
+    "half" the kind's own table — window layers the whole head at their own
+    base, global layers ``rotary_dim`` lanes under YaRN."""
+    if cfg.rope_pairing != "half":
+        return {"theta": cfg.rope_theta}
+    if mixer == "window":
+        return {"inv_freq": yarn_inv_freq(cfg.head_dim, cfg.window_rope_theta or cfg.rope_theta)}
+    table = yarn_inv_freq(
+        cfg.rotary_dim or cfg.head_dim, cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original_max,
+        cfg.yarn_beta_fast, cfg.yarn_beta_slow,
+    )
+    return {"inv_freq": table, "scale": cfg.yarn_attention_factor if cfg.yarn_factor else 1.0}
+
+
+def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
+    """Softmax attention with positions: ``full`` (global) or ``window``
+    (banded), each kind with its own query heads over the model's key/value
+    heads and its own rotation."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
     b, s, _ = h.shape  # s is the sp-local block inside a manual region
     if sp_manual and sp_size > 1:
         positions = jax.lax.axis_index("sp") * s + jnp.arange(s)
     else:
         positions = jnp.arange(s)
-    q, k = h @ lp["wq"], h @ lp["wk"]
-    if cfg.qk_norm:
-        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = k.reshape(b, s, cfg.n_heads, cfg.head_dim)
-    v = (h @ lp["wv"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    q = rotary_embed(q, positions, cfg.rope_theta)
-    k = rotary_embed(k, positions, cfg.rope_theta)
-    att = _causal_core(cfg, mesh, sp_manual, q, k, v)
-    return att.reshape(b, s, cfg.qkv_dim) @ lp["wo"]
+    heads, kv_heads = cfg.mixer_heads(mixer), cfg.kv_heads
+    # a model that declares kinds names them in the trace: attn/window and attn/global, the core inside each
+    name = ("window" if mixer == "window" else "global") if _declares_kinds(cfg) else None
+    with jax.named_scope(name) if name else contextlib.nullcontext():
+        q, k = h @ lp["wq"], h @ lp["wk"]
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        q = q.reshape(b, s, heads, cfg.head_dim)
+        k = k.reshape(b, s, kv_heads, cfg.head_dim)
+        v = (h @ lp["wv"]).reshape(b, s, kv_heads, cfg.head_dim)
+        rotation = _rotation(cfg, mixer)
+        q = rotary_embed(q, positions, **rotation)
+        k = rotary_embed(k, positions, **rotation)
+        said = {}
+        if name:
+            rotated = 2 * len(rotation["inv_freq"]) if "inv_freq" in rotation else cfg.head_dim
+            window = cfg.window if mixer == "window" else 0
+            said = dict(scope=name + "_core", window=window, kind=(heads, kv_heads, window, rotated))
+        att = _causal_core(cfg, mesh, sp_manual, q, k, v, **said)
+        return att.reshape(b, s, heads * cfg.head_dim) @ lp["wo"]
 
 
 def _mix_mla(cfg, mesh, sp_manual, lp, h):
@@ -997,8 +1121,8 @@ def _make_layer_fn(
         x = _constrain(x, _act_spec(sp_manual))
         with jax.named_scope("attn"):
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            if mixer == "full":
-                x = x + part(functools.partial(_mix_full, cfg, mesh, sp_manual))(lp, h)
+            if mixer in ("full", "window"):
+                x = x + part(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer))(lp, h)
             elif mixer == "kda":
                 x = x + _mix_kda(cfg, lp, h)
             else:

@@ -32,9 +32,21 @@ __all__ = [
 _NEG_INF = -1e30
 
 
-def _causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray) -> jnp.ndarray:
-    """[Sq, Sk] True where k may attend (k_pos <= q_pos)."""
-    return k_pos[None, :] <= q_pos[:, None]
+def _causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray, window: Optional[int] = None) -> jnp.ndarray:
+    """[Sq, Sk] True where k may attend: k_pos <= q_pos, and under a band
+    q_pos - window < k_pos as well."""
+    seen = k_pos[None, :] <= q_pos[:, None]
+    if window:
+        seen &= k_pos[None, :] > q_pos[:, None] - window
+    return seen
+
+
+def _each_query_head(q: jnp.ndarray, kv: jnp.ndarray) -> jnp.ndarray:
+    """Grouped-query heads for the code that wants a key/value head a query
+    head: query head a reads head a // (H / Hkv). The kernel
+    (``ops/pallas/flash_attention``) reads a group's one head in place."""
+    group = q.shape[2] // kv.shape[2]
+    return kv if group == 1 else jnp.repeat(kv, group, axis=2)
 
 
 def attention(
@@ -42,16 +54,21 @@ def attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     causal: bool = True,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Plain attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh]; the values
-    may have another width than the keys (the output's), here and in
-    :func:`chunked_attention`."""
+    may have another width than the keys (the output's), and k and v fewer
+    heads than q (grouped-query: a whole number of query heads a key/value
+    head), here and in :func:`chunked_attention`. ``window`` (causal only):
+    position i sees the keys j with i - window < j <= i."""
+    assert causal or not window, "a band is causal"
+    k, v = _each_query_head(q, k), _each_query_head(q, v)
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         s = q.shape[1]
         pos = jnp.arange(s)
-        scores = jnp.where(_causal_mask(pos, pos)[None, None], scores, _NEG_INF)
+        scores = jnp.where(_causal_mask(pos, pos, window)[None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
@@ -63,6 +80,7 @@ def chunked_attention(
     causal: bool = True,
     chunk: int = 512,
     tiers: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Plain attention, one q-block at a time: same contract and numerics
     as :func:`attention` ([B, S, H, Dh] -> [B, S, H, Dh]) but the [S, S]
@@ -87,10 +105,18 @@ def chunked_attention(
     of a d512 / head_dim 64 model before the benchmark existed; not
     measured at the cells' widths).
 
+    Under a band (``window``) no prefix is full: a q-block scores against
+    the ``chunk + window - 1`` keys that end with its last position and no
+    tier is taken — ``(chunk + window - 1) / chunk`` of the band's own work.
+
     Requires ``S % chunk == 0`` (callers fall back to plain otherwise).
     """
     b, s, h, d = q.shape
     assert s % chunk == 0, f"seq {s} not divisible by chunk {chunk}"
+    k, v = _each_query_head(q, k), _each_query_head(q, v)
+    if window:
+        assert causal, "a band is causal"
+        return _banded_chunks(q, k, v, chunk, window)
     if tiers is None:
         tiers = 16 if s >= 16384 else 4
     # the divisibility gate below would otherwise silently drop tiering
@@ -139,6 +165,34 @@ def chunked_attention(
             )
         )
     return jnp.concatenate(outs, axis=1)
+
+
+def _banded_chunks(q, k, v, chunk: int, window: int) -> jnp.ndarray:
+    """:func:`chunked_attention` under a band: q-block i against the keys
+    ``[i·chunk - (window - 1), (i + 1)·chunk)``, zeros (masked) before the
+    sequence's start."""
+    b, s, h, d = q.shape
+    scale = d**-0.5
+    before = min(window - 1, s)  # keys ahead of a block's first position that it can see
+    nq = s // chunk
+    qb = jnp.moveaxis(q.reshape(b, nq, chunk, h, d), 1, 0)
+    pad = ((0, 0), (before, 0), (0, 0), (0, 0))
+    k_pad, v_pad = jnp.pad(k, pad), jnp.pad(v, pad)
+
+    def body(carry, xs):
+        qc, i = xs
+        k_blk = jax.lax.dynamic_slice_in_dim(k_pad, i * chunk, before + chunk, axis=1)
+        v_blk = jax.lax.dynamic_slice_in_dim(v_pad, i * chunk, before + chunk, axis=1)
+        k_pos = i * chunk - before + jnp.arange(before + chunk)
+        q_pos = i * chunk + jnp.arange(chunk)
+        seen = _causal_mask(q_pos, k_pos, window) & (k_pos >= 0)[None, :]
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qc, k_blk) * scale
+        scores = jnp.where(seen[None, None], scores, _NEG_INF)
+        p = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+        return carry, jnp.einsum("bhqk,bkhd->bqhd", p, v_blk)
+
+    _, out = jax.lax.scan(jax.checkpoint(body), 0, (qb, jnp.arange(nq)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, v.shape[-1])
 
 
 def ring_attention_local(

@@ -17,8 +17,11 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["rms_norm", "rotary_embed", "swiglu", "moe_dropless", "moe_dropless_held", "moe_dispatch"]
+__all__ = [
+    "rms_norm", "rotary_embed", "yarn_inv_freq", "swiglu", "moe_dropless", "moe_dropless_held", "moe_dispatch",
+]
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
@@ -28,11 +31,53 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndar
     return (xf * scale).astype(dtype) * weight
 
 
+def yarn_inv_freq(
+    rotary_dim: int, theta: float, factor: float = 0.0, original_max: int = 0,
+    beta_fast: float = 32.0, beta_slow: float = 1.0,
+) -> np.ndarray:
+    """The ``rotary_dim / 2`` inverse frequencies of a rotation over the first
+    ``rotary_dim`` lanes of a head: ``theta^(-2i / rotary_dim)``, and with a
+    ``factor`` YaRN's blend of them — a frequency that turns more than
+    ``beta_fast`` times inside ``original_max`` positions stays, one that turns
+    less than ``beta_slow`` times is divided by ``factor``, a linear ramp over
+    the lane index between the two (the ends floored and ceiled)."""
+    half = rotary_dim // 2
+    freqs = theta ** (-2.0 * np.arange(half, dtype=np.float64) / rotary_dim)
+    if not factor:
+        return freqs.astype(np.float32)
+
+    def lane(turns: float) -> float:  # the lane whose frequency turns that often in original_max
+        return rotary_dim * np.log(original_max / (2 * np.pi * turns)) / (2 * np.log(theta))
+
+    low = max(int(np.floor(lane(beta_fast))), 0)
+    high = min(int(np.ceil(lane(beta_slow))), rotary_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (freqs / factor * ramp + freqs * (1.0 - ramp)).astype(np.float32)
+
+
+def _rotary_half(x: jnp.ndarray, positions: jnp.ndarray, inv_freq, scale: float) -> jnp.ndarray:
+    """Lane i with lane i + r/2 inside the first r = 2·len(inv_freq) lanes;
+    the lanes past r pass. cos and sin carry ``scale``."""
+    half = len(inv_freq)
+    angles = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos = (jnp.cos(angles) * scale)[None, :, None, :]
+    sin = (jnp.sin(angles) * scale)[None, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half : 2 * half].astype(jnp.float32)
+    turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    return jnp.concatenate([t.astype(x.dtype) for t in turned] + [x[..., 2 * half :]], axis=-1)
+
+
 def rotary_embed(
-    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0, *, inv_freq=None, scale: float = 1.0
 ) -> jnp.ndarray:
     """RoPE. x: [B, S, H, Dh], positions: [S] (global positions, so the
-    same code is correct under sequence sharding)."""
+    same code is correct under sequence sharding). Lane 2i turns with lane
+    2i + 1 at ``theta^(-2i / Dh)``; with a table ``inv_freq`` [r/2]
+    (:func:`yarn_inv_freq`) lane i turns with lane i + r/2 inside the first r
+    lanes instead, cos and sin times ``scale``, and the rest of the head passes."""
+    if inv_freq is not None:
+        return _rotary_half(x, positions, inv_freq, scale)
     dh = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, Dh/2]
@@ -93,12 +138,16 @@ def _grouped_matmul_tpu(
     m, k = rows.shape
     tm = min(_GMM_TILE[0], -(-m // 128) * 128)
     pad = -m % tm  # the kernel wants whole row tiles; the rows added belong to no group
+    # the tile was swept for 2-byte operands and the kernel's VMEM is bytes: float32
+    # operands take half the contraction and half the columns (at the full tile
+    # the chip's compiler refuses the kernel: RESOURCE_EXHAUSTED in vmem, PR 41)
+    narrow = 2 if jnp.dtype(rows.dtype).itemsize > 2 else 1
     out = gmm(
         jnp.pad(rows, ((0, pad), (0, 0))) if pad else rows,
         w,
         counts,
         preferred_element_type=rows.dtype,
-        tiling=(tm, min(_GMM_TILE[1], k), min(_GMM_TILE[2], w.shape[2])),
+        tiling=(tm, min(_GMM_TILE[1] // narrow, k), min(_GMM_TILE[2] // narrow, w.shape[2])),
         interpret=interpret,
     )
     return out[:m] if pad else out

@@ -20,7 +20,18 @@ Causal scheduling: the loop over key tiles stops at the diagonal — a tile
 above it is never computed, a K/V block above it never fetched (the index
 maps clamp to the last block the diagonal needs, and a step whose block
 index did not change re-uses the resident block) — and only the tiles the
-diagonal crosses build the mask.
+diagonal crosses build the mask. Under a band (``window``: position i sees
+the keys i - window < j <= i) the loop starts at the tile that holds the
+first row's first key, masks the tiles the band's lower edge crosses as well,
+and a K/V block wholly below the band is not fetched either: at 512 x 512
+tiles and a window of 512 a query tile visits two key tiles, whatever S.
+
+Grouped queries: k and v may have fewer heads than q. In the forward the
+grid runs over query heads and a group's heads read the one K/V block of
+head ``h // group`` in place (consecutive steps, so it stays resident); the
+backward's grid runs over KEY/VALUE heads and its innermost axis sweeps the
+q blocks of every query head of the group, so dK and dV are summed over the
+group in the scratch accumulators and written once.
 
 Layout: with ``head_dim`` a multiple of 128 (one lane tile) a head's
 columns are read in place from the ``[B, S, H·Dh]`` view, no transpose
@@ -83,25 +94,40 @@ _NN = ((1,), (0,))  # a · b
 _TN = ((0,), (0,))  # aᵀ · b
 
 
-def _causal(key0, n_keys, q0, n_q):
-    """[n_keys, n_q] True where the key may be attended (k_pos <= q_pos)."""
+def _causal(key0, n_keys, q0, n_q, window=None):
+    """[n_keys, n_q] True where the key may be attended (k_pos <= q_pos, and
+    under a band q_pos - window < k_pos)."""
     k = key0 + jax.lax.broadcasted_iota(jnp.int32, (n_keys, n_q), 0)
     q = q0 + jax.lax.broadcasted_iota(jnp.int32, (n_keys, n_q), 1)
-    return k <= q
+    if window is None:
+        return k <= q
+    return (k <= q) & (k > q - window)
 
 
-def _over_key_tiles(row0, bq, col0, bk, bkc, causal, step) -> None:
+def _over_key_tiles(row0, bq, col0, bk, bkc, causal, step, window=None) -> None:
     """Run ``step(c, masked)`` for the tiles c of ``bkc`` keys, of the
     ``bk`` resident from ``col0``, that the q rows ``[row0, row0 + bq)``
     may attend: first those wholly below the diagonal, without a mask,
-    then those it crosses; a tile above it is not computed."""
+    then those it crosses; a tile above it is not computed. Under a band the
+    loop starts at the tile that holds the first row's first key
+    (``row0 - window + 1``) and masks the tiles the band's lower edge crosses
+    too: those before the last row's first key."""
     nc = bk // bkc
     if not causal:
         jax.lax.fori_loop(0, nc, lambda c, _: step(c, False), None)
         return
     below = jnp.clip(jnp.maximum(row0 - col0 + 1, 0) // bkc, 0, nc)
     reached = jnp.clip(jnp.maximum(row0 + bq - 1 - col0 + bkc, 0) // bkc, 0, nc)
-    jax.lax.fori_loop(0, below, lambda c, _: step(c, False), None)
+    if window is None:
+        jax.lax.fori_loop(0, below, lambda c, _: step(c, False), None)
+        jax.lax.fori_loop(below, reached, lambda c, _: step(c, True), None)
+        return
+    first = jnp.clip(jnp.maximum(row0 - window + 1 - col0, 0) // bkc, 0, nc)
+    whole = jnp.clip((jnp.maximum(row0 + bq - window - col0, 0) + bkc - 1) // bkc, first, nc)
+    below = jnp.clip(below, first, nc)
+    edge = jnp.minimum(whole, below)
+    jax.lax.fori_loop(first, edge, lambda c, _: step(c, True), None)
+    jax.lax.fori_loop(edge, below, lambda c, _: step(c, False), None)
     jax.lax.fori_loop(below, reached, lambda c, _: step(c, True), None)
 
 
@@ -117,7 +143,7 @@ def _scaled(q_ref, scale):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, bq, bk, bkc, scale, causal,
+    *, bq, bk, bkc, scale, causal, window=None,
 ):
     # transposed scores [bkc, bq]: the running max and sum are lane rows
     # [1, bq] — as a [bq, 1] column each of their updates costs a pass over a
@@ -137,7 +163,7 @@ def _fwd_kernel(
         keys = pl.ds(pl.multiple_of(c * bkc, bkc), bkc)
         st = _dot(k_ref[keys, :], q, _NT)
         if masked:
-            st = jnp.where(_causal(j * bk + c * bkc, bkc, i * bq, bq), st, _NEG_INF)
+            st = jnp.where(_causal(j * bk + c * bkc, bkc, i * bq, bq, window), st, _NEG_INF)
         m_prev = m_ref[:1, :]
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         pt = jnp.exp(st - m_new)
@@ -149,7 +175,7 @@ def _fwd_kernel(
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, step)
+    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, step, window)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
@@ -159,19 +185,22 @@ def _fwd_kernel(
 
 
 # ---------------------------------------------------------------------------
-# backward: grid (b, h, nk, nq) — nq innermost, dk/dv in scratch; dq of
-# this (k block, q block) is complete within the step
+# backward: grid (b, key/value head, nk, group x nq) — the q blocks of every
+# query head of the group innermost, dk/dv in scratch over all of them (a
+# group of one: (b, h, nk, nq)); dq of this (k block, q block) is complete
+# within the step
 # ---------------------------------------------------------------------------
 
 
 def _bwd_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
     dk_acc, dv_acc, dq_acc,
-    *, bq, bk, bkc, scale, causal,
+    *, bq, bk, bkc, scale, causal, window=None, nq=None,
 ):
-    j, i = pl.program_id(2), pl.program_id(3)
+    j, y = pl.program_id(2), pl.program_id(3)
+    i = y if nq is None else y % nq  # nq: q blocks a head, where a group's heads share the sweep
 
-    @pl.when(i == 0)
+    @pl.when(y == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -197,18 +226,18 @@ def _bwd_kernel(
         # along sublanes as they are
         st = _dot(k, q, _NT)
         if masked:
-            st = jnp.where(_causal(j * bk + c * bkc, bkc, i * bq, bq), st, _NEG_INF)
+            st = jnp.where(_causal(j * bk + c * bkc, bkc, i * bq, bq, window), st, _NEG_INF)
         pt = jnp.exp(st - lse)
         dv_acc[keys, :] += _dot(pt.astype(do.dtype), do, _NN)
         dst = (pt * (_dot(v, do, _NT) - delta)).astype(q.dtype)
         dk_acc[keys, :] += _dot(dst, q, _NN)  # q carries the scale
         dq_acc[...] += _dot(dst, k, _TN)
 
-    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, step)
+    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, step, window)
 
     dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
-    @pl.when(i == pl.num_programs(3) - 1)
+    @pl.when(y == pl.num_programs(3) - 1)
     def _finish():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -219,15 +248,22 @@ def _bwd_kernel(
 # ---------------------------------------------------------------------------
 
 
-def _tile(lanes: bool, blk: int, d: int, idx, lead: bool = False) -> pl.BlockSpec:
+def _same_head(h, x, y):
+    return h
+
+
+def _tile(lanes: bool, blk: int, d: int, idx, lead: bool = False, head=_same_head) -> pl.BlockSpec:
     """One [blk, d] tile of a q/k/v-shaped array; ``idx(x, y)`` gives its
     block along S from the grid's last two indices. ``lead``: the array has
-    one more axis in front, indexed by x (dq's part of each k block)."""
+    one more axis in front, indexed by x (dq's part of each k block).
+    ``head(h, x, y)``: the array's head where it is not the grid's (grouped
+    queries: a key/value head in the forward's grid over query heads, a
+    query head in the backward's over key/value heads)."""
     if lanes:  # [B, S, H·Dh]: head h is lane tile h
-        at = lambda b, h, x, y: (b, idx(x, y), h)
+        at = lambda b, h, x, y: (b, idx(x, y), head(h, x, y))
         shape = (None, blk, d)
     else:  # [B, H, S, Dh]
-        at = lambda b, h, x, y: (b, h, idx(x, y), 0)
+        at = lambda b, h, x, y: (b, head(h, x, y), idx(x, y), 0)
         shape = (None, None, blk, d)
     if lead:
         return pl.BlockSpec(
@@ -237,10 +273,10 @@ def _tile(lanes: bool, blk: int, d: int, idx, lead: bool = False) -> pl.BlockSpe
     return pl.BlockSpec(shape, at, memory_space=pltpu.VMEM)
 
 
-def _row(blk: int, idx) -> pl.BlockSpec:
+def _row(blk: int, idx, head=_same_head) -> pl.BlockSpec:
     """One [8, blk] tile of per-position statistics, [B, H, 8, S]."""
     return pl.BlockSpec(
-        (None, None, _ROWS, blk), lambda b, h, x, y: (b, h, 0, idx(x, y)),
+        (None, None, _ROWS, blk), lambda b, h, x, y: (b, head(h, x, y), 0, idx(x, y)),
         memory_space=pltpu.VMEM,
     )
 
@@ -263,27 +299,31 @@ def _resident_bytes(bk: int, d: int, dv: int) -> int:
 
 
 def _fwd(q, k, v, shape, blocks, causal, interpret):
-    b, s, h, d, dv, scale = shape
+    b, s, h, d, dv, scale, group, window = shape
     bq, bk, bkc = blocks
     lanes = q.ndim == 3
     q_at = lambda i, j: i
-    if causal:  # a block above the diagonal: keep the last one needed resident
+    if causal and window is not None:  # and a block below the band: the first one needed
+        k_at = lambda i, j: jnp.clip(j, jnp.maximum(i * bq - window + 1, 0) // bk, (i * bq + bq - 1) // bk)
+    elif causal:  # a block above the diagonal: keep the last one needed resident
         k_at = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
     else:
         k_at = lambda i, j: j
+    # a group's query heads follow one another in the grid, so their one K/V block stays resident
+    kv_head = _same_head if group == 1 else (lambda h, x, y: h // group)
     return pl.pallas_call(
         functools.partial(
-            _fwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal
+            _fwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal, window=window
         ),
         grid=(b, h, s // bq, s // bk),
         in_specs=[
             _tile(lanes, bq, d, q_at),
-            _tile(lanes, bk, d, k_at),
-            _tile(lanes, bk, dv, k_at),
+            _tile(lanes, bk, d, k_at, head=kv_head),
+            _tile(lanes, bk, dv, k_at, head=kv_head),
         ],
         out_specs=[_tile(lanes, bq, dv, q_at), _row(bq, q_at)],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape[:-1] + (v.shape[-1],), q.dtype),
+            jax.ShapeDtypeStruct(q.shape[:-1] + (h * dv if lanes else dv,), q.dtype),
             jax.ShapeDtypeStruct((b, h, _ROWS, s), jnp.float32),
         ],
         scratch_shapes=[
@@ -299,31 +339,37 @@ def _fwd(q, k, v, shape, blocks, causal, interpret):
 
 def _bwd(shape, blocks, causal, interpret, res, do):
     q, k, v, o, lse = res
-    b, s, h, d, dv, scale = shape
+    b, s, h, d, dv, scale, group, window = shape
     bq, bk, bkc = blocks
     lanes = q.ndim == 3
-    nk = s // bk
+    nk, nq = s // bk, s // bq
     k_at = lambda j, i: j
+    if group == 1:
+        q_head, q_blk = _same_head, (lambda j, i: i)
+    else:  # the grid's head is a key/value head; its last axis sweeps the group's heads, nq blocks each
+        q_head = lambda h, j, y: h * group + y // nq
+        q_blk = lambda j, y: y % nq
     if causal:  # a q block above the diagonal: fetch the first one below
-        q_at = lambda j, i: jnp.maximum(i, (j * bk) // bq)
+        q_at = lambda j, y: jnp.maximum(q_blk(j, y), (j * bk) // bq)
     else:
-        q_at = lambda j, i: i
+        q_at = q_blk
     dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal
+            _bwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal, window=window,
+            nq=None if group == 1 else nq,
         ),
-        grid=(b, h, nk, s // bq),
+        grid=(b, h // group, nk, group * nq),
         in_specs=[
-            _tile(lanes, bq, d, q_at),
+            _tile(lanes, bq, d, q_at, head=q_head),
             _tile(lanes, bk, d, k_at),
             _tile(lanes, bk, dv, k_at),
-            _tile(lanes, bq, dv, q_at),
-            _tile(lanes, bq, dv, q_at),
-            _row(bq, q_at),
+            _tile(lanes, bq, dv, q_at, head=q_head),
+            _tile(lanes, bq, dv, q_at, head=q_head),
+            _row(bq, q_at, head=q_head),
         ],
         out_specs=[
             # dq's part of EVERY (k block, q block), zeros above the diagonal
-            _tile(lanes, bq, d, lambda j, i: i, lead=True),
+            _tile(lanes, bq, d, q_blk, lead=True, head=q_head),
             _tile(lanes, bk, d, k_at),
             _tile(lanes, bk, dv, k_at),
         ],
@@ -378,8 +424,16 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Causal flash attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh].
+
+    Grouped queries: k and v may have fewer heads than q, a whole number of
+    query heads a key/value head (query head a reads head a // group); the
+    group's one K/V block is read in place and dK, dV are summed over the
+    group inside the backward kernel. ``window`` (causal only): position i
+    attends to the keys j with i - window < j <= i, and a tile wholly outside
+    that band is not computed.
 
     ``block_q`` x ``block_k`` is the tile of scores computed at a time.
     Requires S % block == 0 (pick smaller blocks for short sequences).
@@ -393,6 +447,11 @@ def flash_attention(
     b, s, h, d = q.shape
     dv = v.shape[-1]
     scale = d**-0.5
+    group = h // k.shape[2]
+    if h % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{h} query heads over {k.shape[2]} key and {v.shape[2]} value heads: groups are whole")
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"window={window}: a band is causal and at least one key wide")
     if d % _LANES and dv % _LANES == 0:
         widen = ((0, 0), (0, 0), (0, 0), (0, -d % _LANES))
         q, k = jnp.pad(q, widen), jnp.pad(k, widen)
@@ -414,7 +473,7 @@ def flash_attention(
     if d % _LANES == 0 and dv % _LANES == 0:
         # a head's columns are whole lane tiles of the [B, S, H·Dh] view
         def pack(x):
-            return x.reshape(b, s, h * x.shape[-1])
+            return x.reshape(b, s, x.shape[2] * x.shape[3])
 
         def unpack(x):
             return x.reshape(b, s, h, dv)
@@ -424,5 +483,6 @@ def flash_attention(
 
         unpack = pack
 
-    o = _flash(pack(q), pack(k), pack(v), (b, s, h, d, dv, scale), blocks, causal, interpret)
+    shape = (b, s, h, d, dv, scale, group, window)
+    o = _flash(pack(q), pack(k), pack(v), shape, blocks, causal, interpret)
     return unpack(o)
