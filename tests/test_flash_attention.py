@@ -682,3 +682,38 @@ def test_the_kda_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
     assert all("tpu_custom_call" in text for text in texts)
+
+
+@pytest.mark.parametrize(
+    "rows, width, dtype",
+    [(32768, 2048, jnp.bfloat16), (8192, 2304, jnp.bfloat16), (32768, 128, jnp.bfloat16), (8192, 2304, jnp.float32)],
+    ids=["laguna_xs2_1g", "kimi_linear_1g", "the_gates_gradient", "float32"],
+)
+def test_the_sum_of_a_tokens_rows_compiles_for_v5e_at_the_cells_shapes(one_v5e_chip, rows, width, dtype):
+    """``ops/layers._rows_to_tokens_tpu`` (PR 44: the held experts' combine and
+    the dispatch's backward, JAX's grouped ``tgmm`` over a one-hot) through
+    Mosaic for a described v5e at the two cells under a share — windows of
+    32 768 rows of 2048 and 8 192 rows of 2304 into 16 384 tokens — as wide as
+    a lane tile (the gates' gradient), and in float32 (``check_laguna.py``'s
+    program: half the columns a tile). In this file: one process a run may
+    load the TPU's library."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.ops import layers
+
+    def of(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_v5e_chip)
+
+    tiles = 16384 // layers._TOKEN_TILE
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(layers._rows_to_tokens_tpu).lower(
+            of((rows, layers._TOKEN_TILE), dtype), of((rows, width), dtype), of((tiles,), jnp.int32)
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (tiles, layers._TOKEN_TILE, width) and out.dtype == dtype

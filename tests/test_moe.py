@@ -32,7 +32,8 @@ from torchft_tpu.models.transformer import (
     loss_and_stats,
     loss_fn,
 )
-from torchft_tpu.ops.layers import moe_dropless
+from torchft_tpu.ops import layers
+from torchft_tpu.ops.layers import moe_dropless, moe_dropless_held
 from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
 from torchft_tpu.parallel.train_step import TrainStep
 
@@ -245,6 +246,252 @@ def test_two_shapes_in_one_program_trace_with_cache_miss_explanations_on():
     with jax.explain_cache_misses(True):
         out = two(jnp.ones((8, 4)), jnp.ones((2, 4, 6)), jnp.ones((2, 6, 4)))
     assert out.shape == (8, 4) and float(out[0, 0]) == 24.0
+
+
+# -- experts held under a share: the rows the layer holds, and no tensor of T·k rows ----------------
+
+T_HELD, K_HELD, E_ALL, HELD, D_HELD, F_HELD = 48, 4, 16, 4, 32, 16
+
+
+def held_reference(tokens, top_idx, top_w, w_gate, w_in, w_out, first_expert):
+    """Every held expert over every token in float32, kept where the token chose it."""
+    y = jnp.zeros(tokens.shape, jnp.float32)
+    for e in range(w_gate.shape[0]):
+        out = (jax.nn.silu(tokens @ w_gate[e]) * (tokens @ w_in[e])) @ w_out[e]
+        gate = jnp.sum(jnp.where(top_idx == first_expert + e, top_w, 0.0), axis=1, keepdims=True)
+        y = y + gate * out
+    return y
+
+
+def held_before_pr44(tokens, top_idx, top_w, w_gate, w_in, w_out, first_expert, row_bound):
+    """``moe_dropless_held`` as it stood at dc040bf, plain (autodiff for its
+    hand-written gradients): all T·k slots sorted and inverted, a window's
+    computed rows spread back over every slot — [T·k, d], a zero row where a
+    slot is not in the window — and an ``einsum`` over a token's k."""
+    t, k = top_idx.shape
+    held = w_gate.shape[0]
+    m = min(row_bound, t * k)
+    local = top_idx.reshape(t * k) - first_expert
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.argsort(order)
+    counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    n_held = ends[-1]
+    order = jnp.pad(order, (0, -(t * k) % m))
+
+    def window(start):
+        slots = jax.lax.dynamic_slice_in_dim(order, start, m)
+        at = jnp.where(inv < n_held, inv - start, -1)
+        rows = tokens[slots // k]
+        edges = jnp.clip(jnp.concatenate([jnp.zeros((1,), ends.dtype), ends]), start, start + m)
+        sizes = edges[1:] - edges[:-1]
+        h = jax.nn.silu(layers._grouped_matmul(rows, w_gate, sizes)) * layers._grouped_matmul(rows, w_in, sizes)
+        out = layers._grouped_matmul(h, w_out, sizes)
+        out = jnp.where((start + jnp.arange(m))[:, None] < n_held, out, jnp.zeros_like(out))
+        padded = jnp.concatenate([out, jnp.zeros((1, out.shape[1]), out.dtype)])
+        back = padded[jnp.where((at >= 0) & (at < m), at, m)].reshape(t, k, -1)  # [T, k, d]
+        return jnp.einsum("tkd,tk->td", back, top_w.astype(back.dtype))
+
+    if m == t * k:
+        return window(0), n_held
+
+    def every_window():
+        y, _ = jax.lax.scan(lambda y, s: (y + window(s), None), jnp.zeros_like(tokens), jnp.arange(0, t * k, m))
+        return y
+
+    return jax.lax.cond(n_held <= m, lambda: window(0), every_window), n_held
+
+
+def held_operands(dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    return (
+        jax.random.normal(keys[0], (T_HELD, D_HELD)).astype(dtype),
+        jax.nn.softmax(2.0 * jax.random.normal(keys[1], (T_HELD, K_HELD)), axis=-1).astype(dtype),
+        (jax.random.normal(keys[2], (HELD, D_HELD, F_HELD)) * D_HELD**-0.5).astype(dtype),
+        (jax.random.normal(keys[3], (HELD, D_HELD, F_HELD)) * D_HELD**-0.5).astype(dtype),
+        (jax.random.normal(keys[4], (HELD, F_HELD, D_HELD)) * F_HELD**-0.5).astype(dtype),
+    )
+
+
+def held_routing(case):
+    """(top_idx [T, k], first_expert, row_bound): k distinct experts of 16 a token."""
+    rng = np.random.default_rng(11)
+    idx = np.stack([rng.permutation(E_ALL)[:K_HELD] for _ in range(T_HELD)])
+    first, bound = 0, 2 * T_HELD * K_HELD * HELD // E_ALL  # twice the rows at balance, as the model sizes it
+    if case == "overflows_the_first_window":
+        bound = 8  # ~48 rows held: the `lax.cond` takes `every_window`
+    elif case == "a_token_with_all_k_held":
+        idx[0] = np.arange(K_HELD)
+    elif case == "a_token_with_none_held":
+        idx[1] = HELD + np.arange(K_HELD)
+    elif case == "an_expert_with_no_rows":
+        for row in idx:  # whoever chose expert 2 takes an absent one it had not chosen
+            row[row == 2] = next(e for e in range(HELD, E_ALL) if e not in row)
+    elif case == "first_expert_above_zero":
+        first = 8
+    elif case == "no_row_held":
+        idx = np.stack([HELD + rng.permutation(E_ALL - HELD)[:K_HELD] for _ in range(T_HELD)])
+    elif case == "one_window_of_every_slot":
+        bound = T_HELD * K_HELD
+    else:
+        assert case == "balanced", case
+    return jnp.asarray(idx, jnp.int32), first, bound
+
+
+HELD_CASES = [
+    "balanced", "overflows_the_first_window", "a_token_with_all_k_held", "a_token_with_none_held",
+    "an_expert_with_no_rows", "first_expert_above_zero", "no_row_held", "one_window_of_every_slot",
+]
+
+
+@pytest.mark.parametrize("case", HELD_CASES)
+def test_the_held_path_agrees_with_the_reference_and_with_the_path_it_replaced(case):
+    """``y``, the rows held and the gradient of every operand — tokens, gates
+    and the three weights — against every held expert over every token in
+    float32, and against the path before PR 44 with its [T·k, d] spread."""
+    top_idx, first, bound = held_routing(case)
+    operands = held_operands()
+    probe = jnp.cos(jnp.arange(T_HELD * D_HELD, dtype=jnp.float32)).reshape(T_HELD, D_HELD)
+    want_rows = int(jnp.sum((top_idx >= first) & (top_idx < first + HELD)))
+    if case == "overflows_the_first_window":
+        assert want_rows > bound
+    if case == "no_row_held":
+        assert want_rows == 0
+
+    def scalar(fn):
+        def loss(*ops):
+            out = fn(*ops)
+            y, rows = out if isinstance(out, tuple) else (out, None)
+            return jnp.sum(y * probe), (y, rows)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, rows)), got = scalar(
+            lambda *ops: moe_dropless_held(ops[0], top_idx, *ops[1:], first_expert=first, row_bound=bound)
+        )(*operands)
+        (_, (y_ref, _)), want = scalar(
+            lambda *ops: held_reference(ops[0], top_idx, *ops[1:], first_expert=first)
+        )(*operands)
+        (_, (y_old, rows_old)), old = scalar(
+            lambda *ops: held_before_pr44(ops[0], top_idx, *ops[1:], first_expert=first, row_bound=bound)
+        )(*operands)
+    assert int(rows) == int(rows_old) == want_rows
+    np.testing.assert_allclose(y, y_ref, atol=2e-5)
+    np.testing.assert_allclose(y, y_old, atol=2e-5)
+    for theirs in (want, old):
+        for name, a, b in zip(("tokens", "top_w", "w_gate", "w_in", "w_out"), got, theirs):
+            scale = float(jnp.max(jnp.abs(b))) or 1.0  # no row held: every gradient is zero
+            assert float(jnp.max(jnp.abs(a - b))) / scale < RTOL, (case, name)
+
+
+def test_the_held_path_keeps_bfloat16_rows_and_sums_a_tokens_rows_in_float32():
+    """bfloat16 operands: the rows stay bfloat16 and a token's k products are
+    summed in float32 and rounded once, as the ``einsum`` it replaced did —
+    the two agree to an ulp of bfloat16, where a sum kept in bfloat16 would
+    be off by k of them."""
+    top_idx, first, bound = held_routing("a_token_with_all_k_held")
+    operands = held_operands(jnp.bfloat16)
+    y, _ = jax.jit(lambda *ops: moe_dropless_held(ops[0], top_idx, *ops[1:], first_expert=first, row_bound=bound))(*operands)
+    old, _ = jax.jit(lambda *ops: held_before_pr44(ops[0], top_idx, *ops[1:], first_expert=first, row_bound=bound))(*operands)
+    exact = held_reference(*(o.astype(jnp.float32) for o in operands[:1]), top_idx, *(o.astype(jnp.float32) for o in operands[1:]), first)
+    assert y.dtype == jnp.bfloat16
+    scale = float(jnp.max(jnp.abs(exact)))
+    assert float(jnp.max(jnp.abs(y.astype(jnp.float32) - old.astype(jnp.float32)))) <= 2**-7 * scale
+    assert float(jnp.max(jnp.abs(y.astype(jnp.float32) - exact))) <= 4 * 2**-7 * scale
+
+
+def _every_aval(jaxpr):
+    """The variables an equation of ``jaxpr`` binds, and those of every jaxpr
+    in an equation's parameters (a `cond`'s branches, a `scan`'s body, a
+    `custom_vjp`'s call, a `platform_dependent`'s lowerings)."""
+    def inside(value):
+        if hasattr(value, "eqns"):
+            yield from _every_aval(value)
+        elif hasattr(value, "jaxpr"):
+            yield from inside(value.jaxpr)
+        elif isinstance(value, (tuple, list)):
+            for v in value:
+                yield from inside(v)
+
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield var.aval
+        for value in eqn.params.values():
+            yield from inside(value)
+
+
+@pytest.mark.parametrize("which", ["forward", "gradient"])
+def test_no_tensor_of_all_the_slots_is_as_wide_as_a_row(which):
+    """The rehearsal's widths (16 experts, 4 held, 4 a token, rows of 32) at
+    64 tokens and a window of 64 of the 256 slots: nothing in the traced
+    program of the held layer — forward, or `jax.grad` with respect to every
+    operand — has T·k rows (or T x k) of width d; vectors may be that long.
+    The path it replaced, walked the same way, has them."""
+    t, k, d = 64, 4, 32
+    rng = np.random.default_rng(3)
+    top_idx = jnp.asarray(np.stack([rng.permutation(16)[:k] for _ in range(t)]), jnp.int32)
+    operands = (jnp.ones((t, d)), jnp.ones((t, k)), jnp.ones((4, d, 16)), jnp.ones((4, d, 16)), jnp.ones((4, 16, d)))
+
+    def traced(fn):
+        value = lambda *ops: jnp.sum(fn(ops[0], top_idx, *ops[1:], first_expert=4, row_bound=64)[0])
+        run = value if which == "forward" else jax.grad(jax.checkpoint(value), argnums=(0, 1, 2, 3, 4))
+        return jax.make_jaxpr(run)(*operands).jaxpr
+
+    def all_slots_wide(jaxpr):
+        return sorted({
+            a.shape for a in _every_aval(jaxpr)
+            if len(getattr(a, "shape", ())) >= 2 and a.shape[-1] == d and int(np.prod(a.shape[:-1])) >= t * k
+        })
+
+    assert all_slots_wide(traced(moe_dropless_held)) == []
+    assert all_slots_wide(traced(held_before_pr44))  # the walk sees them where they are
+
+
+def test_the_held_layer_runs_its_tpu_lowering_in_the_interpreter_forward_and_backward(monkeypatch):
+    """The grouped matmuls AND the one-hot product that sums a token's rows
+    as the TPU runs them — JAX's Pallas kernels, here interpreted — against
+    the lowering off the TPU: ``y`` and every gradient, with a window that
+    does not fill a row tile and token tiles with no row."""
+    gmm_tpu, sum_tpu = layers._grouped_matmul_tpu, layers._rows_to_tokens_tpu
+    top_idx, first, bound = held_routing("an_expert_with_no_rows")
+    operands = held_operands()
+    probe = jnp.sin(jnp.arange(T_HELD * D_HELD, dtype=jnp.float32)).reshape(T_HELD, D_HELD)
+
+    def run():
+        loss = lambda *ops: jnp.sum(
+            moe_dropless_held(ops[0], top_idx, *ops[1:], first_expert=first, row_bound=bound)[0] * probe
+        )
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*operands)
+
+    want = run()
+    calls = []
+
+    def on_tpu(*args, tpu, default):
+        calls.append(1)
+        return tpu(*args)
+
+    monkeypatch.setattr(layers.jax.lax, "platform_dependent", on_tpu)
+    monkeypatch.setattr(layers, "_grouped_matmul_tpu", lambda *a: gmm_tpu(*a, interpret=True))
+    monkeypatch.setattr(layers, "_rows_to_tokens_tpu", lambda *a: sum_tpu(*a, interpret=True))
+    got = run()
+    assert len(calls) >= 6  # three grouped matmuls, the combine, and the backward's products
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    assert max(grad_errors(got[1], want[1])) < RTOL
+
+
+def test_the_sum_of_a_tokens_rows_traces_at_two_shapes_with_cache_miss_explanations_on():
+    """As ``_grouped_matmul`` above: the combine's product is traced at a row
+    width and at a lane tile (the gates' gradient) in one program."""
+    onehot = jnp.arange(6)[:, None] == jnp.arange(layers._TOKEN_TILE)
+    sizes = jnp.asarray([6], jnp.int32)
+    two = jax.jit(lambda a, b: (layers._rows_to_tokens(onehot, a, sizes, 8), layers._rows_to_tokens(onehot, b, sizes, 8)))
+    with jax.explain_cache_misses(True):
+        wide, narrow = two(jnp.ones((6, 16)), jnp.ones((6, 4)))
+    assert wide.shape == (8, 16) and narrow.shape == (8, 4)
+    assert float(wide[5, 0]) == 1.0 and float(wide[6, 0]) == 0.0  # a row a token, none past the sixth
 
 
 def test_remat_on_and_off_agree():

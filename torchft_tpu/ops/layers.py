@@ -217,53 +217,155 @@ def moe_dropless(
     return y, counts
 
 
-def _window_rows_or_zero(rows: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
-    """``rows[at]`` where ``0 <= at < len(rows)``, a zero row elsewhere."""
-    m = rows.shape[0]
-    padded = jnp.concatenate([rows, jnp.zeros((1, rows.shape[1]), rows.dtype)])
-    inside = (at >= 0) & (at < m)
-    return padded.at[jnp.where(inside, at, m)].get(mode="promise_in_bounds")
+# tokens a group of the one-hot products that add a window's rows to their
+# tokens (the MXU's width), and rows a step of the kernel
+_TOKEN_TILE = 128
+_TOKEN_ROWS = 512
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_window_rows(x: jnp.ndarray, window: jnp.ndarray, at: jnp.ndarray, k: int) -> jnp.ndarray:
-    """``x[window // k]`` for ``window`` m consecutive entries of a
-    permutation of the ``k * len(x)`` row slots; ``at`` [T·k] is each slot's
-    place in that window (outside ``[0, m)``: not in it). The backward
-    gathers each slot's gradient from its place (none, outside) and sums a
-    token's k — no scatter."""
-    return x.at[window // k].get(mode="promise_in_bounds")
+def _rows_to_tokens_tpu(
+    onehot: jnp.ndarray, rows: jnp.ndarray, sizes: jnp.ndarray, interpret: bool = False
+) -> jnp.ndarray:
+    """``interpret``: run the kernel in Pallas's interpreter (the CPU tests)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    m, n = rows.shape
+    tm = min(_TOKEN_ROWS, -(-m // 128) * 128)
+    pad = -m % tm  # whole row tiles; the rows added are past every group
+    narrow = 2 if jnp.dtype(rows.dtype).itemsize > 2 else 1  # as _grouped_matmul_tpu
+    if pad:
+        onehot, rows = (jnp.pad(x, ((0, pad), (0, 0))) for x in (onehot, rows))
+    return tgmm(
+        onehot.T,
+        rows,
+        sizes,
+        preferred_element_type=rows.dtype,
+        tiling=(tm, _TOKEN_TILE, min(_GMM_TILE[2] // narrow, n)),
+        interpret=interpret,
+    )
 
 
-def _take_window_rows_fwd(x, window, at, k):
-    return _take_window_rows(x, window, at, k), at
+def _rows_to_tokens(onehot: jnp.ndarray, rows: jnp.ndarray, sizes: jnp.ndarray, t: int) -> jnp.ndarray:
+    """rows [m, n] in token order, ``sizes`` [G] of them in each tile of 128
+    tokens (summing to at most m: the rows past them count nowhere), onehot
+    [m, 128] each row's weight at its token's place in its tile -> [t, n]:
+    ``onehot[rows of tile g]^T @ rows[rows of tile g]`` for every tile, summed
+    in float32. A sum over runs of rows as one grouped matmul: the transpose
+    of :func:`_grouped_matmul` in its matrices, where a scatter-add would go
+    row by row on the TPU. Two lowerings as there (and fresh closures, as
+    there): the Pallas kernel that ships with JAX on the TPU, XLA's ragged
+    product off it."""
+    ragged = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())), lhs_ragged_dimensions=[0], rhs_group_dimensions=[]
+    )
+
+    def xla(onehot, rows, sizes):
+        # in no tile means read by nothing, whatever the memory holds: the
+        # kernel selects by tile, a masked product would not
+        in_a_tile = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
+        rows = jnp.where(in_a_tile, rows, jnp.zeros_like(rows))
+        tiles = jax.lax.ragged_dot_general(onehot, rows, sizes, ragged, preferred_element_type=jnp.float32)
+        return tiles.astype(rows.dtype)
+
+    tiles = jax.lax.platform_dependent(
+        onehot.astype(rows.dtype),
+        rows,
+        sizes,
+        tpu=lambda *args: _rows_to_tokens_tpu(*args),
+        default=xla,
+    )
+    return tiles.reshape(-1, rows.shape[1])[:t]
 
 
-def _take_window_rows_bwd(k, at, g):
-    back = _window_rows_or_zero(g, at).reshape(-1, k, g.shape[-1]).astype(jnp.float32)
-    return jnp.sum(back, axis=1).astype(g.dtype), None, None
+def _in_token_order(rows: jnp.ndarray, plan) -> jnp.ndarray:
+    """A window's rows from expert order into token order ([m, n]): a gather
+    and nothing else, so the rows past the live ones stay whatever the
+    memory held — :func:`_rows_to_tokens` counts them in no tile."""
+    return rows.at[plan["from_place"]].get(mode="promise_in_bounds", unique_indices=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_window_rows(x: jnp.ndarray, plan, t: int) -> jnp.ndarray:
+    """``x[plan["token"]]``: the [m, d] rows of a window of row slots (see
+    :func:`_window_plan`), ordered by expert. The backward puts the rows'
+    gradients into token order, where a token's are one run of at most k, and
+    sums the runs (:func:`_rows_to_tokens`) — no scatter, and nothing wider
+    than the window."""
+    return x.at[plan["token"]].get(mode="promise_in_bounds")
+
+
+def _take_window_rows_fwd(x, plan, t):
+    return _take_window_rows(x, plan, t), plan
+
+
+def _take_window_rows_bwd(t, plan, g):
+    return _rows_to_tokens(plan["onehot"], _in_token_order(g, plan), plan["tile_sizes"], t), None
 
 
 _take_window_rows.defvjp(_take_window_rows_fwd, _take_window_rows_bwd)
 
 
-@jax.custom_vjp
-def _spread_window_rows(rows: jnp.ndarray, window: jnp.ndarray, at: jnp.ndarray) -> jnp.ndarray:
-    """The window's m computed rows back in their slots ([T·k, d]; a zero
-    row where a slot is not in the window); the backward reads each of the
-    window's slots."""
-    return _window_rows_or_zero(rows, at)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_window_rows(out: jnp.ndarray, top_w: jnp.ndarray, plan, t: int) -> jnp.ndarray:
+    """``y[t] = sum_j top_w[t, j] * out[place of slot (t, j)]`` over the
+    window's slots: the m rows into token order, each weighted by its slot's
+    gate (``plan`` carries it beside the slot: ``top_w`` is here for its
+    gradient), a token's run summed in float32 -> [t, d]. Backward, row by
+    row of the window: ``d out[r] = gate[r] * dy[token r]``,
+    ``d top_w[slot r] = <out[r], dy[token r]>`` and zero at every slot
+    outside the window."""
+    weighted = plan["onehot"] * plan["gate_by_token"][:, None].astype(out.dtype)
+    return _rows_to_tokens(weighted, _in_token_order(out, plan), plan["tile_sizes"], t)
 
 
-def _spread_window_rows_fwd(rows, window, at):
-    return _spread_window_rows(rows, window, at), window
+def _combine_window_rows_fwd(out, top_w, plan, t):
+    return _combine_window_rows(out, top_w, plan, t), (out, plan)
 
 
-def _spread_window_rows_bwd(window, g):
-    return g.at[window].get(mode="promise_in_bounds", unique_indices=True), None, None
+def _combine_window_rows_bwd(t, saved, dy):
+    out, plan = saved
+    k = plan["onehot_copy"].shape[1]
+    live = plan["live"][:, None]  # the live rows are the first of either order
+    g = dy.at[plan["token"]].get(mode="promise_in_bounds")
+    d_out = jnp.where(live, plan["gate"][:, None].astype(g.dtype) * g, jnp.zeros_like(g))
+    d_gate = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=1, keepdims=True)
+    # a row's <out, dy> at its slot's copy j, as wide as a lane tile: the same
+    # sum over runs puts it at [token, j]
+    cells = jnp.where(live & plan["onehot_copy"], d_gate, 0.0).astype(dy.dtype)
+    cells = jnp.pad(cells, ((0, 0), (0, -k % 128)))
+    d_top_w = _rows_to_tokens(plan["onehot"], _in_token_order(cells, plan), plan["tile_sizes"], t)
+    return d_out, d_top_w[:, :k], None
 
 
-_spread_window_rows.defvjp(_spread_window_rows_fwd, _spread_window_rows_bwd)
+_combine_window_rows.defvjp(_combine_window_rows_fwd, _combine_window_rows_bwd)
+
+
+def _window_plan(slots: jnp.ndarray, gate: jnp.ndarray, n_live: jnp.ndarray, t: int, k: int):
+    """What moving a window's rows needs, all of it index-sized ([m] or
+    [m, 128]): ``slots`` [m] are row slots (slot ``t * k + j`` is token t's
+    j-th copy) ordered by expert, the first ``n_live`` of them rows of held
+    experts and in slot order inside an expert; ``gate`` [m] each slot's
+    weight. Token order is slot order, so it takes one sort of m keys, which
+    carries each row's place and gate along."""
+    m = slots.shape[0]
+    place = jnp.arange(m, dtype=jnp.int32)
+    live = place < n_live
+    by_token, from_place, gate_by_token = jax.lax.sort(
+        (jnp.where(live, slots, t * k), place, gate), num_keys=1
+    )
+    tiles = -(-t // _TOKEN_TILE)
+    token = by_token // k
+    tile = jnp.where(live, token // _TOKEN_TILE, tiles)  # the rows past the live ones: in no tile
+    return {
+        "token": slots // k,  # by expert
+        "gate": gate,  # by expert
+        "onehot_copy": (slots % k)[:, None] == jnp.arange(k, dtype=slots.dtype),  # by expert, [m, k]
+        "live": live,
+        "from_place": from_place,  # by token: the row's place in expert order
+        "gate_by_token": gate_by_token,
+        "onehot": (token % _TOKEN_TILE)[:, None] == jnp.arange(_TOKEN_TILE, dtype=token.dtype),  # by token
+        "tile_sizes": jnp.sum(tile[:, None] == jnp.arange(tiles, dtype=tile.dtype), axis=0, dtype=jnp.int32),
+    }
 
 
 def moe_dropless_held(
@@ -285,47 +387,59 @@ def moe_dropless_held(
     (y [T, d], rows held = token-expert rows whose expert is here, int32).
 
     Static shapes without a drop: the row slots are sorted by held expert,
-    absent ones last, and go through the grouped matmuls a window of
+    absent ones last — a sort of the T·k keys, which carries each slot's
+    gate along — and go through the grouped matmuls a window of
     ``row_bound`` slots at a time. A step whose held rows fit the first
     window — every step near balance — computes that window and no more; one
     that exceeds it (``lax.cond``) sums over all the windows in turn, each
     at the first's memory, so every row routed to a held expert contributes
-    whatever the load."""
+    whatever the load.
+
+    Only vectors are T·k long. Everything as wide as a row has the window's m
+    rows or the T tokens, forward and backward: the window's rows are
+    gathered by expert, computed, moved into token order (slot order: a
+    token's rows are then one run of at most k) and the runs summed as a
+    grouped one-hot product (:func:`_rows_to_tokens`)."""
     t, k = top_idx.shape
     held = w_gate.shape[0]
     m = min(row_bound, t * k)
     with jax.named_scope("dispatch"):
         local = top_idx.reshape(t * k) - first_expert
         key = jnp.where((local >= 0) & (local < held), local, held)
-        order = jnp.argsort(key, stable=True)  # row slots by held expert, the absent last
-        inv = jnp.argsort(order)
+        # row slots by held expert, the absent last, and their gates with them
+        _, order, gates = jax.lax.sort(
+            (key, jnp.arange(t * k, dtype=jnp.int32), jax.lax.stop_gradient(top_w).reshape(t * k)),
+            num_keys=1,
+            is_stable=True,
+        )
         counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0, dtype=jnp.int32)
         ends = jnp.cumsum(counts)
         n_held = ends[-1]
         # whole windows: the slots added are past every held row, and masked with them
-        order = jnp.pad(order, (0, -(t * k) % m))
+        order, gates = (jnp.pad(x, (0, -(t * k) % m)) for x in (order, gates))
 
     def window(start) -> jnp.ndarray:
         """What the sorted slots ``[start, start + m)`` add to y."""
         with jax.named_scope("dispatch"):
-            slots = jax.lax.dynamic_slice_in_dim(order, start, m)
-            # a held slot's place in the window. The slots past the held ones
-            # are in no expert's group: the grouped matmul leaves their rows
-            # as it found them (whatever the memory held), so nothing may
-            # read them, forward or backward
-            at = jnp.where(inv < n_held, inv - start, -1)
-            rows = _take_window_rows(tokens, slots, at, k)  # [m, d]
+            # the slots past the held ones are in no expert's group: the
+            # grouped matmul leaves their rows as it found them (whatever the
+            # memory held), so nothing may read them, forward or backward
+            plan = _window_plan(
+                jax.lax.dynamic_slice_in_dim(order, start, m),
+                jax.lax.dynamic_slice_in_dim(gates, start, m),
+                jnp.clip(n_held - start, 0, m),
+                t,
+                k,
+            )
+            rows = _take_window_rows(tokens, plan, t)  # [m, d]
             # each held expert's rows inside the window
             edges = jnp.clip(jnp.concatenate([jnp.zeros((1,), ends.dtype), ends]), start, start + m)
             sizes = edges[1:] - edges[:-1]
         with jax.named_scope("experts"):
             h = jax.nn.silu(_grouped_matmul(rows, w_gate, sizes)) * _grouped_matmul(rows, w_in, sizes)
             out = _grouped_matmul(h, w_out, sizes)
-            # rows past the held ones belong to no expert's group
-            out = jnp.where((start + jnp.arange(m))[:, None] < n_held, out, jnp.zeros_like(out))
         with jax.named_scope("combine"):
-            back = _spread_window_rows(out, slots, at).reshape(t, k, -1)
-            return jnp.einsum("tkd,tk->td", back, top_w.astype(back.dtype))
+            return _combine_window_rows(out, top_w, plan, t)
 
     if m == t * k:
         return window(0), n_held
