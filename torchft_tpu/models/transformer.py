@@ -22,12 +22,20 @@ layer a sequence mixer — ``full`` softmax attention with RoPE, ``window``
 (the same over a band of ``window`` keys, ``window_layers``; each of the two
 kinds with its own query heads over ``n_kv_heads`` grouped key/value heads
 and its own rotation), ``kda`` (gated delta-rule linear attention,
-``ops/kda.py``) or ``mla`` (latent attention without positions) — and a
+``ops/kda.py``) or ``mla`` (latent attention: without positions, or with
+the query's and the shared key's last lanes rotated under ``mla_rope_theta``;
+the query one projection or low-rank under ``q_lora_rank``) — and a
 feed-forward, ``dense`` or ``experts``.
 Its parameters are grouped by kind of layer and the stack runs the leading
 layers one by one, then ``lax.scan`` over whole periods of the pattern with
 the period unrolled inside the body (:func:`layer_pattern`). A model of one
 kind is a period of one: today's tree and today's scan.
+
+A multi-token-prediction module (``n_mtp_modules``) is a subtree
+``params["mtp"]`` run after the main stack: one more layer of the last
+layer's kind on ``[norm(Emb(t_{i+1})) ; norm(h_i)]·eh_proj``, its output
+through the main ``out`` table against the second-next token, its loss added
+at ``mtp_loss_weight`` (:func:`_mtp_hidden`).
 """
 
 from __future__ import annotations
@@ -117,7 +125,7 @@ class TransformerConfig:
     # configurations count them; a layer named in neither list mixes with
     # ``full`` attention.
     kda_layers: Tuple[int, ...] = ()  # gated delta-rule linear attention (ops/kda.py)
-    mla_layers: Tuple[int, ...] = ()  # latent attention without positions
+    mla_layers: Tuple[int, ...] = ()  # latent attention (positions: ``mla_rope_theta``)
     # with experts: this many leading layers keep a dense SwiGLU of d_ff
     n_dense_layers: int = 0
     moe_d_ff: int = 0  # one expert's width; 0 => d_ff (a model of expert layers only)
@@ -141,6 +149,12 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # a low-rank query: q = RMSNorm(x·wq_a)·wq_b through this rank; 0 => the one wq
+    q_lora_rank: int = 0
+    # the rotation of the query's last qk_rope lanes and of the shared key (once,
+    # before it is broadcast over heads), paired as ``rope_pairing`` says, at
+    # this base of its own; 0 => no positions, what a latent layer had before
+    mla_rope_theta: float = 0.0
     # -- kda: linear_n_heads x linear_head_dim keys and values, a causal
     # depthwise convolution of conv_kernel taps on q, k and v; the two
     # low-rank gates (decay, output) are linear_head_dim wide inside
@@ -173,6 +187,12 @@ class TransformerConfig:
     yarn_attention_factor: float = 1.0  # cos and sin are scaled by it
     # the sigmoid gate's selection-only bias leaf; False => the k largest scores
     router_selection_bias: bool = True
+    # -- multi-token prediction: this many modules (0 or 1) behind the main
+    # stack, each a layer of the last layer's kind that shares ``embed`` and
+    # ``out``; the training loss is main + mtp_loss_weight x the module's cross
+    # entropy against the second-next token. Weight 0: the module is not run
+    n_mtp_modules: int = 0
+    mtp_loss_weight: float = 0.1
 
     def __post_init__(self) -> None:
         for name in ("kda_layers", "mla_layers", "window_layers", "n_heads_per_layer"):  # a JSON file gives lists
@@ -212,6 +232,15 @@ class TransformerConfig:
             )
         if (self.n_dense_layers or self.n_shared_experts or self.n_experts_held) and not self.n_experts:
             raise ValueError("n_dense_layers, n_shared_experts and n_experts_held describe a model with experts")
+        if (self.q_lora_rank or self.mla_rope_theta) and not self.mla_layers:
+            raise ValueError("q_lora_rank and mla_rope_theta describe a model with mla_layers")
+        if self.mla_rope_theta and self.qk_rope_head_dim % 2:
+            raise ValueError(f"qk_rope_head_dim={self.qk_rope_head_dim}: a rotation pairs an even number of lanes")
+        if self.n_mtp_modules not in (0, 1):
+            raise ValueError(
+                f"n_mtp_modules={self.n_mtp_modules}: one module predicts the second-next token; the chain that "
+                "hands module k's hidden state to module k + 1 for the token after is missing"
+            )
 
     @property
     def layers_per_stage(self) -> int:
@@ -384,8 +413,15 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
     elif mixer == "mla":
         h, rank = cfg.n_heads, cfg.kv_lora_rank
         nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        if cfg.q_lora_rank:
+            layers.update(
+                wq_a=dense(next(more), d, cfg.q_lora_rank, fan_in=d),
+                q_a_norm=ones(cfg.q_lora_rank),
+                wq_b=dense(keys[0], cfg.q_lora_rank, h * (nope + rope), fan_in=cfg.q_lora_rank),
+            )
+        else:
+            layers.update(wq=dense(keys[0], d, h * (nope + rope), fan_in=d))
         layers.update(
-            wq=dense(keys[0], d, h * (nope + rope), fan_in=d),
             w_kva=dense(keys[1], d, rank + rope, fan_in=d),
             kv_norm=ones(rank),
             w_kvb=dense(keys[2], rank, h * (nope + dv), fan_in=rank),
@@ -429,7 +465,10 @@ def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
     ``layers``, each leaf with leading [pp, Lp] axes. A declared pattern
     (:func:`layer_pattern`): ``lead`` and ``periods``, each a dict by kind of
     layer (``"<mixer>.<ff>"``) of that kind's leaves, with leading axes [n]
-    (the leading layers of the kind) and [repeats, n] (those in a period)."""
+    (the leading layers of the kind) and [repeats, n] (those in a period).
+    A multi-token-prediction module adds ``mtp``: the norms of its two inputs
+    (``enorm``, ``hnorm``), ``eh_proj`` [2d, d], ``layer`` (the leaves of one
+    layer of the last layer's kind, no leading axis) and its ``final_norm``."""
     keys = jax.random.split(rng, 16)
     d = cfg.d_model
 
@@ -443,6 +482,15 @@ def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
         "final_norm": jnp.ones((d,), jnp.float32),
         "out": dense(keys[9], d, cfg.vocab_size, fan_in=d),
     }
+    if cfg.n_mtp_modules:
+        key = jax.random.fold_in(rng, 201)
+        params["mtp"] = {
+            "enorm": jnp.ones((d,), jnp.float32),
+            "hnorm": jnp.ones((d,), jnp.float32),
+            "eh_proj": dense(key, 2 * d, d, fan_in=2 * d),
+            "layer": _init_layers(jax.random.fold_in(key, 1), cfg, cfg.layer_kinds()[-1], ()),
+            "final_norm": jnp.ones((d,), jnp.float32),
+        }
     if _of_one_kind(cfg):
         lead = (max(cfg.pp, 1), cfg.layers_per_stage)
         params["layers"] = _init_layers(rng, cfg, cfg.layer_kinds()[0], lead)
@@ -486,9 +534,12 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
             w_beta=spec("fsdp", "tp"), a_log=spec("tp"), dt_bias=spec("tp"), o_norm=spec(None),
         )
     else:
-        layers.update(
-            wq=row, w_kva=spec("fsdp", None), kv_norm=spec(None), w_kvb=spec(None, "tp"), wo=col,
-        )
+        # the narrow side of both low-rank pairs whole, heads over tp
+        layers.update(w_kva=spec("fsdp", None), kv_norm=spec(None), w_kvb=spec(None, "tp"), wo=col)
+        if cfg.q_lora_rank:
+            layers.update(wq_a=spec("fsdp", None), q_a_norm=spec(None), wq_b=spec(None, "tp"))
+        else:
+            layers.update(wq=row)
     if ff == "experts":
         layers.update(
             router=spec("fsdp", None),
@@ -517,6 +568,11 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "final_norm": P(None),
         "out": P("fsdp", "tp"),
     }
+    if cfg.n_mtp_modules:
+        specs["mtp"] = {
+            "enorm": P(None), "hnorm": P(None), "eh_proj": P("fsdp", "tp"),
+            "layer": _layer_specs(cfg, cfg.layer_kinds()[-1], ()), "final_norm": P(None),
+        }
     if _of_one_kind(cfg):
         specs["layers"] = _layer_specs(cfg, cfg.layer_kinds()[0], ("pp", None))
         return specs
@@ -856,11 +912,12 @@ def _say_once(kind: str, key, **fields) -> None:
     )
 
 
-def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, kind=None) -> None:
+def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, kind=None, latent=None) -> None:
     """One ``attention_path`` event and one INFO line per traced shape — and
     per KIND of layer where a model declares a band or grouped heads
-    (``kind``: its (query heads, key/value heads, window, rotated lanes)) —
-    so a worker's log and event trail say which core every program took and why."""
+    (``kind``: its (query heads, key/value heads, window, rotated lanes)); a
+    latent attention says its query's rank and its rotated lanes (``latent``)
+    — so a worker's log and event trail say which core every program took and why."""
     block_q, block_k = blocks or (0, 0)
     key_dim, value_dim = widths or (cfg.head_dim, cfg.head_dim)
     fields = dict(
@@ -871,6 +928,8 @@ def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, 
     if kind is not None:
         heads, kv_heads, window, rotary_dim = kind
         fields.update(n_heads=heads, n_kv_heads=kv_heads, window=window, rotary_dim=rotary_dim)
+    if latent is not None:
+        fields.update(q_lora_rank=latent[0], rotary_dim=latent[1])
     _say_once("attention_path", (*fields.values(), heads), **fields)
 
 
@@ -885,6 +944,8 @@ def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None
         repeats=(cfg.n_layers - n_lead) // len(period),
         experts_held=cfg.experts_held, experts=cfg.n_experts, batch=batch, seq=seq_len,
     )
+    if cfg.n_mtp_modules:  # the module behind the stack, by the kind of its layer
+        fields.update(mtp=_kind_key(kinds[-1]), mtp_weight=cfg.mtp_loss_weight)
     _say_once("layer_pattern", tuple(fields.values()), **fields)
 
 
@@ -918,7 +979,7 @@ def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int], window: Optional[int]
     )(q, k, v)
 
 
-def _causal_core(cfg, mesh, sp_manual, q, k, v, widths=None, scope="core", window=0, kind=None):
+def _causal_core(cfg, mesh, sp_manual, q, k, v, widths=None, scope="core", window=0, kind=None, latent=None):
     """softmax(QKᵀ)V by the code :func:`_attention_path` picks, said once.
     ``window``: the layer's band; k and v may have fewer heads than q."""
     b, s = q.shape[:2]
@@ -928,7 +989,7 @@ def _causal_core(cfg, mesh, sp_manual, q, k, v, widths=None, scope="core", windo
     impl, why, blocks = _attention_path(
         cfg, s, b, mesh, sp_manual, widths=widths, window=window, grouped=k.shape[2] != q.shape[2]
     )
-    _say_attention_path(impl, why, blocks, b, s, cfg, widths, kind)
+    _say_attention_path(impl, why, blocks, b, s, cfg, widths, kind, latent)
     band = window or None  # the cores' "no band"
     with jax.named_scope(scope):
         if impl == "ring" and sp_manual:
@@ -1003,21 +1064,49 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
 
 
 def _mix_mla(cfg, mesh, sp_manual, lp, h):
-    """Latent attention without positions: per-head keys and values come
-    from one normalised latent, a second key part is shared by all heads
-    and NOT rotated; keys are wider than values."""
+    """Latent attention: per-head keys and values come from one normalised
+    latent, a second key part is shared by all heads; keys are wider than
+    values. The query is one projection, or low-rank (``q_lora_rank``: down,
+    RMSNorm, up). Without positions the shared part is NOT rotated; under
+    ``mla_rope_theta`` it and the query's last ``qk_rope_head_dim`` lanes are,
+    the key once, before it is broadcast over heads."""
     b, s, _ = h.shape
     heads, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    if cfg.mla_rope_theta and sp_size > 1:
+        raise ValueError(
+            f"sp={sp_size} with a rotated shared key: a sequence shard rotates by its own positions "
+            "(axis_index('sp') * s, as _mix_full has them) and the ring (ops/attention.ring_attention_local) "
+            "accumulates in the keys' width; the shard's position offset for the latent layer's two rotations "
+            "and a value width in the ring are missing"
+        )
     with jax.named_scope("mla"):
-        q = (h @ lp["wq"]).reshape(b, s, heads, nope + rope)
-        latent = h @ lp["w_kva"]
-        c = rms_norm(latent[..., :rank], lp["kv_norm"], cfg.norm_eps)
-        kv = (c @ lp["w_kvb"]).reshape(b, s, heads, nope + dv)
-        shared = jnp.broadcast_to(latent[:, :, None, rank:], (b, s, heads, rope))
+        if cfg.q_lora_rank:
+            with jax.named_scope("q_lora"):
+                q = rms_norm(h @ lp["wq_a"], lp["q_a_norm"], cfg.norm_eps) @ lp["wq_b"]
+        else:
+            q = h @ lp["wq"]
+        q = q.reshape(b, s, heads, nope + rope)
+        with jax.named_scope("kv_lora"):
+            latent = h @ lp["w_kva"]
+            c = rms_norm(latent[..., :rank], lp["kv_norm"], cfg.norm_eps)
+            kv = (c @ lp["w_kvb"]).reshape(b, s, heads, nope + dv)
+        shared = latent[:, :, None, rank:]
+        if cfg.mla_rope_theta:
+            with jax.named_scope("rope"):
+                positions = jnp.arange(s)
+                rotation = (
+                    {"inv_freq": yarn_inv_freq(rope, cfg.mla_rope_theta)} if cfg.rope_pairing == "half"
+                    else {"theta": cfg.mla_rope_theta}
+                )
+                q = jnp.concatenate([q[..., :nope], rotary_embed(q[..., nope:], positions, **rotation)], axis=-1)
+                shared = rotary_embed(shared, positions, **rotation)
+        shared = jnp.broadcast_to(shared, (b, s, heads, rope))
         k = jnp.concatenate([kv[..., :nope], shared], axis=-1)
         att = _causal_core(
-            cfg, mesh, sp_manual, q, k, kv[..., nope:], widths=(nope + rope, dv), scope="mla_core"
+            cfg, mesh, sp_manual, q, k, kv[..., nope:], widths=(nope + rope, dv), scope="mla_core",
+            latent=(cfg.q_lora_rank, rope if cfg.mla_rope_theta else 0),
         )
         return att.reshape(b, s, heads * dv) @ lp["wo"]
 
@@ -1078,16 +1167,35 @@ def _mix_kda(cfg, lp, h):
         return jnp.moveaxis(out, 0, 1).reshape(b, s, d)
 
 
+@contextlib.contextmanager
+def _scopes(*names: Optional[str]):
+    """``jax.named_scope``s nested in order; a None is skipped."""
+    with contextlib.ExitStack() as stack:
+        for name in names:
+            if name:
+                stack.enter_context(jax.named_scope(name))
+        yield
+
+
 def _make_layer_fn(
     cfg: TransformerConfig, mesh, sp_manual: bool = False, kind: Optional[Tuple[str, str]] = None,
-    remat_parts: bool = False,
+    remat_parts: bool = False, nested: Optional[str] = None, from_input: bool = False,
 ):
     """The function of one layer of ``kind`` (mixer, feed-forward); absent:
     the one kind a model of one kind has. ``remat_parts``: ``jax.checkpoint``
     (``cfg.remat``) around the mixer and around the feed-forward, each by
     itself, where the caller puts none around the layer — but a ``kda`` mixer,
     which checkpoints itself block by block (:data:`_KDA_BLOCK`): a second one
-    around it would run its forward a third time."""
+    around it would run its forward a third time. ``nested``: a name every op
+    of the layer carries INSIDE its top-level scope (``attn/<nested>/...``: the
+    multi-token-prediction module's), so the six scopes stay the whole.
+    ``from_input``: the layer is handed its leaves as they are stored and each
+    part is a function of the layer's own input — the cast to the compute
+    dtype and the part's norm happen INSIDE its checkpoint. What a checkpoint
+    keeps for the backward is then the layer's input and the stored leaves
+    themselves, not a float32 copy of the norm's input, its output and a
+    second set of weights: under a ``lax.scan`` those are kept once a repeat
+    (0.55 GB a repeat at b2 x s8192 x 2048, PERF.md §6, PR 46)."""
     mixer, ff = kind or cfg.layer_kinds()[0]
     part = (lambda fn: _remat(cfg, fn)) if remat_parts else (lambda fn: fn)
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
@@ -1114,29 +1222,41 @@ def _make_layer_fn(
             "short convolution's last taps) from one sp shard to the next is missing"
         )
 
+    def of_input(fn, norm: str):
+        """``fn(lp, h)`` itself, or under ``from_input`` as a function of the
+        stored leaves and the layer's input."""
+        if not from_input:
+            return fn
+
+        def whole(lp, x):
+            lp = _compute_dtype(lp, cfg.dtype)
+            return fn(lp, rms_norm(x, lp[norm], cfg.norm_eps))
+
+        return whole
+
     def layer_fn(x: jnp.ndarray, lp: Dict[str, Any]):
         """(x, aux): aux is (balance term, tokens per expert[, rows held]) of
         a dropless expert layer and () otherwise."""
         aux = ()
         x = _constrain(x, _act_spec(sp_manual))
-        with jax.named_scope("attn"):
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        with _scopes("attn", nested):
+            h = x if from_input else rms_norm(x, lp["ln1"], cfg.norm_eps)
             if mixer in ("full", "window"):
-                x = x + part(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer))(lp, h)
+                x = x + part(of_input(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer), "ln1"))(lp, h)
             elif mixer == "kda":
-                x = x + _mix_kda(cfg, lp, h)
+                x = x + of_input(functools.partial(_mix_kda, cfg), "ln1")(lp, h)
             else:
-                x = x + part(functools.partial(_mix_mla, cfg, mesh, sp_manual))(lp, h)
+                x = x + part(of_input(functools.partial(_mix_mla, cfg, mesh, sp_manual), "ln1"))(lp, h)
 
-        with jax.named_scope("moe" if ff == "experts" else "ffn"):
-            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+        with _scopes("moe" if ff == "experts" else "ffn", nested):
+            h = x if from_input else rms_norm(x, lp["ln2"], cfg.norm_eps)
             if experts_over_chips:
-                x = x + _ffn_moe_ep(lp, h, cfg)
+                x = x + of_input(functools.partial(_ffn_moe_ep, cfg=cfg), "ln2")(lp, h)
             elif ff == "experts":
-                y, aux = part(functools.partial(_ffn_moe, cfg=cfg))(lp, h)
+                y, aux = part(of_input(functools.partial(_ffn_moe, cfg=cfg), "ln2"))(lp, h)
                 x = x + y
             else:
-                x = x + part(_ffn_dense)(lp, h)
+                x = x + part(of_input(_ffn_dense, "ln2"))(lp, h)
         return _constrain(x, _act_spec(sp_manual)), aux
 
     return layer_fn
@@ -1168,16 +1288,26 @@ def _make_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):
     return stage_fn
 
 
+def _scans_layers(cfg: TransformerConfig) -> bool:
+    """Whether the declared pattern's period is ONE layer: the scan over
+    periods is then a scan over layers with more than one repeat, and the
+    stack takes its leaves as they are stored (``_make_layer_fn``'s
+    ``from_input``)."""
+    return len(layer_pattern(cfg)[1]) == 1
+
+
 def _make_pattern_fn(cfg: TransformerConfig, mesh):
     """The stack of a declared pattern: ``(lead, periods, x) -> (x, aux)``.
     The leading layers run one by one, then ``lax.scan`` over the repeats of
-    the period with its layers unrolled in the body, each layer's mixer and
+    the period with its layers unrolled in the body (a period of one layer:
+    over the layers), each layer's mixer and
     feed-forward under a ``jax.checkpoint`` of their own (``remat_parts``).
     ``aux`` is the expert layers' (see ``layer_fn``), stacked in layer order."""
     n_lead, period = layer_pattern(cfg)
     kinds = cfg.layer_kinds()
+    scans_layers = _scans_layers(cfg)
     fns = {
-        _kind_key(kind): _make_layer_fn(cfg, mesh, kind=kind, remat_parts=True)
+        _kind_key(kind): _make_layer_fn(cfg, mesh, kind=kind, remat_parts=True, from_input=scans_layers)
         for kind in set(kinds)
     }
 
@@ -1190,9 +1320,20 @@ def _make_pattern_fn(cfg: TransformerConfig, mesh):
                 auxes.append(aux)
         return x, auxes
 
+    def one(x, lp):
+        x, aux = fns[_kind_key(period[0])](x, lp)
+        return x, [aux] if aux else []
+
     def pattern_fn(lead, periods, x):
         x, lead_aux = run(lead, _slots(kinds[:n_lead]), x)
-        x, period_aux = jax.lax.scan(lambda x, group: run(group, _slots(period), x), x, periods)
+        if scans_layers:
+            # a period of ONE layer: the scan is over the layers themselves. The leaves' unit axis goes out here, so
+            # that what the layer's checkpoints keep for the backward are the scan's own slices (``from_input``): a
+            # slice taken inside the body is a new array, kept once a repeat
+            layers = jax.tree_util.tree_map(lambda a: a[:, 0], periods[_kind_key(period[0])])
+            x, period_aux = jax.lax.scan(one, x, layers)
+        else:
+            x, period_aux = jax.lax.scan(lambda x, group: run(group, _slots(period), x), x, periods)
         if not lead_aux and not period_aux:
             return x, ()
         # [layers with experts, ...]: the leading ones, then repeat by repeat
@@ -1217,7 +1358,7 @@ def _pipeline_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool):
 
 
 def _embed_lookup(
-    params: Dict[str, Any], tokens: jnp.ndarray, dt
+    params: Dict[str, Any], tokens: jnp.ndarray, dt, nested: Optional[str] = None
 ) -> jnp.ndarray:
     """Embedding gather with EXPLICIT gather partitioning (round-3 review
     missing #2): the table is stored P(None, ("tp","fsdp")) — vocab
@@ -1226,7 +1367,7 @@ def _embed_lookup(
     back to "involuntary full rematerialization", replicating [V,D] on
     every device each step). Only the (much smaller) [B,S,D] activation
     is resharded to the standard spec afterwards."""
-    with jax.named_scope("embed"):
+    with _scopes("embed", nested):
         embed = _constrain(params["embed"].astype(dt), P(None, ("tp", "fsdp")))
         tok = _constrain(tokens, P("dp", "sp"))
         x = jnp.take(embed, tok, axis=0)
@@ -1273,6 +1414,15 @@ def _refuse_pattern_under_pp(cfg: TransformerConfig) -> None:
         )
 
 
+def _refuse_mtp_under_pp(cfg: TransformerConfig) -> None:
+    if cfg.n_mtp_modules and max(cfg.pp, 1) > 1:
+        raise ValueError(
+            f"pp={cfg.pp} with a multi-token-prediction module: the module reads the last stage's hidden state "
+            "beside the first stage's embedding table and runs a layer of its own behind the head; a pipeline "
+            "exit that carries both into the manual region (a head_fn with a layer in parallel/pipeline.py) is missing"
+        )
+
+
 def _hidden_states(
     params: Dict[str, Any],
     tokens: jnp.ndarray,
@@ -1296,7 +1446,9 @@ def _hidden_states(
         _refuse_pattern_under_pp(cfg)
         _say_layer_pattern(cfg, b, s)
         lead, periods = _gradients_apart((params["lead"], params["periods"]))
-        x, aux = _make_pattern_fn(cfg, mesh)(_compute_dtype(lead, dt), _compute_dtype(periods, dt), x)
+        if not _scans_layers(cfg):
+            lead, periods = _compute_dtype(lead, dt), _compute_dtype(periods, dt)
+        x, aux = _make_pattern_fn(cfg, mesh)(lead, periods, x)
         return rms_norm(x, params["final_norm"].astype(dt), cfg.norm_eps), aux
 
     layers = _compute_dtype(params["layers"], dt)
@@ -1361,6 +1513,7 @@ def loss_and_stats(
         )
     if not _of_one_kind(cfg):
         _refuse_pattern_under_pp(cfg)
+    _refuse_mtp_under_pp(cfg)
     if max(cfg.pp, 1) > 1 and mesh is not None:
         # pipelined training path: the head (final norm + unembed + NLL)
         # runs inside the pipeline's manual region on the last stage and
@@ -1370,15 +1523,52 @@ def loss_and_stats(
         return _pipelined_loss(params, tokens, cfg, mesh), {}
     x, aux = _hidden_states(params, tokens, cfg, mesh)
     ce = _cross_entropy(params, x, tokens, cfg, mesh)
+    stats = {}
+    if cfg.n_mtp_modules and cfg.mtp_loss_weight:
+        second, mtp_aux = _mtp_hidden(params, x, tokens, cfg, mesh)
+        mtp_ce = _cross_entropy(params, second, tokens, cfg, mesh, ahead=2)
+        # the module's expert layer is the statistics' last row
+        aux = tuple(jnp.concatenate([a, m[None]]) for a, m in zip(aux, mtp_aux)) if aux else mtp_aux
+        stats = {"main_loss": ce, "mtp_loss": mtp_ce}
+        ce = ce + cfg.mtp_loss_weight * mtp_ce
     if not aux:
-        return ce, {}
+        return ce, stats
     balance = jnp.mean(aux[0])
-    stats = {"tokens_per_expert": aux[1], "balance_loss": balance}
+    stats.update(tokens_per_expert=aux[1], balance_loss=balance)
     if len(aux) > 2:  # under a share: the token-expert rows whose expert is held, a layer
         stats["rows_held"] = aux[2]
     if cfg.router_aux_loss_coef:
         ce = ce + cfg.router_aux_loss_coef * balance
     return ce, stats
+
+
+_MTP = "mtp"  # the name the module's ops carry inside their top-level scopes
+
+
+def _mtp_hidden(params: Dict[str, Any], h: jnp.ndarray, tokens: jnp.ndarray, cfg: TransformerConfig, mesh=None):
+    """(hidden states [B, S, D] after the module's own final norm, the layer's
+    aux) of the multi-token-prediction module: ``x_i = [RMSNorm(Emb(t_{i+1});
+    enorm) ; RMSNorm(h_i; hnorm)]·eh_proj`` with ``h`` the main stack's output
+    after its final norm, one layer of the last layer's kind at positions i,
+    the module's ``final_norm``. ``loss_and_stats`` scores them through the
+    MAIN ``out`` table against the SECOND-next token (the last two positions
+    have no target); ``embed`` and ``out`` are the main model's, so their
+    gradients are the sums over both uses. Same [B, S] shapes throughout:
+    position S-1 reads the wrapped-around token 0, sees no loss and, the
+    layer being causal, is seen by no one."""
+    dt = cfg.dtype
+    # the module's layer is unrolled like a pattern's: see ``_gradients_apart``
+    mtp = _gradients_apart(params[_MTP])
+    e = _embed_lookup(params, jnp.roll(tokens, -1, axis=1), dt, nested=_MTP)
+    with _scopes("embed", _MTP):
+        x = jnp.concatenate(
+            [rms_norm(e, mtp["enorm"].astype(dt), cfg.norm_eps), rms_norm(h, mtp["hnorm"].astype(dt), cfg.norm_eps)],
+            axis=-1,
+        ) @ mtp["eh_proj"].astype(dt)
+    layer_fn = _make_layer_fn(cfg, mesh, kind=cfg.layer_kinds()[-1], remat_parts=True, nested=_MTP, from_input=True)
+    x, aux = layer_fn(x, mtp["layer"])
+    with _scopes("head_loss", _MTP):
+        return rms_norm(x, mtp["final_norm"].astype(dt), cfg.norm_eps), aux
 
 
 # Logit-element budget, per device, above which the loss head chunks the
@@ -1393,8 +1583,13 @@ def _cross_entropy(
     tokens: jnp.ndarray,
     cfg: TransformerConfig,
     mesh=None,
+    ahead: int = 1,
 ) -> jnp.ndarray:
-    """Mean next-token cross entropy of final-norm hidden states ``x``."""
+    """Mean cross entropy of final-norm hidden states ``x`` against the token
+    ``ahead`` positions on (1: the next token; 2: the multi-token-prediction
+    module's, whose ops carry its name inside ``head_loss``); the last
+    ``ahead`` positions have no target."""
+    nested = _MTP if ahead > 1 else None
     b, s = tokens.shape
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     # Long-context memory wall: at s=32k vocab=32k the [B,S,V] f32 logits
@@ -1406,14 +1601,22 @@ def _cross_entropy(
     # dense path stays (its per-device logits are S/sp smaller), so scale
     # very long context under sp by adding sp shards, not chunking.
     if sp == 1 and _per_device_logit_elems(cfg, b, s, mesh) > _LOSS_CHUNK_ELEMS:
-        return _chunked_loss(params, x, tokens, cfg, mesh)
-    with jax.named_scope("head_loss"):
+        return _chunked_loss(params, x, tokens, cfg, mesh, ahead)
+    with _scopes("head_loss", nested):
         logits = (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
-        targets = jnp.roll(tokens, -1, axis=1)
+        targets = jnp.roll(tokens, -ahead, axis=1)
         logprobs = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)[..., 0]
-        mask = jnp.ones_like(nll).at[:, -1].set(0.0)
+        mask = _no_target(jnp.ones_like(nll), ahead)
         return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+def _no_target(mask: jnp.ndarray, ahead: int) -> jnp.ndarray:
+    """``mask`` [B, S] with the last ``ahead`` positions zeroed, a position an
+    update: at ``ahead`` 1 the one update every model's program had."""
+    for i in range(1, ahead + 1):
+        mask = mask.at[:, -i].set(0.0)
+    return mask
 
 
 def _per_device_logit_elems(
@@ -1439,14 +1642,15 @@ def _chunked_loss(
     tokens: jnp.ndarray,
     cfg: TransformerConfig,
     mesh=None,
+    ahead: int = 1,
 ) -> jnp.ndarray:
     """Cross entropy without materializing [B, S, V]: scan the unembed +
     softmax over sequence chunks (:func:`_chunked_nll`). Same numbers as
     the dense path (f32 log-sum-exp per position; accumulation order
     differs only in the final f32 sums)."""
     b, s = tokens.shape
-    targets = jnp.roll(tokens, -1, axis=1)
-    mask = jnp.ones((b, s), jnp.float32).at[:, -1].set(0.0)
+    targets = jnp.roll(tokens, -ahead, axis=1)
+    mask = _no_target(jnp.ones((b, s), jnp.float32), ahead)
 
     # chunk size straight from the per-device budget; s needn't divide —
     # the tail chunk is padded and masked out (any s, prime or odd, gets
@@ -1465,6 +1669,9 @@ def _chunked_loss(
     hs = jnp.moveaxis(h.reshape(b, n_chunks, chunk, -1), 1, 0)
     ts = jnp.moveaxis(targets.reshape(b, n_chunks, chunk), 1, 0)
     ms = jnp.moveaxis(mask.reshape(b, n_chunks, chunk), 1, 0)
+    if ahead > 1:
+        with _scopes("head_loss", _MTP):
+            return _chunked_nll_mtp(hs, params["out"], ts, ms)
     with jax.named_scope("head_loss"):
         return _chunked_nll(hs, params["out"], ts, ms)
 
@@ -1534,6 +1741,22 @@ def _chunked_nll_bwd(res, g):
 
 
 _chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
+
+
+@jax.custom_vjp
+def _chunked_nll_mtp(hs, out, ts, ms):
+    """:func:`_chunked_nll` as the multi-token-prediction module calls it: the
+    same forward rule, the backward's two products under the module's name."""
+    return _chunked_nll.__wrapped__(hs, out, ts, ms)
+
+
+def _chunked_nll_mtp_bwd(res, g):
+    dhs, d_out = res
+    with _scopes("head_loss", _MTP):
+        return (g * dhs).astype(dhs.dtype), (g * d_out).astype(d_out.dtype), None, None
+
+
+_chunked_nll_mtp.defvjp(_chunked_nll_fwd, _chunked_nll_mtp_bwd)
 
 
 def _pipelined_loss(

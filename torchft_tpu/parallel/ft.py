@@ -176,6 +176,24 @@ class FTTrainer(SpeculativeCommitMixin):
         with tracing.annotate("moe.counters", **counters):
             pass
 
+    def _record_mtp_counters(self, step: int, sync_span) -> None:
+        """The two terms of the step's loss where a multi-token-prediction
+        module ran (``loss_and_stats``: ``main_loss``, ``mtp_loss``), fetched
+        with the loss: on the ``loss_sync`` span in the Tracer ring and, as
+        ``tft.mtp.counters``, in a profiler trace. Any other model emits nothing."""
+        stats = self._ts.last_stats
+        if "mtp_loss" not in stats:
+            return
+        counters = dict(
+            step=step,
+            main_loss=float(stats["main_loss"]),
+            mtp_loss=float(stats["mtp_loss"]),
+            mtp_weight=float(self._ts.cfg.mtp_loss_weight),
+        )
+        sync_span.set(**counters)
+        with tracing.annotate("mtp.counters", **counters):
+            pass
+
     # -- drive --
 
     def step(self, tokens) -> Tuple[float, bool]:
@@ -234,5 +252,6 @@ class FTTrainer(SpeculativeCommitMixin):
             with TRACER.span("loss_sync") as sync_span:
                 loss = float(loss)
                 self._record_moe_counters(label, sync_span)
+                self._record_mtp_counters(label, sync_span)
             step_span.set(committed=committed)
         return loss, committed

@@ -56,10 +56,11 @@ class TrainStep:
             is_leaf=like_params,
         )
 
-        # router statistics of the last step (``loss_and_stats``: tokens per
-        # expert per layer, the balance term), left on the device: whoever
-        # wants them fetches them (``FTTrainer.step``, with the loss). ``{}``
-        # for a model without dropless experts, which emits none.
+        # statistics of the last step (``loss_and_stats``: tokens per expert
+        # per layer and the balance term of dropless experts; ``main_loss`` and
+        # ``mtp_loss`` where a multi-token-prediction module ran), left on the
+        # device: whoever wants them fetches them (``FTTrainer.step``, with
+        # the loss). ``{}`` for a model with neither.
         self.last_stats: Dict[str, jnp.ndarray] = {}
 
         def compute_loss(params, tokens):
