@@ -5,12 +5,14 @@ parent's (the layer kinds and the reference are ``tests/test_mla_rope_mtp.py``'s
 a file of its own, so that these compile-heavy tests are handed to a worker of
 their own in a run with several."""
 
+import functools
 import hashlib
 import json
 import os
 import re
 from concurrent.futures import ThreadPoolExecutor
 from datetime import timedelta
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +21,7 @@ import optax
 import pytest
 
 from tests.test_mla_rope_mtp import ROOT, SIZES, STACK, make, ref
+from tests.test_window_gqa import kernel_equations
 from torchft_tpu.models import transformer as T
 from torchft_tpu.models.transformer import TransformerConfig, init_params, loss_fn
 from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -26,24 +29,58 @@ from torchft_tpu.parallel.train_step import TrainStep
 
 
 CELLS_PROGRAMS = {
-    # sha256 of the jaxpr of loss_fn's value and gradient at the cell's sizes (b2 x s8192, the chip's branch) at the
-    # parent commit (625ab82): a latent layer with q_lora_rank 0 and no mla_rope_theta, window and grouped layers, the
-    # held experts and the head trace to the program they traced to before this file's fields existed, letter for letter
-    "kimi-linear-1g": "c74dd6980153edfe409c707354a73c6d041433f33d27868230ae8083163857cf",
-    "laguna-xs2-1g": "d55d8dc15e9fd85dd026a449e9ef5a11e0e8fae4d9c6bc4aad17e760ec6a30cc",
+    # sha256 of the jaxpr of loss_fn's value and gradient at the cell's sizes (b2 x s8192, the chip's branch): a latent
+    # layer with q_lora_rank 0 and no mla_rope_theta, window and grouped layers, the held experts and the head trace to
+    # the program they traced to before this file's fields existed — but for what PR 47 moved on purpose (at 625ab82
+    # and 6356440: c74dd6980153edfe…57cf and d55d8dc15e9fd85d…30cc). Old text against new, equation by equation with
+    # the variables renumbered (primitive counts of the whole program, kernels' bodies left out): two ``name``
+    # equations a call of the flash kernel (``attn_core_out``, ``attn_core_lse``: 1 call in kimi-linear-1g, 5 in
+    # laguna-xs2-1g), one ``reduce_precision`` a call (``jax.checkpoint`` puts it on what a policy lets it keep), the
+    # policy of every ``_remat`` checkpoint (None -> ``save_only_these_names``; ``_mix_kda``'s bare one stays None),
+    # and ONE ``pallas_call`` a softmax layer gone: the ``flash_fwd`` of the recomputation. Nothing else.
+    "kimi-linear-1g": "2ca37b1ff0102ed43cfd929ffe6dd7b7d2304c9d67b53a83574a19f3d6a6927d",
+    "laguna-xs2-1g": "bd50d408b7ed0e9d2876d862737ce952d20700046ef4b41ccb917b5f1854eeb0",
+}
+CELLS_KERNELS = {
+    # the programs' ``pallas_call`` equations, each printed on its own (``tests/test_window_gqa.kernel_equations``):
+    # the flash kernel's by digest, the others' (KDA's, the grouped matmuls') as one digest of theirs. Every one is,
+    # letter for letter, an equation the parent's program (6356440) held; what the parent held besides is the forward
+    # of each checkpoint's recomputation and the forward pass's own call with its row statistics unread (the same
+    # equation but for ``_`` where the statistics are bound): kimi-linear-1g ``flash_fwd`` 0a203b9237cb127a +
+    # 55a80147b80989f2 -> 0a203b9237cb127a; laguna-xs2-1g 2 x 397a704a4de61c10 + 3 x 63c7d8251e46e74c +
+    # 3 x 5c4213ebc846dd3e + 2 x 8e4669ff79cb390d -> the last five; ``flash_bwd`` and the rest equal at both commits.
+    "kimi-linear-1g": ({"flash_fwd": ["0a203b9237cb127a"], "flash_bwd": ["79821667815a0b73"]}, "b428188ee614c248"),
+    "laguna-xs2-1g": (
+        {"flash_fwd": ["5c4213ebc846dd3e"] * 3 + ["8e4669ff79cb390d"] * 2, "flash_bwd": ["c8ac0340a8878e7c"] * 3 + ["e221363c77edb6f9"] * 2},
+        "1c7e2d9585a8dd16",
+    ),
 }
 
 
-@pytest.mark.parametrize("name", list(CELLS_PROGRAMS))
-def test_the_two_patterned_cells_programs_are_unchanged(name, monkeypatch):
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+@functools.lru_cache(maxsize=None)
+def _cells_program(name):
+    """The chip's branch of the cell's program, traced once for both tests of it."""
     with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
         tc = json.load(f)["program"]["transformer_config"]
     cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda p, t: loss_fn(p, t, cfg)))(params, jax.ShapeDtypeStruct((2, 8192), jnp.int32))
-    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return jax.make_jaxpr(jax.value_and_grad(lambda p, t: loss_fn(p, t, cfg)))(params, tokens)
+
+
+@pytest.mark.parametrize("name", list(CELLS_PROGRAMS))
+def test_the_two_patterned_cells_programs_are_unchanged(name):
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(_cells_program(name)))
     assert hashlib.sha256(text.encode()).hexdigest() == CELLS_PROGRAMS[name]
+
+
+@pytest.mark.parametrize("name", list(CELLS_KERNELS))
+def test_the_two_patterned_cells_kernel_equations_are_the_parents(name):
+    kernels = kernel_equations(_cells_program(name).jaxpr)
+    flash = {k: kernels.pop(k) for k in ("flash_fwd", "flash_bwd")}
+    others = hashlib.sha256(json.dumps(kernels, sort_keys=True).encode()).hexdigest()[:16]
+    assert (flash, others) == CELLS_KERNELS[name]
 
 
 # -- the share -----------------------------------------------------------------------------------

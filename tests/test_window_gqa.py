@@ -342,14 +342,26 @@ def test_what_the_kernel_refuses():
         flash_attention(q, q, q, causal=False, window=8, interpret=True)
 
 
-# digest of the jaxpr of the kernel's forward and backward calls, and sums of |o|, |dq|, |dk|, |dv| at small sizes,
-# at the parent commit (24d5069): the shapes the four existing cells call the kernel with — 16 heads x 128 at b8 x
-# s2048 (olmo1b-*, olmoe-1g) and the latent attention's 32 heads, keys 192 and values 128, at b2 x s8192
-# (kimi-linear-1g), both at 512 x 512 tiles — trace to the call they traced to before heads could be grouped
-# or banded, letter for letter
+# digest of the jaxpr of the kernel's forward and backward calls, and sums of |o|, |dq|, |dk|, |dv| at small sizes:
+# the shapes the four existing cells call the kernel with — 16 heads x 128 at b8 x s2048 (olmo1b-*, olmoe-1g) and
+# the latent attention's 32 heads, keys 192 and values 128, at b2 x s8192 (kimi-linear-1g), both at 512 x 512 tiles.
+# CELLS_NUMBERS and CELLS_KERNELS are the parent commit's (24d5069, and still 6356440's): the kernels trace to the
+# calls they traced to before heads could be grouped or banded, letter for letter. CELLS_CALLS was moved on purpose by
+# PR 47 (at 6356440: c60a781bc5ad8db8…84b0 and d02d2ac3ca2b5c0f…a630): the forward rule names the output and the row
+# statistics (``checkpoint_name``), so the text of the call's ``vjp`` holds two equations more,
+#     ho:bf16[8,2048,2048] = name[name=attn_core_out] bs
+#     hp:f32[8,16,8,2048] = name[name=attn_core_lse] bt
+# (``ia``/``ib`` over ``ce``/``cf`` at the latent shape) and every variable after them is lettered two further on; the
+# diff of the old text and the new, variables renumbered, is those two lines and the two reads of ``o`` and ``lse`` by
+# ``flash_bwd``. The two ``pallas_call`` equations, each printed on its own (its variables then start at ``a``), are
+# the parent's to the letter: CELLS_KERNELS holds at both commits.
 CELLS_CALLS = {
-    (8, 2048, 16, 128, 128): "c60a781bc5ad8db8ef818cfa2f68a18ba714c653c98f51d08bd2548f128084b0",
-    (2, 8192, 32, 192, 128): "d02d2ac3ca2b5c0f5ac4efab01d805679345c72750d4f517f2ccf137d6aaa630",
+    (8, 2048, 16, 128, 128): "408e87229cf4f06b047adef4c584f43fa0ead0fe35063258ae3aa313a7e3f904",
+    (2, 8192, 32, 192, 128): "a013f26ac293e4c790547f1954692e5989c0b72a6eeb964093f58e5cead41005",
+}
+CELLS_KERNELS = {
+    (8, 2048, 16, 128, 128): {"flash_fwd": ["7389ffc81f497cdf"], "flash_bwd": ["dd0bca565252ddb6"]},
+    (2, 8192, 32, 192, 128): {"flash_fwd": ["0a203b9237cb127a"], "flash_bwd": ["79821667815a0b73"]},
 }
 CELLS_NUMBERS = {
     (1, 1024, 2, 128, 128, 512, 0): [20163.15625, 18679.63671875, 15094.607421875, 15714.056640625],
@@ -358,8 +370,38 @@ CELLS_NUMBERS = {
 }
 
 
-@pytest.mark.parametrize("shape", list(CELLS_CALLS))
-def test_the_existing_cells_calls_of_the_kernel_are_unchanged(shape):
+def jaxprs_in(value):
+    """The jaxprs a parameter of an equation holds, open or closed, alone or in a tuple."""
+    subs = (getattr(sub, "jaxpr", sub) for sub in (value if isinstance(value, (tuple, list)) else (value,)))
+    return [sub for sub in subs if hasattr(sub, "eqns")]
+
+
+def sub_jaxprs(eqn):
+    """The jaxprs among an equation's parameters: a scan's body, a cond's
+    branches, a checkpoint's, a custom rule's, a kernel's."""
+    return [sub for value in eqn.params.values() for sub in jaxprs_in(value)]
+
+
+def every_equation(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in sub_jaxprs(eqn):
+            yield from every_equation(sub)
+
+
+def kernel_equations(jaxpr):
+    """Kernel name -> sorted digests of its ``pallas_call`` equations anywhere
+    in ``jaxpr``, each printed on its own: an equation's text then names its
+    variables from ``a`` on, whatever the program around it holds."""
+    out = {}
+    for eqn in every_equation(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            text = re.sub(r"0x[0-9a-f]+", "0x", str(eqn))
+            out.setdefault(str(eqn.params["name"]), []).append(hashlib.sha256(text.encode()).hexdigest()[:16])
+    return {name: sorted(digests) for name, digests in out.items()}
+
+
+def _the_calls_vjp(shape):
     b, s, h, dk, dv = shape
     q = jax.ShapeDtypeStruct((b, s, h, dk), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16)
@@ -368,8 +410,18 @@ def test_the_existing_cells_calls_of_the_kernel_are_unchanged(shape):
         call = lambda *a: flash_attention(*a, causal=True, block_q=512, block_k=512, interpret=False)
         return jax.vjp(call, q, k, v)[1](jnp.ones((b, s, h, dv), jnp.bfloat16))
 
-    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(both)(q, q, v)))
+    return jax.make_jaxpr(both)(q, q, v)
+
+
+@pytest.mark.parametrize("shape", list(CELLS_CALLS))
+def test_the_existing_cells_calls_of_the_kernel_are_unchanged(shape):
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(_the_calls_vjp(shape)))
     assert hashlib.sha256(text.encode()).hexdigest() == CELLS_CALLS[shape]
+
+
+@pytest.mark.parametrize("shape", list(CELLS_KERNELS))
+def test_the_existing_cells_kernel_equations_are_the_parents(shape):
+    assert kernel_equations(_the_calls_vjp(shape).jaxpr) == CELLS_KERNELS[shape]
 
 
 @pytest.mark.parametrize("shape", list(CELLS_NUMBERS))

@@ -104,8 +104,11 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16  # compute dtype (MXU-native)
     remat: bool = True
     # checkpoint policy under remat: "all" recomputes the whole layer in
-    # the backward (lowest memory); "dots" saves matmul outputs and
-    # recomputes only elementwise/softmax (MXU work runs once — the
+    # the backward (lowest memory) but for the flash kernel's forward, whose
+    # output [B, S, H·Dv] and row statistics [B, H, 8, S] f32 are kept where
+    # the kernel is the core (``_remat``: the kernel runs once a layer a
+    # step; a path without it keeps nothing); "dots" saves matmul outputs
+    # too and recomputes only elementwise/softmax (MXU work runs once — the
     # round-5 sweet spot at short S where memory isn't the constraint)
     remat_policy: str = "all"
     pp: int = 1  # pipeline stages; n_layers % pp == 0
@@ -1262,19 +1265,32 @@ def _make_layer_fn(
     return layer_fn
 
 
+@functools.lru_cache(maxsize=None)
+def _remat_policy(name: str):
+    """What ``jax.checkpoint`` may keep under ``remat_policy`` ``name``. What only the flash kernel's forward can give
+    its backward, output and row statistics, is kept under either policy: the kernel's forward then runs once a layer
+    a step (ops/pallas/flash_attention.py, "Remat"). The names do not occur on a path without the kernel, whose
+    program is what policy None traced. ONE object a name, as None was: JAX keys what it caches of a checkpoint on its
+    policy, and a fresh closure a layer has every unrolled layer of a pattern traced and lowered anew."""
+    from torchft_tpu.ops.pallas.flash_attention import CORE_LSE, CORE_OUT
+
+    core = jax.checkpoint_policies.save_only_these_names(CORE_OUT, CORE_LSE)
+    if name == "all":
+        return core
+    if name == "dots":
+        return jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable, core
+        )
+    raise ValueError(
+        f"remat_policy={name!r}: expected 'all' or "
+        "'dots' (a typo here would silently pay full recompute)"
+    )
+
+
 def _remat(cfg: TransformerConfig, layer_fn):
     if not cfg.remat:
         return layer_fn
-    if cfg.remat_policy == "dots":
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    elif cfg.remat_policy == "all":
-        policy = None
-    else:
-        raise ValueError(
-            f"remat_policy={cfg.remat_policy!r}: expected 'all' or "
-            "'dots' (a typo here would silently pay full recompute)"
-        )
-    return jax.checkpoint(layer_fn, policy=policy)
+    return jax.checkpoint(layer_fn, policy=_remat_policy(cfg.remat_policy))
 
 
 def _make_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool = False):

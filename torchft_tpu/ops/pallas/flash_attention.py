@@ -49,6 +49,18 @@ the q tile once (in f32, rounded to the storage dtype). On CPU the kernels
 run under ``interpret=True`` so unit tests check numerics against
 ``ops.attention``.
 
+Remat: the backward kernel's residuals are (q, k, v, o, lse). q, k and v
+are projections a ``jax.checkpoint`` around the layer computes again; o and
+lse only the forward kernel can give, and both lie in HBM once it has run.
+The forward rule names them (:data:`CORE_OUT`, :data:`CORE_LSE`,
+``jax.ad_checkpoint.checkpoint_name``) and hands the NAMED arrays on as the
+output and as the residuals, so a checkpoint whose policy saves the two names
+(``models/transformer._remat``, policies "all" and "dots" alike) keeps
+[B, S, H·Dv] in the storage dtype and [B, H, 8, S] float32 a call, and its
+recomputation holds no forward call: the kernel's forward runs once a layer
+a step, not twice (PERF.md §6, PR 47). Without such a checkpoint a name is
+the identity.
+
 Role: at the tiles ``models/transformer._flash_blocks`` picks it is the
 speed path of ``attention_impl`` "auto" on a TPU (the policy and its
 measured table: ``_attention_path`` there and PERF.md §6, PR 31), and at
@@ -70,10 +82,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "CORE_OUT", "CORE_LSE"]
+
+# ``checkpoint_name``s of what only the forward kernel can give the backward: its output and its rows' logsumexp
+CORE_OUT = "attn_core_out"
+CORE_LSE = "attn_core_lse"
 
 _NEG_INF = -1e30
 _LANES = 128  # one lane tile: a head of that many columns is read in place
@@ -404,6 +421,9 @@ def _flash(q, k, v, shape, blocks, causal, interpret):
 
 def _flash_fwd(q, k, v, shape, blocks, causal, interpret):
     o, lse = _fwd(q, k, v, shape, blocks, causal, interpret)
+    # the named arrays are the output AND the residuals: under a ``jax.checkpoint`` whose policy saves the two names
+    # nothing reads the un-named ones, so the recomputation holds no forward call (the module's docstring, "Remat")
+    o, lse = checkpoint_name(o, CORE_OUT), checkpoint_name(lse, CORE_LSE)
     return o, (q, k, v, o, lse)
 
 
