@@ -87,6 +87,99 @@ class TestSerialization:
         assert to_host_tree(params)["w"] is params["w"]
 
 
+def _landing_state(kind: str):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    if kind == "dense":
+        return {
+            "w": jnp.arange(24, dtype=jnp.float32).reshape(4, 6),
+            "h": jnp.ones((3, 5), dtype=jnp.bfloat16),
+            "count": jnp.int32(7),
+            "empty": jnp.zeros((0, 4), dtype=jnp.float32),
+            "host": np.arange(5, dtype=np.int64),
+            "obj": ("note", 3),
+        }
+    mesh = make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
+    split = jax.device_put(
+        jnp.arange(16, dtype=jnp.float32).reshape(4, 4),
+        NamedSharding(mesh, P(None, "tp")),
+    )
+    whole = jax.device_put(
+        jnp.arange(6, dtype=jnp.float32), NamedSharding(mesh, P())
+    )
+    return {"split": split, "whole": whole, "lr": 0.1}
+
+
+class TestLanding:
+    """``Flattening``: a bounded number of copies off the device is in
+    flight beyond the one being read, and what lands is what
+    ``flatten_state`` returned before it existed."""
+
+    @pytest.mark.parametrize("kind", ["dense", "sharded"])
+    def test_copies_are_issued_a_few_ahead_of_the_read(self, kind, monkeypatch):
+        import jax
+
+        from torchft_tpu.checkpointing import serialization as ser
+
+        events = []
+        impl = type(jax.numpy.zeros(1))
+        real_issue, real_to_host = impl.copy_to_host_async, ser._to_host
+
+        def issue(self):
+            events.append("issue")
+            return real_issue(self)
+
+        def to_host(leaf, copy=False):
+            events.append("read")
+            return real_to_host(leaf, copy=copy)
+
+        monkeypatch.setattr(impl, "copy_to_host_async", issue)
+        monkeypatch.setattr(ser, "_to_host", to_host)
+        assert ser._COPIES_AHEAD == 2
+        flat = ser.Flattening(_landing_state(kind))
+        assert events == []  # nothing moves before the stream is read
+        bufs = list(flat.buffers())
+        want = {
+            # count, empty, h | host (no copy to issue), w
+            "dense": ["issue"] * 3 + ["read", "read", "issue", "read", "read", "read"],
+            # two distinct shards of the split leaf, the replicated leaf once
+            "sharded": ["issue"] * 3 + ["read"] * 3,
+        }[kind]
+        assert events == want
+        assert flat.nbuffers == len(bufs) == events.count("read")
+        assert flat.nbytes == sum(int(b.nbytes) for b in bufs)
+        assert all(a is b for a, b in zip(flat.landed, bufs))
+
+    @pytest.mark.parametrize("kind", ["dense", "sharded"])
+    def test_header_is_complete_once_everything_landed(self, kind):
+        from torchft_tpu.checkpointing.serialization import (
+            Flattening,
+            buffer_sizes,
+        )
+        import pickle
+
+        state = _landing_state(kind)
+        flat = Flattening(state)
+        landing = flat.buffers()
+        first = next(landing)
+        with pytest.raises(AssertionError, match="exhausted"):
+            flat.header
+        bufs = [first] + list(landing)
+        header, again = flatten_state(state)
+        assert flat.header == header
+        assert [b.tobytes() for b in bufs] == [b.tobytes() for b in again]
+        _, infos = pickle.loads(header)
+        assert buffer_sizes(infos) == [int(b.nbytes) for b in bufs]
+        if kind == "dense":
+            # a 0-d device leaf travels as one element, as it always has
+            by_shape = {i[2]: i for i in infos if i[0] == "arr"}
+            assert (1,) in by_shape and by_shape[(1,)][1] == "int32"
+
+
 class TestRWLock:
     def test_readers_shared_writer_exclusive(self):
         lock = RWLock(timeout=1.0)

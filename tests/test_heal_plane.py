@@ -232,9 +232,9 @@ class TestStripedMultiSource:
         monkeypatch.setenv("TORCHFT_HEAL_META_TIMEOUT_S", "0.3")
         real = dm.leaf_digests
 
-        def slow_digests(buffers):
+        def slow_digests(buffers, **kw):
             time.sleep(1.5)  # well past the 0.3 s bound
-            return real(buffers)
+            return real(buffers, **kw)
 
         monkeypatch.setattr(dm, "leaf_digests", slow_digests)
         state = _state(9)
@@ -494,3 +494,282 @@ class TestQuorumHealSources:
 
         out = _native.compute_quorum_results(self._quorum([4, 4, 4]), "g1", 0)
         assert out["heal_pending"] is False
+
+
+# ---------------------------------------------------------------------------
+# leaves side by side (ISSUE 49): pooled digests, several streams a source,
+# a destination that is never zeroed
+# ---------------------------------------------------------------------------
+
+
+def _digest_tree(kind: str):
+    import ml_dtypes
+
+    rng = np.random.default_rng(49)
+
+    def big(n):
+        return rng.integers(0, 255, n, dtype=np.uint8)
+
+    if kind == "zero_length":
+        return {
+            "a": big(5 << 20).view(np.float32),
+            "e": np.zeros(0, np.float32),
+            "b": big(5 << 20),
+        }
+    if kind == "bfloat16":
+        return {
+            "a": big(6 << 20).view(ml_dtypes.bfloat16).reshape(-1, 1024),
+            "b": big(6 << 20).view(np.float32),
+        }
+    if kind == "dominant":
+        return {
+            "w": big(24 << 20),
+            "x": big(1 << 10),
+            "y": big(3 << 10),
+            "z": np.arange(7, dtype=np.int64),
+        }
+    assert kind == "many"
+    return {f"l{i:02d}": big((1 << 20) + i * 4096) for i in range(37)}
+
+
+# (tree digest, first leaf's, last leaf's) as the parent commit 3ca545f
+# computes them for the same trees: the digests are the protocol's
+PARENT_DIGESTS = {
+    "zero_length": ("3c70439c2605b890", "80060e1b309087f2", "e4a6a0577479b2b4"),
+    "bfloat16": ("0f78d6e456b90768", "19b961dd491f4fd3", "d960e781cdd3222a"),
+    "dominant": ("efac0d2609348518", "dd757824cc4d059b", "f1b4a959661d99e0"),
+    "many": ("a6078d664bd87f44", "7a9c3d0ee0847bc8", "064a16b5e33725bf"),
+}
+
+
+class TestPooledDigests:
+    @pytest.mark.parametrize("workers", [2, 3, 8])
+    @pytest.mark.parametrize("kind", sorted(PARENT_DIGESTS))
+    def test_pooled_equals_inline_in_order(self, kind, workers):
+        _, bufs = flatten_state(_digest_tree(kind))
+        inline = dm.leaf_digests(bufs)
+        # handed over one by one, as buffers still landing are
+        pooled = dm.leaf_digests(iter(bufs), workers=workers)
+        assert pooled == inline
+        tree, first, last = PARENT_DIGESTS[kind]
+        assert (inline[0], inline[-1]) == (first, last)
+        assert dm.tree_digest(pooled) == tree
+
+    @pytest.mark.parametrize(
+        "nbytes,nbuffers,cores,want",
+        [
+            (0, 0, 8, 1),
+            (7 << 20, 40, 8, 1),  # under twice a worker's worth: inline
+            (8 << 20, 40, 8, 2),
+            (7_304_702_144, 40, 30, 16),  # the four-group cell's state
+            (7_304_702_144, 40, 4, 4),  # no more than the cores seen
+            (7_304_702_144, 3, 30, 3),  # one buffer is one stream
+        ],
+    )
+    def test_width_follows_cores_leaves_bytes(
+        self, monkeypatch, nbytes, nbuffers, cores, want
+    ):
+        monkeypatch.setattr(
+            dm.os, "sched_getaffinity", lambda _pid: set(range(cores))
+        )
+        assert dm.digest_workers(nbytes, nbuffers) == want
+
+    def test_small_state_starts_no_thread(self, transports, monkeypatch):
+        def no_pool(*a, **kw):
+            raise AssertionError("a state under the threshold hashed on a pool")
+
+        monkeypatch.setattr(dm, "ThreadPoolExecutor", no_pool)
+        before = {t.ident for t in threading.enumerate()}
+        srv = transports()
+        during = {t.ident for t in threading.enumerate()} - before
+        srv.send_checkpoint([1], 1, _state(1), T)
+        assert srv._stage_stats["digest_workers"] == 1
+        # nothing but the transport's own server thread came to be
+        assert {t.ident for t in threading.enumerate()} - before == during
+
+    def test_large_state_staged_on_a_pool(self, transports, monkeypatch):
+        monkeypatch.setattr(
+            dm.os, "sched_getaffinity", lambda _pid: set(range(4))
+        )
+        names = []
+        real = dm._digest
+
+        def spy(buf):
+            names.append(threading.current_thread().name)
+            return real(buf)
+
+        monkeypatch.setattr(dm, "_digest", spy)
+        state = _digest_tree("many")
+        srv = transports()
+        srv.send_checkpoint([1], 1, state, T)
+        stage = srv._stage_stats
+        assert stage["digest_workers"] == 4
+        assert len(names) == 37
+        assert all(n.startswith("tft_heal_digest") for n in names)
+        # joined when staging ends: the pool takes no core from a step
+        assert not [
+            t for t in threading.enumerate()
+            if t.name.startswith("tft_heal_digest")
+        ]
+        assert srv._tree_digest == PARENT_DIGESTS["many"][0]
+        assert stage["stage_s"] >= stage["d2h_s"] + stage["digest_s"] - 1e-3
+
+
+def _slow_fetches(monkeypatch, native: bool, delay: float = 0.15, fail=None):
+    """Make every range fetch take ``delay`` s, so that the ranges of one
+    pass are in flight together, and let ``fail(src_port)`` refuse a
+    source's ranges after that delay. Returns the list of fetches made,
+    ``(port, offset, length, ok)``."""
+    from torchft_tpu import _native
+    from torchft_tpu.checkpointing import http_transport as ht
+
+    calls = []
+    if native:
+        real = _native.blob_fetch
+
+        def fetch(host, port, token, off, length, view, timeout_ms=60000):
+            time.sleep(delay)
+            if fail is not None and fail(port):
+                calls.append((port, off, length, False))
+                raise ConnectionError(f"blob fetch: injected, port {port}")
+            real(host, port, token, off, length, view, timeout_ms=timeout_ms)
+            calls.append((port, off, length, True))
+
+        monkeypatch.setattr(_native, "blob_fetch", fetch)
+    else:
+        monkeypatch.setenv("TORCHFT_HEAL_NATIVE", "0")
+        real_open = ht._traced_urlopen
+
+        def urlopen(url, timeout):
+            if "/range_" not in url:
+                return real_open(url, timeout)
+            import urllib.parse
+
+            port = urllib.parse.urlsplit(url).port
+            _, off, length = url.rsplit("/", 1)[1].split("_")
+            time.sleep(delay)
+            if fail is not None and fail(port):
+                calls.append((port, int(off), int(length), False))
+                raise ConnectionError(f"range fetch: injected, port {port}")
+            calls.append((port, int(off), int(length), True))
+            return real_open(url, timeout)
+
+        monkeypatch.setattr(ht, "_traced_urlopen", urlopen)
+    return calls
+
+
+def _port_of(transport, native: bool) -> int:
+    return transport._blob.port if native else transport._port
+
+
+SENTINEL = 0xA5
+
+
+def _sentinel_free_state(seed: int):
+    """A state none of whose bytes is the sentinel the test poisons the
+    un-zeroed destination with."""
+    rng = np.random.default_rng(seed)
+    return {
+        "a": rng.integers(0, 100, (300, 1000), dtype=np.uint8),
+        "b": rng.integers(0, 100, 70_001, dtype=np.uint8),
+        "e": np.zeros(0, dtype=np.uint8),
+        "c": rng.integers(0, 100, (64, 64), dtype=np.uint8),
+    }
+
+
+@pytest.fixture
+def poisoned_dest(monkeypatch):
+    from torchft_tpu.checkpointing import http_transport as ht
+
+    made = []
+
+    def alloc(total):
+        made.append(np.full(total, SENTINEL, dtype=np.uint8))
+        return made[-1]
+
+    monkeypatch.setattr(ht, "_alloc_dest", alloc)
+    return made
+
+
+class TestStreamsPerSource:
+    @pytest.mark.parametrize("native", [True, False], ids=["blob", "http"])
+    def test_one_source_several_ranges_in_flight(
+        self, transports, monkeypatch, poisoned_dest, native
+    ):
+        monkeypatch.setenv("TORCHFT_HEAL_STRIPES", "4")
+        calls = _slow_fetches(monkeypatch, native)
+        state = _sentinel_free_state(11)
+        src, rx = transports(), transports()
+        src.send_checkpoint([1], 3, state, T)
+        out = rx.recv_checkpoint_multi([src.metadata()], 3, T)
+        _tree_equal(out, state)
+        assert len(poisoned_dest) == 1
+        assert not (poisoned_dest[0] == SENTINEL).any()
+        stats = rx.last_heal_stats
+        assert stats["streams"] == 4 and len(calls) == 4
+        assert stats["nsources"] == 1 and stats["failures"] == {}
+        (srcstat,) = stats["sources"].values()
+        assert srcstat["ranges"] == 4 and srcstat["bytes"] == src._total
+        # four ranges side by side: the source's wall seconds are one
+        # range's, not four
+        assert 0.15 <= srcstat["seconds"] < 0.45
+        stages = stats["stages"]
+        assert {"meta_s", "alloc_s", "fetch_s", "recv_s", "decode_s"} <= set(stages)
+        assert stages["recv_s"] >= stages["alloc_s"] + stages["fetch_s"] - 1e-3
+        assert stats["source_stage"] == src._stage_stats
+        assert set(stats["source_stage"]) == {
+            "stage_s", "d2h_s", "digest_s", "digest_workers",
+        }
+
+    def test_stripes_follow_the_bytes_where_unset(self, monkeypatch):
+        from torchft_tpu.checkpointing import stripes
+
+        monkeypatch.delenv("TORCHFT_HEAL_STRIPES", raising=False)
+        monkeypatch.setattr(
+            stripes.os, "sched_getaffinity", lambda _pid: set(range(30))
+        )
+        assert stripes.heal_stripes_per_source() == 2
+        assert stripes.heal_stripes_per_source(300_000) == 2
+        assert stripes.heal_stripes_per_source(7_304_702_144) == 7
+        assert stripes.heal_stripes_per_source(1 << 40) == 30  # the cores
+        monkeypatch.setenv("TORCHFT_HEAL_STRIPES", "12")  # a user's floor
+        assert stripes.heal_stripes_per_source(7_304_702_144) == 12
+        monkeypatch.setenv("TORCHFT_HEAL_STRIPES", "1")
+        assert stripes.heal_stripes_per_source(300_000) == 1
+        assert stripes.heal_stripes_per_source(7_304_702_144) == 7
+
+    @pytest.mark.parametrize("native", [True, False], ids=["blob", "http"])
+    @pytest.mark.parametrize("survivor", [True, False], ids=["survivor", "alone"])
+    def test_range_failing_with_several_streams_on_its_source(
+        self, transports, monkeypatch, poisoned_dest, native, survivor
+    ):
+        monkeypatch.setenv("TORCHFT_HEAL_STRIPES", "3")
+        state = _sentinel_free_state(12)
+        good, bad, rx = transports(), transports(), transports()
+        sources = [bad] + ([good] if survivor else [])
+        for s in sources:
+            s.send_checkpoint([1], 4, state, T)
+        bad_port = _port_of(bad, native)
+        calls = _slow_fetches(
+            monkeypatch, native, fail=lambda port: port == bad_port
+        )
+        urls = [s.metadata() for s in sources]
+        if not survivor:
+            with pytest.raises(ConnectionError, match="striped heal incomplete"):
+                rx.recv_checkpoint_multi(urls, 4, T)
+            # three streams stood on the source: each range failed once
+            assert [c[3] for c in calls] == [False] * 3
+            return
+        out = rx.recv_checkpoint_multi(urls, 4, T)
+        _tree_equal(out, state)
+        assert not (poisoned_dest[0] == SENTINEL).any()
+        stats = rx.last_heal_stats
+        assert list(stats["failures"]) == [bad.metadata()]
+        assert stats["nsources"] == 1
+        # every range was fetched exactly once in the end, the failed
+        # ones re-queued once each onto the survivor
+        ok = sorted((off, ln) for _, off, ln, good_ in calls if good_)
+        assert ok == stripe_ranges(good._total, 6)
+        failed = [(off, ln) for _, off, ln, good_ in calls if not good_]
+        assert len(failed) == len(set(failed)) == 3
+        assert stats["sources"][good.metadata()]["bytes"] == good._total

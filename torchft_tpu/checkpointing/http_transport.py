@@ -56,8 +56,8 @@ from torchft_tpu import telemetry
 from torchft_tpu.checkpointing._rwlock import RWLock
 from torchft_tpu.checkpointing import delta as delta_mod
 from torchft_tpu.checkpointing.serialization import (
+    Flattening,
     as_bytes,
-    flatten_state,
     unflatten_state,
 )
 from torchft_tpu.checkpointing.stripes import (
@@ -137,6 +137,11 @@ def _traced_urlopen(url: str, timeout: float):
     return urllib.request.urlopen(req, timeout=timeout)
 
 
+def _alloc_dest(total: int) -> np.ndarray:
+    """The destination of a striped fetch: ``total`` bytes, not zeroed."""
+    return np.empty(total, dtype=np.uint8)
+
+
 # retained import surface: the chunk grouping moved to stripes.py (shared
 # with tests and the heal planner)
 _assign_chunks = assign_chunk_groups
@@ -175,6 +180,7 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
         self._tree_digest: Optional[str] = None
         self._groups: List[List[int]] = []
         self._token = 0
+        self._stage_stats: Dict[str, Any] = {}
         # native blob server (bulk heal bytes), created lazily at first
         # staging; None when the native core is unavailable or disabled
         self._blob = None
@@ -398,6 +404,7 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
             "tree_digest": self._tree_digest,
             "token": self._token,
             "blob_port": getattr(blob, "port", None),
+            "stage": dict(self._stage_stats),
         }
         return [pickle.dumps(meta)]
 
@@ -474,44 +481,49 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
 
     def _stage(self, dst_ranks: List[int], step: int, state_dict: T) -> None:
         t0 = time.perf_counter()
-        header, buffers = flatten_state(state_dict)
-        # pin contiguity: the blob plane serves raw base pointers, and
-        # _to_host already returns contiguous arrays — this is a no-op
-        # guard against exotic leaf types
-        buffers = [np.ascontiguousarray(b) for b in buffers]
-        nbytes = len(header) + sum(int(b.nbytes) for b in buffers)
-        telemetry.record_checkpoint(
-            "stage", nbytes, time.perf_counter() - t0, "http"
-        )
-        telemetry.emit(
-            "checkpoint_send",
-            transport="http",
-            dst_ranks=list(dst_ranks),
-            step=step,
-            bytes=nbytes,
-        )
+        # leaf by leaf: a leaf is hashed while the next ones land
+        # (docs/heal_plane.md "Staging")
+        flat = Flattening(state_dict)
+        buffers = flat.landed
+        workers, digest_s = 1, 0.0
+        trail = self.commit_trail if _heal_digest_enabled() else None
+
+        def hashed(landed_or_landing) -> List[str]:
+            nonlocal workers, digest_s
+            workers = delta_mod.digest_workers(flat.nbytes, flat.nbuffers)
+            ts, waited = time.perf_counter(), flat.wait_s
+            out = delta_mod.leaf_digests(landed_or_landing, workers=workers)
+            # the hashing this thread saw: what it did not spend waiting
+            # for bytes to land
+            digest_s = time.perf_counter() - ts - (flat.wait_s - waited)
+            if trail is not None:
+                trail.record(step, buffers, digests=out)
+            return out
+
+        ent = trail.get(step) if trail is not None else None
+        digests: Optional[List[str]] = None
+        if _heal_digest_enabled() and ent is None:
+            # each leaf is hashed as it lands, on a few threads for a
+            # state worth them, so the window opens when the last digest
+            # is in — not a pass over the whole tree after the last byte
+            digests = hashed(flat.buffers())
+        else:
+            for _ in flat.buffers():
+                pass
+            if ent is not None:
+                # the Manager records the trail from the SAME state at the
+                # step boundary; reuse its digests instead of re-hashing
+                same = ent["sizes"] == [int(b.nbytes) for b in buffers]
+                digests = list(ent["leaves"]) if same else hashed(buffers)
+        header = flat.header
         self._header = header
         self._buffers = buffers
         self._sizes = [int(b.nbytes) for b in buffers]
         self._total = sum(self._sizes)
-        if _heal_digest_enabled():
-            trail = self.commit_trail
-            digests = None
-            if trail is not None:
-                # the Manager records the trail from the SAME state at the
-                # step boundary; reuse its digests instead of re-hashing
-                ent = trail.get(step)
-                if ent is not None and ent["sizes"] == self._sizes:
-                    digests = list(ent["leaves"])
-            if digests is None:
-                digests = delta_mod.leaf_digests(buffers)
-                if trail is not None:
-                    trail.record(step, buffers, digests=digests)
-            self._digests = digests
-            self._tree_digest = delta_mod.tree_digest(digests)
-        else:
-            self._digests = None
-            self._tree_digest = None
+        self._digests = digests
+        self._tree_digest = (
+            delta_mod.tree_digest(digests) if digests is not None else None
+        )
         nchunks = min(self._num_chunks, len(buffers)) if self._num_chunks else 0
         self._groups = (
             assign_chunk_groups(self._sizes, nchunks) if nchunks else []
@@ -519,6 +531,26 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
         self._step = step
         self._token = _next_token()
         self._stage_blob()
+        stage_s = time.perf_counter() - t0
+        # what the window cost its source, by stage: on the event, and in
+        # /stripemeta so that a healer's heal_stats can say why it waited
+        self._stage_stats = {
+            "stage_s": round(stage_s, 4),
+            "d2h_s": round(flat.wait_s, 4),
+            "digest_s": round(digest_s, 4),
+            "digest_workers": workers,
+        }
+        nbytes = len(header) + self._total
+        telemetry.record_checkpoint("stage", nbytes, stage_s, "http")
+        telemetry.LEDGER.record_heal_stage("stage", stage_s)
+        telemetry.emit(
+            "checkpoint_send",
+            transport="http",
+            dst_ranks=list(dst_ranks),
+            step=step,
+            bytes=nbytes,
+            **self._stage_stats,
+        )
         self._lock.w_release()  # open the serving window
         self._allowed = True
 
@@ -728,6 +760,10 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
             "meta", time.perf_counter() - t0
         )
         stats["stages"]["meta_s"] = round(time.perf_counter() - t0, 4)
+        if pmeta.get("stage"):
+            # what the window cost the source (a source of an older
+            # build says nothing)
+            stats["source_stage"] = dict(pmeta["stage"])
 
         if header_cb is not None:
             try:
@@ -738,13 +774,22 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
         # ---- striped fetch (work queue: a dead source's pending ranges
         # re-stripe onto the survivors) ---------------------------------
         t0 = time.perf_counter()
-        dest = bytearray(total)
+        # never zeroed: a range fills its pages or the heal is refused
+        # below (done_bytes), so the first touch of each page happens in
+        # the fetch threads, spread over the ranges — not in one memset
+        # pass over the whole tree that the fetch then overwrites
+        dest = _alloc_dest(total)
         mv = memoryview(dest)
-        ranges = stripe_ranges(total, len(active) * heal_stripes_per_source())
+        alloc_s = time.perf_counter() - t0
+        # as many ranges a source as its bytes are worth, one stream each
+        per_source = heal_stripes_per_source(-(-total // len(active)))
+        ranges = stripe_ranges(total, len(active) * per_source)
         queue: deque = deque(ranges)
         qlock = threading.Lock()
         failures: Dict[str, str] = {}
         done_bytes = [0]
+        in_flight = [0, 0]  # ranges being fetched now, and the most at once
+        src_started: Dict[str, float] = {}  # a source's first range began
 
         def fetch_range(src: str, off: int, length: int) -> None:
             left = max(0.1, deadline - time.monotonic())
@@ -780,19 +825,24 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
                         got += k
 
         def worker(src: str) -> None:
-            srcstat = stats["sources"].setdefault(
-                src, {"bytes": 0, "seconds": 0.0, "ranges": 0}
-            )
+            with qlock:
+                srcstat = stats["sources"].setdefault(
+                    src, {"bytes": 0, "seconds": 0.0, "ranges": 0}
+                )
             while True:
                 with qlock:
-                    if not queue:
+                    # a source one stream saw fail takes no further range
+                    if not queue or src in failures:
                         return
                     off, length = queue.popleft()
-                ts = time.perf_counter()
+                    in_flight[0] += 1
+                    in_flight[1] = max(in_flight)
+                    src_started.setdefault(src, time.perf_counter())
                 try:
                     fetch_range(src, off, length)
                 except Exception as e:  # noqa: BLE001 — re-stripe and retire
                     with qlock:
+                        in_flight[0] -= 1
                         queue.append((off, length))
                         failures[src] = str(e)
                     logger.warning(
@@ -802,31 +852,44 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
                         e,
                     )
                     return
-                dur = time.perf_counter() - ts
-                srcstat["bytes"] += length
-                srcstat["seconds"] += dur
-                srcstat["ranges"] += 1
+                te = time.perf_counter()
                 with qlock:
+                    in_flight[0] -= 1
                     done_bytes[0] += length
+                    srcstat["bytes"] += length
+                    srcstat["ranges"] += 1
+                    # wall seconds from the source's first range to its
+                    # last, so the rate is the source's as this healer saw
+                    # it, whatever the number of streams
+                    srcstat["seconds"] = max(
+                        srcstat["seconds"], te - src_started[src]
+                    )
 
         # re-striping loop: a worker that observed an empty queue exits,
         # but a FAILING worker may re-queue its in-flight range after
         # that — so keep relaunching workers for the surviving sources
         # until the queue drains or every source has failed (each pass
         # either finishes the queue or retires at least one source, so
-        # the loop is bounded by len(active))
+        # the loop is bounded by len(active)). One worker per range in
+        # flight: a source serves as many connections as it has ranges
+        # outstanding (a thread a connection on the blob plane and on
+        # the HTTP fallback alike)
+        t_fetch = time.perf_counter()
         while queue and len(failures) < len(active):
             survivors = [s for s in active if s not in failures]
+            streams = min(per_source, -(-len(queue) // len(survivors)))
             threads = [
                 threading.Thread(
-                    target=worker, args=(s,), name=f"tft_heal_stripe{i}"
+                    target=worker, args=(s,), name=f"tft_heal_stripe{i}.{j}"
                 )
                 for i, s in enumerate(survivors)
+                for j in range(streams)
             ]
             for th in threads:
                 th.start()
             for th in threads:
                 th.join()
+        fetch_s = time.perf_counter() - t_fetch
         if done_bytes[0] != total:
             raise ConnectionError(
                 f"striped heal incomplete: {done_bytes[0]}/{total} bytes "
@@ -834,13 +897,16 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
             )
         recv_s = time.perf_counter() - t0
         telemetry.LEDGER.record_heal_stage("recv", recv_s)
-        for src, st in stats["sources"].items():
+        for st in stats["sources"].values():
             st["gb_per_sec"] = (
                 round(st["bytes"] / st["seconds"] / 1e9, 3)
                 if st["seconds"] > 0
                 else 0.0
             )
+        stats["stages"]["alloc_s"] = round(alloc_s, 4)
+        stats["stages"]["fetch_s"] = round(fetch_s, 4)
         stats["stages"]["recv_s"] = round(recv_s, 4)
+        stats["streams"] = in_flight[1]
         stats["nsources"] = len(active) - len(failures)
         stats["failures"] = failures
 

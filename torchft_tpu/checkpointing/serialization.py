@@ -36,6 +36,7 @@ from __future__ import annotations
 import io
 import pickle
 import struct
+import time
 from typing import Any, BinaryIO, List, Tuple
 
 import numpy as np
@@ -43,6 +44,7 @@ import numpy as np
 _LEN = struct.Struct("<Q")
 
 __all__ = [
+    "Flattening",
     "flatten_state",
     "unflatten_state",
     "save_state",
@@ -230,23 +232,93 @@ def from_transfer_tree(tree: Any, mesh) -> Any:
     )
 
 
-def flatten_state(state: Any) -> Tuple[bytes, List[np.ndarray]]:
-    """Flatten a pytree into ``(header_bytes, array_buffers)``."""
-    leaves, treedef = _tree_util().tree_flatten(state)
-    infos: List[Tuple] = []
-    buffers: List[np.ndarray] = []
-    for leaf in leaves:
-        if _is_array(leaf):
+# device-to-host copies kept in flight beyond the one being read. On a
+# v5e the blocking read of one leaf after another lands 7.3 GB of f32 in
+# 2.2 s, with one or two copies ahead in 1.8 s, and with EVERY leaf's copy
+# issued before the first read — what suits ddp's exchange, whose copies
+# run under the step's compute — in 5.6-8.9 s (PERF.md §6, PR 49): the
+# runtime fills the copies it has been handed side by side, each slowly
+_COPIES_AHEAD = 2
+
+
+class Flattening:
+    """A state tree on its way to the host, leaf by leaf.
+    :meth:`buffers` yields the host buffers in stream order as each lands,
+    with the next ``_COPIES_AHEAD`` device-to-host copies already issued,
+    so a consumer works on leaf *i* under the landing of leaf *i + 1*;
+    :attr:`landed` holds what has arrived so far and :attr:`header` is
+    complete once the stream is exhausted; :attr:`wait_s` is how long
+    the reads blocked. ``nbytes`` / ``nbuffers`` are known at once (from
+    shapes), for callers that size a pool before the bytes exist."""
+
+    def __init__(self, state: Any) -> None:
+        leaves, self._treedef = _tree_util().tree_flatten(state)
+        self._infos: List[Tuple] = []
+        # per leaf: ("obj", leaf) | ("arr", leaf) | ("shards", leaf, desc,
+        # [(index_desc, single-device array), ...])
+        self._pending: List[Tuple] = []
+        # every buffer's source in stream order, and how many of them have
+        # had their copy issued
+        self._parts: List[Any] = []
+        self._issued = 0
+        self.landed: List[np.ndarray] = []
+        # seconds the reading thread stood waiting for bytes to land
+        self.wait_s = 0.0
+        for leaf in leaves:
+            if not _is_array(leaf):
+                self._pending.append(("obj", leaf))
+                continue
             desc = _sharding_desc(leaf)
-            if desc is not None:
-                axis_names, mesh_shape, spec_entries = desc
+            if desc is None:
+                self._pending.append(("arr", leaf))
+                self._parts.append(leaf)
+            else:
                 seen = {}
                 for s in leaf.addressable_shards:
                     idx = _index_desc(s.index, leaf.shape)
                     if idx not in seen:  # replicas ship once
-                        seen[idx] = _to_host(s.data)
-                shard_meta = [(idx, a.nbytes) for idx, a in seen.items()]
-                infos.append(
+                        seen[idx] = s.data
+                self._pending.append(("shards", leaf, desc, list(seen.items())))
+                self._parts.extend(seen.values())
+        self.nbuffers = len(self._parts)
+        self.nbytes = sum(
+            int(p.size) * np.dtype(p.dtype).itemsize for p in self._parts
+        )
+
+    def _land(self, part: Any) -> np.ndarray:
+        """The next buffer of the stream (``part`` is its source), read
+        once the copies up to ``_COPIES_AHEAD`` past it are issued."""
+        upto = min(len(self._parts), len(self.landed) + 1 + _COPIES_AHEAD)
+        for ahead in self._parts[self._issued : upto]:
+            if not isinstance(ahead, np.ndarray):
+                ahead.copy_to_host_async()
+        self._issued = max(self._issued, upto)
+        t0 = time.perf_counter()
+        host = _to_host(part)
+        self.wait_s += time.perf_counter() - t0
+        self.landed.append(host)
+        return host
+
+    def buffers(self):
+        """Yield each host buffer in stream order (blocking until it has
+        landed); one pass."""
+        for item in self._pending:
+            if item[0] == "obj":
+                self._infos.append(("obj", pickle.dumps(item[1])))
+            elif item[0] == "arr":
+                host = self._land(item[1])
+                self._infos.append(
+                    ("arr", _dtype_name(host.dtype), host.shape, host.nbytes)
+                )
+                yield host
+            else:
+                _, leaf, (axis_names, mesh_shape, spec_entries), shards = item
+                shard_meta = []
+                for idx, data in shards:
+                    host = self._land(data)
+                    shard_meta.append((idx, host.nbytes))
+                    yield host
+                self._infos.append(
                     (
                         "shards",
                         _dtype_name(np.dtype(leaf.dtype)),
@@ -256,17 +328,22 @@ def flatten_state(state: Any) -> Tuple[bytes, List[np.ndarray]]:
                         shard_meta,
                     )
                 )
-                buffers.extend(seen.values())
-            else:
-                host = _to_host(leaf)
-                infos.append(
-                    ("arr", _dtype_name(host.dtype), host.shape, host.nbytes)
-                )
-                buffers.append(host)
-        else:
-            infos.append(("obj", pickle.dumps(leaf)))
-    header = pickle.dumps((treedef, infos))
-    return header, buffers
+        self._pending = []
+
+    @property
+    def header(self) -> bytes:
+        assert len(self._infos) == self._treedef.num_leaves, (
+            "the header is complete once buffers() is exhausted"
+        )
+        return pickle.dumps((self._treedef, self._infos))
+
+
+def flatten_state(state: Any) -> Tuple[bytes, List[np.ndarray]]:
+    """Flatten a pytree into ``(header_bytes, array_buffers)``."""
+    flat = Flattening(state)
+    for _ in flat.buffers():
+        pass
+    return flat.header, flat.landed
 
 
 def buffer_sizes(infos: List[Tuple]) -> List[int]:

@@ -45,14 +45,28 @@ def heal_sources_limit() -> int:
         return 4
 
 
-def heal_stripes_per_source() -> int:
-    """Ranges per source (``TORCHFT_HEAL_STRIPES``, default 2): more
-    ranges than sources keeps the tail short and makes re-striping after
-    a source death cheap (only the dead source's pending ranges move)."""
+# one range, and so one stream, for about this many bytes of a source's
+# share: a stream moves 1.4-1.7 GB/s over the blob plane on the v5e host,
+# so a range is under a second of work and a multi-GB heal gets several
+_BYTES_A_STRIPE = 1 << 30
+
+
+def heal_stripes_per_source(bytes_a_source: int = 0) -> int:
+    """Ranges per source, each fetched on a stream of its own: one per
+    ``_BYTES_A_STRIPE`` of the bytes a source is to serve, at most the
+    cores this process sees and at least two — more ranges than sources
+    keeps the tail short and makes re-striping after a source death cheap
+    (only the dead source's pending ranges move).
+    ``TORCHFT_HEAL_STRIPES`` set raises that floor of two (or lowers it
+    to one)."""
     try:
-        return max(1, int(os.environ.get("TORCHFT_HEAL_STRIPES", "2")))
+        floor = max(1, int(os.environ.get("TORCHFT_HEAL_STRIPES", "2")))
     except ValueError:
-        return 2
+        floor = 2
+    derived = min(
+        len(os.sched_getaffinity(0)), -(-bytes_a_source // _BYTES_A_STRIPE)
+    )
+    return max(floor, derived)
 
 
 def stripe_ranges(total_bytes: int, n: int) -> List[Tuple[int, int]]:

@@ -809,11 +809,16 @@ class Manager:
             return None
         try:
             from torchft_tpu.checkpointing import delta as _delta
-            from torchft_tpu.checkpointing.serialization import flatten_state
+            from torchft_tpu.checkpointing.serialization import Flattening
 
-            _header, buffers = flatten_state(self._manager_state_dict())
-            digests = _delta.leaf_digests(buffers)
-            return buffers, _delta.tree_digest(digests)
+            # once a heal, on the quorum thread: each leaf hashed as it
+            # lands, on as many threads as the state is worth
+            flat = Flattening(self._manager_state_dict())
+            digests = _delta.leaf_digests(
+                flat.buffers(),
+                workers=_delta.digest_workers(flat.nbytes, flat.nbuffers),
+            )
+            return flat.landed, _delta.tree_digest(digests)
         except Exception:  # noqa: BLE001 — degrade to a full heal
             self._logger.exception("own-state digest failed")
             return None

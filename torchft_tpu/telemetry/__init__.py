@@ -176,7 +176,8 @@ HEAL_DURATION = REGISTRY.histogram(
 HEAL_STAGE_SECONDS = REGISTRY.counter(
     "tft_heal_stage_seconds_total",
     "Cumulative wall-clock inside the heal data path, by sub-stage "
-    "(meta / recv / decode / device_put — docs/heal_plane.md)",
+    "(stage on the source; meta / recv / decode / device_put on the "
+    "healer — docs/heal_plane.md)",
     labelnames=("stage",),
 )
 PEER_DEATHS = REGISTRY.counter(
@@ -388,7 +389,7 @@ for _reason in ("signal", "deadline", "watchdog", "manual"):
     FLIGHT_DUMPS.labels(reason=_reason)
 for _stage in ("host_copy", "quantize", "wire", "dequant_reduce"):
     WIRE_STAGE_SECONDS.labels(stage=_stage)
-for _stage in ("meta", "recv", "decode", "device_put"):
+for _stage in ("stage", "meta", "recv", "decode", "device_put"):
     HEAL_STAGE_SECONDS.labels(stage=_stage)
 for _phase in PHASES:
     STEP_PHASE_SECONDS.labels(phase=_phase)

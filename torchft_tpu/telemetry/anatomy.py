@@ -216,8 +216,9 @@ class StepLedger:
             telemetry.WIRE_STAGE_SECONDS.labels(stage=phase).inc(seconds)
 
     def record_heal_stage(self, stage: str, seconds: float) -> None:
-        """Accumulate a heal sub-stage (``meta``/``recv``/``decode``/
-        ``device_put`` — docs/heal_plane.md) into the cumulative heal-stage
+        """Accumulate a heal sub-stage (the source's ``stage``; the
+        healer's ``meta``/``recv``/``decode``/``device_put`` —
+        docs/heal_plane.md) into the cumulative heal-stage
         view. Heals are rare, mostly ride the quorum thread, and span step
         boundaries, so these do NOT enter step rows (the row's ``heal``
         phase stays the main-thread apply, PR 8 semantics) — they exist so
