@@ -183,53 +183,28 @@ def main() -> None:
     import time
 
     try:
-        def steps_in_flight() -> int:
-            # committed steps plus the speculative one whose pipelined
-            # vote is still in flight — the loop must count it or a
-            # pipelined run would train one extra step past STEPS
-            return manager.current_step() + (
-                1 if manager.pending_commit() is not None else 0
-            )
-
         prev_step = manager.current_step()
         while manager.current_step() < steps:
-            while steps_in_flight() < steps:
-                # in-flight count, not current_step(): during speculation
-                # the committed counter lags one step, and feeding it to
-                # the sampler would phase-shift the batch schedule vs
-                # sync mode
-                sampler.set_epoch(steps_in_flight())
-                idx = np.fromiter(iter(sampler), dtype=np.int64)[:batch]
+            sampler.set_epoch(manager.current_step())
+            idx = np.fromiter(iter(sampler), dtype=np.int64)[:batch]
 
-                opt.begin_step()  # async quorum overlaps the forward pass
-                loss, grads = value_and_grad(opt.params, x[idx], y[idx])
-                opt.step(grads)
-                if (
-                    manager.current_step() == prev_step
-                    and manager.pending_commit() is None
-                ):
-                    # failed commit (e.g. waiting for enough replicas):
-                    # back off instead of hammering the quorum in a busy
-                    # loop. A pending pipelined vote is NOT a failed
-                    # commit — the counter advances when the next step
-                    # resolves it.
-                    time.sleep(0.2)
-                prev_step = manager.current_step()
-                logger.info(
-                    "step=%d batches_committed=%d participants=%d loss=%.4f",
-                    manager.current_step(),
-                    manager.batches_committed(),
-                    manager.num_participants(),
-                    float(loss),
-                )
-                if ckpt is not None:
-                    ckpt.maybe_save()
-            # pipelined commit (TORCHFT_COMMIT_PIPELINE=1): resolve the
-            # trailing speculative vote (no-op in sync mode). If it is
-            # VETOED the rollback leaves current_step < steps and the
-            # outer loop trains the missing step(s) — sync parity: the
-            # run always ends with exactly `steps` committed steps.
-            opt.finish()
+            opt.begin_step()  # async quorum overlaps the forward pass
+            loss, grads = value_and_grad(opt.params, x[idx], y[idx])
+            opt.step(grads)
+            if manager.current_step() == prev_step:
+                # failed commit (e.g. waiting for enough replicas): back
+                # off instead of hammering the quorum in a busy loop
+                time.sleep(0.2)
+            prev_step = manager.current_step()
+            logger.info(
+                "step=%d batches_committed=%d participants=%d loss=%.4f",
+                manager.current_step(),
+                manager.batches_committed(),
+                manager.num_participants(),
+                float(loss),
+            )
+            if ckpt is not None:
+                ckpt.maybe_save()
         final = jax.tree_util.tree_map(lambda a: np.asarray(a).sum(), opt.params)
         logger.info("done: step=%d param_checksum=%.6f",
                     manager.current_step(),

@@ -376,8 +376,8 @@ void RpcServer::serve_conn(int fd) {
       int64_t timeout_ms = req.geti("_d", 60000);
       int64_t deadline = now_ms() + timeout_ms;
       // env-gated injection: stretch this method's server-side handling
-      // (e.g. TORCHFT_FI_SRV_DELAY=mgr.should_commit:200 is a commit-vote
-      // RTT the pipelined mode must hide)
+      // (e.g. TORCHFT_FI_SRV_DELAY=mgr.should_commit:200 is a 200 ms
+      // commit-vote RTT)
       static const fi::MethodSpec fi_dly =
           fi::parse_method("TORCHFT_FI_SRV_DELAY");
       if (fi_dly.n > 0 && method == fi_dly.method) fi::sleep_ms(fi_dly.n);

@@ -408,6 +408,7 @@ class TestFaultMatrix:
         [
             "torn_cma_pull", "kill_allreduce_cma", "ckpt_serve_death",
             "straggler_group", "perf_regression", "diagnose_straggler",
+            "commit_vote_delay",
         ],
     )
     def test_scenario(self, tmp_path, name):

@@ -12,7 +12,8 @@ from unittest.mock import MagicMock, patch
 import numpy as np
 import pytest
 
-from torchft_tpu.collectives import CollectivesDummy
+from torchft_tpu import telemetry
+from torchft_tpu.collectives import CollectivesDummy, PeerGoneError
 from torchft_tpu.coordination import QuorumResult
 from torchft_tpu.manager import (
     MANAGER_ADDR_KEY,
@@ -35,6 +36,7 @@ def quorum_result(
     recover_dst_ranks=(),
     recover_src_addresses=(),
     heal_pending=False,
+    participant_ids=(),
 ):
     q = QuorumResult()
     q.quorum_id = quorum_id
@@ -50,6 +52,7 @@ def quorum_result(
     q.heal = heal
     q.recover_src_addresses = list(recover_src_addresses)
     q.heal_pending = heal_pending or heal or bool(recover_dst_ranks)
+    q.participant_ids = list(participant_ids)
     return q
 
 
@@ -397,6 +400,42 @@ def test_mixed_epoch_span_on_one_rank_vetoes_group_wide(harness):
     assert h.client.should_commit.call_args.args[2] is True
 
 
+def test_death_watch_requorum_mid_step_vetoes_the_step(harness):
+    """The death watch's early re-quorum rebuilds the plane under a step in
+    flight: an op of the same step then rides the new epoch, the step's ops
+    span two, and the barrier votes it down with the reason on its abort."""
+    h = harness(min_replica_size=1)
+    m = h.manager
+    h.client.should_commit.side_effect = (
+        lambda rank, step, vote, timeout=None, **kw: vote
+    )
+    ids = ["replica_a", "replica_b"]
+    h.client._quorum.side_effect = [
+        quorum_result(quorum_id=123, max_rank=1, participant_ids=ids),
+        # the death watch's re-quorum delivers the shrink
+        quorum_result(quorum_id=124, max_rank=1, participant_ids=["replica_a"]),
+        quorum_result(quorum_id=124, max_rank=1, participant_ids=["replica_a"]),
+    ]
+    m.start_quorum()
+    m.allreduce(np.ones(2, dtype=np.float32)).wait()
+    assert m._quorum_id == 123
+
+    m._on_peer_death(1)  # the peer's socket closed mid-step
+    m.wait_quorum()
+    assert m._quorum_id == 124
+    m.allreduce(np.ones(2, dtype=np.float32)).wait()
+    assert not m.should_commit()
+    assert m.current_step() == 0
+    aborts = telemetry.EVENTS.recent("abort")
+    assert aborts and aborts[-1]["mixed_epochs"] is True and aborts[-1]["step"] == 0
+
+    # the step after it rides one epoch and commits
+    m.start_quorum()
+    m.allreduce(np.ones(2, dtype=np.float32)).wait()
+    assert m.should_commit()
+    assert m.current_step() == 1
+
+
 def test_stale_death_watch_callback_dropped(harness):
     """Round-4 advisor low (manager.py:574): a POLLHUP delivered for an
     OLD plane generation must not map its ring rank through the CURRENT
@@ -539,6 +578,35 @@ def test_step_after_a_latched_error_packs_into_new_buffers(harness, monkeypatch)
     for i in range(4):  # two participants, the dummy plane adds nothing
         np.testing.assert_array_equal(np.asarray(out[f"g{i}"]), (4.0 + i) / 2)
     assert step(5.0, True)[1:] == (4, 4)
+
+
+def test_vote_rpc_failure_reaches_the_caller_and_commits_nothing(harness):
+    """A lost vote is the caller's to see: the step is not counted, its
+    pending work is drained all the same, and the next quorum forms."""
+    h = harness()
+    m = h.manager
+    h.client._quorum.return_value = quorum_result(max_rank=1)
+    h.client.should_commit.side_effect = TimeoutError("vote lost")
+
+    m.start_quorum()
+    t = np.array([2.0, 4.0], dtype=np.float32)
+    work = m.allreduce(t)
+    with pytest.raises(TimeoutError, match="vote lost"):
+        m.should_commit()
+    assert work.done() and m._pending_work == []
+    assert m.current_step() == 0 and m.batches_committed() == 0
+    commits = len(telemetry.EVENTS.recent("commit"))
+
+    h.client.should_commit.side_effect = None
+    h.client.should_commit.return_value = True
+    m.start_quorum()
+    assert m.errored() is None and m.num_participants() == 2
+    m.allreduce(t).wait()
+    assert m.should_commit()
+    # the step the lost vote was for is the one that commits now
+    assert h.client.should_commit.call_args.args[1] == 0
+    assert m.current_step() == 1 and m.batches_committed() == 2
+    assert len(telemetry.EVENTS.recent("commit")) == commits + 1
 
 
 def test_start_quorum_retries_after_timeout(harness):
@@ -745,3 +813,162 @@ def test_the_trace_says_where_the_average_was_taken(
         assert sum(c[total] for c in counters) == pytest.approx(
             sum(a[f] for a in accounts for f in fields), abs=1e-9
         )
+
+
+# ---------------------------------------------------------------------------
+# committed state: what the two drivers of the commit barrier keep of a run
+# in which a step is lost, against a plain run that never took that batch
+# ---------------------------------------------------------------------------
+
+
+class FaultyDummy(CollectivesDummy):
+    """Raises ``PeerGoneError`` in the first allreduce after ``fail_next``
+    is set — the failed-op face of a peer dying mid-exchange."""
+
+    fail_next = False
+
+    def allreduce(self, arrays, op=None, divisor=1):
+        if self.fail_next:
+            self.fail_next = False
+            raise PeerGoneError(0, "peer 0 died mid-op")
+        return super().allreduce(arrays, divisor=divisor)
+
+
+class TestCommittedStateSkipsTheLostStep:
+    STEPS = 5
+    LOST = 2  # 0-based index of the batch whose step does not commit
+    N = 2  # participants in the harness's quorum: the average divides by it
+
+    @pytest.fixture(scope="class")
+    def train_step(self):
+        import jax.numpy as jnp
+        import optax
+
+        from torchft_tpu.models.transformer import TransformerConfig
+        from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+        from torchft_tpu.parallel.train_step import TrainStep
+
+        cfg = TransformerConfig(
+            vocab_size=32, d_model=16, n_layers=1, n_heads=2, head_dim=8,
+            d_ff=32, dtype=jnp.float32,
+        )
+        return TrainStep(cfg, optax.adam(1e-2), make_mesh(MeshConfig(dp=1)))
+
+    def _trainer(self, h, train_step):
+        """(step(batch), state(), plain(batches)) for ``FTTrainer``."""
+        import jax
+        import jax.numpy as jnp
+
+        from torchft_tpu.parallel.ft import FTTrainer
+
+        rng = np.random.default_rng(7)
+        batches = [
+            jnp.asarray(rng.integers(0, 32, (2, 4)), jnp.int32)
+            for _ in range(self.STEPS)
+        ]
+        trainer = FTTrainer(h.manager, train_step)
+        trainer.init(jax.random.PRNGKey(0))
+
+        def plain(kept):
+            ts = train_step
+            params = ts.init_params(jax.random.PRNGKey(0))
+            opt_state = ts.init_opt(params)
+            for tokens in kept:
+                _, grads = ts.grads(params, ts.shard_batch(tokens))
+                grads = jax.tree_util.tree_map(lambda g: g / self.N, grads)
+                params, opt_state = ts.apply(params, opt_state, grads)
+            return params, opt_state
+
+        return batches, trainer.step, lambda: (trainer.params, trainer.opt_state), plain
+
+    def _optimizer(self, h):
+        """The same three for ``ManagedOptimizer`` on a least-squares fit."""
+        import jax
+        import jax.numpy as jnp
+        import optax
+
+        from torchft_tpu.optim import ManagedOptimizer
+
+        rng = np.random.default_rng(7)
+        batches = [
+            (jnp.asarray(rng.normal(size=(8, 4)), jnp.float32),
+             jnp.asarray(rng.normal(size=(8,)), jnp.float32))
+            for _ in range(self.STEPS)
+        ]
+        tx = optax.adam(1e-2)
+        grad_fn = jax.jit(jax.grad(lambda p, x, y: jnp.mean((x @ p["w"] - y) ** 2)))
+        init = {"w": jnp.ones(4, jnp.float32)}
+        opt = ManagedOptimizer(h.manager, tx)
+        opt.init(init)
+
+        def step(batch):
+            opt.begin_step()
+            opt.step(grad_fn(opt.params, *batch))
+
+        @jax.jit
+        def update(params, opt_state, grads):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state
+
+        def plain(kept):
+            params, opt_state = init, tx.init(init)
+            for batch in kept:
+                grads = jax.tree_util.tree_map(
+                    lambda g: g / self.N, grad_fn(params, *batch)
+                )
+                params, opt_state = update(params, opt_state, grads)
+            return params, opt_state
+
+        return batches, step, lambda: (opt.params, opt.opt_state), plain
+
+    @pytest.mark.parametrize("fault", ["veto", "peer_gone", "vote_raises"])
+    @pytest.mark.parametrize("driver", ["trainer", "optimizer"])
+    def test_committed_state_is_the_plain_runs(
+        self, store_server, train_step, driver, fault
+    ):
+        import jax
+
+        h = ManagerHarness(store_server, collectives=FaultyDummy(rank=0, world_size=1))
+        try:
+            m = h.manager
+            h.client._quorum.return_value = quorum_result(max_rank=1)
+            votes = []
+
+            def vote_fn(rank, step, vote, timeout=None, **kw):
+                votes.append((step, vote))
+                if len(votes) == self.LOST + 1:
+                    if fault == "vote_raises":
+                        raise TimeoutError("vote lost")
+                    if fault == "veto":
+                        return False  # another rank of the group voted no
+                return vote
+
+            h.client.should_commit.side_effect = vote_fn
+            batches, step, state, plain = (
+                self._trainer(h, train_step) if driver == "trainer"
+                else self._optimizer(h)
+            )
+            for i, batch in enumerate(batches):
+                h.collectives.fail_next = fault == "peer_gone" and i == self.LOST
+                if fault == "vote_raises" and i == self.LOST:
+                    with pytest.raises(TimeoutError, match="vote lost"):
+                        step(batch)
+                else:
+                    step(batch)
+
+            # one vote a step; the lost step's is cast for the step the next
+            # one commits, and only a failed exchange votes no by itself
+            assert [s for s, _ in votes] == [0, 1, 2, 2, 3]
+            assert [v for _, v in votes] == [
+                not (fault == "peer_gone" and i == self.LOST)
+                for i in range(self.STEPS)
+            ]
+            assert m.current_step() == self.STEPS - 1
+            assert m.batches_committed() == self.N * (self.STEPS - 1)
+            kept = [b for i, b in enumerate(batches) if i != self.LOST]
+            got, want = state(), plain(kept)
+            assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+            for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        finally:
+            h.shutdown()

@@ -64,9 +64,11 @@ def n_params() -> int:
     return sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(shapes))
 
 
-def run_steps(ts, steps, monkeypatch, around=None, commit_pipeline=False):
+def run_steps(ts, steps, monkeypatch, around=None, veto_step=None):
     """``steps`` FT steps on a fresh one-group job; returns (losses, checksum).
-    ``around(fn)`` runs the stepping inside whatever it sets up."""
+    ``around(fn)`` runs the stepping inside whatever it sets up; in step
+    ``veto_step`` this rank votes against the commit, as a rank whose step
+    went wrong would."""
     monkeypatch.setenv("TORCHFT_WIRE_BUCKET_BYTES", str(BUCKET_BYTES))
     lighthouse = LighthouseServer(bind="[::]:0", min_replicas=1)
     store = StoreServer()
@@ -81,7 +83,6 @@ def run_steps(ts, steps, monkeypatch, around=None, commit_pipeline=False):
         rank=0,
         world_size=1,
         timeout=timedelta(seconds=10),
-        commit_pipeline=commit_pipeline,
     )
     try:
         trainer = FTTrainer(manager, ts)
@@ -92,13 +93,18 @@ def run_steps(ts, steps, monkeypatch, around=None, commit_pipeline=False):
             for _ in range(steps)
         ]
 
+        vote = manager._client.should_commit
+
         def drive():
             out = []
-            for tokens in batches:
+            for i, tokens in enumerate(batches):
+                manager._client.should_commit = (
+                    (lambda rank, step, _vote, **kw: vote(rank, step, False, **kw))
+                    if i == veto_step else vote
+                )
                 loss, committed = trainer.step(tokens)
-                assert committed
+                assert committed == (i != veto_step)
                 out.append(loss)
-            assert trainer.finish() in (None, True)
             jax.block_until_ready(trainer.params)
             return out
 
@@ -273,23 +279,25 @@ def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, mo
     assert exchange["attrs"]["pack_bytes"] == exchange["attrs"]["h2d_bytes"] == 4 * n_params()
     # nothing per bucket, and at most 12 new entries a step
     assert not [s for s in spans if s["name"].startswith("exchange.")]
-    assert "resolve_speculation" not in {s["name"] for s in spans}  # no vote was pending
     assert sum(1 for s in spans if s["name"] in STEP_SPANS) <= 12 * 3
 
 
-def test_resolve_speculation_is_a_span_only_while_a_vote_is_pending(traced, train_step, monkeypatch):
-    _, traced_losses, traced_checksum = traced
+def test_a_vetoed_step_has_a_commit_and_no_apply(train_step, monkeypatch):
     tracing.TRACER.clear()
-    losses, checksum = run_steps(train_step, 3, monkeypatch, commit_pipeline=True)
-    assert losses == traced_losses and checksum == traced_checksum
+    run_steps(train_step, 3, monkeypatch, veto_step=1)
     spans = tracing.TRACER.recent()
     steps = [s for s in spans if s["name"] == "step"]
-    # a vote in flight makes the step the one after current_step()
-    assert [s["trace_id"].split(":")[1] for s in steps] == ["0", "1", "2"]
-    resolved = [s for s in spans if s["name"] == "resolve_speculation"]
-    # steps 1 and 2 resolve their predecessor's vote; finish() the last, outside any step
-    assert [s.get("parent_id") for s in resolved] == [steps[1]["span_id"], steps[2]["span_id"]]
-    assert sum(1 for s in spans if s["name"] in STEP_SPANS + ("resolve_speculation",)) <= 12 * 3
+    # the step after a veto is the vetoed step again: nothing was committed
+    assert [s["trace_id"].split(":")[1] for s in steps] == ["0", "1", "1"]
+    assert [s["attrs"]["committed"] for s in steps] == [True, False, True]
+    for step in steps:
+        children = [s["name"] for s in spans if s.get("parent_id") == step["span_id"]]
+        # every piece once — a step computes its gradients once, whatever the
+        # vote says — and the update only behind a commit
+        want = set(STEP_SPANS[1:]) - {"commit.prepare"}
+        if not step["attrs"]["committed"]:
+            want -= {"apply"}
+        assert sorted(children) == sorted(want)
 
 
 def test_programs_and_scopes_have_stable_names(train_step):

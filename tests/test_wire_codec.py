@@ -224,14 +224,8 @@ class _WireStubManager:
     def set_state_dict_fns(self, load, save):
         self._load, self._save = load, save
 
-    def pending_commit(self):
-        return None
-
     def start_quorum(self, **kw):
         pass
-
-    def speculation_allowed(self):
-        return False
 
     def device_data_plane(self):
         return False
@@ -479,9 +473,6 @@ class TestLowRank:
 
         class _Mgr(_WireStubManager):
             _use_async_quorum = False
-
-            def commit_pipeline_enabled(self):
-                return False
 
         mgr = _Mgr([True, True], codec="f32")
         diloco = DiLoCo(mgr, optax.sgd(1.0), sync_every=1, outer_rank=2)

@@ -1,10 +1,9 @@
 """Project-specific concurrency lint over the FT runtime modules.
 
-The Manager runs a quorum long-poll thread, a commit-vote thread, a step
-watchdog, death-watch/evict threads and a speculation fence — thread
-discipline there is load-bearing for the paper's per-step recovery claim,
-and the remaining ROADMAP corruption item is exactly the bug class that
-races produce. torchft's Rust core gets this from the compiler; this AST
+The Manager runs a quorum long-poll thread, a step watchdog and
+death-watch/evict threads — thread discipline there is load-bearing for
+the paper's per-step recovery claim, and the remaining ROADMAP corruption
+item is exactly the bug class that races produce. torchft's Rust core gets this from the compiler; this AST
 lint is the Python analogue: the threading contract becomes checkable
 rules instead of prose.
 

@@ -9,10 +9,8 @@ machine-checked side:
 
 * :mod:`~torchft_tpu.analysis.protocol.spec` — the protocol as an
   explicit state machine: replica states (JOINING / HEALTHY / HEALING /
-  SPECULATING / DEAD), lighthouse epoch rounds, vote folding, the
-  speculation fence (PR 3), error-feedback lineage rollback (PR 6) and
-  the divergence fence (PR 10), with the core invariants as checkable
-  predicates;
+  DEAD), lighthouse epoch rounds, vote folding and the divergence fence
+  (PR 10), with the core invariants as checkable predicates;
 * :mod:`~torchft_tpu.analysis.protocol.checker` — a deterministic DFS
   model checker that exhaustively explores bounded configurations with a
   crash injected at every transition point (the SIGKILL-anywhere
@@ -35,7 +33,6 @@ from torchft_tpu.analysis.protocol.spec import (
     HEALTHY,
     JOINING,
     LEADER,
-    SPECULATING,
     Invariant,
     SpecConfig,
 )
@@ -55,7 +52,6 @@ __all__ = [
     "JOINING",
     "HEALTHY",
     "HEALING",
-    "SPECULATING",
     "DEAD",
     "FOLLOWER",
     "CANDIDATE",

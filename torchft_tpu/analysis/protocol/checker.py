@@ -118,12 +118,11 @@ class CheckResult:
 def _round_closed(state: State, rnd: Round) -> bool:
     """A round no enabled action and no invariant will ever read again:
     every member either resolved its vote or is permanently detached
-    (crashed out / floated away — old round ids are never re-attached)."""
+    (crashed out — old round ids are never re-attached)."""
     for j in rnd.members:
         if j in rnd.resolved:
             continue
-        r = state.replicas[j]
-        if r.round == rnd.rid or r.spec_round == rnd.rid:
+        if state.replicas[j].round == rnd.rid:
             return False
     return True
 
@@ -150,9 +149,9 @@ def _render(
         else:
             mview, view = r.mview, tuple(sorted(rmap(x) for x in r.view))
         reps[rmap(i)] = (
-            r.status, r.step, r.lineage, r.residual, r.joined, r.round,
+            r.status, r.step, r.lineage, r.joined, r.round,
             r.voted, r.abstain, r.worked, r.diverged, r.healer,
-            r.healed, r.spec_round, r.spec_token, r.epoch, mview, view,
+            r.healed, r.epoch, mview, view,
         )
 
     rounds: List[tuple] = []
@@ -377,11 +376,6 @@ GATE_CONFIGS: Dict[str, SpecConfig] = {
         n_replicas=2, min_replicas=1, max_rounds=3,
         crash_budget=1, respawn_budget=1,
     ),
-    # pipelined commit: speculation + the PR 3 fence, crash anywhere
-    "pipelined-2g": SpecConfig(
-        n_replicas=2, min_replicas=1, max_rounds=3,
-        crash_budget=1, respawn_budget=1, speculation=True,
-    ),
     # divergence fence armed against a silently-corrupting compute
     "divergence-fenced-2g": SpecConfig(
         n_replicas=2, min_replicas=1, max_rounds=3,
@@ -393,7 +387,7 @@ GATE_CONFIGS: Dict[str, SpecConfig] = {
         crash_budget=1, respawn_budget=1,
     ),
     # --- the HA tier (ISSUE 20). The replica-group protocol is carried
-    # by the four configs above; these stress the lighthouse tier, so
+    # by the three configs above; these stress the lighthouse tier, so
     # the group side stays minimal to keep the product space honest.
     # leader SIGKILLed mid-epoch, durable-log respawn, one re-election
     "ha-leader-crash": SpecConfig(

@@ -239,7 +239,7 @@ def compile_trace(
                 f"{label}: corrupt lowered with the divergence fence "
                 "armed (commit must abort, retry must be clean)"
             )
-        elif act in ("vote", "vote_spec"):
+        elif act == "vote":
             votes += 1
             voted = True
         elif act == "resolve":

@@ -35,11 +35,6 @@ Rules (rule ids appear in findings and docs/static_analysis.md):
     ``quorum_ready``: a failed heal latches the error, and the step MUST
     abort at the barrier; only the next quorum may commit again.
 
-``rollback-of-commit``
-    A ``commit_rollback`` at a step that already committed: rollback is
-    the veto path of a *speculative* vote — a committed step can never
-    be rolled back (the PR 6 lineage consistency).
-
 ``diverged-commit``
     With the fence armed (``divergence_detected`` carries ``fence``),
     a ``commit`` at the step the sentinel latched on: the fence's
@@ -235,14 +230,6 @@ def check_records(
                     )
                 committed_steps.add(step)
                 max_committed = max(max_committed, step)
-        elif kind == "commit_rollback":
-            if step >= 0 and step in committed_steps:
-                flag(
-                    "rollback-of-commit",
-                    f"commit_rollback at step {step}, which already "
-                    "committed — only a speculative (un-committed) vote "
-                    "can roll back (PR 6 lineage consistency)",
-                )
     return rep
 
 

@@ -6,11 +6,8 @@ that carry the correctness argument:
 
 * **Replicas** (one per replica group — the Manager's unit of commit)
   hold a committed *lineage* — the ordered tuple of per-step commit
-  tokens — plus an error-feedback *residual* version that must track the
-  committed step (PR 6's rollback consistency). A replica is JOINING
-  (pre-first-quorum), HEALTHY, HEALING (behind the round's max step,
-  pulling state from a source), SPECULATING (pipelined commit: the
-  optimizer update applied, the vote still in flight — PR 3), or DEAD.
+  tokens. A replica is JOINING (pre-first-quorum), HEALTHY, HEALING
+  (behind the round's max step, pulling state from a source), or DEAD.
 * **The lighthouse** forms rounds: replicas join, a round *forms* when
   the join barrier is satisfied (every live replica — the quorum), and
   each formed round bumps the epoch (quorum_id). Members compute, vote,
@@ -27,8 +24,7 @@ that carry the correctness argument:
   their last committed state (the checkpoint), rejoin behind, and heal.
 
 ``SpecConfig`` flags deliberately allow *broken* variants — the fences
-off, the join barrier off (split brain), residual rollback off — so the
-checker can demonstrate that each protection is load-bearing: turning
+off, the join barrier off (split brain) — so the checker can demonstrate that each protection is load-bearing: turning
 one off must produce an invariant violation (the seeded-fixture tests
 assert exactly that), and the shipped configuration must produce none.
 
@@ -38,12 +34,8 @@ Invariants (``check_state`` / ``check_terminal``):
   fleet-wide (a split brain or silently diverged commit violates this);
 * ``I2 epoch-monotonic`` — a replica's observed quorum epoch never
   decreases;
-* ``I3 healer-fence``    — a healer never observes (copies) speculative
-  state: heal sources must not be SPECULATING (PR 3's fence);
-* ``I4 residual-rollback`` — every replica's error-feedback residual
-  version equals the step its state actually encodes (committed step, or
-  the provisional step while SPECULATING) — a vetoed speculative update
-  must roll the residual back with the weights (PR 6);
+* ``I4 lineage-length``  — a replica's lineage holds exactly one token
+  per committed step;
 * ``I5 diverged-commit`` — a *detected* divergence (two member states
   disagreeing) never commits while the divergence fence is armed
   (PR 10);
@@ -96,7 +88,7 @@ from dataclasses import dataclass
 from typing import FrozenSet, List, NamedTuple, Optional, Tuple
 
 __all__ = [
-    "JOINING", "HEALTHY", "HEALING", "SPECULATING", "DEAD",
+    "JOINING", "HEALTHY", "HEALING", "DEAD",
     "FOLLOWER", "CANDIDATE", "LEADER",
     "SpecConfig", "Replica", "Round", "State", "Invariant",
     "Lighthouse", "Subagg",
@@ -109,7 +101,6 @@ __all__ = [
 JOINING = "JOINING"
 HEALTHY = "HEALTHY"
 HEALING = "HEALING"
-SPECULATING = "SPECULATING"
 DEAD = "DEAD"
 
 # lighthouse replica (Raft) status values — DEAD is shared
@@ -122,9 +113,8 @@ LEADER = "LEADER"
 class SpecConfig:
     """One bounded configuration of the model.
 
-    The shipped protocol is ``fence_speculation=True``,
-    ``fence_divergence=True`` (sentinel armed), ``join_barrier=True``,
-    ``rollback_residual=True``. Every flag exists so the checker can
+    The shipped protocol is ``fence_divergence=True`` (sentinel armed)
+    and ``join_barrier=True``. Every flag exists so the checker can
     prove the protection matters by turning it off.
     """
 
@@ -134,11 +124,8 @@ class SpecConfig:
     crash_budget: int = 1        # SIGKILL-anywhere injections
     respawn_budget: int = 1
     corrupt_budget: int = 0      # silently-diverging computes
-    speculation: bool = False    # pipelined commit (PR 3 semantics)
     join_barrier: bool = True    # False = split-brain-capable lighthouse
-    fence_speculation: bool = True   # PR 3: heal waits out speculation
     fence_divergence: bool = True    # PR 10: mismatched digests veto
-    rollback_residual: bool = True   # PR 6: veto rolls residual back
 
     # --- the HA layer (ISSUE 20) — all off/neutral by default, so the
     # single-lighthouse configurations above explore the exact PR 15
@@ -161,7 +148,6 @@ class Replica(NamedTuple):
     status: str
     step: int                 # committed step
     lineage: Tuple[str, ...]  # committed tokens; len == step
-    residual: int             # error-feedback accumulator version
     joined: bool              # in the lighthouse's open (unformed) round
     round: int                # formed-round id this replica is in, or -1
     voted: bool               # voted in `round`
@@ -170,8 +156,6 @@ class Replica(NamedTuple):
     diverged: bool            # this round's compute silently corrupted
     healer: bool              # assigned to heal in `round`
     healed: bool              # heal transfer landed
-    spec_round: int           # round id of the in-flight speculative vote
-    spec_token: str           # provisional token (speculation)
     epoch: int                # last quorum epoch observed
     mview: int = 0            # membership version this replica applied
     view: FrozenSet[int] = frozenset()  # its membership view at mview
@@ -290,10 +274,10 @@ def init_state(cfg: SpecConfig) -> State:
     return State(
         replicas=tuple(
             Replica(
-                status=JOINING, step=0, lineage=(), residual=0,
+                status=JOINING, step=0, lineage=(),
                 joined=False, round=-1, voted=False, abstain=False,
                 worked=False, diverged=False, healer=False, healed=False,
-                spec_round=-1, spec_token="", epoch=-1,
+                epoch=-1,
                 mview=0, view=full_view,
             )
             for _ in range(cfg.n_replicas)
@@ -353,15 +337,8 @@ def _live(state: State) -> List[int]:
     return [i for i, r in enumerate(state.replicas) if r.status != DEAD]
 
 
-def _provisional_step(r: Replica) -> int:
-    """The step a replica's in-flight state encodes: committed step,
-    plus one while a speculative update is applied."""
-    return r.step + (1 if r.spec_round >= 0 else 0)
-
-
 def _attached(state: State, rnd: Round, j: int) -> bool:
-    r = state.replicas[j]
-    return r.round == rnd.rid or r.spec_round == rnd.rid
+    return state.replicas[j].round == rnd.rid
 
 
 # --- HA helpers ------------------------------------------------------------
@@ -447,14 +424,13 @@ def enabled_actions(
     if state.crash_budget > 0:
         for i in live:
             r = state.replicas[i]
-            # SIGKILL loses everything in memory: the speculative
-            # update, round membership, the un-committed residual
-            # advance. The committed lineage survives (the checkpoint).
+            # SIGKILL loses everything in memory: round membership and
+            # the step in flight. The committed lineage survives (the
+            # checkpoint).
             dead = r._replace(
                 status=DEAD, joined=False, round=-1, voted=False,
                 abstain=False, worked=False, diverged=False,
-                healer=False, healed=False, spec_round=-1,
-                spec_token="", residual=r.step,
+                healer=False, healed=False,
             )
             ns = _replace(
                 state, i, dead,
@@ -503,8 +479,6 @@ def enabled_actions(
                 home = _home(state, i)
                 if home is None or state.subaggs[home].status == DEAD:
                     continue
-            # pipelined: a replica may join the next round while its
-            # previous vote is still in flight — that IS the pipeline
             ns = _replace(
                 state, i, r._replace(joined=True),
                 open_round=state.open_round | {i},
@@ -522,23 +496,18 @@ def enabled_actions(
         if barrier_ok:
             rid = state.rounds_formed
             epoch = state.epoch + 1
-            # the round attempts the max provisional step of its
-            # members (the physical step the fleet's trainers are on);
+            # the round attempts the max committed step of its members;
             # members behind it heal first
-            max_step = max(
-                _provisional_step(state.replicas[i]) for i in joined
-            )
+            max_step = max(state.replicas[i].step for i in joined)
             reps = list(state.replicas)
             for i in joined:
                 r = reps[i]
-                behind = _provisional_step(r) < max_step
+                behind = r.step < max_step
                 reps[i] = r._replace(
                     joined=False, round=rid, voted=False, abstain=False,
                     worked=False, healer=behind, healed=False,
                     epoch=epoch,
-                    status=(HEALING if behind else (
-                        r.status if r.status == SPECULATING else HEALTHY
-                    )),
+                    status=(HEALING if behind else HEALTHY),
                 )
             ns = state._replace(
                 replicas=tuple(reps),
@@ -594,35 +563,19 @@ def enabled_actions(
                     src = state.replicas[j]
                     if (
                         j == i or src.status == DEAD or src.healer
-                        or not _attached(state, rnd, j)
-                        or (src.round == rnd.rid and src.voted)
+                        or src.round != rnd.rid or src.voted
                     ):
                         continue
-                    speculative = src.spec_round >= 0
-                    if cfg.fence_speculation and speculative:
-                        # PR 3 fence: the heal WAITS until the source's
-                        # vote resolves — the action is disabled, not
-                        # taken (resolve of that vote re-enables it)
-                        continue
                     sourced = True
-                    lineage = src.lineage
-                    step = src.step
-                    if speculative:
-                        # fence off: the staged state illegally carries
-                        # the un-voted provisional update
-                        lineage = lineage + (src.spec_token,)
-                        step += 1
                     healed = r._replace(
-                        step=step, lineage=lineage, residual=step,
+                        step=src.step, lineage=src.lineage,
                         healed=True, status=HEALING,
                     )
-                    label = f"heal({i}<-{j})" + (
-                        "!spec" if speculative else ""
+                    out.append(
+                        (f"heal({i}<-{j})", _replace(state, i, healed))
                     )
-                    out.append((label, _replace(state, i, healed)))
                 # -- heal_fail: transfers can fail (torn stream, source
-                # shutdown) and a fenced-out heal eventually times out:
-                # the healer latches the error and its barrier vote
+                # shutdown): the healer latches the error and its barrier vote
                 # abstains — its own step aborts, nobody else's does
                 if not sourced and not r.voted:
                     ns = _replace(
@@ -634,14 +587,11 @@ def enabled_actions(
                     )
                     out.append((f"heal_fail({i})", ns))
 
-            # -- work: compute this round's reduction. A replica with a
-            # still-unresolved speculative vote resolves it before
-            # issuing the next step's ops (resolve_pending_commit
-            # precedes collectives), so work is gated on spec_round < 0.
+            # -- work: compute this round's reduction
             ready = (not r.healer) or r.healed
             if (
                 r.round == rnd.rid and ready and not r.worked
-                and not r.voted and r.spec_round < 0
+                and not r.voted
             ):
                 with_done = _set_round(
                     state, rnd._replace(done=rnd.done | {i})
@@ -659,11 +609,7 @@ def enabled_actions(
             # -- vote: cast this round's commit vote (with the state
             # digest riding it — the token). The token's step is the
             # REPLICA's committed step at vote time (the vote RPC's
-            # rec.step), not the round label: a replica whose previous
-            # speculation was vetoed legitimately re-attempts its
-            # rolled-back step inside a round labeled one ahead
-            # (manager.py start_quorum's "a veto makes that step's
-            # label one ahead" comment).
+            # step), not the round label.
             # a commit vote must ride a membership view at least as new
             # as the one the round's quorum was computed against: with
             # the fence on, a lagging replica applies its pending deltas
@@ -681,36 +627,16 @@ def enabled_actions(
                     r.step, r.diverged and not r.healer, rnd.epoch
                 )
                 tag = "!stale" if stale_view else ""
-                if cfg.speculation and not r.healer:
-                    # pipelined: apply the update provisionally, vote,
-                    # and float free to start the next step while the
-                    # vote is in flight
-                    spec = r._replace(
-                        voted=True, status=SPECULATING,
-                        spec_round=rnd.rid, spec_token=token,
-                        residual=r.step + 1,  # error-feedback applied
-                        round=-1,
-                    )
-                    ns = _replace(state, i, spec)
-                    ns = _set_round(
-                        ns, rnd._replace(votes=rnd.votes + ((i, token),))
-                    )
-                    out.append((f"vote_spec({i}){tag}", ns))
-                else:
-                    ns = _replace(state, i, r._replace(voted=True))
-                    ns = _set_round(
-                        ns, rnd._replace(votes=rnd.votes + ((i, token),))
-                    )
-                    out.append((f"vote({i}){tag}", ns))
+                ns = _replace(state, i, r._replace(voted=True))
+                ns = _set_round(
+                    ns, rnd._replace(votes=rnd.votes + ((i, token),))
+                )
+                out.append((f"vote({i}){tag}", ns))
 
             # -- resolve: this replica's vote decision lands. Commit is
             # arbitrated PER replica group; the divergence fence is the
             # only fleet-global wait (the cohort digest compare).
-            cast = (
-                (r.round == rnd.rid and r.voted)
-                or r.spec_round == rnd.rid
-            )
-            if cast:
+            if r.round == rnd.rid and r.voted:
                 unresolved = [
                     j for j in rnd.members if j not in rnd.resolved
                 ]
@@ -962,7 +888,6 @@ def _resolve(
     state: State, cfg: SpecConfig, rnd: Round, i: int
 ) -> Tuple[str, State]:
     r = state.replicas[i]
-    was_spec = r.spec_round == rnd.rid
 
     # a member that disappeared BEFORE its collective contribution
     # landed broke the survivors' allreduce: their ops errored, the
@@ -990,9 +915,7 @@ def _resolve(
     }
     diverged = len(tokens) > 1
     latched = state.divergence_latched
-    my_token = r.spec_token if was_spec else next(
-        (t for j, t in rnd.votes if j == i), ""
-    )
+    my_token = next((t for j, t in rnd.votes if j == i), "")
     commit = bool(my_token) and not r.abstain and not lost
     if diverged and cfg.fence_divergence:
         commit = False
@@ -1001,40 +924,20 @@ def _resolve(
     if commit:
         new_step = r.step + 1
         lineage = r.lineage + (my_token,)
-        if was_spec:
-            # resolve the speculation in place: the replica may already
-            # be a member of the NEXT round — leave that round's
-            # bookkeeping (round/voted/worked) untouched
-            rep = r._replace(
-                status=(HEALTHY if r.status == SPECULATING else r.status),
-                step=new_step, lineage=lineage, residual=new_step,
-                spec_round=-1, spec_token="",
-            )
-        else:
-            rep = r._replace(
-                status=HEALTHY, step=new_step, lineage=lineage,
-                residual=new_step, round=-1, voted=False, abstain=False,
-                worked=False, diverged=False, healer=False, healed=False,
-            )
+        rep = r._replace(
+            status=HEALTHY, step=new_step, lineage=lineage,
+            round=-1, voted=False, abstain=False,
+            worked=False, diverged=False, healer=False, healed=False,
+        )
         commits = _commit_record(state.commits, r.step, my_token)
     else:
-        residual = r.step
-        if was_spec and not cfg.rollback_residual:
-            residual = r.step + 1  # the planted PR 6 bug
-        if was_spec:
-            rep = r._replace(
-                status=(HEALTHY if r.status == SPECULATING else r.status),
-                residual=residual, spec_round=-1, spec_token="",
-            )
-        else:
-            rep = r._replace(
-                status=HEALTHY, round=-1, voted=False, abstain=False,
-                worked=False, diverged=False, healer=False,
-                # an aborted heal is discarded with the step: the healer
-                # stays behind until a committing round
-                healed=False,
-                residual=residual,
-            )
+        rep = r._replace(
+            status=HEALTHY, round=-1, voted=False, abstain=False,
+            worked=False, diverged=False, healer=False,
+            # an aborted heal is discarded with the step: the healer
+            # stays behind until a committing round
+            healed=False,
+        )
         commits = state.commits
 
     ns = _replace(state, i, rep, commits=commits,
@@ -1065,30 +968,13 @@ def check_state(
                 "commit",
             ))
 
-    # I3: a heal action that copied speculative state is labeled !spec
-    if action.startswith("heal(") and action.endswith("!spec"):
-        out.append(Invariant(
-            "I3-healer-fence",
-            f"{action}: the healer copied a SPECULATING source's state — "
-            "an un-voted optimizer update leaked into a served "
-            "checkpoint (PR 3 fence violated)",
-        ))
-
-    # I4: residual version == the step the replica's state encodes
+    # I4: one lineage token per committed step
     for i, r in enumerate(state.replicas):
         if r.status == DEAD:
             continue
-        expect = _provisional_step(r)
-        if r.residual != expect:
-            out.append(Invariant(
-                "I4-residual-rollback",
-                f"replica {i}: error-feedback residual v{r.residual} but "
-                f"state encodes step {expect} — a vetoed speculative "
-                "update left the residual un-rolled-back (PR 6)",
-            ))
         if len(r.lineage) != r.step:
             out.append(Invariant(
-                "I4-residual-rollback",
+                "I4-lineage-length",
                 f"replica {i}: lineage length {len(r.lineage)} != "
                 f"committed step {r.step}",
             ))
@@ -1151,9 +1037,8 @@ def check_state(
                     ))
 
     # H3: a commit vote rode a membership view older than the round's
-    # (action-labelled, like I3 — the !stale tag marks the transition)
-    if action.startswith(("vote(", "vote_spec(")) \
-            and action.endswith("!stale"):
+    # (action-labelled — the !stale tag marks the transition)
+    if action.startswith("vote(") and action.endswith("!stale"):
         out.append(Invariant(
             "H3-stale-view-commit",
             f"{action}: the commit vote rode a membership view older "

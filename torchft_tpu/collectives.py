@@ -66,13 +66,10 @@ __all__ = [
 # ---------------------------------------------------------------------------
 # Per-stage wall-clock accounting for the cross-group wire plane
 # (docs/wire_plane.md): host-copy / quantize / wire / dequantize-reduce.
-# The crossgroup bench reads these to attribute its gb_per_sec deltas to a
-# stage instead of reporting an unexplained total (the old
-# pipelined_bf16_wire row's 8.4%-only delta was exactly such a mystery).
-# Since ISSUE 8 both functions are thin shims over the step-anatomy
-# ledger (telemetry/anatomy.py) — ONE source of truth, so the crossgroup
-# stages_per_round_s and the bench step_anatomy row can never drift apart
-# (the shim's old private accumulator dict is gone). The ledger mirrors
+# Both functions are thin shims over the step-anatomy ledger
+# (telemetry/anatomy.py) — ONE source of truth for
+# wire_stage_snapshot()'s callers (tests/test_anatomy.py) and a step's
+# anatomy row. The ledger mirrors
 # every record into tft_wire_stage_seconds_total as before.
 # ---------------------------------------------------------------------------
 

@@ -273,8 +273,8 @@ class ManagerClient:
         from torchft_tpu import telemetry
         from torchft_tpu.faultinject.core import fault_point
 
-        # vote-RPC injection: `delay` is the synthetic commit-barrier RTT
-        # (what the pipelined mode must hide), `error` a lost vote
+        # vote-RPC injection: `delay` is a synthetic commit-barrier RTT,
+        # `error` a lost vote
         fault_point(
             "commit.vote", match="rpc", rank=rank, step=step,
         )

@@ -30,16 +30,10 @@ rewrites the packed bucket before the ring, a dtype the native ring does
 not take, a leaf that is not contiguous — is packed into the bucket buffer
 with ``np.copyto`` and reduced in place, as every bucket was before PR 39.
 
-Pipelined-commit note (docs/commit_pipeline.md): callers must resolve
-any in-flight commit vote (``manager.resolve_pending_commit()``) before
-calling :func:`allreduce_gradients` for the next step — the Manager
-raises otherwise, because gradients of a speculative (possibly about to
-be rolled back) state must never enter a collective. The bucket buffers
-here always own their memory (``np.empty``) and are all the ring ever
-writes — the landing arrays and a caller's NumPy leaves are packed from or
-handed over as a source, and either way only read — so the ring
-reduction can never corrupt the caller's retained gradient pytree across
-a rollback/replay.
+The bucket buffers here always own their memory (``np.empty``) and are
+all the ring ever writes — the landing arrays and a caller's NumPy leaves
+are packed from or handed over as a source, and either way only read — so
+the ring reduction can never corrupt a gradient pytree the caller retains.
 
 The bucket buffers live as long as the bucket plan does. Mapping fresh
 host pages costs ~4 us each where writing touched ones runs at memory
@@ -616,7 +610,7 @@ def _host_exchange(
     # The blocked time is the step's main-thread cost of the cross-group
     # wire — recorded as the anatomy ledger's `wire` phase (NOT via
     # record_wire_stage: that would double it into the op-thread socket
-    # totals the crossgroup bench attributes stages with). In a
+    # totals that collectives.wire_stage_snapshot() reports). In a
     # synchronous fleet a slow peer inflates exactly this wait, which is
     # what lets the straggler detector's local-time signal exclude it.
     item_out: List[np.ndarray] = [None] * len(items)  # type: ignore[list-item]

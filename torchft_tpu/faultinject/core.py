@@ -3,8 +3,8 @@
 The paper's core claim (replica death costs at most one step) is only as
 strong as the failure modes that can be reproduced on demand. Kill/restart
 soaks rely on wall-clock races, so the interesting windows — a peer dying
-*mid*-collective, a CMA pull torn halfway, a commit vote delayed past the
-pipeline's speculation fence — fire rarely and can't be bisected. This
+*mid*-collective, a CMA pull torn halfway, a commit vote delayed by a
+slow manager — fire rarely and can't be bisected. This
 module makes them systematic: every layer faults currently hit by accident
 gets a **named injection site**, and a **seeded schedule** decides,
 deterministically, which occurrences of which sites fire which fault.

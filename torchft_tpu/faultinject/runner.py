@@ -17,8 +17,8 @@ seeded schedule and/or native env knobs on a designated victim):
   the victim survives, the step must latch + flush-re-quorum.
 * ``torn_cma_pull`` — a CMA pull stops partway (torn read, the ROADMAP
   divergence hypothesis); the partial buffer must never average in.
-* ``commit_vote_delay_pipeline`` — every 3rd should_commit vote delayed
-  under ``TORCHFT_COMMIT_PIPELINE=1`` (the speculation fence must hold).
+* ``commit_vote_delay`` — every 3rd should_commit vote delayed 150 ms
+  (a slow commit barrier must cost time, never a step).
 * ``ckpt_serve_death`` — the victim is killed, and the survivor's first
   checkpoint serve to the healer is cut mid-stream; the heal must retry,
   never stage torn state.
@@ -195,10 +195,8 @@ SCENARIOS: List[Scenario] = [
         },
     ),
     Scenario(
-        name="commit_vote_delay_pipeline",
-        description="every 3rd commit vote delayed 150ms under the "
-        "pipelined commit mode",
-        common_env={"TORCHFT_COMMIT_PIPELINE": "1"},
+        name="commit_vote_delay",
+        description="every 3rd commit vote delayed 150ms",
         victim_schedule={
             "seed": 2,
             "rules": [

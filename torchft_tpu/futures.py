@@ -17,7 +17,7 @@ from typing import Any, Callable, Generic, List, Optional, Tuple, TypeVar
 T = TypeVar("T")
 S = TypeVar("S")
 
-__all__ = ["Future", "future_timeout", "future_wait", "run_in_executor"]
+__all__ = ["Future", "future_timeout", "future_wait"]
 
 
 class Future(Generic[T]):
@@ -214,24 +214,3 @@ def future_timeout(fut: Future[T], timeout: timedelta) -> Future[T]:
 def future_wait(fut: Future[T], timeout: timedelta) -> T:
     """Block on ``fut`` up to ``timeout`` (torchft/futures.py:138-165)."""
     return fut.wait(timeout)
-
-
-def run_in_executor(executor: Any, fn: Callable[..., T], *args: Any, **kwargs: Any) -> Future[T]:
-    """Run ``fn`` on ``executor`` (a ``concurrent.futures`` executor) and
-    return a chainable :class:`Future` for the result.
-
-    Bridges the stdlib executor world into this module's continuation
-    style so callers can ``then``/``wait`` the result uniformly — the
-    Manager's pipelined commit vote uses this to ship the
-    ``should_commit`` RPC onto its vote thread while the trainer runs the
-    next step's compute."""
-    out: Future[T] = Future()
-
-    def task() -> None:
-        try:
-            out.set_result(fn(*args, **kwargs))
-        except BaseException as e:  # noqa: BLE001 — error futures carry anything
-            out.set_exception(e)
-
-    executor.submit(task)
-    return out

@@ -23,17 +23,6 @@ DiLoCo note: this implementation uses the paper's pseudogradient sign
 ``backup − local`` (so the outer optimizer *descends* toward the inner
 progress). The reference computes ``local − backup`` (local_sgd.py:211-215),
 which inverts the outer step direction; we keep the paper semantics.
-
-Pipelined-commit note: LocalSGD works unchanged on a manager with
-``commit_pipeline=True`` — ``sync`` resolves any vote a pipelined
-per-step driver left in flight before issuing its own quorum and
-collectives (the manager refuses collectives while a vote is pending),
-then takes the synchronous commit path. Pipelining the *sync* barrier
-would buy nothing anyway: it fires once per ``sync_every`` inner steps,
-so its RTT is already amortized.
-DiLoCo additionally rejects a pipelined manager outright, for the same
-reason it requires synchronous quorum: the outer step must start from a
-fully-settled state on every replica.
 """
 
 from __future__ import annotations
@@ -108,13 +97,6 @@ class LocalSGD:
         return params
 
     def sync(self, params: Any) -> Any:
-        # A pipelined per-step driver may have left a vote in flight: the
-        # manager refuses collectives while one is pending, so resolve it
-        # BEFORE this sync's quorum/averaging (the driver's own
-        # on_resolved callback handles any rollback of its state; getattr
-        # keeps duck-typed test stubs working).
-        if getattr(self._manager, "pending_commit", lambda: None)() is not None:
-            self._manager.resolve_pending_commit()
         self._manager.start_quorum()
         # Functional-JAX heal gap the reference never has: torch heals
         # mutate the model in place, so the caller's reference aliases the
@@ -207,13 +189,6 @@ class DiLoCo(LocalSGD):
             raise ValueError(
                 "DiLoCo requires synchronous quorum; construct the Manager "
                 "with use_async_quorum=False"
-            )
-        # getattr: test stubs/duck-typed managers may predate the knob
-        if getattr(manager, "commit_pipeline_enabled", lambda: False)():
-            raise ValueError(
-                "DiLoCo requires the synchronous commit barrier; construct "
-                "the Manager with commit_pipeline=False (the outer step "
-                "must start from a fully-settled state on every replica)"
             )
         super().__init__(manager, sync_every, error_feedback=error_feedback)
         self._outer_tx = outer_tx

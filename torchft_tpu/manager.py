@@ -40,7 +40,7 @@ from torchft_tpu.checkpointing.transport import CheckpointTransport
 from torchft_tpu.collectives import Collectives, ReduceOp, fill_from_sources
 from torchft_tpu.coordination import ManagerClient, ManagerServer
 from torchft_tpu.faultinject.core import fault_point
-from torchft_tpu.futures import Future, future_timeout, run_in_executor
+from torchft_tpu.futures import Future, future_timeout
 from torchft_tpu.profiling import StepTimer
 from torchft_tpu.store import StoreClient
 
@@ -53,47 +53,8 @@ REPLICA_ID_KEY: str = "manager/replica_id"
 MANAGER_PORT_ENV: str = "TORCHFT_MANAGER_PORT"
 LIGHTHOUSE_ENV: str = "TORCHFT_LIGHTHOUSE"
 STORE_ADDR_ENV: str = "TORCHFT_STORE_ADDR"
-COMMIT_PIPELINE_ENV: str = "TORCHFT_COMMIT_PIPELINE"
 
 __all__ = ["Manager", "WorldSizeMode"]
-
-
-class _PendingCommit:
-    """Book-keeping for one in-flight (pipelined) commit vote.
-
-    Everything the post-vote accounting needs is snapshotted at ISSUE
-    time — by the time the vote resolves, the trainer is mid-way through
-    the next step and the manager's live fields (``_errored``,
-    ``_step_epochs``, ``_step_n``) already describe that step."""
-
-    __slots__ = (
-        "future",
-        "step",
-        "n_step",
-        "local_vote",
-        "enough_replicas",
-        "mixed_epochs",
-        "errored",
-        "prepare_s",
-        "on_resolved",
-        "digest",
-        "epoch",
-    )
-
-    def __init__(self) -> None:
-        self.future: Optional[Future] = None
-        self.step = 0
-        self.n_step = 0
-        self.local_vote = False
-        self.enough_replicas = False
-        self.mixed_epochs = False
-        self.errored: Optional[Exception] = None
-        self.prepare_s = 0.0
-        self.on_resolved: Optional[Callable[[bool], None]] = None
-        # divergence sentinel: this step's folded post-reduce digest and
-        # the plane epoch it was reduced under (docs/observability.md)
-        self.digest: Optional[str] = None
-        self.epoch = -1
 
 
 class WorldSizeMode(Enum):
@@ -175,7 +136,6 @@ class Manager:
         hostname: Optional[str] = None,
         heartbeat_interval: timedelta = timedelta(milliseconds=100),
         checkpoint_transport: Optional[CheckpointTransport[Dict[str, T]]] = None,
-        commit_pipeline: bool = False,
     ) -> None:
         """
         Args:
@@ -199,13 +159,6 @@ class Manager:
                 restarted groups are distinct lighthouse members
             port: rank-0 manager server port (TORCHFT_MANAGER_PORT fallback,
                 else ephemeral)
-            commit_pipeline: opt into pipelined commit — the per-step
-                ``should_commit`` vote is issued asynchronously
-                (:meth:`should_commit_async`) so the next step's compute
-                overlaps the vote RTT; semantics stay identical to sync
-                mode via snapshot/rollback in the trainer (see
-                ``docs/commit_pipeline.md``). ``TORCHFT_COMMIT_PIPELINE=1``
-                enables it too. All replica groups must agree on this.
         """
         self._load_state_dict = load_state_dict
         self._user_state_dict = state_dict
@@ -219,9 +172,6 @@ class Manager:
         self._connect_timeout = connect_timeout
         self._world_size_mode = world_size_mode
         self._min_replica_size = min_replica_size
-        self._commit_pipeline = commit_pipeline or (
-            os.environ.get(COMMIT_PIPELINE_ENV, "0") == "1"
-        )
 
         store_addr = store_addr or os.environ[STORE_ADDR_ENV]
         self._rank: int = rank if rank is not None else int(os.environ["RANK"])
@@ -323,24 +273,11 @@ class Manager:
         #   quorum thread, increments on the main thread post-drain
         self._batches_committed = 0
 
-        # Pipelined commit (see docs/commit_pipeline.md): the vote RPC for
-        # step k rides its own thread + socket while the trainer runs step
-        # k+1's compute. _spec_cond fences the quorum thread's heal
-        # send/recv paths until the main thread resolves the vote, so a
-        # served checkpoint is never speculative state. At most ONE vote
-        # is ever outstanding (should_commit_async asserts it).
-        self._pending_commit: Optional[_PendingCommit] = None
-        self._spec_cond = threading.Condition()
-        self._commit_executor: Optional[ThreadPoolExecutor] = None
-        # dedicated vote client: self._client serializes calls on one
-        # socket, so a pipelined vote would otherwise queue behind (or
-        # ahead of) the next step's long-poll quorum RPC
-        self._commit_client: Optional[ManagerClient] = None
         # rolling steps/sec with quorum/heal steps tagged as outliers;
         # should_commit ticks it, so its outlier durations are the
         # recorded per-step recovery cost (telemetry step_outlier events)
         self.step_timer = StepTimer()
-        # step-anatomy ledger (ISSUE 8): _finish_commit ticks the process
+        # step-anatomy ledger (ISSUE 8): should_commit ticks the process
         # ledger so every step's wall clock is decomposed into phases;
         # attaching the timer exports its tagged-outlier digest through
         # anatomy summaries and the flight-recorder dumps
@@ -352,7 +289,7 @@ class Manager:
 
         self._slo = SloManager()
         # unguarded-ok: quorum-thread handoff — set at heal begin on the
-        #   quorum thread, consumed at the next committed _finish_commit
+        #   quorum thread, consumed at the next committed should_commit
         #   on the main thread (wait_quorum is the barrier)
         self._rejoin_t0: Optional[float] = None
         # opt-in fleet straggler monitor: any Manager that knows the
@@ -462,8 +399,7 @@ class Manager:
         # (epoch, step) cohort at the commit boundary. The fence
         # (TORCHFT_DIVERGENCE_FENCE=1, implies the sentinel) additionally
         # vetoes the commit on a mismatch — all groups must agree on the
-        # fence, like commit_pipeline. Off by default: hashing every
-        # reduced buffer is not free.
+        # fence. Off by default: hashing every reduced buffer is not free.
         self._divergence_fence = (
             os.environ.get("TORCHFT_DIVERGENCE_FENCE", "0") == "1"
         )
@@ -472,7 +408,7 @@ class Manager:
         )
         # ordered per-op tree digests of this step's reduced outputs;
         # appended on the op-callback thread (ops resolve in issue order
-        # — the op thread is serial), folded + cleared at _prepare_commit
+        # — the op thread is serial), folded + cleared at should_commit
         self._step_digests: List[str] = []
         self._divergence_latched = False
 
@@ -545,8 +481,8 @@ class Manager:
         """Trace identity for the in-flight step: (replica, step, epoch)
         are globally agreed values, so spans from different replicas with
         equal step/epoch coordinates correlate on the merged timeline.
-        Uses the physical-step label (see start_quorum) so a pipelined
-        replica's spans carry the same step coordinate as the commit
+        Uses the step's label (see start_quorum), so spans that close
+        after the commit carry the same step coordinate as the commit
         event they belong to."""
         return f"{self._replica_id}:{self._step_label}:{self._quorum_id}"
 
@@ -889,20 +825,11 @@ class Manager:
         if self._cp_thread is not None:
             self._cp_thread.join(timeout=5.0)
             self._cp_thread = None
-        # unblock any quorum thread parked on the speculation fence (its
-        # heal serve will fail downstream, which is fine at shutdown)
-        with self._spec_cond:
-            self._pending_commit = None
-            self._spec_cond.notify_all()
         self._checkpoint_transport.shutdown(wait=wait)
         if self._manager is not None:
             self._manager.shutdown()
         self._executor.shutdown(wait=wait)
-        if self._commit_executor is not None:
-            self._commit_executor.shutdown(wait=wait)
         self._collectives.shutdown()
-        if self._commit_client is not None:
-            self._commit_client.close()
         self._client.close()
         self._store.close()
 
@@ -927,16 +854,11 @@ class Manager:
         self._group_healing = False
         self._step_epochs = set()
         self._step_n = None
-        # Step coordinate for this physical step's trail events and trace
-        # ids. With a pipelined vote still in flight, self._step lags one
-        # behind the step now starting — label optimistically with the
-        # in-flight count so quorum_start/spans/commit of ONE physical
-        # step join on one value (exact in sync mode and on every
-        # committed pipelined step; a veto makes that step's label one
-        # ahead, flagged by its commit_rollback event).
-        self._step_label = self._step + (
-            1 if self._pending_commit is not None else 0
-        )
+        # Step coordinate for this step's trail events and trace ids:
+        # fixed here, so quorum_start/spans/commit of ONE step join on one
+        # value although the commit advances self._step before the step's
+        # last spans close.
+        self._step_label = self._step
         telemetry.TRACER.set_context(
             replica_id=self._replica_id,
             step=self._step_label,
@@ -956,10 +878,7 @@ class Manager:
         )
         if self._heal_trail is not None:
             # differential heal: digest the committed state at this step
-            # boundary (with a pipelined vote outstanding the user
-            # callback serves the rollback snapshot, which IS the
-            # committed state at current_step — same invariant the heal
-            # serve path relies on)
+            # boundary
             self._record_commit_trail()
 
         # Replace-under-lock, wait-outside-lock. Replacement only happens
@@ -1198,14 +1117,6 @@ class Manager:
                     or self._manager_addr not in quorum.recover_src_addresses
                 )
             )
-            if quorum.recover_dst_ranks or quorum.heal or stage_for_stripes:
-                # Pipelined commit: a speculative optimizer update may be
-                # outstanding on the main thread. Serving a checkpoint now
-                # would ship UNCOMMITTED state (and a veto would make the
-                # healer's copy wrong); healing onto a speculative state
-                # would race the rollback. Wait for the main thread to
-                # resolve the vote before any heal traffic.
-                self._await_speculation_settled()
             if quorum.recover_dst_ranks or stage_for_stripes:
                 self._logger.info(
                     f"peers need recovery from us {quorum.recover_dst_ranks}"
@@ -1230,7 +1141,7 @@ class Manager:
                 self._healing = True
                 t_heal = _time.perf_counter()
                 # rejoin-to-commit SLO clock starts at heal begin; the
-                # first committed _finish_commit on the main thread
+                # first committed should_commit on the main thread
                 # observes and clears it
                 self._rejoin_t0 = t_heal
                 telemetry.emit(
@@ -1319,12 +1230,8 @@ class Manager:
                 self.load_state_dict(
                     cast(Dict[str, int], self._pending_state_dict["torchft"])
                 )
-                # the received state dict is authoritative: with pipelined
-                # commit the serving side may have resolved a speculative
-                # vote between REPORTING its step (in the quorum RPC) and
-                # SERVING the checkpoint, so its state can be one step
-                # ahead of quorum.max_step — never rewind below the state
-                # the bytes actually encode
+                # the received state dict is authoritative: never rewind
+                # below the state the bytes actually encode
                 self._step = max(self._step, quorum.max_step)
                 heal_s = _time.perf_counter() - t_heal
                 nbytes = getattr(
@@ -1472,17 +1379,6 @@ class Manager:
             fill_from_sources(tensors, sources)
             return Future.completed(tensors)
 
-        if self._pending_commit is not None:
-            # a collective issued while the previous step's vote is still
-            # in flight belongs to an UNRESOLVED lineage: on a veto its
-            # inputs (gradients of speculative params) are garbage, and
-            # blocking inside wait_quorum here could deadlock against the
-            # quorum thread's speculation fence. The blessed flows
-            # (FTTrainer/ManagedOptimizer/bench) all resolve first.
-            raise RuntimeError(
-                "pipelined commit: resolve_pending_commit() before issuing "
-                "collectives for the next step"
-            )
         self.wait_quorum()
         if self.errored():
             # the quorum thread may have latched a failure DURING the wait
@@ -1782,199 +1678,6 @@ class Manager:
     # commit
     # ------------------------------------------------------------------
 
-    def commit_pipeline_enabled(self) -> bool:
-        """Whether this manager was opted into pipelined commit
-        (``commit_pipeline=True`` / ``TORCHFT_COMMIT_PIPELINE=1``)."""
-        return self._commit_pipeline
-
-    def pending_commit(self) -> Optional[Future]:
-        """The in-flight pipelined vote's future, or None. Read-only peek;
-        use :meth:`resolve_pending_commit` to consume it."""
-        rec = self._pending_commit
-        return rec.future if rec is not None else None
-
-    def speculation_allowed(self) -> bool:
-        """Whether the trainer may apply this step's optimizer update
-        speculatively and vote through :meth:`should_commit_async`.
-
-        False whenever the step is already doomed (error latched, mixed
-        plane epochs, too few replicas) — speculating on a known veto just
-        buys a rollback — and whenever state callbacks are in play this
-        step (healing replicas NEVER speculate: the heal lands at the
-        commit barrier and must not race a speculative apply)."""
-        if not self._commit_pipeline or self._quorum_future is None:
-            return False
-        if self._pending_commit is not None:
-            # at most one speculative step outstanding
-            return False
-        self.wait_quorum()
-        if self._healing or self._group_healing:
-            return False
-        if self._errored is not None or len(self._step_epochs) > 1:
-            return False
-        n = (
-            self._step_n
-            if self._step_n is not None
-            else self._participating_world_size
-        )
-        return n >= self._min_replica_size
-
-    def _await_speculation_settled(self) -> None:
-        """Quorum-thread fence: block (bounded) until no speculative
-        commit is outstanding. The main thread resolves the vote early in
-        every step, so in the blessed flows this wait is sub-step-length;
-        the bound keeps a misbehaving caller from wedging the quorum."""
-        cap = min(self._timeout.total_seconds(), 10.0)
-        with self._spec_cond:
-            settled = self._spec_cond.wait_for(
-                lambda: self._pending_commit is None or self._shutting_down,
-                timeout=cap,
-            )
-        if not settled:
-            self._logger.warn(
-                "speculation fence timed out; serving possibly-speculative "
-                "state (resolve_pending_commit() is overdue on the trainer)"
-            )
-
-    def _prepare_commit(self) -> _PendingCommit:
-        """Shared pre-vote half of the commit barrier: drain the step's
-        pending work, land a staged heal, and snapshot everything the
-        post-vote accounting needs (the live fields describe the NEXT
-        step by the time a pipelined vote resolves)."""
-        import time as _time
-
-        # injection window the ROADMAP flake lives in: workers observed
-        # dying silently right AFTER the commit barrier's drain — a kill
-        # scheduled here reproduces that timing on demand
-        fault_point("commit.vote", match="prepare", step=self._step)
-        t0 = _time.perf_counter()
-        for work in self._pending_work:
-            if self._errored is not None:
-                break
-            try:
-                work.wait()
-            except Exception:
-                # wrap_future already latched it
-                pass
-        self._pending_work = []
-
-        if self._healing:
-            self._apply_pending_state_dict()
-
-        rec = _PendingCommit()
-        rec.step = self._step
-        # membership as of the step's OPS (issue-time snapshot), not of a
-        # death-watch re-quorum that may have landed after them
-        rec.n_step = (
-            self._step_n if self._step_n is not None else self.num_participants()
-        )
-        rec.enough_replicas = rec.n_step >= self._min_replica_size
-        # a step whose collectives spanned two plane epochs (death-watch
-        # re-quorum mid-step) mixed normalization denominators. The span is
-        # a LOCAL observation — the re-quorum can land between ops on one
-        # rank and entirely after another's — but client.should_commit is a
-        # global conjunction, so one rank's veto aborts the step group-wide
-        rec.mixed_epochs = len(self._step_epochs) > 1
-        rec.errored = self._errored
-        rec.local_vote = (
-            rec.enough_replicas and self._errored is None and not rec.mixed_epochs
-        )
-        # divergence sentinel: fold the step's ordered per-op digests
-        # into ONE step digest (delta.py's tree fold) and clear for the
-        # next step; the vote RPC piggybacks it to the lighthouse's
-        # (epoch, step) cohort compare. A step that is not committing
-        # cleanly here (error latched / incomplete digest coverage)
-        # ABSTAINS ("-"): it still completes the cohort so peers' fence
-        # waits never stall on an aborting group, but its partial digest
-        # never enters the comparison — only committing states must
-        # agree, and an aborting step commits nothing to diverge.
-        rec.epoch = self._quorum_id
-        if self._divergence_sentinel:
-            rec.digest = "-"
-            if rec.local_vote and self._step_digests:
-                try:
-                    from torchft_tpu.checkpointing import delta as _delta
-
-                    rec.digest = _delta.tree_digest(self._step_digests)
-                except Exception:  # noqa: BLE001 — degrade to abstain
-                    rec.digest = "-"
-        self._step_digests = []
-
-        if self._errored is not None and self._errored_epoch == self._quorum_id:
-            # the data plane is suspect: request a flush so the next quorum
-            # reconfigures every group into a fresh rendezvous epoch. An
-            # error from a PREVIOUS epoch's plane needs no flush — the
-            # death-watch re-quorum already rebuilt connectivity. Recorded
-            # at ISSUE time (nothing reads it before the next quorum RPC,
-            # which in pipelined mode fires while the vote is in flight).
-            self._commit_failures += 1
-        rec.prepare_s = _time.perf_counter() - t0
-        return rec
-
-    def _finish_commit(
-        self, rec: _PendingCommit, should_commit: bool, barrier_s: float,
-        disallow: bool = True,
-    ) -> None:
-        """Shared post-vote half (MAIN thread only): telemetry, step
-        counters, watchdog/step-timer bookkeeping."""
-        self._watchdog.disarm()
-        telemetry.COMMIT_BARRIER.observe(barrier_s)
-        self._logger.info(
-            f"should_commit={should_commit} "
-            f"enough_replicas={rec.enough_replicas} errored={rec.errored}"
-        )
-
-        if disallow:
-            # close the checkpoint-serving window: after the commit the
-            # staged state is stale
-            self._checkpoint_transport.disallow_checkpoint()
-
-        # trail step number is the step that ran (pre-increment) — every
-        # lifecycle record of one step (quorum_start, commit/abort,
-        # step_outlier) joins on the same step value
-        step_in_trail = rec.step
-        if should_commit:
-            telemetry.COMMITS_TOTAL.labels(outcome="committed").inc()
-            telemetry.emit(
-                "commit", step=step_in_trail, participants=rec.n_step
-            )
-            self._step += 1
-            self._batches_committed += rec.n_step
-            telemetry.CURRENT_STEP.set(self._step)
-        else:
-            telemetry.COMMITS_TOTAL.labels(outcome="aborted").inc()
-            telemetry.emit(
-                "abort",
-                step=step_in_trail,
-                enough_replicas=rec.enough_replicas,
-                mixed_epochs=rec.mixed_epochs,
-                errored=str(rec.errored) if rec.errored else None,
-            )
-        # step boundary for the rolling rate: quorum-reconfigure/heal steps
-        # are tagged as outliers, so the recovery cost of an FT event is
-        # readable from the trail instead of denting the headline rate
-        dur = self.step_timer.tick()
-        if dur is not None and self.step_timer.last_tags:
-            telemetry.emit(
-                "step_outlier",
-                step=step_in_trail,
-                duration_s=round(dur, 4),
-                tags=list(self.step_timer.last_tags),
-                committed=should_commit,
-            )
-        # step-anatomy boundary: the barrier cost joins this step's row,
-        # then the row is assembled (idle = wall minus attributed phases)
-        # and the SLO evaluators see the step's wall/rejoin durations
-        telemetry.LEDGER.record("commit_barrier", barrier_s)
-        row = telemetry.LEDGER.tick(step=step_in_trail)
-        if row is not None:
-            self._slo.observe_step(row["wall_s"])
-        if should_commit and self._rejoin_t0 is not None:
-            import time as _time
-
-            self._slo.observe_rejoin(_time.perf_counter() - self._rejoin_t0)
-            self._rejoin_t0 = None
-
     def should_commit(self, timeout: Optional[timedelta] = None) -> bool:
         """Per-step commit barrier: True iff every rank in the group had a
         clean step. Call after backward, step the optimizer only on True."""
@@ -1986,185 +1689,146 @@ class Manager:
         ), "must call start_quorum before should_commit"
         import time as _time
 
-        if self._pending_commit is not None:
-            # a stray pending vote (caller mixed pipelined and sync paths,
-            # e.g. LocalSGD sync on a pipelined manager): resolve it first
-            # — it belongs to the PREVIOUS step; this call votes the
-            # current one
-            self.resolve_pending_commit()
-
         t_commit = _time.perf_counter()
         # the drain of the step's pending work, apart from the vote RPC
         with telemetry.TRACER.span("commit.prepare", trace_id=self._trace_id()):
-            rec = self._prepare_commit()
+            # injection window the ROADMAP flake lives in: workers observed
+            # dying silently right AFTER the commit barrier's drain — a kill
+            # scheduled here reproduces that timing on demand
+            fault_point("commit.vote", match="prepare", step=self._step)
+            for work in self._pending_work:
+                if self._errored is not None:
+                    break
+                try:
+                    work.wait()
+                except Exception:
+                    # wrap_future already latched it
+                    pass
+            self._pending_work = []
+
+            if self._healing:
+                self._apply_pending_state_dict()
+
+            step = self._step
+            # membership as of the step's OPS (issue-time snapshot), not of
+            # a death-watch re-quorum that may have landed after them
+            n_step = (
+                self._step_n
+                if self._step_n is not None
+                else self.num_participants()
+            )
+            enough_replicas = n_step >= self._min_replica_size
+            # a step whose collectives spanned two plane epochs (death-watch
+            # re-quorum mid-step) mixed normalization denominators. The span
+            # is a LOCAL observation — the re-quorum can land between ops on
+            # one rank and entirely after another's — but
+            # client.should_commit is a global conjunction, so one rank's
+            # veto aborts the step group-wide
+            mixed_epochs = len(self._step_epochs) > 1
+            errored = self._errored
+            local_vote = enough_replicas and errored is None and not mixed_epochs
+            # divergence sentinel: fold the step's ordered per-op digests
+            # into ONE step digest (delta.py's tree fold) and clear for the
+            # next step; the vote RPC piggybacks it to the lighthouse's
+            # (epoch, step) cohort compare. A step that is not committing
+            # cleanly here (error latched / incomplete digest coverage)
+            # ABSTAINS ("-"): it still completes the cohort so peers' fence
+            # waits never stall on an aborting group, but its partial digest
+            # never enters the comparison — only committing states must
+            # agree, and an aborting step commits nothing to diverge.
+            digest: Optional[str] = None
+            epoch = self._quorum_id
+            if self._divergence_sentinel:
+                digest = "-"
+                if local_vote and self._step_digests:
+                    try:
+                        from torchft_tpu.checkpointing import delta as _delta
+
+                        digest = _delta.tree_digest(self._step_digests)
+                    except Exception:  # noqa: BLE001 — degrade to abstain
+                        digest = "-"
+            self._step_digests = []
+
+            if errored is not None and self._errored_epoch == self._quorum_id:
+                # the data plane is suspect: request a flush so the next
+                # quorum reconfigures every group into a fresh rendezvous
+                # epoch. An error from a PREVIOUS epoch's plane needs no
+                # flush — the death-watch re-quorum already rebuilt
+                # connectivity.
+                self._commit_failures += 1
+
         with telemetry.TRACER.span(
             "should_commit",
             trace_id=self._trace_id(),
-            vote=rec.local_vote,
+            vote=local_vote,
         ) as sc_span:
             should_commit = self._client.should_commit(
                 self._rank,
-                rec.step,
-                rec.local_vote,
+                step,
+                local_vote,
                 timeout=timeout or self._timeout,
-                digest=rec.digest,
-                epoch=rec.epoch,
+                digest=digest,
+                epoch=epoch,
                 fence=self._divergence_fence,
             )
             sc_span.set(decision=should_commit)
         # getattr: duck-typed test managers may predate the sentinel
         if getattr(self._client, "last_divergence", False) is True:
-            self._note_divergence(rec.step)
-        self._finish_commit(
-            rec, should_commit, _time.perf_counter() - t_commit
+            self._note_divergence(step)
+        barrier_s = _time.perf_counter() - t_commit
+
+        self._watchdog.disarm()
+        telemetry.COMMIT_BARRIER.observe(barrier_s)
+        self._logger.info(
+            f"should_commit={should_commit} "
+            f"enough_replicas={enough_replicas} errored={errored}"
         )
-        return should_commit
 
-    def should_commit_async(
-        self,
-        timeout: Optional[timedelta] = None,
-        on_resolved: Optional[Callable[[bool], None]] = None,
-    ) -> Future:
-        """Pipelined commit barrier: issue this step's vote on the vote
-        thread and return immediately so the caller can start the next
-        step's compute while the RPC is in flight.
-
-        The caller MUST apply the optimizer update speculatively *before*
-        this call (keeping the pre-update state alive as a rollback
-        snapshot) and MUST call :meth:`resolve_pending_commit` before
-        issuing the next step's collectives or commit. ``on_resolved`` is
-        invoked on the MAIN thread inside that resolution, before the
-        speculation fence lifts — restore the snapshot there on a veto so
-        the quorum thread can never serve a half-rolled-back state.
-
-        Returns the vote :class:`~torchft_tpu.futures.Future` (also held
-        internally as the pending record)."""
-        assert (
-            self._quorum_future is not None
-        ), "must call start_quorum before should_commit_async"
-        assert (
-            self._pending_commit is None
-        ), "at most one speculative commit may be outstanding"
-        assert not self._healing, "healing replica must not speculate"
-
-        with telemetry.TRACER.span("commit.prepare", trace_id=self._trace_id()):
-            rec = self._prepare_commit()
-        rec.on_resolved = on_resolved
-        # close the checkpoint-serving window at ISSUE time: resolution
-        # happens after the NEXT step's quorum, which may re-stage a fresh
-        # checkpoint for a healer — a resolution-time disallow would
-        # clobber that newer window (sync mode has no such inversion)
+        # close the checkpoint-serving window: after the commit the staged
+        # state is stale
         self._checkpoint_transport.disallow_checkpoint()
-        if self._commit_executor is None:
-            self._commit_executor = ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix="commit_vote"
+
+        # trail step number is the step that ran (pre-increment) — every
+        # lifecycle record of one step (quorum_start, commit/abort,
+        # step_outlier) joins on the same step value
+        if should_commit:
+            telemetry.COMMITS_TOTAL.labels(outcome="committed").inc()
+            telemetry.emit("commit", step=step, participants=n_step)
+            self._step += 1
+            self._batches_committed += n_step
+            telemetry.CURRENT_STEP.set(self._step)
+        else:
+            telemetry.COMMITS_TOTAL.labels(outcome="aborted").inc()
+            telemetry.emit(
+                "abort",
+                step=step,
+                enough_replicas=enough_replicas,
+                mixed_epochs=mixed_epochs,
+                errored=str(errored) if errored else None,
             )
-        if self._commit_client is None:
-            self._commit_client = ManagerClient(
-                self._manager_addr, connect_timeout=self._connect_timeout
+        # step boundary for the rolling rate: quorum-reconfigure/heal steps
+        # are tagged as outliers, so the recovery cost of an FT event is
+        # readable from the trail instead of denting the headline rate
+        dur = self.step_timer.tick()
+        if dur is not None and self.step_timer.last_tags:
+            telemetry.emit(
+                "step_outlier",
+                step=step,
+                duration_s=round(dur, 4),
+                tags=list(self.step_timer.last_tags),
+                committed=should_commit,
             )
-        trace_id = self._trace_id()
-        vote_timeout = timeout or self._timeout
-
-        def vote() -> bool:
-            with telemetry.TRACER.span(
-                "should_commit",
-                trace_id=trace_id,
-                vote=rec.local_vote,
-                pipelined=True,
-            ) as sc_span:
-                decision = self._commit_client.should_commit(
-                    self._rank, rec.step, rec.local_vote, timeout=vote_timeout,
-                    digest=rec.digest, epoch=rec.epoch,
-                    fence=self._divergence_fence,
-                )
-                sc_span.set(decision=decision)
-            if getattr(self._commit_client, "last_divergence", False) is True:
-                self._note_divergence(rec.step)
-            return decision
-
-        rec.future = run_in_executor(self._commit_executor, vote)
-        # publish under the fence lock: the quorum thread checks
-        # _pending_commit to decide whether heal traffic must wait
-        with self._spec_cond:
-            self._pending_commit = rec
-        return rec.future
-
-    def resolve_pending_commit(self, rearm: bool = True) -> Optional[bool]:
-        """Resolve the in-flight pipelined vote (MAIN thread only).
-
-        Blocks until the vote RPC lands (in steady state it already has —
-        the next step's compute covered the RTT), runs the post-vote
-        accounting, invokes the issue-time ``on_resolved`` callback (which
-        restores the rollback snapshot on a veto), and lifts the
-        speculation fence. Returns the decision, or None when no vote was
-        outstanding. On a vote RPC failure the snapshot is restored (the
-        step is treated as not applied, matching sync-mode semantics where
-        the exception precedes the optimizer update) and the error
-        re-raised.
-
-        ``rearm`` re-arms the step watchdog for the step now in flight;
-        pass False when resolving at the end of training (no step is
-        running, a re-armed watchdog would false-positive an idle
-        process)."""
-        import time as _time
-
-        rec = self._pending_commit
-        if rec is None:
-            return None
-        t0 = _time.perf_counter()
-        try:
-            assert rec.future is not None
-            decision = rec.future.wait()
-        except BaseException as e:  # noqa: BLE001 — restore, then re-raise
-            self._rollback(rec, error=e)
-            with self._spec_cond:
-                self._pending_commit = None
-                self._spec_cond.notify_all()
-            raise
-        blocked_s = _time.perf_counter() - t0
-        # COMMIT_BARRIER records what the commit COST the main thread: the
-        # issue-time drain plus however long resolution actually blocked —
-        # near-zero when the pipeline fully hid the RTT
-        self._finish_commit(
-            rec, decision, rec.prepare_s + blocked_s, disallow=False
-        )
-        if not decision:
-            self._rollback(rec)
-        elif rec.on_resolved is not None:
-            try:
-                rec.on_resolved(True)
-            except Exception:  # noqa: BLE001
-                self._logger.exception("on_resolved callback failed")
-        with self._spec_cond:
-            self._pending_commit = None
-            self._spec_cond.notify_all()
-        if rearm:
-            # start_quorum for the in-flight step already armed the
-            # watchdog, but _finish_commit just disarmed it — re-arm so
-            # the rest of the step keeps stall coverage
-            self._watchdog.arm(self._step_label)
-        return decision
-
-    def _rollback(
-        self, rec: _PendingCommit, error: Optional[BaseException] = None
-    ) -> None:
-        """Run the caller's snapshot restore + record the rollback."""
-        telemetry.COMMIT_PIPELINE_ROLLBACKS.inc()
-        telemetry.emit(
-            "commit_rollback",
-            step=rec.step,
-            errored=str(error) if error is not None else None,
-        )
-        self._logger.warn(
-            f"pipelined commit vetoed at step {rec.step}; rolling back "
-            f"speculative update"
-        )
-        if rec.on_resolved is not None:
-            try:
-                rec.on_resolved(False)
-            except Exception:  # noqa: BLE001
-                self._logger.exception("rollback callback failed")
+        # step-anatomy boundary: the barrier cost joins this step's row,
+        # then the row is assembled (idle = wall minus attributed phases)
+        # and the SLO evaluators see the step's wall/rejoin durations
+        telemetry.LEDGER.record("commit_barrier", barrier_s)
+        row = telemetry.LEDGER.tick(step=step)
+        if row is not None:
+            self._slo.observe_step(row["wall_s"])
+        if should_commit and self._rejoin_t0 is not None:
+            self._slo.observe_rejoin(_time.perf_counter() - self._rejoin_t0)
+            self._rejoin_t0 = None
+        return should_commit
 
     # ------------------------------------------------------------------
     # state

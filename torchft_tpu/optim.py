@@ -18,21 +18,11 @@ replaces the internal pytrees before the update applies::
 ``step`` averages gradients across replica groups through the Manager and
 applies the optax update only if ``should_commit()`` — otherwise the state
 is untouched and the step is discarded.
-
-Pipelined commit (``Manager(commit_pipeline=True)``,
-docs/commit_pipeline.md): ``step`` applies the update speculatively,
-issues the vote asynchronously, and the vote from step *k* resolves inside
-step *k+1*'s ``step()`` — so the value_and_grad between ``begin_step`` and
-``step`` overlaps the vote RTT. On a veto the pre-update pytrees are
-restored; pass ``grad_fn`` (``params -> grads``) so the in-flight batch
-can be replayed on the restored state — without it, a rollback also drops
-the in-flight batch (the vetoed batch is dropped either way, exactly as
-in sync mode).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 from torchft_tpu.ddp import allreduce_gradients
 from torchft_tpu.manager import Manager
@@ -41,60 +31,7 @@ from torchft_tpu.wire_codec import ErrorFeedback, ErrorFeedbackBinding
 __all__ = ["ManagedOptimizer"]
 
 
-class SpeculativeCommitMixin:
-    """Shared pipelined-commit snapshot plumbing (used by both
-    :class:`ManagedOptimizer` and
-    :class:`~torchft_tpu.parallel.ft.FTTrainer`).
-
-    The owner keeps its live pytrees in ``_params`` / ``_opt_state`` and
-    its manager in ``_manager``; this mixin owns the rollback snapshot,
-    the resolution callback, and the *sticky* replay flag — sticky so a
-    vote resolved out-of-band (e.g. a caller who pre-averages via
-    ``manager.allreduce`` must resolve first, because the manager refuses
-    collectives while a vote is pending) still gets its rollback handled
-    at the next ``step``."""
-
-    _snapshot: Optional[Tuple[Any, Any]] = None
-    _replay_needed = False
-    _efb: Optional[ErrorFeedbackBinding] = None  # wire-plane error feedback
-    rollbacks = 0  # speculative steps undone by a veto
-
-    def _on_vote_resolved(self, committed: bool) -> None:
-        """Runs on the main thread inside ``resolve_pending_commit``,
-        before the speculation fence lifts — so the quorum thread can
-        never observe a half-rolled-back (state, step) pair."""
-        if not committed and self._snapshot is not None:
-            self._params, self._opt_state = self._snapshot
-            self.rollbacks += 1
-            self._replay_needed = True
-        self._snapshot = None
-        # error-feedback residuals share the commit lineage: a vetoed
-        # step's staged residual must never compensate the next step
-        ef = self._efb.instance if self._efb is not None else None
-        if ef is not None:
-            if committed:
-                ef.commit()
-            else:
-                ef.rollback()
-
-    def _consume_replay(self) -> bool:
-        """True once per rollback: the current in-flight gradients were
-        computed on the rolled-back state and must be replayed/dropped."""
-        if self._replay_needed:
-            self._replay_needed = False
-            return True
-        return False
-
-    def finish(self) -> Optional[bool]:
-        """Resolve any outstanding speculative commit — call after the
-        last ``step`` of a pipelined run (idempotent; returns the final
-        vote, or None when nothing was outstanding)."""
-        if self._manager.pending_commit() is None:
-            return None
-        return self._manager.resolve_pending_commit(rearm=False)
-
-
-class ManagedOptimizer(SpeculativeCommitMixin):
+class ManagedOptimizer:
     def __init__(
         self,
         manager: Manager,
@@ -121,10 +58,6 @@ class ManagedOptimizer(SpeculativeCommitMixin):
         self._apply = None
         self._params: Optional[Any] = None
         self._opt_state: Optional[Any] = None
-        # pipelined commit (SpeculativeCommitMixin state)
-        self._snapshot = None
-        self._replay_needed = False
-        self.rollbacks = 0
         # wire-plane error feedback (accumulators ride state_dict through
         # heal/checkpoint; pending residuals follow the commit lineage)
         # auto/lazy/CMA-gate semantics live in the shared binding
@@ -134,7 +67,7 @@ class ManagedOptimizer(SpeculativeCommitMixin):
 
     @property
     def error_feedback(self) -> Optional[ErrorFeedback]:
-        return self._efb.instance if self._efb is not None else None
+        return self._efb.instance
 
     # -- state --
 
@@ -154,12 +87,7 @@ class ManagedOptimizer(SpeculativeCommitMixin):
             self._manager.set_state_dict_fns(self.load_state_dict, self.state_dict)
 
     def state_dict(self) -> Dict[str, Any]:
-        snap = self._snapshot
-        if snap is not None:
-            # mid-speculation a peer must heal from COMMITTED state
-            out = {"params": snap[0], "opt_state": snap[1]}
-        else:
-            out = {"params": self._params, "opt_state": self._opt_state}
+        out = {"params": self._params, "opt_state": self._opt_state}
         ef = self.error_feedback
         if ef is not None:
             # committed residuals only (state_dict() on ErrorFeedback
@@ -171,13 +99,8 @@ class ManagedOptimizer(SpeculativeCommitMixin):
     def load_state_dict(self, state: Dict[str, Any]) -> None:
         self._params = state["params"]
         self._opt_state = state["opt_state"]
-        # a heal supersedes any speculative lineage — including a pending
-        # replay: gradients of the NEXT step are taken on this healed
-        # state, so they are valid, not vetoed-lineage leftovers
-        self._snapshot = None
-        self._replay_needed = False
         ef = self.error_feedback
-        if ef is None and "ef" in state and self._efb is not None:
+        if ef is None and "ef" in state:
             # lazy auto mode (e.g. proxied backend): the heal may land
             # before the first live() — adopt the state's accumulators,
             # don't drop them
@@ -194,56 +117,18 @@ class ManagedOptimizer(SpeculativeCommitMixin):
 
     def begin_step(self, allow_heal: bool = True, shrink_only: bool = False) -> None:
         """Start the (async) quorum — call before the forward pass so the
-        RPC overlaps compute (the reference hooks this into zero_grad). In
-        pipelined mode the previous vote stays in flight here too: it
-        resolves inside the next ``step()``, so the caller's
-        value_and_grad is the compute that hides the vote RTT."""
+        RPC overlaps compute (the reference hooks this into zero_grad)."""
         self._manager.start_quorum(allow_heal=allow_heal, shrink_only=shrink_only)
 
-    def step(
-        self,
-        grads: Any,
-        average: bool = True,
-        grad_fn: Optional[Callable[[Any], Any]] = None,
-    ) -> Any:
+    def step(self, grads: Any, average: bool = True) -> Any:
         """Average ``grads`` across replica groups, then apply the update
         iff the step commits. Returns the current params (healed and/or
         updated). Pass ``average=False`` if the gradients already went
-        through ``manager.allreduce``. ``grad_fn`` (``params -> grads``,
-        pipelined mode only) recomputes the gradients after a rollback so
-        the in-flight batch is replayed instead of dropped."""
+        through ``manager.allreduce``."""
         m = self._manager
-        if m.pending_commit() is not None:
-            # resolve the previous step's vote before this step's
-            # collectives/commit (at most one speculative step outstanding)
-            m.resolve_pending_commit()
         ef = self._efb.live()
-        if self._consume_replay():
-            # a rollback happened — here or out-of-band (an average=False
-            # caller resolves before its own manager.allreduce): ``grads``
-            # were computed on the rolled-back params
-            if grad_fn is None:
-                # cannot replay without the loss fn: drop this batch
-                # too (documented pipelined-mode caveat)
-                return self._params
-            # fresh grads always go through the managed average — any
-            # pre-averaging the caller did belongs to the vetoed lineage
-            grads = allreduce_gradients(
-                m, grad_fn(self._params), error_feedback=ef
-            )
-        elif average:
+        if average:
             grads = allreduce_gradients(m, grads, error_feedback=ef)
-        if m.speculation_allowed():
-            # publish the snapshot before the speculative apply so a
-            # concurrent checkpoint serve never sees mid-update trees
-            self._snapshot = (self._params, self._opt_state)
-            self._params, self._opt_state = self._apply_update(
-                self._params, self._opt_state, grads
-            )
-            # the staged EF residual stays PENDING with the vote; it is
-            # promoted/discarded in _on_vote_resolved with the lineage
-            m.should_commit_async(on_resolved=self._on_vote_resolved)
-            return self._params
         committed = m.should_commit()
         # should_commit may have healed: self._params now reflects the
         # recovered state; the gradient applied to it is the participants'
@@ -265,8 +150,7 @@ class ManagedOptimizer(SpeculativeCommitMixin):
 
     def _apply_update(self, params: Any, opt_state: Any, grads: Any):
         # non-donating on purpose: the input pytrees double as the live
-        # recovery snapshot and, in pipelined mode, as the rollback
-        # snapshot — they must stay alive across the update
+        # recovery snapshot — they must stay alive across the update
         if self._apply is None:
             import jax
             import optax

@@ -15,17 +15,6 @@ TPU equivalent keeps the two planes apart by construction:
 snapshots with the Manager so live recovery (send/recv checkpoint) works
 for sharded params: leaves are gathered to host for transfer and re-placed
 with the TrainStep's shardings on load.
-
-Pipelined commit (``Manager(commit_pipeline=True)`` /
-``TORCHFT_COMMIT_PIPELINE=1``, docs/commit_pipeline.md): instead of
-paying the per-step commit-vote RTT serially, ``step`` applies the
-optimizer update immediately (non-donating, so the pre-update pytrees
-stay alive on device as a rollback snapshot — references, not copies),
-issues the vote asynchronously, and the NEXT step's forward/backward runs
-while the vote is in flight. The vote resolves before the next step's own
-collectives; on a veto the snapshot is restored and the in-flight batch
-is replayed on the restored state — the committed state sequence is
-bit-identical to sync mode.
 """
 
 from __future__ import annotations
@@ -34,7 +23,6 @@ from typing import Any, Dict, Optional, Tuple
 
 from torchft_tpu.ddp import allreduce_gradients
 from torchft_tpu.manager import Manager
-from torchft_tpu.optim import SpeculativeCommitMixin
 from torchft_tpu.parallel.train_step import TrainStep
 from torchft_tpu.telemetry import tracing
 from torchft_tpu.telemetry.tracing import TRACER
@@ -42,20 +30,12 @@ from torchft_tpu.telemetry.tracing import TRACER
 __all__ = ["FTTrainer"]
 
 
-class FTTrainer(SpeculativeCommitMixin):
+class FTTrainer:
     def __init__(self, manager: Manager, train_step: TrainStep) -> None:
         self._manager = manager
         self._ts = train_step
         self._params: Optional[Any] = None
         self._opt_state: Optional[Any] = None
-        # pipelined commit (SpeculativeCommitMixin state): the pre-update
-        # (params, opt_state) of the speculative step, alive until its
-        # vote resolves. While set, state_dict() serves IT — a healing
-        # peer must receive committed state, never a speculative update
-        # that a veto would undo.
-        self._snapshot = None
-        self._replay_needed = False
-        self.rollbacks = 0
 
     # -- state (registered with the Manager for live recovery) --
 
@@ -94,13 +74,6 @@ class FTTrainer(SpeculativeCommitMixin):
         # (serialization.py "shards" infos — the DTensor-spec analogue,
         # pg_transport.py:104-114), so a sharded group never gathers the
         # full model onto one host and replicated copies ship once
-        snap = self._snapshot
-        if snap is not None:
-            # mid-speculation: the committed state is the snapshot. The
-            # Manager's speculation fence normally resolves the vote before
-            # any heal serve, but a bounded fence timeout can still land
-            # here — serving the snapshot is correct either way.
-            return {"params": snap[0], "opt_state": snap[1]}
         return {"params": self._params, "opt_state": self._opt_state}
 
     def load_state_dict(self, state: Dict[str, Any]) -> None:
@@ -126,27 +99,6 @@ class FTTrainer(SpeculativeCommitMixin):
         # opt leaf ("Received incompatible devices"). Leaving the leaves
         # uncommitted lets jit place them consistently on every process.
         self._opt_state = state["opt_state"]
-        # a heal supersedes any speculative lineage: the received state IS
-        # the committed one (the manager resolves the vote before heal
-        # traffic, so this is belt-and-braces for the fence-timeout path).
-        # That includes a pending replay — the next step's gradients are
-        # taken on this healed state, so they are valid, not
-        # vetoed-lineage leftovers
-        self._snapshot = None
-        self._replay_needed = False
-
-    # -- pipelined-commit plumbing: SpeculativeCommitMixin provides
-    # _on_vote_resolved / _consume_replay / finish --
-
-    def _resolve_speculation(self) -> bool:
-        """Resolve the previous step's in-flight vote (no-op when none).
-        Returns True when a rollback happened — here or out-of-band —
-        meaning the current batch's forward/backward ran on the restored
-        state's vetoed successor and must be replayed."""
-        if self._manager.pending_commit() is not None:
-            with TRACER.span("resolve_speculation"):
-                self._manager.resolve_pending_commit()
-        return self._consume_replay()
 
     def _record_moe_counters(self, step: int, sync_span) -> None:
         """The router's load of the step just taken, fetched with the loss
@@ -199,56 +151,29 @@ class FTTrainer(SpeculativeCommitMixin):
     def step(self, tokens) -> Tuple[float, bool]:
         """One fault-tolerant step: quorum → device grads → cross-group
         average (host) → commit gate → device update. Returns
-        (loss, committed).
-
-        In pipelined-commit mode the update is applied speculatively and
-        the returned ``committed`` is the *expected* outcome (True); the
-        authoritative result lands when the NEXT step (or :meth:`finish`)
-        resolves the vote — a veto rolls the update back, replays, and
-        bumps :attr:`rollbacks`."""
+        (loss, committed)."""
         mgr = self._manager
         # the step's own timeline (docs/observability.md): one span per piece
         # of the step, in the Tracer ring and — as tft.<name> — in a profiler
-        # trace. A pipelined vote still in flight makes this the step after
-        # current_step(), as the manager labels it.
-        label = mgr.current_step() + (1 if mgr.pending_commit() is not None else 0)
+        # trace.
+        label = mgr.current_step()
         with TRACER.span("step", step_num=label) as step_span:
             # the call only: the quorum itself runs on the quorum thread
             with TRACER.span("quorum.start"):
                 mgr.start_quorum()
             with TRACER.span("shard_batch"):
                 tokens = self._ts.shard_batch(tokens)
-            # forward/backward first: in pipelined mode this is the compute
-            # that hides the previous step's vote RTT
             with TRACER.span("grads"):  # dispatch only
                 loss, grads = self._ts.grads(self._params, tokens)
-            if self._resolve_speculation():
-                # previous step vetoed: grads above were taken on the now
-                # rolled-back params — replay this batch on the restored state
-                with TRACER.span("grads"):  # a step's second: the replay
-                    loss, grads = self._ts.grads(self._params, tokens)
             # cross the elastic replica axis on host (the `exchange` span)
             grads = allreduce_gradients(mgr, grads)
-            if mgr.speculation_allowed():
-                # keep the pre-update trees alive (references, no copy) and
-                # publish the snapshot BEFORE the apply so a concurrent
-                # checkpoint serve never sees the speculative trees
-                self._snapshot = (self._params, self._opt_state)
-                with TRACER.span("apply"):
+            with TRACER.span("commit"):
+                committed = mgr.should_commit()
+            if committed:
+                with TRACER.span("apply"):  # dispatch only
                     self._params, self._opt_state = self._ts.apply(
-                        self._params, self._opt_state, grads, donate=False
+                        self._params, self._opt_state, grads
                     )
-                with TRACER.span("commit"):
-                    mgr.should_commit_async(on_resolved=self._on_vote_resolved)
-                committed = True
-            else:
-                with TRACER.span("commit"):
-                    committed = mgr.should_commit()
-                if committed:
-                    with TRACER.span("apply"):  # dispatch only
-                        self._params, self._opt_state = self._ts.apply(
-                            self._params, self._opt_state, grads
-                        )
             with TRACER.span("loss_sync") as sync_span:
                 loss = float(loss)
                 self._record_moe_counters(label, sync_span)

@@ -95,20 +95,14 @@ class TrainStep:
                 updates, opt_state = tx.update(grads, opt_state, params)
                 return optax.apply_updates(params, updates), opt_state
 
-        self._apply_shardings = dict(
+        self._apply = jax.jit(
+            tft_apply,
+            donate_argnums=(0, 1),
             in_shardings=(
                 self._param_shardings, self._opt_shardings, self._param_shardings
             ),
             out_shardings=(self._param_shardings, self._opt_shardings),
         )
-        self._apply = jax.jit(
-            tft_apply, donate_argnums=(0, 1), **self._apply_shardings
-        )
-        # pipelined-commit variant, compiled lazily: the inputs must NOT
-        # be donated so the pre-update (params, opt_state) stays alive on
-        # device as the rollback snapshot (a reference, not a copy)
-        self._apply_updates_fn = tft_apply
-        self._apply_keep = None
 
         def tft_fused(params, opt_state, tokens):
             loss, grads, stats = tft_grads(params, tokens)
@@ -195,23 +189,13 @@ class TrainStep:
         self._record_compute(t0)
         return tuple(out)
 
-    def apply(self, params, opt_state, grads, donate: bool = True) -> Tuple[Any, Any]:
-        """Apply (possibly host-averaged) grads.
-
-        ``donate=False`` keeps the input buffers alive (at the cost of the
-        update not being in-place) — required when the caller retains the
-        pre-update trees as a pipelined-commit rollback snapshot."""
+    def apply(self, params, opt_state, grads) -> Tuple[Any, Any]:
+        """Apply (possibly host-averaged) grads; ``params`` and
+        ``opt_state`` are donated."""
         import time as _time
 
         t0 = _time.perf_counter()
         with jax.set_mesh(self.mesh):
-            if donate:
-                out = self._apply(params, opt_state, grads)
-            else:
-                if self._apply_keep is None:
-                    self._apply_keep = jax.jit(
-                        self._apply_updates_fn, **self._apply_shardings
-                    )
-                out = self._apply_keep(params, opt_state, grads)
+            out = self._apply(params, opt_state, grads)
         self._record_compute(t0)
         return out

@@ -155,11 +155,6 @@ COMMIT_BARRIER = REGISTRY.histogram(
 CURRENT_STEP = REGISTRY.gauge(
     "tft_current_step", "Committed step counter of this replica group"
 )
-COMMIT_PIPELINE_ROLLBACKS = REGISTRY.counter(
-    "tft_commit_pipeline_rollbacks_total",
-    "Speculative optimizer updates rolled back because a pipelined "
-    "commit vote resolved to veto (commit_pipeline mode only)",
-)
 
 # heal / recovery
 HEALS_TOTAL = REGISTRY.counter(

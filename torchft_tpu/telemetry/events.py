@@ -60,7 +60,6 @@ CANONICAL_EVENTS = (
     "eviction",
     "commit",
     "abort",
-    "commit_rollback",
     "checkpoint_send",
     "checkpoint_recv",
     "step_outlier",
@@ -93,7 +92,6 @@ LIFECYCLE_EVENTS = (
     "heal_failed",
     "commit",
     "abort",
-    "commit_rollback",
     "divergence_detected",
 )
 
