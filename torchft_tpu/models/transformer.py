@@ -17,14 +17,16 @@ per layer) with ``jax.checkpoint`` rematerialization — compile time and
 HBM both scale O(1) in depth.
 
 A model whose layers are not all of one kind DECLARES its pattern
-(``TransformerConfig.kda_layers`` / ``mla_layers`` / ``n_dense_layers``): per
-layer a sequence mixer — ``full`` softmax attention with RoPE, ``window``
+(``TransformerConfig.kda_layers`` / ``mla_layers`` / ``conv_layers`` /
+``n_dense_layers``): per layer a sequence mixer — ``full`` softmax attention
+with RoPE, ``window``
 (the same over a band of ``window`` keys, ``window_layers``; each of the two
 kinds with its own query heads over ``n_kv_heads`` grouped key/value heads
 and its own rotation), ``kda`` (gated delta-rule linear attention,
 ``ops/kda.py``) or ``mla`` (latent attention: without positions, or with
 the query's and the shared key's last lanes rotated under ``mla_rope_theta``;
-the query one projection or low-rank under ``q_lora_rank``) — and a
+the query one projection or low-rank under ``q_lora_rank``) or ``conv`` (a
+gated causal short convolution: no query, key, score or state) — and a
 feed-forward, ``dense`` or ``experts``.
 Its parameters are grouped by kind of layer and the stack runs the leading
 layers one by one, then ``lax.scan`` over whole periods of the pattern with
@@ -99,6 +101,10 @@ class TransformerConfig:
     # RMSNorm with a learned weight over the whole q and k projections,
     # before the head split and RoPE (OLMoE's attention)
     qk_norm: bool = False
+    # with ``qk_norm``: the norm runs over each head's ``head_dim`` lanes instead,
+    # after the head split and before RoPE, under ONE head_dim-wide weight that
+    # the query heads share and one that the key heads share (LFM2's attention)
+    qk_norm_per_head: bool = False
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16  # compute dtype (MXU-native)
@@ -129,6 +135,9 @@ class TransformerConfig:
     # ``full`` attention.
     kda_layers: Tuple[int, ...] = ()  # gated delta-rule linear attention (ops/kda.py)
     mla_layers: Tuple[int, ...] = ()  # latent attention (positions: ``mla_rope_theta``)
+    # a gated short convolution: [B, C, X] = h·conv_in, y = C ⊙ conv(B ⊙ X) over
+    # ``conv_kernel`` causal depthwise taps, y·conv_out; d_model wide, no positions
+    conv_layers: Tuple[int, ...] = ()
     # with experts: this many leading layers keep a dense SwiGLU of d_ff
     n_dense_layers: int = 0
     moe_d_ff: int = 0  # one expert's width; 0 => d_ff (a model of expert layers only)
@@ -144,6 +153,7 @@ class TransformerConfig:
     # times ``routed_scaling_factor``
     router_gate: str = "softmax"
     router_renormalize: bool = False
+    router_norm_eps: float = 1e-20  # beside the chosen scores' sum where they are renormalised
     routed_scaling_factor: float = 1.0
     # -- mla: q is n_heads x (qk_nope + qk_rope); keys are a per-head part of
     # qk_nope from the latent (kv_lora_rank) and one part of qk_rope shared by
@@ -159,8 +169,9 @@ class TransformerConfig:
     # this base of its own; 0 => no positions, what a latent layer had before
     mla_rope_theta: float = 0.0
     # -- kda: linear_n_heads x linear_head_dim keys and values, a causal
-    # depthwise convolution of conv_kernel taps on q, k and v; the two
-    # low-rank gates (decay, output) are linear_head_dim wide inside
+    # depthwise convolution of conv_kernel taps on q, k and v (a ``conv``
+    # layer's own taps are as many); the two low-rank gates (decay, output)
+    # are linear_head_dim wide inside
     linear_head_dim: int = 0
     linear_n_heads: int = 0
     conv_kernel: int = 4
@@ -198,13 +209,13 @@ class TransformerConfig:
     mtp_loss_weight: float = 0.1
 
     def __post_init__(self) -> None:
-        for name in ("kda_layers", "mla_layers", "window_layers", "n_heads_per_layer"):  # a JSON file gives lists
+        for name in ("kda_layers", "mla_layers", "conv_layers", "window_layers", "n_heads_per_layer"):  # a JSON file gives lists
             object.__setattr__(self, name, tuple(int(i) for i in getattr(self, name)))
-        named = self.kda_layers + self.mla_layers + self.window_layers
+        named = self.kda_layers + self.mla_layers + self.conv_layers + self.window_layers
         if len(set(named)) != len(named) or any(not 1 <= i <= self.n_layers for i in named):
             raise ValueError(
-                f"kda_layers {self.kda_layers}, mla_layers {self.mla_layers} and window_layers "
-                f"{self.window_layers} name layers 1..{self.n_layers}, each at most once"
+                f"kda_layers {self.kda_layers}, mla_layers {self.mla_layers}, conv_layers {self.conv_layers} and "
+                f"window_layers {self.window_layers} name layers 1..{self.n_layers}, each at most once"
             )
         if bool(self.window_layers) != bool(self.window):
             raise ValueError(f"window={self.window} and window_layers={self.window_layers}: a band has both")
@@ -223,6 +234,8 @@ class TransformerConfig:
                     f"{mixer} layers have {sorted(heads)} query heads over {self.kv_heads} key/value heads: "
                     "layers of one kind agree, and the groups are whole"
                 )
+        if self.qk_norm_per_head and not self.qk_norm:
+            raise ValueError("qk_norm_per_head says where qk_norm's norm runs: it comes with qk_norm")
         if self.router_gate not in ("softmax", "sigmoid"):
             raise ValueError(f"router_gate must be 'softmax'|'sigmoid', got {self.router_gate!r}")
         if self.n_experts_held and (
@@ -279,6 +292,7 @@ class TransformerConfig:
         for i in range(1, self.n_layers + 1):
             mixer = (
                 "kda" if i in self.kda_layers else "mla" if i in self.mla_layers
+                else "conv" if i in self.conv_layers
                 else "window" if i in self.window_layers else "full"
             )
             ff = "experts" if self.n_experts and i > self.n_dense_layers else "dense"
@@ -384,7 +398,9 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
             wv=dense(keys[2], d, kv, fan_in=d),
             wo=dense(keys[3], qkv, d, fan_in=qkv),
         )
-        if cfg.qk_norm:
+        if cfg.qk_norm_per_head:
+            layers.update(q_norm=ones(cfg.head_dim), k_norm=ones(cfg.head_dim))
+        elif cfg.qk_norm:
             layers.update(q_norm=ones(qkv), k_norm=ones(kv))
     elif mixer == "kda":
         hd, taps = cfg.linear_head_dim, cfg.conv_kernel
@@ -429,6 +445,13 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
             kv_norm=ones(rank),
             w_kvb=dense(keys[2], rank, h * (nope + dv), fan_in=rank),
             wo=dense(keys[3], h * dv, d, fan_in=h * dv),
+        )
+    elif mixer == "conv":
+        taps = cfg.conv_kernel
+        layers.update(
+            conv_in=dense(next(more), d, 3 * d, fan_in=d),  # B | C | X along the features
+            conv_w=dense(next(more), taps, d, fan_in=taps),
+            conv_out=dense(next(more), d, d, fan_in=d),
         )
     else:
         raise ValueError(f"unknown mixer {mixer!r}")
@@ -524,7 +547,9 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
     layers: Dict[str, Any] = {"ln1": spec(None), "ln2": spec(None)}
     if mixer in ("full", "window"):
         layers.update(wq=row, wk=row, wv=row, wo=col)
-        if cfg.qk_norm:
+        if cfg.qk_norm_per_head:
+            layers.update(q_norm=spec(None), k_norm=spec(None))  # one head wide, whole on every chip
+        elif cfg.qk_norm:
             # over the tp-sharded projection: the norm's mean is one all-reduce
             layers.update(q_norm=spec("tp"), k_norm=spec("tp"))
     elif mixer == "kda":
@@ -536,6 +561,8 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
             w_ga=spec("fsdp", None), w_gb=spec(None, "tp"),
             w_beta=spec("fsdp", "tp"), a_log=spec("tp"), dt_bias=spec("tp"), o_norm=spec(None),
         )
+    elif mixer == "conv":
+        layers.update(conv_in=row, conv_w=spec(None, "tp"), conv_out=col)  # channels over tp
     else:
         # the narrow side of both low-rank pairs whole, heads over tp
         layers.update(w_kva=spec("fsdp", None), kv_norm=spec(None), w_kvb=spec(None, "tp"), wo=col)
@@ -623,7 +650,7 @@ def _route(lp: Dict[str, Any], tokens: jnp.ndarray, cfg: TransformerConfig):
     _, top_idx = jax.lax.top_k(biased, cfg.top_k)
     top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
     if cfg.router_renormalize:
-        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + cfg.router_norm_eps)
     top_w = top_w * cfg.routed_scaling_factor
     return top_w, top_idx, scores / jnp.sum(scores, axis=-1, keepdims=True)
 
@@ -949,6 +976,8 @@ def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None
     )
     if cfg.n_mtp_modules:  # the module behind the stack, by the kind of its layer
         fields.update(mtp=_kind_key(kinds[-1]), mtp_weight=cfg.mtp_loss_weight)
+    if cfg.conv_layers:
+        fields.update(conv_kernel=cfg.conv_kernel)
     _say_once("layer_pattern", tuple(fields.values()), **fields)
 
 
@@ -1048,11 +1077,14 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
     name = ("window" if mixer == "window" else "global") if _declares_kinds(cfg) else None
     with jax.named_scope(name) if name else contextlib.nullcontext():
         q, k = h @ lp["wq"], h @ lp["wk"]
-        if cfg.qk_norm:
+        if cfg.qk_norm and not cfg.qk_norm_per_head:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         q = q.reshape(b, s, heads, cfg.head_dim)
         k = k.reshape(b, s, kv_heads, cfg.head_dim)
+        if cfg.qk_norm_per_head:  # each head's lanes by themselves, the heads under one weight
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         v = (h @ lp["wv"]).reshape(b, s, kv_heads, cfg.head_dim)
         rotation = _rotation(cfg, mixer)
         q = rotary_embed(q, positions, **rotation)
@@ -1170,6 +1202,23 @@ def _mix_kda(cfg, lp, h):
         return jnp.moveaxis(out, 0, 1).reshape(b, s, d)
 
 
+def _mix_conv(lp, h):
+    """A gated short convolution: ``[B, C, X] = h·conv_in`` (in this order
+    along the features), ``y = C ⊙ conv(B ⊙ X)`` with ``conv`` the causal
+    depthwise convolution of ``conv_kernel`` taps (zeros ahead of position 0),
+    ``y·conv_out``; the taps are as many as ``conv_w`` has rows. No query, key,
+    score or state, no positions and no activation function. The elementwise
+    part is the scope ``gated_conv_core``: plain ``jax.numpy`` between the two
+    products, for XLA to fuse."""
+    d = h.shape[-1]
+    with jax.named_scope("gated_conv"):
+        bcx = h @ lp["conv_in"]
+        with jax.named_scope("gated_conv_core"):
+            gate_in, gate_out, x = bcx[..., :d], bcx[..., d : 2 * d], bcx[..., 2 * d :]
+            y = gate_out * short_conv(gate_in * x, lp["conv_w"])
+        return y @ lp["conv_out"]
+
+
 @contextlib.contextmanager
 def _scopes(*names: Optional[str]):
     """``jax.named_scope``s nested in order; a None is skipped."""
@@ -1224,6 +1273,12 @@ def _make_layer_fn(
             "state at the end of the shard before it; the hand-over of that state (and of the "
             "short convolution's last taps) from one sp shard to the next is missing"
         )
+    if mixer == "conv" and sp_size > 1:
+        raise ValueError(
+            f"sp={sp_size} with a conv layer: a sequence shard's first positions convolve over the last "
+            f"{cfg.conv_kernel - 1} positions of the shard before it; the hand-over of those gated inputs "
+            "from one sp shard to the next is missing"
+        )
 
     def of_input(fn, norm: str):
         """``fn(lp, h)`` itself, or under ``from_input`` as a function of the
@@ -1248,6 +1303,8 @@ def _make_layer_fn(
                 x = x + part(of_input(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer), "ln1"))(lp, h)
             elif mixer == "kda":
                 x = x + of_input(functools.partial(_mix_kda, cfg), "ln1")(lp, h)
+            elif mixer == "conv":
+                x = x + part(of_input(_mix_conv, "ln1"))(lp, h)
             else:
                 x = x + part(of_input(functools.partial(_mix_mla, cfg, mesh, sp_manual), "ln1"))(lp, h)
 
