@@ -48,7 +48,7 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(REPO, "chip_smoke_out")
 
 MODEL = "scale_647M"  # models.transformer.PRESETS; widths are not negotiable
-BATCH, SEQ = 4, 1024  # `auto` routes s>=1024 to chunked_attention
+BATCH, SEQ = 4, 1024  # `auto` routes s>=1024 to chunked_attention (head_dim 64: the kernel from s2048 on)
 
 
 class PhaseFailed(Exception):
@@ -421,8 +421,10 @@ def kernels_child(
     mla_shape: Optional[list] = None, kda_shape: Optional[list] = None,
 ) -> None:
     """Runs in the child. Flash forward+backward per (b, s, h, d) at its
-    default blocks and, at the benchmark cells' sequence and heads, at the
-    blocks ``attention_impl`` "auto" picks there; chunked attention at the
+    default blocks — a shape that goes on to (…, value width, key/value heads)
+    is a cell's grouped one, at the blocks ``attention_impl`` "auto" picks for
+    it — and, at the benchmark cells' sequence and heads, at the
+    blocks "auto" picks there; chunked attention at the
     smoke's shape; all in bf16 against ops.attention in f32 (matmuls at
     highest precision). ``mla_shape`` (b, s, h, key width, value width):
     the latent attention's core as "auto" takes it (``mla_cells``);
@@ -453,9 +455,12 @@ def kernels_child(
     def check(name, fn, shape, must_be_mosaic):
         b, s, h, d = shape[:4]
         dv = shape[4] if len(shape) > 4 else d  # values of another width than keys
+        hkv = shape[5] if len(shape) > 5 else h  # grouped heads: fewer key/value heads
         ks = jax.random.split(jax.random.PRNGKey(s + d), 4)
-        q, k = (jax.random.normal(kk, (b, s, h, d), jnp.float32) for kk in ks[:2])
-        v, w = (jax.random.normal(kk, (b, s, h, dv), jnp.float32) for kk in ks[2:])
+        q, k, v, w = (
+            jax.random.normal(kk, (b, s, heads, width), jnp.float32)
+            for kk, heads, width in zip(ks, (h, hkv, hkv, h), (d, d, dv, dv))
+        )
         qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
 
         def loss(f, q, k, v):
@@ -489,14 +494,16 @@ def kernels_child(
         }), flush=True)
         return ok
 
-    oks = [
-        check(
-            f"flash_d{shape[3]}",
-            lambda q, k, v: flash_attention(q, k, v, causal=True),
+    def flash_check(shape):
+        grouped = len(shape) > 5
+        tiles = (_flash_blocks(shape[1], shape[3]) if grouped else None) or (128, 128)
+        return check(
+            f"flash_d{shape[3]}" + ("_grouped" if grouped else ""),
+            lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=tiles[0], block_k=tiles[1]),
             shape, must_be_mosaic=platform == "tpu",
         )
-        for shape in flash_shapes
-    ]
+
+    oks = [flash_check(shape) for shape in flash_shapes]
     block_q, block_k = _flash_blocks(cells_shape[1], cells_shape[3]) or (128, 128)
     oks.append(check(
         "flash_cells",
@@ -584,8 +591,11 @@ def kernels(
     kda_shape: Optional[list] = None,
     timeout: float = 600,
 ) -> List[Dict[str, Any]]:
-    # head_dim 64 and 128 are the two the presets use; S >= 2048
-    flash_shapes = flash_shapes or [[1, 2048, 4, 64], [1, 2048, 4, 128]]
+    # head_dim 64 and 128 are the two the presets use; S >= 2048. The last is
+    # lfm2-8b-a1b-1g's attention layer — s8192, 64 lanes, four query heads a
+    # key/value head — at 4 heads over 1 of its 32 over 8 (the f32 reference's
+    # [B, H, S, S] scores at 1 GB), at the tiles "auto" picks there (PR 52)
+    flash_shapes = flash_shapes or [[1, 2048, 4, 64], [1, 2048, 4, 128], [1, 8192, 4, 64, 64, 1]]
     chunked_shape = chunked_shape or [BATCH, SEQ, 16, 64]  # scale_647M's
     # the benchmark cells' s2048 x 16 heads x 128 (b8 there; 2 keeps the
     # f32 reference's [B,H,S,S] scores at 0.5 GB)

@@ -244,9 +244,19 @@ ATTENTION_PATH_CASES = [
     ("tpu", 2048, 128, "pp", "auto", ("chunked", None)),
     ("tpu", 2048, 128, "sp_manual", "auto", ("ring", None)),
     ("tpu", 2048, 128, "sp", "auto", ("ring", None)),
+    # heads of 64 lanes (PR 52): the kernel from s2048 on; below it, on a CPU and inside
+    # a manual region what they ran before; other widths short of a lane tile declined
+    ("tpu", 8192, 64, None, "auto", ("flash", (512, 512))),  # lfm2-8b-a1b-1g's attention layer
+    ("tpu", 2048, 64, None, "auto", ("flash", (512, 512))),  # scale_647M's heads: 6.3 against 9.2 ms
+    ("tpu", 1024, 64, None, "auto", ("chunked", None)),  # chip_smoke's shape: faster (1.02 against 1.28), 0.4 GB it has not
+    ("tpu", 1536, 64, None, "auto", ("chunked", None)),
+    ("tpu", 512, 64, None, "auto", ("plain", None)),
+    ("tpu", 2048 + 128, 64, None, "auto", ("chunked", None)),
+    ("cpu", 8192, 64, None, "auto", ("chunked", None)),
+    ("tpu", 8192, 64, "pp", "auto", ("chunked", None)),
+    ("tpu", 8192, 96, None, "auto", ("chunked", None)),
+    ("tpu", 8192, 32, None, "auto", ("chunked", None)),
     # shapes the rule declines keep plain / chunked
-    ("tpu", 2048, 64, None, "auto", ("chunked", None)),  # scale_647M's heads
-    ("tpu", 1024, 64, None, "auto", ("chunked", None)),  # chip_smoke's shape
     ("tpu", 2048 + 128, 128, None, "auto", ("chunked", None)),  # no multiple of a tile
     ("tpu", 1024, 128, None, "auto", ("flash", (512, 512))),
     ("tpu", 2048 + 512, 128, None, "auto", ("flash", (512, 512))),
@@ -620,19 +630,45 @@ def test_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, shape):
     assert text.count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("dtype, lanes, least_mib, most_mib", [
+    # the cell's kernel as measured (PR 52): what bf16 asked before the storage dtype was counted, to the byte
+    (jnp.bfloat16, 64, 38, 38), (jnp.bfloat16, 128, 50, 50),
+    # check_lfm2.py's float32 program at 64 lanes: its backward needs 42.5 MiB, which bf16's count (38 + the compiler's
+    # own quarter) refused
+    (jnp.float32, 64, 43, 64),
+], ids=str)
+def test_the_scoped_vmem_limit_counts_the_storage_dtype(dtype, lanes, least_mib, most_mib):
+    """8192 resident keys at 512 x 512 tiles: K, V, dK and dV double-buffered in
+    the storage dtype with their float32 accumulators, over the 8 MiB the base
+    allowance holds. (The compiler refuses the WHOLE float32 program, not the
+    kernel compiled alone: a described v5e passed the latter at either count.)"""
+    import sys
+
+    fa = sys.modules["torchft_tpu.ops.pallas.flash_attention"]
+    limit = fa._params(512, 512, fa._resident_bytes(8192, lanes, lanes, jnp.dtype(dtype).itemsize)).vmem_limit_bytes
+    assert least_mib * 2**20 <= limit <= most_mib * 2**20
+
+
 @pytest.mark.parametrize(
-    "batch, heads, window", [(2, 64, 512), (2, 48, None), (1, 64, 512), (1, 48, None)], ids=str
+    "batch, heads, window, lanes",
+    [(2, 64, 512, 128), (2, 48, None, 128), (1, 64, 512, 128), (1, 48, None, 128), (2, 32, None, 64), (1, 32, None, 64)],
+    ids=str,
 )
-def test_banded_and_grouped_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch, heads, window):
+def test_banded_and_grouped_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch, heads, window, lanes):
     """The two kinds of layer of `laguna-xs2-1g.fused-s8192` (PR 41) — 64 query
     heads over 8 key/value heads under a band of 512, 48 over 8 over the whole
-    prefix — at s8192 and 512 x 512 tiles, the cell's batch and the reference
-    check's batch 1, forward and backward through Mosaic for a described v5e
-    (in this file: one process a run may load the TPU's library)."""
+    prefix — and the attention layer of `lfm2-8b-a1b-1g.fused-s8192` (PR 52: 32
+    over 8 of 64 lanes, through ``[B, H, S, Dh]``), at s8192 and 512 x 512
+    tiles, the cell's batch and the reference check's batch 1, forward and
+    backward through Mosaic for a described v5e (in this file: one process a
+    run may load the TPU's library)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    q = jax.ShapeDtypeStruct((batch, 8192, heads, 128), jnp.bfloat16, sharding=one_v5e_chip)
-    kv = jax.ShapeDtypeStruct((batch, 8192, 8, 128), jnp.bfloat16, sharding=one_v5e_chip)
+    from torchft_tpu.models.transformer import _flash_blocks
+
+    assert _flash_blocks(8192, lanes) == (512, 512)
+    q = jax.ShapeDtypeStruct((batch, 8192, heads, lanes), jnp.bfloat16, sharding=one_v5e_chip)
+    kv = jax.ShapeDtypeStruct((batch, 8192, 8, lanes), jnp.bfloat16, sharding=one_v5e_chip)
 
     def step(q, k, v):
         def loss(q, k, v):

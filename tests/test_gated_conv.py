@@ -362,18 +362,22 @@ def test_the_events_say_the_conv_kind_and_the_core_the_attention_layer_took(monk
     assert (pattern["conv_kernel"], pattern["experts_held"], pattern["experts"]) == (3, 4, 16)
 
 
-def test_heads_of_64_take_the_chunked_scan_on_a_chip():
-    """The cell's attention layer (32 x 64 over 8 x 64, 8 192 positions): the
-    Pallas kernel is picked at whole lane tiles only, so on a TPU ``auto``
-    takes the chunked scan, grouped heads and all."""
+def test_heads_of_64_take_the_kernel_on_a_chip_at_the_cells_length():
+    """The cell's attention layer (32 x 64 over 8 x 64, 8 192 positions): on a
+    TPU ``auto`` takes the Pallas kernel at the tile measured fastest at 64
+    lanes (PERF.md §6, PR 52), grouped heads read in place; on a CPU and
+    inside a manual region the chunked scan keeps it."""
     from unittest import mock
 
     with open(os.path.join(ROOT, "benchmark", "configs", "lfm2-8b-a1b-1g.json")) as f:
         tc = json.load(f)["program"]["transformer_config"]
+    assert tc["attention_impl"] == "auto" and tc["head_dim"] == 64
     cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         impl, why, blocks = T._attention_path(cfg, 8192, 2, None, grouped=True)
-    assert (impl, blocks) == ("chunked", None) and "chunked engage point" in why
+        assert (impl, blocks) == ("flash", (512, 512)) and why == "auto on a tpu: the fastest core measured at this (seq, head_dim)"
+        assert T._attention_path(cfg, 8192, 2, None, sp_manual=True, grouped=True)[0] == "chunked"
+    assert T._attention_path(cfg, 8192, 2, None, grouped=True)[::2] == ("chunked", None)
 
 
 def test_a_conv_layer_refuses_a_sharded_sequence():

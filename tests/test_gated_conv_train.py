@@ -18,6 +18,7 @@ import numpy as np
 import optax
 import pytest
 
+from tests.test_attn_core_remat import kernel_calls
 from tests.test_gated_conv import ROOT, SIZES, make
 from torchft_tpu.models import transformer as T
 from torchft_tpu.models.transformer import TransformerConfig, init_params, loss_fn
@@ -36,9 +37,9 @@ CELLS_PROGRAMS = {
 }
 
 
-@pytest.mark.parametrize("name", list(CELLS_PROGRAMS))
-def test_the_other_cells_programs_and_what_they_say_are_unchanged(name, monkeypatch):
-    shape, program, lines = CELLS_PROGRAMS[name]
+def cells_program(name, shape, monkeypatch):
+    """(jaxpr of ``loss_fn``'s value and gradient, its ``_say_once`` lines) of a
+    benchmark configuration at a cell's size, on the chip's branch."""
     with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
         tc = json.load(f)["program"]["transformer_config"]
     cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
@@ -47,9 +48,32 @@ def test_the_other_cells_programs_and_what_they_say_are_unchanged(name, monkeypa
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda p, t: loss_fn(p, t, cfg)))(params, jax.ShapeDtypeStruct(shape, jnp.int32))
+    return jaxpr, said
+
+
+@pytest.mark.parametrize("name", list(CELLS_PROGRAMS))
+def test_the_other_cells_programs_and_what_they_say_are_unchanged(name, monkeypatch):
+    shape, program, lines = CELLS_PROGRAMS[name]
+    jaxpr, said = cells_program(name, shape, monkeypatch)
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     assert hashlib.sha256(text.encode()).hexdigest() == program
     assert hashlib.sha256("\n".join(said).encode()).hexdigest()[:16] == lines
+
+
+def test_the_cells_attention_layer_runs_the_kernel_once_and_says_so(monkeypatch):
+    """`lfm2-8b-a1b-1g.fused-s8192` on the chip's branch (PR 52): the one softmax
+    layer is the Pallas kernel at 64 lanes — one ``flash_fwd`` and one
+    ``flash_bwd`` in the whole step, so none under ``remat``'s recomputation
+    (its output and row statistics are kept) — and its one ``attention_path``
+    line says which core, tile and heads."""
+    from torchft_tpu.ops.pallas.flash_attention import CORE_LSE, CORE_OUT
+
+    jaxpr, said = cells_program("lfm2-8b-a1b-1g", (2, 8192), monkeypatch)
+    calls = kernel_calls(jaxpr.jaxpr)
+    assert (calls["flash_fwd"], calls["flash_bwd"], calls[CORE_OUT], calls[CORE_LSE]) == (1, 1, 1, 1)
+    (line,) = [text for text in said if text.startswith("attention_path ")]
+    assert line.startswith("attention_path impl=flash block_q=512 block_k=512 batch=2 seq=8192 head_dim=64 value_dim=64 ")
+    assert "reason=auto on a tpu: the fastest core measured at this (seq, head_dim)" in line and line.endswith("n_heads=32 n_kv_heads=8 window=0 rotary_dim=64")
 
 
 # -- the names in the lowered program ----------------------------------------------------------

@@ -309,6 +309,7 @@ def test_a_partial_rotation_turns_lane_i_with_lane_i_plus_half_and_passes_the_re
     (1, 256, 4, 4, 128, 64, 32, 64),    # a band without groups, a key tile of two query tiles
     (1, 64, 2, 2, 16, 512, 32, 32),     # the band wider than the sequence
     (1, 128, 4, 2, 16, 1, 32, 32),      # a band of the key itself
+    (1, 128, 32, 8, 64, None, 64, 32),  # lfm2-8b-a1b-1g's layer: 32 heads over 8 of 64 lanes, through [B, H, S, Dh]
 ])
 def test_the_kernel_agrees_with_plain_attention_forward_and_backward(b, s, h, hkv, d, window, bq, bk):
     ks = jax.random.split(jax.random.PRNGKey(0), 4)

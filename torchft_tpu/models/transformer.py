@@ -121,9 +121,10 @@ class TransformerConfig:
     microbatches: int = 0  # 0 => = pp
     # "auto" | "plain" | "chunked" | "flash". auto picks from what it can
     # observe (``_attention_path``): ring attention under sp > 1; on a TPU,
-    # outside a manual region, at head_dim % 128 == 0 and s % 512 == 0 from
-    # s1024 on the Pallas flash kernel at 512 x 512 tiles (the fastest core
-    # measured there, PERF.md §6 PR 31); else the tiered chunked scan
+    # outside a manual region, at s % 512 == 0 from s1024 on where
+    # head_dim % 128 == 0 and from s2048 on where head_dim is 64, the Pallas
+    # flash kernel at 512 x 512 tiles (the fastest core measured there,
+    # PERF.md §6 PR 31 and, at 64 lanes, PR 52); else the tiered chunked scan
     # (ops/attention.chunked_attention, pure XLA) from s >= 1024 and plain
     # XLA attention below; the kernel again, for memory's sake, past the
     # scores-memory ceiling where chunked cannot run. A layer's window and
@@ -787,15 +788,34 @@ def _flash_blocks(seq_len: int, head_dim: int) -> Optional[Tuple[int, int]]:
     """(block_q, block_k) at which the Pallas kernel is the fastest causal
     core measured on a v5e, or None where plain / chunked keep the shape.
 
-    Measured (my chip run, PR 31; PERF.md §6 holds the table) at 16 heads x
-    128, forward + ``remat``'s forward + backward, device time of the whole
-    call: b8 x s2048 8.3 ms a layer at 512 x 512 against 16.3 ms chunked and
-    22.2 plain (1024 x 1024: 8.8; 256 x 512: 11.6; 128 x 128: 24.4);
-    b4 x s4096 13.8 against 25.5; b8 x s1024 3.1 against 4.9. At head_dim
-    64 (b4 x s1024 x 16: ``scale_647M``) the kernel ties chunked (1.25
-    against 1.28 ms) and a head is then no whole lane tile (a transpose a
-    side): declined. Below s1024 nothing was measured: plain keeps it."""
-    if head_dim % 128 or seq_len % 512 or seq_len < 1024:
+    Measured, device time of the whole call (PERF.md §6 holds the tables).
+    At 16 heads x 128 (my chip run, PR 31), forward + ``remat``'s forward +
+    backward: b8 x s2048 8.3 ms a layer at 512 x 512 against 16.3 ms chunked
+    and 22.2 plain (1024 x 1024: 8.8; 256 x 512: 11.6; 128 x 128: 24.4);
+    b4 x s4096 13.8 against 25.5; b8 x s1024 3.1 against 4.9.
+
+    At head_dim 64 (my chip run, PR 52; the kernel through ``[B, H, S, Dh]``,
+    its forward once, as ``_remat`` has kept it since PR 47), 512 x 512
+    against chunked: b2 x s8192 x 32 heads over 8 28.5 against 92.4 ms
+    (1024 x 1024: 28.0; 256 x 512: 38.5; the lanes padded to 128 and read in
+    place: 31.3; the transposes are 0.6 of the 28.5, ~2 in the cell's step);
+    under a band of 512 there 10.0 against 21.1; b4 x s4096 x 32 over 8 17.2
+    against 49.6; b8 x s2048 x 16 6.3 against 9.2. At b4 x s1024 x 16
+    (``scale_647M``) the kernel's 1.02 beat the scan's 1.28 too — its 1.25
+    with the forward run twice was PR 31's tie — but without ``remat`` its
+    residuals (q, k, v, o in ``[B, H, S, 64]``, half-filled lane tiles) cost
+    that preset's ``grads`` 0.40 GB more (7.38 against 6.98 of temporaries,
+    compiled for a described v5e) and ``chip_smoke.py``'s steady phase no
+    longer loaded on the chip: 64 lanes engage from s2048. Other widths short
+    of a lane tile, and anything below s1024, were not measured: chunked /
+    plain keep them."""
+    if head_dim % 128 == 0:
+        least = 1024
+    elif head_dim == 64:
+        least = 2048
+    else:
+        return None
+    if seq_len % 512 or seq_len < least:
         return None
     return 512, 512
 
@@ -837,9 +857,11 @@ def _use_chunked(cfg: TransformerConfig, seq_len: int) -> bool:
     on, where plain attention's f32 [S,S] scores round-trip HBM. Pure XLA —
     works under GSPMD sharding AND inside the pipeline's manual region,
     unlike the pallas kernel, so it is what "auto" takes there, on a CPU,
-    and at the shapes :func:`_flash_blocks` declines. Override the engage
-    point with TORCHFT_TPU_ATTN_CHUNKED_MIN_S. Sequences not divisible by
-    the chunk fall back to plain (both explicit and auto)."""
+    and at the shapes :func:`_flash_blocks` declines (a head width that is
+    neither 64 nor whole lane tiles, 64 lanes below s2048, a length its
+    tiles do not divide).
+    Override the engage point with TORCHFT_TPU_ATTN_CHUNKED_MIN_S. Sequences
+    not divisible by the chunk fall back to plain (both explicit and auto)."""
     if seq_len % _attn_chunk(seq_len) != 0:
         return False
     if cfg.attention_impl == "chunked":

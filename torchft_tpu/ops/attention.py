@@ -94,8 +94,16 @@ def chunked_attention(
     (forward, ``remat``'s forward, backward; my chip run, PR 31) it takes
     16.3 ms where plain attention takes 22.2 (f32 scores round-trip HBM)
     and the Pallas kernel 8.3, so ``attention_impl`` "auto" takes the
-    kernel there; at head_dim 64 (b4 x s1024 x 16) its 1.28 ms beat plain
-    (2.67) and tie the kernel (1.25), and it stays.
+    kernel there. At head_dim 64 (my chip run, PR 52) it takes 92.4 ms at
+    b2 x s8192 x 32 heads over 8 — f32 scores and probabilities through
+    HBM, a group's key/value head repeated — where the kernel, its forward
+    kept across ``remat``, takes 28.5; 9.2 against 6.3 at b8 x s2048 x 16;
+    at b4 x s1024 x 16 its 1.28 ms beat plain (2.67) and only tied the
+    kernel while that ran its forward twice (1.25), and lose to it now
+    (1.02; the scan reads 1.28 with and without ``remat`` around it) but
+    need 0.40 GB less where nothing is rematerialised (``scale_647M``):
+    "auto" takes the kernel at 64 lanes from s2048 on, and this path keeps
+    s1024 there and what the kernel cannot take.
 
     Causal runs additionally skip provably-masked key blocks via static
     k-prefix TIERS: q-segment t of ``tiers`` only scores against keys

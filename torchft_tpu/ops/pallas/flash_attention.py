@@ -35,7 +35,10 @@ group in the scratch accumulators and written once.
 
 Layout: with ``head_dim`` a multiple of 128 (one lane tile) a head's
 columns are read in place from the ``[B, S, H·Dh]`` view, no transpose
-around the call; other head sizes go through ``[B, H, S, Dh]``. Both
+around the call; other head sizes go through ``[B, H, S, Dh]`` — at 64
+lanes (``lfm2-8b-a1b-1g``: b2 x s8192 x 32 heads over 8) the transposes
+are 0.6 ms of the call's 28.5 where padding the lanes to 128 to read heads
+in place cost 2.7 more (my chip run, PR 52). Both
 kernels work on TRANSPOSED scores ``K·Qᵀ`` ``[block_k, block_q]``: the
 per-query statistics (running max and sum, logsumexp, delta) are then
 lane rows that broadcast along sublanes, where as ``[block_q, 1]`` columns
@@ -62,16 +65,18 @@ a step, not twice (PERF.md §6, PR 47). Without such a checkpoint a name is
 the identity.
 
 Role: at the tiles ``models/transformer._flash_blocks`` picks it is the
-speed path of ``attention_impl`` "auto" on a TPU (the policy and its
-measured table: ``_attention_path`` there and PERF.md §6, PR 31), and at
+speed path of ``attention_impl`` "auto" on a TPU, at whole lane tiles and
+at 64 lanes (the policy and its measured tables: ``_attention_path`` there
+and PERF.md §6, PRs 31 and 52), and at
 any tile the memory-ceiling path for sequences whose [S, S] scores cannot
 fit HBM.
 
 On the chip the kernels compile as written (libtpu 0.0.34, jax 0.9.0):
 ``chip_smoke.py`` phase 3 checks for the Mosaic ``tpu_custom_call`` in
 the lowered text and for agreement of forward and backward with
-``ops.attention`` at head_dim 64 and 128 and at the benchmark cells'
-sequence and heads, which is what guards :func:`_should_interpret`'s
+``ops.attention`` at head_dim 64 and 128, at the benchmark cells'
+sequence and heads and at s8192 with four 64-wide query heads a key/value
+head, which is what guards :func:`_should_interpret`'s
 choice from the backend.
 """
 
@@ -311,8 +316,11 @@ def _params(bq: int, bkc: int, resident: int = 0) -> pltpu.CompilerParams:
     )
 
 
-def _resident_bytes(bk: int, d: int, dv: int) -> int:
-    return 12 * bk * (d + dv)
+def _resident_bytes(bk: int, d: int, dv: int, itemsize: int = 2) -> int:
+    # K and V in and dK and dV out, double-buffered in the storage dtype, and the two f32 accumulators: 12 bytes an
+    # element in bf16, 20 in float32 (a reference check's float32 program: at 64 lanes and 8192 resident keys its
+    # backward needs 42.5 MiB where bf16's count allowed 38.25: the compiler refused the whole program, PR 52)
+    return (4 * itemsize + 4) * bk * (d + dv)
 
 
 def _fwd(q, k, v, shape, blocks, causal, interpret):
@@ -348,7 +356,7 @@ def _fwd(q, k, v, shape, blocks, causal, interpret):
             pltpu.VMEM((_ROWS, bq), jnp.float32),
             pltpu.VMEM((_ROWS, bq), jnp.float32),
         ],
-        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv)),
+        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv, q.dtype.itemsize)),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
@@ -401,7 +409,7 @@ def _bwd(shape, blocks, causal, interpret, res, do):
             pltpu.VMEM((bk, dv), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv)),
+        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv, q.dtype.itemsize)),
         interpret=interpret,
         name="flash_bwd",
     )(q, k, v, o, do, lse)
@@ -434,6 +442,13 @@ _flash.defvjp(_flash_fwd, _bwd)
 # longer one writes a float32 part per K block ([4, B, S, H·D] = 2.1 GB at
 # s8192 under the 2048 this was until PR 35) and sums them outside.
 _RESIDENT_KEYS = 8192
+
+
+def _resident_tiles(s: int, bkc: int) -> int:
+    # K and V arrive in the largest whole number of tiles that divides S and
+    # stays under _RESIDENT_KEYS rows: fewer grid steps and DMAs than a tile
+    # a step, and up to _RESIDENT_KEYS the backward's dq needs no second pass
+    return max(m for m in range(1, max(_RESIDENT_KEYS // bkc, 1) + 1) if (s // bkc) % m == 0)
 
 
 def flash_attention(
@@ -482,13 +497,7 @@ def flash_attention(
         raise ValueError(f"seq len {s} must be a multiple of block sizes ({bq},{bkc})")
     if interpret is None:
         interpret = _should_interpret()
-    # K and V arrive in the largest whole number of tiles that divides S and
-    # stays under _RESIDENT_KEYS rows: fewer grid steps and DMAs than a tile
-    # a step, and up to _RESIDENT_KEYS the backward's dq needs no second pass
-    tiles = max(
-        m for m in range(1, max(_RESIDENT_KEYS // bkc, 1) + 1) if (s // bkc) % m == 0
-    )
-    blocks = (bq, bkc * tiles, bkc)
+    blocks = (bq, bkc * _resident_tiles(s, bkc), bkc)
 
     if d % _LANES == 0 and dv % _LANES == 0:
         # a head's columns are whole lane tiles of the [B, S, H·Dh] view
