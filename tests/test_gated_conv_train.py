@@ -30,10 +30,13 @@ CELLS_PROGRAMS = {
     # sha256 of the jaxpr of loss_fn's value and gradient at the cell's sizes (the chip's branch), and the first 16 of
     # the sha256 of its ``_say_once`` lines: what the parent of the PR that brought ``conv_layers``, ``qk_norm_per_head``
     # and ``router_norm_eps`` traced (b43fc8b), letter for letter. kimi-linear-1g and laguna-xs2-1g are held by
-    # ``tests/test_mla_rope_mtp_train.py`` (2ca37b1f…, bd50d408…: equal at both commits too)
-    "olmo1b-1g": ((8, 2048), "7de40b992dc6f005bf1dba3524c90cbc2b714947604bbf4d993ce9b40f836191", "1bcfe2dfb3ff35a0"),
-    "olmoe-1g": ((8, 2048), "64b9511b12c3d97eb6f47844fb7867aa53ee1c73476b5849b86972aec614d150", "1bcfe2dfb3ff35a0"),
-    "joyai-flash-1g": ((2, 8192), "73eb8bdce2dfd97955095e4573ccf6a4676832e6839723e4f68577ea51066b18", "a6aa64069f77d113"),
+    # ``tests/test_mla_rope_mtp_train.py`` (2ca37b1f…, bd50d408…: equal at both commits too). The three programs are
+    # PR 53's (the tree on top of 5cc5599): the interleaved rotation as ``x·C + swap(x)·S`` with its written gradient
+    # (``ops/layers._turn_pairs``) is the one thing that moved them from 7de40b99…, 64b9511b…, 73eb8bdc…; the rotation
+    # says nothing, so the ``_say_once`` digests are the parent's
+    "olmo1b-1g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a", "1bcfe2dfb3ff35a0"),
+    "olmoe-1g": ((8, 2048), "65b119828cd26a22a39bc945227fb3cef92f2b8ae09109a8c17c196e5a896d2d", "1bcfe2dfb3ff35a0"),
+    "joyai-flash-1g": ((2, 8192), "959938bef56e10a002f8ad665bf8fdd14794506432d5a5dfb20c9a6a6ca95bd7", "a6aa64069f77d113"),
 }
 
 

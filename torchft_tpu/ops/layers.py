@@ -68,27 +68,69 @@ def _rotary_half(x: jnp.ndarray, positions: jnp.ndarray, inv_freq, scale: float)
     return jnp.concatenate([t.astype(x.dtype) for t in turned] + [x[..., 2 * half :]], axis=-1)
 
 
+def _swap_pairs(x: jnp.ndarray) -> jnp.ndarray:
+    """Lane 2i and lane 2i + 1 of the last axis exchanged, in ``x``'s own
+    dtype: one fixed permutation, its own inverse. Carried as a product with
+    the constant 0/1 matrix of it — one non-zero term a lane, so exact — which
+    the TPU runs on the MXU and fuses with what reads it, where a stride-2
+    walk over the lanes (``x[..., ::2]``) lowers to gathers and relayouts of
+    the whole activation and its transpose to scatter-adds (PERF.md §6, PR
+    53: the three forms measured on the whole dense step). Its one price: a
+    non-finite lane spreads over its head (0 · inf), not over its pair."""
+    lanes = np.arange(x.shape[-1])
+    swap = jnp.asarray(lanes[:, None] == (lanes ^ 1)[None, :], x.dtype)
+    # bfloat16 products accumulate in float32 everywhere; wider floats must not be cut to bfloat16 passes
+    precision = None if x.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+    return jax.lax.dot_general(x, swap, (((x.ndim - 1,), (0,)), ((), ())), precision=precision)
+
+
+def _pair_tables(positions: jnp.ndarray, theta: float, dh: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`_turn_pairs`'s float32 tables [1, S, 1, Dh]: pair i's angle
+    ``positions · theta^(-2i / Dh)`` at both of its lanes, the cosine as it
+    is and the sine negative at the even lane."""
+    freqs = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    angles = positions[:, None].astype(jnp.float32) * jnp.repeat(freqs, 2)[None, :]  # [S, Dh]
+    sign = np.where(np.arange(dh) % 2, 1.0, -1.0).astype(np.float32)
+    return jnp.cos(angles)[None, :, None, :], (jnp.sin(angles) * sign)[None, :, None, :]
+
+
+@jax.custom_vjp
+def _turn_pairs(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """``x · cos + swap(x) · sin`` with float32 tables over the whole head
+    width, ``sin`` negative at the even lanes: every pair (2i, 2i + 1) turned
+    by its angle, two float32 products and their one sum, rounded once. Linear
+    in ``x`` and ``swap(sin) = -sin``, so the cotangent is the same turn the
+    other way and is WRITTEN so: autodiff of this body would transpose the two
+    ``astype`` apart and add two bfloat16 roundings in bfloat16 (a whole ulp
+    off where this rounds once; tests/test_rotary.py holds a case)."""
+    out = x.astype(jnp.float32) * cos + _swap_pairs(x).astype(jnp.float32) * sin
+    return out.astype(x.dtype)
+
+
+def _turn_pairs_fwd(x, cos, sin):
+    return _turn_pairs(x, cos, sin), (cos, sin)  # linear in x: no activation is kept
+
+
+def _turn_pairs_bwd(tables, g):
+    cos, sin = tables
+    return _turn_pairs(g, cos, -sin), None, None
+
+
+_turn_pairs.defvjp(_turn_pairs_fwd, _turn_pairs_bwd)
+
+
 def rotary_embed(
     x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0, *, inv_freq=None, scale: float = 1.0
 ) -> jnp.ndarray:
     """RoPE. x: [B, S, H, Dh], positions: [S] (global positions, so the
     same code is correct under sequence sharding). Lane 2i turns with lane
-    2i + 1 at ``theta^(-2i / Dh)``; with a table ``inv_freq`` [r/2]
-    (:func:`yarn_inv_freq`) lane i turns with lane i + r/2 inside the first r
-    lanes instead, cos and sin times ``scale``, and the rest of the head passes."""
+    2i + 1 at ``theta^(-2i / Dh)`` (:func:`_turn_pairs`); with a table
+    ``inv_freq`` [r/2] (:func:`yarn_inv_freq`) lane i turns with lane i + r/2
+    inside the first r lanes instead, cos and sin times ``scale``, and the rest
+    of the head passes."""
     if inv_freq is not None:
         return _rotary_half(x, positions, inv_freq, scale)
-    dh = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, Dh/2]
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
-    x1, x2 = x[..., ::2], x[..., 1::2]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    out1 = xf1 * cos - xf2 * sin
-    out2 = xf1 * sin + xf2 * cos
-    out = jnp.stack([out1, out2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
+    return _turn_pairs(x, *_pair_tables(positions, theta, x.shape[-1]))
 
 
 def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray) -> jnp.ndarray:
