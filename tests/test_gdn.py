@@ -1,0 +1,516 @@
+"""Gated DeltaNet as the sequence mixer of three layers in four, a gated softmax
+layer of grouped heads rotated on a part of their lanes, a softmax router
+renormalised over the chosen and a gated shared expert through the one
+transformer — against the plain reference
+(``benchmark/reference/qwen3_next_decoder.py``, loaded by path: one copy) and the
+core against the delta rule taken one position after another.
+``tests/test_gdn_train.py`` holds ``TrainStep``, the Manager, the names in the
+lowered program and the other cells' programs: a file of its own, so that a
+worker of the tier-1 run gets half of the compiles.
+
+Tolerance of the float32 comparisons: both sides compute in float32 on the CPU
+(matmuls at "highest"); what differs is the order of the sums — the program
+takes the rule in chunks of 64 positions where the reference takes positions,
+sorts rows by expert where the reference masks. Measured here: the loss to 2e-7
+of itself, gradient leaves to 1.3e-4 of their largest entry over the seeds below
+(the convolution's taps and the key projection, through the L2 norm and decays of
+tens of nats a position) — float32's own distance there: against the reference
+in float64 the program reads 5.2e-5 and the float32 reference 7.2e-5 on the same
+leaf (``gdn.dense``, seed 3). A slip in the structure reads 1e-2 and more.
+"""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from torchft_tpu.models import transformer as T
+from torchft_tpu.models.transformer import TransformerConfig, init_params, layer_pattern, loss_fn
+from torchft_tpu.ops.kda import gdn_chunked, kda_recurrent
+from torchft_tpu.ops.layers import rms_norm
+from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RTOL = 3e-4
+BF16_BAND = 3e-2
+
+
+def _load_reference():
+    path = os.path.join(ROOT, "benchmark", "reference", "qwen3_next_decoder.py")
+    spec = importlib.util.spec_from_file_location("qwen3_next_decoder_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load_reference()
+
+BASE = dict(vocab_size=64, d_model=32, norm_eps=1e-6, norm_zero_centered=True)
+GDN = dict(linear_n_heads=4, linear_n_key_heads=2, linear_head_dim=8, conv_kernel=4)
+ATTENTION = dict(
+    n_heads=4, n_kv_heads=2, head_dim=16, rotary_dim=4, rope_pairing="half", rope_theta=1e7,
+    qk_norm=True, qk_norm_per_head=True, attn_output_gate=True,
+)
+EXPERTS = dict(
+    moe_d_ff=16, n_experts=16, n_experts_held=4, expert_share_index=1, top_k=4, n_shared_experts=1,
+    shared_expert_gate=True, router_gate="softmax", router_renormalize=True,
+)
+# the cell's stack at tiny widths: one period, three Gated DeltaNet layers and the gated softmax layer, experts in all
+STACK = dict(BASE, **GDN, **ATTENTION, **EXPERTS, n_layers=4, gdn_layers=(1, 2, 3))
+SIZES = {
+    "gdn.dense": dict(BASE, **GDN, n_heads=2, head_dim=16, d_ff=64, n_layers=2, gdn_layers=(1, 2)),  # one kind: the plain scan
+    "gated.dense": dict(BASE, **ATTENTION, d_ff=64, n_layers=2),
+    "stack": STACK,
+}
+
+
+def off_their_defaults(params):
+    """Norm weights off their start (zero under ``1 + w``, one for the mixer's
+    own), a router with loads that differ: or a norm without its weight, or a
+    plain one taken for a zero-centred one, would pass."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
+    out = []
+    for i, (path, a) in enumerate(leaves):
+        name = path[-1].key
+        if name in ("ln1", "ln2", "q_norm", "k_norm", "final_norm", "o_norm"):
+            a = a + 0.3 * jnp.sin(jnp.arange(a.size, dtype=jnp.float32).reshape(a.shape) + i)
+        elif name == "router":
+            a = a * 3.0
+        out.append(a)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def make(size, seq=40, remat=False, seed=3, **changes):
+    sizes = dict(SIZES[size], **changes)
+    cfg = TransformerConfig(dtype=jnp.float32, remat=remat, **sizes)
+    params = off_their_defaults(init_params(jax.random.PRNGKey(seed), cfg))
+    tokens = jnp.asarray(np.random.default_rng(seed).integers(0, 64, (2, seq)), jnp.int32)
+    return cfg, params, tokens, sizes
+
+
+def grad_errors(g_got, g_want):
+    return jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30)), g_got, g_want
+    )
+
+
+def system(cfg, params, tokens):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(loss_fn), static_argnums=2)(params, tokens, cfg)
+
+
+# -- the core against the rule one position after another ------------------------------------------
+
+
+def core_inputs(seq, decay, hk=2, hv=4, d=8, seed=0):
+    rng = np.random.default_rng(seed)
+    unit = lambda x: x / jnp.sqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, k = unit(draw(2, seq, hk, d)) * d**-0.5, unit(draw(2, seq, hk, d))
+    g = -jnp.asarray(rng.uniform(0, decay, size=(2, seq, hv)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0, 1, size=(2, seq, hv)), jnp.float32)
+    return q, k, draw(2, seq, hv, d), g, beta, draw(2, hv, d, d)
+
+
+def by_position(q, k, v, g, beta, S0):
+    """``kda_recurrent`` with value head j reading key head j // 2 and the head's one decay over its lanes."""
+    rep = v.shape[2] // q.shape[2]
+    return kda_recurrent(jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v, jnp.broadcast_to(g[..., None], v.shape), beta, S0)
+
+
+@pytest.mark.parametrize("seq", [64, 100, 192])
+@pytest.mark.parametrize("decay", [0.1, 3.0, 25.0])  # nats a position at most: mild, a trained model's, the published init's
+def test_the_chunked_core_is_the_recurrence_value_and_every_gradient(seq, decay):
+    """``gdn_chunked`` (16 -> 32 heads' reading at 2 -> 4) against the rule one
+    position after another, outputs, final state and the gradient of all six
+    inputs; a sequence of whole chunks, one with a padded tail, and decays up
+    to 25 nats a POSITION, where the per-channel kernels' two-factor form
+    (80 nats over 16 positions) would not serve."""
+    inputs = core_inputs(seq, decay)
+    probe = jnp.asarray(np.random.default_rng(9).normal(size=inputs[2].shape), jnp.float32)
+
+    def scalar(fn):
+        def f(*a):
+            o, S = fn(*a)
+            return jnp.sum(o * probe) + jnp.sum(S * S), (o, S)
+        return f
+
+    grads = lambda fn: jax.jit(jax.grad(scalar(fn), argnums=tuple(range(6)), has_aux=True))
+    with jax.default_matmul_precision("highest"):
+        g_got, (o_got, S_got) = grads(lambda *a: gdn_chunked(*a[:5], initial_state=a[5]))(*inputs)
+        g_want, (o_want, S_want) = grads(by_position)(*inputs)
+    np.testing.assert_allclose(o_got, o_want, atol=2e-5)
+    np.testing.assert_allclose(S_got, S_want, atol=2e-5)
+    assert max(grad_errors(g_got, g_want)) < 2e-5, grad_errors(g_got, g_want)
+
+
+def test_a_pairs_decay_is_summed_over_its_own_positions():
+    """Thousands of nats ahead of a pair that decays little: the pair's factor
+    is exact to float32's relative step, where the difference of two running
+    sums would carry their absolute one (1e-4 at 2 000)."""
+    from torchft_tpu.ops.kda import _sums_between
+
+    g = jnp.concatenate([jnp.full((30,), -70.0), jnp.asarray([-0.25, -0.125, -0.5]), jnp.full((31,), -70.0)])[:, None]
+    between = np.asarray(_sums_between(g.astype(jnp.float32)))
+    assert between[32, 29] == -0.875 and between[31, 30] == -0.125 and between[10, 10] == 0.0 and between[5, 9] == 0.0
+    assert between[33, 0] == pytest.approx(-70.0 * 29 - 0.875 - 70.0, rel=1e-6)
+
+
+def test_the_value_heads_read_the_key_head_of_their_group():
+    """Value heads 0 and 1 read key head 0, heads 2 and 3 key head 1: moving key head 1 moves the last two alone."""
+    q, k, v, g, beta, S0 = core_inputs(64, 1.0)
+    o = gdn_chunked(q, k, v, g, beta)[0]
+    moved = gdn_chunked(q, k.at[:, :, 1].multiply(-1.0).at[:, :, 1, 0].add(0.3), v, g, beta)[0]
+    change = np.asarray(jnp.max(jnp.abs(o - moved), axis=(0, 1, 3)))
+    assert np.all(change[:2] == 0.0) and np.all(change[2:] > 1e-3)
+    with pytest.raises(AssertionError, match="do not divide"):
+        gdn_chunked(q, k, v[:, :, :3], g[..., :3], beta[..., :3])
+
+
+# -- the program against the reference ------------------------------------------------------------
+
+
+@pytest.mark.parametrize("seed", [3, 4, 5])
+@pytest.mark.parametrize("size", list(SIZES))
+def test_loss_and_every_gradient_leaf_agree_with_the_reference(size, seed):
+    cfg, params, tokens, sizes = make(size, remat=True, seed=seed, seq=72)
+    got, g_got = system(cfg, params, tokens)
+    want, g_want = jax.jit(jax.value_and_grad(lambda p, t: ref.loss(p, t, sizes)))(params, tokens)
+    assert float(got) == pytest.approx(float(want), rel=2e-6)
+    errs = grad_errors(g_got, g_want)
+    assert max(jax.tree_util.tree_leaves(errs)) < RTOL, errs
+
+
+def test_per_sequence_loss_is_what_the_worker_compares():
+    cfg, params, tokens, sizes = make("stack")
+    one = jax.jit(lambda p, t: loss_fn(p, t, cfg))
+    with jax.default_matmul_precision("highest"):
+        per = jax.jit(lambda p, t: ref.per_sequence_loss(p, t, sizes))(params, tokens)
+        mine = [float(one(params, tokens[i : i + 1])) for i in range(2)]
+    np.testing.assert_allclose(per, mine, rtol=2e-6)
+
+
+def test_bfloat16_compute_stays_inside_the_stated_band():
+    cfg, params, tokens, sizes = make("stack")
+    got = float(jax.jit(loss_fn, static_argnums=2)(params, tokens, dataclasses.replace(cfg, dtype=jnp.bfloat16)))
+    assert abs(got - float(ref.loss(params, tokens, sizes))) < BF16_BAND
+
+
+def test_the_reference_finds_each_layers_parameters_in_the_programs_tree():
+    _, params, _, sizes = make("stack")
+    layers = ref.layer_params(params, sizes)
+    assert [("w_ba" in w, "wq" in w and "w_ba" not in w, "shared_scale" in w) for w in layers] == [(True, False, True)] * 3 + [(False, True, True)]
+    np.testing.assert_array_equal(layers[1]["a_log"], params["periods"]["gdn.experts"]["a_log"][0, 1])
+    np.testing.assert_array_equal(layers[3]["q_norm"], params["periods"]["full.experts"]["q_norm"][0, 0])
+
+
+# -- the two mixers by themselves ---------------------------------------------------------------
+
+
+def gdn_leaves(cfg, key=0):
+    lp = jax.tree_util.tree_map(lambda a: a[0, 0], init_params(jax.random.PRNGKey(key), cfg)["layers"])
+    return dict(lp, o_norm=lp["o_norm"] + 0.3 * jnp.sin(jnp.arange(8.0)))
+
+
+@pytest.mark.parametrize("dtype, tol, grad_tol", [(jnp.float32, 2e-5, 1e-4), (jnp.bfloat16, 6e-2, 0.25)])
+@pytest.mark.parametrize("block", [16, 1024])  # three blocks that hand the state and the taps on; one block
+def test_the_gdn_mixer_and_its_gradients_against_the_reference(block, dtype, tol, grad_tol, monkeypatch):
+    """``_mix_gdn`` against the reference's mixer, output and the gradient of
+    every leaf and of the input, with the sequence in one block and in three
+    (the carried state and the convolution's last taps): float32 to the order
+    of the sums, bfloat16 to a few percent of the output's largest entry and to
+    a fifth of a gradient leaf's (the key's taps, behind the L2 norm of eight
+    lanes rounded to eight bits: 0.19)."""
+    monkeypatch.setattr(T, "_KDA_BLOCK", block)
+    sizes = SIZES["gdn.dense"]
+    cfg = TransformerConfig(dtype=jnp.float32, **sizes)
+    lp = gdn_leaves(cfg)
+    h = jax.random.normal(jax.random.PRNGKey(5), (2, 48, 32))
+    probe = jax.random.normal(jax.random.PRNGKey(6), (2, 48, 32))
+    f32 = T._F32_LEAVES
+    cast = lambda tree: {k: a if k in f32 else a.astype(dtype) for k, a in tree.items()}
+
+    def mine(lp, h):
+        y, said = T._mix_gdn(cfg, cast(lp), h.astype(dtype))
+        return jnp.sum(y.astype(jnp.float32) * probe), (y.astype(jnp.float32), said)
+
+    def theirs(lp, h):
+        y = ref._gdn(lp, h, sizes, 1e-6)
+        return jnp.sum(y * probe), (y, None)
+
+    with jax.default_matmul_precision("highest"):
+        (g_got, (y_got, said)), (g_want, (y_want, _)) = (
+            jax.jit(lambda lp, h, f=f: (jax.grad(f, argnums=(0, 1), has_aux=True)(lp, h)))(lp, h) for f in (mine, theirs)
+        )
+    assert float(jnp.max(jnp.abs(y_got - y_want)) / jnp.max(jnp.abs(y_want))) < tol
+    assert max(jax.tree_util.tree_leaves(grad_errors(g_got, g_want))) < grad_tol
+    ba = h @ lp["w_ba"]
+    np.testing.assert_allclose(said["decay_min"], jnp.min(ref._decay(lp, ba[..., 4:])), rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
+    np.testing.assert_allclose(said["beta_mean"], jnp.mean(jax.nn.sigmoid(ba[..., :4])), rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
+
+
+def test_the_gdn_stack_is_causal_and_a_head_has_one_decay():
+    cfg, params, tokens, _ = make("gdn.dense")
+    hidden = jax.jit(lambda t: T._hidden_states(params, t, cfg)[0])
+    t = 17
+    changed = tokens.at[:, t].set((tokens[:, t] + 1) % 64)
+    moved = np.asarray(jnp.max(jnp.abs(hidden(tokens) - hidden(changed)), axis=(0, 2)))
+    assert np.all(moved[:t] == 0.0) and moved[t] > 1e-3 and moved[t + 1] > 0.0  # the taps and the state carry it on
+    leaves = params["layers"]
+    assert leaves["a_log"].shape == leaves["dt_bias"].shape == (1, 2, 4) and leaves["w_ba"].shape == (1, 2, 32, 8)
+    assert leaves["wq"].shape == leaves["wk"].shape == (1, 2, 32, 16) and leaves["wv"].shape == leaves["w_z"].shape == (1, 2, 32, 32)
+    assert bool(jnp.all(leaves["dt_bias"] == 1.0)) and bool(jnp.all(jnp.exp(leaves["a_log"]) <= 16.0))
+
+
+def attention_leaves(cfg, key=0):
+    lp = jax.tree_util.tree_map(lambda a: a[0, 0], init_params(jax.random.PRNGKey(key), cfg)["layers"])
+    wave = lambda phase: 0.3 * jnp.sin(jnp.arange(16.0) + phase)
+    return dict(lp, q_norm=wave(0.0), k_norm=wave(1.0))
+
+
+def test_the_gated_softmax_layer_against_the_reference_and_what_it_is_not():
+    """``_mix_full`` under ``attn_output_gate`` is the reference's attention —
+    a head's lanes ``[q | gate]``, q and k normed head by head under ``1 + w``,
+    4 of 16 lanes rotated, the core's output through ``sigmoid(gate)`` — and is
+    NOT the layer with the gate dropped, with the query's and the gate's lanes
+    taken as two halves of the projection, with a plain-weight norm, or with
+    the whole head rotated."""
+    sizes = SIZES["gated.dense"]
+    cfg = TransformerConfig(dtype=jnp.float32, **sizes)
+    lp = attention_leaves(cfg)
+    assert lp["wq"].shape == (32, 2 * 4 * 16) and lp["wo"].shape == (4 * 16, 32)
+    h = jax.random.normal(jax.random.PRNGKey(9), (2, 24, 32))
+    with jax.default_matmul_precision("highest"):
+        got = T._mix_full(cfg, None, False, lp, h)
+        want = ref._attention(lp, h, sizes, 1e-6)
+        halves = jnp.concatenate([lp["wq"].reshape(32, 4, 2, 16)[:, :, 0].reshape(32, 64), lp["wq"].reshape(32, 4, 2, 16)[:, :, 1].reshape(32, 64)], axis=1)
+        others = {
+            "no gate": T._mix_full(dataclasses.replace(cfg, attn_output_gate=False), None, False, dict(lp, wq=halves[:, :64]), h),
+            "q | gate as halves": T._mix_full(cfg, None, False, dict(lp, wq=halves), h),
+            "plain norm": T._mix_full(dataclasses.replace(cfg, norm_zero_centered=False), None, False, lp, h),
+            "whole head rotated": T._mix_full(dataclasses.replace(cfg, rotary_dim=0), None, False, lp, h),
+        }
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5 * scale
+    for name, other in others.items():
+        assert float(jnp.max(jnp.abs(got - other))) > 1e-2 * scale, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_zero_centred_norm_scales_by_one_plus_its_weight(dtype):
+    x = jax.random.normal(jax.random.PRNGKey(0), (4, 64)).astype(dtype)
+    w = 0.002 * jnp.cos(jnp.arange(64.0))  # under bfloat16's step at 1: 1 + w is 1 there
+    got = rms_norm(x, w.astype(dtype), 1e-6, zero_centered=True).astype(jnp.float32)
+    xf = x.astype(jnp.float32)
+    want = xf / jnp.sqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6) * (1.0 + w.astype(dtype).astype(jnp.float32))
+    np.testing.assert_allclose(got, want, rtol=1e-6 if dtype == jnp.float32 else 4e-3)
+    plain = rms_norm(x, jnp.ones(64, dtype), 1e-6).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(got - plain))) > 0  # the small weight is there, in bfloat16 too
+    np.testing.assert_array_equal(rms_norm(x, jnp.zeros(64, dtype), 1e-6, zero_centered=True), rms_norm(x, jnp.ones(64, dtype), 1e-6))
+    zc = init_params(jax.random.PRNGKey(0), TransformerConfig(dtype=jnp.float32, **SIZES["stack"]))
+    assert float(jnp.max(jnp.abs(zc["final_norm"]))) == 0.0 and float(jnp.max(jnp.abs(zc["periods"]["full.experts"]["q_norm"]))) == 0.0
+    assert bool(jnp.all(zc["periods"]["gdn.experts"]["o_norm"] == 1.0)) and float(jnp.max(jnp.abs(zc["periods"]["gdn.experts"]["ln2"]))) == 0.0
+
+
+# -- the gate, the shared expert and the share ----------------------------------------------------
+
+
+def expert_leaves(d=32, e=16, f=16, key=0):
+    keys = jax.random.split(jax.random.PRNGKey(key), 8)
+    n = lambda k, *shape, fan: jax.random.normal(k, shape) * fan**-0.5
+    return {
+        "router": 3.0 * n(keys[0], d, e, fan=d),
+        "w_gate": n(keys[1], e, d, f, fan=d), "w_in": n(keys[2], e, d, f, fan=d), "w_out": n(keys[3], e, f, d, fan=f),
+        "shared_gate": n(keys[4], d, f, fan=d), "shared_in": n(keys[5], d, f, fan=d), "shared_out": n(keys[6], f, d, fan=f),
+        "shared_scale": n(keys[7], d, 1, fan=d),
+    }
+
+
+def share_of(whole, share, held=4):
+    return {k: (v[held * share : held * share + held] if k in ("w_gate", "w_in", "w_out") else v) for k, v in whole.items()}
+
+
+def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_one():
+    """Four chips, four of sixteen experts each: the four shares' routed parts,
+    with the gated shared expert that every share computes alike counted ONCE,
+    add up to the uncut 16-expert reference, and every token-expert row is
+    computed on exactly one share."""
+    whole = expert_leaves()
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 48, 32))
+    with jax.default_matmul_precision("highest"):
+        want = ref._experts(whole, x, dict(STACK, n_experts_held=0, expert_share_index=0))
+        shared = ref._shared_gate(whole, x) * ref._swiglu(x, whole["shared_gate"], whole["shared_in"], whole["shared_out"])
+        parts, rows = [], []
+        for share in range(4):
+            cfg = TransformerConfig(dtype=jnp.float32, **dict(STACK, expert_share_index=share))
+            lp = share_of(whole, share)
+            y, (_, counts, held, gate) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
+            np.testing.assert_allclose(y, ref._experts(lp, x, dict(STACK, expert_share_index=share)), atol=5e-5)
+            np.testing.assert_allclose(gate, jnp.mean(ref._shared_gate(whole, x)), rtol=1e-5)
+            parts.append(y - shared)
+            rows.append(int(held))
+            assert int(jnp.sum(counts)) == 2 * 48 * 4  # the router counts over all 16, on every share
+    np.testing.assert_allclose(sum(parts) + shared, want, atol=5e-5)
+    assert sum(rows) == 2 * 48 * 4 and len(set(rows)) > 1
+
+
+@pytest.mark.parametrize("kind", [("gdn", "experts"), ("full", "experts")])
+def test_the_four_shares_of_a_whole_layer_add_up_to_the_uncut_layer(kind):
+    """The share test of the guide's §4 on a whole layer of each kind: what
+    every share computes — the residual, the mixer, the gated shared expert —
+    counted once, plus the four shares' routed parts, is the uncut reference's
+    layer."""
+    uncut = dict(STACK, n_experts_held=0, expert_share_index=0)
+    cfg0 = TransformerConfig(dtype=jnp.float32, **STACK)
+    key = "gdn.experts" if kind[0] == "gdn" else "full.experts"
+    lp = off_their_defaults(jax.tree_util.tree_map(lambda a: a[0, 0], init_params(jax.random.PRNGKey(1), cfg0)["periods"][key]))
+    whole = dict(lp, **{k: v for k, v in expert_leaves(key=3).items() if k in ("router", "w_gate", "w_in", "w_out")})
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 48, 32))
+    eps = 1e-6
+    with jax.default_matmul_precision("highest"):
+        want = ref._layer(whole, x, uncut, kind)
+        outs = []
+        for share in range(4):
+            cfg = TransformerConfig(dtype=jnp.float32, **dict(STACK, expert_share_index=share))
+            outs.append(jax.jit(lambda lp, x, cfg=cfg: T._make_layer_fn(cfg, None, kind=kind)(x, lp)[0])(share_of(whole, share), x))
+        # what every share computes alike, from the reference: the residual, the mixer, the gated shared expert
+        mixer = ref._gdn if kind[0] == "gdn" else ref._attention
+        mixed = x + mixer(whole, ref._rms_norm_zc(x, whole["ln1"], eps), uncut, eps)
+        h = ref._rms_norm_zc(mixed, whole["ln2"], eps)
+        alike = mixed + ref._shared_gate(whole, h) * ref._swiglu(h, whole["shared_gate"], whole["shared_in"], whole["shared_out"])
+    np.testing.assert_allclose(sum(out - alike for out in outs) + alike, want, atol=1e-4)
+    assert float(jnp.max(jnp.abs(outs[0] - outs[1]))) > 1e-3  # the shares differ: each holds other experts
+
+
+def test_the_softmax_gate_renormalised_sums_to_one_and_is_todays_with_the_flag_off():
+    lp = expert_leaves()
+    tokens = jax.random.normal(jax.random.PRNGKey(4), (96, 32))
+    off = TransformerConfig(dtype=jnp.float32, **dict(STACK, router_renormalize=False))
+    on = TransformerConfig(dtype=jnp.float32, **STACK)
+    w_off, idx_off, p_off = T._route(lp, tokens, off)
+    w_on, idx_on, p_on = T._route(lp, tokens, on)
+    probs = jax.nn.softmax(tokens @ lp["router"], axis=-1)
+    top, idx = jax.lax.top_k(probs, 4)
+    # the flag off: the chosen probabilities as they are, what the softmax gate always gave (OLMoE's program)
+    np.testing.assert_array_equal(w_off, top)
+    np.testing.assert_array_equal(idx_off, idx)
+    np.testing.assert_array_equal(idx_on, idx)
+    np.testing.assert_array_equal(p_on, p_off)
+    assert float(jnp.min(jnp.sum(w_off, axis=-1))) < 0.9
+    np.testing.assert_allclose(jnp.sum(w_on, axis=-1), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(w_on, top / jnp.sum(top, axis=-1, keepdims=True), rtol=1e-6)
+    mine = jnp.zeros_like(probs).at[jnp.arange(96)[:, None], idx_on].set(w_on)
+    np.testing.assert_allclose(mine, ref._weigh(probs, probs >= top[:, -1:]), rtol=1e-6)
+    assert TransformerConfig().router_renormalize is False
+
+
+def test_the_shared_experts_gate_scales_it_and_trains():
+    cfg = TransformerConfig(dtype=jnp.float32, **STACK)
+    lp = share_of(expert_leaves(), 1)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 32))
+    bare = dataclasses.replace(cfg, shared_expert_gate=False)
+    with jax.default_matmul_precision("highest"):
+        gated, said = T._ffn_moe(lp, x, cfg)
+        plain, said_bare = T._ffn_moe({k: v for k, v in lp.items() if k != "shared_scale"}, x, bare)
+        shared = ref._swiglu(x, lp["shared_gate"], lp["shared_in"], lp["shared_out"])
+        np.testing.assert_allclose(plain - gated, (1.0 - ref._shared_gate(lp, x)) * shared, atol=2e-5)
+    assert len(said) == 4 and len(said_bare) == 3 and 0.3 < float(said[3]) < 0.7
+    grads = jax.grad(lambda lp: jnp.sum(T._ffn_moe(lp, x, cfg)[0] ** 2))(lp)
+    assert grads["shared_scale"].shape == (32, 1) and float(jnp.max(jnp.abs(grads["shared_scale"]))) > 0
+
+
+# -- the pattern, what it says and what it refuses ---------------------------------------------
+
+
+def test_layer_kinds_and_pattern_of_the_published_model_and_of_the_cut():
+    import json
+
+    with open(os.path.join(ROOT, "benchmark", "published", "qwen3-next-80b-a3b-instruct.json")) as f:
+        published = json.load(f)["config"]
+    n, every = published["num_hidden_layers"], published["full_attention_interval"]
+    assert (n, every, published["decoder_sparse_step"], published["mlp_only_layers"]) == (48, 4, 1, [])
+    gdn = tuple(i for i in range(1, n + 1) if i % every)
+    whole = TransformerConfig(**dict(STACK, n_layers=n, gdn_layers=gdn))
+    period = (("gdn", "experts"),) * 3 + (("full", "experts"),)
+    assert whole.layer_kinds() == period * 12 and layer_pattern(whole) == (0, period)
+    with open(os.path.join(ROOT, "benchmark", "configs", "qwen3-next-80b-a3b-1g.json")) as f:
+        tc = json.load(f)["program"]["transformer_config"]
+    cut = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
+    assert cut.layer_kinds() == period and layer_pattern(cut) == (0, period)  # one whole period, scanned once
+    assert (cut.linear_n_heads, cut.linear_key_heads, cut.linear_head_dim, cut.head_dim, cut.rotary_dim) == (32, 16, 128, 256, 64)
+    assert cut.rotary_dim == published["partial_rotary_factor"] * published["head_dim"]
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cut))
+    assert set(params["periods"]) == {"gdn.experts", "full.experts"} and params["lead"] == {}
+    assert params["periods"]["gdn.experts"]["w_ba"].shape == (1, 3, 2048, 64) and params["periods"]["full.experts"]["wq"].shape == (1, 1, 2048, 8192)
+    assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params)) == 625_667_136
+
+
+def test_the_events_say_the_pattern_and_the_core_the_softmax_layer_took(monkeypatch):
+    from torchft_tpu import telemetry
+
+    monkeypatch.setattr(T, "_PATHS_SAID", set())
+    cfg, params, tokens, _ = make("stack", seq=32)
+    seen = {kind: len(telemetry.EVENTS.recent(kind)) for kind in ("attention_path", "layer_pattern")}
+    for _ in range(2):
+        jax.jit(lambda p: loss_fn(p, tokens, cfg, None))(params)
+    (path,) = telemetry.EVENTS.recent("attention_path")[seen["attention_path"]:]  # the one softmax layer, once
+    assert (path["n_heads"], path["n_kv_heads"], path["head_dim"], path["rotary_dim"], path["impl"]) == (4, 2, 16, 4, "plain")
+    (pattern,) = telemetry.EVENTS.recent("layer_pattern")[seen["layer_pattern"]:]
+    assert (pattern["lead"], pattern["period"], pattern["repeats"]) == ("-", "gdn.experts,gdn.experts,gdn.experts,full.experts", 1)
+    assert (pattern["experts_held"], pattern["experts"]) == (4, 16)
+
+
+def test_heads_of_256_take_the_kernel_on_a_chip_at_the_cells_length():
+    """The cell's softmax layer (16 x 256 over 2 x 256, 8 192 positions): on a
+    TPU ``auto`` takes the Pallas kernel at 512 x 512 tiles (256 lanes are two
+    whole lane tiles; PERF.md §6, PR 54 holds the readings), grouped heads read
+    in place; on a CPU and inside a manual region the chunked scan keeps it."""
+    import json
+    from unittest import mock
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "qwen3-next-80b-a3b-1g.json")) as f:
+        tc = json.load(f)["program"]["transformer_config"]
+    assert tc["attention_impl"] == "auto" and tc["head_dim"] == 256
+    cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        impl, why, blocks = T._attention_path(cfg, 8192, 2, None, grouped=True)
+        assert (impl, blocks) == ("flash", (512, 512)) and why == "auto on a tpu: the fastest core measured at this (seq, head_dim)"
+        assert T._attention_path(cfg, 8192, 2, None, sp_manual=True, grouped=True)[0] == "chunked"
+    assert T._attention_path(cfg, 8192, 2, None, grouped=True)[::2] == ("chunked", None)
+
+
+@pytest.mark.parametrize("changes, message", [
+    (dict(gdn_layers=(1, 2, 5)), "gdn_layers .* name layers 1..4, each at most once"),
+    (dict(gdn_layers=(1, 2, 3), kda_layers=(3,)), "each at most once"),
+    (dict(linear_n_key_heads=3), "linear_n_key_heads=3 under linear_n_heads=4: the value heads of gdn_layers divide"),
+    (dict(gdn_layers=(), kda_layers=(1, 2, 3)), "linear_n_key_heads=2 .*a kda layer has one count"),
+    (dict(n_shared_experts=0), "shared_expert_gate scales the shared experts' output"),
+    (dict(rotary_dim=4, rope_pairing="interleaved"), "a partial rotation"),
+])
+def test_what_the_configuration_refuses(changes, message):
+    with pytest.raises(ValueError, match=message):
+        TransformerConfig(**dict(STACK, **changes))
+
+
+@pytest.mark.parametrize("size, message", [
+    ("gdn.dense", "sp=2 with a gdn layer: the recurrent state at a sequence shard's start .* is missing"),
+    ("gated.dense", "sp=2 with a window .* or grouped-query heads: ring attention .* missing"),
+])
+def test_a_sharded_sequence_is_refused_with_what_is_missing(size, message):
+    cfg, params, tokens, _ = make(size, seq=32)
+    sp = make_mesh(MeshConfig(sp=2), devices=jax.devices()[:2])
+    with pytest.raises(ValueError, match=message), jax.set_mesh(sp):
+        jax.jit(lambda p, t: loss_fn(p, t, cfg, sp))(params, tokens)
+
+
+def test_a_pattern_of_these_layers_is_refused_under_a_pipeline_and_experts_over_chips():
+    cfg, params, tokens, _ = make("stack", seq=32)
+    with pytest.raises(ValueError, match="pp=2 with a declared layer pattern: .* are missing"):
+        loss_fn(params, tokens, dataclasses.replace(cfg, pp=2))
+    ep = make_mesh(MeshConfig(ep=2), devices=jax.devices()[:2])
+    with pytest.raises(ValueError, match="ep=2"), jax.set_mesh(ep):
+        jax.jit(lambda p, t: loss_fn(p, t, cfg, ep))(params, tokens)

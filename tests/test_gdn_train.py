@@ -1,0 +1,226 @@
+"""The Gated DeltaNet stack through ``TrainStep`` and the Manager, the names its
+ops carry in the lowered program, what the new cell's program runs and says on
+the chip's branch, and all seven older cells' programs held to the parent's (the
+kinds of layer and the reference are ``tests/test_gdn.py``'s): a file of its own,
+so that these compile-heavy tests are handed to a worker of their own in a run
+with several."""
+
+import functools
+import hashlib
+import json
+import os
+import re
+from concurrent.futures import ThreadPoolExecutor
+from datetime import timedelta
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from tests.test_attn_core_remat import kernel_calls
+from tests.test_gdn import ROOT, SIZES, make
+from torchft_tpu.models import transformer as T
+from torchft_tpu.models.transformer import TransformerConfig, init_params, loss_fn
+from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+from torchft_tpu.parallel.train_step import TrainStep
+
+
+CELLS_PROGRAMS = {
+    # (batch, positions), sha256 of the jaxpr of loss_fn's value and gradient at the cell's sizes on the chip's branch,
+    # and the first 16 of the sha256 of its ``_say_once`` lines: what 4817540 — the parent of the PR that brought
+    # ``gdn_layers``, ``norm_zero_centered``, ``attn_output_gate``, ``shared_expert_gate``, the softmax gate's
+    # renormalisation and the layers' aux by NAME — traced, letter for letter, computed there and here by one script
+    "olmo1b-1g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a", "1bcfe2dfb3ff35a0"),
+    "olmo1b-4g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a", "1bcfe2dfb3ff35a0"),
+    "olmoe-1g": ((8, 2048), "65b119828cd26a22a39bc945227fb3cef92f2b8ae09109a8c17c196e5a896d2d", "1bcfe2dfb3ff35a0"),
+    "kimi-linear-1g": ((2, 8192), "2ca37b1ff0102ed43cfd929ffe6dd7b7d2304c9d67b53a83574a19f3d6a6927d", "efeaeed97ccba4c3"),
+    "laguna-xs2-1g": ((2, 8192), "bd50d408b7ed0e9d2876d862737ce952d20700046ef4b41ccb917b5f1854eeb0", "2e3b7f09d4732e39"),
+    "joyai-flash-1g": ((2, 8192), "959938bef56e10a002f8ad665bf8fdd14794506432d5a5dfb20c9a6a6ca95bd7", "a6aa64069f77d113"),
+    "lfm2-8b-a1b-1g": ((2, 8192), "48a3dee15b7c14d6a20b8d9381be5920893125a39c0a7c00c8932e3248c011b8", "647d94df3e9b6744"),
+}
+NEW_CELL = "qwen3-next-80b-a3b-1g"
+
+
+@functools.lru_cache(maxsize=None)
+def cells_program(name, shape):
+    """(jaxpr of ``loss_fn``'s value and gradient, its ``_say_once`` lines) of a
+    benchmark configuration at a cell's size, on the chip's branch."""
+    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
+        tc = json.load(f)["program"]["transformer_config"]
+    cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
+    said = []
+    say = lambda kind, key, **fields: said.append(kind + " " + " ".join(f"{k}={v}" for k, v in fields.items()))
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), mock.patch.object(T, "_say_once", say):
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda p, t: loss_fn(p, t, cfg)))(params, jax.ShapeDtypeStruct(shape, jnp.int32))
+    return jaxpr, said
+
+
+@pytest.mark.parametrize("name", list(CELLS_PROGRAMS))
+def test_the_seven_older_cells_programs_and_what_they_say_are_the_parents(name):
+    shape, program, lines = CELLS_PROGRAMS[name]
+    jaxpr, said = cells_program(name, shape)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest() == program
+    assert hashlib.sha256("\n".join(said).encode()).hexdigest()[:16] == lines
+
+
+def test_the_new_cells_softmax_layer_runs_the_kernel_once_and_says_its_heads():
+    """`qwen3-next-80b-a3b-1g.fused-s8192` on the chip's branch: the one softmax
+    layer is the Pallas kernel at 256 lanes, 16 query heads over 2 — one
+    ``flash_fwd`` and one ``flash_bwd`` in the whole step, its output and row
+    statistics kept under ``remat`` — and the Gated DeltaNet layers call no
+    kernel at all: the delta rule with one decay a head is ``jax.numpy``
+    (``ops/kda.gdn_chunked``). The one ``attention_path`` line says which core,
+    tile and heads; the ``layer_pattern`` line the period scanned once."""
+    from torchft_tpu.ops.pallas.flash_attention import CORE_LSE, CORE_OUT
+
+    jaxpr, said = cells_program(NEW_CELL, (2, 8192))
+    calls = kernel_calls(jaxpr.jaxpr)
+    assert (calls["flash_fwd"], calls["flash_bwd"], calls[CORE_OUT], calls[CORE_LSE]) == (1, 1, 1, 1)
+    assert not [name for name in calls if "kda" in str(name)]
+    (line,) = [text for text in said if text.startswith("attention_path ")]
+    assert line.startswith("attention_path impl=flash block_q=512 block_k=512 batch=2 seq=8192 head_dim=256 value_dim=256 ")
+    assert "reason=auto on a tpu: the fastest core measured at this (seq, head_dim)" in line
+    assert line.endswith("n_heads=16 n_kv_heads=2 window=0 rotary_dim=64")
+    (pattern,) = [text for text in said if text.startswith("layer_pattern ")]
+    assert "lead=- period=gdn.experts,gdn.experts,gdn.experts,full.experts repeats=1 experts_held=32 experts=512 batch=2 seq=8192" in pattern
+
+
+# -- the names in the lowered program ----------------------------------------------------------
+
+
+def test_the_new_parts_ops_carry_their_names_under_attn_and_moe():
+    """The ``op_name`` of the compiled program's ops, what a device trace
+    carries: a Gated DeltaNet mixer's projections under ``attn/gdn``, its
+    convolution, its decay and gates and its chunked rule under ``conv``,
+    ``gates`` and ``gdn_core`` inside — forward and backward —, the softmax
+    layer under ``attn/global`` with ``global_core`` and the gate on its output
+    ``out_gate`` inside; and in the lowered text the shared expert's gate
+    inside ``moe/shared``."""
+    cfg, params, tokens, _ = make("stack", seq=32, remat=True)
+    lowered = jax.jit(jax.grad(lambda p: loss_fn(p, tokens, cfg))).lower(params)
+    names = set(re.findall(r'op_name="([^"]+)"', lowered.compile().as_text()))
+    under = lambda pattern: [n for n in names if re.search(pattern, n)]
+    block = r"attn/gdn/while/body/closed_call/"  # the mixer's scan over blocks of positions, each under its checkpoint
+    assert under(r"jvp\(\)/.*" + block + "dot_general") and under(r"transpose\(jvp\(\)\)/.*" + block + ".*dot_general")
+    assert under(block + "conv/") and under(block + "gates/dot_general") and under(block + "gates/exp")
+    assert under(block + r"gdn_core/.*dot_general") and under(r"transpose\(jvp\(\)\)/.*attn/gdn/.*gdn_core/")
+    assert not under(r"gdn_core/.*(logistic|softplus|log1p)")  # the decay and the gates stay outside the core
+    assert under(r"attn/.*global/global_core/") and under(r"attn/global/out_gate/exp") and under(r"transpose\(jvp\(\)\)/.*global/out_gate/")
+    assert not under(r"gdn/.*global") and not under(r"global/.*gdn")
+    located = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+    assert "moe/shared/logistic" in located and [n for n in located if re.search(r"moe/.*shared/dot_general", n)]
+    assert [n for n in located if re.search(r"moe/.*router/", n)]
+
+
+# -- TrainStep and the Manager ----------------------------------------------------------------------
+
+
+STATS = {"tokens_per_expert", "balance_loss", "rows_held", "shared_gate_mean", "gdn_decay_min", "gdn_beta_mean"}
+
+
+def test_the_fused_step_is_the_split_pair_on_this_tree():
+    cfg, _, tokens, _ = make("stack", seq=32)
+    mesh = make_mesh(MeshConfig(), devices=jax.devices()[:1])
+    ts = TrainStep(cfg, optax.adamw(1e-2), mesh)
+    params = ts.init_params(jax.random.PRNGKey(0))
+    opt = ts.init_opt(params)
+    batch = ts.shard_batch(tokens)
+    loss, grads = ts.grads(params, batch)
+    split_stats = dict(ts.last_stats)
+    # `apply` donates its state: the fused step below takes copies of it
+    kept = jax.tree_util.tree_map(jnp.copy, (params, opt))
+    split = ts.apply(params, opt, grads)
+    fused_loss, *fused = ts.step(*kept, batch)
+    assert float(loss) == float(fused_loss)
+    assert set(ts.last_stats) == set(split_stats) == STATS
+    assert ts.last_stats["tokens_per_expert"].shape == (4, 16) and ts.last_stats["shared_gate_mean"].shape == (4,)
+    assert ts.last_stats["gdn_decay_min"].shape == ts.last_stats["gdn_beta_mean"].shape == (3,)  # the three gdn layers
+    assert float(jnp.max(ts.last_stats["gdn_decay_min"])) < 0.0
+    for a, b in zip(jax.tree_util.tree_leaves(split), jax.tree_util.tree_leaves(tuple(fused))):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+    start = init_params(jax.random.PRNGKey(0), cfg)["periods"]
+    for kind, leaf in (("gdn.experts", "a_log"), ("gdn.experts", "w_ba"), ("full.experts", "q_norm"), ("full.experts", "shared_scale")):
+        assert float(jnp.max(jnp.abs(fused[0]["periods"][kind][leaf] - start[kind][leaf]))) > 0, leaf  # they train
+
+
+COUNTERS = []
+
+
+def gdn_train_loop(rank, store_addr, runner, total_steps=3):
+    from torchft_tpu.collectives import CollectivesTcp
+    from torchft_tpu.manager import Manager
+    from torchft_tpu.parallel.ft import FTTrainer
+
+    cfg = TransformerConfig(dtype=jnp.float32, remat=False, **SIZES["stack"])
+    mesh = make_mesh(MeshConfig(), devices=jax.devices()[runner.replica_id : runner.replica_id + 1])
+    ts = TrainStep(cfg, optax.sgd(0.05), mesh)
+    manager = Manager(
+        collectives=CollectivesTcp(timeout=timedelta(seconds=10)),
+        load_state_dict=None, state_dict=None, min_replica_size=2, replica_id=str(runner.replica_id),
+        store_addr=store_addr, rank=rank, world_size=runner.world_size,
+        lighthouse_addr=runner.lighthouse_address, timeout=timedelta(seconds=10),
+    )
+    try:
+        trainer = FTTrainer(manager, ts)
+        trainer.init(jax.random.PRNGKey(0))
+        data = np.random.default_rng(3000 + runner.replica_id * 13)
+        while manager.current_step() < total_steps:
+            tokens = jnp.asarray(data.integers(0, cfg.vocab_size, (2, 32)), jnp.int32)
+            trainer.step(tokens)
+            runner.failure_injector.check(rank, manager.current_step())
+        return {"params": jax.tree_util.tree_map(np.asarray, trainer.params), "step": manager.current_step()}
+    finally:
+        manager.shutdown(wait=False)
+
+
+def test_two_groups_exchange_the_gdn_layers_heal_them_and_say_their_counters(monkeypatch):
+    """Two replica groups average the tree with the Gated DeltaNet and gated
+    softmax leaves over the Manager (``FTTrainer.step``) for three steps; one
+    is killed after its second and the trainer started in its place heals the
+    whole tree from the survivor: equal parameters, bit for bit. Each step's
+    ``loss_sync`` carries ``tft.gdn.counters`` and ``tft.moe.counters``."""
+    from tests.test_integration import FailureInjector, Runner
+    from torchft_tpu.coordination import LighthouseServer
+    from torchft_tpu.parallel import ft
+
+    annotate = ft.tracing.annotate
+    monkeypatch.setattr(
+        ft.tracing, "annotate",
+        lambda name, **stats: (COUNTERS.append((name, stats)) if name.endswith(".counters") else None) or annotate(name, **stats),
+    )
+    del COUNTERS[:]
+    lighthouse = LighthouseServer(bind="[::]:0", min_replicas=2)
+    injectors = [FailureInjector(), FailureInjector().fail_at(0, 2)]
+    try:
+        with ThreadPoolExecutor(max_workers=2) as ex:
+            futs = [
+                ex.submit(Runner(
+                    replica_id=i, lighthouse_address=lighthouse.address(), failure_injector=inj,
+                    train_loop=gdn_train_loop,
+                ).run_replica)
+                for i, inj in enumerate(injectors)
+            ]
+            results = [f.result(timeout=240) for f in futs]
+    finally:
+        lighthouse.shutdown()
+    assert injectors[1].count == 1  # the kill happened, and a third trainer took the group's place
+    a, b = results[0][0], results[1][0]
+    assert a["step"] == b["step"] == 3
+    la, ta = jax.tree_util.tree_flatten(a["params"])
+    lb, tb = jax.tree_util.tree_flatten(b["params"])
+    assert ta == tb and {"w_ba", "w_z", "a_log", "dt_bias", "shared_scale"} <= set(a["params"]["periods"]["gdn.experts"])
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(x, y)
+    gdn = [stats for name, stats in COUNTERS if name == "gdn.counters"]
+    moe = [stats for name, stats in COUNTERS if name == "moe.counters"]
+    assert len(gdn) >= 6 and len(moe) == len(gdn)  # three steps of two groups, and the healed one's
+    for stats in gdn:
+        assert set(stats) == {"step", "decay_min", "beta_mean", "shared_gate_mean"}
+        assert stats["decay_min"] < 0.0 and 0.3 < stats["beta_mean"] < 0.7 and 0.3 < stats["shared_gate_mean"] < 0.7
+    for stats in moe:  # 2 x 32 tokens x 4 chosen x 4 layers routed; a quarter of the experts held
+        assert stats["rows_routed"] == 2 * 32 * 4 * 4 and 0 < stats["rows_held"] < stats["rows_routed"]

@@ -17,13 +17,15 @@ per layer) with ``jax.checkpoint`` rematerialization — compile time and
 HBM both scale O(1) in depth.
 
 A model whose layers are not all of one kind DECLARES its pattern
-(``TransformerConfig.kda_layers`` / ``mla_layers`` / ``conv_layers`` /
-``n_dense_layers``): per layer a sequence mixer — ``full`` softmax attention
-with RoPE, ``window``
+(``TransformerConfig.kda_layers`` / ``gdn_layers`` / ``mla_layers`` /
+``conv_layers`` / ``n_dense_layers``): per layer a sequence mixer — ``full``
+softmax attention with RoPE (its output gated lane by lane under
+``attn_output_gate``), ``window``
 (the same over a band of ``window`` keys, ``window_layers``; each of the two
 kinds with its own query heads over ``n_kv_heads`` grouped key/value heads
 and its own rotation), ``kda`` (gated delta-rule linear attention,
-``ops/kda.py``) or ``mla`` (latent attention: without positions, or with
+``ops/kda.py``), ``gdn`` (the same rule with one decay a head and key heads
+shared by value heads) or ``mla`` (latent attention: without positions, or with
 the query's and the shared key's last lanes rotated under ``mla_rope_theta``;
 the query one projection or low-rank under ``q_lora_rank``) or ``conv`` (a
 gated causal short convolution: no query, key, score or state) — and a
@@ -59,7 +61,7 @@ from torchft_tpu.ops.attention import (
     ring_attention,
     ring_attention_local,
 )
-from torchft_tpu.ops.kda import kda_chunked, short_conv
+from torchft_tpu.ops.kda import gdn_chunked, kda_chunked, short_conv
 from torchft_tpu.ops.layers import (
     moe_dispatch,
     moe_dropless,
@@ -91,8 +93,8 @@ class TransformerConfig:
     head_dim: int = 64
     d_ff: int = 1408
     n_experts: int = 0  # 0 => dense FFN
-    # experts per token; the softmax gate applies their weights as they are,
-    # the sigmoid gate renormalises them over the chosen under ``router_renormalize``
+    # experts per token; either gate renormalises their weights over the chosen
+    # under ``router_renormalize`` and else applies them as they are
     top_k: int = 2
     capacity_factor: float = 1.25  # ep > 1 only: the dropless path has none
     # weight of the load-balancing term E·Σ f_e·P_e (mean over layers) in
@@ -105,6 +107,14 @@ class TransformerConfig:
     # after the head split and before RoPE, under ONE head_dim-wide weight that
     # the query heads share and one that the key heads share (LFM2's attention)
     qk_norm_per_head: bool = False
+    # the norms ahead of a layer's two parts, the final one, the q/k norms (and a
+    # multi-token-prediction module's) scale by ``1 + w`` with ``w`` zero at init
+    # (``ops/layers.rms_norm``); a linear mixer's and a latent's own norms stay plain
+    norm_zero_centered: bool = False
+    # ``full`` and ``window`` layers: the query projection is twice as wide, a head's
+    # lanes ``[q | gate]``, and the core's output is scaled by ``sigmoid(gate)``
+    # lane by lane ahead of the output projection
+    attn_output_gate: bool = False
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16  # compute dtype (MXU-native)
@@ -139,6 +149,11 @@ class TransformerConfig:
     # a gated short convolution: [B, C, X] = h·conv_in, y = C ⊙ conv(B ⊙ X) over
     # ``conv_kernel`` causal depthwise taps, y·conv_out; d_model wide, no positions
     conv_layers: Tuple[int, ...] = ()
+    # Gated DeltaNet (``ops/kda.gdn_chunked``): kda's rule and convolution with ONE
+    # log-decay a head a position from ``[b | a] = h·w_ba`` (beta = sigmoid(b), g =
+    # -exp(a_log)·softplus(a + dt_bias)), the output normed head by head and scaled
+    # by SiLU of a projection as wide as the values; no low-rank gates
+    gdn_layers: Tuple[int, ...] = ()
     # with experts: this many leading layers keep a dense SwiGLU of d_ff
     n_dense_layers: int = 0
     moe_d_ff: int = 0  # one expert's width; 0 => d_ff (a model of expert layers only)
@@ -148,7 +163,10 @@ class TransformerConfig:
     n_experts_held: int = 0
     expert_share_index: int = 0
     n_shared_experts: int = 0  # dense SwiGLUs of moe_d_ff beside the routed experts
-    # "softmax": p = softmax(h·Wr), the k largest, applied as they are.
+    # the shared experts' output times ``sigmoid(h·shared_scale)``, one number a token
+    shared_expert_gate: bool = False
+    # "softmax": p = softmax(h·Wr), the k largest, applied as they are
+    # (renormalised over the chosen if ``router_renormalize``).
     # "sigmoid": s = sigmoid(h·Wr), the k largest of s + a selection-only
     # bias, weights s (renormalised over the chosen if ``router_renormalize``)
     # times ``routed_scaling_factor``
@@ -175,6 +193,9 @@ class TransformerConfig:
     # are linear_head_dim wide inside
     linear_head_dim: int = 0
     linear_n_heads: int = 0
+    # -- gdn: linear_n_heads VALUE heads over this many key heads (value head j
+    # reads query/key head j // (heads / key heads)); 0 => as many
+    linear_n_key_heads: int = 0
     conv_kernel: int = 4
     # -- grouped-query heads: ``full`` and ``window`` layers project keys and
     # values to this many heads, and query head a reads key/value head
@@ -210,13 +231,19 @@ class TransformerConfig:
     mtp_loss_weight: float = 0.1
 
     def __post_init__(self) -> None:
-        for name in ("kda_layers", "mla_layers", "conv_layers", "window_layers", "n_heads_per_layer"):  # a JSON file gives lists
+        for name in ("kda_layers", "gdn_layers", "mla_layers", "conv_layers", "window_layers", "n_heads_per_layer"):  # a JSON file gives lists
             object.__setattr__(self, name, tuple(int(i) for i in getattr(self, name)))
-        named = self.kda_layers + self.mla_layers + self.conv_layers + self.window_layers
+        named = self.kda_layers + self.gdn_layers + self.mla_layers + self.conv_layers + self.window_layers
         if len(set(named)) != len(named) or any(not 1 <= i <= self.n_layers for i in named):
             raise ValueError(
-                f"kda_layers {self.kda_layers}, mla_layers {self.mla_layers}, conv_layers {self.conv_layers} and "
+                f"kda_layers {self.kda_layers}, gdn_layers {self.gdn_layers}, mla_layers {self.mla_layers}, "
+                f"conv_layers {self.conv_layers} and "
                 f"window_layers {self.window_layers} name layers 1..{self.n_layers}, each at most once"
+            )
+        if self.linear_n_key_heads and (not self.gdn_layers or self.linear_n_heads % self.linear_n_key_heads):
+            raise ValueError(
+                f"linear_n_key_heads={self.linear_n_key_heads} under linear_n_heads={self.linear_n_heads}: the value "
+                "heads of gdn_layers divide over the key heads in whole groups (a kda layer has one count)"
             )
         if bool(self.window_layers) != bool(self.window):
             raise ValueError(f"window={self.window} and window_layers={self.window_layers}: a band has both")
@@ -249,6 +276,8 @@ class TransformerConfig:
             )
         if (self.n_dense_layers or self.n_shared_experts or self.n_experts_held) and not self.n_experts:
             raise ValueError("n_dense_layers, n_shared_experts and n_experts_held describe a model with experts")
+        if self.shared_expert_gate and not self.n_shared_experts:
+            raise ValueError("shared_expert_gate scales the shared experts' output: it comes with n_shared_experts")
         if (self.q_lora_rank or self.mla_rope_theta) and not self.mla_layers:
             raise ValueError("q_lora_rank and mla_rope_theta describe a model with mla_layers")
         if self.mla_rope_theta and self.qk_rope_head_dim % 2:
@@ -280,6 +309,10 @@ class TransformerConfig:
         return self.n_heads
 
     @property
+    def linear_key_heads(self) -> int:
+        return self.linear_n_key_heads or self.linear_n_heads
+
+    @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
@@ -292,7 +325,8 @@ class TransformerConfig:
         kinds = []
         for i in range(1, self.n_layers + 1):
             mixer = (
-                "kda" if i in self.kda_layers else "mla" if i in self.mla_layers
+                "kda" if i in self.kda_layers else "gdn" if i in self.gdn_layers
+                else "mla" if i in self.mla_layers
                 else "conv" if i in self.conv_layers
                 else "window" if i in self.window_layers else "full"
             )
@@ -389,20 +423,23 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
     def ones(*shape):
         return jnp.ones(lead + shape, jnp.float32)
 
-    layers: Dict[str, Any] = {"ln1": ones(d), "ln2": ones(d)}
+    def unit(*shape):  # a norm's weight that scales by one at init
+        return _unit_weight(cfg, lead + shape)
+
+    layers: Dict[str, Any] = {"ln1": unit(d), "ln2": unit(d)}
     if mixer in ("full", "window"):
         qkv = cfg.mixer_heads(mixer) * cfg.head_dim
         kv = cfg.kv_heads * cfg.head_dim
         layers.update(
-            wq=dense(keys[0], d, qkv, fan_in=d),
+            wq=dense(keys[0], d, 2 * qkv if cfg.attn_output_gate else qkv, fan_in=d),  # gated: a head's [q | gate]
             wk=dense(keys[1], d, kv, fan_in=d),
             wv=dense(keys[2], d, kv, fan_in=d),
             wo=dense(keys[3], qkv, d, fan_in=qkv),
         )
         if cfg.qk_norm_per_head:
-            layers.update(q_norm=ones(cfg.head_dim), k_norm=ones(cfg.head_dim))
+            layers.update(q_norm=unit(cfg.head_dim), k_norm=unit(cfg.head_dim))
         elif cfg.qk_norm:
-            layers.update(q_norm=ones(qkv), k_norm=ones(kv))
+            layers.update(q_norm=unit(qkv), k_norm=unit(kv))
     elif mixer == "kda":
         hd, taps = cfg.linear_head_dim, cfg.conv_kernel
         ch = cfg.linear_n_heads * hd
@@ -428,6 +465,24 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
             dt_bias=_inv_softplus(jnp.exp(jax.random.uniform(
                 next(more), lead + (ch,), jnp.float32, np.log(1e-3), np.log(1e-1)
             ))),
+            o_norm=ones(hd),
+        )
+    elif mixer == "gdn":
+        hd, taps, heads = cfg.linear_head_dim, cfg.conv_kernel, cfg.linear_n_heads
+        kch, vch = cfg.linear_key_heads * hd, heads * hd
+        layers.update(
+            wq=dense(keys[0], d, kch, fan_in=d),
+            wk=dense(keys[1], d, kch, fan_in=d),
+            wv=dense(keys[2], d, vch, fan_in=d),
+            wo=dense(keys[3], vch, d, fan_in=vch),
+            conv_q=dense(next(more), taps, kch, fan_in=taps),
+            conv_k=dense(next(more), taps, kch, fan_in=taps),
+            conv_v=dense(next(more), taps, vch, fan_in=taps),
+            w_z=dense(next(more), d, vch, fan_in=d),
+            w_ba=dense(next(more), d, 2 * heads, fan_in=d),  # b | a: the write strength's and the decay's inputs
+            # the published class's: exp(a_log) uniform in (0, 16], dt_bias 1 — a head forgets up to ~20 nats a position
+            a_log=jnp.log(jax.random.uniform(next(more), lead + (heads,), jnp.float32, 1e-6, 16.0)),
+            dt_bias=ones(heads),
             o_norm=ones(hd),
         )
     elif mixer == "mla":
@@ -473,6 +528,8 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
                 shared_in=dense(next(more), d, fs, fan_in=d),
                 shared_out=dense(next(more), fs, d, fan_in=fs),
             )
+            if cfg.shared_expert_gate:
+                layers.update(shared_scale=dense(next(more), d, 1, fan_in=d))
     else:
         f = cfg.d_ff
         layers.update(
@@ -485,6 +542,16 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
 
 def _inv_softplus(y: jnp.ndarray) -> jnp.ndarray:
     return y + jnp.log(-jnp.expm1(-y))
+
+
+def _unit_weight(cfg: TransformerConfig, shape: Tuple[int, ...]) -> jnp.ndarray:
+    """What a norm that :func:`_norm` applies starts from: ones, or zeros where it scales by ``1 + w``."""
+    return (jnp.zeros if cfg.norm_zero_centered else jnp.ones)(shape, jnp.float32)
+
+
+def _norm(cfg: TransformerConfig, x: jnp.ndarray, weight: jnp.ndarray) -> jnp.ndarray:
+    """RMSNorm of a layer's input, of the stack's output, of q and k: ``norm_eps`` and ``norm_zero_centered``."""
+    return rms_norm(x, weight, cfg.norm_eps, cfg.norm_zero_centered)
 
 
 def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
@@ -506,17 +573,17 @@ def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
 
     params: Dict[str, Any] = {
         "embed": dense(keys[8], cfg.vocab_size, d, fan_in=1.0),
-        "final_norm": jnp.ones((d,), jnp.float32),
+        "final_norm": _unit_weight(cfg, (d,)),
         "out": dense(keys[9], d, cfg.vocab_size, fan_in=d),
     }
     if cfg.n_mtp_modules:
         key = jax.random.fold_in(rng, 201)
         params["mtp"] = {
-            "enorm": jnp.ones((d,), jnp.float32),
-            "hnorm": jnp.ones((d,), jnp.float32),
+            "enorm": _unit_weight(cfg, (d,)),
+            "hnorm": _unit_weight(cfg, (d,)),
             "eh_proj": dense(key, 2 * d, d, fan_in=2 * d),
             "layer": _init_layers(jax.random.fold_in(key, 1), cfg, cfg.layer_kinds()[-1], ()),
-            "final_norm": jnp.ones((d,), jnp.float32),
+            "final_norm": _unit_weight(cfg, (d,)),
         }
     if _of_one_kind(cfg):
         lead = (max(cfg.pp, 1), cfg.layers_per_stage)
@@ -562,6 +629,13 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
             w_ga=spec("fsdp", None), w_gb=spec(None, "tp"),
             w_beta=spec("fsdp", "tp"), a_log=spec("tp"), dt_bias=spec("tp"), o_norm=spec(None),
         )
+    elif mixer == "gdn":
+        # value and key heads over tp, the pair of narrow projections whole
+        layers.update(
+            wq=row, wk=row, wv=row, wo=col, w_z=row,
+            conv_q=spec(None, "tp"), conv_k=spec(None, "tp"), conv_v=spec(None, "tp"),
+            w_ba=spec("fsdp", None), a_log=spec("tp"), dt_bias=spec("tp"), o_norm=spec(None),
+        )
     elif mixer == "conv":
         layers.update(conv_in=row, conv_w=spec(None, "tp"), conv_out=col)  # channels over tp
     else:
@@ -582,6 +656,8 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
             layers.update(router_bias=spec(None))
         if cfg.n_shared_experts:
             layers.update(shared_gate=row, shared_in=row, shared_out=col)
+            if cfg.shared_expert_gate:
+                layers.update(shared_scale=spec("fsdp", None))
     else:
         layers.update(w_gate=row, w_in=row, w_out=col)
     return layers
@@ -644,6 +720,8 @@ def _route(lp: Dict[str, Any], tokens: jnp.ndarray, cfg: TransformerConfig):
     if cfg.router_gate == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
         top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)
+        if cfg.router_renormalize:
+            top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + cfg.router_norm_eps)
         return top_w, top_idx, probs
     scores = jax.nn.sigmoid(logits)
     # the bias moves which experts are chosen and not what they weigh
@@ -669,8 +747,10 @@ def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig):
     experts a token, every chosen expert counted whatever its load; under a
     share (``n_experts_held``) the experts held here compute their part and
     what the absent ones would add is left out; a shared expert is a dense
-    SwiGLU beside them. Returns (y, (balance term ``E·Σ_e f_e·P_e`` over the
-    call's tokens, tokens per expert [E]) and, under a share, the rows held)."""
+    SwiGLU beside them, scaled a token by ``sigmoid(h·shared_scale)`` under
+    ``shared_expert_gate``. Returns (y, (balance term ``E·Σ_e f_e·P_e`` over the
+    call's tokens, tokens per expert [E]), then under a share the rows held, then
+    under ``shared_expert_gate`` that gate's mean: :func:`_moe_said` names them)."""
     b, s, d = x.shape
     tokens = x.reshape(b * s, d)
     with jax.named_scope("router"):
@@ -697,8 +777,19 @@ def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig):
         balance = cfg.n_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
     if cfg.n_shared_experts:
         with jax.named_scope("shared"):
-            y = y + swiglu(tokens, lp["shared_gate"], lp["shared_in"], lp["shared_out"])
+            shared = swiglu(tokens, lp["shared_gate"], lp["shared_in"], lp["shared_out"])
+            if cfg.shared_expert_gate:
+                gate = jax.nn.sigmoid(jnp.dot(tokens, lp["shared_scale"], preferred_element_type=jnp.float32))
+                shared = shared * gate.astype(x.dtype)
+                more += (jnp.mean(gate),)
+            y = y + shared
     return y.reshape(b, s, d), (balance, counts) + more
+
+
+def _moe_said(cfg: TransformerConfig, aux) -> Dict[str, jnp.ndarray]:
+    """What :func:`_ffn_moe` says beside its output, by name."""
+    names = ("balance", "counts") + ("held",) * bool(cfg.n_experts_held) + ("shared_gate",) * cfg.shared_expert_gate
+    return dict(zip(names, aux))
 
 
 def _ffn_moe_ep(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
@@ -808,7 +899,15 @@ def _flash_blocks(seq_len: int, head_dim: int) -> Optional[Tuple[int, int]]:
     compiled for a described v5e) and ``chip_smoke.py``'s steady phase no
     longer loaded on the chip: 64 lanes engage from s2048. Other widths short
     of a lane tile, and anything below s1024, were not measured: chunked /
-    plain keep them."""
+    plain keep them.
+
+    At head_dim 256 (my chip run, PR 54; ``qwen3-next-80b-a3b-1g``'s one softmax
+    layer, b2 x s8192 x 16 heads over 2): 512 x 512 with 8 192 keys of 256
+    lanes resident compiles, loads and reads 8.19 ms forward + 16.04 backward a
+    step, 65.4 % of the core's roofline — the highest share of any core here
+    (two lane tiles a head fill the MXU's contraction twice over). No other
+    tile and not the scan were measured at this width: the rule for whole lane
+    tiles stands."""
     if head_dim % 128 == 0:
         least = 1024
     elif head_dim == 64:
@@ -1087,7 +1186,9 @@ def _rotation(cfg: TransformerConfig, mixer: str) -> Dict[str, Any]:
 def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
     """Softmax attention with positions: ``full`` (global) or ``window``
     (banded), each kind with its own query heads over the model's key/value
-    heads and its own rotation."""
+    heads and its own rotation; under ``attn_output_gate`` the query
+    projection carries a gate a lane, ``[q | gate]`` head by head, and the
+    core's output goes through ``sigmoid(gate)`` (scope ``out_gate``)."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
     b, s, _ = h.shape  # s is the sp-local block inside a manual region
     if sp_manual and sp_size > 1:
@@ -1099,14 +1200,19 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
     name = ("window" if mixer == "window" else "global") if _declares_kinds(cfg) else None
     with jax.named_scope(name) if name else contextlib.nullcontext():
         q, k = h @ lp["wq"], h @ lp["wk"]
+        if cfg.attn_output_gate:
+            q, gate = (
+                x.reshape(b, s, heads * cfg.head_dim)
+                for x in jnp.split(q.reshape(b, s, heads, 2 * cfg.head_dim), 2, axis=-1)
+            )
         if cfg.qk_norm and not cfg.qk_norm_per_head:
-            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+            q = _norm(cfg, q, lp["q_norm"])
+            k = _norm(cfg, k, lp["k_norm"])
         q = q.reshape(b, s, heads, cfg.head_dim)
         k = k.reshape(b, s, kv_heads, cfg.head_dim)
         if cfg.qk_norm_per_head:  # each head's lanes by themselves, the heads under one weight
-            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+            q = _norm(cfg, q, lp["q_norm"])
+            k = _norm(cfg, k, lp["k_norm"])
         v = (h @ lp["wv"]).reshape(b, s, kv_heads, cfg.head_dim)
         rotation = _rotation(cfg, mixer)
         q = rotary_embed(q, positions, **rotation)
@@ -1116,8 +1222,11 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
             rotated = 2 * len(rotation["inv_freq"]) if "inv_freq" in rotation else cfg.head_dim
             window = cfg.window if mixer == "window" else 0
             said = dict(scope=name + "_core", window=window, kind=(heads, kv_heads, window, rotated))
-        att = _causal_core(cfg, mesh, sp_manual, q, k, v, **said)
-        return att.reshape(b, s, heads * cfg.head_dim) @ lp["wo"]
+        att = _causal_core(cfg, mesh, sp_manual, q, k, v, **said).reshape(b, s, heads * cfg.head_dim)
+        if cfg.attn_output_gate:
+            with jax.named_scope("out_gate"):
+                att = att * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(att.dtype)
+        return att @ lp["wo"]
 
 
 def _mix_mla(cfg, mesh, sp_manual, lp, h):
@@ -1168,6 +1277,12 @@ def _mix_mla(cfg, mesh, sp_manual, lp, h):
         return att.reshape(b, s, heads * dv) @ lp["wo"]
 
 
+def _unit_l2(x: jnp.ndarray) -> jnp.ndarray:
+    """x / sqrt(Σx² + 1e-6) over the last axis, in float32: a linear mixer's q and k, head by head."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + 1e-6)
+
+
 # Positions a KDA mixer takes at a time. Everything in it but the recurrent
 # state and the convolution's K-1 taps of history is local to a position, so
 # the mixer runs as a ``lax.scan`` over blocks of the sequence that carries
@@ -1190,10 +1305,6 @@ def _mix_kda(cfg, lp, h):
     f32 = jnp.float32
     blk = _KDA_BLOCK if s % _KDA_BLOCK == 0 else s
 
-    def unit(x):  # L2 over a head, in float32
-        xf = x.astype(f32)
-        return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + 1e-6)
-
     def block(carry, hb):
         state, before = carry  # [B, H, D, D] float32; [B, K-1, 3·ch]: q | k | v ahead of the convolution
         qkv = jnp.concatenate([hb @ lp["wq"], hb @ lp["wk"], hb @ lp["wv"]], axis=-1)
@@ -1210,8 +1321,8 @@ def _mix_kda(cfg, lp, h):
             out_gate = jax.nn.sigmoid(
                 jnp.dot(hb @ lp["w_ga"], lp["w_gb"], preferred_element_type=f32)
             ).astype(hb.dtype).reshape(b, blk, heads, hd)
-        q = (unit(q) * hd**-0.5).astype(v.dtype)
-        k = unit(k).astype(v.dtype)
+        q = (_unit_l2(q) * hd**-0.5).astype(v.dtype)
+        k = _unit_l2(k).astype(v.dtype)
         with jax.named_scope("kda_core"):
             o, state = kda_chunked(q, k, v, g, beta, initial_state=state)
         o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * out_gate
@@ -1222,6 +1333,53 @@ def _mix_kda(cfg, lp, h):
         blocks = jnp.moveaxis(h.reshape(b, s // blk, blk, d), 1, 0)
         _, out = jax.lax.scan(jax.checkpoint(block), start, blocks)
         return jnp.moveaxis(out, 0, 1).reshape(b, s, d)
+
+
+def _mix_gdn(cfg, lp, h):
+    """Gated DeltaNet: q, k, v through a causal short convolution and SiLU, q
+    and k L2-normalised per head, ONE log-decay and one write strength a value
+    head a position from ``[b | a] = h·w_ba``, ``linear_n_heads`` value heads
+    over ``linear_n_key_heads`` key heads (``ops/kda.gdn_chunked``), the output
+    normalised per head under a plain weight and scaled by ``SiLU(h·w_z)``. No
+    positions. Blocks of the sequence under a ``lax.scan`` that carries the
+    state and the convolution's taps, as :func:`_mix_kda`. Returns (y, by name:
+    ``decay_min`` the least log-decay of a position, ``beta_mean`` the mean
+    write strength, over the call)."""
+    b, s, d = h.shape
+    heads, key_heads, hd, taps = cfg.linear_n_heads, cfg.linear_key_heads, cfg.linear_head_dim, cfg.conv_kernel
+    kch, vch = key_heads * hd, heads * hd
+    f32 = jnp.float32
+    blk = _KDA_BLOCK if s % _KDA_BLOCK == 0 else s
+
+    def block(carry, hb):
+        state, before = carry  # [B, H, D, D] float32; [B, K-1, 2·kch + vch]: q | k | v ahead of the convolution
+        qkv = jnp.concatenate([hb @ lp["wq"], hb @ lp["wk"], hb @ lp["wv"]], axis=-1)
+        with jax.named_scope("conv"):
+            filters = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)
+            mixed = jax.nn.silu(short_conv(qkv, filters, before))
+            q = mixed[..., :kch].reshape(b, blk, key_heads, hd)
+            k = mixed[..., kch : 2 * kch].reshape(b, blk, key_heads, hd)
+            v = mixed[..., 2 * kch :].reshape(b, blk, heads, hd)
+        with jax.named_scope("gates"):
+            ba = jnp.dot(hb, lp["w_ba"], preferred_element_type=f32)
+            beta = jax.nn.sigmoid(ba[..., :heads])
+            g = -jnp.exp(lp["a_log"].astype(f32)) * jax.nn.softplus(ba[..., heads:] + lp["dt_bias"].astype(f32))
+            out_gate = jax.nn.silu(
+                jnp.dot(hb, lp["w_z"], preferred_element_type=f32)
+            ).astype(hb.dtype).reshape(b, blk, heads, hd)
+        q = (_unit_l2(q) * hd**-0.5).astype(v.dtype)
+        k = _unit_l2(k).astype(v.dtype)
+        with jax.named_scope("gdn_core"):
+            o, state = gdn_chunked(q, k, v, g, beta, initial_state=state)
+        o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * out_gate
+        return (state, qkv[:, blk - (taps - 1) :]), (o.reshape(b, blk, vch) @ lp["wo"], jnp.min(g), jnp.mean(beta))
+
+    with jax.named_scope("gdn"):
+        start = (jnp.zeros((b, heads, hd, hd), f32), jnp.zeros((b, taps - 1, 2 * kch + vch), h.dtype))
+        blocks = jnp.moveaxis(h.reshape(b, s // blk, blk, d), 1, 0)
+        _, (out, decay_min, beta_mean) = jax.lax.scan(jax.checkpoint(block), start, blocks)
+        stats = {"decay_min": jnp.min(decay_min), "beta_mean": jnp.mean(beta_mean)}
+        return jnp.moveaxis(out, 0, 1).reshape(b, s, d), stats
 
 
 def _mix_conv(lp, h):
@@ -1258,7 +1416,7 @@ def _make_layer_fn(
     """The function of one layer of ``kind`` (mixer, feed-forward); absent:
     the one kind a model of one kind has. ``remat_parts``: ``jax.checkpoint``
     (``cfg.remat``) around the mixer and around the feed-forward, each by
-    itself, where the caller puts none around the layer — but a ``kda`` mixer,
+    itself, where the caller puts none around the layer — but a ``kda`` or ``gdn`` mixer,
     which checkpoints itself block by block (:data:`_KDA_BLOCK`): a second one
     around it would run its forward a third time. ``nested``: a name every op
     of the layer carries INSIDE its top-level scope (``attn/<nested>/...``: the
@@ -1289,9 +1447,9 @@ def _make_layer_fn(
             "a token's rows to the chips holding its experts, and their outputs back, is missing, "
             "and a share held on one chip does not stand in for it"
         )
-    if mixer == "kda" and sp_size > 1:
+    if mixer in ("kda", "gdn") and sp_size > 1:
         raise ValueError(
-            f"sp={sp_size} with a kda layer: the recurrent state at a sequence shard's start is the "
+            f"sp={sp_size} with a {mixer} layer: the recurrent state at a sequence shard's start is the "
             "state at the end of the shard before it; the hand-over of that state (and of the "
             "short convolution's last taps) from one sp shard to the next is missing"
         )
@@ -1310,33 +1468,37 @@ def _make_layer_fn(
 
         def whole(lp, x):
             lp = _compute_dtype(lp, cfg.dtype)
-            return fn(lp, rms_norm(x, lp[norm], cfg.norm_eps))
+            return fn(lp, _norm(cfg, x, lp[norm]))
 
         return whole
 
     def layer_fn(x: jnp.ndarray, lp: Dict[str, Any]):
-        """(x, aux): aux is (balance term, tokens per expert[, rows held]) of
-        a dropless expert layer and () otherwise."""
-        aux = ()
+        """(x, aux): aux by name — what :func:`_ffn_moe` says of a dropless
+        expert layer (:func:`_moe_said`), what :func:`_mix_gdn` says of its
+        mixer — {} otherwise."""
+        aux = {}
         x = _constrain(x, _act_spec(sp_manual))
         with _scopes("attn", nested):
-            h = x if from_input else rms_norm(x, lp["ln1"], cfg.norm_eps)
+            h = x if from_input else _norm(cfg, x, lp["ln1"])
             if mixer in ("full", "window"):
                 x = x + part(of_input(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer), "ln1"))(lp, h)
             elif mixer == "kda":
                 x = x + of_input(functools.partial(_mix_kda, cfg), "ln1")(lp, h)
+            elif mixer == "gdn":
+                y, aux = of_input(functools.partial(_mix_gdn, cfg), "ln1")(lp, h)
+                x = x + y
             elif mixer == "conv":
                 x = x + part(of_input(_mix_conv, "ln1"))(lp, h)
             else:
                 x = x + part(of_input(functools.partial(_mix_mla, cfg, mesh, sp_manual), "ln1"))(lp, h)
 
         with _scopes("moe" if ff == "experts" else "ffn", nested):
-            h = x if from_input else rms_norm(x, lp["ln2"], cfg.norm_eps)
+            h = x if from_input else _norm(cfg, x, lp["ln2"])
             if experts_over_chips:
                 x = x + of_input(functools.partial(_ffn_moe_ep, cfg=cfg), "ln2")(lp, h)
             elif ff == "experts":
-                y, aux = part(of_input(functools.partial(_ffn_moe, cfg=cfg), "ln2"))(lp, h)
-                x = x + y
+                y, said = part(of_input(functools.partial(_ffn_moe, cfg=cfg), "ln2"))(lp, h)
+                x, aux = x + y, {**aux, **_moe_said(cfg, said)}
             else:
                 x = x + part(of_input(_ffn_dense, "ln2"))(lp, h)
         return _constrain(x, _act_spec(sp_manual)), aux
@@ -1397,7 +1559,8 @@ def _make_pattern_fn(cfg: TransformerConfig, mesh):
     the period with its layers unrolled in the body (a period of one layer:
     over the layers), each layer's mixer and
     feed-forward under a ``jax.checkpoint`` of their own (``remat_parts``).
-    ``aux`` is the expert layers' (see ``layer_fn``), stacked in layer order."""
+    ``aux`` is the layers' (see ``layer_fn``) by name, each name stacked over the
+    layers that say it, in layer order."""
     n_lead, period = layer_pattern(cfg)
     kinds = cfg.layer_kinds()
     scans_layers = _scans_layers(cfg)
@@ -1419,6 +1582,15 @@ def _make_pattern_fn(cfg: TransformerConfig, mesh):
         x, aux = fns[_kind_key(period[0])](x, lp)
         return x, [aux] if aux else []
 
+    def rows_of(lead_aux, period_aux, name):
+        """[layers that say ``name``, ...]: the leading ones, then repeat by repeat."""
+        rows = [a[name][None] for a in lead_aux if name in a]
+        inside = [a[name] for a in period_aux if name in a]
+        if inside:
+            inner = jnp.stack(inside, axis=1)  # [repeats, in a period, ...]
+            rows.append(inner.reshape((-1,) + inner.shape[2:]))
+        return jnp.concatenate(rows, axis=0)
+
     def pattern_fn(lead, periods, x):
         x, lead_aux = run(lead, _slots(kinds[:n_lead]), x)
         if scans_layers:
@@ -1429,17 +1601,8 @@ def _make_pattern_fn(cfg: TransformerConfig, mesh):
             x, period_aux = jax.lax.scan(one, x, layers)
         else:
             x, period_aux = jax.lax.scan(lambda x, group: run(group, _slots(period), x), x, periods)
-        if not lead_aux and not period_aux:
-            return x, ()
-        # [layers with experts, ...]: the leading ones, then repeat by repeat
-        parts = []
-        for j in range(len((lead_aux + period_aux)[0])):
-            rows = [a[j][None] for a in lead_aux]
-            if period_aux:
-                inner = jnp.stack([a[j] for a in period_aux], axis=1)  # [repeats, in a period, ...]
-                rows.append(inner.reshape((-1,) + inner.shape[2:]))
-            parts.append(jnp.concatenate(rows, axis=0))
-        return x, tuple(parts)
+        names = dict.fromkeys(name for aux in lead_aux + period_aux for name in aux)
+        return x, {name: rows_of(lead_aux, period_aux, name) for name in names}
 
     return pattern_fn
 
@@ -1526,9 +1689,10 @@ def _hidden_states(
 ):
     """tokens [B, S] -> (final-norm hidden states [B, S, D] in cfg.dtype, aux)
     (everything except the unembed — the chunked loss head consumes this
-    without ever materializing [S, V] logits). ``aux`` is what the layer
-    scan carries out beside the hidden state: (balance term [L], tokens per
-    expert [L, E]) of dropless expert layers, else ()."""
+    without ever materializing [S, V] logits). ``aux`` is what the layers
+    say beside the hidden state, by name (``layer_fn``), each name stacked
+    over the layers that say it: ``balance`` [L] and ``counts`` [L, E] of
+    dropless expert layers, ``decay_min`` of gdn mixers, …; else {}."""
     from torchft_tpu.parallel.pipeline import pipeline_forward
 
     b, s = tokens.shape
@@ -1536,7 +1700,7 @@ def _hidden_states(
     x = _embed_lookup(params, tokens, dt)
 
     pp = max(cfg.pp, 1)
-    aux = ()
+    aux = {}
     if not _of_one_kind(cfg):
         _refuse_pattern_under_pp(cfg)
         _say_layer_pattern(cfg, b, s)
@@ -1544,7 +1708,7 @@ def _hidden_states(
         if not _scans_layers(cfg):
             lead, periods = _compute_dtype(lead, dt), _compute_dtype(periods, dt)
         x, aux = _make_pattern_fn(cfg, mesh)(lead, periods, x)
-        return rms_norm(x, params["final_norm"].astype(dt), cfg.norm_eps), aux
+        return _norm(cfg, x, params["final_norm"].astype(dt)), aux
 
     layers = _compute_dtype(params["layers"], dt)
     if pp == 1:
@@ -1561,7 +1725,7 @@ def _hidden_states(
         x_mb = pipeline_forward(layers, x_mb, stage_fn, mesh)
         x = x_mb.reshape(b, s, -1)
 
-    return rms_norm(x, params["final_norm"].astype(dt), cfg.norm_eps), aux
+    return _norm(cfg, x, params["final_norm"].astype(dt)), aux
 
 
 def forward(
@@ -1598,8 +1762,10 @@ def loss_and_stats(
     """(:func:`loss_fn`'s loss, router statistics of the call). The
     statistics are ``{}`` for a model without dropless experts, else
     ``tokens_per_expert`` [L, E] int32 and ``balance_loss`` (the mean over
-    layers of E·Σ_e f_e·P_e, before the coefficient) — what
-    ``TrainStep`` keeps of its last step."""
+    layers of E·Σ_e f_e·P_e, before the coefficient), under a share
+    ``rows_held`` [L], under ``shared_expert_gate`` ``shared_gate_mean`` [L];
+    of gdn mixers ``gdn_decay_min`` and ``gdn_beta_mean`` [their layers] —
+    what ``TrainStep`` keeps of its last step."""
     if max(cfg.pp, 1) > 1 and cfg.n_experts and cfg.router_aux_loss_coef:
         raise ValueError(
             f"pp={cfg.pp} with router_aux_loss_coef={cfg.router_aux_loss_coef}: "
@@ -1622,16 +1788,19 @@ def loss_and_stats(
     if cfg.n_mtp_modules and cfg.mtp_loss_weight:
         second, mtp_aux = _mtp_hidden(params, x, tokens, cfg, mesh)
         mtp_ce = _cross_entropy(params, second, tokens, cfg, mesh, ahead=2)
-        # the module's expert layer is the statistics' last row
-        aux = tuple(jnp.concatenate([a, m[None]]) for a, m in zip(aux, mtp_aux)) if aux else mtp_aux
+        # the module's layer is the statistics' last row (it is of the last layer's kind: what it says, that layer said)
+        aux = {**aux, **{k: jnp.concatenate([aux[k], m[None]]) for k, m in mtp_aux.items()}} if aux else mtp_aux
         stats = {"main_loss": ce, "mtp_loss": mtp_ce}
         ce = ce + cfg.mtp_loss_weight * mtp_ce
-    if not aux:
+    for name, said in (("decay_min", "gdn_decay_min"), ("beta_mean", "gdn_beta_mean"), ("shared_gate", "shared_gate_mean")):
+        if name in aux:
+            stats[said] = aux[name]
+    if "balance" not in aux:
         return ce, stats
-    balance = jnp.mean(aux[0])
-    stats.update(tokens_per_expert=aux[1], balance_loss=balance)
-    if len(aux) > 2:  # under a share: the token-expert rows whose expert is held, a layer
-        stats["rows_held"] = aux[2]
+    balance = jnp.mean(aux["balance"])
+    stats.update(tokens_per_expert=aux["counts"], balance_loss=balance)
+    if "held" in aux:  # under a share: the token-expert rows whose expert is held, a layer
+        stats["rows_held"] = aux["held"]
     if cfg.router_aux_loss_coef:
         ce = ce + cfg.router_aux_loss_coef * balance
     return ce, stats
@@ -1657,13 +1826,13 @@ def _mtp_hidden(params: Dict[str, Any], h: jnp.ndarray, tokens: jnp.ndarray, cfg
     e = _embed_lookup(params, jnp.roll(tokens, -1, axis=1), dt, nested=_MTP)
     with _scopes("embed", _MTP):
         x = jnp.concatenate(
-            [rms_norm(e, mtp["enorm"].astype(dt), cfg.norm_eps), rms_norm(h, mtp["hnorm"].astype(dt), cfg.norm_eps)],
+            [_norm(cfg, e, mtp["enorm"].astype(dt)), _norm(cfg, h, mtp["hnorm"].astype(dt))],
             axis=-1,
         ) @ mtp["eh_proj"].astype(dt)
     layer_fn = _make_layer_fn(cfg, mesh, kind=cfg.layer_kinds()[-1], remat_parts=True, nested=_MTP, from_input=True)
     x, aux = layer_fn(x, mtp["layer"])
     with _scopes("head_loss", _MTP):
-        return rms_norm(x, mtp["final_norm"].astype(dt), cfg.norm_eps), aux
+        return _norm(cfg, x, mtp["final_norm"].astype(dt)), aux
 
 
 # Logit-element budget, per device, above which the loss head chunks the
@@ -1887,7 +2056,7 @@ def _pipelined_loss(
 
     @jax.named_scope("head_loss")
     def head_fn(hp, outs, t):
-        h = rms_norm(outs, hp["final_norm"], cfg.norm_eps)
+        h = _norm(cfg, outs, hp["final_norm"])
         logits = (h @ hp["out"]).astype(jnp.float32)
         logprobs = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logprobs, t[..., None], axis=-1)[..., 0]

@@ -64,7 +64,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["kda_chunked", "kda_recurrent", "short_conv"]
+__all__ = ["kda_chunked", "gdn_chunked", "kda_recurrent", "short_conv"]
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 # three bfloat16 passes: float32 operands to about 2^-16, half the passes of
@@ -185,6 +185,31 @@ def _decayed_lower(rows: jnp.ndarray, k: jnp.ndarray, G: jnp.ndarray, diag: bool
     return jnp.where((j <= i) if diag else (j < i), jnp.concatenate(out, axis=-2), 0.0)
 
 
+def _sums_between(g: jnp.ndarray) -> jnp.ndarray:
+    """``[..., i, j] = sum of g_t over j < t <= i`` (0 where j >= i) for ONE
+    log-decay a position, g [..., C, 1]: what position j's write has decayed
+    by when position i reads it, summed over the pair's own positions. The
+    difference of two running sums is the same number less exactly: where a
+    head forgets tens of nats a position the sums reach thousands, their
+    float32 steps 1e-4, and a pair that decays little between two such
+    stretches would carry that as a relative error."""
+    c = g.shape[-2]
+    t, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    tril = jnp.tril(jnp.ones((c, c), jnp.float32))
+    return jnp.einsum("it,...tj->...ij", tril, jnp.where(t > j, g, 0.0), precision=_HIGHEST)
+
+
+def _scalar_lower(rows: jnp.ndarray, k: jnp.ndarray, between: jnp.ndarray, diag: bool) -> jnp.ndarray:
+    """:func:`_decayed_lower` where a head has ONE decay a position:
+    ``A[i, j] = (rows_i · k_j) exp(between[i, j])`` (:func:`_sums_between`),
+    one product and one factor whose exponent is <= 0 — nothing overflows
+    whatever the decay, and no sub-blocks are needed."""
+    c = rows.shape[-2]
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    pairs = jnp.einsum("...ic,...jc->...ij", rows, k, precision=_HIGH) * jnp.exp(between)
+    return jnp.where((j <= i) if diag else (j < i), pairs, 0.0)
+
+
 _BASE = 8  # the largest block whose inverse is taken in product form
 
 
@@ -229,9 +254,29 @@ def _unit_lower_inverse(L: jnp.ndarray) -> jnp.ndarray:
     return inv[..., 0, :, :]
 
 
+@jax.custom_vjp
+def _unit_lower_inverse_written(L: jnp.ndarray) -> jnp.ndarray:
+    """:func:`_unit_lower_inverse` with its gradient WRITTEN: ``X = (I + L)^-1``
+    gives ``dL = -Xᵀ dX Xᵀ``, two products of what the forward already holds,
+    where autodiff walks back through every 8-row block and every merge — 31
+    of the 87 ms a layer that three passes of the scalar-decay rule took at
+    b2 x s8192 x 32 x 128 on a v5e (PERF.md §6, PR 54). What falls above the
+    diagonal of ``dL`` meets the mask that made ``L``."""
+    return _unit_lower_inverse(L)
+
+
+def _unit_lower_inverse_written_bwd(X, dX):
+    Xt = jnp.swapaxes(X, -1, -2)
+    return (-jnp.matmul(jnp.matmul(Xt, dX, precision=_HIGHEST), Xt, precision=_HIGHEST),)
+
+
+_unit_lower_inverse_written.defvjp(lambda L: (_unit_lower_inverse(L),) * 2, _unit_lower_inverse_written_bwd)
+
+
 def _chunked(q, k, v, g, beta, S0, chunk):
     """The chunked rule in ``jax.numpy``, exact whatever the decay: q, k, v, g
-    [B, S, H, d] with S whole chunks, beta [B, S, H], S0 [B, H, dk, dv]
+    [B, S, H, d] with S whole chunks (g [B, S, H, 1]: one decay a head,
+    :func:`gdn_chunked`), beta [B, S, H], S0 [B, H, dk, dv]
     float32. What :func:`kda_chunked` runs for widths the kernels do not take
     and, at any width, for a call with a channel that decays too fast for them."""
     b, s, h, dk = q.shape
@@ -247,13 +292,18 @@ def _chunked(q, k, v, g, beta, S0, chunk):
     bc = chunks(beta.astype(f32)[..., None])[..., 0]  # [B, H, N, C]
     tril = jnp.tril(jnp.ones((chunk, chunk), f32))
     G = jnp.einsum("ij,...jc->...ic", tril, gc, precision=_HIGHEST)  # running sum in a chunk
-    a_kk = _decayed_lower(kc, kc, G, diag=False)
-    a_qk = _decayed_lower(qc, kc, G, diag=True)
-    T = _unit_lower_inverse(bc[..., None] * a_kk) * bc[..., None, :]  # (I + diag(b) A)^-1 diag(b)
+    if g.shape[-1] == 1:
+        between = _sums_between(gc)
+        pairs, inverse, to_end = _scalar_lower, _unit_lower_inverse_written, between[..., -1, :, None]
+    else:
+        between, pairs, inverse, to_end = G, _decayed_lower, _unit_lower_inverse, None
+    a_kk = pairs(kc, kc, between, diag=False)
+    a_qk = pairs(qc, kc, between, diag=True)
+    T = inverse(bc[..., None] * a_kk) * bc[..., None, :]  # (I + diag(b) A)^-1 diag(b)
     g_end = G[..., -1:, :]
     k_in = (kc * jnp.exp(G)).astype(dt)  # what a chunk's keys read of S0
     q_in = (qc * jnp.exp(G)).astype(dt)
-    k_out = (kc * jnp.exp(g_end - G)).astype(dt)  # what they leave in the state at its end
+    k_out = (kc * jnp.exp(g_end - G if to_end is None else to_end)).astype(dt)  # what they leave in the state at its end
     Td = T.astype(dt)
     w_v = jnp.einsum("...ij,...jd->...id", Td, vc, preferred_element_type=f32)
     w_k = jnp.einsum("...ij,...jd->...id", Td, k_in, preferred_element_type=f32).astype(dt)
@@ -397,6 +447,44 @@ def kda_chunked(
         o, S_end = _by_decay(q, k, v, g, beta, S0)
     else:
         o, S_end = _chunked(q, k, v, g, beta, S0, chunk)
+    return o[:, :s], S_end
+
+
+def gdn_chunked(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    g: jnp.ndarray,
+    beta: jnp.ndarray,
+    chunk: int = 64,
+    initial_state: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The same rule with ONE log-decay a head a position and key heads shared
+    by value heads (Gated DeltaNet): q, k [B, S, Hk, dk]; v [B, S, Hv, dv], Hv
+    a multiple of Hk, value head j reads key head ``j // (Hv / Hk)``; g
+    [B, S, Hv] float32 (<= 0) and beta [B, S, Hv]. Returns (o [B, S, Hv, dv],
+    the final state [B, Hv, dk, dv] float32); any S, as :func:`kda_chunked`.
+
+    :func:`_chunked` in ``jax.numpy`` at every width and every decay, the
+    pairs of a chunk by :func:`_scalar_lower`. The kernels are not asked:
+    handed this decay broadcast over a head's lanes they compute the same
+    numbers, but only for calls that decay under :data:`_TWO_FACTOR_NATS`
+    inside 16 positions (:func:`_kernel_serves`) — and a head of the published
+    class's initial values (``A`` up to 16, ``dt_bias`` 1) forgets ~20 nats a
+    POSITION, so every call of such a model would compile both forms and run
+    the per-channel exact one, sixteen exponentials a pair for a decay that
+    needs one (PERF.md §6, PR 54)."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    assert hv % hk == 0, f"{hv} value heads do not divide over {hk} key heads"
+    q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
+    g = g.astype(jnp.float32)[..., None]
+    pad = -s % chunk
+    if pad:
+        q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    S0 = jnp.zeros((b, hv, dk, dv), jnp.float32) if initial_state is None else initial_state.astype(jnp.float32)
+    o, S_end = _chunked(q, k, v, g, beta, S0, chunk)
     return o[:, :s], S_end
 
 

@@ -24,10 +24,15 @@ __all__ = [
 ]
 
 
-def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6, zero_centered: bool = False) -> jnp.ndarray:
+    """``x̂ · weight`` with ``x̂ = x / sqrt(mean(x²) + eps)`` in float32. ``zero_centered``: ``x̂ · (1 + weight)``
+    instead, a weight that starts at zero — the product in float32 too and rounded once: in bfloat16 ``1 + w`` is
+    1 for every ``|w|`` under 2^-8, and a weight a few optimizer steps from zero would not be there at all."""
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    if zero_centered:
+        return (xf * scale * (1.0 + weight.astype(jnp.float32))).astype(dtype)
     return (xf * scale).astype(dtype) * weight
 
 

@@ -106,7 +106,7 @@ class FTTrainer:
         Tracer ring and, as ``tft.moe.counters``, in a profiler trace. A
         model without dropless experts has no statistics and emits nothing."""
         stats = self._ts.last_stats
-        if not stats:
+        if "tokens_per_expert" not in stats:
             return
         import numpy as np
 
@@ -146,6 +146,30 @@ class FTTrainer:
         with tracing.annotate("mtp.counters", **counters):
             pass
 
+    def _record_gdn_counters(self, step: int, sync_span) -> None:
+        """What the Gated DeltaNet mixers and a gated shared expert say of the
+        step (``loss_and_stats``), fetched with the loss: the least log-decay
+        of a position over the layers (``ops/kda.gdn_chunked`` is exact
+        whatever it is; the per-channel kernels' two-factor form holds 80 nats
+        over 16 positions), the mean write strength, the mean of the shared
+        expert's gate — on the ``loss_sync`` span in the Tracer ring and, as
+        ``tft.gdn.counters``, in a profiler trace. Any other model emits nothing."""
+        stats = self._ts.last_stats
+        if "gdn_decay_min" not in stats:
+            return
+        import numpy as np
+
+        counters = dict(
+            step=step,
+            decay_min=float(np.min(stats["gdn_decay_min"])),
+            beta_mean=float(np.mean(stats["gdn_beta_mean"])),
+        )
+        if "shared_gate_mean" in stats:
+            counters["shared_gate_mean"] = float(np.mean(stats["shared_gate_mean"]))
+        sync_span.set(**counters)
+        with tracing.annotate("gdn.counters", **counters):
+            pass
+
     # -- drive --
 
     def step(self, tokens) -> Tuple[float, bool]:
@@ -178,5 +202,6 @@ class FTTrainer:
                 loss = float(loss)
                 self._record_moe_counters(label, sync_span)
                 self._record_mtp_counters(label, sync_span)
+                self._record_gdn_counters(label, sync_span)
             step_span.set(committed=committed)
         return loss, committed
