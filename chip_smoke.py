@@ -418,7 +418,7 @@ def steady(
 
 def kernels_child(
     platform: str, flash_shapes: list, chunked_shape: list, cells_shape: list,
-    mla_shape: Optional[list] = None, kda_shape: Optional[list] = None,
+    mla_shape: Optional[list] = None, kda_shape: Optional[list] = None, gdn_shape: Optional[list] = None,
 ) -> None:
     """Runs in the child. Flash forward+backward per (b, s, h, d) at its
     default blocks — a shape that goes on to (…, value width, key/value heads)
@@ -429,7 +429,9 @@ def kernels_child(
     highest precision). ``mla_shape`` (b, s, h, key width, value width):
     the latent attention's core as "auto" takes it (``mla_cells``);
     ``kda_shape`` (b, s, h, d): chunked KDA in bf16 against the recurrence in
-    f32 (``kda_cells``) — both at the hybrid cell's widths and a short length."""
+    f32 (``kda_cells``) — both at the hybrid cell's widths and a short length;
+    ``gdn_shape`` (b, s, key heads, value heads, d): the same rule with one
+    decay a head, key heads shared by value heads (``gdn_cells``)."""
     from torchft_tpu.utils.compile_cache import place_compile_cache
 
     place_compile_cache()
@@ -527,6 +529,8 @@ def kernels_child(
         ))
     if kda_shape:
         oks.append(_kda_cells(kda_shape, dev))
+    if gdn_shape:
+        oks.append(_gdn_cells(gdn_shape, dev))
     sys.exit(0 if all(oks) else 1)
 
 
@@ -546,19 +550,57 @@ def _kda_cells(shape: list, dev) -> bool:
     b, s, h, d = shape
     ks = jax.random.split(jax.random.PRNGKey(s + d), 6)
     q, k, v, w = (jax.random.normal(kk, (b, s, h, d), jnp.float32) for kk in ks[:4])
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d**-0.5
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     g = -jax.nn.softplus(jax.random.normal(ks[4], (b, s, h, d))) * 0.1
     beta = jax.nn.sigmoid(jax.random.normal(ks[5], (b, s, h)))
+    return _held_to_the_recurrence("kda_cells", shape, dev, kda_chunked, kda_recurrent, (q, k, v, g, beta), w)
+
+
+def _gdn_cells(shape: list, dev) -> bool:
+    """The same for the rule with ONE decay a head and key heads shared by
+    value heads (``ops/kda.gdn_chunked``; shape b, s, key heads, value heads,
+    d): on a TPU at heads 128 wide the Pallas kernel pair with the scalar
+    decay, at the decays of the published initial values — ``-A softplus(a +
+    1)`` with A up to 16 a head, ~20 nats a POSITION at the fastest — where the
+    per-channel kernels would not serve."""
+    import jax
+    import jax.numpy as jnp
+
+    from torchft_tpu.ops.kda import gdn_chunked, kda_recurrent
+
+    b, s, hk, hv, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(s + d + hv), 6)
+    q, k = (jax.random.normal(kk, (b, s, hk, d), jnp.float32) for kk in ks[:2])
+    v, w = (jax.random.normal(kk, (b, s, hv, d), jnp.float32) for kk in ks[2:4])
+    g = -jnp.linspace(0.5, 16.0, hv) * jax.nn.softplus(jax.random.normal(ks[4], (b, s, hv)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (b, s, hv)))
+
+    def by_position(q, k, v, g, beta):
+        q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
+        return kda_recurrent(q, k, v, jnp.broadcast_to(g[..., None], v.shape), beta)
+
+    return _held_to_the_recurrence("gdn_cells", shape, dev, gdn_chunked, by_position, (q, k, v, g, beta), w)
+
+
+def _held_to_the_recurrence(name: str, shape: list, dev, chunked, recurrent, inputs, w) -> bool:
+    """One check line: ``chunked`` on bf16 q, k, v — value and the gradients of
+    all five inputs under the probe ``w`` — against ``recurrent`` in f32 on the
+    same rounded operands; q and k enter L2-normalised, q scaled."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v, g, beta = inputs
+    d = q.shape[-1]
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d**-0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
 
     def loss(f, q, k, v, g, beta):
         o = f(q, k, v, g, beta)[0]
         return jnp.sum(o.astype(jnp.float32) * w), o
 
-    got_fn = jax.jit(jax.value_and_grad(lambda *a: loss(kda_chunked, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
+    got_fn = jax.jit(jax.value_and_grad(lambda *a: loss(chunked, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
     with jax.default_matmul_precision("highest"):
-        ref_fn = jax.jit(jax.value_and_grad(lambda *a: loss(kda_recurrent, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
+        ref_fn = jax.jit(jax.value_and_grad(lambda *a: loss(recurrent, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
         (_, o_ref), g_ref = ref_fn(*(x.astype(jnp.float32) for x in (qb, kb, vb)), g, beta)
     # on a TPU, heads 128 wide are the Pallas kernels' (ops/pallas/kda.py): a
     # silent fall back to the jax.numpy form fails here and not only a metric
@@ -575,7 +617,7 @@ def _kda_cells(shape: list, dev) -> bool:
     finite = all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in (o, *grads))
     ok = finite and max(errs.values()) <= atol and (mosaic or not must_be_mosaic)
     print(json.dumps({
-        "check": "kda_cells", "shape": shape, "ok": ok, "mosaic_custom_call": mosaic, "finite": finite,
+        "check": name, "shape": shape, "ok": ok, "mosaic_custom_call": mosaic, "finite": finite,
         "err_minus_rtol_ref": {k: round(v, 5) for k, v in errs.items()}, "atol": atol, "rtol": rtol,
         "compile_and_first_run_s": round(t_first, 2), "device": dev.device_kind,
     }), flush=True)
@@ -589,6 +631,7 @@ def kernels(
     cells_shape: Optional[list] = None,
     mla_shape: Optional[list] = None,
     kda_shape: Optional[list] = None,
+    gdn_shape: Optional[list] = None,
     timeout: float = 600,
 ) -> List[Dict[str, Any]]:
     # head_dim 64 and 128 are the two the presets use; S >= 2048. The last is
@@ -604,9 +647,11 @@ def kernels(
     # wide, KDA's heads of 128 (8 of the 32: the recurrence's reference is slow)
     mla_shape = mla_shape or [1, 2048, 8, 192, 128]
     kda_shape = kda_shape or [1, 1024, 8, 128]
+    # the Gated DeltaNet cell's heads, 16 key heads under 32 value heads of 128
+    gdn_shape = gdn_shape or [1, 1024, 16, 32, 128]
     code = (
         "import chip_smoke; chip_smoke.kernels_child("
-        f"{platform!r}, {flash_shapes!r}, {chunked_shape!r}, {cells_shape!r}, {mla_shape!r}, {kda_shape!r})"
+        f"{platform!r}, {flash_shapes!r}, {chunked_shape!r}, {cells_shape!r}, {mla_shape!r}, {kda_shape!r}, {gdn_shape!r})"
     )
     try:
         text = run_child("3_kernels", [sys.executable, "-c", code], _child_env(), timeout)

@@ -721,6 +721,47 @@ def test_the_kda_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch
 
 
 @pytest.mark.parametrize(
+    "batch, seq, key_heads, heads, dtype",
+    [(2, 1024, 16, 32, jnp.bfloat16), (1, 1024, 16, 32, jnp.float32), (1, 128, 1, 8, jnp.bfloat16), (1, 128, 2, 2, jnp.bfloat16)],
+    ids=["the_cell", "check_qwen3_next_float32", "a_group_over_two_steps", "a_key_head_a_value_head"],
+)
+def test_the_gdn_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch, seq, key_heads, heads, dtype):
+    """``gdn_forward`` / ``gdn_backward`` — the delta rule with ONE decay a
+    head — through Mosaic for a described v5e: a block of the Gated DeltaNet
+    cell's mixer (1024 positions, 16 key heads under 32 value heads, four value
+    heads over two key heads a grid step), the same in float32 (every product
+    at ``HIGHEST``: what ``benchmark/check_qwen3_next.py``'s float32 comparison
+    runs on the chip), a key head whose eight value heads span two grid steps,
+    and as many key heads as value heads."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.ops.pallas import kda as kernels
+
+    def of(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    keys, values = of((batch, seq, key_heads * 128), dtype), of((batch, seq, heads * 128), dtype)
+    small, state = of((batch, seq, heads)), of((batch, heads, 128, 128))
+    starts = of((batch, heads, seq // kernels.CHUNK, 128, 128))
+    forward = jax.jit(lambda *a: kernels.gdn_forward(*a, interpret=False))
+    backward = jax.jit(lambda *a: kernels.gdn_backward(*a, interpret=False))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = (
+            forward.lower(keys, keys, values, small, small, state).compile(),
+            backward.lower(keys, keys, values, small, small, starts, values, state).compile(),
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert all("tpu_custom_call" in c.as_text() for c in compiled)
+    dq, dk, dv, dg, dbeta, ds0 = jax.tree_util.tree_leaves(compiled[1].out_info)
+    assert dq.shape == dk.shape == keys.shape and dv.shape == values.shape  # a key head's gradients summed over its value heads
+    assert dg.shape == dbeta.shape == small.shape and dg.dtype == jnp.float32 and ds0.shape == state.shape
+
+
+@pytest.mark.parametrize(
     "rows, width, dtype",
     [(32768, 2048, jnp.bfloat16), (8192, 2304, jnp.bfloat16), (32768, 128, jnp.bfloat16), (8192, 2304, jnp.float32)],
     ids=["laguna_xs2_1g", "kimi_linear_1g", "the_gates_gradient", "float32"],

@@ -122,15 +122,45 @@ def by_position(q, k, v, g, beta, S0):
     return kda_recurrent(jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v, jnp.broadcast_to(g[..., None], v.shape), beta, S0)
 
 
+def paths_to_kernels(jaxpr, path=(), out=None):
+    """{a ``pallas_call``'s name: the set of chains of primitives around its calls}."""
+    from tests.test_window_gqa import sub_jaxprs
+
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.setdefault(eqn.params["name"], set()).add(path)
+        else:
+            for sub in sub_jaxprs(eqn):
+                paths_to_kernels(sub, path + (eqn.primitive.name,), out)
+    return out
+
+
+def enters_the_kernel(*inputs, **kw):
+    """Whether ``gdn_chunked`` hands these inputs to the Pallas kernel pair — and if so with no ``cond`` around it."""
+    paths = paths_to_kernels(jax.make_jaxpr(lambda *a: gdn_chunked(*a, **kw))(*inputs).jaxpr)
+    assert not any("cond" in path for around in paths.values() for path in around)
+    return "gdn_fwd" in paths
+
+
+WIDE = 128  # the kernels' head width: interpreted here
+
+
+@pytest.mark.parametrize("d", [8, WIDE], ids=["jax.numpy", "gdn_kernel"])
 @pytest.mark.parametrize("seq", [64, 100, 192])
 @pytest.mark.parametrize("decay", [0.1, 3.0, 25.0])  # nats a position at most: mild, a trained model's, the published init's
-def test_the_chunked_core_is_the_recurrence_value_and_every_gradient(seq, decay):
+def test_the_chunked_core_is_the_recurrence_value_and_every_gradient(seq, decay, d):
     """``gdn_chunked`` (16 -> 32 heads' reading at 2 -> 4) against the rule one
     position after another, outputs, final state and the gradient of all six
     inputs; a sequence of whole chunks, one with a padded tail, and decays up
     to 25 nats a POSITION, where the per-channel kernels' two-factor form
-    (80 nats over 16 positions) would not serve."""
-    inputs = core_inputs(seq, decay)
+    (80 nats over 16 positions) would not serve. Heads 8 wide are the
+    ``jax.numpy`` form; heads 128 wide the Pallas kernel pair with one decay a
+    head (``ops/pallas/kda.gdn_forward`` / ``gdn_backward``, interpreted), at
+    every decay alike: a lone chunk padded to the two a grid step takes (64),
+    a tail padded (100), a pair and a half (192)."""
+    inputs = core_inputs(seq, decay, d=d)
+    assert enters_the_kernel(*inputs[:5], initial_state=inputs[5]) == (d == WIDE)
     probe = jnp.asarray(np.random.default_rng(9).normal(size=inputs[2].shape), jnp.float32)
 
     def scalar(fn):
@@ -158,6 +188,86 @@ def test_a_pairs_decay_is_summed_over_its_own_positions():
     between = np.asarray(_sums_between(g.astype(jnp.float32)))
     assert between[32, 29] == -0.875 and between[31, 30] == -0.125 and between[10, 10] == 0.0 and between[5, 9] == 0.0
     assert between[33, 0] == pytest.approx(-70.0 * 29 - 0.875 - 70.0, rel=1e-6)
+
+
+@pytest.mark.parametrize("d", [8, WIDE], ids=["jax.numpy", "gdn_kernel"])
+def test_the_decays_gradient_is_at_the_pair_sums_precision(d):
+    """The same stretch through the whole core, both forms: the three mild
+    positions' pairs are all that is left of the chunk, and the gradient of
+    THEIR decays agrees with the recurrence's to a few float32 steps (measured
+    3e-7 and 4e-7 of the largest) — with the pairs' sums taken as differences
+    of running sums it read 4e-5 (PERF.md §6, PR 54). The kernel takes each sum
+    over the positions it spans as a masked triangular product in VMEM."""
+    q, k, v, _, beta, S0 = core_inputs(64, 1.0, d=d)
+    line = jnp.concatenate([jnp.full((30,), -70.0), jnp.asarray([-0.25, -0.125, -0.5]), jnp.full((31,), -70.0)])
+    g = jnp.broadcast_to(line[None, :, None], beta.shape).astype(jnp.float32)
+    assert enters_the_kernel(q, k, v, g, beta) == (d == WIDE)
+    probe = jnp.asarray(np.random.default_rng(9).normal(size=v.shape), jnp.float32)
+    dg = lambda fn: jax.jit(jax.grad(lambda g: jnp.sum(fn(q, k, v, g, beta, S0)[0] * probe)))(g)
+    with jax.default_matmul_precision("highest"):
+        got, want = dg(lambda *a: gdn_chunked(*a[:5], initial_state=a[5])), dg(by_position)
+    mild = slice(30, 33)
+    assert float(jnp.max(jnp.abs(want[:, mild]))) > 1e-2
+    assert float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))) < 3e-6
+
+
+def test_the_kernel_in_bfloat16_stays_in_the_band_of_the_jax_numpy_form():
+    """bfloat16 q, k, v at the kernels' width: the kernel pair against the
+    float32 recurrence and against ``_chunked`` on the same operands, outputs
+    and the gradients of all five inputs, inside the band
+    ``tests/test_kda.py::test_chunked_kda_in_bfloat16`` states for the
+    per-channel rule (1e-2 absolute on outputs of order 0.3, 3e-2 of a
+    gradient's largest entry)."""
+    from torchft_tpu.ops import kda
+
+    q, k, v, g, beta, _ = core_inputs(192, 3.0, d=WIDE)
+    q, k, v, g, beta = (x[:1] for x in (q, k, v, g, beta))
+    qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    assert enters_the_kernel(qb, kb, vb, g, beta) and not enters_the_kernel(qb, kb, v, g, beta)  # mixed dtypes keep jax.numpy
+
+    def jax_numpy(q, k, v, g, beta):
+        q, k = (jnp.repeat(x, 2, axis=2) for x in (q, k))
+        return kda._chunked(q, k, v, g[..., None], beta, jnp.zeros((1, 4, WIDE, WIDE), jnp.float32), 64)
+
+    scalar = lambda fn: lambda *a: (lambda o: (jnp.sum(jnp.sin(o.astype(jnp.float32))), o))(fn(*a)[0])  # noqa: E731
+    run = lambda fn, *a: jax.jit(jax.grad(scalar(fn), argnums=(0, 1, 2, 3, 4), has_aux=True))(*a)
+    got, o = run(gdn_chunked, qb, kb, vb, g, beta)
+    theirs, o_theirs = run(jax_numpy, qb, kb, vb, g, beta)
+    want, o_want = run(lambda *a: by_position(*a, None), *(x.astype(jnp.float32) for x in (qb, kb, vb)), g, beta)
+    assert o.dtype == jnp.bfloat16 and got[0].dtype == got[1].dtype == jnp.bfloat16 and got[3].dtype == jnp.float32
+    off = lambda a, b: float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
+    assert 1e-5 < off(o, o_want) < 1e-2 and off(o, o_theirs) < 1e-2
+    for mine, other, ref in zip(got, theirs, want):
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert off(mine, ref) < 3e-2 * scale and off(mine, other) < 3e-2 * scale
+
+
+@pytest.mark.parametrize("d", [8, WIDE], ids=["jax.numpy", "gdn_kernel"])
+def test_the_core_carries_a_state_from_block_to_block(d):
+    """192 positions at once, and as 128 then 64 with the first block's final
+    state handed to the second (the mixer's scan over blocks)."""
+    q, k, v, g, beta, _ = (x[:1] for x in core_inputs(192, 3.0, d=d))
+    chunked = jax.jit(gdn_chunked)
+    with jax.default_matmul_precision("highest"):
+        whole, end = chunked(q, k, v, g, beta)
+        first, mid = chunked(*(a[:, :128] for a in (q, k, v, g, beta)))
+        second, end2 = chunked(*(a[:, 128:] for a in (q, k, v, g, beta)), initial_state=mid)
+    np.testing.assert_allclose(jnp.concatenate([first, second], axis=1), whole, atol=2e-6)
+    np.testing.assert_allclose(end2, end, atol=2e-6)
+
+
+@pytest.mark.parametrize("hk, hv", [(1, 8), (2, 2), (1, 2)])
+def test_the_kernel_at_other_groupings_of_value_heads_over_key_heads(hk, hv):
+    """A key head whose eight value heads span two grid steps (its gradients
+    summed after the kernel), as many key heads as value heads, and two value
+    heads a step: outputs and q's and k's gradients against the recurrence."""
+    inputs = tuple(x[:1] for x in core_inputs(64, 3.0, hk=hk, hv=hv, d=WIDE))
+    assert enters_the_kernel(*inputs[:5])
+    probe = jnp.asarray(np.random.default_rng(9).normal(size=inputs[2].shape), jnp.float32)
+    grads = lambda fn: jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a)[0] * probe), argnums=(0, 1, 3)))(*inputs)
+    with jax.default_matmul_precision("highest"):
+        got, want = grads(lambda *a: gdn_chunked(*a[:5], initial_state=a[5])), grads(by_position)
+    assert max(grad_errors(got, want)) < 2e-5, grad_errors(got, want)
 
 
 def test_the_value_heads_read_the_key_head_of_their_group():
@@ -454,7 +564,7 @@ def test_the_events_say_the_pattern_and_the_core_the_softmax_layer_took(monkeypa
 
     monkeypatch.setattr(T, "_PATHS_SAID", set())
     cfg, params, tokens, _ = make("stack", seq=32)
-    seen = {kind: len(telemetry.EVENTS.recent(kind)) for kind in ("attention_path", "layer_pattern")}
+    seen = {kind: len(telemetry.EVENTS.recent(kind)) for kind in ("attention_path", "layer_pattern", "gdn_core_path")}
     for _ in range(2):
         jax.jit(lambda p: loss_fn(p, tokens, cfg, None))(params)
     (path,) = telemetry.EVENTS.recent("attention_path")[seen["attention_path"]:]  # the one softmax layer, once
@@ -462,6 +572,8 @@ def test_the_events_say_the_pattern_and_the_core_the_softmax_layer_took(monkeypa
     (pattern,) = telemetry.EVENTS.recent("layer_pattern")[seen["layer_pattern"]:]
     assert (pattern["lead"], pattern["period"], pattern["repeats"]) == ("-", "gdn.experts,gdn.experts,gdn.experts,full.experts", 1)
     assert (pattern["experts_held"], pattern["experts"]) == (4, 16)
+    (core,) = telemetry.EVENTS.recent("gdn_core_path")[seen["gdn_core_path"]:]  # three layers of one shape, once: heads 8 wide
+    assert (core["core"], core["heads"], core["key_heads"], core["head_dim"], core["chunk"], core["batch"], core["block"]) == ("jax.numpy", 4, 2, 8, 64, 2, 32)
 
 
 def test_heads_of_256_take_the_kernel_on_a_chip_at_the_cells_length():

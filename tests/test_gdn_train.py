@@ -5,6 +5,7 @@ kinds of layer and the reference are ``tests/test_gdn.py``'s): a file of its own
 so that these compile-heavy tests are handed to a worker of their own in a run
 with several."""
 
+import contextlib
 import functools
 import hashlib
 import json
@@ -21,7 +22,7 @@ import optax
 import pytest
 
 from tests.test_attn_core_remat import kernel_calls
-from tests.test_gdn import ROOT, SIZES, make
+from tests.test_gdn import ROOT, SIZES, make, paths_to_kernels
 from torchft_tpu.models import transformer as T
 from torchft_tpu.models.transformer import TransformerConfig, init_params, loss_fn
 from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -45,16 +46,18 @@ NEW_CELL = "qwen3-next-80b-a3b-1g"
 
 
 @functools.lru_cache(maxsize=None)
-def cells_program(name, shape):
+def cells_program(name, shape, devices=1):
     """(jaxpr of ``loss_fn``'s value and gradient, its ``_say_once`` lines) of a
-    benchmark configuration at a cell's size, on the chip's branch."""
+    benchmark configuration at a cell's size, on the chip's branch; traced
+    under a mesh of ``devices`` devices where that is more than one."""
     with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
         tc = json.load(f)["program"]["transformer_config"]
     cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
     said = []
     say = lambda kind, key, **fields: said.append(kind + " " + " ".join(f"{k}={v}" for k, v in fields.items()))
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    with mock.patch.object(jax, "default_backend", lambda: "tpu"), mock.patch.object(T, "_say_once", say):
+    mesh = contextlib.nullcontext() if devices == 1 else jax.set_mesh(make_mesh(MeshConfig(fsdp=devices), devices=jax.devices()[:devices]))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), mock.patch.object(T, "_say_once", say), mesh:
         jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda p, t: loss_fn(p, t, cfg)))(params, jax.ShapeDtypeStruct(shape, jnp.int32))
     return jaxpr, said
 
@@ -72,10 +75,10 @@ def test_the_new_cells_softmax_layer_runs_the_kernel_once_and_says_its_heads():
     """`qwen3-next-80b-a3b-1g.fused-s8192` on the chip's branch: the one softmax
     layer is the Pallas kernel at 256 lanes, 16 query heads over 2 — one
     ``flash_fwd`` and one ``flash_bwd`` in the whole step, its output and row
-    statistics kept under ``remat`` — and the Gated DeltaNet layers call no
-    kernel at all: the delta rule with one decay a head is ``jax.numpy``
-    (``ops/kda.gdn_chunked``). The one ``attention_path`` line says which core,
-    tile and heads; the ``layer_pattern`` line the period scanned once."""
+    statistics kept under ``remat`` — and no layer calls the per-channel
+    delta rule's kernels (``kda_fwd`` / ``kda_bwd``: the Gated DeltaNet layers
+    have their own pair, the test below). The one ``attention_path`` line says
+    which core, tile and heads; the ``layer_pattern`` line the period scanned once."""
     from torchft_tpu.ops.pallas.flash_attention import CORE_LSE, CORE_OUT
 
     jaxpr, said = cells_program(NEW_CELL, (2, 8192))
@@ -88,6 +91,31 @@ def test_the_new_cells_softmax_layer_runs_the_kernel_once_and_says_its_heads():
     assert line.endswith("n_heads=16 n_kv_heads=2 window=0 rotary_dim=64")
     (pattern,) = [text for text in said if text.startswith("layer_pattern ")]
     assert "lead=- period=gdn.experts,gdn.experts,gdn.experts,full.experts repeats=1 experts_held=32 experts=512 batch=2 seq=8192" in pattern
+
+
+def test_the_new_cells_gdn_layers_run_the_kernel_pair_and_say_so():
+    """The three Gated DeltaNet layers on the chip's branch: every block of
+    1 024 positions of every layer is the Pallas kernel pair with one decay a
+    head — a layer and block ``gdn_fwd`` twice (the layer's forward, and the
+    forward the block's checkpoint runs again: the mixer checkpoints itself
+    and is not under the layer's ``remat`` besides) and ``gdn_bwd`` once,
+    3 layers x 8 blocks, each in its own ``jit`` under the scans alone: no
+    ``cond`` around a call (no call's decay picks a path). Every layer's
+    ``gdn_core_path`` line names the kernel. Traced under a mesh of two devices
+    the same configuration calls no such kernel and says ``jax.numpy``."""
+    jaxpr, said = cells_program(NEW_CELL, (2, 8192))
+    calls = kernel_calls(jaxpr.jaxpr)
+    assert (calls["gdn_fwd"], calls["gdn_bwd"]) == (2 * 3 * 8, 3 * 8)
+    paths = paths_to_kernels(jaxpr.jaxpr)
+    assert paths["gdn_fwd"] == {("scan", "scan", "jit"), ("scan", "scan", "remat2", "jit")}
+    assert paths["gdn_bwd"] == {("scan", "scan", "remat2", "jit")}
+    lines = [text for text in said if text.startswith("gdn_core_path ")]
+    assert lines == ["gdn_core_path core=gdn_kernel heads=32 key_heads=16 head_dim=128 chunk=64 batch=2 block=1024"] * 3
+    sharded, said = cells_program(NEW_CELL, (2, 1024), devices=2)
+    calls = kernel_calls(sharded.jaxpr)
+    assert not [name for name in calls if "gdn" in str(name)] and calls["flash_fwd"] == 1
+    lines = [text for text in said if text.startswith("gdn_core_path ")]
+    assert lines == ["gdn_core_path core=jax.numpy heads=32 key_heads=16 head_dim=128 chunk=64 batch=2 block=1024"] * 3
 
 
 # -- the names in the lowered program ----------------------------------------------------------

@@ -61,7 +61,7 @@ from torchft_tpu.ops.attention import (
     ring_attention,
     ring_attention_local,
 )
-from torchft_tpu.ops.kda import gdn_chunked, kda_chunked, short_conv
+from torchft_tpu.ops.kda import gdn_chunked, gdn_core, kda_chunked, short_conv
 from torchft_tpu.ops.layers import (
     moe_dispatch,
     moe_dropless,
@@ -1084,6 +1084,18 @@ def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, 
     _say_once("attention_path", (*fields.values(), heads), **fields)
 
 
+def _say_gdn_core_path(core: str, batch: int, block: int, cfg: TransformerConfig) -> None:
+    """One ``gdn_core_path`` event and one INFO line per traced shape of a
+    Gated DeltaNet mixer: which code ``ops/kda.gdn_chunked`` took for a block
+    of it (``ops/kda.gdn_core``: the Pallas kernel pair ``gdn_kernel``, or
+    ``jax.numpy``), and the heads it saw."""
+    fields = dict(
+        core=core, heads=cfg.linear_n_heads, key_heads=cfg.linear_key_heads, head_dim=cfg.linear_head_dim,
+        chunk=64, batch=batch, block=block,  # the chunk ``gdn_chunked`` takes unasked, which is how the mixer calls it
+    )
+    _say_once("gdn_core_path", tuple(fields.values()), **fields)
+
+
 def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None:
     """One ``layer_pattern`` event and INFO line per traced shape of a model
     with a declared pattern: what the stack unrolls and what it scans."""
@@ -1369,6 +1381,7 @@ def _mix_gdn(cfg, lp, h):
             ).astype(hb.dtype).reshape(b, blk, heads, hd)
         q = (_unit_l2(q) * hd**-0.5).astype(v.dtype)
         k = _unit_l2(k).astype(v.dtype)
+        _say_gdn_core_path(gdn_core(q, k, v), b, blk, cfg)
         with jax.named_scope("gdn_core"):
             o, state = gdn_chunked(q, k, v, g, beta, initial_state=state)
         o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * out_gate

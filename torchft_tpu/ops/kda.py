@@ -55,6 +55,21 @@ and picks by what it sees in its input, no field and no switch:
   CPU rehearsals' heads of 8 and 16) is :func:`_chunked` directly, and so
   is any call traced under a mesh of more than one device, where a Mosaic
   call needs a ``shard_map`` around it that the mixer does not have yet.
+
+:func:`gdn_chunked` — ONE decay a head a position, key heads shared by value
+heads (Gated DeltaNet) — picks the same way (:func:`gdn_core` says which):
+
+* heads 128 wide in chunks of 64 on one device go to a kernel pair of their
+  own in the same file (``gdn_forward`` / ``gdn_backward``, one ``custom_vjp``,
+  :func:`_gdn_kernels`), at EVERY decay: with one decay a row a pair is
+  ``(rows · kᵀ) ⊙ exp(the sum of g between the two)``, one product and one
+  factor whose exponent is <= 0, so there are no sub-blocks, no two factors to
+  overflow, no ``lax.cond`` on the call's ``g`` and no exact path beside the
+  kernels. q and k are read in place, a key head once for all its value heads;
+  g, beta and their gradients are one float32 a head a position (PERF.md §6,
+  PR 55);
+* every other call is :func:`_chunked` with the key heads repeated and the
+  pairs by :func:`_scalar_lower`, as under a mesh of several devices.
 """
 
 from __future__ import annotations
@@ -64,7 +79,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["kda_chunked", "gdn_chunked", "kda_recurrent", "short_conv"]
+__all__ = ["kda_chunked", "gdn_chunked", "gdn_core", "kda_recurrent", "short_conv"]
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 # three bfloat16 passes: float32 operands to about 2^-16, half the passes of
@@ -429,15 +444,7 @@ def kda_chunked(
     that neither decay nor write (g = 0, beta = 0)."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
-    # heads of whole lane tiles on one device are the kernels', which take two
-    # chunks at a time. Under a mesh of several devices the SPMD partitioner
-    # refuses a Mosaic call outside a ``shard_map`` (``transformer._flash_sharded``):
-    # such a program keeps the ``jax.numpy`` form until its mixer brings one
-    kernels = (
-        dk % 128 == 0  # before pallas is imported at all
-        and _kernels().serves(dk, dv, chunk) and q.dtype == k.dtype == v.dtype
-        and jax.sharding.get_abstract_mesh().size <= 1
-    )
+    kernels = _kernels_take(q, k, v, chunk)
     pad = -s % (_kernels().ROWS if kernels else chunk)
     if pad:
         q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
@@ -448,6 +455,54 @@ def kda_chunked(
     else:
         o, S_end = _chunked(q, k, v, g, beta, S0, chunk)
     return o[:, :s], S_end
+
+
+def _kernels_take(q, k, v, chunk) -> bool:
+    """Whether a call is the Pallas kernels': heads of whole lane tiles in the
+    kernels' chunk (they take two at a time), one dtype, one device. Under a
+    mesh of several devices the SPMD partitioner refuses a Mosaic call outside
+    a ``shard_map`` (``transformer._flash_sharded``): such a program keeps the
+    ``jax.numpy`` form until its mixer brings one."""
+    dk, dv = q.shape[-1], v.shape[-1]
+    return (
+        dk % 128 == 0  # before pallas is imported at all
+        and _kernels().serves(dk, dv, chunk) and q.dtype == k.dtype == v.dtype
+        and jax.sharding.get_abstract_mesh().size <= 1
+    )
+
+
+def gdn_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, chunk: int = 64) -> str:
+    """Which code :func:`gdn_chunked` runs for these inputs, from what it sees
+    in them (:func:`_kernels_take`): ``"gdn_kernel"`` — the Pallas kernel pair
+    of ``ops/pallas/kda.py`` with one decay a head — or ``"jax.numpy"``
+    (:func:`_chunked`)."""
+    return "gdn_kernel" if _kernels_take(q, k, v, chunk) else "jax.numpy"
+
+
+# One ``custom_vjp`` around the kernel pair, and no ``lax.cond`` in it: the
+# pairs of one decay a head are ``(q kᵀ) ⊙ exp(sums between)`` with exponents
+# <= 0, so the kernels serve every decay and there is no other form to fall to.
+@jax.custom_vjp
+def _gdn_kernels(q, k, v, g, beta, S0):
+    return _gdn_kernels_fwd(q, k, v, g, beta, S0)[0]
+
+
+def _gdn_kernels_fwd(q, k, v, g, beta, S0):
+    with jax.named_scope("gdn_kernel"):
+        o, starts, S_end = _kernels().gdn_forward(_wide(q), _wide(k), _wide(v), g, beta, S0)
+    # the states at the chunks' starts are the one residual beside the inputs
+    return (o.reshape(v.shape), S_end), (q, k, v, g, beta, starts)
+
+
+def _gdn_kernels_bwd(res, cts):
+    q, k, v, g, beta, starts = res
+    do, d_end = cts
+    with jax.named_scope("gdn_kernel"):
+        dq, dk, dv, dg, dbeta, dS0 = _kernels().gdn_backward(_wide(q), _wide(k), _wide(v), g, beta, starts, _wide(do), d_end)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), dg, dbeta, dS0
+
+
+_gdn_kernels.defvjp(_gdn_kernels_fwd, _gdn_kernels_bwd)
 
 
 def gdn_chunked(
@@ -465,26 +520,36 @@ def gdn_chunked(
     [B, S, Hv] float32 (<= 0) and beta [B, S, Hv]. Returns (o [B, S, Hv, dv],
     the final state [B, Hv, dk, dv] float32); any S, as :func:`kda_chunked`.
 
-    :func:`_chunked` in ``jax.numpy`` at every width and every decay, the
-    pairs of a chunk by :func:`_scalar_lower`. The kernels are not asked:
-    handed this decay broadcast over a head's lanes they compute the same
-    numbers, but only for calls that decay under :data:`_TWO_FACTOR_NATS`
-    inside 16 positions (:func:`_kernel_serves`) — and a head of the published
-    class's initial values (``A`` up to 16, ``dt_bias`` 1) forgets ~20 nats a
-    POSITION, so every call of such a model would compile both forms and run
-    the per-channel exact one, sixteen exponentials a pair for a decay that
-    needs one (PERF.md §6, PR 54)."""
+    Two forms, picked by what the call's inputs are (:func:`gdn_core`), no field
+    and no switch. Heads 128 wide in chunks of 64 on one device — the model on
+    a chip — run the Pallas kernel pair ``gdn_forward`` / ``gdn_backward`` under
+    one ``custom_vjp``, at EVERY decay: a pair is ``(rows · kᵀ) ⊙ exp(the sum of
+    g between them)``, one product and one factor whose exponent is <= 0, so
+    there is nothing to overflow, no condition on the call's ``g`` and no exact
+    path beside it (the per-channel kernels, handed this decay broadcast over a
+    head's lanes, serve under 80 nats in 16 positions only, and the published
+    initial values forget ~20 nats a POSITION: PERF.md §6, PR 54). q and k are
+    read in place, a key head once for all its value heads; g, beta and their
+    gradients travel as one float32 a head a position. Every other call —
+    a head width that is no lane tile (the CPU rehearsals), another chunk,
+    mixed dtypes, a program traced under a mesh of several devices — is
+    :func:`_chunked` in ``jax.numpy`` with the key heads repeated, the pairs of
+    a chunk by :func:`_scalar_lower`, its backward autodiff."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2:]
     assert hv % hk == 0, f"{hv} value heads do not divide over {hk} key heads"
-    q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
-    g = g.astype(jnp.float32)[..., None]
-    pad = -s % chunk
+    kernels = gdn_core(q, k, v, chunk) == "gdn_kernel"
+    g = g.astype(jnp.float32)
+    pad = -s % (_kernels().ROWS if kernels else chunk)
     if pad:
-        q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v))
+        g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (g, beta))
     S0 = jnp.zeros((b, hv, dk, dv), jnp.float32) if initial_state is None else initial_state.astype(jnp.float32)
-    o, S_end = _chunked(q, k, v, g, beta, S0, chunk)
+    if kernels:
+        o, S_end = _gdn_kernels(q, k, v, g, beta.astype(jnp.float32), S0)
+    else:
+        q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
+        o, S_end = _chunked(q, k, v, g[..., None], beta, S0, chunk)
     return o[:, :s], S_end
 
 

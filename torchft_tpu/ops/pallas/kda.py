@@ -1,5 +1,6 @@
-"""The chunked gated delta rule (KDA, ``ops/kda.py``) as pallas TPU kernels,
-fwd + bwd: a head's state and a chunk's products never leave VMEM.
+"""The chunked gated delta rule (``ops/kda.py``: KDA's per-channel decay, and
+Gated DeltaNet's one decay a head) as pallas TPU kernels, fwd + bwd: a head's
+state and a chunk's products never leave VMEM.
 
 ``ops/kda.kda_chunked`` in ``jax.numpy`` writes every intermediate of a chunk
 — the running decay sums, three ``exp`` factors, the decayed products, the
@@ -56,10 +57,40 @@ heads' fill it (one head a step took 2.57 ms a block of 1024 positions
 forward where four take 1.64 and eight 1.57, host dispatch included; PERF.md
 §6, PR 36).
 
-On the chip: ``chip_smoke.py`` ``kda_cells`` holds the kernels, forward and
-gradients, to the recurrence and fails without their Mosaic call in the
-lowered text; ``tests/test_flash_attention.py`` compiles them for a described
-v5e at the cell's shapes; ``tests/test_kda.py`` runs them interpreted.
+ONE decay a head a position (Gated DeltaNet, ``ops/kda.gdn_chunked``) has a
+kernel pair of its own at the end of this file, ``gdn_forward`` /
+``gdn_backward``: the same frame — the grid, two chunks a step, the heads
+stage by stage, the block inverse, ``u``, ``o``, the state's update, the start
+states as the one residual, the precision — with what the scalar decay changes:
+
+* the pairs are ``A[i, j] = (rows_i · k_j) exp(sum of g over j < t <= i)``:
+  one [2·128, 128] x [128, 128] product a KEY head (``[q; k] kᵀ``, shared by
+  its value heads) and one ``exp`` of a [128, 128] block a value head, every
+  exponent <= 0 — no sub-blocks, no reference rows, no clamp, and so no
+  condition on a call's decay: the kernels serve 56 nats a position as they
+  serve 0.1;
+* every sum of g is taken over the positions it spans, as a product with a 0/1
+  mask on the MXU in three exact bfloat16 pieces: between a pair (``tril ·
+  where(t > j, g_t, 0)``: two chunks' sums the diagonal blocks of one
+  [128, 128] matrix), from a chunk's start to a row (``G``) and from a row to
+  its chunk's end (what a key leaves in the state there; the same terms as the
+  pair matrix's last row, taken as a product of their own because the kernel
+  needs them down a column) — never the difference of two running sums, whose
+  float32 step at thousands of nats would be a pair's relative error;
+* g, beta and their gradients travel as ``[B, Hv/hb, S, hb]`` float32, 4 bytes
+  a head a position; a decay scales a row, so ``(q e^G) S`` is ``e^G (q S)``
+  and q enters its products as it was read;
+* q and k blocks are the key heads of a step's value heads (the index map
+  sends value head j to key head ``j // (Hv/Hk)``); ``dq`` and ``dk`` are
+  summed over a key head's value heads inside the step — the pairs' part as
+  four products a KEY head of the summed ``dA ⊙ e^between`` — and, where a
+  group spans several steps, once more outside.
+
+On the chip: ``chip_smoke.py`` ``kda_cells`` and ``gdn_cells`` hold the
+kernels, forward and gradients, to the recurrence and fail without their
+Mosaic call in the lowered text; ``tests/test_flash_attention.py`` compiles
+them for a described v5e at the cells' shapes; ``tests/test_kda.py`` and
+``tests/test_gdn.py`` run them interpreted.
 """
 
 from __future__ import annotations
@@ -71,7 +102,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["kda_forward", "kda_backward", "serves", "CHUNK", "ROWS"]
+__all__ = ["kda_forward", "kda_backward", "gdn_forward", "gdn_backward", "serves", "CHUNK", "ROWS"]
 
 CHUNK = 64  # positions of a chunk: the only one the kernels are written for
 ROWS = 2 * CHUNK  # positions a grid step takes: two chunks, the diagonal blocks of its [128, 128] matrices
@@ -130,19 +161,22 @@ def _square():
     return i, j, i // CHUNK == j // CHUNK
 
 
+def _exact_sum(mask, x, exact, dims=_NN):
+    """``mask · x`` (``maskᵀ · x`` under ``_TN``) for a float32 x, exactly:
+    ones are exact in bfloat16, and three bfloat16 pieces are all of a float32."""
+    if exact:
+        return _dot(mask.astype(_F32), x, dims, True)
+    ones = mask.astype(_BF16)
+    head, tail = _split(x, False)
+    rest = (x - head.astype(_F32) - tail.astype(_F32)).astype(_BF16)
+    return _dot(ones, head, dims, False) + (_dot(ones, tail, dims, False) + _dot(ones, rest, dims, False))
+
+
 def _running_sum(g, exact, transposed=False):
     """``G = tril · g`` over each chunk's rows (``trilᵀ · dG`` for the
-    gradient), exactly — the exponents of everything after: ones are exact in
-    bfloat16, and three bfloat16 pieces are all of a float32."""
+    gradient), exactly — the exponents of everything after."""
     i, j, same = _square()
-    tril = same & (j <= i)
-    dims = _TN if transposed else _NN
-    if exact:
-        return _dot(tril.astype(_F32), g, dims, True)
-    ones = tril.astype(_BF16)
-    head, tail = _split(g, False)
-    rest = (g - head.astype(_F32) - tail.astype(_F32)).astype(_BF16)
-    return _dot(ones, head, dims, False) + (_dot(ones, tail, dims, False) + _dot(ones, rest, dims, False))
+    return _exact_sum(same & (j <= i), g, exact, _TN if transposed else _NN)
 
 
 def _column(block, j):
@@ -504,3 +538,296 @@ def kda_backward(q, k, v, g, beta, starts, do, d_end, interpret=None):
         name="kda_bwd",
     )(q, k, v, g, _beta_blocks(beta, hb), starts, do, d_end)
     return dq, dk, dv, dg, jnp.moveaxis(dbeta, 1, 2).reshape(b, s, h), ds0
+
+
+# ---------------------------------------------------------------------------
+# ONE log-decay a head a position (Gated DeltaNet, ``ops/kda.gdn_chunked``):
+# the same frame, the pairs as (rows · kᵀ) ⊙ exp(sums between)
+# ---------------------------------------------------------------------------
+
+
+def _lanes(columns):
+    """[ROWS, 1] columns side by side, head j's in lane j of a [ROWS, 128] matrix."""
+    lane = _iota((ROWS, _LANES), 1)
+    out = jnp.zeros((ROWS, _LANES), _F32)
+    for j, column in enumerate(columns):
+        out = out + jnp.where(lane == j, column, 0.0)
+    return out
+
+
+def _total(x):
+    """The sum of a matrix, [1, 1]."""
+    return jnp.sum(jnp.sum(x, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _rows_sum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _masks():
+    """Of a [ROWS, ROWS] matrix of two chunks: row, and column j of row i's
+    chunk with j <= i, with j < i, with j > i."""
+    i, j, same = _square()
+    return _iota((ROWS, 1), 0), same & (j <= i), same & (j < i), same & (j > i)
+
+
+def _dot_pieces(pieces, b, dims, exact):
+    """A :func:`_split` float32 operand against one that is exact as it
+    stands (the inputs' q and k): to 2^-16, a product a piece."""
+    out = _dot(pieces[0], b, dims, exact)
+    for piece in pieces[1:]:
+        out = out + _dot(piece, b, dims, exact)
+    return out
+
+
+def _gdn_chunks(q_ref, k_ref, v_ref, g_block, beta_block, hb, kb, exact):
+    """What two chunks' forward and backward share, from their inputs alone,
+    for the ``hb`` value heads of a grid step over its ``kb`` key heads, stage
+    by stage. g, beta [ROWS, hb]: ONE log-decay and one write strength a head
+    a position. Every sum of g is taken over the positions it spans — the
+    pair's own (``between``), a chunk's start to a position (``G``), a
+    position to its chunk's end (``T``) — as a masked triangular product,
+    never as a difference of two running sums."""
+    dt = v_ref.dtype
+    _, lower, strict, later = _masks()
+    gs = [_column(g_block, h) for h in range(hb)]
+    betas = [_column(beta_block, h) for h in range(hb)]
+    g_lanes = _lanes(gs)
+    G_all = _exact_sum(lower, g_lanes, exact)  # every head's sums in two products
+    T_all = _exact_sum(later, g_lanes, exact)
+    qs, ks, vs = _heads(q_ref, kb, dt), _heads(k_ref, kb, dt), _heads(v_ref, hb)
+    # q kᵀ and k kᵀ before the decay: one product a KEY head for all its value heads
+    products = [_dot(jnp.concatenate([q, k], axis=0), k, _NT, exact) for q, k in zip(qs, ks)]  # [2·ROWS, ROWS]
+    group = hb // kb
+    betweens = [_exact_sum(lower, jnp.where(strict, g, 0.0), exact) for g in gs]
+    decayed = [jnp.exp(between) for between in betweens]  # exponents <= 0: nothing overflows whatever the decay
+    a_qk = [jnp.where(lower, products[h // group][:ROWS] * e, 0.0) for h, e in enumerate(decayed)]
+    a_kk = [jnp.where(strict, products[h // group][ROWS:] * e, 0.0) for h, e in enumerate(decayed)]
+    invs = _unit_lower_inverses([beta * a for beta, a in zip(betas, a_kk)], exact)
+    out = []
+    for h in range(hb):
+        k = ks[h // group].astype(_F32)
+        G, T = _column(G_all, h), _column(T_all, h)
+        e_in, e_out = jnp.exp(G), jnp.exp(T)
+        k_in, inv_d = k * e_in, invs[h].astype(dt)
+        out.append(dict(
+            beta=betas[h], v=vs[h], a_qk=a_qk[h], a_kk=a_kk[h], decayed=decayed[h], inv=inv_d, e_in=e_in, e_out=e_out,
+            decay=[jnp.exp(G[CHUNK - 1 : CHUNK, :]), jnp.exp(G[ROWS - 1 : ROWS, :])], k_in=k_in, k_out=k * e_out,
+            # [w_v | w_k] = M^-1 [beta v | beta k e^G]
+            w_v=_dot(inv_d, (betas[h] * vs[h]).astype(dt), _NN, exact),
+            w_k=_dot(inv_d, (betas[h] * k_in).astype(dt), _NN, exact).astype(dt),
+        ))
+    return out, qs, ks
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_ref, end_ref, state, *, hb, kb, exact):
+    n = pl.program_id(2)
+    dt = v_ref.dtype
+
+    @pl.when(n == 0)
+    def _init():
+        for h in range(hb):
+            state[h] = s0_ref[h].T
+
+    xs, qs, _ = _gdn_chunks(q_ref, k_ref, v_ref, g_ref[...], beta_ref[...], hb, kb, exact)
+    group = hb // kb
+    states = [state[h] for h in range(hb)]
+    starts, us, reads = [], [], []
+    for at, half in enumerate(_HALVES):
+        starts.append(states)
+        sd = [st.astype(dt) for st in states]
+        us.append([x["w_v"][half] - _dot(x["w_k"][half], s, _NT, exact) for x, s in zip(xs, sd)])
+        reads.append([_dot(qs[h // group][half], s, _NT, exact) for h, s in enumerate(sd)])
+        states = [
+            st * x["decay"][at] + _dot(u.astype(dt), x["k_out"][half].astype(dt), _TN, exact)
+            for st, x, u in zip(states, xs, us[-1])
+        ]
+    for h, x in enumerate(xs):
+        u = jnp.concatenate([us[0][h], us[1][h]], axis=0).astype(dt)
+        # (q e^G) S = e^G (q S): one decay a row scales the product, not its operand
+        o = x["e_in"] * jnp.concatenate([reads[0][h], reads[1][h]], axis=0) + _dot(x["a_qk"].astype(dt), u, _NN, exact)
+        o_ref[:, h * _LANES : (h + 1) * _LANES] = o.astype(o_ref.dtype)
+        starts_ref[h, 0], starts_ref[h, 1] = starts[0][h], starts[1][h]
+        state[h] = states[h]
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _finish():
+        for h in range(hb):
+            end_ref[h] = state[h].T
+
+
+def _gdn_bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, dend_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds0_ref, dstate, *, hb, kb, exact,
+):
+    n = pl.program_id(2)
+    dt = v_ref.dtype
+
+    @pl.when(n == 0)
+    def _init():
+        for h in range(hb):
+            dstate[h] = dend_ref[h].T
+
+    heads, group = range(hb), hb // kb
+    xs, qs, ks = _gdn_chunks(q_ref, k_ref, v_ref, g_ref[...], beta_ref[...], hb, kb, exact)
+    row, lower, strict, later = _masks()
+    dos = _heads(do_ref, hb)
+    dods = [do.astype(dt) for do in dos]
+    do_ins = [(x["e_in"] * do).astype(dt) for x, do in zip(xs, dos)]  # o = e^G (q S) + A_qk u
+    sts = [[starts_ref[h, at].astype(dt) for at in (0, 1)] for h in heads]  # [dv, dk], as the products take them
+
+    # u of both chunks from their saved start states; A_qkᵀ do of both at once
+    us = [
+        jnp.concatenate([x["w_v"][half] - _dot(x["w_k"][half], st[at], _NT, exact)
+                         for at, half in enumerate(_HALVES)], axis=0)
+        for x, st in zip(xs, sts)
+    ]
+    from_o = [_dot(x["a_qk"].astype(dt), do, _TN, exact) for x, do in zip(xs, dods)]
+    # the second chunk, then the first: S' = decay S + k_outᵀ u;  u = w_v - w_k S
+    ds = [dstate[h] for h in heads]
+    dus, dk_outs, dg_ends = [[None, None] for _ in heads], [[None, None] for _ in heads], [[None, None] for _ in heads]
+    for at in (1, 0):
+        half = _HALVES[at]
+        for h, x in enumerate(xs):
+            dsd = ds[h].astype(dt)
+            du = from_o[h][half] + _dot(x["k_out"][half].astype(dt), dsd, _NT, exact)
+            dus[h][at] = du
+            dk_outs[h][at] = _dot(us[h][half].astype(dt), dsd, _NN, exact)
+            dg_ends[h][at] = _total(ds[h] * starts_ref[h, at]) * x["decay"][at]
+            ds[h] = (
+                _dot(do_ins[h][half], qs[h // group][half], _TN, exact) + ds[h] * x["decay"][at]
+                - _dot(du.astype(dt), x["w_k"][half], _TN, exact)
+            )
+    zeros = lambda: [jnp.zeros((ROWS, _LANES), _F32) for _ in range(kb)]  # noqa: E731
+    dq_sums, dk_sums, d_qk, d_kk = zeros(), zeros(), zeros(), zeros()  # a key head's, over its value heads
+    dGs, dTs, d_betweens, dbetas = [], [], [], []
+    for h, x in enumerate(xs):
+        key = h // group
+        beta, k_in, k_out = x["beta"], x["k_in"], x["k_out"]
+        du = jnp.concatenate(dus[h], axis=0).astype(dt)
+        dk_out = jnp.concatenate(dk_outs[h], axis=0)
+        da_qk = jnp.where(lower, _dot(dods[h], us[h].astype(dt), _NT, exact), 0.0)
+        # e^G (do S): q's gradient through the state's read, as it is
+        dq_in = jnp.concatenate([_dot(do_ins[h][half], sts[h][at], _NN, exact) for at, half in enumerate(_HALVES)], axis=0)
+        dw_k = -jnp.concatenate([_dot(du[half], sts[h][at], _NN, exact) for at, half in enumerate(_HALVES)], axis=0)
+        dr_v = _dot(x["inv"], du, _TN, exact)
+        dr_k = _dot(x["inv"], dw_k.astype(dt), _TN, exact)
+        # M = I + diag(beta) A_kk:  dM = -M^-T dM^-1 M^-T = -(dr_v w_vᵀ + dr_k w_kᵀ)
+        dm = -(
+            _dot(dr_v.astype(dt), x["w_v"].astype(dt), _NT, exact)
+            + _dot(dr_k.astype(dt), x["w_k"], _NT, exact)
+        )
+        da_kk = jnp.where(strict, beta * dm, 0.0)
+        dk_in = beta * dr_k
+        dbetas.append(_rows_sum(dm * x["a_kk"]) + _rows_sum(dr_v * x["v"] + dr_k * k_in))
+        # A = P ⊙ e^between: the pair's sum gets dA ⊙ A, the product dA ⊙ e^between
+        between = _exact_sum(lower, da_qk * x["a_qk"] + da_kk * x["a_kk"], exact, _TN)
+        d_betweens.append(_rows_sum(jnp.where(strict, between, 0.0)))
+        dG = _rows_sum(dq_in * qs[key].astype(_F32) + dk_in * k_in)
+        for at, half in enumerate(_HALVES):  # the chunk's last row also sets its decay
+            dG = dG + jnp.where(row == half.stop - 1, dg_ends[h][at], 0.0)
+        dGs.append(dG)
+        dTs.append(_rows_sum(dk_out * k_out))
+        d_qk[key] = d_qk[key] + da_qk * x["decayed"]
+        d_kk[key] = d_kk[key] + da_kk * x["decayed"]
+        dq_sums[key] = dq_sums[key] + dq_in
+        dk_sums[key] = dk_sums[key] + dk_in * x["e_in"] + dk_out * x["e_out"]
+        dv_ref[:, h * _LANES : (h + 1) * _LANES] = (beta * dr_v).astype(dv_ref.dtype)
+        dstate[h] = ds[h]
+    dg = _lanes(d_betweens) + _exact_sum(lower, _lanes(dGs), exact, _TN) + _exact_sum(later, _lanes(dTs), exact, _TN)
+    dg_ref[...] = dg[:, :hb]
+    dbeta_ref[...] = _lanes(dbetas)[:, :hb]
+    for key in range(kb):  # P = [q; k] kᵀ, one product a key head: so its gradient's four
+        q, k = qs[key], ks[key]
+        d_q, d_k = _split(d_qk[key], exact), _split(d_kk[key], exact)
+        lanes = slice(key * _LANES, (key + 1) * _LANES)
+        dq_ref[:, lanes] = (dq_sums[key] + _dot_pieces(d_q, k, _NN, exact)).astype(dq_ref.dtype)
+        dk_ref[:, lanes] = (
+            dk_sums[key] + _dot_pieces(d_q, q, _TN, exact) + (_dot_pieces(d_k, k, _NN, exact) + _dot_pieces(d_k, k, _TN, exact))
+        ).astype(dk_ref.dtype)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _finish():
+        for h in heads:
+            ds0_ref[h] = dstate[h].T
+
+
+def _gdn_heads(hv: int, hk: int):
+    """(value heads, key heads) a grid step holds: the most value heads that
+    are whole groups of a key head, or a part of one group."""
+    group = hv // hk
+    hb = next(m for m in _HEADS if hv % m == 0 and (m % group == 0 or group % m == 0))
+    return hb, max(1, hb // group)
+
+
+def _gdn_specs(hv, hk, at):
+    hb, kb = _gdn_heads(hv, hk)
+    wide, small, state, starts = _specs(hb, at)
+    # value head j reads key head j // (hv / hk): a step's first value head names its key heads' block
+    keys = pl.BlockSpec(
+        (None, ROWS, _LANES * kb), lambda b, h, n: (b, at(n), h * hb * hk // hv // kb), memory_space=pltpu.VMEM
+    )
+    return hb, kb, keys, wide, small, state, starts
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def gdn_forward(q, k, v, g, beta, initial_state, interpret=None):
+    """q, k [B, S, Hk·128] and v [B, S, Hv·128] in one dtype, g and beta
+    [B, S, Hv] float32, the state [B, Hv, 128, 128] float32; S whole pairs of
+    chunks (:data:`ROWS`), Hv a multiple of Hk. Returns what
+    :func:`kda_forward` does, a state a VALUE head."""
+    b, s, _ = v.shape
+    hv, hk = beta.shape[-1], q.shape[-1] // _LANES
+    hb, kb, keys, wide, small, state, starts = _gdn_specs(hv, hk, lambda n: n)
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, hb=hb, kb=kb, exact=q.dtype == jnp.float32),
+        grid=(b, hv // hb, s // ROWS),
+        in_specs=[keys, keys, wide, small, small, state],
+        out_specs=[wide, starts, state],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, hv, s // CHUNK, _LANES, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, hv, _LANES, _LANES), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((hb, _LANES, _LANES), jnp.float32)],
+        compiler_params=_PARAMS,
+        interpret=_should_interpret() if interpret is None else interpret,
+        name="gdn_fwd",
+    )(q, k, v, _beta_blocks(g, hb), _beta_blocks(beta, hb), initial_state)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def gdn_backward(q, k, v, g, beta, starts, do, d_end, interpret=None):
+    """The gradients of q, k ([B, S, Hk·128]: summed over a key head's value
+    heads), v, g, beta ([B, S, Hv] float32) and the initial state."""
+    b, s, _ = v.shape
+    hv, hk = beta.shape[-1], q.shape[-1] // _LANES
+    n = s // ROWS
+    hb, kb, keys, wide, small, state, starts_spec = _gdn_specs(hv, hk, lambda i: n - 1 - i)
+    # a step writes its key heads' gradients over ITS value heads; where a key
+    # head's group spans several steps they are summed after, in float32
+    parts = hv // hb * kb // hk
+    per_step = jax.ShapeDtypeStruct((b, s, hv // hb * kb * _LANES), q.dtype if parts == 1 else jnp.float32)
+    dq, dk, dv, dg, dbeta, ds0 = pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, hb=hb, kb=kb, exact=q.dtype == jnp.float32),
+        grid=(b, hv // hb, n),
+        in_specs=[keys, keys, wide, small, small, starts_spec, wide, state],
+        out_specs=[
+            pl.BlockSpec((None, ROWS, _LANES * kb), lambda b, h, i: (b, n - 1 - i, h), memory_space=pltpu.VMEM),
+        ] * 2 + [wide, small, small, state],
+        out_shape=[
+            per_step, per_step,
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, hv // hb, s, hb), jnp.float32),
+            jax.ShapeDtypeStruct((b, hv // hb, s, hb), jnp.float32),
+            jax.ShapeDtypeStruct((b, hv, _LANES, _LANES), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((hb, _LANES, _LANES), jnp.float32)],
+        compiler_params=_PARAMS,
+        interpret=_should_interpret() if interpret is None else interpret,
+        name="gdn_bwd",
+    )(q, k, v, _beta_blocks(g, hb), _beta_blocks(beta, hb), starts, do, d_end)
+    if parts > 1:
+        dq, dk = (x.reshape(b, s, hk, parts, _LANES).sum(axis=3).reshape(q.shape).astype(q.dtype) for x in (dq, dk))
+    unblock = lambda x: jnp.moveaxis(x, 1, 2).reshape(b, s, hv)  # noqa: E731
+    return dq, dk, dv, unblock(dg), unblock(dbeta), ds0

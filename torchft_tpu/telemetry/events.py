@@ -77,6 +77,7 @@ CANONICAL_EVENTS = (
     "diagnosis_captured",
     "attention_path",
     "layer_pattern",
+    "gdn_core_path",
 )
 
 # The protocol-lifecycle subset of the vocabulary: the events the
