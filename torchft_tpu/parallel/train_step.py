@@ -13,6 +13,7 @@ Two drive modes:
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, Tuple
 
 import jax
@@ -25,12 +26,16 @@ from torchft_tpu.models.transformer import (
     loss_and_stats,
     param_specs,
 )
+from torchft_tpu.telemetry import builds
 
 __all__ = ["TrainStep"]
 
 
 class TrainStep:
     def __init__(self, cfg: TransformerConfig, tx, mesh) -> None:
+        # before the first tft_* program is traced: its build.* spans and
+        # the totals of tft.build.counters (telemetry/builds.py)
+        builds.install()
         self.cfg = cfg
         self.tx = tx
         self.mesh = mesh
@@ -110,6 +115,9 @@ class TrainStep:
             return loss, new_params, opt_state, stats
 
         self._fused = jax.jit(tft_fused, donate_argnums=(0, 1))
+        # programs whose first call (the build as a caller feels it) is
+        # still to come
+        self._uncalled = {"fused", "grads", "apply"}
 
     # -- state --
 
@@ -154,48 +162,47 @@ class TrainStep:
 
     # -- drive --
 
-    @staticmethod
-    def _record_compute(t0: float) -> None:
+    def _record_compute(self, t0: float, program: str) -> None:
         # step-anatomy `compute` phase: main-thread time inside the jitted
         # calls (dispatch + any blocking; with async dispatch the device
         # tail lands in whoever blocks next — usually the host copy, which
-        # the ledger attributes to host_copy/wire). Best-effort.
-        import time as _time
-
+        # the ledger attributes to host_copy/wire). Best-effort. A
+        # program's first call is its build: `first_call_s` of
+        # tft.build.counters.
         try:
+            seconds = time.perf_counter() - t0
+            if program in self._uncalled:
+                self._uncalled.discard(program)
+                builds.first_call(seconds)
             from torchft_tpu.telemetry.anatomy import LEDGER
 
-            LEDGER.record("compute", _time.perf_counter() - t0)
+            LEDGER.record("compute", seconds)
         except Exception:  # noqa: BLE001 — observability never fails a step
             pass
 
     def step(self, params, opt_state, tokens) -> Tuple[jnp.ndarray, Any, Any]:
         """Fused grads+update (single replica group / no FT averaging)."""
-        import time as _time
-
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         with jax.set_mesh(self.mesh):
             *out, self.last_stats = self._fused(params, opt_state, tokens)
-        self._record_compute(t0)
+        self._record_compute(t0, "fused")
+        builds.annotate_counters()
         return tuple(out)
 
     def grads(self, params, tokens) -> Tuple[jnp.ndarray, Any]:
         """Loss + gradient pytree (still on device)."""
-        import time as _time
-
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         with jax.set_mesh(self.mesh):
             *out, self.last_stats = self._value_and_grad(params, tokens)
-        self._record_compute(t0)
+        self._record_compute(t0, "grads")
+        builds.annotate_counters()
         return tuple(out)
 
     def apply(self, params, opt_state, grads) -> Tuple[Any, Any]:
         """Apply (possibly host-averaged) grads; ``params`` and
         ``opt_state`` are donated."""
-        import time as _time
-
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         with jax.set_mesh(self.mesh):
             out = self._apply(params, opt_state, grads)
-        self._record_compute(t0)
+        self._record_compute(t0, "apply")
         return out

@@ -29,6 +29,11 @@ clock the device plane shares. The hot inner loop — per bucket, on the
 collectives op thread — uses :func:`annotate`, the same annotation with no
 ring entry. With no session open an annotation costs under a microsecond.
 
+Program builds are spans too: ``telemetry/builds.py`` registers listeners
+with ``jax.monitoring`` (the hook JAX itself offers) that open
+``build.trace`` / ``build.lower`` / ``build.compile`` here, on the thread
+that builds, around each outermost stage JAX reports.
+
 Design constraints match the rest of the package: stdlib-only at import
 (the annotation class is taken from an already-imported ``jax``, and is
 skipped when there is none), exception-free on the hot path (a tracing bug
@@ -53,6 +58,7 @@ __all__ = [
     "Tracer",
     "TRACER",
     "annotate",
+    "session_open",
     "chrome_trace",
     "ENV_TRACE_PATH",
     "TRACE_PREFIX",
@@ -65,6 +71,8 @@ ENV_TRACE_RING = "TORCHFT_TRACE_RING"
 TRACE_PREFIX = "tft."
 
 _NO_SPAN = contextlib.nullcontext()
+# jax.profiler.TraceAnnotation.is_enabled, once jax is imported
+_is_enabled = None
 
 
 def _profiler_annotation(name: str, step_num: Optional[int], stats: Dict[str, Any]):
@@ -85,11 +93,26 @@ def _profiler_annotation(name: str, step_num: Optional[int], stats: Dict[str, An
     return profiler.TraceAnnotation(TRACE_PREFIX + name, **scalars)
 
 
+def session_open() -> bool:
+    """Whether a profiler session would record an annotation now; False
+    when JAX was never imported. What :func:`annotate` asks first, so that a
+    caller with many stats can ask before it gathers them."""
+    global _is_enabled
+    if _is_enabled is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return False
+        _is_enabled = profiler.TraceAnnotation.is_enabled
+    return _is_enabled()
+
+
 def annotate(name: str, **stats: Any) -> ContextManager[Any]:
     """The light span of the hot inner loop: ``tft.<name>`` in the
     profiler's trace with ``stats`` as the event's stats, and nothing else
     — no ring entry, no piggyback entry, no lock, no counter. A no-op when
-    JAX was never imported."""
+    JAX was never imported, and while no profiler session is open."""
+    if not session_open():
+        return _NO_SPAN
     return _profiler_annotation(name, None, stats) or _NO_SPAN
 
 
@@ -229,6 +252,17 @@ class _SpanCtx:
         except Exception:  # noqa: BLE001 — tracing must never fail a step
             self._ann = None
         return self.span
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes learned while the span is open (a build's ``cache``):
+        on the span, and on its ``tft.<name>`` event in a profiler trace,
+        which otherwise holds only what was known at entry."""
+        self.span.attrs.update(attrs)
+        if self._ann is not None:
+            try:
+                self._ann.set_metadata(**attrs)
+            except Exception:  # noqa: BLE001 — tracing must never fail a step
+                pass
 
     def __exit__(self, exc_type, exc, tb) -> None:
         if self._ann is not None:

@@ -5,6 +5,11 @@ A respawned replica group recompiles exactly what its predecessor
 compiled, so a cold compile is part of rejoin time unless the cache
 persists and stays put: the directory is part of the cache key, so a
 directory that moves (``tempfile``, a pid, the clock) never hits.
+
+Whether a build hit it is seen from inside: ``telemetry/builds.py`` listens
+on ``jax.monitoring`` and puts every trace, lowering and compile-or-load on
+the Tracer as a ``build.*`` span (``cache`` = ``hit`` / ``miss`` / ``off``)
+and in the totals ``tft.build.counters`` carries.
 """
 
 from __future__ import annotations
@@ -38,6 +43,11 @@ def place_compile_cache() -> str:
     second, which on a v5e left out ``apply`` (0.78 s at 647M parameters)
     and a dozen small ones a respawn compiles again."""
     jax = sys.modules.get("jax")
+    if jax is not None:
+        # a process that imports jax later gets them from TrainStep
+        from torchft_tpu.telemetry import builds
+
+        builds.install()
     if _MIN_SECS_ENV not in os.environ:
         os.environ[_MIN_SECS_ENV] = "0"
         if jax is not None:
