@@ -363,6 +363,117 @@ def test_the_gdn_mixer_and_its_gradients_against_the_reference(block, dtype, tol
     np.testing.assert_allclose(said["beta_mean"], jnp.mean(jax.nn.sigmoid(ba[..., :4])), rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
 
 
+def parents_mix_gdn(cfg, lp, h):
+    """``transformer._mix_gdn`` as 080a434 had it: q | k | v and ``h·w_z`` inside the checkpointed block."""
+    b, s, d = h.shape
+    heads, key_heads, hd, taps = cfg.linear_n_heads, cfg.linear_key_heads, cfg.linear_head_dim, cfg.conv_kernel
+    kch, vch = key_heads * hd, heads * hd
+    f32 = jnp.float32
+    blk = T._KDA_BLOCK if s % T._KDA_BLOCK == 0 else s
+
+    def block(carry, hb):
+        state, before = carry
+        qkv = jnp.concatenate([hb @ lp["wq"], hb @ lp["wk"], hb @ lp["wv"]], axis=-1)
+        filters = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)
+        mixed = jax.nn.silu(T.short_conv(qkv, filters, before))
+        q = mixed[..., :kch].reshape(b, blk, key_heads, hd)
+        k = mixed[..., kch : 2 * kch].reshape(b, blk, key_heads, hd)
+        v = mixed[..., 2 * kch :].reshape(b, blk, heads, hd)
+        ba = jnp.dot(hb, lp["w_ba"], preferred_element_type=f32)
+        beta = jax.nn.sigmoid(ba[..., :heads])
+        g = -jnp.exp(lp["a_log"].astype(f32)) * jax.nn.softplus(ba[..., heads:] + lp["dt_bias"].astype(f32))
+        out_gate = jax.nn.silu(jnp.dot(hb, lp["w_z"], preferred_element_type=f32)).astype(hb.dtype).reshape(b, blk, heads, hd)
+        q = (T._unit_l2(q) * hd**-0.5).astype(v.dtype)
+        k = T._unit_l2(k).astype(v.dtype)
+        o, state = gdn_chunked(q, k, v, g, beta, initial_state=state)
+        o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * out_gate
+        return (state, qkv[:, blk - (taps - 1) :]), (o.reshape(b, blk, vch) @ lp["wo"], jnp.min(g), jnp.mean(beta))
+
+    start = (jnp.zeros((b, heads, hd, hd), f32), jnp.zeros((b, taps - 1, 2 * kch + vch), h.dtype))
+    blocks = jnp.moveaxis(h.reshape(b, s // blk, blk, d), 1, 0)
+    _, (out, decay_min, beta_mean) = jax.lax.scan(jax.checkpoint(block), start, blocks)
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, d), {"decay_min": jnp.min(decay_min), "beta_mean": jnp.mean(beta_mean)}
+
+
+# d 48 -> q, k 16 (2 key heads of 8), v and z 32 (4 value heads), [b | a] 8: each product's width its own
+MIXER = dict(SIZES["gdn.dense"], d_model=48, n_layers=1, gdn_layers=(1,))
+
+
+@pytest.mark.parametrize("seq", [32, 40], ids=["two-blocks", "not-a-multiple"])
+def test_the_gdn_mixer_with_its_projections_ahead_of_the_scan_is_the_parents(seq, monkeypatch):
+    """q, k, v and ``h·w_z`` taken once over the whole sequence and handed to
+    the blocks as ``xs`` are the numbers the parent's block computed for
+    itself: output, what the mixer says of its decays and write strengths, and
+    the gradient to the input and every leaf, at two blocks and at a length
+    that is one block because it is no multiple — to float32's rounding, as
+    ``tests/test_kda.py`` has it for ``_mix_kda``."""
+    from tests.test_kda import largest_difference, mixer_leaves, value_and_gradients
+
+    monkeypatch.setattr(T, "_KDA_BLOCK", 16)
+    cfg = TransformerConfig(dtype=jnp.float32, **MIXER)
+    lp = mixer_leaves(cfg)
+    h, probe = (jax.random.normal(jax.random.PRNGKey(i), (2, seq, 48)) for i in (5, 6))
+    (y, said), grads = value_and_gradients(lambda lp, h: T._mix_gdn(cfg, lp, h), lp, h, probe)
+    (y_parent, said_parent), grads_parent = value_and_gradients(lambda lp, h: parents_mix_gdn(cfg, lp, h), lp, h, probe)
+    assert set(grads[0]) >= {"wq", "wk", "wv", "w_z", "w_ba", "wo", "conv_q", "conv_k", "conv_v", "a_log", "dt_bias", "o_norm"}
+    assert largest_difference(y, y_parent) < 2e-6
+    assert largest_difference(grads, grads_parent) < 1e-5
+    assert largest_difference(said, said_parent) < 1e-6 and set(said) == {"decay_min", "beta_mean"}
+
+
+def projections_of_the_input(jaxpr, d, widths, in_scan=False, out=None):
+    """(inside a ``lax.scan``'s body or not, the output's shape) of every
+    ``dot_general`` anywhere in ``jaxpr`` that contracts an activation's ``d``
+    features into one of ``widths``: a projection of the layer's input, as
+    against a weight's gradient (which contracts positions), the input's
+    (which contracts the width) or a product of another rank."""
+    from tests.test_window_gqa import sub_jaxprs
+
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            lhs, rhs = (v.aval.shape for v in eqn.invars)
+            (contract_l, contract_r), _ = eqn.params["dimension_numbers"]
+            shape = eqn.outvars[0].aval.shape
+            if (
+                len(lhs) >= 3 and contract_l == (len(lhs) - 1,) and lhs[-1] == d and rhs == (d, shape[-1])
+                and contract_r == (0,) and shape[-1] in widths
+            ):
+                out.append((in_scan, shape))
+        for sub in sub_jaxprs(eqn):
+            projections_of_the_input(sub, d, widths, in_scan or eqn.primitive.name == "scan", out)
+    return out
+
+
+@pytest.mark.parametrize("mixer", ["kda", "gdn"])
+def test_the_projections_stand_once_at_the_full_sequence_and_in_neither_scan_body(mixer, monkeypatch):
+    """The traced value and gradient of a linear mixer at four blocks: each of
+    q, k, v (and Gated DeltaNet's z) is ONE product of the whole sequence, on
+    the block-major view the scan takes, and no such product stands in the
+    body of the forward scan or of the backward's (where the block's checkpoint
+    runs the block again): the backward no longer pays them a second time. The
+    parent's program, by the same reading, holds each twice and inside."""
+    from tests import test_kda
+
+    monkeypatch.setattr(T, "_KDA_BLOCK", 16)
+    if mixer == "kda":
+        cfg = TransformerConfig(dtype=jnp.float32, n_layers=1, kda_layers=(1,), **test_kda.MIXER)
+        mine, parents, widths = T._mix_kda, test_kda.parents_mix_kda, [32, 32, 32]
+    else:
+        cfg = TransformerConfig(dtype=jnp.float32, **MIXER)
+        mine, parents, widths = (lambda *a: T._mix_gdn(*a)[0]), (lambda *a: parents_mix_gdn(*a)[0]), [16, 16, 32, 32]
+    lp = test_kda.mixer_leaves(cfg)
+    h = jnp.zeros((2, 64, 48))
+    found = {
+        name: projections_of_the_input(
+            jax.make_jaxpr(jax.value_and_grad(lambda lp, h: jnp.sum(mix(cfg, lp, h)), argnums=(0, 1)))(lp, h).jaxpr, 48, set(widths)
+        )
+        for name, mix in (("mine", mine), ("parents", parents))
+    }
+    assert sorted(found["mine"]) == sorted((False, (4, 2, 16, w)) for w in widths)
+    assert sorted(found["parents"]) == sorted((True, (2, 16, w)) for w in widths * 2)
+
+
 def test_the_gdn_stack_is_causal_and_a_head_has_one_decay():
     cfg, params, tokens, _ = make("gdn.dense")
     hidden = jax.jit(lambda t: T._hidden_states(params, t, cfg)[0])

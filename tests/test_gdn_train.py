@@ -37,7 +37,16 @@ CELLS_PROGRAMS = {
     "olmo1b-1g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a", "1bcfe2dfb3ff35a0"),
     "olmo1b-4g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a", "1bcfe2dfb3ff35a0"),
     "olmoe-1g": ((8, 2048), "65b119828cd26a22a39bc945227fb3cef92f2b8ae09109a8c17c196e5a896d2d", "1bcfe2dfb3ff35a0"),
-    "kimi-linear-1g": ((2, 8192), "2ca37b1ff0102ed43cfd929ffe6dd7b7d2304c9d67b53a83574a19f3d6a6927d", "efeaeed97ccba4c3"),
+    # kimi-linear-1g: re-pinned by the PR that took ``_mix_kda``'s q | k | v out of the block's checkpoint (2ca37b1f…927d
+    # at 080a434 and before). Old text against new, primitive counts of the whole program with the kernels' bodies left
+    # out: ``dot_general`` 733 -> 721, a KDA layer's 3 projections gone from the forward scan's body, the same 3 from
+    # the backward scan's recomputation and their 3 + 3 gradients (to the input, to the weight) from the backward
+    # body — 12 a layer — for 3 + 3 + 3 at [8, 2, 1024, ·] outside the scans; the mixer scan's ``xs`` is (blocks, (q, k, v))
+    # where it was blocks, its carry's taps three arrays where they were one; the convolution and SiLU stand once for
+    # each of q, k, v where they stood once over the concatenation (``logistic`` 58 -> 74, ``pad`` 375 -> 435, the
+    # taps' ``mul`` / ``slice`` / ``add`` with them; the products' and the filters' ``concatenate`` gone). The kernels'
+    # equations did not move: ``tests/test_mla_rope_mtp_train.CELLS_KERNELS`` holds its digests, and the lines said too.
+    "kimi-linear-1g": ((2, 8192), "3d95496bb751b962215b3136713245972454c194746ae73f951c793c78d8f10a", "efeaeed97ccba4c3"),
     "laguna-xs2-1g": ((2, 8192), "bd50d408b7ed0e9d2876d862737ce952d20700046ef4b41ccb917b5f1854eeb0", "2e3b7f09d4732e39"),
     "joyai-flash-1g": ((2, 8192), "959938bef56e10a002f8ad665bf8fdd14794506432d5a5dfb20c9a6a6ca95bd7", "a6aa64069f77d113"),
     "lfm2-8b-a1b-1g": ((2, 8192), "48a3dee15b7c14d6a20b8d9381be5920893125a39c0a7c00c8932e3248c011b8", "647d94df3e9b6744"),

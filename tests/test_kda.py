@@ -1,6 +1,8 @@
 """``ops/kda.py`` — gated delta-rule linear attention in chunked form and the
 causal short convolution — against the recurrence position by position, and
-the flash kernel with keys wider than values, on the CPU (the kernels
+the flash kernel with keys wider than values, and the mixer that calls them
+(``transformer._mix_kda``) against the form it had with its input projections
+inside the block's checkpoint, on the CPU (the kernels
 interpreted). Heads 128 wide take the Pallas kernels of ``ops/pallas/kda.py``
 (the ``wide`` cases: one sequence of two heads, so that an interpreted call
 takes seconds), every other width the ``jax.numpy`` form. ``tests/test_hybrid.py`` holds the model that uses them to the
@@ -11,6 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from torchft_tpu.models import transformer as T
+from torchft_tpu.models.transformer import TransformerConfig, init_params
 from torchft_tpu.ops import kda
 from torchft_tpu.ops.attention import attention, chunked_attention
 
@@ -194,6 +198,90 @@ def test_the_short_convolution_is_causal_and_per_channel():
     assert float(jnp.max(jnp.abs(jnp.delete(bumped, 2, axis=2)))) == 0.0
     # block by block with the taps of history is the whole
     np.testing.assert_allclose(kda.short_conv(x[:, 5:], w, before=x[:, 2:5]), y[:, 5:], atol=1e-6)
+
+
+# -- the mixer: what is outside the block's checkpoint ---------------------------------------------
+
+# tiny widths, each product's its own: d 48 -> q, k, v 32 (4 heads of 8), the gates' rank 8, the write strength 4
+MIXER = dict(vocab_size=64, d_model=48, n_heads=2, head_dim=16, d_ff=64, norm_eps=1e-5, linear_head_dim=8, linear_n_heads=4, conv_kernel=4)
+
+
+def mixer_leaves(cfg, key=0):
+    """One linear-mixer layer's leaves at the program's initial values, the output norm's weight off 1."""
+    lp = jax.tree_util.tree_map(lambda a: a[0, 0], init_params(jax.random.PRNGKey(key), cfg)["layers"])
+    return dict(lp, o_norm=lp["o_norm"] + 0.3 * jnp.sin(jnp.arange(lp["o_norm"].size, dtype=jnp.float32)))
+
+
+def parents_mix_kda(cfg, lp, h):
+    """``transformer._mix_kda`` as 080a434 had it: the three projections inside the checkpointed block."""
+    b, s, d = h.shape
+    heads, hd, taps = cfg.linear_n_heads, cfg.linear_head_dim, cfg.conv_kernel
+    ch = heads * hd
+    f32 = jnp.float32
+    blk = T._KDA_BLOCK if s % T._KDA_BLOCK == 0 else s
+
+    def block(carry, hb):
+        state, before = carry
+        qkv = jnp.concatenate([hb @ lp["wq"], hb @ lp["wk"], hb @ lp["wv"]], axis=-1)
+        filters = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)
+        mixed = jax.nn.silu(kda.short_conv(qkv, filters, before))
+        q, k, v = (mixed[..., i * ch : (i + 1) * ch].reshape(b, blk, heads, hd) for i in range(3))
+        raw = jnp.dot(hb @ lp["w_fa"], lp["w_fb"], preferred_element_type=f32)
+        g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * jax.nn.softplus(raw + lp["dt_bias"].astype(f32)).reshape(b, blk, heads, hd)
+        beta = jax.nn.sigmoid(jnp.dot(hb, lp["w_beta"], preferred_element_type=f32))
+        out_gate = jax.nn.sigmoid(jnp.dot(hb @ lp["w_ga"], lp["w_gb"], preferred_element_type=f32)).astype(hb.dtype).reshape(b, blk, heads, hd)
+        q = (T._unit_l2(q) * hd**-0.5).astype(v.dtype)
+        k = T._unit_l2(k).astype(v.dtype)
+        o, state = kda.kda_chunked(q, k, v, g, beta, initial_state=state)
+        o = T.rms_norm(o, lp["o_norm"], cfg.norm_eps) * out_gate
+        return (state, qkv[:, blk - (taps - 1) :]), o.reshape(b, blk, ch) @ lp["wo"]
+
+    start = (jnp.zeros((b, heads, hd, hd), f32), jnp.zeros((b, taps - 1, 3 * ch), h.dtype))
+    blocks = jnp.moveaxis(h.reshape(b, s // blk, blk, d), 1, 0)
+    _, out = jax.lax.scan(jax.checkpoint(block), start, blocks)
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, d)
+
+
+def value_and_gradients(mix, lp, h, probe):
+    """``mix(lp, h)``'s (output, what it says beside it) and the gradients of
+    ``Σ y·probe`` to every leaf and to the input."""
+
+    def f(lp, h):
+        y, said = mix(lp, h)
+        return jnp.sum(y * probe), (y, said)
+
+    with jax.default_matmul_precision("highest"):
+        grads, out = jax.jit(jax.grad(f, argnums=(0, 1), has_aux=True))(lp, h)
+    return out, grads
+
+
+def largest_difference(got, want):
+    """Over the leaves of two like trees: max |a - b| over max |b|."""
+    return max(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30)), got, want
+    )))
+
+
+@pytest.mark.parametrize("seq", [32, 40], ids=["two-blocks", "not-a-multiple"])
+def test_the_mixer_with_its_projections_ahead_of_the_scan_is_the_parents(seq, monkeypatch):
+    """q | k | v taken once over the whole sequence and handed to the blocks as
+    ``xs`` are the numbers the parent's block computed for itself: the output
+    and the gradient to the input and to every leaf, at two blocks (state and
+    taps handed on) and at a length that is one block because it is no
+    multiple. What differs is float32 rounding: a product of all the rows
+    against a block's, and a weight's gradient summed over positions in one
+    contraction against a block's at a time — at one block not a bit, at two
+    5e-7 of the output's largest entry and 2e-6 of a gradient leaf's (``wq``,
+    behind the L2 norm and the core's inverse), measured here."""
+    monkeypatch.setattr(T, "_KDA_BLOCK", 16)
+    cfg = TransformerConfig(dtype=jnp.float32, n_layers=1, kda_layers=(1,), **MIXER)
+    lp = mixer_leaves(cfg)
+    h, probe = (jax.random.normal(jax.random.PRNGKey(i), (2, seq, 48)) for i in (5, 6))
+    (y, _), grads = value_and_gradients(lambda lp, h: (T._mix_kda(cfg, lp, h), None), lp, h, probe)
+    (y_parent, _), grads_parent = value_and_gradients(lambda lp, h: (parents_mix_kda(cfg, lp, h), None), lp, h, probe)
+    assert set(grads[0]) >= {"wq", "wk", "wv", "wo", "w_fa", "w_fb", "w_ga", "w_gb", "w_beta", "conv_q", "conv_k", "conv_v", "a_log", "dt_bias", "o_norm"}
+    assert largest_difference(y, y_parent) < 2e-6
+    assert largest_difference(grads, grads_parent) < 1e-5
 
 
 def test_the_flash_kernel_pads_keys_to_a_lane_tile_and_reads_heads_in_place():

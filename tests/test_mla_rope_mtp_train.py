@@ -38,7 +38,10 @@ CELLS_PROGRAMS = {
     # laguna-xs2-1g), one ``reduce_precision`` a call (``jax.checkpoint`` puts it on what a policy lets it keep), the
     # policy of every ``_remat`` checkpoint (None -> ``save_only_these_names``; ``_mix_kda``'s bare one stays None),
     # and ONE ``pallas_call`` a softmax layer gone: the ``flash_fwd`` of the recomputation. Nothing else.
-    "kimi-linear-1g": "2ca37b1ff0102ed43cfd929ffe6dd7b7d2304c9d67b53a83574a19f3d6a6927d",
+    # kimi-linear-1g since the PR that took ``_mix_kda``'s q | k | v out of the block's checkpoint (2ca37b1f…927d before
+    # it): the three products a KDA layer out of both scan bodies and once at the full sequence, the mixer scan's second
+    # ``xs`` — old text against new in ``tests/test_gdn_train.CELLS_PROGRAMS``' comment; CELLS_KERNELS below did not move.
+    "kimi-linear-1g": "3d95496bb751b962215b3136713245972454c194746ae73f951c793c78d8f10a",
     "laguna-xs2-1g": "bd50d408b7ed0e9d2876d862737ce952d20700046ef4b41ccb917b5f1854eeb0",
 }
 CELLS_KERNELS = {

@@ -1303,6 +1303,14 @@ def _unit_l2(x: jnp.ndarray) -> jnp.ndarray:
 # core (two dozen of them live in a block's backward) are then a block's and
 # not the sequence's — at b2 x s8192 x 32 x 128 the difference between 15.7 GB
 # of temporaries in the backward, which no chip holds, and 4.5 (PERF.md §6, PR 35).
+# The checkpoint's price is that a block's forward runs twice a step. What is
+# outside it, taken once for the whole sequence ahead of the scan and read by the
+# blocks as ``xs``: the full-rank products of the layer's input (q, k, v; Gated
+# DeltaNet's z) — a product of all the rows is the blocks' products, so the
+# backward does not run them a second time, for their outputs kept (bfloat16
+# 402 MB a layer at Kimi-Linear's widths; 268 MB and z's float32 268 at
+# Qwen3-Next's: PERF.md §6, PR 57). The convolution, the activations, the
+# low-rank gates, the norms and the core stay a block's.
 _KDA_BLOCK = 1024
 
 
@@ -1310,20 +1318,24 @@ def _mix_kda(cfg, lp, h):
     """Gated delta-rule linear attention (``ops/kda.py``): q, k, v through a
     causal short convolution and SiLU, q and k L2-normalised per head, a
     per-channel log-decay and a per-head write strength from the layer's
-    input, the output normalised per head and gated. No positions."""
+    input, the output normalised per head and gated. No positions. The three
+    input projections are taken once, for the whole sequence, AHEAD of the
+    block scan and outside its checkpoint (the note above ``_KDA_BLOCK``);
+    the scan reads them block by block beside the layer's input."""
     b, s, d = h.shape
     heads, hd, taps = cfg.linear_n_heads, cfg.linear_head_dim, cfg.conv_kernel
     ch = heads * hd
     f32 = jnp.float32
     blk = _KDA_BLOCK if s % _KDA_BLOCK == 0 else s
 
-    def block(carry, hb):
-        state, before = carry  # [B, H, D, D] float32; [B, K-1, 3·ch]: q | k | v ahead of the convolution
-        qkv = jnp.concatenate([hb @ lp["wq"], hb @ lp["wk"], hb @ lp["wv"]], axis=-1)
+    def block(carry, xs):
+        state, before = carry  # [B, H, D, D] float32; three of [B, K-1, ch]: q, k, v ahead of the convolution
+        hb, qkv = xs  # [B, blk, d]; three of [B, blk, ch]: h·wq, h·wk, h·wv of these positions
         with jax.named_scope("conv"):
-            filters = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)
-            mixed = jax.nn.silu(short_conv(qkv, filters, before))
-            q, k, v = (mixed[..., i * ch : (i + 1) * ch].reshape(b, blk, heads, hd) for i in range(3))
+            q, k, v = (
+                jax.nn.silu(short_conv(x, lp[w], x0)).reshape(b, blk, heads, hd)
+                for x, w, x0 in zip(qkv, ("conv_q", "conv_k", "conv_v"), before)
+            )
         with jax.named_scope("gates"):
             raw = jnp.dot(hb @ lp["w_fa"], lp["w_fb"], preferred_element_type=f32)
             g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * jax.nn.softplus(
@@ -1338,12 +1350,14 @@ def _mix_kda(cfg, lp, h):
         with jax.named_scope("kda_core"):
             o, state = kda_chunked(q, k, v, g, beta, initial_state=state)
         o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * out_gate
-        return (state, qkv[:, blk - (taps - 1) :]), o.reshape(b, blk, ch) @ lp["wo"]
+        return (state, tuple(x[:, blk - (taps - 1) :] for x in qkv)), o.reshape(b, blk, ch) @ lp["wo"]
 
     with jax.named_scope("kda"):
-        start = (jnp.zeros((b, heads, hd, hd), f32), jnp.zeros((b, taps - 1, 3 * ch), h.dtype))
+        start = (jnp.zeros((b, heads, hd, hd), f32), (jnp.zeros((b, taps - 1, ch), h.dtype),) * 3)
         blocks = jnp.moveaxis(h.reshape(b, s // blk, blk, d), 1, 0)
-        _, out = jax.lax.scan(jax.checkpoint(block), start, blocks)
+        # on the block-major view: the scan's ``xs`` ARE the arrays the backward keeps, with no second copy of them
+        qkv = tuple(blocks @ lp[w] for w in ("wq", "wk", "wv"))
+        _, out = jax.lax.scan(jax.checkpoint(block), start, (blocks, qkv))
         return jnp.moveaxis(out, 0, 1).reshape(b, s, d)
 
 
@@ -1354,43 +1368,49 @@ def _mix_gdn(cfg, lp, h):
     over ``linear_n_key_heads`` key heads (``ops/kda.gdn_chunked``), the output
     normalised per head under a plain weight and scaled by ``SiLU(h·w_z)``. No
     positions. Blocks of the sequence under a ``lax.scan`` that carries the
-    state and the convolution's taps, as :func:`_mix_kda`. Returns (y, by name:
-    ``decay_min`` the least log-decay of a position, ``beta_mean`` the mean
-    write strength, over the call)."""
+    state and the convolution's taps, as :func:`_mix_kda`, and as there the
+    full-rank products of the layer's input — q, k, v, and ``h·w_z`` in
+    float32 — are taken once ahead of the scan and outside the blocks'
+    checkpoint. Returns (y, by name: ``decay_min`` the least log-decay of a
+    position, ``beta_mean`` the mean write strength, over the call)."""
     b, s, d = h.shape
     heads, key_heads, hd, taps = cfg.linear_n_heads, cfg.linear_key_heads, cfg.linear_head_dim, cfg.conv_kernel
     kch, vch = key_heads * hd, heads * hd
     f32 = jnp.float32
     blk = _KDA_BLOCK if s % _KDA_BLOCK == 0 else s
 
-    def block(carry, hb):
-        state, before = carry  # [B, H, D, D] float32; [B, K-1, 2·kch + vch]: q | k | v ahead of the convolution
-        qkv = jnp.concatenate([hb @ lp["wq"], hb @ lp["wk"], hb @ lp["wv"]], axis=-1)
+    def block(carry, xs):
+        state, before = carry  # [B, H, D, D] float32; [B, K-1, kch] twice and [B, K-1, vch]: q, k, v ahead of the convolution
+        hb, qkv, z = xs  # [B, blk, d]; h·wq, h·wk [B, blk, kch] and h·wv [B, blk, vch]; h·w_z [B, blk, vch] float32
         with jax.named_scope("conv"):
-            filters = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)
-            mixed = jax.nn.silu(short_conv(qkv, filters, before))
-            q = mixed[..., :kch].reshape(b, blk, key_heads, hd)
-            k = mixed[..., kch : 2 * kch].reshape(b, blk, key_heads, hd)
-            v = mixed[..., 2 * kch :].reshape(b, blk, heads, hd)
+            q, k, v = (
+                jax.nn.silu(short_conv(x, lp[w], x0)).reshape(b, blk, -1, hd)
+                for x, w, x0 in zip(qkv, ("conv_q", "conv_k", "conv_v"), before)
+            )
         with jax.named_scope("gates"):
             ba = jnp.dot(hb, lp["w_ba"], preferred_element_type=f32)
             beta = jax.nn.sigmoid(ba[..., :heads])
             g = -jnp.exp(lp["a_log"].astype(f32)) * jax.nn.softplus(ba[..., heads:] + lp["dt_bias"].astype(f32))
-            out_gate = jax.nn.silu(
-                jnp.dot(hb, lp["w_z"], preferred_element_type=f32)
-            ).astype(hb.dtype).reshape(b, blk, heads, hd)
+            out_gate = jax.nn.silu(z).astype(hb.dtype).reshape(b, blk, heads, hd)
         q = (_unit_l2(q) * hd**-0.5).astype(v.dtype)
         k = _unit_l2(k).astype(v.dtype)
         _say_gdn_core_path(gdn_core(q, k, v), b, blk, cfg)
         with jax.named_scope("gdn_core"):
             o, state = gdn_chunked(q, k, v, g, beta, initial_state=state)
         o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * out_gate
-        return (state, qkv[:, blk - (taps - 1) :]), (o.reshape(b, blk, vch) @ lp["wo"], jnp.min(g), jnp.mean(beta))
+        before = tuple(x[:, blk - (taps - 1) :] for x in qkv)
+        return (state, before), (o.reshape(b, blk, vch) @ lp["wo"], jnp.min(g), jnp.mean(beta))
 
     with jax.named_scope("gdn"):
-        start = (jnp.zeros((b, heads, hd, hd), f32), jnp.zeros((b, taps - 1, 2 * kch + vch), h.dtype))
+        start = (
+            jnp.zeros((b, heads, hd, hd), f32),
+            tuple(jnp.zeros((b, taps - 1, c), h.dtype) for c in (kch, kch, vch)),
+        )
         blocks = jnp.moveaxis(h.reshape(b, s // blk, blk, d), 1, 0)
-        _, (out, decay_min, beta_mean) = jax.lax.scan(jax.checkpoint(block), start, blocks)
+        # on the block-major view, as in _mix_kda: what the scan takes is what the backward keeps
+        qkv = tuple(blocks @ lp[w] for w in ("wq", "wk", "wv"))
+        z = jnp.dot(blocks, lp["w_z"], preferred_element_type=f32)
+        _, (out, decay_min, beta_mean) = jax.lax.scan(jax.checkpoint(block), start, (blocks, qkv, z))
         stats = {"decay_min": jnp.min(decay_min), "beta_mean": jnp.mean(beta_mean)}
         return jnp.moveaxis(out, 0, 1).reshape(b, s, d), stats
 
