@@ -40,6 +40,15 @@ A multi-token-prediction module (``n_mtp_modules``) is a subtree
 layer's kind on ``[norm(Emb(t_{i+1})) ; norm(h_i)]·eh_proj``, its output
 through the main ``out`` table against the second-next token, its loss added
 at ``mtp_loss_weight`` (:func:`_mtp_hidden`).
+
+A LOOPED stack (``ut_steps`` = T > 1, a model of one kind on ``pp`` = 1) runs
+the same layers T times a step over the same leaves — a ``lax.scan`` over loop
+steps around the scan over layers, the final norm at the end of each turn and
+its output carried into the next; under ``exit_gate`` a learned gate a loop
+step says how much probability leaves there and the loss is the expectation
+of the cross entropy over the T exits less the exit distribution's entropy
+(:func:`_exit_loss`), the exits through one weighted pass of the head.
+``sandwich_norm`` (any kind of layer) norms a layer's two parts' outputs too.
 """
 
 from __future__ import annotations
@@ -229,6 +238,19 @@ class TransformerConfig:
     # entropy against the second-next token. Weight 0: the module is not run
     n_mtp_modules: int = 0
     mtp_loss_weight: float = 0.1
+    # -- a looped stack: the SAME layers run ``ut_steps`` times a step, the final
+    # norm applied at the end of each turn and its output carried into the next
+    # (one kind of layer, ``pp`` 1, no multi-token-prediction module)
+    ut_steps: int = 1
+    # each layer norms its two parts' OUTPUTS too: ``x + N(mix(N(x)))``, ``x + N(ffn(N(x)))``
+    sandwich_norm: bool = False
+    # with ``ut_steps`` > 1: lambda_t = sigmoid(h_t . w_g + b_g) (float32) says how much of
+    # the probability that reached loop step t leaves there (the last step takes what is
+    # left), and the training loss is the expectation of the cross entropy over the exits
+    # less ``exit_entropy_coef`` times the exit distribution's entropy, a token at a time.
+    # Without the gate the loss reads the last loop step alone
+    exit_gate: bool = False
+    exit_entropy_coef: float = 0.0
 
     def __post_init__(self) -> None:
         for name in ("kda_layers", "gdn_layers", "mla_layers", "conv_layers", "window_layers", "n_heads_per_layer"):  # a JSON file gives lists
@@ -287,6 +309,12 @@ class TransformerConfig:
                 f"n_mtp_modules={self.n_mtp_modules}: one module predicts the second-next token; the chain that "
                 "hands module k's hidden state to module k + 1 for the token after is missing"
             )
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps={self.ut_steps}: the stack runs at least once")
+        if self.exit_gate and self.ut_steps == 1:
+            raise ValueError("exit_gate says at which loop step a token leaves: it comes with ut_steps > 1")
+        if self.exit_entropy_coef and not self.exit_gate:
+            raise ValueError("exit_entropy_coef weighs the entropy of exit_gate's distribution: it comes with exit_gate")
 
     @property
     def layers_per_stage(self) -> int:
@@ -427,6 +455,8 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
         return _unit_weight(cfg, lead + shape)
 
     layers: Dict[str, Any] = {"ln1": unit(d), "ln2": unit(d)}
+    if cfg.sandwich_norm:
+        layers.update(post_ln1=unit(d), post_ln2=unit(d))
     if mixer in ("full", "window"):
         qkv = cfg.mixer_heads(mixer) * cfg.head_dim
         kv = cfg.kv_heads * cfg.head_dim
@@ -562,7 +592,9 @@ def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
     (the leading layers of the kind) and [repeats, n] (those in a period).
     A multi-token-prediction module adds ``mtp``: the norms of its two inputs
     (``enorm``, ``hnorm``), ``eh_proj`` [2d, d], ``layer`` (the leaves of one
-    layer of the last layer's kind, no leading axis) and its ``final_norm``."""
+    layer of the last layer's kind, no leading axis) and its ``final_norm``.
+    ``sandwich_norm`` adds ``post_ln1`` and ``post_ln2`` to every layer,
+    ``exit_gate`` the float32 ``exit_gate``: ``w`` [d, 1] and ``b`` [1]."""
     keys = jax.random.split(rng, 16)
     d = cfg.d_model
 
@@ -585,6 +617,9 @@ def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
             "layer": _init_layers(jax.random.fold_in(key, 1), cfg, cfg.layer_kinds()[-1], ()),
             "final_norm": _unit_weight(cfg, (d,)),
         }
+    if cfg.exit_gate:  # drawn like any dense leaf, so that a seeded comparison exercises it
+        key = jax.random.fold_in(rng, 301)
+        params["exit_gate"] = {"w": dense(key, d, 1, fan_in=d), "b": dense(jax.random.fold_in(key, 1), 1, fan_in=d)}
     if _of_one_kind(cfg):
         lead = (max(cfg.pp, 1), cfg.layers_per_stage)
         params["layers"] = _init_layers(rng, cfg, cfg.layer_kinds()[0], lead)
@@ -613,6 +648,8 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
 
     row, col = spec("fsdp", "tp"), spec("tp", "fsdp")
     layers: Dict[str, Any] = {"ln1": spec(None), "ln2": spec(None)}
+    if cfg.sandwich_norm:
+        layers.update(post_ln1=spec(None), post_ln2=spec(None))
     if mixer in ("full", "window"):
         layers.update(wq=row, wk=row, wv=row, wo=col)
         if cfg.qk_norm_per_head:
@@ -680,6 +717,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
             "enorm": P(None), "hnorm": P(None), "eh_proj": P("fsdp", "tp"),
             "layer": _layer_specs(cfg, cfg.layer_kinds()[-1], ()), "final_norm": P(None),
         }
+    if cfg.exit_gate:
+        specs["exit_gate"] = {"w": P(None, None), "b": P(None)}  # d + 1 numbers, whole on every chip
     if _of_one_kind(cfg):
         specs["layers"] = _layer_specs(cfg, cfg.layer_kinds()[0], ("pp", None))
         return specs
@@ -1505,6 +1544,28 @@ def _make_layer_fn(
 
         return whole
 
+    def normed(fn, norm: str):
+        """``fn`` itself, or under ``sandwich_norm`` with its output (the first
+        of them, where it says more) through the norm ``norm`` of its own,
+        inside whatever checkpoint holds ``fn``."""
+        if not cfg.sandwich_norm:
+            return fn
+
+        def whole(lp, h):
+            out = fn(lp, h)
+            y, rest = (out[0], out[1:]) if isinstance(out, tuple) else (out, ())
+            with jax.named_scope("post_norm"):
+                y = _norm(cfg, y, lp[norm])
+            return (y, *rest) if rest else y
+
+        return whole
+
+    def mix(fn):
+        return of_input(normed(fn, "post_ln1"), "ln1")
+
+    def feed(fn):
+        return of_input(normed(fn, "post_ln2"), "ln2")
+
     def layer_fn(x: jnp.ndarray, lp: Dict[str, Any]):
         """(x, aux): aux by name — what :func:`_ffn_moe` says of a dropless
         expert layer (:func:`_moe_said`), what :func:`_mix_gdn` says of its
@@ -1514,26 +1575,26 @@ def _make_layer_fn(
         with _scopes("attn", nested):
             h = x if from_input else _norm(cfg, x, lp["ln1"])
             if mixer in ("full", "window"):
-                x = x + part(of_input(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer), "ln1"))(lp, h)
+                x = x + part(mix(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer)))(lp, h)
             elif mixer == "kda":
-                x = x + of_input(functools.partial(_mix_kda, cfg), "ln1")(lp, h)
+                x = x + mix(functools.partial(_mix_kda, cfg))(lp, h)
             elif mixer == "gdn":
-                y, aux = of_input(functools.partial(_mix_gdn, cfg), "ln1")(lp, h)
+                y, aux = mix(functools.partial(_mix_gdn, cfg))(lp, h)
                 x = x + y
             elif mixer == "conv":
-                x = x + part(of_input(_mix_conv, "ln1"))(lp, h)
+                x = x + part(mix(_mix_conv))(lp, h)
             else:
-                x = x + part(of_input(functools.partial(_mix_mla, cfg, mesh, sp_manual), "ln1"))(lp, h)
+                x = x + part(mix(functools.partial(_mix_mla, cfg, mesh, sp_manual)))(lp, h)
 
         with _scopes("moe" if ff == "experts" else "ffn", nested):
             h = x if from_input else _norm(cfg, x, lp["ln2"])
             if experts_over_chips:
-                x = x + of_input(functools.partial(_ffn_moe_ep, cfg=cfg), "ln2")(lp, h)
+                x = x + feed(functools.partial(_ffn_moe_ep, cfg=cfg))(lp, h)
             elif ff == "experts":
-                y, said = part(of_input(functools.partial(_ffn_moe, cfg=cfg), "ln2"))(lp, h)
+                y, said = part(feed(functools.partial(_ffn_moe, cfg=cfg)))(lp, h)
                 x, aux = x + y, {**aux, **_moe_said(cfg, said)}
             else:
-                x = x + part(of_input(_ffn_dense, "ln2"))(lp, h)
+                x = x + part(feed(_ffn_dense))(lp, h)
         return _constrain(x, _act_spec(sp_manual)), aux
 
     return layer_fn
@@ -1714,6 +1775,40 @@ def _refuse_mtp_under_pp(cfg: TransformerConfig) -> None:
         )
 
 
+def _refuse_loop(cfg: TransformerConfig) -> None:
+    """What a looped stack (``ut_steps`` > 1) cannot run with yet, each by name."""
+    if cfg.ut_steps == 1:
+        return
+    if max(cfg.pp, 1) > 1:
+        raise ValueError(
+            f"ut_steps={cfg.ut_steps} with pp={cfg.pp}: every loop step crosses every pipeline stage, so the last "
+            "stage's normed output goes back to the first ut_steps - 1 times a microbatch; a pipeline schedule with "
+            "that return edge (and the exits' heads on the last stage, parallel/pipeline.py) is missing"
+        )
+    if not _of_one_kind(cfg):
+        raise ValueError(
+            f"ut_steps={cfg.ut_steps} with a declared layer pattern: the loop scans ONE stage function over the loop "
+            "steps with the same leaves; a pattern's leading layers and periods under that scan (and what their "
+            "checkpoints keep a loop step) are missing"
+        )
+    if cfg.n_mtp_modules:
+        raise ValueError(
+            f"ut_steps={cfg.ut_steps} with a multi-token-prediction module: which loop step's hidden state the module "
+            "reads, and how its loss joins the expectation over the exits, is not defined"
+        )
+
+
+def _say_loop(cfg: TransformerConfig, batch: int, seq_len: int) -> None:
+    """One ``loop_shape`` event and INFO line per traced shape of a looped
+    stack: how often which layers run and what the loss reads of the exits."""
+    fields = dict(
+        ut_steps=cfg.ut_steps, layers=cfg.n_layers, applications=cfg.ut_steps * cfg.n_layers,
+        sandwich_norm=cfg.sandwich_norm, exit_gate=cfg.exit_gate, exit_entropy_coef=cfg.exit_entropy_coef,
+        batch=batch, seq=seq_len,
+    )
+    _say_once("loop_shape", tuple(fields.values()), **fields)
+
+
 def _hidden_states(
     params: Dict[str, Any],
     tokens: jnp.ndarray,
@@ -1725,11 +1820,14 @@ def _hidden_states(
     without ever materializing [S, V] logits). ``aux`` is what the layers
     say beside the hidden state, by name (``layer_fn``), each name stacked
     over the layers that say it: ``balance`` [L] and ``counts`` [L, E] of
-    dropless expert layers, ``decay_min`` of gdn mixers, …; else {}."""
+    dropless expert layers, ``decay_min`` of gdn mixers, …; else {}.
+    A looped stack (``ut_steps`` = T > 1) gives every loop step's normed
+    state, [T, B, S, D], and its aux stacked over T·L layer applications."""
     from torchft_tpu.parallel.pipeline import pipeline_forward
 
     b, s = tokens.shape
     dt = cfg.dtype
+    _refuse_loop(cfg)
     x = _embed_lookup(params, tokens, dt)
 
     pp = max(cfg.pp, 1)
@@ -1744,6 +1842,22 @@ def _hidden_states(
         return _norm(cfg, x, params["final_norm"].astype(dt)), aux
 
     layers = _compute_dtype(params["layers"], dt)
+    if cfg.ut_steps > 1:
+        _say_loop(cfg, b, s)
+        stage_fn = _make_stage_fn(cfg, mesh, sp_manual=False)
+        stage = jax.tree_util.tree_map(lambda a: a[0], layers)
+        final_norm = params["final_norm"].astype(dt)
+
+        def turn(x, _):
+            # the same leaves every turn: the scan's transpose sums a leaf's ut_steps gradients
+            x, aux = stage_fn(stage, x)
+            # under its own checkpoint, as a layer is: the backward keeps the turn's last layer output, not the
+            # norm's float32 copy of it, a turn
+            h = _remat(cfg, lambda x: _norm(cfg, x, final_norm))(x)
+            return h, (h, aux)
+
+        _, (hs, aux) = jax.lax.scan(turn, x, None, length=cfg.ut_steps)
+        return hs, jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), aux)
     if pp == 1:
         stage_fn = _make_stage_fn(cfg, mesh, sp_manual=False)
         x, aux = stage_fn(jax.tree_util.tree_map(lambda a: a[0], layers), x)
@@ -1768,8 +1882,10 @@ def forward(
     mesh=None,
 ) -> jnp.ndarray:
     """tokens [B, S] int32 -> logits [B, S, V] (compute in cfg.dtype,
-    logits in float32)."""
+    logits in float32); of a looped stack, the last loop step's."""
     x, _ = _hidden_states(params, tokens, cfg, mesh)
+    if cfg.ut_steps > 1:
+        x = x[-1]
     return (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
 
 
@@ -1782,7 +1898,9 @@ def loss_fn(
     """The training loss: next-token cross entropy; position S-1 is
     unsupervised (targets are tokens shifted left; same [B, S] shape keeps
     sp sharding aligned). With dropless experts and a non-zero
-    ``router_aux_loss_coef``, plus that times the load-balancing term."""
+    ``router_aux_loss_coef``, plus that times the load-balancing term. Of a
+    looped stack with an exit gate: the expectation of the cross entropy over
+    the exits less the exit distribution's entropy (:func:`_exit_loss`)."""
     return loss_and_stats(params, tokens, cfg, mesh)[0]
 
 
@@ -1797,7 +1915,10 @@ def loss_and_stats(
     ``tokens_per_expert`` [L, E] int32 and ``balance_loss`` (the mean over
     layers of E·Σ_e f_e·P_e, before the coefficient), under a share
     ``rows_held`` [L], under ``shared_expert_gate`` ``shared_gate_mean`` [L];
-    of gdn mixers ``gdn_decay_min`` and ``gdn_beta_mean`` [their layers] —
+    of gdn mixers ``gdn_decay_min`` and ``gdn_beta_mean`` [their layers];
+    of a looped stack with an exit gate ``exit_probs`` [T] (each exit's
+    probability, mean over the supervised tokens), ``exit_entropy`` and
+    ``loss_by_step`` [T] (each exit's own cross entropy) —
     what ``TrainStep`` keeps of its last step."""
     if max(cfg.pp, 1) > 1 and cfg.n_experts and cfg.router_aux_loss_coef:
         raise ValueError(
@@ -1808,6 +1929,7 @@ def loss_and_stats(
     if not _of_one_kind(cfg):
         _refuse_pattern_under_pp(cfg)
     _refuse_mtp_under_pp(cfg)
+    _refuse_loop(cfg)
     if max(cfg.pp, 1) > 1 and mesh is not None:
         # pipelined training path: the head (final norm + unembed + NLL)
         # runs inside the pipeline's manual region on the last stage and
@@ -1816,8 +1938,11 @@ def loss_and_stats(
         # consumers, not the training loop
         return _pipelined_loss(params, tokens, cfg, mesh), {}
     x, aux = _hidden_states(params, tokens, cfg, mesh)
-    ce = _cross_entropy(params, x, tokens, cfg, mesh)
     stats = {}
+    if cfg.exit_gate:
+        ce, stats = _exit_loss(params, x, tokens, cfg, mesh)
+    else:
+        ce = _cross_entropy(params, x[-1] if cfg.ut_steps > 1 else x, tokens, cfg, mesh)
     if cfg.n_mtp_modules and cfg.mtp_loss_weight:
         second, mtp_aux = _mtp_hidden(params, x, tokens, cfg, mesh)
         mtp_ce = _cross_entropy(params, second, tokens, cfg, mesh, ahead=2)
@@ -1881,11 +2006,15 @@ def _cross_entropy(
     cfg: TransformerConfig,
     mesh=None,
     ahead: int = 1,
-) -> jnp.ndarray:
+    probs: Optional[jnp.ndarray] = None,
+):
     """Mean cross entropy of final-norm hidden states ``x`` against the token
     ``ahead`` positions on (1: the next token; 2: the multi-token-prediction
     module's, whose ops carry its name inside ``head_loss``); the last
-    ``ahead`` positions have no target."""
+    ``ahead`` positions have no target. With ``probs`` [B, S] (float32, and
+    differentiated): (the mean over the supervised positions of ``probs`` times
+    the position's cross entropy, each position's cross entropy [B, S] — a
+    statistic, which carries no gradient)."""
     nested = _MTP if ahead > 1 else None
     b, s = tokens.shape
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
@@ -1898,14 +2027,48 @@ def _cross_entropy(
     # dense path stays (its per-device logits are S/sp smaller), so scale
     # very long context under sp by adding sp shards, not chunking.
     if sp == 1 and _per_device_logit_elems(cfg, b, s, mesh) > _LOSS_CHUNK_ELEMS:
-        return _chunked_loss(params, x, tokens, cfg, mesh, ahead)
+        return _chunked_loss(params, x, tokens, cfg, mesh, ahead, probs)
     with _scopes("head_loss", nested):
         logits = (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
         targets = jnp.roll(tokens, -ahead, axis=1)
         logprobs = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)[..., 0]
         mask = _no_target(jnp.ones_like(nll), ahead)
+        if probs is not None:
+            return jnp.sum(nll * mask * probs) / jnp.sum(mask), jax.lax.stop_gradient(nll)
         return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+def _exit_loss(params: Dict[str, Any], hs: jnp.ndarray, tokens: jnp.ndarray, cfg: TransformerConfig, mesh=None):
+    """(loss, statistics) of a looped stack's normed states ``hs`` [T, B, S, D]
+    under the exit gate, a token at a time and in float32: ``lambda_t =
+    sigmoid(h_t . w_g + b_g)``, ``p_t = lambda_t prod_{j<t}(1 - lambda_j)`` and
+    the last step takes what is left (``sum_t p_t = 1``); the loss is the mean
+    over the supervised tokens of ``sum_t p_t CE_t - exit_entropy_coef H(p)``.
+    The T exits go through the one head as T batches (:func:`_cross_entropy`
+    under ``probs``: one pass of the unembed a loop step, one accumulator for
+    its gradient), so the mean there is over T times the tokens."""
+    t, b, s, _ = hs.shape
+    with _scopes("head_loss", "exit"):
+        gate = params["exit_gate"]
+        # h . w_g as a float32 sum over lanes: a [d, 1] product has nothing for the MXU
+        logit = jnp.sum(hs.astype(jnp.float32) * gate["w"][:, 0], axis=-1) + gate["b"]
+        stay = jax.nn.log_sigmoid(-logit)  # log(1 - lambda_t)
+        leave = jax.nn.log_sigmoid(logit).at[-1].set(0.0)
+        log_p = leave + jnp.cumsum(stay, axis=0) - stay  # ... + sum_{j<t} log(1 - lambda_j)
+        p = jnp.exp(log_p)
+    expected, nll = _cross_entropy(
+        params, hs.reshape(t * b, s, -1), jnp.tile(tokens, (t, 1)), cfg, mesh, probs=p.reshape(t * b, s)
+    )
+    with _scopes("head_loss", "exit"):
+        mask = _no_target(jnp.ones((b, s), jnp.float32), 1)
+
+        def mean(a):  # over the supervised tokens
+            return jnp.sum(a * mask, axis=(-2, -1)) / jnp.sum(mask)
+
+        entropy = mean(-jnp.sum(p * log_p, axis=0))
+        stats = dict(exit_probs=mean(p), exit_entropy=entropy, loss_by_step=mean(nll.reshape(t, b, s)))
+        return t * expected - cfg.exit_entropy_coef * entropy, stats
 
 
 def _no_target(mask: jnp.ndarray, ahead: int) -> jnp.ndarray:
@@ -1940,11 +2103,12 @@ def _chunked_loss(
     cfg: TransformerConfig,
     mesh=None,
     ahead: int = 1,
-) -> jnp.ndarray:
+    probs: Optional[jnp.ndarray] = None,
+):
     """Cross entropy without materializing [B, S, V]: scan the unembed +
     softmax over sequence chunks (:func:`_chunked_nll`). Same numbers as
     the dense path (f32 log-sum-exp per position; accumulation order
-    differs only in the final f32 sums)."""
+    differs only in the final f32 sums), and under ``probs`` the same pair."""
     b, s = tokens.shape
     targets = jnp.roll(tokens, -ahead, axis=1)
     mask = _no_target(jnp.ones((b, s), jnp.float32), ahead)
@@ -1966,6 +2130,11 @@ def _chunked_loss(
     hs = jnp.moveaxis(h.reshape(b, n_chunks, chunk, -1), 1, 0)
     ts = jnp.moveaxis(targets.reshape(b, n_chunks, chunk), 1, 0)
     ms = jnp.moveaxis(mask.reshape(b, n_chunks, chunk), 1, 0)
+    if probs is not None:
+        ps = jnp.moveaxis(jnp.pad(probs, ((0, 0), (0, pad))).reshape(b, n_chunks, chunk), 1, 0)
+        with jax.named_scope("head_loss"):
+            loss, nll = _chunked_nll(hs, params["out"], ts, ms, ps)
+        return loss, jnp.moveaxis(nll, 0, 1).reshape(b, n_chunks * chunk)[:, :s]
     if ahead > 1:
         with _scopes("head_loss", _MTP):
             return _chunked_nll_mtp(hs, params["out"], ts, ms)
@@ -1973,8 +2142,9 @@ def _chunked_loss(
         return _chunked_nll(hs, params["out"], ts, ms)
 
 
-def _chunk_nll(h_c, out_w, t_c, m_c):
-    """One chunk's f32 logits, their log-sum-exp, and its masked NLL sum."""
+def _chunk_nll(h_c, out_w, t_c, w_c):
+    """One chunk's f32 logits, their log-sum-exp, each position's NLL, and
+    the sum of those under the weights ``w_c``."""
     logits = h_c @ out_w
     # the target's logit is picked BEFORE the cast (the same number): picked
     # after it, XLA keeps an f32 copy of the chunk's logits for the gather
@@ -1982,11 +2152,17 @@ def _chunk_nll(h_c, out_w, t_c, m_c):
     logits = logits.astype(jnp.float32)
     mx = jnp.max(logits, axis=-1, keepdims=True)
     lse = jnp.log(jnp.sum(jnp.exp(logits - mx), axis=-1, keepdims=True)) + mx
-    return logits, lse, jnp.sum((lse - target)[..., 0] * m_c)
+    nll = (lse - target)[..., 0]
+    return logits, lse, nll, jnp.sum(nll * w_c)
+
+
+def _chunk_weights(m_c, p_c):
+    """A chunk's mask, times the weight a position where the call has them."""
+    return m_c if p_c is None else m_c * p_c
 
 
 @jax.custom_vjp
-def _chunked_nll(hs, out, ts, ms):
+def _chunked_nll(hs, out, ts, ms, ps=None):
     """Mean masked NLL of hidden-state chunks ``hs`` [n, B, c, d] through
     the unembed ``out`` [d, V] (cast to ``hs.dtype`` here, so its gradient
     arrives in ``out``'s own dtype). Called plainly it is the forward scan
@@ -1994,47 +2170,68 @@ def _chunked_nll(hs, out, ts, ms):
     scan also forms each chunk's ``softmax - onehot`` while its logits are
     on the chip and multiplies it into both gradients there: three matrix
     products a chunk, not the four of a backward that computes the logits
-    again, and no logits kept."""
+    again, and no logits kept.
+
+    With ``ps`` [n, B, c] (float32), a weight a position beside the mask: the
+    result is ``(sum(nll * ms * ps) / sum(ms), nll [n, B, c])``. ``ps`` carries
+    a cotangent — ``nll * ms / sum(ms)``, which the forward scan already has —
+    and the second result, a statistic, carries none. Without ``ps`` the
+    program is what it was before weights existed, to the letter."""
     out_w = out.astype(hs.dtype)
 
     def body(nll_sum, xt):
-        h_c, t_c, m_c = xt
-        return nll_sum + _chunk_nll(h_c, out_w, t_c, m_c)[2], None
+        h_c, t_c, m_c, p_c = xt
+        _, _, nll_c, total = _chunk_nll(h_c, out_w, t_c, _chunk_weights(m_c, p_c))
+        return nll_sum + total, None if p_c is None else nll_c
 
-    nll_sum, _ = jax.lax.scan(body, jnp.float32(0.0), (hs, ts, ms))
-    return nll_sum / jnp.sum(ms)
+    nll_sum, nll = jax.lax.scan(body, jnp.float32(0.0), (hs, ts, ms, ps))
+    loss = nll_sum / jnp.sum(ms)
+    return loss if ps is None else (loss, nll)
 
 
-def _chunked_nll_fwd(hs, out, ts, ms):
+def _chunked_nll_fwd(hs, out, ts, ms, ps=None):
     out_w = out.astype(hs.dtype)
     cnt = jnp.sum(ms)
 
     def body(carry, xt):
-        h_c, t_c, m_c = xt
+        h_c, t_c, m_c, p_c = xt
+        w_c = _chunk_weights(m_c, p_c)
         nll_sum, d_out = carry
-        logits, lse, nll_c = _chunk_nll(h_c, out_w, t_c, m_c)
+        logits, lse, nll_c, total = _chunk_nll(h_c, out_w, t_c, w_c)
         onehot = jax.nn.one_hot(t_c, logits.shape[-1], dtype=logits.dtype)
         # in the dtype the transposed products of `(h_c @ out_w).astype(f32)`
         # read: that cast's cotangent is cast back to the compute dtype
-        dlogits = ((jnp.exp(logits - lse) - onehot) * (m_c / cnt)[..., None]).astype(h_c.dtype)
+        dlogits = ((jnp.exp(logits - lse) - onehot) * (w_c / cnt)[..., None]).astype(h_c.dtype)
         dh_c = jnp.einsum("bcv,dv->bcd", dlogits, out_w)
         # summed over the chunks in f32, so `d out` is rounded once and not
         # once a chunk: 1.2 ms of a 67 ms head at b8 x s2048 on a v5e
         d_out = d_out + jnp.einsum(
             "bcd,bcv->dv", h_c, dlogits, preferred_element_type=jnp.float32
         )
-        return (nll_sum + nll_c, d_out), dh_c
+        return (nll_sum + total, d_out), dh_c if p_c is None else (dh_c, nll_c)
 
-    (nll_sum, d_out), dhs = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.zeros(out.shape, jnp.float32)), (hs, ts, ms)
+    (nll_sum, d_out), kept = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.zeros(out.shape, jnp.float32)), (hs, ts, ms, ps)
     )
-    return nll_sum / cnt, (dhs, d_out.astype(out.dtype))
+    if ps is None:
+        return nll_sum / cnt, (kept, d_out.astype(out.dtype), None)
+    dhs, nll = kept
+    return (nll_sum / cnt, nll), (dhs, d_out.astype(out.dtype), nll * ms / cnt)
+
+
+def _head_cotangents(res, g):
+    """(hs', out', None, None, ps') of :func:`_chunked_nll` from what its
+    forward scan kept; under weights ``g`` is the pair's and the statistic's
+    part of it is dropped."""
+    dhs, d_out, d_ps = res
+    if d_ps is not None:
+        g = g[0]
+    return (g * dhs).astype(dhs.dtype), (g * d_out).astype(d_out.dtype), None, None, None if d_ps is None else g * d_ps
 
 
 def _chunked_nll_bwd(res, g):
-    dhs, d_out = res
     with jax.named_scope("head_loss"):
-        return (g * dhs).astype(dhs.dtype), (g * d_out).astype(d_out.dtype), None, None
+        return _head_cotangents(res, g)
 
 
 _chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
@@ -2048,9 +2245,8 @@ def _chunked_nll_mtp(hs, out, ts, ms):
 
 
 def _chunked_nll_mtp_bwd(res, g):
-    dhs, d_out = res
     with _scopes("head_loss", _MTP):
-        return (g * dhs).astype(dhs.dtype), (g * d_out).astype(d_out.dtype), None, None
+        return _head_cotangents(res, g)[:4]
 
 
 _chunked_nll_mtp.defvjp(_chunked_nll_fwd, _chunked_nll_mtp_bwd)

@@ -170,6 +170,27 @@ class FTTrainer:
         with tracing.annotate("gdn.counters", **counters):
             pass
 
+    def _record_loop_counters(self, step: int, sync_span) -> None:
+        """What a looped stack's exit gate says of the step (``loss_and_stats``:
+        ``exit_probs``, ``exit_entropy``, ``loss_by_step``), fetched with the
+        loss: each exit's mean probability and its own cross entropy, one
+        number a loop step (``exit_p1`` …, ``loss_step1`` …), and the exit
+        distribution's mean entropy — on the ``loss_sync`` span in the Tracer
+        ring and, as ``tft.loop.counters``, in a profiler trace. Any other
+        model emits nothing."""
+        stats = self._ts.last_stats
+        if "exit_probs" not in stats:
+            return
+        import numpy as np
+
+        counters = dict(step=step, ut_steps=int(self._ts.cfg.ut_steps), exit_entropy=float(stats["exit_entropy"]))
+        for t, (p, loss) in enumerate(zip(np.asarray(stats["exit_probs"]), np.asarray(stats["loss_by_step"])), 1):
+            counters[f"exit_p{t}"] = float(p)
+            counters[f"loss_step{t}"] = float(loss)
+        sync_span.set(**counters)
+        with tracing.annotate("loop.counters", **counters):
+            pass
+
     # -- drive --
 
     def step(self, tokens) -> Tuple[float, bool]:
@@ -203,5 +224,6 @@ class FTTrainer:
                 self._record_moe_counters(label, sync_span)
                 self._record_mtp_counters(label, sync_span)
                 self._record_gdn_counters(label, sync_span)
+                self._record_loop_counters(label, sync_span)
             step_span.set(committed=committed)
         return loss, committed

@@ -63,9 +63,10 @@ class TrainStep:
 
         # statistics of the last step (``loss_and_stats``: tokens per expert
         # per layer and the balance term of dropless experts; ``main_loss`` and
-        # ``mtp_loss`` where a multi-token-prediction module ran), left on the
-        # device: whoever wants them fetches them (``FTTrainer.step``, with
-        # the loss). ``{}`` for a model with neither.
+        # ``mtp_loss`` where a multi-token-prediction module ran; ``exit_probs``,
+        # ``exit_entropy`` and ``loss_by_step`` of a looped stack's exit gate),
+        # left on the device: whoever wants them fetches them
+        # (``FTTrainer.step``, with the loss). ``{}`` for a model with none.
         self.last_stats: Dict[str, jnp.ndarray] = {}
 
         def compute_loss(params, tokens):

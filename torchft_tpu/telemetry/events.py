@@ -78,6 +78,7 @@ CANONICAL_EVENTS = (
     "attention_path",
     "layer_pattern",
     "gdn_core_path",
+    "loop_shape",
 )
 
 # The protocol-lifecycle subset of the vocabulary: the events the
