@@ -18,10 +18,24 @@ pulling, reducing — ``collectives.RING_ACCOUNT``), and per step the pack's
 ``tft.exchange.*`` spans between the exchange's start and its first ring's
 (``before_first_ring_s``: which piece a late first ring waited for).
 
+Beside each ring's start it prints when its piece was ready — its bucket's
+landing wait returned on the main thread (``tft.exchange.d2h_wait``'s end) —
+and whether the chip still computed the gradient tree then
+(``tft.exchange.submit``'s ``under_grads``): a ring that starts as its piece
+lands waited for the chip (``under_grads`` 1) or for the landing copy (0),
+one that starts later waited for the ring before it (``rings_at``).
+
     python scripts/op_thread_account.py benchmark_runs/<cell>/trace.*   # after a --trace 1 run
+    python scripts/op_thread_account.py benchmark_runs/<cell>/result.0.json   # any run, traced or not
 
 One JSON line a trace on stdout; needs ``jax.profiler.ProfileData`` only
-(no backend is initialised).
+(no backend is initialised). A worker's ``result.<g>.json`` says the same of
+an UNTRACED run, from the ``exchange`` span's own attributes
+(``bucket_landed_s``, ``bucket_ring_end_s``, ``bucket_under_grads``; a traced
+run blocks on the whole tree before it exchanges, so only an untraced one
+shows what lies under the backward): per exchange and bucket, when the piece
+landed, when its ring ended, and what the op thread waited for before it —
+``ring`` (the bucket before was still on it), ``chip`` or ``landing``.
 """
 
 from __future__ import annotations
@@ -35,6 +49,7 @@ import sys
 PREFIX = "tft.exchange"
 RING, H2D, AVERAGE = f"{PREFIX}.ring", f"{PREFIX}.h2d", f"{PREFIX}.average"
 ACCOUNT, PACK = f"{PREFIX}.ring.account", f"{PREFIX}.pack"
+LANDED, SUBMIT = f"{PREFIX}.d2h_wait", f"{PREFIX}.submit"
 
 
 def _xplane(path: str) -> str:
@@ -51,6 +66,7 @@ def account(path: str) -> dict:
 
     data = ProfileData.from_file(_xplane(path))
     exchanges, by_line, counters, accounts, packs, pieces = [], {}, [], [], [], []
+    landed, submits = [], []  # (end, stats) of each bucket's landing wait; (start, stats) of its submit
     for plane in data.planes:
         if not plane.name.startswith("/host:CPU"):
             continue
@@ -68,6 +84,10 @@ def account(path: str) -> dict:
                     accounts.append((s, dict(ev.stats)))
                 elif ev.name == PACK:
                     packs.append((s, dict(ev.stats)))
+                elif ev.name == LANDED:
+                    landed.append((e, dict(ev.stats)))
+                elif ev.name == SUBMIT:
+                    submits.append((s, dict(ev.stats)))
                 if ev.name not in (PREFIX, RING, H2D, AVERAGE, ACCOUNT, f"{PREFIX}.counters"):
                     pieces.append((s, e, ev.name[len(PREFIX) + 1:]))
                 elif ev.name in (RING, H2D, AVERAGE):
@@ -127,6 +147,15 @@ def account(path: str) -> dict:
                  **{k: v for k, v in a.items() if k != "bytes"}}
                 for (s, e, _, st), (_, a) in zip(rings, mine)
             ]
+        # the n-th ring of a step is its n-th bucket's: when that piece was
+        # ready, beside when its ring started
+        ready = {st.get("bucket"): t - lo for t, st in landed if lo <= t <= hi}
+        under = {st.get("bucket"): st.get("under_grads") for t, st in submits if lo <= t <= hi}
+        step["rings_at"] = [
+            {"bucket": k, "piece_ready_s": ready.get(k), "under_grads": under.get(k),
+             "ring_start_s": r[0] - lo, "ring_end_s": r[1] - lo}
+            for k, r in enumerate(rings)
+        ]
         before = {}
         for s, e, name in pieces:
             if lo <= s < rings[0][0]:
@@ -154,11 +183,47 @@ def account(path: str) -> dict:
     return out
 
 
+def untraced(path: str) -> dict:
+    """The same question of a worker's ``result.<g>.json``: per exchange of the
+    Tracer's ring and per bucket, when the piece landed, when its ring ended,
+    and what the op thread waited for before that ring."""
+    with open(path) as f:
+        spans = json.load(f).get("exchange_spans") or []
+    steps = []
+    for span in spans:
+        if "bucket_landed_s" not in span:
+            continue
+        landed = [float(t) for t in span["bucket_landed_s"].split(",")]
+        ended = [float(t) for t in span["bucket_ring_end_s"].split(",")]
+        under = [int(u) for u in span["bucket_under_grads"].split(",")]
+        buckets, free = [], 0.0  # when the op thread had its last ring behind it
+        for k, (at, end, u) in enumerate(zip(landed, ended, under)):
+            waited = "ring" if free > at else "chip" if u else "landing"
+            buckets.append({"bucket": k, "piece_ready_s": at, "ring_end_s": end,
+                            "op_thread_idle_before_s": max(at - free, 0.0), "waited_for": waited})
+            free = end
+        steps.append({
+            "step": span.get("step"), "exchange_s": span["dur_s"], "pieces": span.get("pieces"),
+            "bytes_under_grads": span.get("bytes_under_grads"), "buckets": buckets,
+            "waited_for_chip_s": sum(b["op_thread_idle_before_s"] for b in buckets if b["waited_for"] == "chip"),
+            "waited_for_landing_s": sum(b["op_thread_idle_before_s"] for b in buckets if b["waited_for"] == "landing"),
+        })
+    out = {"result": path, "steps": steps}
+    if steps:
+        out["median"] = {
+            k: statistics.median(st[k] for st in steps)
+            for k in ("exchange_s", "bytes_under_grads", "waited_for_chip_s", "waited_for_landing_s")
+        }
+        out["median"]["piece_ready_s"] = [statistics.median(col) for col in zip(*([b["piece_ready_s"] for b in st["buckets"]] for st in steps))]
+        out["median"]["ring_end_s"] = [statistics.median(col) for col in zip(*([b["ring_end_s"] for b in st["buckets"]] for st in steps))]
+    return out
+
+
 def main(argv) -> int:
     if not argv:
         raise SystemExit(__doc__)
     for path in argv:
-        print(json.dumps(account(path)))
+        print(json.dumps(untraced(path) if path.endswith(".json") else account(path)))
     return 0
 
 

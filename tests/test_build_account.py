@@ -10,6 +10,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 import pytest
 from jax._src import monitoring as jax_monitoring
@@ -337,6 +338,34 @@ def test_warm_apply_on_a_daemon_thread_builds_tft_apply(train_step):
     assert {s["tid"] for s in spans} == {t.ident & 0x7FFFFFFF}
 
 
+def test_warm_apply_lowers_the_program_the_first_apply_after_a_heal_runs(train_step, monkeypatch):
+    """``grads`` of this stack comes in pieces, so the update that runs takes
+    pieces: the heal's warm-up lowers THAT program from the transferred shapes
+    (``jax.ShapeDtypeStruct``s without shardings, as ``spec_tree_from_header``
+    gives them), letter for letter what the first ``apply`` lowers from the
+    arrays the exchange put back — one ``jit_tft_apply``, not a second variant
+    for the persistent cache to miss."""
+    ts = train_step
+    assert ts._chain is not None
+    params = ts.init_params(jax.random.PRNGKey(0))
+    opt = ts.init_opt(params)
+    spec = lambda tree: jax.tree_util.tree_map(lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), tree)  # noqa: E731
+    lowered = []
+    monkeypatch.setattr(jax.stages.Lowered, "compile", lambda self, *a, **kw: lowered.append(self))
+    ts.warm_apply(spec(params), spec(opt))
+    monkeypatch.undo()
+    (warmed,) = lowered
+    _, grads = ts.grads(params, ts.shard_batch(jnp.zeros((2, 16), jnp.int32)))
+    # as the host exchange hands them back: placed where `grads` had them
+    averaged = jax.tree_util.tree_map(lambda g: jax.device_put(np.asarray(g), g.sharding), grads)
+    with jax.set_mesh(ts.mesh):
+        ran = ts._apply_pieces.lower(params, opt, averaged)
+    assert ran.as_text() == warmed.as_text() and "module @jit_tft_apply" in ran.as_text()
+    before = len(build_spans("tft_apply"))
+    ts.apply(params, opt, averaged)
+    assert [s["name"] for s in build_spans("tft_apply")[before:]].count("build.compile") <= 1
+
+
 def test_first_call_s_is_each_programs_first_call(train_step):
     ts = TrainStep(CFG, optax.adamw(1e-2), train_step.mesh)
     params = ts.init_params(jax.random.PRNGKey(1))
@@ -396,13 +425,20 @@ def test_the_profilers_trace_holds_the_stages_and_the_counters(train_step, tmp_p
                     lines.append(sorted(events, key=lambda e: e[0]))
     (main,) = lines  # everything was built, and stepped, on this thread
     stages = [(name, stats) for _, name, stats, _ in main if name != "tft.build.counters"]
-    for program in ("tft_grads", "tft_fused"):
+    # this stack is cut a layer at a time: `grads` is a chain of three programs
+    # under one name (head, layer, tail), each built at its first call
+    assert ts._chain is not None
+    for program, built in (("tft_grads", 3), ("tft_fused", 1)):
         mine = [(n, s) for n, s in stages if s["program"] == program]
         # a second call whose arguments are placed otherwise (the fused
-        # step's own outputs) looks its trace up again: microseconds, a trace
-        assert [n for n, _ in mine[:3]] == ["tft." + s for s in STAGES]
-        assert {n for n, _ in mine[3:]} <= {"tft.build.trace"}
-        assert mine[2][1]["cache"] in ("off", "miss", "hit") and "retrieval_s" in mine[2][1]
+        # step's own outputs, a layer's cotangent from the layer above)
+        # looks its trace up again: microseconds, a trace
+        full = [i for i, (n, _) in enumerate(mine) if n == "tft.build.compile"]
+        assert len(full) == built
+        for i in full:
+            assert [n for n, _ in mine[i - 2 : i + 1]] == ["tft." + s for s in STAGES]
+            assert mine[i][1]["cache"] in ("off", "miss", "hit") and "retrieval_s" in mine[i][1]
+        assert {n for i, (n, _) in enumerate(mine) if not any(f - 2 <= i <= f for f in full)} <= {"tft.build.trace"}
     counters = [(stats, dur) for _, name, stats, dur in main if name == "tft.build.counters"]
     assert len(counters) == 3  # one a call of grads / step
     assert all(set(stats) == set(final) for stats, _ in counters)

@@ -642,3 +642,190 @@ def test_the_rings_account_grows_into_the_steps_sums():
         "ring_wait_s": 3 * 0.75, "ring_pull_s": 3 * 0.125, "ring_reduce_s": 3.0,
         "ring_pump_s": 6.0, "ring_pull_bytes": 5 * 64, "ring_reduce_bytes": 5 * 32,
     }
+
+
+# -- a gradient that comes in pieces (utils.pieces.GradPieces) --------------------------
+
+
+PIECES_META = [  # (dtype, nbytes), and the piece each item belongs to
+    (np.dtype(np.float32), 4000), (np.dtype(np.float32), 16),          # the head's
+    (np.dtype(np.float32), 1000), (np.dtype(np.float16), 8), (np.dtype(np.float32), 16),  # a layer's
+    (np.dtype(np.float32), 1000), (np.dtype(np.float16), 8), (np.dtype(np.float32), 16),  # the layer below
+    (np.dtype(np.float32), 4000),                                      # the embedding's
+]
+PIECE_OF = [0, 0, 1, 1, 1, 2, 2, 2, 3]
+
+
+@pytest.mark.parametrize("bucket_bytes", [64, 1024, 1 << 30])
+def test_a_piece_is_a_bucket_whatever_the_bucket_size(bucket_bytes):
+    """Where the tree comes in pieces the plan follows the order given, puts a
+    piece's items of one dtype into one bucket — its small leaves with it, and
+    never two pieces together — and is a function of the metadata alone."""
+    plan = plan_buckets(PIECES_META, bucket_bytes, PIECE_OF)
+    assert plan == [[0, 1], [2, 4], [3], [5, 7], [6], [8]]
+    assert plan == plan_buckets(list(PIECES_META), bucket_bytes, list(PIECE_OF))
+    # every item once, and the buckets start in the order the pieces were given
+    assert sorted(i for idxs in plan for i in idxs) == list(range(len(PIECES_META)))
+    assert [PIECE_OF[idxs[0]] for idxs in plan] == sorted(PIECE_OF[idxs[0]] for idxs in plan)
+
+
+@pytest.mark.parametrize("order", [(0, 1, 2, 3), (3, 1, 0, 2), (2, 3, 1, 0)])
+def test_the_plan_follows_the_order_given(order):
+    """One piece: today's rule by bytes, over the leaves in the order given —
+    whichever that is — and equal for equal metadata."""
+    meta = [(np.dtype(np.float32), 60 + 4 * i) for i in order]
+    plan = plan_buckets(meta, bucket_bytes=128)
+    assert plan == plan_buckets(list(meta), bucket_bytes=128)
+    assert [i for idxs in plan for i in idxs] == list(range(4))  # positions in the order given, never sorted by size
+    assert all(sum(meta[i][1] for i in idxs) <= 128 or len(idxs) == 1 for idxs in plan)
+
+
+class NotReadyYet:
+    """A stand-in for a device array whose program has not ended: its host
+    view blocks until ``ready`` is set (and says whether it ever was)."""
+
+    dtype, shape = np.dtype(np.float32), (16,)
+
+    def __init__(self, value):
+        import threading
+
+        self.ready, self.value, self.timed_out = threading.Event(), value, False
+
+    def is_ready(self):
+        return self.ready.is_set()
+
+    def __array__(self, dtype=None, copy=None):
+        self.timed_out = not self.ready.wait(10)
+        return np.full(self.shape, self.value, self.dtype)
+
+
+def pieces_of(last):
+    from torchft_tpu.utils.pieces import GradPieces
+
+    return GradPieces([
+        {"out": np.full((16,), np.float32(1.0)), "final_norm": np.full((4,), np.float32(2.0))},
+        {"w": np.full((16,), np.float32(3.0))},
+        {"embed": last},
+    ])
+
+
+def test_a_leaf_that_is_not_ready_does_not_hold_an_earlier_buckets_ring():
+    """Where buckets are packed (this stub's plane takes no sources): the head's
+    bucket is on the ring, and the layer's after it, while the last piece is
+    still being computed — the stand-in becomes ready only once two rings were
+    submitted, so an exchange that waited for the whole tree first would sit
+    out the stand-in's timeout."""
+    late = NotReadyYet(5.0)
+
+    class Releasing(RingStub):
+        def allreduce_many(self, tensors):
+            fut = super().allreduce_many(tensors)
+            if len(self.seen) == 2:
+                late.ready.set()
+            return fut
+
+    m = Releasing()
+    out, attrs = exchange(m, pieces_of(late))
+    assert not late.timed_out and [buf.size for buf in m.seen] == [20, 16, 16]
+    np.testing.assert_allclose(np.asarray(out[2]["embed"]), (5.0 + np.arange(16)) / 2)
+    np.testing.assert_allclose(np.asarray(out[0]["final_norm"]), (2.0 + np.arange(4)) / 2)
+    # the first two buckets landed under the last piece's program: 80 + 64 of 208 bytes
+    assert (attrs["pieces"], attrs["buckets"], attrs["bytes_under_grads"]) == (3, 3, 144)
+    assert attrs["bucket_under_grads"] == "1,1,0"
+
+
+@pytest.mark.parametrize("tree, pieces, buckets", [("pieces", 3, 3), ("dict", 1, 2)])
+def test_pieces_and_bytes_under_grads_are_on_the_span(tree, pieces, buckets):
+    """``pieces``: how many the tree came in (1: a tree one program gave whole);
+    ``bytes_under_grads``: 0 when every array was there before the first landing
+    wait returned; a landing time and a ring's end a bucket, in order."""
+    from torchft_tpu.utils.pieces import GradPieces
+
+    done = np.full((16,), np.float32(5.0))
+    grads = pieces_of(done) if tree == "pieces" else {f"g{i}": np.full((16,), np.float32(i)) for i in range(3)}
+    out, attrs = exchange(RingStub(), grads, bucket_bytes=128)
+    assert isinstance(out, GradPieces) == (tree == "pieces")
+    assert (attrs["pieces"], attrs["buckets"], attrs["bytes_under_grads"]) == (pieces, buckets, 0)
+    for key in ("bucket_landed_s", "bucket_ring_end_s"):
+        at = [float(t) for t in attrs[key].split(",")]
+        assert len(at) == buckets and at == sorted(at)
+
+
+def test_the_op_thread_account_of_an_untraced_run_says_what_each_ring_waited_for(tmp_path):
+    """``scripts/op_thread_account.py`` on a worker's ``result.<g>.json``: from
+    the ``exchange`` span's by-bucket attributes, beside each ring when its
+    piece was ready and whether the op thread waited for the chip, for the
+    landing copy, or was still on the ring before."""
+    import importlib.util
+    import json
+    import os
+
+    late = NotReadyYet(5.0)
+
+    class Releasing(RingStub):
+        def allreduce_many(self, tensors):
+            fut = super().allreduce_many(tensors)
+            if len(self.seen) == 2:
+                late.ready.set()
+            return fut
+
+    _, attrs = exchange(Releasing(), pieces_of(late))
+    path = tmp_path / "result.0.json"
+    path.write_text(json.dumps({"exchange_spans": [{"dur_s": 0.5, **attrs}, {"dur_s": 0.1, "step": 0, "buckets": 1}]}))
+    spec = importlib.util.spec_from_file_location(
+        "op_thread_account", os.path.join(os.path.dirname(__file__), "..", "scripts", "op_thread_account.py")
+    )
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    out = script.untraced(str(path))
+    (step,) = out["steps"]  # a span without the by-bucket attributes (an older program's) is left out
+    assert (step["pieces"], step["bytes_under_grads"]) == (3, 144)
+    assert [b["bucket"] for b in step["buckets"]] == [0, 1, 2]
+    # this stub's ring is over before its call returns: the op thread is free when the next piece lands
+    assert [b["waited_for"] for b in step["buckets"]] == ["chip", "chip", "landing"]
+    assert all(b["op_thread_idle_before_s"] >= 0 and b["ring_end_s"] >= b["piece_ready_s"] for b in step["buckets"])
+    assert out["median"]["waited_for_chip_s"] == step["waited_for_chip_s"] > 0
+
+
+@pytest.mark.parametrize("plane, ready_when_submitted", [
+    ("cma", [True, True, True]),           # the groups on one host: every landing, then the rings
+    ("tcp-striped", [False, False, True]),  # across hosts: a ring rides while the next bucket lands
+])
+def test_only_on_one_hosts_memory_every_bucket_lands_before_the_first_ring(plane, ready_when_submitted):
+    """A plane that ``takes_sources()`` reads the landing arrays themselves. On
+    ``cma`` its ring and the landing copies draw on one host's memory bandwidth,
+    so no ring is submitted beside a landing — the first ``allreduce_many`` sees
+    the last piece on the host — while the landings still follow the programs
+    (``bytes_under_grads``). On ``tcp-striped`` the ring is the network's: the
+    head's bucket is on it while the last piece is still being computed."""
+    import threading
+
+    from torchft_tpu.collectives import fill_from_sources
+
+    late = NotReadyYet(5.0)
+    ready_at_submit = []
+
+    class ReadsSources(RingStub):
+        def takes_sources(self):
+            return True
+
+        def plane_info(self):
+            return plane
+
+        def allreduce_many(self, tensors, sources=None):
+            ready_at_submit.append(late.ready.is_set())
+            assert sources is not None  # float32, contiguous, no error feedback: nothing is packed
+            fill_from_sources(tensors, sources)
+            fut = super().allreduce_many(tensors)
+            if len(self.seen) == 2:  # land-first never gets here before the piece is ready
+                late.ready.set()
+            return fut
+
+    if plane == "cma":
+        threading.Timer(0.05, late.ready.set).start()
+    out, attrs = exchange(ReadsSources(), pieces_of(late))
+    assert ready_at_submit == ready_when_submitted and not late.timed_out
+    np.testing.assert_allclose(np.asarray(out[2]["embed"]), (5.0 + np.arange(16)) / 2)
+    np.testing.assert_allclose(np.asarray(out[1]["w"]), (3.0 + np.arange(16)) / 2)
+    assert (attrs["pieces"], attrs["buckets"], attrs["pack_bytes"]) == (3, 3, 0)
+    assert (attrs["bytes_under_grads"], attrs["bucket_under_grads"]) == (144, "1,1,0")

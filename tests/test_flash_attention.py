@@ -630,6 +630,59 @@ def test_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, shape):
     assert text.count("tpu_custom_call") >= 2
 
 
+def test_the_chains_layer_program_runs_the_backward_kernel_alone_on_v5e(one_v5e_chip):
+    """``TrainStep.grads`` as a chain (``transformer.grads_chain``): the layer's
+    program traces the layer's forward to get its ``jax.vjp`` closure, then puts
+    the kernel's output and row statistics that the head's program kept in the
+    closure's place — so compiled for a described v5e it holds the backward
+    kernel and NOT the forward one, which is dead code: the kernel's forward
+    runs once a layer a step, in the head, as under the scan."""
+    import re
+    from unittest import mock
+
+    import optax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.models.transformer import TransformerConfig, init_params
+    from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+    from torchft_tpu.parallel.train_step import TrainStep
+
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=256, n_layers=2, n_heads=2, head_dim=128, d_ff=512,
+        dtype=jnp.bfloat16, remat=True, remat_policy="all", attention_impl="flash",
+    )
+    mesh = make_mesh(MeshConfig(), devices=list(one_v5e_chip.device_set))
+    spec = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip), tree
+    )
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"), jax.set_mesh(mesh):
+            ts = TrainStep(cfg, optax.sgd(1e-2), mesh)
+            head, layer, _ = ts._chain
+            params = spec(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+            tokens = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_v5e_chip)
+            _, _, _, (dx, kept) = jax.eval_shape(head, params, tokens)
+            own = [(a.shape, a.dtype) for a in kept[1]]
+            kernels = {
+                name: set(re.findall(r"flash_(?:fwd|bwd)", lowered.compile().as_text()))
+                for name, lowered in (
+                    ("head", head.lower(params, tokens)),
+                    ("layer", layer.lower(params["layers"], jax.ShapeDtypeStruct((), jnp.int32, sharding=one_v5e_chip), spec(kept), spec(dx))),
+                )
+            }
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert kernels == {"head": {"flash_fwd"}, "layer": {"flash_bwd"}}
+    # what the chain rests on, of JAX's inside: the leaves of the layer's ``jax.vjp`` closure that are neither its
+    # inputs nor constants are, under ``_remat``, what the policy names and nothing else — the kernel's output and its
+    # rows' logsumexp, in that order, a row a layer (``transformer._closure_own``). A JAX that keeps more, less or
+    # in another order fails HERE, not in a gradient on the chip.
+    assert own == [((2, 1, 2048, 256), jnp.bfloat16), ((2, 1, 2, 8, 2048), jnp.float32)]
+
+
 @pytest.mark.parametrize("dtype, lanes, least_mib, most_mib", [
     # the cell's kernel as measured (PR 52): what bf16 asked before the storage dtype was counted, to the byte
     (jnp.bfloat16, 64, 38, 38), (jnp.bfloat16, 128, 50, 50),

@@ -577,7 +577,9 @@ def test_the_fused_step_learns_and_keeps_the_last_steps_statistics(train_step):
     load = np.asarray(stats["tokens_per_expert"])
     assert load.shape == (2, 8) and (load.sum(axis=1) == 2 * 16 * 2).all()
     loss, grads = train_step.grads(params, tokens)  # (loss, grads), as FTTrainer calls it
-    assert jax.tree_util.tree_structure(grads) == jax.tree_util.tree_structure(params)
+    # in pieces, the head's first (this stack is cut a layer at a time); one helper gives the parameters' shape
+    assert len(grads) == 2 + 2 and set(grads[0]) == {"final_norm", "out"} and set(grads[-1]) == {"embed"}
+    assert jax.tree_util.tree_structure(train_step.grads_tree(grads)) == jax.tree_util.tree_structure(params)
 
 
 def test_a_dense_model_has_no_statistics():

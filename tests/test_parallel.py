@@ -210,3 +210,93 @@ class TestNoInvoluntaryRemat:
     def test_ep_tp_fsdp_moe_step_has_no_remat_fallback(self, capfd):
         self._run({"n_experts": 4}, dict(ep=2, tp=2, fsdp=2))
         assert "Involuntary full rematerialization" not in capfd.readouterr().err
+
+
+class TestGradsChain:
+    """``TrainStep.grads`` as a chain of programs where the stack can be cut a
+    layer at a time (``transformer.grads_chain``), and as the one program it
+    was everywhere else."""
+
+    CUT = {
+        "dense": ({}, {}),
+        "experts_with_a_balance_term": ({"n_experts": 4, "top_k": 2, "router_aux_loss_coef": 0.01}, {}),
+        "no_remat": ({"remat": False}, {}),
+        "sharded": ({}, {"fsdp": 2, "sp": 2, "tp": 2}),
+    }
+    # a stack the chain cannot cut, and the sha256 of the text `grads` lowers
+    # to at (8, 16) tokens: computed at 17f6eb6, the parent of the PR that
+    # brought the chain, and here, by one script
+    UNCUT = {
+        "pattern": ({"window": 8, "window_layers": (1, 3)}, {}, "5731e1b68b163eceae191c50ba63cc3ad69cb7c5ed151a04a014158219a41a6f"),
+        "looped": ({"ut_steps": 2}, {}, "b711d118ab86358c3147b673006b5f34a9f8291fb2ba02ac6b8e8eedddf4f3e6"),
+        "mtp": ({"n_mtp_modules": 1}, {}, "b191f883fa53605ba1b0db198f4ae1d57123992f56a55e5210b0804e13b43651"),
+        "pp": ({"pp": 2, "microbatches": 2}, {"pp": 2}, "2958b02deed432b2162883c432f8d1b2f662687923208713ac6cbcddc4ac4893"),
+    }
+
+    @staticmethod
+    def _step(cfg_over, mesh_over):
+        cfg = TransformerConfig(**{**CFG, **cfg_over})
+        mesh_cfg = MeshConfig(**mesh_over)
+        mesh = make_mesh(mesh_cfg, devices=jax.devices()[: mesh_cfg.total])
+        return cfg, mesh, TrainStep(cfg, optax.sgd(1e-2), mesh)
+
+    @pytest.mark.parametrize("name", list(CUT))
+    def test_the_pieces_assemble_to_the_gradient(self, name):
+        """An OLMo-shaped float32 stack: the head's piece first, a layer's from
+        the last to the first, the embedding's last; stacked they are
+        ``jax.grad`` of ``loss_fn`` and the one program's gradients, and
+        ``apply`` takes them as they came."""
+        from torchft_tpu.utils.pieces import GradPieces
+
+        cfg, mesh, ts = self._step(*self.CUT[name])
+        params = ts.init_params(jax.random.PRNGKey(0))
+        t = ts.shard_batch(tokens())
+        loss, grads = ts.grads(params, t)
+        assert isinstance(grads, GradPieces) and len(grads) == cfg.n_layers + 2
+        assert set(grads[0]) == {"final_norm", "out"} and set(grads[-1]) == {"embed"}
+        assert all(leaf.shape[:2] == (1, 1) for piece in grads[1:-1] for leaf in jax.tree_util.tree_leaves(piece))
+        tree = ts.grads_tree(grads)
+        assert jax.tree_util.tree_structure(tree) == jax.tree_util.tree_structure(params)
+        with jax.set_mesh(mesh):
+            loss1, one_program, stats1 = ts._value_and_grad(params, t)
+            wanted = jax.jit(jax.grad(lambda p: loss_fn(p, t, cfg, mesh)))(params)
+        np.testing.assert_allclose(float(loss), float(loss1), rtol=1e-6)
+        assert set(ts.last_stats) == set(stats1)
+        for reference in (wanted, one_program):
+            for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(tree), jax.tree_util.tree_leaves(reference)):
+                np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6, err_msg=jax.tree_util.keystr(path))
+        # the update from the pieces is the update from the tree
+        opt = ts.init_opt(params)
+        kept = jax.tree_util.tree_map(jnp.copy, (params, opt))
+        from_pieces, _ = ts.apply(params, opt, grads)
+        with jax.set_mesh(mesh):
+            from_tree, _ = ts._apply(*kept, one_program)
+        for got, want in zip(jax.tree_util.tree_leaves(from_pieces), jax.tree_util.tree_leaves(from_tree)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-7)
+
+    def test_the_layers_share_one_program(self):
+        cfg, mesh, ts = self._step({}, {})
+        params = ts.init_params(jax.random.PRNGKey(0))
+        t = ts.shard_batch(tokens())
+        head, layer, tail = ts._chain
+        ts.grads(params, t)
+        ts.grads(params, t)
+        # L calls a step of ONE executable: the row is an argument, not a constant
+        # whichever link gave it its cotangent
+        assert (head._cache_size(), layer._cache_size(), tail._cache_size()) == (1, 1, 1)
+
+    @pytest.mark.parametrize("name", list(UNCUT))
+    def test_a_stack_the_chain_cannot_cut_is_one_piece_and_the_parents_program(self, name):
+        import hashlib
+
+        cfg_over, mesh_over, digest = self.UNCUT[name]
+        cfg, mesh, ts = self._step(cfg_over, mesh_over)
+        assert ts._chain is None and ts._apply_pieces is None
+        params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+        with jax.set_mesh(mesh):
+            text = ts._value_and_grad.lower(params, jax.ShapeDtypeStruct((8, 16), jnp.int32)).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest() == digest
+        params = ts.init_params(jax.random.PRNGKey(0))
+        loss, grads = ts.grads(params, ts.shard_batch(tokens()))
+        assert jax.tree_util.tree_structure(grads) == jax.tree_util.tree_structure(params)
+        assert ts.grads_tree(grads) is grads

@@ -50,7 +50,10 @@ EXCHANGE_SUMS = {
     "d2h_wait_s", "pack_s", "tail_wait_s", "utime_s", "stime_s",
     "pack_bytes", "pack_aliased_bytes", "h2d_bytes", "buckets_from_source",
     "ring_wait_s", "ring_pull_s", "ring_reduce_s", "ring_pump_s", "ring_pull_bytes", "ring_reduce_bytes",
+    "pieces", "bytes_under_grads",
 }
+# by bucket, on the span alone (in a trace every bucket has events of its own)
+EXCHANGE_BY_BUCKET = {"bucket_landed_s", "bucket_ring_end_s", "bucket_under_grads"}
 
 
 @pytest.fixture(scope="module")
@@ -275,7 +278,13 @@ def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, mo
     assert last["attrs"]["committed"] is True
     (exchange,) = [s for s in children if s["name"] == "exchange"]
     # the step's sums reach /trace, the JSONL and the piggyback untraced too
-    assert set(exchange["attrs"]) == EXCHANGE_SUMS
+    assert set(exchange["attrs"]) == EXCHANGE_SUMS | EXCHANGE_BY_BUCKET
+    # the chain's pieces, a bucket each, landed and rung in the order given
+    assert exchange["attrs"]["pieces"] == exchange["attrs"]["buckets"] == CFG.n_layers + 2
+    for key in ("bucket_landed_s", "bucket_ring_end_s"):
+        at = [float(t) for t in exchange["attrs"][key].split(",")]
+        assert len(at) == CFG.n_layers + 2 and at == sorted(at) and at[0] > 0
+    assert set(exchange["attrs"]["bucket_under_grads"].split(",")) <= {"0", "1"}
     assert exchange["attrs"]["pack_bytes"] == exchange["attrs"]["h2d_bytes"] == 4 * n_params()
     # nothing per bucket, and at most 12 new entries a step
     assert not [s for s in spans if s["name"].startswith("exchange.")]
@@ -312,14 +321,39 @@ def test_programs_and_scopes_have_stable_names(train_step):
     assert "module @jit_tft_grads" in grads
     assert "module @jit_tft_apply" in apply
     assert "module @jit_tft_fused" in fused
-
-    def scopes(text):
-        found = set()
-        for loc in re.findall(r'loc\("([^"]*)"', text):
-            # a scope is a component of the op's path: attn/add, jvp(embed)/jit
-            found.update(re.findall(r"(?:^|[/(])(embed|attn|ffn|moe|head_loss|optimizer)(?=[/)])", loc))
-        return found
-
     assert scopes(grads) == {"embed", "attn", "ffn", "head_loss"}
     assert scopes(apply) == {"optimizer"}
     assert scopes(fused) == {"embed", "attn", "ffn", "head_loss", "optimizer"}
+
+
+def scopes(text):
+    found = set()
+    for loc in re.findall(r'loc\("([^"]*)"', text):
+        # a scope is a component of the op's path: attn/add, jvp(embed)/jit
+        found.update(re.findall(r"(?:^|[/(])(embed|attn|ffn|moe|head_loss|optimizer)(?=[/)])", loc))
+    return found
+
+
+def test_every_link_of_the_chain_is_a_module_called_tft_grads(train_step):
+    """``grads`` of this stack is a chain of programs (head, a layer's, tail):
+    each is a module ``jit_tft_grads`` — what sums a trace's ``XLA Modules``
+    runs by that name sums the chain — under the scopes of its part of the
+    model, and the update that takes the pieces is ``jit_tft_apply``."""
+    ts = train_step
+    params = ts.init_params(jax.random.PRNGKey(0))
+    opt = ts.init_opt(params)
+    tokens = ts.shard_batch(jnp.zeros((2, 16), jnp.int32))
+    head, layer, tail = ts._chain
+    with jax.set_mesh(ts.mesh):
+        loss, stats, top, (dx, kept) = head(params, tokens)
+        links = {
+            "head": head.lower(params, tokens).as_text(debug_info=True),
+            "layer": layer.lower(params["layers"], np.int32(0), kept, dx).as_text(debug_info=True),
+            "tail": tail.lower(params["embed"], tokens, dx).as_text(debug_info=True),
+        }
+        apply = ts._apply_pieces.lower(params, opt, ts.grad_pieces(params)).as_text(debug_info=True)
+    assert all("module @jit_tft_grads" in text for text in links.values())
+    assert {name: scopes(text) for name, text in links.items()} == {
+        "head": {"embed", "attn", "ffn", "head_loss"}, "layer": {"attn", "ffn"}, "tail": {"embed"},
+    }
+    assert "module @jit_tft_apply" in apply and scopes(apply) == {"optimizer"}

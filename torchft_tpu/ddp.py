@@ -30,6 +30,18 @@ rewrites the packed bucket before the ring, a dtype the native ring does
 not take, a leaf that is not contiguous — is packed into the bucket buffer
 with ``np.copyto`` and reduced in place, as every bucket was before PR 39.
 
+A gradient that comes in pieces (:class:`GradPieces`: ``TrainStep.grads``
+where the backward is a chain of programs, the head's piece first) is
+exchanged in that order, a piece a bucket: the copies are issued in the order
+given and the runtime starts each when its program ends, so the pieces land
+on the host while the chip still computes the later ones (``pieces`` and
+``bytes_under_grads`` on the ``exchange`` span; docs/wire_plane.md has the
+step's timeline). On the plane ``cma`` (the groups on one host), where the ring
+reads the landing arrays themselves, no ring is submitted before the last
+bucket has landed: the two draw on that host's one budget of memory
+bandwidth, and side by side both get less of it. On every other plane a
+bucket rides the ring while the next lands.
+
 The bucket buffers here always own their memory (``np.empty``) and are
 all the ring ever writes — the landing arrays and a caller's NumPy leaves
 are packed from or handed over as a source, and either way only read — so
@@ -91,6 +103,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from torchft_tpu.telemetry import tracing
+from torchft_tpu.utils.pieces import GradPieces
 
 __all__ = ["flatten_buckets", "unflatten_buckets", "allreduce_gradients"]
 
@@ -109,7 +122,9 @@ def default_bucket_bytes() -> int:
     """Streamed-bucket size for the host wire plane — the
     ``TORCHFT_WIRE_BUCKET_BYTES`` env knob, default 25 MB
     (docs/wire_plane.md: smaller buckets start the wire earlier but pay
-    more per-op overhead)."""
+    more per-op overhead). It does not apply to a gradient that comes in
+    pieces (:class:`GradPieces`): there a piece is a bucket, whatever its
+    bytes (:func:`plan_buckets`)."""
     raw = os.environ.get("TORCHFT_WIRE_BUCKET_BYTES")
     if raw:
         try:
@@ -162,11 +177,23 @@ def flatten_buckets(
 
 
 def plan_buckets(
-    meta: Sequence[Tuple[np.dtype, int]], bucket_bytes: int = _DEFAULT_BUCKET_BYTES
+    meta: Sequence[Tuple[np.dtype, int]],
+    bucket_bytes: int = _DEFAULT_BUCKET_BYTES,
+    piece_of: Optional[Sequence[int]] = None,
 ) -> List[List[int]]:
-    """Group item indices into ~``bucket_bytes`` same-dtype buckets from
-    (dtype, nbytes) metadata alone — so the plan exists before any device
-    buffer has been pulled to host (the pipeline needs it up front)."""
+    """Group item indices, in the order given, into ~``bucket_bytes``
+    same-dtype buckets from (dtype, nbytes) metadata alone — so the plan
+    exists before any device buffer has been pulled to host (the pipeline
+    needs it up front), and is the same in every group. ``piece_of`` (each
+    item's piece, where the tree came in pieces): a piece's items of one
+    dtype are ONE bucket whatever their bytes — the piece is what becomes
+    ready at once, and a ring a leaf of it would be more and smaller rings
+    (PERF.md §6, PR 39: fewer and larger reads, never smaller)."""
+    if piece_of is not None:
+        by_piece: Dict[Tuple[int, np.dtype], List[int]] = {}
+        for i, ((dtype, _), piece) in enumerate(zip(meta, piece_of)):
+            by_piece.setdefault((piece, np.dtype(dtype)), []).append(i)
+        return list(by_piece.values())
     plan: List[List[int]] = []
     cur: List[int] = []
     cur_bytes = 0
@@ -363,6 +390,23 @@ def allreduce_gradients(
     Both scale by ``1/num_participants()`` and swallow errors into the
     Manager's latched state.
 
+    ``grads`` may still be being computed. A :class:`GradPieces`
+    (``TrainStep.grads`` as a chain of programs) crosses the host path a
+    piece a bucket, in the order given, which is the order the programs
+    finish: a piece's device-to-host copy starts when its program ends, so
+    the head's and the upper layers' gradients land while the chip computes
+    the layers below, and what follows the chip's last program is the last
+    pieces' landing and the rings (``pieces``, ``bytes_under_grads``,
+    ``bucket_landed_s``, ``bucket_ring_end_s`` and ``bucket_under_grads``
+    on the ``exchange`` span). Any other tree is one piece, bucketed by
+    ``bucket_bytes`` in its own order. What overlaps a ring: the next
+    bucket's landing (and pack), except on the plane ``cma``
+    (``manager.plane_info()``: the groups share one host's memory) where the
+    ring reads the landing arrays (``manager.takes_sources()``, no error
+    feedback) — there every bucket lands first, because a ring beside a
+    landing copy starves both, and only the H2D of the bucket before runs
+    beside a ring.
+
     ``error_feedback`` (a :class:`~torchft_tpu.wire_codec.ErrorFeedback`,
     host path only): each bucket is compensated with the committed
     residual, projected onto the wire codec's grid, and its fresh
@@ -376,6 +420,12 @@ def allreduce_gradients(
     if bucket_bytes is None:
         bucket_bytes = default_bucket_bytes()
     leaves, treedef = _leaves(grads)
+    # each leaf's piece, where the tree says it comes in pieces
+    piece_of_leaf = (
+        [k for k, piece in enumerate(grads) for _ in _leaves(piece)[0]]
+        if isinstance(grads, GradPieces)
+        else None
+    )
     # duck-typed managers (tests, benches) may have no step counter
     step = int(getattr(manager, "current_step", lambda: -1)())
 
@@ -386,7 +436,7 @@ def allreduce_gradients(
             counters: Dict[str, Any] = {}
         else:
             out, counters = _host_exchange(
-                manager, leaves, bucket_bytes, error_feedback, step
+                manager, leaves, bucket_bytes, error_feedback, step, piece_of_leaf
             )
         ru1 = resource.getrusage(resource.RUSAGE_SELF)
         # one syscall at each end, all threads of the process — the
@@ -398,8 +448,11 @@ def allreduce_gradients(
         )
         span.set(**counters)
         # an annotation takes its stats at entry: a zero-length one at exit
-        # carries the counters into the profiler's trace
-        with tracing.annotate("exchange.counters", **counters):
+        # carries the counters into the profiler's trace (but the by-bucket
+        # strings: there every bucket has events of its own)
+        with tracing.annotate(
+            "exchange.counters", **{k: v for k, v in counters.items() if not isinstance(v, str)}
+        ):
             pass
     return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -410,9 +463,11 @@ def _host_exchange(
     bucket_bytes: int,
     error_feedback: Optional[Any],
     step: int,
+    piece_of_leaf: Optional[Sequence[int]],
 ) -> Tuple[List[Any], Dict[str, Any]]:
     """The host path of :func:`allreduce_gradients`: averaged leaves, and
-    the step's sums for the ``exchange`` span."""
+    the step's sums for the ``exchange`` span. ``piece_of_leaf``: each
+    leaf's piece where the tree came in pieces, else None."""
     import jax
 
     from torchft_tpu.collectives import fill_from_sources, record_wire_stage
@@ -428,10 +483,22 @@ def _host_exchange(
     sums = {  # per step
         "buckets_reused": 0, "d2h_pages_kept": 0, "d2h_wait_s": 0.0, "pack_s": 0.0,
         "pack_bytes": 0, "pack_aliased_bytes": 0, "buckets_from_source": 0,
+        # bytes that had landed while the tree's last array was still to be
+        # computed: what the exchange moved under the backward
+        "bytes_under_grads": 0,
     }
+    t_start = time.perf_counter()
+    # by bucket, seconds into the exchange: its landing wait returned (main
+    # thread), its ring had ended (the thread that ran its scatter)
+    landed_s: List[float] = []
+    ring_end_s: List[float] = []
+    under_grads: List[int] = []  # by bucket: it landed while the chip still computed the tree
+    # a host array is there already
+    grads_done = getattr(leaves[-1], "is_ready", lambda: True) if leaves else (lambda: True)
     # whether the data plane reads a bucket's contribution where it landed
     # (duck-typed managers have no such reduction)
     takes_sources = getattr(manager, "takes_sources", lambda: False)
+    plane_info = getattr(manager, "plane_info", lambda: "")
     # the data plane's count of ops averaged inside its ring: its growth
     # over this exchange is how many buckets needed no division pass
     # (duck-typed managers have no such count)
@@ -479,7 +546,9 @@ def _host_exchange(
                 items.append(_Item(li, leaf, dtype, shape))
 
         plan = plan_buckets(
-            [(it.dtype, it.nbytes) for it in items], bucket_bytes
+            [(it.dtype, it.nbytes) for it in items],
+            bucket_bytes,
+            piece_of_leaf and [piece_of_leaf[it.leaf_pos] for it in items],
         )
         # the wait for what the last exchange was still placing is here:
         # microseconds, unless the step in between never ran its update
@@ -493,20 +562,29 @@ def _host_exchange(
 
     h2d_bytes: List[int] = []  # by bucket: what its scatter put
 
-    def _run_bucket(ordinal: int, idxs: List[int]):
-        # what ties this bucket's events together across threads
-        tags = {
-            "step": step,
-            "bucket": ordinal,
-            "bytes": sum(items[i].nbytes for i in idxs),
-        }
+    # what ties a bucket's events together across threads
+    bucket_tags = [
+        {"step": step, "bucket": ordinal, "bytes": sum(items[i].nbytes for i in idxs)}
+        for ordinal, idxs in enumerate(plan)
+    ]
 
+    def _land(ordinal: int, idxs: List[int]) -> List[np.ndarray]:
         # stage 1 (main thread): materialize this bucket's host buffers —
-        # blocks only on *this* bucket's D2H while earlier buckets are
-        # already riding the ring on the op thread
+        # blocks only on *this* bucket's D2H
+        tags = bucket_tags[ordinal]
         t0 = time.perf_counter()
         with tracing.annotate("exchange.d2h_wait", **tags):
             host = [np.asarray(items[i].src) for i in idxs]
+        t1 = time.perf_counter()
+        record_wire_stage("host_copy", t1 - t0)
+        sums["d2h_wait_s"] += t1 - t0
+        landed_s.append(t1 - t_start)
+        under_grads.append(int(not grads_done()))
+        sums["bytes_under_grads"] += under_grads[-1] * tags["bytes"]
+        return host
+
+    def _run_bucket(ordinal: int, idxs: List[int], host: List[np.ndarray]):
+        tags = bucket_tags[ordinal]
         t1 = time.perf_counter()
         # the bucket buffer always owns its memory: the ring writes (and
         # non-participants zero) it alone, never a view of the caller's
@@ -540,8 +618,7 @@ def _host_exchange(
                 buf if all(_put_copies(items[i].src) for i in idxs) else None
             )
         t2 = time.perf_counter()
-        record_wire_stage("host_copy", t2 - t0)
-        sums["d2h_wait_s"] += t1 - t0
+        record_wire_stage("host_copy", t2 - t1)
         sums["pack_s"] += t2 - t1
 
         if error_feedback is not None:
@@ -558,7 +635,7 @@ def _host_exchange(
             record_wire_stage("quantize", time.perf_counter() - t0)
 
         # stage 2 (op thread): quorum-managed ring allreduce of the bucket
-        with tracing.annotate("exchange.submit", **tags):
+        with tracing.annotate("exchange.submit", under_grads=under_grads[ordinal], **tags):
             if source is None:
                 fut = manager.allreduce_many([buf])
             else:
@@ -578,14 +655,16 @@ def _host_exchange(
         shapes = [items[i].shape for i in idxs]
         # one slot a bucket: scatter may run on either thread
         h2d_bytes.append(0)
+        ring_end_s.append(0.0)
 
         def scatter(f):
             # stage 3 (runs on the op thread as soon as this bucket's ring
-            # finishes, while the next bucket's ring occupies the wire — or
-            # inline on the main thread when the ring was done before the
-            # continuation was attached): slice the averaged buffer and
-            # dispatch H2D immediately
+            # finishes, before the next bucket's ring — or inline on the
+            # main thread when the ring was done before the continuation
+            # was attached): slice the averaged buffer and dispatch H2D
+            # immediately
             res = f.value()[0]
+            ring_end_s[ordinal] = time.perf_counter() - t_start
             parts = []
             off = 0
             with tracing.annotate("exchange.h2d", **tags):
@@ -601,8 +680,21 @@ def _host_exchange(
 
         return fut.then(scatter)
 
+    # On the plane `cma` a ring and a landing copy draw on ONE budget — the
+    # memory bandwidth of the host the groups share — and side by side they
+    # get less of it than one after the other: the first ring beside the
+    # other buckets' landings took 0.55 s for 412 MB and 0.12 s after them,
+    # and the landings twice their time (PERF.md §6, PR 59). So there, where
+    # the ring reads the landing arrays themselves, every bucket lands
+    # before the first is submitted; the landings still follow the programs
+    # that compute the pieces. Everywhere else a bucket rides the ring while
+    # the next lands, as before: across hosts (`tcp-striped`) the ring is
+    # the network's, and a packed bucket's (error feedback, the Python ring)
+    # pack is the main thread's own pass over the bytes, in between.
+    land_first = error_feedback is None and takes_sources() and plane_info() == "cma"
+    landed = [_land(ordinal, idxs) if land_first else None for ordinal, idxs in enumerate(plan)]
     bucket_futs = [
-        (idxs, _run_bucket(ordinal, idxs))
+        (idxs, _run_bucket(ordinal, idxs, landed[ordinal] or _land(ordinal, idxs)))
         for ordinal, idxs in enumerate(plan)
     ]
 
@@ -675,6 +767,11 @@ def _host_exchange(
         sums["pack_aliased_bytes"] += ring["copy_aliased_bytes"]
     return out, {
         "buckets": len(plan),
+        "pieces": piece_of_leaf[-1] + 1 if piece_of_leaf else 1,
+        # a bucket a number, comma-separated: an attribute is a scalar
+        "bucket_landed_s": ",".join(f"{t:.4f}" for t in landed_s),
+        "bucket_ring_end_s": ",".join(f"{t:.4f}" for t in ring_end_s),
+        "bucket_under_grads": ",".join(map(str, under_grads)),
         "bytes_d2h": sum(it.nbytes for it in items),
         "h2d_bytes": sum(h2d_bytes),
         "tail_wait_s": tail_wait_s,

@@ -984,11 +984,7 @@ class Manager:
                     # dashboard — lets an operator spot a group that fell
                     # back to a slower plane (e.g. CMA broken-latch
                     # converging everyone to TCP)
-                    plane=(
-                        self._collectives.plane_info()
-                        if hasattr(self._collectives, "plane_info")
-                        else type(self._collectives).__name__
-                    ),
+                    plane=self.plane_info(),
                     # piggybacked telemetry: counters digest + span batch
                     # for the lighthouse's /cluster.json and merged /trace
                     telemetry_payload=self._telemetry_payload(),
@@ -1327,6 +1323,14 @@ class Manager:
         packing them."""
         fn = getattr(self._collectives, "takes_sources", None)
         return bool(fn()) if callable(fn) else False
+
+    def plane_info(self) -> str:
+        """Which transport carries large allreduces this epoch
+        (``Collectives.plane_info``: ``cma`` — the groups share one host's
+        memory —, ``tcp-striped``, ``python-ring``, ...); ``ddp`` orders its
+        landings and rings by it."""
+        fn = getattr(self._collectives, "plane_info", None)
+        return str(fn()) if callable(fn) else type(self._collectives).__name__
 
     def avg_in_ring_ops(self) -> int:
         """The data plane's count of allreduces whose average was taken

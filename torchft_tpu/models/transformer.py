@@ -1937,7 +1937,13 @@ def loss_and_stats(
         # activations psum the plain forward() pays is for logits
         # consumers, not the training loop
         return _pipelined_loss(params, tokens, cfg, mesh), {}
-    x, aux = _hidden_states(params, tokens, cfg, mesh)
+    return _loss_of_hidden(params, *_hidden_states(params, tokens, cfg, mesh), tokens, cfg, mesh)
+
+
+def _loss_of_hidden(params: Dict[str, Any], x: jnp.ndarray, aux, tokens: jnp.ndarray, cfg: TransformerConfig, mesh=None):
+    """:func:`loss_and_stats` of what :func:`_hidden_states` gave: the head
+    (``params["out"]``; an exit gate's and a multi-token-prediction module's
+    leaves where the model has them) and what the layers said (``aux``)."""
     stats = {}
     if cfg.exit_gate:
         ce, stats = _exit_loss(params, x, tokens, cfg, mesh)
@@ -1962,6 +1968,112 @@ def loss_and_stats(
     if cfg.router_aux_loss_coef:
         ce = ce + cfg.router_aux_loss_coef * balance
     return ce, stats
+
+
+def cuts_by_layer(cfg: TransformerConfig) -> bool:
+    """Whether the backward can be cut where the stack's scan iterates
+    (:func:`grads_chain`): layers of one kind scanned once under one stage,
+    and nothing beside the head that reads the stack's last state. A declared
+    pattern, a looped stack, ``pp`` > 1 and a multi-token-prediction module
+    stay ONE program."""
+    return _of_one_kind(cfg) and max(cfg.pp, 1) == 1 and cfg.ut_steps == 1 and not cfg.n_mtp_modules
+
+
+def _closure_own(vjp_fn, inputs):
+    """(the leaves of a ``jax.vjp`` closure, their treedef, the positions of
+    the leaves that are neither an input of the differentiated call nor a
+    constant of its trace): what a forward hands its backward beside the
+    inputs — under :func:`_remat` the values its policy keeps, without it
+    every residual. Two traces of one call at equal shapes give one answer."""
+    leaves, treedef = jax.tree_util.tree_flatten(vjp_fn)
+    given = {id(a) for a in jax.tree_util.tree_leaves(inputs)}
+    own = [i for i, leaf in enumerate(leaves) if isinstance(leaf, jax.core.Tracer) and id(leaf) not in given]
+    return leaves, treedef, own
+
+
+def grads_chain(cfg: TransformerConfig, mesh=None):
+    """``jax.value_and_grad`` of :func:`loss_and_stats` as a chain of L + 2
+    calls of three functions, for a stack that :func:`cuts_by_layer`: the same
+    operations a step, cut where the scan's backward iterates, so that a piece
+    of the gradient exists when ITS call ends and not when the last one does
+    (``TrainStep.grads``; ``ddp`` has a piece landing on the host while the next
+    is computed).
+
+    * ``head(params, tokens)`` -> ``(loss, stats, top, (dx, kept))``: embedding,
+      the stack's forward keeping what ``_remat`` keeps (each layer's input and
+      what the policy names, stacked over the layers), final norm, the head's
+      forward and backward. ``top`` is the gradient of ``final_norm`` and
+      ``out``, ``dx`` the cotangent at the top of the stack.
+    * ``layer(layers, l, kept, dx)`` -> ``(layer l's gradients, dx below)``, from
+      the last layer to the first: the scan's backward body — the checkpoint's
+      second forward, then the backward — on the stored leaves' row ``l``. Each
+      gradient leaf keeps the stacked leaf's leading axes, as [1, 1, ...].
+    * ``tail(embed, tokens, dx)`` -> the embedding's gradient.
+
+    The layer's ``jax.vjp`` closure is traced in ``head`` and in ``layer``
+    alike; ``head`` hands on the leaves of it that are its own
+    (:func:`_closure_own`) and ``layer`` puts row ``l`` of them in the place of
+    its own trace's, whose forward is then dead code: the kernel's output and
+    row statistics are computed once a layer a step, as under the scan.
+    :func:`grads_of_pieces` stacks the pieces into the parameters' tree.
+
+    Returns ``(head, layer, tail, spec)``; ``spec`` is the partition of the
+    cotangent the calls hand on — the activations' own, as the layers
+    constrain them."""
+    layer_fn = _remat(cfg, _make_layer_fn(cfg, mesh))
+    dt = cfg.dtype
+    row = lambda a, l: jax.lax.dynamic_index_in_dim(a, l, keepdims=False)
+
+    def head(params, tokens):
+        x = _embed_lookup(params, tokens, dt)
+        stage = jax.tree_util.tree_map(lambda a: a[0], _compute_dtype(params["layers"], dt))
+
+        def body(x, lp):
+            (y, aux), vjp_fn = jax.vjp(layer_fn, x, lp)
+            leaves, _, own = _closure_own(vjp_fn, (x, lp))
+            return y, (x, aux, [leaves[i] for i in own])
+
+        x, (xs, aux, own) = jax.lax.scan(body, x, stage)
+
+        def top(x, aux, final_norm, out):
+            h = _norm(cfg, x, final_norm.astype(dt))
+            return _loss_of_hidden({"out": out}, h, aux, tokens, cfg, mesh)
+
+        loss, vjp_fn, stats = jax.vjp(top, x, aux, params["final_norm"], params["out"], has_aux=True)
+        dx, daux, d_norm, d_out = vjp_fn(jnp.ones_like(loss))
+        # what the layers say in whole numbers (an expert's count of tokens) carries no cotangent
+        daux = {name: d for name, d in daux.items() if d.dtype != jax.dtypes.float0}
+        return loss, stats, {"final_norm": d_norm, "out": d_out}, (dx, (xs, own, daux))
+
+    def layer(layers, l, kept, dx):
+        xs, own_rows, daux = kept
+        stored = jax.tree_util.tree_map(lambda a: row(a[0], l), layers)
+        x, lp = row(xs, l), _compute_dtype(stored, dt)
+        (_, aux), vjp_fn = jax.vjp(layer_fn, x, lp)
+        leaves, treedef, own = _closure_own(vjp_fn, (x, lp))
+        if [(leaves[i].shape, leaves[i].dtype) for i in own] != [(a.shape[1:], a.dtype) for a in own_rows]:
+            raise AssertionError("grads_chain: the layer's closure traced differently in head and in layer")
+        for i, rows in zip(own, own_rows):
+            leaves[i] = row(rows, l)
+        d_aux = {name: row(daux[name], l) if name in daux else np.zeros(a.shape, jax.dtypes.float0) for name, a in aux.items()}
+        dx, d_lp = jax.tree_util.tree_unflatten(treedef, leaves)((dx, d_aux))
+        # the cast to the compute dtype, transposed: the gradient arrives in the stored leaf's dtype
+        return jax.tree_util.tree_map(lambda g, p: g.astype(p.dtype)[None, None], d_lp, stored), dx
+
+    def tail(embed, tokens, dx):
+        _, vjp_fn = jax.vjp(lambda e: _embed_lookup({"embed": e}, tokens, dt), embed)
+        return {"embed": vjp_fn(dx)[0]}
+
+    return head, layer, tail, _act_spec()
+
+
+def grads_of_pieces(pieces):
+    """The parameters' tree of the gradient :func:`grads_chain` gave in pieces
+    (``top``, layer L-1's ... layer 0's, the embedding's, in that order): the
+    layers' rows concatenated along the stacked leaves' second axis."""
+    top, *layers, bottom = pieces
+    stacked = jax.tree_util.tree_map(lambda *rows: jnp.concatenate(rows, axis=1), *reversed(layers))
+    return {**top, "layers": stacked, **bottom}
 
 
 _MTP = "mtp"  # the name the module's ops carry inside their top-level scopes

@@ -196,7 +196,19 @@ class FTTrainer:
     def step(self, tokens) -> Tuple[float, bool]:
         """One fault-tolerant step: quorum → device grads → cross-group
         average (host) → commit gate → device update. Returns
-        (loss, committed)."""
+        (loss, committed).
+
+        ``grads`` only dispatches, so the exchange starts while the chip
+        computes. Where the stack can be cut a layer at a time ``grads`` is
+        a chain of programs and its gradient comes in pieces, the head's
+        first (``TrainStep.grads``): a piece's device-to-host copy starts as
+        its program ends, so the gradients land on the host while the chip
+        runs the layers below, and what the step adds to the backward is the
+        last pieces' landing and the rings. Any other stack is one program:
+        the exchange's first landing wait is the wait for all of it, and
+        nothing of the exchange lies under the backward. Either way every
+        bucket is awaited before ``should_commit``'s result is used and
+        before ``apply``."""
         mgr = self._manager
         # the step's own timeline (docs/observability.md): one span per piece
         # of the step, in the Tracer ring and — as tft.<name> — in a profiler
