@@ -116,7 +116,7 @@ def kernel_call(q, k, v, bq, bk, heads, seq, lanes, group, scale):
     """``flash_attention``'s call on arrays already in the kernel's layout,
     K and V resident as it keeps them."""
     shape = (q.shape[0], seq, heads, lanes, lanes, scale, group, None)
-    return fa._flash(q, k, v, shape, (bq, bk * fa._resident_tiles(seq, bk), bk), True, fa._should_interpret())
+    return fa._flash(q, k, v, shape, (bq, bk * fa._resident_tiles(seq, bk, lanes), bk), True, fa._should_interpret())
 
 
 def padded_flash(bq, bk):

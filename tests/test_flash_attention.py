@@ -61,8 +61,8 @@ def test_grads_match():
         (1024, (256, 512), 1, 2048),
         (1024, (512, 256), 1, 2048),
         (512, (128, 256), 2, 2048),
-        (512, (256, 128), 1, 256),  # dq in two parts
-        (512, (128, 128), 1, 128),  # a k block a tile: the clamped index maps
+        (512, (256, 128), 1, 128),  # dq in two parts (128-lane heads keep twice _RESIDENT_KEYS rows: two blocks of 256)
+        (512, (128, 128), 1, 64),  # a k block a tile: the clamped index maps
     ],
     ids=str,
 )

@@ -93,6 +93,13 @@ __all__ = [
 ]
 
 
+# the mixers that are causal softmax attention over projected q, k, v: rotated and global, rotated under a band,
+# global without positions — one set of leaves, one function (``_mix_full``)
+_SOFTMAX_MIXERS = ("full", "window", "nope")
+# a gated feed-forward's activation, by ``TransformerConfig.expert_activation``
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -232,6 +239,15 @@ class TransformerConfig:
     yarn_attention_factor: float = 1.0  # cos and sin are scaled by it
     # the sigmoid gate's selection-only bias leaf; False => the k largest scores
     router_selection_bias: bool = True
+    # -- softmax layers WITHOUT positions: the layers named here are ``nope`` layers, global as ``full``
+    # ones (every key j <= i) with q and k as they are projected, whatever rotates the other kinds
+    nope_layers: Tuple[int, ...] = ()
+    # where an expert layer's gate reads: "ffn" — the feed-forward's own normed input, N2 of the state
+    # after the mixer, inside the feed-forward's checkpoint; "layer" — the LAYER's normed input N1(x),
+    # ahead of the mixer: the chosen experts and their weights are then inputs of the feed-forward part
+    router_input: str = "ffn"
+    # the gate's activation in the routed and the shared experts: "silu" | "relu" (dense layers keep SiLU)
+    expert_activation: str = "silu"
     # -- multi-token prediction: this many modules (0 or 1) behind the main
     # stack, each a layer of the last layer's kind that shares ``embed`` and
     # ``out``; the training loss is main + mtp_loss_weight x the module's cross
@@ -253,14 +269,18 @@ class TransformerConfig:
     exit_entropy_coef: float = 0.0
 
     def __post_init__(self) -> None:
-        for name in ("kda_layers", "gdn_layers", "mla_layers", "conv_layers", "window_layers", "n_heads_per_layer"):  # a JSON file gives lists
+        for name in (
+            "kda_layers", "gdn_layers", "mla_layers", "conv_layers", "window_layers", "nope_layers", "n_heads_per_layer",
+        ):  # a JSON file gives lists
             object.__setattr__(self, name, tuple(int(i) for i in getattr(self, name)))
-        named = self.kda_layers + self.gdn_layers + self.mla_layers + self.conv_layers + self.window_layers
+        named = (
+            self.kda_layers + self.gdn_layers + self.mla_layers + self.conv_layers + self.window_layers + self.nope_layers
+        )
         if len(set(named)) != len(named) or any(not 1 <= i <= self.n_layers for i in named):
             raise ValueError(
                 f"kda_layers {self.kda_layers}, gdn_layers {self.gdn_layers}, mla_layers {self.mla_layers}, "
-                f"conv_layers {self.conv_layers} and "
-                f"window_layers {self.window_layers} name layers 1..{self.n_layers}, each at most once"
+                f"conv_layers {self.conv_layers}, window_layers {self.window_layers} and "
+                f"nope_layers {self.nope_layers} name layers 1..{self.n_layers}, each at most once"
             )
         if self.linear_n_key_heads and (not self.gdn_layers or self.linear_n_heads % self.linear_n_key_heads):
             raise ValueError(
@@ -277,7 +297,7 @@ class TransformerConfig:
             raise ValueError(f"rotary_dim={self.rotary_dim}: an even number of a head's {self.head_dim} lanes")
         if self.n_heads_per_layer and len(self.n_heads_per_layer) < self.n_layers:
             raise ValueError(f"n_heads_per_layer has {len(self.n_heads_per_layer)} entries for {self.n_layers} layers")
-        for mixer in ("full", "window"):
+        for mixer in _SOFTMAX_MIXERS:
             heads = {self.layer_heads(i) for i, (m, _) in enumerate(self.layer_kinds(), 1) if m == mixer}
             if len(heads) > 1 or any(h % self.kv_heads for h in heads):
                 raise ValueError(
@@ -298,6 +318,17 @@ class TransformerConfig:
             )
         if (self.n_dense_layers or self.n_shared_experts or self.n_experts_held) and not self.n_experts:
             raise ValueError("n_dense_layers, n_shared_experts and n_experts_held describe a model with experts")
+        if self.router_input not in ("ffn", "layer"):
+            raise ValueError(f"router_input must be 'ffn'|'layer', got {self.router_input!r}")
+        if self.router_input == "layer" and not self.n_experts:
+            raise ValueError("router_input says where an expert layer's gate reads: it comes with n_experts")
+        if self.expert_activation not in _ACTIVATIONS:
+            raise ValueError(f"expert_activation must be one of {sorted(_ACTIVATIONS)}, got {self.expert_activation!r}")
+        if self.expert_activation != "silu" and (not self.n_experts or self.n_dense_layers):
+            raise ValueError(
+                f"expert_activation={self.expert_activation!r} is the experts' gate: it comes with n_experts, and a "
+                "model's leading dense layers (n_dense_layers) gate with SiLU — a dense layer's own activation is missing"
+            )
         if self.shared_expert_gate and not self.n_shared_experts:
             raise ValueError("shared_expert_gate scales the shared experts' output: it comes with n_shared_experts")
         if (self.q_lora_rank or self.mla_rope_theta) and not self.mla_layers:
@@ -330,7 +361,7 @@ class TransformerConfig:
         return self.n_heads_per_layer[layer - 1] if self.n_heads_per_layer else self.n_heads
 
     def mixer_heads(self, mixer: str) -> int:
-        """Query heads of the ``full`` or ``window`` layers (which agree)."""
+        """Query heads of the ``full``, ``window`` or ``nope`` layers (each kind's agree)."""
         for i, (m, _) in enumerate(self.layer_kinds(), 1):
             if m == mixer:
                 return self.layer_heads(i)
@@ -356,7 +387,8 @@ class TransformerConfig:
                 "kda" if i in self.kda_layers else "gdn" if i in self.gdn_layers
                 else "mla" if i in self.mla_layers
                 else "conv" if i in self.conv_layers
-                else "window" if i in self.window_layers else "full"
+                else "window" if i in self.window_layers
+                else "nope" if i in self.nope_layers else "full"
             )
             ff = "experts" if self.n_experts and i > self.n_dense_layers else "dense"
             kinds.append((mixer, ff))
@@ -457,7 +489,7 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
     layers: Dict[str, Any] = {"ln1": unit(d), "ln2": unit(d)}
     if cfg.sandwich_norm:
         layers.update(post_ln1=unit(d), post_ln2=unit(d))
-    if mixer in ("full", "window"):
+    if mixer in _SOFTMAX_MIXERS:
         qkv = cfg.mixer_heads(mixer) * cfg.head_dim
         kv = cfg.kv_heads * cfg.head_dim
         layers.update(
@@ -650,7 +682,7 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
     layers: Dict[str, Any] = {"ln1": spec(None), "ln2": spec(None)}
     if cfg.sandwich_norm:
         layers.update(post_ln1=spec(None), post_ln2=spec(None))
-    if mixer in ("full", "window"):
+    if mixer in _SOFTMAX_MIXERS:
         layers.update(wq=row, wk=row, wv=row, wo=col)
         if cfg.qk_norm_per_head:
             layers.update(q_norm=spec(None), k_norm=spec(None))  # one head wide, whole on every chip
@@ -781,53 +813,87 @@ def _held_row_bound(cfg: TransformerConfig, rows: int) -> int:
     return min(rows, -(-int(2 * at_balance) // 512) * 512)
 
 
-def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig):
+def _router_load(cfg: TransformerConfig, top_idx: jnp.ndarray, probs: jnp.ndarray, counts=None):
+    """(the balance term ``E·Σ_e f_e·P_e`` over the call's tokens, tokens per
+    expert [E] int32 — counted here from ``top_idx`` [T, k] unless given)."""
+    if counts is None:
+        flat = top_idx.reshape(-1)
+        counts = jnp.sum(flat[:, None] == jnp.arange(cfg.n_experts, dtype=flat.dtype), axis=0, dtype=jnp.int32)
+    frac = counts.astype(jnp.float32) / top_idx.shape[0]  # sums to k; no gradient
+    return cfg.n_experts * jnp.sum(frac * jnp.mean(probs, axis=0)), counts
+
+
+def _gate_ahead(lp: Dict[str, Any], h: jnp.ndarray, cfg: TransformerConfig):
+    """The router's gate under ``router_input`` "layer": :func:`_route` of the
+    layer's normed input ``h`` [B, S, d], ahead of the mixer. Returns ((weights
+    [T, k] as they are applied, experts [T, k]) — what :func:`_ffn_moe` takes as
+    ``gate`` — and (balance term, tokens per expert [E])). The weights carry
+    their gradient back through ``h`` into the layer's input, beside the mixer's."""
+    tokens = h.reshape(-1, h.shape[-1])
+    with jax.named_scope("router"):
+        top_w, top_idx, probs = _route(lp, tokens, cfg)
+        return (top_w, top_idx), _router_load(cfg, top_idx, probs)
+
+
+def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig, gate=None):
     """Sparse experts, dropless: the router's gate (:func:`_route`) picks k
     experts a token, every chosen expert counted whatever its load; under a
     share (``n_experts_held``) the experts held here compute their part and
     what the absent ones would add is left out; a shared expert is a dense
-    SwiGLU beside them, scaled a token by ``sigmoid(h·shared_scale)`` under
-    ``shared_expert_gate``. Returns (y, (balance term ``E·Σ_e f_e·P_e`` over the
-    call's tokens, tokens per expert [E]), then under a share the rows held, then
-    under ``shared_expert_gate`` that gate's mean: :func:`_moe_said` names them)."""
+    gated FFN beside them, scaled a token by ``sigmoid(h·shared_scale)`` under
+    ``shared_expert_gate``. ``gate``: the (weights, experts) that
+    :func:`_gate_ahead` chose ahead of the mixer — the router is then not run
+    here, and its load is not said here. Returns (y, (balance term
+    ``E·Σ_e f_e·P_e`` over the call's tokens, tokens per expert [E]) unless
+    ``gate`` is given, then under a share the rows held, under a ReLU the share
+    of the computed rows' gate lanes it zeroed, under ``shared_expert_gate``
+    that gate's mean: :func:`_moe_said` names them)."""
     b, s, d = x.shape
     tokens = x.reshape(b * s, d)
-    with jax.named_scope("router"):
-        top_w, top_idx, probs = _route(lp, tokens, cfg)
+    if gate is None:
+        with jax.named_scope("router"):
+            top_w, top_idx, probs = _route(lp, tokens, cfg)
+    else:
+        top_w, top_idx = gate
+    # an activation that leaves lanes exactly zero says how many
+    act = dict(activation=_ACTIVATIONS[cfg.expert_activation], gate_zeros=cfg.expert_activation != "silu")
     if cfg.n_experts_held:
-        y, held = moe_dropless_held(
+        y, held, *zeroed = moe_dropless_held(
             tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"],
             first_expert=cfg.expert_share_index * cfg.n_experts_held,
             row_bound=_held_row_bound(cfg, b * s * cfg.top_k),
+            **act,
         )
-        with jax.named_scope("router"):
-            flat = top_idx.reshape(-1)
-            counts = jnp.sum(
-                flat[:, None] == jnp.arange(cfg.n_experts, dtype=flat.dtype), axis=0, dtype=jnp.int32
-            )
-        more = (held,)
+        counts = None
+        more = (held, *zeroed)
     else:
-        y, counts = moe_dropless(
-            tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"]
+        y, counts, *zeroed = moe_dropless(
+            tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"], **act
         )
-        more = ()
-    with jax.named_scope("router"):
-        frac = counts.astype(jnp.float32) / (b * s)  # sums to k; no gradient
-        balance = cfg.n_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
+        more = tuple(zeroed)
+    if gate is None:
+        with jax.named_scope("router"):
+            more = _router_load(cfg, top_idx, probs, counts) + more
     if cfg.n_shared_experts:
         with jax.named_scope("shared"):
-            shared = swiglu(tokens, lp["shared_gate"], lp["shared_in"], lp["shared_out"])
+            shared = swiglu(
+                tokens, lp["shared_gate"], lp["shared_in"], lp["shared_out"], _ACTIVATIONS[cfg.expert_activation]
+            )
             if cfg.shared_expert_gate:
-                gate = jax.nn.sigmoid(jnp.dot(tokens, lp["shared_scale"], preferred_element_type=jnp.float32))
-                shared = shared * gate.astype(x.dtype)
-                more += (jnp.mean(gate),)
+                gate_s = jax.nn.sigmoid(jnp.dot(tokens, lp["shared_scale"], preferred_element_type=jnp.float32))
+                shared = shared * gate_s.astype(x.dtype)
+                more += (jnp.mean(gate_s),)
             y = y + shared
-    return y.reshape(b, s, d), (balance, counts) + more
+    return y.reshape(b, s, d), more
 
 
-def _moe_said(cfg: TransformerConfig, aux) -> Dict[str, jnp.ndarray]:
-    """What :func:`_ffn_moe` says beside its output, by name."""
-    names = ("balance", "counts") + ("held",) * bool(cfg.n_experts_held) + ("shared_gate",) * cfg.shared_expert_gate
+def _moe_said(cfg: TransformerConfig, aux, gate_ahead: bool = False) -> Dict[str, jnp.ndarray]:
+    """What :func:`_ffn_moe` says beside its output, by name (``gate_ahead``:
+    it was handed its gate, and the router's load is :func:`_gate_ahead`'s to say)."""
+    names = (
+        ("balance", "counts") * (not gate_ahead) + ("held",) * bool(cfg.n_experts_held)
+        + ("gate_zeros",) * (cfg.expert_activation != "silu") + ("shared_gate",) * cfg.shared_expert_gate
+    )
     return dict(zip(names, aux))
 
 
@@ -1135,6 +1201,19 @@ def _say_gdn_core_path(core: str, batch: int, block: int, cfg: TransformerConfig
     _say_once("gdn_core_path", tuple(fields.values()), **fields)
 
 
+def _say_expert_path(cfg: TransformerConfig, batch: int, seq_len: int) -> None:
+    """One ``expert_path`` event and INFO line per traced shape of a model whose
+    expert layers take their gate from the layer's input or gate with another
+    activation than SiLU: where the router reads, which activation the experts
+    use, and how many of the router's experts are held."""
+    fields = dict(
+        router_input=cfg.router_input, activation=cfg.expert_activation, router_gate=cfg.router_gate,
+        renormalize=cfg.router_renormalize, experts=cfg.n_experts, experts_held=cfg.experts_held, top_k=cfg.top_k,
+        batch=batch, seq=seq_len,
+    )
+    _say_once("expert_path", tuple(fields.values()), **fields)
+
+
 def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None:
     """One ``layer_pattern`` event and INFO line per traced shape of a model
     with a declared pattern: what the stack unrolls and what it scans."""
@@ -1212,17 +1291,22 @@ def _causal_core(cfg, mesh, sp_manual, q, k, v, widths=None, scope="core", windo
 
 def _declares_kinds(cfg: TransformerConfig) -> bool:
     """Whether the model declares what makes its softmax layers differ by
-    kind: a band, grouped heads, heads by layer or a table of frequencies."""
+    kind: a band, layers without positions, grouped heads, heads by layer or a
+    table of frequencies."""
     return bool(
-        cfg.window_layers or cfg.n_kv_heads or cfg.n_heads_per_layer or cfg.rope_pairing != "interleaved"
+        cfg.window_layers or cfg.nope_layers or cfg.n_kv_heads or cfg.n_heads_per_layer
+        or cfg.rope_pairing != "interleaved"
     )
 
 
-def _rotation(cfg: TransformerConfig, mixer: str) -> Dict[str, Any]:
+def _rotation(cfg: TransformerConfig, mixer: str) -> Optional[Dict[str, Any]]:
     """``rotary_embed``'s arguments for a ``full`` or ``window`` layer: the
     one ``rope_theta`` with today's lane pairing, or under ``rope_pairing``
     "half" the kind's own table — window layers the whole head at their own
-    base, global layers ``rotary_dim`` lanes under YaRN."""
+    base, global layers ``rotary_dim`` lanes under YaRN. None for a ``nope``
+    layer: q and k go to the core as they were projected."""
+    if mixer == "nope":
+        return None
     if cfg.rope_pairing != "half":
         return {"theta": cfg.rope_theta}
     if mixer == "window":
@@ -1235,9 +1319,11 @@ def _rotation(cfg: TransformerConfig, mixer: str) -> Dict[str, Any]:
 
 
 def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
-    """Softmax attention with positions: ``full`` (global) or ``window``
-    (banded), each kind with its own query heads over the model's key/value
-    heads and its own rotation; under ``attn_output_gate`` the query
+    """Softmax attention: ``full`` (global) or ``window`` (banded), each kind
+    with its own query heads over the model's key/value heads and its own
+    rotation, or ``nope`` (global, no positions: nothing is rotated; in the
+    trace it is ``global`` too, and its ``attention_path`` line says
+    ``rotary_dim`` 0); under ``attn_output_gate`` the query
     projection carries a gate a lane, ``[q | gate]`` head by head, and the
     core's output goes through ``sigmoid(gate)`` (scope ``out_gate``)."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
@@ -1266,11 +1352,14 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
             k = _norm(cfg, k, lp["k_norm"])
         v = (h @ lp["wv"]).reshape(b, s, kv_heads, cfg.head_dim)
         rotation = _rotation(cfg, mixer)
-        q = rotary_embed(q, positions, **rotation)
-        k = rotary_embed(k, positions, **rotation)
+        if rotation is not None:
+            q = rotary_embed(q, positions, **rotation)
+            k = rotary_embed(k, positions, **rotation)
         said = {}
         if name:
-            rotated = 2 * len(rotation["inv_freq"]) if "inv_freq" in rotation else cfg.head_dim
+            rotated = (
+                0 if rotation is None else 2 * len(rotation["inv_freq"]) if "inv_freq" in rotation else cfg.head_dim
+            )
             window = cfg.window if mixer == "window" else 0
             said = dict(scope=name + "_core", window=window, kind=(heads, kv_heads, window, rotated))
         att = _causal_core(cfg, mesh, sp_manual, q, k, v, **said).reshape(b, s, heads * cfg.head_dim)
@@ -1519,6 +1608,21 @@ def _make_layer_fn(
             "a token's rows to the chips holding its experts, and their outputs back, is missing, "
             "and a share held on one chip does not stand in for it"
         )
+    gate_ahead = ff == "experts" and cfg.router_input == "layer"
+    if experts_over_chips and (gate_ahead or cfg.expert_activation != "silu"):
+        raise ValueError(
+            f"ep={ep_size} with router_input={cfg.router_input!r}, expert_activation={cfg.expert_activation!r}: experts over "
+            "chips still run the top-2 capacity dispatch (_ffn_moe_ep), which routes on its own input inside the "
+            "feed-forward and gates with SiLU; a dispatch over ep that takes a gate it is given (the chosen experts "
+            "and weights as inputs of the all-to-all) and an activation argument are missing"
+        )
+    pp_size = max(cfg.pp, mesh.shape.get("pp", 1) if mesh is not None else 1)
+    if pp_size > 1 and ff == "experts" and (gate_ahead or cfg.expert_activation != "silu"):
+        raise ValueError(
+            f"pp={pp_size} with router_input={cfg.router_input!r}, expert_activation={cfg.expert_activation!r}: the "
+            "dropless experts' grouped matmul (ops/layers._grouped_matmul) is a Pallas call, which the pipeline's "
+            "manual region cannot trace; a grouped matmul typed for that region (its outputs' varying mesh axes) is missing"
+        )
     if mixer in ("kda", "gdn") and sp_size > 1:
         raise ValueError(
             f"sp={sp_size} with a {mixer} layer: the recurrent state at a sequence shard's start is the "
@@ -1534,13 +1638,13 @@ def _make_layer_fn(
 
     def of_input(fn, norm: str):
         """``fn(lp, h)`` itself, or under ``from_input`` as a function of the
-        stored leaves and the layer's input."""
+        stored leaves and the layer's input (and of what else it takes by name)."""
         if not from_input:
             return fn
 
-        def whole(lp, x):
+        def whole(lp, x, **more):
             lp = _compute_dtype(lp, cfg.dtype)
-            return fn(lp, _norm(cfg, x, lp[norm]))
+            return fn(lp, _norm(cfg, x, lp[norm]), **more)
 
         return whole
 
@@ -1551,8 +1655,8 @@ def _make_layer_fn(
         if not cfg.sandwich_norm:
             return fn
 
-        def whole(lp, h):
-            out = fn(lp, h)
+        def whole(lp, h, **more):
+            out = fn(lp, h, **more)
             y, rest = (out[0], out[1:]) if isinstance(out, tuple) else (out, ())
             with jax.named_scope("post_norm"):
                 y = _norm(cfg, y, lp[norm])
@@ -1570,17 +1674,26 @@ def _make_layer_fn(
         """(x, aux): aux by name — what :func:`_ffn_moe` says of a dropless
         expert layer (:func:`_moe_said`), what :func:`_mix_gdn` says of its
         mixer — {} otherwise."""
-        aux = {}
+        aux, gate = {}, {}
         x = _constrain(x, _act_spec(sp_manual))
+        if ff == "experts" and (gate_ahead or cfg.expert_activation != "silu"):
+            _say_expert_path(cfg, *x.shape[:2])
         with _scopes("attn", nested):
             h = x if from_input else _norm(cfg, x, lp["ln1"])
-            if mixer in ("full", "window"):
+        if gate_ahead:
+            # the gate is a function of the layer's input, as the mixer is: a part of its own ahead of the mixer, its
+            # [T, k] weights and experts kept for the feed-forward part (inputs of its checkpoint, not recomputed in it)
+            with _scopes("moe", nested):
+                chosen, load = part(of_input(functools.partial(_gate_ahead, cfg=cfg), "ln1"))(lp, h)
+                gate, aux = {"gate": chosen}, dict(zip(("balance", "counts"), load))
+        with _scopes("attn", nested):
+            if mixer in _SOFTMAX_MIXERS:
                 x = x + part(mix(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer)))(lp, h)
             elif mixer == "kda":
                 x = x + mix(functools.partial(_mix_kda, cfg))(lp, h)
             elif mixer == "gdn":
-                y, aux = mix(functools.partial(_mix_gdn, cfg))(lp, h)
-                x = x + y
+                y, said = mix(functools.partial(_mix_gdn, cfg))(lp, h)
+                x, aux = x + y, {**aux, **said}
             elif mixer == "conv":
                 x = x + part(mix(_mix_conv))(lp, h)
             else:
@@ -1591,8 +1704,8 @@ def _make_layer_fn(
             if experts_over_chips:
                 x = x + feed(functools.partial(_ffn_moe_ep, cfg=cfg))(lp, h)
             elif ff == "experts":
-                y, said = part(feed(functools.partial(_ffn_moe, cfg=cfg)))(lp, h)
-                x, aux = x + y, {**aux, **_moe_said(cfg, said)}
+                y, said = part(feed(functools.partial(_ffn_moe, cfg=cfg)))(lp, h, **gate)
+                x, aux = x + y, {**aux, **_moe_said(cfg, said, gate_ahead)}
             else:
                 x = x + part(feed(_ffn_dense))(lp, h)
         return _constrain(x, _act_spec(sp_manual)), aux
@@ -1914,7 +2027,8 @@ def loss_and_stats(
     statistics are ``{}`` for a model without dropless experts, else
     ``tokens_per_expert`` [L, E] int32 and ``balance_loss`` (the mean over
     layers of E·Σ_e f_e·P_e, before the coefficient), under a share
-    ``rows_held`` [L], under ``shared_expert_gate`` ``shared_gate_mean`` [L];
+    ``rows_held`` [L], under ReLU-gated experts ``gate_zero_share`` [L], under
+    ``shared_expert_gate`` ``shared_gate_mean`` [L];
     of gdn mixers ``gdn_decay_min`` and ``gdn_beta_mean`` [their layers];
     of a looped stack with an exit gate ``exit_probs`` [T] (each exit's
     probability, mean over the supervised tokens), ``exit_entropy`` and
@@ -1965,6 +2079,8 @@ def _loss_of_hidden(params: Dict[str, Any], x: jnp.ndarray, aux, tokens: jnp.nda
     stats.update(tokens_per_expert=aux["counts"], balance_loss=balance)
     if "held" in aux:  # under a share: the token-expert rows whose expert is held, a layer
         stats["rows_held"] = aux["held"]
+    if "gate_zeros" in aux:  # of the computed rows' gate lanes, the share a ReLU left zero, a layer
+        stats["gate_zero_share"] = aux["gate_zeros"]
     if cfg.router_aux_loss_coef:
         ce = ce + cfg.router_aux_loss_coef * balance
     return ce, stats

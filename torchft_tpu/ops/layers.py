@@ -138,10 +138,23 @@ def rotary_embed(
     return _turn_pairs(x, *_pair_tables(positions, theta, x.shape[-1]))
 
 
-def swiglu(x: jnp.ndarray, w_gate: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray) -> jnp.ndarray:
-    """SwiGLU FFN: (silu(x@w_gate) * (x@w_in)) @ w_out."""
-    h = jax.nn.silu(x @ w_gate) * (x @ w_in)
+def swiglu(
+    x: jnp.ndarray, w_gate: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray, activation=jax.nn.silu
+) -> jnp.ndarray:
+    """A gated FFN: (activation(x@w_gate) * (x@w_in)) @ w_out — SwiGLU under
+    SiLU, ReGLU under ``jax.nn.relu``."""
+    h = activation(x @ w_gate) * (x @ w_in)
     return h @ w_out
+
+
+def _lanes_zeroed(gated: jnp.ndarray, live=None) -> jnp.ndarray:
+    """How many lanes of the activated gate ``gated`` [m, f] are exactly zero
+    (what a ReLU cuts), over the rows ``live`` [m] says are some expert's: a
+    float32 count, which carries no gradient."""
+    zero = gated == 0
+    if live is not None:
+        zero = zero & live[:, None]
+    return jnp.sum(zero, dtype=jnp.float32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -231,7 +244,9 @@ def moe_dropless(
     w_gate: jnp.ndarray,
     w_in: jnp.ndarray,
     w_out: jnp.ndarray,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    activation=jax.nn.silu,
+    gate_zeros: bool = False,
+) -> Tuple[jnp.ndarray, ...]:
     """Dropless top-k experts with static shapes: every one of a token's k
     experts contributes, whatever its load — no capacity, no drop.
 
@@ -240,7 +255,10 @@ def moe_dropless(
     [E, f, d]. The T·k token-expert rows are ordered by expert (stable
     sort), each expert's contiguous group goes through its three matrices
     (:func:`_grouped_matmul`), and the rows are put back, weighted and summed
-    per token. Returns (y [T, d], tokens per expert [E] int32).
+    per token. ``activation`` is the gate's (SiLU; ``jax.nn.relu`` for
+    ReLU-gated experts). Returns (y [T, d], tokens per expert [E] int32), and
+    under ``gate_zeros`` a third: the share of the rows' gate lanes that the
+    activation left exactly zero (float32).
 
     Scopes ``dispatch`` / ``experts`` / ``combine`` nest under the caller's
     ``moe`` (docs/observability.md)."""
@@ -254,13 +272,14 @@ def moe_dropless(
         counts = jnp.sum(flat[:, None] == jnp.arange(e, dtype=flat.dtype), axis=0, dtype=jnp.int32)
         rows = _take_rows(tokens, order, inv, k)  # [T·k, d]
     with jax.named_scope("experts"):
-        h = jax.nn.silu(_grouped_matmul(rows, w_gate, counts)) * _grouped_matmul(
-            rows, w_in, counts
-        )
+        gated = activation(_grouped_matmul(rows, w_gate, counts))
+        h = gated * _grouped_matmul(rows, w_in, counts)
         out = _grouped_matmul(h, w_out, counts)
     with jax.named_scope("combine"):
         out = _take_rows(out, inv, order, 1).reshape(t, k, -1)
         y = jnp.einsum("tkd,tk->td", out, top_w.astype(out.dtype))
+    if gate_zeros:
+        return y, counts, _lanes_zeroed(gated) / gated.size
     return y, counts
 
 
@@ -424,14 +443,18 @@ def moe_dropless_held(
     w_out: jnp.ndarray,
     first_expert: int,
     row_bound: int,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    activation=jax.nn.silu,
+    gate_zeros: bool = False,
+) -> Tuple[jnp.ndarray, ...]:
     """:func:`moe_dropless` for a layer that holds a contiguous block of the
     experts a router chooses among (a chip's share of a deployment that
     divides each layer's experts over chips): ``top_idx`` counts over ALL
     the router's experts, the weights are those of experts
     ``[first_expert, first_expert + H)``. What the absent experts would add
     is left out — their rows are neither gathered nor computed. Returns
-    (y [T, d], rows held = token-expert rows whose expert is here, int32).
+    (y [T, d], rows held = token-expert rows whose expert is here, int32), and
+    under ``gate_zeros`` a third: the share of the held rows' gate lanes that
+    ``activation`` left exactly zero (float32; 0 where no row is held).
 
     Static shapes without a drop: the row slots are sorted by held expert,
     absent ones last — a sort of the T·k keys, which carries each slot's
@@ -465,8 +488,9 @@ def moe_dropless_held(
         # whole windows: the slots added are past every held row, and masked with them
         order, gates = (jnp.pad(x, (0, -(t * k) % m)) for x in (order, gates))
 
-    def window(start) -> jnp.ndarray:
-        """What the sorted slots ``[start, start + m)`` add to y."""
+    def window(start):
+        """(what the sorted slots ``[start, start + m)`` add to y, under
+        ``gate_zeros`` the gate lanes of their held rows left zero, else ())."""
         with jax.named_scope("dispatch"):
             # the slots past the held ones are in no expert's group: the
             # grouped matmul leaves their rows as it found them (whatever the
@@ -483,22 +507,27 @@ def moe_dropless_held(
             edges = jnp.clip(jnp.concatenate([jnp.zeros((1,), ends.dtype), ends]), start, start + m)
             sizes = edges[1:] - edges[:-1]
         with jax.named_scope("experts"):
-            h = jax.nn.silu(_grouped_matmul(rows, w_gate, sizes)) * _grouped_matmul(rows, w_in, sizes)
+            gated = activation(_grouped_matmul(rows, w_gate, sizes))
+            h = gated * _grouped_matmul(rows, w_in, sizes)
             out = _grouped_matmul(h, w_out, sizes)
+            zeroed = _lanes_zeroed(gated, plan["live"]) if gate_zeros else ()
         with jax.named_scope("combine"):
-            return _combine_window_rows(out, top_w, plan, t)
-
-    if m == t * k:
-        return window(0), n_held
+            return _combine_window_rows(out, top_w, plan, t), zeroed
 
     def every_window():
-        def add(y, start):
-            return y + jax.checkpoint(window)(start), None
+        def add(total, start):
+            return jax.tree_util.tree_map(jnp.add, total, jax.checkpoint(window)(start)), None
 
-        y, _ = jax.lax.scan(add, jnp.zeros(tokens.shape, tokens.dtype), jnp.arange(0, t * k, m))
-        return y
+        nothing = (jnp.zeros(tokens.shape, tokens.dtype), jnp.float32(0.0) if gate_zeros else ())
+        return jax.lax.scan(add, nothing, jnp.arange(0, t * k, m))[0]
 
-    return jax.lax.cond(n_held <= m, lambda: window(0), every_window), n_held
+    if m == t * k:
+        y, zeroed = window(0)
+    else:
+        y, zeroed = jax.lax.cond(n_held <= m, lambda: window(0), every_window)
+    if gate_zeros:
+        return y, n_held, zeroed / (jnp.maximum(n_held, 1).astype(jnp.float32) * w_gate.shape[2])
+    return y, n_held
 
 
 def moe_dispatch(

@@ -12,7 +12,8 @@ of K and V in VMEM, and loops inside the step over tiles of ``block_k``
 keys — pallas double-buffers the next step's blocks against this one's
 compute; the running accumulators (acc/m/l; dk/dv) live in VMEM scratch
 that persists across the inner grid sweep (TPU grids execute sequentially
-per core). With the whole sequence resident (S <= ``_RESIDENT_KEYS``) the forward is one
+per core). With the whole sequence resident (S <= ``_RESIDENT_KEYS`` at 256-lane heads,
+twice that at 128 lanes and under: ``_resident_tiles``) the forward is one
 step a q block and the backward's dQ is complete within its step; longer
 sequences write dQ in one part per K block, summed outside.
 
@@ -437,18 +438,25 @@ def _flash_fwd(q, k, v, shape, blocks, causal, interpret):
 
 _flash.defvjp(_flash_fwd, _bwd)
 
-# Most rows of K and V a grid step keeps in VMEM. A sequence up to this long
-# is one K block: the backward's dQ is then complete within its step, where a
-# longer one writes a float32 part per K block ([4, B, S, H·D] = 2.1 GB at
-# s8192 under the 2048 this was until PR 35) and sums them outside.
+# Most rows of K and V a grid step keeps in VMEM, for heads of TWO lane tiles
+# (256 lanes: ``qwen3-next-80b-a3b-1g``'s 8 192 keys, which compile and load, PR
+# 54); heads of one lane tile keep twice as many rows in the same bytes (16 384
+# keys of 128 lanes: ``smallthinker-21b-a3b-1g`` at its full context). A
+# sequence up to that long is one K block: the backward's dQ is then complete
+# within its step, where a longer one writes a float32 part per K block
+# ([4, B, S, H·D] = 2.1 GB at s8192 under the 2048 this was until PR 35; [2, B,
+# S, H·D] = 0.94 GB a layer at b2 x s16384 x 28 heads of 128 under a limit in
+# rows alone) and sums them outside.
 _RESIDENT_KEYS = 8192
 
 
-def _resident_tiles(s: int, bkc: int) -> int:
+def _resident_tiles(s: int, bkc: int, lanes: int = 2 * _LANES) -> int:
     # K and V arrive in the largest whole number of tiles that divides S and
-    # stays under _RESIDENT_KEYS rows: fewer grid steps and DMAs than a tile
-    # a step, and up to _RESIDENT_KEYS the backward's dq needs no second pass
-    return max(m for m in range(1, max(_RESIDENT_KEYS // bkc, 1) + 1) if (s // bkc) % m == 0)
+    # stays under the resident rows of heads ``lanes`` wide (the wider of keys
+    # and values, in whole lane tiles): fewer grid steps and DMAs than a tile
+    # a step, and up to there the backward's dq needs no second pass
+    rows = _RESIDENT_KEYS * max(1, 2 * _LANES // (-(-lanes // _LANES) * _LANES))
+    return max(m for m in range(1, max(rows // bkc, 1) + 1) if (s // bkc) % m == 0)
 
 
 def flash_attention(
@@ -497,7 +505,7 @@ def flash_attention(
         raise ValueError(f"seq len {s} must be a multiple of block sizes ({bq},{bkc})")
     if interpret is None:
         interpret = _should_interpret()
-    blocks = (bq, bkc * _resident_tiles(s, bkc), bkc)
+    blocks = (bq, bkc * _resident_tiles(s, bkc, max(d, dv)), bkc)
 
     if d % _LANES == 0 and dv % _LANES == 0:
         # a head's columns are whole lane tiles of the [B, S, H·Dh] view

@@ -123,6 +123,8 @@ class FTTrainer:
             rows_routed=rows_routed,
             rows_held=int(np.asarray(stats["rows_held"]).sum()) if "rows_held" in stats else rows_routed,
         )
+        if "gate_zero_share" in stats:  # ReLU-gated experts: of the computed rows' gate lanes, the share left zero
+            counters["gate_zero_share"] = float(np.mean(stats["gate_zero_share"]))
         sync_span.set(**counters)
         # an annotation takes its stats at entry: a zero-length one carries them
         with tracing.annotate("moe.counters", **counters):

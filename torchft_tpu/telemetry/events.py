@@ -79,6 +79,7 @@ CANONICAL_EVENTS = (
     "layer_pattern",
     "gdn_core_path",
     "loop_shape",
+    "expert_path",
 )
 
 # The protocol-lifecycle subset of the vocabulary: the events the
