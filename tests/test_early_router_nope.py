@@ -517,9 +517,13 @@ def test_a_dp_x_fsdp_x_tp_mesh_gives_the_unsharded_loss():
 # window and global kinds, grouped heads and ``moe_dropless_held``; qwen3-next-80b-a3b-1g ``_route``'s renormalised
 # softmax, a shared expert and its gate. (The nine cells' jaxprs at their REAL sizes are held to their parents' by
 # ``CELLS_PROGRAMS`` in tests/test_gated_conv_train.py, test_gdn_train.py, test_mla_rope_mtp_train.py and
-# test_looped_train.py, which this PR leaves as they are.)
+# test_looped_train.py, which this PR leaves as they are.) laguna-xs2-1g re-pinned by the PR that took
+# ``jnp.take_along_axis`` out of ``_route``'s sigmoid branch (30c86893…85fd at b50bcfa and before): a ``gather`` a sparse
+# layer a forward pass and its ``scatter-add`` gone, ``_chosen``'s compare, select and sum over the experts in their
+# place — old counts against new in ``tests/test_gdn_train.CELLS_PROGRAMS``' comment; qwen3-next-80b-a3b-1g's softmax
+# gate reads ``top_k``'s own values and did not move.
 REHEARSAL_PROGRAMS = {
-    "laguna-xs2-1g": "30c86893c64bae3ffcbd7f877a2ce3cd780865d45d7f663a5cb087ac5a2d85fd",
+    "laguna-xs2-1g": "f33873fbe0128e8c1a4868ed1a45969285f26c5c6927ad3e923f3c55b9333d12",
     "qwen3-next-80b-a3b-1g": "6a3ed70638d27b8879d03a1c6168a7bfff66cfbe6c74f18844d2acbfb8af3d9c",
 }
 

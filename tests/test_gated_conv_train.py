@@ -33,10 +33,13 @@ CELLS_PROGRAMS = {
     # ``tests/test_mla_rope_mtp_train.py`` (2ca37b1f…, bd50d408…: equal at both commits too). The three programs are
     # PR 53's (the tree on top of 5cc5599): the interleaved rotation as ``x·C + swap(x)·S`` with its written gradient
     # (``ops/layers._turn_pairs``) is the one thing that moved them from 7de40b99…, 64b9511b…, 73eb8bdc…; the rotation
-    # says nothing, so the ``_say_once`` digests are the parent's
+    # says nothing, so the ``_say_once`` digests are the parent's. joyai-flash-1g since the PR that took
+    # ``jnp.take_along_axis`` out of ``_route``'s sigmoid branch (959938be…5bd7 at b50bcfa and before): ``gather`` -1 a
+    # sparse layer a forward pass and ``scatter-add`` -1 a layer, ``_chosen``'s compare, select and sum over the experts
+    # in their place — old counts against new in ``tests/test_gdn_train.CELLS_PROGRAMS``' comment
     "olmo1b-1g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a", "1bcfe2dfb3ff35a0"),
     "olmoe-1g": ((8, 2048), "65b119828cd26a22a39bc945227fb3cef92f2b8ae09109a8c17c196e5a896d2d", "1bcfe2dfb3ff35a0"),
-    "joyai-flash-1g": ((2, 8192), "959938bef56e10a002f8ad665bf8fdd14794506432d5a5dfb20c9a6a6ca95bd7", "a6aa64069f77d113"),
+    "joyai-flash-1g": ((2, 8192), "6f42f5a08abb4906ec7d0a4899666dc66f2cd00b6baa019b8d4c7e0f007eefaa", "a6aa64069f77d113"),
 }
 
 
@@ -48,6 +51,9 @@ def cells_program(name, shape, monkeypatch):
     cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
     said = []
     monkeypatch.setattr(T, "_say_once", lambda kind, key, **fields: said.append(kind + " " + " ".join(f"{k}={v}" for k, v in fields.items())))
+    # a pinned text is a fresh process's: what this process traced before (all of tests/test_gdn_train.py ahead of this
+    # file in one worker, for one) changes which sub-jaxprs are one object, and the printed text hoists those
+    jax.clear_caches()
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda p, t: loss_fn(p, t, cfg)))(params, jax.ShapeDtypeStruct(shape, jnp.int32))

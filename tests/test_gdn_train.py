@@ -46,10 +46,22 @@ CELLS_PROGRAMS = {
     # each of q, k, v where they stood once over the concatenation (``logistic`` 58 -> 74, ``pad`` 375 -> 435, the
     # taps' ``mul`` / ``slice`` / ``add`` with them; the products' and the filters' ``concatenate`` gone). The kernels'
     # equations did not move: ``tests/test_mla_rope_mtp_train.CELLS_KERNELS`` holds its digests, and the lines said too.
-    "kimi-linear-1g": ((2, 8192), "3d95496bb751b962215b3136713245972454c194746ae73f951c793c78d8f10a", "efeaeed97ccba4c3"),
-    "laguna-xs2-1g": ((2, 8192), "bd50d408b7ed0e9d2876d862737ce952d20700046ef4b41ccb917b5f1854eeb0", "2e3b7f09d4732e39"),
-    "joyai-flash-1g": ((2, 8192), "959938bef56e10a002f8ad665bf8fdd14794506432d5a5dfb20c9a6a6ca95bd7", "a6aa64069f77d113"),
-    "lfm2-8b-a1b-1g": ((2, 8192), "48a3dee15b7c14d6a20b8d9381be5920893125a39c0a7c00c8932e3248c011b8", "647d94df3e9b6744"),
+    # The four cells whose experts a sigmoid gate chooses, re-pinned by the PR that took ``jnp.take_along_axis`` out of
+    # ``_route`` (kimi-linear-1g 3d95496b…f10a, laguna-xs2-1g bd50d408…eeb0, joyai-flash-1g 959938be…5bd7, lfm2-8b-a1b-1g
+    # 48a3dee1…11b8 at b50bcfa and before; the first of them itself re-pinned as the comment above says). Old text
+    # against new, primitive counts of the whole program with the kernels' bodies left out, kimi-linear-1g (four sparse
+    # layers written out; laguna-xs2-1g moves by the same numbers, joyai-flash-1g — one scanned layer and the module's —
+    # and lfm2-8b-a1b-1g by half of each): ``gather`` 492 -> 484, one a sparse layer a forward pass (the layer's and
+    # the one ``remat`` runs again), and ``scatter-add`` 390 -> 386, its transpose, one a layer; with them the gather's
+    # index arithmetic (``lt`` 2278 -> 2270, ``add`` 3074 -> 3062, ``reshape`` 965 -> 957). In their place ``_chosen``:
+    # ``iota`` 885 -> 893 and ``eq`` 1705 -> 1713 (the expert axis against the chosen indices, a forward pass),
+    # ``select_n`` 4422 -> 4426 and ``reduce_sum`` 767 -> 783 (the select and the sum over E, forward; the same select
+    # and the sum over k, backward), ``broadcast_in_dim`` 6129 -> 6181, ``convert_element_type`` 2920 -> 2928. No other
+    # primitive's count moved; the lines said did not, nor the six other configurations' programs.
+    "kimi-linear-1g": ((2, 8192), "c77a0955b4efb0d810d9ccd174575a9e663e78a80a52a9534018e1c79e7b2fa8", "efeaeed97ccba4c3"),
+    "laguna-xs2-1g": ((2, 8192), "bca2e146dd3fd083383d11a9fe1568b3895c094838f88e1317c9f784ffc1d5c5", "2e3b7f09d4732e39"),
+    "joyai-flash-1g": ((2, 8192), "6f42f5a08abb4906ec7d0a4899666dc66f2cd00b6baa019b8d4c7e0f007eefaa", "a6aa64069f77d113"),
+    "lfm2-8b-a1b-1g": ((2, 8192), "89c3681a21cc85d34798a6ff43f5c68f1cbbc6f13c3ad39e15f67cb634ff2287", "647d94df3e9b6744"),
 }
 NEW_CELL = "qwen3-next-80b-a3b-1g"
 
@@ -64,6 +76,9 @@ def cells_program(name, shape, devices=1):
     cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
     said = []
     say = lambda kind, key, **fields: said.append(kind + " " + " ".join(f"{k}={v}" for k, v in fields.items()))
+    # a pinned text is a fresh process's: what this process traced before (all of tests/test_gdn_train.py ahead of this
+    # file in one worker, for one) changes which sub-jaxprs are one object, and the printed text hoists those
+    jax.clear_caches()
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     mesh = contextlib.nullcontext() if devices == 1 else jax.set_mesh(make_mesh(MeshConfig(fsdp=devices), devices=jax.devices()[:devices]))
     with mock.patch.object(jax, "default_backend", lambda: "tpu"), mock.patch.object(T, "_say_once", say), mesh:
@@ -78,6 +93,27 @@ def test_the_seven_older_cells_programs_and_what_they_say_are_the_parents(name):
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     assert hashlib.sha256(text.encode()).hexdigest() == program
     assert hashlib.sha256("\n".join(said).encode()).hexdigest()[:16] == lines
+
+
+SIGMOID_CELLS = ("kimi-linear-1g", "laguna-xs2-1g", "joyai-flash-1g", "lfm2-8b-a1b-1g")
+
+
+@pytest.mark.parametrize("name", SIGMOID_CELLS)
+def test_a_sigmoid_gates_program_reads_its_weights_without_a_gather_of_the_scores(name):
+    """On the chip's branch, at the cell's size: no ``gather`` reads and no
+    ``scatter-add`` writes the router's [T, E] float32 scores anywhere in the
+    program — under ``moe/router`` or not, in the forward, in what ``remat`` runs
+    again or in the backward (the parent's held 8 + 4 in kimi-linear-1g: a scalar
+    gather of 131 072 elements took 1.34 ms on the chip). The walk is on the
+    right arrays: it finds the gate's ``top_k`` of them, under the router's name."""
+    from tests.test_moe import gathers_of_scores
+
+    jaxpr, _ = cells_program(name, CELLS_PROGRAMS[name][0])
+    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
+        tc = json.load(f)["program"]["transformer_config"]
+    chosen = gathers_of_scores(jaxpr.jaxpr, 2 * 8192, tc["n_experts"], primitives=("top_k",))
+    assert tc["router_gate"] == "sigmoid" and chosen and all("/router" in stack for _, stack in chosen)
+    assert gathers_of_scores(jaxpr.jaxpr, 2 * 8192, tc["n_experts"]) == []
 
 
 def test_the_new_cells_softmax_layer_runs_the_kernel_once_and_says_its_heads():

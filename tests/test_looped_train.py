@@ -491,13 +491,17 @@ CELLS_PROGRAMS = {
     # (batch, positions) and the sha256 of the jaxpr of loss_fn's value and gradient at the cell's sizes on the chip's
     # branch: what 6d5d1c7 — the parent of the PR that brought ``ut_steps``, ``sandwich_norm``, ``exit_gate`` and the
     # head's weights — traced, letter for letter, computed there and here by one script
+    # (the four whose experts a sigmoid gate chooses — kimi-linear-1g, laguna-xs2-1g, joyai-flash-1g, lfm2-8b-a1b-1g —
+    # re-pinned by the PR that took ``jnp.take_along_axis`` out of ``_route``: ``gather`` -1 a sparse layer a forward
+    # pass, ``scatter-add`` -1 a layer, ``_chosen``'s compare, select and sum in their place; old counts against new in
+    # ``tests/test_gdn_train.CELLS_PROGRAMS``' comment)
     "olmo1b-1g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a"),
     "olmo1b-4g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a"),
     "olmoe-1g": ((8, 2048), "65b119828cd26a22a39bc945227fb3cef92f2b8ae09109a8c17c196e5a896d2d"),
-    "kimi-linear-1g": ((2, 8192), "3d95496bb751b962215b3136713245972454c194746ae73f951c793c78d8f10a"),
-    "laguna-xs2-1g": ((2, 8192), "bd50d408b7ed0e9d2876d862737ce952d20700046ef4b41ccb917b5f1854eeb0"),
-    "joyai-flash-1g": ((2, 8192), "959938bef56e10a002f8ad665bf8fdd14794506432d5a5dfb20c9a6a6ca95bd7"),
-    "lfm2-8b-a1b-1g": ((2, 8192), "48a3dee15b7c14d6a20b8d9381be5920893125a39c0a7c00c8932e3248c011b8"),
+    "kimi-linear-1g": ((2, 8192), "c77a0955b4efb0d810d9ccd174575a9e663e78a80a52a9534018e1c79e7b2fa8"),
+    "laguna-xs2-1g": ((2, 8192), "bca2e146dd3fd083383d11a9fe1568b3895c094838f88e1317c9f784ffc1d5c5"),
+    "joyai-flash-1g": ((2, 8192), "6f42f5a08abb4906ec7d0a4899666dc66f2cd00b6baa019b8d4c7e0f007eefaa"),
+    "lfm2-8b-a1b-1g": ((2, 8192), "89c3681a21cc85d34798a6ff43f5c68f1cbbc6f13c3ad39e15f67cb634ff2287"),
     "qwen3-next-80b-a3b-1g": ((2, 8192), "f987432a4fe8549e93feedc1bc1c287da5cc3c589e9beef49e7b43900bc8867f"),
 }
 NEW_CELL = "ouro-2_6b-1g"
@@ -512,6 +516,9 @@ def cells_program(name, shape):
     cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
     said = []
     say = lambda kind, key, **fields: said.append(kind + " " + " ".join(f"{k}={v}" for k, v in fields.items()))
+    # a pinned text is a fresh process's: what this process traced before (all of tests/test_gdn_train.py ahead of this
+    # file in one worker, for one) changes which sub-jaxprs are one object, and the printed text hoists those
+    jax.clear_caches()
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     with mock.patch.object(jax, "default_backend", lambda: "tpu"), mock.patch.object(T, "_say_once", say):
         jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda p, t: loss_fn(p, t, cfg)))(params, jax.ShapeDtypeStruct(shape, jnp.int32))

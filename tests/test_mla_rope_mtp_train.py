@@ -41,8 +41,12 @@ CELLS_PROGRAMS = {
     # kimi-linear-1g since the PR that took ``_mix_kda``'s q | k | v out of the block's checkpoint (2ca37b1f…927d before
     # it): the three products a KDA layer out of both scan bodies and once at the full sequence, the mixer scan's second
     # ``xs`` — old text against new in ``tests/test_gdn_train.CELLS_PROGRAMS``' comment; CELLS_KERNELS below did not move.
-    "kimi-linear-1g": "3d95496bb751b962215b3136713245972454c194746ae73f951c793c78d8f10a",
-    "laguna-xs2-1g": "bd50d408b7ed0e9d2876d862737ce952d20700046ef4b41ccb917b5f1854eeb0",
+    # Both since the PR that took ``jnp.take_along_axis`` out of ``_route``'s sigmoid branch (3d95496b…f10a and
+    # bd50d408…eeb0 at b50bcfa and before): ``gather`` 492 -> 484 / 418 -> 410 (one a sparse layer a forward pass) and
+    # ``scatter-add`` 390 -> 386 / 365 -> 361 (its transpose), ``_chosen``'s ``iota``, ``eq``, ``select_n`` and
+    # ``reduce_sum`` over the experts in their place — the whole list in the same comment; CELLS_KERNELS did not move.
+    "kimi-linear-1g": "c77a0955b4efb0d810d9ccd174575a9e663e78a80a52a9534018e1c79e7b2fa8",
+    "laguna-xs2-1g": "bca2e146dd3fd083383d11a9fe1568b3895c094838f88e1317c9f784ffc1d5c5",
 }
 CELLS_KERNELS = {
     # the programs' ``pallas_call`` equations, each printed on its own (``tests/test_window_gqa.kernel_equations``):
@@ -66,6 +70,9 @@ def _cells_program(name):
     with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
         tc = json.load(f)["program"]["transformer_config"]
     cfg = TransformerConfig(**{**tc, "dtype": jnp.dtype(tc["dtype"])})
+    # a pinned text is a fresh process's: what this process traced before (all of tests/test_gdn_train.py ahead of this
+    # file in one worker, for one) changes which sub-jaxprs are one object, and the printed text hoists those
+    jax.clear_caches()
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):

@@ -193,6 +193,117 @@ def test_every_expert_chosen_is_the_dense_sum_over_all_experts():
     assert max(grad_errors(got, want)) < RTOL
 
 
+# -- the sigmoid gate's k weights, read without a gather -------------------------------------
+
+
+def route_with_a_gather(lp, tokens, cfg):
+    """The sigmoid branch of ``_route`` as it stood while it read the chosen
+    experts' weights with ``jnp.take_along_axis`` — a gather of T·k scalars,
+    1.34 ms a layer a pass on the chip at [16384, 256] — written out here as
+    the plain reference of what the select in its place has to give."""
+    scores = jax.nn.sigmoid(jnp.dot(tokens, lp["router"], preferred_element_type=jnp.float32))
+    biased = scores + lp["router_bias"].astype(jnp.float32) if "router_bias" in lp else scores
+    _, top_idx = jax.lax.top_k(biased, cfg.top_k)
+    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+    if cfg.router_renormalize:
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + cfg.router_norm_eps)
+    return top_w * cfg.routed_scaling_factor, top_idx
+
+
+def sigmoid_router(experts, k, bias, renormalize, scale, tied, tokens=64, d=32):
+    """(config, the router's leaves, tokens [T, d], a [T, k] mix for a scalar
+    of the weights). ``tied``: every odd expert is its even neighbour's copy —
+    column and bias — so every token's biased scores tie in pairs."""
+    cfg = TransformerConfig(**{
+        **SIZES["e8k2"], "n_experts": experts, "top_k": k, "router_gate": "sigmoid",
+        "router_renormalize": renormalize, "routed_scaling_factor": scale,
+    })
+    keys = jax.random.split(jax.random.PRNGKey(experts + k), 4)
+    lp = {"router": 3.0 * jax.random.normal(keys[0], (d, experts)) * d**-0.5}
+    if bias:
+        lp["router_bias"] = 0.2 * jax.random.normal(keys[1], (experts,))
+    if tied:
+        lp = {name: leaf.at[..., 1::2].set(leaf[..., 0::2]) for name, leaf in lp.items()}
+    return cfg, lp, jax.random.normal(keys[2], (tokens, d)), jax.random.normal(keys[3], (tokens, k))
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+@pytest.mark.parametrize("renormalize,scale", [(True, 2.446), (False, 2.446), (True, 1.0)], ids=["renorm_x2.446", "asis_x2.446", "renorm_x1"])
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "nobias"])
+@pytest.mark.parametrize("experts,k", [(32, 4), (256, 8), (16, 4)])
+def test_the_sigmoid_gates_weights_and_gradients_are_the_gathers_bit_for_bit(experts, k, bias, renormalize, scale, tied):
+    """``_route``'s sigmoid branch against :func:`route_with_a_gather`: the same
+    experts in the same order, the weights bit-equal, and the gradient of a
+    scalar of the weights to the tokens and to the router bit-equal — one term
+    of each of the select's sums is not zero. With tied biased scores the lower
+    index comes first, as ``jax.lax.top_k`` says it does."""
+    cfg, lp, tokens, mix = sigmoid_router(experts, k, bias, renormalize, scale, tied)
+    got_w, got_idx, probs = transformer._route(lp, tokens, cfg)
+    want_w, want_idx = route_with_a_gather(lp, tokens, cfg)
+    np.testing.assert_array_equal(got_idx, want_idx)
+    np.testing.assert_array_equal(got_w, want_w)
+    np.testing.assert_allclose(jnp.sum(probs, axis=-1), 1.0, rtol=1e-6)
+    if renormalize:
+        np.testing.assert_allclose(jnp.sum(got_w, axis=-1), scale, rtol=1e-6)
+    if tied:  # a token's choice is made of whole pairs, the even one first, at one weight
+        assert bool(jnp.all(got_idx[:, 0::2] % 2 == 0)) and bool(jnp.all(got_idx[:, 1::2] == got_idx[:, 0::2] + 1))
+        np.testing.assert_array_equal(got_w[:, 0::2], got_w[:, 1::2])
+
+    def scalar(route):
+        return lambda tokens, router: jnp.sum(mix * route({**lp, "router": router}, tokens, cfg)[0] ** 2)
+
+    got = jax.grad(scalar(transformer._route), argnums=(0, 1))(tokens, lp["router"])
+    want = jax.grad(scalar(route_with_a_gather), argnums=(0, 1))(tokens, lp["router"])
+    for g, w in zip(got, want):
+        assert float(jnp.max(jnp.abs(w))) > 0
+        np.testing.assert_array_equal(g, w)
+
+
+def gathers_of_scores(jaxpr, tokens, experts, primitives=("gather", "scatter-add"), above=""):
+    """The ``gather`` and ``scatter-add`` equations (or those of ``primitives``)
+    anywhere in ``jaxpr`` whose operand is a float32 [tokens, experts], as
+    (primitive, the named scopes around it: an equation's name stack is told
+    from its own jaxpr, so the enclosing equations' stand before it)."""
+    from tests.test_window_gqa import sub_jaxprs
+
+    found = []
+    for eqn in jaxpr.eqns:
+        stack = above + "/" + str(eqn.source_info.name_stack)
+        if eqn.primitive.name in primitives and (eqn.invars[0].aval.shape, eqn.invars[0].aval.dtype) == ((tokens, experts), jnp.float32):
+            found.append((eqn.primitive.name, stack))
+        for sub in sub_jaxprs(eqn):
+            found += gathers_of_scores(sub, tokens, experts, primitives, stack)
+    return found
+
+
+def test_the_sigmoid_gates_program_holds_no_gather_of_the_scores():
+    """Value and gradient of the gate at a small size: no ``gather`` reads and
+    no ``scatter-add`` writes a [T, E] float32; with the gather put back the
+    walk finds one of each under the router's name, so it sees them where they
+    are. Compiled, the read alone and its transpose give the gather's bits
+    (around them a fusion may order the renormalisation's sums its own way:
+    the eager cases above hold the whole branch to the reference)."""
+    cfg, lp, tokens, mix = sigmoid_router(32, 4, bias=True, renormalize=True, scale=2.446, tied=False)
+
+    def program(route):
+        def scalar(tokens, router):
+            with jax.named_scope("router"):
+                return jnp.sum(mix * route({**lp, "router": router}, tokens, cfg)[0] ** 2)
+        return jax.make_jaxpr(jax.value_and_grad(scalar, argnums=(0, 1)))(tokens, lp["router"]).jaxpr
+
+    assert gathers_of_scores(program(transformer._route), 64, 32) == []
+    found = gathers_of_scores(program(route_with_a_gather), 64, 32)
+    assert sorted(name for name, _ in found) == ["gather", "scatter-add"] and all("router" in stack for _, stack in found)
+
+    scores = jax.nn.sigmoid(tokens @ lp["router"])
+    top_idx = jax.lax.top_k(scores + lp["router_bias"], cfg.top_k)[1]
+    gather = lambda scores, top_idx: jnp.take_along_axis(scores, top_idx, axis=-1)
+    np.testing.assert_array_equal(jax.jit(transformer._chosen)(scores, top_idx), jax.jit(gather)(scores, top_idx))
+    got, want = (jax.jit(jax.grad(lambda s: jnp.sum(mix * read(s, top_idx))))(scores) for read in (transformer._chosen, gather))
+    assert float(jnp.max(jnp.abs(want))) > 0
+    np.testing.assert_array_equal(got, want)
+
+
 def test_the_tpu_kernel_and_the_xla_form_are_the_same_grouped_matmul():
     """The Pallas kernel the TPU runs (here in the interpreter) against
     ``jax.lax.ragged_dot``: values and both gradients, with rows that do not
