@@ -1,4 +1,7 @@
+import collections
 import os
+
+import pytest
 
 # Force JAX onto a virtual 8-device CPU mesh for all tests: multi-chip
 # sharding is validated without TPU hardware (the driver separately
@@ -145,3 +148,179 @@ def skip_if_known_corruption(
             f"known pre-existing native corruption in a worker ({sig!r})"
             f"{pm}; see ROADMAP open items"
         )
+
+
+# ---------------------------------------------------------------------------
+# One small fault-tolerant job, traced: tests/test_tft_spans.py reads its
+# spans' structure, tests/test_telemetry_readers.py that every name the
+# benchmark reads is written. Imports are lazy, as above.
+# ---------------------------------------------------------------------------
+
+# the smallest bucket the knob allows, so that one step has several
+SPANS_BUCKET_BYTES = 1 << 16
+
+
+def spans_cfg():
+    import jax.numpy as jnp
+
+    from torchft_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=2, head_dim=32, d_ff=128,
+        dtype=jnp.float32,
+    )
+
+
+@pytest.fixture(scope="module")
+def spans_train_step():
+    import jax
+    import optax
+
+    from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
+    from torchft_tpu.parallel.train_step import TrainStep
+
+    mesh = make_mesh(MeshConfig(), devices=jax.devices()[:1])
+    return TrainStep(spans_cfg(), optax.adamw(1e-2), mesh)
+
+
+def run_steps(ts, steps, monkeypatch, around=None, veto_step=None, hold_quorum=False):
+    """``steps`` FT steps on a fresh one-group job; returns (losses, checksum).
+    ``around(fn)`` runs the stepping inside whatever it sets up; in step
+    ``veto_step`` this rank votes against the commit, as a rank whose step
+    went wrong would; under ``hold_quorum`` the quorum is asked for only once
+    the main thread waits for it, so that a step this small blocks on its
+    quorum as a step at size does."""
+    import threading
+    from datetime import timedelta
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchft_tpu.collectives import CollectivesTcp
+    from torchft_tpu.coordination import LighthouseServer
+    from torchft_tpu.manager import Manager
+    from torchft_tpu.parallel.ft import FTTrainer
+    from torchft_tpu.store import StoreServer
+
+    cfg = spans_cfg()
+    monkeypatch.setenv("TORCHFT_WIRE_BUCKET_BYTES", str(SPANS_BUCKET_BYTES))
+    lighthouse = LighthouseServer(bind="[::]:0", min_replicas=1)
+    store = StoreServer()
+    manager = Manager(
+        collectives=CollectivesTcp(timeout=timedelta(seconds=10)),
+        load_state_dict=None,
+        state_dict=None,
+        min_replica_size=1,
+        replica_id="spans_0",
+        store_addr=store.address(),
+        lighthouse_addr=lighthouse.address(),
+        rank=0,
+        world_size=1,
+        timeout=timedelta(seconds=10),
+    )
+    try:
+        trainer = FTTrainer(manager, ts)
+        trainer.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(0)
+        batches = [
+            jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 16)), jnp.int32)
+            for _ in range(steps)
+        ]
+
+        vote = manager._client.should_commit
+        if hold_quorum:
+            wait, quorum = manager.wait_quorum, manager._client._quorum
+            waited_for = threading.Event()
+
+            def wait_quorum():
+                # a step waits more than once; only its first wait blocks
+                if not manager._quorum_future.done():
+                    waited_for.set()
+                return wait()
+
+            def held_quorum(*args, **kwargs):
+                assert waited_for.wait(timeout=60), "nobody waited for the quorum"
+                waited_for.clear()
+                return quorum(*args, **kwargs)
+
+            manager.wait_quorum, manager._client._quorum = wait_quorum, held_quorum
+
+        def drive():
+            out = []
+            for i, tokens in enumerate(batches):
+                manager._client.should_commit = (
+                    (lambda rank, step, _vote, **kw: vote(rank, step, False, **kw))
+                    if i == veto_step else vote
+                )
+                loss, committed = trainer.step(tokens)
+                assert committed == (i != veto_step)
+                out.append(loss)
+            jax.block_until_ready(trainer.params)
+            return out
+
+        losses = around(drive) if around else drive()
+        checksum = sum(
+            float(jnp.sum(l)) for l in jax.tree_util.tree_leaves(trainer.params)
+        )
+        return losses, checksum
+    finally:
+        manager.shutdown(wait=False)
+        store.shutdown()
+        lighthouse.shutdown()
+
+
+Traced = collections.namedtuple(
+    "Traced", "lines losses checksum ledger_rows ring_spans"
+)
+
+
+@pytest.fixture(scope="module")
+def traced(spans_train_step, tmp_path_factory):
+    """Three steps under a profiler session, the quorum beside the compute
+    as in production: the host lines of the trace, the losses, the checksum
+    and the Tracer's ``exchange`` spans of those steps. Then three more,
+    untraced and with the quorum held, for the LEDGER's rows: a step this
+    small books a ``quorum_wait`` only when it has to wait."""
+    import glob
+
+    import jax
+
+    from torchft_tpu import telemetry
+    from torchft_tpu.telemetry import tracing
+
+    trace_dir = str(tmp_path_factory.mktemp("xplane"))
+    mp = pytest.MonkeyPatch()
+
+    def around(drive):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        try:
+            return drive()
+        finally:
+            jax.profiler.stop_trace()
+
+    try:
+        losses, checksum = run_steps(spans_train_step, 3, mp, around)
+        ring_spans = telemetry.TRACER.recent("exchange", limit=3)
+        run_steps(spans_train_step, 3, mp, hold_quorum=True)
+        ledger_rows = telemetry.LEDGER.dump()["rows"][-3:]
+    finally:
+        mp.undo()
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    lines = []  # one dict per host thread: name -> [(start_ns, end_ns, stats)]
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            by_name = {}
+            for ev in line.events:
+                if ev.name.startswith(tracing.TRACE_PREFIX):
+                    by_name.setdefault(ev.name[len(tracing.TRACE_PREFIX):], []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+                    )
+            if by_name:
+                lines.append(by_name)
+    return Traced(lines, losses, checksum, ledger_rows, ring_spans)

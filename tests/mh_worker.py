@@ -54,8 +54,13 @@ def main() -> None:
     mesh = global_mesh(MeshConfig(dp=2, tp=2))
     ts = TrainStep(cfg, optax.sgd(0.05), mesh)
 
+    # no fault is injected here, so a deadline only has to end a hang:
+    # on a busy host one group can be tens of seconds behind the other
+    # (its compile, its gloo set-up), and an op that gives up on a peer
+    # that is merely late fails the run on the neighbours' load
+    op_timeout = timedelta(seconds=120)
     manager = Manager(
-        collectives=CollectivesTcp(timeout=timedelta(seconds=15)),
+        collectives=CollectivesTcp(timeout=op_timeout),
         load_state_dict=None,  # wired by FTTrainer.init
         state_dict=None,
         min_replica_size=2,
@@ -64,7 +69,7 @@ def main() -> None:
         rank=rank,
         world_size=world,
         lighthouse_addr=lighthouse_addr,
-        timeout=timedelta(seconds=15),
+        timeout=op_timeout,
     )
     try:
         trainer = FTTrainer(manager, ts)

@@ -180,36 +180,28 @@ class TestWireDriftFixtures:
         finds = [f for f in wiredrift.run() if f.rule == "heal-env-drift"]
         assert finds == []
 
-    def test_obs_env_covers_tsdb_and_regression_families(self):
-        # the ISSUE 11 satellite: the obs-env-drift rule must enforce the
-        # new TORCHFT_TSDB_* / TORCHFT_REGRESSION_* families in BOTH
-        # directions, like the SLO/straggler families before them
+    def test_obs_env_covers_tsdb_family(self):
+        # the obs-env-drift rule must enforce the TORCHFT_TSDB_* family
+        # in BOTH directions, like the SLO/straggler families before it
         py = {
             "a.py": 'os.environ.get("TORCHFT_TSDB_RETAIN")\n'
-                    'os.environ.get("TORCHFT_TSDB_GHOST")\n'
-                    'os.environ.get("TORCHFT_REGRESSION_DELTA")\n'
-                    'os.environ.get("TORCHFT_REGRESSION_GHOST")\n',
+                    'os.environ.get("TORCHFT_TSDB_GHOST")\n',
         }
         doc = (
             "| knob | default |\n"
             "| `TORCHFT_TSDB_RETAIN` | 512 |\n"
             "| `TORCHFT_TSDB_STALE` | 1 |\n"
-            "| `TORCHFT_REGRESSION_DELTA` | 0.05 |\n"
-            "| `TORCHFT_REGRESSION_STALE` | 1 |\n"
         )
         finds = wiredrift.check_obs_env(py, doc)
         msgs = {f.symbol: f.message for f in finds}
-        for ghost in ("TORCHFT_TSDB_GHOST", "TORCHFT_REGRESSION_GHOST"):
-            assert ghost in msgs and "missing from" in msgs[ghost]
-        for stale in ("TORCHFT_TSDB_STALE", "TORCHFT_REGRESSION_STALE"):
-            assert stale in msgs and "no code reads" in msgs[stale]
+        assert "missing from" in msgs["TORCHFT_TSDB_GHOST"]
+        assert "no code reads" in msgs["TORCHFT_TSDB_STALE"]
         assert "TORCHFT_TSDB_RETAIN" not in msgs
-        assert "TORCHFT_REGRESSION_DELTA" not in msgs
 
     def test_obs_env_covers_prof_and_diag_families(self):
         # the ISSUE 12 satellite: the obs-env-drift rule must enforce
         # the TORCHFT_PROF_* / TORCHFT_DIAG_* families in BOTH
-        # directions, like the six families before them
+        # directions, like the five families before them
         py = {
             "a.py": 'os.environ.get("TORCHFT_PROF_HZ")\n'
                     'os.environ.get("TORCHFT_PROF_GHOST")\n'

@@ -242,7 +242,6 @@ def _mk_engine(tmp_path, **kw) -> DiagnosisEngine:
 
 _TRIGGER_FIXTURE: Dict[str, Dict] = {
     "straggler_detected": {"group": "g1", "p50_s": 0.4},
-    "perf_regression": {"replica": "g1", "series": "local_s", "step": 7},
     "slo_breach": {"slo": "step_time", "threshold_s": 0.5},
     "watchdog_stall": {"step": 9, "elapsed_s": 120.0},
     "divergence_detected": {"step": 11, "fence": False},
@@ -250,9 +249,9 @@ _TRIGGER_FIXTURE: Dict[str, Dict] = {
 
 
 class TestTriggerEngine:
-    def test_debounce_once_per_episode_all_five(self, tmp_path):
+    def test_debounce_once_per_episode_all_four(self, tmp_path):
         # every trigger captures exactly once per episode, across ALL
-        # five latch events; the matching *_cleared re-arms; latches
+        # four latch events; the matching *_cleared re-arms; latches
         # with no cleared event re-arm only after rearm_s
         now = [0.0]
         eng = _mk_engine(tmp_path, rearm_s=600.0, clock=lambda: now[0])
@@ -263,17 +262,12 @@ class TestTriggerEngine:
                 telemetry.emit(kind, **fields)  # same episode: debounced
             assert eng.bundle_count == len(TRIGGER_EVENTS)
 
-            # the three clearable triggers re-arm on their *_cleared
+            # the two clearable triggers re-arm on their *_cleared
             telemetry.emit("straggler_cleared", group="g1")
-            telemetry.emit(
-                "perf_regression_cleared", replica="g1", series="local_s"
-            )
             telemetry.emit("slo_recovered", slo="step_time")
-            for kind in (
-                "straggler_detected", "perf_regression", "slo_breach"
-            ):
+            for kind in ("straggler_detected", "slo_breach"):
                 telemetry.emit(kind, **_TRIGGER_FIXTURE[kind])
-            assert eng.bundle_count == len(TRIGGER_EVENTS) + 3
+            assert eng.bundle_count == len(TRIGGER_EVENTS) + 2
 
             # watchdog/divergence have no cleared event: still latched...
             telemetry.emit("watchdog_stall", **_TRIGGER_FIXTURE["watchdog_stall"])
@@ -281,7 +275,7 @@ class TestTriggerEngine:
                 "divergence_detected",
                 **_TRIGGER_FIXTURE["divergence_detected"],
             )
-            assert eng.bundle_count == len(TRIGGER_EVENTS) + 3
+            assert eng.bundle_count == len(TRIGGER_EVENTS) + 2
             # ...until the re-arm window passes
             now[0] += 601.0
             telemetry.emit("watchdog_stall", **_TRIGGER_FIXTURE["watchdog_stall"])
@@ -289,7 +283,7 @@ class TestTriggerEngine:
                 "divergence_detected",
                 **_TRIGGER_FIXTURE["divergence_detected"],
             )
-            assert eng.bundle_count == len(TRIGGER_EVENTS) + 5
+            assert eng.bundle_count == len(TRIGGER_EVENTS) + 4
         finally:
             eng.remove()
 
@@ -356,9 +350,6 @@ class TestTriggerEngine:
         eng = _mk_engine(tmp_path).install()
         try:
             telemetry.emit("straggler_detected", group="SOME_OTHER_GROUP")
-            telemetry.emit(
-                "perf_regression", replica="not_me", series="local_s"
-            )
             assert eng.bundle_count == 0
             # prefix matching both ways (manager ids carry uuid suffixes)
             telemetry.emit("straggler_detected", group="g1-uuid-suffix")
@@ -495,28 +486,6 @@ class TestFlightDumpStacks:
 
 
 class TestStatusHints:
-    def test_critical_path_no_monitor_vs_empty_vs_ok(self):
-        from torchft_tpu.telemetry import critical_path as cp
-
-        cp.set_reporter(None)
-        assert json.loads(cp.report_json())["status"] == "no-monitor"
-        att = cp.CriticalPathAttributor()
-        cp.set_reporter(att)
-        try:
-            assert json.loads(cp.report_json())["status"] == "empty"
-            att.observe_step(
-                5,
-                {
-                    "a": {"wall_s": 1.0, "local_s": 0.9,
-                          "phases": {"compute": 0.9}},
-                    "b": {"wall_s": 1.0, "local_s": 0.5,
-                          "phases": {"compute": 0.5}},
-                },
-            )
-            assert json.loads(cp.report_json())["status"] == "ok"
-        finally:
-            cp.set_reporter(None)
-
     def test_lighthouse_diagnosis_json_empty_then_ok(self):
         import urllib.request
 

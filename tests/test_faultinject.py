@@ -400,15 +400,15 @@ def test_2replica_commit_vote_delay_and_recv_error():
 
 @pytest.mark.faultmatrix
 class TestFaultMatrix:
-    """Multi-process scenario matrix (excluded from tier-1; also
-    runnable as `python -m torchft_tpu.faultinject.runner`)."""
+    """Multi-process scenario matrix (outside the default run, inside
+    tier-1, whose ``-m 'not slow'`` replaces ``addopts``; also runnable
+    as `python -m torchft_tpu.faultinject.runner`)."""
 
     @pytest.mark.parametrize(
         "name",
         [
             "torn_cma_pull", "kill_allreduce_cma", "ckpt_serve_death",
-            "straggler_group", "perf_regression", "diagnose_straggler",
-            "commit_vote_delay",
+            "straggler_group", "diagnose_straggler", "commit_vote_delay",
         ],
     )
     def test_scenario(self, tmp_path, name):
@@ -427,13 +427,6 @@ class TestFaultMatrix:
             # exactly one bundle (ISSUE 12)
             res = runner.run_diagnose_scenario(
                 scn, str(tmp_path / name), steps=24, timeout_s=420
-            )
-        elif name == "perf_regression":
-            # custom three-leg runner (control + mid-run onset +
-            # kill/respawn persistence) with the regression sentinel and
-            # critical-path monitors hosted by this process
-            res = runner.run_perf_regression_scenario(
-                scn, str(tmp_path / name), timeout_s=600
             )
         else:
             res = runner.run_scenario(

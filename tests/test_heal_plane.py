@@ -151,7 +151,26 @@ def _tree_equal(a, b):
 
 
 class TestStripedMultiSource:
-    def test_two_sources_bit_identical(self, transports):
+    def test_two_sources_bit_identical(self, transports, monkeypatch):
+        from torchft_tpu import _native
+
+        # WHICH source serves a range is a race between threads of one
+        # busy host: a source whose streams are scheduled late finds the
+        # queue drained. So no range moves before each source has been
+        # asked for one — then a plan that gives both sources streams has
+        # both serve bytes, and one that does not fails every range here
+        asked, both = set(), threading.Event()
+        real_fetch = _native.blob_fetch
+
+        def fetch_once_both_were_asked(host, port, *args, **kw):
+            asked.add(port)
+            if len(asked) == 2:
+                both.set()
+            if not both.wait(T.total_seconds()):
+                raise ConnectionError(f"only the source at {asked} was asked")
+            return real_fetch(host, port, *args, **kw)
+
+        monkeypatch.setattr(_native, "blob_fetch", fetch_once_both_were_asked)
         state = _state(1)
         s1, s2, rx = transports(), transports(), transports()
         s1.send_checkpoint([1], 3, state, T)
@@ -159,11 +178,15 @@ class TestStripedMultiSource:
         out = rx.recv_checkpoint_multi([s1.metadata(), s2.metadata()], 3, T)
         _tree_equal(out, state)
         stats = rx.last_heal_stats
-        assert stats["mode"] == "striped"
-        assert stats["nsources"] == 2
-        # per-source throughput attribution present for every source
-        for src_stats in stats["sources"].values():
-            assert src_stats["bytes"] > 0 and "gb_per_sec" in src_stats
+        assert stats["mode"] == "striped" and both.is_set()
+        # the plan: both staged the same tree, both were given streams,
+        # neither failed, and between them they served every byte
+        assert set(stats["sources"]) == {s1.metadata(), s2.metadata()}
+        assert stats["nsources"] == 2 and stats["failures"] == {}
+        _, buffers = flatten_state(state)
+        served = [src["bytes"] for src in stats["sources"].values()]
+        assert min(served) > 0 and sum(served) == sum(b.nbytes for b in buffers)
+        assert all("gb_per_sec" in src for src in stats["sources"].values())
         assert {"meta_s", "recv_s", "decode_s"} <= set(stats["stages"])
 
     def test_divergent_source_excluded(self, transports):

@@ -287,6 +287,9 @@ def test_heal_uses_multi_source_with_cohort(harness):
         "user": {"recovered": True},
         "torchft": {"step": 20, "batches_committed": 0},
     }
+    h.transport.last_heal_stats = {
+        "stages": {"meta_s": 0.25, "recv_s": 0.5}, "streams": 2,
+    }
     m.start_quorum()
     m.wait_quorum()
     assert m._healing
@@ -294,6 +297,12 @@ def test_heal_uses_multi_source_with_cohort(harness):
     sources = call.args[0]
     assert len(sources) == 2  # both cohort members' metadata resolved
     assert call.kwargs["header_cb"] is not None
+    # the heal's own account rides its heal_end, where the benchmark's
+    # bootstrap_heal_s / heal_meta_s / heal_recv_s / heal_fetch_streams
+    # read it (benchmark/heal_stats.py)
+    (*_, ended) = telemetry.EVENTS.recent("heal_end")
+    assert ended["step"] == 20 and isinstance(ended["duration_s"], float)
+    assert ended["heal_stats"] == h.transport.last_heal_stats
 
 
 def test_commit_trail_recorded_at_step_boundaries(harness, monkeypatch):

@@ -58,11 +58,24 @@ def test_recovery_1of4_one_step_envelope():
     """Round-4: with the death watch (socket-FIN-driven evict + early
     re-quorum overlapping the doomed step), killing 1-of-4 groups must
     cost the survivors at most ONE committed step (the reference's
-    product promise, README.md:29-47). The box can be contended,
-    so one retry is allowed — but it is LOGGED and every run's envelope
-    lands in the failure message, so a silently-degrading envelope shows
-    up as retry noise in CI history instead of being masked (round-4
-    review weak #6)."""
+    product promise, README.md:29-47). No clock decides it. The lease and
+    the join wait are longer than the harness waits for anything
+    (``timeout_s``), so the lighthouse counts the killed group among the
+    living until somebody evicts it: without an eviction the survivors'
+    next quorum cannot form and the run ends in the harness's
+    TimeoutError. With it, the envelope is a count: the survivor's
+    attempts that did not commit.
+
+    What it cannot tell apart: the watch switched off while a failed op
+    still reports its dead peer. That run also loses one attempt, which
+    blocks for the op deadline (tests/test_manager.py holds the watch's
+    own re-quorum). The deadline stays at 5 s because the watch does not
+    end every doomed step: a survivor that left the step early leaves the
+    two others in a reduce that only the deadline ends. On a busy host
+    that can follow the doomed step's own abort, two attempts lost, so
+    one retry is allowed, LOGGED, and every run's envelope lands in the
+    failure message: a degrading envelope shows up as retry noise in CI
+    history instead of being masked (round-4 review weak #6)."""
     import warnings
 
     runs = []
@@ -71,22 +84,21 @@ def test_recovery_1of4_one_step_envelope():
             total_steps=25,
             kill_at_step=6,
             step_sleep=0.05,
-            op_timeout=1.0,
-            heartbeat_timeout_ms=1000,
+            op_timeout=5.0,
+            heartbeat_timeout_ms=300_000,
+            join_timeout_ms=300_000,
             timeout_s=120.0,
             num_groups=4,
         )
         runs.append(r.as_dict())
-        if r.survivor_steps_lost <= 1:
+        # whatever the count, the eviction is what let the run end
+        assert r.evictions_total >= 1 and r.requorum_without_victim, runs
+        if r.survivor_failed_attempts <= 1:
             break
         warnings.warn(
-            f"recovery envelope attempt {attempt} exceeded 1 lost step: "
+            f"recovery envelope attempt {attempt} lost more than 1 attempt: "
             f"{runs[-1]} (retrying once; a persistent retry pattern here "
             "means the envelope is degrading)",
             stacklevel=1,
         )
-    assert runs[-1]["survivor_steps_lost"] <= 1, {"all_attempts": runs}
-    # the blackout itself (not just net lost steps) must stay bounded:
-    # the death watch's early re-quorum should land the survivor's first
-    # post-kill commit within ~2 steady steps even on a contended box
-    assert runs[-1]["blackout_steps"] <= 4.0, {"all_attempts": runs}
+    assert runs[-1]["survivor_failed_attempts"] <= 1, {"all_attempts": runs}

@@ -5,33 +5,17 @@ a one-group Manager over CollectivesTcp, read back from the ``.xplane.pb`` a
 """
 
 import contextlib
-import glob
-import os
 import re
-from datetime import timedelta
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
-import pytest
 
-from torchft_tpu.collectives import CollectivesTcp
-from torchft_tpu.coordination import LighthouseServer
-from torchft_tpu.manager import Manager
-from torchft_tpu.models.transformer import TransformerConfig, init_params
-from torchft_tpu.parallel.ft import FTTrainer
-from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
-from torchft_tpu.parallel.train_step import TrainStep
-from torchft_tpu.store import StoreServer
+from conftest import SPANS_BUCKET_BYTES as BUCKET_BYTES, run_steps, spans_cfg
+from torchft_tpu.models.transformer import init_params
 from torchft_tpu.telemetry import tracing
 
-CFG = TransformerConfig(
-    vocab_size=256, d_model=64, n_layers=2, n_heads=2, head_dim=32, d_ff=128,
-    dtype=jnp.float32,
-)
-# the smallest bucket the knob allows, so that one step has several
-BUCKET_BYTES = 1 << 16
+CFG = spans_cfg()
 
 STEP_SPANS = (
     "step", "quorum.start", "shard_batch", "grads", "exchange", "commit",
@@ -56,107 +40,9 @@ EXCHANGE_SUMS = {
 EXCHANGE_BY_BUCKET = {"bucket_landed_s", "bucket_ring_end_s", "bucket_under_grads"}
 
 
-@pytest.fixture(scope="module")
-def train_step():
-    mesh = make_mesh(MeshConfig(), devices=jax.devices()[:1])
-    return TrainStep(CFG, optax.adamw(1e-2), mesh)
-
-
 def n_params() -> int:
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), CFG))
     return sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(shapes))
-
-
-def run_steps(ts, steps, monkeypatch, around=None, veto_step=None):
-    """``steps`` FT steps on a fresh one-group job; returns (losses, checksum).
-    ``around(fn)`` runs the stepping inside whatever it sets up; in step
-    ``veto_step`` this rank votes against the commit, as a rank whose step
-    went wrong would."""
-    monkeypatch.setenv("TORCHFT_WIRE_BUCKET_BYTES", str(BUCKET_BYTES))
-    lighthouse = LighthouseServer(bind="[::]:0", min_replicas=1)
-    store = StoreServer()
-    manager = Manager(
-        collectives=CollectivesTcp(timeout=timedelta(seconds=10)),
-        load_state_dict=None,
-        state_dict=None,
-        min_replica_size=1,
-        replica_id="spans_0",
-        store_addr=store.address(),
-        lighthouse_addr=lighthouse.address(),
-        rank=0,
-        world_size=1,
-        timeout=timedelta(seconds=10),
-    )
-    try:
-        trainer = FTTrainer(manager, ts)
-        trainer.init(jax.random.PRNGKey(0))
-        rng = np.random.default_rng(0)
-        batches = [
-            jnp.asarray(rng.integers(0, CFG.vocab_size, (2, 16)), jnp.int32)
-            for _ in range(steps)
-        ]
-
-        vote = manager._client.should_commit
-
-        def drive():
-            out = []
-            for i, tokens in enumerate(batches):
-                manager._client.should_commit = (
-                    (lambda rank, step, _vote, **kw: vote(rank, step, False, **kw))
-                    if i == veto_step else vote
-                )
-                loss, committed = trainer.step(tokens)
-                assert committed == (i != veto_step)
-                out.append(loss)
-            jax.block_until_ready(trainer.params)
-            return out
-
-        losses = around(drive) if around else drive()
-        checksum = sum(
-            float(jnp.sum(l)) for l in jax.tree_util.tree_leaves(trainer.params)
-        )
-        return losses, checksum
-    finally:
-        manager.shutdown(wait=False)
-        store.shutdown()
-        lighthouse.shutdown()
-
-
-@pytest.fixture(scope="module")
-def traced(train_step, tmp_path_factory):
-    """(host lines of the trace of three steps, their losses, the checksum)."""
-    trace_dir = str(tmp_path_factory.mktemp("xplane"))
-    mp = pytest.MonkeyPatch()
-
-    def around(drive):
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 2
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        try:
-            return drive()
-        finally:
-            jax.profiler.stop_trace()
-
-    try:
-        losses, checksum = run_steps(train_step, 3, mp, around)
-    finally:
-        mp.undo()
-    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
-    lines = []  # one dict per host thread: name -> [(start_ns, end_ns, stats)]
-    for plane in jax.profiler.ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:CPU"):
-            continue
-        for line in plane.lines:
-            by_name = {}
-            for ev in line.events:
-                if ev.name.startswith(tracing.TRACE_PREFIX):
-                    by_name.setdefault(ev.name[len(tracing.TRACE_PREFIX):], []).append(
-                        (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
-                    )
-            if by_name:
-                lines.append(by_name)
-    return lines, losses, checksum
 
 
 def main_line(lines):
@@ -165,13 +51,13 @@ def main_line(lines):
 
 
 def test_every_span_is_in_the_trace_on_its_thread(traced):
-    lines, _, _ = traced
+    lines = traced.lines
     main = main_line(lines)
     assert len(main["step"]) == 3
     assert [s["step_num"] for _, _, s in main["step"]] == [0, 1, 2]
-    for name in STEP_SPANS + MAIN_THREAD:
-        assert name in main, name
-    # children lie inside their step, on the main thread's line
+    # that each name is there at all is a case of its own in
+    # tests/test_telemetry_readers.py; here, where it lies: children inside
+    # their step, on the main thread's line
     for name in STEP_SPANS[1:] + MAIN_THREAD:
         for s, e, _ in main[name]:
             assert any(s0 <= s and e <= e0 for s0, e0, _ in main["step"]), name
@@ -193,7 +79,7 @@ def test_every_span_is_in_the_trace_on_its_thread(traced):
 
 
 def test_bucket_stats_tie_the_threads_together(traced):
-    lines, _, _ = traced
+    lines = traced.lines
     main = main_line(lines)
     everything = {}
     for ln in lines:
@@ -239,7 +125,7 @@ def test_bucket_stats_tie_the_threads_together(traced):
 def test_each_ring_is_followed_by_its_account(traced):
     """``exchange.ring.account``: zero-length, on the op thread, one a ring,
     after it and before the next."""
-    lines, _, _ = traced
+    lines = traced.lines
     (op,) = [ln for ln in lines if "exchange.ring" in ln]
     rings, accounts = sorted(op["exchange.ring"]), sorted(op["exchange.ring.account"])
     assert sum(len(ln.get("exchange.ring.account", ())) for ln in lines) == len(accounts) == len(rings)
@@ -254,12 +140,12 @@ def test_each_ring_is_followed_by_its_account(traced):
         assert all(account[k] == 0 for k in RING_ACCOUNT)
 
 
-def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, monkeypatch):
-    _, traced_losses, traced_checksum = traced
+def test_without_a_session_the_ring_gains_step_spans_only(traced, spans_train_step, monkeypatch):
+    traced_losses, traced_checksum = traced.losses, traced.checksum
     tracing.TRACER.clear()
     # ... and the annotations change nothing: same loss, same parameters
     monkeypatch.setattr(tracing, "annotate", lambda name, **stats: contextlib.nullcontext())
-    losses, checksum = run_steps(train_step, 3, monkeypatch)
+    losses, checksum = run_steps(spans_train_step, 3, monkeypatch)
     assert losses[0] == traced_losses[0] and checksum == traced_checksum
 
     spans = tracing.TRACER.recent()
@@ -291,9 +177,9 @@ def test_without_a_session_the_ring_gains_step_spans_only(traced, train_step, mo
     assert sum(1 for s in spans if s["name"] in STEP_SPANS) <= 12 * 3
 
 
-def test_a_vetoed_step_has_a_commit_and_no_apply(train_step, monkeypatch):
+def test_a_vetoed_step_has_a_commit_and_no_apply(spans_train_step, monkeypatch):
     tracing.TRACER.clear()
-    run_steps(train_step, 3, monkeypatch, veto_step=1)
+    run_steps(spans_train_step, 3, monkeypatch, veto_step=1)
     spans = tracing.TRACER.recent()
     steps = [s for s in spans if s["name"] == "step"]
     # the step after a veto is the vetoed step again: nothing was committed
@@ -309,8 +195,8 @@ def test_a_vetoed_step_has_a_commit_and_no_apply(train_step, monkeypatch):
         assert sorted(children) == sorted(want)
 
 
-def test_programs_and_scopes_have_stable_names(train_step):
-    ts = train_step
+def test_programs_and_scopes_have_stable_names(spans_train_step):
+    ts = spans_train_step
     params = ts.init_params(jax.random.PRNGKey(0))
     opt = ts.init_opt(params)
     tokens = ts.shard_batch(jnp.zeros((2, 16), jnp.int32))
@@ -334,12 +220,12 @@ def scopes(text):
     return found
 
 
-def test_every_link_of_the_chain_is_a_module_called_tft_grads(train_step):
+def test_every_link_of_the_chain_is_a_module_called_tft_grads(spans_train_step):
     """``grads`` of this stack is a chain of programs (head, a layer's, tail):
     each is a module ``jit_tft_grads`` — what sums a trace's ``XLA Modules``
     runs by that name sums the chain — under the scopes of its part of the
     model, and the update that takes the pieces is ``jit_tft_apply``."""
-    ts = train_step
+    ts = spans_train_step
     params = ts.init_params(jax.random.PRNGKey(0))
     opt = ts.init_opt(params)
     tokens = ts.shard_batch(jnp.zeros((2, 16), jnp.int32))

@@ -1,22 +1,15 @@
-"""Fleet time machine (ISSUE 11): native time-series store, per-commit
-critical-path attribution, and the perf-regression sentinel.
+"""The lighthouse's time-series store and what rides the same piggyback.
 
 Covers the native tsdb (piggyback ingest → /timeseries.json range
 queries, same-step overwrite, kill/respawn ring persistence, fan-out-cap
 loud degrade, C-ABI snapshot), the 64 KiB anatomy-digest cap (dropped
-loudly, never truncated — satellite), `merge_lathist` overflow-bucket
-exactness (satellite), the series builder, the Page-Hinkley detector
-(warm-up immunity, spike robustness, floor, latch/clear hysteresis,
-barrier exclusion), per-step critical-path attribution + the what-if
-estimate, both fleet monitors against a live in-process lighthouse, the
-/critical_path.json route, the postmortem --perf window mode, and the
-faultinject `after` onset rule.
+loudly, never truncated), `merge_lathist` overflow-bucket exactness, the
+series builder, and the faultinject `after` onset rule.
 """
 
 from __future__ import annotations
 
 import json
-import os
 import urllib.request
 from datetime import timedelta
 from types import SimpleNamespace
@@ -341,321 +334,6 @@ class TestBuildSeries:
                           "phase.compute"):
             assert essential in s, s
         assert not any(k.startswith("flag.") for k in s)
-
-
-# ---------------------------------------------------------------------------
-# Page-Hinkley detector
-# ---------------------------------------------------------------------------
-
-
-class TestPageHinkley:
-    def _ph(self, **kw):
-        from torchft_tpu.telemetry.regression import PageHinkley
-
-        kw.setdefault("delta", 0.1)
-        kw.setdefault("lam", 4.0)
-        kw.setdefault("min_n", 8)
-        kw.setdefault("k", 4)
-        return PageHinkley(**kw)
-
-    def test_level_shift_latches_once_then_clears_on_recovery(self):
-        ph = self._ph()
-        evs = []
-        for x in [0.1] * 12 + [0.25] * 10 + [0.1] * 10:
-            r = ph.observe(x)
-            if r:
-                evs.append(r)
-        assert evs == ["latched", "cleared"]
-        assert ph.latches == 1
-        assert 0.09 < ph.baseline < 0.12  # pre-shift level, frozen
-
-    def test_jit_warmup_does_not_poison_the_baseline(self):
-        # the real trace that broke the mean-based first cut: two 30-40x
-        # warm-up samples, then steady, then a +150ms shift — the median
-        # location must latch the shift anyway
-        ph = self._ph()
-        xs = [4.0, 0.8] + [0.09] * 10 + [0.25] * 8
-        evs = [r for x in xs for r in [ph.observe(x)] if r]
-        assert evs == ["latched"]
-
-    def test_single_spike_does_not_latch(self):
-        ph = self._ph()
-        xs = [0.1] * 20 + [3.0] + [0.1] * 20
-        assert [r for x in xs for r in [ph.observe(x)] if r] == []
-
-    def test_steady_jitter_does_not_latch(self):
-        import random
-
-        rng = random.Random(42)
-        ph = self._ph()
-        for _ in range(200):
-            assert ph.observe(0.1 + rng.uniform(-0.02, 0.02)) is None
-
-    def test_floor_disarms_micro_series(self):
-        # the control-soak lesson: a relative test on a 1ms stream is
-        # scheduler noise — 5x shifts under the floor must not latch
-        ph = self._ph(floor=0.02)
-        xs = [0.001] * 12 + [0.006] * 20
-        assert [r for x in xs for r in [ph.observe(x)] if r] == []
-
-    def test_warmup_min_n_blocks_early_latch(self):
-        ph = self._ph(min_n=8)
-        for x in [0.1, 0.5, 0.1, 0.5, 0.1]:  # wild but < min_n samples
-            assert ph.observe(x) is None
-
-
-class TestRegressionDetector:
-    def setup_method(self):
-        telemetry.reset()
-
-    def teardown_method(self):
-        telemetry.reset()
-
-    def test_latch_names_replica_and_phase_and_emits(self):
-        from torchft_tpu.telemetry.regression import RegressionDetector
-
-        det = RegressionDetector(min_n=6, k=3)
-        events = []
-        for step in range(30):
-            v = 0.1 if step < 15 else 0.3
-            ev = det.observe("gB", "phase.compute", step, v)
-            if ev:
-                events.append(ev)
-        assert len(events) == 1
-        ev = events[0]
-        assert ev["event"] == "perf_regression"
-        assert ev["replica"] == "gB" and ev["phase"] == "compute"
-        assert det.regressed() == [("gB", "phase.compute")]
-        kinds = [e["event"] for e in telemetry.EVENTS.recent()]
-        assert "perf_regression" in kinds
-
-    def test_barrier_phases_not_watched_by_default(self):
-        from torchft_tpu.telemetry.regression import RegressionDetector
-
-        det = RegressionDetector(min_n=4, k=2)
-        for step in range(40):
-            v = 0.05 if step < 20 else 0.5
-            assert det.observe("g", "phase.commit_barrier", step, v) is None
-            assert det.observe("g", "phase.wire", step, v) is None
-
-    def test_explicit_listing_overrides_barrier_exclusion(self, monkeypatch):
-        from torchft_tpu.telemetry.regression import RegressionDetector
-
-        monkeypatch.setenv(
-            "TORCHFT_REGRESSION_SERIES", "phase.commit_barrier"
-        )
-        det = RegressionDetector(min_n=4, k=2)
-        assert det.watched("phase.commit_barrier")
-        assert not det.watched("local_s")
-
-
-# ---------------------------------------------------------------------------
-# critical-path attribution
-# ---------------------------------------------------------------------------
-
-
-class TestCriticalPath:
-    def setup_method(self):
-        telemetry.reset()
-
-    def teardown_method(self):
-        from torchft_tpu.telemetry import critical_path
-
-        critical_path.set_reporter(None)
-        telemetry.reset()
-
-    def test_attribute_step_names_gater_and_phase(self):
-        from torchft_tpu.telemetry.critical_path import attribute_step
-
-        att = attribute_step({
-            "g0": {"wall_s": 0.5, "local_s": 0.2,
-                   "phases": {"compute": 0.15, "wire": 0.3}},
-            "g1": {"wall_s": 0.5, "local_s": 0.45,
-                   "phases": {"compute": 0.4, "wire": 0.02}},
-        })
-        assert att["gating"] == "g1" and att["phase"] == "compute"
-        assert att["blame_s"] == pytest.approx(0.25)
-        assert att["whatif_wall_s"] == pytest.approx(0.25)
-
-    def test_blame_never_lands_on_barrier_phases(self):
-        from torchft_tpu.telemetry.critical_path import attribute_step
-
-        # the gater's excess sits entirely in its wire wait — blame must
-        # fall back to its largest LOCAL phase, not the barrier
-        att = attribute_step({
-            "g0": {"wall_s": 0.3, "local_s": 0.1,
-                   "phases": {"compute": 0.1}},
-            "g1": {"wall_s": 0.3, "local_s": 0.25,
-                   "phases": {"compute": 0.1, "wire": 0.15}},
-        })
-        assert att["gating"] == "g1"
-        assert "wire" not in att["phase_blame"]
-
-    def test_single_replica_attributes_nothing(self):
-        from torchft_tpu.telemetry.critical_path import attribute_step
-
-        assert attribute_step(
-            {"g0": {"wall_s": 1.0, "local_s": 0.9, "phases": {}}}
-        ) is None
-
-    def test_attributor_accumulates_and_reports_whatif(self):
-        from torchft_tpu.telemetry.critical_path import (
-            CriticalPathAttributor,
-        )
-
-        attr = CriticalPathAttributor()
-        for step in range(10):
-            attr.observe_step(step, {
-                "g0": {"wall_s": 0.4, "local_s": 0.1,
-                       "phases": {"compute": 0.1}},
-                "g1": {"wall_s": 0.4, "local_s": 0.3,
-                       "phases": {"compute": 0.3}},
-            })
-        rep = attr.report()
-        assert rep["steps"] == 10
-        assert rep["blame"][0]["replica"] == "g1"
-        assert rep["blame"][0]["phase"] == "compute"
-        assert rep["blame"][0]["share"] == pytest.approx(1.0)
-        # removing g1's excess: 0.4 -> 0.2 per step, rate doubles
-        assert rep["whatif_steps_per_sec"] == pytest.approx(
-            2 * rep["measured_steps_per_sec"], rel=1e-6
-        )
-        assert attr.blame_by_replica() == pytest.approx({"g1": 2.0})
-        # the counter mirror carries the same totals
-        child = telemetry.CRITICAL_PATH_SECONDS.labels(
-            replica="g1", phase="compute"
-        )
-        assert child.value == pytest.approx(2.0)
-
-    def test_critical_path_json_route(self):
-        from torchft_tpu.checkpointing.http_transport import HTTPTransport
-        from torchft_tpu.telemetry import critical_path
-
-        transport = HTTPTransport(timeout=timedelta(seconds=5))
-        try:
-            url = f"http://localhost:{transport._port}/critical_path.json"
-            body = _get_json(url)
-            assert body["monitor"] is False and body["steps"] == 0
-            attr = critical_path.CriticalPathAttributor()
-            attr.observe_step(1, {
-                "g0": {"wall_s": 0.2, "local_s": 0.1, "phases": {}},
-                "g1": {"wall_s": 0.2, "local_s": 0.15,
-                       "phases": {"compute": 0.15}},
-            })
-            critical_path.set_reporter(attr)
-            body = _get_json(url)
-            assert body["monitor"] is True and body["steps"] == 1
-            assert body["blame"][0]["replica"] == "g1"
-        finally:
-            transport.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# fleet monitors against a live lighthouse
-# ---------------------------------------------------------------------------
-
-
-class TestMonitorsEndToEnd:
-    def setup_method(self):
-        telemetry.reset()
-
-    def teardown_method(self):
-        from torchft_tpu.telemetry import critical_path
-
-        critical_path.set_reporter(None)
-        telemetry.reset()
-
-    def test_regression_and_critical_path_monitors(self, lighthouse):
-        from torchft_tpu.telemetry.critical_path import CriticalPathMonitor
-        from torchft_tpu.telemetry.regression import (
-            RegressionDetector,
-            RegressionMonitor,
-        )
-
-        lh, client = lighthouse
-        rm = RegressionMonitor(
-            lh.address(),
-            detector=RegressionDetector(min_n=6, k=3),
-            poll_s=0.05,
-        )
-        cpm = CriticalPathMonitor(lh.address())
-        events = []
-        for step in range(36):
-            slow = step >= 18
-            for rid, base in (("gA", 0.1), ("gB", 0.1)):
-                local = base + (0.15 if (slow and rid == "gB") else 0.0)
-                _feed(client, rid, step, {
-                    "local_s": local,
-                    "wall_s": local + 0.05,
-                    "phase.compute": local,
-                })
-            events.extend(rm.poll_once())
-            cpm.poll_once()
-        cpm.drain()
-        latched = [e for e in events if e["event"] == "perf_regression"]
-        assert latched and all(e["replica"] == "gB" for e in latched)
-        # within a few observations of the onset at step 18
-        assert min(e["step"] for e in latched) <= 28
-        blame = cpm.attributor.blame_by_replica()
-        assert blame.get("gB", 0) > 0.8 * sum(blame.values())
-        rep = cpm.attributor.report()
-        assert rep["whatif_steps_per_sec"] > rep["measured_steps_per_sec"]
-
-    def test_monitor_survives_unreachable_lighthouse(self):
-        from torchft_tpu.telemetry.regression import RegressionMonitor
-
-        rm = RegressionMonitor("http://127.0.0.1:9", poll_s=0.05)
-        assert rm.poll_once() == []  # degrades, never raises
-
-
-# ---------------------------------------------------------------------------
-# postmortem --perf window mode
-# ---------------------------------------------------------------------------
-
-
-class TestPostmortemPerf:
-    def test_perf_windows_from_black_boxes(self, tmp_path, monkeypatch):
-        from torchft_tpu.telemetry.blackbox import BlackBox
-        from torchft_tpu.telemetry.postmortem import (
-            perf_windows,
-            render_perf_text,
-        )
-
-        box = BlackBox(path=str(tmp_path / "tft_bb_91001.bb"))
-        box.set_context(replica_id="gShift", step=0, quorum_epoch=1)
-        for step in range(1, 30):
-            local = 4.0 if step == 1 else (0.1 if step < 18 else 0.3)
-            box.record(
-                "anatomy_tick", step=step,
-                wall_s=local + 0.02, local_s=local,
-            )
-        box.close()
-        rep = perf_windows(str(tmp_path), min_n=6)
-        info = rep["replicas"]["gShift"]
-        assert info["steps"] == 29
-        latched = [
-            e for e in info["shifts"] if e["event"] == "perf_regression"
-        ]
-        assert latched, rep
-        assert all(e["replica"] == "gShift" for e in latched)
-        assert info["local_tail_mean_s"] > info["local_head_mean_s"] or \
-            latched  # the shift is visible one way or the other
-        text = render_perf_text(rep)
-        assert "gShift" in text and "perf_regression" in text
-
-    def test_perf_cli(self, tmp_path):
-        from torchft_tpu.telemetry.blackbox import BlackBox
-        from torchft_tpu.telemetry import postmortem
-
-        box = BlackBox(path=str(tmp_path / "tft_bb_91002.bb"))
-        box.set_context(replica_id="gA", step=0, quorum_epoch=1)
-        for step in range(1, 10):
-            box.record(
-                "anatomy_tick", step=step, wall_s=0.1, local_s=0.09
-            )
-        box.close()
-        rc = postmortem.main([str(tmp_path), "--perf", "--window", "5"])
-        assert rc == 0
 
 
 # ---------------------------------------------------------------------------

@@ -86,7 +86,6 @@ RUNTIME_MODULES = (
     "torchft_tpu/telemetry/slo.py",
     "torchft_tpu/telemetry/timeseries.py",
     "torchft_tpu/telemetry/blackbox.py",
-    "torchft_tpu/telemetry/critical_path.py",
 )
 
 _LOCK_FACTORIES = {"Lock", "RLock", "Condition", "Semaphore", "BoundedSemaphore"}

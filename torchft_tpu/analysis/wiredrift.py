@@ -39,11 +39,10 @@ Checks (rule ids):
 
 ``obs-env-drift``
     Same contract for the step-anatomy/SLO/straggler/forensics/
-    divergence/time-series/regression knob families (``TORCHFT_SLO_*`` /
+    divergence/time-series knob families (``TORCHFT_SLO_*`` /
     ``TORCHFT_STRAGGLER_*`` / ``TORCHFT_BLACKBOX_*`` /
-    ``TORCHFT_DIVERGENCE_*`` / ``TORCHFT_TSDB_*`` /
-    ``TORCHFT_REGRESSION_*``) against the knob registry in
-    ``docs/observability.md``.
+    ``TORCHFT_DIVERGENCE_*`` / ``TORCHFT_TSDB_*``) against the knob
+    registry in ``docs/observability.md``.
 
 ``heal-env-drift``
     Same contract for the heal-plane knob family (``TORCHFT_HEAL_*``)
@@ -287,7 +286,7 @@ def check_wire_env(
 
 
 _OBS_RE = re.compile(
-    r"TORCHFT_(?:SLO|STRAGGLER|BLACKBOX|DIVERGENCE|TSDB|REGRESSION|PROF"
+    r"TORCHFT_(?:SLO|STRAGGLER|BLACKBOX|DIVERGENCE|TSDB|PROF"
     r"|DIAG|TELEMETRY)_[A-Z0-9_]+"
 )
 
@@ -296,9 +295,9 @@ def check_obs_env(
     py_texts: Dict[str, str], obs_doc_text: str
 ) -> List[Finding]:
     """The TORCHFT_SLO_* / TORCHFT_STRAGGLER_* / TORCHFT_BLACKBOX_* /
-    TORCHFT_DIVERGENCE_* / TORCHFT_TSDB_* / TORCHFT_REGRESSION_* /
-    TORCHFT_PROF_* / TORCHFT_DIAG_* knob families vs the
-    docs/observability.md knob registry, both directions (the
+    TORCHFT_DIVERGENCE_* / TORCHFT_TSDB_* / TORCHFT_PROF_* /
+    TORCHFT_DIAG_* knob families vs the docs/observability.md knob
+    registry, both directions (the
     wire-env-drift contract for the step-anatomy, forensics, divergence,
     history and diagnosis planes). The TSDB and PROF knobs are ALSO
     parsed natively (tsdb.h / profiler.h getenv) — the Python references

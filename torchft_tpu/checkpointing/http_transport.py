@@ -221,22 +221,6 @@ class HTTPTransport(CheckpointTransport[T], Generic[T]):
                     except (BrokenPipeError, socket.timeout):
                         pass
                     return
-                # per-commit critical-path attribution (ISSUE 11): same
-                # lock-free contract as /metrics — serves the process
-                # attributor's report (empty shape when no monitor runs)
-                if self.path.rstrip("/") == "/critical_path.json":
-                    from torchft_tpu.telemetry import critical_path
-
-                    body = critical_path.report_json().encode()
-                    self.send_response(200)
-                    self.send_header("Content-Type", "application/json")
-                    self.send_header("Content-Length", str(len(body)))
-                    self.end_headers()
-                    try:
-                        self.wfile.write(body)
-                    except (BrokenPipeError, socket.timeout):
-                        pass
-                    return
                 # bound socket writes so one stalled healing peer can't hold
                 # the read lock forever (which would block the next
                 # disallow_checkpoint and fail should_commit on this side)

@@ -58,8 +58,8 @@ keeps its own hit counter; ``nth`` fires on the nth matching occurrence
 (once), ``every`` on every k-th, ``p`` Bernoulli per occurrence from an
 RNG seeded by ``(seed, rule index, site, match)`` — so a fixed seed
 replays the IDENTICAL injection sequence (asserted by test) — and
-``after`` on EVERY occurrence from the after-th onward (a mid-run onset:
-the perf-regression scenario's level shift). ``limit`` caps total fires
+``after`` on EVERY occurrence from the after-th onward (a mid-run
+onset). ``limit`` caps total fires
 (default 1 for ``nth``, unlimited otherwise).
 
 Every fired injection emits a ``fault_injected`` telemetry event, bumps
@@ -232,10 +232,9 @@ class _Rule:
         self.nth = spec.get("nth")
         self.every = spec.get("every")
         self.p = spec.get("p")
-        # onset semantics (ISSUE 11): fire on EVERY matching occurrence
-        # from the after-th onward — a mid-run level shift (the perf-
-        # regression scenario's +150ms delay) needs a clean onset step,
-        # which nth (one-shot) and every (periodic from the start)
+        # onset semantics: fire on EVERY matching occurrence from the
+        # after-th onward — a mid-run level shift needs a clean onset
+        # step, which nth (one-shot) and every (periodic from the start)
         # cannot express
         self.after = spec.get("after")
         if sum(

@@ -24,8 +24,16 @@ ordering are the result, the seconds are only bounded loosely by tests):
 * ``rejoin_to_commit_s`` — respawn exec → the rejoiner's first committed
   step, covering store bootstrap, quorum join, live checkpoint heal, and
   one training step.
-* ``steady_step_s`` — median healthy step time, so the blackout can be
-  read in step units (``survivor_steps_lost``, ``blackout_steps``).
+* ``survivor_failed_attempts`` — the product's claim as a count: the
+  step attempts of the surviving group that did not commit (a vote that
+  came back "no", or a deadline the worker retried after), from its last
+  commit before the kill to its first commit of a step the victim can
+  have had no part in (two past the victim's last). A count says how
+  many steps went, not how long one blocked: run with a lease and a join
+  wait longer than ``timeout_s`` and only an eviction lets the run end,
+  and ``evictions_total`` / ``requorum_without_victim`` say it happened.
+* ``steady_step_s`` — median healthy step time, so the blackout can also
+  be read in step units (``blackout_steps``).
 
 The detection cadence is configurable; the defaults here use aggressive
 1 s leases (the reference's defaults — 5 s heartbeat timeout, 60 s op
@@ -58,6 +66,12 @@ def _emit(log, **event) -> None:
     log.flush()
 
 
+def _go_file(event_log: str) -> str:
+    """The file the orchestrator makes, beside the event logs, once every
+    group has started."""
+    return os.path.join(os.path.dirname(event_log), "go")
+
+
 def _worker() -> None:
     """Numpy-only FT training loop; commits are timestamped to the event
     log. Deliberately jax-free so killing it never disturbs an
@@ -79,6 +93,7 @@ def _worker() -> None:
         )
 
     gid = int(os.environ["REPLICA_GROUP_ID"])
+    num_groups = int(os.environ["NUM_REPLICA_GROUPS"])
     total_steps = int(os.environ["TORCHFT_BENCH_STEPS"])
     step_sleep = float(os.environ.get("TORCHFT_BENCH_STEP_SLEEP", "0.05"))
     op_timeout = float(os.environ.get("TORCHFT_BENCH_OP_TIMEOUT", "1.0"))
@@ -121,10 +136,20 @@ def _worker() -> None:
         connect_timeout=timedelta(seconds=10),
     )
     _emit(log, event="start", gid=gid, pid=os.getpid())
+    # no step before every group heartbeats: a group that ran ahead alone
+    # would be killed with no peer holding a socket to it, to evict it
+    while not os.path.exists(_go_file(os.environ["TORCHFT_EVENT_LOG"])):
+        time.sleep(0.01)
     rng = np.random.default_rng(gid)
     heal_stats_seen: Dict[str, object] = {}
     try:
-        while manager.current_step() < total_steps:
+        # past ``total_steps`` the groups step on until all of them reduce
+        # together again: survivors that ran out of steps while a respawn
+        # was still starting would leave it nobody to rejoin
+        while (
+            manager.current_step() < total_steps
+            or manager.num_participants() < num_groups
+        ):
             try:
                 manager.start_quorum()
                 time.sleep(step_sleep)  # the "forward/backward" of the toy step
@@ -138,7 +163,9 @@ def _worker() -> None:
                 # orchestrator's own deadline still bounds a true wedge)
                 _emit(log, event="timeout_retry", gid=gid, err=str(e)[:120])
                 continue
-            if committed:
+            if not committed:
+                _emit(log, event="abort", gid=gid, step=manager.current_step())
+            else:
                 params["w"] -= 0.01 * grad
                 params["steps_seen"] += 1
                 # latch this worker's most recent heal attribution (the
@@ -153,6 +180,7 @@ def _worker() -> None:
                     event="commit",
                     gid=gid,
                     step=manager.current_step(),
+                    participants=manager.num_participants(),
                     pid=os.getpid(),
                 )
     finally:
@@ -195,8 +223,13 @@ class RecoveryResult:
     survivor_blackout_s: float
     rejoin_to_commit_s: float
     steady_step_s: float
-    survivor_steps_lost: int
+    survivor_failed_attempts: int
     total_steps: int
+    # the lighthouse's count of evictions it accepted, and whether the
+    # survivor's trail holds a quorum after the kill that the killed
+    # incarnation is no member of
+    evictions_total: int = 0
+    requorum_without_victim: bool = False
     # FT event-trail digest (event kind -> count across all groups) plus
     # the raw per-group trail paths, so the envelope numbers above can be
     # cross-checked against the recorded quorum/heal/peer-death sequence
@@ -225,7 +258,9 @@ class RecoveryResult:
             "blackout_steps": round(
                 self.survivor_blackout_s / max(self.steady_step_s, 1e-9), 1
             ),
-            "survivor_steps_lost": self.survivor_steps_lost,
+            "survivor_failed_attempts": self.survivor_failed_attempts,
+            "evictions_total": self.evictions_total,
+            "requorum_without_victim": self.requorum_without_victim,
         }
         if self.rejoin_slo is not None:
             out["rejoin_slo_s"] = self.rejoin_slo.get("rejoin_threshold_s")
@@ -310,6 +345,7 @@ def measure_recovery(
     timeout_s: float = 120.0,
     num_groups: int = 2,
     rejoin_slo_s: float = 1.0,
+    join_timeout_ms: int = 100,
 ) -> RecoveryResult:
     """Kill 1 of ``num_groups`` replica groups and measure the envelope
     (``num_groups=4`` is the north-star shape: survive killing 1-of-4
@@ -326,7 +362,7 @@ def measure_recovery(
     lighthouse = LighthouseServer(
         bind="[::]:0",
         min_replicas=1,
-        join_timeout_ms=100,
+        join_timeout_ms=join_timeout_ms,
         heartbeat_timeout_ms=heartbeat_timeout_ms,
     )
     addr = lighthouse.address().split("//", 1)[-1]
@@ -355,12 +391,19 @@ def measure_recovery(
                 num_groups,
             )
 
-        # let the victim reach the kill step
+        for g in range(num_groups):
+            _wait_for(
+                logs[g], lambda e: e["event"] == "start", timeout_s, procs=procs
+            )
+        open(_go_file(logs[0]), "w").close()
+        # let the victim reach the kill step, in a quorum of all the groups
         _wait_for(
             logs[victim_gid],
-            lambda e: e["event"] == "commit" and e["step"] >= kill_at_step,
+            lambda e: e["event"] == "commit"
+            and e["step"] >= kill_at_step
+            and e["participants"] == num_groups,
             timeout_s,
-            procs=[p for p in procs if p],
+            procs=procs,
         )
         victim = procs[victim_gid]
         t_kill = time.time()
@@ -403,16 +446,33 @@ def measure_recovery(
                 # otherwise go unnoticed and falsify the envelope
                 raise RuntimeError(f"group {g} exited rc={rc}")
 
-        g0 = [e for e in _read_events(logs[0]) if e["event"] == "commit"]
+        g0_events = _read_events(logs[0])
+        g0 = [e for e in g0_events if e["event"] == "commit"]
         pre = [e for e in g0 if e["t"] <= t_kill]
         steady = [b["t"] - a["t"] for a, b in zip(pre, pre[1:])]
         steady_step = sorted(steady)[len(steady) // 2] if steady else step_sleep
         last_pre_t = pre[-1]["t"] if pre else t_kill
-        last_pre_step = pre[-1]["step"] if pre else kill_at_step
         blackout = post["t"] - last_pre_t
-        # committed steps the survivor would have made during the blackout,
-        # minus the ones it did make: the "< 1 step" envelope in step units
-        lost = max(0, int(blackout / steady_step) - (post["step"] - last_pre_step))
+        # the "< 1 step" envelope: the survivor's attempts that did not
+        # commit, up to its first commit of a step the victim cannot have
+        # reduced into (the timestamps only order one file's records)
+        victim_last = max(
+            (
+                e["step"]
+                for e in _read_events(logs[victim_gid])
+                if e["event"] == "commit" and e["t"] <= t_kill
+            ),
+            default=kill_at_step,
+        )
+        clear_t = next(
+            (e["t"] for e in g0 if e["step"] >= victim_last + 2), float("inf")
+        )
+        lost = sum(
+            1
+            for e in g0_events
+            if e["event"] in ("abort", "timeout_retry")
+            and last_pre_t < e["t"] < clear_t
+        )
         from torchft_tpu.telemetry import read_trail
 
         ft_events: Dict[str, int] = {}
@@ -420,15 +480,34 @@ def measure_recovery(
             for rec in read_trail(path):
                 kind = rec.get("event", "?")
                 ft_events[kind] = ft_events.get(kind, 0) + 1
+        g0_quorums = [
+            r for r in read_trail(trails[0]) if r.get("event") == "quorum_ready"
+        ]
+        killed = {
+            p
+            for r in g0_quorums
+            if r["ts"] <= t_kill
+            for p in r["participants"]
+            if p.startswith(f"group{victim_gid}_")
+        }
+        requorum_without_victim = any(
+            r["ts"] > t_kill and not killed & set(r["participants"])
+            for r in g0_quorums
+        )
         # snapshot the cluster aggregation while the lighthouse is alive:
         # the merged trace IS the incident timeline (kill -> eviction ->
         # re-quorum -> heal) across every replica
-        from torchft_tpu.telemetry.native import fetch_merged_trace, poll_cluster
+        from torchft_tpu.telemetry.native import (
+            fetch_merged_trace,
+            poll_cluster,
+            poll_lighthouse,
+        )
 
         merged_trace_path = os.path.join(tmp, "cluster_trace.json")
         if fetch_merged_trace(lighthouse.address(), path=merged_trace_path) is None:
             merged_trace_path = None
         cluster = poll_cluster(lighthouse.address())
+        status = poll_lighthouse(lighthouse.address()) or {}
         # the rejoiner's SLO verdict + heal attribution: take the LAST
         # slo/heal_stats records in its log — those are the respawned
         # incarnation's (the killed one's records, if any, precede them)
@@ -461,8 +540,10 @@ def measure_recovery(
             survivor_blackout_s=blackout,
             rejoin_to_commit_s=rejoin["t"] - t_respawn,
             steady_step_s=steady_step,
-            survivor_steps_lost=lost,
+            survivor_failed_attempts=lost,
             total_steps=total_steps,
+            evictions_total=int(status.get("evictions_total", 0)),
+            requorum_without_victim=requorum_without_victim,
             ft_events=ft_events,
             trail_paths=list(trails),
             t_kill_unix=t_kill,

@@ -271,33 +271,6 @@ SCENARIOS: List[Scenario] = [
         quick=False,
     ),
     Scenario(
-        name="perf_regression",
-        description="+150ms collective.issue delay injected on group 1 "
-        "MID-RUN (the `after` onset rule): the perf-regression sentinel "
-        "(Page-Hinkley over the lighthouse's retained time series) must "
-        "latch exactly once per shifted series, naming the injected "
-        "group, within K commits of onset; critical-path attribution "
-        "must blame that group for >=80% of post-onset gating seconds "
-        "with a what-if estimate within 25% of the control leg's "
-        "measured step rate; /timeseries.json must serve the full "
-        "history across a replica kill/respawn (third leg); and an "
-        "equal-length control soak must latch ZERO regressions (custom "
-        "runner: run_perf_regression_scenario)",
-        victim_schedule={
-            "seed": 7,
-            "rules": [
-                {
-                    "site": "collective.issue",
-                    "match": "allreduce",
-                    "after": 13,
-                    "action": "delay",
-                    "ms": 150,
-                }
-            ],
-        },
-        quick=False,
-    ),
-    Scenario(
         name="stripe_heal_peer_death",
         description="3 groups (custom runner): the victim g2 is "
         "SIGKILLed mid-run and respawns into a striped multi-source heal "
@@ -1220,430 +1193,6 @@ def run_diagnose_scenario(
     )
 
 
-def run_perf_regression_scenario(
-    scn: Scenario, workdir: str, steps: int = 16, timeout_s: float = 600.0,
-) -> Result:
-    """The ``perf_regression`` scenario (ISSUE 11): three legs proving the
-    fleet time machine end to end.
-
-    **Control leg** — 2-group soak, no injection, the runner hosting the
-    perf-regression sentinel (:class:`RegressionMonitor`) and the
-    critical-path attributor (:class:`CriticalPathMonitor`) against the
-    live lighthouse's ``/timeseries.json``. Must latch ZERO regressions
-    (the false-positive gate).
-
-    **Injected leg** — identical soak, but group 1 submits every
-    allreduce late FROM the onset occurrence onward (the `after` rule —
-    a level shift, not a transient), the shift sized at ~1x the control
-    leg's measured median step wall (floor 150 ms) so the
-    relative-threshold sentinel sees a doubling at any host load. Asserts: (a) the sentinel
-    latches at least one series, every latch names the injected group,
-    and each (replica, series) latches exactly once; (b) the first latch
-    lands within K=10 commits of the measured onset step; (c) post-onset
-    critical-path blame lands >=80% on the injected group; (d) the
-    post-onset what-if steps/s estimate is within 25% of the measured
-    no-injection step rate — the SAME leg's steady pre-onset window, so
-    the two sides of the comparison share the box's load (the first cut
-    compared against the control leg and failed whenever background load
-    shifted between legs; a cross-leg reference measures the weather,
-    not the estimator); (e) checksums stay finite and bit-identical (a
-    delay must never corrupt averages).
-
-    **Persistence leg** — group 1 is SIGKILLed mid-run and respawned
-    (fresh replica uuid): after the run, ``/timeseries.json`` must still
-    serve the DEAD incarnation's pre-kill ring alongside the respawn's —
-    the full history across a kill/respawn, which is exactly what the
-    postmortem consumer needs."""
-    from torchft_tpu.coordination import LighthouseServer
-    from torchft_tpu.telemetry.critical_path import CriticalPathMonitor
-    from torchft_tpu.telemetry.regression import (
-        RegressionDetector,
-        RegressionMonitor,
-    )
-
-    victim_id = "train_bytes_1"
-    leg_steps = max(steps, 28)  # PH warm-up + onset + detection margin
-    K_COMMITS = 10
-    # slightly conservative vs the defaults: this box runs 2 jax workers
-    # on few cores, so per-step jitter is real — a wider drift allowance
-    # keeps the control leg honest while the ~1x-median shift still
-    # latches within a handful of samples
-    det_cfg = dict(delta=0.1, lam=4.0, min_n=8, k=4)
-
-    def leg(name: str, inject: bool, delay_ms: Optional[int] = None):
-        """One monitored 2-group soak. Returns (err, reg_events,
-        attributions, fired, onset_ts, workdir)."""
-        wd = os.path.join(workdir, name)
-        os.makedirs(wd, exist_ok=True)
-        evidence_dir = os.path.join(wd, "evidence")
-        os.makedirs(evidence_dir, exist_ok=True)
-        with open(os.path.join(wd, "corpus.bin"), "wb") as f:
-            f.write(bytes(range(256)) * 24)
-        # the tsdb store is process-global (one lighthouse per process in
-        # production); this runner hosts several lighthouses in ONE
-        # process across legs/scenarios, so clear the store or every
-        # previous leg's rings — same step numbers, different replicas —
-        # contaminate this leg's /timeseries.json and mix into the
-        # per-step attribution rows (found as a pytest-matrix-order
-        # failure: a prior straggler leg's 0.4s locals out-gated the
-        # live victim)
-        from torchft_tpu import _native
-
-        _native.tsdb_reset()
-        lighthouse = LighthouseServer(bind="[::]:0", min_replicas=2)
-        addr = lighthouse.address().split("//", 1)[-1]
-        monitor = RegressionMonitor(
-            lighthouse.address(),
-            detector=RegressionDetector(**det_cfg),
-            poll_s=0.25,
-        )
-        cpm = CriticalPathMonitor(lighthouse.address())
-        reg_events: List[Dict] = []
-        attributions: List[Dict] = []
-        env0 = _worker_env(scn, 0)
-        env1 = _worker_env(scn, 1)
-        if not inject:
-            env1.pop("TORCHFT_FAULT_SCHEDULE", None)
-        elif delay_ms is not None:
-            # the level shift is sized off the control leg's measured
-            # steady wall (see the call sites): PH's lambda/delta are
-            # RELATIVE to the running location, so a fixed 150 ms shift
-            # that latches instantly on idle ~0.08 s steps is invisible
-            # on a loaded box running ~0.5 s steps
-            sched = json.loads(env1["TORCHFT_FAULT_SCHEDULE"])
-            sched["rules"][0]["ms"] = int(delay_ms)
-            env1["TORCHFT_FAULT_SCHEDULE"] = json.dumps(sched)
-        procs = {
-            0: _spawn(0, addr, wd, leg_steps, env0),
-            1: _spawn(1, addr, wd, leg_steps, env1),
-        }
-        deadline = time.monotonic() + timeout_s
-        err: Optional[str] = None
-        try:
-            while True:
-                # the runner IS the history-plane consumer: poll
-                # synchronously so the detection sequence is
-                # deterministic per leg; ONE fetch feeds both consumers
-                try:
-                    from torchft_tpu.telemetry.timeseries import (
-                        poll_timeseries,
-                    )
-
-                    reply = poll_timeseries(lighthouse.address())
-                    if reply:
-                        reg_events.extend(monitor.poll_once(reply=reply))
-                        attributions.extend(cpm.poll_once(reply=reply))
-                except Exception:  # noqa: BLE001 — scrape races are fine
-                    pass
-                done = {g: p.poll() for g, p in procs.items()}
-                for gid, rc in done.items():
-                    if rc is not None and rc != 0:
-                        err = (
-                            f"{name}: g{gid} rc={rc}; log tail: "
-                            f"{_read_log(wd, gid)[-1000:]}"
-                        )
-                        break
-                if err or all(rc is not None for rc in done.values()):
-                    break
-                if time.monotonic() > deadline:
-                    err = f"{name}: timeout after {timeout_s}s"
-                    break
-                time.sleep(0.25)
-            # final sweep: the last steps' samples land with the final
-            # quorum RPCs — poll once more, then force pending steps out
-            try:
-                reg_events.extend(monitor.poll_once())
-                attributions.extend(cpm.poll_once())
-                attributions.extend(cpm.drain())
-            except Exception:  # noqa: BLE001
-                pass
-        finally:
-            for p in procs.values():
-                if p.poll() is None:
-                    p.kill()
-            lighthouse.shutdown()
-        if err is None:
-            cs_err, _sums = _final_checksums(wd)
-            if cs_err:
-                err = f"{name}: {cs_err}"
-        evidence = read_evidence(evidence_dir)
-        onset_ts = min(
-            (r["ts"] for r in evidence if r.get("action") == "delay"),
-            default=None,
-        )
-        return err, reg_events, attributions, len(evidence), onset_ts, wd
-
-    def onset_step_from_trail(wd: str, onset_ts: Optional[float]) -> int:
-        """The first step COMMITTED after the first delay fired — the
-        onset in commit coordinates (evidence records carry wall ts; the
-        victim's trail carries (ts, step) for every commit)."""
-        if onset_ts is None:
-            return -1
-        from torchft_tpu.telemetry.events import read_trail
-
-        try:
-            trail = read_trail(os.path.join(wd, "trail1.jsonl"))
-        except OSError:
-            return -1
-        commits = sorted(
-            (r["ts"], r.get("step", -1))
-            for r in trail
-            if r.get("event") == "commit"
-        )
-        for ts, step in commits:
-            if ts >= onset_ts:
-                return int(step)
-        return -1
-
-    # ---- control leg: the zero-false-latch gate -----------------------
-    err, ctl_events, ctl_atts, _f, _o, _wd = leg("control", inject=False)
-    if err:
-        return Result(scn.name, "failed", err)
-    ctl_regressions = [
-        e for e in ctl_events if e["event"] == "perf_regression"
-    ]
-    if ctl_regressions:
-        return Result(
-            scn.name, "failed",
-            f"control soak latched regressions (false positives): "
-            f"{ctl_regressions}",
-        )
-    if not ctl_atts:
-        return Result(
-            scn.name, "failed",
-            "control leg produced no critical-path attributions (no "
-            "per-step series reached the lighthouse?)",
-        )
-    # size the injected shift off the measured steady wall (post-warm-up
-    # commits only — the first ~8 steps are jit compiles): 1x the median
-    # step time is a doubling, which the relative-lambda PH latches in a
-    # handful of samples at ANY load level, where the spec's fixed
-    # 150 ms floor only clears the gate on an idle box
-    ctl_walls = sorted(
-        a["wall_s"] for a in ctl_atts
-        if a.get("wall_s") and a.get("step") is not None and a["step"] >= 8
-    )
-    delay_ms = 150
-    if ctl_walls:
-        delay_ms = max(150, int(1000 * ctl_walls[len(ctl_walls) // 2]))
-
-    # ---- injected leg -------------------------------------------------
-    err, events, atts, fired, onset_ts, wd = leg(
-        "injected", inject=True, delay_ms=delay_ms
-    )
-    if err:
-        return Result(scn.name, "failed", err, fired=fired)
-    if fired == 0:
-        return Result(
-            scn.name, "failed",
-            "no injection evidence recorded — the delay never fired",
-        )
-    regressions = [e for e in events if e["event"] == "perf_regression"]
-    if not regressions:
-        return Result(
-            scn.name, "failed",
-            f"sentinel latched nothing across {len(atts)} attributed "
-            f"steps (events: {events})", fired=fired,
-        )
-    wrong = [
-        e for e in regressions if not e["replica"].startswith(victim_id)
-    ]
-    if wrong:
-        return Result(
-            scn.name, "failed",
-            f"sentinel named non-injected replica(s): {wrong}",
-            fired=fired,
-        )
-    seen_series = [e["series"] for e in regressions]
-    if len(seen_series) != len(set(seen_series)):
-        return Result(
-            scn.name, "failed",
-            f"a series latched more than once in one episode: "
-            f"{regressions}", fired=fired,
-        )
-    onset_step = onset_step_from_trail(wd, onset_ts)
-    first_latch_step = min(e["step"] for e in regressions)
-    if onset_step >= 0 and first_latch_step > onset_step + K_COMMITS:
-        return Result(
-            scn.name, "failed",
-            f"first latch at step {first_latch_step}, more than "
-            f"{K_COMMITS} commits after onset step {onset_step}",
-            fired=fired,
-        )
-    # post-onset critical path: >=80% of blamed seconds on the victim
-    post = [
-        a for a in atts
-        if a.get("step") is not None
-        and (onset_step < 0 or a["step"] >= onset_step)
-        and a.get("blame_s", 0) > 0
-    ]
-    blame_by: Dict[str, float] = {}
-    for a in post:
-        blame_by[a["gating"]] = blame_by.get(a["gating"], 0.0) + a["blame_s"]
-    total_blame = sum(blame_by.values())
-    victim_blame = sum(
-        s for r, s in blame_by.items() if r.startswith(victim_id)
-    )
-    if total_blame <= 0 or victim_blame < 0.8 * total_blame:
-        return Result(
-            scn.name, "failed",
-            f"post-onset blame not >=80% on {victim_id}: {blame_by} "
-            f"(onset step {onset_step})", fired=fired,
-        )
-    # what-if: removing the gater's excess should recover the measured
-    # no-injection rate — the SAME leg's steady pre-onset window (skip
-    # the 30-40x jit warm-up steps), so estimator and reference share
-    # the box's load (the Coz-style estimate the attribution exists to
-    # produce)
-    pre_walls = [
-        a["wall_s"] for a in atts
-        if a.get("wall_s") and a.get("step") is not None
-        and 8 <= a["step"] < (onset_step if onset_step >= 0 else 10 ** 9)
-    ]
-    post_whatif = [a["whatif_wall_s"] for a in post if a.get("whatif_wall_s")]
-    whatif_sps = (
-        len(post_whatif) / sum(post_whatif) if post_whatif else 0.0
-    )
-    pre_sps = len(pre_walls) / sum(pre_walls) if pre_walls else 0.0
-    if not whatif_sps or not pre_sps or abs(whatif_sps / pre_sps - 1.0) > 0.25:
-        return Result(
-            scn.name, "failed",
-            f"what-if estimate {whatif_sps:.3f} steps/s not within 25% "
-            f"of the pre-onset no-injection rate {pre_sps:.3f} steps/s",
-            fired=fired,
-        )
-
-    # ---- persistence leg: kill/respawn, full history survives ---------
-    p_err = _persistence_leg(workdir, leg_steps, timeout_s)
-    if p_err:
-        return Result(scn.name, "failed", p_err, fired=fired)
-
-    return Result(
-        scn.name, "passed",
-        f"latched {sorted(set(seen_series))} on {victim_id}* at step "
-        f"{first_latch_step} (onset {onset_step}); post-onset blame "
-        f"{victim_blame / total_blame:.0%}; what-if {whatif_sps:.2f} vs "
-        f"pre-onset {pre_sps:.2f} steps/s ({len(ctl_atts)}-step control "
-        f"soak: zero latches); kill/respawn history served",
-        fired=fired,
-    )
-
-
-def _persistence_leg(
-    workdir: str, steps: int, timeout_s: float
-) -> Optional[str]:
-    """Kill group 1 mid-run, respawn it, and assert /timeseries.json
-    still serves BOTH incarnations' rings (the dead uuid's pre-kill
-    history + the respawn's post-heal samples). Returns an error string
-    or None."""
-    from torchft_tpu.coordination import LighthouseServer
-    from torchft_tpu.telemetry.timeseries import poll_timeseries
-
-    wd = os.path.join(workdir, "persistence")
-    os.makedirs(wd, exist_ok=True)
-    evidence_dir = os.path.join(wd, "evidence")
-    os.makedirs(evidence_dir, exist_ok=True)
-    with open(os.path.join(wd, "corpus.bin"), "wb") as f:
-        f.write(bytes(range(256)) * 24)
-    kill_schedule = json.dumps({
-        "seed": 8,
-        "rules": [{
-            "site": "collective.issue", "match": "allreduce",
-            "nth": 6, "action": "kill", "sig": 9,
-        }],
-    })
-    # process-global store: clear the previous legs' rings (see leg())
-    from torchft_tpu import _native
-
-    _native.tsdb_reset()
-    lighthouse = LighthouseServer(bind="[::]:0", min_replicas=2)
-    addr = lighthouse.address().split("//", 1)[-1]
-    procs = {
-        0: _spawn(0, addr, wd, steps, {}),
-        1: _spawn(1, addr, wd, steps,
-                  {"TORCHFT_FAULT_SCHEDULE": kill_schedule}),
-    }
-    respawned = False
-    deadline = time.monotonic() + timeout_s
-    err: Optional[str] = None
-    try:
-        while True:
-            for gid, p in list(procs.items()):
-                if p.poll() is None or p.returncode == 0:
-                    continue
-                kills = [
-                    r for r in read_evidence(evidence_dir)
-                    if r.get("action") == "kill" and r.get("pid") == p.pid
-                ]
-                if kills and not respawned:
-                    respawned = True
-                    procs[gid] = _spawn(gid, addr, wd, steps, {})
-                else:
-                    err = (
-                        f"persistence: g{gid} rc={p.returncode} "
-                        f"unexplained; log tail: "
-                        f"{_read_log(wd, gid)[-800:]}"
-                    )
-                    break
-            if err or all(p.poll() is not None for p in procs.values()):
-                break
-            if time.monotonic() > deadline:
-                err = f"persistence: timeout after {timeout_s}s"
-                break
-            time.sleep(0.5)
-        if err is None and not respawned:
-            err = "persistence: the scheduled kill never fired"
-        if err is None:
-            # the whole point: query the lighthouse BEFORE shutdown —
-            # the dead incarnation's ring must still be there, next to
-            # the respawn's
-            reply = poll_timeseries(lighthouse.address())
-            if not reply:
-                err = "persistence: /timeseries.json unreachable"
-            else:
-                rings = {
-                    rid: body for rid, body in reply["replicas"].items()
-                    if "local_s" in body
-                }
-                g1 = [r for r in rings if r.startswith("train_bytes_1")]
-                if len(g1) < 2:
-                    err = (
-                        f"persistence: expected BOTH g1 incarnations' "
-                        f"rings (dead + respawn), got {sorted(rings)}"
-                    )
-                else:
-                    # dead incarnation: pre-kill history retained; some
-                    # ring reaches the end of the run
-                    counts = {
-                        r: len(rings[r]["local_s"]["samples"]) for r in g1
-                    }
-                    max_step = max(
-                        s[1]
-                        for body in rings.values()
-                        for s in body["local_s"]["samples"]
-                    )
-                    if min(counts.values()) < 1:
-                        err = (
-                            f"persistence: an incarnation's ring is "
-                            f"empty: {counts}"
-                        )
-                    elif max_step < steps - 4:
-                        err = (
-                            f"persistence: history stops at step "
-                            f"{max_step} of {steps}"
-                        )
-    finally:
-        for p in procs.values():
-            if p.poll() is None:
-                p.kill()
-        lighthouse.shutdown()
-    if err is None:
-        cs_err, _sums = _final_checksums(wd)
-        if cs_err:
-            err = f"persistence: {cs_err}"
-    return err
-
-
 def run_postmortem_scenario(
     scn: Scenario, workdir: str, steps: int = 16, timeout_s: float = 600.0,
     extra_env: Optional[Dict[str, str]] = None,
@@ -2123,19 +1672,6 @@ def main(argv: Optional[List[str]] = None) -> int:
             res = run_diagnose_scenario(
                 scn, wd, steps=steps, timeout_s=args.timeout,
                 extra_env=extra_env, worker_argv=worker_argv,
-            )
-        elif scn.name == "perf_regression":
-            if args.sanitize:
-                ap.error(
-                    "perf_regression is not wired for --sanitize (the "
-                    "detection loop needs the jax trainer's time-series "
-                    "piggyback); run it unsanitized"
-                )
-            # custom three-leg runner (control + injected onset +
-            # kill/respawn persistence) with the regression sentinel and
-            # critical-path monitors hosted by the runner process
-            res = run_perf_regression_scenario(
-                scn, wd, steps=steps, timeout_s=args.timeout
             )
         elif scn.name == "stripe_heal_peer_death":
             # custom 3-group runner: a striped heal needs two sources so

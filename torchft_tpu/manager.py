@@ -304,35 +304,6 @@ class Manager:
             from torchft_tpu.telemetry.slo import FleetMonitor
 
             self._fleet_monitor = FleetMonitor(self._lighthouse_addr).start()
-        # opt-in history-plane monitors (ISSUE 11): the perf-regression
-        # sentinel and the critical-path attributor both consume the
-        # lighthouse's retained time series; one knob hosts both (one
-        # history plane), rank 0 only, like the straggler monitor
-        self._regression_monitor = None
-        self._critical_path_monitor = None
-        self._cp_stop = threading.Event()
-        self._cp_thread: Optional[threading.Thread] = None
-        if (
-            os.environ.get("TORCHFT_REGRESSION_MONITOR", "0") == "1"
-            and self._lighthouse_addr is not None
-            and self._rank == 0
-        ):
-            from torchft_tpu.telemetry.critical_path import (
-                CriticalPathMonitor,
-            )
-            from torchft_tpu.telemetry.regression import RegressionMonitor
-
-            # one poll thread feeds BOTH consumers from one
-            # /timeseries.json fetch per interval — the full-ring reply
-            # can be megabytes, and two independent pollers would pay it
-            # (and the lighthouse's tsdb mutex) twice
-            self._regression_monitor = RegressionMonitor(
-                self._lighthouse_addr
-            )
-            self._critical_path_monitor = CriticalPathMonitor(
-                self._lighthouse_addr
-            )
-            self._start_history_thread()
 
         self._participating_rank: Optional[int] = None
         self._participating_world_size: int = 0
@@ -370,7 +341,7 @@ class Manager:
         # always-on at TORCHFT_PROF_HZ (0 disarms; the native sampler
         # arms itself at thread registration), and — when
         # TORCHFT_DIAG_DIR is set — a DiagnosisEngine turns latch events
-        # (straggler / perf-regression / SLO / watchdog / divergence)
+        # (straggler / SLO / watchdog / divergence)
         # into bounded deep-capture bundles, announced on the piggyback.
         from torchft_tpu.telemetry.diagnosis import DiagnosisEngine, diag_dir
         from torchft_tpu.telemetry.profiler import PROFILER
@@ -411,33 +382,6 @@ class Manager:
         # — the op thread is serial), folded + cleared at should_commit
         self._step_digests: List[str] = []
         self._divergence_latched = False
-
-    def _start_history_thread(self) -> None:
-        """Poll loop hosting the history-plane consumers (rank 0, armed
-        by TORCHFT_REGRESSION_MONITOR=1): ONE /timeseries.json fetch per
-        TORCHFT_REGRESSION_POLL_S feeds the regression sentinel and the
-        critical-path attributor — each keeps its own per-(replica,
-        series) cursor, so sharing the reply is free."""
-        from torchft_tpu.telemetry.regression import _env_float
-        from torchft_tpu.telemetry.timeseries import poll_timeseries
-
-        poll_s = _env_float("TORCHFT_REGRESSION_POLL_S", 2.0)
-
-        def run() -> None:
-            while not self._cp_stop.wait(poll_s):
-                try:
-                    reply = poll_timeseries(self._lighthouse_addr)
-                    if not reply:
-                        continue
-                    self._regression_monitor.poll_once(reply=reply)
-                    self._critical_path_monitor.poll_once(reply=reply)
-                except Exception:  # noqa: BLE001 — monitoring must not die
-                    pass
-
-        self._cp_thread = threading.Thread(
-            target=run, daemon=True, name="tft_history_monitor"
-        )
-        self._cp_thread.start()
 
     def _on_stall(self, step: int, elapsed_s: float, threshold_s: float) -> None:
         """Watchdog stall callback (watchdog thread): ship the stuck
@@ -819,12 +763,6 @@ class Manager:
             self._diagnosis.remove()
         if self._fleet_monitor is not None:
             self._fleet_monitor.stop()
-        if self._regression_monitor is not None:
-            self._regression_monitor.stop()
-        self._cp_stop.set()
-        if self._cp_thread is not None:
-            self._cp_thread.join(timeout=5.0)
-            self._cp_thread = None
         self._checkpoint_transport.shutdown(wait=wait)
         if self._manager is not None:
             self._manager.shutdown()

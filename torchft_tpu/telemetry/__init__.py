@@ -312,30 +312,6 @@ DIVERGENCE_TOTAL = REGISTRY.counter(
     "docs/observability.md 'Divergence sentinel')",
 )
 
-# fleet time machine (ISSUE 11): per-commit critical-path attribution
-# (telemetry/critical_path.py) and the perf-regression sentinel
-# (telemetry/regression.py) over the retained time series
-CRITICAL_PATH_SECONDS = REGISTRY.counter(
-    "tft_critical_path_seconds_total",
-    "Blamed seconds per (replica, phase): for each committed step, the "
-    "excess local time of the step's gating replica over the fleet "
-    "median, split across its non-barrier anatomy phases — see "
-    "docs/observability.md 'Critical path'",
-    labelnames=("replica", "phase"),
-)
-CRITICAL_PATH_WHATIF = REGISTRY.gauge(
-    "tft_critical_path_whatif_steps_per_sec",
-    "What-if fleet throughput: steps/s if every step's gating replica "
-    "had run at the fleet median local time (Coz-style causal estimate)",
-)
-PERF_REGRESSION_TOTAL = REGISTRY.counter(
-    "tft_perf_regression_total",
-    "Page-Hinkley level-shift latches over the retained time series, by "
-    "(replica, series) — the threshold-free whole-fleet-drift detector "
-    "(docs/observability.md 'Perf regression')",
-    labelnames=("replica", "series"),
-)
-
 # diagnosis plane (ISSUE 12): always-on profiler samples (both planes)
 # and latch-triggered deep-capture bundles (telemetry/diagnosis.py)
 PROF_SAMPLES = REGISTRY.counter(

@@ -346,9 +346,9 @@ class StepLedger:
     def last_row(self) -> Optional[Dict[str, Any]]:
         """The most recent step row (step / wall_s / local_s / phases) or
         None — the per-step sample the time-series piggyback publishes
-        (telemetry/timeseries.py): percentiles smooth exactly the level
-        shifts the regression sentinel exists to catch, so the retained
-        series carries raw per-step values."""
+        (telemetry/timeseries.py): percentiles would smooth a level
+        shift away, so the retained series carries raw per-step
+        values."""
         with self._lock:
             if not self._rows:
                 return None

@@ -2,8 +2,8 @@
 (ISSUE 12).
 
 The fleet already *detects* well: straggler latches, burn-rate SLOs, the
-step watchdog, the divergence sentinel and the perf-regression sentinel
-all fire precise, debounced events. But each one bottoms out at phase
+step watchdog and the divergence sentinel all fire precise, debounced
+events. But each one bottoms out at phase
 granularity — "train_bytes_1 lost 150 ms/step in compute" — and nothing
 can say *which code*. This module closes that gap the way production
 fleets do (Google-Wide-Profiler-style): the profilers are ALWAYS ON at
@@ -13,10 +13,10 @@ attaching a profiler after the fact.
 
 One :class:`DiagnosisEngine` per process (the Manager hosts it whenever
 ``TORCHFT_DIAG_DIR`` is set). It subscribes to the live event trail and,
-on any of the five latch events —
+on any of the four latch events —
 
-    ``straggler_detected``, ``perf_regression``, ``slo_breach``,
-    ``watchdog_stall``, ``divergence_detected``
+    ``straggler_detected``, ``slo_breach``, ``watchdog_stall``,
+    ``divergence_detected``
 
 — debounced **once per episode** (re-armed by the matching ``*_cleared``
 event, or after ``TORCHFT_DIAG_REARM_S`` for latches that never clear),
@@ -68,7 +68,6 @@ __all__ = [
 # own; the engine re-arms after TORCHFT_DIAG_REARM_S instead)
 TRIGGER_EVENTS: Dict[str, Optional[str]] = {
     "straggler_detected": "straggler_cleared",
-    "perf_regression": "perf_regression_cleared",
     "slo_breach": "slo_recovered",
     "watchdog_stall": None,
     "divergence_detected": None,
@@ -115,16 +114,10 @@ def _subject(record: Dict[str, Any]) -> Optional[str]:
 def _episode_key(kind: str, record: Dict[str, Any]) -> tuple:
     """The debounce key: one episode per (trigger, subject, stream).
     The stream discriminator keeps DISTINCT latches independent — the
-    two SLOs (step_time / rejoin_commit) share one event kind, and a
-    perf_regression on wall_s is a different episode than one on
-    phase.compute; without it, a rejoin breach would be swallowed by a
-    live step_time episode and its recovery would re-arm the wrong
-    latch."""
-    return (
-        kind,
-        _subject(record),
-        record.get("slo") or record.get("series"),
-    )
+    two SLOs (step_time / rejoin_commit) share one event kind; without
+    it, a rejoin breach would be swallowed by a live step_time episode
+    and its recovery would re-arm the wrong latch."""
+    return (kind, _subject(record), record.get("slo"))
 
 
 def _lathist_delta_quantiles(

@@ -72,8 +72,6 @@ CANONICAL_EVENTS = (
     "straggler_cleared",
     "divergence_detected",
     "blackbox_recovered",
-    "perf_regression",
-    "perf_regression_cleared",
     "diagnosis_captured",
     "attention_path",
     "layer_pattern",

@@ -42,8 +42,7 @@ from torchft_tpu.telemetry.blackbox import (
 )
 
 __all__ = [
-    "collect_boxes", "analyze", "classify", "render_text",
-    "perf_windows", "render_perf_text", "main",
+    "collect_boxes", "analyze", "classify", "render_text", "main",
 ]
 
 # record kinds that mark "something went wrong here" on the timeline
@@ -365,110 +364,6 @@ def analyze(
     return report
 
 
-def perf_windows(
-    root: str,
-    window: int = 0,
-    delta: Optional[float] = None,
-    lam: Optional[float] = None,
-    min_n: Optional[int] = None,
-) -> Dict[str, Any]:
-    """``--perf`` window mode (ISSUE 11): reconstruct each replica's
-    per-step wall/local series from the crash-durable ``anatomy_tick``
-    black-box records — the SAME series the lighthouse time-series store
-    retains live, read post-hoc from disk — and run the perf-regression
-    sentinel (:mod:`torchft_tpu.telemetry.regression` Page-Hinkley)
-    offline over them. Answers "when did this fleet get slow" from the
-    boxes ALONE, after every live surface died with its processes.
-
-    ``window`` keeps only the last N steps per replica (0 = all).
-    Returns per-replica: the step range, first/last-window means, and
-    every latched shift with its onset step."""
-    from torchft_tpu.telemetry.regression import RegressionDetector
-
-    boxes = collect_boxes(root)
-    # replica -> [(step, wall_s, local_s)] in recorded order
-    series: Dict[str, List[Tuple[int, float, float]]] = {}
-    for box in boxes:
-        src = box["replica"] or f"pid:{box['pid']}"
-        for rec in box["records"]:
-            if rec.get("k") != "anatomy_tick":
-                continue
-            try:
-                step = int(rec.get("step", rec.get("st", -1)))
-                wall = float(rec.get("wall_s", 0.0))
-                local = float(rec.get("local_s", 0.0))
-            except (TypeError, ValueError):
-                continue
-            if step >= 0 and wall > 0:
-                series.setdefault(src, []).append((step, wall, local))
-    kwargs: Dict[str, Any] = {}
-    if delta is not None:
-        kwargs["delta"] = delta
-    if lam is not None:
-        kwargs["lam"] = lam
-    if min_n is not None:
-        kwargs["min_n"] = min_n
-    # unlike the live monitor (which excludes wall_s — the straggler/
-    # critical-path planes already own cross-replica wall analysis),
-    # this offline window feeds BOTH reconstructed series, so watch both:
-    # a barrier-dominated degradation shows in wall while local stays
-    # flat, and 'no level shift latched' would be a lie
-    detector = RegressionDetector(
-        prefixes=("local_s", "wall_s", "phase."), **kwargs
-    )
-    out: Dict[str, Any] = {"root": root, "replicas": {}}
-    for src, samples in sorted(series.items()):
-        samples.sort(key=lambda t: t[0])
-        if window > 0:
-            samples = samples[-window:]
-        shifts: List[Dict[str, Any]] = []
-        for step, wall, local in samples:
-            for name, value in (("wall_s", wall), ("local_s", local)):
-                ev = detector.observe(src, name, step, value)
-                if ev is not None:
-                    shifts.append(ev)
-        locals_ = [s[2] for s in samples]
-        head = locals_[: max(1, len(locals_) // 4)]
-        tail = locals_[-max(1, len(locals_) // 4):]
-        out["replicas"][src] = {
-            "steps": len(samples),
-            "step_range": [samples[0][0], samples[-1][0]] if samples else [],
-            "local_head_mean_s": (
-                round(sum(head) / len(head), 6) if head else None
-            ),
-            "local_tail_mean_s": (
-                round(sum(tail) / len(tail), 6) if tail else None
-            ),
-            "shifts": shifts,
-        }
-    out["regressed"] = [
-        {"replica": r, "series": s} for r, s in detector.regressed()
-    ]
-    return out
-
-
-def render_perf_text(report: Dict[str, Any]) -> str:
-    lines = [f"perf window of {report['root']}"]
-    for src, info in sorted(report.get("replicas", {}).items()):
-        lines.append(
-            f"  {src}: {info['steps']} steps {info['step_range']} "
-            f"local mean {info['local_head_mean_s']}s -> "
-            f"{info['local_tail_mean_s']}s"
-        )
-        for ev in info.get("shifts", []):
-            lines.append(
-                f"    {ev['event']}: {ev['series']} at step {ev['step']}"
-                + (
-                    f" (baseline {ev['baseline_s']}s -> {ev['value_s']}s)"
-                    if "baseline_s" in ev
-                    else ""
-                )
-            )
-    if not report.get("regressed"):
-        lines.append("  no level shift latched")
-    return "\n".join(lines)
-
-
 def render_text(report: Dict[str, Any]) -> str:
     """Human-readable incident summary (the JSON report is the machine
     surface; this is the triage page)."""
@@ -531,13 +426,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="also write the full report JSON here")
     ap.add_argument("--timeline", type=int, default=0,
                     help="print the last N merged timeline records")
-    ap.add_argument("--perf", action="store_true",
-                    help="perf window mode: reconstruct per-replica "
-                    "wall/local step series from the boxes' anatomy "
-                    "ticks and run the perf-regression sentinel offline")
-    ap.add_argument("--window", type=int, default=0,
-                    help="--perf: analyze only the last N steps per "
-                    "replica (0 = all)")
     ap.add_argument("--bundles", nargs="?", const="", default=None,
                     metavar="DIR",
                     help="fold diagnosis bundles (bundle.json dirs) into "
@@ -549,15 +437,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     "(analysis/protocol) and flag illegal transitions; "
                     "exit 2 on any finding")
     args = ap.parse_args(argv)
-
-    if args.perf:
-        perf = perf_windows(args.dir, window=args.window)
-        print(render_perf_text(perf))
-        if args.json_out:
-            with open(args.json_out, "w", encoding="utf-8") as f:
-                json.dump(perf, f, indent=1, default=str)
-            print(f"report: {args.json_out}")
-        return 0
 
     report = analyze(args.dir, bundles_dir=args.bundles)
     print(render_text(report))

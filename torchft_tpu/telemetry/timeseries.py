@@ -10,18 +10,17 @@ control-plane round trips. This module is both ends of that pipe:
   ``{name: float}`` sample map the Manager attaches to its telemetry
   payload each step: the last step row's wall/local/per-phase seconds
   (``telemetry.anatomy.StepLedger.last_row`` — raw per-step values, not
-  percentiles, because percentile smoothing is exactly what would hide
-  the level shifts the regression sentinel catches), the rolling local
-  p50, lathist-derived native p50/p99s, and the SLO/stuck/divergence
+  percentiles, because percentile smoothing would hide a level shift
+  from whoever reads the ring), the rolling local p50, lathist-derived
+  native p50/p99s, and the SLO/stuck/divergence
   flags as 0/1 series. The lighthouse stays schema-blind: names are
   opaque strings, so this vocabulary can evolve without touching C++.
 
 * :func:`poll_timeseries` — the fleet side. One ``GET /timeseries.json``
   range query (``since`` step cursor, ``max_points`` stride
-  downsampling, replica/series substring filters) against the lighthouse
-  that the critical-path attributor
-  (:mod:`torchft_tpu.telemetry.critical_path`) and the perf-regression
-  sentinel (:mod:`torchft_tpu.telemetry.regression`) both consume.
+  downsampling, replica/series substring filters) against the
+  lighthouse. Its readers are operators (the route itself) and the
+  diagnosis bundle's tsdb window (:mod:`torchft_tpu.telemetry.diagnosis`).
 
 Series vocabulary published by :func:`build_series` (all seconds unless
 flagged):
@@ -63,7 +62,7 @@ from __future__ import annotations
 import json
 import os
 import urllib.request
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 __all__ = [
     "DEFAULT_RETAIN",
@@ -71,7 +70,6 @@ __all__ = [
     "series_enabled",
     "build_series",
     "poll_timeseries",
-    "iter_new_samples",
 ]
 
 DEFAULT_RETAIN = 512
@@ -146,10 +144,10 @@ def build_series(
         if len(out) > cap:
             # deterministic PRIORITY trim — the lighthouse would refuse
             # the overflow anyway; trimming here controls WHICH series
-            # survive. Ordered by consumer criticality, not
-            # alphabetically: wall/local and the phase decomposition
-            # feed the critical-path and regression planes and must
-            # outlive diagnostics like lat.* quantiles and the 0/1 flags
+            # survive. Ordered by what a reader of the ring needs
+            # first, not alphabetically: wall/local and the phase
+            # decomposition are the step's account and must outlive
+            # diagnostics like lat.* quantiles and the 0/1 flags
             # (a lexicographic trim would cut wall_s FIRST and keep
             # flag.* — exactly backwards).
             def rank(name: str) -> int:
@@ -207,32 +205,3 @@ def poll_timeseries(
             return json.loads(resp.read().decode())
     except Exception:  # noqa: BLE001
         return None
-
-
-def iter_new_samples(
-    reply: Dict[str, Any],
-    cursor: Dict[Tuple[str, str], int],
-) -> Iterable[Tuple[str, str, int, int, float]]:
-    """Yield ``(replica, series, epoch, step, value)`` for every sample in
-    ``reply`` newer than the per-(replica, series) ``cursor`` (mutated in
-    place), in step order per series. The shared consumption idiom of the
-    regression sentinel and the critical-path monitor: both poll the full
-    ring and dedup here, so a replica lagging the fleet-wide max step
-    (or a respawn restarting at step 0) never loses samples to a global
-    since-cursor."""
-    for rid, all_series in (reply.get("replicas") or {}).items():
-        for name, body in (all_series or {}).items():
-            key = (rid, name)
-            last = cursor.get(key)
-            for sample in body.get("samples") or []:
-                try:
-                    epoch, step, value = (
-                        int(sample[0]), int(sample[1]), float(sample[2]),
-                    )
-                except (TypeError, ValueError, IndexError):
-                    continue
-                if last is not None and step <= last:
-                    continue
-                cursor[key] = step
-                last = step
-                yield rid, name, epoch, step, value
