@@ -521,7 +521,8 @@ def test_a_dp_x_fsdp_x_tp_mesh_gives_the_unsharded_loss():
 # ``jnp.take_along_axis`` out of ``_route``'s sigmoid branch (30c86893…85fd at b50bcfa and before): a ``gather`` a sparse
 # layer a forward pass and its ``scatter-add`` gone, ``_chosen``'s compare, select and sum over the experts in their
 # place — old counts against new in ``tests/test_gdn_train.CELLS_PROGRAMS``' comment; qwen3-next-80b-a3b-1g's softmax
-# gate reads ``top_k``'s own values and did not move.
+# gate reads ``top_k``'s own values and did not move. Nor did either by the PR that hands the delta rule's block inverse
+# on as a second residual: at the rehearsal's head width, no lane tile, qwen3-next-80b-a3b-1g's mixer is the ``jax.numpy`` form.
 REHEARSAL_PROGRAMS = {
     "laguna-xs2-1g": "f33873fbe0128e8c1a4868ed1a45969285f26c5c6927ad3e923f3c55b9333d12",
     "qwen3-next-80b-a3b-1g": "6a3ed70638d27b8879d03a1c6168a7bfff66cfbe6c74f18844d2acbfb8af3d9c",

@@ -758,6 +758,7 @@ def test_the_kda_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch
     wide, g = of((batch, seq, heads * 128), jnp.bfloat16), of((batch, seq, heads * 128), jnp.float32)
     beta, state = of((batch, seq, heads), jnp.float32), of((batch, heads, 128, 128), jnp.float32)
     starts = of((batch, heads, seq // kernels.CHUNK, 128, 128), jnp.float32)
+    inverse = of((batch, heads, seq // kernels.ROWS, kernels.ROWS, kernels.ROWS), jnp.bfloat16)  # the second residual (PR 64)
     forward = jax.jit(lambda *a: kernels.kda_forward(*a, interpret=False))
     backward = jax.jit(lambda *a: kernels.kda_backward(*a, interpret=False))
     jax.config.update("jax_enable_compilation_cache", False)
@@ -765,7 +766,7 @@ def test_the_kda_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch
     try:
         texts = (
             forward.lower(wide, wide, wide, g, beta, state).compile().as_text(),
-            backward.lower(wide, wide, wide, g, beta, starts, wide, state).compile().as_text(),
+            backward.lower(wide, wide, wide, g, beta, starts, inverse, wide, state).compile().as_text(),
         )
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
@@ -796,6 +797,7 @@ def test_the_gdn_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch
     keys, values = of((batch, seq, key_heads * 128), dtype), of((batch, seq, heads * 128), dtype)
     small, state = of((batch, seq, heads)), of((batch, heads, 128, 128))
     starts = of((batch, heads, seq // kernels.CHUNK, 128, 128))
+    inverse = of((batch, heads, seq // kernels.ROWS, kernels.ROWS, kernels.ROWS), dtype)  # the second residual (PR 64)
     forward = jax.jit(lambda *a: kernels.gdn_forward(*a, interpret=False))
     backward = jax.jit(lambda *a: kernels.gdn_backward(*a, interpret=False))
     jax.config.update("jax_enable_compilation_cache", False)
@@ -803,7 +805,7 @@ def test_the_gdn_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch
     try:
         compiled = (
             forward.lower(keys, keys, values, small, small, state).compile(),
-            backward.lower(keys, keys, values, small, small, starts, values, state).compile(),
+            backward.lower(keys, keys, values, small, small, starts, inverse, values, state).compile(),
         )
     finally:
         jax.config.update("jax_enable_compilation_cache", True)

@@ -281,6 +281,35 @@ def test_the_value_heads_read_the_key_head_of_their_group():
         gdn_chunked(q, k, v[:, :, :3], g[..., :3], beta[..., :3])
 
 
+# -- the second residual: ``gdn_bwd`` reads ``M^-1`` where it formed it again (PR 64) ------------------------------
+
+
+def kernels_inputs(dtype, seq=256):
+    """One sequence of two key heads under four value heads as ``gdn_forward`` takes them, a state, and both cotangents."""
+    from torchft_tpu.ops.kda import _wide
+
+    q, k, v, g, beta, state = (x[:1] for x in core_inputs(seq, 3.0, d=WIDE, seed=5))
+    rng = np.random.default_rng(17)
+    do, d_end = (jnp.asarray(rng.normal(size=x.shape), jnp.float32) for x in (v, state))
+    return (_wide(q.astype(dtype)), _wide(k.astype(dtype)), _wide(v.astype(dtype)), g, beta), state, _wide(do.astype(dtype)), 0.1 * d_end
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_backward_given_the_forwards_inverse_is_the_one_that_forms_it_again(dtype, monkeypatch):
+    """``tests/test_kda.py``'s check of the per-channel pair, on ``gdn_forward`` /
+    ``gdn_backward``: an inverse a VALUE head, four of them the one grid step's."""
+    from tests.test_kda import the_backward_given_the_forwards_inverse_is_the_one_that_forms_it_again as check
+
+    check(monkeypatch, "gdn", 4, *kernels_inputs(dtype))
+
+
+@pytest.mark.parametrize("dtype, parents", [(jnp.float32, (84, 150)), (jnp.bfloat16, (176, 262))], ids=["float32", "bfloat16"])
+def test_the_backward_kernels_body_holds_the_inverses_products_no_more(dtype, parents, monkeypatch):
+    from tests.test_kda import the_backward_kernels_body_holds_the_inverses_products_no_more as check
+
+    check(monkeypatch, "gdn", 4, parents, *kernels_inputs(dtype, seq=128))
+
+
 # -- the program against the reference ------------------------------------------------------------
 
 

@@ -58,7 +58,20 @@ CELLS_PROGRAMS = {
     # ``select_n`` 4422 -> 4426 and ``reduce_sum`` 767 -> 783 (the select and the sum over E, forward; the same select
     # and the sum over k, backward), ``broadcast_in_dim`` 6129 -> 6181, ``convert_element_type`` 2920 -> 2928. No other
     # primitive's count moved; the lines said did not, nor the six other configurations' programs.
-    "kimi-linear-1g": ((2, 8192), "c77a0955b4efb0d810d9ccd174575a9e663e78a80a52a9534018e1c79e7b2fa8", "efeaeed97ccba4c3"),
+    # kimi-linear-1g (and qwen3-next-80b-a3b-1g in ``tests/test_looped_train.CELLS_PROGRAMS``) re-pinned by the PR that
+    # hands the delta rule's block inverse from the forward kernels to the backward kernels as a second residual
+    # (kimi-linear-1g c77a0955…2fa8, qwen3-next-80b-a3b-1g f987432a…867f at bfd8bba and before). Old text against new:
+    # each of the 8 ``kda_fwd`` / 6 ``gdn_fwd`` calls has one more result (3 -> 4: o, starts, the inverse
+    # [2, 32, 8, 128, 128] bfloat16, the final state), each of the 4 ``kda_bwd`` / 3 ``gdn_bwd`` calls one more operand
+    # (8 -> 9), and the residual rides beside ``starts`` through the ``custom_vjp`` and, in kimi-linear-1g, through
+    # ``_by_decay``'s two ``cond``s, whose exact branch makes zeros of it: ``broadcast_in_dim`` 6181 -> 6185, one a KDA
+    # layer, and no other primitive's count of the whole program (kernels' bodies left out) moved in either. Inside the
+    # bodies, ``dot_general`` at the four heads of a grid step: ``kda_bwd`` 376 -> 256 and ``gdn_bwd`` 262 -> 142 (the
+    # inverse's ten products a head in three bfloat16 passes gone: 30 x 4), ``kda_fwd`` 216 and ``gdn_fwd`` 176 as they
+    # were. The lines said did not move, nor kimi-linear-1g's ``flash_fwd`` / ``flash_bwd`` equations
+    # (``tests/test_mla_rope_mtp_train.CELLS_KERNELS``: its digest of the other kernels did, b428188e…c248 before), nor
+    # the eight other configurations' programs.
+    "kimi-linear-1g": ((2, 8192), "95884449c5ebe967d5da04ee8378df4ed21a92774ffc276e2763a82f0bd7bc64", "efeaeed97ccba4c3"),
     "laguna-xs2-1g": ((2, 8192), "bca2e146dd3fd083383d11a9fe1568b3895c094838f88e1317c9f784ffc1d5c5", "2e3b7f09d4732e39"),
     "joyai-flash-1g": ((2, 8192), "6f42f5a08abb4906ec7d0a4899666dc66f2cd00b6baa019b8d4c7e0f007eefaa", "a6aa64069f77d113"),
     "lfm2-8b-a1b-1g": ((2, 8192), "89c3681a21cc85d34798a6ff43f5c68f1cbbc6f13c3ad39e15f67cb634ff2287", "647d94df3e9b6744"),

@@ -182,6 +182,143 @@ def test_a_strong_decay_takes_the_exact_path_at_the_kernels_width():
         assert relative(a, b) < 1e-6
 
 
+# -- the second residual: the backward kernels read ``M^-1`` where they formed it again (PR 64) ----------------
+
+
+def kernel_bodies(fn, *args):
+    """{a Pallas kernel's name: its body's jaxpr} of the calls ``fn`` traces to, and what ``fn`` returns, as shapes."""
+    from tests.test_window_gqa import every_equation
+
+    jaxpr, out = jax.make_jaxpr(fn, return_shape=True)(*args)
+    return {str(e.params["name"]): e.params["jaxpr"] for e in every_equation(jaxpr.jaxpr) if e.primitive.name == "pallas_call"}, out
+
+
+def dots(jaxpr):
+    from tests.test_window_gqa import every_equation
+
+    return sum(e.primitive.name == "dot_general" for e in every_equation(jaxpr))
+
+
+def calls_of_the_inverse(monkeypatch):
+    """Counts ``_unit_lower_inverses``' calls from here on: the matrices of each, in the list returned."""
+    K = kda._kernels()
+    calls, formed = [], K._unit_lower_inverses
+    monkeypatch.setattr(K, "_unit_lower_inverses", lambda Ls, exact: calls.append(len(Ls)) or formed(Ls, exact))
+    return calls
+
+
+# a rule's backward kernel, and its chunks formed from the kernel's input refs with NO inverse given
+FORMS_IT_AGAIN = {
+    "kda": ("_bwd_kernel", lambda K, r, hb, exact: K._chunks(
+        *(K._heads(ref, hb) for ref in r[:4]), [K._column(r[4][...], j) for j in range(hb)], r[2].dtype, exact)),
+    "gdn": ("_gdn_bwd_kernel", lambda K, r, hb, kb, exact: K._gdn_chunks(*r[:3], r[3][...], r[4][...], hb, kb, exact)[0]),
+}
+
+
+def a_backward_that_forms_the_inverse(monkeypatch, rule):
+    """The parent's backward (before PR 64) out of this tree's: the kernel takes
+    no notice of the residual it is handed and forms ``M^-1`` from its inputs
+    with the forward's code (``_unit_lower_inverses``), cast as the products
+    take it. A traced call from here on (``.__wrapped__``: the jit holds the
+    other trace) is that backward."""
+    K = kda._kernels()
+    name, chunks = FORMS_IT_AGAIN[rule]
+    given = getattr(K, name)
+
+    def kernel(*refs, **static):
+        formed = [x["inv"] for x in chunks(K, refs, **static)]
+        given(*refs[:6], formed, *refs[7:], **static)
+
+    monkeypatch.setattr(K, name, kernel)
+
+
+def kernels_inputs(dtype, seq=256):
+    q, k, v, g, beta = kda_inputs(1, seq, dtype=dtype, seed=3, **WIDE)
+    keys = jax.random.split(jax.random.PRNGKey(13), 3)
+    state, d_end = (0.1 * jax.random.normal(key, (1, 2, 128, 128)) for key in keys[:2])
+    do = jax.random.normal(keys[2], v.shape).astype(dtype)
+    return tuple(kda._wide(x) for x in (q, k, v, g)) + (beta,), state, kda._wide(do), d_end
+
+
+def the_backward_given_the_forwards_inverse_is_the_one_that_forms_it_again(monkeypatch, rule, heads, inputs, state, do, d_end):
+    """``<rule>_forward`` hands on each pair of chunks' ``M^-1`` a head as its
+    products took it (the inputs' dtype), ``<rule>_backward`` reads it: dq, dk,
+    dv, dg, dbeta and dS0 are, bit for bit, those of a backward that is handed
+    zeros and forms the inverse itself from the same inputs. ``heads``: those
+    of the one grid step, so of the one call of ``_unit_lower_inverses``."""
+    K = kda._kernels()
+    forward, backward = getattr(K, rule + "_forward"), getattr(K, rule + "_backward")
+    o, starts, inv, end = forward(*inputs, state)
+    pairs = do.shape[1] // K.ROWS
+    assert inv.shape == (1, heads, pairs, K.ROWS, K.ROWS) and inv.dtype == do.dtype
+    assert starts.shape == (1, heads, 2 * pairs, 128, 128) and starts.dtype == jnp.float32
+    i, j = np.indices((K.ROWS, K.ROWS))
+    held = np.asarray(inv.astype(jnp.float32))  # unit lower triangular, a chunk a diagonal block
+    assert np.all(held[..., i == j] == 1.0) and not np.any(held[..., (j > i) | (i // K.CHUNK != j // K.CHUNK)])
+    assert np.any(held[..., (j < i) & (i // K.CHUNK == j // K.CHUNK)])
+    got = backward(*inputs, starts, inv, do, d_end)
+    calls = calls_of_the_inverse(monkeypatch)
+    a_backward_that_forms_the_inverse(monkeypatch, rule)
+    want = backward.__wrapped__(*inputs, starts, jnp.zeros_like(inv), do, d_end)
+    assert calls == [heads]
+    for name, a, b in zip(("dq", "dk", "dv", "dg", "dbeta", "dS0"), got, want):
+        assert a.dtype == b.dtype and bool(jnp.any(a != 0)) and bool(jnp.all(a == b)), name
+
+
+def the_backward_kernels_body_holds_the_inverses_products_no_more(monkeypatch, rule, heads, parents, inputs, state, do, d_end):
+    """The chain of ten dependent small products a head (three bfloat16 passes
+    each where the inputs are bfloat16) left ``<rule>_bwd``'s body and stayed
+    in ``<rule>_fwd``'s — ``parents``: the two bodies' ``dot_general``s at the
+    parent (bfd8bba) — and ``_unit_lower_inverses`` is called where the
+    forward's body is traced and nowhere else."""
+    K = kda._kernels()
+    forward, backward = getattr(K, rule + "_forward").__wrapped__, getattr(K, rule + "_backward").__wrapped__
+    exact = do.dtype == jnp.float32
+    chain = dots(jax.make_jaxpr(lambda L: K._unit_lower_inverses([L], exact))(jnp.zeros((K.ROWS, K.ROWS))).jaxpr)
+    assert chain == (10 if exact else 30)
+    calls = calls_of_the_inverse(monkeypatch)
+    fwd, (_, starts, inv, _) = kernel_bodies(forward, *inputs, state)
+    assert calls == [heads]
+    bwd, _ = kernel_bodies(backward, *inputs, starts, inv, do, d_end)
+    assert calls == [heads]
+    assert (dots(fwd[rule + "_fwd"]), dots(bwd[rule + "_bwd"])) == (parents[0], parents[1] - heads * chain)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_backward_given_the_forwards_inverse_is_the_one_that_forms_it_again(dtype, monkeypatch):
+    the_backward_given_the_forwards_inverse_is_the_one_that_forms_it_again(monkeypatch, "kda", 2, *kernels_inputs(dtype))
+
+
+@pytest.mark.parametrize("dtype, parents", [(jnp.float32, (48, 92)), (jnp.bfloat16, (108, 188))], ids=["float32", "bfloat16"])
+def test_the_backward_kernels_body_holds_the_inverses_products_no_more(dtype, parents, monkeypatch):
+    the_backward_kernels_body_holds_the_inverses_products_no_more(monkeypatch, "kda", 2, parents, *kernels_inputs(dtype, seq=128))
+
+
+def test_the_exact_branch_leaves_zeros_of_the_kernels_residuals_and_reads_none():
+    """Under ``_by_decay``'s ``cond`` both branches hand on ``starts`` and the
+    inverse: the kernels' where the call's decay is theirs, zeros of the same
+    shapes and dtypes where the ``jax.numpy`` form runs — whose gradients are
+    its own ``vjp``'s whatever those two residuals hold."""
+    args = kda_inputs(1, 128, decay=30.0, **WIDE)
+    mild = args[:3] + (args[3] / 30.0,) + args[4:]
+    state = jnp.zeros((1, 2, 128, 128), jnp.float32)
+    cts = (jax.random.normal(jax.random.PRNGKey(2), args[2].shape), 0.1 * jax.random.normal(jax.random.PRNGKey(3), state.shape))
+    _, strong_res = jax.jit(kda._by_decay_fwd)(*args, state)
+    _, mild_res = jax.jit(kda._by_decay_fwd)(*mild, state)
+    assert not bool(strong_res[0]) and bool(mild_res[0])
+    for zeros, kernels in zip(strong_res[-2:], mild_res[-2:]):
+        assert (zeros.shape, zeros.dtype) == (kernels.shape, kernels.dtype)
+        assert not bool(jnp.any(zeros != 0)) and bool(jnp.any(kernels != 0))
+    in_bfloat16 = jax.eval_shape(kda._by_decay_fwd, *kda_inputs(1, 128, dtype=jnp.bfloat16, **WIDE), state)[1]
+    assert [r.dtype for r in in_bfloat16[-2:]] == [jnp.float32, jnp.bfloat16]  # the inverse as the products take it
+    backward = jax.jit(kda._by_decay_bwd)
+    got = backward(strong_res, cts)
+    unread = backward(strong_res[:-2] + tuple(jnp.ones_like(r) for r in strong_res[-2:]), cts)
+    want = jax.jit(lambda *a: jax.vjp(jax_numpy_form, *a[:5])[1](a[5:]))(*args, *cts)
+    for a, b, c in zip(got, unread, want):
+        assert bool(jnp.all(a == b)) and relative(a, c) < 1e-6
+
+
 def test_the_short_convolution_is_causal_and_per_channel():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 6))
     w = jax.random.normal(jax.random.PRNGKey(1), (4, 6))

@@ -494,15 +494,19 @@ CELLS_PROGRAMS = {
     # (the four whose experts a sigmoid gate chooses — kimi-linear-1g, laguna-xs2-1g, joyai-flash-1g, lfm2-8b-a1b-1g —
     # re-pinned by the PR that took ``jnp.take_along_axis`` out of ``_route``: ``gather`` -1 a sparse layer a forward
     # pass, ``scatter-add`` -1 a layer, ``_chosen``'s compare, select and sum in their place; old counts against new in
-    # ``tests/test_gdn_train.CELLS_PROGRAMS``' comment)
+    # ``tests/test_gdn_train.CELLS_PROGRAMS``' comment; kimi-linear-1g and qwen3-next-80b-a3b-1g re-pinned by the PR that
+    # hands the delta rule's block inverse from the forward kernels to the backward kernels — c77a0955…2fa8 and
+    # f987432a…867f at bfd8bba and before: one more result a ``kda_fwd`` / ``gdn_fwd`` call, one more operand a
+    # ``kda_bwd`` / ``gdn_bwd`` call, the backward bodies' ``dot_general`` 376 -> 256 / 262 -> 142, and in kimi-linear-1g
+    # ``broadcast_in_dim`` 6181 -> 6185 for the exact branch's zeros; the whole list in the same comment)
     "olmo1b-1g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a"),
     "olmo1b-4g": ((8, 2048), "73b3ad2e4c16cf95dbaa9a851fae74342c302e8e3ab0adcee3edcbad96db533a"),
     "olmoe-1g": ((8, 2048), "65b119828cd26a22a39bc945227fb3cef92f2b8ae09109a8c17c196e5a896d2d"),
-    "kimi-linear-1g": ((2, 8192), "c77a0955b4efb0d810d9ccd174575a9e663e78a80a52a9534018e1c79e7b2fa8"),
+    "kimi-linear-1g": ((2, 8192), "95884449c5ebe967d5da04ee8378df4ed21a92774ffc276e2763a82f0bd7bc64"),
     "laguna-xs2-1g": ((2, 8192), "bca2e146dd3fd083383d11a9fe1568b3895c094838f88e1317c9f784ffc1d5c5"),
     "joyai-flash-1g": ((2, 8192), "6f42f5a08abb4906ec7d0a4899666dc66f2cd00b6baa019b8d4c7e0f007eefaa"),
     "lfm2-8b-a1b-1g": ((2, 8192), "89c3681a21cc85d34798a6ff43f5c68f1cbbc6f13c3ad39e15f67cb634ff2287"),
-    "qwen3-next-80b-a3b-1g": ((2, 8192), "f987432a4fe8549e93feedc1bc1c287da5cc3c589e9beef49e7b43900bc8867f"),
+    "qwen3-next-80b-a3b-1g": ((2, 8192), "d04fb3afa1f2339cd136c45f96c7db6e1334e3452c1aded8104cd7f7e2d10055"),
 }
 NEW_CELL = "ouro-2_6b-1g"
 

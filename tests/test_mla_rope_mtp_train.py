@@ -45,7 +45,13 @@ CELLS_PROGRAMS = {
     # bd50d408…eeb0 at b50bcfa and before): ``gather`` 492 -> 484 / 418 -> 410 (one a sparse layer a forward pass) and
     # ``scatter-add`` 390 -> 386 / 365 -> 361 (its transpose), ``_chosen``'s ``iota``, ``eq``, ``select_n`` and
     # ``reduce_sum`` over the experts in their place — the whole list in the same comment; CELLS_KERNELS did not move.
-    "kimi-linear-1g": "c77a0955b4efb0d810d9ccd174575a9e663e78a80a52a9534018e1c79e7b2fa8",
+    # kimi-linear-1g since the PR that hands the delta rule's block inverse from ``kda_fwd`` to ``kda_bwd`` as a second
+    # residual (c77a0955…2fa8 at bfd8bba and before): one more result a ``kda_fwd`` call (8 of them), one more operand a
+    # ``kda_bwd`` call (4), the residual beside ``starts`` through ``_by_decay``'s ``cond``s (``broadcast_in_dim``
+    # 6181 -> 6185: the exact branch's zeros) — the whole list in the same comment. In CELLS_KERNELS below the flash
+    # kernel's two equations did not move and the digest of the others did (b428188ee614c248 before: the twelve KDA
+    # equations are new, ``kda_bwd``'s body with 256 ``dot_general`` where it had 376; the grouped matmuls' are the parent's).
+    "kimi-linear-1g": "95884449c5ebe967d5da04ee8378df4ed21a92774ffc276e2763a82f0bd7bc64",
     "laguna-xs2-1g": "bca2e146dd3fd083383d11a9fe1568b3895c094838f88e1317c9f784ffc1d5c5",
 }
 CELLS_KERNELS = {
@@ -56,7 +62,7 @@ CELLS_KERNELS = {
     # equation but for ``_`` where the statistics are bound): kimi-linear-1g ``flash_fwd`` 0a203b9237cb127a +
     # 55a80147b80989f2 -> 0a203b9237cb127a; laguna-xs2-1g 2 x 397a704a4de61c10 + 3 x 63c7d8251e46e74c +
     # 3 x 5c4213ebc846dd3e + 2 x 8e4669ff79cb390d -> the last five; ``flash_bwd`` and the rest equal at both commits.
-    "kimi-linear-1g": ({"flash_fwd": ["0a203b9237cb127a"], "flash_bwd": ["79821667815a0b73"]}, "b428188ee614c248"),
+    "kimi-linear-1g": ({"flash_fwd": ["0a203b9237cb127a"], "flash_bwd": ["79821667815a0b73"]}, "862f0484c3af2135"),
     "laguna-xs2-1g": (
         {"flash_fwd": ["5c4213ebc846dd3e"] * 3 + ["8e4669ff79cb390d"] * 2, "flash_bwd": ["c8ac0340a8878e7c"] * 3 + ["e221363c77edb6f9"] * 2},
         "1c7e2d9585a8dd16",

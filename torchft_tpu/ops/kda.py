@@ -365,17 +365,17 @@ def _kernels():
 
 def _kernel_forward(q, k, v, g, beta, S0):
     with jax.named_scope("kda_kernel"):
-        o, starts, S_end = _kernels().kda_forward(
+        o, starts, inv, S_end = _kernels().kda_forward(
             _wide(q), _wide(k), _wide(v), _wide(g.astype(jnp.float32)), beta.astype(jnp.float32), S0
         )
-    return o.reshape(v.shape), S_end, starts
+    return o.reshape(v.shape), S_end, starts, inv
 
 
-def _kernel_backward(q, k, v, g, beta, S0, starts, do, d_end):
+def _kernel_backward(q, k, v, g, beta, S0, starts, inv, do, d_end):
     with jax.named_scope("kda_kernel"):
         dq, dk, dv, dg, dbeta, dS0 = _kernels().kda_backward(
             _wide(q), _wide(k), _wide(v), _wide(g.astype(jnp.float32)), beta.astype(jnp.float32),
-            starts, _wide(do), d_end,
+            starts, inv, _wide(do), d_end,
         )
     return (
         dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
@@ -405,21 +405,25 @@ def _by_decay(q, k, v, g, beta, S0):
 
 
 def _by_decay_fwd(q, k, v, g, beta, S0):
-    # the states at the chunks' starts are the kernel path's one residual
-    # beside the inputs; the exact path differentiates itself and leaves zeros
+    # the states at the chunks' starts and the pairs of chunks' M^-1 are the
+    # kernel path's residuals beside the inputs; the exact path differentiates
+    # itself and leaves zeros
     b, s, h, d = q.shape
+    chunk, rows = _kernels().CHUNK, _kernels().ROWS
     serves = _kernel_serves(g)
-    o, S_end, starts = jax.lax.cond(
+    o, S_end, starts, inv = jax.lax.cond(
         serves,
         lambda *a: _kernel_forward(*a),
-        lambda *a: _exact(*a) + (jnp.zeros((b, h, s // _kernels().CHUNK, d, d), jnp.float32),),
+        lambda *a: _exact(*a) + (
+            jnp.zeros((b, h, s // chunk, d, d), jnp.float32), jnp.zeros((b, h, s // rows, rows, rows), v.dtype),
+        ),
         q, k, v, g, beta, S0,
     )
-    return (o, S_end), (serves, q, k, v, g, beta, S0, starts)
+    return (o, S_end), (serves, q, k, v, g, beta, S0, starts, inv)
 
 
 def _by_decay_bwd(res, cts):
-    def exact(q, k, v, g, beta, S0, starts, do, d_end):
+    def exact(q, k, v, g, beta, S0, starts, inv, do, d_end):
         return jax.vjp(lambda *a: _exact(*a), q, k, v, g, beta, S0)[1]((do, d_end))
 
     return jax.lax.cond(res[0], lambda *a: _kernel_backward(*a), exact, *res[1:], *cts)
@@ -489,16 +493,18 @@ def _gdn_kernels(q, k, v, g, beta, S0):
 
 def _gdn_kernels_fwd(q, k, v, g, beta, S0):
     with jax.named_scope("gdn_kernel"):
-        o, starts, S_end = _kernels().gdn_forward(_wide(q), _wide(k), _wide(v), g, beta, S0)
-    # the states at the chunks' starts are the one residual beside the inputs
-    return (o.reshape(v.shape), S_end), (q, k, v, g, beta, starts)
+        o, starts, inv, S_end = _kernels().gdn_forward(_wide(q), _wide(k), _wide(v), g, beta, S0)
+    # the states at the chunks' starts and the pairs of chunks' M^-1 are the residuals beside the inputs
+    return (o.reshape(v.shape), S_end), (q, k, v, g, beta, starts, inv)
 
 
 def _gdn_kernels_bwd(res, cts):
-    q, k, v, g, beta, starts = res
+    q, k, v, g, beta, starts, inv = res
     do, d_end = cts
     with jax.named_scope("gdn_kernel"):
-        dq, dk, dv, dg, dbeta, dS0 = _kernels().gdn_backward(_wide(q), _wide(k), _wide(v), g, beta, starts, _wide(do), d_end)
+        dq, dk, dv, dg, dbeta, dS0 = _kernels().gdn_backward(
+            _wide(q), _wide(k), _wide(v), g, beta, starts, inv, _wide(do), d_end
+        )
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), dg, dbeta, dS0
 
 

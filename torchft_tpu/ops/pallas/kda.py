@@ -11,12 +11,16 @@ does all of it on the chip. Two kernels:
 
 * the forward: grid ``(batch, head blocks, pairs of chunks)``, the last axis
   sequential, the heads' states in float32 scratch across it. It writes the
-  outputs, the state at every chunk's START (what the backward recomputes a
-  chunk from) and the final state.
+  outputs, the two residuals beside the inputs — the state at every chunk's
+  START and every pair of chunks' ``M^-1`` as the products take it (bfloat16
+  where the inputs are: 32 KB a head a pair, 16.8 MB a block of 1024
+  positions of 2 x 32 heads) — and the final state.
 * the backward: the same grid walked from the last pair to the first,
-  carrying ``dS``. A step recomputes its chunks' ``G``, ``A``, ``M^-1``,
-  ``u`` from the inputs and the saved start states, then the gradients of
-  q, k, v, g, beta, and at the first pair the initial state's.
+  carrying ``dS``. A step recomputes its chunks' ``G``, ``A`` and ``u`` from
+  the inputs and the saved start states and READS ``M^-1``: forming it is 30
+  of a forward step's ~41 bfloat16 passes a head, and the forward of the
+  block's checkpoint has just done so (PERF.md §6, PR 64). Then the
+  gradients of q, k, v, g, beta, and at the first pair the initial state's.
 
 Two chunks a step because everything of a chunk that does not read the state
 — the running sums, the decayed products, the inverse, ``M^-1 [beta v | beta
@@ -61,7 +65,8 @@ ONE decay a head a position (Gated DeltaNet, ``ops/kda.gdn_chunked``) has a
 kernel pair of its own at the end of this file, ``gdn_forward`` /
 ``gdn_backward``: the same frame — the grid, two chunks a step, the heads
 stage by stage, the block inverse, ``u``, ``o``, the state's update, the start
-states as the one residual, the precision — with what the scalar decay changes:
+states and the inverse as the two residuals, the precision — with what the
+scalar decay changes:
 
 * the pairs are ``A[i, j] = (rows_i · k_j) exp(sum of g over j < t <= i)``:
   one [2·128, 128] x [128, 128] product a KEY head (``[q; k] kᵀ``, shared by
@@ -262,18 +267,22 @@ def _unit_lower_inverses(Ls, exact):
     return invs
 
 
-def _chunks(qs, ks, vs, gs, betas, dt, exact):
+def _chunks(qs, ks, vs, gs, betas, dt, exact, invs=None):
     """What two chunks' forward and backward share, from their inputs alone
     (no state), for every head of a grid step, stage by stage: q, k, v
-    [ROWS, 128] float32, g [ROWS, 128], beta [ROWS, 1] a head."""
+    [ROWS, 128] float32, g [ROWS, 128], beta [ROWS, 1] a head. ``invs``: the
+    heads' ``M^-1`` as the products take them, in ``dt`` — what the forward
+    saved, in the backward; formed here where there is none, in the forward."""
     Gs = [_running_sum(g, exact) for g in gs]
     pairs = [_pairs(q, k, G, exact) for q, k, G in zip(qs, ks, Gs)]
-    invs = _unit_lower_inverses([beta * a_kk for beta, (_, a_kk, _) in zip(betas, pairs)], exact)
+    if invs is None:
+        Ls = [beta * a_kk for beta, (_, a_kk, _) in zip(betas, pairs)]
+        invs = [inv.astype(dt) for inv in _unit_lower_inverses(Ls, exact)]
     out = []
-    for q, k, v, G, beta, (a_qk, a_kk, factors), inv in zip(qs, ks, vs, Gs, betas, pairs, invs):
+    for q, k, v, G, beta, (a_qk, a_kk, factors), inv_d in zip(qs, ks, vs, Gs, betas, pairs, invs):
         ends = G[CHUNK - 1 : CHUNK, :], G[ROWS - 1 : ROWS, :]
         e_in, e_out = jnp.exp(G), jnp.exp(_by_chunk(*ends) - G)
-        k_in, inv_d = k * e_in, inv.astype(dt)
+        k_in = k * e_in
         out.append(dict(
             factors, a_qk=a_qk, a_kk=a_kk, inv=inv_d, e_in=e_in, e_out=e_out,
             decay=[jnp.exp(end) for end in ends], q_in=q * e_in, k_in=k_in, k_out=k * e_out,
@@ -297,7 +306,7 @@ def _heads(ref, hb, dtype=_F32):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_ref, end_ref, state, *, hb, exact):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_ref, inv_ref, end_ref, state, *, hb, exact):
     n = pl.program_id(2)
     dt = v_ref.dtype
 
@@ -326,6 +335,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_ref,
         o = jnp.concatenate([reads[0][j], reads[1][j]], axis=0) + _dot(x["a_qk"].astype(dt), u, _NN, exact)
         o_ref[:, j * _LANES : (j + 1) * _LANES] = o.astype(o_ref.dtype)
         starts_ref[j, 0], starts_ref[j, 1] = starts[0][j], starts[1][j]
+        inv_ref[j] = x["inv"]
         state[j] = states[j]
 
     @pl.when(n == pl.num_programs(2) - 1)
@@ -340,7 +350,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_ref,
 
 
 def _bwd_kernel(
-    q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, dend_ref,
+    q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, inv_ref, do_ref, dend_ref,
     dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds0_ref, dstate, *, hb, exact,
 ):
     n = pl.program_id(2)
@@ -356,7 +366,7 @@ def _bwd_kernel(
     betas = [_column(beta_block, j) for j in heads]
     qs, ks, vs = _heads(q_ref, hb), _heads(k_ref, hb), _heads(v_ref, hb)
     dos = _heads(do_ref, hb, dt)
-    xs = _chunks(qs, ks, vs, _heads(g_ref, hb), betas, dt, exact)
+    xs = _chunks(qs, ks, vs, _heads(g_ref, hb), betas, dt, exact, [inv_ref[j] for j in heads])
     sts = [[starts_ref[j, at].astype(dt) for at in (0, 1)] for j in heads]  # [dv, dk], as the products take them
     i, jj, same = _square()
 
@@ -465,7 +475,8 @@ def _specs(hb, at):
     starts = pl.BlockSpec(
         (None, hb, 2, _LANES, _LANES), lambda b, h, n: (b, h, at(n), 0, 0), memory_space=vm
     )
-    return wide, beta, state, starts
+    inv = pl.BlockSpec((None, hb, None, ROWS, ROWS), lambda b, h, n: (b, h, at(n), 0, 0), memory_space=vm)
+    return wide, beta, state, starts, inv
 
 
 _PARAMS = pltpu.CompilerParams(
@@ -487,20 +498,22 @@ def kda_forward(q, k, v, g, beta, initial_state, interpret=None):
     """q, k, v [B, S, H·128] in one dtype, g [B, S, H·128] float32, beta
     [B, S, H] float32, the state [B, H, 128, 128] float32; S whole pairs of
     chunks (:data:`ROWS`). Returns (o [B, S, H·128], the state at each chunk's
-    start [B, H, S/64, 128, 128] as the kernels hold it ([dv, dk]), the final
+    start [B, H, S/64, 128, 128] as the kernels hold it ([dv, dk]), each pair
+    of chunks' ``M^-1`` [B, H, S/128, 128, 128] in q's dtype, the final
     state)."""
     b, s, _ = q.shape
     h = beta.shape[-1]
     hb = _heads_a_step(h)
-    wide, beta_spec, state, starts = _specs(hb, lambda n: n)
+    wide, beta_spec, state, starts, inv = _specs(hb, lambda n: n)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, hb=hb, exact=q.dtype == jnp.float32),
         grid=(b, h // hb, s // ROWS),
         in_specs=[wide, wide, wide, wide, beta_spec, state],
-        out_specs=[wide, starts, state],
+        out_specs=[wide, starts, inv, state],
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((b, h, s // CHUNK, _LANES, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s // ROWS, ROWS, ROWS), v.dtype),
             jax.ShapeDtypeStruct((b, h, _LANES, _LANES), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hb, _LANES, _LANES), jnp.float32)],
@@ -511,18 +524,18 @@ def kda_forward(q, k, v, g, beta, initial_state, interpret=None):
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
-def kda_backward(q, k, v, g, beta, starts, do, d_end, interpret=None):
+def kda_backward(q, k, v, g, beta, starts, inv, do, d_end, interpret=None):
     """The gradients of q, k, v, g, beta and the initial state, from the
-    forward's inputs, its ``starts``, and the cotangents of o and of the final
-    state."""
+    forward's inputs, its ``starts`` and ``M^-1``, and the cotangents of o and
+    of the final state."""
     b, s, _ = q.shape
     h = beta.shape[-1]
     hb, n = _heads_a_step(h), s // ROWS
-    wide, beta_spec, state, starts_spec = _specs(hb, lambda i: n - 1 - i)
+    wide, beta_spec, state, starts_spec, inv_spec = _specs(hb, lambda i: n - 1 - i)
     dq, dk, dv, dg, dbeta, ds0 = pl.pallas_call(
         functools.partial(_bwd_kernel, hb=hb, exact=q.dtype == jnp.float32),
         grid=(b, h // hb, n),
-        in_specs=[wide, wide, wide, wide, beta_spec, starts_spec, wide, state],
+        in_specs=[wide, wide, wide, wide, beta_spec, starts_spec, inv_spec, wide, state],
         out_specs=[wide, wide, wide, wide, beta_spec, state],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -536,7 +549,7 @@ def kda_backward(q, k, v, g, beta, starts, do, d_end, interpret=None):
         compiler_params=_PARAMS,
         interpret=_should_interpret() if interpret is None else interpret,
         name="kda_bwd",
-    )(q, k, v, g, _beta_blocks(beta, hb), starts, do, d_end)
+    )(q, k, v, g, _beta_blocks(beta, hb), starts, inv, do, d_end)
     return dq, dk, dv, dg, jnp.moveaxis(dbeta, 1, 2).reshape(b, s, h), ds0
 
 
@@ -580,14 +593,14 @@ def _dot_pieces(pieces, b, dims, exact):
     return out
 
 
-def _gdn_chunks(q_ref, k_ref, v_ref, g_block, beta_block, hb, kb, exact):
+def _gdn_chunks(q_ref, k_ref, v_ref, g_block, beta_block, hb, kb, exact, invs=None):
     """What two chunks' forward and backward share, from their inputs alone,
     for the ``hb`` value heads of a grid step over its ``kb`` key heads, stage
     by stage. g, beta [ROWS, hb]: ONE log-decay and one write strength a head
     a position. Every sum of g is taken over the positions it spans — the
     pair's own (``between``), a chunk's start to a position (``G``), a
     position to its chunk's end (``T``) — as a masked triangular product,
-    never as a difference of two running sums."""
+    never as a difference of two running sums. ``invs`` as :func:`_chunks` takes them."""
     dt = v_ref.dtype
     _, lower, strict, later = _masks()
     gs = [_column(g_block, h) for h in range(hb)]
@@ -603,13 +616,14 @@ def _gdn_chunks(q_ref, k_ref, v_ref, g_block, beta_block, hb, kb, exact):
     decayed = [jnp.exp(between) for between in betweens]  # exponents <= 0: nothing overflows whatever the decay
     a_qk = [jnp.where(lower, products[h // group][:ROWS] * e, 0.0) for h, e in enumerate(decayed)]
     a_kk = [jnp.where(strict, products[h // group][ROWS:] * e, 0.0) for h, e in enumerate(decayed)]
-    invs = _unit_lower_inverses([beta * a for beta, a in zip(betas, a_kk)], exact)
+    if invs is None:
+        invs = [inv.astype(dt) for inv in _unit_lower_inverses([beta * a for beta, a in zip(betas, a_kk)], exact)]
     out = []
     for h in range(hb):
         k = ks[h // group].astype(_F32)
         G, T = _column(G_all, h), _column(T_all, h)
         e_in, e_out = jnp.exp(G), jnp.exp(T)
-        k_in, inv_d = k * e_in, invs[h].astype(dt)
+        k_in, inv_d = k * e_in, invs[h]
         out.append(dict(
             beta=betas[h], v=vs[h], a_qk=a_qk[h], a_kk=a_kk[h], decayed=decayed[h], inv=inv_d, e_in=e_in, e_out=e_out,
             decay=[jnp.exp(G[CHUNK - 1 : CHUNK, :]), jnp.exp(G[ROWS - 1 : ROWS, :])], k_in=k_in, k_out=k * e_out,
@@ -620,7 +634,9 @@ def _gdn_chunks(q_ref, k_ref, v_ref, g_block, beta_block, hb, kb, exact):
     return out, qs, ks
 
 
-def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_ref, end_ref, state, *, hb, kb, exact):
+def _gdn_fwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_ref, inv_ref, end_ref, state, *, hb, kb, exact,
+):
     n = pl.program_id(2)
     dt = v_ref.dtype
 
@@ -648,6 +664,7 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_
         o = x["e_in"] * jnp.concatenate([reads[0][h], reads[1][h]], axis=0) + _dot(x["a_qk"].astype(dt), u, _NN, exact)
         o_ref[:, h * _LANES : (h + 1) * _LANES] = o.astype(o_ref.dtype)
         starts_ref[h, 0], starts_ref[h, 1] = starts[0][h], starts[1][h]
+        inv_ref[h] = x["inv"]
         state[h] = states[h]
 
     @pl.when(n == pl.num_programs(2) - 1)
@@ -657,7 +674,7 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, starts_
 
 
 def _gdn_bwd_kernel(
-    q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, dend_ref,
+    q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, inv_ref, do_ref, dend_ref,
     dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds0_ref, dstate, *, hb, kb, exact,
 ):
     n = pl.program_id(2)
@@ -669,7 +686,9 @@ def _gdn_bwd_kernel(
             dstate[h] = dend_ref[h].T
 
     heads, group = range(hb), hb // kb
-    xs, qs, ks = _gdn_chunks(q_ref, k_ref, v_ref, g_ref[...], beta_ref[...], hb, kb, exact)
+    xs, qs, ks = _gdn_chunks(
+        q_ref, k_ref, v_ref, g_ref[...], beta_ref[...], hb, kb, exact, [inv_ref[h] for h in heads]
+    )
     row, lower, strict, later = _masks()
     dos = _heads(do_ref, hb)
     dods = [do.astype(dt) for do in dos]
@@ -762,12 +781,12 @@ def _gdn_heads(hv: int, hk: int):
 
 def _gdn_specs(hv, hk, at):
     hb, kb = _gdn_heads(hv, hk)
-    wide, small, state, starts = _specs(hb, at)
+    wide, small, state, starts, inv = _specs(hb, at)
     # value head j reads key head j // (hv / hk): a step's first value head names its key heads' block
     keys = pl.BlockSpec(
         (None, ROWS, _LANES * kb), lambda b, h, n: (b, at(n), h * hb * hk // hv // kb), memory_space=pltpu.VMEM
     )
-    return hb, kb, keys, wide, small, state, starts
+    return hb, kb, keys, wide, small, state, starts, inv
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
@@ -778,15 +797,16 @@ def gdn_forward(q, k, v, g, beta, initial_state, interpret=None):
     :func:`kda_forward` does, a state a VALUE head."""
     b, s, _ = v.shape
     hv, hk = beta.shape[-1], q.shape[-1] // _LANES
-    hb, kb, keys, wide, small, state, starts = _gdn_specs(hv, hk, lambda n: n)
+    hb, kb, keys, wide, small, state, starts, inv = _gdn_specs(hv, hk, lambda n: n)
     return pl.pallas_call(
         functools.partial(_gdn_fwd_kernel, hb=hb, kb=kb, exact=q.dtype == jnp.float32),
         grid=(b, hv // hb, s // ROWS),
         in_specs=[keys, keys, wide, small, small, state],
-        out_specs=[wide, starts, state],
+        out_specs=[wide, starts, inv, state],
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((b, hv, s // CHUNK, _LANES, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, hv, s // ROWS, ROWS, ROWS), v.dtype),
             jax.ShapeDtypeStruct((b, hv, _LANES, _LANES), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hb, _LANES, _LANES), jnp.float32)],
@@ -797,13 +817,14 @@ def gdn_forward(q, k, v, g, beta, initial_state, interpret=None):
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
-def gdn_backward(q, k, v, g, beta, starts, do, d_end, interpret=None):
+def gdn_backward(q, k, v, g, beta, starts, inv, do, d_end, interpret=None):
     """The gradients of q, k ([B, S, Hk·128]: summed over a key head's value
-    heads), v, g, beta ([B, S, Hv] float32) and the initial state."""
+    heads), v, g, beta ([B, S, Hv] float32) and the initial state, from what
+    :func:`gdn_forward` took and its ``starts`` and ``M^-1``."""
     b, s, _ = v.shape
     hv, hk = beta.shape[-1], q.shape[-1] // _LANES
     n = s // ROWS
-    hb, kb, keys, wide, small, state, starts_spec = _gdn_specs(hv, hk, lambda i: n - 1 - i)
+    hb, kb, keys, wide, small, state, starts_spec, inv_spec = _gdn_specs(hv, hk, lambda i: n - 1 - i)
     # a step writes its key heads' gradients over ITS value heads; where a key
     # head's group spans several steps they are summed after, in float32
     parts = hv // hb * kb // hk
@@ -811,7 +832,7 @@ def gdn_backward(q, k, v, g, beta, starts, do, d_end, interpret=None):
     dq, dk, dv, dg, dbeta, ds0 = pl.pallas_call(
         functools.partial(_gdn_bwd_kernel, hb=hb, kb=kb, exact=q.dtype == jnp.float32),
         grid=(b, hv // hb, n),
-        in_specs=[keys, keys, wide, small, small, starts_spec, wide, state],
+        in_specs=[keys, keys, wide, small, small, starts_spec, inv_spec, wide, state],
         out_specs=[
             pl.BlockSpec((None, ROWS, _LANES * kb), lambda b, h, i: (b, n - 1 - i, h), memory_space=pltpu.VMEM),
         ] * 2 + [wide, small, small, state],
@@ -826,7 +847,7 @@ def gdn_backward(q, k, v, g, beta, starts, do, d_end, interpret=None):
         compiler_params=_PARAMS,
         interpret=_should_interpret() if interpret is None else interpret,
         name="gdn_bwd",
-    )(q, k, v, _beta_blocks(g, hb), _beta_blocks(beta, hb), starts, do, d_end)
+    )(q, k, v, _beta_blocks(g, hb), _beta_blocks(beta, hb), starts, inv, do, d_end)
     if parts > 1:
         dq, dk = (x.reshape(b, s, hk, parts, _LANES).sum(axis=3).reshape(q.shape).astype(q.dtype) for x in (dq, dk))
     unblock = lambda x: jnp.moveaxis(x, 1, 2).reshape(b, s, hv)  # noqa: E731
