@@ -573,6 +573,51 @@ class TestChunkedAttention:
 
 
 # ---------------------------------------------------------------------------
+# the staircase of block diffusion: 2·S rows [noised ; clean] a sequence
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "half, block, heads, kv_heads",
+    [(256, 4, 2, 2), (256, 8, 8, 1), (512, 4, 8, 1), (512, 8, 2, 1), (128, 4, 2, 1)],
+    ids=str,
+)
+def test_the_staircase_kernel_matches_the_dense_mask(half, block, heads, kv_heads):
+    """The kernel (interpreted) under ``block_diffusion`` against
+    ``ops.attention`` under the dense mask, forward and dQ / dK / dV, at tiles
+    of 128: halves of two and of four tiles (full clean tiles below the
+    staircase, masked ones on it, a noised row's own tile first), D 4 and 8, 8
+    query heads a key/value head (dK, dV summed over the group in the scratch),
+    and a sequence of ONE tile a half, where every tile visited is masked."""
+    ks = jax.random.split(jax.random.PRNGKey(half + block), 4)
+    q, do = (jax.random.normal(k, (1, 2 * half, heads, 128), jnp.float32) for k in ks[:2])
+    k, v = (jax.random.normal(key, (1, 2 * half, kv_heads, 128), jnp.float32) for key in ks[2:])
+    flash = lambda q, k, v: flash_attention(q, k, v, block_q=128, block_k=128, block_diffusion=block)
+    dense = lambda q, k, v: attention(q, k, v, block_diffusion=block)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)), np.asarray(dense(q, k, v)), atol=2e-5)
+    g_fl = jax.grad(lambda *a: jnp.sum(flash(*a) * do), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(dense(*a) * do), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_fl, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def test_the_staircase_at_uneven_tiles_and_what_it_refuses(monkeypatch):
+    """Query tiles of 256 over key tiles of 128: a q tile's own noised keys are
+    two tiles, and the clean tiles it crosses two more. Sequences whose K and V
+    arrive in several blocks are refused by name."""
+    import sys
+
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    q, k, v = (jax.random.normal(key, (1, 1024, 1, 128), jnp.float32) for key in ks)
+    got = flash_attention(q, k, v, block_q=256, block_k=128, block_diffusion=8)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(attention(q, k, v, block_diffusion=8)), atol=2e-5)
+    fa = sys.modules["torchft_tpu.ops.pallas.flash_attention"]
+    monkeypatch.setattr(fa, "_RESIDENT_KEYS", 256)  # 512 rows of 128 lanes resident: two K blocks
+    with pytest.raises(ValueError, match="the staircase runs with the whole sequence resident"):
+        flash_attention(q, k, v, block_q=128, block_k=128, block_diffusion=8)
+
+
+# ---------------------------------------------------------------------------
 # the chip's compiler, without the chip: what interpret mode cannot refuse
 # (a slice off the tiling, more VMEM than a kernel may use). The topology is
 # described inside a fixture, never at import: one process at a time may load
@@ -740,6 +785,36 @@ def test_banded_and_grouped_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_
     assert compiled.as_text().count("tpu_custom_call") >= 2
     dq, dk, dv = jax.tree_util.tree_leaves(compiled.out_info)[1:]
     assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape  # dK, dV summed over the group in the kernel
+
+
+@pytest.mark.parametrize("batch", [2, 1])
+def test_the_staircase_kernels_compile_for_v5e_at_the_cells_shape(one_v5e_chip, batch):
+    """``sdar-30b-a3b-1g.fused-s8192`` (PR 65): 16 384 rows ``[noised ; clean]``
+    of 32 query heads over 4 key/value heads of 128 lanes, all 16 384 rows of K
+    and V resident, 512 x 512 tiles, block 4, the cell's batch and the reference
+    check's batch 1, forward and backward through Mosaic for a described v5e."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    q = jax.ShapeDtypeStruct((batch, 16384, 32, 128), jnp.bfloat16, sharding=one_v5e_chip)
+    kv = jax.ShapeDtypeStruct((batch, 16384, 4, 128), jnp.bfloat16, sharding=one_v5e_chip)
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, block_q=512, block_k=512, interpret=False, block_diffusion=4)
+            return jnp.sum(o.astype(jnp.float32))
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(step).lower(q, kv, kv).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    dq, dk, dv = jax.tree_util.tree_leaves(compiled.out_info)[1:]
+    assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape
 
 
 @pytest.mark.parametrize("batch, seq, heads", [(2, 1024, 32), (1, 1024, 8), (1, 128, 2)], ids=str)

@@ -267,6 +267,16 @@ class TransformerConfig:
     # Without the gate the loss reads the last loop step alone
     exit_gate: bool = False
     exit_entropy_coef: float = 0.0
+    # -- block-diffusion training (``diffusion_block`` = D > 0; 0 => next-token training): a sequence of S clean ids
+    # runs as 2·S rows ``[noised ; clean]`` — in the noised half an id is replaced by ``diffusion_mask_id`` with the
+    # probability t of its block of D positions — row r at position ``r mod S`` under the staircase mask of
+    # ``ops.attention.block_diffusion_mask``, and the loss is the mean over the S positions of ``m/t`` times the cross
+    # entropy of the NOISED row against the clean id at the same position. The noise is a function of the sequence's own
+    # ids and ``diffusion_seed`` (:func:`_diffusion_noise`): t uniform on ``[diffusion_t_min, 1]`` a block
+    diffusion_block: int = 0
+    diffusion_t_min: float = 1e-3
+    diffusion_mask_id: int = -1  # -1 => the last row of the vocabulary held
+    diffusion_seed: int = 0
 
     def __post_init__(self) -> None:
         for name in (
@@ -346,6 +356,50 @@ class TransformerConfig:
             raise ValueError("exit_gate says at which loop step a token leaves: it comes with ut_steps > 1")
         if self.exit_entropy_coef and not self.exit_gate:
             raise ValueError("exit_entropy_coef weighs the entropy of exit_gate's distribution: it comes with exit_gate")
+        if self.diffusion_block:
+            self._refuse_diffusion()
+
+    def _refuse_diffusion(self) -> None:
+        """What block-diffusion training cannot run with yet, each by name."""
+        d = self.diffusion_block
+        if d < 0 or 128 % d:
+            raise ValueError(
+                f"diffusion_block={d}: a block divides the sequence's tiles (128 x 128 the smallest, so a power of two up "
+                "to 128); a staircase whose steps cross a tile's rows (a mask that knows each row's half and block apart) "
+                "is missing"
+            )
+        if not 0.0 < self.diffusion_t_min <= 1.0:
+            raise ValueError(f"diffusion_t_min={self.diffusion_t_min}: the least noise level lies in (0, 1], and weighs 1/t")
+        if not -1 <= self.diffusion_mask_id < self.vocab_size:
+            raise ValueError(f"diffusion_mask_id={self.diffusion_mask_id}: a row of the {self.vocab_size} held, or -1 for the last")
+        other = sorted({m for m, _ in self.layer_kinds()} - {"full"})
+        if other:
+            raise ValueError(
+                f"diffusion_block={d} with {other} layers: only ``full`` softmax layers know the 2·S rows — positions that "
+                "repeat and the staircase mask; a band, a latent's cores, a recurrent state or a convolution over "
+                "[noised ; clean] (each noised block continuing the CLEAN prefix's state) are missing"
+            )
+        if self.n_mtp_modules:
+            raise ValueError(
+                f"diffusion_block={d} with a multi-token-prediction module: the module reads the token one ahead and "
+                "predicts the second-next; what it reads and predicts on a noised row is not defined"
+            )
+        if self.ut_steps > 1:
+            raise ValueError(
+                f"diffusion_block={d} with ut_steps={self.ut_steps}: the exits' heads read every row of every loop step; "
+                "a looped stack whose exits read the noised half alone is missing"
+            )
+        if max(self.pp, 1) > 1:
+            raise ValueError(
+                f"diffusion_block={d} with pp={self.pp}: the pipeline's head shifts targets and weighs positions alike "
+                "(_pipelined_loss), and its stages run the chunked core; a head on the noised rows with per-position "
+                "weights inside the manual region is missing"
+            )
+        if self.attention_impl == "chunked":
+            raise ValueError(
+                f"diffusion_block={d} with attention_impl='chunked': ops/attention.chunked_attention scans q blocks "
+                "against a causal prefix; the staircase's two runs of keys a q block are missing there"
+            )
 
     @property
     def layers_per_stage(self) -> int:
@@ -370,6 +424,11 @@ class TransformerConfig:
     @property
     def linear_key_heads(self) -> int:
         return self.linear_n_key_heads or self.linear_n_heads
+
+    @property
+    def mask_id(self) -> int:
+        """The id a noised position shows under block diffusion."""
+        return self.diffusion_mask_id if self.diffusion_mask_id >= 0 else self.vocab_size - 1
 
     @property
     def expert_d_ff(self) -> int:
@@ -1096,7 +1155,7 @@ def _use_chunked(cfg: TransformerConfig, seq_len: int) -> bool:
 
 def _attention_path(
     cfg: TransformerConfig, seq_len: int, batch: int, mesh, sp_manual: bool = False,
-    widths: Optional[Tuple[int, int]] = None, window: int = 0, grouped: bool = False,
+    widths: Optional[Tuple[int, int]] = None, window: int = 0, grouped: bool = False, diffusion: bool = False,
 ) -> Tuple[str, str, Optional[Tuple[int, int]]]:
     """(impl, reason, (block_q, block_k) or None): which code computes the
     causal core softmax(QKᵀ)V of a layer, decided from what can be
@@ -1107,8 +1166,14 @@ def _attention_path(
     "plain", each of which takes the same two. ``widths``: a head's (key,
     value) widths where they are not ``cfg.head_dim`` (a latent attention's):
     the kernel reads heads in place when the VALUES are whole lane tiles and
-    pads the keys with zero columns to the next one."""
+    pads the keys with zero columns to the next one. ``diffusion``: the rows are
+    a block-diffusion sequence ``[noised ; clean]`` (``seq_len`` counts both
+    halves): the kernel where "auto" takes it at the HALF's length (its tiles
+    divide a half) or it is asked for, else plain attention under the dense
+    staircase mask — the ring and the chunked scan do not know the rule."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    if diffusion:
+        return _diffusion_path(cfg, seq_len, batch, mesh, sp_manual, sp_size)
     if sp_size > 1 and (window or grouped):
         raise ValueError(
             f"sp={sp_size} with a window ({window}) or grouped-query heads: ring attention "
@@ -1162,6 +1227,36 @@ def _attention_path(
     return "plain", why, None
 
 
+def _diffusion_path(cfg: TransformerConfig, rows: int, batch: int, mesh, sp_manual: bool, sp_size: int):
+    """:func:`_attention_path` of a block-diffusion layer over ``rows`` = 2·S rows."""
+    if sp_size > 1:
+        raise ValueError(
+            f"sp={sp_size} with diffusion_block={cfg.diffusion_block}: ring attention (ops/attention.ring_attention_local) "
+            "masks by k_pos <= q_pos on one run of positions; a ring over [noised ; clean] — which shard holds which half, "
+            "and the staircase between a shard's rows and the block it is passed — is missing"
+        )
+    inside_manual = sp_manual or (mesh is not None and mesh.shape.get("pp", 1) > 1)
+    fast = _flash_blocks(rows // 2, cfg.head_dim)
+    if cfg.attention_impl == "auto" and fast is not None and jax.default_backend() == "tpu" and not inside_manual:
+        return "flash", "auto on a tpu: the kernel walks the staircase's live tiles", fast
+    if _use_flash(cfg, rows, batch, mesh) and not inside_manual:
+        why = "attention_impl" if cfg.attention_impl == "flash" else "auto: the dense staircase's scores would not fit the chip"
+        return "flash", why, fast or (128, 128)
+    return "plain", "the staircase as a dense mask", None
+
+
+def _live_tiles(rows: int, blocks: Optional[Tuple[int, int]]) -> Tuple[int, int]:
+    """(tiles the staircase kernel visits, tiles of the square) a head over
+    ``rows`` = 2·S rows at ``blocks``: a noised q tile its own and the clean
+    ones up to it, a clean q tile the clean ones up to it."""
+    if not blocks:
+        return 0, 0
+    bq, bk = (min(b, rows // 2) for b in blocks)
+    ends = [(i + 1) * bq for i in range(rows // 2 // bq)]  # a q tile's last position + 1, in either half
+    upto = sum(-(-e // bk) for e in ends)  # clean key tiles that start before it
+    return 2 * upto + sum(-(-e // bk) - (e - bq) // bk for e in ends), (rows // bq) * (rows // bk)
+
+
 _PATHS_SAID: set = set()
 
 
@@ -1181,7 +1276,7 @@ def _say_once(kind: str, key, **fields) -> None:
     )
 
 
-def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, kind=None, latent=None) -> None:
+def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, kind=None, latent=None, diffusion=False) -> None:
     """One ``attention_path`` event and one INFO line per traced shape — and
     per KIND of layer where a model declares a band or grouped heads
     (``kind``: its (query heads, key/value heads, window, rotated lanes)); a
@@ -1199,6 +1294,9 @@ def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, 
         fields.update(n_heads=heads, n_kv_heads=kv_heads, window=window, rotary_dim=rotary_dim)
     if latent is not None:
         fields.update(q_lora_rank=latent[0], rotary_dim=latent[1])
+    if diffusion:  # ``seq`` counts both halves; the tiles the kernel visits of the square's, a head
+        live, square = _live_tiles(seq_len, blocks if impl == "flash" else None)
+        fields.update(diffusion_block=cfg.diffusion_block, live_tiles=live, tiles=square)
     _say_once("attention_path", (*fields.values(), heads), **fields)
 
 
@@ -1245,7 +1343,7 @@ def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None
     _say_once("layer_pattern", tuple(fields.values()), **fields)
 
 
-def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int], window: Optional[int] = None):
+def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int], window: Optional[int] = None, block_diffusion: int = 0):
     """Flash attention under GSPMD: pallas_call has no partitioning rules,
     so without shard_map the SPMD partitioner would all-gather q/k/v onto
     every chip. Attention is independent per (batch, head), so manualize
@@ -1254,7 +1352,7 @@ def _flash_sharded(q, k, v, mesh, blocks: Tuple[int, int], window: Optional[int]
     from torchft_tpu.ops.pallas.flash_attention import flash_attention
 
     kernel = functools.partial(
-        flash_attention, causal=True, block_q=blocks[0], block_k=blocks[1], window=window,
+        flash_attention, causal=True, block_q=blocks[0], block_k=blocks[1], window=window, block_diffusion=block_diffusion,
     )
     if mesh is None:
         return kernel(q, k, v)
@@ -1282,12 +1380,17 @@ def _causal_core(cfg, mesh, sp_manual, q, k, v, widths=None, scope="core", windo
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
     # s is the sp-local block inside a manual region; the rule
     # reads sp from the mesh, not from s
+    diffusion = cfg.diffusion_block  # then the rows are [noised ; clean] and the rule is the staircase's
     impl, why, blocks = _attention_path(
-        cfg, s, b, mesh, sp_manual, widths=widths, window=window, grouped=k.shape[2] != q.shape[2]
+        cfg, s, b, mesh, sp_manual, widths=widths, window=window, grouped=k.shape[2] != q.shape[2], diffusion=bool(diffusion)
     )
-    _say_attention_path(impl, why, blocks, b, s, cfg, widths, kind, latent)
+    _say_attention_path(impl, why, blocks, b, s, cfg, widths, kind, latent, bool(diffusion))
     band = window or None  # the cores' "no band"
     with jax.named_scope(scope):
+        if diffusion and impl == "flash":
+            return _flash_sharded(q, k, v, mesh, blocks, block_diffusion=diffusion)
+        if diffusion:
+            return attention(q, k, v, block_diffusion=diffusion)
         if impl == "ring" and sp_manual:
             return ring_attention_local(q, k, v, sp_size, causal=True)
         if impl == "ring":
@@ -1343,11 +1446,16 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
     b, s, _ = h.shape  # s is the sp-local block inside a manual region
     if sp_manual and sp_size > 1:
         positions = jax.lax.axis_index("sp") * s + jnp.arange(s)
+    elif cfg.diffusion_block:  # the rows are [noised ; clean]: a position occurs twice
+        positions = jnp.arange(s) % (s // 2)
     else:
         positions = jnp.arange(s)
     heads, kv_heads = cfg.mixer_heads(mixer), cfg.kv_heads
-    # a model that declares kinds names them in the trace: attn/window and attn/global, the core inside each
+    # a model that declares kinds names them in the trace: attn/window and attn/global, the core inside each; a
+    # block-diffusion layer is a kind of its own, attn/blockdiff with blockdiff_core inside
     name = ("window" if mixer == "window" else "global") if _declares_kinds(cfg) else None
+    if cfg.diffusion_block:
+        name = "blockdiff"
     with jax.named_scope(name) if name else contextlib.nullcontext():
         q, k = h @ lp["wq"], h @ lp["wk"]
         if cfg.attn_output_gate:
@@ -1935,6 +2043,59 @@ def _say_loop(cfg: TransformerConfig, batch: int, seq_len: int) -> None:
     _say_once("loop_shape", tuple(fields.values()), **fields)
 
 
+def _diffusion_noise(tokens: jnp.ndarray, cfg: TransformerConfig):
+    """(t [B, S/D] float32, m [B, S] bool) of block-diffusion training: each
+    block's noise level and which positions show the mask id — a pure function
+    of a sequence's OWN ids and ``diffusion_seed``, so that ``loss_fn(params,
+    tokens, cfg, mesh)`` takes no further argument and anyone can rebuild it:
+
+    * ``c = Σ_ℓ x_ℓ·(2ℓ + 1) mod 2³¹`` (uint32 arithmetic, which wraps at 2³²);
+    * ``key = fold_in(PRNGKey(diffusion_seed), c)``, ``(key_t, key_m) = split(key)``;
+    * ``t_b = t_min + (1 - t_min)·U_b``, ``U = uniform(key_t, [S/D])`` float32,
+      computed as ``(U_b + t_min / (1 - t_min))·(1 - t_min)``;
+    * ``m_ℓ = V_ℓ < t_⌊ℓ/D⌋``, ``V = uniform(key_m, [S])`` float32.
+
+    A sequence met again draws the same noise; a trainer that wants otherwise
+    changes the seed by epoch. The sum comes FIRST and the one product after
+    it: written as a product and then a sum, XLA:CPU contracts the two into one
+    rounding under ``jit`` (and through an ``optimization_barrier``) and not
+    eagerly, and 27 % of the levels differ by an ulp; as written (t, m) are
+    the same bits on a CPU and on the chip, under ``jit`` or not."""
+    s, d = tokens.shape[1], cfg.diffusion_block
+    if s % d:
+        raise ValueError(f"diffusion_block={d} does not divide the sequence's {s} positions")
+    base = jax.random.PRNGKey(cfg.diffusion_seed)
+    odd = 2 * jnp.arange(s, dtype=jnp.uint32) + 1
+
+    def one(ids):
+        c = jnp.sum(ids.astype(jnp.uint32) * odd, dtype=jnp.uint32) & jnp.uint32(0x7FFFFFFF)
+        key_t, key_m = jax.random.split(jax.random.fold_in(base, c))
+        least = cfg.diffusion_t_min
+        t = (jax.random.uniform(key_t, (s // d,), jnp.float32) + jnp.float32(least / (1.0 - least))) * jnp.float32(1.0 - least)
+        return t, jax.random.uniform(key_m, (s,), jnp.float32) < jnp.repeat(t, d)
+
+    with _scopes("embed", "noise"):
+        return jax.vmap(one)(tokens)
+
+
+def _diffusion_rows(tokens: jnp.ndarray, cfg: TransformerConfig) -> jnp.ndarray:
+    """The ids a step embeds: ``tokens`` [B, S], or under block diffusion the
+    2·S rows ``[noised ; clean]`` a sequence, [B, 2·S]."""
+    if not cfg.diffusion_block:
+        return tokens
+    _, m = _diffusion_noise(tokens, cfg)
+    with _scopes("embed", "noise"):
+        return jnp.concatenate([jnp.where(m, jnp.asarray(cfg.mask_id, tokens.dtype), tokens), tokens], axis=1)
+
+
+def _final_norm(cfg: TransformerConfig, x: jnp.ndarray, weight: jnp.ndarray) -> jnp.ndarray:
+    """The final norm of the rows the head reads: under block diffusion the
+    noised half alone (the clean half has no loss and needs no logits)."""
+    if cfg.diffusion_block:
+        x = x[:, : x.shape[1] // 2]
+    return _norm(cfg, x, weight.astype(cfg.dtype))
+
+
 def _hidden_states(
     params: Dict[str, Any],
     tokens: jnp.ndarray,
@@ -1948,13 +2109,15 @@ def _hidden_states(
     over the layers that say it: ``balance`` [L] and ``counts`` [L, E] of
     dropless expert layers, ``decay_min`` of gdn mixers, …; else {}.
     A looped stack (``ut_steps`` = T > 1) gives every loop step's normed
-    state, [T, B, S, D], and its aux stacked over T·L layer applications."""
+    state, [T, B, S, D], and its aux stacked over T·L layer applications.
+    Under block diffusion the layers run the 2·S rows ``[noised ; clean]``
+    (their aux counts both halves) and the state returned is the NOISED half's."""
     from torchft_tpu.parallel.pipeline import pipeline_forward
 
     b, s = tokens.shape
     dt = cfg.dtype
     _refuse_loop(cfg)
-    x = _embed_lookup(params, tokens, dt)
+    x = _embed_lookup(params, _diffusion_rows(tokens, cfg), dt)
 
     pp = max(cfg.pp, 1)
     aux = {}
@@ -1965,7 +2128,7 @@ def _hidden_states(
         if not _scans_layers(cfg):
             lead, periods = _compute_dtype(lead, dt), _compute_dtype(periods, dt)
         x, aux = _make_pattern_fn(cfg, mesh)(lead, periods, x)
-        return _norm(cfg, x, params["final_norm"].astype(dt)), aux
+        return _final_norm(cfg, x, params["final_norm"]), aux
 
     layers = _compute_dtype(params["layers"], dt)
     if cfg.ut_steps > 1:
@@ -1998,7 +2161,7 @@ def _hidden_states(
         x_mb = pipeline_forward(layers, x_mb, stage_fn, mesh)
         x = x_mb.reshape(b, s, -1)
 
-    return _norm(cfg, x, params["final_norm"].astype(dt)), aux
+    return _final_norm(cfg, x, params["final_norm"]), aux
 
 
 def forward(
@@ -2008,7 +2171,8 @@ def forward(
     mesh=None,
 ) -> jnp.ndarray:
     """tokens [B, S] int32 -> logits [B, S, V] (compute in cfg.dtype,
-    logits in float32); of a looped stack, the last loop step's."""
+    logits in float32); of a looped stack, the last loop step's; under block
+    diffusion the NOISED rows' (the step's own draw of the noise)."""
     x, _ = _hidden_states(params, tokens, cfg, mesh)
     if cfg.ut_steps > 1:
         x = x[-1]
@@ -2026,7 +2190,10 @@ def loss_fn(
     sp sharding aligned). With dropless experts and a non-zero
     ``router_aux_loss_coef``, plus that times the load-balancing term. Of a
     looped stack with an exit gate: the expectation of the cross entropy over
-    the exits less the exit distribution's entropy (:func:`_exit_loss`)."""
+    the exits less the exit distribution's entropy (:func:`_exit_loss`). Under
+    block diffusion (``diffusion_block``): the mean over the S positions of
+    ``m/t`` times the noised row's cross entropy against the clean id at the
+    same position, no shift (:func:`_diffusion_loss`)."""
     return loss_and_stats(params, tokens, cfg, mesh)[0]
 
 
@@ -2045,8 +2212,10 @@ def loss_and_stats(
     of gdn mixers ``gdn_decay_min`` and ``gdn_beta_mean`` [their layers];
     of a looped stack with an exit gate ``exit_probs`` [T] (each exit's
     probability, mean over the supervised tokens), ``exit_entropy`` and
-    ``loss_by_step`` [T] (each exit's own cross entropy) —
-    what ``TrainStep`` keeps of its last step."""
+    ``loss_by_step`` [T] (each exit's own cross entropy); under block
+    diffusion ``masked_share``, ``noise_weight_mean`` and
+    ``loss_masked_unweighted``, and the router's statistics then count 2·S
+    rows a sequence — what ``TrainStep`` keeps of its last step."""
     if max(cfg.pp, 1) > 1 and cfg.n_experts and cfg.router_aux_loss_coef:
         raise ValueError(
             f"pp={cfg.pp} with router_aux_loss_coef={cfg.router_aux_loss_coef}: "
@@ -2072,7 +2241,9 @@ def _loss_of_hidden(params: Dict[str, Any], x: jnp.ndarray, aux, tokens: jnp.nda
     (``params["out"]``; an exit gate's and a multi-token-prediction module's
     leaves where the model has them) and what the layers said (``aux``)."""
     stats = {}
-    if cfg.exit_gate:
+    if cfg.diffusion_block:
+        ce, stats = _diffusion_loss(params, x, tokens, cfg, mesh)
+    elif cfg.exit_gate:
         ce, stats = _exit_loss(params, x, tokens, cfg, mesh)
     else:
         ce = _cross_entropy(params, x[-1] if cfg.ut_steps > 1 else x, tokens, cfg, mesh)
@@ -2097,6 +2268,27 @@ def _loss_of_hidden(params: Dict[str, Any], x: jnp.ndarray, aux, tokens: jnp.nda
     if cfg.router_aux_loss_coef:
         ce = ce + cfg.router_aux_loss_coef * balance
     return ce, stats
+
+
+def _diffusion_loss(params: Dict[str, Any], x: jnp.ndarray, tokens: jnp.ndarray, cfg: TransformerConfig, mesh=None):
+    """(loss, statistics) of block-diffusion training from the NOISED half's
+    normed state ``x`` [B, S, D]: ``mean_ℓ (m_ℓ / t_⌊ℓ/D⌋)·CE(x_ℓ, tokens_ℓ)``
+    — the target is the row's own clean id, no shift, every position counted in
+    the mean and the unmasked ones weighing nothing. Through the one head as
+    weights a position (:func:`_cross_entropy` under ``probs``, ``ahead`` 0).
+    Says ``masked_share`` (mean of m), ``noise_weight_mean`` (mean of m/t,
+    about 1) and ``loss_masked_unweighted`` (the masked positions' mean cross entropy)."""
+    t, m = _diffusion_noise(tokens, cfg)
+    with jax.named_scope("head_loss"):
+        shown = m.astype(jnp.float32)
+        weight = shown / jnp.repeat(t, cfg.diffusion_block, axis=1)
+    loss, nll = _cross_entropy(params, x, tokens, cfg, mesh, ahead=0, probs=weight)
+    with jax.named_scope("head_loss"):
+        stats = dict(
+            masked_share=jnp.mean(shown), noise_weight_mean=jnp.mean(weight),
+            loss_masked_unweighted=jnp.sum(nll * shown) / jnp.maximum(jnp.sum(shown), 1.0),
+        )
+    return loss, stats
 
 
 def cuts_by_layer(cfg: TransformerConfig) -> bool:
@@ -2154,7 +2346,7 @@ def grads_chain(cfg: TransformerConfig, mesh=None):
     row = lambda a, l: jax.lax.dynamic_index_in_dim(a, l, keepdims=False)
 
     def head(params, tokens):
-        x = _embed_lookup(params, tokens, dt)
+        x = _embed_lookup(params, _diffusion_rows(tokens, cfg), dt)
         stage = jax.tree_util.tree_map(lambda a: a[0], _compute_dtype(params["layers"], dt))
 
         def body(x, lp):
@@ -2165,7 +2357,7 @@ def grads_chain(cfg: TransformerConfig, mesh=None):
         x, (xs, aux, own) = jax.lax.scan(body, x, stage)
 
         def top(x, aux, final_norm, out):
-            h = _norm(cfg, x, final_norm.astype(dt))
+            h = _final_norm(cfg, x, final_norm)
             return _loss_of_hidden({"out": out}, h, aux, tokens, cfg, mesh)
 
         loss, vjp_fn, stats = jax.vjp(top, x, aux, params["final_norm"], params["out"], has_aux=True)
@@ -2190,7 +2382,7 @@ def grads_chain(cfg: TransformerConfig, mesh=None):
         return jax.tree_util.tree_map(lambda g, p: g.astype(p.dtype)[None, None], d_lp, stored), dx
 
     def tail(embed, tokens, dx):
-        _, vjp_fn = jax.vjp(lambda e: _embed_lookup({"embed": e}, tokens, dt), embed)
+        _, vjp_fn = jax.vjp(lambda e: _embed_lookup({"embed": e}, _diffusion_rows(tokens, cfg), dt), embed)
         return {"embed": vjp_fn(dx)[0]}
 
     return head, layer, tail, _act_spec()
@@ -2251,7 +2443,8 @@ def _cross_entropy(
 ):
     """Mean cross entropy of final-norm hidden states ``x`` against the token
     ``ahead`` positions on (1: the next token; 2: the multi-token-prediction
-    module's, whose ops carry its name inside ``head_loss``); the last
+    module's, whose ops carry its name inside ``head_loss``; 0: the position's
+    own id, block diffusion's target on a noised row); the last
     ``ahead`` positions have no target. With ``probs`` [B, S] (float32, and
     differentiated): (the mean over the supervised positions of ``probs`` times
     the position's cross entropy, each position's cross entropy [B, S] — a

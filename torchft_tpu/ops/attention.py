@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 __all__ = [
     "attention",
+    "block_diffusion_mask",
     "chunked_attention",
     "ring_attention",
     "ring_attention_local",
@@ -41,6 +42,22 @@ def _causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray, window: Optional[int] =
     return seen
 
 
+def block_diffusion_mask(seq_len: int, block: int) -> jnp.ndarray:
+    """[2S, 2S] True where row r sees key c of a block-diffusion sequence
+    ``[noised ; clean]`` (S = ``seq_len`` rows each; row r has position
+    ``r mod S`` and block ``position // block``): a noised row the noised keys
+    of its own block and the clean keys of the blocks BEFORE it, a clean row
+    the clean keys of its block and of those before; no row sees a noised key
+    of another block. Inside a block the rows see each other in both directions."""
+    row = jnp.arange(2 * seq_len)
+    noised, blk = row < seq_len, (row % seq_len) // block
+    q_noised, k_noised = noised[:, None], noised[None, :]
+    return jnp.where(
+        k_noised, q_noised & (blk[None, :] == blk[:, None]),
+        jnp.where(q_noised, blk[None, :] < blk[:, None], blk[None, :] <= blk[:, None]),
+    )
+
+
 def _each_query_head(q: jnp.ndarray, kv: jnp.ndarray) -> jnp.ndarray:
     """Grouped-query heads for the code that wants a key/value head a query
     head: query head a reads head a // (H / Hkv). The kernel
@@ -55,17 +72,23 @@ def attention(
     v: jnp.ndarray,
     causal: bool = True,
     window: Optional[int] = None,
+    block_diffusion: int = 0,
 ) -> jnp.ndarray:
     """Plain attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh]; the values
     may have another width than the keys (the output's), and k and v fewer
     heads than q (grouped-query: a whole number of query heads a key/value
     head), here and in :func:`chunked_attention`. ``window`` (causal only):
-    position i sees the keys j with i - window < j <= i."""
+    position i sees the keys j with i - window < j <= i. ``block_diffusion``
+    (in place of both): the S rows are ``[noised ; clean]`` under
+    :func:`block_diffusion_mask`."""
     assert causal or not window, "a band is causal"
+    assert not (block_diffusion and window), "the staircase has no band"
     k, v = _each_query_head(q, k), _each_query_head(q, v)
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if causal:
+    if block_diffusion:
+        scores = jnp.where(block_diffusion_mask(q.shape[1] // 2, block_diffusion)[None, None], scores, _NEG_INF)
+    elif causal:
         s = q.shape[1]
         pos = jnp.arange(s)
         scores = jnp.where(_causal_mask(pos, pos, window)[None, None], scores, _NEG_INF)

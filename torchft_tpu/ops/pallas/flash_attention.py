@@ -27,6 +27,16 @@ first row's first key, masks the tiles the band's lower edge crosses as well,
 and a K/V block wholly below the band is not fetched either: at 512 x 512
 tiles and a window of 512 a query tile visits two key tiles, whatever S.
 
+Block diffusion (``block_diffusion`` = D): the rows are a sequence twice,
+``[noised ; clean]``, and the mask is a staircase over the two halves
+(:func:`_over_staircase_tiles`): a q tile of the noised half visits the
+noised tile that holds its own positions and the clean tiles up to its own,
+one of the clean half the clean tiles up to its own — n² + 2n of the 4n² tiles
+at n tiles a half, 288 of 1 024 at 512 x 512 and S 8192 for S² + 4S pairs =
+256.1 tiles' worth at D 4 — and builds the mask only in the tiles the
+staircase or a block's edge crosses. The whole sequence is one resident K
+block there (a longer one is refused by name).
+
 Grouped queries: k and v may have fewer heads than q. In the forward the
 grid runs over query heads and a group's heads read the one K/V block of
 head ``h // group`` in place (consecutive steps, so it stays resident); the
@@ -154,6 +164,58 @@ def _over_key_tiles(row0, bq, col0, bk, bkc, causal, step, window=None) -> None:
     jax.lax.fori_loop(below, reached, lambda c, _: step(c, True), None)
 
 
+def _staircase(key0, n_keys, q0, n_q, block, lo, hi):
+    """[n_keys, n_q] True where the key at position ``key0 + r`` lies in
+    ``[f + lo, f + hi)``, f the first position of the block (of ``block``
+    positions) that holds the query's ``q0 + c``: ``lo`` None — every key
+    before ``f + hi``. The block's start is worked out on the queries' lane
+    row alone; the [n_keys, n_q] work is an iota and the comparisons."""
+    k = key0 + jax.lax.broadcasted_iota(jnp.int32, (n_keys, n_q), 0)
+    q = q0 + jax.lax.broadcasted_iota(jnp.int32, (1, n_q), 1)
+    first = q - (q & (block - 1))  # ``block`` is a power of two
+    seen = k < first + hi
+    return seen if lo is None else seen & (k >= first + lo)
+
+
+def _over_staircase_tiles(row0, bq, nc, bkc, half, block, step) -> None:
+    """Run ``step(c, seen)`` for the tiles c of ``bkc`` keys, of the ``nc``
+    resident (the whole ``[noised ; clean]`` sequence, ``half`` rows each),
+    that the q rows ``[row0, row0 + bq)`` may attend under block diffusion;
+    ``seen``: None where every pair of the tile is, else what builds its mask.
+    Row r has position ``r mod half`` and block ``position // block``. A
+    NOISED row sees the noised keys of its own block — the tiles that hold its
+    own positions, masked, and visited FIRST, so that every row has a key
+    before any tile in which it has none — and the clean keys of the blocks
+    before it; a CLEAN row the clean keys of its block and those before; no
+    row sees a noised key of another block. Of the clean keys the tiles that
+    end at or before the rows' first position run without a mask, those up to
+    the rows' last position build it, and the others are not computed:
+    ``block`` divides the tiles, so a tile's rows lie in one half."""
+    clean = row0 >= half
+    q0 = row0 - jnp.where(clean, half, 0)  # the rows' first position
+    own = lambda c: functools.partial(_staircase, c * bkc, bkc, q0, bq, block, 0, block)
+    # clean keys: tile c holds the positions from c * bkc - half; a noised row stops before its block, a clean one after
+    upto = jnp.where(clean, block, 0)
+    before = lambda c: functools.partial(_staircase, c * bkc - half, bkc, q0, bq, block, None, upto)
+    diag = q0 // bkc
+    jax.lax.fori_loop(diag, jnp.where(clean, diag, (q0 + bq + bkc - 1) // bkc), lambda c, _: step(c, own(c)), None)
+    whole = (half + q0) // bkc
+    jax.lax.fori_loop(half // bkc, whole, lambda c, _: step(c, None), None)
+    jax.lax.fori_loop(whole, jnp.minimum((half + q0 + bq + bkc - 1) // bkc, nc), lambda c, _: step(c, before(c)), None)
+
+
+def _walk(i, j, bq, bk, bkc, causal, window, diffusion, step) -> None:
+    """``step(c, seen)`` over the key tiles of grid step (q block i, k block j)
+    under the call's rule: the staircase of block diffusion, or the causal /
+    banded / full walk of :func:`_over_key_tiles`."""
+    if diffusion:
+        _over_staircase_tiles(i * bq, bq, bk // bkc, bkc, *diffusion, step)
+        return
+    # the mask and the scalars it starts from are traced where ``step`` asks for them, after its first product
+    seen = lambda c: lambda: _causal(j * bk + c * bkc, bkc, i * bq, bq, window)
+    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, lambda c, masked: step(c, seen(c) if masked else None), window)
+
+
 def _scaled(q_ref, scale):
     # 1/sqrt(d) once on the [bq, d] tile, not on every [bq, bkc] of scores
     return (q_ref[...].astype(jnp.float32) * scale).astype(q_ref.dtype)
@@ -166,7 +228,7 @@ def _scaled(q_ref, scale):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, bq, bk, bkc, scale, causal, window=None,
+    *, bq, bk, bkc, scale, causal, window=None, diffusion=None,
 ):
     # transposed scores [bkc, bq]: the running max and sum are lane rows
     # [1, bq] — as a [bq, 1] column each of their updates costs a pass over a
@@ -182,11 +244,11 @@ def _fwd_kernel(
 
     q = _scaled(q_ref, scale)
 
-    def step(c, masked: bool):
+    def step(c, seen):
         keys = pl.ds(pl.multiple_of(c * bkc, bkc), bkc)
         st = _dot(k_ref[keys, :], q, _NT)
-        if masked:
-            st = jnp.where(_causal(j * bk + c * bkc, bkc, i * bq, bq, window), st, _NEG_INF)
+        if seen is not None:
+            st = jnp.where(seen(), st, _NEG_INF)
         m_prev = m_ref[:1, :]
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         pt = jnp.exp(st - m_new)
@@ -198,7 +260,7 @@ def _fwd_kernel(
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, step, window)
+    _walk(i, j, bq, bk, bkc, causal, window, diffusion, step)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
@@ -218,7 +280,7 @@ def _fwd_kernel(
 def _bwd_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
     dk_acc, dv_acc, dq_acc,
-    *, bq, bk, bkc, scale, causal, window=None, nq=None,
+    *, bq, bk, bkc, scale, causal, window=None, nq=None, diffusion=None,
 ):
     j, y = pl.program_id(2), pl.program_id(3)
     i = y if nq is None else y % nq  # nq: q blocks a head, where a group's heads share the sweep
@@ -242,21 +304,21 @@ def _bwd_kernel(
         preferred_element_type=jnp.float32,
     )[:1, :]
 
-    def step(c, masked: bool):
+    def step(c, seen):
         keys = pl.ds(pl.multiple_of(c * bkc, bkc), bkc)
         k, v = k_ref[keys, :], v_ref[keys, :]
         # transposed scores [bkc, bq]: the statistics' lane rows broadcast
         # along sublanes as they are
         st = _dot(k, q, _NT)
-        if masked:
-            st = jnp.where(_causal(j * bk + c * bkc, bkc, i * bq, bq, window), st, _NEG_INF)
+        if seen is not None:
+            st = jnp.where(seen(), st, _NEG_INF)
         pt = jnp.exp(st - lse)
         dv_acc[keys, :] += _dot(pt.astype(do.dtype), do, _NN)
         dst = (pt * (_dot(v, do, _NT) - delta)).astype(q.dtype)
         dk_acc[keys, :] += _dot(dst, q, _NN)  # q carries the scale
         dq_acc[...] += _dot(dst, k, _TN)
 
-    _over_key_tiles(i * bq, bq, j * bk, bk, bkc, causal, step, window)
+    _walk(i, j, bq, bk, bkc, causal, window, diffusion, step)
 
     dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
@@ -325,7 +387,7 @@ def _resident_bytes(bk: int, d: int, dv: int, itemsize: int = 2) -> int:
 
 
 def _fwd(q, k, v, shape, blocks, causal, interpret):
-    b, s, h, d, dv, scale, group, window = shape
+    b, s, h, d, dv, scale, group, window, diffusion = shape
     bq, bk, bkc = blocks
     lanes = q.ndim == 3
     q_at = lambda i, j: i
@@ -339,7 +401,7 @@ def _fwd(q, k, v, shape, blocks, causal, interpret):
     kv_head = _same_head if group == 1 else (lambda h, x, y: h // group)
     return pl.pallas_call(
         functools.partial(
-            _fwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal, window=window
+            _fwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal, window=window, diffusion=diffusion
         ),
         grid=(b, h, s // bq, s // bk),
         in_specs=[
@@ -365,7 +427,7 @@ def _fwd(q, k, v, shape, blocks, causal, interpret):
 
 def _bwd(shape, blocks, causal, interpret, res, do):
     q, k, v, o, lse = res
-    b, s, h, d, dv, scale, group, window = shape
+    b, s, h, d, dv, scale, group, window, diffusion = shape
     bq, bk, bkc = blocks
     lanes = q.ndim == 3
     nk, nq = s // bk, s // bq
@@ -382,7 +444,7 @@ def _bwd(shape, blocks, causal, interpret, res, do):
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal, window=window,
-            nq=None if group == 1 else nq,
+            nq=None if group == 1 else nq, diffusion=diffusion,
         ),
         grid=(b, h // group, nk, group * nq),
         in_specs=[
@@ -468,8 +530,22 @@ def flash_attention(
     block_k: int = 128,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
+    block_diffusion: int = 0,
 ) -> jnp.ndarray:
     """Causal flash attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh].
+
+    ``block_diffusion`` = D > 0 (in place of ``causal`` and ``window``): the
+    rows are a sequence twice, ``[noised ; clean]``, S/2 rows each; row r has
+    position ``r mod S/2`` and block ``position // D``. A noised row sees the
+    noised keys of its own block (in both directions) and the clean keys of
+    the blocks before it, a clean row the clean keys of its own block and of
+    those before, no row a noised key of another block
+    (``ops.attention.block_diffusion_mask`` is the rule as a table). A tile in
+    which no pair is seen is not computed (:func:`_over_staircase_tiles`: of
+    n tiles a half, n² + 2n of the 4n² are visited); the mask is built in the
+    tiles the staircase or a block's edge crosses. D is a power of two that
+    divides the tiles, the tiles divide S/2, and all S rows of K and V are
+    resident (``_resident_tiles``: 16 384 rows of 128 lanes).
 
     Grouped queries: k and v may have fewer heads than q, a whole number of
     query heads a key/value head (query head a reads head a // group); the
@@ -495,6 +571,8 @@ def flash_attention(
         raise ValueError(f"{h} query heads over {k.shape[2]} key and {v.shape[2]} value heads: groups are whole")
     if window is not None and not (causal and window >= 1):
         raise ValueError(f"window={window}: a band is causal and at least one key wide")
+    if block_diffusion and window is not None:
+        raise ValueError(f"block_diffusion={block_diffusion} with window={window}: the staircase has no band")
     if d % _LANES and dv % _LANES == 0:
         widen = ((0, 0), (0, 0), (0, 0), (0, -d % _LANES))
         q, k = jnp.pad(q, widen), jnp.pad(k, widen)
@@ -506,6 +584,21 @@ def flash_attention(
     if interpret is None:
         interpret = _should_interpret()
     blocks = (bq, bkc * _resident_tiles(s, bkc, max(d, dv)), bkc)
+    diffusion = None
+    if block_diffusion:
+        half = s // 2
+        if block_diffusion & (block_diffusion - 1) or s % 2 or half % bq or half % bkc or bq % block_diffusion or bkc % block_diffusion:
+            raise ValueError(
+                f"block_diffusion={block_diffusion} over {s} rows at tiles {bq} x {bkc}: the block is a power of two "
+                "that divides both tiles, and the tiles divide each half of the rows"
+            )
+        if blocks[1] != s:
+            raise ValueError(
+                f"block_diffusion over {s} rows of {max(d, dv)} lanes: K and V arrive in blocks of {blocks[1]} rows; the "
+                "index maps that fetch a noised row's own block and then the clean blocks before it (and the backward's "
+                "q blocks of one K block) are missing — the staircase runs with the whole sequence resident"
+            )
+        causal, diffusion = False, (half, block_diffusion)
 
     if d % _LANES == 0 and dv % _LANES == 0:
         # a head's columns are whole lane tiles of the [B, S, H·Dh] view
@@ -520,6 +613,6 @@ def flash_attention(
 
         unpack = pack
 
-    shape = (b, s, h, d, dv, scale, group, window)
+    shape = (b, s, h, d, dv, scale, group, window, diffusion)
     o = _flash(pack(q), pack(k), pack(v), shape, blocks, causal, interpret)
     return unpack(o)

@@ -193,6 +193,29 @@ class FTTrainer:
         with tracing.annotate("loop.counters", **counters):
             pass
 
+    def _record_diffusion_counters(self, step: int, sync_span) -> None:
+        """What block-diffusion training says of the step's noise
+        (``loss_and_stats``: ``masked_share``, ``noise_weight_mean``,
+        ``loss_masked_unweighted``), fetched with the loss: the share of
+        positions that showed the mask id, the mean of their weights m/t (about
+        1: a step far from it drew few blocks or small levels) and the masked
+        positions' own cross entropy — on the ``loss_sync`` span in the Tracer
+        ring and, as ``tft.diffusion.counters``, in a profiler trace. Any other
+        model emits nothing."""
+        stats = self._ts.last_stats
+        if "masked_share" not in stats:
+            return
+        counters = dict(
+            step=step,
+            block=int(self._ts.cfg.diffusion_block),
+            masked_share=float(stats["masked_share"]),
+            noise_weight_mean=float(stats["noise_weight_mean"]),
+            loss_masked_unweighted=float(stats["loss_masked_unweighted"]),
+        )
+        sync_span.set(**counters)
+        with tracing.annotate("diffusion.counters", **counters):
+            pass
+
     # -- drive --
 
     def step(self, tokens) -> Tuple[float, bool]:
@@ -239,5 +262,6 @@ class FTTrainer:
                 self._record_mtp_counters(label, sync_span)
                 self._record_gdn_counters(label, sync_span)
                 self._record_loop_counters(label, sync_span)
+                self._record_diffusion_counters(label, sync_span)
             step_span.set(committed=committed)
         return loss, committed
