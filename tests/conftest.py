@@ -13,8 +13,17 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # respawn the same toy models dozens of times and each respawn otherwise
 # recompiles from scratch — on the 2-core CI box that recompile tax alone
 # pushes the full 'not slow' tier against its wall-clock budget.
+# The driver's machine starts WITHOUT this directory: its run compiles every
+# program once, where a builder's /tmp holds earlier sessions' (so a tier-1
+# time is quoted with this variable on an empty directory, or not at all).
+# A test's program runs once or a few times, so XLA does not optimise it
+# (backend level 0, LLVM's expensive passes off): the lowered text and its
+# digests are what they were, a cold run loses a quarter of its seconds. A
+# test that pins what an OPTIMISED program computes turns the flag back on
+# for itself (`jax_disable_most_optimizations`, read at each compile).
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/tft_jax_cache")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
+os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

@@ -476,3 +476,22 @@ def test_without_a_session_a_call_costs_no_annotation(monkeypatch):
     with tracing.annotate("exchange.pack", step=1):
         pass
     assert not made
+
+
+def test_a_spawned_trainer_builds_under_this_processs_cache_and_optimisation_settings(monkeypatch, tmp_path):
+    """What ``tests/conftest.py`` sets is what JAX reads in this process and
+    what a trainer of the soak tiers is started with: the child loads what the
+    run has compiled, and optimises as little."""
+    import subprocess
+
+    from tests.test_chaos import _spawn
+
+    started = []
+    monkeypatch.setattr(subprocess, "Popen", lambda cmd, env, **kw: started.append(env))
+    _spawn(0, "localhost:0", str(tmp_path))
+    (env,) = started
+    settings = ("JAX_COMPILATION_CACHE_DIR", "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "JAX_DISABLE_MOST_OPTIMIZATIONS")
+    assert {name: env[name] for name in settings} == {name: os.environ[name] for name in settings}
+    assert jax.config.jax_compilation_cache_dir == env[settings[0]]
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == float(env[settings[1]])
+    assert jax.config.values["jax_disable_most_optimizations"] == (env[settings[2]] == "1")

@@ -97,9 +97,13 @@ def grad_errors(g_got, g_want):
     )
 
 
+# jitted once for the file (``tests/test_gdn.py`` says why): a test that patches what TRACING reads builds its own
+_loss_and_grads = jax.jit(jax.value_and_grad(loss_fn), static_argnums=2)
+
+
 def system(cfg, params, tokens):
     with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(loss_fn), static_argnums=2)(params, tokens, cfg)
+        return _loss_and_grads(params, tokens, cfg)
 
 
 def reference(params, tokens, sizes):

@@ -20,6 +20,7 @@ leaf (``gdn.dense``, seed 3). A slip in the structure reads 1e-2 and more.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import os
 
@@ -98,9 +99,15 @@ def grad_errors(g_got, g_want):
     )
 
 
+# One jitted function a file, so that cases which differ in a seed or a value, and tests that take one configuration
+# at one shape, share a trace (it is keyed by ``cfg``, the shapes and the precision; tracing and lowering are half of
+# a cold run's seconds). A test that patches what TRACING reads — a module's constant, a path's choice — builds its own.
+_loss_and_grads = jax.jit(jax.value_and_grad(loss_fn), static_argnums=2)
+
+
 def system(cfg, params, tokens):
     with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(loss_fn), static_argnums=2)(params, tokens, cfg)
+        return _loss_and_grads(params, tokens, cfg)
 
 
 # -- the core against the rule one position after another ------------------------------------------
@@ -313,12 +320,18 @@ def test_the_backward_kernels_body_holds_the_inverses_products_no_more(dtype, pa
 # -- the program against the reference ------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def reference_of(size):
+    """The reference's loss and gradients at one of SIZES, jitted once: the seeds share its trace."""
+    return jax.jit(jax.value_and_grad(lambda p, t: ref.loss(p, t, SIZES[size])))
+
+
 @pytest.mark.parametrize("seed", [3, 4, 5])
 @pytest.mark.parametrize("size", list(SIZES))
 def test_loss_and_every_gradient_leaf_agree_with_the_reference(size, seed):
     cfg, params, tokens, sizes = make(size, remat=True, seed=seed, seq=72)
     got, g_got = system(cfg, params, tokens)
-    want, g_want = jax.jit(jax.value_and_grad(lambda p, t: ref.loss(p, t, sizes)))(params, tokens)
+    want, g_want = reference_of(size)(params, tokens)
     assert float(got) == pytest.approx(float(want), rel=2e-6)
     errs = grad_errors(g_got, g_want)
     assert max(jax.tree_util.tree_leaves(errs)) < RTOL, errs

@@ -352,6 +352,9 @@ def test_manager_2replica_quorum_commit_events():
     from torchft_tpu.coordination import LighthouseServer
 
     telemetry.EVENTS.clear()
+    # a quorum RPC carries 64 spans: what earlier tests of this worker left
+    # pending would fill all three steps' batches ahead of this run's spans
+    telemetry.TRACER.clear()
     quorums0 = telemetry.QUORUMS_TOTAL.value
     commits0 = telemetry.COMMITS_TOTAL.labels(outcome="committed").value
     lh = LighthouseServer(bind="[::]:0", min_replicas=2)
