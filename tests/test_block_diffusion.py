@@ -374,7 +374,7 @@ def test_twenty_fused_steps_on_one_batch_lower_the_loss():
         loss, params, opt = ts.step(params, opt, ts.shard_batch(tokens))
         losses.append(float(loss))
     assert losses[-1] < losses[0] - 1.0, losses
-    assert {"masked_share", "noise_weight_mean", "loss_masked_unweighted", "rows_held"} <= set(ts.last_stats)
+    assert {"masked_share", "noise_weight_mean", "loss_masked_unweighted", "rows_held", "window_live_share"} <= set(ts.last_stats)
 
 
 def test_the_chain_of_grads_runs_a_diffusion_stack():
@@ -508,7 +508,7 @@ def test_without_a_block_nothing_is_drawn_and_the_loss_is_next_token():
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 32)), jnp.int32)
     text = lambda cfg: jax.jit(jax.value_and_grad(lambda p: loss_fn(p, tokens, cfg))).lower(params).as_text()
     assert text(a) == text(b) and "threefry" not in text(a)
-    assert set(loss_and_stats(params, tokens, a)[1]) == {"tokens_per_expert", "balance_loss", "rows_held"}
+    assert set(loss_and_stats(params, tokens, a)[1]) == {"tokens_per_expert", "balance_loss", "rows_held", "window_live_share"}
 
 
 def test_attention_takes_the_rule_as_a_dense_mask():

@@ -281,7 +281,7 @@ def test_the_feed_forward_takes_the_gate_it_is_given_and_keeps_it_for_the_backwa
     h1 = T._norm(cfg, x, lp["ln1"])
     gate, (balance, counts) = T._gate_ahead(lp, h1, cfg)
     y, said = T._ffn_moe(dict(lp, router=jnp.full_like(lp["router"], jnp.nan)), x, cfg, gate)
-    assert set(T._moe_said(cfg, said, gate_ahead=True)) == {"held", "gate_zeros"} and bool(jnp.all(jnp.isfinite(y)))
+    assert set(T._moe_said(cfg, said, gate_ahead=True)) == {"held", "live_share", "gate_zeros"} and bool(jnp.all(jnp.isfinite(y)))
     assert int(jnp.sum(counts)) == 2 * 24 * 4 and float(balance) > 0
     # the layer as three checkpoints: the router's part holds the top-k and no grouped matmul, the mixer's neither,
     # the feed-forward's the grouped matmuls and NO top-k — the chosen experts [T, k] int32 are among its inputs
@@ -471,7 +471,7 @@ def test_the_fused_step_learns_on_the_stack_and_says_the_relus_share():
         loss, params, opt = ts.step(params, opt, ts.shard_batch(tokens))
         losses.append(float(loss))
     assert losses[-1] < losses[0] - 0.3
-    assert set(ts.last_stats) == {"tokens_per_expert", "balance_loss", "rows_held", "gate_zero_share"}
+    assert set(ts.last_stats) == {"tokens_per_expert", "balance_loss", "rows_held", "window_live_share", "gate_zero_share"}
     load = np.asarray(ts.last_stats["tokens_per_expert"])
     assert load.shape == (4, 16) and (load.sum(axis=1) == 2 * 32 * 4).all()  # the four layers, all 16 experts
     np.testing.assert_array_equal(ts.last_stats["rows_held"], load[:, 4:8].sum(axis=1))  # share 1 holds experts 4..7
@@ -527,9 +527,12 @@ def test_a_dp_x_fsdp_x_tp_mesh_gives_the_unsharded_loss():
 # place — old counts against new in ``tests/test_gdn_train.CELLS_PROGRAMS``' comment; qwen3-next-80b-a3b-1g's softmax
 # gate reads ``top_k``'s own values and did not move. Nor did either by the PR that hands the delta rule's block inverse
 # on as a second residual: at the rehearsal's head width, no lane tile, qwen3-next-80b-a3b-1g's mixer is the ``jax.numpy`` form.
+# Both re-pinned by the PR that moves the held layer's rows by the count of live ones (``ops/layers._live_rows``; f33873fb…3d12 and
+# 6a3ed706…3d9c at 92920b9 and before): a ``while`` over passes of rows into ``jax.lax.empty`` where a gather of the window's
+# places stood, and ``window_live_share`` a layer — old counts against new in ``tests/test_gdn_train.CELLS_PROGRAMS``' comment.
 REHEARSAL_PROGRAMS = {
-    "laguna-xs2-1g": "f33873fbe0128e8c1a4868ed1a45969285f26c5c6927ad3e923f3c55b9333d12",
-    "qwen3-next-80b-a3b-1g": "6a3ed70638d27b8879d03a1c6168a7bfff66cfbe6c74f18844d2acbfb8af3d9c",
+    "laguna-xs2-1g": "c612dc3332214c96f79565849f9f6f5baab0e0f718558eebedb1e7ce1dc9fbbe",
+    "qwen3-next-80b-a3b-1g": "6fd1c101ef255eeb789da3d66a1ae2b22a8f306efdd9bf1b98ca27d7371a627a",
 }
 
 

@@ -271,7 +271,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
         for share in range(4):
             cfg = TransformerConfig(dtype=jnp.float32, **dict(QUARTER, expert_share_index=share))
             lp = {k: (v[8 * share : 8 * share + 8] if k in ("w_gate", "w_in", "w_out") else v) for k, v in whole.items()}
-            y, (_, counts, held) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
+            y, (_, counts, held, _) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
             np.testing.assert_allclose(y, ref._experts(lp, x, dict(QUARTER, expert_share_index=share)), atol=5e-5)
             parts.append(y)
             rows.append(int(held))

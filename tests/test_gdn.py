@@ -611,7 +611,7 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_one():
         for share in range(4):
             cfg = TransformerConfig(dtype=jnp.float32, **dict(STACK, expert_share_index=share))
             lp = share_of(whole, share)
-            y, (_, counts, held, gate) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
+            y, (_, counts, held, _, gate) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
             np.testing.assert_allclose(y, ref._experts(lp, x, dict(STACK, expert_share_index=share)), atol=5e-5)
             np.testing.assert_allclose(gate, jnp.mean(ref._shared_gate(whole, x)), rtol=1e-5)
             parts.append(y - shared)
@@ -681,7 +681,7 @@ def test_the_shared_experts_gate_scales_it_and_trains():
         plain, said_bare = T._ffn_moe({k: v for k, v in lp.items() if k != "shared_scale"}, x, bare)
         shared = ref._swiglu(x, lp["shared_gate"], lp["shared_in"], lp["shared_out"])
         np.testing.assert_allclose(plain - gated, (1.0 - ref._shared_gate(lp, x)) * shared, atol=2e-5)
-    assert len(said) == 4 and len(said_bare) == 3 and 0.3 < float(said[3]) < 0.7
+    assert len(said) == 5 and len(said_bare) == 4 and 0.3 < float(said[4]) < 0.7
     grads = jax.grad(lambda lp: jnp.sum(T._ffn_moe(lp, x, cfg)[0] ** 2))(lp)
     assert grads["shared_scale"].shape == (32, 1) and float(jnp.max(jnp.abs(grads["shared_scale"]))) > 0
 

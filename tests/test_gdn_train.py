@@ -71,10 +71,27 @@ CELLS_PROGRAMS = {
     # were. The lines said did not move, nor kimi-linear-1g's ``flash_fwd`` / ``flash_bwd`` equations
     # (``tests/test_mla_rope_mtp_train.CELLS_KERNELS``: its digest of the other kernels did, b428188e…c248 before), nor
     # the eight other configurations' programs.
-    "kimi-linear-1g": ((2, 8192), "95884449c5ebe967d5da04ee8378df4ed21a92774ffc276e2763a82f0bd7bc64", "efeaeed97ccba4c3"),
-    "laguna-xs2-1g": ((2, 8192), "bca2e146dd3fd083383d11a9fe1568b3895c094838f88e1317c9f784ffc1d5c5", "2e3b7f09d4732e39"),
-    "joyai-flash-1g": ((2, 8192), "6f42f5a08abb4906ec7d0a4899666dc66f2cd00b6baa019b8d4c7e0f007eefaa", "a6aa64069f77d113"),
-    "lfm2-8b-a1b-1g": ((2, 8192), "89c3681a21cc85d34798a6ff43f5c68f1cbbc6f13c3ad39e15f67cb634ff2287", "647d94df3e9b6744"),
+    # All four (and qwen3-next-80b-a3b-1g in ``tests/test_looped_train.CELLS_PROGRAMS``) re-pinned by the PR that moves the
+    # held expert layer's rows by the count of live ones (``ops/layers._live_rows``; kimi-linear-1g 95884449…bc64,
+    # laguna-xs2-1g bca2e146…d5c5, joyai-flash-1g 6f42f5a0…efaa, lfm2-8b-a1b-1g 89c3681a…2287, qwen3-next-80b-a3b-1g
+    # d04fb3af…0055 at 92920b9 and before). Old text against new, primitive counts of the whole program with the kernels'
+    # bodies left out, kimi-linear-1g (four sparse layers written out; laguna-xs2-1g and qwen3-next-80b-a3b-1g move by
+    # the same numbers, joyai-flash-1g and lfm2-8b-a1b-1g by half of each): ``while`` 0 -> 20 and ``empty`` 0 -> 20 —
+    # a layer's five moves of rows (dispatch, the combine's rows into token order, ``dy`` by row, the rows' gradients into
+    # token order, dispatch recomputed) in the first window's branch of the layer's ``cond``; ``every_window``'s branch
+    # and the gates' 128-wide cells keep their one gather — ``dynamic_update_slice`` 16 -> 36 and ``dynamic_slice``
+    # 480 -> 500 (a pass's indices out, its rows in), ``min`` 32 -> 52 (the last pass's start), ``gather`` 484 as it was:
+    # the same gathers, 20 of them now of 512 places in a loop's body where they were of the window's m; the loops' index
+    # arithmetic, ``jax.lax``'s own and no jitted helper's (``add`` 3062 -> 3142, ``lt`` 2270 -> 2330, ``select_n`` 4426 ->
+    # 4466, ``mul`` 2001 -> 2021, ``jit`` 5878 as it was) and ``window_live_share`` a layer (of the ``div`` 920 -> 944 and
+    # ``convert_element_type`` 2928 -> 2972 four each, ``broadcast_in_dim`` 6185 -> 6189, ``concatenate`` 1194 -> 1195,
+    # ``reshape`` 957 -> 958). No other primitive's count moved; the lines said did not, nor any ``pallas_call`` equation
+    # (``tests/test_mla_rope_mtp_train.CELLS_KERNELS``), nor the programs of olmo1b-1g, olmo1b-4g, olmoe-1g
+    # (``moe_dropless`` is a path of its own) and ouro-2_6b-1g.
+    "kimi-linear-1g": ((2, 8192), "cc8a5bf3a088965f2ebdc7646e00a24e8607833b758d1463aab7965f38dd9084", "efeaeed97ccba4c3"),
+    "laguna-xs2-1g": ((2, 8192), "fff2093b75eb0dc94979323804b0efc5dbcafb846fdd8288c0cdf1dc0664623b", "2e3b7f09d4732e39"),
+    "joyai-flash-1g": ((2, 8192), "7a555ecf1ac27d1a22f69d07f2b4c7ba78ee1d2fba664a3111a569a1c01e6515", "a6aa64069f77d113"),
+    "lfm2-8b-a1b-1g": ((2, 8192), "60537e3b0b4b4a9f639fee9a44b050d7d3126bcb67a01999fd5bea4e897efb9c", "647d94df3e9b6744"),
 }
 NEW_CELL = "qwen3-next-80b-a3b-1g"
 
@@ -206,7 +223,7 @@ def test_the_new_parts_ops_carry_their_names_under_attn_and_moe():
 # -- TrainStep and the Manager ----------------------------------------------------------------------
 
 
-STATS = {"tokens_per_expert", "balance_loss", "rows_held", "shared_gate_mean", "gdn_decay_min", "gdn_beta_mean"}
+STATS = {"tokens_per_expert", "balance_loss", "rows_held", "window_live_share", "shared_gate_mean", "gdn_decay_min", "gdn_beta_mean"}
 
 
 def test_the_fused_step_is_the_split_pair_on_this_tree():
@@ -310,3 +327,7 @@ def test_two_groups_exchange_the_gdn_layers_heal_them_and_say_their_counters(mon
         assert stats["decay_min"] < 0.0 and 0.3 < stats["beta_mean"] < 0.7 and 0.3 < stats["shared_gate_mean"] < 0.7
     for stats in moe:  # 2 x 32 tokens x 4 chosen x 4 layers routed; a quarter of the experts held
         assert stats["rows_routed"] == 2 * 32 * 4 * 4 and 0 < stats["rows_held"] < stats["rows_routed"]
+        # rows held over the window's slots, mean and fullest of the four layers: the row moves cost by it
+        bound = T._held_row_bound(TransformerConfig(**SIZES["stack"]), 2 * 32 * 4)
+        assert stats["window_live_share"] == pytest.approx(stats["rows_held"] / (4 * bound))
+        assert stats["window_live_share"] <= stats["window_live_share_max"] < 2

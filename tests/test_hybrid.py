@@ -270,7 +270,7 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(bound, monkeypatch):
         routed, rows = [], []
         for share in range(4):
             cfg, lp, _, share_sizes = expert_layer(share)
-            y, (_, counts, held) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
+            y, (_, counts, held, _) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
             routed.append(y - shared)
             rows.append(int(held))
             np.testing.assert_allclose(y, ref._experts(whole_to(lp, whole), x, share_sizes)[0], atol=2e-5)

@@ -14,7 +14,7 @@ import optax
 import pytest
 
 from tests.test_hybrid import REPEATING, RTOL, grad_errors, make
-from torchft_tpu.models.transformer import TransformerConfig, loss_and_stats, loss_fn
+from torchft_tpu.models.transformer import TransformerConfig, _held_row_bound, loss_and_stats, loss_fn
 from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
 from torchft_tpu.parallel.train_step import TrainStep
 
@@ -46,10 +46,14 @@ def test_the_fused_step_learns_and_keeps_the_rows_held():
         losses.append(float(loss))
     assert losses[-1] < losses[0] - 0.3
     stats = ts.last_stats
-    assert set(stats) == {"tokens_per_expert", "balance_loss", "rows_held"}
+    assert set(stats) == {"tokens_per_expert", "balance_loss", "rows_held", "window_live_share"}
     load = np.asarray(stats["tokens_per_expert"])
     assert load.shape == (4, 16) and (load.sum(axis=1) == 2 * 32 * 4).all()  # the four expert layers, all 16 experts
     np.testing.assert_array_equal(stats["rows_held"], load[:, :4].sum(axis=1))  # share 0 holds experts 0..3
+    # ... over the window's slots, a layer: here the window is every slot (a tile of 512 holds all 256), so about a quarter
+    bound = _held_row_bound(cfg, 2 * 32 * 4)
+    assert bound == 2 * 32 * 4
+    np.testing.assert_allclose(stats["window_live_share"], np.asarray(stats["rows_held"]) / bound, rtol=1e-6)
 
 
 def hybrid_train_loop(rank, store_addr, runner, total_steps=4):

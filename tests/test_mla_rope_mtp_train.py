@@ -51,8 +51,12 @@ CELLS_PROGRAMS = {
     # 6181 -> 6185: the exact branch's zeros) — the whole list in the same comment. In CELLS_KERNELS below the flash
     # kernel's two equations did not move and the digest of the others did (b428188ee614c248 before: the twelve KDA
     # equations are new, ``kda_bwd``'s body with 256 ``dot_general`` where it had 376; the grouped matmuls' are the parent's).
-    "kimi-linear-1g": "95884449c5ebe967d5da04ee8378df4ed21a92774ffc276e2763a82f0bd7bc64",
-    "laguna-xs2-1g": "bca2e146dd3fd083383d11a9fe1568b3895c094838f88e1317c9f784ffc1d5c5",
+    # Every configuration with ``n_experts_held`` re-pinned by the PR that moves the held layer's rows by the
+    # count of live ones (``ops/layers._live_rows``): a ``while`` over passes of 512 places into ``jax.lax.empty`` where a
+    # gather of the window's m places stood, and ``window_live_share`` a layer — old digests and counts against new in
+    # ``tests/test_gdn_train.CELLS_PROGRAMS``' comment.
+    "kimi-linear-1g": "cc8a5bf3a088965f2ebdc7646e00a24e8607833b758d1463aab7965f38dd9084",
+    "laguna-xs2-1g": "fff2093b75eb0dc94979323804b0efc5dbcafb846fdd8288c0cdf1dc0664623b",
 }
 CELLS_KERNELS = {
     # the programs' ``pallas_call`` equations, each printed on its own (``tests/test_window_gqa.kernel_equations``):
@@ -127,7 +131,7 @@ def test_the_sixteen_shares_of_a_layer_add_up_to_the_uncut_layer():
         for share in range(16):
             cfg = TransformerConfig(dtype=jnp.float32, **dict(sizes, expert_share_index=share))
             lp = {k: (v[share : share + 1] if k in ("w_gate", "w_in", "w_out") else v) for k, v in whole.items()}
-            y, (_, counts, held) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
+            y, (_, counts, held, _) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
             routed.append(y - shared)
             rows.append(int(held))
             assert int(jnp.sum(counts)) == 2 * 48 * 4  # the router counts over all 16, on every share
@@ -173,7 +177,7 @@ def test_the_fused_step_is_the_split_pair_on_this_tree():
     split = ts.apply(params, opt, grads)
     fused_loss, *fused = ts.step(*kept, batch)
     assert float(loss) == float(fused_loss)
-    assert set(ts.last_stats) == set(split_stats) == {"main_loss", "mtp_loss", "tokens_per_expert", "balance_loss", "rows_held"}
+    assert set(ts.last_stats) == set(split_stats) == {"main_loss", "mtp_loss", "tokens_per_expert", "balance_loss", "rows_held", "window_live_share"}
     assert float(ts.last_stats["mtp_loss"]) == float(split_stats["mtp_loss"])
     for a, b in zip(jax.tree_util.tree_leaves(split), jax.tree_util.tree_leaves(tuple(fused))):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)

@@ -14,6 +14,7 @@ more; the tests below show each.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import os
 from datetime import timedelta
@@ -445,6 +446,10 @@ def held_routing(case):
         idx = np.stack([HELD + rng.permutation(E_ALL - HELD)[:K_HELD] for _ in range(T_HELD)])
     elif case == "one_window_of_every_slot":
         bound = T_HELD * K_HELD
+    elif case == "overflows_a_window_of_two_and_a_half_passes":
+        bound = 40  # ~48 rows held: `every_window`, whose windows move their rows as one gather whatever PASSES says
+    elif case == "several_passes_a_move":
+        pass  # the balanced case, its 96 slots moved 16 a pass
     else:
         assert case == "balanced", case
     return jnp.asarray(idx, jnp.int32), first, bound
@@ -453,19 +458,23 @@ def held_routing(case):
 HELD_CASES = [
     "balanced", "overflows_the_first_window", "a_token_with_all_k_held", "a_token_with_none_held",
     "an_expert_with_no_rows", "first_expert_above_zero", "no_row_held", "one_window_of_every_slot",
+    "overflows_a_window_of_two_and_a_half_passes", "several_passes_a_move",
 ]
+# rows a pass of the loop that moves a window's live rows, where a case sets it under the window's slots
+PASSES = {"overflows_a_window_of_two_and_a_half_passes": 16, "several_passes_a_move": 16}
 
 
 @pytest.mark.parametrize("case", HELD_CASES)
-def test_the_held_path_agrees_with_the_reference_and_with_the_path_it_replaced(case):
+def test_the_held_path_agrees_with_the_reference_and_with_the_path_it_replaced(case, monkeypatch):
     """``y``, the rows held and the gradient of every operand — tokens, gates
     and the three weights — against every held expert over every token in
     float32, and against the path before PR 44 with its [T·k, d] spread."""
     top_idx, first, bound = held_routing(case)
+    monkeypatch.setattr(layers, "_MOVE_ROWS", PASSES.get(case, layers._MOVE_ROWS))
     operands = held_operands()
     probe = jnp.cos(jnp.arange(T_HELD * D_HELD, dtype=jnp.float32)).reshape(T_HELD, D_HELD)
     want_rows = int(jnp.sum((top_idx >= first) & (top_idx < first + HELD)))
-    if case == "overflows_the_first_window":
+    if case.startswith("overflows"):
         assert want_rows > bound
     if case == "no_row_held":
         assert want_rows == 0
@@ -511,6 +520,56 @@ def test_the_held_path_keeps_bfloat16_rows_and_sums_a_tokens_rows_in_float32():
     scale = float(jnp.max(jnp.abs(exact)))
     assert float(jnp.max(jnp.abs(y.astype(jnp.float32) - old.astype(jnp.float32)))) <= 2**-7 * scale
     assert float(jnp.max(jnp.abs(y.astype(jnp.float32) - exact))) <= 4 * 2**-7 * scale
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def live_rows(src, idx, n_live, rows_a_pass):
+    return layers._live_rows(src, idx, n_live, rows_a_pass)
+
+
+@pytest.mark.parametrize("d", [256, 384])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("n_live", [0, 1, 15, 16, 17, 40])
+def test_a_move_moves_the_live_rows_and_ends_with_them(n_live, dtype, d):
+    """``_live_rows`` at 40 places, 16 a pass (two passes and an overlapping
+    half): the live rows are the gather's, whatever the count — none, one, a
+    pass's edge and the rows beside it, all; no pass runs past the live rows
+    (off the TPU ``jax.lax.empty`` is zeros: a row no pass wrote reads 0); and a
+    row of ``src`` that only dead places name may hold NaN."""
+    rng = np.random.default_rng(n_live)
+    idx = rng.permutation(64)[:40]
+    src = rng.standard_normal((64, d)) + 3.0  # no zero row
+    src[idx[n_live:]] = np.nan  # the dead places' rows
+    src, idx = jnp.asarray(src, dtype), jnp.asarray(idx, jnp.int32)
+    got = live_rows(src, idx, jnp.int32(n_live), 16)
+    assert got.shape == (40, d) and got.dtype == dtype
+    np.testing.assert_array_equal(got[:n_live], src[idx[:n_live]])
+    assert bool(jnp.all(jnp.isfinite(got[:n_live].astype(jnp.float32))))
+    moved = min(-(-n_live // 16) * 16, 40)  # whole passes; the third starts at place 24
+    unwritten = got[moved if moved <= 32 else 40 :]
+    assert bool(jnp.all(unwritten == 0))
+
+
+def test_a_token_with_no_held_row_may_hold_nan_and_nothing_reads_it(monkeypatch):
+    """Token 1 chose absent experts alone: its slots are dead places of the
+    window, and with NaN in its row ``y`` and every gradient are what they
+    were — its own rows zero — forward and backward, 16 rows a pass."""
+    monkeypatch.setattr(layers, "_MOVE_ROWS", 16)
+    top_idx, first, bound = held_routing("a_token_with_none_held")
+    operands = held_operands()
+    poisoned = (operands[0].at[1].set(jnp.nan), *operands[1:])
+    probe = jnp.cos(jnp.arange(T_HELD * D_HELD, dtype=jnp.float32)).reshape(T_HELD, D_HELD)
+
+    @jax.jit
+    def run(*ops):
+        loss = lambda *ops: jnp.sum(moe_dropless_held(ops[0], top_idx, *ops[1:], first_expert=first, row_bound=bound)[0] * probe)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*ops)
+
+    (want, g_want), (got, g_got) = run(*operands), run(*poisoned)
+    assert float(got) == float(want)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_array_equal(a, b)
+    assert not g_got[0][1].any() and not g_got[1][1].any()
 
 
 def _every_aval(jaxpr):

@@ -488,7 +488,7 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer():
         routed, rows = [], []
         for share in range(8):
             cfg, lp, _, share_sizes = expert_layer(share)
-            y, (_, counts, held) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
+            y, (_, counts, held, _) = jax.jit(lambda lp, x, cfg=cfg: T._ffn_moe(lp, x, cfg))(lp, x)
             routed.append(y - shared)
             rows.append(int(held))
             mine = dict(whole, **{k: lp[k] for k in ("w_gate", "w_in", "w_out")})

@@ -917,7 +917,8 @@ def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig, gate=No
     :func:`_gate_ahead` chose ahead of the mixer — the router is then not run
     here, and its load is not said here. Returns (y, (balance term
     ``E·Σ_e f_e·P_e`` over the call's tokens, tokens per expert [E]) unless
-    ``gate`` is given, then under a share the rows held, under a ReLU the share
+    ``gate`` is given, then under a share the rows held and their share of the
+    window's slots (past 1: the layer took every window), under a ReLU the share
     of the computed rows' gate lanes it zeroed, under ``shared_expert_gate``
     that gate's mean: :func:`_moe_said` names them)."""
     b, s, d = x.shape
@@ -930,14 +931,16 @@ def _ffn_moe(lp: Dict[str, Any], x: jnp.ndarray, cfg: TransformerConfig, gate=No
     # an activation that leaves lanes exactly zero says how many
     act = dict(activation=_ACTIVATIONS[cfg.expert_activation], gate_zeros=cfg.expert_activation != "silu")
     if cfg.n_experts_held:
+        bound = _held_row_bound(cfg, b * s * cfg.top_k)
         y, held, *zeroed = moe_dropless_held(
             tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"],
             first_expert=cfg.expert_share_index * cfg.n_experts_held,
-            row_bound=_held_row_bound(cfg, b * s * cfg.top_k),
+            row_bound=bound,
             **act,
         )
         counts = None
-        more = (held, *zeroed)
+        # beside the rows held, their share of the window the row moves and grouped matmuls are sized for
+        more = (held, held.astype(jnp.float32) / bound, *zeroed)
     else:
         y, counts, *zeroed = moe_dropless(
             tokens, top_idx, top_w.astype(x.dtype), lp["w_gate"], lp["w_in"], lp["w_out"], **act
@@ -963,7 +966,7 @@ def _moe_said(cfg: TransformerConfig, aux, gate_ahead: bool = False) -> Dict[str
     """What :func:`_ffn_moe` says beside its output, by name (``gate_ahead``:
     it was handed its gate, and the router's load is :func:`_gate_ahead`'s to say)."""
     names = (
-        ("balance", "counts") * (not gate_ahead) + ("held",) * bool(cfg.n_experts_held)
+        ("balance", "counts") * (not gate_ahead) + ("held", "live_share") * bool(cfg.n_experts_held)
         + ("gate_zeros",) * (cfg.expert_activation != "silu") + ("shared_gate",) * cfg.shared_expert_gate
     )
     return dict(zip(names, aux))
@@ -2207,7 +2210,7 @@ def loss_and_stats(
     statistics are ``{}`` for a model without dropless experts, else
     ``tokens_per_expert`` [L, E] int32 and ``balance_loss`` (the mean over
     layers of E·Σ_e f_e·P_e, before the coefficient), under a share
-    ``rows_held`` [L], under ReLU-gated experts ``gate_zero_share`` [L], under
+    ``rows_held`` and ``window_live_share`` [L], under ReLU-gated experts ``gate_zero_share`` [L], under
     ``shared_expert_gate`` ``shared_gate_mean`` [L];
     of gdn mixers ``gdn_decay_min`` and ``gdn_beta_mean`` [their layers];
     of a looped stack with an exit gate ``exit_probs`` [T] (each exit's
@@ -2263,6 +2266,7 @@ def _loss_of_hidden(params: Dict[str, Any], x: jnp.ndarray, aux, tokens: jnp.nda
     stats.update(tokens_per_expert=aux["counts"], balance_loss=balance)
     if "held" in aux:  # under a share: the token-expert rows whose expert is held, a layer
         stats["rows_held"] = aux["held"]
+        stats["window_live_share"] = aux["live_share"]  # of the window's slots; past 1, the layer took every window
     if "gate_zeros" in aux:  # of the computed rows' gate lanes, the share a ReLU left zero, a layer
         stats["gate_zero_share"] = aux["gate_zeros"]
     if cfg.router_aux_loss_coef:

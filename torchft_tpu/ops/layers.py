@@ -343,21 +343,50 @@ def _rows_to_tokens(onehot: jnp.ndarray, rows: jnp.ndarray, sizes: jnp.ndarray, 
     return tiles.reshape(-1, rows.shape[1])[:t]
 
 
+# rows a pass of the loop that moves a window's live rows (:func:`_live_rows`)
+_MOVE_ROWS = 512
+
+
+def _live_rows(src: jnp.ndarray, idx: jnp.ndarray, n_live, rows_a_pass: int = 0) -> jnp.ndarray:
+    """``src[idx]`` -> [m, n] for the first ``n_live`` of ``idx``'s m places,
+    ``rows_a_pass`` (``_MOVE_ROWS`` unless given) a pass of a loop that ends
+    with the live ones — as the grouped matmuls' grids do — into a buffer
+    nothing has written (``jax.lax.empty``: the TPU allocates, no pass fills
+    it). The rows past the last pass stay whatever the memory held, and
+    nothing may read them: the contract the layer keeps for the grouped
+    matmul's output already. ``n_live`` None: one gather of all m places."""
+    if n_live is None:
+        return src.at[idx].get(mode="promise_in_bounds")
+    m = idx.shape[0]
+    step = min(rows_a_pass or _MOVE_ROWS, m)
+
+    def move(i, out):
+        start = jax.lax.min(i * step, m - step)  # the last pass of an m that is no whole number of them overlaps the one before
+        rows = src.at[jax.lax.dynamic_slice(idx, (start,), (step,))].get(mode="promise_in_bounds")
+        return jax.lax.dynamic_update_slice(out, rows, (start, 0))
+
+    # ``jax.lax`` arithmetic: the ``jax.numpy`` forms are jitted functions, whose equations a printed program shares by
+    # what the process traced before (the cells' pinned texts, tests/test_gdn_train.CELLS_PROGRAMS)
+    passes = jax.lax.div(n_live + (step - 1), step)
+    return jax.lax.fori_loop(0, passes, move, jax.lax.empty((m, src.shape[1]), src.dtype))
+
+
 def _in_token_order(rows: jnp.ndarray, plan) -> jnp.ndarray:
-    """A window's rows from expert order into token order ([m, n]): a gather
-    and nothing else, so the rows past the live ones stay whatever the
-    memory held — :func:`_rows_to_tokens` counts them in no tile."""
-    return rows.at[plan["from_place"]].get(mode="promise_in_bounds", unique_indices=True)
+    """A window's live rows from expert order into token order ([m, n]): a
+    move and nothing else (:func:`_live_rows`), so the rows past the live ones
+    stay whatever the memory held — :func:`_rows_to_tokens` counts them in no
+    tile."""
+    return _live_rows(rows, plan["from_place"], plan["n_live"])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _take_window_rows(x: jnp.ndarray, plan, t: int) -> jnp.ndarray:
     """``x[plan["token"]]``: the [m, d] rows of a window of row slots (see
-    :func:`_window_plan`), ordered by expert. The backward puts the rows'
-    gradients into token order, where a token's are one run of at most k, and
-    sums the runs (:func:`_rows_to_tokens`) — no scatter, and nothing wider
-    than the window."""
-    return x.at[plan["token"]].get(mode="promise_in_bounds")
+    :func:`_window_plan`), ordered by expert, the live ones moved and no
+    other (:func:`_live_rows`). The backward puts the rows' gradients into
+    token order, where a token's are one run of at most k, and sums the runs
+    (:func:`_rows_to_tokens`) — no scatter, and nothing wider than the window."""
+    return _live_rows(x, plan["token"], plan["n_live"])
 
 
 def _take_window_rows_fwd(x, plan, t):
@@ -392,27 +421,32 @@ def _combine_window_rows_bwd(t, saved, dy):
     out, plan = saved
     k = plan["onehot_copy"].shape[1]
     live = plan["live"][:, None]  # the live rows are the first of either order
-    g = dy.at[plan["token"]].get(mode="promise_in_bounds")
+    g = _live_rows(dy, plan["token"], plan["n_live"])
     d_out = jnp.where(live, plan["gate"][:, None].astype(g.dtype) * g, jnp.zeros_like(g))
     d_gate = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=1, keepdims=True)
     # a row's <out, dy> at its slot's copy j, as wide as a lane tile: the same
     # sum over runs puts it at [token, j]
     cells = jnp.where(live & plan["onehot_copy"], d_gate, 0.0).astype(dy.dtype)
     cells = jnp.pad(cells, ((0, 0), (0, -k % 128)))
-    d_top_w = _rows_to_tokens(plan["onehot"], _in_token_order(cells, plan), plan["tile_sizes"], t)
+    # the cells are a lane tile wide, a sixteenth of a row: one gather of the window's places, which XLA computes
+    # them inside of — a loop would have them written out first (+32 MB a layer's backward at 65 536 places, and the
+    # diffusion cell's step program stands within 30 MB of the chip's memory: PERF.md §6, PR 68)
+    cells = cells.at[plan["from_place"]].get(mode="promise_in_bounds", unique_indices=True)
+    d_top_w = _rows_to_tokens(plan["onehot"], cells, plan["tile_sizes"], t)
     return d_out, d_top_w[:, :k], None
 
 
 _combine_window_rows.defvjp(_combine_window_rows_fwd, _combine_window_rows_bwd)
 
 
-def _window_plan(slots: jnp.ndarray, gate: jnp.ndarray, n_live: jnp.ndarray, t: int, k: int):
+def _window_plan(slots: jnp.ndarray, gate: jnp.ndarray, n_live: jnp.ndarray, t: int, k: int, live_moves: bool = True):
     """What moving a window's rows needs, all of it index-sized ([m] or
     [m, 128]): ``slots`` [m] are row slots (slot ``t * k + j`` is token t's
     j-th copy) ordered by expert, the first ``n_live`` of them rows of held
     experts and in slot order inside an expert; ``gate`` [m] each slot's
     weight. Token order is slot order, so it takes one sort of m keys, which
-    carries each row's place and gate along."""
+    carries each row's place and gate along. ``live_moves``: the window's row
+    moves follow ``n_live`` (:func:`_live_rows`); else each is one gather of m."""
     m = slots.shape[0]
     place = jnp.arange(m, dtype=jnp.int32)
     live = place < n_live
@@ -427,6 +461,7 @@ def _window_plan(slots: jnp.ndarray, gate: jnp.ndarray, n_live: jnp.ndarray, t: 
         "gate": gate,  # by expert
         "onehot_copy": (slots % k)[:, None] == jnp.arange(k, dtype=slots.dtype),  # by expert, [m, k]
         "live": live,
+        "n_live": n_live if live_moves else None,  # the rows a move moves (:func:`_live_rows`)
         "from_place": from_place,  # by token: the row's place in expert order
         "gate_by_token": gate_by_token,
         "onehot": (token % _TOKEN_TILE)[:, None] == jnp.arange(_TOKEN_TILE, dtype=token.dtype),  # by token
@@ -469,7 +504,10 @@ def moe_dropless_held(
     rows or the T tokens, forward and backward: the window's rows are
     gathered by expert, computed, moved into token order (slot order: a
     token's rows are then one run of at most k) and the runs summed as a
-    grouped one-hot product (:func:`_rows_to_tokens`)."""
+    grouped one-hot product (:func:`_rows_to_tokens`). The four moves of rows
+    a layer, as the grouped matmuls, cost by the window's LIVE rows
+    (:func:`_live_rows`) in a step whose held rows fit the first window: at
+    balance half the window."""
     t, k = top_idx.shape
     held = w_gate.shape[0]
     m = min(row_bound, t * k)
@@ -488,7 +526,7 @@ def moe_dropless_held(
         # whole windows: the slots added are past every held row, and masked with them
         order, gates = (jnp.pad(x, (0, -(t * k) % m)) for x in (order, gates))
 
-    def window(start):
+    def window(start, live_moves=True):
         """(what the sorted slots ``[start, start + m)`` add to y, under
         ``gate_zeros`` the gate lanes of their held rows left zero, else ())."""
         with jax.named_scope("dispatch"):
@@ -501,6 +539,7 @@ def moe_dropless_held(
                 jnp.clip(n_held - start, 0, m),
                 t,
                 k,
+                live_moves,
             )
             rows = _take_window_rows(tokens, plan, t)  # [m, d]
             # each held expert's rows inside the window
@@ -515,8 +554,11 @@ def moe_dropless_held(
             return _combine_window_rows(out, top_w, plan, t), zeroed
 
     def every_window():
+        # a gather of m places a move, as before PR 68: with the moves' loops inside this scan too the diffusion
+        # cell's step program no longer fits the chip (over 15.75 GB by 5 MB; PERF.md §6, PR 68), and a step takes
+        # this branch only past the bound
         def add(total, start):
-            return jax.tree_util.tree_map(jnp.add, total, jax.checkpoint(window)(start)), None
+            return jax.tree_util.tree_map(jnp.add, total, jax.checkpoint(functools.partial(window, live_moves=False))(start)), None
 
         nothing = (jnp.zeros(tokens.shape, tokens.dtype), jnp.float32(0.0) if gate_zeros else ())
         return jax.lax.scan(add, nothing, jnp.arange(0, t * k, m))[0]

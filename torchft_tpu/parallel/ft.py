@@ -123,6 +123,9 @@ class FTTrainer:
             rows_routed=rows_routed,
             rows_held=int(np.asarray(stats["rows_held"]).sum()) if "rows_held" in stats else rows_routed,
         )
+        if "window_live_share" in stats:  # under a share: rows held over the window's slots, a layer (the row moves cost by it)
+            share = np.asarray(stats["window_live_share"])
+            counters.update(window_live_share=float(share.mean()), window_live_share_max=float(share.max()))
         if "gate_zero_share" in stats:  # ReLU-gated experts: of the computed rows' gate lanes, the share left zero
             counters["gate_zero_share"] = float(np.mean(stats["gate_zero_share"]))
         sync_span.set(**counters)
