@@ -25,7 +25,9 @@ softmax attention with RoPE (its output gated lane by lane under
 kinds with its own query heads over ``n_kv_heads`` grouped key/value heads
 and its own rotation), ``kda`` (gated delta-rule linear attention,
 ``ops/kda.py``), ``gdn`` (the same rule with one decay a head and key heads
-shared by value heads) or ``mla`` (latent attention: without positions, or with
+shared by value heads), ``ssd`` (a Mamba-2 state-space mixer, ``ssd_layers``: the same
+scalar decay with no correction, one B and one C a position for all heads,
+a ``D`` skip and a gated norm over all the channels) or ``mla`` (latent attention: without positions, or with
 the query's and the shared key's last lanes rotated under ``mla_rope_theta``;
 the query one projection or low-rank under ``q_lora_rank``) or ``conv`` (a
 gated causal short convolution: no query, key, score or state) — and a
@@ -70,7 +72,7 @@ from torchft_tpu.ops.attention import (
     ring_attention,
     ring_attention_local,
 )
-from torchft_tpu.ops.kda import gdn_chunked, gdn_core, kda_chunked, short_conv
+from torchft_tpu.ops.kda import gdn_chunked, gdn_core, kda_chunked, short_conv, ssd_chunked
 from torchft_tpu.ops.layers import (
     moe_dispatch,
     moe_dropless,
@@ -277,20 +279,45 @@ class TransformerConfig:
     diffusion_t_min: float = 1e-3
     diffusion_mask_id: int = -1  # -1 => the last row of the vocabulary held
     diffusion_seed: int = 0
+    # -- Mamba-2 state-space mixers (``ops/kda.ssd_chunked``): ``[z | xBC | dt] = h·ssd_in``, ``xBC`` through a causal
+    # depthwise convolution of ``conv_kernel`` taps WITH a bias and SiLU, split ``x`` [heads x head_dim], ``B`` and ``C``
+    # [ssd_state_dim each] — ONE of each a position for all heads (``ssd_n_groups`` 1); ``Δ = softplus(dt + dt_bias)`` a
+    # head, the state of a head [ssd_state_dim, ssd_head_dim]: ``H_t = exp(-Δ_t·exp(a_log))·H_{t-1} + Δ_t·B_t ⊗ x_t``,
+    # ``y_t = H_tᵀ·C_t + d_skip·x_t``; ``RMSNorm(y ⊙ SiLU(z))`` over ALL heads x head_dim channels under one plain
+    # weight, then ``·ssd_out``. No positions
+    ssd_layers: Tuple[int, ...] = ()
+    ssd_state_dim: int = 0
+    ssd_head_dim: int = 0
+    ssd_n_heads: int = 0
+    ssd_n_groups: int = 1
+    ssd_expand: int = 0  # the channels as a multiple of d_model, where a source states it: heads x head_dim is held to it
+    # -- four multipliers (Granite's), each 1 or 0 for what the program always did: the embedding's rows times
+    # ``embed_scale``; each of a layer's two branches times ``residual_scale`` where it joins the residual stream; a
+    # softmax layer's scores times ``attn_scale`` (0 => ``head_dim**-0.5``); the logits DIVIDED by ``logits_scale``
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0
+    logits_scale: float = 1.0
+    # the head reads the embedding table (logits = h·embedᵀ): there is no ``out`` leaf, and the table's gradient is
+    # the sum of the head's and the lookup's
+    tie_embeddings: bool = False
 
     def __post_init__(self) -> None:
         for name in (
             "kda_layers", "gdn_layers", "mla_layers", "conv_layers", "window_layers", "nope_layers", "n_heads_per_layer",
+            "ssd_layers",
         ):  # a JSON file gives lists
             object.__setattr__(self, name, tuple(int(i) for i in getattr(self, name)))
         named = (
             self.kda_layers + self.gdn_layers + self.mla_layers + self.conv_layers + self.window_layers + self.nope_layers
+            + self.ssd_layers
         )
         if len(set(named)) != len(named) or any(not 1 <= i <= self.n_layers for i in named):
             raise ValueError(
                 f"kda_layers {self.kda_layers}, gdn_layers {self.gdn_layers}, mla_layers {self.mla_layers}, "
-                f"conv_layers {self.conv_layers}, window_layers {self.window_layers} and "
-                f"nope_layers {self.nope_layers} name layers 1..{self.n_layers}, each at most once"
+                f"conv_layers {self.conv_layers}, window_layers {self.window_layers}, "
+                f"nope_layers {self.nope_layers} and ssd_layers {self.ssd_layers} name layers 1..{self.n_layers}, "
+                "each at most once"
             )
         if self.linear_n_key_heads and (not self.gdn_layers or self.linear_n_heads % self.linear_n_key_heads):
             raise ValueError(
@@ -358,6 +385,68 @@ class TransformerConfig:
             raise ValueError("exit_entropy_coef weighs the entropy of exit_gate's distribution: it comes with exit_gate")
         if self.diffusion_block:
             self._refuse_diffusion()
+        self._refuse_ssd()
+        self._refuse_multipliers()
+
+    def _refuse_ssd(self) -> None:
+        """What a state-space mixer cannot run with yet, each by name."""
+        if not self.ssd_layers:
+            return
+        if self.ssd_n_groups != 1:
+            raise ValueError(
+                f"ssd_n_groups={self.ssd_n_groups}: one B and one C a position serve all heads and one norm runs over all "
+                "channels; B and C a group of heads, and the gated norm a group (its statistics over a group's channels), "
+                "are missing"
+            )
+        if min(self.ssd_state_dim, self.ssd_head_dim, self.ssd_n_heads) < 1:
+            raise ValueError(
+                f"ssd_layers with ssd_state_dim={self.ssd_state_dim}, ssd_head_dim={self.ssd_head_dim}, "
+                f"ssd_n_heads={self.ssd_n_heads}: a state-space mixer has all three sizes"
+            )
+        if self.ssd_expand and self.ssd_n_heads * self.ssd_head_dim != self.ssd_expand * self.d_model:
+            raise ValueError(
+                f"ssd_n_heads={self.ssd_n_heads} x ssd_head_dim={self.ssd_head_dim} is not ssd_expand={self.ssd_expand} x "
+                f"d_model={self.d_model}: the heads are the channels"
+            )
+        if max(self.pp, 1) > 1:
+            raise ValueError(
+                f"pp={self.pp} with ssd layers: a stage's scan carries no state between microbatches and the pipeline's "
+                "manual region has not traced the mixer's block scan; a stage function for it is missing"
+            )
+        if self.ut_steps > 1:
+            raise ValueError(
+                f"ut_steps={self.ut_steps} with ssd layers: what a looped stack's checkpoints keep of the mixer's block "
+                "scan a loop step is not settled; a looped state-space stack is missing"
+            )
+        if self.n_mtp_modules:
+            raise ValueError(
+                "a multi-token-prediction module behind ssd layers: the module would be a state-space layer that starts "
+                "from an empty state at every step's position 0 of the shifted stream; what state it reads is not defined"
+            )
+
+    def _refuse_multipliers(self) -> None:
+        """Where the four multipliers and the tied table are not applied yet, each by name."""
+        scaled = self.embed_scale != 1.0 or self.residual_scale != 1.0 or self.logits_scale != 1.0 or self.attn_scale != 0.0
+        if not (scaled or self.tie_embeddings):
+            return
+        if self.attn_scale < 0 or self.logits_scale <= 0:
+            raise ValueError(
+                f"attn_scale={self.attn_scale}, logits_scale={self.logits_scale}: a scale on the scores and a divisor of "
+                "the logits are positive"
+            )
+        if max(self.pp, 1) > 1:
+            raise ValueError(
+                f"pp={self.pp} with embed_scale / residual_scale / attn_scale / logits_scale / tie_embeddings: the "
+                "pipeline's head (_pipelined_loss) reads its own ``out`` table on the last stage and the embedding is "
+                "looked up ahead of the first; a head that is handed the first stage's table, and the multipliers inside "
+                "the manual region, are missing"
+            )
+        if scaled and (self.n_mtp_modules or self.ut_steps > 1):
+            raise ValueError(
+                "embed_scale / residual_scale / attn_scale / logits_scale with a multi-token-prediction module or a looped "
+                "stack: whether the module's second embedding and each loop step's re-entry are scaled is not defined by "
+                "any source this program runs; the multipliers there are missing"
+            )
 
     def _refuse_diffusion(self) -> None:
         """What block-diffusion training cannot run with yet, each by name."""
@@ -376,7 +465,7 @@ class TransformerConfig:
         if other:
             raise ValueError(
                 f"diffusion_block={d} with {other} layers: only ``full`` softmax layers know the 2·S rows — positions that "
-                "repeat and the staircase mask; a band, a latent's cores, a recurrent state or a convolution over "
+                "repeat and the staircase mask; a band, a latent's cores, a recurrent or state-space state or a convolution over "
                 "[noised ; clean] (each noised block continuing the CLEAN prefix's state) are missing"
             )
         if self.n_mtp_modules:
@@ -447,7 +536,8 @@ class TransformerConfig:
                 else "mla" if i in self.mla_layers
                 else "conv" if i in self.conv_layers
                 else "window" if i in self.window_layers
-                else "nope" if i in self.nope_layers else "full"
+                else "nope" if i in self.nope_layers
+                else "ssd" if i in self.ssd_layers else "full"
             )
             ff = "experts" if self.n_experts and i > self.n_dense_layers else "dense"
             kinds.append((mixer, ff))
@@ -522,8 +612,9 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 
 
 # leaves that stay float32 under a narrower compute dtype: the decay's two
-# parameters (a rounded log-decay is another model) and the selection bias
-_F32_LEAVES = ("a_log", "dt_bias", "router_bias")
+# parameters (a rounded log-decay is another model), the selection bias and a
+# state-space mixer's skip
+_F32_LEAVES = ("a_log", "dt_bias", "router_bias", "d_skip")
 
 
 def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[int, ...]) -> Dict[str, Any]:
@@ -630,6 +721,25 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
             conv_w=dense(next(more), taps, d, fan_in=taps),
             conv_out=dense(next(more), d, d, fan_in=d),
         )
+    elif mixer == "ssd":
+        taps, heads = cfg.conv_kernel, cfg.ssd_n_heads
+        inner = heads * cfg.ssd_head_dim
+        mixed = inner + 2 * cfg.ssd_state_dim  # what the convolution runs over: x | B | C
+        layers.update(
+            ssd_in=dense(next(more), d, inner + mixed + heads, fan_in=d),  # z | x B C | dt along the features
+            conv_w=dense(next(more), taps, mixed, fan_in=taps),
+            # a depthwise convolution's bias as its published class draws it: uniform within 1/sqrt(taps)
+            conv_b=jax.random.uniform(next(more), lead + (mixed,), jnp.float32, -(taps**-0.5), taps**-0.5),
+            # Mamba-2's published initial values: exp(a_log) uniform in [1, 16], softplus(dt_bias) log-uniform in
+            # [1e-3, 1e-1], the skip 1 — a head forgets between a thousandth of a nat and 1.6 nats a position
+            a_log=jnp.log(jax.random.uniform(next(more), lead + (heads,), jnp.float32, 1.0, 16.0)),
+            dt_bias=_inv_softplus(jnp.exp(jax.random.uniform(
+                next(more), lead + (heads,), jnp.float32, np.log(1e-3), np.log(1e-1)
+            ))),
+            d_skip=ones(heads),
+            y_norm=ones(inner),
+            ssd_out=dense(next(more), inner, d, fan_in=inner),
+        )
     else:
         raise ValueError(f"unknown mixer {mixer!r}")
     if ff == "experts":
@@ -685,7 +795,8 @@ def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
     (``enorm``, ``hnorm``), ``eh_proj`` [2d, d], ``layer`` (the leaves of one
     layer of the last layer's kind, no leading axis) and its ``final_norm``.
     ``sandwich_norm`` adds ``post_ln1`` and ``post_ln2`` to every layer,
-    ``exit_gate`` the float32 ``exit_gate``: ``w`` [d, 1] and ``b`` [1]."""
+    ``exit_gate`` the float32 ``exit_gate``: ``w`` [d, 1] and ``b`` [1].
+    ``tie_embeddings``: no ``out`` leaf."""
     keys = jax.random.split(rng, 16)
     d = cfg.d_model
 
@@ -695,10 +806,14 @@ def init_params(rng, cfg: TransformerConfig) -> Dict[str, Any]:
         )
 
     params: Dict[str, Any] = {
-        "embed": dense(keys[8], cfg.vocab_size, d, fan_in=1.0),
+        # a tied table is a head's too: rows of unit length, as ``out``'s columns are — at normal(0, 1) a row the head
+        # would score a position's own id at d / logits_scale (a loss of 255 at Granite's sizes, past bfloat16's steps)
+        "embed": dense(keys[8], cfg.vocab_size, d, fan_in=float(d) if cfg.tie_embeddings else 1.0),
         "final_norm": _unit_weight(cfg, (d,)),
         "out": dense(keys[9], d, cfg.vocab_size, fan_in=d),
     }
+    if cfg.tie_embeddings:
+        del params["out"]  # the head reads ``embed``
     if cfg.n_mtp_modules:
         key = jax.random.fold_in(rng, 201)
         params["mtp"] = {
@@ -766,6 +881,13 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
         )
     elif mixer == "conv":
         layers.update(conv_in=row, conv_w=spec(None, "tp"), conv_out=col)  # channels over tp
+    elif mixer == "ssd":
+        # whole over tp: one norm runs over all the channels and B and C serve all heads, so a share of the heads
+        # would need the other chips' sum of squares — the partitioner keeps it correct, nothing here divides it
+        layers.update(
+            ssd_in=spec("fsdp", None), conv_w=spec(None, None), conv_b=spec(None), a_log=spec(None), dt_bias=spec(None),
+            d_skip=spec(None), y_norm=spec(None), ssd_out=spec(None, "fsdp"),
+        )
     else:
         # the narrow side of both low-rank pairs whole, heads over tp
         layers.update(w_kva=spec("fsdp", None), kv_norm=spec(None), w_kvb=spec(None, "tp"), wo=col)
@@ -803,6 +925,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "final_norm": P(None),
         "out": P("fsdp", "tp"),
     }
+    if cfg.tie_embeddings:
+        del specs["out"]
     if cfg.n_mtp_modules:
         specs["mtp"] = {
             "enorm": P(None), "hnorm": P(None), "eh_proj": P("fsdp", "tp"),
@@ -1175,6 +1299,8 @@ def _attention_path(
     divide a half) or it is asked for, else plain attention under the dense
     staircase mask — the ring and the chunked scan do not know the rule."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    if batch == 0:  # a batch of one sequence sliced past its end (benchmark/worker.py's second sequence): no kernel has a grid of none
+        return "plain", "an empty batch", None
     if diffusion:
         return _diffusion_path(cfg, seq_len, batch, mesh, sp_manual, sp_size)
     if sp_size > 1 and (window or grouped):
@@ -1315,6 +1441,17 @@ def _say_gdn_core_path(core: str, batch: int, block: int, cfg: TransformerConfig
     _say_once("gdn_core_path", tuple(fields.values()), **fields)
 
 
+def _say_ssd_core_path(batch: int, block: int, cfg: TransformerConfig) -> None:
+    """One ``ssd_core_path`` event and one INFO line per traced shape of a
+    state-space mixer: the form ``ops/kda.ssd_chunked`` takes for a block of it
+    (``jax.numpy``: there is no kernel yet), its chunk, heads, state and block."""
+    fields = dict(
+        core="jax.numpy", heads=cfg.ssd_n_heads, head_dim=cfg.ssd_head_dim, state=cfg.ssd_state_dim,
+        groups=cfg.ssd_n_groups, chunk=min(_SSD_CHUNK, block), batch=batch, block=block,
+    )
+    _say_once("ssd_core_path", tuple(fields.values()), **fields)
+
+
 def _say_expert_path(cfg: TransformerConfig, batch: int, seq_len: int) -> None:
     """One ``expert_path`` event and INFO line per traced shape of a model whose
     expert layers take their gate from the layer's input or gate with another
@@ -1343,6 +1480,11 @@ def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None
         fields.update(mtp=_kind_key(kinds[-1]), mtp_weight=cfg.mtp_loss_weight)
     if cfg.conv_layers:
         fields.update(conv_kernel=cfg.conv_kernel)
+    if cfg.ssd_layers:
+        fields.update(
+            ssd_heads=cfg.ssd_n_heads, ssd_head_dim=cfg.ssd_head_dim, ssd_state=cfg.ssd_state_dim, conv_kernel=cfg.conv_kernel,
+            tied=cfg.tie_embeddings,
+        )
     _say_once("layer_pattern", tuple(fields.values()), **fields)
 
 
@@ -1479,6 +1621,8 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
         if rotation is not None:
             q = rotary_embed(q, positions, **rotation)
             k = rotary_embed(k, positions, **rotation)
+        if cfg.attn_scale:  # every core scales by head_dim**-0.5: the query carries the rest (a power of two here: exact)
+            q = q * jnp.asarray(cfg.attn_scale * cfg.head_dim**0.5, q.dtype)
         said = {}
         if name:
             rotated = (
@@ -1667,6 +1811,72 @@ def _mix_gdn(cfg, lp, h):
         return jnp.moveaxis(out, 0, 1).reshape(b, s, d), stats
 
 
+# Positions of a chunk of a state-space mixer's core (``ops/kda.ssd_chunked``); the result does not depend on it. A
+# chunk's pairs cost chunk x head_dim operations a position a head, its two products with the state 2 x state x
+# head_dim whatever the chunk — but in the ``jax.numpy`` form the core's time is the pairs' elementwise passes over
+# [heads, chunk, chunk] float32: 128 read 0.570 s a device step at Granite's widths on a v5e, 64 0.606, 256 0.710
+# (PERF.md §6, PR 69). ``benchmark/opcounts/granite_hybrid.py`` counts at the same number
+_SSD_CHUNK = 128
+
+
+def _mix_ssd(cfg, lp, h):
+    """A Mamba-2 state-space mixer (``TransformerConfig.ssd_layers`` has the
+    equations). Blocks of the sequence under a ``lax.scan`` that carries the
+    state and the convolution's taps, each under its own ``jax.checkpoint``, as
+    :func:`_mix_gdn` — but EVERYTHING of the mixer is a block's, the wide
+    product of the layer's input ``[z | x B C]`` too: kept outside the
+    checkpoint, as ``_mix_gdn`` keeps its q, k, v, it is 138 MB a layer at
+    8 192 positions, 1.25 GB over nine mixers that a 772 M-parameter state does
+    not leave (PERF.md §6, PR 69), and computed again in the backward it is
+    what every checkpointed layer does with its projections. The 64 columns of
+    ``dt`` are a float32 product of their own (a decay's input rounded to
+    bfloat16 is another decay). Scopes inside ``ssd``: ``ssd_in``, ``conv``,
+    ``gates``, ``ssd_core``, ``gated_norm`` (the skip and the gate with it),
+    ``ssd_out``. Returns (y, by name: ``ssd_decay_min`` the least log-decay of a
+    position, ``ssd_dt_mean`` the mean step, ``ssd_state_rms`` of the final state)."""
+    b, s, d = h.shape
+    heads, hd, n_state, taps = cfg.ssd_n_heads, cfg.ssd_head_dim, cfg.ssd_state_dim, cfg.conv_kernel
+    inner = heads * hd
+    mixed = inner + 2 * n_state
+    f32 = jnp.float32
+    blk = _KDA_BLOCK if s % _KDA_BLOCK == 0 else s
+
+    def block(carry, hb):
+        state, before = carry  # [B, H, N, P] float32; [B, K-1, mixed]: x | B | C ahead of the convolution
+        with jax.named_scope("ssd_in"):
+            zx = hb @ lp["ssd_in"][:, : inner + mixed]  # [B, blk, inner + mixed]: z | x B C of these positions
+        z, mix = zx[..., :inner], zx[..., inner:]
+        with jax.named_scope("conv"):
+            xbc = jax.nn.silu(short_conv(mix, lp["conv_w"], before) + lp["conv_b"])
+            x = xbc[..., :inner].reshape(b, blk, heads, hd)
+            b_in, c_out = xbc[..., inner : inner + n_state], xbc[..., inner + n_state :]
+        with jax.named_scope("gates"):
+            dt = jnp.dot(hb, lp["ssd_in"][:, inner + mixed :], preferred_element_type=f32)
+            delta = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))  # [B, blk, H]
+            g = -jnp.exp(lp["a_log"].astype(f32)) * delta
+            v = (x * delta[..., None]).astype(x.dtype)
+        _say_ssd_core_path(b, blk, cfg)
+        with jax.named_scope("ssd_core"):
+            y, state = ssd_chunked(c_out, b_in, v, g, chunk=min(_SSD_CHUNK, blk), initial_state=state)
+        with jax.named_scope("gated_norm"):
+            y = y.astype(f32) + lp["d_skip"].astype(f32)[:, None] * x
+            # the gate goes in BEFORE the norm, and the norm's input stays float32: rounded once, after the weight
+            y = rms_norm(y.reshape(b, blk, inner) * jax.nn.silu(z.astype(f32)), lp["y_norm"], cfg.norm_eps).astype(hb.dtype)
+        with jax.named_scope("ssd_out"):
+            out = y @ lp["ssd_out"]
+        return (state, mix[:, blk - (taps - 1) :]), (out, jnp.min(g, initial=0.0), jnp.mean(delta))  # a log-decay is <= 0
+
+    with jax.named_scope("ssd"):
+        start = (jnp.zeros((b, heads, n_state, hd), f32), jnp.zeros((b, taps - 1, mixed), h.dtype))
+        blocks = jnp.moveaxis(h.reshape(b, s // blk, blk, d), 1, 0)
+        (state, _), (out, decay_min, dt_mean) = jax.lax.scan(jax.checkpoint(block), start, blocks)
+        stats = {
+            "ssd_decay_min": jnp.min(decay_min), "ssd_dt_mean": jnp.mean(dt_mean),
+            "ssd_state_rms": jnp.sqrt(jnp.mean(jax.lax.stop_gradient(state) ** 2)),
+        }
+        return jnp.moveaxis(out, 0, 1).reshape(b, s, d), stats
+
+
 def _mix_conv(lp, h):
     """A gated short convolution: ``[B, C, X] = h·conv_in`` (in this order
     along the features), ``y = C ⊙ conv(B ⊙ X)`` with ``conv`` the causal
@@ -1701,7 +1911,7 @@ def _make_layer_fn(
     """The function of one layer of ``kind`` (mixer, feed-forward); absent:
     the one kind a model of one kind has. ``remat_parts``: ``jax.checkpoint``
     (``cfg.remat``) around the mixer and around the feed-forward, each by
-    itself, where the caller puts none around the layer — but a ``kda`` or ``gdn`` mixer,
+    itself, where the caller puts none around the layer — but a ``kda``, ``gdn`` or ``ssd`` mixer,
     which checkpoints itself block by block (:data:`_KDA_BLOCK`): a second one
     around it would run its forward a third time. ``nested``: a name every op
     of the layer carries INSIDE its top-level scope (``attn/<nested>/...``: the
@@ -1747,7 +1957,7 @@ def _make_layer_fn(
             "dropless experts' grouped matmul (ops/layers._grouped_matmul) is a Pallas call, which the pipeline's "
             "manual region cannot trace; a grouped matmul typed for that region (its outputs' varying mesh axes) is missing"
         )
-    if mixer in ("kda", "gdn") and sp_size > 1:
+    if mixer in ("kda", "gdn", "ssd") and sp_size > 1:
         raise ValueError(
             f"sp={sp_size} with a {mixer} layer: the recurrent state at a sequence shard's start is the "
             "state at the end of the shard before it; the hand-over of that state (and of the "
@@ -1794,10 +2004,17 @@ def _make_layer_fn(
     def feed(fn):
         return of_input(normed(fn, "post_ln2"), "ln2")
 
+    def join(x, y):
+        """The residual stream plus a branch's output, under ``residual_scale`` the branch times it: the product and
+        the sum in float32, rounded once."""
+        if cfg.residual_scale == 1.0:
+            return x + y
+        return (x.astype(jnp.float32) + cfg.residual_scale * y.astype(jnp.float32)).astype(x.dtype)
+
     def layer_fn(x: jnp.ndarray, lp: Dict[str, Any]):
         """(x, aux): aux by name — what :func:`_ffn_moe` says of a dropless
         expert layer (:func:`_moe_said`), what :func:`_mix_gdn` says of its
-        mixer — {} otherwise."""
+        or :func:`_mix_ssd` of its mixer — {} otherwise."""
         aux, gate = {}, {}
         x = _constrain(x, _act_spec(sp_manual))
         if ff == "experts" and (gate_ahead or cfg.expert_activation != "silu"):
@@ -1812,26 +2029,26 @@ def _make_layer_fn(
                 gate, aux = {"gate": chosen}, dict(zip(("balance", "counts"), load))
         with _scopes("attn", nested):
             if mixer in _SOFTMAX_MIXERS:
-                x = x + part(mix(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer)))(lp, h)
+                x = join(x, part(mix(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer)))(lp, h))
             elif mixer == "kda":
-                x = x + mix(functools.partial(_mix_kda, cfg))(lp, h)
-            elif mixer == "gdn":
-                y, said = mix(functools.partial(_mix_gdn, cfg))(lp, h)
-                x, aux = x + y, {**aux, **said}
+                x = join(x, mix(functools.partial(_mix_kda, cfg))(lp, h))
+            elif mixer in ("gdn", "ssd"):
+                y, said = mix(functools.partial(_mix_gdn if mixer == "gdn" else _mix_ssd, cfg))(lp, h)
+                x, aux = join(x, y), {**aux, **said}
             elif mixer == "conv":
-                x = x + part(mix(_mix_conv))(lp, h)
+                x = join(x, part(mix(_mix_conv))(lp, h))
             else:
-                x = x + part(mix(functools.partial(_mix_mla, cfg, mesh, sp_manual)))(lp, h)
+                x = join(x, part(mix(functools.partial(_mix_mla, cfg, mesh, sp_manual)))(lp, h))
 
         with _scopes("moe" if ff == "experts" else "ffn", nested):
             h = x if from_input else _norm(cfg, x, lp["ln2"])
             if experts_over_chips:
-                x = x + feed(functools.partial(_ffn_moe_ep, cfg=cfg))(lp, h)
+                x = join(x, feed(functools.partial(_ffn_moe_ep, cfg=cfg))(lp, h))
             elif ff == "experts":
                 y, said = part(feed(functools.partial(_ffn_moe, cfg=cfg)))(lp, h, **gate)
-                x, aux = x + y, {**aux, **_moe_said(cfg, said, gate_ahead)}
+                x, aux = join(x, y), {**aux, **_moe_said(cfg, said, gate_ahead)}
             else:
-                x = x + part(feed(_ffn_dense))(lp, h)
+                x = join(x, part(feed(_ffn_dense))(lp, h))
         return _constrain(x, _act_spec(sp_manual)), aux
 
     return layer_fn
@@ -1947,7 +2164,7 @@ def _pipeline_stage_fn(cfg: TransformerConfig, mesh, sp_manual: bool):
 
 
 def _embed_lookup(
-    params: Dict[str, Any], tokens: jnp.ndarray, dt, nested: Optional[str] = None
+    params: Dict[str, Any], tokens: jnp.ndarray, dt, nested: Optional[str] = None, scale: float = 1.0
 ) -> jnp.ndarray:
     """Embedding gather with EXPLICIT gather partitioning (round-3 review
     missing #2): the table is stored P(None, ("tp","fsdp")) — vocab
@@ -1955,9 +2172,12 @@ def _embed_lookup(
     (SPMD cannot partition a vocab-sharded gather and previously fell
     back to "involuntary full rematerialization", replicating [V,D] on
     every device each step). Only the (much smaller) [B,S,D] activation
-    is resharded to the standard spec afterwards."""
+    is resharded to the standard spec afterwards. ``scale``
+    (``embed_scale``) multiplies the float32 table ahead of its cast, so a row
+    is rounded once."""
     with _scopes("embed", nested):
-        embed = _constrain(params["embed"].astype(dt), P(None, ("tp", "fsdp")))
+        table = params["embed"] if scale == 1.0 else params["embed"] * scale
+        embed = _constrain(table.astype(dt), P(None, ("tp", "fsdp")))
         tok = _constrain(tokens, P("dp", "sp"))
         x = jnp.take(embed, tok, axis=0)
         # reshard to the activation spec ONE axis move per step — GSPMD
@@ -2120,7 +2340,7 @@ def _hidden_states(
     b, s = tokens.shape
     dt = cfg.dtype
     _refuse_loop(cfg)
-    x = _embed_lookup(params, _diffusion_rows(tokens, cfg), dt)
+    x = _embed_lookup(params, _diffusion_rows(tokens, cfg), dt, scale=cfg.embed_scale)
 
     pp = max(cfg.pp, 1)
     aux = {}
@@ -2179,7 +2399,19 @@ def forward(
     x, _ = _hidden_states(params, tokens, cfg, mesh)
     if cfg.ut_steps > 1:
         x = x[-1]
-    return (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
+    return _scaled_logits(cfg, (x @ _out_table(params, cfg).astype(cfg.dtype)).astype(jnp.float32))
+
+
+def _out_table(params: Dict[str, Any], cfg: TransformerConfig) -> jnp.ndarray:
+    """The head's table [d, V]: the ``out`` leaf, or under ``tie_embeddings``
+    the embedding's transpose — the head's cotangent then arrives as one
+    float32 [d, V] array, and autodiff adds its transpose to the lookup's."""
+    return params["embed"].T if cfg.tie_embeddings else params["out"]
+
+
+def _scaled_logits(cfg: TransformerConfig, logits: jnp.ndarray) -> jnp.ndarray:
+    """Float32 logits divided by ``logits_scale``; at 1 the array itself."""
+    return logits if cfg.logits_scale == 1.0 else logits / cfg.logits_scale
 
 
 def loss_fn(
@@ -2212,7 +2444,10 @@ def loss_and_stats(
     layers of E·Σ_e f_e·P_e, before the coefficient), under a share
     ``rows_held`` and ``window_live_share`` [L], under ReLU-gated experts ``gate_zero_share`` [L], under
     ``shared_expert_gate`` ``shared_gate_mean`` [L];
-    of gdn mixers ``gdn_decay_min`` and ``gdn_beta_mean`` [their layers];
+    of gdn mixers ``gdn_decay_min`` and ``gdn_beta_mean`` [their layers]; of
+    state-space mixers ``ssd_decay_min`` (the least log-decay of a position)
+    and ``ssd_dt_mean`` [their layers] and ``ssd_state_rms`` (of the last
+    mixer's final state): which numerical regime a run is in;
     of a looped stack with an exit gate ``exit_probs`` [T] (each exit's
     probability, mean over the supervised tokens), ``exit_entropy`` and
     ``loss_by_step`` [T] (each exit's own cross entropy); under block
@@ -2257,9 +2492,14 @@ def _loss_of_hidden(params: Dict[str, Any], x: jnp.ndarray, aux, tokens: jnp.nda
         aux = {**aux, **{k: jnp.concatenate([aux[k], m[None]]) for k, m in mtp_aux.items()}} if aux else mtp_aux
         stats = {"main_loss": ce, "mtp_loss": mtp_ce}
         ce = ce + cfg.mtp_loss_weight * mtp_ce
-    for name, said in (("decay_min", "gdn_decay_min"), ("beta_mean", "gdn_beta_mean"), ("shared_gate", "shared_gate_mean")):
+    for name, said in (
+        ("decay_min", "gdn_decay_min"), ("beta_mean", "gdn_beta_mean"), ("shared_gate", "shared_gate_mean"),
+        ("ssd_decay_min", "ssd_decay_min"), ("ssd_dt_mean", "ssd_dt_mean"),
+    ):
         if name in aux:
             stats[said] = aux[name]
+    if "ssd_state_rms" in aux:  # of the LAST state-space mixer's final state
+        stats["ssd_state_rms"] = aux["ssd_state_rms"][-1]
     if "balance" not in aux:
         return ce, stats
     balance = jnp.mean(aux["balance"])
@@ -2299,9 +2539,14 @@ def cuts_by_layer(cfg: TransformerConfig) -> bool:
     """Whether the backward can be cut where the stack's scan iterates
     (:func:`grads_chain`): layers of one kind scanned once under one stage,
     and nothing beside the head that reads the stack's last state. A declared
-    pattern, a looped stack, ``pp`` > 1 and a multi-token-prediction module
-    stay ONE program."""
-    return _of_one_kind(cfg) and max(cfg.pp, 1) == 1 and cfg.ut_steps == 1 and not cfg.n_mtp_modules
+    pattern, a looped stack, ``pp`` > 1, a multi-token-prediction module and a
+    tied table (the head's piece and the tail's would be two gradients of ONE
+    leaf, and the chain hands each leaf's on when ITS call ends) stay ONE
+    program. Layers of one kind that are ``ssd`` cut like any other."""
+    return (
+        _of_one_kind(cfg) and max(cfg.pp, 1) == 1 and cfg.ut_steps == 1 and not cfg.n_mtp_modules
+        and not cfg.tie_embeddings
+    )
 
 
 def _closure_own(vjp_fn, inputs):
@@ -2345,12 +2590,17 @@ def grads_chain(cfg: TransformerConfig, mesh=None):
     Returns ``(head, layer, tail, spec)``; ``spec`` is the partition of the
     cotangent the calls hand on — the activations' own, as the layers
     constrain them."""
+    if not cuts_by_layer(cfg):
+        raise ValueError(
+            "grads_chain: this stack does not cut by layer (cuts_by_layer) — a declared pattern, a loop, pp > 1, a "
+            "multi-token-prediction module or tie_embeddings (two pieces of one leaf's gradient) stays one program"
+        )
     layer_fn = _remat(cfg, _make_layer_fn(cfg, mesh))
     dt = cfg.dtype
     row = lambda a, l: jax.lax.dynamic_index_in_dim(a, l, keepdims=False)
 
     def head(params, tokens):
-        x = _embed_lookup(params, _diffusion_rows(tokens, cfg), dt)
+        x = _embed_lookup(params, _diffusion_rows(tokens, cfg), dt, scale=cfg.embed_scale)
         stage = jax.tree_util.tree_map(lambda a: a[0], _compute_dtype(params["layers"], dt))
 
         def body(x, lp):
@@ -2386,7 +2636,7 @@ def grads_chain(cfg: TransformerConfig, mesh=None):
         return jax.tree_util.tree_map(lambda g, p: g.astype(p.dtype)[None, None], d_lp, stored), dx
 
     def tail(embed, tokens, dx):
-        _, vjp_fn = jax.vjp(lambda e: _embed_lookup({"embed": e}, _diffusion_rows(tokens, cfg), dt), embed)
+        _, vjp_fn = jax.vjp(lambda e: _embed_lookup({"embed": e}, _diffusion_rows(tokens, cfg), dt, scale=cfg.embed_scale), embed)
         return {"embed": vjp_fn(dx)[0]}
 
     return head, layer, tail, _act_spec()
@@ -2467,7 +2717,7 @@ def _cross_entropy(
     if sp == 1 and _per_device_logit_elems(cfg, b, s, mesh) > _LOSS_CHUNK_ELEMS:
         return _chunked_loss(params, x, tokens, cfg, mesh, ahead, probs)
     with _scopes("head_loss", nested):
-        logits = (x @ params["out"].astype(cfg.dtype)).astype(jnp.float32)
+        logits = _scaled_logits(cfg, (x @ _out_table(params, cfg).astype(cfg.dtype)).astype(jnp.float32))
         targets = jnp.roll(tokens, -ahead, axis=1)
         logprobs = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logprobs, targets[..., None], axis=-1)[..., 0]
@@ -2568,26 +2818,32 @@ def _chunked_loss(
     hs = jnp.moveaxis(h.reshape(b, n_chunks, chunk, -1), 1, 0)
     ts = jnp.moveaxis(targets.reshape(b, n_chunks, chunk), 1, 0)
     ms = jnp.moveaxis(mask.reshape(b, n_chunks, chunk), 1, 0)
+    out = _out_table(params, cfg)
+    # a divisor of the logits other than 1 goes into the chunks as one more (undifferentiated) argument; at 1 it is
+    # absent, and the call and its program are what they were
+    scale = None if cfg.logits_scale == 1.0 else jnp.float32(cfg.logits_scale)
     if probs is not None:
         ps = jnp.moveaxis(jnp.pad(probs, ((0, 0), (0, pad))).reshape(b, n_chunks, chunk), 1, 0)
         with jax.named_scope("head_loss"):
-            loss, nll = _chunked_nll(hs, params["out"], ts, ms, ps)
+            loss, nll = _chunked_nll(hs, out, ts, ms, ps, scale)
         return loss, jnp.moveaxis(nll, 0, 1).reshape(b, n_chunks * chunk)[:, :s]
     if ahead > 1:
         with _scopes("head_loss", _MTP):
-            return _chunked_nll_mtp(hs, params["out"], ts, ms)
+            return _chunked_nll_mtp(hs, out, ts, ms)
     with jax.named_scope("head_loss"):
-        return _chunked_nll(hs, params["out"], ts, ms)
+        return _chunked_nll(hs, out, ts, ms, None, scale)
 
 
-def _chunk_nll(h_c, out_w, t_c, w_c):
-    """One chunk's f32 logits, their log-sum-exp, each position's NLL, and
-    the sum of those under the weights ``w_c``."""
+def _chunk_nll(h_c, out_w, t_c, w_c, scale=None):
+    """One chunk's f32 logits (divided by ``scale`` where given), their
+    log-sum-exp, each position's NLL, and the sum of those under the weights ``w_c``."""
     logits = h_c @ out_w
     # the target's logit is picked BEFORE the cast (the same number): picked
     # after it, XLA keeps an f32 copy of the chunk's logits for the gather
     target = jnp.take_along_axis(logits, t_c[..., None], axis=-1).astype(jnp.float32)
     logits = logits.astype(jnp.float32)
+    if scale is not None:
+        target, logits = target / scale, logits / scale
     mx = jnp.max(logits, axis=-1, keepdims=True)
     lse = jnp.log(jnp.sum(jnp.exp(logits - mx), axis=-1, keepdims=True)) + mx
     nll = (lse - target)[..., 0]
@@ -2600,7 +2856,7 @@ def _chunk_weights(m_c, p_c):
 
 
 @jax.custom_vjp
-def _chunked_nll(hs, out, ts, ms, ps=None):
+def _chunked_nll(hs, out, ts, ms, ps=None, scale=None):
     """Mean masked NLL of hidden-state chunks ``hs`` [n, B, c, d] through
     the unembed ``out`` [d, V] (cast to ``hs.dtype`` here, so its gradient
     arrives in ``out``'s own dtype). Called plainly it is the forward scan
@@ -2614,12 +2870,14 @@ def _chunked_nll(hs, out, ts, ms, ps=None):
     result is ``(sum(nll * ms * ps) / sum(ms), nll [n, B, c])``. ``ps`` carries
     a cotangent — ``nll * ms / sum(ms)``, which the forward scan already has —
     and the second result, a statistic, carries none. Without ``ps`` the
-    program is what it was before weights existed, to the letter."""
+    program is what it was before weights existed, to the letter. ``scale``
+    (a float32 scalar, ``logits_scale``): the chunks' float32 logits are divided
+    by it, and their cotangent with them; it carries no cotangent itself."""
     out_w = out.astype(hs.dtype)
 
     def body(nll_sum, xt):
         h_c, t_c, m_c, p_c = xt
-        _, _, nll_c, total = _chunk_nll(h_c, out_w, t_c, _chunk_weights(m_c, p_c))
+        _, _, nll_c, total = _chunk_nll(h_c, out_w, t_c, _chunk_weights(m_c, p_c), scale)
         return nll_sum + total, None if p_c is None else nll_c
 
     nll_sum, nll = jax.lax.scan(body, jnp.float32(0.0), (hs, ts, ms, ps))
@@ -2627,7 +2885,7 @@ def _chunked_nll(hs, out, ts, ms, ps=None):
     return loss if ps is None else (loss, nll)
 
 
-def _chunked_nll_fwd(hs, out, ts, ms, ps=None):
+def _chunked_nll_fwd(hs, out, ts, ms, ps=None, scale=None):
     out_w = out.astype(hs.dtype)
     cnt = jnp.sum(ms)
 
@@ -2635,11 +2893,13 @@ def _chunked_nll_fwd(hs, out, ts, ms, ps=None):
         h_c, t_c, m_c, p_c = xt
         w_c = _chunk_weights(m_c, p_c)
         nll_sum, d_out = carry
-        logits, lse, nll_c, total = _chunk_nll(h_c, out_w, t_c, w_c)
+        logits, lse, nll_c, total = _chunk_nll(h_c, out_w, t_c, w_c, scale)
         onehot = jax.nn.one_hot(t_c, logits.shape[-1], dtype=logits.dtype)
         # in the dtype the transposed products of `(h_c @ out_w).astype(f32)`
         # read: that cast's cotangent is cast back to the compute dtype
-        dlogits = ((jnp.exp(logits - lse) - onehot) * (w_c / cnt)[..., None]).astype(h_c.dtype)
+        # (the probabilities first and the weight after them: the order the program's text always had)
+        off = jnp.exp(logits - lse) - onehot
+        dlogits = (off * (w_c / cnt if scale is None else w_c / (cnt * scale))[..., None]).astype(h_c.dtype)
         dh_c = jnp.einsum("bcv,dv->bcd", dlogits, out_w)
         # summed over the chunks in f32, so `d out` is rounded once and not
         # once a chunk: 1.2 ms of a 67 ms head at b8 x s2048 on a v5e
@@ -2658,13 +2918,13 @@ def _chunked_nll_fwd(hs, out, ts, ms, ps=None):
 
 
 def _head_cotangents(res, g):
-    """(hs', out', None, None, ps') of :func:`_chunked_nll` from what its
-    forward scan kept; under weights ``g`` is the pair's and the statistic's
-    part of it is dropped."""
+    """(hs', out', None, None, ps', None) of :func:`_chunked_nll` from what
+    its forward scan kept; under weights ``g`` is the pair's and the
+    statistic's part of it is dropped."""
     dhs, d_out, d_ps = res
     if d_ps is not None:
         g = g[0]
-    return (g * dhs).astype(dhs.dtype), (g * d_out).astype(d_out.dtype), None, None, None if d_ps is None else g * d_ps
+    return (g * dhs).astype(dhs.dtype), (g * d_out).astype(d_out.dtype), None, None, None if d_ps is None else g * d_ps, None
 
 
 def _chunked_nll_bwd(res, g):

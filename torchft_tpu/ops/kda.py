@@ -70,6 +70,15 @@ heads (Gated DeltaNet) — picks the same way (:func:`gdn_core` says which):
   PR 55);
 * every other call is :func:`_chunked` with the key heads repeated and the
   pairs by :func:`_scalar_lower`, as under a mesh of several devices.
+
+:func:`ssd_chunked` is the third decay rule on the same helpers, the scalar
+decay WITHOUT the delta rule's correction (Mamba-2's state-space duality):
+``S_t = exp(g_t) S_{t-1} + k_t ⊗ v_t``, ``o_t = S_tᵀ q_t`` with ONE q and ONE k
+a position for all the heads. There is no triangular system and no ``M⁻¹``: a
+chunk's pairs are :func:`_scalar_lower` of :func:`_sums_between`, ``q·kᵀ``
+taken once a chunk for all heads, and the state's two products run over all
+heads' lanes at once. ``jax.numpy`` only: a kernel pair in ``ops/pallas/kda.py``'s
+frame is the next step (PERF.md §7).
 """
 
 from __future__ import annotations
@@ -79,7 +88,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["kda_chunked", "gdn_chunked", "gdn_core", "kda_recurrent", "short_conv"]
+__all__ = ["kda_chunked", "gdn_chunked", "gdn_core", "kda_recurrent", "short_conv", "ssd_chunked", "ssd_recurrent"]
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 # three bfloat16 passes: float32 operands to about 2^-16, half the passes of
@@ -576,5 +585,83 @@ def kda_recurrent(q, k, v, g, beta, initial_state=None):
 
     S0 = jnp.zeros((b, h, dk, dv), f32) if initial_state is None else initial_state.astype(f32)
     xs = tuple(jnp.moveaxis(x.astype(f32), 1, 0) for x in (q, k, v, g, beta))
+    S_end, o = jax.lax.scan(step, S0, xs)
+    return jnp.moveaxis(o, 0, 1).astype(v.dtype), S_end
+
+
+def ssd_chunked(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    g: jnp.ndarray,
+    chunk: int = 64,
+    initial_state: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The scalar-decay rule without a correction (Mamba-2's SSD, one group):
+    ``S_t = exp(g_t) S_{t-1} + k_t ⊗ v_t``, ``o_t = S_tᵀ q_t``. q, k [B, S, N]
+    — ONE a position, shared by all heads (a state-space layer's C and B); v
+    [B, S, H, P] (its ``Δ·x``); g [B, S, H] float32 log-decay (<= 0, its
+    ``Δ·A``). Returns (o [B, S, H, P] in v's dtype, the final state
+    [B, H, N, P] float32). Any S: the tail is padded with positions that
+    neither decay nor write.
+
+    A chunk's pairs are ``(q_i · k_j) exp(sum of g over j < t <= i)``
+    (:func:`_scalar_lower` over :func:`_sums_between`: the difference first,
+    then ``exp`` of a number <= 0 — no factor ``exp(+Λ)`` exists at any decay),
+    the product ``q·kᵀ`` taken ONCE a chunk for all heads. What a chunk's
+    positions write is decayed to the chunk's end on the VALUE side (k has no
+    head to carry a head's decay) and what they read of the state at the
+    chunk's start is decayed on the output side, so both products with the
+    state run over all heads' lanes at once, [C, N] x [N, H·P]. The scan over
+    chunks is elementwise. Large products in the inputs' dtype with float32
+    accumulation; decays, pairs and the state float32; the backward is autodiff."""
+    b, s, h, p = v.shape
+    n_state = q.shape[-1]
+    dt, f32 = v.dtype, jnp.float32
+    g = g.astype(f32)
+    pad = -s % chunk
+    if pad:
+        q, k = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (q, k))
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        g = jnp.pad(g, ((0, 0), (0, pad), (0, 0)))
+    n = (s + pad) // chunk
+    S0 = jnp.zeros((b, h, n_state, p), f32) if initial_state is None else initial_state.astype(f32)
+    qc, kc = (x.reshape(b, n, chunk, n_state) for x in (q, k))
+    vc = v.reshape(b, n, chunk, h, p)
+    gc = jnp.moveaxis(g.reshape(b, n, chunk, h), 3, 1)[..., None]  # [B, H, n, C, 1]
+    between = _sums_between(gc)  # [B, H, n, C, C]
+    pairs = _scalar_lower(qc.astype(f32)[:, None], kc.astype(f32)[:, None], between, diag=True)
+    to_end = jnp.moveaxis(between[..., -1, :], 1, 3)  # [B, n, C, H]: position j's write, at the chunk's end
+    from_start = jnp.moveaxis(between[..., :, 0] + gc[..., :1, 0], 1, 3)  # the chunk's first state, at position i
+    o = jnp.einsum("bhnij,bnjhp->bnihp", pairs.astype(dt), vc, preferred_element_type=f32)
+    writes = jnp.einsum(
+        "bnjk,bnjhp->nbkhp", kc, (vc * jnp.exp(to_end)[..., None]).astype(dt), preferred_element_type=f32
+    )
+    decay = jnp.moveaxis(jnp.exp(from_start[:, :, -1]), 1, 0)[:, :, None, :, None]  # [n, B, 1, H, 1]: a whole chunk's
+
+    def step(S, xs):  # S [B, N, H, P]
+        decay_n, writes_n = xs
+        return decay_n * S + writes_n, S
+
+    S_end, starts = jax.lax.scan(step, jnp.moveaxis(S0, 1, 2), (decay, writes))
+    read = jnp.einsum("bnik,nbkhp->bnihp", qc, starts.astype(dt), preferred_element_type=f32)
+    o = o + read * jnp.exp(from_start)[..., None]
+    return o.reshape(b, n * chunk, h, p)[:, :s].astype(dt), jnp.moveaxis(S_end, 2, 1)
+
+
+def ssd_recurrent(q, k, v, g, initial_state=None):
+    """:func:`ssd_chunked`'s rule itself, one position after another (a
+    ``lax.scan`` over S) in float32: what the tests and ``benchmark/check_granite.py``
+    hold the chunked form to. Same arguments and results."""
+    f32 = jnp.float32
+    b, s, h, p = v.shape
+
+    def step(S, xs):
+        q_t, k_t, v_t, g_t = xs  # [B, N], [B, N], [B, H, P], [B, H]
+        S = jnp.exp(g_t)[..., None, None] * S + k_t[:, None, :, None] * v_t[:, :, None, :]
+        return S, jnp.einsum("bhkp,bk->bhp", S, q_t, precision=_HIGHEST)
+
+    S0 = jnp.zeros((b, h, q.shape[-1], p), f32) if initial_state is None else initial_state.astype(f32)
+    xs = tuple(jnp.moveaxis(x.astype(f32), 1, 0) for x in (q, k, v, g))
     S_end, o = jax.lax.scan(step, S0, xs)
     return jnp.moveaxis(o, 0, 1).astype(v.dtype), S_end
