@@ -419,6 +419,7 @@ def steady(
 def kernels_child(
     platform: str, flash_shapes: list, chunked_shape: list, cells_shape: list,
     mla_shape: Optional[list] = None, kda_shape: Optional[list] = None, gdn_shape: Optional[list] = None,
+    ssd_shape: Optional[list] = None,
 ) -> None:
     """Runs in the child. Flash forward+backward per (b, s, h, d) at its
     default blocks — a shape that goes on to (…, value width, key/value heads)
@@ -431,7 +432,9 @@ def kernels_child(
     ``kda_shape`` (b, s, h, d): chunked KDA in bf16 against the recurrence in
     f32 (``kda_cells``) — both at the hybrid cell's widths and a short length;
     ``gdn_shape`` (b, s, key heads, value heads, d): the same rule with one
-    decay a head, key heads shared by value heads (``gdn_cells``)."""
+    decay a head, key heads shared by value heads (``gdn_cells``);
+    ``ssd_shape`` (b, s, heads, head width, state): the scalar decay without a
+    correction, one q and one k a position for all heads (``ssd_cells``)."""
     from torchft_tpu.utils.compile_cache import place_compile_cache
 
     place_compile_cache()
@@ -531,6 +534,8 @@ def kernels_child(
         oks.append(_kda_cells(kda_shape, dev))
     if gdn_shape:
         oks.append(_gdn_cells(gdn_shape, dev))
+    if ssd_shape:
+        oks.append(_ssd_cells(ssd_shape, dev))
     sys.exit(0 if all(oks) else 1)
 
 
@@ -581,33 +586,55 @@ def _gdn_cells(shape: list, dev) -> bool:
     return _held_to_the_recurrence("gdn_cells", shape, dev, gdn_chunked, by_position, (q, k, v, g, beta), w)
 
 
-def _held_to_the_recurrence(name: str, shape: list, dev, chunked, recurrent, inputs, w) -> bool:
-    """One check line: ``chunked`` on bf16 q, k, v — value and the gradients of
-    all five inputs under the probe ``w`` — against ``recurrent`` in f32 on the
-    same rounded operands; q and k enter L2-normalised, q scaled."""
+def _ssd_cells(shape: list, dev) -> bool:
+    """The same for the scalar decay WITHOUT a correction (``ops/kda.ssd_chunked``;
+    shape b, s, heads, head width, state): ONE q and ONE k a position for all
+    heads, no ``beta``. On a TPU at a state 128 wide under heads of 64 the
+    Pallas kernel pair ``ssd_fwd`` / ``ssd_bwd`` in the mixer's chunk of 128, at
+    decays from mild to 16 nats a POSITION."""
     import jax
     import jax.numpy as jnp
 
-    q, k, v, g, beta = inputs
+    from torchft_tpu.ops.kda import ssd_chunked, ssd_recurrent
+
+    b, s, h, p, n = shape
+    ks = jax.random.split(jax.random.PRNGKey(s + p + h), 5)
+    q, k = (jax.random.normal(kk, (b, s, n), jnp.float32) for kk in ks[:2])
+    v, w = (jax.random.normal(kk, (b, s, h, p), jnp.float32) for kk in ks[2:4])
+    g = -jnp.linspace(0.05, 16.0, h) * jax.nn.softplus(jax.random.normal(ks[4], (b, s, h)))
+    chunked = lambda q, k, v, g: ssd_chunked(q, k, v, g, chunk=128)  # noqa: E731 — the mixer's chunk
+    return _held_to_the_recurrence("ssd_cells", shape, dev, chunked, ssd_recurrent, (q, k, v, g), w)
+
+
+def _held_to_the_recurrence(name: str, shape: list, dev, chunked, recurrent, inputs, w) -> bool:
+    """One check line: ``chunked`` on bf16 q, k, v — value and the gradients of
+    every input (q, k, v, g and, where the rule has one, beta) under the probe
+    ``w`` — against ``recurrent`` in f32 on the same rounded operands; q and k
+    enter L2-normalised, q scaled."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v, *rest = inputs
     d = q.shape[-1]
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d**-0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
 
-    def loss(f, q, k, v, g, beta):
-        o = f(q, k, v, g, beta)[0]
+    def loss(f, *a):
+        o = f(*a)[0]
         return jnp.sum(o.astype(jnp.float32) * w), o
 
-    got_fn = jax.jit(jax.value_and_grad(lambda *a: loss(chunked, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
+    every = tuple(range(len(inputs)))
+    got_fn = jax.jit(jax.value_and_grad(lambda *a: loss(chunked, *a), argnums=every, has_aux=True))
     with jax.default_matmul_precision("highest"):
-        ref_fn = jax.jit(jax.value_and_grad(lambda *a: loss(recurrent, *a), argnums=(0, 1, 2, 3, 4), has_aux=True))
-        (_, o_ref), g_ref = ref_fn(*(x.astype(jnp.float32) for x in (qb, kb, vb)), g, beta)
-    # on a TPU, heads 128 wide are the Pallas kernels' (ops/pallas/kda.py): a
+        ref_fn = jax.jit(jax.value_and_grad(lambda *a: loss(recurrent, *a), argnums=every, has_aux=True))
+        (_, o_ref), g_ref = ref_fn(*(x.astype(jnp.float32) for x in (qb, kb, vb)), *rest)
+    # on a TPU, keys 128 wide are the Pallas kernels' (ops/pallas/kda.py): a
     # silent fall back to the jax.numpy form fails here and not only a metric
     must_be_mosaic = dev.platform == "tpu" and d == 128
-    mosaic = "tpu_custom_call" in got_fn.lower(qb, kb, vb, g, beta).as_text()
+    mosaic = "tpu_custom_call" in got_fn.lower(qb, kb, vb, *rest).as_text()
     t0 = time.perf_counter()
-    (_, o), grads = jax.block_until_ready(got_fn(qb, kb, vb, g, beta))
+    (_, o), grads = jax.block_until_ready(got_fn(qb, kb, vb, *rest))
     t_first = time.perf_counter() - t0
     atol = rtol = 2e-2
     errs = {  # past the relative part, in units of the array's scale
@@ -632,6 +659,7 @@ def kernels(
     mla_shape: Optional[list] = None,
     kda_shape: Optional[list] = None,
     gdn_shape: Optional[list] = None,
+    ssd_shape: Optional[list] = None,
     timeout: float = 600,
 ) -> List[Dict[str, Any]]:
     # head_dim 64 and 128 are the two the presets use; S >= 2048. The last is
@@ -649,9 +677,12 @@ def kernels(
     kda_shape = kda_shape or [1, 1024, 8, 128]
     # the Gated DeltaNet cell's heads, 16 key heads under 32 value heads of 128
     gdn_shape = gdn_shape or [1, 1024, 16, 32, 128]
+    # the state-space cell's widths: 64 heads of 64 under ONE key 128 wide, a block of its mixer
+    ssd_shape = ssd_shape or [1, 1024, 64, 64, 128]
     code = (
         "import chip_smoke; chip_smoke.kernels_child("
-        f"{platform!r}, {flash_shapes!r}, {chunked_shape!r}, {cells_shape!r}, {mla_shape!r}, {kda_shape!r}, {gdn_shape!r})"
+        f"{platform!r}, {flash_shapes!r}, {chunked_shape!r}, {cells_shape!r}, {mla_shape!r}, {kda_shape!r}, {gdn_shape!r}, "
+        f"{ssd_shape!r})"
     )
     try:
         text = run_child("3_kernels", [sys.executable, "-c", code], _child_env(), timeout)

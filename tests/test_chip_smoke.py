@@ -230,8 +230,11 @@ def test_rehearse_kernels(smoke):
     checks = smoke.kernels(
         "cpu", [[1, 256, 2, 64], [1, 256, 4, 64, 64, 1]], [2, 256, 2, 64], [1, 256, 2, 128],
         mla_shape=[1, 256, 2, 24, 16], kda_shape=[1, 160, 2, 16], gdn_shape=[1, 160, 2, 4, 16],
+        ssd_shape=[1, 160, 4, 8, 16],
     )
-    assert [c["check"] for c in checks] == ["flash_d64", "flash_d64_grouped", "flash_cells", "chunked", "mla_cells", "kda_cells", "gdn_cells"]
+    assert [c["check"] for c in checks] == [
+        "flash_d64", "flash_d64_grouped", "flash_cells", "chunked", "mla_cells", "kda_cells", "gdn_cells", "ssd_cells",
+    ]
     assert all(c["ok"] and not c.get("mosaic_custom_call") for c in checks)
 
 

@@ -892,6 +892,51 @@ def test_the_gdn_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch
 
 
 @pytest.mark.parametrize(
+    "seq, heads, p, dtype",
+    [(1024, 64, 64, jnp.bfloat16), (2048, 64, 64, jnp.float32), (256, 8, 128, jnp.bfloat16)],
+    ids=["the_cell", "check_granite_float32", "heads_a_whole_tile"],
+)
+def test_the_ssd_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, seq, heads, p, dtype):
+    """``ssd_forward`` / ``ssd_backward`` — the scalar decay without a
+    correction — through Mosaic for a described v5e: a block of the Granite
+    cell's mixer (batch 1, 1024 positions, 64 heads of 64 under a state of 128,
+    eight heads in four lane tiles a grid step), the float32 shape
+    ``benchmark/check_granite.py``'s core check runs on the chip (2048
+    positions, every product at ``HIGHEST``), and heads a whole tile wide."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.ops.pallas import kda as kernels
+
+    def of(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    state_dim = 128
+    keys, values = of((1, seq, state_dim), dtype), of((1, seq, heads * p), dtype)
+    small, state = of((1, seq, heads)), of((1, heads, state_dim, p))
+    starts = of((1, seq // kernels.SSD_CHUNK, state_dim, heads * p))  # the one residual beside the inputs
+    forward = jax.jit(lambda *a: kernels.ssd_forward(*a, interpret=False))
+    backward = jax.jit(lambda *a: kernels.ssd_backward(*a, interpret=False))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = (
+            forward.lower(keys, keys, values, small, state).compile(),
+            backward.lower(keys, keys, values, small, starts, values, state).compile(),
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert all("tpu_custom_call" in c.as_text() for c in compiled)
+    o, at_starts, end = jax.tree_util.tree_leaves(compiled[0].out_info)
+    assert (o.shape, o.dtype) == (values.shape, dtype) and (at_starts.shape, at_starts.dtype) == (starts.shape, jnp.float32)
+    assert (end.shape, end.dtype) == (state.shape, jnp.float32)
+    dq, dk, dv, dg, ds0 = jax.tree_util.tree_leaves(compiled[1].out_info)
+    assert dq.shape == dk.shape == keys.shape and dq.dtype == dk.dtype == dtype  # ONE key a position: summed over all heads
+    assert (dv.shape, dv.dtype) == (values.shape, dtype) and (dg.shape, dg.dtype) == (small.shape, jnp.float32)
+    assert (ds0.shape, ds0.dtype) == (state.shape, jnp.float32)
+
+
+@pytest.mark.parametrize(
     "rows, width, dtype",
     [(32768, 2048, jnp.bfloat16), (8192, 2304, jnp.bfloat16), (32768, 128, jnp.bfloat16), (8192, 2304, jnp.float32)],
     ids=["laguna_xs2_1g", "kimi_linear_1g", "the_gates_gradient", "float32"],

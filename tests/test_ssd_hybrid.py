@@ -17,7 +17,7 @@ import pytest
 
 from torchft_tpu.models import transformer as T
 from torchft_tpu.models.transformer import TransformerConfig, init_params, loss_and_stats, loss_fn
-from torchft_tpu.ops.kda import ssd_chunked, ssd_recurrent
+from torchft_tpu.ops.kda import ssd_chunked, ssd_core, ssd_recurrent
 from torchft_tpu.parallel.mesh import MeshConfig, make_mesh
 from torchft_tpu.parallel.train_step import TrainStep
 
@@ -235,6 +235,10 @@ def test_the_core_says_its_form_and_chunk_once(monkeypatch):
     said = telemetry.EVENTS.recent("ssd_core_path")[before:]
     assert len(said) == 1
     assert {k: said[0][k] for k in ("core", "chunk", "heads", "state", "block")} == dict(core="jax.numpy", chunk=37, heads=8, state=8, block=37)
+    # ``core`` is what ``ops/kda.ssd_core`` answers for the block's call — a state 8 wide is no lane tile: the kernels'
+    # widths, and the event saying ``ssd_kernel``, are ``tests/test_ssd_kernel.py``'s
+    of = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    assert said[0]["core"] == ssd_core(of(2, 37, 8), of(2, 37, 8), of(2, 37, 8, 4), 37)
     pattern = telemetry.EVENTS.recent("layer_pattern")[-1]
     assert pattern["lead"].count("ssd.dense") == 5 and "nope.dense" in pattern["lead"] and pattern["tied"] is True
 

@@ -72,7 +72,7 @@ from torchft_tpu.ops.attention import (
     ring_attention,
     ring_attention_local,
 )
-from torchft_tpu.ops.kda import gdn_chunked, gdn_core, kda_chunked, short_conv, ssd_chunked
+from torchft_tpu.ops.kda import gdn_chunked, gdn_core, kda_chunked, short_conv, ssd_chunked, ssd_core
 from torchft_tpu.ops.layers import (
     moe_dispatch,
     moe_dropless,
@@ -1441,12 +1441,13 @@ def _say_gdn_core_path(core: str, batch: int, block: int, cfg: TransformerConfig
     _say_once("gdn_core_path", tuple(fields.values()), **fields)
 
 
-def _say_ssd_core_path(batch: int, block: int, cfg: TransformerConfig) -> None:
+def _say_ssd_core_path(core: str, batch: int, block: int, cfg: TransformerConfig) -> None:
     """One ``ssd_core_path`` event and one INFO line per traced shape of a
-    state-space mixer: the form ``ops/kda.ssd_chunked`` takes for a block of it
-    (``jax.numpy``: there is no kernel yet), its chunk, heads, state and block."""
+    state-space mixer: which code ``ops/kda.ssd_chunked`` took for a block of it
+    (``ops/kda.ssd_core``: the Pallas kernel pair ``ssd_kernel``, or
+    ``jax.numpy``), its chunk, heads, state and block."""
     fields = dict(
-        core="jax.numpy", heads=cfg.ssd_n_heads, head_dim=cfg.ssd_head_dim, state=cfg.ssd_state_dim,
+        core=core, heads=cfg.ssd_n_heads, head_dim=cfg.ssd_head_dim, state=cfg.ssd_state_dim,
         groups=cfg.ssd_n_groups, chunk=min(_SSD_CHUNK, block), batch=batch, block=block,
     )
     _say_once("ssd_core_path", tuple(fields.values()), **fields)
@@ -1813,9 +1814,11 @@ def _mix_gdn(cfg, lp, h):
 
 # Positions of a chunk of a state-space mixer's core (``ops/kda.ssd_chunked``); the result does not depend on it. A
 # chunk's pairs cost chunk x head_dim operations a position a head, its two products with the state 2 x state x
-# head_dim whatever the chunk — but in the ``jax.numpy`` form the core's time is the pairs' elementwise passes over
-# [heads, chunk, chunk] float32: 128 read 0.570 s a device step at Granite's widths on a v5e, 64 0.606, 256 0.710
-# (PERF.md §6, PR 69). ``benchmark/opcounts/granite_hybrid.py`` counts at the same number
+# head_dim whatever the chunk. 128 is the one chunk the Pallas kernel pair is written for (``ops/pallas/kda.SSD_CHUNK``:
+# a head's pairs are one whole [128, 128] MXU tile; ``ops/kda.ssd_core`` says which calls are the kernels'), and in the
+# ``jax.numpy`` form, whose time is the pairs' elementwise passes over [heads, chunk, chunk] float32, it was the fastest
+# of three: 0.570 s a device step at Granite's widths on a v5e, 64 0.606, 256 0.710 (PERF.md §6, PR 69).
+# ``benchmark/opcounts/granite_hybrid.py`` counts at the same number
 _SSD_CHUNK = 128
 
 
@@ -1855,9 +1858,10 @@ def _mix_ssd(cfg, lp, h):
             delta = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))  # [B, blk, H]
             g = -jnp.exp(lp["a_log"].astype(f32)) * delta
             v = (x * delta[..., None]).astype(x.dtype)
-        _say_ssd_core_path(b, blk, cfg)
+        chunk = min(_SSD_CHUNK, blk)
+        _say_ssd_core_path(ssd_core(c_out, b_in, v, chunk), b, blk, cfg)
         with jax.named_scope("ssd_core"):
-            y, state = ssd_chunked(c_out, b_in, v, g, chunk=min(_SSD_CHUNK, blk), initial_state=state)
+            y, state = ssd_chunked(c_out, b_in, v, g, chunk=chunk, initial_state=state)
         with jax.named_scope("gated_norm"):
             y = y.astype(f32) + lp["d_skip"].astype(f32)[:, None] * x
             # the gate goes in BEFORE the norm, and the norm's input stays float32: rounded once, after the weight

@@ -77,8 +77,19 @@ decay WITHOUT the delta rule's correction (Mamba-2's state-space duality):
 a position for all the heads. There is no triangular system and no ``M⁻¹``: a
 chunk's pairs are :func:`_scalar_lower` of :func:`_sums_between`, ``q·kᵀ``
 taken once a chunk for all heads, and the state's two products run over all
-heads' lanes at once. ``jax.numpy`` only: a kernel pair in ``ops/pallas/kda.py``'s
-frame is the next step (PERF.md §7).
+heads' lanes at once. It picks as the two above (:func:`ssd_core` says which):
+
+* a state of whole lane tiles under heads 64 or 128 wide, in chunks of 128 on
+  one device — the model on a chip — goes to a third kernel pair of the same
+  file (``ssd_forward`` / ``ssd_backward``, one ``custom_vjp``,
+  :func:`_ssd_kernels`), at every decay: a chunk's pair matrices and a head's
+  state stay in VMEM, where the ``jax.numpy`` form writes ``float32[heads,
+  chunks, 128, 128]`` to HBM pass after pass, forward and again for autodiff
+  (PERF.md §6, PR 70). The state at every chunk's start is the one residual;
+* every other call — the CPU rehearsals' widths, another chunk, mixed dtypes,
+  a mesh of several devices — is :func:`_ssd_chunks` in ``jax.numpy``, its
+  backward autodiff: what the kernels are tested against beside
+  :func:`ssd_recurrent`.
 """
 
 from __future__ import annotations
@@ -88,7 +99,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["kda_chunked", "gdn_chunked", "gdn_core", "kda_recurrent", "short_conv", "ssd_chunked", "ssd_recurrent"]
+__all__ = [
+    "kda_chunked", "gdn_chunked", "gdn_core", "kda_recurrent", "short_conv", "ssd_chunked", "ssd_core", "ssd_recurrent",
+]
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 # three bfloat16 passes: float32 operands to about 2^-16, half the passes of
@@ -589,6 +602,47 @@ def kda_recurrent(q, k, v, g, beta, initial_state=None):
     return jnp.moveaxis(o, 0, 1).astype(v.dtype), S_end
 
 
+def ssd_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, chunk: int = 64) -> str:
+    """Which code :func:`ssd_chunked` runs for these inputs, from what it sees
+    in them: ``"ssd_kernel"`` — the Pallas kernel pair of ``ops/pallas/kda.py``
+    without a correction — where the state is whole lane tiles, the heads'
+    lanes are whole tiles of heads 64 or 128 wide, the chunk is the kernels'
+    128, q, k and v are of one dtype and the program is one device's (as
+    :func:`_kernels_take`); ``"jax.numpy"`` for every other call — one with no
+    sequence in it too, which has no grid to run."""
+    n_state, (heads, p) = q.shape[-1], v.shape[2:]
+    kernels = (
+        n_state % 128 == 0 and heads * p % 128 == 0 and v.size > 0  # before pallas is imported at all
+        and _kernels().ssd_serves(n_state, heads, p, chunk) and q.dtype == k.dtype == v.dtype
+        and jax.sharding.get_abstract_mesh().size <= 1
+    )
+    return "ssd_kernel" if kernels else "jax.numpy"
+
+
+# One ``custom_vjp`` around the kernel pair, as :func:`_gdn_kernels`: every exponent is <= 0, so the kernels serve
+# every decay. The state at every chunk's start is the one residual beside the inputs.
+@jax.custom_vjp
+def _ssd_kernels(q, k, v, g, S0):
+    return _ssd_kernels_fwd(q, k, v, g, S0)[0]
+
+
+def _ssd_kernels_fwd(q, k, v, g, S0):
+    with jax.named_scope("ssd_kernel"):
+        o, starts, S_end = _kernels().ssd_forward(q, k, _wide(v), g, S0)
+    return (o.reshape(v.shape), S_end), (q, k, v, g, starts)
+
+
+def _ssd_kernels_bwd(res, cts):
+    q, k, v, g, starts = res
+    do, d_end = cts
+    with jax.named_scope("ssd_kernel"):
+        dq, dk, dv, dg, dS0 = _kernels().ssd_backward(q, k, _wide(v), g, starts, _wide(do), d_end)
+    return dq, dk, dv.reshape(v.shape), dg, dS0
+
+
+_ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
+
+
 def ssd_chunked(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -612,20 +666,37 @@ def ssd_chunked(
     positions write is decayed to the chunk's end on the VALUE side (k has no
     head to carry a head's decay) and what they read of the state at the
     chunk's start is decayed on the output side, so both products with the
-    state run over all heads' lanes at once, [C, N] x [N, H·P]. The scan over
-    chunks is elementwise. Large products in the inputs' dtype with float32
-    accumulation; decays, pairs and the state float32; the backward is autodiff."""
+    state run over all heads' lanes at once, [C, N] x [N, H·P]. Large products
+    in the inputs' dtype with float32 accumulation; decays, pairs and the
+    state float32.
+
+    Two forms of this, picked by what the call's inputs are (:func:`ssd_core`),
+    no field and no switch: a state of whole lane tiles under heads 64 or 128
+    wide in chunks of 128 on one device — the model on a chip — runs the Pallas
+    kernel pair ``ssd_forward`` / ``ssd_backward`` under one ``custom_vjp``, a
+    chunk's pair matrices and a head's state in VMEM; every other call (the
+    CPU rehearsals' widths, another chunk, mixed dtypes, a mesh of several
+    devices) runs the ``jax.numpy`` below, the scan over chunks elementwise,
+    its backward autodiff."""
     b, s, h, p = v.shape
-    n_state = q.shape[-1]
-    dt, f32 = v.dtype, jnp.float32
-    g = g.astype(f32)
+    kernels = ssd_core(q, k, v, chunk) == "ssd_kernel"
+    g = g.astype(jnp.float32)
     pad = -s % chunk
     if pad:
         q, k = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (q, k))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         g = jnp.pad(g, ((0, 0), (0, pad), (0, 0)))
-    n = (s + pad) // chunk
-    S0 = jnp.zeros((b, h, n_state, p), f32) if initial_state is None else initial_state.astype(f32)
+    S0 = jnp.zeros((b, h, q.shape[-1], p), jnp.float32) if initial_state is None else initial_state.astype(jnp.float32)
+    o, S_end = _ssd_kernels(q, k, v, g, S0) if kernels else _ssd_chunks(q, k, v, g, S0, chunk)
+    return o[:, :s], S_end
+
+
+def _ssd_chunks(q, k, v, g, S0, chunk):
+    """:func:`ssd_chunked` in ``jax.numpy``: S whole chunks, g and S0 float32.
+    What the Pallas kernel pair is tested against beside :func:`ssd_recurrent`."""
+    b, s, h, p = v.shape
+    n_state, n = q.shape[-1], s // chunk
+    dt, f32 = v.dtype, jnp.float32
     qc, kc = (x.reshape(b, n, chunk, n_state) for x in (q, k))
     vc = v.reshape(b, n, chunk, h, p)
     gc = jnp.moveaxis(g.reshape(b, n, chunk, h), 3, 1)[..., None]  # [B, H, n, C, 1]
@@ -646,7 +717,7 @@ def ssd_chunked(
     S_end, starts = jax.lax.scan(step, jnp.moveaxis(S0, 1, 2), (decay, writes))
     read = jnp.einsum("bnik,nbkhp->bnihp", qc, starts.astype(dt), preferred_element_type=f32)
     o = o + read * jnp.exp(from_start)[..., None]
-    return o.reshape(b, n * chunk, h, p)[:, :s].astype(dt), jnp.moveaxis(S_end, 2, 1)
+    return o.reshape(b, s, h, p).astype(dt), jnp.moveaxis(S_end, 2, 1)
 
 
 def ssd_recurrent(q, k, v, g, initial_state=None):

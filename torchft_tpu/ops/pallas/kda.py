@@ -1,5 +1,6 @@
 """The chunked gated delta rule (``ops/kda.py``: KDA's per-channel decay, and
-Gated DeltaNet's one decay a head) as pallas TPU kernels, fwd + bwd: a head's
+Gated DeltaNet's one decay a head) and the scalar decay without its correction
+(Mamba-2's state-space duality) as pallas TPU kernels, fwd + bwd: a head's
 state and a chunk's products never leave VMEM.
 
 ``ops/kda.kda_chunked`` in ``jax.numpy`` writes every intermediate of a chunk
@@ -91,11 +92,45 @@ scalar decay changes:
   four products a KEY head of the summed ``dA ⊙ e^between`` — and, where a
   group spans several steps, once more outside.
 
-On the chip: ``chip_smoke.py`` ``kda_cells`` and ``gdn_cells`` hold the
-kernels, forward and gradients, to the recurrence and fail without their
-Mosaic call in the lowered text; ``tests/test_flash_attention.py`` compiles
-them for a described v5e at the cells' shapes; ``tests/test_kda.py`` and
-``tests/test_gdn.py`` run them interpreted.
+The scalar decay WITHOUT a correction (Mamba-2's state-space duality,
+``ops/kda.ssd_chunked``: ``S_t = exp(g_t) S_{t-1} + k_t ⊗ v_t``, ``o_t = S_tᵀ
+q_t``) is the third pair, ``ssd_forward`` / ``ssd_backward``, after that: the
+grid, the sequential last axis, the state in float32 scratch, the sums of g as
+0/1-mask products, the precision and the layout of ``g`` are the frame's;
+what the rule drops from it:
+
+* there is no ``beta``, no ``k·kᵀ``, no ``M^-1`` and so no ``u``, ``w_v``,
+  ``w_k`` and no second residual: a head's work in a chunk is one ``exp`` of a
+  [128, 128] block, the mask, one apply. With no inverse whose merges stop at
+  64 there are no "two chunks as diagonal blocks" either: a grid step is ONE
+  chunk of 128 (:data:`SSD_CHUNK`), a head's pairs one whole MXU tile;
+* q and k are ONE a position for all heads (``[B, S, N]``): ``q·kᵀ`` is taken
+  once a step, and both products with the state run over the step's heads'
+  lanes at once — the state is held ``[N, hb·P]``, every head's lanes side by
+  side, the read ``[128, N] x [N, hb·P]`` decayed on the OUTPUT side, the
+  write ``kᵀ (v e^T)`` decayed to the chunk's end on the VALUE side. The
+  residual, the states at the chunks' starts, is kept in that layout
+  (``[B, S/128, N, H·P]`` float32: nobody else reads it);
+* a value head is 64 lanes (or 128): the heads of a step are taken from the
+  ``[B, S, H·P]`` view in whole lane tiles, two heads a tile, and a head's
+  products are the tile's with the other head's lanes zero
+  (:func:`_tile_heads`) — half a tile of an operand costs the MXU a whole one
+  either way;
+* ``dq`` and ``dk`` are all the heads': the pairs' part as two products a step
+  of the summed ``dA ⊙ e^between``, the state's part by the contraction over
+  the step's heads' lanes, and the head blocks' once more outside;
+* g's gradient through the pairs takes no product a head: position t is in
+  the sums of the pairs j < t <= i, so it gets the sum from t on of ``dA ⊙ A``'s
+  row sums less its column sums — a lane and a sublane reduction a head, and
+  one 0/1-mask product a step shared with ``G``'s gradient. (A difference in
+  a GRADIENT, whose terms are of the gradient's own size; the sums of g in the
+  exponents are never one.)
+
+On the chip: ``chip_smoke.py`` ``kda_cells``, ``gdn_cells`` and ``ssd_cells``
+hold the kernels, forward and gradients, to the recurrence and fail without
+their Mosaic call in the lowered text; ``tests/test_flash_attention.py``
+compiles them for a described v5e at the cells' shapes; ``tests/test_kda.py``,
+``tests/test_gdn.py`` and ``tests/test_ssd_kernel.py`` run them interpreted.
 """
 
 from __future__ import annotations
@@ -107,7 +142,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["kda_forward", "kda_backward", "gdn_forward", "gdn_backward", "serves", "CHUNK", "ROWS"]
+__all__ = [
+    "kda_forward", "kda_backward", "gdn_forward", "gdn_backward", "ssd_forward", "ssd_backward",
+    "serves", "ssd_serves", "CHUNK", "ROWS", "SSD_CHUNK",
+]
 
 CHUNK = 64  # positions of a chunk: the only one the kernels are written for
 ROWS = 2 * CHUNK  # positions a grid step takes: two chunks, the diagonal blocks of its [128, 128] matrices
@@ -852,3 +890,279 @@ def gdn_backward(q, k, v, g, beta, starts, inv, do, d_end, interpret=None):
         dq, dk = (x.reshape(b, s, hk, parts, _LANES).sum(axis=3).reshape(q.shape).astype(q.dtype) for x in (dq, dk))
     unblock = lambda x: jnp.moveaxis(x, 1, 2).reshape(b, s, hv)  # noqa: E731
     return dq, dk, dv, unblock(dg), unblock(dbeta), ds0
+
+
+# ---------------------------------------------------------------------------
+# The scalar decay WITHOUT a correction (Mamba-2's state-space duality,
+# ``ops/kda.ssd_chunked``): the frame without its inverse — ONE chunk of 128 a
+# grid step, one q and one k a position for all heads
+# ---------------------------------------------------------------------------
+
+SSD_CHUNK = ROWS  # positions of a chunk, all a grid step takes: one whole [128, 128] tile a head
+
+
+def ssd_serves(n_state: int, heads: int, p: int, chunk: int) -> bool:
+    """Whether the state-space kernels take these widths: the state whole lane
+    tiles, the heads' lanes whole tiles read in place (a value head half a
+    tile or one), one chunk size."""
+    return n_state % _LANES == 0 and p in (_LANES // 2, _LANES) and heads * p % _LANES == 0 and chunk == SSD_CHUNK
+
+
+def _ssd_heads(heads: int, p: int) -> int:
+    """The heads a grid step holds, in whole lane tiles, chosen as
+    :func:`_gdn_heads` chooses: the most tiles of :data:`_HEADS` that divide the
+    heads' lanes. Sixteen tiles a step run 1 % of the cell's step faster than
+    four (what a step does once for all its heads is a third of a step of four
+    tiles) and cost 12 s of its set-up: the kernels' bodies are written out a
+    head, and lowering them to Mosaic took 16 s where four tiles take 4.5
+    (PERF.md §6, PR 70)."""
+    tiles = heads * p // _LANES
+    return next(m for m in _HEADS if tiles % m == 0) * _LANES // p
+
+
+def _ssd_masks():
+    """Of ONE chunk's [ROWS, ROWS] matrix: row, column j <= row i, j < i, j > i."""
+    i, j = _iota((ROWS, ROWS), 0), _iota((ROWS, ROWS), 1)
+    return _iota((ROWS, 1), 0), j <= i, j < i, j > i
+
+
+def _tile_heads(x, p):
+    """A [rows, 128] lane tile once a head of it, the other heads' lanes zero:
+    a head ``p`` lanes wide is half a tile or a whole one, and a product with
+    the masked tile is the product with the head."""
+    if p == _LANES:
+        return [x]
+    lane = _iota(x.shape, 1)
+    return [jnp.where((lane >= i * p) & (lane < (i + 1) * p), x, jnp.zeros_like(x)) for i in range(_LANES // p)]
+
+
+def _over_tile(values, p, rows=ROWS):
+    """A value a row ([rows, 1] or [1, 1]) of each head of a lane tile, over that head's lanes: [rows, 128]."""
+    out = jnp.broadcast_to(values[-1], (rows, _LANES))
+    if len(values) == 1:
+        return out
+    lane = _iota((rows, _LANES), 1)
+    for i, value in enumerate(values[:-1]):
+        out = jnp.where((lane >= i * p) & (lane < (i + 1) * p), value, out)
+    return out
+
+
+def _rows_as_lanes(rows):
+    """[1, ROWS] rows, head j's as the column in lane j of a [ROWS, 128] matrix (:func:`_lanes` for rows): the
+    heads' rows one under another, turned once."""
+    held = -(-len(rows) // 8) * 8
+    at = _iota((held, ROWS), 0)
+    out = jnp.zeros((held, ROWS), _F32)
+    for j, r in enumerate(rows):
+        out = jnp.where(at == j, r, out)
+    if held < _LANES:
+        out = jnp.concatenate([out, jnp.zeros((_LANES - held, ROWS), _F32)], axis=0)
+    return out.T
+
+
+def _wide_tiles(tiles):
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
+
+
+def _ssd_chunk(q_ref, k_ref, g_block, hb, p, exact):
+    """What a chunk's forward and backward share, from q, k and g alone, for
+    the ``hb`` heads of a grid step: ``q·kᵀ`` ONCE for all of them, and a head's
+    three sums of g — between a pair, from the chunk's start through a row
+    (``G``: what the state at the start has decayed by when the row reads it),
+    from a row to the chunk's end (``T``: what the row's write has decayed by
+    there) — each over the positions it spans (:func:`_gdn_chunks`'s rule)."""
+    _, lower, strict, later = _ssd_masks()
+    gs = [_column(g_block, h) for h in range(hb)]
+    g_lanes = _lanes(gs)
+    G_all, T_all = _exact_sum(lower, g_lanes, exact), _exact_sum(later, g_lanes, exact)  # every head's in two products
+    q, k = q_ref[...], k_ref[...]
+    products = _dot(q, k, _NT, exact)  # [ROWS, ROWS]
+    out = []
+    for h, g in enumerate(gs):
+        decayed = jnp.exp(_exact_sum(lower, jnp.where(strict, g, 0.0), exact))  # exponents <= 0 at every decay
+        G = _column(G_all, h)
+        out.append(dict(
+            decayed=decayed, a=jnp.where(lower, products * decayed, 0.0),
+            e_in=jnp.exp(G), e_out=jnp.exp(_column(T_all, h)), decay=jnp.exp(G[ROWS - 1 : ROWS, :]),
+        ))
+    return out, q, k
+
+
+def _ssd_fwd_kernel(q_ref, k_ref, v_ref, g_ref, s0_ref, o_ref, starts_ref, end_ref, state, *, hb, p, exact):
+    n = pl.program_id(2)
+    dt = v_ref.dtype
+
+    @pl.when(n == 0)
+    def _init():
+        state[...] = s0_ref[...]
+
+    xs, q, k = _ssd_chunk(q_ref, k_ref, g_ref[...], hb, p, exact)
+    S = state[...]  # [N, hb·P]: the state as every head's lanes side by side
+    starts_ref[...] = S
+    read = _dot(q, S.astype(dt), _NN, exact)  # every head's read of the state at the chunk's start, at once
+    per = _LANES // p
+    written, decays = [], []
+    for t in range(hb // per):
+        lanes, mine = slice(t * _LANES, (t + 1) * _LANES), xs[t * per : (t + 1) * per]
+        v = v_ref[:, lanes]
+        o = read[:, lanes] * _over_tile([x["e_in"] for x in mine], p)  # decayed on the OUTPUT side
+        for x, v_head in zip(mine, _tile_heads(v, p)):
+            o = o + _dot(x["a"].astype(dt), v_head, _NN, exact)
+        o_ref[:, lanes] = o.astype(o_ref.dtype)
+        # what the chunk's positions write, decayed to its end on the VALUE side: k has no head
+        written.append((v.astype(_F32) * _over_tile([x["e_out"] for x in mine], p)).astype(dt))
+        decays.append(_over_tile([x["decay"] for x in mine], p, rows=1))
+    state[...] = S * _wide_tiles(decays) + _dot(k, _wide_tiles(written), _TN, exact)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _finish():
+        end_ref[...] = state[...]
+
+
+def _ssd_bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, starts_ref, do_ref, dend_ref, dq_ref, dk_ref, dv_ref, dg_ref, ds0_ref, dstate,
+    *, hb, p, exact,
+):
+    n = pl.program_id(2)
+    dt = v_ref.dtype
+
+    @pl.when(n == 0)
+    def _init():
+        dstate[...] = dend_ref[...]
+
+    xs, q, k = _ssd_chunk(q_ref, k_ref, g_ref[...], hb, p, exact)
+    row, lower, strict, later = _ssd_masks()
+    S, d_end = starts_ref[...], dstate[...]  # the state at the chunk's start; the cotangent of the one at its end
+    Sd, d_end_d = S.astype(dt), d_end.astype(dt)
+    # o = e^G (q S) + A v;  S' = decay S + kᵀ (v e^T)
+    read = _dot(q, Sd, _NN, exact)
+    d_written = _dot(k, d_end_d, _NN, exact)  # [ROWS, hb·P]
+    per = _LANES // p
+    d_products = jnp.zeros((ROWS, ROWS), _F32)  # q kᵀ is all the heads': so its gradient, summed
+    d_reads, written, decays, leaving, entering, dGs, dTs = [], [], [], [], [], [], []
+    for t in range(hb // per):
+        lanes, mine = slice(t * _LANES, (t + 1) * _LANES), xs[t * per : (t + 1) * per]
+        v, do = v_ref[:, lanes], do_ref[:, lanes]
+        v32, do32 = v.astype(_F32), do.astype(_F32)
+        e_in, e_out = _over_tile([x["e_in"] for x in mine], p), _over_tile([x["e_out"] for x in mine], p)
+        do_in = do32 * e_in
+        d_reads.append(do_in.astype(dt))
+        written.append((v32 * e_out).astype(dt))
+        decays.append(_over_tile([x["decay"] for x in mine], p, rows=1))
+        dv = d_written[:, lanes] * e_out
+        through_G = _tile_heads(do_in * read[:, lanes], p)
+        through_T = _tile_heads(dv * v32, p)
+        through_decay = _tile_heads(d_end[:, lanes] * S[:, lanes], p)
+        for i, (x, do_head) in enumerate(zip(mine, _tile_heads(do, p))):
+            dv = dv + _dot(x["a"].astype(dt), do_head, _TN, exact)
+            da = jnp.where(lower, _dot(do_head, v, _NT, exact), 0.0)
+            d_products = d_products + da * x["decayed"]
+            # A = (q kᵀ) ⊙ e^between: the pair's sum gets dA ⊙ A, and position t is in the sums of the pairs j < t <= i.
+            # Those of t and not of t + 1 are row t's, those of t + 1 and not of t column t's: g's gradient is the
+            # sum from t on of what leaves less what enters — taken with dG's below, in the same product
+            d_between = jnp.where(strict, da * x["a"], 0.0)
+            leaving.append(_rows_sum(d_between))
+            entering.append(jnp.sum(d_between, axis=0, keepdims=True))
+            # the chunk's last row also sets the state's decay over the whole chunk
+            dGs.append(_rows_sum(through_G[i]) + jnp.where(row == ROWS - 1, _total(through_decay[i]) * x["decay"], 0.0))
+            dTs.append(_rows_sum(through_T[i]))
+        dv_ref[:, lanes] = dv.astype(dv_ref.dtype)
+    d_read, d_pairs = _wide_tiles(d_reads), _split(d_products, exact)
+    # both products with the state over the step's heads' lanes at once: their sum over the heads is the contraction
+    dq_ref[...] = (_dot(d_read, Sd, _NT, exact) + _dot_pieces(d_pairs, k, _NN, exact)).astype(dq_ref.dtype)
+    dk_ref[...] = (_dot(_wide_tiles(written), d_end_d, _NT, exact) + _dot_pieces(d_pairs, q, _TN, exact)).astype(dk_ref.dtype)
+    from_a_row_on = _lanes(dGs) + _lanes(leaving) - _rows_as_lanes(entering)
+    dg = _exact_sum(lower, from_a_row_on, exact, _TN) + _exact_sum(later, _lanes(dTs), exact, _TN)
+    dg_ref[...] = dg[:, :hb]
+    dstate[...] = d_end * _wide_tiles(decays) + _dot(q, d_read, _TN, exact)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _finish():
+        ds0_ref[...] = dstate[...]
+
+
+def _ssd_specs(n_state, hb, p, at):
+    """Block specs by role; ``at(n)`` gives the chunk a grid step works on."""
+    vm, width = pltpu.VMEM, hb * p
+    keys = pl.BlockSpec((None, ROWS, n_state), lambda b, h, n: (b, at(n), 0), memory_space=vm)
+    wide = pl.BlockSpec((None, ROWS, width), lambda b, h, n: (b, at(n), h), memory_space=vm)
+    small = pl.BlockSpec((None, None, ROWS, hb), lambda b, h, n: (b, h, at(n), 0), memory_space=vm)
+    state = pl.BlockSpec((None, n_state, width), lambda b, h, n: (b, 0, h), memory_space=vm)
+    starts = pl.BlockSpec((None, None, n_state, width), lambda b, h, n: (b, at(n), 0, h), memory_space=vm)
+    return keys, wide, small, state, starts
+
+
+def _state_lanes(state):  # [B, H, N, P] -> [B, N, H·P]: every head's lanes side by side, as the kernels hold it
+    b, h, n_state, p = state.shape
+    return jnp.moveaxis(state, 1, 2).reshape(b, n_state, h * p)
+
+
+def _state_heads(state, p):  # and back
+    b, n_state, width = state.shape
+    return jnp.moveaxis(state.reshape(b, n_state, width // p, p), 2, 1)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def ssd_forward(q, k, v, g, initial_state, interpret=None):
+    """q, k [B, S, N] and v [B, S, H·P] in one dtype, g [B, S, H] float32, the
+    state [B, H, N, P] float32; S whole chunks (:data:`SSD_CHUNK`). Returns
+    (o [B, S, H·P], the state at each chunk's start [B, S/128, N, H·P] as the
+    kernels hold it — the one residual beside the inputs — and the final
+    state [B, H, N, P])."""
+    b, s, width = v.shape
+    n_state, h = q.shape[-1], g.shape[-1]
+    p = width // h
+    hb = _ssd_heads(h, p)
+    keys, wide, small, state, starts = _ssd_specs(n_state, hb, p, lambda n: n)
+    o, at_starts, end = pl.pallas_call(
+        functools.partial(_ssd_fwd_kernel, hb=hb, p=p, exact=q.dtype == jnp.float32),
+        grid=(b, h // hb, s // ROWS),
+        in_specs=[keys, keys, wide, small, state],
+        out_specs=[wide, starts, state],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, s // ROWS, n_state, width), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_state, width), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((n_state, hb * p), jnp.float32)],
+        compiler_params=_PARAMS,
+        interpret=_should_interpret() if interpret is None else interpret,
+        name="ssd_fwd",
+    )(q, k, v, _beta_blocks(g, hb), _state_lanes(initial_state))
+    return o, at_starts, _state_heads(end, p)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def ssd_backward(q, k, v, g, starts, do, d_end, interpret=None):
+    """The gradients of q, k ([B, S, N]: summed over all heads), v, g
+    ([B, S, H] float32) and the initial state, from what :func:`ssd_forward`
+    took, its ``starts``, and the cotangents of o and of the final state."""
+    b, s, width = v.shape
+    n_state, h = q.shape[-1], g.shape[-1]
+    p = width // h
+    hb = _ssd_heads(h, p)
+    n, parts = s // ROWS, h // hb
+    keys, wide, small, state, starts_spec = _ssd_specs(n_state, hb, p, lambda i: n - 1 - i)
+    # a step writes q's and k's gradients over ITS heads; the head blocks' are summed after, in float32
+    per_step = jax.ShapeDtypeStruct((b, s, parts * n_state), q.dtype if parts == 1 else jnp.float32)
+    dq, dk, dv, dg, ds0 = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, hb=hb, p=p, exact=q.dtype == jnp.float32),
+        grid=(b, parts, n),
+        in_specs=[keys, keys, wide, small, starts_spec, wide, state],
+        out_specs=[
+            pl.BlockSpec((None, ROWS, n_state), lambda b, h, i: (b, n - 1 - i, h), memory_space=pltpu.VMEM),
+        ] * 2 + [wide, small, state],
+        out_shape=[
+            per_step, per_step,
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, parts, s, hb), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_state, width), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((n_state, hb * p), jnp.float32)],
+        compiler_params=_PARAMS,
+        interpret=_should_interpret() if interpret is None else interpret,
+        name="ssd_bwd",
+    )(q, k, v, _beta_blocks(g, hb), starts, do, _state_lanes(d_end))
+    if parts > 1:
+        dq, dk = (x.reshape(b, s, parts, n_state).sum(axis=2).astype(q.dtype) for x in (dq, dk))
+    return dq, dk, dv, jnp.moveaxis(dg, 1, 2).reshape(b, s, h), _state_heads(ds0, p)

@@ -76,6 +76,7 @@ CANONICAL_EVENTS = (
     "attention_path",
     "layer_pattern",
     "gdn_core_path",
+    "ssd_core_path",
     "loop_shape",
     "expert_path",
 )
