@@ -817,6 +817,49 @@ def test_the_staircase_kernels_compile_for_v5e_at_the_cells_shape(one_v5e_chip, 
     assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape
 
 
+def test_the_selection_kernels_compile_for_v5e_at_the_cells_shape(one_v5e_chip):
+    """``keye-vl-2_0-30b-a3b-1g.fused-s16384`` (PR 71): one sequence of 16 384
+    positions, 32 query heads over 4 key/value heads of 128 lanes with all
+    16 384 rows of K and V resident, under the int8 table of selected pairs at
+    512 x 512 tiles — the flash kernel's forward and backward, the 32 heads'
+    probabilities of a q block's selected pairs (``head_probs``), and the
+    selector's scores and their backward (16 heads of 64 over one key head,
+    ``ops/pallas/indexer``) — through Mosaic for a described v5e."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.ops.pallas.flash_attention import head_probs, scaled_head_major
+    from torchft_tpu.ops.pallas.indexer import indexer_scores_bwd_t, indexer_scores_t
+
+    of = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+    q, kv = of((1, 16384, 32, 128), jnp.bfloat16), of((1, 16384, 4, 128), jnp.bfloat16)
+    table, live = of((32, 1, 16384, 512), jnp.int8), of((32, 1, 32), jnp.int32)
+    q_i, k_i, w = of((1, 16, 16384, 64), jnp.bfloat16), of((1, 16384, 64), jnp.bfloat16), of((1, 16, 16384), jnp.float32)
+
+    def core(q, k, v, table, live):
+        def loss(q, k, v):
+            o, lse = flash_attention(q, k, v, block_q=512, block_k=512, interpret=False, selected=table, live=live)
+            return jnp.sum(o.astype(jnp.float32)), lse
+
+        (_, lse), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return grads, head_probs(scaled_head_major(q), k.transpose(0, 2, 1, 3), lse, table, live, 7, 512, interpret=False)
+
+    def selector(q_i, k_i, w):
+        scores = indexer_scores_t(q_i, k_i, w, 7, 512, 512, interpret=False)
+        return scores, indexer_scores_bwd_t(q_i, k_i, w, scores, 7, 512, interpret=False)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cored = jax.jit(core).lower(q, kv, kv, table, live).compile()
+        selected = jax.jit(selector).lower(q_i, k_i, w).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert cored.as_text().count("tpu_custom_call") >= 3 and selected.as_text().count("tpu_custom_call") >= 2
+    (dq, dk, dv), p = cored.out_info
+    assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape and p.shape == (1, 16384, 512)
+
+
 @pytest.mark.parametrize("batch, seq, heads", [(2, 1024, 32), (1, 1024, 8), (1, 128, 2)], ids=str)
 def test_the_kda_kernels_compile_for_v5e_at_the_cells_shapes(one_v5e_chip, batch, seq, heads):
     """``ops/pallas/kda.py``, forward and backward, through Mosaic for a

@@ -511,6 +511,9 @@ CELLS_PROGRAMS = {
     "joyai-flash-1g": ((2, 8192), "7a555ecf1ac27d1a22f69d07f2b4c7ba78ee1d2fba664a3111a569a1c01e6515"),
     "lfm2-8b-a1b-1g": ((2, 8192), "60537e3b0b4b4a9f639fee9a44b050d7d3126bcb67a01999fd5bea4e897efb9c"),
     "qwen3-next-80b-a3b-1g": ((2, 8192), "4dc1c3836effffda86c5d724912e04f538e6ad4203c81e9f57bc5fbccd35ecaa"),
+    # PR 71's own cell, pinned at the commit that brought it (a learned key selector in front of every core): the eight
+    # rows above are what they were before ``sparse_topk`` existed
+    "keye-vl-2_0-30b-a3b-1g": ((1, 16384), "c87f025a820a257463c30d8433ec11daf6b9bfafbcd299da0930e7e0b936303e"),
 }
 NEW_CELL = "ouro-2_6b-1g"
 

@@ -69,8 +69,13 @@ from jax.sharding import PartitionSpec as P
 from torchft_tpu.ops.attention import (
     attention,
     chunked_attention,
+    indexer_scores,
     ring_attention,
     ring_attention_local,
+    select_top,
+    selected_head_probs,
+    selected_softmax,
+    selection_kl,
 )
 from torchft_tpu.ops.kda import gdn_chunked, gdn_core, kda_chunked, short_conv, ssd_chunked, ssd_core
 from torchft_tpu.ops.layers import (
@@ -88,6 +93,7 @@ __all__ = [
     "PRESETS",
     "layer_pattern",
     "init_params",
+    "selections",
     "param_specs",
     "forward",
     "loss_fn",
@@ -301,6 +307,16 @@ class TransformerConfig:
     # the head reads the embedding table (logits = h·embedᵀ): there is no ``out`` leaf, and the table's gradient is
     # the sum of the head's and the lookup's
     tie_embeddings: bool = False
+    # -- a learned key selector in front of every ``full`` core (``sparse_topk`` = k > 0; 0 => none: the dense causal
+    # core). ``indexer_heads`` heads of ``indexer_head_dim`` lanes over ONE key head score every causal pair from the
+    # layer's normed input behind a ``stop_gradient``: ``I[t, s] = (heads·dim)^-1/2 · Σ_j w[t, j]·ReLU(q_j[t]·k[s])``,
+    # q and k rotated over their whole width (lane i with i + dim/2 at ``rope_theta``), float32; a query attends to the
+    # keys whose score is at least its k-th largest (every query head the same keys; all of them up to position k - 1),
+    # and the selector's three leaves are trained by ``KL(sg(p) ‖ softmax_selected(I))`` a query, p the mean over the
+    # query heads of the core's probabilities — the mean over positions, summed over layers, beside the next-token loss
+    sparse_topk: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
 
     def __post_init__(self) -> None:
         for name in (
@@ -387,6 +403,60 @@ class TransformerConfig:
             self._refuse_diffusion()
         self._refuse_ssd()
         self._refuse_multipliers()
+        if self.sparse_topk or self.indexer_heads or self.indexer_head_dim:
+            self._refuse_sparse()
+
+    def _refuse_sparse(self) -> None:
+        """What a learned key selector cannot run with yet, each by name."""
+        k = self.sparse_topk
+        if k < 1 or self.indexer_heads < 1 or self.indexer_head_dim < 2 or self.indexer_head_dim % 2:
+            raise ValueError(
+                f"sparse_topk={k}, indexer_heads={self.indexer_heads}, indexer_head_dim={self.indexer_head_dim}: a key "
+                "selector has all three, and its rotation pairs an even number of lanes"
+            )
+        if self.window or self.nope_layers or self.n_heads_per_layer:
+            raise ValueError(
+                f"sparse_topk={k} with a window, layers without positions or heads by layer: the selector stands in front of "
+                "``full`` layers of one kind; a selection inside a band (the k largest of the band's keys) and a selector "
+                "a declared kind are missing"
+            )
+        other = sorted({m for m, _ in self.layer_kinds()} - {"full"})
+        if other:
+            raise ValueError(
+                f"sparse_topk={k} with {other} layers: the selection enters the grouped-query core alone; a selector in front "
+                "of a latent core (its shared rotated key and per-head parts under one table of pairs) and a model that "
+                "mixes selected layers with linear, state-space or convolution mixers are missing"
+            )
+        if self.diffusion_block:
+            raise ValueError(
+                f"sparse_topk={k} with diffusion_block={self.diffusion_block}: the selection is causal over one run of "
+                "positions; a selector over [noised ; clean] (which half's keys a noised row ranks) is missing"
+            )
+        if self.ut_steps > 1:
+            raise ValueError(
+                f"sparse_topk={k} with ut_steps={self.ut_steps}: the selector's loss is a term a layer a step; what a looped "
+                "stack sums (a term a loop step over shared selector weights) is not defined"
+            )
+        if self.n_mtp_modules:
+            raise ValueError(
+                f"sparse_topk={k} with a multi-token-prediction module: the module's layer would select over the shifted "
+                "stream with a selector of its own; its leaves and its term in the loss are missing"
+            )
+        if max(self.pp, 1) > 1:
+            raise ValueError(
+                f"sparse_topk={k} with pp={self.pp}: the selector's loss does not cross pipeline stages (what the layers say "
+                "is dropped there, _pipeline_stage_fn) and the selection's kernels cannot run in the manual region"
+            )
+        if self.attention_impl == "chunked":
+            raise ValueError(
+                f"sparse_topk={k} with attention_impl='chunked': ops/attention.chunked_attention scans q blocks against a "
+                "causal prefix; a table of selected pairs a block is missing there"
+            )
+        if self.attn_output_gate or self.attn_scale or self.sandwich_norm:
+            raise ValueError(
+                f"sparse_topk={k} with attn_output_gate, attn_scale or sandwich_norm: the selector's target reads the core's "
+                "probabilities at head_dim**-0.5 from an ungated, un-normed branch; the other three are missing"
+            )
 
     def _refuse_ssd(self) -> None:
         """What a state-space mixer cannot run with yet, each by name."""
@@ -652,6 +722,12 @@ def _init_layers(rng, cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple
             layers.update(q_norm=unit(cfg.head_dim), k_norm=unit(cfg.head_dim))
         elif cfg.qk_norm:
             layers.update(q_norm=unit(qkv), k_norm=unit(kv))
+        if cfg.sparse_topk:  # the selector's three projections: I is O(1) at normal / sqrt(fan_in)
+            hi, di = cfg.indexer_heads, cfg.indexer_head_dim
+            layers.update(
+                idx_wq=dense(next(more), d, hi * di, fan_in=d), idx_wk=dense(next(more), d, di, fan_in=d),
+                idx_ww=dense(next(more), d, hi, fan_in=d),
+            )
     elif mixer == "kda":
         hd, taps = cfg.linear_head_dim, cfg.conv_kernel
         ch = cfg.linear_n_heads * hd
@@ -863,6 +939,8 @@ def _layer_specs(cfg: TransformerConfig, kind: Tuple[str, str], lead: Tuple[Any,
         elif cfg.qk_norm:
             # over the tp-sharded projection: the norm's mean is one all-reduce
             layers.update(q_norm=spec("tp"), k_norm=spec("tp"))
+        if cfg.sparse_topk:  # the selector's heads over tp as the core's; its one key head and the weights whole
+            layers.update(idx_wq=row, idx_wk=spec("fsdp", None), idx_ww=spec("fsdp", None))
     elif mixer == "kda":
         # heads over tp, as full attention's; the narrow side of the low-rank gates whole
         layers.update(
@@ -1283,6 +1361,7 @@ def _use_chunked(cfg: TransformerConfig, seq_len: int) -> bool:
 def _attention_path(
     cfg: TransformerConfig, seq_len: int, batch: int, mesh, sp_manual: bool = False,
     widths: Optional[Tuple[int, int]] = None, window: int = 0, grouped: bool = False, diffusion: bool = False,
+    sparse: bool = False,
 ) -> Tuple[str, str, Optional[Tuple[int, int]]]:
     """(impl, reason, (block_q, block_k) or None): which code computes the
     causal core softmax(QKᵀ)V of a layer, decided from what can be
@@ -1297,12 +1376,23 @@ def _attention_path(
     a block-diffusion sequence ``[noised ; clean]`` (``seq_len`` counts both
     halves): the kernel where "auto" takes it at the HALF's length (its tiles
     divide a half) or it is asked for, else plain attention under the dense
-    staircase mask — the ring and the chunked scan do not know the rule."""
+    staircase mask — the ring and the chunked scan do not know the rule.
+    ``sparse``: the core runs under a learned selection (``sparse_topk``): the
+    kernel under the table of selected pairs where "auto" takes it or it is
+    asked for, else plain attention under the dense table (:func:`_sparse_path`)."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
+    if sparse and sp_size > 1:
+        raise ValueError(
+            f"sp={sp_size} with sparse_topk={cfg.sparse_topk}: ring attention (ops/attention.ring_attention_local) passes "
+            "every K/V block to every shard; a selection under the ring — a query's k largest over ALL shards' keys (a "
+            "threshold agreed across the ring) and the selector's scores against keys a shard does not hold — is missing"
+        )
     if batch == 0:  # a batch of one sequence sliced past its end (benchmark/worker.py's second sequence): no kernel has a grid of none
         return "plain", "an empty batch", None
     if diffusion:
         return _diffusion_path(cfg, seq_len, batch, mesh, sp_manual, sp_size)
+    if sparse:
+        return _sparse_path(cfg, seq_len, batch, mesh)
     if sp_size > 1 and (window or grouped):
         raise ValueError(
             f"sp={sp_size} with a window ({window}) or grouped-query heads: ring attention "
@@ -1374,6 +1464,26 @@ def _diffusion_path(cfg: TransformerConfig, rows: int, batch: int, mesh, sp_manu
     return "plain", "the staircase as a dense mask", None
 
 
+def _sparse_path(cfg: TransformerConfig, seq_len: int, batch: int, mesh):
+    """:func:`_attention_path` of a core under a learned selection: the kernels
+    (the selection as a table of int8 tiles in the flash kernel's walk, the
+    selector's scores and its target a q block at a time) where "auto" takes
+    the kernel for the dense core or it is asked for; else dense tables."""
+    if mesh is not None and any(n > 1 for n in mesh.shape.values()):
+        raise ValueError(
+            f"sparse_topk={cfg.sparse_topk} on a mesh of {dict(mesh.shape)}: the selection's kernels run on one chip's "
+            "whole batch and heads; their manual region over dp / fsdp / tp (the table of pairs split by batch, the "
+            "selector's target summed over a shard's heads and then over tp) is missing"
+        )
+    fast = _flash_blocks(seq_len, cfg.head_dim)
+    if cfg.attention_impl == "auto" and fast is not None and jax.default_backend() == "tpu":
+        return "flash", "auto on a tpu: the kernel's dense walk under the table of selected pairs", fast
+    if _use_flash(cfg, seq_len, batch, mesh) and seq_len % 128 == 0:
+        why = "attention_impl" if cfg.attention_impl == "flash" else "auto: the dense table's scores would not fit the chip"
+        return "flash", why, fast or (128, 128)
+    return "plain", "the selection as a dense table", None
+
+
 def _live_tiles(rows: int, blocks: Optional[Tuple[int, int]]) -> Tuple[int, int]:
     """(tiles the staircase kernel visits, tiles of the square) a head over
     ``rows`` = 2·S rows at ``blocks``: a noised q tile its own and the clean
@@ -1405,7 +1515,9 @@ def _say_once(kind: str, key, **fields) -> None:
     )
 
 
-def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, kind=None, latent=None, diffusion=False) -> None:
+def _say_attention_path(
+    impl, reason, blocks, batch, seq_len, cfg, widths=None, kind=None, latent=None, diffusion=False, sparse=False,
+) -> None:
     """One ``attention_path`` event and one INFO line per traced shape — and
     per KIND of layer where a model declares a band or grouped heads
     (``kind``: its (query heads, key/value heads, window, rotated lanes)); a
@@ -1426,6 +1538,13 @@ def _say_attention_path(impl, reason, blocks, batch, seq_len, cfg, widths=None, 
     if diffusion:  # ``seq`` counts both halves; the tiles the kernel visits of the square's, a head
         live, square = _live_tiles(seq_len, blocks if impl == "flash" else None)
         fields.update(diffusion_block=cfg.diffusion_block, live_tiles=live, tiles=square)
+    if sparse:  # the selector, and how its selection reaches the core
+        fields.update(
+            sparse_topk=cfg.sparse_topk, indexer=f"{cfg.indexer_heads}x{cfg.indexer_head_dim}",
+            selection="int8 tiles [S/block_q, B, S, block_q] in the kernel's walk, dead tiles skipped" if impl == "flash"
+            else "a dense [B, S, S] table",
+            threshold="bisected on the float32 bits, exact",
+        )
     _say_once("attention_path", (*fields.values(), heads), **fields)
 
 
@@ -1481,6 +1600,8 @@ def _say_layer_pattern(cfg: TransformerConfig, batch: int, seq_len: int) -> None
         fields.update(mtp=_kind_key(kinds[-1]), mtp_weight=cfg.mtp_loss_weight)
     if cfg.conv_layers:
         fields.update(conv_kernel=cfg.conv_kernel)
+    if cfg.sparse_topk:
+        fields.update(sparse_topk=cfg.sparse_topk, indexer_heads=cfg.indexer_heads, indexer_head_dim=cfg.indexer_head_dim)
     if cfg.ssd_layers:
         fields.update(
             ssd_heads=cfg.ssd_n_heads, ssd_head_dim=cfg.ssd_head_dim, ssd_state=cfg.ssd_state_dim, conv_kernel=cfg.conv_kernel,
@@ -1587,7 +1708,9 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
     trace it is ``global`` too, and its ``attention_path`` line says
     ``rotary_dim`` 0); under ``attn_output_gate`` the query
     projection carries a gate a lane, ``[q | gate]`` head by head, and the
-    core's output goes through ``sigmoid(gate)`` (scope ``out_gate``)."""
+    core's output goes through ``sigmoid(gate)`` (scope ``out_gate``). Under
+    a learned selection (``sparse_topk``) the core is :func:`_sparse_core` and
+    the mixer says ``(output, what the selector said)``."""
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
     b, s, _ = h.shape  # s is the sp-local block inside a manual region
     if sp_manual and sp_size > 1:
@@ -1602,6 +1725,8 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
     name = ("window" if mixer == "window" else "global") if _declares_kinds(cfg) else None
     if cfg.diffusion_block:
         name = "blockdiff"
+    if cfg.sparse_topk:  # a kind of its own too: attn/sparse with indexer, select, sparse_core and indexer_loss inside
+        name = "sparse"
     with jax.named_scope(name) if name else contextlib.nullcontext():
         q, k = h @ lp["wq"], h @ lp["wk"]
         if cfg.attn_output_gate:
@@ -1624,6 +1749,9 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
             k = rotary_embed(k, positions, **rotation)
         if cfg.attn_scale:  # every core scales by head_dim**-0.5: the query carries the rest (a power of two here: exact)
             q = q * jnp.asarray(cfg.attn_scale * cfg.head_dim**0.5, q.dtype)
+        if cfg.sparse_topk:
+            att, said = _sparse_core(cfg, mesh, sp_manual, lp, h, q, k, v, positions)
+            return att.reshape(b, s, heads * cfg.head_dim) @ lp["wo"], said
         said = {}
         if name:
             rotated = (
@@ -1636,6 +1764,165 @@ def _mix_full(cfg, mesh, sp_manual, lp, h, mixer="full"):
             with jax.named_scope("out_gate"):
                 att = att * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(att.dtype)
         return att @ lp["wo"]
+
+
+def _sparse_core(cfg, mesh, sp_manual, lp, h, q, k, v, positions):
+    """(softmax(QKᵀ)V over the keys a learned selector picks, what the selector
+    said): ``q`` [B, S, H, D], ``k``, ``v`` [B, S, Hkv, D] rotated, ``h`` the
+    layer's normed input. Four scopes inside ``attn/sparse``:
+
+    * ``indexer`` — the selector's three projections from ``stop_gradient(h)``,
+      its rotation, and the scores ``I`` (float32, 512 queries at a time on the
+      kernel path: ``ops/pallas/indexer``);
+    * ``select`` — a query's threshold, the k-th largest causal score, exact
+      (``ops.attention.select_top``), and the table of selected pairs;
+    * ``sparse_core`` — the core under the table (the flash kernel's walk, or
+      plain attention under the dense table), forward and backward;
+    * ``indexer_loss`` — the target ``p`` (the mean over the query heads of the
+      core's probabilities on the selected pairs, detached) and the mean over
+      positions of ``KL(p ‖ softmax_selected(I))``, whose gradient reaches the
+      selector's three leaves and nothing else.
+
+    Says ``indexer_kl`` (the term, differentiated), ``selected_mean`` (keys a
+    query), ``selected_over`` (rows a tie gave more than k) and ``tiles_live``
+    (of the causal key tiles of the kernel's walk, the share that hold a
+    selected pair)."""
+    b, s = q.shape[:2]
+    hi, di, top = cfg.indexer_heads, cfg.indexer_head_dim, cfg.sparse_topk
+    impl, why, blocks = _attention_path(cfg, s, b, mesh, sp_manual, sparse=True)
+    _say_attention_path(impl, why, blocks, b, s, cfg, kind=(q.shape[2], k.shape[2], 0, cfg.head_dim), sparse=True)
+    hs = jax.lax.stop_gradient(h)
+    with jax.named_scope("indexer"):
+        table = {"inv_freq": yarn_inv_freq(di, cfg.rope_theta)}  # lane i with lane i + di/2, the whole width
+        q_i = rotary_embed((hs @ lp["idx_wq"]).reshape(b, s, hi, di), positions, **table)
+        k_i = rotary_embed((hs @ lp["idx_wk"]).reshape(b, s, 1, di), positions, **table)[:, :, 0]
+        w = jnp.einsum("bsd,dj->bsj", hs, lp["idx_ww"], preferred_element_type=jnp.float32) * (hi * di) ** -0.5
+    if impl == "flash":
+        att, said = _sparse_tiles(cfg, blocks, q, k, v, q_i, k_i, w)
+        return att, {**said, "selector_input": h} if _SAY_SELECTION else said
+    tile = min(512, s)  # the statistics' tile where no kernel walks one
+    with jax.named_scope("indexer"):
+        scores = indexer_scores(q_i, k_i, w)
+    with jax.named_scope("select"):
+        seen, _, over = select_top(scores, jnp.tril(jnp.ones((s, s), bool)), top, -1)
+        live = jnp.any(seen.reshape(b, s // tile, tile, s // tile, tile), axis=(2, 4)) if s % tile == 0 and b else None
+    with jax.named_scope("sparse_core"):
+        att = attention(q, k, v, selected=seen)
+    with jax.named_scope("indexer_loss"):
+        p = selected_head_probs(jax.lax.stop_gradient(q), jax.lax.stop_gradient(k), seen)
+        kl = jnp.mean(selection_kl(scores, p, seen))
+    said = _selector_said(kl, jnp.sum(seen, axis=-1), over, live, b * (s // tile))
+    return att, {**said, "selected": seen, "selector_input": h} if _SAY_SELECTION else said
+
+
+# set by :func:`selections` while it traces: the layers then also say their table of selected pairs and what the selector read
+_SAY_SELECTION = False
+
+
+def _selector_said(kl, taken, over, live, q_tiles: int):
+    """What :func:`_sparse_core` says, from the term, the keys each query took,
+    the rows over k and which tiles are live (None: no whole tiles) of
+    ``q_tiles`` q tiles over all sequences — tile i of a sequence has i + 1
+    causal key tiles, so the square's causal tiles are ``q_tiles·(n + 1)/2``
+    at n tiles a side."""
+    share = jnp.float32(0.0)
+    if live is not None and q_tiles:
+        share = jnp.sum(live.astype(jnp.float32)) / (q_tiles * (live.shape[-1] + 1) / 2)
+    return dict(
+        indexer_kl=kl, selected_mean=jnp.mean(taken.astype(jnp.float32)),
+        selected_over=jnp.sum(over.astype(jnp.float32)), tiles_live=share,
+    )
+
+
+def _sparse_tiles(cfg, blocks, q, k, v, q_i, k_i, w):
+    """:func:`_sparse_core` on the kernel path, ``block_q`` queries at a time:
+    one scan gives each q block's scores (``indexer_scores_t``), threshold and
+    int8 tiles of the table; the flash kernel runs under the table and hands on
+    its row statistics; a second scan (:func:`_indexer_kl`) gives the term."""
+    from torchft_tpu.ops.pallas.flash_attention import flash_attention, tiles_live
+    from torchft_tpu.ops.pallas.indexer import indexer_scores_t
+
+    b, s = q.shape[:2]
+    bq, bk = (min(x, s) for x in blocks)
+    top = cfg.sparse_topk
+    with jax.named_scope("indexer"):
+        q_t, w_t = q_i.transpose(0, 2, 1, 3), w.transpose(0, 2, 1)  # head-major: a head's rows are a kernel's block
+
+    detached = jax.lax.stop_gradient((q_t, k_i, w_t))  # nothing of the selection is differentiated
+
+    def one(i):
+        with jax.named_scope("indexer"):
+            scores = indexer_scores_t(*detached, i, bq, bk)  # [B, S, bq]
+        with jax.named_scope("select"):
+            causal = jnp.arange(s)[:, None] <= i * bq + jnp.arange(bq)[None, :]
+            seen, _, over = select_top(scores, causal, top, 1)
+            return seen.astype(jnp.int8), jnp.sum(seen, axis=1, dtype=jnp.int32), over
+
+    selected, taken, over = jax.lax.map(one, jnp.arange(s // bq))  # [nq, B, S, bq] int8, [nq, B, bq] twice
+    with jax.named_scope("select"):
+        live = tiles_live(selected, bk)
+    with jax.named_scope("sparse_core"):
+        att, lse = flash_attention(q, k, v, block_q=bq, block_k=bk, selected=selected, live=live)
+    with jax.named_scope("indexer_loss"):
+        kl = _indexer_kl(q_t, k_i, w_t, q, k, lse, selected, live, bk)
+    said = _selector_said(kl, taken, over, live, b * (s // bq))
+    if _SAY_SELECTION:  # the tiles back as a dense table [B, Sq, Sk]
+        said["selected"] = selected.transpose(1, 0, 3, 2).reshape(b, s, s) != 0
+    return att, said
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _indexer_kl(q_t, k_i, w_t, q, k, lse, selected, live, bk):
+    """The mean over sequences and positions of ``KL(p ‖ softmax_selected(I))``,
+    a q block at a time: ``I`` again from the selector's rotated projections
+    (``q_t`` [B, Hi, S, Di], ``k_i`` [B, S, Di], ``w_t`` [B, Hi, S]), ``p`` from
+    the core's q, k and row statistics (``head_probs``), on the pairs of
+    ``selected``. Differentiable in the selector's three alone: the forward
+    pass emits their closed-form cotangents (``∂/∂I = (softmax(I) - p)/(B·S)``
+    on the selected pairs, through ``indexer_scores_bwd_t``), so no block's
+    scores or probabilities are kept for a backward pass."""
+    return _indexer_kl_blocks(q_t, k_i, w_t, q, k, lse, selected, live, bk, False)[0]
+
+
+def _indexer_kl_blocks(q_t, k_i, w_t, q, k, lse, selected, live, bk, with_grads: bool):
+    from torchft_tpu.ops.pallas.flash_attention import head_probs, scaled_head_major
+    from torchft_tpu.ops.pallas.indexer import indexer_scores_bwd_t, indexer_scores_t
+
+    nq, b, s, bq = selected.shape
+    q_m, k_m = scaled_head_major(q), k.transpose(0, 2, 1, 3)
+
+    def one(carry, i):
+        total, dk = carry
+        seen = selected[i] != 0
+        scores = indexer_scores_t(q_t, k_i, w_t, i, bq, bk)
+        p = head_probs(q_m, k_m, lse, selected, live, i, bk)
+        soft, log_soft = selected_softmax(scores, seen, 1)
+        total = total + jnp.sum(jnp.where(seen & (p > 0), p * (jnp.log(jnp.maximum(p, 1e-38)) - log_soft), 0.0))
+        if not with_grads:
+            return (total, dk), None
+        g = (soft - p) / (b * s)  # both are zero off the selection
+        dq_blk, dk_blk, dw_blk = indexer_scores_bwd_t(q_t, k_i, w_t, g, i, bk)
+        return (total, dk + dk_blk), (dq_blk, dw_blk)
+
+    (total, dk), blocks = jax.lax.scan(one, (jnp.float32(0.0), jnp.zeros(k_i.shape, jnp.float32)), jnp.arange(nq))
+    if not with_grads:
+        return total / (b * s), None
+    dq, dw = blocks  # [nq, B, Hi, bq, Di], [nq, B, Hi, bq]: block-major back to [B, Hi, S, ...]
+    dq = dq.transpose(1, 2, 0, 3, 4).reshape(q_t.shape)
+    dw = dw.transpose(1, 2, 0, 3).reshape(w_t.shape)
+    return total / (b * s), (dq.astype(q_t.dtype), dk.astype(k_i.dtype), dw.astype(w_t.dtype))
+
+
+def _indexer_kl_fwd(q_t, k_i, w_t, q, k, lse, selected, live, bk):
+    return _indexer_kl_blocks(q_t, k_i, w_t, q, k, lse, selected, live, bk, True)
+
+
+def _indexer_kl_bwd(bk, grads, g):
+    # the target is detached and the table is no number: the core's q, k and row statistics and the table get nothing
+    return (*((g * d.astype(jnp.float32)).astype(d.dtype) for d in grads), None, None, None, None, None)
+
+
+_indexer_kl.defvjp(_indexer_kl_fwd, _indexer_kl_bwd)
 
 
 def _mix_mla(cfg, mesh, sp_manual, lp, h):
@@ -2033,7 +2320,11 @@ def _make_layer_fn(
                 gate, aux = {"gate": chosen}, dict(zip(("balance", "counts"), load))
         with _scopes("attn", nested):
             if mixer in _SOFTMAX_MIXERS:
-                x = join(x, part(mix(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer)))(lp, h))
+                y = part(mix(functools.partial(_mix_full, cfg, mesh, sp_manual, mixer=mixer)))(lp, h)
+                if cfg.sparse_topk:  # the mixer says what its selector said beside its output
+                    y, said = y
+                    aux = {**aux, **said}
+                x = join(x, y)
             elif mixer == "kda":
                 x = join(x, mix(functools.partial(_mix_kda, cfg))(lp, h))
             elif mixer in ("gdn", "ssd"):
@@ -2406,6 +2697,24 @@ def forward(
     return _scaled_logits(cfg, (x @ _out_table(params, cfg).astype(cfg.dtype)).astype(jnp.float32))
 
 
+def selections(params: Dict[str, Any], tokens: jnp.ndarray, cfg: TransformerConfig, mesh=None, with_inputs: bool = False):
+    """[L, B, S, S] bool: the pairs each layer's learned selector picked in a
+    forward pass over ``tokens`` (``sparse_topk`` > 0) — row t of a layer is the
+    set of keys query t attended to — by the path the step itself takes (the
+    kernel path's tiles, turned back into a dense table). ``with_inputs``: and
+    what each layer's selector read, the layer's normed input [L, B, S, d] in
+    the compute dtype. For the checks that compare the program's sets with a
+    reference's (from the same input: what differs is the selector's own
+    rounding) and hand them to it."""
+    global _SAY_SELECTION
+    _SAY_SELECTION = True
+    try:
+        said = _hidden_states(params, tokens, cfg, mesh)[1]
+    finally:
+        _SAY_SELECTION = False
+    return (said["selected"], said["selector_input"]) if with_inputs else said["selected"]
+
+
 def _out_table(params: Dict[str, Any], cfg: TransformerConfig) -> jnp.ndarray:
     """The head's table [d, V]: the ``out`` leaf, or under ``tie_embeddings``
     the embedding's transpose — the head's cotangent then arrives as one
@@ -2504,6 +2813,12 @@ def _loss_of_hidden(params: Dict[str, Any], x: jnp.ndarray, aux, tokens: jnp.nda
             stats[said] = aux[name]
     if "ssd_state_rms" in aux:  # of the LAST state-space mixer's final state
         stats["ssd_state_rms"] = aux["ssd_state_rms"][-1]
+    if "indexer_kl" in aux:  # a learned selector: its term a layer, summed beside the next-token loss at weight one
+        stats.update(
+            lm_loss=ce, indexer_loss=aux["indexer_kl"], selected_mean=jnp.mean(aux["selected_mean"]),
+            selected_over_k=jnp.sum(aux["selected_over"]), sparse_tiles_live_share=aux["tiles_live"],
+        )
+        ce = ce + jnp.sum(aux["indexer_kl"])
     if "balance" not in aux:
         return ce, stats
     balance = jnp.mean(aux["balance"])
@@ -2718,7 +3033,8 @@ def _cross_entropy(
     # already sharded and a global-s scan would fight that sharding: the
     # dense path stays (its per-device logits are S/sp smaller), so scale
     # very long context under sp by adding sp shards, not chunking.
-    if sp == 1 and _per_device_logit_elems(cfg, b, s, mesh) > _LOSS_CHUNK_ELEMS:
+    # (an empty batch — benchmark/worker.py's second slice of a batch of one — has no logits to chunk: the dense path)
+    if sp == 1 and b and _per_device_logit_elems(cfg, b, s, mesh) > _LOSS_CHUNK_ELEMS:
         return _chunked_loss(params, x, tokens, cfg, mesh, ahead, probs)
     with _scopes("head_loss", nested):
         logits = _scaled_logits(cfg, (x @ _out_table(params, cfg).astype(cfg.dtype)).astype(jnp.float32))

@@ -26,8 +26,14 @@ __all__ = [
     "attention",
     "block_diffusion_mask",
     "chunked_attention",
+    "indexer_scores",
+    "kth_largest",
     "ring_attention",
     "ring_attention_local",
+    "select_top",
+    "selected_head_probs",
+    "selected_softmax",
+    "selection_kl",
 ]
 
 _NEG_INF = -1e30
@@ -73,6 +79,7 @@ def attention(
     causal: bool = True,
     window: Optional[int] = None,
     block_diffusion: int = 0,
+    selected: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Plain attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh]; the values
     may have another width than the keys (the output's), and k and v fewer
@@ -80,13 +87,18 @@ def attention(
     head), here and in :func:`chunked_attention`. ``window`` (causal only):
     position i sees the keys j with i - window < j <= i. ``block_diffusion``
     (in place of both): the S rows are ``[noised ; clean]`` under
-    :func:`block_diffusion_mask`."""
+    :func:`block_diffusion_mask`. ``selected`` (in place of all three): [B, Sq,
+    Sk], True where the query sees the key — every head of a query the same
+    keys; the table holds causality and carries no gradient."""
     assert causal or not window, "a band is causal"
     assert not (block_diffusion and window), "the staircase has no band"
+    assert selected is None or not (window or block_diffusion), "the table is the whole rule"
     k, v = _each_query_head(q, k), _each_query_head(q, v)
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if block_diffusion:
+    if selected is not None:
+        scores = jnp.where(selected[:, None], scores, _NEG_INF)
+    elif block_diffusion:
         scores = jnp.where(block_diffusion_mask(q.shape[1] // 2, block_diffusion)[None, None], scores, _NEG_INF)
     elif causal:
         s = q.shape[1]
@@ -94,6 +106,82 @@ def attention(
         scores = jnp.where(_causal_mask(pos, pos, window)[None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def indexer_scores(q_i: jnp.ndarray, k_i: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """A key selector's scores ``I[b, t, s] = Σ_j w[b, t, j]·ReLU(q_i[b, t, j]·k_i[b, s])``, float32 [B, Sq, Sk]:
+    ``q_i`` [B, Sq, Hi, Di] over ONE key head ``k_i`` [B, Sk, Di], ``w`` [B, Sq, Hi] float32 (any constant is on it).
+    Products in the operands' dtype, accumulated in float32; every pair, causal or not."""
+    x = jnp.einsum("btjd,bsd->bjts", q_i, k_i, preferred_element_type=jnp.float32)
+    return jnp.einsum("bjts,btj->bts", jax.nn.relu(x), w.astype(jnp.float32))
+
+
+def _sortable(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> uint32 in the same order (-0.0 just under +0.0); a finite value or an infinity is above 0."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    return jax.lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+def _unsortable(u: jnp.ndarray) -> jnp.ndarray:
+    key = jax.lax.bitcast_convert_type(u ^ jnp.uint32(0x80000000), jnp.int32)
+    return jax.lax.bitcast_convert_type(key ^ ((key >> 31) & jnp.int32(0x7FFFFFFF)), jnp.float32)
+
+
+def kth_largest(u: jnp.ndarray, k: int, axis: int) -> jnp.ndarray:
+    """The ``k``-th largest of the uint32 keys ``u`` along ``axis`` — the largest T with ``count(u >= T) >= k`` — and
+    0 where fewer than k keys are above 0: exact, by a search over T's bits from the top, four bits a pass (15
+    thresholds counted in one reduction over the keys: eight reads of ``u`` in all, where a sort moves it
+    log² S times and ``approx_max_k`` has a recall under one)."""
+    axis %= u.ndim
+    tau = jnp.zeros(u.shape[:axis] + u.shape[axis + 1:], jnp.uint32)
+    digits = jnp.arange(1, 16, dtype=jnp.uint32).reshape((15,) + (1,) * tau.ndim)
+    for shift in range(28, -1, -4):
+        above = u[None] >= jnp.expand_dims(tau[None] | (digits << shift), axis + 1)
+        counts = jnp.sum(above, axis=axis + 1, dtype=jnp.int32)  # [15, ...]: falls as the threshold rises
+        tau = tau | (jnp.sum(counts >= k, axis=0).astype(jnp.uint32) << shift)
+    return tau
+
+
+def select_top(scores: jnp.ndarray, causal: jnp.ndarray, k: int, axis: int):
+    """(seen, tau, over): the pairs a selector's float32 ``scores`` select — the causal keys whose score is at least
+    the row's ``k``-th largest causal score, every causal key of a row with no more than k of them; ties at the
+    threshold select more than k. ``causal``: bool, broadcast against ``scores``; ``axis``: the keys'. ``tau`` is the
+    threshold a row (-inf where every causal key is taken), ``over`` whether a row took more than ``min(k, its causal
+    keys)``. Nothing here is differentiated."""
+    scores = jax.lax.stop_gradient(scores)
+    scores = jnp.where(scores == 0.0, 0.0, scores)  # -0.0 (a negative weight on a ReLU's zero) ties with +0.0, as floats compare
+    u = jnp.where(causal, _sortable(scores), jnp.uint32(0))
+    tau = kth_largest(u, k, axis)
+    seen = (u >= jnp.expand_dims(tau, axis)) & causal
+    taken = jnp.sum(seen, axis=axis, dtype=jnp.int32)
+    allowed = jnp.minimum(jnp.sum(jnp.broadcast_to(causal, u.shape), axis=axis, dtype=jnp.int32), k)
+    return seen, jnp.where(tau == 0, -jnp.inf, _unsortable(tau)), taken > allowed
+
+
+def selected_head_probs(q: jnp.ndarray, k: jnp.ndarray, seen: jnp.ndarray) -> jnp.ndarray:
+    """``p[b, t, s]`` float32: the mean over the query heads of each head's softmax over the keys ``seen`` [B, Sq, Sk]
+    lets query t see — a selector's target, which sums to one over a row's keys."""
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, _each_query_head(q, k)) * q.shape[-1] ** -0.5
+    probs = jax.nn.softmax(jnp.where(seen[:, None], scores.astype(jnp.float32), _NEG_INF), axis=-1)
+    return jnp.where(seen, jnp.mean(probs, axis=1), 0.0)
+
+
+def selected_softmax(scores: jnp.ndarray, seen: jnp.ndarray, axis: int = -1):
+    """(softmax, log softmax) of a selector's float32 ``scores`` over the keys ``seen`` lets a query see, along
+    ``axis``; zero and the floor elsewhere."""
+    logits = jnp.where(seen, scores, _NEG_INF)
+    top = jnp.max(logits, axis=axis, keepdims=True)
+    soft = jnp.where(seen, jnp.exp(logits - top), 0.0)
+    norm = jnp.sum(soft, axis=axis, keepdims=True)
+    return soft / norm, logits - top - jnp.log(norm)
+
+
+def selection_kl(scores: jnp.ndarray, p: jnp.ndarray, seen: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """``Σ_s p·(log p - log softmax_seen(scores))`` a query: the KL divergence of a selector's softmax over the keys
+    it selected from the target ``p`` (zero off the selection, detached by the caller), float32, ``axis`` the keys'."""
+    log_soft = selected_softmax(scores, seen, axis)[1]
+    return jnp.sum(jnp.where(seen & (p > 0), p * (jnp.log(jnp.maximum(p, 1e-38)) - log_soft), 0.0), axis=axis)
 
 
 def chunked_attention(

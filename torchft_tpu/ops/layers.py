@@ -510,6 +510,9 @@ def moe_dropless_held(
     balance half the window."""
     t, k = top_idx.shape
     held = w_gate.shape[0]
+    if t == 0:  # a batch of one sequence sliced past its end (benchmark/worker.py's second sequence): no row, no window
+        nothing = (jnp.zeros(tokens.shape, tokens.dtype), jnp.int32(0))
+        return (*nothing, jnp.float32(0.0)) if gate_zeros else nothing
     m = min(row_bound, t * k)
     with jax.named_scope("dispatch"):
         local = top_idx.reshape(t * k) - first_expert

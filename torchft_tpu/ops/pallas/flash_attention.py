@@ -37,6 +37,17 @@ at n tiles a half, 288 of 1 024 at 512 x 512 and S 8192 for S² + 4S pairs =
 staircase or a block's edge crosses. The whole sequence is one resident K
 block there (a longer one is refused by name).
 
+A selection (``selected``): the pairs a query sees arrive as a table of int8
+tiles, transposed as the scores are — ``selected[i, b, s, t]`` says whether
+query ``i·block_q + t`` of sequence b sees key s; the table holds causality,
+so no tile builds a mask of its own — with, a tile, whether any of its pairs is
+seen (``live``, in SMEM): the walk stops at the diagonal and steps over a tile
+in which none is (:func:`_over_selected_tiles`). The whole sequence is one
+resident K block there, and the grids keep the query heads of a key/value
+group on consecutive steps, so the group reads a q block's tiles of the table
+once. :func:`head_probs` walks the same tiles for the probabilities of the
+selected pairs summed over the query heads, from the forward's row statistics.
+
 Grouped queries: k and v may have fewer heads than q. In the forward the
 grid runs over query heads and a group's heads read the one K/V block of
 head ``h // group`` in place (consecutive steps, so it stays resident); the
@@ -102,7 +113,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "CORE_OUT", "CORE_LSE"]
+__all__ = ["flash_attention", "head_probs", "selected_tiles", "tiles_live", "CORE_OUT", "CORE_LSE"]
 
 # ``checkpoint_name``s of what only the forward kernel can give the backward: its output and its rows' logsumexp
 CORE_OUT = "attn_core_out"
@@ -204,10 +215,32 @@ def _over_staircase_tiles(row0, bq, nc, bkc, half, block, step) -> None:
     jax.lax.fori_loop(whole, jnp.minimum((half + q0 + bq + bkc - 1) // bkc, nc), lambda c, _: step(c, before(c)), None)
 
 
-def _walk(i, j, bq, bk, bkc, causal, window, diffusion, step) -> None:
+def _over_selected_tiles(row0, bq, nc, bkc, sel_ref, live, step) -> None:
+    """Run ``step(c, seen)`` for the tiles c of ``bkc`` keys, of the ``nc``
+    resident, that hold a pair the q rows ``[row0, row0 + bq)`` see under a
+    selection: those up to the diagonal (the table holds causality: none is
+    seen past it) for which ``live(c)`` is not zero; ``seen`` reads the tile of
+    the table, which is every tile's mask. A row whose keys all lie in later
+    tiles meets a tile of its own with nothing seen: its running maximum stays
+    at the floor, and the first key it does see wipes what it summed there."""
+    reached = jnp.clip((row0 + bq - 1 + bkc) // bkc, 0, nc)
+
+    def visit(c, _):
+        @pl.when(live(c) != 0)
+        def _seen():
+            step(c, lambda: sel_ref[pl.ds(pl.multiple_of(c * bkc, bkc), bkc), :].astype(jnp.int32) != 0)
+
+    jax.lax.fori_loop(0, reached, visit, None)
+
+
+def _walk(i, j, bq, bk, bkc, causal, window, diffusion, step, sel=None) -> None:
     """``step(c, seen)`` over the key tiles of grid step (q block i, k block j)
-    under the call's rule: the staircase of block diffusion, or the causal /
-    banded / full walk of :func:`_over_key_tiles`."""
+    under the call's rule: the staircase of block diffusion, the tiles a
+    selection (``sel``: the table's block and ``live``) leaves live, or the
+    causal / banded / full walk of :func:`_over_key_tiles`."""
+    if sel is not None:
+        _over_selected_tiles(i * bq, bq, bk // bkc, bkc, *sel, step)
+        return
     if diffusion:
         _over_staircase_tiles(i * bq, bq, bk // bkc, bkc, *diffusion, step)
         return
@@ -226,17 +259,35 @@ def _scaled(q_ref, scale):
 # ---------------------------------------------------------------------------
 
 
+def _sel_of(sel_ref, live_ref, i):
+    # the walk's view of a selection: the q block's tiles of the table and, a key tile, whether it holds a seen pair
+    b = pl.program_id(0)  # read here: the interpreter knows a grid index at the kernel's top level alone
+    return sel_ref, lambda c: live_ref[i, b, c]
+
+
+def _at(selected: bool, cond):
+    """``pl.when(cond())``, or under a selection — one K block, so every grid
+    step starts and ends its rows — the call itself."""
+    return (lambda fn: fn()) if selected else pl.when(cond())
+
+
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, bq, bk, bkc, scale, causal, window=None, diffusion=None,
+    q_ref, k_ref, v_ref, *rest,
+    bq, bk, bkc, scale, causal, window=None, diffusion=None, selected=False,
 ):
     # transposed scores [bkc, bq]: the running max and sum are lane rows
     # [1, bq] — as a [bq, 1] column each of their updates costs a pass over a
     # [bq, 128] array, more than the scores' own at 512 keys — and the output
     # accumulates transposed, [d, bq], turned once per q block
+    sel = None
+    if selected:  # grid (b, key/value head, nq, the group's heads)
+        sel_ref, live_ref, *rest = rest
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     i, j = pl.program_id(2), pl.program_id(3)
+    if selected:
+        j, sel = 0, _sel_of(sel_ref, live_ref, i)
 
-    @pl.when(j == 0)
+    @_at(selected, lambda: j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -260,9 +311,9 @@ def _fwd_kernel(
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    _walk(i, j, bq, bk, bkc, causal, window, diffusion, step)
+    _walk(i, j, bq, bk, bkc, causal, window, diffusion, step, sel)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @_at(selected, lambda: j == pl.num_programs(3) - 1)
     def _finish():
         l = jnp.maximum(l_ref[:1, :], 1e-30)
         o_ref[...] = (acc_ref[...] / l).T.astype(o_ref.dtype)
@@ -278,12 +329,19 @@ def _fwd_kernel(
 
 
 def _bwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, dq_acc,
-    *, bq, bk, bkc, scale, causal, window=None, nq=None, diffusion=None,
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
+    bq, bk, bkc, scale, causal, window=None, nq=None, diffusion=None, selected=0,
 ):
+    sel = None
+    if selected:
+        sel_ref, live_ref, *rest = rest
+    dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, dq_acc = rest
     j, y = pl.program_id(2), pl.program_id(3)
-    i = y if nq is None else y % nq  # nq: q blocks a head, where a group's heads share the sweep
+    if selected:  # ``selected`` heads a group: a q block's heads follow one another, so its tiles of the table are read once
+        i = y // selected
+        sel = _sel_of(sel_ref, live_ref, i)
+    else:
+        i = y if nq is None else y % nq  # nq: q blocks a head, where a group's heads share the sweep
 
     @pl.when(y == 0)
     def _init():
@@ -318,7 +376,7 @@ def _bwd_kernel(
         dk_acc[keys, :] += _dot(dst, q, _NN)  # q carries the scale
         dq_acc[...] += _dot(dst, k, _TN)
 
-    _walk(i, j, bq, bk, bkc, causal, window, diffusion, step)
+    _walk(i, j, bq, bk, bkc, causal, window, diffusion, step, sel)
 
     dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
@@ -386,6 +444,50 @@ def _resident_bytes(bk: int, d: int, dv: int, itemsize: int = 2) -> int:
     return (4 * itemsize + 4) * bk * (d + dv)
 
 
+def _sel_specs(bq: int, bk: int, q_blk):
+    """The blocks of a selection's two arrays: the q block's tiles of the table
+    ``[nq, B, S, bq]`` int8 — ``q_blk(x, y)`` the q block of a grid step — in
+    VMEM, and ``live`` ``[nq, B, S // bkc]`` int32 whole in SMEM."""
+    return [
+        pl.BlockSpec((None, None, bk, bq), lambda b, h, x, y: (q_blk(x, y), b, 0, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+    ]
+
+
+def _fwd_selected(q, k, v, sel, shape, blocks, interpret):
+    """:func:`_fwd` under a selection: the grid is (b, key/value head, q block,
+    the group's heads), so that the group's heads of one q block follow one
+    another and its tiles of the table stay resident."""
+    b, s, h, d, dv, scale, group, _, _ = shape
+    bq, bk, bkc = blocks
+    lanes = q.ndim == 3
+    q_at, k_at = (lambda i, g: i), (lambda i, g: 0)
+    q_head = lambda hk, i, g: hk * group + g
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=True, selected=True),
+        grid=(b, h // group, s // bq, group),
+        in_specs=[
+            _tile(lanes, bq, d, q_at, head=q_head),
+            _tile(lanes, bk, d, k_at),
+            _tile(lanes, bk, dv, k_at),
+            *_sel_specs(bq, bk, q_at),
+        ],
+        out_specs=[_tile(lanes, bq, dv, q_at, head=q_head), _row(bq, q_at, head=q_head)],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape[:-1] + (h * dv if lanes else dv,), q.dtype),
+            jax.ShapeDtypeStruct((b, h, _ROWS, s), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((dv, bq), jnp.float32),
+            pltpu.VMEM((_ROWS, bq), jnp.float32),
+            pltpu.VMEM((_ROWS, bq), jnp.float32),
+        ],
+        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv, q.dtype.itemsize) + 2 * bk * bq),
+        interpret=interpret,
+        name="flash_fwd",
+    )(q, k, v, *sel)
+
+
 def _fwd(q, k, v, shape, blocks, causal, interpret):
     b, s, h, d, dv, scale, group, window, diffusion = shape
     bq, bk, bkc = blocks
@@ -425,14 +527,19 @@ def _fwd(q, k, v, shape, blocks, causal, interpret):
     )(q, k, v)
 
 
-def _bwd(shape, blocks, causal, interpret, res, do):
+def _bwd(shape, blocks, causal, interpret, res, do, sel=None):
     q, k, v, o, lse = res
     b, s, h, d, dv, scale, group, window, diffusion = shape
     bq, bk, bkc = blocks
     lanes = q.ndim == 3
     nk, nq = s // bk, s // bq
     k_at = lambda j, i: j
-    if group == 1:
+    more, more_specs, more_bytes = {}, [], 0
+    if sel is not None:  # a q block's heads follow one another: its tiles of the table are read once a key/value head
+        q_head = lambda h, j, y: h * group + y % group
+        q_blk = lambda j, y: y // group
+        more, more_specs, more_bytes = dict(selected=group), _sel_specs(bq, bk, q_blk), 2 * bk * bq
+    elif group == 1:
         q_head, q_blk = _same_head, (lambda j, i: i)
     else:  # the grid's head is a key/value head; its last axis sweeps the group's heads, nq blocks each
         q_head = lambda h, j, y: h * group + y // nq
@@ -444,7 +551,7 @@ def _bwd(shape, blocks, causal, interpret, res, do):
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kernel, bq=bq, bk=bk, bkc=bkc, scale=scale, causal=causal, window=window,
-            nq=None if group == 1 else nq, diffusion=diffusion,
+            nq=None if group == 1 else nq, diffusion=diffusion, **more,
         ),
         grid=(b, h // group, nk, group * nq),
         in_specs=[
@@ -454,6 +561,7 @@ def _bwd(shape, blocks, causal, interpret, res, do):
             _tile(lanes, bq, dv, q_at, head=q_head),
             _tile(lanes, bq, dv, q_at, head=q_head),
             _row(bq, q_at, head=q_head),
+            *more_specs,
         ],
         out_specs=[
             # dq's part of EVERY (k block, q block), zeros above the diagonal
@@ -472,10 +580,10 @@ def _bwd(shape, blocks, causal, interpret, res, do):
             pltpu.VMEM((bk, dv), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv, q.dtype.itemsize)),
+        compiler_params=_params(bq, bkc, _resident_bytes(bk, d, dv, q.dtype.itemsize) + more_bytes),
         interpret=interpret,
         name="flash_bwd",
-    )(q, k, v, o, do, lse)
+    )(q, k, v, o, do, lse, *(sel or ()))
     dq = dq[0] if nk == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
     return dq, dk, dv
 
@@ -499,6 +607,115 @@ def _flash_fwd(q, k, v, shape, blocks, causal, interpret):
 
 
 _flash.defvjp(_flash_fwd, _bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_selected(q, k, v, selected, live, shape, blocks, interpret):
+    """(output, row statistics) under a selection; the statistics carry no
+    gradient (what reads them — the selector's target — is detached)."""
+    return _fwd_selected(q, k, v, (selected, live), shape, blocks, interpret)
+
+
+def _flash_selected_fwd(q, k, v, selected, live, shape, blocks, interpret):
+    o, lse = _fwd_selected(q, k, v, (selected, live), shape, blocks, interpret)
+    o, lse = checkpoint_name(o, CORE_OUT), checkpoint_name(lse, CORE_LSE)
+    return (o, lse), (q, k, v, o, lse, selected, live)
+
+
+def _flash_selected_bwd(shape, blocks, interpret, res, cts):
+    *res, selected, live = res
+    return (*_bwd(shape, blocks, True, interpret, tuple(res), cts[0], (selected, live)), None, None)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+# ---------------------------------------------------------------------------
+# the selected pairs' probabilities, summed over the query heads: grid (b, key tile) for ONE q block
+# ---------------------------------------------------------------------------
+
+
+def _probs_kernel(i_ref, live_ref, q_ref, k_ref, lse_ref, sel_ref, p_ref, acc_ref, *, heads, group, bq, bkc):
+    b, c = pl.program_id(0), pl.program_id(1)
+    i = i_ref[0]
+    seen = (c * bkc < i * bq + bq) & (live_ref[i, b, c] != 0)  # the walk's tiles: up to the diagonal, and live
+
+    @pl.when(seen)
+    def _sum():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def head(a, _):
+            st = _dot(k_ref[a // group], q_ref[a], _NT)  # q carries the scale, as the core's does
+            acc_ref[...] += jnp.exp(st - lse_ref[a, :1, :])
+
+        jax.lax.fori_loop(0, heads, head, None)
+        p_ref[...] = jnp.where(sel_ref[...].astype(jnp.int32) != 0, acc_ref[...] * (1.0 / heads), 0.0)
+
+    @pl.when(jnp.logical_not(seen))
+    def _none():
+        p_ref[...] = jnp.zeros_like(p_ref)
+
+
+def scaled_head_major(q: jnp.ndarray) -> jnp.ndarray:
+    """[B, S, H, D] -> [B, H, S, D] with 1/sqrt(D) on it, rounded as the core's kernels round it (:func:`_scaled`)."""
+    return (q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(q.dtype).transpose(0, 2, 1, 3)
+
+
+def head_probs(
+    q: jnp.ndarray, k: jnp.ndarray, lse: jnp.ndarray, selected: jnp.ndarray, live: jnp.ndarray, i,
+    block_k: int = 128, interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """``p[b, s, t] = (1/H)·Σ_a exp(q_a[i·bq + t]·k_{a // group}[s] - lse_a[i·bq + t])`` on the pairs q block ``i``
+    selects and 0 elsewhere, float32 ``[B, S, bq]``: the mean over the query heads of each head's softmax over the
+    selected keys, which sums to one over a row's keys — the selector's target. ``q`` [B, H, S, D] is
+    :func:`scaled_head_major`'s, ``k`` [B, Hkv, S, D] head-major as well, ``lse`` [B, H, 8, S] the forward kernel's
+    row statistics, ``selected`` / ``live`` the core's own (:func:`selected_tiles`, :func:`tiles_live`), ``i`` a
+    traced index. The walk is the core's: a tile past the diagonal or without a selected pair is zeros, and its keys
+    are not fetched. No gradient: the target is detached."""
+    b, h, s, d = q.shape
+    nq, _, _, bq = selected.shape
+    bkc = min(block_k, s)
+    group = h // k.shape[1]
+    if interpret is None:
+        interpret = _should_interpret()
+    last = lambda i_ref: (i_ref[0] * bq + bq - 1) // bkc  # the last key tile the q block reaches
+    return pl.pallas_call(
+        functools.partial(_probs_kernel, heads=h, group=group, bq=bq, bkc=bkc),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, s // bkc),
+            in_specs=[
+                pl.BlockSpec((None, h, bq, d), lambda b, c, i_ref, live: (b, 0, i_ref[0], 0)),
+                pl.BlockSpec((None, h // group, bkc, d), lambda b, c, i_ref, live: (b, 0, jnp.minimum(c, last(i_ref)), 0)),
+                pl.BlockSpec((None, h, _ROWS, bq), lambda b, c, i_ref, live: (b, 0, 0, i_ref[0])),
+                pl.BlockSpec((None, None, bkc, bq), lambda b, c, i_ref, live: (i_ref[0], b, jnp.minimum(c, last(i_ref)), 0)),
+            ],
+            out_specs=pl.BlockSpec((None, bkc, bq), lambda b, c, i_ref, live: (b, c, 0)),
+            scratch_shapes=[pltpu.VMEM((bkc, bq), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, s, bq), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=min(112 << 20, (32 << 20) + 40 * bq * bkc),
+        ),
+        interpret=interpret,
+        name="head_probs",
+    )(jnp.asarray(i, jnp.int32).reshape(1), live, q, k, lse, selected)
+
+
+def selected_tiles(seen: jnp.ndarray, block_q: int) -> jnp.ndarray:
+    """A table of pairs ``seen`` [B, Sq, Sk] (True where the query sees the
+    key; causality is the table's to hold) as the kernels read it: int8
+    ``[Sq // bq, B, Sk, bq]``, a q block's tiles transposed as the scores are."""
+    b, sq, sk = seen.shape
+    bq = min(block_q, sq)
+    return seen.reshape(b, sq // bq, bq, sk).transpose(1, 0, 3, 2).astype(jnp.int8)
+
+
+def tiles_live(selected: jnp.ndarray, block_k: int) -> jnp.ndarray:
+    """int32 ``[nq, B, S // bkc]``: whether a tile of ``selected`` holds a seen pair."""
+    nq, b, s, bq = selected.shape
+    bkc = min(block_k, s)
+    return jnp.any(selected.reshape(nq, b, s // bkc, bkc * bq) != 0, axis=-1).astype(jnp.int32)
+
 
 # Most rows of K and V a grid step keeps in VMEM, for heads of TWO lane tiles
 # (256 lanes: ``qwen3-next-80b-a3b-1g``'s 8 192 keys, which compile and load, PR
@@ -531,8 +748,18 @@ def flash_attention(
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
     block_diffusion: int = 0,
-) -> jnp.ndarray:
+    selected: Optional[jnp.ndarray] = None,
+    live: Optional[jnp.ndarray] = None,
+):
     """Causal flash attention. q/k/v: [B, S, H, Dh] -> [B, S, H, Dh].
+
+    ``selected`` (in place of ``causal``, ``window`` and ``block_diffusion``):
+    the pairs seen, as :func:`selected_tiles` lays them out at ``block_q``
+    (int8 ``[S // block_q, B, S, block_q]``; the table holds causality), with
+    ``live`` (:func:`tiles_live` at ``block_k``; worked out here if absent).
+    Returns ``(output, row statistics [B, H, 8, S] float32)`` then: the
+    logsumexp of each head's selected scores, which :func:`head_probs` reads.
+    The table carries no gradient. The whole sequence is one resident K block.
 
     ``block_diffusion`` = D > 0 (in place of ``causal`` and ``window``): the
     rows are a sequence twice, ``[noised ; clean]``, S/2 rows each; row r has
@@ -573,6 +800,8 @@ def flash_attention(
         raise ValueError(f"window={window}: a band is causal and at least one key wide")
     if block_diffusion and window is not None:
         raise ValueError(f"block_diffusion={block_diffusion} with window={window}: the staircase has no band")
+    if selected is not None and (window is not None or block_diffusion):
+        raise ValueError(f"selected with window={window}, block_diffusion={block_diffusion}: the table is the whole rule")
     if d % _LANES and dv % _LANES == 0:
         widen = ((0, 0), (0, 0), (0, 0), (0, -d % _LANES))
         q, k = jnp.pad(q, widen), jnp.pad(k, widen)
@@ -614,5 +843,16 @@ def flash_attention(
         unpack = pack
 
     shape = (b, s, h, d, dv, scale, group, window, diffusion)
+    if selected is not None:
+        if blocks[1] != s or selected.shape != (s // bq, b, s, bq):
+            raise ValueError(
+                f"selected {selected.shape} over {s} rows of {max(d, dv)} lanes at tiles {bq} x {bkc}: the table is "
+                f"[S / block_q, B, S, block_q] and all {s} rows of K and V are one resident block (here {blocks[1]}); a "
+                "selection over several K blocks (the table's blocks a K block, dq's parts) is missing"
+            )
+        if live is None:
+            live = tiles_live(selected, bkc)
+        o, lse = _flash_selected(pack(q), pack(k), pack(v), selected, live, shape, blocks, interpret)
+        return unpack(o), lse
     o = _flash(pack(q), pack(k), pack(v), shape, blocks, causal, interpret)
     return unpack(o)

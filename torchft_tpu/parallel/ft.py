@@ -219,6 +219,34 @@ class FTTrainer:
         with tracing.annotate("diffusion.counters", **counters):
             pass
 
+    def _record_sparse_counters(self, step: int, sync_span) -> None:
+        """What a learned key selector says of the step (``loss_and_stats``:
+        ``lm_loss``, ``indexer_loss``, ``selected_mean``, ``selected_over_k``,
+        ``sparse_tiles_live_share``), fetched with the loss: the next-token
+        loss apart from the selector's own term (summed over layers), the keys
+        a query attended to, the rows a tie at the threshold gave more than k,
+        and the share of the kernel's causal key tiles that hold a selected
+        pair (the mean over layers: what a walk that steps over dead tiles
+        saves) — on the ``loss_sync`` span in the Tracer ring and, as
+        ``tft.sparse.counters``, in a profiler trace. Any other model emits
+        nothing."""
+        stats = self._ts.last_stats
+        if "indexer_loss" not in stats:
+            return
+        import numpy as np
+
+        counters = dict(
+            step=step,
+            lm_loss=float(stats["lm_loss"]),
+            indexer_loss=float(np.sum(stats["indexer_loss"])),
+            selected_mean=float(stats["selected_mean"]),
+            selected_over_k=float(stats["selected_over_k"]),
+            sparse_tiles_live_share=float(np.mean(stats["sparse_tiles_live_share"])),
+        )
+        sync_span.set(**counters)
+        with tracing.annotate("sparse.counters", **counters):
+            pass
+
     # -- drive --
 
     def step(self, tokens) -> Tuple[float, bool]:
@@ -266,5 +294,6 @@ class FTTrainer:
                 self._record_gdn_counters(label, sync_span)
                 self._record_loop_counters(label, sync_span)
                 self._record_diffusion_counters(label, sync_span)
+                self._record_sparse_counters(label, sync_span)
             step_span.set(committed=committed)
         return loss, committed
